@@ -47,10 +47,14 @@ def init(address: Optional[str] = None, *,
     if graftprof is not None:
         from ray_tpu.utils.config import GlobalConfig
         GlobalConfig.initialize({"graftprof": bool(graftprof)})
+    import os
+
+    from ray_tpu import accelerators
+    # Before any process is spawned: the agent and its workers inherit it.
+    accelerators.compile_cache_env(os.environ)
     if address is None:
         # Driver scripts launched by job submission (and the reference's
         # RAY_ADDRESS convention) connect via env.
-        import os
         address = os.environ.get("RAY_TPU_ADDRESS") or None
     if address is None:
         _global_node = LocalNode(resources=resources)

@@ -53,8 +53,10 @@ def _get_lib():
                     from ray_tpu.core.object_store import _get_lib as gl
                     _lib = gl()
                 except Exception as e:  # missing toolchain/build failure
-                    logger.debug("graftcopy native library unavailable: %r",
-                                 e)
+                    # Loud: the plane silently turning itself off is how
+                    # a build fault hides (chip_smoke.py fails on it).
+                    logger.warning("graftcopy native library unavailable; "
+                                   "plane disabled: %r", e)
                     _lib = False
     return _lib or None
 

@@ -1040,12 +1040,32 @@ class Controller:
                 s.health = "alive"
         return dead
 
+    def _forgive_stall(self, stall_s: float) -> None:
+        """The health loop just woke `stall_s` late: this process was not
+        running (its own loop was blocked, or the whole machine froze — a
+        TPU runtime starting up in any process can stop every process of
+        a VM for seconds while it pins memory). Heartbeats and pulses
+        that arrived meanwhile are still unread in their sockets, so
+        silence over that stretch is evidence about the controller, not
+        about any node: push every node's liveness clock forward by it
+        instead of declaring a healthy cluster dead."""
+        logger.warning("controller did not run for %.1fs; not counting "
+                       "it as node silence", stall_s)
+        for node in self.nodes.values():
+            node.last_heartbeat += stall_s
+        for series in self.pulse.series.values():
+            series.last_rx_mono += stall_s
+
     async def _health_loop(self) -> None:
         period = GlobalConfig.health_check_period_ms / 1000
         timeout = GlobalConfig.health_check_timeout_ms / 1000
         last_reconcile = time.monotonic()
         while True:
+            slept_at = time.monotonic()
             await asyncio.sleep(period)
+            stall = time.monotonic() - slept_at - period
+            if stall > period:
+                self._forgive_stall(stall)
             cutoff = time.monotonic() - timeout
             for node in list(self.nodes.values()):
                 if node.state == NodeState.ALIVE and node.last_heartbeat < cutoff:

@@ -632,10 +632,7 @@ class NodeAgent:
                 "its tasks are retriable" if retriable
                 else "restartable actor")
             self.num_oom_kills = getattr(self, "num_oom_kills", 0) + 1
-            try:
-                victim.proc.terminate()
-            except Exception:
-                pass
+            self._stop_worker(victim)
             # Cooldown: let the kill land and memory readings catch up
             # before selecting another victim, else sustained pressure
             # kills one worker per tick faster than /proc/meminfo moves.
@@ -672,10 +669,7 @@ class NodeAgent:
             for w in list(self.idle_workers):
                 if w.idle_since and now - w.idle_since > ttl:
                     self.idle_workers.remove(w)
-                    try:
-                        w.proc.terminate()
-                    except Exception:
-                        pass
+                    self._stop_worker(w)
 
     @staticmethod
     def _pid_alive(pid: int) -> bool:
@@ -916,7 +910,8 @@ class NodeAgent:
                       python_exe: Optional[str] = None,
                       memory_bytes: Optional[int] = None,
                       cpus: Optional[float] = None,
-                      image_uri: Optional[str] = None) -> WorkerProc:
+                      image_uri: Optional[str] = None,
+                      holds_tpu: bool = False) -> WorkerProc:
         env = dict(os.environ)
         stack_token = f"{os.getpid()}-{self._worker_seq}-{time.time_ns()}"
         env["RAY_TPU_STACK_TOKEN"] = stack_token
@@ -927,6 +922,14 @@ class NodeAgent:
         env["RAY_TPU_SESSION_DIR"] = self.session_dir
         if getattr(self, "_sock_path", ""):
             env["RAY_TPU_AGENT_SOCK"] = self._sock_path
+        from ray_tpu import accelerators
+        accelerators.compile_cache_env(env)
+        if self.resources_total.get("TPU") and not holds_tpu:
+            # A chip belongs to one process at a time and this agent
+            # does the accounting: a worker that was granted no chip
+            # (pooled task workers, zero-TPU actors) must not open one,
+            # or the worker that WAS granted it fails or hangs.
+            env["JAX_PLATFORMS"] = "cpu"
         if extra_env:
             # runtime_env env_vars (reference: runtime_env plugin env_vars)
             # must land before the interpreter starts: JAX/XLA read
@@ -1273,10 +1276,7 @@ class NodeAgent:
                     w.ready.wait(), GlobalConfig.worker_register_timeout_s)
                 self._push_idle(w)
             except Exception:
-                try:
-                    w.proc.terminate()
-                except Exception:
-                    pass
+                self._stop_worker(w)
 
     async def _pop_worker(self) -> WorkerProc:
         while self.idle_workers:
@@ -1299,7 +1299,7 @@ class NodeAgent:
                 w.idle_since = time.monotonic()
                 self.idle_workers.append(w)
             else:
-                w.proc.terminate()
+                self._stop_worker(w)
 
     # ------------------------------------------------------------------
     # leases (reference: cluster_lease_manager.cc QueueAndScheduleLease +
@@ -1723,15 +1723,24 @@ class NodeAgent:
         chips: List[int] = []
         n_tpu = int(tpu_req)
         if n_tpu > 0:
-            if len(self.tpu_free_chips) < n_tpu:
-                resources_add(avail, resources)
-                raise RuntimeError("insufficient TPU chips for actor")
-            chips = self.tpu_free_chips[:n_tpu]
-            del self.tpu_free_chips[:n_tpu]
             from ray_tpu import accelerators
             env_vars = dict(env_vars or {})
-            # Explicit user pinning wins over automatic assignment.
-            for k, v in accelerators.worker_env_for_chips(chips).items():
+            # Explicit pinning (a train gang maps rank r to chips r*c..)
+            # wins over automatic assignment, and is what gets accounted.
+            pinned = env_vars.get("TPU_VISIBLE_CHIPS")
+            chips = ([int(c) for c in pinned.split(",")] if pinned
+                     else self.tpu_free_chips[:n_tpu])
+            if len(chips) != n_tpu or \
+                    not set(chips) <= set(self.tpu_free_chips):
+                resources_add(avail, resources)
+                raise RuntimeError(
+                    f"insufficient TPU chips for actor: need {n_tpu}"
+                    + (f" as TPU_VISIBLE_CHIPS={pinned}" if pinned else "")
+                    + f", free: {self.tpu_free_chips}")
+            self.tpu_free_chips = [c for c in self.tpu_free_chips
+                                   if c not in chips]
+            for k, v in accelerators.worker_env_for_chips(
+                    chips, int(self.resources_total["TPU"])).items():
                 env_vars.setdefault(k, v)
         w: Optional[WorkerProc] = None
         try:
@@ -1750,7 +1759,7 @@ class NodeAgent:
                 memory_bytes=int(resources["memory"])
                 if resources.get("memory") else None,
                 cpus=float(resources.get("CPU", 0)) or None,
-                image_uri=image_uri)
+                image_uri=image_uri, holds_tpu=bool(chips))
             await asyncio.wait_for(w.ready.wait(),
                                    GlobalConfig.worker_register_timeout_s)
             w.dedicated_actor = actor_id
@@ -1769,22 +1778,42 @@ class NodeAgent:
             self.tpu_assigned.pop(actor_id, None)
             if w is not None:
                 w.dedicated_actor = None
-                try:
-                    w.proc.terminate()
-                except Exception:
-                    pass
+                self._stop_worker(w)
             resources_add(avail, resources)
             if chips:
                 self.tpu_free_chips.extend(chips)
                 self.tpu_free_chips.sort()
             raise
 
+    # How long a worker gets to die of SIGTERM before SIGKILL.
+    _KILL_GRACE_S = 3.0
+
+    def _stop_worker(self, w: WorkerProc) -> None:
+        """SIGTERM now, SIGKILL if the process is still there after a
+        grace. SIGTERM alone is not enough: jax.distributed installs a
+        handler that merely notes a "preemption" (found with the train
+        gang of chip_smoke.py --chips 4, whose workers outlived their
+        job), and a worker that lingers keeps its TPU chip from the next
+        process that is granted it."""
+        try:
+            w.proc.terminate()
+        except Exception:
+            return
+
+        def _kill() -> None:
+            if w.proc.poll() is None:
+                logger.warning("worker pid %s ignored SIGTERM for %.0fs; "
+                               "killing it", w.proc.pid, self._KILL_GRACE_S)
+                w.proc.kill()
+
+        asyncio.get_running_loop().call_later(self._KILL_GRACE_S, _kill)
+
     async def kill_actor_worker(self, actor_id: bytes) -> None:
         for w in self.workers.values():
             if w.dedicated_actor == actor_id:
                 w.dedicated_actor = None  # suppress death report (intended)
                 await self._release_actor_allocation(actor_id)
-                w.proc.terminate()
+                self._stop_worker(w)
                 return
 
     # ------------------------------------------------------------------
@@ -2428,12 +2457,21 @@ class NodeAgent:
                 self._fastpath.stop()
             except Exception:
                 pass
-        for w in self.workers.values():
+        live = [w for w in self.workers.values() if w.proc.poll() is None]
+        for w in live:
+            try:
+                w.proc.terminate()
+            except Exception:
+                pass
+        # As _stop_worker: SIGKILL whatever SIGTERM did not end (this
+        # process is about to exit and nothing would do it later).
+        deadline = time.monotonic() + self._KILL_GRACE_S
+        while time.monotonic() < deadline and any(
+                w.proc.poll() is None for w in live):
+            await asyncio.sleep(0.05)
+        for w in live:
             if w.proc.poll() is None:
-                try:
-                    w.proc.terminate()
-                except Exception:
-                    pass
+                w.proc.kill()
         # Workers' graftrpc listener sockets live in the session dir;
         # terminated workers can't unlink their own, so sweep them here.
         try:

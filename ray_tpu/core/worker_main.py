@@ -53,8 +53,19 @@ def main() -> None:
             if os.getppid() != parent:  # agent died; fate-share
                 break
     finally:
-        cw.shutdown()
-        sys.exit(0)
+        # The agent is gone and this process must not outlive it — least
+        # of all holding a TPU chip the next job needs. A soft exit can
+        # block for minutes in another library's atexit hook
+        # (jax.distributed's shutdown barrier waits for peers that were
+        # killed), so: bounded runtime cleanup, then a hard exit.
+        import threading
+        threading.Timer(5.0, os._exit, (0,)).start()
+        try:
+            cw.shutdown()
+            sys.stdout.flush()
+            sys.stderr.flush()
+        finally:
+            os._exit(0)
 
 
 if __name__ == "__main__":

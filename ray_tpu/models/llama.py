@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_tpu.ops.attention import flash_attention, repeat_kv
+from ray_tpu.ops.attention import (flash_attention, pallas_eligible,
+                                   repeat_kv)
 from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.ops.norms import apply_rope, rms_norm, rope_frequencies
 from ray_tpu.ops.ring_attention import ring_attention
@@ -166,8 +167,47 @@ def param_count(cfg: LlamaConfig) -> int:
 # Forward
 # ---------------------------------------------------------------------------
 
+def _mesh_axes(spec_entry) -> Tuple[str, ...]:
+    if spec_entry is None:
+        return ()
+    return (spec_entry,) if isinstance(spec_entry, str) else tuple(spec_entry)
+
+
+def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
+               ctx: Optional[ParallelContext]) -> jax.Array:
+    """Causal flash attention on [B, H, S, hd] under the context's mesh.
+
+    GSPMD cannot partition a Mosaic (Pallas TPU) kernel, and its
+    lowering insists that EVERY mesh axis be manual around it. So where
+    the kernel path is taken on a multi-device mesh (the XLA reference
+    path partitions like any other op and keeps GSPMD's freedom to pad
+    uneven batches), the call sits in a shard_map over the whole mesh:
+    each device runs the kernel on its own [B/batch_axes, H/tp, S, hd]
+    block, replicated over the remaining axes, and no collective is
+    needed (attention never mixes batch rows or heads)."""
+    if ctx is None or ctx.mesh.size == 1 or not pallas_eligible(q, k):
+        return flash_attention(q, k, v, True)
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        # Inside the pp shard_map the kernel would need a NESTED
+        # shard_map over the remaining axes; jax 0.9 compiles its
+        # forward but Shardy rejects the sharding of the residual it
+        # saves for the backward ("manual axis after free axis").
+        raise NotImplementedError(
+            "the Pallas attention kernel cannot run under pipeline "
+            "parallelism (pp > 1) on TPU yet: jax 0.9 cannot "
+            "differentiate a shard_map nested in the pp shard_map. Use "
+            "dp/fsdp/tp (or sp ring attention) without pp.")
+    batch_axes = _mesh_axes(ctx.rules["batch"])
+    head_axes = _mesh_axes(ctx.rules["heads"])
+    spec = P(batch_axes or None, head_axes or None, None, None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, True), mesh=ctx.mesh,
+        in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
+
+
 def _layer_fwd(lp: Dict[str, jax.Array], x: jax.Array, cos, sin, positions,
-               cfg: LlamaConfig, sp_manual: bool) -> jax.Array:
+               cfg: LlamaConfig, sp_manual: bool,
+               ctx: Optional[ParallelContext] = None) -> jax.Array:
     B, S, D = x.shape
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
@@ -186,7 +226,7 @@ def _layer_fwd(lp: Dict[str, jax.Array], x: jax.Array, cos, sin, positions,
     if sp_manual:
         attn = ring_attention(q, k, v, axis_name="sp", causal=True)
     else:
-        attn = flash_attention(q, k, v, True)
+        attn = _attention(q, k, v, ctx)
     attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
 
@@ -207,7 +247,9 @@ def _layer_fwd(lp: Dict[str, jax.Array], x: jax.Array, cos, sin, positions,
 
 
 def _stack_fwd(layers_p: Dict[str, Any], x: jax.Array, cos, sin,
-               cfg: LlamaConfig, sp_manual: bool) -> Tuple[jax.Array, jax.Array]:
+               cfg: LlamaConfig, sp_manual: bool,
+               ctx: Optional[ParallelContext] = None
+               ) -> Tuple[jax.Array, jax.Array]:
     """Scan over a stack of layers (leading 'layers' axis on every leaf).
 
     Returns (x, summed MoE aux loss across the stack)."""
@@ -219,7 +261,8 @@ def _stack_fwd(layers_p: Dict[str, Any], x: jax.Array, cos, sin,
 
     def body(carry, lp):
         x, aux_sum = carry
-        x, aux = _layer_fwd(lp, x, cos, sin, positions, cfg, sp_manual)
+        x, aux = _layer_fwd(lp, x, cos, sin, positions, cfg, sp_manual,
+                            ctx)
         return (x, aux_sum + aux), None
 
     if cfg.remat:
@@ -271,7 +314,7 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
         x_mb = x.reshape(M, B // M, *x.shape[1:])
 
         stage_fn = functools.partial(_stack_fwd, cos=cos, sin=sin, cfg=cfg,
-                                     sp_manual=sp_manual)
+                                     sp_manual=sp_manual, ctx=ctx)
         manual = {"pp"} | ({"sp"} if sp_manual else set())
         param_spec = jax.tree.map(lambda _: P("pp"), stage_layers)
         mb_spec = P(None, None, "sp", None) if sp_manual else P()
@@ -303,7 +346,7 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
             axis_names={"sp"})
         x, aux = stack(params["layers"], x)
     else:
-        x, aux = _stack_fwd(params["layers"], x, cos, sin, cfg, False)
+        x, aux = _stack_fwd(params["layers"], x, cos, sin, cfg, False, ctx)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(dt))

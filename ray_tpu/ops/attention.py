@@ -20,26 +20,61 @@ Layout: [batch, num_heads, seq, head_dim] (BHSD).
 
 from __future__ import annotations
 
+import collections
 import functools
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-only module; import guarded so CPU test envs can load this file.
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from ray_tpu.utils import get_logger
+
+logger = get_logger("ops.attention")
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
+# Which implementation each traced flash_attention call took, counted at
+# TRACE time ("fwd_pallas", "fwd_reference", "bwd_pallas",
+# "bwd_reference"): the dispatch below is otherwise invisible from
+# outside a jitted program, and a benchmark must be able to assert that
+# the kernel it names is the one that ran.
+_path_counts: collections.Counter = collections.Counter()
+
+
+def attention_path_counts() -> Dict[str, int]:
+    """Copy of the per-process trace-time path counters."""
+    return dict(_path_counts)
+
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
+
+
+def pallas_eligible(q: jax.Array, k: jax.Array) -> bool:
+    """The dispatch gate: Pallas on a TPU backend for 128-aligned
+    sequence lengths and head dims, the XLA reference path otherwise
+    (CPU tests, unaligned shapes)."""
+    return (_on_tpu() and q.shape[2] % 128 == 0 and k.shape[2] % 128 == 0
+            and q.shape[-1] % 128 == 0)
+
+
+def _use_pallas(kind: str, q: jax.Array, k: jax.Array) -> bool:
+    """pallas_eligible, with the choice counted and logged."""
+    use = pallas_eligible(q, k)
+    path = "pallas" if use else "reference"
+    _path_counts[f"{kind}_{path}"] += 1
+    logger.debug("flash_attention %s: %s path, q=%s kv_len=%d", kind, path,
+                 q.shape, k.shape[2])
+    return use
+
+
+def _out_struct(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
+    """pallas_call output type that varies over the same manual mesh
+    axes as `like` — inside a shard_map the kernel's outputs are as
+    device-varying as its inputs, and shard_map's checker needs it said."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _pick_block(seq: int, pref: int) -> int:
@@ -165,8 +200,8 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q=1024,
             pl.BlockSpec((1, 1, block_q), lambda bh, qi, ki: (bh, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32),
+            _out_struct((b * h, sq, d), q.dtype, q),
+            _out_struct((b * h, 1, sq), jnp.float32, q),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -341,8 +376,8 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, *, causal, sm_scale,
             pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, skv, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, skv, d), v.dtype),
+            _out_struct((b * h, skv, d), k.dtype, k),
+            _out_struct((b * h, skv, d), v.dtype, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -369,7 +404,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, *, causal, sm_scale,
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
         ],
-        out_shape=[jax.ShapeDtypeStruct((b * h, sq, d), q.dtype)],
+        out_shape=[_out_struct((b * h, sq, d), q.dtype, q)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -393,8 +428,7 @@ def flash_attention(q, k, v, causal: bool = True,
 
 def _flash_fwd(q, k, v, causal, sm_scale):
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    if _on_tpu() and q.shape[2] % 128 == 0 and k.shape[2] % 128 == 0 \
-            and q.shape[-1] % 128 == 0:
+    if _use_pallas("fwd", q, k):
         return _flash_fwd_pallas(q, k, v, causal=causal, sm_scale=scale)
     return _fwd_with_lse_reference(q, k, v, causal=causal, sm_scale=scale)
 
@@ -407,8 +441,7 @@ def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_k_bwd):
 def _flash_vjp_bwd(causal, sm_scale, block_k_bwd, res, dout):
     q, k, v, out, lse = res
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    if _on_tpu() and q.shape[2] % 128 == 0 and k.shape[2] % 128 == 0 \
-            and q.shape[-1] % 128 == 0:
+    if _use_pallas("bwd", q, k):
         return _flash_bwd_pallas(q, k, v, out, lse, dout, causal=causal,
                                  sm_scale=scale)
     skv = k.shape[2]

@@ -171,14 +171,7 @@ def _merge_hybrid(groups: list, config: "MeshConfig") -> Mesh:
     virtual CPU slices work identically for the multi-chip dry run."""
     ici_shape = config.ici_shape
     dcn_shape = config.dcn_shape
-    slice_arrays = []
-    for g in groups:
-        try:
-            a = mesh_utils.create_device_mesh(
-                ici_shape, devices=g, allow_split_physical_axes=True)
-        except Exception:
-            a = np.array(g).reshape(ici_shape)
-        slice_arrays.append(a)
+    slice_arrays = [_ici_mesh(ici_shape, g) for g in groups]
     arr = np.empty(dcn_shape + ici_shape, dtype=object)
     for si, sa in enumerate(slice_arrays):
         arr[np.unravel_index(si, dcn_shape)] = sa
@@ -187,6 +180,16 @@ def _merge_hybrid(groups: list, config: "MeshConfig") -> Mesh:
     k = len(AXIS_NAMES)
     arr = arr.transpose([ax for i in range(k) for ax in (i, k + i)])
     return Mesh(arr.reshape(config.shape), AXIS_NAMES)
+
+
+def _ici_mesh(shape: tuple, devices: list) -> np.ndarray:
+    """Device array for one slice. create_device_mesh lays the axes onto
+    the physical torus for devices that have coordinates and is a plain
+    reshape for those that do not (CPU), so it is never second-guessed:
+    a topology it cannot map raises, instead of silently becoming a
+    naive reshape that puts the wrong collectives on the wrong links."""
+    return mesh_utils.create_device_mesh(
+        shape, devices=devices, allow_split_physical_axes=True)
 
 
 def _select_single_slice(devices: list, n: int) -> list:
@@ -225,14 +228,15 @@ def build_mesh(config: MeshConfig,
         raise ValueError(
             f"MeshConfig {config} needs {n} devices but only {len(devices)} available")
     devices = list(devices)
+    if n < len(devices) and devices[0].platform != "cpu":
+        from ray_tpu.utils import get_logger
+        get_logger("mesh").warning(
+            "MeshConfig %s uses %d of the %d %s devices this process "
+            "holds; the rest stay idle", config, n, len(devices),
+            devices[0].platform)
     if config.num_slices == 1:
         devices = _select_single_slice(devices, n)
-        try:
-            dev_array = mesh_utils.create_device_mesh(
-                config.shape, devices=devices, allow_split_physical_axes=True)
-        except Exception:
-            dev_array = np.array(devices).reshape(config.shape)
-        return Mesh(dev_array, AXIS_NAMES)
+        return Mesh(_ici_mesh(config.shape, devices), AXIS_NAMES)
 
     # Multi-slice (DCN) mesh. Validate axis/DCN divisibility up front
     # (ici_shape raises the precise error; per = prod(ici_shape) >= 1

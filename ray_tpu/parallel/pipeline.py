@@ -24,13 +24,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _vary(x, axis_name):
-    try:
-        return jax.lax.pcast(x, (axis_name,), to="varying")
-    except AttributeError:  # pragma: no cover - older jax spelling
-        return jax.lax.pvary(x, (axis_name,))
-
-
 def gpipe_spmd(stage_fn: Callable[[Any, jax.Array], "tuple[jax.Array, jax.Array] | jax.Array"],
                stage_params: Any,
                microbatches: jax.Array,
@@ -57,7 +50,7 @@ def gpipe_spmd(stage_fn: Callable[[Any, jax.Array], "tuple[jax.Array, jax.Array]
 
     # mb_in: cast to pp-varying; init buffers derive from it (times zero) so
     # they inherit every other manual axis the caller's shard_map has (e.g. sp).
-    mb_in = _vary(microbatches, axis_name)
+    mb_in = jax.lax.pcast(microbatches, (axis_name,), to="varying")
     out0 = mb_in * 0
     state0 = out0[0]
     # Scalar zero derived from out0 so it inherits the manual-axis varying

@@ -22,10 +22,12 @@ points at a checkpoint saved by ray_tpu.train).
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu.serve as serve
+from ray_tpu.utils import get_logger
 
 
 @dataclass
@@ -43,6 +45,14 @@ class LLMConfig:
     params_path: str = ""          # ray_tpu.train checkpoint dir (optional)
     tokenizer: Optional[Callable[[str], List[int]]] = None
     detokenizer: Optional[Callable[[List[int]], str]] = None
+
+
+def _check_engine_health(engine) -> None:
+    """Serve's health probe for an engine-backed replica: a dead engine
+    loop, or a prefill bucket the compiler refused, makes it unhealthy."""
+    fault = engine.error or engine.warm_error
+    if fault:
+        raise RuntimeError(f"LLM engine unhealthy:\n{fault}")
 
 
 class LLMServer:
@@ -73,6 +83,32 @@ class LLMServer:
         if self.cfg.detokenizer is not None:
             return self.cfg.detokenizer(ids)
         return ids
+
+    def check_health(self) -> None:
+        _check_engine_health(self.engine)
+
+    def device_info(self) -> Dict[str, Any]:
+        """Where this replica runs: the device as JAX reports it, the
+        chips the agent pinned, the attention path each traced program
+        took, and whether each warmed prefill width holds the kernel."""
+        import jax
+
+        from ray_tpu.ops.attention import attention_path_counts
+
+        dev = jax.devices()[0]
+        return {
+            "platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count(),
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "pid": os.getpid(),
+            "attention_paths": attention_path_counts(),
+            "warm_buckets": sorted(self.engine._warm),
+            "prefill_has_tpu_custom_call": {
+                w: "tpu_custom_call" in self.engine.lowered_prefill_text(w)
+                for w in sorted(self.engine._warm)},
+            "compile_cache_dir": os.environ.get(
+                "JAX_COMPILATION_CACHE_DIR"),
+        }
 
     def __call__(self, body: Dict[str, Any]):
         """Streaming completion: yields decoded chunks (OpenAI-ish
@@ -153,6 +189,14 @@ def _model_from_cfg(cfg: "LLMConfig"):
         params = jax.tree.map(jnp.asarray, _unflatten(host))
     else:
         params = init_params(mcfg, jax.random.PRNGKey(0))
+    # device_put with no target lands on device 0: right only if chip
+    # pinning really left this replica the chips it was granted.
+    if jax.devices()[0].platform == "tpu" and cfg.num_tpus and \
+            jax.device_count() != int(cfg.num_tpus):
+        raise RuntimeError(
+            f"replica was granted num_tpus={cfg.num_tpus} but sees "
+            f"{jax.device_count()} TPU devices (TPU_VISIBLE_CHIPS="
+            f"{os.environ.get('TPU_VISIBLE_CHIPS')!r}): chip pinning failed")
     return mcfg, jax.device_put(params)
 
 
@@ -222,6 +266,8 @@ class PrefillServer:
         for width in sorted(self._warm):
             warm(width)
 
+        self.warm_error: Optional[str] = None
+
         def warm_rest():
             for width in self.buckets:
                 if width not in self._warm:
@@ -229,10 +275,20 @@ class PrefillServer:
                         warm(width)
                         self._warm.add(width)
                     except Exception:
+                        import traceback
+                        self.warm_error = traceback.format_exc()
+                        get_logger("serve.llm").error(
+                            "prefill bucket %d failed to warm:\n%s",
+                            width, self.warm_error)
                         return
 
         threading.Thread(target=warm_rest, daemon=True,
                          name="prefill-bucket-warm").start()
+
+    def check_health(self) -> None:
+        if self.warm_error:
+            raise RuntimeError(
+                f"prefill bucket warm-up failed:\n{self.warm_error}")
 
     def prefill(self, body: Dict[str, Any]) -> Dict[str, Any]:
         import jax.numpy as jnp
@@ -283,6 +339,9 @@ class DecodeServer:
                              decode_chunk=cfg.decode_chunk,
                              page_size=cfg.page_size,
                              n_pages=cfg.kv_pages)
+
+    def check_health(self) -> None:
+        _check_engine_health(self.engine)
 
     def decode_stream(self, meta: Dict[str, Any]):
         """Pull the prefilled KV (device plane; slice-aware) and stream

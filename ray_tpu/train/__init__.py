@@ -10,8 +10,19 @@ from ray_tpu.train.scaling_policy import (ElasticScalingPolicy,
                                           ScalingPolicy)
 from ray_tpu.train.session import (get_context, get_dataset_shard, profile,
                                    report, save_checkpoint)
-from ray_tpu.train.spmd import (default_optimizer, make_train_fns,
-                                state_shardings)
+
+_SPMD_NAMES = ("default_optimizer", "make_train_fns", "state_shardings")
+
+
+def __getattr__(name):
+    # Lazy: spmd imports jax at module level, and a driver that only
+    # builds a JaxTrainer must stay off jax (the chip belongs to its
+    # workers; see chip_smoke.py).
+    if name in _SPMD_NAMES:
+        from ray_tpu.train import spmd
+        return getattr(spmd, name)
+    raise AttributeError(name)
+
 
 __all__ = [
     "AsyncCheckpointer", "Checkpoint", "CheckpointConfig",

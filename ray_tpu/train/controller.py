@@ -127,11 +127,13 @@ class TrainController:
         port = cw._run(cw._client_for_worker(addr0).call(
             "probe_free_port")).result()
         coord = f"{addr0[0]}:{port}"
+        tpu_env = self._tpu_gang_env(info["bundle_nodes"], nodes, n)
 
         actor_cls = ray_tpu.remote(TrainWorker)
         workers = []
         for rank in range(n):
-            env: Dict[str, Optional[str]] = dict(self._worker_env)
+            env: Dict[str, Optional[str]] = dict(tpu_env[rank])
+            env.update(self._worker_env)
             env["RAY_TPU_TRAIN_COORD"] = coord
             env["RAY_TPU_TRAIN_RANK"] = str(rank)
             env["RAY_TPU_TRAIN_WORLD"] = str(n)
@@ -145,6 +147,44 @@ class TrainController:
                 opts["num_tpus"] = float(self._scaling.chips_per_worker or 1)
             workers.append(actor_cls.options(**opts).remote())
         return workers
+
+    def _tpu_gang_env(self, bundle_nodes: list, nodes: dict,
+                      n: int) -> List[Dict[str, str]]:
+        """Per-rank TPU env that makes the gang ONE topology. One worker
+        per host needs nothing here: each holds its host's chips and the
+        TPU runtime's own env joins the hosts. Several workers on one
+        host are each pinned to their own chips, and unless libtpu is
+        told they form one process grid (bounds, each other's ports,
+        task ids) each is an island and the first cross-process
+        collective has no ICI topology to ride."""
+        hosts = set(bundle_nodes)
+        if not self._scaling.use_tpu or len(hosts) == n:
+            return [{} for _ in range(n)]
+        c = int(self._scaling.chips_per_worker or 1)
+        host_chips = int(nodes[bundle_nodes[0]]["resources_total"]
+                         .get("TPU", 0))
+        if len(hosts) > 1 or n * c != host_chips:
+            raise TrainingFailedError(
+                f"{n} TPU workers x {c} chips landed on {len(hosts)} "
+                f"host(s) of {host_chips} chips: workers that share a "
+                f"host must together hold all of its chips, on one "
+                f"host (libtpu joins processes only as a full grid); "
+                f"use one worker per host or num_workers * "
+                f"chips_per_worker == chips on the host")
+        from ray_tpu import accelerators
+        cw = _api._cw()
+        addr = tuple(nodes[bundle_nodes[0]]["addr"])
+        ports: List[int] = []
+        while len(ports) < n:
+            p = cw._run(cw._client_for_worker(addr).call(
+                "probe_free_port")).result()
+            if p not in ports:
+                ports.append(p)
+        try:
+            return [accelerators.gang_env(rank, n, c, ports, host=addr[0])
+                    for rank in range(n)]
+        except ValueError as e:
+            raise TrainingFailedError(str(e)) from e
 
     def _teardown(self, pg, workers) -> None:
         for w in workers:

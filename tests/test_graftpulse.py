@@ -123,6 +123,33 @@ def test_aggregator_folds_nodes_and_drops_garbage():
     assert agg.total_queue_depth() == 2
 
 
+def test_controller_forgives_silence_it_slept_through():
+    """A stall of the controller itself (or of the whole machine: a TPU
+    runtime start-up freezes every process of a VM for seconds) must not
+    read as node silence — found on the chip, where it killed a healthy
+    one-node cluster 9 s into its first train job."""
+    import time
+
+    from ray_tpu.core.controller import Controller, NodeEntry
+
+    c = Controller()
+    node_id = b"\x07" * 16
+    node = c.nodes[node_id] = NodeEntry(node_id, ("127.0.0.1", 1),
+                                        {"CPU": 1.0}, {})
+    stalled_for = 9.0  # > pulse_dead_ms: the FSM's verdict would be "dead"
+    woke = time.monotonic()
+    node.last_heartbeat = woke - stalled_for - 0.5
+    c.pulse.ingest(node_id.hex()[:12], graftpulse.encode(_pulse(seq=1)),
+                   rx_mono=woke - stalled_for - 0.5)
+    assert [n for n, _ in c._pulse_health_pass()] == [node_id]
+    c._forgive_stall(stalled_for)
+    assert c._pulse_health_pass() == []
+    assert woke - node.last_heartbeat < 1.0
+    # Real silence still counts: nothing arrives for another 9 s.
+    c.pulse.series[node_id.hex()[:12]].last_rx_mono -= stalled_for
+    assert [n for n, _ in c._pulse_health_pass()] == [node_id]
+
+
 def test_aggregator_window_bounds_aggregates():
     """snapshot(window=N) folds only the last N pulses per node — the
     contract behind /api/cluster?window=N and the soak verdict's

@@ -91,6 +91,10 @@ class _FakeDev:
     single-process fixture have none, so the by_slice path was untested
     before round 5 — VERDICT r4 weak #2)."""
 
+    # No coordinates: create_device_mesh lays these out like CPU devices.
+    platform = "cpu"
+    device_kind = "cpu"
+
     def __init__(self, i, slice_index):
         self.id = i
         self.slice_index = slice_index

@@ -26,6 +26,8 @@ CONFIGS = [
     ("pp2_dp2_fsdp2", MeshConfig(pp=2, dp=2, fsdp=2), TINY),
     ("ep2_dp2_tp2", MeshConfig(dp=2, ep=2, tp=2), TINY_MOE),
     ("pp2_ep2_sp2", MeshConfig(pp=2, ep=2, sp=2), TINY_MOE),
+    # Two virtual slices: dp crosses DCN outermost, tp stays inside a slice.
+    ("dcn2_dp4_tp2", MeshConfig(dp=4, tp=2, dcn_dp=2), TINY),
 ]
 
 

@@ -1,0 +1,168 @@
+"""Compiles for a TPU that is described, not attached, plus the env that
+pins worker processes to chips.
+
+The TPU compiler is installed wherever libtpu is, so the kernels of the
+main path are compiled here for `v5e:2x2` at their real shapes: what the
+chip's compiler would refuse (a slice off the tiling, too much VMEM, a
+Mosaic call GSPMD cannot partition) fails in tier-1 and costs no chip
+time. A compile is not a run — tests/test_ops.py checks values (on the
+reference path) and chip_smoke.py checks them on the chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from ray_tpu import accelerators  # noqa: E402
+from ray_tpu.ops import attention  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def topo():
+    try:
+        from jax.experimental import topologies
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or it cannot describe v5e
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """An executable compiled for a described chip is written to the
+    persistent cache but cannot be read back without the chip; keep the
+    cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+FLASH_SHAPES = [(2, 32, 2048, 128),   # Llama-2-7B attention at batch 2
+                (1, 8, 256, 128)]     # one 256-token prefill bucket
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES,
+                         ids=["x".join(map(str, s)) for s in FLASH_SHAPES])
+def test_flash_kernels_compile_for_v5e(topo, shape, direction):
+    b, h, s, d = shape
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((b, h, s), jnp.float32, sharding=one_chip)
+    if direction == "fwd":
+        fn = jax.jit(lambda q, k, v: attention._flash_fwd_pallas(
+            q, k, v, causal=True, sm_scale=d ** -0.5))
+        args = (x, x, x)
+    else:
+        fn = jax.jit(lambda q, k, v, o, l, do: attention._flash_bwd_pallas(
+            q, k, v, o, l, do, causal=True, sm_scale=d ** -0.5))
+        args = (x, x, x, x, lse, x)
+    lowered = fn.lower(*args)
+    assert "tpu_custom_call" in lowered.as_text()
+    assert lowered.compile().memory_analysis().temp_size_in_bytes >= 0
+
+
+def _tiny_step(topo, mesh_cfg, monkeypatch):
+    """The whole train step, lowered for the described chips. The model
+    asks jax.devices() which attention path to take and sees this
+    sandbox's CPU, so the test — not the program — steers it."""
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.parallel import ParallelContext
+    from ray_tpu.train.spmd import (default_optimizer, make_train_fns,
+                                    state_shardings)
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    cfg = LlamaConfig(vocab_size=1024, d_model=512, n_layers=2, n_heads=4,
+                      n_kv_heads=2, d_ff=1024, max_seq=256)
+    ctx = ParallelContext.create(mesh_cfg, devices=list(topo.devices))
+    init, step = make_train_fns(cfg, ctx)
+    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, state_shardings(cfg, ctx, default_optimizer()))
+    toks = jax.ShapeDtypeStruct((4, 256), jnp.int32,
+                                sharding=ctx.batch_sharding())
+    return step.lower(state, toks)
+
+
+def test_dp4_train_step_compiles_for_v5e_2x2(topo, monkeypatch):
+    """GSPMD cannot partition a Mosaic kernel: on a four-chip mesh the
+    model must wrap the call in a shard_map, or this fails to lower."""
+    from ray_tpu.parallel import MeshConfig
+
+    lowered = _tiny_step(topo, MeshConfig(dp=4), monkeypatch)
+    assert "tpu_custom_call" in lowered.as_text()
+    compiled = lowered.compile()
+    assert "all-reduce" in compiled.as_text()  # the gradient reduction
+
+
+def test_pp_with_kernel_fails_clearly(topo, monkeypatch):
+    """Under pp the kernel would need a nested shard_map, which jax 0.9
+    cannot differentiate; the model says so instead of a verifier dump."""
+    from ray_tpu.parallel import MeshConfig
+
+    with pytest.raises(NotImplementedError, match="pipeline"):
+        _tiny_step(topo, MeshConfig(pp=2, dp=2), monkeypatch)
+
+
+# ---------------------------------------------------------------------------
+# Chip pinning env (no compiler needed)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("chips,bounds", [
+    ([2], "1,1,1"),          # one chip of a 2x2 host
+    ([0, 1], "1,2,1"),       # two
+    ([0, 1, 2, 3], None),    # the whole host: nothing overridden
+], ids=["1-chip", "2-chips", "4-chips"])
+def test_worker_env_for_chips_on_2x2_host(chips, bounds):
+    env = accelerators.worker_env_for_chips(chips, host_chips=4)
+    if bounds is None:
+        # Not "1,4,1", which describes no 2x2 host: libtpu's own view.
+        assert env == {}
+        return
+    assert env["TPU_VISIBLE_CHIPS"] == ",".join(map(str, chips))
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == bounds
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"  # an island
+    assert env["ALLOW_MULTIPLE_LIBTPU_LOAD"] == "1"  # others share the host
+
+
+def test_attached_device_files_outrank_the_host_env(monkeypatch):
+    """Found on the chip: a VM passed one chip of a 2x2 host (as
+    /dev/vfio/2) still carries TPU_CHIPS_PER_HOST_BOUNDS=2,2,1."""
+    monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    monkeypatch.setattr(
+        accelerators.glob, "glob",
+        lambda pat: ["/dev/vfio/2"] if pat == "/dev/vfio/[0-9]*" else [])
+    assert accelerators.num_tpu_chips() == 1
+    monkeypatch.setattr(accelerators.glob, "glob", lambda pat: [])
+    assert accelerators.num_tpu_chips() == 4  # no files: the env is all
+
+
+def test_worker_env_rejects_a_group_libtpu_cannot_describe():
+    with pytest.raises(ValueError, match="3 TPU chips"):
+        accelerators.worker_env_for_chips([0, 1, 2], host_chips=4)
+
+
+def test_gang_env_joins_one_chip_workers_into_a_2x2():
+    ports = [8476, 8477, 8478, 8479]
+    envs = [accelerators.gang_env(r, 4, 1, ports, host="10.0.0.1")
+            for r in range(4)]
+    for rank, env in enumerate(envs):
+        assert env["TPU_VISIBLE_CHIPS"] == str(rank)
+        assert env["CLOUD_TPU_TASK_ID"] == str(rank)
+        assert env["TPU_PROCESS_PORT"] == str(ports[rank])
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert env["TPU_PROCESS_BOUNDS"] == "2,2,1"  # one topology, not 4
+    assert len({e["TPU_PROCESS_ADDRESSES"] for e in envs}) == 1
+    assert envs[0]["TPU_PROCESS_ADDRESSES"].split(",")[3] == "10.0.0.1:8479"
+    with pytest.raises(ValueError, match="cannot join"):
+        accelerators.gang_env(0, 3, 1, [1, 2, 3])
