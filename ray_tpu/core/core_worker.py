@@ -4097,13 +4097,17 @@ class CoreWorker:
                         out.append((False, TaskCancelledError(
                             f"task {s.name} cancelled"), ""))
                         continue
-                    # Register for cancel interruption, like _execute.
+                    # Register for cancel interruption, like _execute,
+                    # and make the task the ambient trace context: what it
+                    # submits, and the spans it opens, nest under it.
                     self._exec_threads[s.task_id] = tid
+                    _trace_local.ctx = (s.trace_id or s.task_id, s.task_id)
                     try:
                         out.append((True, method(*args, **kwargs), ""))
                     except BaseException as e:  # per-task error reply
                         out.append((False, e, traceback.format_exc()))
                     finally:
+                        _trace_local.ctx = None
                         self._exec_threads.pop(s.task_id, None)
                 return out
 

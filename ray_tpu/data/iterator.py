@@ -15,6 +15,7 @@ import numpy as np
 
 import ray_tpu
 from ray_tpu.data.block import BlockAccessor, concat_blocks
+from ray_tpu.utils import tracing
 
 
 def _format_batch(batch, batch_format: str):
@@ -47,10 +48,15 @@ def iter_batches_from_refs(ref_iter: Iterator[Any], *, batch_size: Optional[int]
     it = iter(ref_iter)
     carry = None  # leftover rows as a block
     while True:
-        fill(it)
+        # The dataset's executor hands out references one at a time: for a
+        # streaming split that is a call to its coordinator.
+        with tracing.span("data.iter.next_ref", held=len(window)):
+            fill(it)
         if not window:
             break
-        block = ray_tpu.get(window.pop(0))
+        with tracing.span("data.iter.get_block") as got:
+            block = ray_tpu.get(window.pop(0))
+            got["rows"] = BlockAccessor(block).num_rows()
         if carry is not None:
             block = concat_blocks([carry, block])
             carry = None
@@ -62,8 +68,10 @@ def iter_batches_from_refs(ref_iter: Iterator[Any], *, batch_size: Optional[int]
             continue
         start = 0
         while n - start >= batch_size:
-            yield _format_batch(acc.slice(start, start + batch_size),
-                                batch_format)
+            with tracing.span("data.iter.format", rows=batch_size):
+                batch = _format_batch(acc.slice(start, start + batch_size),
+                                      batch_format)
+            yield batch
             start += batch_size
         if start < n:
             carry = acc.slice(start, n)
@@ -95,14 +103,18 @@ def iter_jax_batches_from_refs(ref_iter: Iterator[Any], *,
                                         batch_format="numpy",
                                         prefetch_blocks=prefetch_blocks,
                                         drop_last=drop_last):
-        if batch_size is not None and drop_last:
-            n = len(next(iter(batch.values()))) if batch else 0
-            if n != batch_size:
-                continue
-        if sharding is not None and global_batch:
-            yield {k: jax.make_array_from_process_local_data(sharding, v)
-                   for k, v in batch.items()}
-        elif sharding is not None:
-            yield {k: jax.device_put(v, sharding) for k, v in batch.items()}
-        else:
-            yield {k: jax.device_put(v) for k, v in batch.items()}
+        n = len(next(iter(batch.values()))) if batch else 0
+        if batch_size is not None and drop_last and n != batch_size:
+            continue
+        with tracing.span(
+                "data.iter.device_put", rows=n,
+                bytes=sum(int(v.nbytes) for v in batch.values())):
+            if sharding is not None and global_batch:
+                out = {k: jax.make_array_from_process_local_data(sharding, v)
+                       for k, v in batch.items()}
+            elif sharding is not None:
+                out = {k: jax.device_put(v, sharding)
+                       for k, v in batch.items()}
+            else:
+                out = {k: jax.device_put(v) for k, v in batch.items()}
+        yield out

@@ -212,37 +212,47 @@ def _layer_fwd(lp: Dict[str, jax.Array], x: jax.Array, cos, sin, positions,
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
 
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dh->bsh", h, lp["wq"].astype(dt))
-    k = jnp.einsum("bsd,dh->bsh", h, lp["wk"].astype(dt))
-    v = jnp.einsum("bsd,dh->bsh", h, lp["wv"].astype(dt))
-    q = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(B, S, KVH, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(B, S, KVH, hd).transpose(0, 2, 1, 3)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    k = repeat_kv(k, H // KVH)
-    v = repeat_kv(v, H // KVH)
-    if sp_manual:
-        attn = ring_attention(q, k, v, axis_name="sp", causal=True)
-    else:
-        attn = _attention(q, k, v, ctx)
-    attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
-    x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
+    # Scope names are the vocabulary serve/engine.py uses too: a device
+    # trace is reduced by them (benchmark/program_trace.py).
+    with jax.named_scope("attn_norm"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dh->bsh", h, lp["wq"].astype(dt))
+        k = jnp.einsum("bsd,dh->bsh", h, lp["wk"].astype(dt))
+        v = jnp.einsum("bsd,dh->bsh", h, lp["wv"].astype(dt))
+        q = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
+        k = k.reshape(B, S, KVH, hd).transpose(0, 2, 1, 3)
+        v = v.reshape(B, S, KVH, hd).transpose(0, 2, 1, 3)
+    with jax.named_scope("rope"):
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+    with jax.named_scope("attn"):
+        k = repeat_kv(k, H // KVH)
+        v = repeat_kv(v, H // KVH)
+        if sp_manual:
+            attn = ring_attention(q, k, v, axis_name="sp", causal=True)
+        else:
+            attn = _attention(q, k, v, ctx)
+        attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
+    with jax.named_scope("attn_out"):
+        x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
 
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.n_experts > 0:
-        flat = h.reshape(B * S, D)
-        out, aux = moe_ffn(flat, lp["router"].astype(dt),
-                           lp["w_up"].astype(dt), lp["w_gate"].astype(dt),
-                           lp["w_down"].astype(dt), top_k=cfg.top_k_experts)
-        x = x + out.reshape(B, S, D)
-    else:
-        gate = jnp.einsum("bsd,df->bsf", h, lp["w_gate"].astype(dt))
-        up = jnp.einsum("bsd,df->bsf", h, lp["w_up"].astype(dt))
-        x = x + jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                           lp["w_down"].astype(dt))
-        aux = jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp_norm"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if cfg.n_experts > 0:
+            flat = h.reshape(B * S, D)
+            out, aux = moe_ffn(
+                flat, lp["router"].astype(dt), lp["w_up"].astype(dt),
+                lp["w_gate"].astype(dt), lp["w_down"].astype(dt),
+                top_k=cfg.top_k_experts)
+            x = x + out.reshape(B, S, D)
+        else:
+            gate = jnp.einsum("bsd,df->bsf", h, lp["w_gate"].astype(dt))
+            up = jnp.einsum("bsd,df->bsf", h, lp["w_up"].astype(dt))
+            x = x + jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                               lp["w_down"].astype(dt))
+            aux = jnp.zeros((), jnp.float32)
     return x, aux
 
 
@@ -280,7 +290,8 @@ def _stack_fwd(layers_p: Dict[str, Any], x: jax.Array, cos, sin,
         body = jax.checkpoint(body, policy=policy) if policy is not None \
             else jax.checkpoint(body)
     aux0 = (x[(0,) * x.ndim] * 0).astype(jnp.float32)  # inherits x's vma type
-    (x, aux), _ = jax.lax.scan(body, (x, aux0), layers_p)
+    with jax.named_scope("layers"):
+        (x, aux), _ = jax.lax.scan(body, (x, aux0), layers_p)
     return x, aux
 
 
@@ -290,8 +301,11 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
                      ) -> Tuple[jax.Array, jax.Array]:
     """tokens [B, S] -> (logits [B, S, V] float32, MoE aux loss scalar)."""
     dt = cfg.dtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+    with jax.named_scope("rope"):
+        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq,
+                                    cfg.rope_theta)
 
     sp = ctx.sp if ctx else 1
     pp = ctx.pp if ctx else 1
@@ -348,9 +362,10 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
     else:
         x, aux = _stack_fwd(params["layers"], x, cos, sin, cfg, False, ctx)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(dt))
-    return logits.astype(jnp.float32), aux
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(dt))
+        return logits.astype(jnp.float32), aux
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
@@ -364,17 +379,19 @@ def loss_fn(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
     """Next-token cross-entropy (+ weighted MoE aux loss); targets = tokens
     shifted left, last position masked."""
     logits, aux = forward_with_aux(params, tokens, cfg, ctx)
-    targets = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
-    mask = jnp.concatenate(
-        [jnp.ones(tokens[:, 1:].shape, jnp.float32),
-         jnp.zeros(tokens[:, :1].shape, jnp.float32)], axis=1)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    ce = (logz - gold) * mask
-    loss = jnp.sum(ce) / jnp.maximum(jnp.sum(mask), 1.0)
-    if cfg.n_experts > 0:
-        loss = loss + cfg.moe_aux_weight * aux
-    return loss, {"loss": loss, "tokens": jnp.sum(mask)}
+    with jax.named_scope("loss"):
+        targets = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
+        mask = jnp.concatenate(
+            [jnp.ones(tokens[:, 1:].shape, jnp.float32),
+             jnp.zeros(tokens[:, :1].shape, jnp.float32)], axis=1)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None],
+                                   axis=-1)[..., 0]
+        ce = (logz - gold) * mask
+        loss = jnp.sum(ce) / jnp.maximum(jnp.sum(mask), 1.0)
+        if cfg.n_experts > 0:
+            loss = loss + cfg.moe_aux_weight * aux
+        return loss, {"loss": loss, "tokens": jnp.sum(mask)}
 
 
 def flops_per_token(cfg: LlamaConfig, seq: int) -> float:

@@ -187,6 +187,7 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q=1024,
         block_q=block_q, block_k=block_k, kv_seq_len=skv)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -362,6 +363,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, *, causal, sm_scale,
         functools.partial(_flash_bwd_dkv_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, block_k=block_k,
                           q_seq_len=sq),
+        name="flash_bwd_dkv",
         grid=(b * h, skv // block_k, sq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
@@ -392,6 +394,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, dout, *, causal, sm_scale,
         functools.partial(_flash_bwd_dq_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, block_k=block_k,
                           kv_seq_len=skv),
+        name="flash_bwd_dq",
         grid=(b * h, sq // block_q, skv // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),

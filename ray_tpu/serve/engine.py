@@ -40,10 +40,11 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
-from ray_tpu.utils import get_logger
+from ray_tpu.utils import get_logger, tracing
 
 logger = get_logger("serve.engine")
 
@@ -66,39 +67,50 @@ def _make_prefill_core(mcfg):
     def _prefill_layer(carry, lp):
         x, cos, sin = carry
         B, Sq, _ = x.shape
-        h = rms_norm(x, lp["attn_norm"], mcfg.norm_eps)
-        q = jnp.einsum("bsd,dh->bsh", h, lp["wq"].astype(dt))
-        k = jnp.einsum("bsd,dh->bsh", h, lp["wk"].astype(dt))
-        v = jnp.einsum("bsd,dh->bsh", h, lp["wv"].astype(dt))
-        q = q.reshape(B, Sq, H, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(B, Sq, KVH, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(B, Sq, KVH, hd).transpose(0, 2, 1, 3)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        attn = flash_attention(q, repeat_kv(k, H // KVH),
-                               repeat_kv(v, H // KVH), True)
-        attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd)
-        x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
-        h = rms_norm(x, lp["mlp_norm"], mcfg.norm_eps)
-        gate = jnp.einsum("bsd,df->bsf", h, lp["w_gate"].astype(dt))
-        up = jnp.einsum("bsd,df->bsf", h, lp["w_up"].astype(dt))
-        x = x + jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                           lp["w_down"].astype(dt))
+        with jax.named_scope("attn_norm"):
+            h = rms_norm(x, lp["attn_norm"], mcfg.norm_eps)
+        with jax.named_scope("qkv"):
+            q = jnp.einsum("bsd,dh->bsh", h, lp["wq"].astype(dt))
+            k = jnp.einsum("bsd,dh->bsh", h, lp["wk"].astype(dt))
+            v = jnp.einsum("bsd,dh->bsh", h, lp["wv"].astype(dt))
+            q = q.reshape(B, Sq, H, hd).transpose(0, 2, 1, 3)
+            k = k.reshape(B, Sq, KVH, hd).transpose(0, 2, 1, 3)
+            v = v.reshape(B, Sq, KVH, hd).transpose(0, 2, 1, 3)
+        with jax.named_scope("rope"):
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with jax.named_scope("attn"):
+            attn = flash_attention(q, repeat_kv(k, H // KVH),
+                                   repeat_kv(v, H // KVH), True)
+            attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd)
+        with jax.named_scope("attn_out"):
+            x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
+        with jax.named_scope("mlp_norm"):
+            h = rms_norm(x, lp["mlp_norm"], mcfg.norm_eps)
+        with jax.named_scope("mlp"):
+            gate = jnp.einsum("bsd,df->bsf", h, lp["w_gate"].astype(dt))
+            up = jnp.einsum("bsd,df->bsf", h, lp["w_up"].astype(dt))
+            x = x + jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                               lp["w_down"].astype(dt))
         # cache pre-repeat k/v: [S, KVH, hd] (B == 1 squeezed)
         return (x, cos, sin), (k[0].transpose(1, 0, 2),
                                v[0].transpose(1, 0, 2))
 
     def core(params, tokens, length):
-        x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
-        cos, sin = rope_frequencies(hd, tokens.shape[1], mcfg.rope_theta)
-        (x, _, _), (ks, vs) = jax.lax.scan(
-            _prefill_layer, (x, cos, sin), params["layers"])
-        x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
-        last_h = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
-                                              keepdims=False)
-        logits = jnp.einsum("bd,dv->bv", last_h,
-                            params["lm_head"].astype(dt))
-        first = jnp.argmax(logits[0]).astype(jnp.int32)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+        with jax.named_scope("rope"):
+            cos, sin = rope_frequencies(hd, tokens.shape[1], mcfg.rope_theta)
+        with jax.named_scope("layers"):
+            (x, _, _), (ks, vs) = jax.lax.scan(
+                _prefill_layer, (x, cos, sin), params["layers"])
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
+            last_h = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
+                                                  keepdims=False)
+            logits = jnp.einsum("bd,dv->bv", last_h,
+                                params["lm_head"].astype(dt))
+            first = jnp.argmax(logits[0]).astype(jnp.int32)
         return first, ks, vs, logits[0].astype(jnp.float32)
 
     return core
@@ -120,20 +132,21 @@ def _sample_tokens(logits, temp, topk, keys, pos, cap=TOPK_CAP):
     import jax.numpy as jnp
 
     cap = min(cap, logits.shape[-1])
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    vals, idxs = jax.lax.top_k(logits.astype(jnp.float32), cap)
-    k_eff = jnp.where(topk > 0, jnp.minimum(topk, cap), cap)
-    mask = jnp.arange(cap)[None, :] < k_eff[:, None]
-    scaled = jnp.where(mask, vals / jnp.maximum(temp, 1e-6)[:, None],
-                       -1e30)
 
     def one_gumbel(key, p):
         return jax.random.gumbel(jax.random.fold_in(key, p), (cap,))
 
-    g = jax.vmap(one_gumbel)(keys, pos)
-    pick = jnp.argmax(scaled + g, axis=-1)
-    sampled = jnp.take_along_axis(idxs, pick[:, None], axis=1)[:, 0]
-    return jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        vals, idxs = jax.lax.top_k(logits.astype(jnp.float32), cap)
+        k_eff = jnp.where(topk > 0, jnp.minimum(topk, cap), cap)
+        mask = jnp.arange(cap)[None, :] < k_eff[:, None]
+        scaled = jnp.where(mask, vals / jnp.maximum(temp, 1e-6)[:, None],
+                           -1e30)
+        g = jax.vmap(one_gumbel)(keys, pos)
+        pick = jnp.argmax(scaled + g, axis=-1)
+        sampled = jnp.take_along_axis(idxs, pick[:, None], axis=1)[:, 0]
+        return jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
 
 
 def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
@@ -164,12 +177,13 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         L, W = ks.shape[0], ks.shape[1]
         wp = -(-W // page)
         pad = wp * page - W
-        ksp = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        vsp = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        ksp = ksp.reshape(L, wp, page, KVH, hd)
-        vsp = vsp.reshape(L, wp, page, KVH, hd)
-        kc = kc.at[:, pages[:wp]].set(ksp)
-        vc = vc.at[:, pages[:wp]].set(vsp)
+        with jax.named_scope("kv_write"):
+            ksp = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            vsp = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            ksp = ksp.reshape(L, wp, page, KVH, hd)
+            vsp = vsp.reshape(L, wp, page, KVH, hd)
+            kc = kc.at[:, pages[:wp]].set(ksp)
+            vc = vc.at[:, pages[:wp]].set(vsp)
         return kc, vc
 
     # ------------------------------------------------------------------
@@ -207,48 +221,58 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
 
     def _decode_layer(x, lp, kc_l, vc_l, bt, pos, act, cos, sin):
         # x [ns, D]; kc_l/vc_l [n_pages, page, KVH, hd]; bt [ns, maxp]
-        h = rms_norm(x, lp["attn_norm"], mcfg.norm_eps)
-        q = (h @ lp["wq"].astype(dt)).reshape(ns, H, hd)
-        k = (h @ lp["wk"].astype(dt)).reshape(ns, KVH, hd)
-        v = (h @ lp["wv"].astype(dt)).reshape(ns, KVH, hd)
-        w = jnp.minimum(pos, S - 1)
-        c = cos[w][:, None]
-        s = sin[w][:, None]
-        q = _rope_one(q, c, s)
-        k = _rope_one(k, c, s)
+        with jax.named_scope("attn_norm"):
+            h = rms_norm(x, lp["attn_norm"], mcfg.norm_eps)
+        with jax.named_scope("qkv"):
+            q = (h @ lp["wq"].astype(dt)).reshape(ns, H, hd)
+            k = (h @ lp["wk"].astype(dt)).reshape(ns, KVH, hd)
+            v = (h @ lp["wv"].astype(dt)).reshape(ns, KVH, hd)
+        with jax.named_scope("rope"):
+            w = jnp.minimum(pos, S - 1)
+            c = cos[w][:, None]
+            s = sin[w][:, None]
+            q = _rope_one(q, c, s)
+            k = _rope_one(k, c, s)
         # Scatter k/v at each slot's (page, offset). Inactive slots (and
         # positions past a slot's reservation) route to the NULL page 0,
         # whose content is never read unmasked — the write stays a
         # fixed-shape scatter with no data-dependent branches.
-        idx = jnp.arange(ns)
-        pp = jnp.where(act, bt[idx, w // page], 0)
-        off = jnp.where(act, w % page, 0)
-        kc_l = kc_l.at[pp, off].set(k)
-        vc_l = vc_l.at[pp, off].set(v)
+        with jax.named_scope("kv_write"):
+            idx = jnp.arange(ns)
+            pp = jnp.where(act, bt[idx, w // page], 0)
+            off = jnp.where(act, w % page, 0)
+            kc_l = kc_l.at[pp, off].set(k)
+            vc_l = vc_l.at[pp, off].set(v)
         # Gather each slot's pages -> its logical KV history.
-        kh = kc_l[bt].reshape(ns, CTX, KVH, hd)
-        vh = vc_l[bt].reshape(ns, CTX, KVH, hd)
+        with jax.named_scope("kv_gather"):
+            kh = kc_l[bt].reshape(ns, CTX, KVH, hd)
+            vh = vc_l[bt].reshape(ns, CTX, KVH, hd)
         # Grouped-query attention against the gathered history.
-        qg = q.reshape(ns, KVH, H // KVH, hd).astype(jnp.float32)
-        scores = jnp.einsum("nkgd,nskd->nkgs", qg,
-                            kh.astype(jnp.float32)) / (hd ** 0.5)
-        mask = jnp.arange(CTX)[None, :] <= w[:, None]        # [ns, CTX]
-        scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-        wts = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("nkgs,nskd->nkgd", wts,
-                          vh.astype(jnp.float32))
-        attn = attn.reshape(ns, H * hd).astype(dt)
-        x = x + attn @ lp["wo"].astype(dt)
-        h = rms_norm(x, lp["mlp_norm"], mcfg.norm_eps)
-        gate = h @ lp["w_gate"].astype(dt)
-        up = h @ lp["w_up"].astype(dt)
-        x = x + (jax.nn.silu(gate) * up) @ lp["w_down"].astype(dt)
+        with jax.named_scope("attn"):
+            qg = q.reshape(ns, KVH, H // KVH, hd).astype(jnp.float32)
+            scores = jnp.einsum("nkgd,nskd->nkgs", qg,
+                                kh.astype(jnp.float32)) / (hd ** 0.5)
+            mask = jnp.arange(CTX)[None, :] <= w[:, None]    # [ns, CTX]
+            scores = jnp.where(mask[:, None, None, :], scores, -1e30)
+            wts = jax.nn.softmax(scores, axis=-1)
+            attn = jnp.einsum("nkgs,nskd->nkgd", wts,
+                              vh.astype(jnp.float32))
+            attn = attn.reshape(ns, H * hd).astype(dt)
+        with jax.named_scope("attn_out"):
+            x = x + attn @ lp["wo"].astype(dt)
+        with jax.named_scope("mlp_norm"):
+            h = rms_norm(x, lp["mlp_norm"], mcfg.norm_eps)
+        with jax.named_scope("mlp"):
+            gate = h @ lp["w_gate"].astype(dt)
+            up = h @ lp["w_up"].astype(dt)
+            x = x + (jax.nn.silu(gate) * up) @ lp["w_down"].astype(dt)
         return x, kc_l, vc_l
 
     def _step(params, kc, vc, bt, last, pos, active, cos, sin,
               temp, topk, keys):
         act = active & (pos < S)
-        x = jnp.take(params["embed"], last, axis=0).astype(dt)
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], last, axis=0).astype(dt)
 
         def body(carry, layer):
             x = carry
@@ -257,16 +281,21 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
                                           act, cos, sin)
             return x, (kc_l, vc_l)
 
-        x, (kc, vc) = jax.lax.scan(body, x, (params["layers"], kc, vc))
-        x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
-        logits = x @ params["lm_head"].astype(dt)          # [ns, V]
+        # The arena rides this scan: whatever the compiler copies to carry
+        # it is named `layers` and nothing deeper.
+        with jax.named_scope("layers"):
+            x, (kc, vc) = jax.lax.scan(body, x, (params["layers"], kc, vc))
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
+            logits = x @ params["lm_head"].astype(dt)      # [ns, V]
         nxt = _sample_tokens(logits, temp, topk, keys, pos)
         nxt = jnp.where(act, nxt, last)
         pos2 = jnp.where(act, pos + 1, pos)
         return kc, vc, nxt, pos2
 
     def decode(params, kc, vc, bt, last, pos, active, temp, topk, keys):
-        cos, sin = rope_frequencies(hd, S, mcfg.rope_theta)
+        with jax.named_scope("rope"):
+            cos, sin = rope_frequencies(hd, S, mcfg.rope_theta)
         out0 = jnp.zeros((ns, chunk), jnp.int32)
 
         def body(i, carry):
@@ -305,7 +334,8 @@ def _seed_key(seed: int):
 
 class _Request:
     __slots__ = ("ids", "max_tokens", "out", "produced", "slot",
-                 "adopt_kv", "first", "temperature", "top_k", "seed")
+                 "adopt_kv", "first", "temperature", "top_k", "seed",
+                 "rid", "t_submit", "ctx")
 
     def __init__(self, ids: List[int], max_tokens: int,
                  adopt_kv: Optional[Tuple[Any, Any]] = None,
@@ -324,6 +354,12 @@ class _Request:
         # side, so this engine never re-emits it).
         self.adopt_kv = adopt_kv
         self.first = first
+        # Set by Engine._enqueue on the submitting thread: the request's
+        # number, when it joined the queue, and the replica call's trace
+        # context, which the engine loop's spans carry for it.
+        self.rid = -1
+        self.t_submit = 0.0
+        self.ctx = None
 
 
 class Engine:
@@ -389,6 +425,15 @@ class Engine:
         self._last_d = jnp.zeros(n_slots, jnp.int32)
         self._pos_d = jnp.zeros(n_slots, jnp.int32)
         self.peak_pages_used = 0
+        # Running totals since start (`counters()`; what the engine's spans
+        # say a request or a chunk at a time).
+        self.admitted = 0
+        self.queue_wait_s_sum = 0.0
+        self.prefill_tokens = 0
+        self.prefill_padded_tokens = 0     # bucket width less the prompt
+        self.decode_chunks = 0
+        self.decode_useful_tokens = 0
+        self._next_rid = 0
         self._pending: deque = deque()
         self._plock = threading.Lock()
         self._wake = threading.Event()
@@ -407,30 +452,27 @@ class Engine:
         # writes target the null page (pages = zeros), so they never
         # touch real KV state.
         self._warm = {self.buckets[0], self.buckets[-1]}
-        null_pages = jnp.zeros(self.maxp, jnp.int32)
-        null_key = jnp.zeros(2, jnp.uint32)
         for width in sorted(self._warm):
-            toks = jnp.zeros((1, width), jnp.int32)
-            self._kc, self._vc, first = self._prefill(
-                self.params, self._kc, self._vc, null_pages, toks, 1,
-                0.0, 0, null_key)
-            kv = jnp.zeros((mcfg.n_layers, width, mcfg.n_kv_heads,
-                            mcfg.head_dim), mcfg.dtype)
-            self._kc, self._vc = self._adopt(self._kc, self._vc,
-                                             null_pages, kv, kv)
-        self._kc, self._vc, self._last_d, self._pos_d, out = self._decode(
-            self.params, self._kc, self._vc, jnp.asarray(self._bt),
-            self._last_d, self._pos_d, jnp.zeros(n_slots, bool),
-            jnp.asarray(self._temp), jnp.asarray(self._topk),
-            jnp.asarray(self._skeys))
+            self._kc, self._vc, first = self._warm_width(
+                self._kc, self._vc, width)
+        with tracing.compile_span("serve.engine.warm", program="decode",
+                                  width=n_slots):
+            self._kc, self._vc, self._last_d, self._pos_d, out = \
+                self._decode(
+                    self.params, self._kc, self._vc, jnp.asarray(self._bt),
+                    self._last_d, self._pos_d, jnp.zeros(n_slots, bool),
+                    jnp.asarray(self._temp), jnp.asarray(self._topk),
+                    jnp.asarray(self._skeys))
         # Warm both poke variants: host-int `first` (adopt path) and
         # device-scalar `first` (prefill path).
-        self._last_d, self._pos_d = self._poke(self._last_d, self._pos_d,
-                                               0, 0, 0)
-        self._last_d, self._pos_d = self._poke(self._last_d, self._pos_d,
-                                               0, first, 0)
-        self._last_d, self._pos_d = self._poke(self._last_d, self._pos_d,
-                                               0, 0, 0)
+        with tracing.compile_span("serve.engine.warm", program="poke",
+                                  width=n_slots):
+            self._last_d, self._pos_d = self._poke(
+                self._last_d, self._pos_d, 0, 0, 0)
+            self._last_d, self._pos_d = self._poke(
+                self._last_d, self._pos_d, 0, first, 0)
+            self._last_d, self._pos_d = self._poke(
+                self._last_d, self._pos_d, 0, 0, 0)
         int(first)
         # Emission FIFO: the dispatch loop enqueues device arrays; the
         # emitter thread performs the host syncs. maxsize bounds how far
@@ -452,6 +494,27 @@ class Engine:
                 name="llm-bucket-warm")
             self._warm_thread.start()
 
+    def _warm_width(self, kc, vc, width: int):
+        """First calls of the prefill and adopt programs of one bucket
+        width, writing to the null page of the arena given (pages = zeros:
+        never real KV state). Returns (kc, vc, first token on the device)."""
+        jnp, m = self._jnp, self.mcfg
+        null_pages = jnp.zeros(self.maxp, jnp.int32)
+        with tracing.compile_span("serve.engine.warm", program="prefill",
+                                  width=width):
+            kc, vc, first = self._prefill(
+                self.params, kc, vc, null_pages,
+                jnp.zeros((1, width), jnp.int32), 1, 0.0, 0,
+                jnp.zeros(2, jnp.uint32))
+        # The PD adopt program for this width too (a first cross-pool
+        # handoff must not compile in the loop).
+        with tracing.compile_span("serve.engine.warm", program="adopt",
+                                  width=width):
+            kv = jnp.zeros((m.n_layers, width, m.n_kv_heads, m.head_dim),
+                           m.dtype)
+            kc, vc = self._adopt(kc, vc, null_pages, kv, kv)
+        return kc, vc, first
+
     def _warm_buckets(self, widths: List[int]) -> None:
         """Warm intermediate prefill buckets off the engine loop; each
         becomes eligible the moment its compile lands. Runs real calls
@@ -459,24 +522,13 @@ class Engine:
         a SCRATCH kv arena — the live arenas are donated on every engine
         call and must never be touched from this thread. Costs one
         transient extra arena while warming."""
-        import jax.numpy as jnp
         try:
             kc, vc = self._empty()
-            m = self.mcfg
-            null_pages = jnp.zeros(self.maxp, jnp.int32)
             for width in widths:
                 if self._stop:
                     return
-                toks = jnp.zeros((1, width), jnp.int32)
-                kc, vc, first = self._prefill(
-                    self.params, kc, vc, null_pages, toks, 1, 0.0, 0,
-                    jnp.zeros(2, jnp.uint32))
+                kc, vc, first = self._warm_width(kc, vc, width)
                 int(first)  # host sync: compile fully landed
-                # Warm the PD adopt program for this width too (a first
-                # cross-pool handoff must not compile in the loop).
-                kv = jnp.zeros((m.n_layers, width, m.n_kv_heads,
-                                m.head_dim), m.dtype)
-                kc, vc = self._adopt(kc, vc, null_pages, kv, kv)
                 self._warm.add(width)
         except Exception:
             # Prompts keep rounding up to the buckets that did warm, but
@@ -520,10 +572,7 @@ class Engine:
         if max_tokens <= 0:
             req.out.put(None)  # nothing to generate; skip the prefill too
             return req.out
-        with self._plock:
-            self._pending.append(req)
-        self._wake.set()
-        return req.out
+        return self._enqueue(req)
 
     def submit_prefilled(self, ks: Any, vs: Any, length: int, first: int,
                          max_tokens: int, *, temperature: float = 0.0,
@@ -543,10 +592,28 @@ class Engine:
         if max_tokens <= 1:
             req.out.put(None)  # prefill's first token was the whole ask
             return req.out
+        return self._enqueue(req)
+
+    def _enqueue(self, req: _Request) -> "queue.Queue":
+        req.ctx = tracing.context()
         with self._plock:
+            req.rid = self._next_rid
+            self._next_rid += 1
+            req.t_submit = time.monotonic()
             self._pending.append(req)
         self._wake.set()
         return req.out
+
+    def counters(self) -> Dict[str, Any]:
+        """Running totals since the engine started: the operator's view of
+        what `serve.engine.admit` and `serve.engine.decode_dispatch` spans
+        say one at a time. Occupancy is `decode_useful_tokens` over
+        `decode_chunks * n_slots * chunk`; padding is
+        `prefill_padded_tokens` over it plus `prefill_tokens`."""
+        return {k: getattr(self, k) for k in (
+            "admitted", "queue_wait_s_sum", "prefill_tokens",
+            "prefill_padded_tokens", "decode_chunks",
+            "decode_useful_tokens", "peak_pages_used", "n_slots", "chunk")}
 
     def stop(self) -> None:
         self._stop = True
@@ -577,7 +644,6 @@ class Engine:
         Prefills for a BURST of admissions are all dispatched (and their
         first-token transfers started) before anything blocks, so N
         admissions cost ~one round-trip, not N."""
-        np, jnp = self._np, self._jnp
         S = self.mcfg.max_seq
         emits: List[Tuple[_Request, Any, bool]] = []  # (req, first, done)
         while True:
@@ -594,71 +660,26 @@ class Engine:
 
             with self._plock:
                 self._pending.popleft()
-            pages = [self._free.pop() for _ in range(need)]
-            self._slot_pages[slot] = pages
-            self.peak_pages_used = max(self.peak_pages_used,
-                                       self.pages_in_use())
-            self._bt[slot, :] = 0
-            self._bt[slot, :need] = pages
-            pages_arr = np.zeros(self.maxp, np.int32)
-            pages_arr[:need] = pages
-            pages_arr = jnp.asarray(pages_arr)
-            if req.adopt_kv is not None:
-                # Disaggregated handoff: write the external KV into the
-                # slot's pages; `first` was already streamed by the
-                # prefill side. An UNWARMED handoff width is host-padded
-                # to the next warmed bucket (a zero tail is never
-                # attended — the mask stops at pos) instead of compiling
-                # a fresh adopt program inside the loop.
-                ks, vs = req.adopt_kv
-                req.adopt_kv = None
-                width = ks.shape[1]
-                if width not in self._warm:
-                    target = next(b for b in self.buckets
-                                  if b >= width and b in self._warm)
-                    pk = np.zeros((ks.shape[0], target) + ks.shape[2:],
-                                  np.asarray(ks).dtype)
-                    pv = np.zeros_like(pk)
-                    pk[:, :width] = np.asarray(ks)
-                    pv[:, :width] = np.asarray(vs)
-                    ks, vs = jnp.asarray(pk), jnp.asarray(pv)
-                self._kc, self._vc = self._adopt(
-                    self._kc, self._vc, pages_arr, ks, vs)
-                first = req.first
-            else:
-                # Only WARMED buckets are eligible (round up until the
-                # background warm lands) — never compile in the engine
-                # loop.
-                width = next(b for b in self.buckets
-                             if b >= len(req.ids) and b in self._warm)
-                toks = np.zeros((1, width), np.int32)
-                toks[0, :len(req.ids)] = req.ids
-                self._kc, self._vc, first = self._prefill(
-                    self.params, self._kc, self._vc, pages_arr,
-                    jnp.asarray(toks), len(req.ids),
-                    float(req.temperature), int(req.top_k),
-                    jnp.asarray(_seed_key(req.seed)))
-            req.slot = slot
-            self._slot_req[slot] = req
-            self._pos[slot] = len(req.ids)
-            self._active[slot] = True
-            # Sampling state applies on BOTH branches (a PD handoff
-            # continues decoding with the request's params).
-            self._temp[slot] = req.temperature
-            self._topk[slot] = req.top_k
-            self._skeys[slot] = _seed_key(req.seed)
-            req.produced = 1
-            # Device-side slot bookkeeping (async — never a host
-            # round-trip; `first` stays a device scalar on the prefill
-            # path).
-            self._last_d, self._pos_d = self._poke(
-                self._last_d, self._pos_d, slot, first,
-                int(self._pos[slot]))
-            done = (req.produced >= req.max_tokens
-                    or self._pos[slot] >= S)
-            if done:
-                self._finish_state(slot)
-            emits.append((req, first, done))
+                left = len(self._pending)
+            adopting = req.adopt_kv is not None
+            width = req.adopt_kv[0].shape[1] if adopting else len(req.ids)
+            # Only WARMED buckets are eligible (round up until the
+            # background warm lands) — never compile in the engine loop.
+            bucket = next(b for b in self.buckets
+                          if b >= width and b in self._warm)
+            waited = time.monotonic() - req.t_submit
+            self.admitted += 1
+            self.queue_wait_s_sum += waited
+            if not adopting:
+                self.prefill_tokens += width
+                self.prefill_padded_tokens += bucket - width
+            with tracing.span(
+                    "serve.engine.admit", ctx=req.ctx, rid=req.rid,
+                    kind="adopt" if adopting else "prefill",
+                    prompt_tokens=len(req.ids), bucket=bucket,
+                    queue_wait_us=int(waited * 1e6), pending=left,
+                    pages_free=len(self._free) - need):
+                emits.append(self._place(req, slot, need, bucket))
         # Start EVERY device->host copy first (async), THEN enqueue: a
         # burst overlaps all its transfers even when the bounded
         # _emit_q.put blocks partway through the enqueue loop.
@@ -671,6 +692,70 @@ class Engine:
             # The emitter thread performs the int(first) sync — the
             # dispatch loop never blocks on the device.
             self._emit_q.put(("first", req, first, done))
+
+    def _place(self, req: _Request, slot: int, need: int,
+               bucket: int) -> Tuple[_Request, Any, bool]:
+        """Grant `need` pages and the slot, dispatch the prefill (or the
+        adopt) at width `bucket` and the poke. Returns the emitter's item:
+        (req, first token, finished already)."""
+        np, jnp = self._np, self._jnp
+        S = self.mcfg.max_seq
+        pages = [self._free.pop() for _ in range(need)]
+        self._slot_pages[slot] = pages
+        self.peak_pages_used = max(self.peak_pages_used,
+                                   self.pages_in_use())
+        self._bt[slot, :] = 0
+        self._bt[slot, :need] = pages
+        pages_arr = np.zeros(self.maxp, np.int32)
+        pages_arr[:need] = pages
+        pages_arr = jnp.asarray(pages_arr)
+        if req.adopt_kv is not None:
+            # Disaggregated handoff: write the external KV into the
+            # slot's pages; `first` was already streamed by the prefill
+            # side. An UNWARMED handoff width is host-padded to the next
+            # warmed bucket (a zero tail is never attended — the mask
+            # stops at pos) instead of compiling a fresh adopt program
+            # inside the loop.
+            ks, vs = req.adopt_kv
+            req.adopt_kv = None
+            width = ks.shape[1]
+            if width != bucket:
+                pk = np.zeros((ks.shape[0], bucket) + ks.shape[2:],
+                              np.asarray(ks).dtype)
+                pv = np.zeros_like(pk)
+                pk[:, :width] = np.asarray(ks)
+                pv[:, :width] = np.asarray(vs)
+                ks, vs = jnp.asarray(pk), jnp.asarray(pv)
+            self._kc, self._vc = self._adopt(
+                self._kc, self._vc, pages_arr, ks, vs)
+            first = req.first
+        else:
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :len(req.ids)] = req.ids
+            self._kc, self._vc, first = self._prefill(
+                self.params, self._kc, self._vc, pages_arr,
+                jnp.asarray(toks), len(req.ids),
+                float(req.temperature), int(req.top_k),
+                jnp.asarray(_seed_key(req.seed)))
+        req.slot = slot
+        self._slot_req[slot] = req
+        self._pos[slot] = len(req.ids)
+        self._active[slot] = True
+        # Sampling state applies on BOTH branches (a PD handoff
+        # continues decoding with the request's params).
+        self._temp[slot] = req.temperature
+        self._topk[slot] = req.top_k
+        self._skeys[slot] = _seed_key(req.seed)
+        req.produced = 1
+        # Device-side slot bookkeeping (async — never a host round-trip;
+        # `first` stays a device scalar on the prefill path).
+        self._last_d, self._pos_d = self._poke(
+            self._last_d, self._pos_d, slot, first, int(self._pos[slot]))
+        done = bool(req.produced >= req.max_tokens
+                    or self._pos[slot] >= S)
+        if done:
+            self._finish_state(slot)
+        return req, first, done
 
     def _finish_state(self, slot: int) -> None:
         """Free the slot + pages (host control state only — the stream's
@@ -720,19 +805,24 @@ class Engine:
             try:
                 if item[0] == "first":
                     _, req, first, done = item
-                    if req.first < 0:
-                        req.out.put([int(first)])
-                    if done:
-                        req.out.put(None)
+                    # Ends at the engine's first-token instant.
+                    with tracing.span(
+                            "serve.engine.emit", ctx=req.ctx, rid=req.rid,
+                            kind="first" if req.first < 0 else "adopt"):
+                        if req.first < 0:
+                            req.out.put([int(first)])
+                        if done:
+                            req.out.put(None)
                 else:  # ("chunk", out_d, plan)
                     _, out_d, plan = item
-                    out_h = np.asarray(out_d)
-                    for slot, req, take, fin in plan:
-                        toks = [int(t) for t in out_h[slot, :take]]
-                        if toks:
-                            req.out.put(toks)
-                        if fin:
-                            req.out.put(None)
+                    with tracing.span("serve.engine.emit", kind="chunk"):
+                        out_h = np.asarray(out_d)
+                        for slot, req, take, fin in plan:
+                            toks = [int(t) for t in out_h[slot, :take]]
+                            if toks:
+                                req.out.put(toks)
+                            if fin:
+                                req.out.put(None)
             except BaseException:
                 import traceback
                 self.error = self.error or traceback.format_exc()
@@ -782,24 +872,31 @@ class Engine:
             # _bt/_active in place while the dispatched chunk is still
             # queued — an aliased buffer would let those mutations reach
             # into the in-flight computation.
-            self._kc, self._vc, self._last_d, self._pos_d, out_d = \
-                self._decode(self.params, self._kc, self._vc,
-                             jnp.asarray(self._bt.copy()), self._last_d,
-                             self._pos_d,
-                             jnp.asarray(self._active.copy()),
-                             jnp.asarray(self._temp.copy()),
-                             jnp.asarray(self._topk.copy()),
-                             jnp.asarray(self._skeys.copy()))
-            self._pos = np.where(
-                self._active, np.minimum(self._pos + self.chunk, S),
-                self._pos).astype(np.int32)
-            for slot, req, take, fin in plan:
-                if fin and self._slot_req[slot] is req:
-                    self._finish_state(slot)
-            try:
-                out_d.copy_to_host_async()
-            except AttributeError:
-                pass
+            useful = sum(take for _, _, take, _ in plan)
+            self.decode_chunks += 1
+            self.decode_useful_tokens += useful
+            with tracing.span("serve.engine.decode_dispatch", useful=useful,
+                              capacity=self.n_slots * self.chunk,
+                              active=len(plan)):
+                self._kc, self._vc, self._last_d, self._pos_d, out_d = \
+                    self._decode(self.params, self._kc, self._vc,
+                                 jnp.asarray(self._bt.copy()), self._last_d,
+                                 self._pos_d,
+                                 jnp.asarray(self._active.copy()),
+                                 jnp.asarray(self._temp.copy()),
+                                 jnp.asarray(self._topk.copy()),
+                                 jnp.asarray(self._skeys.copy()))
+                self._pos = np.where(
+                    self._active, np.minimum(self._pos + self.chunk, S),
+                    self._pos).astype(np.int32)
+                for slot, req, take, fin in plan:
+                    if fin and self._slot_req[slot] is req:
+                        self._finish_state(slot)
+                try:
+                    out_d.copy_to_host_async()
+                except AttributeError:
+                    pass
             # Blocks when the emitter is `maxsize` chunks behind — the
-            # pipeline-depth bound.
-            self._emit_q.put(("chunk", out_d, plan))
+            # pipeline-depth bound, which this span shows from the host.
+            with tracing.span("serve.engine.emit_block"):
+                self._emit_q.put(("chunk", out_d, plan))

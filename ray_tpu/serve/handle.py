@@ -18,6 +18,7 @@ from typing import Any, Dict, List, Optional
 import cloudpickle
 
 import ray_tpu
+from ray_tpu.utils import tracing
 
 
 class DeploymentResponse:
@@ -117,6 +118,7 @@ class Router:
         self._checked = 0.0
         self._lock = threading.Lock()
         self._qlen_cache: Dict[bytes, tuple] = {}  # aid -> (qlen, ts)
+        self._probes = 0  # queue_len RPCs sent
         # model_id -> replica actor_id: sticky multiplexing affinity
         # (reference: serve/multiplex.py routes to replicas holding the
         # model; ours is client-side stickiness with pow-2 fallback).
@@ -141,6 +143,14 @@ class Router:
         """Power-of-two-choices over live queue lengths (reference:
         pow_2_router.py:52 choose_replicas); multiplexed requests stick
         to the replica that last served their model id."""
+        probes = self._probes
+        with tracing.span("serve.router.choose", profiler=False) as sp:
+            chosen = self._choose(model_id)
+            sp.update(replicas=len(self._replicas),
+                      probes=self._probes - probes)
+        return chosen
+
+    def _choose(self, model_id: str):
         self._refresh()
         replicas = self._replicas
         if not replicas:
@@ -178,6 +188,7 @@ class Router:
         hit = self._qlen_cache.get(aid)
         if hit is not None and now - hit[1] < self._QLEN_TTL_S:
             return hit[0]
+        self._probes += 1
         q = ray_tpu.get(replica.queue_len.remote(), timeout=5)
         self._qlen_cache[aid] = (q, now)
         return q

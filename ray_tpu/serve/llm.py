@@ -27,7 +27,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu.serve as serve
-from ray_tpu.utils import get_logger
+from ray_tpu.utils import get_logger, tracing
 
 
 @dataclass
@@ -108,6 +108,7 @@ class LLMServer:
                 for w in sorted(self.engine._warm)},
             "compile_cache_dir": os.environ.get(
                 "JAX_COMPILATION_CACHE_DIR"),
+            "engine_counters": self.engine.counters(),
         }
 
     def __call__(self, body: Dict[str, Any]):
@@ -259,9 +260,11 @@ class PrefillServer:
         self._warm = {self.buckets[0], self.buckets[-1]}
 
         def warm(width: int) -> None:
-            out = self._core(self.params,
-                             jnp.zeros((1, width), jnp.int32), 1)
-            jax.block_until_ready(out)
+            with tracing.compile_span("serve.engine.warm",
+                                      program="prefill", width=width):
+                out = self._core(self.params,
+                                 jnp.zeros((1, width), jnp.int32), 1)
+                jax.block_until_ready(out)
 
         for width in sorted(self._warm):
             warm(width)

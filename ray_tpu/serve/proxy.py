@@ -12,12 +12,13 @@ from __future__ import annotations
 
 import asyncio
 import json
+import os
 import threading
 from typing import Any, Dict, Optional
 
 import ray_tpu
 from ray_tpu.serve.handle import DeploymentHandle
-from ray_tpu.utils import get_logger
+from ray_tpu.utils import get_logger, tracing
 
 logger = get_logger("serve.proxy")
 
@@ -145,23 +146,34 @@ class HttpProxy:
             payload = body.decode(errors="replace")
         loop = asyncio.get_running_loop()
         stream = headers.get("x-serve-stream", "").lower() in ("1", "true")
-        if stream:
-            # Stream errors terminate the chunked body/connection; a 500
-            # status after chunks were sent would corrupt the protocol.
-            await self._stream_response(handle, payload, writer, loop)
-            return
-        try:
-            response = await loop.run_in_executor(
-                None, lambda: handle.remote(payload).result(timeout=120))
-            self._respond(writer, 200, json.dumps(
-                {"result": response}).encode())
-        except Exception as e:
-            self._respond(writer, 500,
-                          json.dumps({"error": repr(e)}).encode())
-        await writer.drain()
+        # This request is the root of a trace: the replica call made on its
+        # behalf, and every span under that, carry its id.
+        trace_id = os.urandom(16)
+        with tracing.span("serve.proxy.request", ctx=(trace_id, b""),
+                          profiler=False, path=path, stream=stream):
+            if stream:
+                # Stream errors terminate the chunked body/connection; a
+                # 500 status after chunks were sent would corrupt the
+                # protocol.
+                await self._stream_response(handle, payload, writer, loop,
+                                            trace_id)
+                return
 
-    async def _stream_response(self, handle, payload, writer,
-                               loop) -> None:
+            def call():
+                with tracing.root(trace_id):
+                    return handle.remote(payload).result(timeout=120)
+
+            try:
+                response = await loop.run_in_executor(None, call)
+                self._respond(writer, 200, json.dumps(
+                    {"result": response}).encode())
+            except Exception as e:
+                self._respond(writer, 500,
+                              json.dumps({"error": repr(e)}).encode())
+            await writer.drain()
+
+    async def _stream_response(self, handle, payload, writer, loop,
+                               trace_id: bytes) -> None:
         """Chunked transfer from a streaming deployment method — tokens
         flow as the replica yields (TTFT = first chunk)."""
         writer.write(b"HTTP/1.1 200 OK\r\n"
@@ -198,10 +210,11 @@ class HttpProxy:
             it = None
             try:
                 it = handle.stream(payload)
-                for item in it:
-                    if gone.is_set():
-                        raise _ClientGone()
-                    put_blocking(("item", item))
+                with tracing.root(trace_id):   # it submits at its first item
+                    for item in it:
+                        if gone.is_set():
+                            raise _ClientGone()
+                        put_blocking(("item", item))
             except _ClientGone:
                 pass
             except BaseException as e:  # noqa: BLE001

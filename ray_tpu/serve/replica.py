@@ -17,6 +17,8 @@ from typing import Any, Dict, Optional
 
 import cloudpickle
 
+from ray_tpu.utils import tracing
+
 
 class Replica:
     """One deployment copy (created via the actor runtime)."""
@@ -53,17 +55,19 @@ class Replica:
         self._ongoing += 1
         self._total += 1
         try:
-            async with self._sem:
-                _set_current_model_id(model_id)
-                if inspect.iscoroutinefunction(fn):
-                    return await fn(*args, **kwargs)
-                loop = asyncio.get_running_loop()
-                # copy_context: run_in_executor does NOT propagate
-                # contextvars, and get_multiplexed_model_id must work
-                # inside sync callables too.
-                ctx = contextvars.copy_context()
-                return await loop.run_in_executor(
-                    None, lambda: ctx.run(fn, *args, **kwargs))
+            with tracing.span("serve.replica.call", profiler=False,
+                              method=method, ongoing=self._ongoing):
+                async with self._sem:
+                    _set_current_model_id(model_id)
+                    if inspect.iscoroutinefunction(fn):
+                        return await fn(*args, **kwargs)
+                    loop = asyncio.get_running_loop()
+                    # copy_context: run_in_executor does NOT propagate
+                    # contextvars, and get_multiplexed_model_id must work
+                    # inside sync callables too.
+                    ctx = contextvars.copy_context()
+                    return await loop.run_in_executor(
+                        None, lambda: ctx.run(fn, *args, **kwargs))
         finally:
             self._ongoing -= 1
 
@@ -79,7 +83,9 @@ class Replica:
         self._total += 1
         try:
             _set_current_model_id(model_id)
-            yield from fn(*args, **kwargs)
+            with tracing.span("serve.replica.call", profiler=False,
+                              method=method, ongoing=self._ongoing):
+                yield from fn(*args, **kwargs)
         finally:
             self._ongoing -= 1
 

@@ -102,10 +102,12 @@ def get_dataset_shard(name: str = "train"):
     return get_context().get_dataset_shard(name)
 
 
-def profile():
+def profile(directory: Optional[str] = None):
     """Context manager: capture a JAX profiler trace (XPlane, viewable in
-    TensorBoard/XProf) into the run's storage path (reference analogue:
-    SURVEY §5.1 — task timeline + JAX profiler as the TPU tracing story).
+    TensorBoard/XProf) of the device and of `ray_tpu.utils.tracing` spans
+    (`train.step`, `data.iter.*`), without Python's own frames. It goes under
+    `directory`, or under the run's storage path; with neither it refuses
+    rather than write somewhere nobody will look.
 
         with ray_tpu.train.profile():
             state, m = step_fn(state, batch)
@@ -117,11 +119,16 @@ def profile():
     def _ctx():
         import jax
         ctx = get_context()
-        base = ctx.storage_path or "/tmp/ray_tpu_profiles"
+        base = directory or ctx.storage_path
+        if not base:
+            raise RuntimeError("profile() needs a directory: pass one, or "
+                               "set RunConfig.storage_path")
         out = os.path.join(base, ctx.experiment_name or "train_run",
                            f"profile-rank{ctx.rank}")
         os.makedirs(out, exist_ok=True)
-        jax.profiler.start_trace(out)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(out, profiler_options=opts)
         try:
             yield out
         finally:

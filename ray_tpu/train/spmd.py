@@ -8,6 +8,7 @@ sharded step, optimizer-state sharding, and donation).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -19,6 +20,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.models import llama
 from ray_tpu.parallel.context import ParallelContext
 from ray_tpu.parallel.sharding import tree_shardings
+from ray_tpu.utils import tracing
 
 TrainState = Dict[str, Any]  # {"params", "opt_state", "step"}
 
@@ -43,6 +45,31 @@ def state_shardings(cfg: llama.LlamaConfig, ctx: ParallelContext,
     return {"params": param_sh, "opt_state": opt_sh, "step": replicated}
 
 
+class _Traced:
+    """A jitted function with spans around its dispatch: `train.compile`
+    around the first call, which traces and compiles, and, for the step,
+    `train.step` around each later one (a step to XProf too). Everything
+    else (`lower`, `trace`, ...) is the jitted function's own."""
+
+    def __init__(self, jitted: Callable, program: str):
+        self._jitted, self._program, self._calls = jitted, program, 0
+
+    def __call__(self, *args):
+        n, self._calls = self._calls, self._calls + 1
+        if n == 0:
+            around = tracing.compile_span("train.compile",
+                                          program=self._program)
+        elif self._program == "step":
+            around = tracing.span("train.step", step_num=n)
+        else:
+            around = contextlib.nullcontext()
+        with around:
+            return self._jitted(*args)
+
+    def __getattr__(self, name: str):
+        return getattr(self._jitted, name)
+
+
 def make_train_fns(cfg: llama.LlamaConfig, ctx: ParallelContext,
                    opt: Optional[optax.GradientTransformation] = None,
                    loss_fn: Optional[Callable] = None,
@@ -64,9 +91,11 @@ def make_train_fns(cfg: llama.LlamaConfig, ctx: ParallelContext,
     def step_fn(state: TrainState, tokens: jax.Array):
         (l, metrics), grads = jax.value_and_grad(loss, has_aux=True)(
             state["params"], tokens)
-        updates, new_opt = opt.update(grads, state["opt_state"], state["params"])
-        new_params = optax.apply_updates(state["params"], updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):   # clip + AdamW + apply
+            updates, new_opt = opt.update(grads, state["opt_state"],
+                                          state["params"])
+            new_params = optax.apply_updates(state["params"], updates)
+            gnorm = optax.global_norm(grads)
         metrics = dict(metrics, grad_norm=gnorm)
         return ({"params": new_params, "opt_state": new_opt,
                  "step": state["step"] + 1}, metrics)
@@ -76,4 +105,4 @@ def make_train_fns(cfg: llama.LlamaConfig, ctx: ParallelContext,
                        in_shardings=(shardings, batch_sh),
                        out_shardings=(shardings, None),
                        donate_argnums=(0,))
-    return init_jit, step_jit
+    return _Traced(init_jit, "init"), _Traced(step_jit, "step")

@@ -182,12 +182,17 @@ def test_profile_captures_trace(tmp_path):
 
     from ray_tpu.train import session as sess
 
-    ctx = sess.TrainContext(0, 1, "proftest", str(tmp_path))
+    ctx = sess.TrainContext(0, 1, "proftest", "")
     sess._start_session(ctx)
     try:
-        with sess.profile() as out:
+        # No storage path and no directory: it refuses, and writes nowhere.
+        with pytest.raises(RuntimeError, match="needs a directory"):
+            with sess.profile():
+                pass
+        with sess.profile(str(tmp_path)) as out:
             x = jnp.ones((64, 64))
             (x @ x).block_until_ready()
+        assert out.startswith(str(tmp_path))
         found = []
         for root, _dirs, files in os.walk(out):
             found.extend(files)

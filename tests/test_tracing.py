@@ -1,0 +1,296 @@
+"""ray_tpu.utils.tracing: span() and its two sinks, the engine's spans and
+counters, the scope names in the lowered programs, the iterator's spans, and
+a span under an actor task on the timeline. CPU only."""
+
+import glob
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.utils import tracing
+
+SCOPES_DECODE = ("embed", "layers", "attn_norm", "qkv", "rope", "kv_write",
+                 "kv_gather", "attn", "attn_out", "mlp_norm", "mlp", "head",
+                 "sample")
+SCOPES_PREFILL = ("embed", "layers", "attn_norm", "qkv", "rope", "attn",
+                  "attn_out", "mlp_norm", "mlp", "head", "kv_write", "sample")
+SCOPES_TRAIN = ("embed", "layers", "attn_norm", "qkv", "rope", "attn",
+                "attn_out", "mlp_norm", "mlp", "head", "loss", "optimizer")
+
+
+class _Profiled:
+    """`with _Profiled(dir) as p:` traces the body; `p.events(prefix)` are
+    the host events (name, start_ns, end_ns, stats) it left."""
+
+    def __init__(self, directory):
+        self.dir = str(directory)
+
+    def __enter__(self):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+
+    def events(self, prefix):
+        (path,) = glob.glob(os.path.join(self.dir, "**", "*.xplane.pb"),
+                            recursive=True)
+        data = jax.profiler.ProfileData.from_file(path)
+        return sorted(
+            (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith(prefix))
+
+
+def test_span_with_no_profiler_and_no_worker_is_cheap_and_records_nothing(
+        monkeypatch):
+    import ray_tpu.api
+    monkeypatch.setattr(ray_tpu.api, "_core_worker", None)
+    assert tracing._worker() is None and tracing.context() is None
+    t0 = time.perf_counter()
+    for i in range(10_000):
+        with tracing.span("test.noop", i=i) as args:
+            pass
+    assert time.perf_counter() - t0 < 0.2
+    assert args == {"i": 9_999}      # the body's copy, handed nowhere
+
+
+def test_compile_span_counts_what_jax_compiled():
+    @jax.jit
+    def f(x):
+        return x * 3 + 1
+
+    with tracing.compile_span("test.compile", program="f") as first:
+        f(jnp.ones(7)).block_until_ready()
+    with tracing.compile_span("test.compile", program="f") as again:
+        f(jnp.ones(7)).block_until_ready()
+    assert first["compiles"] >= 1 and first["compile_s"] > 0
+    assert again["compiles"] == 0 and again["program"] == "f"
+
+
+@pytest.fixture(scope="module")
+def engine():
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    from ray_tpu.serve.engine import Engine
+    cfg = LlamaConfig.tiny()
+    eng = Engine(init_params(cfg, jax.random.PRNGKey(0)), cfg, n_slots=4,
+                 decode_chunk=4, page_size=16)
+    while sorted(eng._warm) != sorted(eng.buckets):
+        assert not eng.warm_error, eng.warm_error
+        time.sleep(0.05)
+    yield eng
+    eng.stop()
+
+
+def test_engine_spans_land_in_the_profile_and_agree_with_counters(
+        engine, tmp_path):
+    asks = [(5, 7), (40, 12), (17, 3)]      # (prompt tokens, max_tokens)
+    before = engine.counters()
+    with _Profiled(tmp_path) as prof:
+        streams = [engine.submit(list(range(1, 1 + n)), m) for n, m in asks]
+        chunks = []
+        for q in streams:
+            got = []
+            while (toks := q.get()) is not None:
+                got.append(toks)
+            chunks.append(got)
+    after = engine.counters()
+    delta = {k: after[k] - before[k] for k in after
+             if k not in ("peak_pages_used", "n_slots", "chunk")}
+
+    admits = [s for n, _, _, s in prof.events("serve.engine.admit")]
+    assert [a["rid"] for a in admits] == sorted(a["rid"] for a in admits)
+    assert [a["prompt_tokens"] for a in admits] == [n for n, _ in asks]
+    assert all(a["kind"] == "prefill" and a["queue_wait_us"] >= 0
+               and a["bucket"] in engine.buckets
+               and a["bucket"] >= a["prompt_tokens"] for a in admits)
+    assert admits[0]["pending"] >= admits[-1]["pending"] == 0
+    padded = sum(a["bucket"] - a["prompt_tokens"] for a in admits)
+    assert delta["admitted"] == 3
+    assert delta["prefill_tokens"] == sum(n for n, _ in asks)
+    assert delta["prefill_padded_tokens"] == padded == 27 + 24 + 15
+    assert delta["queue_wait_s_sum"] * 1e6 >= \
+        sum(a["queue_wait_us"] for a in admits) - 3
+
+    chunks_d = [s for n, _, _, s in
+                prof.events("serve.engine.decode_dispatch")]
+    streamed_after_first = sum(len(c) for got in chunks for c in got[1:])
+    assert streamed_after_first == sum(m - 1 for _, m in asks)
+    assert sum(c["useful"] for c in chunks_d) == streamed_after_first \
+        == delta["decode_useful_tokens"]
+    assert len(chunks_d) == delta["decode_chunks"]
+    assert all(c["capacity"] == 16 and 1 <= c["active"] <= 3
+               and c["useful"] <= c["active"] * 4 for c in chunks_d)
+
+    emits = prof.events("serve.engine.emit")
+    firsts = [s for n, _, _, s in emits
+              if n == "serve.engine.emit" and s["kind"] == "first"]
+    assert sorted(f["rid"] for f in firsts) == [a["rid"] for a in admits]
+    assert sum(1 for n, _, _, s in emits if n == "serve.engine.emit"
+               and s["kind"] == "chunk") == len(chunks_d)
+    assert sum(1 for n, _, _, _ in emits
+               if n == "serve.engine.emit_block") == len(chunks_d)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "train_step"])
+def test_scope_names_are_in_the_lowered_program(engine, program):
+    def shape_of(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+    if program == "train_step":
+        from ray_tpu.models.llama import LlamaConfig
+        from ray_tpu.parallel import MeshConfig, ParallelContext
+        from ray_tpu.train.spmd import make_train_fns
+        cfg = LlamaConfig.tiny()
+        init, step = make_train_fns(
+            cfg, ParallelContext.create(MeshConfig(), jax.devices()[:1]))
+        state = jax.eval_shape(init._jitted, jax.random.PRNGKey(0))
+        lowered = step.lower(state, jax.ShapeDtypeStruct((2, 32), jnp.int32))
+        wanted = SCOPES_TRAIN
+    else:
+        e = engine
+        arenas = (shape_of(e._kc), shape_of(e._vc))
+        params = jax.tree.map(shape_of, e.params)
+        if program == "decode":
+            n = e.n_slots
+            lowered = e._decode.lower(
+                params, *arenas, jax.ShapeDtypeStruct((n, e.maxp), jnp.int32),
+                jax.ShapeDtypeStruct((n,), jnp.int32),
+                jax.ShapeDtypeStruct((n,), jnp.int32),
+                jax.ShapeDtypeStruct((n,), jnp.bool_),
+                jax.ShapeDtypeStruct((n,), jnp.float32),
+                jax.ShapeDtypeStruct((n,), jnp.int32),
+                jax.ShapeDtypeStruct((n, 2), jnp.uint32))
+            wanted = SCOPES_DECODE
+        else:
+            lowered = e._prefill.lower(
+                params, *arenas, jax.ShapeDtypeStruct((e.maxp,), jnp.int32),
+                jax.ShapeDtypeStruct((1, 32), jnp.int32), 1, 0.0, 0,
+                jax.ShapeDtypeStruct((2,), jnp.uint32))
+            wanted = SCOPES_PREFILL
+    # `loc("jit(f)/attn/dot_general"`; relative to an outlined scan body,
+    # `loc("attn/dot_general"`; differentiated, `jvp(attn)`.
+    text = lowered.as_text(debug_info=True)
+    missing = [s for s in wanted
+               if not re.search(rf'[/"(]{s}[/)]', text)]
+    assert not missing, missing
+
+
+def test_flash_kernels_are_named_in_the_tpu_lowering():
+    from ray_tpu.ops import attention as A
+    x = jax.ShapeDtypeStruct((1, 2, 256, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((1, 2, 256), jnp.float32)
+    fwd = jax.jit(lambda q, k, v: A._flash_fwd_pallas(
+        q, k, v, causal=True, sm_scale=0.1)).trace(x, x, x).lower(
+            lowering_platforms=("tpu",)).as_text()
+    bwd = jax.jit(lambda q, k, v, o, l, do: A._flash_bwd_pallas(
+        q, k, v, o, l, do, causal=True, sm_scale=0.1)).trace(
+            x, x, x, x, lse, x).lower(lowering_platforms=("tpu",)).as_text()
+    assert 'kernel_name = "flash_fwd"' in fwd
+    assert 'kernel_name = "flash_bwd_dkv"' in bwd
+    assert 'kernel_name = "flash_bwd_dq"' in bwd
+
+
+def test_train_step_wrapper_keeps_the_jitted_functions_surface():
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.parallel import MeshConfig, ParallelContext
+    from ray_tpu.train.spmd import make_train_fns
+    cfg = LlamaConfig.tiny(n_layers=1)
+    init, step = make_train_fns(
+        cfg, ParallelContext.create(MeshConfig(), jax.devices()[:1]))
+    assert callable(step.lower) and callable(init.lower)
+    state = init(jax.random.PRNGKey(0))
+    toks = jnp.zeros((2, 32), jnp.int32)
+    state, m0 = step(state, toks)      # train.compile
+    state, m1 = step(state, toks)      # train.step 1
+    assert int(state["step"]) == 2 and float(m1["loss"]) < float(m0["loss"])
+
+
+# -- with a runtime: the iterator's spans, and the timeline sink -------------
+
+@pytest.fixture(scope="module")
+def cluster():
+    from ray_tpu.core.cluster_utils import Cluster
+    c = Cluster(num_nodes=1, resources={"CPU": 4})
+    c.connect()
+    yield c
+    c.shutdown()
+
+
+def test_iter_jax_batches_same_arrays_and_its_spans_a_block(
+        cluster, tmp_path):
+    import ray_tpu
+    from ray_tpu.data.iterator import iter_jax_batches_from_refs
+    blocks = [{"tokens": np.arange(i * 32, (i + 1) * 32,
+                                   dtype=np.int32).reshape(4, 8)}
+              for i in range(3)]
+    refs = [ray_tpu.put(b) for b in blocks]
+    with _Profiled(tmp_path) as prof:
+        got = list(iter_jax_batches_from_refs(iter(refs), batch_size=4))
+    assert len(got) == 3
+    for batch, block in zip(got, blocks):
+        assert isinstance(batch["tokens"], jax.Array)
+        np.testing.assert_array_equal(np.asarray(batch["tokens"]),
+                                      block["tokens"])
+    names = [n for n, _, _, _ in prof.events("data.iter.")]
+    assert sorted(names) == sorted(
+        ["data.iter.get_block", "data.iter.format",
+         "data.iter.device_put"] * 3
+        + ["data.iter.next_ref"] * 4)      # the last finds the source dry
+    puts = [s for n, _, _, s in prof.events("data.iter.device_put")]
+    assert all(p["rows"] == 4 and p["bytes"] == 4 * 8 * 4 for p in puts)
+
+
+def test_span_inside_an_actor_task_is_on_the_timeline_under_its_trace(
+        cluster):
+    import ray_tpu
+    from ray_tpu import state
+    from ray_tpu.core._native import graftscope
+    if not (graftscope.available() and graftscope.enabled()):
+        pytest.skip("graftscope recorder unavailable")
+
+    @ray_tpu.remote
+    class Worker:
+        def work(self, n):
+            import threading
+
+            from ray_tpu.utils import tracing
+            with tracing.span("test.inside_task", n=n):
+                ctx = tracing.context()
+            # A thread acting for the task carries the context explicitly.
+            def other():
+                with tracing.span("test.other_thread", ctx=ctx, n=n):
+                    pass
+            t = threading.Thread(target=other)
+            t.start()
+            t.join()
+            return ctx[0].hex()
+
+    w = Worker.remote()
+    # The path to the timeline is best-effort (a flush the controller misses
+    # is dropped), so ask again until one call's spans are all there.
+    deadline = time.time() + 40     # the worker's flusher ticks every 2 s
+    mine, track = [], None
+    while time.time() < deadline and (len(mine) < 2 or track is None):
+        trace_id = ray_tpu.get(w.work.remote(3))
+        time.sleep(3.0)
+        events = state.timeline(native=True)
+        mine = [e for e in events if e["name"].startswith("test.")
+                and e["args"].get("trace_id") == trace_id]
+        track = next(((e["pid"], e["tid"]) for e in events
+                      if e.get("cat") == "task"
+                      and e["args"]["trace_id"] == trace_id), None)
+    assert {e["name"] for e in mine} == {"test.inside_task",
+                                         "test.other_thread"}
+    for e in mine:
+        assert e["cat"] == "program" and e["args"]["n"] == 3
+        assert e["args"]["mono_ns"] > 0 and e["dur"] >= 0
+        assert (e["pid"], e["tid"]) == track     # nested under the task
