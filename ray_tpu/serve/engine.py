@@ -8,12 +8,16 @@ engine is OURS):
 - **Paged KV arena** `[n_layers, n_pages, page, kv_heads, head_dim]` with
   a per-slot BLOCK TABLE `[n_slots, max_pages]` of physical page ids —
   vLLM's block-table design recast for XLA: the table is a device array,
-  reads are one gather per layer (`kc[bt]`), writes are one scatter at
-  each slot's position. A 50-token request holds ceil(50/page) pages, not
-  a max_seq strip, so concurrency is bounded by TOKENS in flight, not by
-  worst-case sequences. Page 0 is the NULL page: unused/overflow table
-  entries point at it, making out-of-reservation writes harmless and
-  gathers of unused pages maskable — no data-dependent control flow.
+  reads are one gather per layer straight out of the arena (`kc[l, bt]`),
+  writes are one scatter of a row per slot straight into it
+  (`kc.at[l, page, offset]`). The decode program updates the arena IN
+  PLACE: it is a loop carry that nothing but those two ops touches, so
+  no copy of it (or of a layer's slab) is ever made. A 50-token request
+  holds ceil(50/page) pages, not a max_seq strip, so concurrency is
+  bounded by TOKENS in flight, not by worst-case sequences. Page 0 is
+  the NULL page: unused/overflow table entries point at it, making
+  out-of-reservation writes harmless and gathers of unused pages
+  maskable — no data-dependent control flow.
 - **Reservation admission**: a request is admitted when
   ceil(min(len+max_tokens, max_seq)/page) free pages exist — growth can
   then never fail mid-decode, so there is no preemption/recompute path
@@ -219,8 +223,9 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         out = jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
         return out.astype(x.dtype)
 
-    def _decode_layer(x, lp, kc_l, vc_l, bt, pos, act, cos, sin):
-        # x [ns, D]; kc_l/vc_l [n_pages, page, KVH, hd]; bt [ns, maxp]
+    def _decode_layer(x, kc, vc, lp, l, bt, pos, act, cos, sin):
+        # x [ns, D]; kc/vc the WHOLE arena [L, n_pages, page, KVH, hd];
+        # l this layer's index (traced scalar); bt [ns, maxp]
         with jax.named_scope("attn_norm"):
             h = rms_norm(x, lp["attn_norm"], mcfg.norm_eps)
         with jax.named_scope("qkv"):
@@ -233,20 +238,23 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             s = sin[w][:, None]
             q = _rope_one(q, c, s)
             k = _rope_one(k, c, s)
-        # Scatter k/v at each slot's (page, offset). Inactive slots (and
-        # positions past a slot's reservation) route to the NULL page 0,
-        # whose content is never read unmasked — the write stays a
-        # fixed-shape scatter with no data-dependent branches.
+        # Scatter k/v at each slot's (layer, page, offset), straight into
+        # the arena: `ns` rows, no layer slab cut out or put back.
+        # Inactive slots (and positions past a slot's reservation) route
+        # to the NULL page 0, whose content is never read unmasked — the
+        # write stays a fixed-shape scatter with no data-dependent
+        # branches.
         with jax.named_scope("kv_write"):
             idx = jnp.arange(ns)
             pp = jnp.where(act, bt[idx, w // page], 0)
             off = jnp.where(act, w % page, 0)
-            kc_l = kc_l.at[pp, off].set(k)
-            vc_l = vc_l.at[pp, off].set(v)
-        # Gather each slot's pages -> its logical KV history.
+            kc = kc.at[l, pp, off].set(k)
+            vc = vc.at[l, pp, off].set(v)
+        # Gather each slot's pages of this layer -> its logical KV
+        # history: one gather with (layer, page) start indices.
         with jax.named_scope("kv_gather"):
-            kh = kc_l[bt].reshape(ns, CTX, KVH, hd)
-            vh = vc_l[bt].reshape(ns, CTX, KVH, hd)
+            kh = kc[l, bt].reshape(ns, CTX, KVH, hd)
+            vh = vc[l, bt].reshape(ns, CTX, KVH, hd)
         # Grouped-query attention against the gathered history.
         with jax.named_scope("attn"):
             qg = q.reshape(ns, KVH, H // KVH, hd).astype(jnp.float32)
@@ -266,7 +274,7 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             gate = h @ lp["w_gate"].astype(dt)
             up = h @ lp["w_up"].astype(dt)
             x = x + (jax.nn.silu(gate) * up) @ lp["w_down"].astype(dt)
-        return x, kc_l, vc_l
+        return x, kc, vc
 
     def _step(params, kc, vc, bt, last, pos, active, cos, sin,
               temp, topk, keys):
@@ -275,16 +283,24 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             x = jnp.take(params["embed"], last, axis=0).astype(dt)
 
         def body(carry, layer):
-            x = carry
-            lp, kc_l, vc_l = layer
-            x, kc_l, vc_l = _decode_layer(x, lp, kc_l, vc_l, bt, pos,
-                                          act, cos, sin)
-            return x, (kc_l, vc_l)
+            x, kc, vc = carry
+            lp, l = layer
+            return _decode_layer(x, kc, vc, lp, l, bt, pos, act,
+                                 cos, sin), None
 
-        # The arena rides this scan: whatever the compiler copies to carry
-        # it is named `layers` and nothing deeper.
+        # The arena rides this scan's CARRY, and only a scatter and a
+        # gather touch it, so the layer loop, the chunk loop around it and
+        # the donated entry buffers all alias ONE buffer: a step changes
+        # `ns` rows a layer and moves nothing else. It must stay out of
+        # the scan's xs/ys: an xs is read-only and a ys is a freshly
+        # stacked result, so the compiler would slice every layer's slab
+        # out, write it into a second arena and copy that back as the next
+        # step's carry (2.9 GB a step at 12 layers x 929 pages; PERF.md,
+        # PR 25). The xs are the layer's weights and its index.
         with jax.named_scope("layers"):
-            x, (kc, vc) = jax.lax.scan(body, x, (params["layers"], kc, vc))
+            (x, kc, vc), _ = jax.lax.scan(
+                body, (x, kc, vc),
+                (params["layers"], jnp.arange(mcfg.n_layers)))
         with jax.named_scope("head"):
             x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
             logits = x @ params["lm_head"].astype(dt)      # [ns, V]

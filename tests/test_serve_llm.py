@@ -343,3 +343,195 @@ def test_sampling_temperature_topk_seed():
         assert outs[0] == greedy and outs[1] == s1 and outs[2] == s3
     finally:
         eng.stop()
+
+
+# ----------------------------------------------------------------------
+# The decode program updates the KV arena in place (PR 25)
+# ----------------------------------------------------------------------
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _all_eqns(sub)
+
+
+def _tiny_decode(n_layers=3):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    from ray_tpu.serve.engine import _build_fns
+
+    cfg = LlamaConfig(vocab_size=128, d_model=32, n_layers=n_layers,
+                      n_heads=4, n_kv_heads=2, d_ff=64, max_seq=64,
+                      dtype=np.float32)
+    ns, chunk, page, n_pages = 3, 4, 16, 9
+    _, decode, _, _, empty = _build_fns(cfg, ns, chunk, page, n_pages)
+    kc, vc = empty()
+    args = (init_params(cfg, jax.random.PRNGKey(0)), kc, vc,
+            jnp.zeros((ns, cfg.max_seq // page), jnp.int32),
+            jnp.zeros(ns, jnp.int32), jnp.zeros(ns, jnp.int32),
+            jnp.zeros(ns, bool), jnp.zeros(ns, jnp.float32),
+            jnp.zeros(ns, jnp.int32), jnp.zeros((ns, 2), jnp.uint32))
+    return decode, args, chunk
+
+
+def test_decode_arena_is_a_scan_carry_only_scattered_and_gathered():
+    """The arena must ride the layer scan's CARRY and be touched by one
+    scatter and one gather for each of K and V — nothing else. As an
+    xs/ys of the scan it is sliced out a layer at a time and restacked
+    into a second arena every step (55% of a decode step on the chip
+    before PR 25). Read on the jaxpr, so any backend shows it."""
+    import jax
+
+    decode, args, chunk = _tiny_decode(n_layers=3)
+    arena = args[1].shape
+    slab = arena[1:]
+    scans = [e for e in _all_eqns(jax.make_jaxpr(decode)(*args).jaxpr)
+             if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == [chunk, arena[0]]
+    layers = scans[1]
+    n_fixed = layers.params["num_consts"] + layers.params["num_carry"]
+    carry = layers.invars[layers.params["num_consts"]:n_fixed]
+    xs, ys = layers.invars[n_fixed:], layers.outvars[
+        layers.params["num_carry"]:]
+    assert [v.aval.shape for v in carry].count(arena) == 2
+    assert not [v.aval.shape for v in list(xs) + list(ys)
+                if v.aval.shape in (arena, slab)]
+    # Inside the layer: no slab exists, and the arena (as it came in, or
+    # as a scatter left it) is consumed by scatter and gather alone.
+    body = layers.params["jaxpr"].jaxpr
+    consumers = []
+    for eqn in _all_eqns(body):
+        shapes = [getattr(v.aval, "shape", None)
+                  for v in list(eqn.invars) + list(eqn.outvars)]
+        assert slab not in shapes, eqn
+        if arena in shapes[:len(eqn.invars)]:
+            consumers.append(eqn.primitive.name)
+    assert sorted(consumers) == ["gather", "gather", "scatter", "scatter"]
+
+
+def test_decode_call_donates_the_arena():
+    """In place across calls too: the arena handed to decode_jit is
+    consumed (deleted), not copied, wherever the backend donates."""
+    decode, args, _ = _tiny_decode(n_layers=2)
+    kc, vc, last, pos = args[1], args[2], args[4], args[5]
+    out = decode(*args)
+    out[0].block_until_ready()
+    if not last.is_deleted():
+        pytest.skip("this backend does not donate buffers")
+    assert kc.is_deleted() and vc.is_deleted() and pos.is_deleted()
+    assert out[0].shape == kc.shape and out[1].shape == vc.shape
+
+
+@pytest.fixture(scope="module")
+def paged3():
+    """A 3-layer float32 engine over 4 usable pages of 16 tokens and 3
+    slots, with the naive reference: a full causal forward pass a token,
+    sampled by the engine's own (seed, position) rule."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.llama import LlamaConfig, forward, init_params
+    from ray_tpu.serve.engine import Engine, _sample_tokens, _seed_key
+
+    cfg = LlamaConfig(vocab_size=128, d_model=32, n_layers=3, n_heads=4,
+                      n_kv_heads=2, d_ff=64, max_seq=64, dtype=np.float32)
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    fwd = jax.jit(lambda p, t: forward(p, t, cfg, None))
+
+    def naive(prompt, n, temperature=0.0, top_k=0, seed=0):
+        ids, out = list(prompt), []
+        key = jnp.asarray(_seed_key(seed))[None]
+        for _ in range(n):
+            # One shape for every length: causal, so the zero tail is
+            # never attended by the row that is read.
+            toks = np.zeros((1, cfg.max_seq), np.int32)
+            toks[0, :len(ids)] = ids
+            row = fwd(params, jnp.asarray(toks))[0, len(ids) - 1]
+            out.append(int(_sample_tokens(
+                row[None], jnp.asarray([temperature], jnp.float32),
+                jnp.asarray([top_k], jnp.int32), key,
+                jnp.asarray([len(ids) - 1], jnp.int32))[0]))
+            ids.append(out[-1])
+        return out
+
+    eng = Engine(params, cfg, n_slots=3, decode_chunk=4, page_size=16,
+                 n_pages=5)
+
+    def gen(prompt, n, **kw):
+        q = eng.submit(prompt, n, **kw)
+        out = []
+        while True:
+            item = q.get(timeout=60)
+            if item is None:
+                return out
+            out.extend(item)
+
+    yield eng, gen, naive
+    eng.stop()
+
+
+def _together(gen, asks):
+    import threading
+    outs = [None] * len(asks)
+
+    def run(i):
+        prompt, n, kw = asks[i]
+        outs[i] = gen(prompt, n, **kw)
+
+    ts = [threading.Thread(target=run, args=(i,)) for i in range(len(asks))]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(timeout=120)
+    return outs
+
+
+SAMPLING = {"greedy": {},
+            "sampled": {"temperature": 0.8, "top_k": 5, "seed": 1234}}
+
+
+@pytest.mark.parametrize("sampling", sorted(SAMPLING))
+@pytest.mark.parametrize("scenario", ["page_boundary_mid_chunk",
+                                      "slots_go_inactive",
+                                      "freed_pages_reused"])
+def test_paged_engine_matches_naive_cases(paged3, scenario, sampling):
+    """test_paged_engine_matches_naive_greedy's check, on the paths an
+    in-place arena could break: a write that crosses into the slot's next
+    page in the middle of a chunk, slots that are (or fall) inactive while
+    another decodes (their rows land in null page 0), and a request that
+    is handed the pages a finished one left its rows in. Tokens equal the
+    naive reference exactly, greedy and seeded."""
+    eng, gen, naive = paged3
+    kw = SAMPLING[sampling]
+    if scenario == "page_boundary_mid_chunk":
+        # Positions 14..23: the first chunk of 4 writes 14, 15 | 16, 17.
+        prompt = list(range(3, 17))
+        want = naive(prompt, 10, **kw)
+        assert gen(prompt, 10, **kw) == want
+        assert (want == naive(prompt, 10)) == (not kw)  # sampling samples
+    elif scenario == "slots_go_inactive":
+        # One slot never used, one finishing after 3 tokens while the
+        # third keeps decoding across a page boundary.
+        asks = [([9, 8, 7], 3, kw), (list(range(20, 34)), 12, kw)]
+        assert _together(gen, asks) == [naive(*a[:2], **kw) for a in asks]
+    else:
+        # 3 of the 4 usable pages each: the second request is handed at
+        # least two pages holding the first one's rows.
+        first, second = list(range(1, 34)), [5] * 30 + [6, 7, 8]
+        assert gen(first, 9, **kw) == naive(first, 9, **kw)
+        assert eng.pages_in_use() == 0
+        assert gen(second, 9, **kw) == naive(second, 9, **kw)
+        assert eng.peak_pages_used <= 4
+    assert eng.error is None

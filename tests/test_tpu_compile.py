@@ -114,6 +114,52 @@ def test_pp_with_kernel_fails_clearly(topo, monkeypatch):
         _tiny_step(topo, MeshConfig(pp=2, dp=2), monkeypatch)
 
 
+def test_decode_program_updates_the_arena_in_place_on_v5e(topo):
+    """The serving decode chunk at Mistral-7B widths (4 of its layers),
+    compiled for the chip: the KV arena must alias through the layer
+    loop, the chunk loop and the donated entry buffers. As a scan xs/ys
+    it was sliced out a layer at a time and restacked into a second arena
+    every step: 24 of a 44 ms step on the chip (PERF.md, PR 25). The
+    jaxpr test in tests/test_serve_llm.py holds the program's shape; this
+    one holds what the TPU compiler makes of it."""
+    import re
+
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    from ray_tpu.serve.engine import _build_fns
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = LlamaConfig(vocab_size=32768, d_model=4096, n_layers=4,
+                      n_heads=32, n_kv_heads=8, d_ff=14336, max_seq=4096,
+                      dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    ns, chunk, page, n_pages = 16, 8, 64, 929
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    _, decode, _, _, _ = _build_fns(cfg, ns, chunk, page, n_pages)
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    arena = sds((cfg.n_layers, n_pages, page, 8, 128), jnp.bfloat16)
+    compiled = decode.lower(
+        params, arena, arena, sds((ns, cfg.max_seq // page), jnp.int32),
+        sds((ns,), jnp.int32), sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
+        sds((ns,), jnp.float32), sds((ns,), jnp.int32),
+        sds((ns, 2), jnp.uint32)).compile()
+    # Every op whose result is arena- or slab-shaped: none may be a copy, a
+    # slice or an update-slice, bare or fused (a fusion carries the op in
+    # its name: `bitcast_dynamic-update-slice_fusion`).
+    results = re.findall(
+        r"%(\S+) = bf16\[(?:4,)?929,64,8,128\]\S* ([\w-]+)\(",
+        compiled.as_text())
+    assert "scatter" in {op for _, op in results}  # the pattern still reads
+    moved = [name for name, op in results
+             if op == "copy" or "dynamic-" in op + name]
+    assert not moved, moved
+    one_arena = cfg.n_layers * n_pages * page * 8 * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_arena
+
+
 # ---------------------------------------------------------------------------
 # Chip pinning env (no compiler needed)
 # ---------------------------------------------------------------------------
