@@ -87,13 +87,21 @@ def check_outputs(ctx, replica: Replica, host, port, path) -> Dict[str, Any]:
     gaps = replica.ask("bench_check", [
         {"prompt": r.prompt, "served": s} for r, s in zip(reqs, served)],
         timeout=900.0)
+    verdict = judge(gaps, chk)
+    return dict(verdict, prompt_lengths=lengths, logit_gaps=gaps,
+                ok=verdict["ok"] and all(len(s) == chk["tokens"]
+                                         for s in served))
+
+
+def judge(gaps: List[List[float]], chk: Dict[str, Any]) -> Dict[str, Any]:
+    """The limits of a traffic file's `check` applied to the gaps of a run
+    (or of its control, benchmark/control.py)."""
     flat = [g for case in gaps for g in case]
     worst, mean = max(flat), sum(flat) / len(flat)
-    return {"prompt_lengths": lengths, "logit_gaps": gaps, "worst_gap": worst,
-            "mean_gap": mean, "tolerance": chk["logit_tolerance"],
+    return {"worst_gap": worst, "mean_gap": mean,
+            "tolerance": chk["logit_tolerance"],
             "mean_tolerance": chk["mean_logit_tolerance"],
-            "ok": all(len(s) == chk["tokens"] for s in served)
-            and worst <= chk["logit_tolerance"]
+            "ok": worst <= chk["logit_tolerance"]
             and mean <= chk["mean_logit_tolerance"]}
 
 

@@ -1,19 +1,19 @@
-"""Model step (prefill): device time of the `jit_prefill` programs in the
-traced window over the thousands of prompt tokens prefilled in it (requests
-whose first token the replica stamped inside the traced window).
-device_trace."""
+"""Model step (prefill): device time of the `jit_prefill` executions of the
+trace over the thousands of prompt tokens they prefilled, both sides from the
+trace's own pairing of each `serve.engine.admit` span with its execution
+(program_trace.ProgramTrace.prefills), so both cover the same requests. (Up
+to PR 25 the tokens were counted between the driver's marks around the
+profiler calls; a saturated replica answers `bench_trace_stop` seconds late,
+the trace runs on, and the time was summed over twice the window the tokens
+came from: 101.7 read where this reads about half.) device_trace."""
+
+from benchmark import program_trace
 
 
 def read(run):
-    data, marks = run["trace_data"], run["marks"]
-    if data is None or "trace_start" not in marks:
+    t = program_trace.load(run)
+    pairs = t.prefills() if t else []
+    tokens = sum(admit.args["prompt_tokens"] for admit, _, _ in pairs)
+    if not tokens:
         return None
-    by_id = {str(o["index"]): o for o in run["outcomes"]}
-    tokens = sum(by_id[i]["prompt_tokens"]
-                 for i, s in run["replica"]["stamps"].items()
-                 if i in by_id
-                 and marks["trace_start"] <= s[1] <= marks["trace_stop"])
-    d = data.module_durations("jit_prefill")
-    if not tokens or not d:
-        return None
-    return sum(d) * 1e3 / (tokens / 1e3)
+    return sum(e - s for _, (_, s, e), _ in pairs) / 1e6 / (tokens / 1e3)

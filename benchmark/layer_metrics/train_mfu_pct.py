@@ -1,8 +1,8 @@
 """Device (train): operations the forward and backward passes require per
-token (benchmark/flops.py; recompute not counted) times this run's tokens a
+token (the architecture's counts; recompute not counted) times this run's tokens a
 second a chip, over the chip's peak. host_clock over a count from shapes."""
 
-from benchmark import flops, peaks
+from benchmark import models, peaks
 from benchmark.stats import train_rate
 
 
@@ -10,6 +10,7 @@ def read(run):
     rate = train_rate(run)
     if rate is None:
         return None
-    per_token = flops.train_flops_per_token(run["config"], run["seq"])
+    counts = models.adapter(run["config"]["arch"]).counts
+    per_token = counts.train_flops_per_token(run["config"], run["seq"])
     return 100.0 * rate * per_token / peaks.peak(
         run["device"]["kind"], "bf16_flops_per_s")

@@ -3,14 +3,32 @@ the program's `LlamaConfig` (ray_tpu/models/llama.py), which is the one block
 this repo runs: pre-norm, rotary (split-half), grouped-query attention,
 SwiGLU, untied head, no biases, no sliding window.
 
-A configuration file names its adapter under `arch`. An adapter is a module
-here with `to_model_kwargs(model, dtypes, max_seq)`, `init_params` and
-`check_supported`; a later architecture is a new file.
+A configuration file names its adapter under `arch`, and the adapter is the
+one place the harness learns anything that depends on the architecture. A
+later architecture is a new file here with the same names:
+
+  check_supported, build_config, init_params   the program's model, from the
+                                               published keys and the seed
+  reference()    the module with the plain float32 forward of this block:
+                 `served_token_gaps(params, m, prompt, served)` and
+                 `loss_and_check_grads(params, m, tokens)`
+  loss_fn        the system's side of the train check
+  CHECK_LEAVES   name -> path in the parameter tree of each leaf whose
+                 gradient the train check compares with the reference's
+  counts         the module with `train_flops_per_token(m, seq)`,
+                 `prefill_flops(m, n)`, `decode_step_ops_bytes(...)` and
+                 `total_params(m)` for this block
+  REHEARSE       the tiny widths `--rehearse` runs on the CPU
+
+Importing an adapter imports neither jax nor the program: `run.py`, which
+must stay off the chip, reads `REHEARSE` and `counts` from it.
 """
 
 from __future__ import annotations
 
 from typing import Any, Dict
+
+from benchmark import flops as counts  # noqa: F401  (the dense block's counts)
 
 KEYS = {  # published key -> LlamaConfig field
     "vocab_size": "vocab_size",
@@ -22,6 +40,15 @@ KEYS = {  # published key -> LlamaConfig field
     "rope_theta": "rope_theta",
     "rms_norm_eps": "norm_eps",
 }
+
+
+CHECK_LEAVES = {"final_norm": ("final_norm",),
+                "attn_norm": ("layers", "attn_norm"),
+                "mlp_norm": ("layers", "mlp_norm")}
+
+REHEARSE = {"hidden_size": 64, "num_attention_heads": 4,
+            "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
+            "vocab_size": 256, "num_hidden_layers": 2}
 
 
 def check_supported(model: Dict[str, Any]) -> None:
@@ -69,3 +96,13 @@ def init_params(cfg, seed: int):
     from ray_tpu.models.llama import init_params as _init
     key = jax.random.PRNGKey(int(seed) % (2 ** 31 - 1))
     return jax.jit(lambda k: _init(cfg, k))(key)
+
+
+def reference():
+    from benchmark import reference as ref
+    return ref
+
+
+def loss_fn(params, tokens, cfg, pctx):
+    from ray_tpu.models import llama
+    return llama.loss_fn(params, tokens, cfg, pctx)

@@ -9,7 +9,9 @@ Everything a cell is made of is data that this file finds by name:
   benchmark/configs/<config>.json      sizes, `arch`, dtypes, deployment
   benchmark/traffic/<traffic>.json     the mix, and `kind`: the driver
   benchmark/drivers/<kind>.py          brings the system up, offers the load
-  benchmark/models/<arch>.py           published keys -> the program's config
+  benchmark/models/<arch>.py           published keys -> the program's config;
+                                       the reference, the check's leaves, the
+                                       counts and the rehearsal widths
   benchmark/end_to_end/<metric>.py     reader: run record -> value
   benchmark/layer_metrics/<metric>.py  reader: run record -> value or None
 
@@ -76,8 +78,9 @@ def load_reader(bench_dir: str, group_dir: str, name: str) -> Callable:
 
 def apply_rehearsal(bench_dir: str, config: Dict[str, Any]) -> float:
     """Tiny widths for the sandbox; returns the factor for traffic lengths."""
+    from benchmark import models
     r = load_json(bench_dir, "rehearse.json")
-    config.update(r["model"][config["arch"]])
+    config.update(models.adapter(config["arch"]).REHEARSE)
     dep = config["deployment"]
     dep["max_seq"] = r["max_seq"]
     if "engine" in dep:
@@ -142,6 +145,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         return 1
     if run is None:          # a sweep: it printed its own lines
         return 0
+    # Each number the correctness check compared, beside its limit.
+    print("check:", json.dumps(
+        {k: v for k, v in run["check"].items() if k != "logit_gaps"},
+        default=str), flush=True)
 
     group, reader_dir = (("per_layer", "layer_metrics") if args.trace
                          else ("end_to_end", "end_to_end"))

@@ -15,12 +15,12 @@ correctness check, the profiler, and the counters the per-layer metrics read.
 
 from __future__ import annotations
 
-import importlib
 import json
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from benchmark import models
 from ray_tpu.serve.llm import LLMConfig, LLMServer
 
 COMPILE_EVENTS = ("/jax/compilation_cache/cache_hits",
@@ -47,7 +47,7 @@ class BenchLLMServer(LLMServer):
 
         spec = json.loads(spec_json)
         model, eng = spec["model"], spec["engine"]
-        adapter = importlib.import_module(f"benchmark.models.{spec['arch']}")
+        adapter = self.adapter = models.adapter(spec["arch"])
         self.model = model
         # The inherited methods read tokenizer, detokenizer and d_model here.
         self.cfg = LLMConfig(
@@ -119,8 +119,8 @@ class BenchLLMServer(LLMServer):
 
     def bench_check(self, cases: List[Dict[str, List[int]]]) -> List[List[float]]:
         """Per case, the reference's largest logit minus its logit of each
-        served token (benchmark/reference.py)."""
-        from benchmark import reference
+        served token (the adapter's plain reference)."""
+        reference = self.adapter.reference()
         return [reference.served_token_gaps(self.engine.params, self.model,
                                             c["prompt"], c["served"])
                 for c in cases]

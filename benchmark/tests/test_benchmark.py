@@ -20,7 +20,14 @@ BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, ROOT)
 
-from benchmark import flops, peaks, trace, traffic  # noqa: E402
+import benchmark  # noqa: E402
+from benchmark import flops, models, peaks, trace, traffic  # noqa: E402
+
+FIXTURE = os.path.join(HERE, "fixtures", "tinymoe")
+CONTRACT = ("check_supported", "build_config", "init_params", "reference",
+            "loss_fn", "CHECK_LEAVES", "counts", "REHEARSE")
+COUNTS = ("train_flops_per_token", "prefill_flops", "decode_step_ops_bytes",
+          "total_params")
 
 MISTRAL = dict(hidden_size=4096, num_attention_heads=32, num_key_value_heads=8,
                intermediate_size=14336, vocab_size=32768, num_hidden_layers=2)
@@ -146,40 +153,152 @@ def test_unknown_device_kind_is_an_error():
         peaks.peak("TPU v9", "bf16_flops_per_s")
 
 
-# -- the reference against the program, tiny widths ------------------------
+# -- the adapter contract: reference against program, counts ---------------
 
-def test_reference_agrees_with_the_program_at_tiny_widths():
-    import jax
-    import jax.numpy as jnp
+@pytest.fixture
+def tinymoe_on_path():
+    """The fixture architecture's files, importable under the names they
+    would have had they been added to benchmark/ (as the copy test adds them)."""
+    added = [(benchmark.__path__, FIXTURE),
+             (models.__path__, os.path.join(FIXTURE, "models"))]
+    for path, d in added:
+        path.append(d)
+    yield
+    for path, d in added:
+        path.remove(d)
+    for name in [n for n in sys.modules if "tinymoe" in n]:
+        del sys.modules[name]
 
-    from benchmark import reference
-    from benchmark.models import llama as adapter
-    from ray_tpu.models import llama
 
-    model = dict(vocab_size=256, hidden_size=64, num_hidden_layers=2,
-                 num_attention_heads=4, num_key_value_heads=2,
-                 intermediate_size=128, rope_theta=1e6, rms_norm_eps=1e-5)
+def _tiny(arch):
+    adapter = models.adapter(arch)
+    model = dict(adapter.REHEARSE, rope_theta=1e6, rms_norm_eps=1e-5)
     cfg = adapter.build_config(model, {"params": "float32",
                                        "activations": "float32"}, 128)
-    assert (cfg.n_kv_heads, cfg.d_ff, cfg.rope_theta) == (2, 128, 1e6)
-    params = adapter.init_params(cfg, 3)
+    return adapter, model, cfg, adapter.init_params(cfg, 3)
+
+
+@pytest.mark.parametrize("arch", ["llama", "tinymoe"])
+def test_adapter_exposes_the_whole_contract(arch, tinymoe_on_path):
+    adapter = models.adapter(arch)
+    assert not [n for n in CONTRACT if not hasattr(adapter, n)]
+    assert not [n for n in COUNTS if not callable(getattr(adapter.counts, n))]
+    ref = adapter.reference()
+    assert callable(ref.served_token_gaps) and callable(ref.loss_and_check_grads)
+    assert {"hidden_size", "num_hidden_layers", "vocab_size"} <= set(adapter.REHEARSE)
+    for path in adapter.CHECK_LEAVES.values():
+        assert all(isinstance(k, str) for k in path)
+
+
+@pytest.mark.parametrize("arch", ["llama", "tinymoe"])
+def test_reference_agrees_with_the_program_at_tiny_widths(arch, tinymoe_on_path):
+    """Through the contract alone: the serve check's gaps and the train
+    check's comparison (benchmark/train_loop.py), program against reference
+    on the same float32 weights."""
+    import jax.numpy as jnp
+
+    from benchmark.train_loop import _check_against_reference
+    from ray_tpu.models import llama
+
+    adapter, model, cfg, params = _tiny(arch)
+    if arch == "llama":
+        assert (cfg.n_kv_heads, cfg.d_ff, cfg.rope_theta) == (2, 128, 1e6)
+    else:
+        assert (cfg.n_experts, cfg.top_k_experts, cfg.d_ff) == (4, 2, 64)
     toks = np.random.default_rng(0).integers(0, 256, (2, 96), dtype=np.int32)
-    want = llama.forward(params, jnp.asarray(toks), cfg)
-    got = reference.logits_last(params, model, list(toks[0]), 17)
-    assert np.allclose(got, want[0, -17:], atol=2e-4)
-    loss, grads = reference.loss_and_norm_grads(params, model, jnp.asarray(toks))
-    sys_loss, _ = llama.loss_fn(params, jnp.asarray(toks), cfg)
-    assert float(loss) == pytest.approx(float(sys_loss), rel=1e-5)
-    assert grads["attn_norm"].shape == (2, 64)
-    gaps = reference.served_token_gaps(
-        params, model, list(toks[0, :90]), [int(jnp.argmax(want[0, 89]))])
-    assert gaps == [pytest.approx(0.0, abs=1e-4)]
+    # One row, as the serve check sees it: a sparse block's capacity, and so
+    # which assignments overflow, is counted over all the tokens of a call.
+    want = np.asarray(llama.forward(params, jnp.asarray(toks[:1]), cfg))[0]
+    ref = adapter.reference()
+    # A served token's gap is the reference's largest logit less its logit of
+    # that token: the program's own logits say what it must be. Token 5 is
+    # arbitrary; the argmax must read 0.
+    prompt, tail = [int(t) for t in toks[0, :80]], [int(t) for t in toks[0, 80:]]
+    gaps = ref.served_token_gaps(params, model, prompt, tail + [5])
+    rows = want[79:]
+    served = np.asarray(tail + [5])
+    assert np.allclose(gaps, rows.max(-1) - rows[np.arange(17), served],
+                       atol=2e-4)
+    assert ref.served_token_gaps(params, model, prompt + tail,
+                                 [int(want[-1].argmax())]) == \
+        [pytest.approx(0.0, abs=1e-4)]
+    chk = _check_against_reference(adapter, params, jnp.asarray(toks), cfg,
+                                   None, model, 64)
+    assert chk["loss_rel_err"] < 1e-5 and chk["param_dtypes"] == ["float32"]
+    names = {"final_norm", "last_attn_norm", "last_mlp_norm"}
+    assert set(chk["grad_rel_err"]) == (names | {"last_router"}
+                                        if arch == "tinymoe" else names)
+    assert max(chk["grad_rel_err"].values()) < 1e-4, chk
+
+
+def test_dense_reference_cannot_pass_a_sparse_block(tinymoe_on_path):
+    """`tinymoe` pointed at llama's reference (what the harness did for every
+    `arch` before it asked the adapter) raises or disagrees; it never passes."""
+    import jax.numpy as jnp
+
+    from benchmark.train_loop import _check_against_reference
+
+    _, model, cfg, params = _tiny("tinymoe")
+    wrong = models.adapter("tinymoe_llamaref")
+    assert wrong.reference() is models.adapter("llama").reference()
+    toks = jnp.asarray(np.random.default_rng(0).integers(
+        0, 256, (1, 64), dtype=np.int32))
+    try:
+        chk = _check_against_reference(wrong, params, toks, cfg, None, model, 64)
+    except (TypeError, ValueError):
+        return
+    assert chk["loss_rel_err"] > 1e-3 or \
+        max(chk["grad_rel_err"].values()) > 0.08
+
+
+def test_sparse_counts_against_a_hand_count(tinymoe_on_path):
+    counts = models.adapter("tinymoe").counts
+    m = dict(models.adapter("tinymoe").REHEARSE)
+    # a token multiplies: q 64x64, k and v 64x32 each, o 64x64, the router
+    # 64x4, and 2 of 4 experts of three 64x64 matrices; the head 64x256.
+    active = 4096 + 2 * 2048 + 4096 + 256 + 2 * 3 * 4096
+    assert active == 37_120
+    attn = 2 * 4.0 * 4 * 16 * (128 * 129 / 2) / 128      # 2 layers, causal
+    assert counts.train_flops_per_token(m, 128) == \
+        3 * (2 * (2 * active + 16_384) + attn) == 642_816
+    assert counts.total_params(m) == \
+        2 * (active + 2 * 3 * 4096 + 2 * 64) + 2 * 16_384 + 64
+    assert counts.prefill_flops(m, 128) == \
+        2.0 * 2 * active * 128 + attn * 128 + 2.0 * 16_384
+    ops, byts = counts.decode_step_ops_bytes(m, [100, 28], 2, 2)
+    assert ops == 2 * 2 * (2 * active + 16_384) + 2 * 4.0 * 4 * 16 * 128
+    assert byts == 2 * (counts.total_params(m) - 16_384) \
+        + 2 * (2 * 2 * 16 * 2) * 128
+    # and the dense block's count of the same keys is another number
+    assert flops.train_flops_per_token(m, 128) != 642_816
+
+
+def test_dense_counts_are_reached_through_the_adapter():
+    counts = models.adapter("llama").counts
+    assert counts.train_flops_per_token(MISTRAL, 4096) == \
+        flops.train_flops_per_token(MISTRAL, 4096)
+    assert counts.total_params(MISTRAL) == 2 * (218_103_808 + 8192) \
+        + 2 * 134_217_728 + 4096
 
 
 # -- the manifest ----------------------------------------------------------
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+# Widths may never be reduced: hidden, intermediate, latent, state and
+# projection sizes, head sizes, expansion factors, experts per token, windows.
+WIDTH = re.compile(r"(hidden_size|intermediate_size|head_dim|_dim\b|_rank\b"
+                   r"|num_experts_per_tok|sliding_window|state_size|d_state"
+                   r"|d_conv|conv_kernel|expand|projection)")
+# What each source publishes (the keys that decide the shapes of its blocks).
+PUBLISHED = {
+    "https://huggingface.co/mistralai/Mistral-7B-v0.3/blob/main/config.json":
+        dict(hidden_size=4096, num_attention_heads=32, num_key_value_heads=8,
+             intermediate_size=14336, vocab_size=32768, rope_theta=1e6,
+             num_hidden_layers=32),
+}
 
 
 def test_manifest_is_consistent_with_the_files():
@@ -203,9 +322,9 @@ def test_manifest_is_consistent_with_the_files():
         assert os.path.exists(os.path.join(BENCH, "models", cfg["arch"] + ".py"))
         assert set(c["reduced"]) == set(cfg["reduced"])
         assert cfg["assumed"] and cfg["deployment"]["chips"] in (1, 4)
-        assert (cfg["hidden_size"], cfg["num_key_value_heads"],
-                cfg["intermediate_size"], cfg["vocab_size"],
-                cfg["rope_theta"]) == (4096, 8, 14336, 32768, 1e6)
+        assert not WIDTH.search(" ".join(c["reduced"])), c["reduced"]
+        for key, value in PUBLISHED.get(c["source"], {}).items():
+            assert cfg[key] == value or key in c["reduced"], (c["name"], key)
     e2e = {e["name"]: e for e in m["end_to_end"]}
     assert "setup_s" in e2e and "workloads" not in e2e["setup_s"]
     for group, folder in (("end_to_end", "end_to_end"),
@@ -230,13 +349,17 @@ def test_manifest_is_consistent_with_the_files():
 
 # -- a later PR adds only files and entries --------------------------------
 
-def _rehearse(root, cell, trace_flag, seconds="3"):
+def _run_rehearsal(root, cell, trace_flag, seconds="3"):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
-    p = subprocess.run(
+    return subprocess.run(
         [sys.executable, os.path.join(root, "benchmark", "run.py"),
          "--workload", cell, "--seed", "2147483999", "--seconds", seconds,
          "--trace", str(trace_flag), "--rehearse"],
         env=env, capture_output=True, text=True, timeout=600)
+
+
+def _rehearse(root, cell, trace_flag, seconds="3"):
+    p = _run_rehearsal(root, cell, trace_flag, seconds)
     assert p.returncode == 3, p.stderr[-3000:]   # a rehearsal is not a result
     assert p.stdout.strip() == ""
     return json.loads(p.stderr.strip().splitlines()[-1])
@@ -284,3 +407,79 @@ def test_new_cell_config_and_metric_are_files_and_entries(tmp_path):
     assert "decode_step_ms" not in layer["metrics"]   # no device on the CPU
     old = _rehearse(root, "train-1chip", 0)
     assert old["correct"] and old["metrics"]["train_tokens_per_s_per_chip"]["value"] > 0
+
+
+def _only_additions(src, dst):
+    """Every file of `src` is in `dst`, byte for byte; returns what `dst` adds."""
+    import filecmp
+    added = []
+
+    def walk(cmp, rel):
+        assert not cmp.left_only and not cmp.diff_files and not cmp.funny_files, \
+            (rel, cmp.left_only, cmp.diff_files)
+        added.extend(os.path.join(rel, n) for n in cmp.right_only)
+        for name, sub in cmp.subdirs.items():
+            walk(sub, os.path.join(rel, name))
+
+    walk(filecmp.dircmp(src, dst, ignore=["out", "__pycache__"]), "")
+    return sorted(added)
+
+
+def test_new_architecture_is_files_and_entries(tmp_path):
+    """Copy the benchmark and add an ARCHITECTURE that is not `llama` (adapter,
+    reference, counts, rehearsal widths, configuration, a reader) as new
+    files plus entries. Its train cell rehearses correct against its OWN
+    reference, a reader sees its OWN count, and the same block held to the
+    dense reference is not correct."""
+    root = str(tmp_path)
+    copy = os.path.join(root, "benchmark")
+    shutil.copytree(BENCH, copy,
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    shutil.copytree(FIXTURE, copy, dirs_exist_ok=True)
+    cfg = load(FIXTURE, "configs", "tinymoe-train.json")
+    with open(os.path.join(copy, "configs/tinymoe-llamaref-train.json"),
+              "w") as f:
+        json.dump(dict(cfg, arch="tinymoe_llamaref"), f)
+    added = _only_additions(BENCH, copy)
+    assert added == ["configs/tinymoe-llamaref-train.json",
+                     "configs/tinymoe-train.json", "flops_tinymoe.py",
+                     "layer_metrics/train_flops_per_token.py",
+                     "models/tinymoe.py", "models/tinymoe_llamaref.py",
+                     "reference_tinymoe.py"]
+    m = load(ROOT, "BENCHMARK.json")
+    cells = []
+    for name in ("tinymoe", "tinymoe-llamaref"):
+        m["configs"].append({"name": name, "source": "test fixture",
+                             "file": f"benchmark/configs/{name}-train.json",
+                             "reduced": [], "why": "test"})
+        m["workloads"].append({"name": name + ".pretrain-4k", "config": name,
+                               "traffic": "pretrain-4k", "chips": 1,
+                               "why": "test"})
+        cells.append(name + ".pretrain-4k")
+    for e in m["end_to_end"]:
+        if e["name"] == "train_tokens_per_s_per_chip":
+            e["workloads"] += cells
+    m["per_layer"].append({"name": "train_flops_per_token", "unit": "ops/token",
+                           "better": "lower", "source": "program_counter",
+                           "layer": "device (train)",
+                           "moves": "train_tokens_per_s_per_chip",
+                           "workloads": cells})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(m, f)
+    e2e = _rehearse(root, cells[0], 0)
+    assert e2e["correct"] and e2e["failed"] == 0
+    assert e2e["metrics"]["train_tokens_per_s_per_chip"]["value"] > 0
+    with open(os.path.join(copy, "out", cells[0], "2147483999",
+                           "run-trace0.json")) as f:
+        chk = json.load(f)["check"]
+    assert set(chk["grad_rel_err"]) == {"final_norm", "last_attn_norm",
+                                        "last_mlp_norm", "last_router"}
+    layer = _rehearse(root, cells[0], 1)
+    # the fixture's own count at its rehearsal widths (the hand count of
+    # test_sparse_counts_against_a_hand_count), not the dense block's
+    assert layer["metrics"]["train_flops_per_token"]["value"] == 642_816
+    wrong = _run_rehearsal(root, cells[1], 0)
+    assert wrong.stdout.strip() == ""
+    assert wrong.returncode == 1 or (
+        wrong.returncode == 3 and not json.loads(
+            wrong.stderr.strip().splitlines()[-1])["correct"]), wrong.stderr[-3000:]

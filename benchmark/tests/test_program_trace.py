@@ -19,7 +19,7 @@ NEW_READERS = [
     "engine_prefill_emit_ms", "prefill_padding_pct", "decode_occupancy_pct",
     "decode_layers_carry_ms", "decode_kv_ms", "decode_attn_ms",
     "decode_mlp_ms", "ingest_get_ms", "ingest_device_put_ms",
-    "train_optimizer_ms", "train_head_loss_ms"]
+    "train_optimizer_ms", "train_head_loss_ms", "prefill_ms_per_ktok"]
 
 
 def build(spans=(), modules=(), ops=()) -> pt.ProgramTrace:
@@ -261,6 +261,10 @@ def test_serve_readers_on_a_built_trace(monkeypatch):
         pytest.approx(100.0 * (28 + 212 + 24) / (128 + 512 + 64))
     assert read("decode_occupancy_pct", t, monkeypatch) == \
         pytest.approx(100.0 * 64 / 256)
+    # the two paired prefills: 90 + 70 ms for 100 + 300 prompt tokens; the
+    # third admit's prefill ran after the trace and counts on neither side
+    assert read("prefill_ms_per_ktok", t, monkeypatch) == \
+        pytest.approx(160 / 0.4)
 
 
 RECORDED = os.path.join(HERE, "tiny24.xplane.pb")
@@ -304,6 +308,8 @@ def test_recorded_trace_has_the_programs_spans_and_scopes(recorded):
     ("ingest_device_put_ms", 0.27829),
     ("train_optimizer_ms", 0.015676718),
     ("train_head_loss_ms", 0.005868594 + 0.007951484),
+    # 0.040479 + 0.027980 + 0.032665 ms for 129 + 39 + 69 prompt tokens
+    ("prefill_ms_per_ktok", 0.101123672 / 0.237),
 ])
 def test_every_new_reader_on_the_recorded_trace(recorded, name, value,
                                                 monkeypatch):

@@ -1,8 +1,9 @@
 """The train loop a cell's JaxTrainer workers run: user code of Ray Train, as
-`chip_smoke.py::_train_loop` is. It builds the program's `LlamaConfig` from the
-configuration file, takes `make_train_fns` and the `ray_tpu.data` iterator as
-they are, checks the model against the reference in set-up, warms the step,
-and then steps until the window ends.
+`chip_smoke.py::_train_loop` is. It builds the program's model config from the
+configuration file through the architecture's adapter (benchmark/models/),
+takes `make_train_fns` and the `ray_tpu.data` iterator as they are, checks the
+model against the adapter's reference in set-up, warms the step, and then
+steps until the window ends.
 
 Times are CLOCK_MONOTONIC, which the workers and the parent share on one
 machine. Every rank reports; the controller keeps rank 0's.
@@ -10,11 +11,12 @@ machine. Every rank reports; the controller keeps rank 0's.
 
 from __future__ import annotations
 
-import importlib
 import math
 import os
 import time
 from typing import Any, Dict
+
+from benchmark import models
 
 
 def _agree_to_stop(stop: bool, world: int) -> bool:
@@ -28,47 +30,65 @@ def _agree_to_stop(stop: bool, world: int) -> bool:
     return bool(multihost_utils.broadcast_one_to_all(np.int32(stop)))
 
 
-def _check_against_reference(params, tokens, cfg, pctx, model, positions):
-    """The system's loss and norm gradients (models.llama.loss_fn: kernels,
-    remat, bf16 activations) on the first `positions` of the first batch,
-    against benchmark/reference.py on the same weights."""
+def _leaf(tree, path):
+    for key in path:
+        tree = tree[key]
+    return tree
+
+
+def _with_leaf(tree, path, value):
+    if not path:
+        return value
+    return dict(tree, **{path[0]: _with_leaf(tree[path[0]], path[1:], value)})
+
+
+def _check_against_reference(adapter, params, tokens, cfg, pctx, model,
+                             positions):
+    """The system's loss and the gradients of the adapter's `CHECK_LEAVES`
+    (`adapter.loss_fn`: kernels, remat, bf16 activations) on the first
+    `positions` of the first batch, against the adapter's plain reference on
+    the same weights. A leaf stacked over the layers is compared at the last
+    layer, as `last_<name>`."""
     import jax
-    import jax.numpy as jnp
 
-    from benchmark import reference
-    from ray_tpu.models import llama
+    leaves = adapter.CHECK_LEAVES
+    reference = adapter.reference()
 
-    def norms_of(p):
-        return {"final_norm": p["final_norm"],
-                "attn_norm": p["layers"]["attn_norm"],
-                "mlp_norm": p["layers"]["mlp_norm"]}
-
-    def system(norms, p, toks):
-        q = dict(p, final_norm=norms["final_norm"],
-                 layers=dict(p["layers"], attn_norm=norms["attn_norm"],
-                             mlp_norm=norms["mlp_norm"]))
-        return llama.loss_fn(q, toks[:, :positions], cfg, pctx)[0]
+    def system(picked, p, toks):
+        for name, path in leaves.items():
+            p = _with_leaf(p, path, picked[name])
+        return adapter.loss_fn(p, toks[:, :positions], cfg, pctx)[0]
 
     sys_loss, sys_g = jax.jit(jax.value_and_grad(system))(
-        norms_of(params), params, tokens)
-    ref_loss, ref_g = jax.jit(lambda p, t: reference.loss_and_norm_grads(
+        {name: _leaf(params, path) for name, path in leaves.items()},
+        params, tokens)
+    ref_loss, ref_g = jax.jit(lambda p, t: reference.loss_and_check_grads(
         p, model, t[:, :positions]))(params, tokens)
+
+    return dict(
+        compare(leaves, float(sys_loss), sys_g, float(ref_loss), ref_g),
+        param_dtypes=sorted({str(x.dtype) for x in jax.tree.leaves(params)}))
+
+
+def compare(leaves, loss, grads, ref_loss, ref_grads) -> Dict[str, Any]:
+    """Relative errors of a loss and of the gradients of `leaves` against the
+    reference's; a leaf stacked over the layers at its last layer."""
+    import jax.numpy as jnp
 
     def rel(a, b):
         a, b = a.astype(jnp.float32), b.astype(jnp.float32)
         return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-30))
 
-    return {
-        "loss": float(sys_loss), "ref_loss": float(ref_loss),
-        "loss_rel_err": abs(float(sys_loss) - float(ref_loss))
-        / abs(float(ref_loss)),
-        "grad_rel_err": {
-            "final_norm": rel(sys_g["final_norm"], ref_g["final_norm"]),
-            "last_attn_norm": rel(sys_g["attn_norm"][-1], ref_g["attn_norm"][-1]),
-            "last_mlp_norm": rel(sys_g["mlp_norm"][-1], ref_g["mlp_norm"][-1]),
-        },
-        "param_dtypes": sorted({str(x.dtype) for x in jax.tree.leaves(params)}),
-    }
+    grad_rel_err = {}
+    for name, path in leaves.items():
+        if path[0] == "layers":
+            grad_rel_err["last_" + name] = rel(grads[name][-1],
+                                               ref_grads[name][-1])
+        else:
+            grad_rel_err[name] = rel(grads[name], ref_grads[name])
+    return {"loss": loss, "ref_loss": ref_loss,
+            "loss_rel_err": abs(loss - ref_loss) / abs(ref_loss),
+            "grad_rel_err": grad_rel_err}
 
 
 def loop(config: Dict[str, Any]) -> None:
@@ -93,7 +113,7 @@ def loop(config: Dict[str, Any]) -> None:
                             or jax.device_count() != config["chips"]):
         raise RuntimeError(f"this cell needs {config['chips']} TPU chip(s); "
                            f"jax sees {jax.device_count()} x {dev.platform}")
-    adapter = importlib.import_module(f"benchmark.models.{model['arch']}")
+    adapter = models.adapter(model["arch"])
     cfg = adapter.build_config(model, model["dtypes"], dep["max_seq"])
     pctx = ParallelContext.create(MeshConfig(**dep.get("mesh", {})))
     init, step = make_train_fns(cfg, pctx)
@@ -107,7 +127,8 @@ def loop(config: Dict[str, Any]) -> None:
     first = next(feed)["tokens"]
 
     check = _check_against_reference(
-        state["params"], first, cfg, pctx, model, config["check_positions"])
+        adapter, state["params"], first, cfg, pctx, model,
+        config["check_positions"])
     # Warm the step (its compile is set-up), and the stop broadcast.
     losses = []
     batch = first
