@@ -1,0 +1,110 @@
+"""The two halves of the decoder block that do not depend on how the block
+meets its KV cache, written once: training (`llama._layer_fwd`), the
+engine's prefill and its decode step (`serve/engine.py`) all call them.
+
+  attention_inputs   attn_norm -> q, k, v -> (q/k norm) -> heads -> RoPE
+  feed_forward       mlp_norm -> dense SwiGLU, or router + experts
+
+Between them sits the attention itself, which stays with its caller: flash
+or ring attention over the whole sequence, or a scatter into and a gather out
+of the paged arena.
+
+`x` is `[batch, seq, d_model]` (training, prefill) or `[slots, d_model]` (a
+decode step: one token a slot). Scope names are the one vocabulary a device
+trace is reduced by (benchmark/program_trace.py); the sparse half's own
+(`router`, `moe_dispatch`, `experts`, `moe_combine`) lie inside `mlp`, and
+`qk_norm` inside `qkv`, so the outer names keep their meaning.
+"""
+
+from __future__ import annotations
+
+from typing import Callable, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.moe import moe_ffn
+from ray_tpu.ops.norms import rms_norm
+
+
+def attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
+                     rope: Callable[[jax.Array], jax.Array]
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """-> q `[batch, heads, seq, hd]`, k and v `[batch, kv_heads, seq, hd]`
+    (`[slots, heads, hd]` for a decode step), q and k rotated by `rope`,
+    which is handed a tensor in that layout. With `cfg.qk_norm`, q and k are
+    RMS-normalised over their WHOLE projection, all heads together, before
+    the split into heads (OLMoE)."""
+    lead = x.shape[:-1]
+    H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    dt = cfg.dtype
+
+    def heads(t, n):
+        t = t.reshape(*lead, n, hd)
+        return t.transpose(0, 2, 1, 3) if len(lead) == 2 else t
+
+    with jax.named_scope("attn_norm"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        q = h @ lp["wq"].astype(dt)
+        k = h @ lp["wk"].astype(dt)
+        v = h @ lp["wv"].astype(dt)
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+                k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        q, k, v = heads(q, H), heads(k, KVH), heads(v, KVH)
+    with jax.named_scope("rope"):
+        q = rope(q)
+        k = rope(k)
+    return q, k, v
+
+
+def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
+                 live: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
+    """x + FFN(mlp_norm(x)). A dense block returns (x, None); a sparse one
+    (x, (aux loss, tokens per expert `[n_experts]` int32)), the count over the
+    rows `live` marks (`x`'s shape less its last axis; every row if None).
+    With `layer`, the experts' weights in `lp` are the stacks of all layers
+    and `layer` this block's index (`expert_stacks`, `ops.moe.moe_ffn`)."""
+    dt = cfg.dtype
+    with jax.named_scope("mlp_norm"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if cfg.n_experts > 0:
+            out, aux, counts = moe_ffn(
+                h.reshape(-1, h.shape[-1]), lp["router"].astype(dt),
+                lp["w_up"].astype(dt), lp["w_gate"].astype(dt),
+                lp["w_down"].astype(dt), top_k=cfg.top_k_experts,
+                norm_topk_prob=cfg.norm_topk_prob,
+                live=None if live is None else live.reshape(-1), layer=layer)
+            return x + out.reshape(x.shape), (aux, counts)
+        gate = h @ lp["w_gate"].astype(dt)
+        up = h @ lp["w_up"].astype(dt)
+        return x + (jax.nn.silu(gate) * up) @ lp["w_down"].astype(dt), None
+
+
+def expert_stacks(layers: Dict[str, jax.Array], cfg
+                  ) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
+    """A model's stacked layer parameters, split for a scan over the layers
+    that runs no gradient (serving): (what the scan slices a layer at a time,
+    the experts' stacks its body reads whole and hands `feed_forward` with
+    the layer's index; empty for a dense model). The stacks come back in the
+    compute dtype, cast here, once and outside the scan: nothing where they
+    are stored in it (the `Engine` sees to that when it is built), a copy of
+    all the experts a call where they are not. Training keeps the slice
+    (`llama._layer_fwd`): a gradient through the stack would be written whole
+    once a layer."""
+    names = ("w_gate", "w_up", "w_down") if cfg.n_experts > 0 else ()
+    return ({k: v for k, v in layers.items() if k not in names},
+            {k: layers[k].astype(cfg.dtype) for k in names})
+
+
+def expert_stats(counts: jax.Array) -> jax.Array:
+    """What a serving program hands back of one layer's routing, `[E + 1]`
+    int32 that add up over layers and steps: tokens per expert, then the
+    number of distinct experts touched."""
+    return jnp.concatenate(
+        [counts, jnp.sum(counts > 0, dtype=jnp.int32)[None]])

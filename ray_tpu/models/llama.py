@@ -26,9 +26,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.models.block import attention_inputs, feed_forward
 from ray_tpu.ops.attention import (flash_attention, pallas_eligible,
                                    repeat_kv)
-from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.ops.norms import apply_rope, rms_norm, rope_frequencies
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel.context import ParallelContext
@@ -45,10 +45,15 @@ class LlamaConfig:
     max_seq: int = 2048
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
-    # MoE: 0 experts = dense FFN in every layer.
+    # MoE: 0 experts = dense FFN in every layer; d_ff is then ONE expert's
+    # width. norm_topk_prob: the router's weights renormalised over the
+    # selected experts (Mixtral) or left as the softmax over all (OLMoE).
     n_experts: int = 0
     top_k_experts: int = 2
+    norm_topk_prob: bool = True
     moe_aux_weight: float = 0.01
+    # RMS norm of q and k over the whole projection, before the heads (OLMoE).
+    qk_norm: bool = False
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -98,6 +103,9 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "wo": ("layers", "heads", "embed"),
         "mlp_norm": ("layers", "embed"),
     }
+    if cfg.qk_norm:
+        layers.update({"q_norm": ("layers", "heads"),
+                       "k_norm": ("layers", "kv_heads")})
     if cfg.n_experts > 0:
         layers.update({
             "router": ("layers", "embed", "expert"),
@@ -136,6 +144,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "wo": norm((L, H * hd, D), next(ks)),
         "mlp_norm": jnp.ones((L, D), pd),
     }
+    if cfg.qk_norm:
+        layers.update({"q_norm": jnp.ones((L, H * hd), pd),
+                       "k_norm": jnp.ones((L, KVH * hd), pd)})
     if cfg.n_experts > 0:
         E = cfg.n_experts
         layers.update({
@@ -208,24 +219,15 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def _layer_fwd(lp: Dict[str, jax.Array], x: jax.Array, cos, sin, positions,
                cfg: LlamaConfig, sp_manual: bool,
                ctx: Optional[ParallelContext] = None) -> jax.Array:
-    B, S, D = x.shape
+    B, S, _ = x.shape
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
 
-    # Scope names are the vocabulary serve/engine.py uses too: a device
-    # trace is reduced by them (benchmark/program_trace.py).
-    with jax.named_scope("attn_norm"):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    with jax.named_scope("qkv"):
-        q = jnp.einsum("bsd,dh->bsh", h, lp["wq"].astype(dt))
-        k = jnp.einsum("bsd,dh->bsh", h, lp["wk"].astype(dt))
-        v = jnp.einsum("bsd,dh->bsh", h, lp["wv"].astype(dt))
-        q = q.reshape(B, S, H, hd).transpose(0, 2, 1, 3)
-        k = k.reshape(B, S, KVH, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(B, S, KVH, hd).transpose(0, 2, 1, 3)
-    with jax.named_scope("rope"):
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+    # The block's two cache-free halves are models/block.py's, shared with
+    # serve/engine.py; scope names are the vocabulary a device trace is
+    # reduced by (benchmark/program_trace.py).
+    q, k, v = attention_inputs(
+        lp, x, cfg, lambda t: apply_rope(t, cos, sin, positions))
     with jax.named_scope("attn"):
         k = repeat_kv(k, H // KVH)
         v = repeat_kv(v, H // KVH)
@@ -237,22 +239,8 @@ def _layer_fwd(lp: Dict[str, jax.Array], x: jax.Array, cos, sin, positions,
     with jax.named_scope("attn_out"):
         x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
 
-    with jax.named_scope("mlp_norm"):
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    with jax.named_scope("mlp"):
-        if cfg.n_experts > 0:
-            flat = h.reshape(B * S, D)
-            out, aux = moe_ffn(
-                flat, lp["router"].astype(dt), lp["w_up"].astype(dt),
-                lp["w_gate"].astype(dt), lp["w_down"].astype(dt),
-                top_k=cfg.top_k_experts)
-            x = x + out.reshape(B, S, D)
-        else:
-            gate = jnp.einsum("bsd,df->bsf", h, lp["w_gate"].astype(dt))
-            up = jnp.einsum("bsd,df->bsf", h, lp["w_up"].astype(dt))
-            x = x + jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                               lp["w_down"].astype(dt))
-            aux = jnp.zeros((), jnp.float32)
+    x, sparse = feed_forward(lp, x, cfg)
+    aux = sparse[0] if sparse else jnp.zeros((), jnp.float32)
     return x, aux
 
 

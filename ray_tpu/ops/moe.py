@@ -1,96 +1,111 @@
-"""Mixture-of-experts routing and dispatch for the expert-parallel (``ep``)
-mesh axis.
+"""Mixture-of-experts routing and experts, for training and for serving.
 
 The reference framework only passes expert-parallel sizes through to vLLM
 (SURVEY.md §2.3 — EP row: "Not in Ray"); here MoE is a native layer.
-Dispatch is CAPACITY-BASED gather/scatter (GShard/Switch style): each
-expert processes at most ``capacity = tokens*top_k*capacity_factor/E``
-tokens, so compute is O(tokens * top_k * capacity_factor * d * f) instead
-of the round-1 dense dispatch's O(tokens * n_experts * d * f) — an
-E/(k*cf) FLOPs saving — while every shape stays static for XLA. The
-experts' weight leading axis carries the logical "expert" axis which the
-sharding rules map onto ``ep``; the scatter/gather lowers to the
-expert-parallel all-to-all under GSPMD.
+
+One path, and it computes the published mixture exactly: router logits and
+softmax in float32, top-k, the weights either left as the softmax over ALL
+experts gives them (OLMoE, `norm_topk_prob: false`) or renormalised over the
+selected k (Mixtral), and EVERY assignment computed. There is no capacity and
+so no dropped token: a token's output depends on that token alone, never on
+who shares its batch. Shapes stay static without a capacity because the
+`tokens * k` assignments are sorted by expert and the experts run as grouped
+matmuls over the sorted rows (`jax.lax.ragged_dot`: group e is the
+`group_sizes[e]` rows after those of the experts before it), so compute is
+O(tokens * k * d * f) whatever the skew. The experts' weight leading axis
+carries the logical "expert" axis which the sharding rules map onto `ep`.
 """
 
 from __future__ import annotations
 
-import math
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 
-def top_k_routing(gate_logits: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True
+                  ) -> Tuple[jax.Array, jax.Array]:
     """gate_logits: [tokens, n_experts] -> (weights [tokens, k], idx [tokens, k]).
 
-    Weights are softmaxed over the selected k (Mixtral-style).
+    The weights are the float32 softmax over all experts at the k largest;
+    `norm_topk_prob` renormalises them to sum to one (which equals the
+    softmax over the selected k, Mixtral's; OLMoE publishes False).
     """
-    vals, idx = jax.lax.top_k(gate_logits, k)
-    weights = jax.nn.softmax(vals.astype(jnp.float32), axis=-1)
+    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+    weights, idx = jax.lax.top_k(probs, k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, idx
 
 
 def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
-            w_down: jax.Array, *, top_k: int = 2,
-            capacity_factor: float = 1.25
-            ) -> Tuple[jax.Array, jax.Array]:
-    """SwiGLU MoE feed-forward with capacity-based dispatch.
+            w_down: jax.Array, *, top_k: int = 2, norm_topk_prob: bool = True,
+            live: Optional[jax.Array] = None,
+            layer: Optional[jax.Array] = None
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """SwiGLU MoE feed-forward over sorted assignments.
 
     x: [tokens, d_model]
     gate_w: [d_model, n_experts] router weights
     w_up/w_gate: [n_experts, d_model, d_ff]; w_down: [n_experts, d_ff, d_model]
-    Returns (out [tokens, d_model], aux_loss scalar). Tokens routed to an
-    expert already at capacity are dropped for that expert (standard
-    Switch/GShard overflow semantics; raise capacity_factor to avoid).
+    live: [tokens] bool, the rows that are somebody's token (not a bucket's
+    padding, not an idle slot). Every row is computed either way, each from
+    itself alone; `live` only keeps the others out of the count.
+    layer: with it, w_up/w_gate/w_down are the STACKS of all the model's
+    layers, `[n_layers, n_experts, ...]`, and `layer` (a traced index) says
+    whose experts these tokens meet. The grouped matmul then reads the stack
+    where it lies, the other layers' experts as empty groups; handed one
+    layer sliced out of a scanned stack, its kernel is first given a copy of
+    that layer's experts (0.8 GB a layer at OLMoE's widths, every step).
+    Returns (out [tokens, d_model], aux_loss scalar, tokens per expert
+    [n_experts] int32 over the live rows).
     """
-    tokens, d_model = x.shape
+    tokens, _ = x.shape
     n_experts = gate_w.shape[-1]
-    logits = jnp.einsum("td,de->te", x, gate_w,
-                        preferred_element_type=jnp.float32)
-    weights, idx = top_k_routing(logits, top_k)          # [t,k], [t,k]
-    one_hot = jax.nn.one_hot(idx, n_experts, dtype=jnp.float32)  # [t,k,e]
+    with jax.named_scope("router"):
+        logits = jnp.einsum("td,de->te", x, gate_w,
+                            preferred_element_type=jnp.float32)
+        weights, idx = top_k_routing(logits, top_k, norm_topk_prob)
 
-    capacity = max(1, math.ceil(tokens * top_k * capacity_factor
-                                / n_experts))
+    with jax.named_scope("moe_dispatch"):
+        # Assignments token-major (t, j) -> sorted by expert; the sort is
+        # stable, so an expert's rows keep the order of their tokens.
+        flat_expert = idx.reshape(-1)                       # [t*k]
+        order = jnp.argsort(flat_expert)
+        token_of = order // top_k
+        chosen = flat_expert[:, None] == jnp.arange(n_experts)[None, :]
+        group_sizes = jnp.sum(chosen, axis=0, dtype=jnp.int32)   # [e]
+        xs = x[token_of]                                    # [t*k, d]
+        groups = group_sizes
+        if layer is not None:
+            stacked = w_up.shape[0] * n_experts
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros(stacked, jnp.int32), group_sizes,
+                (layer * n_experts,))
+            w_up, w_gate, w_down = (w.reshape(stacked, *w.shape[2:])
+                                    for w in (w_up, w_gate, w_down))
 
-    # Flatten assignments token-major: slot position of each assignment
-    # within its expert via a running count (no sort needed).
-    flat_expert = idx.reshape(-1)                        # [t*k]
-    flat_weight = weights.reshape(-1)                    # [t*k]
-    flat_token = jnp.repeat(jnp.arange(tokens), top_k)   # [t*k]
-    # int32 cumsum: float32 counting loses exactness past 2^24 assignments
-    # (slot collisions would silently corrupt dispatch at large batches).
-    flat_oh_i = one_hot.reshape(tokens * top_k, n_experts).astype(jnp.int32)
-    pos_in_expert = jnp.cumsum(flat_oh_i, axis=0) - flat_oh_i  # [t*k, e]
-    pos = jnp.sum(pos_in_expert * flat_oh_i, axis=-1).astype(jnp.int32)
-    keep = pos < capacity
-    # Overflow assignments land in a trash slot past the real buffer.
-    slot = jnp.where(keep, flat_expert * capacity + pos,
-                     n_experts * capacity).astype(jnp.int32)
+    with jax.named_scope("experts"):
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, groups)) \
+            * jax.lax.ragged_dot(xs, w_up, groups)
+        ys = jax.lax.ragged_dot(h, w_down, groups)          # [t*k, d]
 
-    # Dispatch: gather tokens into [e*c(+trash), d], compute experts on
-    # static [e, c, d] shapes (leading axis shards over ep), combine back.
-    buf = jnp.zeros((n_experts * capacity + 1, d_model), x.dtype)
-    buf = buf.at[slot].set(x[flat_token])
-    xe = buf[:n_experts * capacity].reshape(n_experts, capacity, d_model)
-    h_up = jnp.einsum("ecd,edf->ecf", xe, w_up)
-    h_gate = jnp.einsum("ecd,edf->ecf", xe, w_gate)
-    h = jax.nn.silu(h_gate) * h_up
-    expert_out = jnp.einsum("ecf,efd->ecd", h, w_down)   # [e, c, d]
+    with jax.named_scope("moe_combine"):
+        # Back to token-major by a gather and one sum over a token's k
+        # rows in a fixed order: no scatter-add, whose order of additions
+        # (and so the last bit of a token's output) would be the batch's.
+        back = jnp.argsort(order)
+        per_token = ys[back].reshape(tokens, top_k, -1).astype(jnp.float32)
+        out = jnp.einsum("tkd,tk->td", per_token, weights)
 
-    flat_out = jnp.concatenate(
-        [expert_out.reshape(n_experts * capacity, d_model),
-         jnp.zeros((1, d_model), expert_out.dtype)])     # trash slot -> 0
-    gathered = flat_out[slot].astype(jnp.float32)        # [t*k, d]
-    contrib = gathered * (flat_weight * keep)[:, None]
-    out = jnp.zeros((tokens, d_model), jnp.float32).at[flat_token].add(
-        contrib)
-
+    if live is None:
+        counts = group_sizes
+    else:
+        counts = jnp.sum(chosen & jnp.repeat(live, top_k)[:, None], axis=0,
+                         dtype=jnp.int32)
     # Load-balancing aux loss (Switch-style): mean prob * mean assignment frac.
     probs = jax.nn.softmax(logits, axis=-1)
-    frac_tokens = jnp.mean(one_hot.sum(axis=1), axis=0)  # [e]
-    frac_prob = jnp.mean(probs, axis=0)
-    aux = n_experts * jnp.sum(frac_tokens * frac_prob)
-    return out.astype(x.dtype), aux
+    frac_tokens = group_sizes.astype(jnp.float32) / tokens
+    aux = n_experts * jnp.sum(frac_tokens * jnp.mean(probs, axis=0))
+    return out.astype(x.dtype), aux, counts

@@ -42,6 +42,7 @@ and the n-step decode chunk over all slots.
 
 from __future__ import annotations
 
+import functools
 import queue
 import threading
 import time
@@ -54,60 +55,63 @@ logger = get_logger("serve.engine")
 
 
 def _make_prefill_core(mcfg):
-    """fn(params, tokens[1, B], length) -> (first_token, ks, vs) where
-    ks/vs are [L, B, KVH, hd] — the shared prefill pass used by the
-    in-engine prefill AND the disaggregated PrefillServer (reference:
+    """fn(params, tokens[1, B], length) -> (first_token, ks, vs, the last
+    position's logits, experts) where ks/vs are [L, B, KVH, hd] and `experts`
+    is None for a dense model, else `models.block.expert_stats` of the
+    prompt's tokens summed over the layers — the shared prefill pass used
+    by the in-engine prefill AND the disaggregated PrefillServer (reference:
     llm/_internal/serve/deployments/prefill_decode_disagg/ — there the
     split is two vLLM pools; here both halves share one traced core)."""
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.models.block import (attention_inputs, expert_stacks,
+                                      expert_stats, feed_forward)
     from ray_tpu.ops.attention import flash_attention, repeat_kv
     from ray_tpu.ops.norms import apply_rope, rms_norm, rope_frequencies
 
     H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
     dt = mcfg.dtype
+    sparse = mcfg.n_experts > 0
 
-    def _prefill_layer(carry, lp):
-        x, cos, sin = carry
+    def _prefill_layer(stacks, carry, layer):
+        x, cos, sin, live = carry
+        lp, l = layer if sparse else (layer, None)
+        lp = dict(lp, **stacks)
         B, Sq, _ = x.shape
-        with jax.named_scope("attn_norm"):
-            h = rms_norm(x, lp["attn_norm"], mcfg.norm_eps)
-        with jax.named_scope("qkv"):
-            q = jnp.einsum("bsd,dh->bsh", h, lp["wq"].astype(dt))
-            k = jnp.einsum("bsd,dh->bsh", h, lp["wk"].astype(dt))
-            v = jnp.einsum("bsd,dh->bsh", h, lp["wv"].astype(dt))
-            q = q.reshape(B, Sq, H, hd).transpose(0, 2, 1, 3)
-            k = k.reshape(B, Sq, KVH, hd).transpose(0, 2, 1, 3)
-            v = v.reshape(B, Sq, KVH, hd).transpose(0, 2, 1, 3)
-        with jax.named_scope("rope"):
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+        q, k, v = attention_inputs(lp, x, mcfg,
+                                   lambda t: apply_rope(t, cos, sin))
         with jax.named_scope("attn"):
             attn = flash_attention(q, repeat_kv(k, H // KVH),
                                    repeat_kv(v, H // KVH), True)
             attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd)
         with jax.named_scope("attn_out"):
             x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
-        with jax.named_scope("mlp_norm"):
-            h = rms_norm(x, lp["mlp_norm"], mcfg.norm_eps)
-        with jax.named_scope("mlp"):
-            gate = jnp.einsum("bsd,df->bsf", h, lp["w_gate"].astype(dt))
-            up = jnp.einsum("bsd,df->bsf", h, lp["w_up"].astype(dt))
-            x = x + jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                               lp["w_down"].astype(dt))
+        x, routed = feed_forward(lp, x, mcfg, live, l)
         # cache pre-repeat k/v: [S, KVH, hd] (B == 1 squeezed)
-        return (x, cos, sin), (k[0].transpose(1, 0, 2),
-                               v[0].transpose(1, 0, 2))
+        ys = (k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2))
+        if sparse:
+            ys += (expert_stats(routed[1]),)
+        return (x, cos, sin, live), ys
 
     def core(params, tokens, length):
         with jax.named_scope("embed"):
             x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
         with jax.named_scope("rope"):
             cos, sin = rope_frequencies(hd, tokens.shape[1], mcfg.rope_theta)
+        # The bucket's padding is computed like any row, each from itself
+        # alone (no capacity for it to take), and left out of the count.
+        live = (jnp.arange(tokens.shape[1])[None] < length) if sparse \
+            else None
+        # The experts' stacks stay whole (`expert_stacks`): the scan slices
+        # the rest, and carries the layer's index for them.
+        sliced, stacks = expert_stacks(params["layers"], mcfg)
+        if sparse:
+            sliced = (sliced, jnp.arange(mcfg.n_layers))
         with jax.named_scope("layers"):
-            (x, _, _), (ks, vs) = jax.lax.scan(
-                _prefill_layer, (x, cos, sin), params["layers"])
+            (x, *_), (ks, vs, *routed) = jax.lax.scan(
+                functools.partial(_prefill_layer, stacks),
+                (x, cos, sin, live), sliced)
         with jax.named_scope("head"):
             x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
             last_h = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
@@ -115,7 +119,8 @@ def _make_prefill_core(mcfg):
             logits = jnp.einsum("bd,dv->bv", last_h,
                                 params["lm_head"].astype(dt))
             first = jnp.argmax(logits[0]).astype(jnp.int32)
-        return first, ks, vs, logits[0].astype(jnp.float32)
+        experts = jnp.sum(routed[0], axis=0) if sparse else None
+        return first, ks, vs, logits[0].astype(jnp.float32), experts
 
     return core
 
@@ -158,11 +163,11 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.models.block import (attention_inputs, expert_stacks,
+                                      expert_stats, feed_forward)
     from ray_tpu.ops.norms import rms_norm, rope_frequencies
 
-    if mcfg.n_experts > 0:
-        raise ValueError("the serving engine supports dense models only")
-
+    sparse = mcfg.n_experts > 0
     S = mcfg.max_seq
     H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
     dt = mcfg.dtype
@@ -200,14 +205,14 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         max_seq — jax.jit compiles one program per bucket shape, so a
         short prompt pays a short prefill, not a max_seq one); writes
         the slot's pages, returns the first generated token (sampled,
-        or greedy when temp == 0)."""
-        _, ks, vs, logits_row = _core(params, tokens, length)
+        or greedy when temp == 0) and the core's `experts`."""
+        _, ks, vs, logits_row, experts = _core(params, tokens, length)
         kc, vc = _write_pages(kc, vc, pages, ks, vs)
         first = _sample_tokens(logits_row[None],
                                jnp.asarray(temp)[None],
                                jnp.asarray(topk)[None], key[None],
                                jnp.asarray(length - 1)[None])[0]
-        return kc, vc, first
+        return kc, vc, first, experts
 
     def adopt(kc, vc, pages, ks, vs):
         """Write externally-prefilled k/v (a PrefillServer handoff) into
@@ -225,19 +230,14 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
 
     def _decode_layer(x, kc, vc, lp, l, bt, pos, act, cos, sin):
         # x [ns, D]; kc/vc the WHOLE arena [L, n_pages, page, KVH, hd];
-        # l this layer's index (traced scalar); bt [ns, maxp]
-        with jax.named_scope("attn_norm"):
-            h = rms_norm(x, lp["attn_norm"], mcfg.norm_eps)
-        with jax.named_scope("qkv"):
-            q = (h @ lp["wq"].astype(dt)).reshape(ns, H, hd)
-            k = (h @ lp["wk"].astype(dt)).reshape(ns, KVH, hd)
-            v = (h @ lp["wv"].astype(dt)).reshape(ns, KVH, hd)
+        # l this layer's index (traced scalar); bt [ns, maxp]; a sparse
+        # model's expert weights in lp are all the layers' (`expert_stacks`)
         with jax.named_scope("rope"):
             w = jnp.minimum(pos, S - 1)
             c = cos[w][:, None]
             s = sin[w][:, None]
-            q = _rope_one(q, c, s)
-            k = _rope_one(k, c, s)
+        q, k, v = attention_inputs(lp, x, mcfg,
+                                   lambda t: _rope_one(t, c, s))
         # Scatter k/v at each slot's (layer, page, offset), straight into
         # the arena: `ns` rows, no layer slab cut out or put back.
         # Inactive slots (and positions past a slot's reservation) route
@@ -268,25 +268,27 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             attn = attn.reshape(ns, H * hd).astype(dt)
         with jax.named_scope("attn_out"):
             x = x + attn @ lp["wo"].astype(dt)
-        with jax.named_scope("mlp_norm"):
-            h = rms_norm(x, lp["mlp_norm"], mcfg.norm_eps)
-        with jax.named_scope("mlp"):
-            gate = h @ lp["w_gate"].astype(dt)
-            up = h @ lp["w_up"].astype(dt)
-            x = x + (jax.nn.silu(gate) * up) @ lp["w_down"].astype(dt)
-        return x, kc, vc
+        # An idle slot's row is computed like any other, from itself alone,
+        # and left out of the count.
+        x, routed = feed_forward(lp, x, mcfg, act, l if sparse else None)
+        return x, kc, vc, routed
 
-    def _step(params, kc, vc, bt, last, pos, active, cos, sin,
-              temp, topk, keys):
+    def _step(params, sliced, stacks, kc, vc, experts, bt, last, pos, active,
+              cos, sin, temp, topk, keys):
+        # sliced, stacks: `expert_stacks` of the layers, split (and where
+        # need be cast) once a chunk, outside the loop over its steps
         act = active & (pos < S)
         with jax.named_scope("embed"):
             x = jnp.take(params["embed"], last, axis=0).astype(dt)
 
         def body(carry, layer):
-            x, kc, vc = carry
+            x, kc, vc, *experts = carry
             lp, l = layer
-            return _decode_layer(x, kc, vc, lp, l, bt, pos, act,
-                                 cos, sin), None
+            x, kc, vc, routed = _decode_layer(
+                x, kc, vc, dict(lp, **stacks), l, bt, pos, act, cos, sin)
+            if sparse:
+                experts = [experts[0] + expert_stats(routed[1])]
+            return (x, kc, vc, *experts), None
 
         # The arena rides this scan's CARRY, and only a scatter and a
         # gather touch it, so the layer loop, the chunk loop around it and
@@ -298,32 +300,39 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         # step's carry (2.9 GB a step at 12 layers x 929 pages; PERF.md,
         # PR 25). The xs are the layer's weights and its index.
         with jax.named_scope("layers"):
-            (x, kc, vc), _ = jax.lax.scan(
-                body, (x, kc, vc),
-                (params["layers"], jnp.arange(mcfg.n_layers)))
+            (x, kc, vc, *experts), _ = jax.lax.scan(
+                body, (x, kc, vc, *experts),
+                (sliced, jnp.arange(mcfg.n_layers)))
         with jax.named_scope("head"):
             x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
             logits = x @ params["lm_head"].astype(dt)      # [ns, V]
         nxt = _sample_tokens(logits, temp, topk, keys, pos)
         nxt = jnp.where(act, nxt, last)
         pos2 = jnp.where(act, pos + 1, pos)
-        return kc, vc, nxt, pos2
+        return kc, vc, experts, nxt, pos2
 
     def decode(params, kc, vc, bt, last, pos, active, temp, topk, keys):
+        """-> (kc, vc, last, pos, tokens [ns, chunk], experts): `experts` is
+        None for a dense model, else `expert_stats` of the live slots'
+        tokens summed over the chunk's steps and the layers."""
         with jax.named_scope("rope"):
             cos, sin = rope_frequencies(hd, S, mcfg.rope_theta)
         out0 = jnp.zeros((ns, chunk), jnp.int32)
+        experts0 = [jnp.zeros(mcfg.n_experts + 1, jnp.int32)] if sparse \
+            else []
+        sliced, stacks = expert_stacks(params["layers"], mcfg)
 
         def body(i, carry):
-            kc, vc, last, pos, out = carry
-            kc, vc, nxt, pos = _step(params, kc, vc, bt, last, pos,
-                                     active, cos, sin, temp, topk, keys)
+            kc, vc, last, pos, out, *experts = carry
+            kc, vc, experts, nxt, pos = _step(
+                params, sliced, stacks, kc, vc, experts, bt, last, pos,
+                active, cos, sin, temp, topk, keys)
             out = out.at[:, i].set(nxt)
-            return kc, vc, nxt, pos, out
+            return (kc, vc, nxt, pos, out, *experts)
 
-        kc, vc, last, pos, out = jax.lax.fori_loop(
-            0, chunk, body, (kc, vc, last, pos, out0))
-        return kc, vc, last, pos, out
+        kc, vc, last, pos, out, *experts = jax.lax.fori_loop(
+            0, chunk, body, (kc, vc, last, pos, out0, *experts0))
+        return kc, vc, last, pos, out, (experts[0] if sparse else None)
 
     def poke(last, pos, slot, first, length):
         """Admission bookkeeping ON DEVICE: set one slot's (last, pos).
@@ -398,7 +407,7 @@ class Engine:
         self.mcfg = mcfg
         self.n_slots = n_slots
         self.chunk = decode_chunk
-        self.params = params
+        self.params = self._experts_in_compute_dtype(params, mcfg)
         S = mcfg.max_seq
         self.page = min(page_size, S)
         self.maxp = -(-S // self.page)
@@ -449,6 +458,14 @@ class Engine:
         self.prefill_padded_tokens = 0     # bucket width less the prompt
         self.decode_chunks = 0
         self.decode_useful_tokens = 0
+        # A sparse model's routing, as the programs count it on the device
+        # (`models.block.expert_stats`) and the emitter thread adds it up:
+        # tokens per expert over prefills and decode steps, and the distinct
+        # experts touched, summed over a chunk's steps and the layers.
+        self._sparse = mcfg.n_experts > 0
+        self.expert_tokens = np.zeros(mcfg.n_experts, np.int64)
+        self.decode_experts_touched = 0
+        self._touched_last_chunk = 0
         self._next_rid = 0
         self._pending: deque = deque()
         self._plock = threading.Lock()
@@ -473,7 +490,7 @@ class Engine:
                 self._kc, self._vc, width)
         with tracing.compile_span("serve.engine.warm", program="decode",
                                   width=n_slots):
-            self._kc, self._vc, self._last_d, self._pos_d, out = \
+            self._kc, self._vc, self._last_d, self._pos_d, out, _ = \
                 self._decode(
                     self.params, self._kc, self._vc, jnp.asarray(self._bt),
                     self._last_d, self._pos_d, jnp.zeros(n_slots, bool),
@@ -518,7 +535,7 @@ class Engine:
         null_pages = jnp.zeros(self.maxp, jnp.int32)
         with tracing.compile_span("serve.engine.warm", program="prefill",
                                   width=width):
-            kc, vc, first = self._prefill(
+            kc, vc, first, _ = self._prefill(
                 self.params, kc, vc, null_pages,
                 jnp.zeros((1, width), jnp.int32), 1, 0.0, 0,
                 jnp.zeros(2, jnp.uint32))
@@ -574,6 +591,27 @@ class Engine:
             jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
 
     # ------------------------------------------------------------------
+    @staticmethod
+    def _experts_in_compute_dtype(params, mcfg):
+        """A sparse model's expert stacks are read whole by every layer of
+        every program (`models.block.expert_stacks`), so they are held in the
+        compute dtype: stored otherwise, they are cast here, once, and the
+        log says so (the engine then holds that copy of the experts; the
+        caller may drop its own)."""
+        names = [k for k in ("w_gate", "w_up", "w_down")
+                 if mcfg.n_experts > 0
+                 and params["layers"][k].dtype != mcfg.dtype]
+        if not names:
+            return params
+        logger.warning(
+            "expert weights are stored as %s and computed in %s: the engine "
+            "casts its own copy once", params["layers"][names[0]].dtype,
+            mcfg.dtype)
+        layers = dict(params["layers"])
+        for k in names:
+            layers[k] = layers[k].astype(mcfg.dtype)
+        return dict(params, layers=layers)
+
     def submit(self, ids: List[int], max_tokens: int, *,
                temperature: float = 0.0, top_k: int = 0,
                seed: int = 0) -> "queue.Queue":
@@ -625,11 +663,20 @@ class Engine:
         what `serve.engine.admit` and `serve.engine.decode_dispatch` spans
         say one at a time. Occupancy is `decode_useful_tokens` over
         `decode_chunks * n_slots * chunk`; padding is
-        `prefill_padded_tokens` over it plus `prefill_tokens`."""
-        return {k: getattr(self, k) for k in (
+        `prefill_padded_tokens` over it plus `prefill_tokens`. A sparse
+        model adds `expert_tokens` (assignments per expert, all layers,
+        prefills and decode steps, as far as the emitter has fetched them)
+        and `decode_experts_touched` (distinct experts, summed over decode
+        steps and layers: over `decode_chunks * chunk * n_layers` it is the
+        experts whose weights a layer reads in a step)."""
+        out = {k: getattr(self, k) for k in (
             "admitted", "queue_wait_s_sum", "prefill_tokens",
             "prefill_padded_tokens", "decode_chunks",
             "decode_useful_tokens", "peak_pages_used", "n_slots", "chunk")}
+        if self._sparse:
+            out["expert_tokens"] = [int(n) for n in self.expert_tokens]
+            out["decode_experts_touched"] = self.decode_experts_touched
+        return out
 
     def stop(self) -> None:
         self._stop = True
@@ -661,7 +708,7 @@ class Engine:
         first-token transfers started) before anything blocks, so N
         admissions cost ~one round-trip, not N."""
         S = self.mcfg.max_seq
-        emits: List[Tuple[_Request, Any, bool]] = []  # (req, first, done)
+        emits: List[Tuple] = []  # (req, first, done, experts)
         while True:
             with self._plock:
                 req = self._pending[0] if self._pending else None
@@ -699,21 +746,21 @@ class Engine:
         # Start EVERY device->host copy first (async), THEN enqueue: a
         # burst overlaps all its transfers even when the bounded
         # _emit_q.put blocks partway through the enqueue loop.
-        for _, first, _ in emits:
+        for _, first, _, _ in emits:
             try:
                 first.copy_to_host_async()
             except AttributeError:
                 pass  # host int (adopt path)
-        for req, first, done in emits:
+        for item in emits:
             # The emitter thread performs the int(first) sync — the
             # dispatch loop never blocks on the device.
-            self._emit_q.put(("first", req, first, done))
+            self._emit_q.put(("first",) + item)
 
     def _place(self, req: _Request, slot: int, need: int,
-               bucket: int) -> Tuple[_Request, Any, bool]:
+               bucket: int) -> Tuple[_Request, Any, bool, Any]:
         """Grant `need` pages and the slot, dispatch the prefill (or the
         adopt) at width `bucket` and the poke. Returns the emitter's item:
-        (req, first token, finished already)."""
+        (req, first token, finished already, the prefill's `experts`)."""
         np, jnp = self._np, self._jnp
         S = self.mcfg.max_seq
         pages = [self._free.pop() for _ in range(need)]
@@ -744,11 +791,11 @@ class Engine:
                 ks, vs = jnp.asarray(pk), jnp.asarray(pv)
             self._kc, self._vc = self._adopt(
                 self._kc, self._vc, pages_arr, ks, vs)
-            first = req.first
+            first, experts = req.first, None
         else:
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :len(req.ids)] = req.ids
-            self._kc, self._vc, first = self._prefill(
+            self._kc, self._vc, first, experts = self._prefill(
                 self.params, self._kc, self._vc, pages_arr,
                 jnp.asarray(toks), len(req.ids),
                 float(req.temperature), int(req.top_k),
@@ -771,7 +818,7 @@ class Engine:
                     or self._pos[slot] >= S)
         if done:
             self._finish_state(slot)
-        return req, first, done
+        return req, first, done, experts
 
     def _finish_state(self, slot: int) -> None:
         """Free the slot + pages (host control state only — the stream's
@@ -820,7 +867,7 @@ class Engine:
                 return
             try:
                 if item[0] == "first":
-                    _, req, first, done = item
+                    _, req, first, done, experts = item
                     # Ends at the engine's first-token instant.
                     with tracing.span(
                             "serve.engine.emit", ctx=req.ctx, rid=req.rid,
@@ -829,8 +876,16 @@ class Engine:
                             req.out.put([int(first)])
                         if done:
                             req.out.put(None)
-                else:  # ("chunk", out_d, plan)
-                    _, out_d, plan = item
+                    if experts is not None:
+                        # After the token is out. A span of no length: the
+                        # profiler fixes a span's arguments when it opens.
+                        touched = self._count_experts(experts)
+                        with tracing.span("serve.engine.prefill_experts",
+                                          ctx=req.ctx, rid=req.rid,
+                                          touched=touched):
+                            pass
+                else:  # ("chunk", out_d, plan, experts)
+                    _, out_d, plan, experts = item
                     with tracing.span("serve.engine.emit", kind="chunk"):
                         out_h = np.asarray(out_d)
                         for slot, req, take, fin in plan:
@@ -839,6 +894,9 @@ class Engine:
                                 req.out.put(toks)
                             if fin:
                                 req.out.put(None)
+                    if experts is not None:
+                        self._touched_last_chunk = self._count_experts(experts)
+                        self.decode_experts_touched += self._touched_last_chunk
             except BaseException:
                 import traceback
                 self.error = self.error or traceback.format_exc()
@@ -849,6 +907,13 @@ class Engine:
                 else:
                     for _, req, _, _ in item[2]:
                         req.out.put(None)
+
+    def _count_experts(self, experts) -> int:
+        """Emitter thread: add one program's `expert_stats` to the running
+        `expert_tokens`; returns its distinct experts touched."""
+        stats = self._np.asarray(experts)
+        self.expert_tokens = self.expert_tokens + stats[:-1]
+        return int(stats[-1])
 
     def _run_inner(self) -> None:
         np, jnp = self._np, self._jnp
@@ -891,10 +956,18 @@ class Engine:
             useful = sum(take for _, _, take, _ in plan)
             self.decode_chunks += 1
             self.decode_useful_tokens += useful
+            # What the emitter has fetched so far: the chunk before's distinct
+            # experts (over its steps and the layers) and the running tokens
+            # per expert, `:`-joined (the profiler splits arguments at `,`);
+            # put together only where a span is recorded.
+            routed = {"experts_touched": self._touched_last_chunk,
+                      "expert_tokens": ":".join(map(str, self.expert_tokens))
+                      } if self._sparse and tracing.recording() else {}
             with tracing.span("serve.engine.decode_dispatch", useful=useful,
                               capacity=self.n_slots * self.chunk,
-                              active=len(plan)):
-                self._kc, self._vc, self._last_d, self._pos_d, out_d = \
+                              active=len(plan), **routed):
+                (self._kc, self._vc, self._last_d, self._pos_d, out_d,
+                 experts_d) = \
                     self._decode(self.params, self._kc, self._vc,
                                  jnp.asarray(self._bt.copy()), self._last_d,
                                  self._pos_d,
@@ -915,4 +988,4 @@ class Engine:
             # Blocks when the emitter is `maxsize` chunks behind — the
             # pipeline-depth bound, which this span shows from the host.
             with tracing.span("serve.engine.emit_block"):
-                self._emit_q.put(("chunk", out_d, plan))
+                self._emit_q.put(("chunk", out_d, plan, experts_d))

@@ -306,7 +306,7 @@ class PrefillServer:
                      if b >= len(ids) and b in self._warm)
         toks = np.zeros((1, width), np.int32)
         toks[0, :len(ids)] = ids
-        first, ks, vs, logits_row = self._core(
+        first, ks, vs, logits_row, _ = self._core(
             self.params, jnp.asarray(toks), len(ids))
         temp = float(body.get("temperature", 0.0))
         if temp > 0:

@@ -62,6 +62,17 @@ def root(trace_id: bytes) -> Iterator[None]:
         cw._trace_local.ctx = before
 
 
+def recording() -> bool:
+    """Whether a span opened now has a sink: a profiler session is open, or
+    this process's graftscope assembler is on. For a caller whose span
+    arguments cost something to put together."""
+    jax = sys.modules.get("jax")
+    if jax is not None and jax.profiler.TraceAnnotation.is_enabled():
+        return True
+    worker = _worker()
+    return worker is not None and worker._scope_asm() is not None
+
+
 @contextlib.contextmanager
 def span(name: str, ctx: Optional[Context] = None, profiler: bool = True,
          **args: Any) -> Iterator[Dict[str, Any]]:

@@ -1,0 +1,314 @@
+"""The benchmark's adapter contract (benchmark/models/<arch>.py), seen by
+tier-1: the cluster-free cases of benchmark/tests/test_benchmark.py, imported
+by name and run for the adapters `llama` and `olmoe`, and what `olmoe` adds:
+its refusals, its counts against a hand count, its readers on a synthetic
+trace and on the engine's own spans. No cluster, no port, no clock.
+"""
+
+import dataclasses
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import models, program_trace  # noqa: E402
+from benchmark.tests import test_benchmark as cases  # noqa: E402
+
+ARCHS = ["llama", "olmoe"]
+# config.json of allenai/OLMoE-1B-7B-0125-Instruct, as the catalog beside the
+# model-configs guide has it.
+OLMOE_PUBLISHED = dict(
+    attention_bias=False, clip_qkv=None, hidden_act="silu", hidden_size=2048,
+    intermediate_size=1024, max_position_embeddings=4096, model_type="olmoe",
+    norm_topk_prob=False, num_attention_heads=16, num_experts=64,
+    num_experts_per_tok=8, num_hidden_layers=16, num_key_value_heads=16,
+    rms_norm_eps=1e-05, rope_scaling=None, rope_theta=10000,
+    tie_word_embeddings=False, vocab_size=50304)
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_adapter_exposes_the_whole_contract(arch):
+    cases.test_adapter_exposes_the_whole_contract(arch, None)
+
+
+def test_llama_reference_agrees_with_the_program_at_rehearsal_widths():
+    cases.test_reference_agrees_with_the_program_at_tiny_widths("llama", None)
+
+
+def test_olmoe_reference_agrees_with_the_program_at_rehearsal_widths():
+    """As the case above, through the contract alone: the serve check's gaps
+    and the train check's comparison, program against reference on the same
+    float32 weights."""
+    import jax.numpy as jnp
+
+    from benchmark.train_loop import _check_against_reference
+    from ray_tpu.models import llama
+
+    adapter, model, cfg, params = cases._tiny("olmoe")
+    assert (cfg.n_experts, cfg.top_k_experts, cfg.d_ff, cfg.norm_topk_prob,
+            cfg.qk_norm, cfg.moe_aux_weight) == (8, 2, 32, False, True, 0.0)
+    toks = np.random.default_rng(0).integers(0, 256, (2, 96), dtype=np.int32)
+    want = np.asarray(llama.forward(params, jnp.asarray(toks[:1]), cfg))[0]
+    ref = adapter.reference()
+    prompt, tail = [int(t) for t in toks[0, :80]], [int(t) for t in toks[0, 80:]]
+    gaps = ref.served_token_gaps(params, model, prompt, tail + [5])
+    rows, served = want[79:], np.asarray(tail + [5])
+    assert np.allclose(gaps, rows.max(-1) - rows[np.arange(17), served],
+                       atol=2e-4)
+    chk = _check_against_reference(adapter, params, jnp.asarray(toks), cfg,
+                                   None, model, 64)
+    assert chk["loss_rel_err"] < 1e-5 and chk["param_dtypes"] == ["float32"]
+    assert set(chk["grad_rel_err"]) == {
+        "final_norm", "last_attn_norm", "last_mlp_norm", "last_router",
+        "last_q_norm", "last_k_norm"}
+    assert max(chk["grad_rel_err"].values()) < 1e-4, chk
+
+
+def test_dense_counts_are_reached_through_the_adapter():
+    cases.test_dense_counts_are_reached_through_the_adapter()
+
+
+def test_olmoe_counts_against_a_hand_count():
+    """At the published widths; `intermediate_size` is ONE expert's width."""
+    counts = models.adapter("olmoe").counts
+    m = dict(OLMOE_PUBLISHED)
+    attn = 4 * 2048 * 2048                      # q, k, v, o: MHA, 16 x 128
+    expert = 3 * 2048 * 1024
+    assert (attn, expert) == (16_777_216, 6_291_456)
+    active = attn + 2048 * 64 + 8 * expert      # + the router + 8 experts
+    assert active == 67_239_936
+    layer = attn + 2048 * 64 + 64 * expert + 2 * 2048 + 2 * 2048
+    assert layer == 419_569_664                 # ISSUE 27: "419.6 M a layer"
+    head = 2048 * 50304
+    assert counts.total_params(m) == 16 * layer + 2 * head + 2048 \
+        == 6_919_161_856                        # the 7 B of the model's name
+    causal = 4.0 * 16 * 128 * (4096 * 4097 / 2)
+    assert counts.prefill_flops(m, 4096) == \
+        2.0 * 16 * active * 4096 + 16 * causal + 2.0 * head
+    assert counts.train_flops_per_token(m, 4096) == \
+        3 * (2.0 * (16 * active + head) + 16 * causal / 4096)
+    # one layer's grouped matmuls: 8 assignments a token, 55 experts touched
+    ops, byts = counts.experts_ops_bytes(m, 16 * 8, 55, 2, 2)
+    assert ops == 2.0 * expert * 128 == 1_610_612_736
+    assert byts == 55 * expert * 2 + 2 * 128 * 2048 * 2
+    # a decode step: the touched experts' weights, never all 64 by assumption
+    ops, byts = counts.decode_step_ops_bytes(m, [100, 28], 2, 2,
+                                             experts_touched=12.5)
+    assert ops == 2 * 2.0 * (16 * active + head) + 16 * 4.0 * 16 * 128 * 128
+    assert byts == 2 * (16 * (attn + 2048 * 64 + 4 * 2048 + 12.5 * expert)
+                        + head + 2048) + 16 * (2 * 16 * 128 * 2) * 128
+    with pytest.raises(TypeError):
+        counts.decode_step_ops_bytes(m, [100], 2, 2)
+
+
+def test_manifest_is_consistent_with_the_files():
+    cases.test_manifest_is_consistent_with_the_files()
+    manifest = cases.load(ROOT, "BENCHMARK.json")
+    entry = next(c for c in manifest["configs"]
+                 if c["name"] == "olmoe-1b-7b-serve")
+    cfg = cases.load(ROOT, entry["file"])
+    assert entry["reduced"] == ["num_hidden_layers"] == list(cfg["reduced"])
+    differs = {k for k, v in OLMOE_PUBLISHED.items() if cfg.get(k, "-") != v}
+    assert differs == {"num_hidden_layers"}
+    assert cfg["reduced"]["num_hidden_layers"]["published"] == 16
+    assert cfg["reduced"]["num_hidden_layers"]["run"] == \
+        cfg["num_hidden_layers"]
+    assert entry["source"] == cfg["source_url"]
+    eng = cfg["deployment"]["engine"]
+    assert eng["kv_pages"] == 1 + eng["n_slots"] * eng["max_seq"] // eng["page_size"]
+    cell = next(w for w in manifest["workloads"]
+                if w["name"] == "serve-batch-olmoe")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("olmoe-1b-7b-serve", "batch-summarize-olmoe", 1)
+    # its mix is `serve-batch`'s number for number: the driver (which asks
+    # the adapter before a cluster starts), the words and the check differ.
+    # The check keeps a prompt for each bucket of the mix, reads 1,024 served
+    # tokens where 96 let the int8 control pass, and its limits are tighter.
+    mine = cases.load(cases.BENCH, "traffic", "batch-summarize-olmoe.json")
+    theirs = cases.load(cases.BENCH, "traffic", "batch-summarize.json")
+    differ = {k for k in mine if mine[k] != theirs.get(k)}
+    assert differ == {"kind", "what", "check"}
+    chk, base = mine["check"], theirs["check"]
+    assert set(chk) == set(base)
+    assert chk["prompt_lengths"][:3] == base["prompt_lengths"]
+    assert len(chk["prompt_lengths"]) * chk["tokens"] == 1024
+    assert chk["logit_tolerance"] < base["logit_tolerance"]
+    assert chk["mean_logit_tolerance"] < base["mean_logit_tolerance"]
+    assert mine["kind"] == "serve_closed_checked"
+
+
+@pytest.mark.parametrize("key,value", [
+    ("shared_expert_intermediate_size", 1024), ("attention_bias", True),
+    ("clip_qkv", 8.0), ("tie_word_embeddings", True)],
+    ids=["shared-expert", "bias", "clip_qkv", "tied-embeddings"])
+def test_olmoe_refuses_what_its_block_does_not_compute(key, value):
+    adapter = models.adapter("olmoe")
+    adapter.check_supported(OLMOE_PUBLISHED)
+    with pytest.raises(ValueError, match="cannot run this model"):
+        adapter.check_supported(dict(OLMOE_PUBLISHED, **{key: value}))
+
+
+def test_olmoe_build_config_names_the_field_an_older_program_lacks(monkeypatch):
+    """The parent of PR 27 has no `norm_topk_prob` and no `qk_norm`: the
+    adapter must say so, not run another block under OLMoE's name."""
+    from ray_tpu.models import llama
+
+    @dataclasses.dataclass(frozen=True)
+    class Older:
+        n_experts: int = 0
+        top_k_experts: int = 2
+        moe_aux_weight: float = 0.01
+
+    monkeypatch.setattr(llama, "LlamaConfig", Older)
+    with pytest.raises(ValueError, match=r"norm_topk_prob.*qk_norm"):
+        models.adapter("olmoe").build_config(
+            dict(OLMOE_PUBLISHED), {"params": "bfloat16",
+                                    "activations": "bfloat16"}, 4096)
+
+
+# -- the readers of the four `moe` metrics -----------------------------------
+
+def _reader(name):
+    from benchmark.run import HERE, load_reader
+    return load_reader(HERE, "layer_metrics", name)
+
+
+def _synthetic_trace():
+    """One decode chunk of 2 steps x 1 layer and one prefill, in nanoseconds,
+    between edge programs that `whole_modules` drops; `while` bodies enclose
+    their instructions as on the chip."""
+    P = "jit(prefill)/layers/while/body/"
+    D = "jit(decode)/while/body/layers/while/body/"
+    ops = [("jit(prefill)/layers/while", 1000, 2000),
+           (P + "qkv/dot_general:", 1000, 1100),
+           (P + "qkv/qk_norm/mul:", 1100, 1150),
+           (P + "mlp/router/dot_general:", 1150, 1200),
+           (P + "mlp/moe_dispatch/sort:", 1200, 1300),
+           ("", 1300, 1700),              # XLA's ragged-dot kernel: no scope
+           (P + "mlp/experts/mul:", 1700, 1800),
+           (P + "mlp/moe_combine/gather:", 1800, 1900),
+           ("jit(decode)/while", 3000, 4000),
+           (D + "attn/dot_general:", 3000, 3300),
+           (D + "mlp/experts/ragged_dot:", 3300, 3700),
+           (D + "mlp/moe_combine/gather:", 3700, 3800),
+           (D + "mlp_norm/mul:", 3800, 3900)]
+    modules = [("jit_poke", 0, 10), ("jit_prefill", 1000, 2000),
+               ("jit_decode", 3000, 4000), ("jit_poke", 5000, 5010)]
+    Span = program_trace.Span
+    spans = [
+        Span("serve.engine.admit", 900, 950, dict(
+            rid=7, kind="prefill", prompt_tokens=2000, bucket=2048,
+            queue_wait_us=1)),
+        Span("serve.engine.emit", 2010, 2020, dict(rid=7, kind="first")),
+        Span("serve.engine.prefill_experts", 2020, 2020, dict(rid=7,
+                                                              touched=64)),
+        Span("serve.engine.decode_dispatch", 2900, 2950, dict(
+            useful=16, capacity=32, active=16, experts_touched=0,
+            expert_tokens="0:0:0:0")),
+        Span("serve.engine.decode_dispatch", 4100, 4150, dict(
+            useful=16, capacity=32, active=16, experts_touched=100,
+            expert_tokens="10:40:20:10")),
+    ]
+    return program_trace.ProgramTrace(spans, modules, ops)
+
+
+def _device_view(names):
+    """`trace.py`'s view of chip 0: events by their HLO text."""
+    from benchmark import trace
+    return trace.Trace([trace.Chip("chip0", names, [], [])], [], 0.0, 1.0)
+
+
+def test_moe_readers_on_a_synthetic_trace(monkeypatch):
+    from benchmark import moe_trace, peaks
+
+    t = _synthetic_trace()
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    kernel = _device_view([
+        ("%ragged-dot-none.2 = bf16[32768,1024]{1,0} custom-call(%a, %b), "
+         'custom_call_target="tpu_custom_call"', 1300.9, 1700.2),
+        ("%fusion.1 = bf16[4096,2048]{1,0} fusion(%c)", 1700.0, 1800.0)])
+    per = moe_trace.by_scope({"trace_data": kernel}, t,
+                             [m for m in t.modules if m[0] == "jit_prefill"])
+    assert per[0]["experts"] == 500 and "" not in per[0]
+    assert moe_trace.self_ns(t, t.modules[1:2])[0][""] == 400
+    # program_trace's own vocabulary still charges the sparse scopes to `mlp`
+    assert t.scope_ms("jit_decode")["mlp"] == pytest.approx(500 / 1e6)
+    per = moe_trace.self_ns(t, t.whole_modules("jit_decode"))
+    assert per == [{"attn": 300, "experts": 400, "moe_combine": 100,
+                    "mlp_norm": 100, "": 100}]
+    m = dict(OLMOE_PUBLISHED, arch="olmoe", num_hidden_layers=1,
+             dtypes={"params": "bfloat16", "activations": "bfloat16"},
+             deployment={"engine": {"decode_chunk": 2}})
+    run = {"config": m, "cell": "x", "seed": 0, "trace_data": kernel,
+           "device": {"kind": "TPU v5 lite"}}
+    assert _reader("decode_moe_ms")(run) == pytest.approx(500 / 1e6 / 2)
+    assert _reader("prefill_moe_ms_per_ktok")(run) == \
+        pytest.approx(750 / 1e6 / 2.0)
+    assert _reader("expert_load_max_over_mean")(run) == pytest.approx(2.0)
+    counts = models.adapter("olmoe").counts
+    f, b = peaks.peak("TPU v5 lite", "bf16_flops_per_s"), \
+        peaks.peak("TPU v5 lite", "hbm_bytes_per_s")
+
+    def least(rows, touched):
+        ops, byts = counts.experts_ops_bytes(m, rows * 8, touched, 2, 2)
+        return max(ops / f, byts / b)
+
+    want = (least(2000, 64) + 2 * least(16, 50.0)) / ((500 + 400) / 1e9)
+    assert _reader("moe_experts_roofline_pct")(run) == \
+        pytest.approx(100 * want)
+    # a dense program's trace: no such scopes, kernels or counters, no metric
+    sparse_only = re.compile("/(experts|moe_combine|moe_dispatch|router)")
+    dense = program_trace.ProgramTrace(
+        [program_trace.Span(sp.name, sp.start, sp.end, {
+            k: v for k, v in sp.args.items()
+            if k not in ("experts_touched", "expert_tokens")})
+         for sp in t.spans if sp.name != "serve.engine.prefill_experts"],
+        t.modules, [(sparse_only.sub("", p), s, e) for p, s, e in t.ops])
+    monkeypatch.setattr(program_trace, "load", lambda run: dense)
+    for name in ("decode_moe_ms", "prefill_moe_ms_per_ktok",
+                 "expert_load_max_over_mean", "moe_experts_roofline_pct"):
+        assert _reader(name)(dict(run, trace_data=_device_view([]))) is None
+
+
+def test_engine_spans_carry_what_the_readers_read(tmp_path, monkeypatch):
+    """The engine's own spans through the profiler and back: `:`-joined
+    running tokens per expert and the chunk before's distinct experts on
+    `serve.engine.decode_dispatch`, `touched` by `rid` on
+    `serve.engine.prefill_experts`."""
+    import jax
+
+    from ray_tpu.serve.engine import Engine
+
+    adapter, model, cfg, params = cases._tiny("olmoe")
+    eng = Engine(params, cfg, n_slots=4, decode_chunk=4, page_size=16)
+    try:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        outs = [eng.submit(list(range(3, 3 + n)), 24) for n in (20, 33)]
+        for q in outs:
+            while q.get(timeout=120) is not None:
+                pass
+        jax.profiler.stop_trace()
+        routed = eng.counters()
+    finally:
+        eng.stop()
+    t = program_trace.load_path(str(tmp_path))
+    chunks = t.named("serve.engine.decode_dispatch")
+    assert len(chunks) >= 6
+    last = [int(n) for n in str(chunks[-1].args["expert_tokens"]).split(":")]
+    assert len(last) == 8 and 0 < sum(last) <= sum(routed["expert_tokens"])
+    # 2 layers x 4 steps x at most 2 slots x 2 experts a token
+    assert 2 * 4 * 2 <= chunks[-1].args["experts_touched"] <= 2 * 4 * 2 * 2
+    pre = t.named("serve.engine.prefill_experts")
+    assert sorted(s.args["rid"] for s in pre) == [0, 1]
+    assert all(2 * 2 <= s.args["touched"] <= 2 * 8 for s in pre)
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    assert 1.0 <= _reader("expert_load_max_over_mean")({}) <= 8.0
+    assert routed["decode_experts_touched"] >= \
+        sum(s.args["experts_touched"] for s in chunks)

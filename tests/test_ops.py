@@ -109,12 +109,27 @@ class TestNorms:
 
 
 class TestMoE:
-    def test_top_k_routing(self):
+    @pytest.mark.parametrize("norm", [True, False],
+                             ids=["renormalised", "softmax-over-all"])
+    def test_top_k_routing(self, norm):
+        """The k largest of the float32 softmax over ALL experts; either left
+        as they are (OLMoE) or renormalised to sum to one, which is the
+        softmax over the selected logits (Mixtral)."""
         logits = jnp.array([[1.0, 3.0, 2.0], [0.0, -1.0, 5.0]])
-        w, idx = top_k_routing(logits, 2)
+        w, idx = top_k_routing(logits, 2, norm_topk_prob=norm)
         assert idx.shape == (2, 2)
         assert int(idx[0, 0]) == 1 and int(idx[1, 0]) == 2
-        np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, atol=1e-6)
+        probs = np.asarray(jax.nn.softmax(logits, axis=-1))
+        picked = np.take_along_axis(probs, np.asarray(idx), axis=-1)
+        if norm:
+            np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, atol=1e-6)
+            np.testing.assert_allclose(
+                np.asarray(w), np.asarray(jax.nn.softmax(
+                    jnp.take_along_axis(logits, idx, axis=-1), axis=-1)),
+                atol=1e-6)
+        else:
+            np.testing.assert_allclose(np.asarray(w), picked, atol=1e-7)
+            assert float(w.sum(-1).max()) < 1.0
 
     def test_moe_matches_dense_when_one_expert(self):
         key = jax.random.PRNGKey(0)
@@ -125,15 +140,16 @@ class TestMoE:
         w_up = jax.random.normal(ks[1], (1, d, f))
         w_gate = jax.random.normal(ks[2], (1, d, f))
         w_down = jax.random.normal(ks[3], (1, f, d))
-        out, aux = moe_ffn(x, gate_w, w_up, w_gate, w_down, top_k=1,
-                           capacity_factor=2.0)
+        out, aux, counts = moe_ffn(x, gate_w, w_up, w_gate, w_down, top_k=1)
         dense = jax.nn.silu(x @ w_gate[0]) * (x @ w_up[0]) @ w_down[0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                    atol=1e-5)
+        assert list(np.asarray(counts)) == [t]
 
-    def test_capacity_dispatch_matches_reference_combine(self):
-        """With capacity high enough that nothing drops, the gather/scatter
-        dispatch must equal the straightforward dense-combine computation."""
+    @pytest.mark.parametrize("norm", [True, False])
+    def test_sorted_dispatch_matches_reference_combine(self, norm):
+        """The sort, grouped matmuls and gather must equal the
+        straightforward dense-combine computation, every assignment kept."""
         key = jax.random.PRNGKey(1)
         t, d, f, e, k = 16, 8, 12, 4, 2
         ks = jax.random.split(key, 5)
@@ -142,12 +158,13 @@ class TestMoE:
         w_up = jax.random.normal(ks[1], (e, d, f))
         w_gate = jax.random.normal(ks[2], (e, d, f))
         w_down = jax.random.normal(ks[3], (e, f, d))
-        out, aux = moe_ffn(x, gate_w, w_up, w_gate, w_down, top_k=k,
-                           capacity_factor=float(e))  # no drops possible
+        live = jnp.arange(t) < 10
+        out, aux, counts = moe_ffn(x, gate_w, w_up, w_gate, w_down, top_k=k,
+                                   norm_topk_prob=norm, live=live)
 
         # Reference: dense every-expert-sees-every-token combine.
         logits = x @ gate_w
-        weights, idx = top_k_routing(logits, k)
+        weights, idx = top_k_routing(logits, k, norm)
         one_hot = jax.nn.one_hot(idx, e, dtype=jnp.float32)
         combine = jnp.einsum("tk,tke->te", weights, one_hot)
         h = jax.nn.silu(jnp.einsum("td,edf->etf", x, w_gate)) * \
@@ -156,33 +173,61 @@ class TestMoE:
         dense = jnp.einsum("etd,te->td", expert_out, combine)
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                    rtol=2e-4, atol=1e-5)
+        # the count is of the live rows' assignments; every row is computed
+        np.testing.assert_array_equal(
+            np.asarray(counts), np.asarray(one_hot[:10].sum((0, 1)), np.int32))
 
-    def test_capacity_dispatch_drops_overflow(self):
-        """Tokens past an expert's capacity contribute zero (Switch
-        semantics) — and the op still differentiates."""
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_stack_with_layer_index_equals_the_slice(self, layer):
+        """Serving hands `moe_ffn` every layer's experts and the layer's index
+        (the other layers are empty groups of the grouped matmul); training
+        hands it the layer's slice. One computation, bit for bit."""
+        t, d, f, e, k, n_layers = 12, 8, 12, 4, 2, 3
+        ks = jax.random.split(jax.random.PRNGKey(3), 5)
+        x = jax.random.normal(ks[0], (t, d))
+        gate_w = jax.random.normal(ks[4], (d, e))
+        w_up = jax.random.normal(ks[1], (n_layers, e, d, f))
+        w_gate = jax.random.normal(ks[2], (n_layers, e, d, f))
+        w_down = jax.random.normal(ks[3], (n_layers, e, f, d))
+        sliced = jax.jit(lambda l: moe_ffn(
+            x, gate_w, w_up[l], w_gate[l], w_down[l], top_k=k,
+            norm_topk_prob=False))(jnp.int32(layer))
+        stacked = jax.jit(lambda l: moe_ffn(
+            x, gate_w, w_up, w_gate, w_down, top_k=k, norm_topk_prob=False,
+            layer=l))(jnp.int32(layer))
+        for a, b in zip(sliced, stacked):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_no_token_dropped_under_skew(self):
+        """A router of zeros sends EVERY token to expert 0 (top_k breaks the
+        tie to the lowest index): the skew that capacity_factor 1.25 answered
+        by dropping 6 of 8 tokens. Every token still gets its expert's
+        output, and the op still differentiates."""
         t, d, f, e = 8, 4, 8, 2
         key = jax.random.PRNGKey(2)
         ks = jax.random.split(key, 4)
         x = jax.random.normal(ks[0], (t, d))
-        # Zero router logits: top_k tie-breaks to expert 0 for EVERY token.
         gate_w = jnp.zeros((d, e))
         w_up = jax.random.normal(ks[1], (e, d, f))
         w_gate = jax.random.normal(ks[2], (e, d, f))
         w_down = jax.random.normal(ks[3], (e, f, d))
-        # capacity = ceil(8*1*0.5/2) = 2: only 2 of 8 tokens survive.
-        out, _ = moe_ffn(x, gate_w, w_up, w_gate, w_down, top_k=1,
-                         capacity_factor=0.5)
-        nonzero_rows = np.flatnonzero(
-            np.abs(np.asarray(out)).sum(axis=-1) > 1e-7)
-        assert len(nonzero_rows) == 2, nonzero_rows
+        out, _, counts = moe_ffn(x, gate_w, w_up, w_gate, w_down, top_k=1,
+                                 norm_topk_prob=False)
+        assert list(np.asarray(counts)) == [t, 0]
+        # weight 1/2: the softmax over both experts, not renormalised
+        dense = 0.5 * (jax.nn.silu(x @ w_gate[0]) * (x @ w_up[0])) @ w_down[0]
+        np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
+                                   rtol=1e-5, atol=1e-6)
 
-        def loss(xx):
-            o, aux = moe_ffn(xx, gate_w, w_up, w_gate, w_down, top_k=1,
-                             capacity_factor=0.5)
+        def loss(xx, gw):
+            o, aux, _ = moe_ffn(xx, gw, w_up, w_gate, w_down, top_k=1,
+                                norm_topk_prob=False)
             return jnp.sum(o ** 2) + aux
 
-        g = jax.grad(loss)(x)
-        assert np.isfinite(np.asarray(g)).all()
+        gx, gg = jax.grad(loss, argnums=(0, 1))(x, gate_w)
+        assert np.isfinite(np.asarray(gx)).all()
+        assert np.abs(np.asarray(gx)).min(axis=-1).max() > 0   # every row
+        assert np.isfinite(np.asarray(gg)).all() and np.abs(gg).max() > 0
 
 
 def test_flash_attention_pallas_backward_tpu():
