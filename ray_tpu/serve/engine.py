@@ -5,19 +5,22 @@ python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_models.py:170 —
 engine_kwargs feed vLLM's continuous batcher + paged attention; here the
 engine is OURS):
 
-- **Paged KV arena** `[n_layers, n_pages, page, kv_heads, head_dim]` with
-  a per-slot BLOCK TABLE `[n_slots, max_pages]` of physical page ids —
-  vLLM's block-table design recast for XLA: the table is a device array,
-  reads are one gather per layer straight out of the arena (`kc[l, bt]`),
-  writes are one scatter of a row per slot straight into it
-  (`kc.at[l, page, offset]`). The decode program updates the arena IN
+- **Paged KV arena** `[n_layers, n_pages, kv_heads, page, head_dim]` (a
+  page of all heads is one contiguous run, a (page, head) block a whole
+  tile) with a per-slot BLOCK TABLE `[n_slots, max_pages]` of physical
+  page ids — vLLM's block-table design: the table is a device array,
+  a decode step writes each slot's row by putting its current page back
+  with that row replaced (`kc.at[l, page]`), and decode attention
+  (`ops.attention.paged_decode_attention`) is handed the WHOLE arena, the
+  layer's index, the table and the live lengths, and reads each slot's
+  live pages where they lie. The decode program updates the arena IN
   PLACE: it is a loop carry that nothing but those two ops touches, so
   no copy of it (or of a layer's slab) is ever made. A 50-token request
   holds ceil(50/page) pages, not a max_seq strip, so concurrency is
   bounded by TOKENS in flight, not by worst-case sequences. Page 0 is
   the NULL page: unused/overflow table entries point at it, making
-  out-of-reservation writes harmless and gathers of unused pages
-  maskable — no data-dependent control flow.
+  out-of-reservation writes harmless, and attention never reads past a
+  slot's live length — no data-dependent control flow outside the kernel.
 - **Reservation admission**: a request is admitted when
   ceil(min(len+max_tokens, max_seq)/page) free pages exist — growth can
   then never fail mid-decode, so there is no preemption/recompute path
@@ -165,6 +168,7 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
 
     from ray_tpu.models.block import (attention_inputs, expert_stacks,
                                       expert_stats, feed_forward)
+    from ray_tpu.ops.attention import paged_decode_attention
     from ray_tpu.ops.norms import rms_norm, rope_frequencies
 
     sparse = mcfg.n_experts > 0
@@ -172,11 +176,9 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
     dt = mcfg.dtype
     ns = n_slots
-    maxp = -(-S // page)          # logical pages per slot
-    CTX = maxp * page             # gathered context width (>= S)
 
     def empty_caches():
-        shape = (mcfg.n_layers, n_pages, page, KVH, hd)
+        shape = (mcfg.n_layers, n_pages, KVH, page, hd)
         return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
     def _write_pages(kc, vc, pages, ks, vs):
@@ -189,8 +191,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         with jax.named_scope("kv_write"):
             ksp = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
             vsp = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            ksp = ksp.reshape(L, wp, page, KVH, hd)
-            vsp = vsp.reshape(L, wp, page, KVH, hd)
+            ksp = ksp.reshape(L, wp, page, KVH, hd).transpose(0, 1, 3, 2, 4)
+            vsp = vsp.reshape(L, wp, page, KVH, hd).transpose(0, 1, 3, 2, 4)
             kc = kc.at[:, pages[:wp]].set(ksp)
             vc = vc.at[:, pages[:wp]].set(vsp)
         return kc, vc
@@ -229,7 +231,7 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         return out.astype(x.dtype)
 
     def _decode_layer(x, kc, vc, lp, l, bt, pos, act, cos, sin):
-        # x [ns, D]; kc/vc the WHOLE arena [L, n_pages, page, KVH, hd];
+        # x [ns, D]; kc/vc the WHOLE arena [L, n_pages, KVH, page, hd];
         # l this layer's index (traced scalar); bt [ns, maxp]; a sparse
         # model's expert weights in lp are all the layers' (`expert_stacks`)
         with jax.named_scope("rope"):
@@ -238,34 +240,31 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             s = sin[w][:, None]
         q, k, v = attention_inputs(lp, x, mcfg,
                                    lambda t: _rope_one(t, c, s))
-        # Scatter k/v at each slot's (layer, page, offset), straight into
-        # the arena: `ns` rows, no layer slab cut out or put back.
+        # Put k/v at each slot's (layer, page, offset), straight into the
+        # arena, no layer slab cut out or put back: the slot's page is read,
+        # its row replaced, and the page scattered back. A scatter of the
+        # `ns` rows alone would be less to move, but its window (every kv
+        # head's row `off`) is strided in this layout, and XLA then lays the
+        # WHOLE arena out the other way round and copies it to and from the
+        # attention kernel every layer (AOT for v5e, PR 28); whole pages
+        # are the layout's own unit.
         # Inactive slots (and positions past a slot's reservation) route
-        # to the NULL page 0, whose content is never read unmasked — the
-        # write stays a fixed-shape scatter with no data-dependent
-        # branches.
+        # to the NULL page 0, which attention never reads — the write
+        # stays a fixed-shape scatter with no data-dependent branches.
         with jax.named_scope("kv_write"):
             idx = jnp.arange(ns)
             pp = jnp.where(act, bt[idx, w // page], 0)
             off = jnp.where(act, w % page, 0)
-            kc = kc.at[l, pp, off].set(k)
-            vc = vc.at[l, pp, off].set(v)
-        # Gather each slot's pages of this layer -> its logical KV
-        # history: one gather with (layer, page) start indices.
-        with jax.named_scope("kv_gather"):
-            kh = kc[l, bt].reshape(ns, CTX, KVH, hd)
-            vh = vc[l, bt].reshape(ns, CTX, KVH, hd)
-        # Grouped-query attention against the gathered history.
+            here = (jnp.arange(page) == off[:, None])[:, None, :, None]
+            kc = kc.at[l, pp].set(jnp.where(here, k[:, :, None], kc[l, pp]))
+            vc = vc.at[l, pp].set(jnp.where(here, v[:, :, None], vc[l, pp]))
+        # Each active slot's query against its positions 0..w, read from
+        # the arena's pages in place (grouped heads and all); an idle slot
+        # reads nothing.
         with jax.named_scope("attn"):
-            qg = q.reshape(ns, KVH, H // KVH, hd).astype(jnp.float32)
-            scores = jnp.einsum("nkgd,nskd->nkgs", qg,
-                                kh.astype(jnp.float32)) / (hd ** 0.5)
-            mask = jnp.arange(CTX)[None, :] <= w[:, None]    # [ns, CTX]
-            scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-            wts = jax.nn.softmax(scores, axis=-1)
-            attn = jnp.einsum("nkgs,nskd->nkgd", wts,
-                              vh.astype(jnp.float32))
-            attn = attn.reshape(ns, H * hd).astype(dt)
+            attn = paged_decode_attention(
+                q, kc, vc, l, bt, jnp.where(act, w + 1, 0))
+            attn = attn.reshape(ns, H * hd)
         with jax.named_scope("attn_out"):
             x = x + attn @ lp["wo"].astype(dt)
         # An idle slot's row is computed like any other, from itself alone,
@@ -290,10 +289,12 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
                 experts = [experts[0] + expert_stats(routed[1])]
             return (x, kc, vc, *experts), None
 
-        # The arena rides this scan's CARRY, and only a scatter and a
-        # gather touch it, so the layer loop, the chunk loop around it and
-        # the donated entry buffers all alias ONE buffer: a step changes
-        # `ns` rows a layer and moves nothing else. It must stay out of
+        # The arena rides this scan's CARRY, and only the page write and
+        # the attention kernel's reads touch it, so the layer loop, the
+        # chunk loop around it and the donated entry buffers all alias ONE
+        # buffer: a step rewrites `ns` pages a layer and moves nothing else
+        # (the kernel is handed the arena and `l`, never `kc[l]`: a custom
+        # call given a slice is first given a copy of it). It must stay out of
         # the scan's xs/ys: an xs is read-only and a ys is a freshly
         # stacked result, so the compiler would slice every layer's slab
         # out, write it into a second arena and copy that back as the next
@@ -458,6 +459,10 @@ class Engine:
         self.prefill_padded_tokens = 0     # bucket width less the prompt
         self.decode_chunks = 0
         self.decode_useful_tokens = 0
+        # Positions the active slots held when each chunk was dispatched:
+        # what decode attention had to read, a layer, at the chunk's first
+        # step (against n_slots * max_seq, what a whole-table gather moves).
+        self.live_kv_tokens = 0
         # A sparse model's routing, as the programs count it on the device
         # (`models.block.expert_stats`) and the emitter thread adds it up:
         # tokens per expert over prefills and decode steps, and the distinct
@@ -663,7 +668,9 @@ class Engine:
         what `serve.engine.admit` and `serve.engine.decode_dispatch` spans
         say one at a time. Occupancy is `decode_useful_tokens` over
         `decode_chunks * n_slots * chunk`; padding is
-        `prefill_padded_tokens` over it plus `prefill_tokens`. A sparse
+        `prefill_padded_tokens` over it plus `prefill_tokens`.
+        `live_kv_tokens` over `decode_chunks * n_slots * max_seq` is the
+        share of the block tables that was live at dispatch. A sparse
         model adds `expert_tokens` (assignments per expert, all layers,
         prefills and decode steps, as far as the emitter has fetched them)
         and `decode_experts_touched` (distinct experts, summed over decode
@@ -672,7 +679,8 @@ class Engine:
         out = {k: getattr(self, k) for k in (
             "admitted", "queue_wait_s_sum", "prefill_tokens",
             "prefill_padded_tokens", "decode_chunks",
-            "decode_useful_tokens", "peak_pages_used", "n_slots", "chunk")}
+            "decode_useful_tokens", "live_kv_tokens", "peak_pages_used",
+            "n_slots", "chunk")}
         if self._sparse:
             out["expert_tokens"] = [int(n) for n in self.expert_tokens]
             out["decode_experts_touched"] = self.decode_experts_touched
@@ -954,8 +962,10 @@ class Engine:
             # queued — an aliased buffer would let those mutations reach
             # into the in-flight computation.
             useful = sum(take for _, _, take, _ in plan)
+            live_kv = int(self._pos[self._active].sum())
             self.decode_chunks += 1
             self.decode_useful_tokens += useful
+            self.live_kv_tokens += live_kv
             # What the emitter has fetched so far: the chunk before's distinct
             # experts (over its steps and the layers) and the running tokens
             # per expert, `:`-joined (the profiler splits arguments at `,`);
@@ -965,7 +975,8 @@ class Engine:
                       } if self._sparse and tracing.recording() else {}
             with tracing.span("serve.engine.decode_dispatch", useful=useful,
                               capacity=self.n_slots * self.chunk,
-                              active=len(plan), **routed):
+                              active=len(plan), live_kv_tokens=live_kv,
+                              **routed):
                 (self._kc, self._vc, self._last_d, self._pos_d, out_d,
                  experts_d) = \
                     self._decode(self.params, self._kc, self._vc,

@@ -91,3 +91,18 @@ def pytest_runtest_call(item):
     finally:
         _signal.alarm(0)
         _signal.signal(_signal.SIGALRM, old)
+
+
+@pytest.fixture
+def kernel_in_interpret_mode(monkeypatch):
+    """An engine built under this fixture takes the Pallas decode-attention
+    kernel, interpreted, on this CPU: what `serve.engine._build_fns` imports
+    is the function with its `interpret` argument set, so the program needs
+    no switch for the tests' sake."""
+    import functools
+
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(
+        attention, "paged_decode_attention",
+        functools.partial(attention.paged_decode_attention, interpret=True))

@@ -149,6 +149,49 @@ def test_engine_prefill_then_paged_decode_match_the_reference(tiny, engine):
     assert routed["decode_experts_touched"] > 0
 
 
+def test_engine_tokens_with_the_decode_kernel_equal_the_reference_paths(
+        tiny, request):
+    """The sparse model through the engine with decode attention on the
+    Pallas kernel (interpret mode, on this CPU) and on the XLA reference
+    path: the same greedy tokens through a prefill, an adopt and chunks in
+    which slots join and leave; and the kernel's tokens are the plain
+    reference's largest logits too."""
+    from ray_tpu.ops import attention
+
+    cfg, params = tiny
+    core = jax.jit(_make_prefill_core(cfg))
+    prompts = [_tokens(14, 11), _tokens(20, 12), _tokens(3, 13)]
+
+    def served():
+        eng = Engine(params, cfg, n_slots=2, decode_chunk=4, page_size=16)
+        try:
+            a = eng.submit(prompts[0], 11)             # positions 14..24
+            first, ks, vs, _, _ = core(params, jnp.asarray(
+                [prompts[1] + [0] * 12], jnp.int32), len(prompts[1]))
+            b = eng.submit_prefilled(ks, vs, len(prompts[1]), int(first), 6)
+            c = eng.submit(prompts[2], 9)              # waits for a slot
+            outs = []
+            for q in (a, b, c):
+                toks = []
+                while (chunk := q.get(timeout=120)) is not None:
+                    toks += chunk
+                outs.append(toks)
+            return outs, int(first)
+        finally:
+            eng.stop()
+
+    want, _ = served()
+    before = attention.attention_path_counts().get("decode_pallas", 0)
+    request.getfixturevalue("kernel_in_interpret_mode")
+    got, first = served()
+    assert attention.attention_path_counts().get("decode_pallas", 0) > before
+    assert got == want and [len(t) for t in got] == [11, 5, 9]
+    got[1] = [first] + got[1]          # the adopt's first token was out
+    for prompt, toks in zip(prompts, got):
+        gaps = ref.served_token_gaps(params, MODEL, prompt, toks)
+        assert max(gaps) < LOGIT_TOL, gaps
+
+
 # -- (c) nobody's answer depends on the batch --------------------------------
 
 def test_a_request_alone_beside_fifteen_others_and_in_two_buckets(tiny, engine):

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops.attention import (attention_reference, flash_attention,
+from ray_tpu.ops.attention import (attention_path_counts, attention_reference,
+                                   flash_attention, paged_decode_attention,
                                    repeat_kv)
 from ray_tpu.ops.moe import moe_ffn, top_k_routing
 from ray_tpu.ops.norms import apply_rope, rms_norm, rope_frequencies
@@ -54,6 +55,92 @@ class TestFlashAttention:
         assert y.shape == (2, 6, 3, 4)
         np.testing.assert_array_equal(np.asarray(y[:, 0]), np.asarray(y[:, 1]))
         np.testing.assert_array_equal(np.asarray(y[:, 0]), np.asarray(x[:, 0]))
+
+
+class TestPagedDecodeAttention:
+    """`paged_decode_attention`: the Pallas kernel in INTERPRET mode (an
+    argument, so the CPU runs the kernel's own code) against the XLA
+    reference path and against plain attention over each slot's history
+    laid out in order."""
+
+    PAGE, MAXP, KVH, HD, LAYERS = 16, 6, 2, 128, 3
+    # idle, one token, a page boundary - 1 / on it / + 1, the full table
+    LENGTHS = (0, 1, PAGE - 1, PAGE, PAGE + 1, MAXP * PAGE)
+
+    def _arena(self, groups, dtype, seed=0):
+        """-> (q, kc, vc, layer, block_table, lengths, histories): pages
+        handed out in a shuffled order, every page no slot holds (the null
+        page 0 first of all) full of NaN, and so are the OTHER layers."""
+        ns, page, maxp = len(self.LENGTHS), self.PAGE, self.MAXP
+        rng = np.random.default_rng(seed)
+        n_pages = 1 + ns * maxp
+        shape = (self.LAYERS, n_pages, self.KVH, page, self.HD)
+        kc = np.full(shape, np.nan, np.float32)
+        vc = np.full(shape, np.nan, np.float32)
+        free = list(rng.permutation(np.arange(1, n_pages)))
+        bt = np.zeros((ns, maxp), np.int32)
+        layer, hist = 1, []
+        for s, n in enumerate(self.LENGTHS):
+            pages = [free.pop() for _ in range(-(-n // page))]
+            bt[s, :len(pages)] = pages
+            # a live page is finite to its end: the tail past `n` is read
+            # and masked, as the engine's recycled pages are
+            k = rng.standard_normal((len(pages) * page, self.KVH, self.HD))
+            v = rng.standard_normal((len(pages) * page, self.KVH, self.HD))
+            for i, pg in enumerate(pages):
+                rows = slice(i * page, (i + 1) * page)
+                kc[layer, pg] = k[rows].transpose(1, 0, 2)
+                vc[layer, pg] = v[rows].transpose(1, 0, 2)
+            hist.append((k[:n], v[:n]))
+        q = rng.standard_normal((ns, self.KVH * groups, self.HD))
+        cast = lambda x: jnp.asarray(x, dtype)  # noqa: E731
+        return (cast(q), cast(kc), cast(vc), jnp.int32(layer),
+                jnp.asarray(bt), jnp.asarray(self.LENGTHS, jnp.int32), hist)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("groups", [4, 1], ids=["gqa4", "mha"])
+    def test_kernel_in_interpret_mode_matches_the_reference(self, groups,
+                                                            dtype):
+        *args, hist = self._arena(groups, dtype)
+        before = attention_path_counts()
+        ref = jax.jit(paged_decode_attention)(*args)
+        # two pages a block: the page is smaller than the block, the full
+        # table is three blocks, and PAGE + 1 ends one page into a block
+        out = jax.jit(functools.partial(
+            paged_decode_attention, interpret=True,
+            pages_per_block=2))(*args)
+        after = attention_path_counts()
+        assert after.get("decode_reference", 0) == \
+            before.get("decode_reference", 0) + 1
+        assert after.get("decode_pallas", 0) == \
+            before.get("decode_pallas", 0) + 1
+        out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+        assert np.isfinite(out).all() and np.isfinite(ref).all()
+        tol = 1e-5 if dtype == jnp.float32 else 2e-2   # one bf16 ulp at 2
+        np.testing.assert_allclose(out, ref, atol=tol)
+        # and both against attention over the history in its logical order
+        q = np.asarray(args[0], np.float32)
+        for s, (k, v) in enumerate(hist):
+            if not len(k):
+                assert (out[s] == 0).all() and (ref[s] == 0).all()
+                continue
+            k = np.asarray(jnp.asarray(k, dtype), np.float32)
+            v = np.asarray(jnp.asarray(v, dtype), np.float32)
+            for h in range(q.shape[1]):
+                sc = k[:, h // groups] @ q[s, h] / np.sqrt(self.HD)
+                w = np.exp(sc - sc.max())
+                want = (w / w.sum()) @ v[:, h // groups]
+                np.testing.assert_allclose(out[s, h], want, atol=tol)
+
+    def test_default_block_covers_the_whole_table(self):
+        """With no `pages_per_block` the block is sized in bytes and capped
+        at the table: one block here, every page of it conditional."""
+        *args, _ = self._arena(4, jnp.float32, seed=1)
+        out = paged_decode_attention(*args, interpret=True)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(paged_decode_attention(*args)),
+            atol=1e-5)
 
 
 class TestRingAttention:

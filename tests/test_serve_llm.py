@@ -385,14 +385,21 @@ def _tiny_decode(n_layers=3):
     return decode, args, chunk
 
 
-def test_decode_arena_is_a_scan_carry_only_scattered_and_gathered():
-    """The arena must ride the layer scan's CARRY and be touched by one
-    scatter and one gather for each of K and V — nothing else. As an
-    xs/ys of the scan it is sliced out a layer at a time and restacked
-    into a second arena every step (55% of a decode step on the chip
-    before PR 25). Read on the jaxpr, so any backend shows it."""
+@pytest.mark.parametrize("path", ["reference", "kernel"])
+def test_decode_arena_is_a_scan_carry_only_written_and_attended(
+        path, request):
+    """The arena must ride the layer scan's CARRY and be touched, for each
+    of K and V, by the page write (a gather and a scatter of the slots'
+    current pages) and by decode attention: one `pallas_call` handed the
+    WHOLE arena on the kernel's path, the reference path's gather
+    otherwise — nothing else. As an xs/ys of the scan it is sliced out a
+    layer at a time and restacked into a second arena every step (55% of a
+    decode step on the chip before PR 25); a kernel handed `kc[l]` is
+    handed a copy. Read on the jaxpr, so any backend shows it."""
     import jax
 
+    if path == "kernel":
+        request.getfixturevalue("kernel_in_interpret_mode")
     decode, args, chunk = _tiny_decode(n_layers=3)
     arena = args[1].shape
     slab = arena[1:]
@@ -408,16 +415,76 @@ def test_decode_arena_is_a_scan_carry_only_scattered_and_gathered():
     assert not [v.aval.shape for v in list(xs) + list(ys)
                 if v.aval.shape in (arena, slab)]
     # Inside the layer: no slab exists, and the arena (as it came in, or
-    # as a scatter left it) is consumed by scatter and gather alone.
+    # as the write left it) is consumed by those ops alone. (What is
+    # inside the kernel's own jaxpr is its business: it sees references.)
     body = layers.params["jaxpr"].jaxpr
     consumers = []
-    for eqn in _all_eqns(body):
+    for eqn in body.eqns:
         shapes = [getattr(v.aval, "shape", None)
                   for v in list(eqn.invars) + list(eqn.outvars)]
         assert slab not in shapes, eqn
         if arena in shapes[:len(eqn.invars)]:
             consumers.append(eqn.primitive.name)
-    assert sorted(consumers) == ["gather", "gather", "scatter", "scatter"]
+    write = ["gather", "scatter"] * 2
+    attend = ["pallas_call"] if path == "kernel" else ["gather"] * 2
+    assert sorted(consumers) == sorted(write + attend)
+
+
+def _drain(q):
+    out = []
+    while (item := q.get(timeout=120)) is not None:
+        out.extend(item)
+    return out
+
+
+def _prefill_adopt_and_two_chunks(cfg, params):
+    """Greedy tokens of three requests through a new 2-slot engine: one
+    prefilled here that decodes over three chunks and a page boundary, one
+    ADOPTED (its KV prefilled outside, as a PrefillServer hands it over)
+    that joins beside it and leaves first, and a third that can only join
+    once a slot is free."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.serve.engine import Engine, _make_prefill_core
+
+    eng = Engine(params, cfg, n_slots=2, decode_chunk=4, page_size=16)
+    try:
+        a = eng.submit(list(range(3, 17)), 11)     # positions 14..24
+        prompt = [5] * 20
+        first, ks, vs, _, _ = jax.jit(_make_prefill_core(cfg))(
+            params, jnp.asarray([prompt + [0] * 12], jnp.int32), len(prompt))
+        b = eng.submit_prefilled(ks, vs, len(prompt), int(first), 6)
+        c = eng.submit([9, 8, 7], 9)
+        return [_drain(q) for q in (a, b, c)], eng.counters()
+    finally:
+        eng.stop()
+
+
+def test_engine_tokens_with_the_kernel_equal_the_reference_paths(
+        request):
+    """The Pallas decode kernel (interpret mode: its own code, on this
+    CPU) inside the whole engine, against the XLA reference path in the
+    same engine: the same greedy tokens through a prefill, an adopt and
+    chunks in which slots join and leave."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    from ray_tpu.ops.attention import attention_path_counts
+
+    cfg = LlamaConfig(vocab_size=128, d_model=32, n_layers=3, n_heads=4,
+                      n_kv_heads=2, d_ff=64, max_seq=64, dtype=np.float32)
+    params = init_params(cfg, jax.random.PRNGKey(1))
+    want, counters = _prefill_adopt_and_two_chunks(cfg, params)
+    assert [len(t) for t in want] == [11, 5, 9]    # the adopt's first is out
+    before = attention_path_counts().get("decode_pallas", 0)
+    request.getfixturevalue("kernel_in_interpret_mode")
+    got, counters_k = _prefill_adopt_and_two_chunks(cfg, params)
+    assert attention_path_counts().get("decode_pallas", 0) > before
+    assert got == want
+    assert counters_k["decode_useful_tokens"] == \
+        counters["decode_useful_tokens"] == 10 + 5 + 8
 
 
 def test_decode_call_donates_the_arena():

@@ -114,24 +114,42 @@ def test_pp_with_kernel_fails_clearly(topo, monkeypatch):
         _tiny_step(topo, MeshConfig(pp=2, dp=2), monkeypatch)
 
 
-def test_decode_program_updates_the_arena_in_place_on_v5e(topo):
-    """The serving decode chunk at Mistral-7B widths (4 of its layers),
-    compiled for the chip: the KV arena must alias through the layer
-    loop, the chunk loop and the donated entry buffers. As a scan xs/ys
-    it was sliced out a layer at a time and restacked into a second arena
-    every step: 24 of a 44 ms step on the chip (PERF.md, PR 25). The
-    jaxpr test in tests/test_serve_llm.py holds the program's shape; this
-    one holds what the TPU compiler makes of it."""
+# (vocab, d_model, heads, kv heads, d_ff, pages): the attention widths of
+# the two serve configurations of BENCHMARK.json, 4 of their layers. OLMoE's
+# feed-forward is dense here: it is the attention (MHA, 16 kv heads of 128,
+# one query head a kv head) that the decode kernel has to adapt to.
+DECODE_WIDTHS = {"mistral-7b": (32768, 4096, 32, 8, 14336, 929),
+                 "olmoe-attn": (50304, 2048, 16, 16, 1024, 1025)}
+
+
+@pytest.mark.parametrize("widths", sorted(DECODE_WIDTHS))
+def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
+        topo, widths, monkeypatch):
+    """The serving decode chunk, compiled for the chip: the KV arena must
+    alias through the layer loop, the chunk loop and the donated entry
+    buffers. As a scan xs/ys it was sliced out a layer at a time and
+    restacked into a second arena every step: 24 of a 44 ms step on the
+    chip (PERF.md, PR 25). And attention must read it where it lies: one
+    Mosaic kernel a layer, handed the whole arena, and no gathered copy of
+    every slot's whole block table (10 of a 21 ms step, 43 of 54 under MHA
+    with its float32 copy; PERF.md, PR 28). The jaxpr test in
+    tests/test_serve_llm.py holds the program's shape; this one holds what
+    the TPU compiler makes of it."""
     import re
 
     from ray_tpu.models.llama import LlamaConfig, init_params
     from ray_tpu.serve.engine import _build_fns
 
+    # The engine asks jax.devices() which attention path to take and sees
+    # this sandbox's CPU, so the test, not the program, steers it.
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     one_chip = SingleDeviceSharding(topo.devices[0])
-    cfg = LlamaConfig(vocab_size=32768, d_model=4096, n_layers=4,
-                      n_heads=32, n_kv_heads=8, d_ff=14336, max_seq=4096,
+    vocab, d_model, H, KVH, d_ff, n_pages = DECODE_WIDTHS[widths]
+    cfg = LlamaConfig(vocab_size=vocab, d_model=d_model, n_layers=4,
+                      n_heads=H, n_kv_heads=KVH, d_ff=d_ff, max_seq=4096,
                       dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
-    ns, chunk, page, n_pages = 16, 8, 64, 929
+    ns, chunk, page, hd = 16, 8, 64, cfg.head_dim
+    maxp = cfg.max_seq // page
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -140,24 +158,51 @@ def test_decode_program_updates_the_arena_in_place_on_v5e(topo):
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype),
         jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
-    arena = sds((cfg.n_layers, n_pages, page, 8, 128), jnp.bfloat16)
+    slab = (n_pages, KVH, page, hd)
+    arena = sds((cfg.n_layers,) + slab, jnp.bfloat16)
     compiled = decode.lower(
-        params, arena, arena, sds((ns, cfg.max_seq // page), jnp.int32),
+        params, arena, arena, sds((ns, maxp), jnp.int32),
         sds((ns,), jnp.int32), sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
         sds((ns,), jnp.float32), sds((ns,), jnp.int32),
         sds((ns, 2), jnp.uint32)).compile()
+    text = compiled.as_text()
+    # (name, dtype, dims, op) of every instruction with an array result.
+    results = [(name, dtype, tuple(int(d) for d in dims.split(",")), op)
+               for name, dtype, dims, op in re.findall(
+                   r"%(\S+) = (\w+)\[([\d,]+)\]\S* ([\w-]+)\(", text)]
     # Every op whose result is arena- or slab-shaped: none may be a copy, a
     # slice or an update-slice, bare or fused (a fusion carries the op in
     # its name: `bitcast_dynamic-update-slice_fusion`).
-    results = re.findall(
-        r"%(\S+) = bf16\[(?:4,)?929,64,8,128\]\S* ([\w-]+)\(",
-        compiled.as_text())
-    assert "scatter" in {op for _, op in results}  # the pattern still reads
-    moved = [name for name, op in results
+    on_arena = [(name, op) for name, _, dims, op in results
+                if dims in (slab, (cfg.n_layers,) + slab)]
+    assert "scatter" in {op for _, op in on_arena}  # the pattern still reads
+    moved = [name for name, op in on_arena
              if op == "copy" or "dynamic-" in op + name]
     assert not moved, moved
-    one_arena = cfg.n_layers * n_pages * page * 8 * 128 * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < one_arena
+    # No instruction makes the gathered history, in any order of its
+    # dimensions, with slots and pages merged or apart, in any dtype.
+    gathered = [sorted(d) for d in ((ns * maxp, page, KVH, hd),
+                                    (ns, maxp, page, KVH, hd),
+                                    (ns, maxp * page, KVH, hd))]
+    made = [(name, dtype, dims) for name, dtype, dims, _ in results
+            if sorted(dims) in gathered]
+    assert not made, made
+    # One Mosaic kernel in the program, the layer body's attention, and its
+    # operands are the two whole arenas.
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    dims = ",".join(map(str, (cfg.n_layers,) + slab))
+    assert calls[0].count(f"bf16[{dims}]") == 2, calls[0]
+    # Temporaries stay under one layer's slab (the gathered K and V alone
+    # were 1.1 slabs each, their float32 copies twice that), once the copy
+    # of the stacked `wq` that every decode program still makes is set
+    # aside (ROADMAP S10: 134 MB at Mistral's widths, more than the slab).
+    s10 = sum(2 * cfg.n_layers * d_model * H * hd
+              for _, dtype, dims, op in results if op == "copy"
+              and dims == (cfg.n_layers, d_model, H * hd))
+    one_slab = n_pages * page * KVH * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes - s10 < one_slab
 
 
 # ---------------------------------------------------------------------------

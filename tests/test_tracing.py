@@ -15,8 +15,7 @@ import pytest
 from ray_tpu.utils import tracing
 
 SCOPES_DECODE = ("embed", "layers", "attn_norm", "qkv", "rope", "kv_write",
-                 "kv_gather", "attn", "attn_out", "mlp_norm", "mlp", "head",
-                 "sample")
+                 "attn", "attn_out", "mlp_norm", "mlp", "head", "sample")
 SCOPES_PREFILL = ("embed", "layers", "attn_norm", "qkv", "rope", "attn",
                   "attn_out", "mlp_norm", "mlp", "head", "kv_write", "sample")
 SCOPES_TRAIN = ("embed", "layers", "attn_norm", "qkv", "rope", "attn",
@@ -138,6 +137,30 @@ def test_engine_spans_land_in_the_profile_and_agree_with_counters(
                and s["kind"] == "chunk") == len(chunks_d)
     assert sum(1 for n, _, _, _ in emits
                if n == "serve.engine.emit_block") == len(chunks_d)
+
+
+def test_decode_dispatch_carries_the_live_kv_tokens_the_host_holds(
+        engine, tmp_path):
+    """`live_kv_tokens` on a `serve.engine.decode_dispatch` span is the sum
+    over the active slots of the position each held when the chunk was
+    dispatched: what decode attention reads a layer at the chunk's first
+    step. One request at a time, so the host's own sum is known here: a
+    prompt of n tokens is dispatched at n, n + chunk, ..."""
+    asks = [(5, 7), (40, 12)]               # (prompt tokens, max_tokens)
+    before = engine.counters()["live_kv_tokens"]
+    with _Profiled(tmp_path) as prof:
+        for n, m in asks:
+            q = engine.submit(list(range(1, 1 + n)), m)
+            while q.get() is not None:
+                pass
+    spans = [s for _, _, _, s in prof.events("serve.engine.decode_dispatch")]
+    want = [n + engine.chunk * i for n, m in asks
+            for i in range(-(-(m - 1) // engine.chunk))]
+    assert [s["live_kv_tokens"] for s in spans] == want == [5, 9, 40, 44, 48]
+    assert all(s["active"] == 1 for s in spans)
+    assert engine.counters()["live_kv_tokens"] - before == sum(want)
+    # beside it, what a whole-table gather would have moved
+    assert sum(want) < len(spans) * engine.n_slots * engine.mcfg.max_seq
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill", "train_step"])
