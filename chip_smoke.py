@@ -328,9 +328,10 @@ def _cache_entries(cache_dir: str) -> int:
 # ---------------------------------------------------------------------------
 
 def _train_loop(config: Dict[str, Any]) -> None:
-    """Runs in every JaxTrainer worker: bench.py's loop (make_train_fns +
-    iter_jax_batches), with the compile made explicit so that its time,
-    its text and its memory are on record."""
+    """Runs in every JaxTrainer worker: the train loop of
+    benchmark/train_loop.py (make_train_fns + iter_jax_batches), with the
+    compile made explicit so that its time, its text and its memory are on
+    record."""
     import jax
     import numpy as np
 
@@ -524,7 +525,8 @@ def phase_train(*, model: Dict[str, Any], batch: int, seq: int, steps: int,
 
 def _http_stream(url: str, prompt: List[int], max_tokens: int
                  ) -> Dict[str, Any]:
-    """One greedy streaming completion, as bench_serve.py sends it."""
+    """One greedy streaming completion, as benchmark/http_load.py sends
+    it."""
     req = urllib.request.Request(
         url, data=json.dumps({"prompt": prompt,
                               "max_tokens": max_tokens}).encode(),

@@ -83,12 +83,18 @@ void CheckScatter(void* eng, size_t nsegs, size_t seg_len, size_t gap) {
   assert(std::system(("rm -rf " + dir).c_str()) == 0);
 }
 
-void TestSequentialScatter() {
-  void* eng = copy_engine_create(-1);  // clamps to 0 workers
-  assert(copy_engine_threads(eng) == 0);
+void TestAutoSizedScatter() {
+  // nthreads <= 0 auto-sizes: the cores there are less the caller's own,
+  // at most 16, and none on a one-core host. Whatever the pool, a scatter
+  // under one chunk runs sequentially on the calling thread.
+  unsigned hw = std::thread::hardware_concurrency();
+  int want = hw > 1 ? (int)hw - 1 : 0;
+  if (want > 16) want = 16;
+  void* eng = copy_engine_create(-1);
+  assert(copy_engine_threads(eng) == want);
   CheckScatter(eng, 5, 1000, 37);
   copy_engine_destroy(eng);
-  std::printf("  sequential scatter OK\n");
+  std::printf("  auto-sized (%d workers), sequential scatter OK\n", want);
 }
 
 void TestPooledScatter() {
@@ -177,7 +183,7 @@ void TestLinkat() {
 }  // namespace
 
 int main() {
-  TestSequentialScatter();
+  TestAutoSizedScatter();
   TestPooledScatter();
   TestConcurrentScatters();
   TestErrorPropagation();

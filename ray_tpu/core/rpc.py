@@ -149,14 +149,18 @@ class RpcServer:
     async def stop(self) -> None:
         for srv in self._servers:
             srv.close()
-            await srv.wait_closed()
-        self._servers.clear()
         # Closing the listeners only stops NEW connections; a stopped
         # server must also drop established ones so clients see the loss
-        # (and fail their pending calls) instead of waiting forever.
+        # (and fail their pending calls) instead of waiting forever. And
+        # before `wait_closed`: since Python 3.12 it waits for every
+        # established connection to end, so a peer that keeps its socket
+        # open (a controller dialled into an agent) held stop() for good.
         for w in list(self._conns):
             w.close()
         self._conns.clear()
+        for srv in self._servers:
+            await srv.wait_closed()
+        self._servers.clear()
 
     async def _on_client(self, reader: asyncio.StreamReader,
                          writer: asyncio.StreamWriter) -> None:

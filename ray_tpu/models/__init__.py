@@ -1,6 +1,5 @@
-from ray_tpu.models.llama import (LlamaConfig, flops_per_token, forward,
-                                  init_params, logical_axes, loss_fn,
-                                  param_count)
+from ray_tpu.models.llama import (LlamaConfig, forward, init_params,
+                                  logical_axes, loss_fn, param_count)
 
 __all__ = ["LlamaConfig", "forward", "init_params", "logical_axes", "loss_fn",
-           "param_count", "flops_per_token"]
+           "param_count"]

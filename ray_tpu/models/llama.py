@@ -380,10 +380,3 @@ def loss_fn(params: Dict[str, Any], tokens: jax.Array, cfg: LlamaConfig,
         if cfg.n_experts > 0:
             loss = loss + cfg.moe_aux_weight * aux
         return loss, {"loss": loss, "tokens": jnp.sum(mask)}
-
-
-def flops_per_token(cfg: LlamaConfig, seq: int) -> float:
-    """Approximate training FLOPs/token (6N + attention term) for MFU."""
-    n = param_count(cfg) - cfg.vocab_size * cfg.d_model  # exclude embed lookup
-    attn = 12 * cfg.n_layers * cfg.d_model * seq  # 2*2*3 * L * D * S (fwd+bwd qk+av)
-    return 6.0 * n + attn

@@ -14,9 +14,9 @@ Design:
     elsewhere); backward is the standard two-kernel Pallas flash backward
     (dK/dV pass + dQ pass, bf16 MXU matmuls with f32 accumulation), with a
     blockwise XLA fallback off-TPU / for unaligned shapes.
-  * ``paged_decode_attention`` — one query token a slot against a PAGED KV
-    cache: a Pallas TPU kernel that reads a slot's live pages where they lie
-    in the arena (the XLA gather over the whole block table elsewhere).
+
+Decode attention over the paged KV cache lives with the cache, in
+``ops/paged_kv.py``; it counts its path here (``attention_path_counts``).
 
 Layout: [batch, num_heads, seq, head_dim] (BHSD).
 """
@@ -38,11 +38,11 @@ logger = get_logger("ops.attention")
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# Which implementation each traced flash_attention or paged_decode_attention
-# call took, counted at TRACE time ("fwd_pallas", "fwd_reference",
-# "bwd_pallas", "bwd_reference", "decode_pallas", "decode_reference"): the
-# dispatch below is otherwise invisible from
-# outside a jitted program, and a benchmark must be able to assert that
+# Which implementation each traced flash_attention or
+# paged_kv.paged_decode_attention call took, counted at TRACE time
+# ("fwd_pallas", "fwd_reference", "bwd_pallas", "bwd_reference",
+# "decode_pallas", "decode_reference"): the dispatch is otherwise invisible
+# from outside a jitted program, and a benchmark must be able to assert that
 # the kernel it names is the one that ran.
 _path_counts: collections.Counter = collections.Counter()
 
@@ -498,221 +498,3 @@ def repeat_kv(x: jax.Array, n_rep: int) -> jax.Array:
     b, kvh, s, d = x.shape
     return jnp.broadcast_to(x[:, :, None], (b, kvh, n_rep, s, d)).reshape(
         b, kvh * n_rep, s, d)
-
-
-# ---------------------------------------------------------------------------
-# Decode attention over a paged KV cache
-# ---------------------------------------------------------------------------
-
-# A block of pages, the kernel's unit of DMA and of matmul: 512 tokens where
-# VMEM allows (on a v5e, at both Mistral-7B's and OLMoE's head layouts, 256
-# tokens a block read 46-52% of the HBM roofline and 512 read 69-80%; 1,024
-# no more, and 16 kv heads of them do not fit), and never more than 2 MiB a
-# buffer: there are four, K and V of the block computed and of the next.
-_DECODE_BLOCK_TOKENS = 512
-_DECODE_BLOCK_BYTES = 2 << 20
-
-
-def _sublanes(dtype) -> int:
-    """Rows of one packed (sublane, 128) tile of `dtype`."""
-    return 8 * 4 // jnp.dtype(dtype).itemsize
-
-
-def _paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
-                         o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
-                         sm_scale: float, groups: int, split: bool,
-                         pages_per_block: int):
-    """One grid step = one slot. Its live pages come in by DMA, a block of
-    `pages_per_block` at a time, double-buffered; the loop over blocks has a
-    DYNAMIC trip count, so a short or idle slot costs what it holds and the
-    grid does not grow with the block table. Online softmax a kv head, f32
-    statistics and accumulator."""
-    _, n_kv, T, _ = kbuf.shape
-    page = T // pages_per_block
-    max_pages = bt_ref.shape[1]
-    slot = pl.program_id(0)
-    layer = layer_ref[0]
-    length = len_ref[slot]
-    live_pages = pl.cdiv(length, page)
-    n_blocks = pl.cdiv(live_pages, pages_per_block)
-
-    @pl.when(slot == 0)
-    def _clear():
-        # A block's tail past the live pages is never fetched: what lies
-        # there is masked, and must be finite (0 x NaN is NaN). After this
-        # the buffers only ever hold zeros or real K/V.
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
-
-    def each_copy(block, buf, fn):
-        for i in range(pages_per_block):
-            idx = block * pages_per_block + i
-            page_id = bt_ref[slot, jnp.minimum(idx, max_pages - 1)]
-            rows = pl.ds(i * page, page)
-
-            @pl.when(idx < live_pages)
-            def _():
-                fn(pltpu.make_async_copy(k_hbm.at[layer, page_id],
-                                         kbuf.at[buf, :, rows, :],
-                                         sem.at[0, buf]))
-                fn(pltpu.make_async_copy(v_hbm.at[layer, page_id],
-                                         vbuf.at[buf, :, rows, :],
-                                         sem.at[1, buf]))
-
-    m_ref[...] = jnp.full_like(m_ref, DEFAULT_MASK_VALUE)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(n_blocks > 0)
-    def _first():
-        each_copy(0, 0, lambda c: c.start())
-
-    def block_body(b, carry):
-        buf = b % 2
-
-        @pl.when(b + 1 < n_blocks)
-        def _next():
-            each_copy(b + 1, 1 - buf, lambda c: c.start())
-
-        each_copy(b, buf, lambda c: c.wait())
-        rows = q_ref.shape[2]
-        live = (b * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
-                < length)
-        upper = jax.lax.broadcasted_iota(jnp.int32, (rows, T), 0) < groups
-        for h in range(n_kv):
-            k = kbuf[buf, h]                                   # [T, hd]
-            v = vbuf[buf, h]
-            s = jax.lax.dot_general(
-                q_ref[0, h], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # [rows, T]
-            s = jnp.where(live, s, DEFAULT_MASK_VALUE)
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
-            if split:
-                # q's rows come twice (see the wrapper): the upper copy
-                # carries p rounded to the cache's dtype, the lower what the
-                # rounding dropped, so ONE pass of V through the MXU gives
-                # p.v with p's float32 mantissa to 16 bits.
-                p = jnp.where(upper, p,
-                              p - p.astype(v.dtype).astype(jnp.float32))
-            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
-        return carry
-
-    jax.lax.fori_loop(0, n_blocks, block_body, 0)
-    # An idle slot (length 0) walked nothing: l is 0 and so is its output.
-    o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-
-
-def _paged_decode_pallas(q, kc, vc, layer, block_table, lengths, *,
-                         sm_scale, pages_per_block, interpret):
-    ns, H, hd = q.shape
-    _, _, n_kv, page, _ = kc.shape
-    groups = H // n_kv
-    # float32 softmax weights against a narrower cache: see `split` above.
-    split = jnp.dtype(kc.dtype).itemsize < 4
-    copies = 2 if split else 1
-    tile = _sublanes(kc.dtype)
-    rows = -(-copies * groups // tile) * tile
-    qg = q.reshape(ns, n_kv, groups, hd).astype(kc.dtype)
-    qg = jnp.concatenate(
-        [qg] * copies + [jnp.zeros((ns, n_kv, rows - copies * groups, hd),
-                                   kc.dtype)], axis=2)
-    if pages_per_block is None:
-        page_bytes = n_kv * page * hd * jnp.dtype(kc.dtype).itemsize
-        pages_per_block = max(1, min(_DECODE_BLOCK_TOKENS // page,
-                                     _DECODE_BLOCK_BYTES // page_bytes))
-    pages_per_block = min(pages_per_block, block_table.shape[1])
-    T = pages_per_block * page
-    kernel = functools.partial(
-        _paged_decode_kernel, sm_scale=sm_scale, groups=groups, split=split,
-        pages_per_block=pages_per_block)
-    slot_block = pl.BlockSpec((1, n_kv, rows, hd),
-                              lambda s, *_: (s, 0, 0, 0))
-    out = pl.pallas_call(
-        kernel,
-        name="paged_decode",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,       # layer, lengths, block table
-            grid=(ns,),
-            in_specs=[slot_block,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=slot_block,
-            scratch_shapes=[
-                pltpu.VMEM((2, n_kv, T, hd), kc.dtype),
-                pltpu.VMEM((2, n_kv, T, hd), vc.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
-                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
-                pltpu.VMEM((n_kv, rows, hd), jnp.float32),
-            ]),
-        out_shape=jax.ShapeDtypeStruct((ns, n_kv, rows, hd), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
-      block_table.astype(jnp.int32), qg, kc, vc)
-    out = sum(out[:, :, i * groups:(i + 1) * groups] for i in range(copies))
-    return out.reshape(ns, H, hd).astype(q.dtype)
-
-
-def _paged_decode_reference(q, kc, vc, layer, block_table, lengths, *,
-                            sm_scale):
-    """The XLA path: gather every page of every slot's table out of the
-    layer, float32 softmax over the whole context under a length mask."""
-    ns, H, hd = q.shape
-    _, _, n_kv, page, _ = kc.shape
-    groups, ctx = H // n_kv, block_table.shape[1] * page
-    qg = q.reshape(ns, n_kv, groups, hd).astype(jnp.float32)
-    # [ns, max_pages, n_kv, page, hd]
-    kh = kc[layer, block_table].astype(jnp.float32)
-    vh = vc[layer, block_table].astype(jnp.float32)
-    scores = jnp.einsum("nkgd,npktd->nkgpt", qg, kh).reshape(
-        ns, n_kv, groups, ctx) * sm_scale
-    live = jnp.arange(ctx)[None, :] < lengths[:, None]          # [ns, ctx]
-    scores = jnp.where(live[:, None, None, :], scores, DEFAULT_MASK_VALUE)
-    wts = jax.nn.softmax(scores, axis=-1).reshape(
-        ns, n_kv, groups, ctx // page, page)
-    # What a dead position holds is masked out of v too: 0 x NaN is NaN.
-    vh = jnp.where(live.reshape(ns, ctx // page, 1, page, 1), vh, 0.0)
-    out = jnp.einsum("nkgpt,npktd->nkgd", wts, vh)
-    return out.reshape(ns, H, hd).astype(q.dtype)
-
-
-def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
-                           sm_scale: Optional[float] = None,
-                           pages_per_block: Optional[int] = None,
-                           interpret: bool = False):
-    """Attention of ONE query token a slot against a paged KV cache.
-
-    q [ns, H, hd]; kc, vc the WHOLE arena [L, n_pages, KVH, page, hd] and
-    `layer` the index into it (a traced scalar: a kernel handed `kc[layer]`
-    is first given a copy of that slab); block_table [ns, max_pages] of
-    physical page ids; lengths [ns], the positions each slot attends to
-    (0: an idle slot, whose output is 0). Slot s reads positions
-    0..lengths[s]-1, position t at page block_table[s, t // page], row
-    t % page. Query head h reads kv head h // (H // KVH). -> [ns, H, hd].
-
-    On a TPU (or with `interpret`, for tests on the CPU) a Pallas kernel
-    that walks only the live pages, in place; elsewhere XLA's gather of the
-    whole table. Table entries past a slot's live pages are never read by
-    the kernel; what lies past `lengths` inside the last live page is read
-    and masked, so it must be finite.
-    """
-    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    page, hd = kc.shape[3], kc.shape[4]
-    use = interpret or (_on_tpu() and hd % 128 == 0
-                        and page % _sublanes(kc.dtype) == 0)
-    _path_counts["decode_pallas" if use else "decode_reference"] += 1
-    if use:
-        return _paged_decode_pallas(
-            q, kc, vc, layer, block_table, lengths, sm_scale=scale,
-            pages_per_block=pages_per_block, interpret=interpret)
-    return _paged_decode_reference(q, kc, vc, layer, block_table, lengths,
-                                   sm_scale=scale)

@@ -1,0 +1,307 @@
+"""The paged KV cache on the device: its layout, and the only ops on it.
+
+The arena is two arrays (K and V) of `[n_layers, n_pages, kv_heads, page,
+head_dim]`: a page of all heads is one contiguous run, a (page, head) block
+a whole tile. A slot's positions live in the physical pages its row of the
+BLOCK TABLE `[n_slots, max_pages]` names, position t at page
+`table[slot, t // page]`, row `t % page`. Page 0 is the NULL page: unused
+table entries point at it, padding and idle slots write to it, and no
+attention ever reads it, so every write is a fixed-shape scatter with no
+data-dependent branch. Which pages a slot holds is the host's business
+(`serve.page_pool.PagePool`); nothing outside this module indexes the arena.
+
+  * ``empty`` makes the arena, ``write_prompt`` puts a prefill's K/V into a
+    slot's pages, ``write_token`` one decode step's row a slot.
+  * ``paged_decode_attention`` is one query token a slot against the arena:
+    a Pallas TPU kernel that reads a slot's live pages where they lie (the
+    XLA gather over the whole block table elsewhere), counted at trace time
+    in `attention.attention_path_counts()` as `decode_pallas` /
+    `decode_reference`.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# The module, not its names: the path counters are one per process, and
+# tests steer `_on_tpu` by patching it there.
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
+
+
+# ---------------------------------------------------------------------------
+# The layout
+# ---------------------------------------------------------------------------
+
+def empty(n_layers: int, n_pages: int, kv_heads: int, page: int,
+          head_dim: int, dtype):
+    """-> (kc, vc), the zeroed arena."""
+    shape = (n_layers, n_pages, kv_heads, page, head_dim)
+    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def write_prompt(kc, vc, pages, ks, vs):
+    """Scatter prefilled [L, W, KVH, hd] k/v into physical pages.
+    W is static (one program per bucket width); `pages[:wp]` entries
+    of 0 route padding into the null page."""
+    L, W, KVH, hd = ks.shape
+    page = kc.shape[3]
+    wp = -(-W // page)
+    pad = wp * page - W
+    with jax.named_scope("kv_write"):
+        ksp = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        vsp = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        ksp = ksp.reshape(L, wp, page, KVH, hd).transpose(0, 1, 3, 2, 4)
+        vsp = vsp.reshape(L, wp, page, KVH, hd).transpose(0, 1, 3, 2, 4)
+        kc = kc.at[:, pages[:wp]].set(ksp)
+        vc = vc.at[:, pages[:wp]].set(vsp)
+    return kc, vc
+
+
+def write_token(kc, vc, layer, block_table, w, active, k, v):
+    """Put one decode step's k/v [ns, KVH, hd] at each slot's (layer, page,
+    offset) for its position w [ns], straight into the arena, no layer slab
+    cut out or put back: the slot's page is read, its row replaced, and the
+    page scattered back. A scatter of the `ns` rows alone would be less to
+    move, but its window (every kv head's row `off`) is strided in this
+    layout, and XLA then lays the WHOLE arena out the other way round and
+    copies it to and from the attention kernel every layer (AOT for v5e,
+    PR 28); whole pages are the layout's own unit.
+    Inactive slots (and positions past a slot's reservation) route to the
+    NULL page 0, which attention never reads: the write stays a fixed-shape
+    scatter with no data-dependent branches."""
+    ns, page = k.shape[0], kc.shape[3]
+    with jax.named_scope("kv_write"):
+        idx = jnp.arange(ns)
+        pp = jnp.where(active, block_table[idx, w // page], 0)
+        off = jnp.where(active, w % page, 0)
+        here = (jnp.arange(page) == off[:, None])[:, None, :, None]
+        kc = kc.at[layer, pp].set(
+            jnp.where(here, k[:, :, None], kc[layer, pp]))
+        vc = vc.at[layer, pp].set(
+            jnp.where(here, v[:, :, None], vc[layer, pp]))
+    return kc, vc
+
+
+# ---------------------------------------------------------------------------
+# Decode attention over the arena
+# ---------------------------------------------------------------------------
+
+# A block of pages, the kernel's unit of DMA and of matmul: 512 tokens where
+# VMEM allows (on a v5e, at both Mistral-7B's and OLMoE's head layouts, 256
+# tokens a block read 46-52% of the HBM roofline and 512 read 69-80%; 1,024
+# no more, and 16 kv heads of them do not fit), and never more than 2 MiB a
+# buffer: there are four, K and V of the block computed and of the next.
+_DECODE_BLOCK_TOKENS = 512
+_DECODE_BLOCK_BYTES = 2 << 20
+
+
+def _sublanes(dtype) -> int:
+    """Rows of one packed (sublane, 128) tile of `dtype`."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def _paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
+                         sm_scale: float, groups: int, split: bool,
+                         pages_per_block: int):
+    """One grid step = one slot. Its live pages come in by DMA, a block of
+    `pages_per_block` at a time, double-buffered; the loop over blocks has a
+    DYNAMIC trip count, so a short or idle slot costs what it holds and the
+    grid does not grow with the block table. Online softmax a kv head, f32
+    statistics and accumulator."""
+    _, n_kv, T, _ = kbuf.shape
+    page = T // pages_per_block
+    max_pages = bt_ref.shape[1]
+    slot = pl.program_id(0)
+    layer = layer_ref[0]
+    length = len_ref[slot]
+    live_pages = pl.cdiv(length, page)
+    n_blocks = pl.cdiv(live_pages, pages_per_block)
+
+    @pl.when(slot == 0)
+    def _clear():
+        # A block's tail past the live pages is never fetched: what lies
+        # there is masked, and must be finite (0 x NaN is NaN). After this
+        # the buffers only ever hold zeros or real K/V.
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def each_copy(block, buf, fn):
+        for i in range(pages_per_block):
+            idx = block * pages_per_block + i
+            page_id = bt_ref[slot, jnp.minimum(idx, max_pages - 1)]
+            rows = pl.ds(i * page, page)
+
+            @pl.when(idx < live_pages)
+            def _():
+                fn(pltpu.make_async_copy(k_hbm.at[layer, page_id],
+                                         kbuf.at[buf, :, rows, :],
+                                         sem.at[0, buf]))
+                fn(pltpu.make_async_copy(v_hbm.at[layer, page_id],
+                                         vbuf.at[buf, :, rows, :],
+                                         sem.at[1, buf]))
+
+    m_ref[...] = jnp.full_like(m_ref, DEFAULT_MASK_VALUE)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        each_copy(0, 0, lambda c: c.start())
+
+    def block_body(b, carry):
+        buf = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            each_copy(b + 1, 1 - buf, lambda c: c.start())
+
+        each_copy(b, buf, lambda c: c.wait())
+        rows = q_ref.shape[2]
+        live = (b * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
+                < length)
+        upper = jax.lax.broadcasted_iota(jnp.int32, (rows, T), 0) < groups
+        for h in range(n_kv):
+            k = kbuf[buf, h]                                   # [T, hd]
+            v = vbuf[buf, h]
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [rows, T]
+            s = jnp.where(live, s, DEFAULT_MASK_VALUE)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            if split:
+                # q's rows come twice (see the wrapper): the upper copy
+                # carries p rounded to the cache's dtype, the lower what the
+                # rounding dropped, so ONE pass of V through the MXU gives
+                # p.v with p's float32 mantissa to 16 bits.
+                p = jnp.where(upper, p,
+                              p - p.astype(v.dtype).astype(jnp.float32))
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block_body, 0)
+    # An idle slot (length 0) walked nothing: l is 0 and so is its output.
+    o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _paged_decode_pallas(q, kc, vc, layer, block_table, lengths, *,
+                         sm_scale, pages_per_block, interpret):
+    ns, H, hd = q.shape
+    _, _, n_kv, page, _ = kc.shape
+    groups = H // n_kv
+    # float32 softmax weights against a narrower cache: see `split` above.
+    split = jnp.dtype(kc.dtype).itemsize < 4
+    copies = 2 if split else 1
+    tile = _sublanes(kc.dtype)
+    rows = -(-copies * groups // tile) * tile
+    qg = q.reshape(ns, n_kv, groups, hd).astype(kc.dtype)
+    qg = jnp.concatenate(
+        [qg] * copies + [jnp.zeros((ns, n_kv, rows - copies * groups, hd),
+                                   kc.dtype)], axis=2)
+    if pages_per_block is None:
+        page_bytes = n_kv * page * hd * jnp.dtype(kc.dtype).itemsize
+        pages_per_block = max(1, min(_DECODE_BLOCK_TOKENS // page,
+                                     _DECODE_BLOCK_BYTES // page_bytes))
+    pages_per_block = min(pages_per_block, block_table.shape[1])
+    T = pages_per_block * page
+    kernel = functools.partial(
+        _paged_decode_kernel, sm_scale=sm_scale, groups=groups, split=split,
+        pages_per_block=pages_per_block)
+    slot_block = pl.BlockSpec((1, n_kv, rows, hd),
+                              lambda s, *_: (s, 0, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        name="paged_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,       # layer, lengths, block table
+            grid=(ns,),
+            in_specs=[slot_block,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=slot_block,
+            scratch_shapes=[
+                pltpu.VMEM((2, n_kv, T, hd), kc.dtype),
+                pltpu.VMEM((2, n_kv, T, hd), vc.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rows, hd), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((ns, n_kv, rows, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
+      block_table.astype(jnp.int32), qg, kc, vc)
+    out = sum(out[:, :, i * groups:(i + 1) * groups] for i in range(copies))
+    return out.reshape(ns, H, hd).astype(q.dtype)
+
+
+def _paged_decode_reference(q, kc, vc, layer, block_table, lengths, *,
+                            sm_scale):
+    """The XLA path: gather every page of every slot's table out of the
+    layer, float32 softmax over the whole context under a length mask."""
+    ns, H, hd = q.shape
+    _, _, n_kv, page, _ = kc.shape
+    groups, ctx = H // n_kv, block_table.shape[1] * page
+    qg = q.reshape(ns, n_kv, groups, hd).astype(jnp.float32)
+    # [ns, max_pages, n_kv, page, hd]
+    kh = kc[layer, block_table].astype(jnp.float32)
+    vh = vc[layer, block_table].astype(jnp.float32)
+    scores = jnp.einsum("nkgd,npktd->nkgpt", qg, kh).reshape(
+        ns, n_kv, groups, ctx) * sm_scale
+    live = jnp.arange(ctx)[None, :] < lengths[:, None]          # [ns, ctx]
+    scores = jnp.where(live[:, None, None, :], scores, DEFAULT_MASK_VALUE)
+    wts = jax.nn.softmax(scores, axis=-1).reshape(
+        ns, n_kv, groups, ctx // page, page)
+    # What a dead position holds is masked out of v too: 0 x NaN is NaN.
+    vh = jnp.where(live.reshape(ns, ctx // page, 1, page, 1), vh, 0.0)
+    out = jnp.einsum("nkgpt,npktd->nkgd", wts, vh)
+    return out.reshape(ns, H, hd).astype(q.dtype)
+
+
+def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
+                           sm_scale: Optional[float] = None,
+                           pages_per_block: Optional[int] = None,
+                           interpret: bool = False):
+    """Attention of ONE query token a slot against a paged KV cache.
+
+    q [ns, H, hd]; kc, vc the WHOLE arena [L, n_pages, KVH, page, hd] and
+    `layer` the index into it (a traced scalar: a kernel handed `kc[layer]`
+    is first given a copy of that slab); block_table [ns, max_pages] of
+    physical page ids; lengths [ns], the positions each slot attends to
+    (0: an idle slot, whose output is 0). Slot s reads positions
+    0..lengths[s]-1, position t at page block_table[s, t // page], row
+    t % page. Query head h reads kv head h // (H // KVH). -> [ns, H, hd].
+
+    On a TPU (or with `interpret`, for tests on the CPU) a Pallas kernel
+    that walks only the live pages, in place; elsewhere XLA's gather of the
+    whole table. Table entries past a slot's live pages are never read by
+    the kernel; what lies past `lengths` inside the last live page is read
+    and masked, so it must be finite.
+    """
+    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    page, hd = kc.shape[3], kc.shape[4]
+    use = interpret or (attention._on_tpu() and hd % 128 == 0
+                        and page % _sublanes(kc.dtype) == 0)
+    attention._path_counts["decode_pallas" if use else "decode_reference"] += 1
+    if use:
+        return _paged_decode_pallas(
+            q, kc, vc, layer, block_table, lengths, sm_scale=scale,
+            pages_per_block=pages_per_block, interpret=interpret)
+    return _paged_decode_reference(q, kc, vc, layer, block_table, lengths,
+                                   sm_scale=scale)

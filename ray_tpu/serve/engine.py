@@ -5,27 +5,21 @@ python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_models.py:170 —
 engine_kwargs feed vLLM's continuous batcher + paged attention; here the
 engine is OURS):
 
-- **Paged KV arena** `[n_layers, n_pages, kv_heads, page, head_dim]` (a
-  page of all heads is one contiguous run, a (page, head) block a whole
-  tile) with a per-slot BLOCK TABLE `[n_slots, max_pages]` of physical
-  page ids — vLLM's block-table design: the table is a device array,
-  a decode step writes each slot's row by putting its current page back
-  with that row replaced (`kc.at[l, page]`), and decode attention
-  (`ops.attention.paged_decode_attention`) is handed the WHOLE arena, the
-  layer's index, the table and the live lengths, and reads each slot's
-  live pages where they lie. The decode program updates the arena IN
-  PLACE: it is a loop carry that nothing but those two ops touches, so
-  no copy of it (or of a layer's slab) is ever made. A 50-token request
-  holds ceil(50/page) pages, not a max_seq strip, so concurrency is
-  bounded by TOKENS in flight, not by worst-case sequences. Page 0 is
-  the NULL page: unused/overflow table entries point at it, making
-  out-of-reservation writes harmless, and attention never reads past a
-  slot's live length — no data-dependent control flow outside the kernel.
-- **Reservation admission**: a request is admitted when
-  ceil(min(len+max_tokens, max_seq)/page) free pages exist — growth can
-  then never fail mid-decode, so there is no preemption/recompute path
-  (vLLM's watermark policy, made strict). Requests queue FIFO while
-  pages are short; finishing requests return their pages.
+- **Paged KV cache**, kept behind two names. The DEVICE side is
+  `ops/paged_kv.py`: the arena's layout, the null page, and the only ops
+  on it (`empty`, `write_prompt`, `write_token`, `paged_decode_attention`).
+  The HOST side is `serve/page_pool.py::PagePool`: which physical pages
+  each slot holds, the reservation rule, and the block table
+  `[n_slots, max_pages]` a decode chunk is handed (vLLM's block-table
+  design). This module lays nothing out and does no page arithmetic. What
+  it owes the cache: the decode program updates the arena IN PLACE, as a
+  loop carry that nothing but those ops touches, so no copy of it (or of a
+  layer's slab) is ever made (see `_step`).
+- **Reservation admission**: a request is admitted when the pages
+  `PagePool.pages_for` says it can ever need are free: growth can then
+  never fail mid-decode, so there is no preemption/recompute path.
+  Requests queue FIFO while pages are short; finishing requests return
+  their pages.
 - **Sync-free dispatch loop + emitter thread**: the engine loop ONLY
   dispatches device work (prefills, decode chunks, slot pokes) — every
   host<->device sync (fetching first tokens and chunk outputs) happens
@@ -39,8 +33,10 @@ engine is OURS):
 
 A small fixed set of compiled programs serves all traffic: one prefill
 per power-of-2 BUCKET width (a short prompt pays a short prefill — the
-TTFT lever; smallest and largest warmed at startup, others on first use)
-and the n-step decode chunk over all slots.
+TTFT lever; smallest and largest warmed before the loop starts, the rest on
+a background thread, and until a width is warm a prompt rounds UP to the
+next one that is: nothing compiles inside the loop), its `adopt` twin for a
+PD handoff, the n-step decode chunk over all slots, and the slot poke.
 """
 
 from __future__ import annotations
@@ -52,6 +48,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from ray_tpu.serve.page_pool import PagePool
 from ray_tpu.utils import get_logger, tracing
 
 logger = get_logger("serve.engine")
@@ -162,14 +159,15 @@ def _sample_tokens(logits, temp, topk, keys, pos, cap=TOPK_CAP):
 
 
 def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
-    """Build (prefill_jit, decode_jit, adopt_jit, empty_caches)."""
+    """Build (prefill_jit, decode_jit, adopt_jit, poke_jit, empty_caches)."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models.block import (attention_inputs, expert_stacks,
                                       expert_stats, feed_forward)
-    from ray_tpu.ops.attention import paged_decode_attention
     from ray_tpu.ops.norms import rms_norm, rope_frequencies
+    from ray_tpu.ops.paged_kv import (empty, paged_decode_attention,
+                                      write_prompt, write_token)
 
     sparse = mcfg.n_experts > 0
     S = mcfg.max_seq
@@ -177,25 +175,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     dt = mcfg.dtype
     ns = n_slots
 
-    def empty_caches():
-        shape = (mcfg.n_layers, n_pages, KVH, page, hd)
-        return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
-
-    def _write_pages(kc, vc, pages, ks, vs):
-        """Scatter prefilled [L, W, KVH, hd] k/v into physical pages.
-        W is static (one program per bucket width); `pages[:wp]` entries
-        of 0 route padding into the null page."""
-        L, W = ks.shape[0], ks.shape[1]
-        wp = -(-W // page)
-        pad = wp * page - W
-        with jax.named_scope("kv_write"):
-            ksp = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            vsp = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            ksp = ksp.reshape(L, wp, page, KVH, hd).transpose(0, 1, 3, 2, 4)
-            vsp = vsp.reshape(L, wp, page, KVH, hd).transpose(0, 1, 3, 2, 4)
-            kc = kc.at[:, pages[:wp]].set(ksp)
-            vc = vc.at[:, pages[:wp]].set(vsp)
-        return kc, vc
+    empty_caches = functools.partial(empty, mcfg.n_layers, n_pages, KVH, page,
+                                     hd, dt)
 
     # ------------------------------------------------------------------
     # prefill: full causal pass over ONE padded prompt, k/v -> pages
@@ -209,7 +190,7 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         the slot's pages, returns the first generated token (sampled,
         or greedy when temp == 0) and the core's `experts`."""
         _, ks, vs, logits_row, experts = _core(params, tokens, length)
-        kc, vc = _write_pages(kc, vc, pages, ks, vs)
+        kc, vc = write_prompt(kc, vc, pages, ks, vs)
         first = _sample_tokens(logits_row[None],
                                jnp.asarray(temp)[None],
                                jnp.asarray(topk)[None], key[None],
@@ -219,7 +200,7 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     def adopt(kc, vc, pages, ks, vs):
         """Write externally-prefilled k/v (a PrefillServer handoff) into
         the slot's pages."""
-        return _write_pages(kc, vc, pages, ks, vs)
+        return write_prompt(kc, vc, pages, ks, vs)
 
     # ------------------------------------------------------------------
     # decode: one token for every active slot per step, `chunk` steps
@@ -231,35 +212,17 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         return out.astype(x.dtype)
 
     def _decode_layer(x, kc, vc, lp, l, bt, pos, act, cos, sin):
-        # x [ns, D]; kc/vc the WHOLE arena [L, n_pages, KVH, page, hd];
-        # l this layer's index (traced scalar); bt [ns, maxp]; a sparse
-        # model's expert weights in lp are all the layers' (`expert_stacks`)
+        # x [ns, D]; kc/vc the WHOLE arena (`ops.paged_kv`); l this layer's
+        # index (traced scalar); bt the block table; a sparse model's expert
+        # weights in lp are all the layers' (`expert_stacks`)
         with jax.named_scope("rope"):
             w = jnp.minimum(pos, S - 1)
             c = cos[w][:, None]
             s = sin[w][:, None]
         q, k, v = attention_inputs(lp, x, mcfg,
                                    lambda t: _rope_one(t, c, s))
-        # Put k/v at each slot's (layer, page, offset), straight into the
-        # arena, no layer slab cut out or put back: the slot's page is read,
-        # its row replaced, and the page scattered back. A scatter of the
-        # `ns` rows alone would be less to move, but its window (every kv
-        # head's row `off`) is strided in this layout, and XLA then lays the
-        # WHOLE arena out the other way round and copies it to and from the
-        # attention kernel every layer (AOT for v5e, PR 28); whole pages
-        # are the layout's own unit.
-        # Inactive slots (and positions past a slot's reservation) route
-        # to the NULL page 0, which attention never reads — the write
-        # stays a fixed-shape scatter with no data-dependent branches.
-        with jax.named_scope("kv_write"):
-            idx = jnp.arange(ns)
-            pp = jnp.where(act, bt[idx, w // page], 0)
-            off = jnp.where(act, w % page, 0)
-            here = (jnp.arange(page) == off[:, None])[:, None, :, None]
-            kc = kc.at[l, pp].set(jnp.where(here, k[:, :, None], kc[l, pp]))
-            vc = vc.at[l, pp].set(jnp.where(here, v[:, :, None], vc[l, pp]))
-        # Each active slot's query against its positions 0..w, read from
-        # the arena's pages in place (grouped heads and all); an idle slot
+        kc, vc = write_token(kc, vc, l, bt, w, act, k, v)
+        # Each active slot's query against its positions 0..w; an idle slot
         # reads nothing.
         with jax.named_scope("attn"):
             attn = paged_decode_attention(
@@ -409,23 +372,12 @@ class Engine:
         self.n_slots = n_slots
         self.chunk = decode_chunk
         self.params = self._experts_in_compute_dtype(params, mcfg)
-        S = mcfg.max_seq
-        self.page = min(page_size, S)
-        self.maxp = -(-S // self.page)
-        if n_pages is None:
-            # Null page + half the worst case: density comes from short
-            # requests reserving only what len+max_tokens needs.
-            n_pages = 1 + max(self.maxp, (n_slots * self.maxp + 1) // 2)
-        if n_pages < 1 + self.maxp:
-            raise ValueError(
-                f"n_pages={n_pages} cannot hold one max_seq request "
-                f"({self.maxp} pages of {self.page} tokens) + null page")
-        self.n_pages = n_pages
+        self.pool = PagePool(n_slots, mcfg.max_seq, page_size, n_pages)
+        self.n_pages = self.pool.n_pages
         (self._prefill, self._decode, self._adopt, self._poke,
-         empty) = _build_fns(mcfg, n_slots, decode_chunk, self.page,
-                             n_pages)
-        self._empty = empty
-        self._kc, self._vc = empty()
+         self._empty) = _build_fns(mcfg, n_slots, decode_chunk,
+                                   self.pool.page, self.n_pages)
+        self._kc, self._vc = self._empty()
         # Prefill shape buckets (powers of 2, capped at max_seq): a
         # 50-token prompt prefills 64 wide, not max_seq wide — the TTFT
         # lever the reference gets from vLLM's chunked prefill.
@@ -435,12 +387,9 @@ class Engine:
             self.buckets.append(b)
             b *= 2
         self.buckets.append(mcfg.max_seq)
-        # host-side slot + page state (control flow is host-predicted;
-        # only token VALUES come back from the device)
+        # host-side slot state (control flow is host-predicted; only token
+        # VALUES come back from the device)
         self._slot_req: List[Optional[_Request]] = [None] * n_slots
-        self._slot_pages: List[List[int]] = [[] for _ in range(n_slots)]
-        self._free: List[int] = list(range(n_pages - 1, 0, -1))
-        self._bt = np.zeros((n_slots, self.maxp), np.int32)
         self._pos = np.zeros(n_slots, np.int32)
         self._active = np.zeros(n_slots, bool)
         # Per-slot sampling state (temp 0 = greedy; key seeded per
@@ -497,7 +446,8 @@ class Engine:
                                   width=n_slots):
             self._kc, self._vc, self._last_d, self._pos_d, out, _ = \
                 self._decode(
-                    self.params, self._kc, self._vc, jnp.asarray(self._bt),
+                    self.params, self._kc, self._vc,
+                    jnp.asarray(self.pool.block_table),
                     self._last_d, self._pos_d, jnp.zeros(n_slots, bool),
                     jnp.asarray(self._temp), jnp.asarray(self._topk),
                     jnp.asarray(self._skeys))
@@ -537,7 +487,7 @@ class Engine:
         width, writing to the null page of the arena given (pages = zeros:
         never real KV state). Returns (kc, vc, first token on the device)."""
         jnp, m = self._jnp, self.mcfg
-        null_pages = jnp.zeros(self.maxp, jnp.int32)
+        null_pages = jnp.zeros(self.pool.maxp, jnp.int32)
         with tracing.compile_span("serve.engine.warm", program="prefill",
                                   width=width):
             kc, vc, first, _ = self._prefill(
@@ -591,7 +541,7 @@ class Engine:
         return self._prefill.lower(
             jax.tree.map(shape_of, self.params), shape_of(self._kc),
             shape_of(self._vc),
-            jax.ShapeDtypeStruct((self.maxp,), jnp.int32),
+            jax.ShapeDtypeStruct((self.pool.maxp,), jnp.int32),
             jax.ShapeDtypeStruct((1, width), jnp.int32), 1, 0.0, 0,
             jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
 
@@ -702,7 +652,7 @@ class Engine:
             self._warm_thread.join(timeout=60)
 
     def pages_in_use(self) -> int:
-        return (self.n_pages - 1) - len(self._free)
+        return self.pool.in_use()
 
     # ------------------------------------------------------------------
     def _admit(self) -> None:
@@ -715,7 +665,6 @@ class Engine:
         Prefills for a BURST of admissions are all dispatched (and their
         first-token transfers started) before anything blocks, so N
         admissions cost ~one round-trip, not N."""
-        S = self.mcfg.max_seq
         emits: List[Tuple] = []  # (req, first, done, experts)
         while True:
             with self._plock:
@@ -725,8 +674,8 @@ class Engine:
             slot = next((i for i in range(self.n_slots)
                          if not self._active[i]
                          and self._slot_req[i] is None), None)
-            need = -(-min(len(req.ids) + req.max_tokens, S) // self.page)
-            if slot is None or len(self._free) < need:
+            need = self.pool.pages_for(len(req.ids), req.max_tokens)
+            if slot is None or self.pool.free < need:
                 break  # head-of-line waits for a finish
 
             with self._plock:
@@ -749,7 +698,7 @@ class Engine:
                     kind="adopt" if adopting else "prefill",
                     prompt_tokens=len(req.ids), bucket=bucket,
                     queue_wait_us=int(waited * 1e6), pending=left,
-                    pages_free=len(self._free) - need):
+                    pages_free=self.pool.free - need):
                 emits.append(self._place(req, slot, need, bucket))
         # Start EVERY device->host copy first (async), THEN enqueue: a
         # burst overlaps all its transfers even when the bounded
@@ -771,15 +720,9 @@ class Engine:
         (req, first token, finished already, the prefill's `experts`)."""
         np, jnp = self._np, self._jnp
         S = self.mcfg.max_seq
-        pages = [self._free.pop() for _ in range(need)]
-        self._slot_pages[slot] = pages
+        pages_arr = jnp.asarray(self.pool.grant(slot, need))
         self.peak_pages_used = max(self.peak_pages_used,
-                                   self.pages_in_use())
-        self._bt[slot, :] = 0
-        self._bt[slot, :need] = pages
-        pages_arr = np.zeros(self.maxp, np.int32)
-        pages_arr[:need] = pages
-        pages_arr = jnp.asarray(pages_arr)
+                                   self.pool.in_use())
         if req.adopt_kv is not None:
             # Disaggregated handoff: write the external KV into the
             # slot's pages; `first` was already streamed by the prefill
@@ -834,9 +777,7 @@ class Engine:
         slot's final tokens)."""
         self._slot_req[slot] = None
         self._active[slot] = False
-        self._free.extend(self._slot_pages[slot])
-        self._slot_pages[slot] = []
-        self._bt[slot, :] = 0
+        self.pool.release(slot)
         self._temp[slot] = 0.0
         self._topk[slot] = 0
 
@@ -957,10 +898,10 @@ class Engine:
                 self._wake.clear()
                 continue
             # COPIES, not views: jnp.asarray may alias numpy memory
-            # (zero-copy on the CPU backend), and this loop mutates
-            # _bt/_active in place while the dispatched chunk is still
-            # queued — an aliased buffer would let those mutations reach
-            # into the in-flight computation.
+            # (zero-copy on the CPU backend), and this loop mutates the
+            # block table and _active in place while the dispatched chunk
+            # is still queued — an aliased buffer would let those mutations
+            # reach into the in-flight computation.
             useful = sum(take for _, _, take, _ in plan)
             live_kv = int(self._pos[self._active].sum())
             self.decode_chunks += 1
@@ -980,8 +921,8 @@ class Engine:
                 (self._kc, self._vc, self._last_d, self._pos_d, out_d,
                  experts_d) = \
                     self._decode(self.params, self._kc, self._vc,
-                                 jnp.asarray(self._bt.copy()), self._last_d,
-                                 self._pos_d,
+                                 jnp.asarray(self.pool.block_table.copy()),
+                                 self._last_d, self._pos_d,
                                  jnp.asarray(self._active.copy()),
                                  jnp.asarray(self._temp.copy()),
                                  jnp.asarray(self._topk.copy()),

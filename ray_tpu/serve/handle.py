@@ -47,12 +47,13 @@ class DeploymentResponse:
         return self._ref
 
 
-# ONE pubsub subscription per CORE WORKER invalidates every live router
-# (weakly referenced, so handles still GC); per-router subscriptions
-# would leak a perpetual poll loop per handle. Keyed by the worker, not
-# a process-lifetime boolean: a shutdown + re-init gets a fresh
-# subscription on the new worker's loop.
-_routers: "Any" = None
+# ONE pubsub subscription per CORE WORKER invalidates every live router,
+# and every ingress route table (`routing.RouteTable`): whatever registers
+# here has an `_invalidate()`. They are weakly referenced, so handles still
+# GC; per-router subscriptions would leak a perpetual poll loop per handle.
+# Keyed by the worker, not a process-lifetime boolean: a shutdown + re-init
+# gets a fresh subscription on the new worker's loop.
+_invalidated: "Any" = None
 _sub_cw: "Any" = None  # weakref to the core worker currently subscribed
 
 
@@ -63,13 +64,13 @@ def _ttl_warning() -> None:
         "to the %ss table TTL", Router._TABLE_TTL_S)
 
 
-def _register_router(router: "Router") -> None:
-    global _routers, _sub_cw
+def _invalidate_on_serve_events(obj: "Any") -> None:
+    global _invalidated, _sub_cw
     import weakref
 
-    if _routers is None:
-        _routers = weakref.WeakSet()
-    _routers.add(router)
+    if _invalidated is None:
+        _invalidated = weakref.WeakSet()
+    _invalidated.add(obj)
     try:
         from ray_tpu.core.pubsub import Subscription
         from ray_tpu.core.ref import get_core_worker
@@ -81,8 +82,8 @@ def _register_router(router: "Router") -> None:
         return  # this worker already runs the subscription
 
     def _invalidate(_event):
-        for r in list(_routers):
-            r._checked = 0.0  # next choose re-reads the table
+        for r in list(_invalidated):
+            r._invalidate()
 
     async def _start():
         global _sub_cw
@@ -123,7 +124,10 @@ class Router:
         # (reference: serve/multiplex.py routes to replicas holding the
         # model; ours is client-side stickiness with pow-2 fallback).
         self._model_affinity: Dict[str, bytes] = {}
-        _register_router(self)
+        _invalidate_on_serve_events(self)
+
+    def _invalidate(self) -> None:
+        self._checked = 0.0  # next choose re-reads the table
 
     def _refresh(self, force: bool = False) -> None:
         now = time.monotonic()

@@ -4,9 +4,10 @@ Analogue of the reference's LLM layer (reference: python/ray/llm/ —
 _internal/serve/deployments/llm/ wraps an engine as Serve deployments with
 an OpenAI-compatible router, TP/PP sizes placed via PGs). TPU-native:
 the engine IS this framework's Llama; decode runs in jitted device-side
-chunks (one host sync per chunk — see bench_serve.py for the latency
-math); replicas are serve deployments with num_tpus, streamed over the
-proxy's chunked HTTP path.
+chunks (one host sync per chunk, on the engine's emitter thread; PERF.md
+§3 has the latency metrics and where each is measured); replicas are
+serve deployments with num_tpus, streamed over the proxy's chunked HTTP
+path.
 
 Tokenization is bring-your-own (`LLMConfig.tokenizer` /`detokenizer`
 callables); the default passes token-id lists through untouched — there

@@ -11,13 +11,17 @@ import time
 from typing import Dict, Optional
 
 import ray_tpu
-from ray_tpu.serve.handle import DeploymentHandle
+from ray_tpu.serve.handle import (DeploymentHandle,
+                                  _invalidate_on_serve_events)
 
 
 class RouteTable:
     """route_prefix -> deployment resolution + handle cache. Refreshes
     are rate-limited (negative cache) so unknown-path probes can't
-    hammer the controller.
+    hammer the controller; a deploy or a delete, pushed by the controller
+    on the same "serve_events" channel that invalidates the routers, ends
+    the negative cache at once, so a route is reachable as soon as its
+    `serve.run` returns, whatever was probed just before.
 
     Shared across the HTTP proxy's and gRPC proxy's thread pools: the
     refresh claim and the handle cache are lock-guarded (the routes dict
@@ -31,6 +35,10 @@ class RouteTable:
         self._handles: Dict[str, DeploymentHandle] = {}
         self._last_refresh = 0.0
         self._lock = threading.Lock()
+        _invalidate_on_serve_events(self)
+
+    def _invalidate(self) -> None:
+        self._last_refresh = 0.0  # the next unknown path re-reads the table
 
     @property
     def routes(self) -> Dict[str, str]:

@@ -101,8 +101,8 @@ def kernel_in_interpret_mode(monkeypatch):
     no switch for the tests' sake."""
     import functools
 
-    from ray_tpu.ops import attention
+    from ray_tpu.ops import paged_kv
 
     monkeypatch.setattr(
-        attention, "paged_decode_attention",
-        functools.partial(attention.paged_decode_attention, interpret=True))
+        paged_kv, "paged_decode_attention",
+        functools.partial(paged_kv.paged_decode_attention, interpret=True))

@@ -9,10 +9,10 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import (attention_path_counts, attention_reference,
-                                   flash_attention, paged_decode_attention,
-                                   repeat_kv)
+                                   flash_attention, repeat_kv)
 from ray_tpu.ops.moe import moe_ffn, top_k_routing
 from ray_tpu.ops.norms import apply_rope, rms_norm, rope_frequencies
+from ray_tpu.ops.paged_kv import paged_decode_attention
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel import MeshConfig, build_mesh
 

@@ -1,0 +1,90 @@
+"""`ray_tpu/ops/paged_kv.py` alone, no engine around it: the layout's three
+writers and its reader against plain attention. (The kernel's own cases, page
+boundaries and dtypes, are in tests/test_ops.py, which tier-1 leaves out.)"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import paged_kv
+from ray_tpu.ops.attention import attention_reference, repeat_kv
+from ray_tpu.ops.paged_kv import paged_decode_attention
+
+
+class TestPagedKvLayout:
+    """`ops/paged_kv.py` alone, no engine around it: what `write_prompt` and
+    `write_token` put into the arena is what `paged_decode_attention` reads
+    back, and it equals plain attention over the same keys and values."""
+
+    PAGE, MAXP, HD, LAYERS, LAYER = 8, 4, 128, 2, 1
+
+    @pytest.mark.parametrize("path", ["reference", "kernel"])
+    @pytest.mark.parametrize("heads", [(8, 2), (4, 4)], ids=["gqa4", "mha"])
+    def test_prompt_then_tokens_then_attention(self, heads, path):
+        H, KVH = heads
+        page, maxp, hd = self.PAGE, self.MAXP, self.HD
+        rng = np.random.default_rng(0)
+        # slot 0: a prompt that ends inside its second page, then decode
+        # steps across the boundary into the third; slot 1: from nothing,
+        # inside one page; slot 2: idle throughout.
+        prompt, steps, ns = page + 5, 5, 3
+        bt = np.zeros((ns, maxp), np.int32)
+        bt[0, :3] = (7, 2, 5)
+        bt[1, :1] = (4,)
+        kc, vc = paged_kv.empty(self.LAYERS, 9, KVH, page, hd, jnp.float32)
+        assert kc.shape == vc.shape == (self.LAYERS, 9, KVH, page, hd)
+        # the prompt, padded to a bucket of 16 (W), every layer of it
+        ks = rng.standard_normal((self.LAYERS, 16, KVH, hd)).astype("f4")
+        vs = rng.standard_normal((self.LAYERS, 16, KVH, hd)).astype("f4")
+        ks[:, prompt:] = vs[:, prompt:] = 0.0
+        kc, vc = jax.jit(paged_kv.write_prompt)(
+            kc, vc, jnp.asarray(bt[0]), jnp.asarray(ks), jnp.asarray(vs))
+        hist_k = [list(ks[self.LAYER, :prompt]), [], []]
+        hist_v = [list(vs[self.LAYER, :prompt]), [], []]
+        active = np.array([True, True, False])
+        write = jax.jit(paged_kv.write_token)
+        attend = jax.jit(functools.partial(
+            paged_decode_attention, interpret=path == "kernel"))
+        for step in range(steps):
+            w = np.array([prompt + step, step, 3], np.int32)
+            k = rng.standard_normal((ns, KVH, hd)).astype("f4")
+            v = rng.standard_normal((ns, KVH, hd)).astype("f4")
+            null_before = (np.asarray(kc[:, 0]), np.asarray(vc[:, 0]))
+            held_before = np.asarray(kc[:, [1, 3, 6, 8]])
+            kc, vc = write(kc, vc, jnp.int32(self.LAYER), jnp.asarray(bt),
+                           jnp.asarray(w), jnp.asarray(active),
+                           jnp.asarray(k), jnp.asarray(v))
+            # the idle slot wrote its row to the null page, this layer, and
+            # nothing else changed there; pages no table names are untouched
+            want_k, want_v = (x.copy() for x in null_before)
+            want_k[self.LAYER, :, 0], want_v[self.LAYER, :, 0] = k[2], v[2]
+            np.testing.assert_array_equal(np.asarray(kc[:, 0]), want_k)
+            np.testing.assert_array_equal(np.asarray(vc[:, 0]), want_v)
+            np.testing.assert_array_equal(np.asarray(kc[:, [1, 3, 6, 8]]),
+                                          held_before)
+            for s in (0, 1):
+                hist_k[s].append(k[s])
+                hist_v[s].append(v[s])
+            q = rng.standard_normal((ns, H, hd)).astype("f4")
+            out = np.asarray(attend(
+                jnp.asarray(q), kc, vc, jnp.int32(self.LAYER),
+                jnp.asarray(bt), jnp.asarray(np.where(active, w + 1, 0))))
+            assert (out[2] == 0).all()
+            for s in (0, 1):
+                # [1, H, 1, hd] against [1, H, n, hd], the last position
+                kk = repeat_kv(jnp.asarray(np.stack(hist_k[s]))
+                               .transpose(1, 0, 2)[None], H // KVH)
+                vv = repeat_kv(jnp.asarray(np.stack(hist_v[s]))
+                               .transpose(1, 0, 2)[None], H // KVH)
+                want = attention_reference(
+                    jnp.asarray(q[s])[None, :, None], kk, vv, causal=False)
+                np.testing.assert_allclose(out[s], np.asarray(want[0, :, 0]),
+                                           atol=2e-5)
+        # the prompt's padding went to the null page or the tail of its own
+        # last page, never to a page of another slot
+        np.testing.assert_array_equal(
+            np.asarray(kc[self.LAYER, 4, :, steps:]), 0.0)
+

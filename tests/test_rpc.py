@@ -98,3 +98,32 @@ def test_chaos_injection_retries_through():
         await srv.stop()
 
     run(main())
+
+
+def test_stop_drops_established_connections_and_returns():
+    """A peer that keeps its socket open must not hold `stop()`: since
+    Python 3.12 `Server.wait_closed()` waits for every connection, so the
+    established ones are dropped first. The peer sees the loss: its call
+    in flight fails instead of waiting for a reply that cannot come."""
+
+    class Svc:
+        async def ping(self):
+            return "pong"
+
+        async def park(self):
+            await asyncio.sleep(60)
+
+    async def main():
+        srv = RpcServer("t")
+        srv.register_object(Svc())
+        port = await srv.start_tcp("127.0.0.1", 0)
+        client = RpcClient(("127.0.0.1", port), timeout=30.0, max_retries=0)
+        assert await client.call("ping") == "pong"   # connection is up
+        parked = asyncio.ensure_future(client.call("park"))
+        await asyncio.sleep(0.1)
+        await asyncio.wait_for(srv.stop(), 5.0)
+        with pytest.raises(Exception):
+            await asyncio.wait_for(parked, 5.0)
+        await client.close()
+
+    run(main())

@@ -185,7 +185,8 @@ def test_scope_names_are_in_the_lowered_program(engine, program):
         if program == "decode":
             n = e.n_slots
             lowered = e._decode.lower(
-                params, *arenas, jax.ShapeDtypeStruct((n, e.maxp), jnp.int32),
+                params, *arenas,
+                jax.ShapeDtypeStruct((n, e.pool.maxp), jnp.int32),
                 jax.ShapeDtypeStruct((n,), jnp.int32),
                 jax.ShapeDtypeStruct((n,), jnp.int32),
                 jax.ShapeDtypeStruct((n,), jnp.bool_),
@@ -195,7 +196,8 @@ def test_scope_names_are_in_the_lowered_program(engine, program):
             wanted = SCOPES_DECODE
         else:
             lowered = e._prefill.lower(
-                params, *arenas, jax.ShapeDtypeStruct((e.maxp,), jnp.int32),
+                params, *arenas,
+                jax.ShapeDtypeStruct((e.pool.maxp,), jnp.int32),
                 jax.ShapeDtypeStruct((1, 32), jnp.int32), 1, 0.0, 0,
                 jax.ShapeDtypeStruct((2,), jnp.uint32))
             wanted = SCOPES_PREFILL
