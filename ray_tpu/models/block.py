@@ -5,6 +5,10 @@ engine's prefill and its decode step (`serve/engine.py`) all call them.
   attention_inputs   attn_norm -> q, k, v -> (q/k norm) -> heads -> RoPE
   feed_forward       mlp_norm -> dense SwiGLU, or router + experts
 
+and the two ways a program that runs no gradient (serving) holds its layer
+stacks differently from training, each so that the compiler reads a layer's
+weights where they lie: `fuse_qkv` / `split_qkv` and `expert_stacks`.
+
 Between them sits the attention itself, which stays with its caller: flash
 or ring attention over the whole sequence, or a scatter into and a gather out
 of the paged arena.
@@ -18,7 +22,7 @@ trace is reduced by (benchmark/program_trace.py); the sparse half's own
 
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +38,8 @@ def attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
     (`[slots, heads, hd]` for a decode step), q and k rotated by `rope`,
     which is handed a tensor in that layout. With `cfg.qk_norm`, q and k are
     RMS-normalised over their WHOLE projection, all heads together, before
-    the split into heads (OLMoE)."""
+    the split into heads (OLMoE). `lp` holds the projection as training does,
+    `wq`, `wk`, `wv`, or as serving does, one `wqkv` (`fuse_qkv`)."""
     lead = x.shape[:-1]
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
@@ -46,9 +51,13 @@ def attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
     with jax.named_scope("attn_norm"):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     with jax.named_scope("qkv"):
-        q = h @ lp["wq"].astype(dt)
-        k = h @ lp["wk"].astype(dt)
-        v = h @ lp["wv"].astype(dt)
+        if "wqkv" in lp:    # serving (`fuse_qkv`): one matmul, its columns
+            q, k, v = jnp.split(h @ lp["wqkv"].astype(dt), _qkv_ends(cfg),
+                                axis=-1)
+        else:               # training: a matrix each
+            q = h @ lp["wq"].astype(dt)
+            k = h @ lp["wk"].astype(dt)
+            v = h @ lp["wv"].astype(dt)
         if cfg.qk_norm:
             with jax.named_scope("qk_norm"):
                 q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
@@ -86,17 +95,59 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
         return x + (jax.nn.silu(gate) * up) @ lp["w_down"].astype(dt), None
 
 
+_QKV = ("wq", "wk", "wv")
+
+
+def _qkv_ends(cfg) -> List[int]:
+    """The columns of the fused projection at which q's end and k's end."""
+    H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    return [H * hd, (H + KVH) * hd]
+
+
+def fuse_qkv(params: Dict[str, Any]) -> Dict[str, Any]:
+    """A model's parameters as the serving programs take them: the layers'
+    `wq`, `wk`, `wv` `[L, d_model, n * hd]` joined along their columns into ONE
+    stack `wqkv` `[L, d_model, (H + 2 KVH) * hd]`, once, when a server is
+    built. Handed three stacks, the TPU compiler lays each out for its own
+    matmul, copies all three at every program's entry, slices a layer's
+    matrices out as copies, and keeps the whole `wk` stack moving in and out
+    of fast memory every layer of every decode step (a quarter of a Mistral
+    decode step; PERF.md, PR 30). One stack it reads a layer at a time, in
+    place, inside the matmul, as it reads the MLP's. The columns are the
+    three matmuls' columns, so q, k and v are what they were, to the order in
+    which a row is summed. Training keeps a matrix each (`attention_inputs`):
+    its gradient, optimizer state, checkpoints and `tp` sharding are by
+    matrix. The result holds no reference to the three stacks."""
+    layers = dict(params["layers"])
+    layers["wqkv"] = jnp.concatenate([layers.pop(k) for k in _QKV], axis=-1)
+    return dict(params, layers=layers)
+
+
+def split_qkv(params: Dict[str, Any], cfg) -> Dict[str, Any]:
+    """`fuse_qkv` undone: the tree a checkpoint, the train step or a plain
+    reference reads, its `wq`, `wk`, `wv` cut from the fused stack anew at
+    every call (bit for bit what was fused), so nothing holds a second copy
+    of the projections longer than its caller does."""
+    layers = dict(params["layers"])
+    parts = jnp.split(layers.pop("wqkv"), _qkv_ends(cfg), axis=-1)
+    return dict(params, layers=dict(layers, **dict(zip(_QKV, parts))))
+
+
 def expert_stacks(layers: Dict[str, jax.Array], cfg
                   ) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
-    """A model's stacked layer parameters, split for a scan over the layers
-    that runs no gradient (serving): (what the scan slices a layer at a time,
-    the experts' stacks its body reads whole and hands `feed_forward` with
-    the layer's index; empty for a dense model). The stacks come back in the
-    compute dtype, cast here, once and outside the scan: nothing where they
-    are stored in it (the `Engine` sees to that when it is built), a copy of
-    all the experts a call where they are not. Training keeps the slice
+    """A model's stacked layer parameters in the serving layout (`fuse_qkv`;
+    the published one is refused: serving has one projection path), split
+    for a scan over the layers that runs no gradient: (what the scan slices a
+    layer at a time, the experts' stacks its body reads whole and hands
+    `feed_forward` with the layer's index; empty for a dense model). The
+    stacks come back in the compute dtype, cast here, once and outside the
+    scan: nothing where they are stored in it (the `Engine` sees to that when
+    it is built), a copy of all the experts a call where they are not. Training keeps the slice
     (`llama._layer_fwd`): a gradient through the stack would be written whole
     once a layer."""
+    if "wqkv" not in layers:
+        raise ValueError("a serving program takes `fuse_qkv(params)`: one "
+                         "q/k/v projection stack, not a matrix each")
     names = ("w_gate", "w_up", "w_down") if cfg.n_experts > 0 else ()
     return ({k: v for k, v in layers.items() if k not in names},
             {k: layers[k].astype(cfg.dtype) for k in names})

@@ -362,16 +362,30 @@ class Engine:
     def __init__(self, params, mcfg, *, n_slots: int = 8,
                  decode_chunk: int = 8, page_size: int = 64,
                  n_pages: Optional[int] = None):
+        """`params` is the published tree, on the device; its `wq`, `wk` and
+        `wv` stacks are consumed (see below), the rest is shared."""
         import jax
         import jax.numpy as jnp
         import numpy as np
+
+        from ray_tpu.models.block import fuse_qkv
 
         self._np = np
         self._jnp = jnp
         self.mcfg = mcfg
         self.n_slots = n_slots
         self.chunk = decode_chunk
-        self.params = self._experts_in_compute_dtype(params, mcfg)
+        # The weights as the programs read them: one q/k/v stack, the experts
+        # in the compute dtype, made once, here. The caller's three projection
+        # stacks are TAKEN OVER, as a donated argument is: deleted once fused,
+        # whoever holds them (`self.params` gives them back). A caller still
+        # holds its tree while this constructor warms up, and may still when
+        # the warm-up thread allocates its scratch arena: the projections
+        # twice over are 0.6 GB at 12 Mistral layers, 0.2 on OLMoE, whose
+        # warm-up peaks within 0.9 GB of the chip's memory (PERF.md, §4).
+        self._params = fuse_qkv(self._experts_in_compute_dtype(params, mcfg))
+        for name in set(params["layers"]) - set(self._params["layers"]):
+            params["layers"][name].delete()
         self.pool = PagePool(n_slots, mcfg.max_seq, page_size, n_pages)
         self.n_pages = self.pool.n_pages
         (self._prefill, self._decode, self._adopt, self._poke,
@@ -446,7 +460,7 @@ class Engine:
                                   width=n_slots):
             self._kc, self._vc, self._last_d, self._pos_d, out, _ = \
                 self._decode(
-                    self.params, self._kc, self._vc,
+                    self._params, self._kc, self._vc,
                     jnp.asarray(self.pool.block_table),
                     self._last_d, self._pos_d, jnp.zeros(n_slots, bool),
                     jnp.asarray(self._temp), jnp.asarray(self._topk),
@@ -491,7 +505,7 @@ class Engine:
         with tracing.compile_span("serve.engine.warm", program="prefill",
                                   width=width):
             kc, vc, first, _ = self._prefill(
-                self.params, kc, vc, null_pages,
+                self._params, kc, vc, null_pages,
                 jnp.zeros((1, width), jnp.int32), 1, 0.0, 0,
                 jnp.zeros(2, jnp.uint32))
         # The PD adopt program for this width too (a first cross-pool
@@ -539,13 +553,23 @@ class Engine:
             return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
         return self._prefill.lower(
-            jax.tree.map(shape_of, self.params), shape_of(self._kc),
+            jax.tree.map(shape_of, self._params), shape_of(self._kc),
             shape_of(self._vc),
             jax.ShapeDtypeStruct((self.pool.maxp,), jnp.int32),
             jax.ShapeDtypeStruct((1, width), jnp.int32), 1, 0.0, 0,
             jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
 
     # ------------------------------------------------------------------
+    @property
+    def params(self):
+        """The model's parameters as they are published (`wq`, `wk`, `wv` a
+        matrix each: what a checkpoint or a plain reference reads), split
+        from the engine's fused stack at every call: the engine holds no
+        second copy of the projections, and the caller holds this one only
+        as long as it keeps it."""
+        from ray_tpu.models.block import split_qkv
+        return split_qkv(self._params, self.mcfg)
+
     @staticmethod
     def _experts_in_compute_dtype(params, mcfg):
         """A sparse model's expert stacks are read whole by every layer of
@@ -747,7 +771,7 @@ class Engine:
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :len(req.ids)] = req.ids
             self._kc, self._vc, first, experts = self._prefill(
-                self.params, self._kc, self._vc, pages_arr,
+                self._params, self._kc, self._vc, pages_arr,
                 jnp.asarray(toks), len(req.ids),
                 float(req.temperature), int(req.top_k),
                 jnp.asarray(_seed_key(req.seed)))
@@ -920,7 +944,7 @@ class Engine:
                               **routed):
                 (self._kc, self._vc, self._last_d, self._pos_d, out_d,
                  experts_d) = \
-                    self._decode(self.params, self._kc, self._vc,
+                    self._decode(self._params, self._kc, self._vc,
                                  jnp.asarray(self.pool.block_table.copy()),
                                  self._last_d, self._pos_d,
                                  jnp.asarray(self._active.copy()),

@@ -232,11 +232,14 @@ class PrefillServer:
         import jax
         import jax.numpy as jnp
 
+        from ray_tpu.models.block import fuse_qkv
         from ray_tpu.serve.engine import Engine, _make_prefill_core
 
         cfg: LLMConfig = cloudpickle.loads(cfg_blob)
         self.cfg = cfg
-        self.mcfg, self.params = _model_from_cfg(cfg)
+        self.mcfg, params = _model_from_cfg(cfg)
+        # The layout the shared prefill core reads, as in the engine.
+        self.params = fuse_qkv(params)
         self._core = jax.jit(_make_prefill_core(self.mcfg))
         from ray_tpu.serve.engine import _sample_tokens
 

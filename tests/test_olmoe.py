@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from benchmark import models
 from benchmark import reference_olmoe as ref
 from ray_tpu.models import llama
-from ray_tpu.models.block import feed_forward
+from ray_tpu.models.block import feed_forward, fuse_qkv
 from ray_tpu.serve.engine import Engine, _make_prefill_core
 
 LOGIT_TOL = 2e-4
@@ -117,7 +117,10 @@ def _serve(engine, prompts, n):
 @pytest.fixture(scope="module")
 def engine(tiny):
     cfg, params = tiny
-    eng = Engine(params, cfg, n_slots=16, decode_chunk=4, page_size=16)
+    # A copy: the engine takes its tree's q/k/v stacks over, and `tiny` is
+    # every test's.
+    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=16,
+                 decode_chunk=4, page_size=16)
     yield eng
     eng.stop()
 
@@ -137,7 +140,8 @@ def test_engine_prefill_then_paged_decode_match_the_reference(tiny, engine):
     core = jax.jit(_make_prefill_core(cfg))
     for prompt in prompts:
         padded = jnp.asarray([prompt + [0] * (64 - len(prompt))], jnp.int32)
-        first, _, _, logits, experts = core(params, padded, len(prompt))
+        first, _, _, logits, experts = core(fuse_qkv(params), padded,
+                                            len(prompt))
         want = _ref_logits(params, prompt, 1)[0]
         assert np.abs(np.asarray(logits) - want).max() < LOGIT_TOL
         # every live token counted twice (2 experts) in each of 2 layers,
@@ -163,10 +167,11 @@ def test_engine_tokens_with_the_decode_kernel_equal_the_reference_paths(
     prompts = [_tokens(14, 11), _tokens(20, 12), _tokens(3, 13)]
 
     def served():
-        eng = Engine(params, cfg, n_slots=2, decode_chunk=4, page_size=16)
+        eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=2,
+                     decode_chunk=4, page_size=16)
         try:
             a = eng.submit(prompts[0], 11)             # positions 14..24
-            first, ks, vs, _, _ = core(params, jnp.asarray(
+            first, ks, vs, _, _ = core(fuse_qkv(params), jnp.asarray(
                 [prompts[1] + [0] * 12], jnp.int32), len(prompts[1]))
             b = eng.submit_prefilled(ks, vs, len(prompts[1]), int(first), 6)
             c = eng.submit(prompts[2], 9)              # waits for a slot
@@ -204,7 +209,7 @@ def test_a_request_alone_beside_fifteen_others_and_in_two_buckets(tiny, engine):
     # The prefill program in two bucket widths: the padding is computed, takes
     # nobody's place, and changes nothing (float32 sums in another order).
     core = jax.jit(_make_prefill_core(cfg))
-    rows = [np.asarray(core(params, jnp.asarray(
+    rows = [np.asarray(core(fuse_qkv(params), jnp.asarray(
         [prompt + [9] * (width - len(prompt))], jnp.int32), len(prompt))[3])
         for width in (32, 128)]
     assert np.abs(rows[0] - rows[1]).max() < 1e-5
@@ -239,7 +244,8 @@ def test_experts_stored_in_another_dtype_are_cast_once_and_loudly(
         "w_gate": "bfloat16", "w_up": "bfloat16", "w_down": "bfloat16"}
     core = jax.jit(_make_prefill_core(half))
     prompt = jnp.asarray([_tokens(64, 4)], jnp.int32)
-    for a, b in zip(core(params, prompt, 50), core(held, prompt, 50)):
+    for a, b in zip(core(fuse_qkv(params), prompt, 50),
+                    core(fuse_qkv(held), prompt, 50)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -254,7 +260,8 @@ def test_every_token_to_the_same_experts_still_equals_the_reference(tiny):
     got = np.asarray(llama.forward(params, jnp.asarray([seq], jnp.int32), cfg))[0]
     assert np.abs(got - _ref_logits(params, seq, 64)).max() < LOGIT_TOL
     core = jax.jit(_make_prefill_core(cfg))
-    experts = np.asarray(core(params, jnp.asarray([seq], jnp.int32), 64)[4])
+    experts = np.asarray(
+        core(fuse_qkv(params), jnp.asarray([seq], jnp.int32), 64)[4])
     assert list(experts) == [128, 128, 0, 0, 0, 0, 0, 0, 4]
 
 

@@ -227,7 +227,9 @@ def test_paged_engine_matches_naive_greedy():
             ids.append(out[-1])
         return out
 
-    eng = Engine(params, cfg, n_slots=3, decode_chunk=4, page_size=16)
+    # A copy: the engine takes its tree's q/k/v stacks over, `naive` reads them.
+    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=3,
+                 decode_chunk=4, page_size=16)
     try:
         def gen(prompt, n):
             q = eng.submit(prompt, n)
@@ -368,6 +370,7 @@ def _tiny_decode(n_layers=3):
     import jax.numpy as jnp
     import numpy as np
 
+    from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import LlamaConfig, init_params
     from ray_tpu.serve.engine import _build_fns
 
@@ -377,7 +380,7 @@ def _tiny_decode(n_layers=3):
     ns, chunk, page, n_pages = 3, 4, 16, 9
     _, decode, _, _, empty = _build_fns(cfg, ns, chunk, page, n_pages)
     kc, vc = empty()
-    args = (init_params(cfg, jax.random.PRNGKey(0)), kc, vc,
+    args = (fuse_qkv(init_params(cfg, jax.random.PRNGKey(0))), kc, vc,
             jnp.zeros((ns, cfg.max_seq // page), jnp.int32),
             jnp.zeros(ns, jnp.int32), jnp.zeros(ns, jnp.int32),
             jnp.zeros(ns, bool), jnp.zeros(ns, jnp.float32),
@@ -446,14 +449,18 @@ def _prefill_adopt_and_two_chunks(cfg, params):
     import jax
     import jax.numpy as jnp
 
+    from ray_tpu.models.block import fuse_qkv
     from ray_tpu.serve.engine import Engine, _make_prefill_core
 
-    eng = Engine(params, cfg, n_slots=2, decode_chunk=4, page_size=16)
+    # A copy: the engine takes its tree's q/k/v stacks over.
+    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=2,
+                 decode_chunk=4, page_size=16)
     try:
         a = eng.submit(list(range(3, 17)), 11)     # positions 14..24
         prompt = [5] * 20
         first, ks, vs, _, _ = jax.jit(_make_prefill_core(cfg))(
-            params, jnp.asarray([prompt + [0] * 12], jnp.int32), len(prompt))
+            fuse_qkv(params), jnp.asarray([prompt + [0] * 12], jnp.int32),
+            len(prompt))
         b = eng.submit_prefilled(ks, vs, len(prompt), int(first), 6)
         c = eng.submit([9, 8, 7], 9)
         return [_drain(q) for q in (a, b, c)], eng.counters()
@@ -533,8 +540,8 @@ def paged3():
             ids.append(out[-1])
         return out
 
-    eng = Engine(params, cfg, n_slots=3, decode_chunk=4, page_size=16,
-                 n_pages=5)
+    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=3,
+                 decode_chunk=4, page_size=16, n_pages=5)
 
     def gen(prompt, n, **kw):
         q = eng.submit(prompt, n, **kw)

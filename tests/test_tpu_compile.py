@@ -137,6 +137,7 @@ def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
     the TPU compiler makes of it."""
     import re
 
+    from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import LlamaConfig, init_params
     from ray_tpu.serve.engine import _build_fns
 
@@ -156,8 +157,8 @@ def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
 
     _, decode, _, _, _ = _build_fns(cfg, ns, chunk, page, n_pages)
     params = jax.tree.map(
-        lambda x: sds(x.shape, x.dtype),
-        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)))))
     slab = (n_pages, KVH, page, hd)
     arena = sds((cfg.n_layers,) + slab, jnp.bfloat16)
     compiled = decode.lower(
@@ -194,15 +195,39 @@ def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
     assert len(calls) == 1, calls
     dims = ",".join(map(str, (cfg.n_layers,) + slab))
     assert calls[0].count(f"bf16[{dims}]") == 2, calls[0]
-    # Temporaries stay under one layer's slab (the gathered K and V alone
-    # were 1.1 slabs each, their float32 copies twice that), once the copy
-    # of the stacked `wq` that every decode program still makes is set
-    # aside (ROADMAP S10: 134 MB at Mistral's widths, more than the slab).
-    s10 = sum(2 * cfg.n_layers * d_model * H * hd
-              for _, dtype, dims, op in results if op == "copy"
-              and dims == (cfg.n_layers, d_model, H * hd))
+    # The weights are read where they lie, a layer at a time, inside the
+    # matmul that uses them: nothing the program MATERIALISES (an instruction
+    # outside the fusions' bodies) is a stack of all the layers of a weight
+    # matrix, or one layer's matrix, made by a copy, a slice or either half
+    # of an asynchronous one, bare or fused by name. Handed
+    # `wq`, `wk`, `wv` a stack each, the compiler re-laid all three at entry
+    # (`copy.18-20`), sliced a layer's out as copies
+    # (`constant_dynamic-slice_fusion.6-8`) and moved the whole `wk` stack out
+    # of and into fast memory every layer of every step (`copy-done.1`,
+    # `slice-done` x 4): 28% of a decode step on the chip (PERF.md, PR 30).
+    bodies = set(re.findall(r" fusion\(.*calls=%([\w.-]+)", text))
+    materialised = [
+        (name, tuple(int(d) for d in dims.split(",")), op)
+        for comp, block in re.findall(
+            r"^(?:ENTRY )?%(\S+) \(.*?\{$(.*?)^\}", text, re.M | re.S)
+        if comp not in bodies
+        for name, dims, op in re.findall(
+            r"%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", block)]
+    assert any("fusion" in op for _, _, op in materialised)  # still reads
+    stacks = {tuple(x.shape) for x in jax.tree.leaves(params["layers"])
+              if len(x.shape) == 3}
+    stacks |= {(cfg.n_layers, d_model, n * hd) for n in (H, KVH)}
+    weights = stacks | {(1,) + w[1:] for w in stacks} | {
+        w[1:] for w in stacks}
+    moved = [(name, dims) for name, dims, op in materialised
+             if dims in weights and re.search("copy|slice", op + name)]
+    assert not moved, moved
+    # Temporaries: nothing set aside, and far under one layer's slab (the
+    # gathered K and V alone were 1.1 slabs each, their float32 copies twice
+    # that; the re-laid projection stacks 577 MiB at 12 Mistral layers,
+    # against 1 MiB now).
     one_slab = n_pages * page * KVH * hd * 2
-    assert compiled.memory_analysis().temp_size_in_bytes - s10 < one_slab
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20 < one_slab
 
 
 # ---------------------------------------------------------------------------

@@ -181,7 +181,7 @@ def test_scope_names_are_in_the_lowered_program(engine, program):
     else:
         e = engine
         arenas = (shape_of(e._kc), shape_of(e._vc))
-        params = jax.tree.map(shape_of, e.params)
+        params = jax.tree.map(shape_of, e._params)
         if program == "decode":
             n = e.n_slots
             lowered = e._decode.lower(
