@@ -3,6 +3,8 @@ meets its KV cache, written once: training (`llama._layer_fwd`), the
 engine's prefill and its decode step (`serve/engine.py`) all call them.
 
   attention_inputs   attn_norm -> q, k, v -> (q/k norm) -> heads -> RoPE
+                     (+ a sparse-attention indexer's queries, key and head
+                     weights, from the same normed input)
   feed_forward       mlp_norm -> dense SwiGLU, or router + experts
 
 and the two ways a program that runs no gradient (serving) holds its layer
@@ -28,44 +30,64 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.moe import moe_ffn
-from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.norms import layer_norm, rms_norm
 
 
 def attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
-                     rope: Callable[[jax.Array], jax.Array]
-                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                     rope: Callable[[jax.Array], jax.Array],
+                     index_rope: Optional[Callable] = None) -> Tuple:
     """-> q `[batch, heads, seq, hd]`, k and v `[batch, kv_heads, seq, hd]`
     (`[slots, heads, hd]` for a decode step), q and k rotated by `rope`,
-    which is handed a tensor in that layout. With `cfg.qk_norm`, q and k are
+    which is handed a tensor in that layout. `cfg.qk_norm` True: q and k are
     RMS-normalised over their WHOLE projection, all heads together, before
-    the split into heads (OLMoE). `lp` holds the projection as training does,
-    `wq`, `wk`, `wv`, or as serving does, one `wqkv` (`fuse_qkv`)."""
+    the split into heads (OLMoE); "head": over each head's own `hd`, after
+    it (the Qwen3 family). `lp` holds the projection as training does,
+    `wq`, `wk`, `wv`, or as serving does, one `wqkv` (`fuse_qkv`).
+
+    With `cfg.index_topk` (a learned sparse-attention indexer,
+    `ops/sparse_attention.py`) a fourth element follows: (qI `[batch,
+    index_heads, seq, Id]`, kI `[batch, 1, seq, Id]`, w `[batch, seq,
+    index_heads]` float32; `[slots, ...]` without the seq axis for a decode
+    step), projected from the same normed input (three more matrices, or
+    three more column groups of `wqkv`), kI through a LayerNorm, qI and kI
+    rotated by `index_rope`, w scaled by index_heads^-1/2 * Id^-1/2."""
     lead = x.shape[:-1]
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
 
-    def heads(t, n):
-        t = t.reshape(*lead, n, hd)
+    def heads(t, n, d=hd):
+        t = t.reshape(*lead, n, d)
         return t.transpose(0, 2, 1, 3) if len(lead) == 2 else t
 
     with jax.named_scope("attn_norm"):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     with jax.named_scope("qkv"):
         if "wqkv" in lp:    # serving (`fuse_qkv`): one matmul, its columns
-            q, k, v = jnp.split(h @ lp["wqkv"].astype(dt), _qkv_ends(cfg),
-                                axis=-1)
+            parts = jnp.split(h @ lp["wqkv"].astype(dt), _qkv_ends(cfg),
+                              axis=-1)
         else:               # training: a matrix each
-            q = h @ lp["wq"].astype(dt)
-            k = h @ lp["wk"].astype(dt)
-            v = h @ lp["wv"].astype(dt)
-        if cfg.qk_norm:
+            parts = [h @ lp[name].astype(dt) for name in _fused_names(cfg)]
+        q, k, v = parts[:3]
+        if cfg.qk_norm is True:
             with jax.named_scope("qk_norm"):
                 q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
                 k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
         q, k, v = heads(q, H), heads(k, KVH), heads(v, KVH)
+        if cfg.qk_norm == "head":
+            with jax.named_scope("qk_norm"):
+                q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+                k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        if cfg.index_topk:
+            IH, Id = cfg.index_heads, cfg.index_head_dim
+            iq, ik, iw = parts[3:]
+            ik = layer_norm(ik, lp["ik_norm"], lp["ik_bias"], cfg.norm_eps)
+            qi, ki = heads(iq, IH, Id), heads(ik, 1, Id)
+            w = iw.astype(jnp.float32) * (IH ** -0.5 * Id ** -0.5)
     with jax.named_scope("rope"):
         q = rope(q)
         k = rope(k)
+        if cfg.index_topk:
+            return q, k, v, (index_rope(qi), index_rope(ki), w)
     return q, k, v
 
 
@@ -96,41 +118,58 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
 
 
 _QKV = ("wq", "wk", "wv")
+_INDEX = ("wiq", "wik", "wiw")
+
+
+def _fused_names(cfg) -> Tuple[str, ...]:
+    """The projections of the block's normed input, in the order of the
+    fused stack's column groups: q, k, v, then an indexer's three."""
+    return _QKV + (_INDEX if cfg.index_topk else ())
 
 
 def _qkv_ends(cfg) -> List[int]:
-    """The columns of the fused projection at which q's end and k's end."""
+    """The columns of the fused projection at which each group but the last
+    ends."""
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    return [H * hd, (H + KVH) * hd]
+    ends = [H * hd, (H + KVH) * hd]
+    if cfg.index_topk:
+        iq = (H + 2 * KVH) * hd
+        ends += [iq, iq + cfg.index_heads * cfg.index_head_dim,
+                 iq + (cfg.index_heads + 1) * cfg.index_head_dim]
+    return ends
 
 
 def fuse_qkv(params: Dict[str, Any]) -> Dict[str, Any]:
     """A model's parameters as the serving programs take them: the layers'
-    `wq`, `wk`, `wv` `[L, d_model, n * hd]` joined along their columns into ONE
-    stack `wqkv` `[L, d_model, (H + 2 KVH) * hd]`, once, when a server is
-    built. Handed three stacks, the TPU compiler lays each out for its own
-    matmul, copies all three at every program's entry, slices a layer's
-    matrices out as copies, and keeps the whole `wk` stack moving in and out
-    of fast memory every layer of every decode step (a quarter of a Mistral
-    decode step; PERF.md, PR 30). One stack it reads a layer at a time, in
-    place, inside the matmul, as it reads the MLP's. The columns are the
-    three matmuls' columns, so q, k and v are what they were, to the order in
-    which a row is summed. Training keeps a matrix each (`attention_inputs`):
-    its gradient, optimizer state, checkpoints and `tp` sharding are by
-    matrix. The result holds no reference to the three stacks."""
+    `wq`, `wk`, `wv` `[L, d_model, n * hd]` (and an indexer's `wiq`, `wik`,
+    `wiw`, further columns of the same input) joined along their columns into
+    ONE stack `wqkv` `[L, d_model, (H + 2 KVH) * hd (+ ...)]`, once, when a
+    server is built. Handed three stacks, the TPU compiler lays each out for
+    its own matmul, copies all three at every program's entry, slices a
+    layer's matrices out as copies, and keeps the whole `wk` stack moving in
+    and out of fast memory every layer of every decode step (a quarter of a
+    Mistral decode step; PERF.md, PR 30). One stack it reads a layer at a
+    time, in place, inside the matmul, as it reads the MLP's. The columns are
+    the separate matmuls' columns, so q, k and v are what they were, to the
+    order in which a row is summed. Training keeps a matrix each
+    (`attention_inputs`): its gradient, optimizer state, checkpoints and `tp`
+    sharding are by matrix. The result holds no reference to the stacks it
+    joined."""
     layers = dict(params["layers"])
-    layers["wqkv"] = jnp.concatenate([layers.pop(k) for k in _QKV], axis=-1)
+    names = _QKV + tuple(n for n in _INDEX if n in layers)
+    layers["wqkv"] = jnp.concatenate([layers.pop(k) for k in names], axis=-1)
     return dict(params, layers=layers)
 
 
 def split_qkv(params: Dict[str, Any], cfg) -> Dict[str, Any]:
     """`fuse_qkv` undone: the tree a checkpoint, the train step or a plain
-    reference reads, its `wq`, `wk`, `wv` cut from the fused stack anew at
-    every call (bit for bit what was fused), so nothing holds a second copy
-    of the projections longer than its caller does."""
+    reference reads, its matrices cut from the fused stack anew at every
+    call (bit for bit what was fused), so nothing holds a second copy of the
+    projections longer than its caller does."""
     layers = dict(params["layers"])
     parts = jnp.split(layers.pop("wqkv"), _qkv_ends(cfg), axis=-1)
-    return dict(params, layers=dict(layers, **dict(zip(_QKV, parts))))
+    return dict(params, layers=dict(layers, **dict(zip(_fused_names(cfg),
+                                                       parts))))
 
 
 def expert_stacks(layers: Dict[str, jax.Array], cfg

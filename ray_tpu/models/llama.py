@@ -29,8 +29,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.models.block import attention_inputs, feed_forward
 from ray_tpu.ops.attention import (flash_attention, pallas_eligible,
                                    repeat_kv)
-from ray_tpu.ops.norms import apply_rope, rms_norm, rope_frequencies
+from ray_tpu.ops.norms import (apply_rope, mrope_tables, rms_norm,
+                               rope_frequencies)
 from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.ops.sparse_attention import sparse_attention
 from ray_tpu.parallel.context import ParallelContext
 
 
@@ -52,8 +54,20 @@ class LlamaConfig:
     top_k_experts: int = 2
     norm_topk_prob: bool = True
     moe_aux_weight: float = 0.01
-    # RMS norm of q and k over the whole projection, before the heads (OLMoE).
-    qk_norm: bool = False
+    # RMS norm of q and k: True, over the whole projection, before the heads
+    # (OLMoE); "head", over each head's own head_dim (the Qwen3 family).
+    qk_norm: Any = False
+    # A head's width; 0 => d_model // n_heads.
+    head_dim: int = 0
+    # Multimodal RoPE (Qwen2-VL): the rotary frequencies in sections, one a
+    # position stream (temporal, height, width); None => one stream.
+    mrope_section: Optional[Tuple[int, ...]] = None
+    # Learned sparse attention (ops/sparse_attention.py): index_topk > 0 =>
+    # an indexer of index_heads heads of index_head_dim picks the index_topk
+    # positions a query attends to.
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -66,9 +80,9 @@ class LlamaConfig:
     remat_policy: str = "full"
     num_microbatches: int = 0          # 0 => equal to pp size
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
 
     # ---- presets ----
     @staticmethod
@@ -104,8 +118,16 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         "mlp_norm": ("layers", "embed"),
     }
     if cfg.qk_norm:
-        layers.update({"q_norm": ("layers", "heads"),
-                       "k_norm": ("layers", "kv_heads")})
+        per_head = cfg.qk_norm == "head"
+        layers.update({
+            "q_norm": ("layers", "head_dim" if per_head else "heads"),
+            "k_norm": ("layers", "head_dim" if per_head else "kv_heads")})
+    if cfg.index_topk:
+        layers.update({"wiq": ("layers", "embed", None),
+                       "wik": ("layers", "embed", None),
+                       "wiw": ("layers", "embed", None),
+                       "ik_norm": ("layers", None),
+                       "ik_bias": ("layers", None)})
     if cfg.n_experts > 0:
         layers.update({
             "router": ("layers", "embed", "expert"),
@@ -131,6 +153,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     L, D, H, KVH = cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads
     hd, F, V = cfg.head_dim, cfg.d_ff, cfg.vocab_size
     pd = cfg.param_dtype
+    # One list of keys for every model: a leaf's key is its place in it, so
+    # the indexer's leaves (the last) move no other model's weights.
     ks = iter(jax.random.split(key, 16))
 
     def norm(shape, k, scale=0.02):
@@ -145,8 +169,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "mlp_norm": jnp.ones((L, D), pd),
     }
     if cfg.qk_norm:
-        layers.update({"q_norm": jnp.ones((L, H * hd), pd),
-                       "k_norm": jnp.ones((L, KVH * hd), pd)})
+        per_head = cfg.qk_norm == "head"
+        layers.update({
+            "q_norm": jnp.ones((L, hd if per_head else H * hd), pd),
+            "k_norm": jnp.ones((L, hd if per_head else KVH * hd), pd)})
     if cfg.n_experts > 0:
         E = cfg.n_experts
         layers.update({
@@ -161,12 +187,20 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             "w_up": norm((L, D, F), next(ks)),
             "w_down": norm((L, F, D), next(ks)),
         })
-    return {
+    out = {
         "embed": norm((V, D), next(ks)),
         "layers": layers,
         "final_norm": jnp.ones((D,), pd),
         "lm_head": norm((D, V), next(ks)),
     }
+    if cfg.index_topk:
+        IH, Id = cfg.index_heads, cfg.index_head_dim
+        layers.update({"wiq": norm((L, D, IH * Id), next(ks)),
+                       "wik": norm((L, D, Id), next(ks)),
+                       "wiw": norm((L, D, IH), next(ks)),
+                       "ik_norm": jnp.ones((L, Id), pd),
+                       "ik_bias": jnp.zeros((L, Id), pd)})
+    return out
 
 
 def param_count(cfg: LlamaConfig) -> int:
@@ -218,7 +252,8 @@ def _attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def _layer_fwd(lp: Dict[str, jax.Array], x: jax.Array, cos, sin, positions,
                cfg: LlamaConfig, sp_manual: bool,
-               ctx: Optional[ParallelContext] = None) -> jax.Array:
+               ctx: Optional[ParallelContext] = None,
+               index_tables=None) -> jax.Array:
     B, S, _ = x.shape
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
@@ -226,15 +261,23 @@ def _layer_fwd(lp: Dict[str, jax.Array], x: jax.Array, cos, sin, positions,
     # The block's two cache-free halves are models/block.py's, shared with
     # serve/engine.py; scope names are the vocabulary a device trace is
     # reduced by (benchmark/program_trace.py).
-    q, k, v = attention_inputs(
-        lp, x, cfg, lambda t: apply_rope(t, cos, sin, positions))
+    q, k, v, *index = attention_inputs(
+        lp, x, cfg, lambda t: apply_rope(t, cos, sin, positions),
+        (lambda t: apply_rope(t, *index_tables)) if index_tables else None)
     with jax.named_scope("attn"):
-        k = repeat_kv(k, H // KVH)
-        v = repeat_kv(v, H // KVH)
-        if sp_manual:
-            attn = ring_attention(q, k, v, axis_name="sp", causal=True)
+        if index:
+            # Attention over the indexer's selection: K and V by kv head, the
+            # selection the same for every head.
+            qi, ki, w = index[0]
+            attn = sparse_attention(q, k, v, qi.transpose(0, 2, 1, 3),
+                                    ki[:, 0], w, cfg.index_topk)
+        elif sp_manual:
+            attn = ring_attention(q, repeat_kv(k, H // KVH),
+                                  repeat_kv(v, H // KVH), axis_name="sp",
+                                  causal=True)
         else:
-            attn = _attention(q, k, v, ctx)
+            attn = _attention(q, repeat_kv(k, H // KVH),
+                              repeat_kv(v, H // KVH), ctx)
         attn = attn.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     with jax.named_scope("attn_out"):
         x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
@@ -256,11 +299,30 @@ def _stack_fwd(layers_p: Dict[str, Any], x: jax.Array, cos, sin,
     else:
         offset = 0
     positions = offset + jnp.arange(x.shape[1])
+    index_tables = None
+    if cfg.index_topk:
+        if sp_manual or (ctx is not None and ctx.mesh.size > 1):
+            raise NotImplementedError(
+                "sparse attention (index_topk > 0) runs on one device: its "
+                "selection is over the whole sequence and is not sharded")
+        # The indexer's own rotary tables, over its own width, at the text
+        # stream's positions.
+        icos, isin = rope_frequencies(cfg.index_head_dim, cfg.max_seq,
+                                      cfg.rope_theta)
+        index_tables = (icos, isin, positions)
+    if cfg.mrope_section:
+        # Three position streams; tokens alone set them equal (a vision
+        # tower would hand its own), and the tables are then the rows
+        # `positions` names: from here on a token's row is its index.
+        cos, sin = mrope_tables(
+            cos, sin, jnp.broadcast_to(positions, (3,) + positions.shape),
+            cfg.mrope_section)
+        positions = None
 
     def body(carry, lp):
         x, aux_sum = carry
         x, aux = _layer_fwd(lp, x, cos, sin, positions, cfg, sp_manual,
-                            ctx)
+                            ctx, index_tables)
         return (x, aux_sum + aux), None
 
     if cfg.remat:

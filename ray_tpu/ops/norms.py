@@ -16,6 +16,16 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (x * weight.astype(jnp.float32)).astype(dtype)
 
 
+def layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
+               eps: float = 1e-5) -> jax.Array:
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    x = x - jnp.mean(x, axis=-1, keepdims=True)
+    x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+    return (x * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(dtype)
+
+
 def rope_frequencies(head_dim: int, max_seq: int, theta: float = 10000.0):
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
                                 / head_dim))
@@ -35,3 +45,23 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * si, x1 * si + x2 * c], axis=-1)
     return out.astype(x.dtype)
+
+
+def mrope_tables(cos: jax.Array, sin: jax.Array, positions: jax.Array,
+                 sections) -> tuple:
+    """Multimodal RoPE (Qwen2-VL's `mrope_section`): three position streams
+    (temporal, height, width), `positions` [3, s], and the rotary
+    frequencies cut into `sections` (summing to d//2) of which section j
+    turns by stream j. cos/sin: [max_seq, d//2] -> (cos, sin) [s, d//2], one
+    row a token, which `apply_rope` takes with `positions=None`. Text gives
+    the three streams equal, and the rows are then `cos[positions[0]]`: the
+    RoPE above."""
+    stream = jnp.repeat(jnp.arange(len(sections)), jnp.asarray(sections),
+                        total_repeat_length=cos.shape[-1])       # [d//2]
+    mine = stream[None, :] == jnp.arange(len(sections))[:, None]  # [3, d//2]
+
+    def pick(table):
+        return jnp.sum(jnp.where(mine[:, None, :], table[positions], 0.0),
+                       axis=0)
+
+    return pick(cos), pick(sin)

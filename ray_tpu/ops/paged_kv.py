@@ -12,6 +12,19 @@ data-dependent branch. Which pages a slot holds is the host's business
 
   * ``empty`` makes the arena, ``write_prompt`` puts a prefill's K/V into a
     slot's pages, ``write_token`` one decode step's row a slot.
+  * A model with a sparse-attention indexer keeps a SECOND kind of row under
+    the same block table: one indexer key a position a layer, in an array of
+    its own, `[n_layers, n_pages, page, index_dim]` (``empty_index``,
+    ``write_prompt_rows``, ``write_token_rows``). Same pages, same null
+    page, same host policy: a slot's page p holds its K, V and indexer keys.
+    `ops.sparse_attention.sparse_decode_attention` reads all three. Its
+    decode gathers single positions, not pages, so its K and V lie BY TOKEN
+    (``empty(..., by_token=True)``): `[n_layers, n_pages, page, kv_heads *
+    head_dim]`, a position's K of every kv head one row of 1 KiB, which a
+    gather moves three times as fast as a row of 256 B a head (0.53 against
+    1.50 ms for 16 x 2,048 positions of one layer on a v5e; PERF.md, PR 32).
+    ``write_prompt`` and ``write_token`` tell the two layouts by their rank,
+    and write a by-token arena as they write the indexer's: a row a position.
   * ``paged_decode_attention`` is one query token a slot against the arena:
     a Pallas TPU kernel that reads a slot's live pages where they lie (the
     XLA gather over the whole block table elsewhere), counted at trace time
@@ -40,10 +53,31 @@ from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
 # ---------------------------------------------------------------------------
 
 def empty(n_layers: int, n_pages: int, kv_heads: int, page: int,
-          head_dim: int, dtype):
-    """-> (kc, vc), the zeroed arena."""
-    shape = (n_layers, n_pages, kv_heads, page, head_dim)
+          head_dim: int, dtype, by_token: bool = False):
+    """-> (kc, vc), the zeroed arena; `by_token`: for a reader that gathers
+    positions (see the top)."""
+    shape = (n_layers, n_pages, page, kv_heads * head_dim) if by_token \
+        else (n_layers, n_pages, kv_heads, page, head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def empty_index(n_layers: int, n_pages: int, page: int, index_dim: int,
+                dtype):
+    """-> ic, the zeroed arena of indexer keys."""
+    return jnp.zeros((n_layers, n_pages, page, index_dim), dtype)
+
+
+def write_prompt_rows(arena, pages, rows):
+    """Scatter a prefill's rows [L, W, R], one a position, into the physical
+    pages of an arena `[L, n_pages, page, R]` (K or V by token; an indexer's
+    keys). As `write_prompt`: W static, `pages[:wp]` entries of 0 route
+    padding into the null page."""
+    L, W, R = rows.shape
+    page = arena.shape[2]
+    wp = -(-W // page)
+    with jax.named_scope("kv_write"):
+        rows = jnp.pad(rows, ((0, 0), (0, wp * page - W), (0, 0)))
+        return arena.at[:, pages[:wp]].set(rows.reshape(L, wp, page, R))
 
 
 def write_prompt(kc, vc, pages, ks, vs):
@@ -51,6 +85,9 @@ def write_prompt(kc, vc, pages, ks, vs):
     W is static (one program per bucket width); `pages[:wp]` entries
     of 0 route padding into the null page."""
     L, W, KVH, hd = ks.shape
+    if kc.ndim == 4:        # by token
+        return (write_prompt_rows(kc, pages, ks.reshape(L, W, KVH * hd)),
+                write_prompt_rows(vc, pages, vs.reshape(L, W, KVH * hd)))
     page = kc.shape[3]
     wp = -(-W // page)
     pad = wp * page - W
@@ -62,6 +99,19 @@ def write_prompt(kc, vc, pages, ks, vs):
         kc = kc.at[:, pages[:wp]].set(ksp)
         vc = vc.at[:, pages[:wp]].set(vsp)
     return kc, vc
+
+
+def write_token_rows(arena, layer, block_table, w, active, row):
+    """One decode step's row [ns, R] a slot into an arena `[L, n_pages,
+    page, R]`, as `write_token` (which see): the slot's page read, its row
+    replaced, the page put back; inactive slots to the null page."""
+    ns, page = row.shape[0], arena.shape[2]
+    with jax.named_scope("kv_write"):
+        pp = jnp.where(active, block_table[jnp.arange(ns), w // page], 0)
+        off = jnp.where(active, w % page, 0)
+        here = (jnp.arange(page) == off[:, None])[:, :, None]
+        return arena.at[layer, pp].set(
+            jnp.where(here, row[:, None], arena[layer, pp]))
 
 
 def write_token(kc, vc, layer, block_table, w, active, k, v):
@@ -76,6 +126,12 @@ def write_token(kc, vc, layer, block_table, w, active, k, v):
     Inactive slots (and positions past a slot's reservation) route to the
     NULL page 0, which attention never reads: the write stays a fixed-shape
     scatter with no data-dependent branches."""
+    if kc.ndim == 4:        # by token
+        ns = k.shape[0]
+        return (write_token_rows(kc, layer, block_table, w, active,
+                                 k.reshape(ns, -1)),
+                write_token_rows(vc, layer, block_table, w, active,
+                                 v.reshape(ns, -1)))
     ns, page = k.shape[0], kc.shape[3]
     with jax.named_scope("kv_write"):
         idx = jnp.arange(ns)

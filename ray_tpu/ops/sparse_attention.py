@@ -1,0 +1,434 @@
+"""Learned sparse attention (the DeepSeek-Sparse-Attention indexer): a small
+scorer picks, for every query, the `topk` earlier positions its attention
+may read, and softmax attention runs over those alone.
+
+For a query t with indexer queries qI[t, j] (j = 1..IH heads of width Id),
+one indexer key kI[s] a position and head weights w[t, j] (already scaled):
+
+    I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])        float32
+    S[t]    = the `topk` positions s <= t with the largest I[t, s]
+              (all of them while t < topk; ties to the smaller s)
+    o[t]    = softmax attention of q[t] over {k[s], v[s] : s in S[t]}
+
+The selection is EXACT: `jax.lax.top_k` on the XLA paths, and on the TPU
+kernel a bisection on the scores' bit patterns that returns the same set,
+ties included (`lax.approx_max_k` would be another model). Scores are
+accumulated in float32 from the inputs' dtype and never exist as
+`[IH, T, T]`: both paths work a block of queries at a time.
+
+  * ``sparse_attention`` is the whole-sequence form (training, the engine's
+    prefill): on a TPU two Pallas kernels, `index_select` (scores and
+    selection of a block of query rows in fast memory, out comes the
+    selection's mask) and `masked_flash` (flash attention under that mask,
+    a kv head's whole group of query heads a grid step, so K, V and the mask
+    are read once a group); elsewhere XLA, a block of queries at a time.
+    Which ran is counted at trace time in `attention.attention_path_counts()`
+    as `sparse_pallas` / `sparse_reference`.
+  * ``sparse_decode_attention`` is one query token a slot against the paged
+    caches (`ops/paged_kv.py`): it scores the slot's live indexer keys,
+    selects, and gathers ONLY the selected K and V rows out of the arena.
+
+Scope names `indexer`, `select` and `sparse_attn` lie inside the caller's
+`attn`.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
+
+_INT_MIN = -2 ** 31
+
+
+def index_scores(qi: jax.Array, ki: jax.Array, w: jax.Array) -> jax.Array:
+    """qi [..., T, IH, Id], ki [..., S, Id], w [..., T, IH] -> I [..., T, S]
+    float32. An exact zero is +0.0 (a negative weight on a dead relu gives
+    -0.0, which a comparison of bit patterns would rank below it)."""
+    s = jnp.einsum("...tjd,...sd->...tjs", qi, ki,
+                   preferred_element_type=jnp.float32)
+    scores = jnp.einsum("...tjs,...tj->...ts", jax.nn.relu(s),
+                        w.astype(jnp.float32))
+    return jnp.where(scores == 0, 0.0, scores)
+
+
+def select_mask(scores: jax.Array, valid: jax.Array, topk: int) -> jax.Array:
+    """scores [..., T, S] float32, valid [..., T, S] bool (the positions a
+    query may see at all) -> bool [..., T, S]: the `topk` valid positions of
+    each row with the largest score, ties to the smaller index
+    (`lax.top_k`'s order); every valid position of a row that has no more
+    than `topk`."""
+    S = scores.shape[-1]
+    if topk >= S:
+        return valid
+    _, idx = jax.lax.top_k(jnp.where(valid, scores, -jnp.inf), topk)
+    idx = idx.reshape(-1, topk)
+    picked = jnp.zeros((idx.shape[0], S), bool).at[
+        jnp.arange(idx.shape[0])[:, None], idx].set(True)
+    return picked.reshape(scores.shape) & valid
+
+
+# ---------------------------------------------------------------------------
+# XLA path: a block of queries at a time
+# ---------------------------------------------------------------------------
+
+_REF_BLOCK = 512
+
+
+def _masked_attention_rows(q, k, v, mask, sm_scale):
+    """q [B, KVH, G, T, hd], k/v [B, KVH, S, hd], mask [B, T, S] bool."""
+    s = jnp.einsum("bkgtd,bksd->bkgts", q, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(mask[:, None, None], s, DEFAULT_MASK_VALUE)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bkgts,bksd->bkgtd", p.astype(v.dtype), v)
+
+
+def _sparse_reference(q, k, v, qi, ki, w, topk, sm_scale):
+    B, H, S, hd = q.shape
+    KVH = k.shape[1]
+    qg = q.reshape(B, KVH, H // KVH, S, hd)
+    block = _REF_BLOCK if S % _REF_BLOCK == 0 else S
+    cols = jnp.arange(S)
+
+    def rows(start):
+        at = lambda x, axis: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            x, start, block, axis)
+        with jax.named_scope("indexer"):
+            scores = jax.lax.stop_gradient(
+                index_scores(at(qi, 1), ki, at(w, 1)))       # [B, block, S]
+        with jax.named_scope("select"):
+            causal = cols[None, :] <= (start + jnp.arange(block))[:, None]
+            mask = select_mask(scores, causal[None], topk)
+        with jax.named_scope("sparse_attn"):
+            return _masked_attention_rows(at(qg, 3), k, v, mask, sm_scale)
+
+    if block == S:
+        out = rows(0)
+    else:
+        out = jax.lax.map(rows, jnp.arange(0, S, block))   # [n, B, KVH, G, block, hd]
+        out = jnp.moveaxis(out, 0, 3).reshape(B, KVH, H // KVH, S, hd)
+    return out.reshape(B, H, S, hd)
+
+
+# ---------------------------------------------------------------------------
+# TPU kernels
+# ---------------------------------------------------------------------------
+
+def _index_select_kernel(qi_ref, w_ref, ki_ref, mask_ref, key_ref, *,
+                         topk: int, block_q: int, chunk: int):
+    """One grid step = `block_q` query rows against every key: their scores
+    as order-preserving int32 keys in `key_ref` [block_q, S], the topk-th
+    largest key of each row by bisection on its 32 bits (each probe one
+    compare-and-count pass over the rows' live columns), the ties at it by a
+    second bisection on the column index, and out goes the mask. Only the
+    column chunks at or under the block's last row are ever touched."""
+    heads = qi_ref.shape[0]
+    S = ki_ref.shape[0]
+    row0 = pl.program_id(0) * block_q
+    n_chunks = pl.cdiv(row0 + block_q, chunk)
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, chunk), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_q, chunk), 1)
+
+    def at(c):
+        return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+
+    def score_chunk(c, carry):
+        kib = ki_ref[at(c), :]                                # [chunk, Id]
+        acc = jnp.zeros((block_q, chunk), jnp.float32)
+        for j in range(heads):
+            s = jax.lax.dot_general(
+                qi_ref[j], kib, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [block_q, chunk]
+            acc = acc + jnp.maximum(s, 0.0) * w_ref[j]
+        acc = jnp.where(acc == 0.0, 0.0, acc)
+        bits = jax.lax.bitcast_convert_type(acc, jnp.int32)
+        key = bits ^ ((bits >> 31) & 0x7FFFFFFF)     # int order = float order
+        key_ref[:, at(c)] = jnp.where(c * chunk + lane <= rows, key, _INT_MIN)
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, score_chunk, 0)
+
+    def count(pred):
+        """Per row, over the live chunks, how many columns `pred(key, col)`
+        holds for."""
+        def body(c, acc):
+            hit = pred(key_ref[:, at(c)], c * chunk + lane)
+            return acc + jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+        return jax.lax.fori_loop(0, n_chunks, body,
+                                 jnp.zeros((block_q, 1), jnp.int32))
+
+    def write(pick):
+        def body(c, carry):
+            key = key_ref[:, at(c)]
+            keep = pick(key, c * chunk + lane) & (key != _INT_MIN)
+            mask_ref[:, at(c)] = keep.astype(mask_ref.dtype)
+            return carry
+        jax.lax.fori_loop(0, n_chunks, body, 0)
+
+        def rest(c, carry):
+            mask_ref[:, at(c)] = jnp.zeros((block_q, chunk), mask_ref.dtype)
+            return carry
+        jax.lax.fori_loop(n_chunks, S // chunk, rest, 0)
+
+    @pl.when(row0 + block_q <= topk)
+    def _all():           # no row of the block has more than topk candidates
+        write(lambda key, col: col >= 0)
+
+    @pl.when(row0 + block_q > topk)
+    def _select():
+        nonneg = count(lambda key, col: key >= 0)
+        t0 = jnp.where(nonneg >= topk, 0, _INT_MIN).astype(jnp.int32)
+
+        def bit(i, t):
+            cand = t + jax.lax.shift_left(jnp.int32(1), 30 - i)
+            n = count(lambda key, col: key >= cand)
+            return jnp.where(n >= topk, cand, t)
+
+        t = jax.lax.fori_loop(0, 31, bit, t0)    # the topk-th largest key
+        need = topk - count(lambda key, col: key > t)
+        # The ties at t: the `need` of the smallest columns, i.e. those
+        # under the largest p with count(key == t, col < p) <= need.
+
+        def tie_bit(i, p):
+            cand = p + jax.lax.shift_left(jnp.int32(1), S.bit_length() - 1 - i)
+            n = count(lambda key, col: (key == t) & (col < cand))
+            return jnp.where(n <= need, cand, p)
+
+        p = jax.lax.fori_loop(0, S.bit_length(), tie_bit,
+                              jnp.zeros((block_q, 1), jnp.int32))
+        write(lambda key, col: (key > t) | ((key == t) & (col < p)))
+
+
+def _index_select_pallas(qi, ki, w, topk, *, block_q=256, chunk=1024,
+                         interpret=False):
+    """qi [S, IH, Id], ki [S, Id], w [S, IH] float32 -> int8 [S, S]: 1 where
+    the query (row) selects the key (column)."""
+    S, heads, dim = qi.shape
+    block_q = attention._pick_block(S, block_q)
+    chunk = attention._pick_block(S, chunk)
+    kernel = functools.partial(_index_select_kernel, topk=topk,
+                               block_q=block_q, chunk=chunk)
+    return pl.pallas_call(
+        kernel,
+        name="index_select",
+        grid=(S // block_q,),
+        in_specs=[
+            pl.BlockSpec((heads, block_q, dim), lambda i: (0, i, 0)),
+            pl.BlockSpec((heads, block_q, 1), lambda i: (0, i, 0)),
+            pl.BlockSpec((S, dim), lambda i: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((block_q, S), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((S, S), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((block_q, S), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(qi.transpose(1, 0, 2), w.astype(jnp.float32).T[:, :, None], ki)
+
+
+def _masked_flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref,
+                         acc_ref, m_ref, l_ref, *, sm_scale: float,
+                         block_q: int, block_k: int):
+    """Flash attention of one kv head's GROUP of query heads (G x block_q
+    rows) against a block of its keys, under the causal selection mask."""
+    q_idx, kv_idx = pl.program_id(1), pl.program_id(2)
+    G, _, hd = q_ref.shape[1:]
+
+    @pl.when(kv_idx == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, DEFAULT_MASK_VALUE)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    # The mask is causal: a block wholly above the diagonal selects nothing.
+    @pl.when(kv_idx * block_k <= q_idx * block_q + (block_q - 1))
+    def _body():
+        q = q_ref[0].reshape(G * block_q, hd)
+        s = jax.lax.dot_general(
+            q, k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        keep = mask_ref[...].astype(jnp.int32) != 0           # [block_q, block_k]
+        s = jnp.where(keep[None], s.reshape(G, block_q, block_k),
+                      DEFAULT_MASK_VALUE).reshape(G * block_q, block_k)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # A row whose every column so far is masked has m == the mask value
+        # and p == 1 there: its l and acc are wiped by `corr` == 0 when its
+        # first real score arrives, and every row selects some column.
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    def _finalize():
+        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+        o_ref[0] = out.reshape(G, block_q, hd).astype(o_ref.dtype)
+
+
+def _masked_flash_pallas(q, k, v, mask, *, sm_scale, block_q=256,
+                         block_k=1024, interpret=False):
+    """q [KVH, G, S, hd], k/v [KVH, S, hd], mask int8 [S, S] (causal)
+    -> [KVH, G, S, hd]."""
+    KVH, G, S, hd = q.shape
+    block_q = attention._pick_block(S, block_q)
+    block_k = attention._pick_block(S, block_k)
+
+    def last_block(qi):          # the last kv block a query block can see
+        return (qi * block_q + block_q - 1) // block_k
+
+    kernel = functools.partial(_masked_flash_kernel, sm_scale=sm_scale,
+                               block_q=block_q, block_k=block_k)
+    # Blocks above the diagonal are skipped; their index is clamped to the
+    # last visible one, so nothing is fetched for them.
+    kv_spec = pl.BlockSpec(
+        (1, block_k, hd),
+        lambda h, qi, ki: (h, jnp.minimum(ki, last_block(qi)), 0))
+    q_spec = pl.BlockSpec((1, G, block_q, hd), lambda h, qi, ki: (h, 0, qi, 0))
+    return pl.pallas_call(
+        kernel,
+        name="masked_flash",
+        grid=(KVH, S // block_q, S // block_k),
+        in_specs=[
+            q_spec, kv_spec, kv_spec,
+            pl.BlockSpec((block_q, block_k),
+                         lambda h, qi, ki: (qi, jnp.minimum(ki, last_block(qi)))),
+        ],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((G * block_q, hd), jnp.float32),
+            pltpu.VMEM((G * block_q, 1), jnp.float32),
+            pltpu.VMEM((G * block_q, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(q, k, v, mask)
+
+
+def _sparse_pallas_fwd(q, k, v, qi, ki, w, topk, sm_scale, interpret=False):
+    B, H, S, hd = q.shape
+    KVH = k.shape[1]
+
+    def one(args):
+        q, k, v, qi, ki, w = args
+        with jax.named_scope("select"):       # scores and selection, fused
+            mask = _index_select_pallas(qi, ki, w, topk, interpret=interpret)
+        with jax.named_scope("sparse_attn"):
+            return _masked_flash_pallas(
+                q.reshape(KVH, H // KVH, S, hd), k, v, mask,
+                sm_scale=sm_scale, interpret=interpret).reshape(H, S, hd)
+
+    if B == 1:
+        return one((q[0], k[0], v[0], qi[0], ki[0], w[0]))[None]
+    return jax.lax.map(one, (q, k, v, qi, ki, w))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _sparse_pallas(q, k, v, qi, ki, w, topk, sm_scale):
+    return _sparse_pallas_fwd(q, k, v, qi, ki, w, topk, sm_scale)
+
+
+def _sparse_pallas_vjp_fwd(q, k, v, qi, ki, w, topk, sm_scale):
+    return (_sparse_pallas_fwd(q, k, v, qi, ki, w, topk, sm_scale),
+            (q, k, v, qi, ki, w))
+
+
+def _sparse_pallas_vjp_bwd(topk, sm_scale, res, dout):
+    """The XLA path's gradient (the selection is recomputed there; the
+    indexer takes none: its scores only choose)."""
+    q, k, v, qi, ki, w = res
+    _, vjp = jax.vjp(lambda q, k, v: _sparse_reference(
+        q, k, v, qi, ki, w, topk, sm_scale), q, k, v)
+    return vjp(dout) + tuple(jnp.zeros_like(x) for x in (qi, ki, w))
+
+
+_sparse_pallas.defvjp(_sparse_pallas_vjp_fwd, _sparse_pallas_vjp_bwd)
+
+
+def sparse_attention(q, k, v, qi, ki, w, topk: int, *,
+                     sm_scale: Optional[float] = None,
+                     interpret: bool = False) -> jax.Array:
+    """Causal attention of a whole sequence under the indexer's selection.
+
+    q [B, H, S, hd]; k, v [B, KVH, S, hd] (query head h reads kv head
+    h // (H // KVH)); qi [B, S, IH, Id] and ki [B, S, Id], rotated; w
+    [B, S, IH], scaled. -> [B, H, S, hd]. With `topk >= S` it is dense
+    causal attention. The indexer takes no gradient through this."""
+    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    S, hd = q.shape[2], q.shape[3]
+    use = interpret or (attention._on_tpu() and S % 128 == 0
+                        and hd % 128 == 0)
+    attention._path_counts["sparse_pallas" if use else "sparse_reference"] += 1
+    if interpret:
+        return _sparse_pallas_fwd(q, k, v, qi, ki, w, topk, scale, True)
+    if use:
+        return _sparse_pallas(q, k, v, qi, ki, w, topk, scale)
+    return _sparse_reference(q, k, v, qi, ki, w, topk, scale)
+
+
+# ---------------------------------------------------------------------------
+# Decode: one query token a slot against the paged caches
+# ---------------------------------------------------------------------------
+
+def sparse_decode_attention(q, qi, w, kc, vc, ic, layer, block_table,
+                            lengths, topk: int, *,
+                            sm_scale: Optional[float] = None) -> jax.Array:
+    """q [ns, H, hd]; qi [ns, IH, Id] and w [ns, IH] this token's indexer
+    query and head weights; kc, vc the K/V arena laid out BY TOKEN, `[L,
+    n_pages, page, KVH * hd]`, and ic the indexer keys' `[L, n_pages, page,
+    Id]` (`ops/paged_kv.py`), `layer` the index into all three; block_table
+    [ns, max_pages]; lengths [ns], the positions 0..lengths-1 a slot may
+    read (0: an idle slot, whose output is 0). -> [ns, H, hd].
+
+    Scores every live indexer key of the slot (its pages of `ic`: 128 B a
+    position), selects the `topk` largest exactly, and gathers the selected
+    rows of K and V alone out of the arena."""
+    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    ns, H, hd = q.shape
+    _, n_pages, page, row = kc.shape
+    KVH = row // hd
+    ctx = block_table.shape[1] * page
+    kk = min(topk, ctx)
+    with jax.named_scope("indexer"):
+        keys = ic[layer, block_table].reshape(ns, ctx, ic.shape[-1])
+        scores = index_scores(qi[:, None], keys, w[:, None])[:, 0]   # [ns, ctx]
+        live = jnp.arange(ctx)[None, :] < lengths[:, None]
+        scores = jnp.where(live, scores, -jnp.inf)
+    with jax.named_scope("select"):
+        vals, idx = jax.lax.top_k(scores, kk)                 # [ns, kk]
+        picked = vals > -jnp.inf
+        idx = jnp.where(picked, idx, 0)
+    with jax.named_scope("sparse_attn"):
+        # The arena as rows of one position each: a gather of rows leaves
+        # it where and how it lies (indexed `kc[layer, pages, rows]`, XLA
+        # re-lays the whole arena and copies it to and from every page
+        # write; AOT for v5e).
+        pages = jnp.take_along_axis(block_table, idx // page, axis=1)
+        at = (layer * n_pages + pages) * page + idx % page        # [ns, kk]
+        ks = kc.reshape(-1, row)[at].reshape(ns, kk, KVH, hd)
+        vs = vc.reshape(-1, row)[at].reshape(ns, kk, KVH, hd)
+        qg = q.reshape(ns, KVH, H // KVH, hd)
+        s = jnp.einsum("nkgd,nskd->nkgs", qg, ks,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(picked[:, None, None], s, DEFAULT_MASK_VALUE)
+        p = jax.nn.softmax(s, axis=-1)
+        # What an unpicked row holds is masked out of v too (0 x NaN), and an
+        # idle slot, whose every weight is 1 / kk, gives 0.
+        vs = jnp.where(picked[:, :, None, None], vs, 0)
+        out = jnp.einsum("nkgs,nskd->nkgd", p.astype(vs.dtype), vs)
+    return out.reshape(ns, H, hd).astype(q.dtype)

@@ -14,7 +14,10 @@ engine is OURS):
   design). This module lays nothing out and does no page arithmetic. What
   it owes the cache: the decode program updates the arena IN PLACE, as a
   loop carry that nothing but those ops touches, so no copy of it (or of a
-  layer's slab) is ever made (see `_step`).
+  layer's slab) is ever made (see `_step`). A model with a sparse-attention
+  indexer (`mcfg.index_topk`) has a third array under the same block table,
+  its indexer keys (`ic`, `paged_kv.empty_index`): the programs take and
+  return it after everything else, and it is None for every other model.
 - **Reservation admission**: a request is admitted when the pages
   `PagePool.pages_for` says it can ever need are free: growth can then
   never fail mid-decode, so there is no preemption/recompute path.
@@ -54,6 +57,13 @@ from ray_tpu.utils import get_logger, tracing
 logger = get_logger("serve.engine")
 
 
+# Rows of a prefill that meet the sparse feed-forward at once: its sorted
+# copies are `rows x experts a token` wide (2.5 GiB of temporaries at 4,096
+# rows of OLMoE's widths), so a wider bucket goes through in blocks of this
+# many rows; each row is computed from itself alone, so nothing changes.
+_MOE_ROWS = 4096
+
+
 def _make_prefill_core(mcfg):
     """fn(params, tokens[1, B], length) -> (first_token, ks, vs, the last
     position's logits, experts) where ks/vs are [L, B, KVH, hd] and `experts`
@@ -61,57 +71,89 @@ def _make_prefill_core(mcfg):
     prompt's tokens summed over the layers — the shared prefill pass used
     by the in-engine prefill AND the disaggregated PrefillServer (reference:
     llm/_internal/serve/deployments/prefill_decode_disagg/ — there the
-    split is two vLLM pools; here both halves share one traced core)."""
+    split is two vLLM pools; here both halves share one traced core). A
+    model with a sparse-attention indexer adds a sixth element, its indexer
+    keys [L, B, Id]."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models.block import (attention_inputs, expert_stacks,
                                       expert_stats, feed_forward)
     from ray_tpu.ops.attention import flash_attention, repeat_kv
-    from ray_tpu.ops.norms import apply_rope, rms_norm, rope_frequencies
+    from ray_tpu.ops.norms import (apply_rope, mrope_tables, rms_norm,
+                                   rope_frequencies)
+    from ray_tpu.ops.sparse_attention import sparse_attention
 
     H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
     dt = mcfg.dtype
     sparse = mcfg.n_experts > 0
+    indexed = mcfg.index_topk > 0
+
+    def _feed_forward(lp, x, live, l):
+        """`feed_forward` over at most `_MOE_ROWS` rows at a time."""
+        Sq = x.shape[1]
+        if not sparse or Sq <= _MOE_ROWS:
+            return feed_forward(lp, x, mcfg, live, l)
+        outs, counts = [], 0
+        for start in range(0, Sq, _MOE_ROWS):
+            rows = slice(start, start + _MOE_ROWS)
+            y, (_, n) = feed_forward(lp, x[:, rows], mcfg, live[:, rows], l)
+            outs.append(y)
+            counts = counts + n
+        return jnp.concatenate(outs, axis=1), (None, counts)
 
     def _prefill_layer(stacks, carry, layer):
-        x, cos, sin, live = carry
+        x, cos, sin, live, *itables = carry
         lp, l = layer if sparse else (layer, None)
         lp = dict(lp, **stacks)
         B, Sq, _ = x.shape
-        q, k, v = attention_inputs(lp, x, mcfg,
-                                   lambda t: apply_rope(t, cos, sin))
+        q, k, v, *index = attention_inputs(
+            lp, x, mcfg, lambda t: apply_rope(t, cos, sin),
+            (lambda t: apply_rope(t, *itables)) if indexed else None)
         with jax.named_scope("attn"):
-            attn = flash_attention(q, repeat_kv(k, H // KVH),
-                                   repeat_kv(v, H // KVH), True)
+            if indexed:
+                qi, ki, w = index[0]
+                attn = sparse_attention(q, k, v, qi.transpose(0, 2, 1, 3),
+                                        ki[:, 0], w, mcfg.index_topk)
+            else:
+                attn = flash_attention(q, repeat_kv(k, H // KVH),
+                                       repeat_kv(v, H // KVH), True)
             attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd)
         with jax.named_scope("attn_out"):
             x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
-        x, routed = feed_forward(lp, x, mcfg, live, l)
+        x, routed = _feed_forward(lp, x, live, l)
         # cache pre-repeat k/v: [S, KVH, hd] (B == 1 squeezed)
         ys = (k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2))
         if sparse:
             ys += (expert_stats(routed[1]),)
-        return (x, cos, sin, live), ys
+        if indexed:
+            ys += (ki[0, 0],)                                  # [S, Id]
+        return (x, cos, sin, live, *itables), ys
 
     def core(params, tokens, length):
+        width = tokens.shape[1]
         with jax.named_scope("embed"):
             x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
         with jax.named_scope("rope"):
-            cos, sin = rope_frequencies(hd, tokens.shape[1], mcfg.rope_theta)
+            cos, sin = rope_frequencies(hd, width, mcfg.rope_theta)
+            if mcfg.mrope_section:      # text: the three streams are equal
+                cos, sin = mrope_tables(
+                    cos, sin, jnp.broadcast_to(jnp.arange(width), (3, width)),
+                    mcfg.mrope_section)
+            itables = rope_frequencies(mcfg.index_head_dim, width,
+                                       mcfg.rope_theta) if indexed else ()
         # The bucket's padding is computed like any row, each from itself
         # alone (no capacity for it to take), and left out of the count.
-        live = (jnp.arange(tokens.shape[1])[None] < length) if sparse \
-            else None
+        live = (jnp.arange(width)[None] < length) if sparse else None
         # The experts' stacks stay whole (`expert_stacks`): the scan slices
         # the rest, and carries the layer's index for them.
         sliced, stacks = expert_stacks(params["layers"], mcfg)
         if sparse:
             sliced = (sliced, jnp.arange(mcfg.n_layers))
         with jax.named_scope("layers"):
-            (x, *_), (ks, vs, *routed) = jax.lax.scan(
+            (x, *_), (ks, vs, *more) = jax.lax.scan(
                 functools.partial(_prefill_layer, stacks),
-                (x, cos, sin, live), sliced)
+                (x, cos, sin, live, *itables), sliced)
         with jax.named_scope("head"):
             x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
             last_h = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
@@ -119,8 +161,9 @@ def _make_prefill_core(mcfg):
             logits = jnp.einsum("bd,dv->bv", last_h,
                                 params["lm_head"].astype(dt))
             first = jnp.argmax(logits[0]).astype(jnp.int32)
-        experts = jnp.sum(routed[0], axis=0) if sparse else None
-        return first, ks, vs, logits[0].astype(jnp.float32), experts
+        experts = jnp.sum(more[0], axis=0) if sparse else None
+        out = (first, ks, vs, logits[0].astype(jnp.float32), experts)
+        return out + ((more[-1],) if indexed else ())
 
     return core
 
@@ -165,36 +208,51 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
 
     from ray_tpu.models.block import (attention_inputs, expert_stacks,
                                       expert_stats, feed_forward)
-    from ray_tpu.ops.norms import rms_norm, rope_frequencies
-    from ray_tpu.ops.paged_kv import (empty, paged_decode_attention,
-                                      write_prompt, write_token)
+    from ray_tpu.ops.norms import mrope_tables, rms_norm, rope_frequencies
+    from ray_tpu.ops.paged_kv import (empty, empty_index,
+                                      paged_decode_attention, write_prompt,
+                                      write_prompt_rows, write_token,
+                                      write_token_rows)
+    from ray_tpu.ops.sparse_attention import sparse_decode_attention
 
     sparse = mcfg.n_experts > 0
+    indexed = mcfg.index_topk > 0
     S = mcfg.max_seq
     H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
     dt = mcfg.dtype
     ns = n_slots
 
-    empty_caches = functools.partial(empty, mcfg.n_layers, n_pages, KVH, page,
-                                     hd, dt)
+    def empty_caches():
+        """-> (kc, vc), and ic after them for a model with an indexer."""
+        kv = empty(mcfg.n_layers, n_pages, KVH, page, hd, dt,
+                   by_token=indexed)
+        if indexed:
+            kv += (empty_index(mcfg.n_layers, n_pages, page,
+                               mcfg.index_head_dim, dt),)
+        return kv
 
     # ------------------------------------------------------------------
     # prefill: full causal pass over ONE padded prompt, k/v -> pages
     # ------------------------------------------------------------------
     _core = _make_prefill_core(mcfg)
 
-    def prefill(params, kc, vc, pages, tokens, length, temp, topk, key):
+    def prefill(params, kc, vc, pages, tokens, length, temp, topk, key,
+                ic=None):
         """tokens [1, B] padded to a BUCKET width (powers of 2 up to
         max_seq — jax.jit compiles one program per bucket shape, so a
         short prompt pays a short prefill, not a max_seq one); writes
         the slot's pages, returns the first generated token (sampled,
-        or greedy when temp == 0) and the core's `experts`."""
-        _, ks, vs, logits_row, experts = _core(params, tokens, length)
+        or greedy when temp == 0) and the core's `experts` (and `ic`, the
+        indexer keys' arena, where the model has one)."""
+        _, ks, vs, logits_row, experts, *iks = _core(params, tokens, length)
         kc, vc = write_prompt(kc, vc, pages, ks, vs)
         first = _sample_tokens(logits_row[None],
                                jnp.asarray(temp)[None],
                                jnp.asarray(topk)[None], key[None],
                                jnp.asarray(length - 1)[None])[0]
+        if indexed:
+            return (kc, vc, first, experts,
+                    write_prompt_rows(ic, pages, iks[0]))
         return kc, vc, first, experts
 
     def adopt(kc, vc, pages, ks, vs):
@@ -211,32 +269,47 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         out = jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
         return out.astype(x.dtype)
 
-    def _decode_layer(x, kc, vc, lp, l, bt, pos, act, cos, sin):
-        # x [ns, D]; kc/vc the WHOLE arena (`ops.paged_kv`); l this layer's
-        # index (traced scalar); bt the block table; a sparse model's expert
-        # weights in lp are all the layers' (`expert_stacks`)
+    def _decode_layer(x, kc, vc, ic, lp, l, bt, pos, act, cos, sin, itables):
+        # x [ns, D]; kc/vc (and ic) the WHOLE arena (`ops.paged_kv`); l this
+        # layer's index (traced scalar); bt the block table; a sparse model's
+        # expert weights in lp are all the layers' (`expert_stacks`)
         with jax.named_scope("rope"):
             w = jnp.minimum(pos, S - 1)
-            c = cos[w][:, None]
-            s = sin[w][:, None]
-        q, k, v = attention_inputs(lp, x, mcfg,
-                                   lambda t: _rope_one(t, c, s))
+            if mcfg.mrope_section:      # text: the three streams are equal
+                c, s = mrope_tables(cos, sin, jnp.broadcast_to(w, (3, ns)),
+                                    mcfg.mrope_section)
+                c, s = c[:, None], s[:, None]
+            else:
+                c = cos[w][:, None]
+                s = sin[w][:, None]
+            if indexed:     # the indexer's own tables, over its own width
+                ci, si = (t[w][:, None] for t in itables)
+        q, k, v, *index = attention_inputs(
+            lp, x, mcfg, lambda t: _rope_one(t, c, s),
+            (lambda t: _rope_one(t, ci, si)) if indexed else None)
         kc, vc = write_token(kc, vc, l, bt, w, act, k, v)
+        if indexed:
+            qi, ki, iw = index[0]
+            ic = write_token_rows(ic, l, bt, w, act, ki[:, 0])
         # Each active slot's query against its positions 0..w; an idle slot
         # reads nothing.
         with jax.named_scope("attn"):
-            attn = paged_decode_attention(
-                q, kc, vc, l, bt, jnp.where(act, w + 1, 0))
+            lengths = jnp.where(act, w + 1, 0)
+            if indexed:
+                attn = sparse_decode_attention(
+                    q, qi, iw, kc, vc, ic, l, bt, lengths, mcfg.index_topk)
+            else:
+                attn = paged_decode_attention(q, kc, vc, l, bt, lengths)
             attn = attn.reshape(ns, H * hd)
         with jax.named_scope("attn_out"):
             x = x + attn @ lp["wo"].astype(dt)
         # An idle slot's row is computed like any other, from itself alone,
         # and left out of the count.
         x, routed = feed_forward(lp, x, mcfg, act, l if sparse else None)
-        return x, kc, vc, routed
+        return x, kc, vc, ic, routed
 
-    def _step(params, sliced, stacks, kc, vc, experts, bt, last, pos, active,
-              cos, sin, temp, topk, keys):
+    def _step(params, sliced, stacks, kc, vc, ic, experts, bt, last, pos,
+              active, cos, sin, itables, temp, topk, keys):
         # sliced, stacks: `expert_stacks` of the layers, split (and where
         # need be cast) once a chunk, outside the loop over its steps
         act = active & (pos < S)
@@ -244,13 +317,14 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             x = jnp.take(params["embed"], last, axis=0).astype(dt)
 
         def body(carry, layer):
-            x, kc, vc, *experts = carry
+            x, kc, vc, ic, *experts = carry
             lp, l = layer
-            x, kc, vc, routed = _decode_layer(
-                x, kc, vc, dict(lp, **stacks), l, bt, pos, act, cos, sin)
+            x, kc, vc, ic, routed = _decode_layer(
+                x, kc, vc, ic, dict(lp, **stacks), l, bt, pos, act, cos, sin,
+                itables)
             if sparse:
                 experts = [experts[0] + expert_stats(routed[1])]
-            return (x, kc, vc, *experts), None
+            return (x, kc, vc, ic, *experts), None
 
         # The arena rides this scan's CARRY, and only the page write and
         # the attention kernel's reads touch it, so the layer loop, the
@@ -264,8 +338,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         # step's carry (2.9 GB a step at 12 layers x 929 pages; PERF.md,
         # PR 25). The xs are the layer's weights and its index.
         with jax.named_scope("layers"):
-            (x, kc, vc, *experts), _ = jax.lax.scan(
-                body, (x, kc, vc, *experts),
+            (x, kc, vc, ic, *experts), _ = jax.lax.scan(
+                body, (x, kc, vc, ic, *experts),
                 (sliced, jnp.arange(mcfg.n_layers)))
         with jax.named_scope("head"):
             x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
@@ -273,30 +347,37 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         nxt = _sample_tokens(logits, temp, topk, keys, pos)
         nxt = jnp.where(act, nxt, last)
         pos2 = jnp.where(act, pos + 1, pos)
-        return kc, vc, experts, nxt, pos2
+        return kc, vc, ic, experts, nxt, pos2
 
-    def decode(params, kc, vc, bt, last, pos, active, temp, topk, keys):
+    def decode(params, kc, vc, bt, last, pos, active, temp, topk, keys,
+               ic=None):
         """-> (kc, vc, last, pos, tokens [ns, chunk], experts): `experts` is
         None for a dense model, else `expert_stats` of the live slots'
-        tokens summed over the chunk's steps and the layers."""
+        tokens summed over the chunk's steps and the layers. With an
+        indexer, `ic` follows."""
         with jax.named_scope("rope"):
             cos, sin = rope_frequencies(hd, S, mcfg.rope_theta)
+            itables = rope_frequencies(mcfg.index_head_dim, S,
+                                       mcfg.rope_theta) if indexed else ()
         out0 = jnp.zeros((ns, chunk), jnp.int32)
         experts0 = [jnp.zeros(mcfg.n_experts + 1, jnp.int32)] if sparse \
             else []
         sliced, stacks = expert_stacks(params["layers"], mcfg)
 
         def body(i, carry):
-            kc, vc, last, pos, out, *experts = carry
-            kc, vc, experts, nxt, pos = _step(
-                params, sliced, stacks, kc, vc, experts, bt, last, pos,
-                active, cos, sin, temp, topk, keys)
+            kc, vc, ic, last, pos, out, *experts = carry
+            kc, vc, ic, experts, nxt, pos = _step(
+                params, sliced, stacks, kc, vc, ic, experts, bt, last, pos,
+                active, cos, sin, itables, temp, topk, keys)
             out = out.at[:, i].set(nxt)
-            return (kc, vc, nxt, pos, out, *experts)
+            return (kc, vc, ic, nxt, pos, out, *experts)
 
-        kc, vc, last, pos, out, *experts = jax.lax.fori_loop(
-            0, chunk, body, (kc, vc, last, pos, out0, *experts0))
-        return kc, vc, last, pos, out, (experts[0] if sparse else None)
+        kc, vc, ic, last, pos, out, *experts = jax.lax.fori_loop(
+            0, chunk, body, (kc, vc, ic, last, pos, out0, *experts0))
+        experts = experts[0] if sparse else None
+        if indexed:
+            return kc, vc, last, pos, out, experts, ic
+        return kc, vc, last, pos, out, experts
 
     def poke(last, pos, slot, first, length):
         """Admission bookkeeping ON DEVICE: set one slot's (last, pos).
@@ -306,8 +387,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         return last.at[slot].set(first), pos.at[slot].set(length)
 
     import jax as _jax
-    prefill_jit = _jax.jit(prefill, donate_argnums=(1, 2))
-    decode_jit = _jax.jit(decode, donate_argnums=(1, 2, 4, 5))
+    prefill_jit = _jax.jit(prefill, donate_argnums=(1, 2, 9))
+    decode_jit = _jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10))
     adopt_jit = _jax.jit(adopt, donate_argnums=(0, 1))
     poke_jit = _jax.jit(poke, donate_argnums=(0, 1))
     return prefill_jit, decode_jit, adopt_jit, poke_jit, empty_caches
@@ -391,7 +472,10 @@ class Engine:
         (self._prefill, self._decode, self._adopt, self._poke,
          self._empty) = _build_fns(mcfg, n_slots, decode_chunk,
                                    self.pool.page, self.n_pages)
-        self._kc, self._vc = self._empty()
+        # `_ic`: the indexer keys' arena of a model with sparse attention,
+        # under the same block table; None for every other model.
+        self._kc, self._vc, *ic = self._empty()
+        self._ic = ic[0] if ic else None
         # Prefill shape buckets (powers of 2, capped at max_seq): a
         # 50-token prompt prefills 64 wide, not max_seq wide — the TTFT
         # lever the reference gets from vLLM's chunked prefill.
@@ -426,6 +510,14 @@ class Engine:
         # what decode attention had to read, a layer, at the chunk's first
         # step (against n_slots * max_seq, what a whole-table gather moves).
         self.live_kv_tokens = 0
+        # A sparse-attention model: the keys its decode steps selected, over
+        # the chunks' steps and the active slots (min(positions, index_topk)
+        # a slot a step; a layer reads that many K and V rows), against
+        # `decode_live_keys`, the positions those slots held (what dense
+        # attention would read). Host arithmetic on the positions.
+        self._index_topk = mcfg.index_topk
+        self.decode_selected_keys = 0
+        self.decode_live_keys = 0
         # A sparse model's routing, as the programs count it on the device
         # (`models.block.expert_stats`) and the emitter thread adds it up:
         # tokens per expert over prefills and decode steps, and the distinct
@@ -454,17 +546,18 @@ class Engine:
         # touch real KV state.
         self._warm = {self.buckets[0], self.buckets[-1]}
         for width in sorted(self._warm):
-            self._kc, self._vc, first = self._warm_width(
-                self._kc, self._vc, width)
+            self._kc, self._vc, self._ic, first = self._warm_width(
+                self._kc, self._vc, self._ic, width)
         with tracing.compile_span("serve.engine.warm", program="decode",
                                   width=n_slots):
-            self._kc, self._vc, self._last_d, self._pos_d, out, _ = \
-                self._decode(
+            (self._kc, self._vc, self._last_d, self._pos_d, out, _,
+             *ic) = self._decode(
                     self._params, self._kc, self._vc,
                     jnp.asarray(self.pool.block_table),
                     self._last_d, self._pos_d, jnp.zeros(n_slots, bool),
                     jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._skeys))
+                    jnp.asarray(self._skeys), self._ic)
+            self._ic = ic[0] if ic else None
         # Warm both poke variants: host-int `first` (adopt path) and
         # device-scalar `first` (prefill path).
         with tracing.compile_span("serve.engine.warm", program="poke",
@@ -496,18 +589,21 @@ class Engine:
                 name="llm-bucket-warm")
             self._warm_thread.start()
 
-    def _warm_width(self, kc, vc, width: int):
+    def _warm_width(self, kc, vc, ic, width: int):
         """First calls of the prefill and adopt programs of one bucket
         width, writing to the null page of the arena given (pages = zeros:
-        never real KV state). Returns (kc, vc, first token on the device)."""
+        never real KV state). Returns (kc, vc, ic, first token on the
+        device)."""
         jnp, m = self._jnp, self.mcfg
         null_pages = jnp.zeros(self.pool.maxp, jnp.int32)
         with tracing.compile_span("serve.engine.warm", program="prefill",
                                   width=width):
-            kc, vc, first, _ = self._prefill(
+            kc, vc, first, _, *more = self._prefill(
                 self._params, kc, vc, null_pages,
                 jnp.zeros((1, width), jnp.int32), 1, 0.0, 0,
-                jnp.zeros(2, jnp.uint32))
+                jnp.zeros(2, jnp.uint32), ic)
+        if more:     # a model with an indexer: no PD handoff carries its keys
+            return kc, vc, more[0], first
         # The PD adopt program for this width too (a first cross-pool
         # handoff must not compile in the loop).
         with tracing.compile_span("serve.engine.warm", program="adopt",
@@ -515,7 +611,7 @@ class Engine:
             kv = jnp.zeros((m.n_layers, width, m.n_kv_heads, m.head_dim),
                            m.dtype)
             kc, vc = self._adopt(kc, vc, null_pages, kv, kv)
-        return kc, vc, first
+        return kc, vc, ic, first
 
     def _warm_buckets(self, widths: List[int]) -> None:
         """Warm intermediate prefill buckets off the engine loop; each
@@ -525,11 +621,12 @@ class Engine:
         call and must never be touched from this thread. Costs one
         transient extra arena while warming."""
         try:
-            kc, vc = self._empty()
+            kc, vc, *ic = self._empty()
+            ic = ic[0] if ic else None
             for width in widths:
                 if self._stop:
                     return
-                kc, vc, first = self._warm_width(kc, vc, width)
+                kc, vc, ic, first = self._warm_width(kc, vc, ic, width)
                 int(first)  # host sync: compile fully landed
                 self._warm.add(width)
         except Exception:
@@ -557,7 +654,8 @@ class Engine:
             shape_of(self._vc),
             jax.ShapeDtypeStruct((self.pool.maxp,), jnp.int32),
             jax.ShapeDtypeStruct((1, width), jnp.int32), 1, 0.0, 0,
-            jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text()
+            jax.ShapeDtypeStruct((2,), jnp.uint32),
+            None if self._ic is None else shape_of(self._ic)).as_text()
 
     # ------------------------------------------------------------------
     @property
@@ -619,6 +717,10 @@ class Engine:
         only tokens AFTER `first`."""
         if self.error is not None or not self._thread.is_alive():
             raise RuntimeError(f"LLM engine died:\n{self.error}")
+        if self._ic is not None:
+            raise NotImplementedError(
+                "a PD handoff carries K and V, not a sparse-attention "
+                "indexer's keys: this model serves from one engine")
         req = _Request([0] * min(length, self.mcfg.max_seq - 1),
                        max_tokens, adopt_kv=(ks, vs), first=first,
                        temperature=temperature, top_k=top_k, seed=seed)
@@ -649,7 +751,9 @@ class Engine:
         prefills and decode steps, as far as the emitter has fetched them)
         and `decode_experts_touched` (distinct experts, summed over decode
         steps and layers: over `decode_chunks * chunk * n_layers` it is the
-        experts whose weights a layer reads in a step)."""
+        experts whose weights a layer reads in a step). A sparse-attention
+        model adds `decode_selected_keys` over `decode_live_keys`: the share
+        of the live positions its decode steps read K and V of."""
         out = {k: getattr(self, k) for k in (
             "admitted", "queue_wait_s_sum", "prefill_tokens",
             "prefill_padded_tokens", "decode_chunks",
@@ -658,6 +762,9 @@ class Engine:
         if self._sparse:
             out["expert_tokens"] = [int(n) for n in self.expert_tokens]
             out["decode_experts_touched"] = self.decode_experts_touched
+        if self._index_topk:
+            out["decode_selected_keys"] = self.decode_selected_keys
+            out["decode_live_keys"] = self.decode_live_keys
         return out
 
     def stop(self) -> None:
@@ -770,11 +877,12 @@ class Engine:
         else:
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :len(req.ids)] = req.ids
-            self._kc, self._vc, first, experts = self._prefill(
+            self._kc, self._vc, first, experts, *ic = self._prefill(
                 self._params, self._kc, self._vc, pages_arr,
                 jnp.asarray(toks), len(req.ids),
                 float(req.temperature), int(req.top_k),
-                jnp.asarray(_seed_key(req.seed)))
+                jnp.asarray(_seed_key(req.seed)), self._ic)
+            self._ic = ic[0] if ic else None
         req.slot = slot
         self._slot_req[slot] = req
         self._pos[slot] = len(req.ids)
@@ -938,19 +1046,30 @@ class Engine:
             routed = {"experts_touched": self._touched_last_chunk,
                       "expert_tokens": ":".join(map(str, self.expert_tokens))
                       } if self._sparse and tracing.recording() else {}
+            if self._index_topk:
+                # What each step of the chunk reads, a layer: a slot at
+                # position p attends to p + 1 positions, the indexer's
+                # index_topk of them at most.
+                reads = (self._pos[self._active][:, None] + 1
+                         + np.arange(self.chunk)[None, :]).clip(max=S)
+                picked = int(np.minimum(reads, self._index_topk).sum())
+                self.decode_selected_keys += picked
+                self.decode_live_keys += int(reads.sum())
+                routed.update(selected_keys=picked, live_keys=int(reads.sum()))
             with tracing.span("serve.engine.decode_dispatch", useful=useful,
                               capacity=self.n_slots * self.chunk,
                               active=len(plan), live_kv_tokens=live_kv,
                               **routed):
                 (self._kc, self._vc, self._last_d, self._pos_d, out_d,
-                 experts_d) = \
+                 experts_d, *ic) = \
                     self._decode(self._params, self._kc, self._vc,
                                  jnp.asarray(self.pool.block_table.copy()),
                                  self._last_d, self._pos_d,
                                  jnp.asarray(self._active.copy()),
                                  jnp.asarray(self._temp.copy()),
                                  jnp.asarray(self._topk.copy()),
-                                 jnp.asarray(self._skeys.copy()))
+                                 jnp.asarray(self._skeys.copy()), self._ic)
+                self._ic = ic[0] if ic else None
                 self._pos = np.where(
                     self._active, np.minimum(self._pos + self.chunk, S),
                     self._pos).astype(np.int32)

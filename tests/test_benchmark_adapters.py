@@ -1,8 +1,11 @@
 """The benchmark's adapter contract (benchmark/models/<arch>.py), seen by
 tier-1: the cluster-free cases of benchmark/tests/test_benchmark.py, imported
-by name and run for the adapters `llama` and `olmoe`, and what `olmoe` adds:
-its refusals, its counts against a hand count, its readers on a synthetic
-trace and on the engine's own spans. No cluster, no port, no clock.
+by name and run for the adapters `llama`, `olmoe` and `keye`, and what
+`olmoe` adds: its refusals, its counts against a hand count, its readers on a
+synthetic trace and on the engine's own spans; and for `keye` its manifest
+entries against the catalog's row and the sparse-attention readers on a
+synthetic trace (its block against its reference is tests/test_keye.py). No
+cluster, no port, no clock.
 """
 
 import dataclasses
@@ -19,7 +22,7 @@ sys.path.insert(0, ROOT)
 from benchmark import models, program_trace  # noqa: E402
 from benchmark.tests import test_benchmark as cases  # noqa: E402
 
-ARCHS = ["llama", "olmoe"]
+ARCHS = ["llama", "olmoe", "keye"]
 # config.json of allenai/OLMoE-1B-7B-0125-Instruct, as the catalog beside the
 # model-configs guide has it.
 OLMOE_PUBLISHED = dict(
@@ -312,3 +315,135 @@ def test_engine_spans_carry_what_the_readers_read(tmp_path, monkeypatch):
     assert 1.0 <= _reader("expert_load_max_over_mean")({}) <= 8.0
     assert routed["decode_experts_touched"] >= \
         sum(s.args["experts_touched"] for s in chunks)
+
+
+# -- arch `keye`: the manifest's entries, and the sparse-attention readers ----
+
+# `config` of the catalog's row Keye-VL-2.0-30B-A3B (the language model's keys
+# of Kwai-Keye/Keye-VL-2.0-30B-A3B config.json).
+KEYE_PUBLISHED = dict(
+    attention_bias=False, decoder_sparse_step=1, head_dim=128,
+    hidden_act="silu", hidden_size=2048, intermediate_size=6144,
+    max_position_embeddings=262144, max_window_layers=48, mlp_only_layers=[],
+    model_type="KeyeVL2", moe_intermediate_size=768, norm_topk_prob=True,
+    num_attention_heads=32, num_experts=128, num_experts_per_tok=8,
+    num_hidden_layers=48, num_key_value_heads=4, num_local_experts=128,
+    rms_norm_eps=1e-06,
+    rope_scaling={"mrope_section": [16, 24, 24], "rope_type": "default",
+                  "type": "default"},
+    rope_theta=10000000,
+    sa_config={"indexer_head_dim": 64, "indexer_num_heads": 16,
+               "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+               "q_chunk_size": 512, "topk": 2048},
+    sliding_window=None, tie_word_embeddings=False, use_sliding_window=False,
+    vocab_size=151936)
+
+
+def test_keye_manifest_entries_are_the_catalogs_row_cut_in_depth_alone():
+    manifest = cases.load(ROOT, "BENCHMARK.json")
+    entry = next(c for c in manifest["configs"]
+                 if c["name"] == "keye-vl-2.0-30b-a3b-serve")
+    cfg = cases.load(ROOT, entry["file"])
+    assert entry["reduced"] == ["num_hidden_layers"] == list(cfg["reduced"])
+    differs = {k for k, v in KEYE_PUBLISHED.items() if cfg.get(k, "-") != v}
+    assert differs == {"num_hidden_layers"}
+    cut = cfg["reduced"]["num_hidden_layers"]
+    assert cut["published"] == 48 and 4 <= cut["run"] <= 7
+    assert cut["run"] == cfg["num_hidden_layers"]
+    assert entry["source"] == cfg["source_url"] and cfg["arch"] == "keye"
+    eng = cfg["deployment"]["engine"]
+    assert eng["kv_pages"] == 1 + eng["n_slots"] * eng["max_seq"] // eng["page_size"]
+    models.adapter("keye").check_supported(cfg)
+    cell = next(w for w in manifest["workloads"]
+                if w["name"] == "serve-longdoc-keye")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("keye-vl-2.0-30b-a3b-serve", "longdoc-qa-keye", 1)
+    mix = cases.load(cases.BENCH, "traffic", "longdoc-qa-keye.json")
+    assert mix["kind"] == "serve_closed_checked"
+    # every prompt lands in the engine's widest bucket, past top-k, and a
+    # request fits max_seq; the check holds one prompt in that bucket
+    top, seq = cfg["sa_config"]["topk"], eng["max_seq"]
+    assert seq // 2 < mix["prompt_tokens"]["min"] and top < seq // 2
+    assert mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"] <= seq
+    assert any(seq // 2 < n < seq - mix["check"]["tokens"]
+               for n in mix["check"]["prompt_lengths"])
+    reported = {m["name"] for g in ("end_to_end", "per_layer")
+                for m in manifest[g]
+                if "serve-longdoc-keye" in m.get("workloads", ())}
+    assert reported >= {
+        "batch_tokens_per_s", "prefill_ms_per_ktok", "kv_pages_peak_pct",
+        "prefill_moe_ms_per_ktok", "decode_moe_ms",
+        "moe_experts_roofline_pct", "expert_load_max_over_mean",
+        "prefill_index_ms_per_ktok", "decode_sparse_attn_ms",
+        "sparse_decode_roofline_pct", "index_roofline_pct",
+        "selected_share_pct"}
+    assert "decode_attn_roofline_pct" not in reported  # not its kernel
+
+
+def test_sparse_attention_readers_on_a_synthetic_trace(monkeypatch):
+    from benchmark import peaks, sparse_attn_trace
+
+    P = "jit(prefill)/layers/while/body/"
+    D = "jit(decode)/while/body/layers/while/body/"
+    ops = [("jit(prefill)/layers/while", 1000, 2000),
+           (P + "qkv/dot_general:", 1000, 1100),
+           (P + "attn/select/pallas_call:", 1100, 1500),
+           (P + "attn/sparse_attn/pallas_call:", 1500, 1800),
+           (P + "attn/transpose:", 1800, 1900),
+           ("jit(decode)/while", 3000, 4000),
+           (D + "attn/indexer/dot_general:", 3000, 3100),
+           (D + "attn/select/top_k:", 3100, 3300),
+           (D + "attn/sparse_attn/gather:", 3300, 3700),
+           (D + "attn/reshape:", 3700, 3750),
+           (D + "mlp/experts/ragged_dot:", 3750, 3900)]
+    modules = [("jit_poke", 0, 10), ("jit_prefill", 1000, 2000),
+               ("jit_decode", 3000, 4000), ("jit_poke", 5000, 5010)]
+    Span = program_trace.Span
+    spans = [
+        Span("serve.engine.admit", 900, 950, dict(
+            rid=7, kind="prefill", prompt_tokens=8000, bucket=8192,
+            queue_wait_us=1)),
+        Span("serve.engine.emit", 2010, 2020, dict(rid=7, kind="first")),
+        Span("serve.engine.decode_dispatch", 2900, 2950, dict(
+            useful=16, capacity=32, active=16, selected_keys=2 * 16 * 2048,
+            live_keys=2 * 16 * 7000))]
+    t = program_trace.ProgramTrace(spans, modules, ops)
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    # program_trace's own vocabulary still charges the deeper scopes to `attn`
+    assert t.scope_ms("jit_decode")["attn"] == pytest.approx(750 / 1e6)
+    per = sparse_attn_trace.by_scope(t, t.whole_modules("jit_decode"))
+    assert per == [{"indexer": 100, "select": 200, "sparse_attn": 400,
+                    "attn": 50, "experts": 150, "": 100}]
+    m = dict(KEYE_PUBLISHED, arch="keye", num_hidden_layers=1,
+             dtypes={"params": "bfloat16", "activations": "bfloat16"},
+             deployment={"engine": {"decode_chunk": 2}})
+    run = {"config": m, "cell": "x", "seed": 0,
+           "device": {"kind": "TPU v5 lite"}}
+    assert _reader("decode_sparse_attn_ms")(run) == \
+        pytest.approx(700 / 1e6 / 2)
+    assert _reader("prefill_index_ms_per_ktok")(run) == \
+        pytest.approx(400 / 1e6 / 8.0)
+    assert _reader("selected_share_pct")(run) == \
+        pytest.approx(100 * 2048 / 7000)
+    counts = models.adapter("keye").counts
+    f, b = peaks.peak("TPU v5 lite", "bf16_flops_per_s"), \
+        peaks.peak("TPU v5 lite", "hbm_bytes_per_s")
+    ops_, byts = counts.sparse_decode_counts(m, 2 * 16 * 2048, 2 * 16 * 7000, 2)
+    assert byts / b > ops_ / f             # the gather's bytes bound it
+    assert _reader("sparse_decode_roofline_pct")(run) == \
+        pytest.approx(100 * (byts / b) / (700 / 1e9))
+    ops_, byts = counts.index_select_ops_bytes(m, 8000, 2)
+    assert _reader("index_roofline_pct")(run) == \
+        pytest.approx(100 * max(ops_ / f, byts / b) / (400 / 1e9))
+    # a program without the scopes or counters (the parent; a dense model)
+    deeper = re.compile("/(indexer|select|sparse_attn)")
+    plain = program_trace.ProgramTrace(
+        [Span(sp.name, sp.start, sp.end, {
+            k: v for k, v in sp.args.items()
+            if k not in ("selected_keys", "live_keys")}) for sp in spans],
+        modules, [(deeper.sub("", p), s, e) for p, s, e in ops])
+    monkeypatch.setattr(program_trace, "load", lambda run: plain)
+    for name in ("decode_sparse_attn_ms", "prefill_index_ms_per_ktok",
+                 "selected_share_pct", "sparse_decode_roofline_pct",
+                 "index_roofline_pct"):
+        assert _reader(name)(run) is None, name

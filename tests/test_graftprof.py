@@ -114,23 +114,28 @@ def test_native_ring_roundtrip_and_thread_registry():
 
 @pytest.mark.skipif(not graftprof.available(), reason="native lib missing")
 def test_gil_probe_times_c_extension_hold():
-    import ctypes
-    # PyDLL calls do NOT release the GIL — usleep() here models a
-    # C extension crunching under the lock. The wall-stack sampler is
-    # blind to these windows (it needs the GIL to run); the native
-    # probe times exactly them.
-    libc = ctypes.PyDLL(None)
+    import random
+    # One C call that never releases the GIL and burns CPU under it — a C
+    # extension crunching under the lock: `list.sort` on floats, ~0.2 s a
+    # call. The wall-stack sampler is blind to these windows (it needs the
+    # GIL to run); the native probe times exactly them. (A hold that SLEEPS
+    # under the lock, `PyDLL(None).usleep`, leaves every tick idle: the
+    # sampler then stretches its period 16-fold and probes every 1.28 s, and
+    # whether one probe landed inside a 600 ms window was luck of the phase:
+    # the test passed or failed in streaks with the order of the suite.)
+    rng = random.Random(0)
+    data = [rng.random() for _ in range(400_000)]
     before = graftprof.gil_wait_ns()
     assert graftprof.start(hz=100)
     try:
-        for _ in range(6):
-            libc.usleep(100_000)  # 100 ms GIL hold, 600 ms total
+        for _ in range(4):
+            sorted(data)
     finally:
         graftprof.stop()
     waited = graftprof.gil_wait_ns() - before
     assert graftprof.gil_probes() > 0
     assert waited > 50_000_000, \
-        f"GIL probe saw only {waited} ns across a 600 ms hold"
+        f"GIL probe saw only {waited} ns across the holds"
 
 
 # ---------------------------------------------------------------------------

@@ -88,3 +88,67 @@ class TestPagedKvLayout:
         np.testing.assert_array_equal(
             np.asarray(kc[self.LAYER, 4, :, steps:]), 0.0)
 
+
+
+class TestRowsByTokenAndIndexerKeys:
+    """The layout a reader that gathers positions asks for (K and V by
+    token), and the second kind of row under the same block table (an
+    indexer's keys): the same pages, the same null page, the same writers'
+    contract as the arena above."""
+
+    PAGE, MAXP, HD, KVH, ID, LAYERS = 8, 4, 16, 2, 4, 2
+
+    def test_by_token_holds_what_the_head_major_arena_holds(self):
+        page, hd, KVH, L = self.PAGE, self.HD, self.KVH, self.LAYERS
+        rng = np.random.default_rng(1)
+        bt = np.zeros((2, self.MAXP), np.int32)
+        bt[0, :3], bt[1, :1] = (7, 2, 5), (4,)
+        ks = jnp.asarray(rng.standard_normal((L, 16, KVH, hd)), jnp.float32)
+        vs = jnp.asarray(rng.standard_normal((L, 16, KVH, hd)), jnp.float32)
+        arenas = {}
+        for by_token in (False, True):
+            kc, vc = paged_kv.empty(L, 9, KVH, page, hd, jnp.float32,
+                                    by_token=by_token)
+            assert kc.shape == ((L, 9, page, KVH * hd) if by_token
+                                else (L, 9, KVH, page, hd))
+            kc, vc = jax.jit(paged_kv.write_prompt)(
+                kc, vc, jnp.asarray(bt[0]), ks, vs)
+            for step in range(3):      # slot 0 crosses into its third page
+                w = np.array([14 + step, step], np.int32)
+                k = jnp.asarray(rng.standard_normal((2, KVH, hd)), jnp.float32)
+                kc, vc = jax.jit(paged_kv.write_token)(
+                    kc, vc, jnp.int32(1), jnp.asarray(bt), jnp.asarray(w),
+                    jnp.asarray([True, step < 2]), k, -k)
+            arenas[by_token] = (np.asarray(kc), np.asarray(vc))
+            rng = np.random.default_rng(1)     # the same draws again
+            rng.standard_normal((2, L, 16, KVH, hd))
+        for a, b in zip(arenas[False], arenas[True]):
+            np.testing.assert_array_equal(
+                a.transpose(0, 1, 3, 2, 4).reshape(b.shape), b)
+        assert arenas[True][0][1, 5, 0].any()          # position 16, layer 1
+
+    def test_indexer_keys_go_where_k_and_v_go(self):
+        page, L, dim = self.PAGE, self.LAYERS, self.ID
+        rng = np.random.default_rng(2)
+        bt = np.zeros((3, self.MAXP), np.int32)
+        bt[0, :3], bt[1, :1] = (7, 2, 5), (4,)
+        ic = paged_kv.empty_index(L, 9, page, dim, jnp.float32)
+        assert ic.shape == (L, 9, page, dim)
+        iks = rng.standard_normal((L, 16, dim)).astype("f4")
+        iks[:, 13:] = 0.0                  # a prompt of 13 in a bucket of 16
+        ic = jax.jit(paged_kv.write_prompt_rows)(ic, jnp.asarray(bt[0]),
+                                                  jnp.asarray(iks))
+        np.testing.assert_array_equal(np.asarray(ic[:, 7]), iks[:, :page])
+        np.testing.assert_array_equal(np.asarray(ic[:, 2]), iks[:, page:])
+        held = np.asarray(ic)
+        ik = rng.standard_normal((3, dim)).astype("f4")
+        ic = jax.jit(paged_kv.write_token_rows)(
+            ic, jnp.int32(1), jnp.asarray(bt),
+            jnp.asarray([13, 0, 3], np.int32),
+            jnp.asarray([True, True, False]), jnp.asarray(ik))
+        got = np.asarray(ic)
+        np.testing.assert_array_equal(got[1, 2, 13 - page], ik[0])
+        np.testing.assert_array_equal(got[1, 4, 0], ik[1])
+        np.testing.assert_array_equal(got[1, 0, 0], ik[2])   # idle: null page
+        changed = got != held
+        assert changed.sum() <= 3 * dim and not changed[0].any()

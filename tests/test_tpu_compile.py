@@ -231,6 +231,90 @@ def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
 
 
 # ---------------------------------------------------------------------------
+# Sparse attention (ops/sparse_attention.py) at Keye-VL-2.0's widths
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kernel", ["index_select", "masked_flash"])
+def test_sparse_attention_kernels_compile_for_v5e(topo, kernel):
+    """Prefill's two kernels in the 8,192 bucket: 16 indexer heads of 64,
+    top-k 2,048; GQA 32/4 heads of 128 under the selection's mask."""
+    from ray_tpu.ops import sparse_attention as sa
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    S = 8192
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if kernel == "index_select":
+        lowered = jax.jit(
+            lambda qi, ki, w: sa._index_select_pallas(qi, ki, w, 2048)).lower(
+            sds((S, 16, 64), jnp.bfloat16), sds((S, 64), jnp.bfloat16),
+            sds((S, 16), jnp.float32))
+    else:
+        kv = sds((4, S, 128), jnp.bfloat16)
+        lowered = jax.jit(lambda q, k, v, m: sa._masked_flash_pallas(
+            q, k, v, m, sm_scale=128 ** -0.5)).lower(
+            sds((4, 8, S, 128), jnp.bfloat16), kv, kv, sds((S, S), jnp.int8))
+    text = lowered.compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_sparse_decode_leaves_the_arenas_where_they_lie_on_v5e(
+        topo, monkeypatch):
+    """The decode chunk of a model with an indexer, at Keye's widths and the
+    cell's engine sizes (2 layers): K, V and the indexer keys ride the loops
+    as carries, and the gather of the selected positions reads the K/V arena
+    (by token: `[L, pages, page, KVH * hd]`) as rows. Indexed through its
+    dimensions instead, XLA re-lays the whole arena and copies it (1 GiB at
+    4 layers) to and from every page write, every layer of every step."""
+    import json
+    import re
+
+    from benchmark import models
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.serve.engine import _build_fns
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/keye-vl-2.0-30b-a3b-serve.json")) as f:
+        config = json.load(f)
+    config["num_hidden_layers"] = 2
+    eng = config["deployment"]["engine"]
+    cfg = models.adapter("keye").build_config(config, config["dtypes"],
+                                              eng["max_seq"])
+    ns, page = eng["n_slots"], eng["page_size"]
+    maxp = cfg.max_seq // page
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    _, decode, _, _, empty = _build_fns(cfg, ns, eng["decode_chunk"], page,
+                                        eng["kv_pages"])
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)))))
+    kc, vc, ic = (sds(x.shape, x.dtype) for x in jax.eval_shape(empty))
+    compiled = decode.lower(
+        params, kc, vc, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
+        sds((ns,), jnp.int32), sds((ns,), jnp.bool_), sds((ns,), jnp.float32),
+        sds((ns,), jnp.int32), sds((ns, 2), jnp.uint32), ic).compile()
+    # (The indexer keys' arena, 64 wide under 128 lanes, is re-tiled once at
+    # the chunk's entry and exit: 2 x 67 MB a chunk of 8 steps, not a layer.)
+    shapes = {tuple(kc.shape), tuple(kc.shape[1:])}
+    moved = [(name, op) for name, dims, op in re.findall(
+        r"%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", compiled.as_text())
+        if tuple(int(d) for d in dims.split(",")) in shapes
+        and (op in ("copy", "transpose") or "dynamic-" in op + name)]
+    assert not moved, moved
+    assert kc.shape == (2, eng["kv_pages"], page, 4 * 128)
+    one_slab = kc.shape[1] * page * kc.shape[3] * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_slab
+
+
+# ---------------------------------------------------------------------------
 # Chip pinning env (no compiler needed)
 # ---------------------------------------------------------------------------
 
