@@ -26,13 +26,16 @@ engine is OURS):
 - **Sync-free dispatch loop + emitter thread**: the engine loop ONLY
   dispatches device work (prefills, decode chunks, slot pokes) — every
   host<->device sync (fetching first tokens and chunk outputs) happens
-  on a separate EMITTER thread consuming a bounded FIFO. Slot/page
-  control state advances deterministically on the host (token VALUES
-  are the only device-dependent output), so chunks dispatch
-  back-to-back and admissions slot in mid-pipeline; the host<->device
-  round-trip is paid off the critical path. The FIFO bound (see
-  `_emit_q`) is the pipeline depth. One fixed-shape XLA program serves
-  every step (no recompiles).
+  on a separate EMITTER thread consuming a FIFO. Slot/page control
+  state advances deterministically on the host (token VALUES are the
+  only device-dependent output), so chunks dispatch back-to-back and
+  admissions slot in mid-pipeline; the host<->device round-trip is paid
+  off the critical path. The pipeline's depth is a count the loop owns:
+  it dispatches a decode chunk only while fewer than `_DEPTH` are in
+  flight (dispatched, output not yet fetched by the emitter), and the
+  wait for room is one a submit ends, so an arrival with a free slot is
+  admitted at once and its prefill queues behind at most `_DEPTH` chunks.
+  One fixed-shape XLA program serves every step (no recompiles).
 
 A small fixed set of compiled programs serves all traffic: one prefill
 per power-of-2 BUCKET width (a short prompt pays a short prefill — the
@@ -62,6 +65,14 @@ logger = get_logger("serve.engine")
 # rows of OLMoE's widths), so a wider bucket goes through in blocks of this
 # many rows; each row is computed from itself alone, so nothing changes.
 _MOE_ROWS = 4096
+
+# Decode chunks in flight (dispatched, output not yet fetched) beyond which the
+# loop dispatches no other: one executing and one queued behind it. The second
+# is all that keeps the device from idling between chunks (the host's share of
+# a 64 ms chunk is about 3 ms); every one beyond it buys nothing and costs each
+# arrival a chunk of waiting before its prefill. PERF.md section 6, PR 33, has
+# the measurement, depth 1's included.
+_DEPTH = 2
 
 
 def _make_prefill_core(mcfg):
@@ -502,6 +513,7 @@ class Engine:
         # say a request or a chunk at a time).
         self.admitted = 0
         self.queue_wait_s_sum = 0.0
+        self.admit_chunks_ahead = 0        # chunks in flight, summed over admits
         self.prefill_tokens = 0
         self.prefill_padded_tokens = 0     # bucket width less the prompt
         self.decode_chunks = 0
@@ -528,8 +540,12 @@ class Engine:
         self._touched_last_chunk = 0
         self._next_rid = 0
         self._pending: deque = deque()
-        self._plock = threading.Lock()
-        self._wake = threading.Event()
+        # What the loop waits on (`_stand`), under one lock: the pending
+        # queue and its `_next_rid` (a submit notifies), and the decode
+        # chunks in flight, which the loop raises at dispatch and the
+        # emitter lowers, and notifies, once a chunk's output is fetched.
+        self._cv = threading.Condition()
+        self._in_flight = 0
         self._stop = False
         self.error: Optional[str] = None
         # Traceback of a prefill bucket that failed to compile in the
@@ -570,11 +586,10 @@ class Engine:
                 self._last_d, self._pos_d, 0, 0, 0)
         int(first)
         # Emission FIFO: the dispatch loop enqueues device arrays; the
-        # emitter thread performs the host syncs. maxsize bounds how far
-        # dispatch can run ahead of the device (pipeline depth): 2 keeps
-        # chunks back-to-back while a newly-arrived request's prefill
-        # never queues behind more than 2 chunks.
-        self._emit_q: "queue.Queue" = queue.Queue(maxsize=2)
+        # emitter thread performs the host syncs. No `put` blocks: it holds
+        # at most `_DEPTH` chunks (the loop's own count, `_in_flight`) and a
+        # "first" item a slot.
+        self._emit_q: "queue.Queue" = queue.Queue()
         self._emitter = threading.Thread(target=self._emit_loop,
                                          daemon=True, name="llm-emit")
         self._emitter.start()
@@ -731,18 +746,21 @@ class Engine:
 
     def _enqueue(self, req: _Request) -> "queue.Queue":
         req.ctx = tracing.context()
-        with self._plock:
+        with self._cv:
             req.rid = self._next_rid
             self._next_rid += 1
             req.t_submit = time.monotonic()
             self._pending.append(req)
-        self._wake.set()
+            self._cv.notify_all()    # the loop admits it now, room or none
         return req.out
 
     def counters(self) -> Dict[str, Any]:
         """Running totals since the engine started: the operator's view of
         what `serve.engine.admit` and `serve.engine.decode_dispatch` spans
-        say one at a time. Occupancy is `decode_useful_tokens` over
+        say one at a time. `admit_chunks_ahead` over `admitted` is the
+        decode chunks that were in flight when a request was admitted, which
+        its prefill queued behind (`_DEPTH` at most). Occupancy is
+        `decode_useful_tokens` over
         `decode_chunks * n_slots * chunk`; padding is
         `prefill_padded_tokens` over it plus `prefill_tokens`.
         `live_kv_tokens` over `decode_chunks * n_slots * max_seq` is the
@@ -755,7 +773,8 @@ class Engine:
         model adds `decode_selected_keys` over `decode_live_keys`: the share
         of the live positions its decode steps read K and V of."""
         out = {k: getattr(self, k) for k in (
-            "admitted", "queue_wait_s_sum", "prefill_tokens",
+            "admitted", "queue_wait_s_sum", "admit_chunks_ahead",
+            "prefill_tokens",
             "prefill_padded_tokens", "decode_chunks",
             "decode_useful_tokens", "live_kv_tokens", "peak_pages_used",
             "n_slots", "chunk")}
@@ -768,13 +787,11 @@ class Engine:
         return out
 
     def stop(self) -> None:
-        self._stop = True
-        self._wake.set()
+        with self._cv:
+            self._stop = True
+            self._cv.notify_all()
         self._thread.join(timeout=10)
-        try:
-            self._emit_q.put(None, timeout=10)  # sentinel: drain + exit
-        except queue.Full:
-            pass
+        self._emit_q.put(None)  # sentinel: drain + exit
         self._emitter.join(timeout=30)
         # Join the background bucket warmer too: a daemon thread still
         # inside an XLA compile at interpreter shutdown aborts the
@@ -794,11 +811,11 @@ class Engine:
         inactive and never touches its freshly-allocated pages; the
         prefill + poke ops simply queue behind it on the device.
         Prefills for a BURST of admissions are all dispatched (and their
-        first-token transfers started) before anything blocks, so N
-        admissions cost ~one round-trip, not N."""
+        first-token transfers started) before any is handed to the
+        emitter, so N admissions cost ~one round-trip, not N."""
         emits: List[Tuple] = []  # (req, first, done, experts)
         while True:
-            with self._plock:
+            with self._cv:
                 req = self._pending[0] if self._pending else None
             if req is None:
                 break
@@ -809,9 +826,10 @@ class Engine:
             if slot is None or self.pool.free < need:
                 break  # head-of-line waits for a finish
 
-            with self._plock:
+            with self._cv:
                 self._pending.popleft()
                 left = len(self._pending)
+                ahead = self._in_flight
             adopting = req.adopt_kv is not None
             width = req.adopt_kv[0].shape[1] if adopting else len(req.ids)
             # Only WARMED buckets are eligible (round up until the
@@ -821,6 +839,7 @@ class Engine:
             waited = time.monotonic() - req.t_submit
             self.admitted += 1
             self.queue_wait_s_sum += waited
+            self.admit_chunks_ahead += ahead
             if not adopting:
                 self.prefill_tokens += width
                 self.prefill_padded_tokens += bucket - width
@@ -829,11 +848,10 @@ class Engine:
                     kind="adopt" if adopting else "prefill",
                     prompt_tokens=len(req.ids), bucket=bucket,
                     queue_wait_us=int(waited * 1e6), pending=left,
-                    pages_free=self.pool.free - need):
+                    pages_free=self.pool.free - need, chunks_ahead=ahead):
                 emits.append(self._place(req, slot, need, bucket))
         # Start EVERY device->host copy first (async), THEN enqueue: a
-        # burst overlaps all its transfers even when the bounded
-        # _emit_q.put blocks partway through the enqueue loop.
+        # burst overlaps all its transfers.
         for _, first, _, _ in emits:
             try:
                 first.copy_to_host_async()
@@ -929,7 +947,7 @@ class Engine:
             for slot in range(self.n_slots):
                 self._finish(slot)
             while True:
-                with self._plock:
+                with self._cv:
                     req = self._pending.popleft() if self._pending else None
                 if req is None:
                     break
@@ -968,7 +986,14 @@ class Engine:
                 else:  # ("chunk", out_d, plan, experts)
                     _, out_d, plan, experts = item
                     with tracing.span("serve.engine.emit", kind="chunk"):
-                        out_h = np.asarray(out_d)
+                        try:
+                            out_h = self._fetch(out_d)
+                        finally:
+                            # The chunk has left the device (or failed):
+                            # room for the next, before the streams are fed.
+                            with self._cv:
+                                self._in_flight -= 1
+                                self._cv.notify_all()
                         for slot, req, take, fin in plan:
                             toks = [int(t) for t in out_h[slot, :take]]
                             if toks:
@@ -989,6 +1014,11 @@ class Engine:
                     for _, req, _, _ in item[2]:
                         req.out.put(None)
 
+    def _fetch(self, out_d):
+        """A decode chunk's tokens on the host: the emitter's wait for the
+        device (a test holds the emitter here)."""
+        return self._np.asarray(out_d)
+
     def _count_experts(self, experts) -> int:
         """Emitter thread: add one program's `expert_stats` to the running
         `expert_tokens`; returns its distinct experts touched."""
@@ -996,18 +1026,33 @@ class Engine:
         self.expert_tokens = self.expert_tokens + stats[:-1]
         return int(stats[-1])
 
+    def _stand(self, ready) -> None:
+        """The loop's one wait: admit what has arrived, then stand until
+        `ready()` (asked under `_cv`) or `stop()`. A submit ends the wait for
+        another round of admission first, so an arrival with a free slot and
+        pages is admitted whether or not the pipeline has room; the emitter
+        ends it when it has fetched a chunk's output."""
+        while not self._stop:
+            with self._cv:
+                seen = self._next_rid
+            self._admit()
+            with self._cv:
+                self._cv.wait_for(lambda: self._stop or ready()
+                                  or self._next_rid != seen)
+                if ready():
+                    return
+
     def _run_inner(self) -> None:
         np, jnp = self._np, self._jnp
         S = self.mcfg.max_seq
-        while not self._stop:
-            # Admission is pipeline-safe: an in-flight chunk saw the new
-            # slot as inactive, and its prefill/poke queue behind that
-            # chunk on the device.
-            self._admit()
-            if not self._active.any():
-                self._wake.wait(timeout=0.5)
-                self._wake.clear()
-                continue
+        while True:
+            # Idle until an admission makes a slot live. Admission is
+            # pipeline-safe: an in-flight chunk saw the new slot as
+            # inactive, and its prefill/poke queue behind that chunk on
+            # the device.
+            self._stand(self._active.any)
+            if self._stop:
+                return
             # Predict this chunk's control outcome on the host: per-slot
             # emit counts and finishes depend only on pos/produced, never
             # on token values — so the chunk's finishes free slots/pages
@@ -1025,10 +1070,6 @@ class Engine:
                        or self._pos[slot] + valid >= S)
                 req.produced += take
                 plan.append((slot, req, take, fin))
-            if not plan:  # defensive: never hot-spin
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
-                continue
             # COPIES, not views: jnp.asarray may alias numpy memory
             # (zero-copy on the CPU backend), and this loop mutates the
             # block table and _active in place while the dispatched chunk
@@ -1080,7 +1121,11 @@ class Engine:
                     out_d.copy_to_host_async()
                 except AttributeError:
                     pass
-            # Blocks when the emitter is `maxsize` chunks behind — the
-            # pipeline-depth bound, which this span shows from the host.
+            with self._cv:
+                self._in_flight += 1
+            self._emit_q.put(("chunk", out_d, plan, experts_d))
+            # How long the loop then stood for want of pipeline room: fewer
+            # than `_DEPTH` chunks in flight (or no slot left to decode for).
             with tracing.span("serve.engine.emit_block"):
-                self._emit_q.put(("chunk", out_d, plan, experts_d))
+                self._stand(lambda: self._in_flight < _DEPTH
+                            or not self._active.any())

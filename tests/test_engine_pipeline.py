@@ -1,0 +1,257 @@
+"""serve/engine.py's dispatch loop: the decode chunks it keeps in flight
+(`_DEPTH`), the wait a submit ends, what `chunks_ahead` counts, that a
+request's tokens do not depend on who shared its chunks, and that the loop and
+the emitter leave when told to. A tiny engine on the CPU; the emitter is held,
+slowed or broken at `Engine._fetch`, its one wait for the device."""
+
+import sys
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from ray_tpu.serve.engine import _DEPTH, Engine
+from test_tracing import _Profiled
+
+
+def _build():
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    cfg = LlamaConfig.tiny()
+    eng = Engine(init_params(cfg, jax.random.PRNGKey(0)), cfg, n_slots=4,
+                 decode_chunk=4, page_size=16)
+    while sorted(eng._warm) != sorted(eng.buckets):
+        assert not eng.warm_error, eng.warm_error
+        time.sleep(0.05)
+    return eng
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = _build()
+    yield eng
+    eng.stop()
+
+
+def _until(cond, seconds=20.0):
+    deadline = time.monotonic() + seconds
+    while not cond():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.002)
+
+
+def _drain(q, seconds=60.0):
+    """The stream's items up to its terminating None."""
+    got = []
+    while (item := q.get(timeout=seconds)) is not None:
+        got.append(item)
+    return got
+
+
+class _Fetch:
+    """`with _Fetch(engine, fn):` puts `fn(fetch, out_d)` in the place of the
+    emitter's fetch of a chunk; with no `fn`, every fetch is held until
+    `release()`."""
+
+    def __init__(self, eng, fn=None):
+        self.eng, self.fn = eng, fn or self._hold
+        self.gate = threading.Event()
+
+    def _hold(self, fetch, out_d):
+        assert self.gate.wait(60), "never released"
+        return fetch(out_d)
+
+    def release(self):
+        self.gate.set()
+
+    def __enter__(self):
+        fetch = self.eng._fetch
+        self.eng._fetch = lambda out_d: self.fn(fetch, out_d)
+        return self
+
+    def __exit__(self, *exc):
+        self.release()
+        del self.eng._fetch
+
+
+def _delta(eng, before):
+    now = eng.counters()
+    return {k: now[k] - before[k] for k in
+            ("admitted", "admit_chunks_ahead", "decode_chunks")}
+
+
+def test_loop_stops_at_the_depth_and_admits_an_arrival_meanwhile(
+        engine, tmp_path):
+    """With the emitter held at its first fetch, the loop dispatches `_DEPTH`
+    chunks and no more; a request submitted then is admitted and placed (its
+    prefill dispatched) behind those chunks, before anything is released."""
+    assert engine._emit_q.maxsize == 0          # no `put` can block the loop
+    before = engine.counters()
+    with _Profiled(tmp_path) as prof, _Fetch(engine) as emitter:
+        a = engine.submit(list(range(1, 9)), 40)
+        _until(lambda: engine._in_flight == _DEPTH)
+        time.sleep(0.3)                 # a loop that ran ahead would, now
+        assert _delta(engine, before)["decode_chunks"] == _DEPTH
+        b = engine.submit(list(range(1, 30)), 6)
+        _until(lambda: engine._active.sum() == 2)
+        assert _delta(engine, before) == {
+            "admitted": 2, "admit_chunks_ahead": _DEPTH,
+            "decode_chunks": _DEPTH}
+        assert engine._in_flight == _DEPTH and b.empty()
+        emitter.release()
+        got_a, got_b = _drain(a), _drain(b)
+    assert sum(map(len, got_a)) == 40 and sum(map(len, got_b)) == 6
+    assert engine._in_flight == 0
+
+    ahead = {s["prompt_tokens"]: (s["chunks_ahead"], end) for _, _, end, s in
+             prof.events("serve.engine.admit")}
+    assert ahead[8][0] == 0 and ahead[29][0] == _DEPTH
+    dispatched = [start for _, start, _, _ in
+                  prof.events("serve.engine.decode_dispatch")]
+    fetched = [end for n, _, end, s in prof.events("serve.engine.emit")
+               if n == "serve.engine.emit" and s["kind"] == "chunk"]
+    # b's admit span closed (its prefill and poke dispatched) with `_DEPTH`
+    # chunks dispatched and none fetched.
+    assert sum(t < ahead[29][1] for t in dispatched) == _DEPTH
+    assert ahead[29][1] < min(fetched)
+    assert len(dispatched) == len(fetched) == len(
+        [1 for n, *_ in prof.events("serve.engine.emit_block")])
+
+
+def test_chunks_ahead_is_0_on_an_idle_engine_and_at_most_the_depth(
+        engine, tmp_path):
+    rng = np.random.default_rng(33)
+    before = engine.counters()
+    with _Profiled(tmp_path) as prof:
+        assert sum(map(len, _drain(engine.submit([1, 2, 3], 9)))) == 9
+        streams = []
+        for _ in range(14):
+            n, m = int(rng.integers(3, 60)), int(rng.integers(2, 30))
+            streams.append((m, engine.submit(list(range(1, 1 + n)), m)))
+            time.sleep(float(rng.uniform(0, 0.004)))
+        for m, q in streams:
+            assert sum(map(len, _drain(q))) == m
+    ahead = [s["chunks_ahead"] for _, _, _, s in
+             prof.events("serve.engine.admit")]
+    assert len(ahead) == 15 and ahead[0] == 0
+    assert all(0 <= n <= _DEPTH for n in ahead)
+    assert _delta(engine, before)["admit_chunks_ahead"] == sum(ahead)
+
+
+def _mix(rng, n):
+    """(ids, max_tokens, sampling) a request: half greedy, half sampled."""
+    asks = []
+    for i in range(n):
+        ids = [int(t) for t in rng.integers(1, 256, int(rng.integers(3, 70)))]
+        sampling = {} if i % 2 else {
+            "temperature": 0.8, "top_k": 5, "seed": int(rng.integers(1 << 30))}
+        asks.append((ids, int(rng.integers(1, 40)), sampling))
+    return asks
+
+
+def test_a_request_streams_the_same_tokens_alone_or_mid_pipeline(engine):
+    """A seeded mix on 4 slots and 16 pages: requests join while chunks are
+    in flight (the emitter is slowed, so the pipeline stands at its depth),
+    leave, and free pages the later ones wait for. Each stream is what the
+    same request streams alone on the same engine."""
+    rng = np.random.default_rng(2033)
+    asks = _mix(rng, 12)
+    assert sum(engine.pool.pages_for(len(ids), m) for ids, m, _ in asks) \
+        > 2 * (engine.n_pages - 1)              # pages are reused
+    before = engine.counters()
+
+    def slow(fetch, out_d):
+        time.sleep(0.004)
+        return fetch(out_d)
+
+    with _Fetch(engine, slow):
+        streams = []
+        for ids, m, sampling in asks:
+            streams.append(engine.submit(ids, m, **sampling))
+            time.sleep(float(rng.uniform(0, 0.01)))
+        together = [_drain(q) for q in streams]
+    assert _delta(engine, before)["admit_chunks_ahead"] > 0   # joins mid-pipeline
+    assert engine.pages_in_use() == 0
+
+    for (ids, m, sampling), got in zip(asks, together):
+        alone = _drain(engine.submit(ids, m, **sampling))
+        assert len(got[0]) == 1                 # "first" precedes the chunks
+        assert all(1 <= len(c) <= engine.chunk for c in got[1:])
+        flat = [t for c in got for t in c]
+        assert flat == [t for c in alone for t in c]
+        assert len(flat) == min(m, engine.mcfg.max_seq - len(ids))
+
+
+def test_submits_from_many_threads_are_all_served_and_counted(engine):
+    """More submitting threads than cores under a short switch interval: no
+    submit is lost to the loop's wait, and the count in flight comes back to 0."""
+    before = engine.counters()
+    done, errors = [], []
+
+    def client(k):
+        try:
+            for j in range(4):
+                m = 2 + (k + j) % 7
+                got = _drain(engine.submit([1 + k, 2 + j, 3], m))
+                assert sum(map(len, got)) == m
+            done.append(k)
+        except Exception as e:          # reported by the test's own thread
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        clients = [threading.Thread(target=client, args=(k,))
+                   for k in range(16)]
+        for t in clients:
+            t.start()
+        for t in clients:
+            t.join(120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and len(done) == 16, errors
+    d = _delta(engine, before)
+    assert d["admitted"] == 16 * 4
+    assert 0 <= d["admit_chunks_ahead"] <= _DEPTH * d["admitted"]
+    assert engine._in_flight == 0 and engine.pages_in_use() == 0
+
+
+def _threads_of(eng):
+    return {eng._thread, eng._emitter, eng._warm_thread}
+
+
+def test_stop_ends_the_wait_for_room_and_leaves_no_thread():
+    eng = _build()
+    with _Fetch(eng) as emitter:
+        eng.submit(list(range(1, 9)), 40)
+        _until(lambda: eng._in_flight == _DEPTH)
+        stopper = threading.Thread(target=eng.stop)
+        stopper.start()
+        eng._thread.join(5)             # the loop stood waiting for room
+        assert not eng._thread.is_alive()
+        emitter.release()
+        stopper.join(30)
+        assert not stopper.is_alive()
+    assert not _threads_of(eng) & set(threading.enumerate())
+
+
+def test_a_failing_fetch_ends_its_streams_and_later_submits_raise():
+    eng = _build()
+
+    def broken(fetch, out_d):
+        raise RuntimeError("fetch failed")
+
+    with _Fetch(eng, broken):
+        a = eng.submit(list(range(1, 9)), 12)
+        b = eng.submit(list(range(1, 20)), 7)
+        got_a, got_b = _drain(a, 20), _drain(b, 20)
+        # the first tokens came out before the chunk that failed
+        assert [len(c) for c in got_a + got_b] == [1, 1]
+        assert "fetch failed" in eng.error
+        with pytest.raises(RuntimeError, match="fetch failed"):
+            eng.submit([1, 2, 3], 4)
+        # every chunk still leaves the count: the loop is not wedged
+        _until(lambda: not eng._active.any() and eng._in_flight == 0)
+    eng.stop()
+    assert not _threads_of(eng) & set(threading.enumerate())
