@@ -6,6 +6,9 @@ engine's prefill and its decode step (`serve/engine.py`) all call them.
                      (+ a sparse-attention indexer's queries, key and head
                      weights, from the same normed input)
   feed_forward       mlp_norm -> dense SwiGLU, or router + experts
+  mamba_mixer        a state-space layer's whole mixer, over a sequence or
+                     for one token a slot: its state is an argument and a
+                     result, so where the state lives is the caller's
 
 and the two ways a program that runs no gradient (serving) holds its layer
 stacks differently from training, each so that the compiler reads a layer's
@@ -31,6 +34,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.ops.norms import layer_norm, rms_norm
+from ray_tpu.ops.ssm import causal_conv, selective_scan, ssm_step
 
 
 def attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
@@ -115,6 +119,63 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
         gate = h @ lp["w_gate"].astype(dt)
         up = h @ lp["w_up"].astype(dt)
         return x + (jax.nn.silu(gate) * up) @ lp["w_down"].astype(dt), None
+
+
+def mamba_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
+                window=None, *, step: bool = False, length=None
+                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x + mixer(norm(x)) for a state-space (Mamba-1) layer, Jamba's: the
+    time step, B and C each RMS-normalised after their projection.
+
+      u, z  = split(norm(x) W_in)                               scope ssm_in
+      u     = silu(b + sum_k w[k] * u_{t-K+1+k})                      conv
+      r, B, C = split(u W_x), each RMS-normalised;
+      dt    = softplus(r W_dt + b_dt) (float32), A = -exp(A_log)
+                                                                ssm_params
+      s_t   = exp(dt_t (x) A) s_{t-1} + (dt_t u_t) (x) B_t;
+      y_t   = s_t . C_t + D u_t                                      scan
+      out   = x + (y * silu(z)) W_out                             ssm_out
+
+    x `[S, D]`, one sequence, from `state` `[N, Di]` and `window` `[K - 1,
+    Di]` (None: a sequence's start), through `ops.ssm.selective_scan`, which
+    also applies the gate; rows at and past `length` leave state and window
+    as they were. Or, with `step`, x `[ns, D]`, one token a slot, from
+    `state` `[ns, N, Di]` and `window` `[K - 1, ns, Di]` (`ops/slot_state.py`
+    has both layouts). -> (out, state, window)."""
+    dt = cfg.dtype
+    R, N, eps = cfg.ssm_dt_rank, cfg.ssm_state, cfg.norm_eps
+    with jax.named_scope("ssm_in"):
+        h = rms_norm(x, lp["norm"], eps)
+        u, z = jnp.split(h @ lp["in_proj"].astype(dt), 2, axis=-1)
+    with jax.named_scope("conv"):
+        if step:    # each slot a sequence of one row, its window its own
+            u, window = jax.vmap(causal_conv, (0, None, None, 1), (0, 1))(
+                u[:, None], lp["conv_w"], lp["conv_b"], window)
+            u = u[:, 0]
+        else:
+            u, window = causal_conv(u, lp["conv_w"], lp["conv_b"], window,
+                                    length)
+        u = jax.nn.silu(u).astype(dt)
+    with jax.named_scope("ssm_params"):
+        r, b, c = jnp.split(u @ lp["x_proj"].astype(dt), [R, R + N], axis=-1)
+        r = rms_norm(r, lp["dt_norm"], eps)
+        b = rms_norm(b, lp["b_norm"], eps)
+        c = rms_norm(c, lp["c_norm"], eps)
+        step_size = jax.nn.softplus(
+            jnp.dot(r, lp["dt_proj"].astype(dt),
+                    preferred_element_type=jnp.float32)
+            + lp["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(lp["A_log"].astype(jnp.float32))
+    with jax.named_scope("scan"):
+        if step:
+            y, state = ssm_step(u, step_size, a, b, c, lp["D"], state)
+        else:
+            y, state = selective_scan(u, step_size, a, b, c, lp["D"], state,
+                                      length, z=z)
+    with jax.named_scope("ssm_out"):
+        if step:
+            y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+        return x + y @ lp["out_proj"].astype(dt), state, window
 
 
 _QKV = ("wq", "wk", "wv")

@@ -68,6 +68,24 @@ class LlamaConfig:
     index_topk: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
+    # State-space (Mamba-1) layers among the attention layers, a hybrid
+    # stack: ssm_state > 0 => every layer whose index is not in `attn_layers`
+    # is a Mamba mixer (ops/ssm.py; `models.block.mamba_mixer`) of inner
+    # width ssm_expand * d_model, ssm_state states a channel, a causal
+    # convolution over ssm_conv inputs and a time-step projection of rank
+    # ssm_dt_rank; every layer keeps its feed-forward. The parameters are two
+    # stacks, `layers` (the attention layers, in order) and `mamba` (the
+    # rest), so no layer holds weights of the kind it is not.
+    ssm_state: int = 0
+    ssm_expand: int = 2
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 0
+    attn_layers: Optional[Tuple[int, ...]] = None
+    # False: attention takes no position signal at all (no RoPE), as in a
+    # hybrid whose state-space layers carry the order of the sequence.
+    rope: bool = True
+    # True: the head is the embedding transposed; `lm_head` is no leaf.
+    tie_embeddings: bool = False
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -83,6 +101,40 @@ class LlamaConfig:
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.attn_layers is not None:
+            object.__setattr__(self, "attn_layers",
+                               tuple(sorted(self.attn_layers)))
+        if bool(self.ssm_state) != (self.attn_layers is not None):
+            raise ValueError("ssm_state and attn_layers come together: the "
+                             "state-space widths and which layers are not "
+                             "state-space layers")
+
+    @property
+    def kv_layers(self) -> int:
+        """Layers that keep K and V: the attention layers."""
+        return self.n_layers if self.attn_layers is None \
+            else len(self.attn_layers)
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_expand * self.d_model
+
+    def segments(self) -> Tuple[Tuple[str, int, int], ...]:
+        """A hybrid stack in the order it runs: ("mamba", lo, hi), a run of
+        state-space layers as ordinals into the `mamba` stack, or ("attn",
+        a, a + 1), one attention layer as its ordinal into `layers`."""
+        out, a, m = [], 0, 0
+        for i in range(self.n_layers):
+            if i in self.attn_layers:
+                out.append(("attn", a, a + 1))
+                a += 1
+            else:
+                if out and out[-1][0] == "mamba":
+                    out[-1] = ("mamba", out[-1][1], m + 1)
+                else:
+                    out.append(("mamba", m, m + 1))
+                m += 1
+        return tuple(out)
 
     # ---- presets ----
     @staticmethod
@@ -141,20 +193,41 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             "w_up": ("layers", "embed", "mlp"),
             "w_down": ("layers", "mlp", "embed"),
         })
-    return {
+    out = {
         "embed": ("vocab", "embed"),
         "layers": layers,
         "final_norm": ("embed",),
         "lm_head": ("embed", "vocab"),
     }
+    if cfg.tie_embeddings:
+        del out["lm_head"]
+    if cfg.ssm_state:
+        out["mamba"] = {
+            "norm": ("layers", "embed"),
+            "in_proj": ("layers", "embed", "mlp"),
+            "conv_w": ("layers", None, "mlp"),
+            "conv_b": ("layers", "mlp"),
+            "x_proj": ("layers", "mlp", None),
+            "dt_norm": ("layers", None),
+            "b_norm": ("layers", None),
+            "c_norm": ("layers", None),
+            "dt_proj": ("layers", None, "mlp"),
+            "dt_bias": ("layers", "mlp"),
+            "A_log": ("layers", None, "mlp"),
+            "D": ("layers", "mlp"),
+            "out_proj": ("layers", "mlp", "embed"),
+            **{k: layers[k] for k in ("mlp_norm", "w_gate", "w_up",
+                                      "w_down")}}
+    return out
 
 
 def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
-    L, D, H, KVH = cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads
+    L, D, H, KVH = cfg.kv_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads
     hd, F, V = cfg.head_dim, cfg.d_ff, cfg.vocab_size
     pd = cfg.param_dtype
     # One list of keys for every model: a leaf's key is its place in it, so
-    # the indexer's leaves (the last) move no other model's weights.
+    # the indexer's leaves (the last) move no other model's weights, and the
+    # state-space stack draws from a list of its own (`_init_mamba`).
     ks = iter(jax.random.split(key, 16))
 
     def norm(shape, k, scale=0.02):
@@ -193,6 +266,14 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         "final_norm": jnp.ones((D,), pd),
         "lm_head": norm((D, V), next(ks)),
     }
+    if cfg.tie_embeddings:
+        del out["lm_head"]
+    if cfg.ssm_state:
+        if cfg.n_experts or cfg.index_topk:
+            raise NotImplementedError(
+                "a hybrid stack (ssm_state > 0) has a dense feed-forward and "
+                "plain attention: no sparse experts, no indexer")
+        out["mamba"] = _init_mamba(cfg, jax.random.fold_in(key, 1), norm)
     if cfg.index_topk:
         IH, Id = cfg.index_heads, cfg.index_head_dim
         layers.update({"wiq": norm((L, D, IH * Id), next(ks)),
@@ -201,6 +282,41 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                        "ik_norm": jnp.ones((L, Id), pd),
                        "ik_bias": jnp.zeros((L, Id), pd)})
     return out
+
+
+def _init_mamba(cfg: LlamaConfig, key: jax.Array, norm) -> Dict[str, Any]:
+    """The stack of state-space layers, each with its own feed-forward. The
+    channel axis is the minor one of every leaf (`ops/ssm.py`): `A_log` is
+    `[N, Di]` and the convolution's weights `[K, Di]`. A and the time step's
+    bias start as Mamba's own do (A = -(1..N); softplus(bias) log-uniform in
+    1e-3..1e-1), so that a state carries over hundreds of rows, not two."""
+    Lm = cfg.n_layers - cfg.kv_layers
+    D, F, Di, N = cfg.d_model, cfg.d_ff, cfg.ssm_inner, cfg.ssm_state
+    K, R, pd = cfg.ssm_conv, cfg.ssm_dt_rank, cfg.param_dtype
+    ks = iter(jax.random.split(key, 16))
+    step = jnp.exp(jax.random.uniform(
+        next(ks), (Lm, Di), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+    return {
+        "norm": jnp.ones((Lm, D), pd),
+        "in_proj": norm((Lm, D, 2 * Di), next(ks)),
+        "conv_w": norm((Lm, K, Di), next(ks), K ** -0.5),
+        "conv_b": norm((Lm, Di), next(ks)),
+        "x_proj": norm((Lm, Di, R + 2 * N), next(ks)),
+        "dt_norm": jnp.ones((Lm, R), pd),
+        "b_norm": jnp.ones((Lm, N), pd),
+        "c_norm": jnp.ones((Lm, N), pd),
+        "dt_proj": norm((Lm, R, Di), next(ks)),
+        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
+        "A_log": (jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[:, None]
+                  + jax.random.normal(next(ks), (Lm, N, Di)) * 0.02
+                  ).astype(pd),
+        "D": jnp.ones((Lm, Di), pd),
+        "out_proj": norm((Lm, Di, D), next(ks)),
+        "mlp_norm": jnp.ones((Lm, D), pd),
+        "w_gate": norm((Lm, D, F), next(ks)),
+        "w_up": norm((Lm, D, F), next(ks)),
+        "w_down": norm((Lm, F, D), next(ks)),
+    }
 
 
 def param_count(cfg: LlamaConfig) -> int:
@@ -262,7 +378,9 @@ def _layer_fwd(lp: Dict[str, jax.Array], x: jax.Array, cos, sin, positions,
     # serve/engine.py; scope names are the vocabulary a device trace is
     # reduced by (benchmark/program_trace.py).
     q, k, v, *index = attention_inputs(
-        lp, x, cfg, lambda t: apply_rope(t, cos, sin, positions),
+        lp, x, cfg,
+        (lambda t: apply_rope(t, cos, sin, positions)) if cfg.rope
+        else (lambda t: t),
         (lambda t: apply_rope(t, *index_tables)) if index_tables else None)
     with jax.named_scope("attn"):
         if index:
@@ -350,6 +468,11 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
                      ctx: Optional[ParallelContext] = None
                      ) -> Tuple[jax.Array, jax.Array]:
     """tokens [B, S] -> (logits [B, S, V] float32, MoE aux loss scalar)."""
+    if cfg.ssm_state:
+        raise NotImplementedError(
+            "state-space layers (ssm_state > 0) run through Serve only: the "
+            "training forward has no hybrid stack and `ops.ssm`'s kernel no "
+            "backward (ROADMAP, Reach)")
     dt = cfg.dtype
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
@@ -414,7 +537,11 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
 
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(dt))
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(dt))
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x,
+                                params["lm_head"].astype(dt))
         return logits.astype(jnp.float32), aux
 
 

@@ -18,6 +18,15 @@ engine is OURS):
   indexer (`mcfg.index_topk`) has a third array under the same block table,
   its indexer keys (`ic`, `paged_kv.empty_index`): the programs take and
   return it after everything else, and it is None for every other model.
+  A model with state-space layers (`mcfg.ssm_state`) keeps K and V for its
+  attention layers only, the arena's layers being their ordinals, and beside
+  it a per-SLOT recurrent state of fixed size (`ops/slot_state.py`): no
+  pages, overwritten whole by the prefill that admits a request into the
+  slot, moved by a decode step only where the slot is active, carried and
+  donated as the arena is; `state`, the programs' last argument and result,
+  None for every other model. Its stack is not a scan over identical layers
+  but SEGMENTS (`LlamaConfig.segments`): a scan over each run of state-space
+  layers, the attention layers between them inline.
 - **Reservation admission**: a request is admitted when the pages
   `PagePool.pages_for` says it can ever need are free: growth can then
   never fail mid-decode, so there is no preemption/recompute path.
@@ -84,7 +93,12 @@ def _make_prefill_core(mcfg):
     llm/_internal/serve/deployments/prefill_decode_disagg/ — there the
     split is two vLLM pools; here both halves share one traced core). A
     model with a sparse-attention indexer adds a sixth element, its indexer
-    keys [L, B, Id]."""
+    keys [L, B, Id]; a model with state-space layers, after `experts`
+    (None), its layers' final (ssm state [Lm, N, Di], convolution window
+    [Lm, K - 1, Di]) after the prompt's last real token, and its ks/vs are
+    those of the attention layers alone."""
+    if mcfg.ssm_state:
+        return _make_hybrid_prefill_core(mcfg)
     import jax
     import jax.numpy as jnp
 
@@ -179,6 +193,99 @@ def _make_prefill_core(mcfg):
     return core
 
 
+def _make_hybrid_prefill_core(mcfg):
+    """`_make_prefill_core` for a hybrid stack: the segments in order, a scan
+    over each run of state-space layers (the stacks stay whole, the body reads
+    its layer by index, as a scan reads its `xs`) and each attention layer
+    inline. Rows past `length` reach no real row: attention and the
+    convolution are causal, and the state-space layers are told `length`."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.block import (attention_inputs, feed_forward,
+                                      mamba_mixer)
+    from ray_tpu.ops.attention import flash_attention, repeat_kv
+    from ray_tpu.ops.norms import apply_rope, rms_norm, rope_frequencies
+
+    if mcfg.n_experts or mcfg.index_topk:
+        raise NotImplementedError(
+            "a hybrid stack serves a dense feed-forward and plain attention")
+    H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
+    dt = mcfg.dtype
+
+    def attention_layer(lp, x, rope):
+        B, Sq, _ = x.shape
+        q, k, v = attention_inputs(lp, x, mcfg, rope)
+        with jax.named_scope("attn"):
+            attn = flash_attention(q, repeat_kv(k, H // KVH),
+                                   repeat_kv(v, H // KVH), True)
+            attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd)
+        with jax.named_scope("attn_out"):
+            x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
+        x, _ = feed_forward(lp, x, mcfg)
+        return x, k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2)
+
+    def core(params, tokens, length):
+        if "wqkv" not in params["layers"]:
+            raise ValueError("a serving program takes `fuse_qkv(params)`")
+        width = tokens.shape[1]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+        if mcfg.rope:
+            with jax.named_scope("rope"):
+                cos, sin = rope_frequencies(hd, width, mcfg.rope_theta)
+            rope = lambda t: apply_rope(t, cos, sin)
+        else:
+            rope = lambda t: t
+
+        def mamba_layer(x, i):
+            lp = _layer_of(params["mamba"], i)
+            y, state, window = mamba_mixer(lp, x[0], mcfg, length=length)
+            y, _ = feed_forward(lp, y[None], mcfg)
+            return y, (state, window)
+
+        ks, vs, states, windows = [], [], [], []
+        with jax.named_scope("layers"):
+            for kind, lo, hi in mcfg.segments():
+                if kind == "attn":
+                    x, k, v = attention_layer(
+                        _layer_of(params["layers"], lo), x, rope)
+                    ks.append(k)
+                    vs.append(v)
+                else:
+                    x, (state, window) = jax.lax.scan(
+                        mamba_layer, x, jnp.arange(lo, hi))
+                    states.append(state)
+                    windows.append(window)
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
+            last_h = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
+                                                  keepdims=False)
+            logits = _head_logits(params, last_h, mcfg)
+            first = jnp.argmax(logits[0]).astype(jnp.int32)
+        return (first, jnp.stack(ks), jnp.stack(vs),
+                logits[0].astype(jnp.float32), None,
+                (jnp.concatenate(states), jnp.concatenate(windows)))
+
+    return core
+
+
+def _layer_of(stack, i):
+    """Layer `i` of a stack of layers (a leading axis on every leaf): what a
+    scan over the stack hands its body, read by index."""
+    import jax
+    return jax.tree.map(lambda w: w[i], stack)
+
+
+def _head_logits(params, h, mcfg):
+    """h [rows, D] -> logits [rows, V]: the head, or the embedding transposed
+    where the model ties them."""
+    import jax.numpy as jnp
+    if mcfg.tie_embeddings:
+        return jnp.einsum("bd,vd->bv", h, params["embed"].astype(mcfg.dtype))
+    return h @ params["lm_head"].astype(mcfg.dtype)
+
+
 # Compile-time cap on per-request top_k (jax.lax.top_k needs a static
 # width; requests asking for more sample from the best TOPK_CAP).
 TOPK_CAP = 64
@@ -218,28 +325,37 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     import jax.numpy as jnp
 
     from ray_tpu.models.block import (attention_inputs, expert_stacks,
-                                      expert_stats, feed_forward)
+                                      expert_stats, feed_forward, mamba_mixer)
     from ray_tpu.ops.norms import mrope_tables, rms_norm, rope_frequencies
     from ray_tpu.ops.paged_kv import (empty, empty_index,
                                       paged_decode_attention, write_prompt,
                                       write_prompt_rows, write_token,
                                       write_token_rows)
+    from ray_tpu.ops.slot_state import (empty_state, layer_state,
+                                        update_layer, write_state)
     from ray_tpu.ops.sparse_attention import sparse_decode_attention
 
     sparse = mcfg.n_experts > 0
     indexed = mcfg.index_topk > 0
+    hybrid = mcfg.ssm_state > 0
     S = mcfg.max_seq
     H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
     dt = mcfg.dtype
     ns = n_slots
 
     def empty_caches():
-        """-> (kc, vc), and ic after them for a model with an indexer."""
-        kv = empty(mcfg.n_layers, n_pages, KVH, page, hd, dt,
+        """-> (kc, vc), the arena of the layers that keep K and V; after
+        them ic for a model with an indexer, or the recurrent state
+        (`ops/slot_state.py`) for one with state-space layers."""
+        kv = empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt,
                    by_token=indexed)
         if indexed:
             kv += (empty_index(mcfg.n_layers, n_pages, page,
                                mcfg.index_head_dim, dt),)
+        if hybrid:
+            kv += (empty_state(mcfg.n_layers - mcfg.kv_layers, ns,
+                               mcfg.ssm_state, mcfg.ssm_inner, mcfg.ssm_conv,
+                               dt),)
         return kv
 
     # ------------------------------------------------------------------
@@ -248,13 +364,15 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     _core = _make_prefill_core(mcfg)
 
     def prefill(params, kc, vc, pages, tokens, length, temp, topk, key,
-                ic=None):
+                ic=None, state=None, slot=None):
         """tokens [1, B] padded to a BUCKET width (powers of 2 up to
         max_seq — jax.jit compiles one program per bucket shape, so a
         short prompt pays a short prefill, not a max_seq one); writes
         the slot's pages, returns the first generated token (sampled,
         or greedy when temp == 0) and the core's `experts` (and `ic`, the
-        indexer keys' arena, where the model has one)."""
+        indexer keys' arena, where the model has one; or `state`, the
+        recurrent state with slot `slot`'s rows overwritten by the prompt's
+        final ones, where it has state-space layers)."""
         _, ks, vs, logits_row, experts, *iks = _core(params, tokens, length)
         kc, vc = write_prompt(kc, vc, pages, ks, vs)
         first = _sample_tokens(logits_row[None],
@@ -264,6 +382,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         if indexed:
             return (kc, vc, first, experts,
                     write_prompt_rows(ic, pages, iks[0]))
+        if hybrid:
+            return kc, vc, first, experts, write_state(state, slot, *iks[0])
         return kc, vc, first, experts
 
     def adopt(kc, vc, pages, ks, vs):
@@ -286,7 +406,9 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         # expert weights in lp are all the layers' (`expert_stacks`)
         with jax.named_scope("rope"):
             w = jnp.minimum(pos, S - 1)
-            if mcfg.mrope_section:      # text: the three streams are equal
+            if not mcfg.rope:           # attention takes no position signal
+                c = s = None
+            elif mcfg.mrope_section:    # text: the three streams are equal
                 c, s = mrope_tables(cos, sin, jnp.broadcast_to(w, (3, ns)),
                                     mcfg.mrope_section)
                 c, s = c[:, None], s[:, None]
@@ -296,7 +418,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             if indexed:     # the indexer's own tables, over its own width
                 ci, si = (t[w][:, None] for t in itables)
         q, k, v, *index = attention_inputs(
-            lp, x, mcfg, lambda t: _rope_one(t, c, s),
+            lp, x, mcfg,
+            (lambda t: _rope_one(t, c, s)) if mcfg.rope else (lambda t: t),
             (lambda t: _rope_one(t, ci, si)) if indexed else None)
         kc, vc = write_token(kc, vc, l, bt, w, act, k, v)
         if indexed:
@@ -319,8 +442,35 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         x, routed = feed_forward(lp, x, mcfg, act, l if sparse else None)
         return x, kc, vc, ic, routed
 
+    def _hybrid_layers(params, x, kc, vc, state, bt, pos, act):
+        """One token a slot through a hybrid stack's segments: the arena's
+        layer is the attention layer's ordinal, the state's the state-space
+        layer's; both ride the carry (see `_step`)."""
+        def mamba_layer(carry, i):
+            x, state = carry
+            lp = _layer_of(params["mamba"], i)
+            # The state's read and its write back are the update's traffic:
+            # under the scope that times the update (`scan`).
+            with jax.named_scope("scan"):
+                ssm, window = layer_state(state, i)
+            x, ssm, window = mamba_mixer(lp, x, mcfg, ssm, window, step=True)
+            with jax.named_scope("scan"):
+                state = update_layer(state, i, act, ssm, window)
+            x, _ = feed_forward(lp, x, mcfg)
+            return (x, state), None
+
+        for kind, lo, hi in mcfg.segments():
+            if kind == "attn":
+                x, kc, vc, _, _ = _decode_layer(
+                    x, kc, vc, None, _layer_of(params["layers"], lo), lo, bt,
+                    pos, act, None, None, ())
+            else:
+                (x, state), _ = jax.lax.scan(mamba_layer, (x, state),
+                                             jnp.arange(lo, hi))
+        return x, kc, vc, state
+
     def _step(params, sliced, stacks, kc, vc, ic, experts, bt, last, pos,
-              active, cos, sin, itables, temp, topk, keys):
+              active, cos, sin, itables, temp, topk, keys, state=None):
         # sliced, stacks: `expert_stacks` of the layers, split (and where
         # need be cast) once a chunk, outside the loop over its steps
         act = active & (pos < S)
@@ -349,45 +499,54 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         # step's carry (2.9 GB a step at 12 layers x 929 pages; PERF.md,
         # PR 25). The xs are the layer's weights and its index.
         with jax.named_scope("layers"):
-            (x, kc, vc, ic, *experts), _ = jax.lax.scan(
-                body, (x, kc, vc, ic, *experts),
-                (sliced, jnp.arange(mcfg.n_layers)))
+            if hybrid:      # segments, not one scan: `_hybrid_layers`
+                x, kc, vc, state = _hybrid_layers(params, x, kc, vc, state,
+                                                  bt, pos, act)
+            else:
+                (x, kc, vc, ic, *experts), _ = jax.lax.scan(
+                    body, (x, kc, vc, ic, *experts),
+                    (sliced, jnp.arange(mcfg.n_layers)))
         with jax.named_scope("head"):
             x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
-            logits = x @ params["lm_head"].astype(dt)      # [ns, V]
+            logits = _head_logits(params, x, mcfg)         # [ns, V]
         nxt = _sample_tokens(logits, temp, topk, keys, pos)
         nxt = jnp.where(act, nxt, last)
         pos2 = jnp.where(act, pos + 1, pos)
-        return kc, vc, ic, experts, nxt, pos2
+        return kc, vc, ic, experts, nxt, pos2, state
 
     def decode(params, kc, vc, bt, last, pos, active, temp, topk, keys,
-               ic=None):
+               ic=None, state=None):
         """-> (kc, vc, last, pos, tokens [ns, chunk], experts): `experts` is
         None for a dense model, else `expert_stats` of the live slots'
         tokens summed over the chunk's steps and the layers. With an
-        indexer, `ic` follows."""
-        with jax.named_scope("rope"):
-            cos, sin = rope_frequencies(hd, S, mcfg.rope_theta)
-            itables = rope_frequencies(mcfg.index_head_dim, S,
-                                       mcfg.rope_theta) if indexed else ()
+        indexer, `ic` follows; with state-space layers, `state`."""
+        cos = sin = None
+        itables = ()
+        if mcfg.rope:
+            with jax.named_scope("rope"):
+                cos, sin = rope_frequencies(hd, S, mcfg.rope_theta)
+                itables = rope_frequencies(mcfg.index_head_dim, S,
+                                           mcfg.rope_theta) if indexed else ()
         out0 = jnp.zeros((ns, chunk), jnp.int32)
         experts0 = [jnp.zeros(mcfg.n_experts + 1, jnp.int32)] if sparse \
             else []
         sliced, stacks = expert_stacks(params["layers"], mcfg)
 
         def body(i, carry):
-            kc, vc, ic, last, pos, out, *experts = carry
-            kc, vc, ic, experts, nxt, pos = _step(
+            kc, vc, ic, state, last, pos, out, *experts = carry
+            kc, vc, ic, experts, nxt, pos, state = _step(
                 params, sliced, stacks, kc, vc, ic, experts, bt, last, pos,
-                active, cos, sin, itables, temp, topk, keys)
+                active, cos, sin, itables, temp, topk, keys, state)
             out = out.at[:, i].set(nxt)
-            return (kc, vc, ic, nxt, pos, out, *experts)
+            return (kc, vc, ic, state, nxt, pos, out, *experts)
 
-        kc, vc, ic, last, pos, out, *experts = jax.lax.fori_loop(
-            0, chunk, body, (kc, vc, ic, last, pos, out0, *experts0))
+        kc, vc, ic, state, last, pos, out, *experts = jax.lax.fori_loop(
+            0, chunk, body, (kc, vc, ic, state, last, pos, out0, *experts0))
         experts = experts[0] if sparse else None
         if indexed:
             return kc, vc, last, pos, out, experts, ic
+        if hybrid:
+            return kc, vc, last, pos, out, experts, state
         return kc, vc, last, pos, out, experts
 
     def poke(last, pos, slot, first, length):
@@ -398,8 +557,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         return last.at[slot].set(first), pos.at[slot].set(length)
 
     import jax as _jax
-    prefill_jit = _jax.jit(prefill, donate_argnums=(1, 2, 9))
-    decode_jit = _jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10))
+    prefill_jit = _jax.jit(prefill, donate_argnums=(1, 2, 9, 10))
+    decode_jit = _jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10, 11))
     adopt_jit = _jax.jit(adopt, donate_argnums=(0, 1))
     poke_jit = _jax.jit(poke, donate_argnums=(0, 1))
     return prefill_jit, decode_jit, adopt_jit, poke_jit, empty_caches
@@ -461,6 +620,7 @@ class Engine:
         import numpy as np
 
         from ray_tpu.models.block import fuse_qkv
+        from ray_tpu.ops.slot_state import state_bytes
 
         self._np = np
         self._jnp = jnp
@@ -484,9 +644,12 @@ class Engine:
          self._empty) = _build_fns(mcfg, n_slots, decode_chunk,
                                    self.pool.page, self.n_pages)
         # `_ic`: the indexer keys' arena of a model with sparse attention,
-        # under the same block table; None for every other model.
-        self._kc, self._vc, *ic = self._empty()
-        self._ic = ic[0] if ic else None
+        # under the same block table. `_state`: the per-slot recurrent state
+        # of a model with state-space layers (`ops/slot_state.py`). Each None
+        # for every other model, and no model has both.
+        self._hybrid = mcfg.ssm_state > 0
+        self._kc, self._vc, *more = self._empty()
+        self._ic, self._state = self._third(more)
         # Prefill shape buckets (powers of 2, capped at max_seq): a
         # 50-token prompt prefills 64 wide, not max_seq wide — the TTFT
         # lever the reference gets from vLLM's chunked prefill.
@@ -530,6 +693,10 @@ class Engine:
         self._index_topk = mcfg.index_topk
         self.decode_selected_keys = 0
         self.decode_live_keys = 0
+        # A model with state-space layers: the bytes of recurrent state held
+        # on the device, and the admissions that overwrote a slot's.
+        self.state_bytes = state_bytes(self._state) if self._hybrid else 0
+        self.state_writes = 0
         # A sparse model's routing, as the programs count it on the device
         # (`models.block.expert_stats`) and the emitter thread adds it up:
         # tokens per expert over prefills and decode steps, and the distinct
@@ -562,18 +729,19 @@ class Engine:
         # touch real KV state.
         self._warm = {self.buckets[0], self.buckets[-1]}
         for width in sorted(self._warm):
-            self._kc, self._vc, self._ic, first = self._warm_width(
-                self._kc, self._vc, self._ic, width)
+            self._kc, self._vc, self._ic, self._state, first = \
+                self._warm_width(self._kc, self._vc, self._ic, self._state,
+                                 width)
         with tracing.compile_span("serve.engine.warm", program="decode",
                                   width=n_slots):
             (self._kc, self._vc, self._last_d, self._pos_d, out, _,
-             *ic) = self._decode(
+             *more) = self._decode(
                     self._params, self._kc, self._vc,
                     jnp.asarray(self.pool.block_table),
                     self._last_d, self._pos_d, jnp.zeros(n_slots, bool),
                     jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._skeys), self._ic)
-            self._ic = ic[0] if ic else None
+                    jnp.asarray(self._skeys), self._ic, self._state)
+            self._ic, self._state = self._third(more)
         # Warm both poke variants: host-int `first` (adopt path) and
         # device-scalar `first` (prefill path).
         with tracing.compile_span("serve.engine.warm", program="poke",
@@ -604,11 +772,19 @@ class Engine:
                 name="llm-bucket-warm")
             self._warm_thread.start()
 
-    def _warm_width(self, kc, vc, ic, width: int):
+    def _third(self, more):
+        """(ic, state) from what a program returned after its fixed results:
+        the one further cache this model has, if it has one."""
+        if not more:
+            return None, None
+        return (None, more[0]) if self._hybrid else (more[0], None)
+
+    def _warm_width(self, kc, vc, ic, state, width: int):
         """First calls of the prefill and adopt programs of one bucket
         width, writing to the null page of the arena given (pages = zeros:
-        never real KV state). Returns (kc, vc, ic, first token on the
-        device)."""
+        never real KV state; a recurrent state's slot 0, before any request
+        holds it, or a scratch one's). Returns (kc, vc, ic, state, first
+        token on the device)."""
         jnp, m = self._jnp, self.mcfg
         null_pages = jnp.zeros(self.pool.maxp, jnp.int32)
         with tracing.compile_span("serve.engine.warm", program="prefill",
@@ -616,9 +792,10 @@ class Engine:
             kc, vc, first, _, *more = self._prefill(
                 self._params, kc, vc, null_pages,
                 jnp.zeros((1, width), jnp.int32), 1, 0.0, 0,
-                jnp.zeros(2, jnp.uint32), ic)
-        if more:     # a model with an indexer: no PD handoff carries its keys
-            return kc, vc, more[0], first
+                jnp.zeros(2, jnp.uint32), ic, state,
+                0 if self._hybrid else None)
+        if more:     # no PD handoff carries an indexer's keys or a state
+            return (kc, vc, *self._third(more), first)
         # The PD adopt program for this width too (a first cross-pool
         # handoff must not compile in the loop).
         with tracing.compile_span("serve.engine.warm", program="adopt",
@@ -626,22 +803,23 @@ class Engine:
             kv = jnp.zeros((m.n_layers, width, m.n_kv_heads, m.head_dim),
                            m.dtype)
             kc, vc = self._adopt(kc, vc, null_pages, kv, kv)
-        return kc, vc, ic, first
+        return kc, vc, ic, state, first
 
     def _warm_buckets(self, widths: List[int]) -> None:
         """Warm intermediate prefill buckets off the engine loop; each
         becomes eligible the moment its compile lands. Runs real calls
         (the only way to reliably populate jit's dispatch cache) against
-        a SCRATCH kv arena — the live arenas are donated on every engine
-        call and must never be touched from this thread. Costs one
-        transient extra arena while warming."""
+        a SCRATCH kv arena (and recurrent state) — the live ones are donated
+        on every engine call and must never be touched from this thread.
+        Costs one transient extra arena while warming."""
         try:
-            kc, vc, *ic = self._empty()
-            ic = ic[0] if ic else None
+            kc, vc, *more = self._empty()
+            ic, state = self._third(more)
             for width in widths:
                 if self._stop:
                     return
-                kc, vc, ic, first = self._warm_width(kc, vc, ic, width)
+                kc, vc, ic, state, first = self._warm_width(kc, vc, ic,
+                                                            state, width)
                 int(first)  # host sync: compile fully landed
                 self._warm.add(width)
         except Exception:
@@ -670,7 +848,9 @@ class Engine:
             jax.ShapeDtypeStruct((self.pool.maxp,), jnp.int32),
             jax.ShapeDtypeStruct((1, width), jnp.int32), 1, 0.0, 0,
             jax.ShapeDtypeStruct((2,), jnp.uint32),
-            None if self._ic is None else shape_of(self._ic)).as_text()
+            None if self._ic is None else shape_of(self._ic),
+            jax.tree.map(shape_of, self._state),
+            0 if self._hybrid else None).as_text()
 
     # ------------------------------------------------------------------
     @property
@@ -732,10 +912,11 @@ class Engine:
         only tokens AFTER `first`."""
         if self.error is not None or not self._thread.is_alive():
             raise RuntimeError(f"LLM engine died:\n{self.error}")
-        if self._ic is not None:
+        if self._ic is not None or self._hybrid:
             raise NotImplementedError(
                 "a PD handoff carries K and V, not a sparse-attention "
-                "indexer's keys: this model serves from one engine")
+                "indexer's keys nor a state-space layer's recurrent state: "
+                "this model serves from one engine")
         req = _Request([0] * min(length, self.mcfg.max_seq - 1),
                        max_tokens, adopt_kv=(ks, vs), first=first,
                        temperature=temperature, top_k=top_k, seed=seed)
@@ -771,7 +952,11 @@ class Engine:
         steps and layers: over `decode_chunks * chunk * n_layers` it is the
         experts whose weights a layer reads in a step). A sparse-attention
         model adds `decode_selected_keys` over `decode_live_keys`: the share
-        of the live positions its decode steps read K and V of."""
+        of the live positions its decode steps read K and V of. A model with
+        state-space layers adds `state_bytes`, the recurrent state it holds
+        on the device for all slots, and `state_writes`, the admissions that
+        overwrote a slot's (a decode chunk moves the active slots' share of
+        `state_bytes` once a step)."""
         out = {k: getattr(self, k) for k in (
             "admitted", "queue_wait_s_sum", "admit_chunks_ahead",
             "prefill_tokens",
@@ -784,6 +969,9 @@ class Engine:
         if self._index_topk:
             out["decode_selected_keys"] = self.decode_selected_keys
             out["decode_live_keys"] = self.decode_live_keys
+        if self._hybrid:
+            out["state_bytes"] = self.state_bytes
+            out["state_writes"] = self.state_writes
         return out
 
     def stop(self) -> None:
@@ -895,12 +1083,14 @@ class Engine:
         else:
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :len(req.ids)] = req.ids
-            self._kc, self._vc, first, experts, *ic = self._prefill(
+            self._kc, self._vc, first, experts, *more = self._prefill(
                 self._params, self._kc, self._vc, pages_arr,
                 jnp.asarray(toks), len(req.ids),
                 float(req.temperature), int(req.top_k),
-                jnp.asarray(_seed_key(req.seed)), self._ic)
-            self._ic = ic[0] if ic else None
+                jnp.asarray(_seed_key(req.seed)), self._ic, self._state,
+                slot if self._hybrid else None)
+            self._ic, self._state = self._third(more)
+            self.state_writes += self._hybrid
         req.slot = slot
         self._slot_req[slot] = req
         self._pos[slot] = len(req.ids)
@@ -1102,15 +1292,16 @@ class Engine:
                               active=len(plan), live_kv_tokens=live_kv,
                               **routed):
                 (self._kc, self._vc, self._last_d, self._pos_d, out_d,
-                 experts_d, *ic) = \
+                 experts_d, *more) = \
                     self._decode(self._params, self._kc, self._vc,
                                  jnp.asarray(self.pool.block_table.copy()),
                                  self._last_d, self._pos_d,
                                  jnp.asarray(self._active.copy()),
                                  jnp.asarray(self._temp.copy()),
                                  jnp.asarray(self._topk.copy()),
-                                 jnp.asarray(self._skeys.copy()), self._ic)
-                self._ic = ic[0] if ic else None
+                                 jnp.asarray(self._skeys.copy()), self._ic,
+                                 self._state)
+                self._ic, self._state = self._third(more)
                 self._pos = np.where(
                     self._active, np.minimum(self._pos + self.chunk, S),
                     self._pos).astype(np.int32)

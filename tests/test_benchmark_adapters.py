@@ -1,11 +1,12 @@
 """The benchmark's adapter contract (benchmark/models/<arch>.py), seen by
 tier-1: the cluster-free cases of benchmark/tests/test_benchmark.py, imported
-by name and run for the adapters `llama`, `olmoe` and `keye`, and what
+by name and run for the adapters `llama`, `olmoe`, `keye` and `jamba`, and what
 `olmoe` adds: its refusals, its counts against a hand count, its readers on a
 synthetic trace and on the engine's own spans; and for `keye` its manifest
 entries against the catalog's row and the sparse-attention readers on a
-synthetic trace (its block against its reference is tests/test_keye.py). No
-cluster, no port, no clock.
+synthetic trace (its block against its reference is tests/test_keye.py);
+and for `jamba` the same three (its stack against its reference is
+tests/test_jamba.py). No cluster, no port, no clock.
 """
 
 import dataclasses
@@ -22,7 +23,7 @@ sys.path.insert(0, ROOT)
 from benchmark import models, program_trace  # noqa: E402
 from benchmark.tests import test_benchmark as cases  # noqa: E402
 
-ARCHS = ["llama", "olmoe", "keye"]
+ARCHS = ["llama", "olmoe", "keye", "jamba"]
 # config.json of allenai/OLMoE-1B-7B-0125-Instruct, as the catalog beside the
 # model-configs guide has it.
 OLMOE_PUBLISHED = dict(
@@ -447,3 +448,161 @@ def test_sparse_attention_readers_on_a_synthetic_trace(monkeypatch):
                  "selected_share_pct", "sparse_decode_roofline_pct",
                  "index_roofline_pct"):
         assert _reader(name)(run) is None, name
+
+
+# -- arch `jamba`: the manifest's entries, and the state-space readers --------
+
+# `config` of the catalog's row AI21-Jamba2-3B (ai21labs/AI21-Jamba2-3B
+# config.json).
+JAMBA_PUBLISHED = dict(
+    attn_layer_offset=7, attn_layer_period=14, expert_layer_offset=1,
+    expert_layer_period=2, hidden_act="silu", hidden_size=2560,
+    intermediate_size=8192, mamba_conv_bias=True, mamba_d_conv=4,
+    mamba_d_state=16, mamba_dt_rank=160, mamba_expand=2,
+    mamba_proj_bias=False, max_position_embeddings=262144,
+    model_type="jamba", num_attention_heads=20, num_experts=1,
+    num_experts_per_tok=1, num_hidden_layers=28, num_key_value_heads=1,
+    num_logits_to_keep=1, rms_norm_eps=1e-06, sliding_window=None,
+    tie_word_embeddings=True, use_mamba_kernels=True, vocab_size=65536)
+
+
+def test_jamba_manifest_entries_are_the_catalogs_row_uncut():
+    manifest = cases.load(ROOT, "BENCHMARK.json")
+    entry = next(c for c in manifest["configs"]
+                 if c["name"] == "jamba2-3b-serve")
+    cfg = cases.load(ROOT, entry["file"])
+    assert entry["reduced"] == [] == list(cfg["reduced"])
+    assert not {k for k, v in JAMBA_PUBLISHED.items() if cfg.get(k, "-") != v}
+    assert entry["source"] == cfg["source_url"] and cfg["arch"] == "jamba"
+    eng = cfg["deployment"]["engine"]
+    assert eng["kv_pages"] == 1 + eng["n_slots"] * eng["max_seq"] // eng["page_size"]
+    adapter = models.adapter("jamba")
+    adapter.check_supported(cfg)
+    # the layer pattern 7/14, the tied head, no positions, MQA 20 on 1
+    built = adapter.build_config(cfg, cfg["dtypes"], eng["max_seq"])
+    assert built.attn_layers == (7, 21) and built.kv_layers == 2
+    assert built.segments() == (("mamba", 0, 7), ("attn", 0, 1),
+                                ("mamba", 7, 20), ("attn", 1, 2),
+                                ("mamba", 20, 26))
+    assert built.tie_embeddings and not built.rope
+    assert (built.n_heads, built.n_kv_heads, built.head_dim, built.ssm_inner,
+            built.ssm_state, built.ssm_dt_rank, built.ssm_conv) == \
+        (20, 1, 128, 5120, 16, 160, 4)
+    assert adapter.counts.total_params(cfg) == 3_029_337_472
+    cell = next(w for w in manifest["workloads"]
+                if w["name"] == "serve-batch-jamba2")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == \
+        ("jamba2-3b-serve", "batch-summarize-jamba2", 1)
+    mix = cases.load(cases.BENCH, "traffic", "batch-summarize-jamba2.json")
+    theirs = cases.load(cases.BENCH, "traffic", "batch-summarize.json")
+    assert mix["kind"] == "serve_closed_checked"
+    for key in ("arrivals", "prompt_tokens", "output_tokens", "trace"):
+        assert mix[key] == theirs[key], key
+    assert mix["shape_seed"] != theirs["shape_seed"]
+    # a prompt in the widest bucket with padding behind it, and one whose
+    # decode crosses a page boundary
+    chk, seq, page = mix["check"], eng["max_seq"], eng["page_size"]
+    assert any(seq // 2 < n < seq - chk["tokens"]
+               for n in chk["prompt_lengths"])
+    assert any(n // page != (n + chk["tokens"]) // page
+               for n in chk["prompt_lengths"])
+    reported = {m["name"] for g in ("end_to_end", "per_layer")
+                for m in manifest[g]
+                if "serve-batch-jamba2" in m.get("workloads", ())}
+    assert reported == {
+        "batch_tokens_per_s", "prefill_ms_per_ktok", "kv_pages_peak_pct",
+        "prefill_ssm_ms_per_ktok", "scan_roofline_pct", "decode_ssm_ms",
+        "decode_state_roofline_pct"}
+    # not `decode_attn_roofline_pct`: its bytes multiply by ALL the layers
+
+
+def test_jamba_build_config_names_the_field_an_older_program_lacks(monkeypatch):
+    """The parent of PR 35 has no state-space fields: the adapter must say
+    so in the parent process, in `build_config`, not run another stack under
+    Jamba's name nor leave it to a replica's constructor."""
+    from ray_tpu.models import llama
+
+    @dataclasses.dataclass(frozen=True)
+    class Older:
+        n_experts: int = 0
+        index_topk: int = 0
+
+    monkeypatch.setattr(llama, "LlamaConfig", Older)
+    with pytest.raises(ValueError, match=r"ssm_state.*attn_layers.*tie_emb"):
+        models.adapter("jamba").build_config(
+            dict(JAMBA_PUBLISHED), {"params": "bfloat16",
+                                    "activations": "bfloat16"}, 4096)
+
+
+def test_state_space_readers_on_a_synthetic_trace(monkeypatch):
+    from benchmark import peaks, ssm_trace
+
+    P = "jit(prefill)/layers/while/body/"
+    D = "jit(decode)/while/body/layers/while/body/"
+    ops = [("jit(prefill)/layers/while", 1000, 2000),
+           (P + "ssm_in/dot_general:", 1000, 1100),
+           (P + "conv/mul:", 1100, 1150),
+           (P + "ssm_params/dot_general:", 1150, 1200),
+           (P + "scan/pallas_call:", 1200, 1600),
+           (P + "ssm_out/dot_general:", 1600, 1700),
+           (P + "mlp/dot_general:", 1700, 1900),
+           ("jit(prefill)/layers/attn/pallas_call:", 1900, 1950),
+           ("jit(decode)/while", 3000, 4000),
+           (D + "ssm_in/dot_general:", 3000, 3100),
+           (D + "conv/mul:", 3100, 3120),
+           (D + "ssm_params/dot_general:", 3120, 3200),
+           (D + "scan/exp:", 3200, 3400),
+           (D + "scan/state_write/dynamic_update_slice:", 3400, 3500),
+           (D + "ssm_out/dot_general:", 3500, 3600),
+           (D + "mlp/dot_general:", 3600, 3900)]
+    modules = [("jit_poke", 0, 10), ("jit_prefill", 1000, 2000),
+               ("jit_decode", 3000, 4000), ("jit_poke", 5000, 5010)]
+    Span = program_trace.Span
+    spans = [
+        Span("serve.engine.admit", 900, 950, dict(
+            rid=7, kind="prefill", prompt_tokens=3000, bucket=4096,
+            queue_wait_us=1)),
+        Span("serve.engine.emit", 2010, 2020, dict(rid=7, kind="first")),
+        Span("serve.engine.decode_dispatch", 2900, 2950, dict(
+            useful=16, capacity=32, active=15, live_kv_tokens=30000))]
+    t = program_trace.ProgramTrace(spans, modules, ops)
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    # program_trace's own vocabulary charges the mixer's scopes to `layers`
+    assert t.scope_ms("jit_decode")["layers"] == pytest.approx(600 / 1e6)
+    per = ssm_trace.by_scope(t, t.whole_modules("jit_decode"))
+    assert per == [{"ssm_in": 100, "conv": 20, "ssm_params": 80, "scan": 300,
+                    "ssm_out": 100, "mlp": 300, "": 100}]
+    m = dict(JAMBA_PUBLISHED, arch="jamba",
+             dtypes={"params": "bfloat16", "activations": "bfloat16"},
+             deployment={"engine": {"decode_chunk": 2}})
+    run = {"config": m, "cell": "x", "seed": 0,
+           "device": {"kind": "TPU v5 lite"}}
+    assert _reader("decode_ssm_ms")(run) == pytest.approx(600 / 1e6 / 2)
+    assert _reader("prefill_ssm_ms_per_ktok")(run) == \
+        pytest.approx(700 / 1e6 / 3.0)
+    counts = models.adapter("jamba").counts
+    f, b = peaks.peak("TPU v5 lite", "bf16_flops_per_s"), \
+        peaks.peak("TPU v5 lite", "hbm_bytes_per_s")
+    ops_, byts = counts.selective_scan_ops_bytes(m, 3000, 2)
+    assert byts / b > ops_ / f         # no vector peak: the bytes bound it
+    assert _reader("scan_roofline_pct")(run) == \
+        pytest.approx(100 * 26 * (byts / b) / (400 / 1e9))
+    moved = counts.decode_state_bytes(m, 15 * 2, 2)
+    assert moved == 2 * 30 * 26 * 5120 * (16 * 4 + 3 * 2)
+    assert _reader("decode_state_roofline_pct")(run) == \
+        pytest.approx(100 * (moved / b) / (300 / 1e9))
+    # a program without the scopes (the parent; any other model)
+    deeper = re.compile("/(ssm_in|conv|ssm_params|scan|ssm_out)")
+    plain = program_trace.ProgramTrace(
+        spans, modules, [(deeper.sub("", p), s, e) for p, s, e in ops])
+    monkeypatch.setattr(program_trace, "load", lambda run: plain)
+    for name in ("decode_ssm_ms", "prefill_ssm_ms_per_ktok",
+                 "scan_roofline_pct", "decode_state_roofline_pct"):
+        assert _reader(name)(run) is None, name
+    # and a model whose counts know no state, whatever the trace holds
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    other = dict(run, config=dict(OLMOE_PUBLISHED, arch="olmoe",
+                                  dtypes=m["dtypes"],
+                                  deployment=m["deployment"]))
+    for name in ("scan_roofline_pct", "decode_state_roofline_pct"):
+        assert _reader(name)(other) is None, name

@@ -35,7 +35,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.fixture()
 def ring(tmp_path):
     """This process's ring parked in a throwaway store dir. Works in
-    both writer modes (native lib or the pure-Python mmap fallback)."""
+    both writer modes (native lib or the pure-Python mmap fallback).
+    Records that earlier tests of this worker logged before any ring was
+    open are dropped first: `open_ring` would replay them into this one."""
+    graftlog._pending.clear()
     assert graftlog.open_ring(str(tmp_path))
     yield str(tmp_path)
     graftlog.close_ring()

@@ -22,7 +22,8 @@ class TestPagedKvLayout:
     PAGE, MAXP, HD, LAYERS, LAYER = 8, 4, 128, 2, 1
 
     @pytest.mark.parametrize("path", ["reference", "kernel"])
-    @pytest.mark.parametrize("heads", [(8, 2), (4, 4)], ids=["gqa4", "mha"])
+    @pytest.mark.parametrize("heads", [(8, 2), (4, 4), (20, 1)],
+                             ids=["gqa4", "mha", "mqa20"])
     def test_prompt_then_tokens_then_attention(self, heads, path):
         H, KVH = heads
         page, maxp, hd = self.PAGE, self.MAXP, self.HD
@@ -88,6 +89,37 @@ class TestPagedKvLayout:
         np.testing.assert_array_equal(
             np.asarray(kc[self.LAYER, 4, :, steps:]), 0.0)
 
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    def test_twenty_query_heads_on_one_kv_head(self, dtype):
+        """MQA at `groups` 20 (a hybrid model's attention layers): the
+        kernel's rows are the 20 query heads of the one kv head, padded to
+        whole tiles (24 rows of float32; 2 x 20 -> 48 of bfloat16, whose
+        softmax weights go through the MXU in two halves), against
+        `_paged_decode_reference`; and `repeat_kv` at 20 is each query head
+        reading that one head."""
+        H, page, maxp, hd, ns = 20, self.PAGE, self.MAXP, self.HD, 3
+        rng = np.random.default_rng(3)
+        kc = jnp.asarray(rng.standard_normal((2, 9, 1, page, hd)), dtype)
+        vc = jnp.asarray(rng.standard_normal((2, 9, 1, page, hd)), dtype)
+        bt = np.zeros((ns, maxp), np.int32)
+        bt[0], bt[1, :2] = (7, 2, 5, 1), (4, 8)
+        lengths = jnp.asarray([4 * page, page + 3, 0], jnp.int32)
+        q = jnp.asarray(rng.standard_normal((ns, H, hd)), dtype)
+        args = (q, kc, vc, jnp.int32(1), jnp.asarray(bt), lengths)
+        got = paged_decode_attention(*args, interpret=True)
+        want = paged_kv._paged_decode_reference(*args, sm_scale=hd ** -0.5)
+        tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), atol=tol)
+        assert got.shape == (ns, H, hd) and not np.asarray(got[2]).any()
+        k = jnp.asarray(rng.standard_normal((1, 1, 5, hd)), dtype)
+        rep = repeat_kv(k, H)
+        assert rep.shape == (1, H, 5, hd)
+        np.testing.assert_array_equal(
+            np.asarray(rep, np.float32),
+            np.broadcast_to(np.asarray(k, np.float32), (1, H, 5, hd)))
 
 
 class TestRowsByTokenAndIndexerKeys:

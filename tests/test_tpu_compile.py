@@ -315,6 +315,102 @@ def test_sparse_decode_leaves_the_arenas_where_they_lie_on_v5e(
 
 
 # ---------------------------------------------------------------------------
+# State-space layers (ops/ssm.py) at AI21-Jamba2-3B's widths
+# ---------------------------------------------------------------------------
+
+def test_selective_scan_kernel_compiles_for_v5e(topo):
+    """4,096 rows of 5,120 channels and 16 states, bfloat16 rows and a
+    float32 time step, gated: one Mosaic kernel, and nothing of size rows x
+    channels x states beside it (that would be 1.3 GB)."""
+    from ray_tpu.ops import ssm
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    S, Di, N = 4096, 5120, 16
+    rows, maps = sds((S, Di), jnp.bfloat16), sds((S, N), jnp.bfloat16)
+    lowered = jax.jit(lambda x, dt, a, b, c, d, s0, z, n: ssm._scan_pallas(
+        x, dt, a, b, c, d, s0, n, z, interpret=False,
+        block_channels=ssm._BLOCK_CHANNELS, block_rows=ssm._BLOCK_ROWS)
+    ).lower(rows, sds((S, Di), jnp.float32), sds((N, Di), jnp.float32), maps,
+            maps, sds((Di,), jnp.float32), sds((N, Di), jnp.float32), rows,
+            sds((), jnp.int32))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    mem = lowered.compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 4 * S * N * 4 + (1 << 20)  # B, C re-laid
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_jamba_programs_keep_arena_and_state_in_place_on_v5e(
+        topo, program, monkeypatch):
+    """The hybrid stack's decode chunk and its 4,096-bucket prefill at the
+    cell's sizes (benchmark/configs/jamba2-3b-serve.json): the K/V arena of
+    the 2 attention layers and the recurrent state of the 26 state-space
+    layers are donated and alias the outputs; decode's attention is the
+    `paged_decode` kernel at MQA `groups` 20, prefill's scan the
+    `selective_scan` kernel; and no program sets a layer's weights aside
+    (the stacks are read by index inside the segment's loop, the attention
+    layers' by a constant one)."""
+    import json
+
+    from benchmark import models
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.serve.engine import _build_fns
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "jamba2-3b-serve.json")) as f:
+        model = json.load(f)
+    eng = model["deployment"]["engine"]
+    cfg = models.adapter("jamba").build_config(model, model["dtypes"],
+                                               eng["max_seq"])
+    ns, page = eng["n_slots"], eng["page_size"]
+    maxp = eng["max_seq"] // page
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    prefill, decode, _, _, empty = _build_fns(cfg, ns, eng["decode_chunk"],
+                                              page, eng["kv_pages"])
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)))))
+    assert "lm_head" not in params
+    kc, vc, state = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                                 jax.eval_shape(empty))
+    assert kc.shape == (2, eng["kv_pages"], 1, page, 128)
+    assert [tuple(x.shape) for x in state] == [(26, ns, 16, 5120),
+                                               (26, 3, ns, 5120)]
+    before = dict(attention.attention_path_counts())
+    if program == "decode":
+        lowered = decode.lower(
+            params, kc, vc, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
+            sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
+            sds((ns,), jnp.float32), sds((ns,), jnp.int32),
+            sds((ns, 2), jnp.uint32), None, state)
+        kernel, path = "paged_decode", "decode_pallas"
+    else:
+        lowered = prefill.lower(
+            params, kc, vc, sds((maxp,), jnp.int32), sds((1, 4096), jnp.int32),
+            1, 0.0, 0, sds((2,), jnp.uint32), None, state, 0)
+        kernel, path = "selective_scan", "scan_pallas"
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text and kernel in text
+    counts = attention.attention_path_counts()
+    assert counts[path] > before.get(path, 0)
+    mem = lowered.compile().memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in (kc, vc) + tuple(state))
+    assert mem.alias_size_in_bytes >= held
+    # A layer's weights set aside would be 0.2 GB (a Mamba layer), a
+    # segment's 1.4; the prefill's own temporaries are its activations.
+    assert mem.temp_size_in_bytes < ((16 << 20) if program == "decode"
+                                     else (256 << 20))
+
+
+# ---------------------------------------------------------------------------
 # Chip pinning env (no compiler needed)
 # ---------------------------------------------------------------------------
 
