@@ -47,11 +47,15 @@ engine is OURS):
   One fixed-shape XLA program serves every step (no recompiles).
 
 A small fixed set of compiled programs serves all traffic: one prefill
-per power-of-2 BUCKET width (a short prompt pays a short prefill — the
-TTFT lever; smallest and largest warmed before the loop starts, the rest on
-a background thread, and until a width is warm a prompt rounds UP to the
-next one that is: nothing compiles inside the loop), its `adopt` twin for a
-PD handoff, the n-step decode chunk over all slots, and the slot poke.
+per BUCKET width of a ladder (`prefill_widths`: doubling, and a quarter of
+an octave apart in the octave under max_seq, so a short prompt pays a short
+prefill — the TTFT lever — and a long one pads by a fifth at most, not by
+half; the smallest and every rung wider than half of max_seq warmed before
+the loop starts, the rest on a background thread, and until a width is warm
+a prompt rounds UP to the next one that is: nothing compiles inside the loop),
+an `adopt` twin for a PD handoff at each of the doubling widths
+(`doubling_widths`), the n-step decode chunk over all slots, and the slot
+poke.
 """
 
 from __future__ import annotations
@@ -82,6 +86,49 @@ _MOE_ROWS = 4096
 # arrival a chunk of waiting before its prefill. PERF.md section 6, PR 33, has
 # the measurement, depth 1's included.
 _DEPTH = 2
+
+# Smallest prefill width; the widths double from here (`doubling_widths`).
+_MIN_BUCKET = 32
+# No rung of the prefill ladder is closer to the one before than this many
+# rows (`prefill_widths`), so that every rung between doubling widths is a
+# multiple of it: `ops/ssm.py` walks a sequence in blocks of 512 rows, and
+# `ops/attention.py::_pick_block` halves its preferred 1024 until it divides
+# the width, so the flash kernels (and `ops/sparse_attention.py`'s two) keep
+# blocks of 512 or 1024 rows, where a width of 1280 or 1792 would leave 256.
+_MIN_RUNG_GAP = 512
+
+
+def doubling_widths(max_seq: int) -> List[int]:
+    """`_MIN_BUCKET` doubled while it is under `max_seq`, then `max_seq`: the
+    widths a PD handoff arrives at (a `PrefillServer` pads a prompt to one of
+    these) and so the widths of the engine's `adopt` programs."""
+    widths = []
+    b = min(_MIN_BUCKET, max_seq)
+    while b < max_seq:
+        widths.append(b)
+        b *= 2
+    return widths + [max_seq]
+
+
+def prefill_widths(max_seq: int) -> List[int]:
+    """The engine's ladder of prefill widths, a function of `max_seq` alone:
+    `doubling_widths`, and between the last two of them (the octave under a
+    `max_seq` that is a power of two) rungs a quarter of the lower one apart
+    but `_MIN_RUNG_GAP` rows at least (for 4096: 2048, 2560, 3072, 3584,
+    4096; for 8192: 4096, 5120, 6144, 7168, 8192; for 2048: 1024, 1536,
+    2048). Every prefill operation computes the bucket's padding like any
+    row, so a prompt in that octave pads by a fifth of its bucket at most (a
+    third at the least gap) where doubling allowed a half. The top octave
+    alone, because a rung is not free: a program more to trace, read from the
+    compile cache and load at every start (0.7-1.6 s each at the benchmark's
+    widths, 6-12 s where it has to compile: PERF.md section 6, PR 36) and
+    8-16 MB of device memory for its executable; the longest prompts are
+    where padding costs most rows, and what a deployment sizes `max_seq`
+    for."""
+    doubling = doubling_widths(max_seq)
+    top = doubling[-2] if len(doubling) > 1 else max_seq
+    step = max(top // 4, _MIN_RUNG_GAP)
+    return sorted({*doubling, *range(top, max_seq, step)})
 
 
 def _make_prefill_core(mcfg):
@@ -365,10 +412,10 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
 
     def prefill(params, kc, vc, pages, tokens, length, temp, topk, key,
                 ic=None, state=None, slot=None):
-        """tokens [1, B] padded to a BUCKET width (powers of 2 up to
-        max_seq — jax.jit compiles one program per bucket shape, so a
-        short prompt pays a short prefill, not a max_seq one); writes
-        the slot's pages, returns the first generated token (sampled,
+        """tokens [1, B] padded to a BUCKET width (a rung of
+        `prefill_widths` — jax.jit compiles one program per bucket shape, so
+        a prompt pays a prefill of about its own length, not a max_seq one);
+        writes the slot's pages, returns the first generated token (sampled,
         or greedy when temp == 0) and the core's `experts` (and `ic`, the
         indexer keys' arena, where the model has one; or `state`, the
         recurrent state with slot `slot`'s rows overwritten by the prompt's
@@ -607,9 +654,6 @@ class Engine:
     submit() from any thread; each request streams token chunks through
     its own queue."""
 
-    # Smallest prefill bucket; buckets double up to max_seq.
-    _MIN_BUCKET = 32
-
     def __init__(self, params, mcfg, *, n_slots: int = 8,
                  decode_chunk: int = 8, page_size: int = 64,
                  n_pages: Optional[int] = None):
@@ -650,15 +694,13 @@ class Engine:
         self._hybrid = mcfg.ssm_state > 0
         self._kc, self._vc, *more = self._empty()
         self._ic, self._state = self._third(more)
-        # Prefill shape buckets (powers of 2, capped at max_seq): a
-        # 50-token prompt prefills 64 wide, not max_seq wide — the TTFT
-        # lever the reference gets from vLLM's chunked prefill.
-        self.buckets: List[int] = []
-        b = min(self._MIN_BUCKET, mcfg.max_seq)
-        while b < mcfg.max_seq:
-            self.buckets.append(b)
-            b *= 2
-        self.buckets.append(mcfg.max_seq)
+        # Prefill shape buckets (`prefill_widths`): a 50-token prompt
+        # prefills 64 wide and, under a max_seq of 4096, a 2,100-token one
+        # 2,560 wide, not max_seq wide — the TTFT lever, and most of the
+        # padding, that the reference gets from vLLM's chunked prefill. A PD
+        # handoff is adopted at the doubling widths alone, its sender's.
+        self.buckets: List[int] = prefill_widths(mcfg.max_seq)
+        self._adopt_widths = doubling_widths(mcfg.max_seq)
         # host-side slot state (control flow is host-predicted; only token
         # VALUES come back from the device)
         self._slot_req: List[Optional[_Request]] = [None] * n_slots
@@ -719,15 +761,25 @@ class Engine:
         # background warm: that width never becomes available, so the
         # replica is degraded and its health check must say so.
         self.warm_error: Optional[str] = None
-        # Warm the decode program + the SMALLEST and LARGEST prefill
-        # buckets before serving (serve's startup grace covers the XLA
-        # compiles); intermediate buckets warm in a BACKGROUND thread —
-        # until one is ready, prompts round UP to the next warmed bucket,
-        # so an unwarmed shape never compiles inside the engine loop
-        # (which would freeze every in-flight decode stream). Warm
-        # writes target the null page (pages = zeros), so they never
-        # touch real KV state.
-        self._warm = {self.buckets[0], self.buckets[-1]}
+        # Warm the decode program + the SMALLEST prefill bucket and every
+        # one WIDER THAN HALF OF max_seq before serving (serve's startup
+        # grace covers the XLA compiles); the buckets between warm in a
+        # BACKGROUND thread — until one is ready, prompts round UP to the
+        # next warmed bucket, so an unwarmed shape never compiles inside
+        # the engine loop (which would freeze every in-flight decode
+        # stream). Warm writes target the null page (pages = zeros), so
+        # they never touch real KV state. Why the split is at half: the
+        # thread warms against a SCRATCH arena, and a wide prefill's
+        # temporaries do not fit beside a second arena (at OLMoE's widths
+        # weights 6.64 GiB, two arenas 8.00 and a 2048-wide prefill's
+        # temporaries are 15.41 of the 15.75 the chip gives, and a
+        # 4096-wide prefill has 2.54 of them: PERF.md section 4); here the
+        # live arena is the only one, as for the widest bucket, which fits
+        # if serving does. Each is a real first call, not
+        # `lower().compile()`: only that fills jit's dispatch cache, and a
+        # cache read at the first request is a compilation inside the loop.
+        self._warm = {self.buckets[0]} | {
+            b for b in self.buckets if 2 * b > mcfg.max_seq}
         for width in sorted(self._warm):
             self._kc, self._vc, self._ic, self._state, first = \
                 self._warm_width(self._kc, self._vc, self._ic, self._state,
@@ -780,11 +832,11 @@ class Engine:
         return (None, more[0]) if self._hybrid else (more[0], None)
 
     def _warm_width(self, kc, vc, ic, state, width: int):
-        """First calls of the prefill and adopt programs of one bucket
-        width, writing to the null page of the arena given (pages = zeros:
-        never real KV state; a recurrent state's slot 0, before any request
-        holds it, or a scratch one's). Returns (kc, vc, ic, state, first
-        token on the device)."""
+        """First call of the prefill program of one bucket width and, at a
+        doubling width, of its adopt twin, writing to the null page of the
+        arena given (pages = zeros: never real KV state; a recurrent state's
+        slot 0, before any request holds it, or a scratch one's). Returns
+        (kc, vc, ic, state, first token on the device)."""
         jnp, m = self._jnp, self.mcfg
         null_pages = jnp.zeros(self.pool.maxp, jnp.int32)
         with tracing.compile_span("serve.engine.warm", program="prefill",
@@ -796,6 +848,8 @@ class Engine:
                 0 if self._hybrid else None)
         if more:     # no PD handoff carries an indexer's keys or a state
             return (kc, vc, *self._third(more), first)
+        if width not in self._adopt_widths:     # no handoff has this width
+            return kc, vc, ic, state, first
         # The PD adopt program for this width too (a first cross-pool
         # handoff must not compile in the loop).
         with tracing.compile_span("serve.engine.warm", program="adopt",
@@ -1022,7 +1076,9 @@ class Engine:
             width = req.adopt_kv[0].shape[1] if adopting else len(req.ids)
             # Only WARMED buckets are eligible (round up until the
             # background warm lands) — never compile in the engine loop.
-            bucket = next(b for b in self.buckets
+            # A handoff rounds within the widths that have an adopt program.
+            bucket = next(b for b in (self._adopt_widths if adopting
+                                      else self.buckets)
                           if b >= width and b in self._warm)
             waited = time.monotonic() - req.t_submit
             self.admitted += 1

@@ -233,7 +233,7 @@ class PrefillServer:
         import jax.numpy as jnp
 
         from ray_tpu.models.block import fuse_qkv
-        from ray_tpu.serve.engine import Engine, _make_prefill_core
+        from ray_tpu.serve.engine import _make_prefill_core, doubling_widths
 
         cfg: LLMConfig = cloudpickle.loads(cfg_blob)
         self.cfg = cfg
@@ -255,17 +255,13 @@ class PrefillServer:
                                   jnp.asarray(pos)[None])[0]
 
         self._sample1 = jax.jit(_sample_first)
-        # Same bucket ladder + warm policy as the engine: smallest and
-        # largest warm eagerly; intermediates warm in the background and
-        # requests round UP to a warmed width until then (a synchronous
-        # compile inside a request would spike TTFT for everything
-        # queued behind it).
-        self.buckets: List[int] = []
-        b = min(Engine._MIN_BUCKET, self.mcfg.max_seq)
-        while b < self.mcfg.max_seq:
-            self.buckets.append(b)
-            b *= 2
-        self.buckets.append(self.mcfg.max_seq)
+        # The doubling widths, which are those of the decode side's `adopt`
+        # programs (its own prefill ladder is finer in its top octave; this
+        # pool still pads to a power of two). Smallest and largest warm
+        # eagerly; intermediates warm in the background and requests round
+        # UP to a warmed width until then (a synchronous compile inside a
+        # request would spike TTFT for everything queued behind it).
+        self.buckets: List[int] = doubling_widths(self.mcfg.max_seq)
         self._warm = {self.buckets[0], self.buckets[-1]}
 
         def warm(width: int) -> None:

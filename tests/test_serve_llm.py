@@ -109,7 +109,8 @@ def test_prefill_buckets_cross_boundary():
     """Bucketed prefill: prompts on either side of a bucket boundary
     produce the same tokens as each other's greedy continuation — the
     bucket width is a shape choice, never a semantics change. Engine
-    buckets are powers of 2 capped at max_seq."""
+    buckets double up to 1024 (tests/test_prefill_ladder.py has the rungs
+    above it) and end at max_seq."""
     import jax
 
     from ray_tpu.models.llama import LlamaConfig, init_params

@@ -47,7 +47,9 @@ def _no_compile_cache():
 
 
 FLASH_SHAPES = [(2, 32, 2048, 128),   # Llama-2-7B attention at batch 2
-                (1, 8, 256, 128)]     # one 256-token prefill bucket
+                (1, 8, 256, 128),     # one 256-token prefill bucket
+                (1, 32, 3584, 128)]   # a rung between 2048 and 4096: blocks
+#                                       of 512 (serve/engine.py::prefill_widths)
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
@@ -234,13 +236,15 @@ def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
 # Sparse attention (ops/sparse_attention.py) at Keye-VL-2.0's widths
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("S", [8192, 7168, 5120])
 @pytest.mark.parametrize("kernel", ["index_select", "masked_flash"])
-def test_sparse_attention_kernels_compile_for_v5e(topo, kernel):
-    """Prefill's two kernels in the 8,192 bucket: 16 indexer heads of 64,
-    top-k 2,048; GQA 32/4 heads of 128 under the selection's mask."""
+def test_sparse_attention_kernels_compile_for_v5e(topo, kernel, S):
+    """Prefill's two kernels in the 8,192 bucket and in two of the rungs under
+    it (`serve/engine.py::prefill_widths`; multiples of 1,024, so the key
+    blocks stay 1,024 wide): 16 indexer heads of 64, top-k 2,048; GQA 32/4
+    heads of 128 under the selection's mask."""
     from ray_tpu.ops import sparse_attention as sa
     one_chip = SingleDeviceSharding(topo.devices[0])
-    S = 8192
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -318,17 +322,20 @@ def test_sparse_decode_leaves_the_arenas_where_they_lie_on_v5e(
 # State-space layers (ops/ssm.py) at AI21-Jamba2-3B's widths
 # ---------------------------------------------------------------------------
 
-def test_selective_scan_kernel_compiles_for_v5e(topo):
-    """4,096 rows of 5,120 channels and 16 states, bfloat16 rows and a
-    float32 time step, gated: one Mosaic kernel, and nothing of size rows x
-    channels x states beside it (that would be 1.3 GB)."""
+@pytest.mark.parametrize("S", [4096, 3584, 2560])
+def test_selective_scan_kernel_compiles_for_v5e(topo, S):
+    """4,096 rows (and two narrower rungs of the prefill ladder, each a
+    multiple of the kernel's 512-row block) of 5,120 channels and 16 states,
+    bfloat16 rows and a float32 time step, gated: one Mosaic kernel, and
+    nothing of size rows x channels x states beside it (that would be 1.3
+    GB)."""
     from ray_tpu.ops import ssm
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    S, Di, N = 4096, 5120, 16
+    Di, N = 5120, 16
     rows, maps = sds((S, Di), jnp.bfloat16), sds((S, N), jnp.bfloat16)
     lowered = jax.jit(lambda x, dt, a, b, c, d, s0, z, n: ssm._scan_pallas(
         x, dt, a, b, c, d, s0, n, z, interpret=False,
