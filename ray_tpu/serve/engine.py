@@ -719,6 +719,12 @@ class Engine:
         self.admitted = 0
         self.queue_wait_s_sum = 0.0
         self.admit_chunks_ahead = 0        # chunks in flight, summed over admits
+        self.admit_decoding_slots = 0      # slots live, summed over admits
+        # When each slot was last freed (`_finish_state`; None until it has
+        # had a tenant), and the time the slots then stood empty, summed
+        # over the admissions that refilled them.
+        self._slot_freed: List[Optional[float]] = [None] * n_slots
+        self.slot_idle_s_sum = 0.0
         self.prefill_tokens = 0
         self.prefill_padded_tokens = 0     # bucket width less the prompt
         self.decode_chunks = 0
@@ -994,7 +1000,15 @@ class Engine:
         what `serve.engine.admit` and `serve.engine.decode_dispatch` spans
         say one at a time. `admit_chunks_ahead` over `admitted` is the
         decode chunks that were in flight when a request was admitted, which
-        its prefill queued behind (`_DEPTH` at most). Occupancy is
+        its prefill queued behind (`_DEPTH` at most).
+        `admit_decoding_slots` over `admitted` is the slots that were live
+        when a request was admitted, whose next chunk waited on the device
+        through its prefill (times a prefill's length: the decode time a
+        prefill stalls). `slot_idle_s_sum` over `admitted` is how long the
+        slot a request was given had stood empty since its last tenant
+        finished (nothing for a slot's first tenant); over `n_slots` times
+        the seconds elapsed it is the share of slot-time left unfilled.
+        Occupancy is
         `decode_useful_tokens` over
         `decode_chunks * n_slots * chunk`; padding is
         `prefill_padded_tokens` over it plus `prefill_tokens`.
@@ -1013,7 +1027,7 @@ class Engine:
         `state_bytes` once a step)."""
         out = {k: getattr(self, k) for k in (
             "admitted", "queue_wait_s_sum", "admit_chunks_ahead",
-            "prefill_tokens",
+            "admit_decoding_slots", "slot_idle_s_sum", "prefill_tokens",
             "prefill_padded_tokens", "decode_chunks",
             "decode_useful_tokens", "live_kv_tokens", "peak_pages_used",
             "n_slots", "chunk")}
@@ -1072,6 +1086,8 @@ class Engine:
                 self._pending.popleft()
                 left = len(self._pending)
                 ahead = self._in_flight
+                # Their next chunk queues behind this request's prefill.
+                decoding = int(self._active.sum())
             adopting = req.adopt_kv is not None
             width = req.adopt_kv[0].shape[1] if adopting else len(req.ids)
             # Only WARMED buckets are eligible (round up until the
@@ -1080,10 +1096,15 @@ class Engine:
             bucket = next(b for b in (self._adopt_widths if adopting
                                       else self.buckets)
                           if b >= width and b in self._warm)
-            waited = time.monotonic() - req.t_submit
+            now = time.monotonic()
+            waited = now - req.t_submit
+            freed = self._slot_freed[slot]
+            slot_idle = 0.0 if freed is None else now - freed
             self.admitted += 1
             self.queue_wait_s_sum += waited
             self.admit_chunks_ahead += ahead
+            self.admit_decoding_slots += decoding
+            self.slot_idle_s_sum += slot_idle
             if not adopting:
                 self.prefill_tokens += width
                 self.prefill_padded_tokens += bucket - width
@@ -1092,7 +1113,8 @@ class Engine:
                     kind="adopt" if adopting else "prefill",
                     prompt_tokens=len(req.ids), bucket=bucket,
                     queue_wait_us=int(waited * 1e6), pending=left,
-                    pages_free=self.pool.free - need, chunks_ahead=ahead):
+                    pages_free=self.pool.free - need, chunks_ahead=ahead,
+                    decoding=decoding, slot_idle_us=int(slot_idle * 1e6)):
                 emits.append(self._place(req, slot, need, bucket))
         # Start EVERY device->host copy first (async), THEN enqueue: a
         # burst overlaps all its transfers.
@@ -1173,6 +1195,7 @@ class Engine:
         slot's final tokens)."""
         self._slot_req[slot] = None
         self._active[slot] = False
+        self._slot_freed[slot] = time.monotonic()
         self.pool.release(slot)
         self._temp[slot] = 0.0
         self._topk[slot] = 0
@@ -1277,7 +1300,12 @@ class Engine:
         `ready()` (asked under `_cv`) or `stop()`. A submit ends the wait for
         another round of admission first, so an arrival with a free slot and
         pages is admitted whether or not the pipeline has room; the emitter
-        ends it when it has fetched a chunk's output."""
+        ends it when it has fetched a chunk's output. `_run_inner` stands
+        here twice a round and names each wait for the trace:
+        `serve.engine.idle` encloses the stand for a live slot, opened only
+        when none is live; `serve.engine.emit_block` the stand for pipeline
+        room after a dispatch. `serve.engine.admit` spans nest inside
+        either, so what the loop waited is the span less those."""
         while not self._stop:
             with self._cv:
                 seen = self._next_rid
@@ -1295,8 +1323,13 @@ class Engine:
             # Idle until an admission makes a slot live. Admission is
             # pipeline-safe: an in-flight chunk saw the new slot as
             # inactive, and its prefill/poke queue behind that chunk on
-            # the device.
-            self._stand(self._active.any)
+            # the device. A saturated engine finds a slot live here and
+            # opens no span: `serve.engine.idle` is nothing to do.
+            if self._active.any():
+                self._stand(self._active.any)
+            else:
+                with tracing.span("serve.engine.idle"):
+                    self._stand(self._active.any)
             if self._stop:
                 return
             # Predict this chunk's control outcome on the host: per-slot

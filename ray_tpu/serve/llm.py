@@ -91,7 +91,11 @@ class LLMServer:
     def device_info(self) -> Dict[str, Any]:
         """Where this replica runs: the device as JAX reports it, the
         chips the agent pinned, the attention path each traced program
-        took, and whether each warmed prefill width holds the kernel."""
+        took, whether each warmed prefill width holds the kernel, and the
+        engine's running totals (`Engine.counters()`: read twice, their
+        differences are whole-window means, among them the slots a prefill
+        stalled, `admit_decoding_slots`, and how long a freed slot stood
+        empty before its next tenant, `slot_idle_s_sum`)."""
         import jax
 
         from ray_tpu.ops.attention import attention_path_counts

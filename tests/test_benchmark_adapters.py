@@ -512,7 +512,8 @@ def test_jamba_manifest_entries_are_the_catalogs_row_uncut():
     assert reported == {
         "batch_tokens_per_s", "prefill_ms_per_ktok", "kv_pages_peak_pct",
         "prefill_ssm_ms_per_ktok", "scan_roofline_pct", "decode_ssm_ms",
-        "decode_state_roofline_pct"}
+        "decode_state_roofline_pct",
+        "engine_slot_refill_ms", "prefill_stall_pct"}        # PR 37
     # not `decode_attn_roofline_pct`: its bytes multiply by ALL the layers
 
 

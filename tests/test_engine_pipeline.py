@@ -16,11 +16,11 @@ from ray_tpu.serve.engine import _DEPTH, Engine
 from test_tracing import _Profiled
 
 
-def _build():
+def _build(n_slots=4):
     from ray_tpu.models.llama import LlamaConfig, init_params
     cfg = LlamaConfig.tiny()
-    eng = Engine(init_params(cfg, jax.random.PRNGKey(0)), cfg, n_slots=4,
-                 decode_chunk=4, page_size=16)
+    eng = Engine(init_params(cfg, jax.random.PRNGKey(0)), cfg,
+                 n_slots=n_slots, decode_chunk=4, page_size=16)
     while sorted(eng._warm) != sorted(eng.buckets):
         assert not eng.warm_error, eng.warm_error
         time.sleep(0.05)
@@ -75,10 +75,10 @@ class _Fetch:
         del self.eng._fetch
 
 
-def _delta(eng, before):
+def _delta(eng, before, keys=("admitted", "admit_chunks_ahead",
+                              "decode_chunks")):
     now = eng.counters()
-    return {k: now[k] - before[k] for k in
-            ("admitted", "admit_chunks_ahead", "decode_chunks")}
+    return {k: now[k] - before[k] for k in keys}
 
 
 def test_loop_stops_at_the_depth_and_admits_an_arrival_meanwhile(
@@ -95,6 +95,9 @@ def test_loop_stops_at_the_depth_and_admits_an_arrival_meanwhile(
         assert _delta(engine, before)["decode_chunks"] == _DEPTH
         b = engine.submit(list(range(1, 30)), 6)
         _until(lambda: engine._active.sum() == 2)
+        # b's admit span has closed: its "first" item is queued behind the
+        # `_DEPTH - 1` chunks the held emitter has not taken
+        _until(lambda: engine._emit_q.qsize() == _DEPTH)
         assert _delta(engine, before) == {
             "admitted": 2, "admit_chunks_ahead": _DEPTH,
             "decode_chunks": _DEPTH}
@@ -137,6 +140,123 @@ def test_chunks_ahead_is_0_on_an_idle_engine_and_at_most_the_depth(
     assert len(ahead) == 15 and ahead[0] == 0
     assert all(0 <= n <= _DEPTH for n in ahead)
     assert _delta(engine, before)["admit_chunks_ahead"] == sum(ahead)
+
+
+def test_slot_idle_is_the_time_a_freed_slot_stood_empty(tmp_path):
+    """Two requests through the one slot of an engine: the first tenant reads
+    0; the second reads the time since the first's last chunk was dispatched
+    (where the slot is freed), which holds the pause this test makes between
+    the first stream's end and the second submit and is held by the profile's
+    own clock from that dispatch's start to the second admit's. The counter
+    is the spans' sum."""
+    eng = _build(n_slots=1)
+    try:
+        before = eng.counters()
+        with _Profiled(tmp_path) as prof:
+            assert sum(map(len, _drain(eng.submit([1, 2, 3], 9)))) == 9
+            t_drained = time.monotonic()
+            time.sleep(0.05)
+            t_submit = time.monotonic()
+            assert sum(map(len, _drain(eng.submit([4, 5], 6)))) == 6
+        d = _delta(eng, before, ("admitted", "slot_idle_s_sum"))
+    finally:
+        eng.stop()
+    first, second = prof.events("serve.engine.admit")
+    assert first[3]["slot_idle_us"] == 0
+    idle_us = second[3]["slot_idle_us"]
+    assert idle_us >= (t_submit - t_drained) * 1e6 >= 50_000
+    freed_in = [start for _, start, end, _ in
+                prof.events("serve.engine.decode_dispatch")
+                if end <= second[1]][-1]
+    assert idle_us <= (second[1] - freed_in) / 1e3
+    assert d["admitted"] == 2
+    assert 0 <= d["slot_idle_s_sum"] * 1e6 - idle_us <= 3 * 2
+
+
+def test_decoding_on_an_admit_span_is_the_slots_live_at_that_instant(
+        engine, tmp_path):
+    """0 on an idle engine, n after n admissions that have not finished (the
+    emitter is held, so none does), and `admit_decoding_slots` is its sum."""
+    before = engine.counters()
+    with _Profiled(tmp_path) as prof, _Fetch(engine) as emitter:
+        streams = []
+        for n in range(3):
+            streams.append(engine.submit(list(range(1, 6 + n)), 40))
+            _until(lambda: engine._active.sum() == n + 1)
+        emitter.release()
+        for q in streams:
+            assert sum(map(len, _drain(q))) == 40
+    admits = [s for _, _, _, s in prof.events("serve.engine.admit")]
+    assert [a["decoding"] for a in admits] == [0, 1, 2]
+    assert _delta(engine, before, ("admitted", "admit_decoding_slots")) == {
+        "admitted": 3, "admit_decoding_slots": 0 + 1 + 2}
+    # every slot here has had a tenant in an earlier test of this module or
+    # is a first tenant's: the refill time is never negative
+    assert all(a["slot_idle_us"] >= 0 for a in admits)
+
+
+def test_idle_span_is_open_only_while_no_slot_is_live_and_states_are_disjoint(
+        engine, tmp_path):
+    """A request alone, a pause, three at once with the emitter slowed, a
+    pause, a request alone. `serve.engine.idle` covers the pauses, encloses
+    the admission round that ends each (arrivals that one round takes together
+    would all be inside it) and nothing of the time from there to the start
+    of the dispatch that frees the last slot: a busy engine opens none. The
+    stand the trace began in left no event (a span records when it closes, if
+    it opened in the trace), which is what `engine_trace.edge_idles` is for.
+    On the loop thread the four states' self times never overlap."""
+    from benchmark import engine_trace, program_trace
+
+    def slow(fetch, out_d):
+        time.sleep(0.003)
+        return fetch(out_d)
+
+    with _Profiled(tmp_path):
+        time.sleep(0.03)
+        assert sum(map(len, _drain(engine.submit([1, 2, 3], 9)))) == 9
+        time.sleep(0.03)
+        with _Fetch(engine, slow):
+            streams = [engine.submit(list(range(1, 9)), 30)]
+            _until(lambda: engine._active.any())    # its own admission round
+            streams += [engine.submit(list(range(1, 10 + k)), 30)
+                        for k in range(2)]
+            for q in streams:
+                assert sum(map(len, _drain(q))) == 30
+        time.sleep(0.03)
+        assert sum(map(len, _drain(engine.submit([7, 8], 5)))) == 5
+    t = program_trace.load_path(str(tmp_path))
+    idles = t.named(engine_trace.IDLE)
+    admits = t.named(engine_trace.ADMIT)
+    dispatches = t.named("serve.engine.decode_dispatch")
+    assert len(admits) == 5 and len(idles) == 2
+    assert [a.args["decoding"] for a in admits] == [0, 0, 1, 2, 0]
+
+    def last_dispatch_before(span):
+        return [d for d in dispatches if d.start < span.start][-1]
+
+    # a stand ends with the admission round that made a slot live
+    live = [(admits[0].end, last_dispatch_before(admits[1]).start),
+            (idles[0].end, last_dispatch_before(admits[4]).start),
+            (idles[1].end, dispatches[-1].start)]
+    own = engine_trace.self_intervals(t, engine_trace.IDLE)
+    assert len(own) == 4            # each stand, either side of its admit
+    for s, e in own:
+        assert all(e <= a or s >= b for a, b in live), (s, e, live)
+    # the stand that an arrival ends encloses its admit and no dispatch
+    for idle, admit in zip(idles, (admits[1], admits[4])):
+        assert idle.start <= admit.start and admit.end <= idle.end
+        assert not [d for d in dispatches if idle.start < d.start < idle.end]
+    assert all(e - s >= 0.03 * 1e9 for s, e in own[::2])    # the two pauses
+    # the trace began and ended in a stand: its first loop span is an admit
+    # that found no slot decoding, its last the wait after the last dispatch
+    first, last = min(s.start for s in t.spans), max(s.end for s in t.spans)
+    blocks = t.named("serve.engine.emit_block")
+    assert engine_trace.edge_idles(t, (first - 5.0, last + 7.0)) == [
+        (first - 5.0, admits[0].start), (blocks[-1].end, last + 7.0)]
+    every = sorted(iv for state in engine_trace.STATES
+                   for iv in engine_trace.self_intervals(t, state))
+    assert all(b[0] >= a[1] for a, b in zip(every, every[1:]))
+    assert len(blocks) == len(dispatches)
 
 
 def _mix(rng, n):

@@ -1,0 +1,254 @@
+"""benchmark/engine_trace.py and the four readers of PR 37 on traces built
+here by hand (no profiler, no device, no clock), and the guard that keeps the
+program's span names, PERF.md's list of them and the readers' literals one
+vocabulary."""
+
+import glob
+import os
+import re
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import engine_trace, program_trace, trace  # noqa: E402
+from benchmark.program_trace import ProgramTrace, Span  # noqa: E402
+
+IDLE, BLOCK, ADMIT, DISPATCH = engine_trace.STATES
+MS = 1_000_000      # ns
+
+
+def _reader(name):
+    from benchmark.run import HERE, load_reader
+    return load_reader(HERE, "layer_metrics", name)
+
+
+def _admit(rid, start, end, **args):
+    return Span(ADMIT, start, end, dict(
+        rid=rid, kind="prefill", prompt_tokens=1000, bucket=1024,
+        queue_wait_us=1, **args))
+
+
+def _serve_trace():
+    """A window of 10,000 ns on a 4-slot engine. The chip runs 3,000-4,500
+    (a prefill, a chunk), 5,300-6,800 (the same) and 7,000-8,000 (a chunk);
+    its four gaps are the ones the tests below split by hand."""
+    spans = [
+        Span(IDLE, 0, 2920, {}),                    # ends with its admission
+        _admit(1, 2500, 2900, decoding=0, slot_idle_us=0),
+        Span(DISPATCH, 2930, 2990, dict(useful=4, capacity=16, active=1)),
+        Span(BLOCK, 2990, 5200, {}),                # admits while it stands
+        _admit(2, 5000, 5100, decoding=1, slot_idle_us=1500),
+        Span("serve.engine.emit", 4010, 4020, dict(rid=1, kind="first")),
+        Span("serve.engine.emit", 4400, 5150, dict(kind="chunk")),  # emitter
+        Span("serve.engine.emit", 6310, 6320, dict(rid=2, kind="first")),
+        Span(BLOCK, 7900, 8100, {}),                # the last loop span
+        _admit(3, 7950, 7970, decoding=2, slot_idle_us=2500),
+        _admit(4, 7970, 7990, decoding=3, slot_idle_us=5000),
+    ]
+    modules = [("jit_prefill", 3000, 4000), ("jit_decode", 4000, 4500),
+               ("jit_prefill", 5300, 6300), ("jit_decode", 6300, 6800),
+               ("jit_decode", 7000, 8000)]
+    ops = [("jit(prefill)/mlp/dot_general:", 3000, 4000),
+           ("jit(decode)/while", 4000, 4500),
+           ("jit(decode)/while/body/mlp/dot_general:", 4100, 4400),
+           ("jit(prefill)/mlp/dot_general:", 5300, 6300),
+           ("jit(decode)/while", 6300, 6800), ("jit(decode)/while", 7000, 8000)]
+    return ProgramTrace(sorted(spans, key=lambda s: s.start), modules, ops)
+
+
+def _without_pr37(t):
+    """The same trace as the parent would have written it."""
+    return ProgramTrace(
+        [Span(s.name, s.start, s.end, {k: v for k, v in s.args.items()
+                                       if k not in ("decoding", "slot_idle_us")})
+         for s in t.spans if s.name != IDLE], t.modules, t.ops)
+
+
+def _run(window_ns=10_000.0):
+    return {"cell": "x", "seed": 0,
+            "config": {"deployment": {"engine": {"n_slots": 4}}},
+            "device": {"window_s": window_ns / 1e9},
+            "trace_data": trace.Trace([], [], 0.0, window_ns)}
+
+
+def test_idle_gaps_split_each_gap_by_the_loop_state_that_covers_it():
+    t = _serve_trace()
+    assert engine_trace.self_intervals(t, IDLE) == [(0, 2500), (2900, 2920)]
+    assert engine_trace.self_intervals(t, BLOCK) == [
+        (2990, 5000), (5100, 5200), (7900, 7950), (7990, 8100)]
+    assert engine_trace.self_intervals(t, ADMIT) == [
+        (2500, 2900), (5000, 5100), (7950, 7990)]
+    # the stand the trace ended in left no event: the last loop span is an
+    # `emit_block`, so what follows it is idle; the first is no bare admit
+    assert engine_trace.edge_idles(t, (0, 10_000)) == [(8100, 10_000)]
+    pieces = engine_trace.idle_gaps(t, (0, 10_000))
+    assert pieces == [
+        (IDLE, 0, 2500), (ADMIT, 2500, 2900), (IDLE, 2900, 2920),
+        (engine_trace.NO_SPAN, 2920, 2930), (DISPATCH, 2930, 2990),
+        (BLOCK, 2990, 3000),
+        # under `emit_block`, less the admit nested in it; the emitter's
+        # `serve.engine.emit` span over the same time is not subtracted
+        (BLOCK, 4500, 5000), (ADMIT, 5000, 5100), (BLOCK, 5100, 5200),
+        (engine_trace.NO_SPAN, 5200, 5300),
+        (engine_trace.NO_SPAN, 6800, 7000),         # under nothing
+        (BLOCK, 8000, 8100), (IDLE, 8100, 10_000)]
+    assert sum(e - s for _, s, e in pieces) == 10_000 - 4_000   # the idle time
+    per = engine_trace.shares(t, _run()["trace_data"])
+    assert per == pytest.approx({IDLE: 44.2, BLOCK: 7.1, ADMIT: 5.0,
+                                 DISPATCH: 0.6, engine_trace.NO_SPAN: 3.1})
+    assert engine_trace.with_work_pct(per) == pytest.approx(60.0 - 44.2)
+    gaps = engine_trace.longest(pieces, k=2)
+    assert [round(g * 1e9) for g, _ in gaps] == [3000, 2000]
+    assert [name for name, _ in gaps[0][1]] == [
+        IDLE, ADMIT, DISPATCH, engine_trace.NO_SPAN, BLOCK]
+    # without `trace.py`'s view, the window is first to last of what it holds
+    assert engine_trace.window_of(t) == (0, 8100)
+    assert sum(e - s for _, s, e in engine_trace.idle_gaps(t)) == 8100 - 4000
+
+
+def test_a_trace_that_began_in_a_stand_gives_its_head_to_idle():
+    """No idle span was recorded: the one the trace began in ended with the
+    first admission, which found no slot decoding."""
+    t = _serve_trace()
+    t = ProgramTrace([s for s in t.spans if s.name != IDLE], t.modules, t.ops)
+    assert engine_trace.edge_idles(t, (0, 10_000)) == [(0, 2500),
+                                                       (8100, 10_000)]
+    per = engine_trace.shares(t, _run()["trace_data"])
+    assert per[IDLE] == pytest.approx(44.0)     # all but 2900-2920
+    busy = ProgramTrace([s for s in t.spans if s.args.get("rid") != 1],
+                        t.modules, t.ops)       # first loop span: a dispatch
+    assert engine_trace.edge_idles(busy, (0, 10_000)) == [(8100, 10_000)]
+
+
+@pytest.mark.parametrize("name,want", [
+    # mean(1.5, 2.5, 5.0), not their median: rid 1 reads 0, a first tenant
+    ("engine_slot_refill_ms", 3.0),
+    # rid 1's prefill ran 1,000 ns with 0 slots decoding, rid 2's 1,000 with
+    # 1; the others' never ran: 1,000 slot-ns of 10,000 ns x 4 slots
+    ("prefill_stall_pct", 2.5),
+    ("idle_with_work_pct", 15.8),
+])
+def test_serve_readers_on_a_hand_built_trace(monkeypatch, name, want):
+    t = _serve_trace()
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    assert _reader(name)(_run()) == pytest.approx(want)
+    old = _without_pr37(t)
+    monkeypatch.setattr(program_trace, "load", lambda run: old)
+    assert _reader(name)(_run()) is None
+    monkeypatch.setattr(program_trace, "load", lambda run: None)
+    assert _reader(name)(_run()) is None
+    # a rehearsal's trace: host spans, no device plane
+    cpu = ProgramTrace(t.spans, [], [])
+    monkeypatch.setattr(program_trace, "load", lambda run: cpu)
+    run = dict(_run(), device={}, trace_data=None)
+    assert _reader(name)(run) == (want if name == "engine_slot_refill_ms"
+                                  else None)
+
+
+def test_ingest_next_ref_reader_on_a_hand_built_trace(monkeypatch):
+    def step(n, at):
+        return Span("train.step", at * MS, (at + 10) * MS, dict(step_num=n))
+
+    spans = [step(0, 0), Span("data.iter.next_ref", 20 * MS, 22 * MS, {}),
+             Span("data.iter.get_block", 22 * MS, 30 * MS, {}),
+             step(1, 100), Span("data.iter.next_ref", 120 * MS, 123 * MS, {}),
+             Span("data.iter.next_ref", 125 * MS, 126 * MS, {}),
+             step(2, 200), Span("data.iter.next_ref", 220 * MS, 229 * MS, {}),
+             step(3, 300)]
+    t = ProgramTrace(spans, [], [])
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    assert _reader("ingest_next_ref_ms")({}) == pytest.approx(4.0)   # 2, 4, 9
+    assert _reader("ingest_get_ms")({}) == pytest.approx(0.0)   # 8, 0, 0
+    bare = ProgramTrace([s for s in spans if "next_ref" not in s.name], [], [])
+    monkeypatch.setattr(program_trace, "load", lambda run: bare)
+    assert _reader("ingest_next_ref_ms")({}) is None
+
+
+def test_manifest_entries_of_the_four_readers():
+    from benchmark.tests.test_benchmark import load
+    per_layer = {p["name"]: p for p in load(ROOT, "BENCHMARK.json")["per_layer"]}
+    batch = ["serve-batch", "serve-batch-olmoe", "serve-longdoc-keye",
+             "serve-batch-jamba2"]
+    want = {
+        "engine_slot_refill_ms": ("scheduler (serve)", "ms",
+                                  "batch_tokens_per_s", batch),
+        "prefill_stall_pct": ("scheduler (serve)", "%",
+                              "batch_tokens_per_s", batch),
+        "idle_with_work_pct": ("device", "%", "ttft_p95_ms", ["serve-chat"]),
+        "ingest_next_ref_ms": ("scheduler (train)", "ms",
+                               "train_tokens_per_s_per_chip", ["train-1chip"]),
+    }
+    assert list(per_layer)[-4:] == list(want)       # appended, in this order
+    for name, (layer, unit, moves, cells) in want.items():
+        p = per_layer[name]
+        assert (p["layer"], p["unit"], p["moves"], p["workloads"],
+                p["better"], p["source"]) == (layer, unit, moves, cells,
+                                              "lower", "program_span")
+
+
+# -- one vocabulary: the program's spans, PERF.md's list, the readers --------
+
+_SPAN_CALL = re.compile(
+    r"tracing\.(?:span|compile_span)\(\s*\"([^\"]+)\"")
+_READ_CALL = re.compile(r"\b(?:named|per_step_ms)\(")
+_SPAN_NAME = re.compile(r"\"((?:serve|train|data)\.[a-z_.]+)\"")
+
+
+def _emitted():
+    names = set()
+    for path in glob.glob(os.path.join(ROOT, "ray_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            names.update(_SPAN_CALL.findall(f.read()))
+    return sorted(names)
+
+
+def _read():
+    """Span names in the arguments of every `named(` / `per_step_ms(` call of
+    the readers and of the modules they share, and `engine_trace`'s states."""
+    names = set(engine_trace.STATES)
+    for path in glob.glob(os.path.join(ROOT, "benchmark", "layer_metrics",
+                                       "*.py")) + \
+            glob.glob(os.path.join(ROOT, "benchmark", "*.py")):
+        with open(path) as f:
+            text = f.read()
+        for call in _READ_CALL.finditer(text):
+            depth, i = 1, call.end()
+            while depth and i < len(text):
+                depth += {"(": 1, ")": -1}.get(text[i], 0)
+                i += 1
+            names.update(_SPAN_NAME.findall(text[call.end():i]))
+    return sorted(names)
+
+
+def _perf_md_names():
+    with open(os.path.join(ROOT, "PERF.md")) as f:
+        text = f.read()
+    start = text.index("**Every name the program emits")
+    return text[start:text.index("\n## 4.", start)]
+
+
+@pytest.mark.parametrize("kind,name",
+                         [("emitted", n) for n in _emitted()]
+                         + [("read", n) for n in _read()])
+def test_span_names_are_one_vocabulary(kind, name):
+    """Every span the program opens is in PERF.md section 3's paragraph of
+    names with what reads it, and every span name a reader asks a trace for
+    is one the program opens: no span 'read by no metric' unknown to the
+    record, no reader of a span that was renamed."""
+    if kind == "emitted":
+        assert f"`{name}`" in _perf_md_names(), \
+            f"{name} is opened under ray_tpu/ and PERF.md section 3 lacks it"
+    else:
+        assert name in _emitted(), f"a reader asks for {name}: nothing opens it"
+
+
+def test_the_guard_sees_the_names_it_is_for():
+    assert {"serve.engine.idle", "serve.engine.admit", "data.iter.next_ref",
+            "train.compile", "serve.engine.warm"} <= set(_emitted())
+    assert {"serve.engine.admit", "serve.engine.decode_dispatch",
+            "data.iter.next_ref", "data.iter.format", "train.step",
+            "serve.engine.emit_block", "serve.engine.idle"} <= set(_read())
