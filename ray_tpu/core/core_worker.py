@@ -160,6 +160,9 @@ class CoreWorker:
         self._is_actor_worker = False
         self._exec_pool = concurrent.futures.ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="task-exec")
+        # An async actor's streams (create_actor_local): None everywhere else.
+        self._stream_pool: Optional[
+            concurrent.futures.ThreadPoolExecutor] = None
         self._worker_clients: Dict[Address, RpcClient] = {}
         # actor_id -> (addr, client, incarnation)
         self._actor_clients: Dict[bytes, Tuple[Address, RpcClient, int]] = {}
@@ -3731,7 +3734,8 @@ class CoreWorker:
     # actor method may PARK awaiting a later call (signal patterns) — a
     # single-in-flight flusher would deadlock it. Seqno ordering across
     # concurrent batches is preserved by assignment order here plus the
-    # worker's per-caller ordering gate.
+    # worker's per-caller ordering gate. A stream's push is not counted
+    # (the flusher says why).
     _ACTOR_PUSH_INFLIGHT = 32
 
     async def _flush_actor_pushes(self, actor_id: bytes) -> None:
@@ -3757,13 +3761,19 @@ class CoreWorker:
                 # contained refs) still holds them.
                 # And one retry budget per batch: never coalesce tasks
                 # with different max_retries.
-                def _has_refs(spec):
-                    return (_spec_has_ref_args(spec)
+                # A STREAM ships alone too, and takes none of the in-flight
+                # slots: its push's reply is the stream's END, which waits
+                # for no other member's, and the push is in flight as long
+                # as the stream, so the 33rd concurrent stream to an actor
+                # (and every plain call behind it) would wait for one of
+                # the first 32 to end.
+                def _alone(spec):
+                    return (spec.streaming or _spec_has_ref_args(spec)
                             or bool(self._task_arg_refs.get(spec.task_id)))
                 n = 1
-                if not _has_refs(buf[0][0]):
+                if not _alone(buf[0][0]):
                     while (n < cap and n < len(buf)
-                           and not _has_refs(buf[n][0])
+                           and not _alone(buf[n][0])
                            and buf[n][0].max_retries
                            == buf[0][0].max_retries
                            # Same method only: a fast probe must never
@@ -3774,25 +3784,30 @@ class CoreWorker:
                         n += 1
                 batch = buf[:n]
                 del buf[:n]
-                await sem.acquire()
+                if batch[0][0].streaming:
+                    def release():
+                        pass
+                else:
+                    await sem.acquire()
+                    release = sem.release
                 try:
                     # Prepare IN flusher order (seqnos must follow the
                     # submission order even with concurrent sends).
                     prepared = await self._prepare_actor_batch(actor_id,
                                                                batch)
                 except BaseException as e:
-                    sem.release()
+                    release()
                     err = e if isinstance(e, Exception) \
                         else WorkerCrashedError(repr(e))
                     for spec, fut in batch:
                         self._settle_spec_error(spec, fut, err)
                     continue
                 if prepared is None:
-                    sem.release()
+                    release()
                     continue
                 # lint: allow(rpc-in-loop: this loop IS the coalescer — one batched push per drained batch, inflight-bounded by the semaphore)
                 task = spawn(self._send_actor_batch(actor_id, *prepared))
-                task.add_done_callback(lambda _t, _s=sem: _s.release())
+                task.add_done_callback(lambda _t, _r=release: _r())
         finally:
             self._actor_flushing.discard(actor_id)
             # Submissions land from user threads: one may have appended
@@ -3967,8 +3982,14 @@ class CoreWorker:
         self._actor_is_async = any(
             _is_coro_attr(m) for m in dir(cls)
             if not m.startswith("__") or m == "__call__")
-        self._actor_sem = asyncio.Semaphore(
-            int(creation.get("max_concurrency") or 1000))
+        concurrency = int(creation.get("max_concurrency") or 1000)
+        self._actor_sem = asyncio.Semaphore(concurrency)
+        if self._actor_is_async:
+            # Every call the semaphore lets in can be a stream, and a stream
+            # holds its producer thread until it ends: as many threads as
+            # calls (made as streams need them, kept for the next).
+            self._stream_pool = concurrent.futures.ThreadPoolExecutor(
+                max_workers=concurrency, thread_name_prefix="stream-exec")
 
     async def cancel_task(self, task_id: bytes, force: bool = False) -> bool:
         """Cancel an incoming/running task on THIS worker (reference:
@@ -4357,12 +4378,16 @@ class CoreWorker:
             pass
 
     async def _execute_streaming(self, spec: TaskSpec, fn) -> dict:
-        """Run a generator task: the exec thread pulls items from the user
+        """Run a generator task: a producer thread pulls items from the user
         generator and emits each to the owner as its own return object,
         with a small send window; the owner's report handler parks its
         reply for consumer backpressure (reference:
         task_manager.cc HandleReportGeneratorItemReturns +
-        generator_waiter.cc)."""
+        generator_waiter.cc). The thread is the ordered exec thread for a
+        task or a sync actor (one stream at a time, in order), and one of
+        the actor's own `_stream_pool` for an async actor: every stream
+        its `max_concurrency` admits runs at once, whatever the host's
+        CPU count, and none holds a thread of the loop's default executor."""
         from ray_tpu.core.common import TaskCancelledError
         loop = asyncio.get_running_loop()
         owner = self._client_for_worker(tuple(spec.owner_addr))
@@ -4417,14 +4442,12 @@ class CoreWorker:
                 graftlog.flush_stdio_tee()
 
         try:
-            # Async actors stream CONCURRENTLY (default thread pool): a
-            # long-running generator must not head-of-line-block the
-            # single ordered exec thread — two clients streaming from one
-            # replica each get their own producer thread. Sync actors
-            # keep the ordered exec pool.
-            pool = None if getattr(self, "_actor_is_async", False) \
-                else self._exec_pool
-            total = await loop.run_in_executor(pool, run_gen)
+            # Never the loop's default executor: its min(32, cpus + 4)
+            # threads made the 18th stream on a 13-core host wait for
+            # another to END, and whatever else the process sends there
+            # (a replica's sync callables) queued behind the streams.
+            total = await loop.run_in_executor(
+                self._stream_pool or self._exec_pool, run_gen)
         except BaseException as e:
             tb = traceback.format_exc()
             err = e if isinstance(e, TaskCancelledError) else \
@@ -4462,10 +4485,12 @@ class CoreWorker:
 
     # ------------------------------------------------------------------
     def shutdown(self) -> None:
-        try:
-            self._exec_pool.shutdown(wait=False)
-        except Exception:
-            pass
+        for pool in (self._exec_pool, self._stream_pool):
+            try:
+                if pool is not None:
+                    pool.shutdown(wait=False)
+            except Exception:
+                pass
 
         # Drop the recycled staging inode (its pages die with us; live
         # objects hold their own hex link).

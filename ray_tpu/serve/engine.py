@@ -720,6 +720,7 @@ class Engine:
         self.queue_wait_s_sum = 0.0
         self.admit_chunks_ahead = 0        # chunks in flight, summed over admits
         self.admit_decoding_slots = 0      # slots live, summed over admits
+        self.admit_pending = 0             # requests left waiting, summed
         # When each slot was last freed (`_finish_state`; None until it has
         # had a tenant), and the time the slots then stood empty, summed
         # over the admissions that refilled them.
@@ -1004,10 +1005,13 @@ class Engine:
         `admit_decoding_slots` over `admitted` is the slots that were live
         when a request was admitted, whose next chunk waited on the device
         through its prefill (times a prefill's length: the decode time a
-        prefill stalls). `slot_idle_s_sum` over `admitted` is how long the
-        slot a request was given had stood empty since its last tenant
-        finished (nothing for a slot's first tenant); over `n_slots` times
-        the seconds elapsed it is the share of slot-time left unfilled.
+        prefill stalls). `admit_pending` over `admitted` is the requests
+        still waiting in `_pending` behind the one admitted: the depth of
+        the queue a saturated engine holds. `slot_idle_s_sum` over
+        `admitted` is how long the slot a request was given had stood
+        empty since its last tenant finished (nothing for a slot's first
+        tenant); over `n_slots` times the seconds elapsed it is the share
+        of slot-time left unfilled.
         Occupancy is
         `decode_useful_tokens` over
         `decode_chunks * n_slots * chunk`; padding is
@@ -1027,7 +1031,8 @@ class Engine:
         `state_bytes` once a step)."""
         out = {k: getattr(self, k) for k in (
             "admitted", "queue_wait_s_sum", "admit_chunks_ahead",
-            "admit_decoding_slots", "slot_idle_s_sum", "prefill_tokens",
+            "admit_decoding_slots", "admit_pending", "slot_idle_s_sum",
+            "prefill_tokens",
             "prefill_padded_tokens", "decode_chunks",
             "decode_useful_tokens", "live_kv_tokens", "peak_pages_used",
             "n_slots", "chunk")}
@@ -1104,6 +1109,7 @@ class Engine:
             self.queue_wait_s_sum += waited
             self.admit_chunks_ahead += ahead
             self.admit_decoding_slots += decoding
+            self.admit_pending += left
             self.slot_idle_s_sum += slot_idle
             if not adopting:
                 self.prefill_tokens += width

@@ -94,8 +94,9 @@ class LLMServer:
         took, whether each warmed prefill width holds the kernel, and the
         engine's running totals (`Engine.counters()`: read twice, their
         differences are whole-window means, among them the slots a prefill
-        stalled, `admit_decoding_slots`, and how long a freed slot stood
-        empty before its next tenant, `slot_idle_s_sum`)."""
+        stalled, `admit_decoding_slots`, how many requests waited behind an
+        admission, `admit_pending`, and how long a freed slot stood empty
+        before its next tenant, `slot_idle_s_sum`)."""
         import jax
 
         from ray_tpu.ops.attention import attention_path_counts

@@ -3,9 +3,21 @@
 Analogue of the reference's replica (reference: serve/_private/replica.py
 ReplicaActor:1095 — user callable wrapping, concurrent request handling,
 health checks, ongoing-request metrics for the router and autoscaler).
-Async actor: requests run concurrently on the io loop up to
-max_ongoing_requests; queue_len() answers router probes instantly even
-while requests are in flight.
+Async actor: queue_len() answers router probes instantly even while
+requests are in flight.
+
+What `max_ongoing_requests` bounds, and what it does not: it is the
+semaphore around `handle_request` (unary calls; a sync callable among them
+runs in a thread of the loop's default executor). A stream
+(`handle_request_streaming`, the path of every HTTP stream) takes no
+semaphore: the runtime gives it a producer thread of its own at once
+(`core_worker._execute_streaming`: the actor's own pool, as wide as the
+actor's max_concurrency), so every request the router sends reaches the
+user's generator when it arrives, and what cannot run yet waits inside the
+deployment: `LLMServer`'s in `Engine._pending`, FIFO, where a freed slot's
+next tenant is already at hand. `queue_len()` counts unary calls and streams
+alike, waiting or running, so the power-of-two router balances on the true
+load.
 """
 
 from __future__ import annotations
@@ -42,10 +54,11 @@ class Replica:
 
     async def handle_request(self, method: str, args_blob: bytes,
                              model_id: str = ""):
-        """Run one request through the user callable (async-concurrent).
-        Sync callables go to a thread pool — running them on the io loop
-        would stall health checks and queue probes, and the controller
-        would kill a merely-busy replica."""
+        """Run one request through the user callable (async-concurrent, at
+        most max_ongoing_requests at once). Sync callables go to the loop's
+        default thread pool, which no stream ever holds a thread of: running
+        them on the io loop would stall health checks and queue probes, and
+        the controller would kill a merely-busy replica."""
         import contextvars
 
         from ray_tpu.serve.multiplex import _set_current_model_id
@@ -74,7 +87,8 @@ class Replica:
     def handle_request_streaming(self, method: str, args_blob: bytes,
                                  model_id: str = ""):
         """Streaming variant: the user method is a (sync) generator; items
-        stream back through the runtime's ObjectRefGenerator."""
+        stream back through the runtime's ObjectRefGenerator. Not bounded
+        by max_ongoing_requests (the module's docstring says what is)."""
         from ray_tpu.serve.multiplex import _set_current_model_id
 
         args, kwargs = cloudpickle.loads(args_blob)

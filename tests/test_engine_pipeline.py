@@ -337,6 +337,126 @@ def test_submits_from_many_threads_are_all_served_and_counted(engine):
     assert engine._in_flight == 0 and engine.pages_in_use() == 0
 
 
+def _small_mix(rng, n, least):
+    """`_mix` cut to requests of 4 pages at most, `least` tokens at least: 4
+    slots' worth always fit the pool, so only a slot is ever waited for."""
+    return [(ids[:30], least + m % (31 - least), sampling)
+            for ids, m, sampling in _mix(rng, n)]
+
+
+class _Served:
+    """What a replica hosts: `LLMServer.__call__` without the HTTP body, a
+    generator over one request's stream. The test puts its engine here."""
+
+    engine = None
+
+    def generate(self, ids, max_tokens, sampling):
+        q = self.engine.submit(ids, max_tokens, **sampling)
+        while (item := q.get(timeout=60)) is not None:
+            yield item
+
+
+class _Clients:
+    """`serve.replica.Replica` in this process over `eng`, with
+    `max_ongoing_requests` = `n_slots` as `serve/llm.py` deploys it, and one
+    thread a request iterating `handle_request_streaming` as the runtime's
+    producer threads do. `send` returns when the engine has the request, so
+    the order of the calls is the order of the submits."""
+
+    def __init__(self, eng):
+        import cloudpickle
+
+        from ray_tpu.serve.replica import Replica
+        _Served.engine = self.eng = eng
+        self._dumps = cloudpickle.dumps
+        self.replica = Replica(self._dumps(_Served), self._dumps(((), {})),
+                               "llm", max_ongoing=eng.n_slots)
+        self.threads, self.got = [], {}
+
+    def send(self, k, ids, max_tokens, sampling):
+        def client():
+            self.got[k] = list(self.replica.handle_request_streaming(
+                "generate", self._dumps(((ids, max_tokens, sampling), {}))))
+
+        seen = self.eng._next_rid
+        self.threads.append(threading.Thread(target=client))
+        self.threads[-1].start()
+        _until(lambda: self.eng._next_rid == seen + 1)
+
+    def join(self):
+        for t in self.threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in self.threads)
+        assert self.replica._ongoing == 0
+        return [self.got[k] for k in sorted(self.got)]
+
+
+def test_three_times_the_slots_stream_through_a_replica_and_queue_at_the_engine(
+        engine, tmp_path):
+    """Nothing between the router and `Engine.submit` holds a stream back: 12
+    requests on 4 slots are all at the engine at once, 8 of them in `_pending`
+    (the emitter is held, so no slot frees), the replica counts all 12 for the
+    router, and they are admitted in the order they were submitted. The admit
+    spans say how many still waited, `admit_pending` is their sum, and every
+    stream is what its request streams alone."""
+    rng = np.random.default_rng(3814)
+    asks = _small_mix(rng, 12, least=12)   # outlasts the chunks in flight
+    clients = _Clients(engine)
+    before = engine.counters()
+    with _Profiled(tmp_path) as prof, _Fetch(engine) as emitter:
+        for k, ask in enumerate(asks):
+            clients.send(k, *ask)
+        _until(lambda: engine._active.sum() == engine.n_slots)
+        assert len(engine._pending) == 8 and clients.replica._ongoing == 12
+        assert clients.replica._max_ongoing == engine.n_slots
+        emitter.release()
+        together = clients.join()
+    admits = [s for _, _, _, s in prof.events("serve.engine.admit")]
+    assert [a["prompt_tokens"] for a in admits] == [
+        len(ids) for ids, _, _ in asks]                 # FIFO, none overtaken
+    assert [a["rid"] for a in admits] == sorted(a["rid"] for a in admits)
+    waiting = [a["pending"] for a in admits]
+    assert waiting[-1] == 0 and max(waiting) >= 7 and sum(waiting) > 0
+    assert _delta(engine, before, ("admitted", "admit_pending")) == {
+        "admitted": 12, "admit_pending": sum(waiting)}
+    # the last eight waited at the engine through their predecessors' decode
+    assert all(a["queue_wait_us"] > 0 for a in admits[engine.n_slots:])
+    for (ids, m, sampling), got in zip(asks, together):
+        alone = _drain(engine.submit(ids, m, **sampling))
+        assert [t for c in got for t in c] == [t for c in alone for t in c]
+        assert sum(map(len, got)) == min(m, engine.mcfg.max_seq - len(ids))
+    assert engine.pages_in_use() == 0
+
+
+def test_a_freed_slot_with_a_request_pending_is_refilled_within_a_chunk(
+        engine, tmp_path):
+    """S14: with requests waiting in `_pending`, the slot a chunk's dispatch
+    frees has its next tenant admitted by the loop's next stand, not when a
+    client has seen a stream end: `slot_idle_us` of every such refill is
+    under the time of one chunk (the emitter's fetch is slowed to 50 ms a
+    chunk, which is then the least a chunk takes), and its admit span opens
+    before the freeing chunk's output has been fetched."""
+    chunk_s = 0.05
+
+    def slow(fetch, out_d):
+        time.sleep(chunk_s)
+        return fetch(out_d)
+
+    rng = np.random.default_rng(3815)
+    asks = _small_mix(rng, 12, least=6)
+    clients = _Clients(engine)
+    with _Profiled(tmp_path) as prof, _Fetch(engine, slow):
+        for k, ask in enumerate(asks):
+            clients.send(k, *ask)
+        clients.join()
+    admits = [s for _, _, _, s in prof.events("serve.engine.admit")]
+    refills = [a for a in admits[engine.n_slots:]
+               if a["queue_wait_us"] > a["slot_idle_us"]]   # it was waiting
+    assert len(refills) >= 6, admits
+    assert max(a["slot_idle_us"] for a in refills) < chunk_s * 1e6, refills
+    assert max(a["chunks_ahead"] for a in refills) >= 1     # mid-pipeline
+
+
 def _threads_of(eng):
     return {eng._thread, eng._emitter, eng._warm_thread}
 
@@ -373,5 +493,37 @@ def test_a_failing_fetch_ends_its_streams_and_later_submits_raise():
             eng.submit([1, 2, 3], 4)
         # every chunk still leaves the count: the loop is not wedged
         _until(lambda: not eng._active.any() and eng._in_flight == 0)
+    eng.stop()
+    assert not _threads_of(eng) & set(threading.enumerate())
+
+
+def test_a_failing_dispatch_ends_the_streams_still_pending_too():
+    """The loop's own fault (`_run`'s drain): with 2 slots live and 5 more
+    requests waiting in `_pending`, a decode dispatch that raises ends all
+    seven streams, through the replica, and later submits raise."""
+    eng = _build(n_slots=2)
+    clients = _Clients(eng)
+
+    def broken(*args):
+        raise RuntimeError("dispatch failed")
+
+    with _Fetch(eng) as emitter:
+        for k in range(7):
+            clients.send(k, list(range(1, 9 + k)), 40, {})
+        _until(lambda: eng._in_flight == _DEPTH)
+        assert eng._active.sum() == 2 and len(eng._pending) == 5
+        eng._decode = broken
+        emitter.release()
+        got = clients.join()
+    _until(lambda: not eng._thread.is_alive())      # the drain's last None
+    assert "dispatch failed" in eng.error and not eng._pending
+    # the two tenants streamed at most what was dispatched before the fault
+    # (the drain's None may overtake what the emitter still holds, a first
+    # token included); the five that waited streamed nothing and ended
+    streamed = [sum(map(len, g)) for g in got]
+    assert all(n <= 1 + _DEPTH * eng.chunk for n in streamed[:2])
+    assert streamed[2:] == [0] * 5
+    with pytest.raises(RuntimeError, match="dispatch failed"):
+        eng.submit([1, 2, 3], 4)
     eng.stop()
     assert not _threads_of(eng) & set(threading.enumerate())

@@ -136,6 +136,70 @@ def test_actor_streaming_method(cluster):
     assert [ray_tpu.get(r) for r in gen] == ["tok0", "tok1", "tok2", "tok3"]
 
 
+def test_async_actor_streams_run_all_at_once_off_the_default_executor(
+        cluster):
+    """40 streaming calls on one async actor each hold a producer thread of
+    their own until every one has started and the actor is told to let go:
+    none waits for a thread that another stream's END frees, however few
+    the loop's default executor has (2 here; min(32, cpus + 4) otherwise,
+    which is 17 on the 13-core chip host), nor for one of the caller's 32
+    pushes in flight. Meanwhile the actor's sync method answers, and so does
+    a sync callable that an async method sends to the default executor, as
+    `serve.replica.Replica.handle_request` does: no stream holds a thread of
+    that either."""
+    import asyncio
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    n = 40
+
+    @ray_tpu.remote
+    class Gate:
+        def __init__(self):
+            self.lock = threading.Lock()
+            self.started = 0
+            self.let_go = threading.Event()
+
+        async def shrink_default_executor(self, workers):
+            asyncio.get_running_loop().set_default_executor(
+                ThreadPoolExecutor(max_workers=workers))
+
+        def stream(self, i):
+            with self.lock:
+                self.started += 1
+            assert self.let_go.wait(90), "never let go"
+            yield i
+
+        def started_now(self):
+            return self.started
+
+        async def open(self):
+            def sync_callable():
+                self.let_go.set()
+                return self.started
+
+            return await asyncio.get_running_loop().run_in_executor(
+                None, sync_callable)
+
+    gate = Gate.remote()
+    ray_tpu.get(gate.shrink_default_executor.remote(2), timeout=30)
+    streams = []
+    deadline = time.monotonic() + 90
+    for i in range(n):
+        # One push a stream (the caller coalesces none), each in flight
+        # until the gate opens: the caller's cap on pushes in flight to one
+        # actor (32) must not count them, or the 33rd never starts and the
+        # plain call behind it never returns.
+        streams.append(
+            gate.stream.options(num_returns="streaming").remote(i))
+        while ray_tpu.get(gate.started_now.remote(), timeout=30) <= i:
+            assert time.monotonic() < deadline, f"stream {i} never started"
+            time.sleep(0.01)
+    assert ray_tpu.get(gate.open.remote(), timeout=30) == n
+    assert sorted(ray_tpu.get(ref) for gen in streams for ref in gen) \
+        == list(range(n))
+
+
 def test_cancel_running_task(cluster):
     @ray_tpu.remote
     def spin():
