@@ -5,7 +5,16 @@ engine's prefill and its decode step (`serve/engine.py`) all call them.
   attention_inputs   attn_norm -> q, k, v -> (q/k norm) -> heads -> RoPE
                      (+ a sparse-attention indexer's queries, key and head
                      weights, from the same normed input)
-  feed_forward       mlp_norm -> dense SwiGLU, or router + experts
+  latent_attention_inputs
+                     its peer for latent attention (MLA): attn_norm -> q
+                     through its latent and norm, one latent row and one
+                     rotated key a token, and either every head's key and
+                     value up-projected from the latent (a prompt) or the key
+                     up-projection absorbed into q (a decode step, whose
+                     attention then reads the cached rows themselves;
+                     `latent_attention_output` is the value's half)
+  feed_forward       mlp_norm -> dense SwiGLU, or router + experts (+ a
+                     shared expert; the experts a share of the router's)
   mamba_mixer        a state-space layer's whole mixer, over a sequence or
                      for one token a slot: its state is an argument and a
                      result, so where the state lives is the caller's
@@ -95,6 +104,87 @@ def attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
     return q, k, v
 
 
+def latent_attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
+                            rope: Callable[[jax.Array], jax.Array], *,
+                            absorb: bool = False) -> Tuple:
+    """Latent attention's (MLA's) cache-free half, the DeepSeek-V3 block's:
+
+      cq       = rmsnorm(h W_DQ);  [q_n ; q_r] = cq W_UQ  a head   scope q_latent
+      [c ; kr] = h W_DKV;  c = rmsnorm(c)                          kv_latent
+      q_r, kr rotated by `rope` (ONE kr a token, shared by all heads)   rope
+      a prompt:       [k_n ; v] = c W_UKV  a head                   kv_up
+      a decode step:  ql = q_n W_UK^T  a head  (`absorb`)           absorb
+
+    `h` is the normed input; what a cache keeps of a token is `c` and the
+    rotated `kr`, nothing else. -> for a prompt (x `[batch, seq, d_model]`)
+    q_n `[b, H, s, dn]`, q_r `[b, H, s, dr]`, k_n `[b, H, s, dn]`, v `[b, H,
+    s, dv]`, c `[b, s, rkv]`, kr `[b, s, dr]`: head h's key is `[k_n[h] ;
+    kr]`. With `absorb` (x `[slots, d_model]`) ql `[ns, H, rkv]`, q_r `[ns, H,
+    dr]`, c `[ns, rkv]`, kr `[ns, dr]`: head h's score against a cached row
+    (c_s, kr_s) is `ql[h] . c_s + q_r[h] . kr_s`, the same number as `[q_n ;
+    q_r] . [c_s W_UK ; kr_s]`, and its output `(sum_s p_s c_s) W_UV`
+    (`latent_attention_output`). `lp` holds the up-projections as they are
+    published, `w_uq` `[rq, H * (dn + dr)]` and `w_ukv` `[rkv, H * (dn +
+    dv)]`, or as serving does, each cut by what it makes: `w_uq_n`, `w_uq_r`,
+    `w_uk` `[rkv, H, dn]` and `w_uv` `[rkv, H, dv]` (`fuse_qkv`)."""
+    lead = x.shape[:-1]
+    H, rkv = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
+    dt, eps = cfg.dtype, cfg.norm_eps
+    seq = len(lead) == 2
+
+    with jax.named_scope("attn_norm"):
+        h = rms_norm(x, lp["attn_norm"], eps)
+    with jax.named_scope("qkv"):
+        with jax.named_scope("q_latent"):
+            cq = rms_norm(h @ lp["w_dq"].astype(dt), lp["q_norm"], eps)
+            if "w_uq_n" in lp:      # serving: a matrix for each part of q
+                q_n = (cq @ lp["w_uq_n"].astype(dt)).reshape(*lead, H, dn)
+                q_r = (cq @ lp["w_uq_r"].astype(dt)).reshape(*lead, H, dr)
+            else:
+                q = (cq @ lp["w_uq"].astype(dt)).reshape(*lead, H, dn + dr)
+                q_n, q_r = q[..., :dn], q[..., dn:]
+            if seq:
+                q_n, q_r = (t.transpose(0, 2, 1, 3) for t in (q_n, q_r))
+        with jax.named_scope("kv_latent"):
+            ckr = h @ lp["w_dkv"].astype(dt)
+            c = rms_norm(ckr[..., :rkv], lp["kv_norm"], eps)
+            kr = ckr[..., rkv:]
+    with jax.named_scope("rope"):
+        q_r = rope(q_r)
+        kr = rope(kr[:, None])[:, 0]        # one head for the rotation
+    w_uk, w_uv = _up_projections(lp, cfg)
+    with jax.named_scope("qkv"):
+        if absorb:
+            with jax.named_scope("absorb"):
+                ql = jnp.einsum("nhd,rhd->nhr", q_n, w_uk.astype(dt))
+            return ql, q_r, c, kr
+        with jax.named_scope("kv_up"):
+            k_n = jnp.einsum("bsr,rhd->bhsd", c, w_uk.astype(dt))
+            v = jnp.einsum("bsr,rhd->bhsd", c, w_uv.astype(dt))
+    return q_n, q_r, k_n, v, c, kr
+
+
+def latent_attention_output(lp: Dict[str, jax.Array], ol: jax.Array, cfg
+                            ) -> jax.Array:
+    """The value's half of the absorbed form: ol `[ns, H, rkv]`, each head's
+    softmax-weighted sum of the cached latent rows, through that head's value
+    up-projection -> `[ns, H * dv]`, what `wo` takes."""
+    _, w_uv = _up_projections(lp, cfg)
+    with jax.named_scope("absorb"):
+        o = jnp.einsum("nhr,rhd->nhd", ol.astype(cfg.dtype),
+                       w_uv.astype(cfg.dtype))
+    return o.reshape(ol.shape[0], -1)
+
+
+def _up_projections(lp, cfg):
+    """(W_UK `[rkv, H, dn]`, W_UV `[rkv, H, dv]`) from either layout."""
+    if "w_uk" in lp:
+        return lp["w_uk"], lp["w_uv"]
+    w = lp["w_ukv"].reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+    return w[..., :cfg.qk_nope_dim], w[..., cfg.qk_nope_dim:]
+
+
 def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
                  live: Optional[jax.Array] = None,
                  layer: Optional[jax.Array] = None
@@ -103,18 +193,33 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
     (x, (aux loss, tokens per expert `[n_experts]` int32)), the count over the
     rows `live` marks (`x`'s shape less its last axis; every row if None).
     With `layer`, the experts' weights in `lp` are the stacks of all layers
-    and `layer` this block's index (`expert_stacks`, `ops.moe.moe_ffn`)."""
+    and `layer` this block's index (`expert_stacks`, `ops.moe.moe_ffn`).
+    A layer is sparse if it has a router: a sparse model's leading dense
+    layers (`cfg.first_dense`) have none. The router's variant, the share of
+    the experts held (the count is then per HELD expert) and a shared expert
+    are `cfg`'s (`LlamaConfig.routing`, `experts_held`, `n_shared_experts`)."""
     dt = cfg.dtype
     with jax.named_scope("mlp_norm"):
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     with jax.named_scope("mlp"):
-        if cfg.n_experts > 0:
+        if cfg.n_experts > 0 and "router" in lp:
+            more = {}
+            if cfg.latent:
+                routing = cfg.routing()
+                if routing is not None:
+                    routing = dict(routing, bias=lp["router_bias"])
+                more = dict(
+                    routing=routing, held=cfg.experts_held,
+                    shared=tuple(lp["ws_" + k].astype(dt)
+                                 for k in ("gate", "up", "down"))
+                    if cfg.n_shared_experts else None)
             out, aux, counts = moe_ffn(
                 h.reshape(-1, h.shape[-1]), lp["router"].astype(dt),
                 lp["w_up"].astype(dt), lp["w_gate"].astype(dt),
                 lp["w_down"].astype(dt), top_k=cfg.top_k_experts,
                 norm_topk_prob=cfg.norm_topk_prob,
-                live=None if live is None else live.reshape(-1), layer=layer)
+                live=None if live is None else live.reshape(-1), layer=layer,
+                **more)
             return x + out.reshape(x.shape), (aux, counts)
         gate = h @ lp["w_gate"].astype(dt)
         up = h @ lp["w_up"].astype(dt)
@@ -200,7 +305,53 @@ def _qkv_ends(cfg) -> List[int]:
     return ends
 
 
-def fuse_qkv(params: Dict[str, Any]) -> Dict[str, Any]:
+_LATENT_STACKS = ("layers", "dense")
+
+
+def _fuse_latent(params, cfg):
+    """A latent-attention model as the serving programs take it: each
+    stack's `w_ukv` `[L, rkv, H * (dn + dv)]` cut ONCE into `w_uk` `[L, rkv,
+    H, dn]` and `w_uv` `[L, rkv, H, dv]`, and its `w_uq` `[L, rq, H * (dn +
+    dr)]` into `w_uq_n` `[L, rq, H * dn]` and `w_uq_r` `[L, rq, H * dr]`. A
+    decode step multiplies by each apart (`latent_attention_inputs`), and a
+    slice of a joined matrix inside the program is a copy of it every layer
+    of every step: of `w_uq`, cut at 128 of a head's 192 columns, 75 MB a
+    layer a step at DeepSeek-V3's widths, after a copy of the whole stack
+    at every call (0.5 of a 13.5 ms step; PERF.md, PR 39)."""
+    H, dn = cfg.n_heads, cfg.qk_nope_dim
+    out = dict(params)
+    for name in _LATENT_STACKS:
+        if name in params:
+            layers = dict(params[name])
+            w = layers.pop("w_ukv")
+            w = w.reshape(*w.shape[:2], H, -1)
+            layers["w_uk"] = w[..., :dn]
+            layers["w_uv"] = w[..., dn:]
+            w = layers.pop("w_uq")
+            w = w.reshape(*w.shape[:2], H, -1)
+            layers["w_uq_n"] = w[..., :dn].reshape(*w.shape[:2], -1)
+            layers["w_uq_r"] = w[..., dn:].reshape(*w.shape[:2], -1)
+            out[name] = layers
+    return out
+
+
+def _split_latent(params):
+    out = dict(params)
+    for name in _LATENT_STACKS:
+        if name in params:
+            layers = dict(params[name])
+            L, r, H, _ = layers["w_uk"].shape
+            layers["w_ukv"] = jnp.concatenate(
+                [layers.pop("w_uk"), layers.pop("w_uv")], -1).reshape(L, r, -1)
+            rq = layers["w_uq_n"].shape[1]
+            layers["w_uq"] = jnp.concatenate(
+                [layers.pop(k).reshape(L, rq, H, -1)
+                 for k in ("w_uq_n", "w_uq_r")], -1).reshape(L, rq, -1)
+            out[name] = layers
+    return out
+
+
+def fuse_qkv(params: Dict[str, Any], cfg=None) -> Dict[str, Any]:
     """A model's parameters as the serving programs take them: the layers'
     `wq`, `wk`, `wv` `[L, d_model, n * hd]` (and an indexer's `wiq`, `wik`,
     `wiw`, further columns of the same input) joined along their columns into
@@ -215,7 +366,10 @@ def fuse_qkv(params: Dict[str, Any]) -> Dict[str, Any]:
     order in which a row is summed. Training keeps a matrix each
     (`attention_inputs`): its gradient, optimizer state, checkpoints and `tp`
     sharding are by matrix. The result holds no reference to the stacks it
-    joined."""
+    joined. A latent-attention model (`cfg` says which) has no q, k and v
+    matrices to join: its serving layout is `_fuse_latent`'s."""
+    if cfg is not None and cfg.latent:
+        return _fuse_latent(params, cfg)
     layers = dict(params["layers"])
     names = _QKV + tuple(n for n in _INDEX if n in layers)
     layers["wqkv"] = jnp.concatenate([layers.pop(k) for k in names], axis=-1)
@@ -227,6 +381,8 @@ def split_qkv(params: Dict[str, Any], cfg) -> Dict[str, Any]:
     reference reads, its matrices cut from the fused stack anew at every
     call (bit for bit what was fused), so nothing holds a second copy of the
     projections longer than its caller does."""
+    if cfg.latent:
+        return _split_latent(params)
     layers = dict(params["layers"])
     parts = jnp.split(layers.pop("wqkv"), _qkv_ends(cfg), axis=-1)
     return dict(params, layers=dict(layers, **dict(zip(_fused_names(cfg),
@@ -245,10 +401,11 @@ def expert_stacks(layers: Dict[str, jax.Array], cfg
     it is built), a copy of all the experts a call where they are not. Training keeps the slice
     (`llama._layer_fwd`): a gradient through the stack would be written whole
     once a layer."""
-    if "wqkv" not in layers:
+    if "wqkv" not in layers and "w_uk" not in layers:
         raise ValueError("a serving program takes `fuse_qkv(params)`: one "
                          "q/k/v projection stack, not a matrix each")
-    names = ("w_gate", "w_up", "w_down") if cfg.n_experts > 0 else ()
+    names = ("w_gate", "w_up", "w_down") \
+        if cfg.n_experts > 0 and "router" in layers else ()
     return ({k: v for k, v in layers.items() if k not in names},
             {k: layers[k].astype(cfg.dtype) for k in names})
 

@@ -86,6 +86,42 @@ class LlamaConfig:
     rope: bool = True
     # True: the head is the embedding transposed; `lm_head` is no leaf.
     tie_embeddings: bool = False
+    # Latent attention (MLA, the DeepSeek-V3 family): kv_lora_rank > 0 => q
+    # goes through a latent of q_lora_rank and a norm, and a token leaves ONE
+    # normed latent row of kv_lora_rank and ONE rotated key of qk_rope_dim,
+    # shared by all heads, from which every head's key (qk_nope_dim, beside
+    # the shared qk_rope_dim) and value (v_head_dim) are up-projected
+    # (`models.block.latent_attention_inputs`). That row of kv_lora_rank +
+    # qk_rope_dim numbers is all a serving cache holds
+    # (`ops/paged_kv.py::empty_latent`); n_kv_heads and head_dim say nothing.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: (factor, original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale_all_dim) => `ops.norms.yarn_frequencies` and `softmax_scale`.
+    rope_yarn: Optional[Tuple[float, ...]] = None
+    # The first `first_dense` layers have a dense feed-forward of width
+    # `d_ff_dense`, the rest the sparse one (n_experts > 0). Their parameters
+    # are the stack `dense`; `layers` holds the sparse layers.
+    first_dense: int = 0
+    d_ff_dense: int = 0
+    # Shared experts: a dense SwiGLU of width n_shared_experts * d_ff every
+    # token meets beside its routed experts.
+    n_shared_experts: int = 0
+    # The router's variant (`ops.moe.top_k_routing`): "softmax", or "sigmoid"
+    # with a selection bias (a leaf, `router_bias`), `n_group` groups of which
+    # `topk_group` stay, and the factor `routed_scale` on the weights.
+    router_score: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scale: float = 1.0
+    # (offset, count): this program holds experts offset .. offset + count - 1
+    # of the n_experts the router scores, one share of an expert-parallel
+    # deployment, and computes their part of every layer's mixture
+    # (`ops.moe.moe_ffn`); None: every expert.
+    experts_held: Optional[Tuple[int, int]] = None
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -104,6 +140,20 @@ class LlamaConfig:
         if self.attn_layers is not None:
             object.__setattr__(self, "attn_layers",
                                tuple(sorted(self.attn_layers)))
+        if self.latent and (self.ssm_state or self.index_topk or self.qk_norm
+                            or self.mrope_section or self.tie_embeddings):
+            raise ValueError("latent attention (kv_lora_rank > 0) comes with "
+                             "no state-space layers, indexer, q/k norm, "
+                             "mrope or tied head")
+        if self.first_dense and not (self.latent and self.n_experts
+                                     and self.first_dense < self.n_layers):
+            raise ValueError("first_dense: leading dense layers under a "
+                             "sparse latent-attention stack")
+        if (self.experts_held or self.n_shared_experts
+                or self.router_score != "softmax") and not self.latent:
+            raise ValueError("a share of the experts, shared experts and the "
+                             "sigmoid router are served by the latent-"
+                             "attention stack alone (kv_lora_rank > 0)")
         if bool(self.ssm_state) != (self.attn_layers is not None):
             raise ValueError("ssm_state and attn_layers come together: the "
                              "state-space widths and which layers are not "
@@ -116,13 +166,56 @@ class LlamaConfig:
             else len(self.attn_layers)
 
     @property
+    def latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a token leaves in a latent cache, a layer."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights this program holds."""
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
+    @property
+    def softmax_scale(self) -> float:
+        """What attention multiplies q . k by: head width^-1/2, times YaRN's
+        m^2, m = 0.1 mscale_all_dim ln(factor) + 1."""
+        import math
+        if not self.latent:
+            return self.head_dim ** -0.5
+        scale = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+        if self.rope_yarn:
+            factor, *_, mscale_all_dim = self.rope_yarn
+            scale *= (0.1 * mscale_all_dim * math.log(factor) + 1.0) ** 2
+        return scale
+
+    def routing(self) -> Optional[Dict[str, Any]]:
+        """`ops.moe.moe_ffn`'s `routing` but the bias, which is a leaf; None
+        for the softmax router."""
+        if self.router_score == "softmax":
+            return None
+        return dict(score=self.router_score, n_group=self.n_group,
+                    topk_group=self.topk_group, scale=self.routed_scale)
+
+    @property
     def ssm_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
     def segments(self) -> Tuple[Tuple[str, int, int], ...]:
-        """A hybrid stack in the order it runs: ("mamba", lo, hi), a run of
-        state-space layers as ordinals into the `mamba` stack, or ("attn",
-        a, a + 1), one attention layer as its ordinal into `layers`."""
+        """A stack that is not one scan over `layers`, in the order it runs,
+        each segment a kind and ordinals lo..hi-1 into that kind's stack of
+        parameters. A hybrid: ("mamba", lo, hi), a run of state-space layers
+        in `mamba`, or ("attn", a, a + 1), one attention layer in `layers`.
+        A latent-attention stack, each kind the name of its stack:
+        ("dense", 0, first_dense), the leading dense layers, if it has any,
+        then ("layers", 0, n_layers - first_dense), the rest."""
+        if self.latent:
+            lead = (("dense", 0, self.first_dense),) if self.first_dense \
+                else ()
+            return lead + (("layers", 0, self.n_layers - self.first_dense),)
         out, a, m = [], 0, 0
         for i in range(self.n_layers):
             if i in self.attn_layers:
@@ -160,7 +253,43 @@ class LlamaConfig:
 # Parameters
 # ---------------------------------------------------------------------------
 
+def _latent_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    attn = {
+        "attn_norm": ("layers", "embed"),
+        "w_dq": ("layers", "embed", None),
+        "q_norm": ("layers", None),
+        "w_uq": ("layers", None, "heads"),
+        "w_dkv": ("layers", "embed", None),
+        "kv_norm": ("layers", None),
+        "w_ukv": ("layers", None, "heads"),
+        "wo": ("layers", "heads", "embed"),
+        "mlp_norm": ("layers", "embed"),
+    }
+    dense = {"w_gate": ("layers", "embed", "mlp"),
+             "w_up": ("layers", "embed", "mlp"),
+             "w_down": ("layers", "mlp", "embed")}
+    out = {"embed": ("vocab", "embed"), "final_norm": ("embed",),
+           "lm_head": ("embed", "vocab")}
+    if cfg.n_experts:
+        out["layers"] = dict(
+            attn, router=("layers", "embed", "expert"),
+            w_gate=("layers", "expert", "embed", "mlp"),
+            w_up=("layers", "expert", "embed", "mlp"),
+            w_down=("layers", "expert", "mlp", "embed"))
+        if cfg.router_score == "sigmoid":
+            out["layers"]["router_bias"] = ("layers", "expert")
+        if cfg.n_shared_experts:
+            out["layers"].update({"ws_" + k[2:]: v for k, v in dense.items()})
+    else:
+        out["layers"] = dict(attn, **dense)
+    if cfg.first_dense:
+        out["dense"] = dict(attn, **dense)
+    return out
+
+
 def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    if cfg.latent:
+        return _latent_axes(cfg)
     layers: Dict[str, Tuple] = {
         "attn_norm": ("layers", "embed"),
         "wq": ("layers", "embed", "heads"),
@@ -221,7 +350,69 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     return out
 
 
+def _init_latent(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
+    """A latent-attention model: `layers`, the sparse layers (all of them if
+    the model is dense), and `dense`, the `first_dense` leading ones; both
+    hold MLA's five matrices and two norms, neither a weight of a kind it is
+    not. The experts' stacks hold the experts HELD (`cfg.experts_held`); the
+    router scores them all. Keys are drawn from lists of this function's own,
+    so no other model's weights move."""
+    D, H, V, pd = cfg.d_model, cfg.n_heads, cfg.vocab_size, cfg.param_dtype
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+
+    def norm(shape, k, scale=0.02):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(pd)
+
+    def attention(L, ks):
+        return {
+            "attn_norm": jnp.ones((L, D), pd),
+            "w_dq": norm((L, D, rq), next(ks)),
+            "q_norm": jnp.ones((L, rq), pd),
+            "w_uq": norm((L, rq, H * (dn + dr)), next(ks)),
+            "w_dkv": norm((L, D, rkv + dr), next(ks)),
+            "kv_norm": jnp.ones((L, rkv), pd),
+            "w_ukv": norm((L, rkv, H * (dn + dv)), next(ks)),
+            "wo": norm((L, H * dv, D), next(ks)),
+            "mlp_norm": jnp.ones((L, D), pd),
+        }
+
+    def swiglu(lead, F, ks, prefix="w_"):
+        return {prefix + "gate": norm((*lead, D, F), next(ks)),
+                prefix + "up": norm((*lead, D, F), next(ks)),
+                prefix + "down": norm((*lead, F, D), next(ks))}
+
+    top = iter(jax.random.split(key, 4))
+    out = {"embed": norm((V, D), next(top)),
+           "final_norm": jnp.ones((D,), pd),
+           "lm_head": norm((D, V), next(top))}
+    Ls = cfg.n_layers - cfg.first_dense
+    ks = iter(jax.random.split(next(top), 16))
+    layers = attention(Ls, ks)
+    if cfg.n_experts:
+        layers["router"] = norm((Ls, D, cfg.n_experts), next(ks))
+        layers.update(swiglu((Ls, cfg.n_held), cfg.d_ff, ks))
+        if cfg.router_score == "sigmoid":
+            # Published as zeros and moved by training UNTIL the load is
+            # even; drawn here at the scale of every other leaf, so that it
+            # decides some choices and the load stays near even.
+            layers["router_bias"] = norm((Ls, cfg.n_experts), next(ks))
+        if cfg.n_shared_experts:
+            layers.update(swiglu((Ls,), cfg.n_shared_experts * cfg.d_ff, ks,
+                                 "ws_"))
+    else:
+        layers.update(swiglu((Ls,), cfg.d_ff, ks))
+    out["layers"] = layers
+    if cfg.first_dense:
+        ks = iter(jax.random.split(next(top), 16))
+        out["dense"] = dict(attention(cfg.first_dense, ks),
+                            **swiglu((cfg.first_dense,), cfg.d_ff_dense, ks))
+    return out
+
+
 def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
+    if cfg.latent:
+        return _init_latent(cfg, key)
     L, D, H, KVH = cfg.kv_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads
     hd, F, V = cfg.head_dim, cfg.d_ff, cfg.vocab_size
     pd = cfg.param_dtype
@@ -473,6 +664,12 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
             "state-space layers (ssm_state > 0) run through Serve only: the "
             "training forward has no hybrid stack and `ops.ssm`'s kernel no "
             "backward (ROADMAP, Reach)")
+    if cfg.latent:
+        raise NotImplementedError(
+            "latent attention (kv_lora_rank > 0) runs through Serve only: the "
+            "training forward has no stack of dense-then-sparse segments, "
+            "and a share of the experts (experts_held) takes no gradient "
+            "for the experts that are absent (ROADMAP, Reach)")
     dt = cfg.dtype
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dt)

@@ -41,7 +41,8 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # Which implementation each traced flash_attention or
 # paged_kv.paged_decode_attention call took, counted at TRACE time
 # ("fwd_pallas", "fwd_reference", "bwd_pallas", "bwd_reference",
-# "decode_pallas", "decode_reference"): the dispatch is otherwise invisible
+# "decode_pallas", "decode_reference"; latent attention's "latent_fwd_*" and
+# "latent_decode_*"): the dispatch is otherwise invisible
 # from outside a jitted program, and a benchmark must be able to assert that
 # the kernel it names is the one that ran.
 _path_counts: collections.Counter = collections.Counter()
@@ -232,6 +233,133 @@ def _fwd_with_lse_reference(q, k, v, *, causal, sm_scale):
     out = jnp.einsum("bhqk,bhkd->bhqd", (p / l).astype(v.dtype), v)
     lse = (m + jnp.log(l))[..., 0]
     return out, lse
+
+
+# ---------------------------------------------------------------------------
+# A prompt's latent attention (MLA): keys in two parts, values narrower
+# ---------------------------------------------------------------------------
+
+def _latent_flash_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                         acc_ref, m_ref, l_ref, *, sm_scale: float,
+                         block_q: int, block_k: int, kv_seq_len: int):
+    """`_flash_fwd_kernel`, causal, with a head's score the sum of two
+    products: its own part of the key (`kn`) and the rotary part every head
+    shares (`kr`, one array for all heads: the grid hands each head the same
+    block of it)."""
+    kv_idx = pl.program_id(2)
+    q_idx = pl.program_id(1)
+
+    @pl.when(kv_idx == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def _body(masked: bool):
+        contract = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(qn_ref[0], kn_ref[0], contract,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[0], kr_ref[0], contract,
+                                   preferred_element_type=jnp.float32)
+             ) * sm_scale
+        if masked:
+            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    visible = kv_idx * block_k <= q_idx * block_q + (block_q - 1)
+    full = kv_idx * block_k + (block_k - 1) <= q_idx * block_q
+    pl.when(visible & jnp.logical_not(full))(functools.partial(_body, True))
+    pl.when(full)(functools.partial(_body, False))
+
+    @pl.when(kv_idx == (kv_seq_len // block_k) - 1)
+    def _finalize():
+        o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def _latent_flash_pallas(q_n, q_r, k_n, k_r, v, *, sm_scale, interpret,
+                         block_q=1024, block_k=1024):
+    b, h, s, dn = q_n.shape
+    dr, dv = q_r.shape[-1], v.shape[-1]
+    block_q = _pick_block(s, block_q)
+    block_k = _pick_block(s, block_k)
+    kernel = functools.partial(
+        _latent_flash_kernel, sm_scale=sm_scale, block_q=block_q,
+        block_k=block_k, kv_seq_len=s)
+
+    def by_q(d):
+        return pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
+
+    def by_k(d):
+        return pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0))
+
+    out = pl.pallas_call(
+        kernel,
+        name="latent_flash_fwd",
+        grid=(b * h, s // block_q, s // block_k),
+        in_specs=[by_q(dn), by_q(dr), by_k(dn),
+                  # the shared rotary key: head bh of batch row bh // h
+                  pl.BlockSpec((1, block_k, dr),
+                               lambda bh, qi, ki: (bh // h, ki, 0)),
+                  by_k(dv)],
+        out_specs=by_q(dv),
+        out_shape=_out_struct((b * h, s, dv), v.dtype, v),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, dv), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(q_n.reshape(b * h, s, dn), q_r.reshape(b * h, s, dr),
+      k_n.reshape(b * h, s, dn), k_r, v.reshape(b * h, s, dv))
+    return out.reshape(b, h, s, dv)
+
+
+def latent_flash_attention(q_n, q_r, k_n, k_r, v, sm_scale: float, *,
+                           interpret: bool = False):
+    """Causal attention of a prompt under latent attention (MLA): head h's
+    query is `[q_n[h] ; q_r[h]]` and its key `[k_n[h] ; k_r]`, the rotary part
+    `k_r` `[b, s, dr]` ONE for all heads, its value `v[h]` of a width of its
+    own (192, 192 and 128 at DeepSeek-V3's widths, which `flash_attention`,
+    one width for all three and a multiple of 128, does not take). q_n, k_n
+    `[b, H, s, dn]`, q_r `[b, H, s, dr]`, v `[b, H, s, dv]` -> `[b, H, s,
+    dv]`. No gradient: serving's.
+
+    On a TPU (or with `interpret`) the Pallas kernel `latent_flash_fwd`, to
+    which the two parts of a key go apart, so `k_r` is never repeated for the
+    heads in memory; elsewhere the XLA reference. Counted in
+    `attention_path_counts()` as `latent_fwd_pallas` / `latent_fwd_reference`.
+    """
+    s, dn, dv = q_n.shape[2], q_n.shape[-1], v.shape[-1]
+    use = interpret or (_on_tpu() and s % 128 == 0 and dn % 128 == 0
+                        and dv % 128 == 0)
+    _path_counts["latent_fwd_pallas" if use else "latent_fwd_reference"] += 1
+    if use:
+        return _latent_flash_pallas(q_n, q_r, k_n, k_r, v, sm_scale=sm_scale,
+                                    interpret=interpret)
+    scores = (jnp.einsum("bhqd,bhkd->bhqk", q_n, k_n,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhqd,bkd->bhqk", q_r, k_r,
+                           preferred_element_type=jnp.float32)) * sm_scale
+    pos = jnp.arange(s)
+    scores = jnp.where(pos[:, None] >= pos[None, :], scores,
+                       DEFAULT_MASK_VALUE)
+    p = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
 # ---------------------------------------------------------------------------

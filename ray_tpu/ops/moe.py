@@ -14,35 +14,165 @@ matmuls over the sorted rows (`jax.lax.ragged_dot`: group e is the
 `group_sizes[e]` rows after those of the experts before it), so compute is
 O(tokens * k * d * f) whatever the skew. The experts' weight leading axis
 carries the logical "expert" axis which the sharding rules map onto `ep`.
+
+The router has the published variants as arguments (`top_k_routing`): softmax
+scores (OLMoE, Mixtral, the Qwen3 family) or sigmoid scores with a selection
+bias that chooses and does not weigh, group-limited top-k and a scaling factor
+(the DeepSeek-V3 family). And a layer may hold a SHARE of the experts (`held`:
+one chip of an expert-parallel deployment): it routes over every expert,
+computes its own experts' part of the mixture, and a token's assignments to
+absent experts weigh nothing here. No capacity, no dropped token, nothing in
+the place of the absent chips or of their exchange.
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 
-def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True
+def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True,
+                  *, score: str = "softmax",
+                  bias: Optional[jax.Array] = None, n_group: int = 1,
+                  topk_group: int = 1, scale: float = 1.0
                   ) -> Tuple[jax.Array, jax.Array]:
     """gate_logits: [tokens, n_experts] -> (weights [tokens, k], idx [tokens, k]).
 
-    The weights are the float32 softmax over all experts at the k largest;
-    `norm_topk_prob` renormalises them to sum to one (which equals the
-    softmax over the selected k, Mixtral's; OLMoE publishes False).
+    `score` "softmax": the weights are the float32 softmax over all experts at
+    the k largest; `norm_topk_prob` renormalises them to sum to one (which
+    equals the softmax over the selected k, Mixtral's; OLMoE publishes False).
+
+    `score` "sigmoid" (DeepSeek-V3's `noaux_tc`): s = sigmoid(logits) in
+    float32; the CHOICE is made on s' = s + `bias` (the selection bias, which
+    chooses and does not weigh); with `n_group` > 1 the experts lie in
+    `n_group` groups of equal size, a group scores the sum of its two largest
+    s', the `topk_group` best groups stay and the k largest s' are taken among
+    their experts alone (ties to the smaller index, here and there); the
+    weights are s (NOT s') at the chosen k, renormalised if `norm_topk_prob`,
+    times `scale` (`routed_scaling_factor`).
     """
-    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
-    weights, idx = jax.lax.top_k(probs, k)
+    if score == "softmax":
+        probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+        weights, idx = jax.lax.top_k(probs, k)
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        return weights, idx
+    if score != "sigmoid":
+        raise ValueError(f"router score {score!r}: 'softmax' or 'sigmoid'")
+    s = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    choice = s if bias is None else s + bias.astype(jnp.float32)
+    if n_group > 1:
+        tokens, n = choice.shape
+        grouped = choice.reshape(tokens, n_group, n // n_group)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(group_score, topk_group)       # [t, topk_group]
+        keep = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None],
+                       axis=1)                                  # [t, n_group]
+        choice = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(
+            tokens, n)
+    _, idx = jax.lax.top_k(choice, k)
+    weights = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk_prob:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    return weights, idx
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return weights * scale, idx
+
+
+def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+           w_down: jax.Array) -> jax.Array:
+    """(silu(x w_gate) * (x w_up)) w_down: one dense expert."""
+    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+# Rows of a share's sorted assignments that meet the grouped matmuls at once,
+# as a multiple of the rows that would under even routing (`tokens x k x held
+# / experts`): the held experts' rows come first in the sorted order, so a
+# block of this many holds them all but for a routing four times as skewed
+# towards this share, and then a second block follows (`_share_experts`).
+# Walking all `tokens x k` rows, fifteen in sixteen of them in no group, took
+# the grouped matmuls 20 and the combine 13 of a 137 ms prefill (PERF.md,
+# PR 39); nothing is dropped at any skew.
+_SHARE_BLOCK = 4
+
+
+def _share_experts(x, w_gate, w_up, w_down, layer, idx, weights, held,
+                   n_experts):
+    """The routed part that the experts `held` = (offset, count) give: `idx`,
+    `weights` [tokens, k] the router's choice over all `n_experts`. ->
+    (out [tokens, d] float32, chosen [tokens * k, count] bool: which
+    assignment fell to which held expert).
+
+    The assignments are sorted so that those to held experts come first, by
+    expert (the sort is stable: an expert's rows keep the order of their
+    tokens), and walked in blocks of `rows` rows while a block still holds a
+    local one: a dynamic trip count, one block in all but a freak routing.
+    A block's rows are gathered, run through the grouped matmuls with the
+    group sizes clipped to the block, and combined by a gather back to
+    token-major and one sum over a token's k assignments in a fixed order
+    (no scatter-add), an assignment outside the block, or to an absent
+    expert, weighing 0."""
+    tokens, top_k = idx.shape
+    offset, n_held = held
+    total = tokens * top_k
+    with jax.named_scope("moe_dispatch"):
+        flat = idx.reshape(-1) - offset
+        flat = jnp.where((flat >= 0) & (flat < n_held), flat, n_held)
+        order = jnp.argsort(flat)               # held first, by expert
+        back = jnp.argsort(order).reshape(tokens, top_k)
+        chosen = flat[:, None] == jnp.arange(n_held)[None, :]
+        group_sizes = jnp.sum(chosen, axis=0, dtype=jnp.int32)
+        ends = jnp.cumsum(group_sizes)
+        n_local = ends[-1]
+        even = -(-total * n_held // n_experts)
+        rows = min(total, -(-_SHARE_BLOCK * even // 8) * 8)
+        stacked = None
+        if layer is not None:
+            stacked = w_up.shape[0] * n_held
+            w_up, w_gate, w_down = (w.reshape(stacked, *w.shape[2:])
+                                    for w in (w_up, w_gate, w_down))
+
+    def block(carry):
+        lo, out = carry
+        with jax.named_scope("moe_dispatch"):
+            # order[lo + r], without reading past the list's end
+            take = jnp.minimum(lo + jnp.arange(rows), total - 1)
+            xs = x[order[take] // top_k]                     # [rows, d]
+            groups = (jnp.clip(ends, lo, lo + rows)
+                      - jnp.clip(ends - group_sizes, lo, lo + rows))
+            if layer is not None:
+                groups = jax.lax.dynamic_update_slice(
+                    jnp.zeros(stacked, jnp.int32), groups, (layer * n_held,))
+        with jax.named_scope("experts"):
+            h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, groups)) \
+                * jax.lax.ragged_dot(xs, w_up, groups)
+            ys = jax.lax.ragged_dot(h, w_down, groups)       # [rows, d]
+        with jax.named_scope("moe_combine"):
+            # A row in no group is whatever the grouped matmul left there:
+            # it is replaced, not multiplied by 0 (0 x NaN is NaN).
+            ys = jnp.where((lo + jnp.arange(rows) < n_local)[:, None], ys, 0)
+            here = (back >= lo) & (back < jnp.minimum(lo + rows, n_local))
+            per_token = ys[jnp.clip(back - lo, 0, rows - 1)]  # [t, k, d]
+            out = out + jnp.einsum(
+                "tkd,tk->td", per_token, jnp.where(here, weights, 0.0),
+                preferred_element_type=jnp.float32)
+        return lo + rows, out
+
+    out0 = jnp.zeros(x.shape, jnp.float32)
+    if rows == total:       # one block holds every assignment
+        return block((jnp.int32(0), out0))[1], chosen
+    _, out = jax.lax.while_loop(lambda c: c[0] < n_local, block,
+                                (jnp.int32(0), out0))
+    return out, chosen
 
 
 def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
             w_down: jax.Array, *, top_k: int = 2, norm_topk_prob: bool = True,
             live: Optional[jax.Array] = None,
-            layer: Optional[jax.Array] = None
+            layer: Optional[jax.Array] = None,
+            routing: Optional[Dict[str, Any]] = None,
+            held: Optional[Tuple[int, int]] = None,
+            shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """SwiGLU MoE feed-forward over sorted assignments.
 
@@ -58,52 +188,77 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
     where it lies, the other layers' experts as empty groups; handed one
     layer sliced out of a scanned stack, its kernel is first given a copy of
     that layer's experts (0.8 GB a layer at OLMoE's widths, every step).
+    routing: the router's variant, `top_k_routing`'s keyword arguments (None:
+    softmax).
+    held: (offset, count), static: w_up/w_gate/w_down hold experts offset ..
+    offset + count - 1 of the `n_experts` the router scores, ONE share of an
+    expert-parallel layer. The router still scores every expert; the
+    assignments to held experts are computed (`_share_experts`) and the rest
+    weigh 0. What comes back is this share's PART of the mixture: the parts
+    of all shares add up to the whole layer's.
+    shared: (w_gate, w_up, w_down) of a dense expert every token meets, added
+    to the routed part (once: a deployment's other shares add none).
     Returns (out [tokens, d_model], aux_loss scalar, tokens per expert
-    [n_experts] int32 over the live rows).
+    [n_experts] int32 over the live rows; per HELD expert `[count]` with
+    `held`, whose sum is the local assignments).
     """
     tokens, _ = x.shape
     n_experts = gate_w.shape[-1]
     with jax.named_scope("router"):
         logits = jnp.einsum("td,de->te", x, gate_w,
                             preferred_element_type=jnp.float32)
-        weights, idx = top_k_routing(logits, top_k, norm_topk_prob)
+        weights, idx = top_k_routing(logits, top_k, norm_topk_prob,
+                                     **(routing or {}))
 
-    with jax.named_scope("moe_dispatch"):
-        # Assignments token-major (t, j) -> sorted by expert; the sort is
-        # stable, so an expert's rows keep the order of their tokens.
-        flat_expert = idx.reshape(-1)                       # [t*k]
-        order = jnp.argsort(flat_expert)
-        token_of = order // top_k
-        chosen = flat_expert[:, None] == jnp.arange(n_experts)[None, :]
-        group_sizes = jnp.sum(chosen, axis=0, dtype=jnp.int32)   # [e]
-        xs = x[token_of]                                    # [t*k, d]
-        groups = group_sizes
-        if layer is not None:
-            stacked = w_up.shape[0] * n_experts
-            groups = jax.lax.dynamic_update_slice(
-                jnp.zeros(stacked, jnp.int32), group_sizes,
-                (layer * n_experts,))
-            w_up, w_gate, w_down = (w.reshape(stacked, *w.shape[2:])
-                                    for w in (w_up, w_gate, w_down))
+    if held is not None:
+        out, chosen = _share_experts(x, w_gate, w_up, w_down, layer, idx,
+                                     weights, held, n_experts)
+        group_sizes = jnp.sum(chosen, axis=0, dtype=jnp.int32)
+    else:
+        with jax.named_scope("moe_dispatch"):
+            # Assignments token-major (t, j) -> sorted by expert; the sort is
+            # stable, so an expert's rows keep the order of their tokens.
+            flat_expert = idx.reshape(-1)                       # [t*k]
+            order = jnp.argsort(flat_expert)
+            token_of = order // top_k
+            chosen = flat_expert[:, None] == jnp.arange(n_experts)[None, :]
+            group_sizes = jnp.sum(chosen, axis=0, dtype=jnp.int32)   # [e]
+            xs = x[token_of]                                    # [t*k, d]
+            groups = group_sizes
+            if layer is not None:
+                stacked = w_up.shape[0] * n_experts
+                groups = jax.lax.dynamic_update_slice(
+                    jnp.zeros(stacked, jnp.int32), group_sizes,
+                    (layer * n_experts,))
+                w_up, w_gate, w_down = (w.reshape(stacked, *w.shape[2:])
+                                        for w in (w_up, w_gate, w_down))
 
-    with jax.named_scope("experts"):
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, groups)) \
-            * jax.lax.ragged_dot(xs, w_up, groups)
-        ys = jax.lax.ragged_dot(h, w_down, groups)          # [t*k, d]
+        with jax.named_scope("experts"):
+            h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, groups)) \
+                * jax.lax.ragged_dot(xs, w_up, groups)
+            ys = jax.lax.ragged_dot(h, w_down, groups)          # [t*k, d]
 
-    with jax.named_scope("moe_combine"):
-        # Back to token-major by a gather and one sum over a token's k
-        # rows in a fixed order: no scatter-add, whose order of additions
-        # (and so the last bit of a token's output) would be the batch's.
-        back = jnp.argsort(order)
-        per_token = ys[back].reshape(tokens, top_k, -1).astype(jnp.float32)
-        out = jnp.einsum("tkd,tk->td", per_token, weights)
+        with jax.named_scope("moe_combine"):
+            # Back to token-major by a gather and one sum over a token's k
+            # rows in a fixed order: no scatter-add, whose order of additions
+            # (and so the last bit of a token's output) would be the batch's.
+            back = jnp.argsort(order)
+            per_token = ys[back].reshape(tokens, top_k, -1).astype(
+                jnp.float32)
+            out = jnp.einsum("tkd,tk->td", per_token, weights)
+
+    if shared is not None:
+        with jax.named_scope("shared_expert"):
+            out = out + swiglu(x, *shared).astype(jnp.float32)
 
     if live is None:
         counts = group_sizes
     else:
         counts = jnp.sum(chosen & jnp.repeat(live, top_k)[:, None], axis=0,
                          dtype=jnp.int32)
+    if held is not None or routing:
+        # No published load-balancing loss for a share or a sigmoid router.
+        return out.astype(x.dtype), jnp.zeros((), jnp.float32), counts
     # Load-balancing aux loss (Switch-style): mean prob * mean assignment frac.
     probs = jax.nn.softmax(logits, axis=-1)
     frac_tokens = group_sizes.astype(jnp.float32) / tokens

@@ -34,6 +34,42 @@ def rope_frequencies(head_dim: int, max_seq: int, theta: float = 10000.0):
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
+def yarn_inv_frequencies(dim: int, theta: float, factor: float,
+                         original_max: int, beta_fast: float,
+                         beta_slow: float) -> jax.Array:
+    """YaRN's `dim // 2` rotary frequencies: pair i turns by
+    f_i = theta^(-2i/dim) where it turns fast (extrapolated as trained), by
+    f_i / factor where it turns slowly (interpolated), and by a linear ramp
+    between the two from pair `lo` to pair `hi`, the pairs that make
+    `beta_fast` and `beta_slow` turns over the `original_max` positions of
+    training: floor and ceil of dim ln(original_max / (b 2 pi)) / (2 ln
+    theta), clipped to [0, dim - 1]."""
+    import math
+    f = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+
+    def pair(turns):
+        return dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(pair(beta_fast)), 0)
+    hi = min(math.ceil(pair(beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    return f / factor * ramp + f * (1.0 - ramp)
+
+
+def yarn_frequencies(dim: int, max_seq: int, theta: float, factor: float,
+                     original_max: int, beta_fast: float, beta_slow: float):
+    """`rope_frequencies` at YaRN's frequencies: (cos, sin) [max_seq, dim//2].
+    The tables carry no magnitude factor: it is 1 where `mscale` equals
+    `mscale_all_dim`, and YaRN's correction then lies on the softmax scale
+    alone (`LlamaConfig.softmax_scale`)."""
+    inv_freq = yarn_inv_frequencies(dim, theta, factor, original_max,
+                                    beta_fast, beta_slow)
+    freqs = jnp.outer(jnp.arange(max_seq, dtype=jnp.float32), inv_freq)
+    return jnp.cos(freqs), jnp.sin(freqs)
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
                positions: jax.Array | None = None) -> jax.Array:
     """x: [b, h, s, d]; cos/sin: [max_seq, d//2]; positions: [s] global positions."""

@@ -25,6 +25,25 @@ data-dependent branch. Which pages a slot holds is the host's business
     1.50 ms for 16 x 2,048 positions of one layer on a v5e; PERF.md, PR 32).
     ``write_prompt`` and ``write_token`` tell the two layouts by their rank,
     and write a by-token arena as they write the indexer's: a row a position.
+  * A latent-attention model (MLA) keeps a THIRD kind of row and no other:
+    one row a position a layer of `kv_lora_rank + qk_rope_dim` numbers (the
+    normed latent, then the rotated shared key; 512 + 64 at DeepSeek-V3's
+    widths), `[n_layers, n_pages, page, width]` (``empty_latent``), written
+    a row a position as the indexer's are. There is ONE such row for all
+    query heads and no V arena at all: ``write_prompt`` and ``write_token``
+    are not used, the model's programs call ``write_prompt_rows`` and
+    ``write_token_rows`` with rows from ``latent_rows``. One array, not
+    latent and key apart, and its rows as wide as the tiles they lie in: a
+    TPU tile is 128 lanes, so a 576-wide row lies in 640 (five tiles, the
+    last half empty) whatever shape is declared, a DMA can only cut whole
+    tiles out of it (Mosaic refuses a slice of 576 lanes), and rows of 512
+    and of 64 apart would lie in 512 + 128, the same 640. So the arena is
+    DECLARED 640 wide, lanes 576..639 zero, 1,280 B a token a layer in
+    bfloat16 for 1,152 of use; one array is one DMA a page and one scatter a
+    write.
+    ``paged_latent_decode`` reads it in place: all heads of a slot against
+    each block of its live rows, counted as `latent_decode_pallas` /
+    `latent_decode_reference`.
   * ``paged_decode_attention`` is one query token a slot against the arena:
     a Pallas TPU kernel that reads a slot's live pages where they lie (the
     XLA gather over the whole block table elsewhere), counted at trace time
@@ -67,6 +86,30 @@ def empty_index(n_layers: int, n_pages: int, page: int, index_dim: int,
     return jnp.zeros((n_layers, n_pages, page, index_dim), dtype)
 
 
+_LANES = 128
+
+
+def empty_latent(n_layers: int, n_pages: int, page: int, width: int, dtype):
+    """-> the zeroed arena of a latent-attention model's rows (see the top),
+    all it caches: `width` numbers a position (latent + rotary key), in rows
+    of the next multiple of 128 lanes."""
+    return jnp.zeros((n_layers, n_pages, page, -(-width // _LANES) * _LANES),
+                     dtype)
+
+
+def _to_width(rows, arena):
+    """`rows` [..., n] with zeros up to the arena's width, in its dtype."""
+    pad = [(0, 0)] * (rows.ndim - 1) + [(0, arena.shape[-1] - rows.shape[-1])]
+    return jnp.pad(rows, pad).astype(arena.dtype)
+
+
+def latent_rows(c, kr, arena):
+    """A latent arena's rows from the normed latent `c` [..., rank] and the
+    rotated shared key `kr` [..., dr]: side by side, then zeros up to the
+    arena's width."""
+    return _to_width(jnp.concatenate([c, kr], axis=-1), arena)
+
+
 def write_prompt_rows(arena, pages, rows):
     """Scatter a prefill's rows [L, W, R], one a position, into the physical
     pages of an arena `[L, n_pages, page, R]` (K or V by token; an indexer's
@@ -84,6 +127,8 @@ def write_prompt(kc, vc, pages, ks, vs):
     """Scatter prefilled [L, W, KVH, hd] k/v into physical pages.
     W is static (one program per bucket width); `pages[:wp]` entries
     of 0 route padding into the null page."""
+    if vc is None:          # a latent arena: `ks` [L, W, rank + dr]
+        return write_prompt_rows(kc, pages, _to_width(ks, kc)), None
     L, W, KVH, hd = ks.shape
     if kc.ndim == 4:        # by token
         return (write_prompt_rows(kc, pages, ks.reshape(L, W, KVH * hd)),
@@ -361,3 +406,175 @@ def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
             pages_per_block=pages_per_block, interpret=interpret)
     return _paged_decode_reference(q, kc, vc, layer, block_table, lengths,
                                    sm_scale=scale)
+
+
+# ---------------------------------------------------------------------------
+# Decode attention over a latent arena (MLA, the absorbed form)
+# ---------------------------------------------------------------------------
+
+def _latent_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, c_hbm, o_ref,
+                          buf, sem, m_ref, l_ref, acc_ref, *, sm_scale: float,
+                          rank: int, pages_per_block: int):
+    """One grid step = one slot: ALL its query heads `[H, width]` (the
+    absorbed query beside the rotary one) against its live rows, a block of
+    `pages_per_block` pages at a time, fetched where they lie by DMA,
+    double-buffered, under a dynamic trip count (`_paged_decode_kernel`). A
+    row is key and value at once: the score takes all `width` lanes, the
+    output the first `rank` (the latent). Online softmax, float32 statistics
+    and accumulator."""
+    _, T, _ = buf.shape
+    page = T // pages_per_block
+    max_pages = bt_ref.shape[1]
+    slot = pl.program_id(0)
+    layer = layer_ref[0]
+    length = len_ref[slot]
+    live_pages = pl.cdiv(length, page)
+    n_blocks = pl.cdiv(live_pages, pages_per_block)
+
+    @pl.when(slot == 0)
+    def _clear():
+        # Rows past the live pages are never fetched, only masked: they have
+        # to be finite (0 x NaN is NaN).
+        buf[...] = jnp.zeros_like(buf)
+
+    def each_copy(block, b, fn):
+        for i in range(pages_per_block):
+            idx = block * pages_per_block + i
+            page_id = bt_ref[slot, jnp.minimum(idx, max_pages - 1)]
+
+            @pl.when(idx < live_pages)
+            def _():
+                fn(pltpu.make_async_copy(
+                    c_hbm.at[layer, page_id],
+                    buf.at[b, pl.ds(i * page, page), :], sem.at[b]))
+
+    m_ref[...] = jnp.full_like(m_ref, DEFAULT_MASK_VALUE)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        each_copy(0, 0, lambda c: c.start())
+
+    def block_body(b, carry):
+        cur = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            each_copy(b + 1, 1 - cur, lambda c: c.start())
+
+        each_copy(b, cur, lambda c: c.wait())
+        rows = buf[cur]                                        # [T, width]
+        heads = q_ref.shape[1]
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale      # [H, T]
+        live = (b * T + jax.lax.broadcasted_iota(jnp.int32, (heads, T), 1)
+                < length)
+        s = jnp.where(live, s, DEFAULT_MASK_VALUE)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block_body, 0)
+    # An idle slot (length 0) walked nothing: l is 0 and so is its output.
+    o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _latent_decode_pallas(q, arena, layer, block_table, lengths, *, rank,
+                          sm_scale, pages_per_block, interpret):
+    ns, H, width = q.shape
+    page = arena.shape[2]
+    if pages_per_block is None:
+        pages_per_block = max(1, _DECODE_BLOCK_TOKENS // page)
+    pages_per_block = min(pages_per_block, block_table.shape[1])
+    T = pages_per_block * page
+    kernel = functools.partial(_latent_decode_kernel, sm_scale=sm_scale,
+                               rank=rank, pages_per_block=pages_per_block)
+    out = pl.pallas_call(
+        kernel,
+        name="paged_latent_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,       # layer, lengths, block table
+            grid=(ns,),
+            in_specs=[pl.BlockSpec((1, H, width), lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, rank), lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, T, width), arena.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, rank), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((ns, H, rank), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
+      block_table.astype(jnp.int32), q.astype(arena.dtype), arena)
+    return out
+
+
+def _latent_decode_reference(q, arena, layer, block_table, lengths, *, rank,
+                             sm_scale):
+    """The XLA path: gather every page of every slot's table out of the
+    layer, float32 softmax over the whole context under a length mask."""
+    ns = q.shape[0]
+    page = arena.shape[2]
+    ctx = block_table.shape[1] * page
+    rows = arena[layer, block_table].astype(jnp.float32).reshape(ns, ctx, -1)
+    live = jnp.arange(ctx)[None, :] < lengths[:, None]          # [ns, ctx]
+    # What a dead position holds is masked out of the value too.
+    rows = jnp.where(live[:, :, None], rows, 0.0)
+    scores = jnp.einsum("nhw,nsw->nhs", q.astype(jnp.float32), rows) \
+        * sm_scale
+    scores = jnp.where(live[:, None, :], scores, DEFAULT_MASK_VALUE)
+    wts = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("nhs,nsr->nhr", wts, rows[..., :rank])
+    return jnp.where((lengths > 0)[:, None, None], out, 0.0)
+
+
+def paged_latent_decode(ql, q_r, arena, layer, block_table, lengths, *,
+                        sm_scale: float,
+                        pages_per_block: Optional[int] = None,
+                        interpret: bool = False):
+    """Latent attention's decode step in its absorbed form: ONE query token
+    a slot, all its heads against the slot's cached rows, each row key and
+    value at once.
+
+    ql [ns, H, rank], each head's query with the key up-projection absorbed
+    (`models.block.latent_attention_inputs`); q_r [ns, H, dr], its rotary
+    part; arena [L, n_pages, page, rank + dr and zeros to a multiple of 128],
+    the WHOLE latent arena (`empty_latent`) and `layer` the index into it; block_table, lengths as
+    `paged_decode_attention`'s. Head h's score at position s is `sm_scale *
+    (ql[h] . c_s + q_r[h] . kr_s)` where the row is `[c_s ; kr_s]`; ->
+    `sum_s p_s c_s`, float32 [ns, H, rank] (`latent_attention_output` takes
+    it through the value up-projection). 2 H (2 rank + dr) operations for
+    every row of (rank + dr) numbers read: at 128 heads of 512 + 64 in
+    bfloat16, 242 to the byte, where a v5e's peaks stand 240 to 1.
+
+    On a TPU (or with `interpret`) the Pallas kernel `paged_latent_decode`,
+    which walks only the live pages, in place; elsewhere XLA's gather of the
+    whole table."""
+    rank = ql.shape[-1]
+    page = arena.shape[2]
+    q = latent_rows(ql.astype(q_r.dtype), q_r, arena)   # zeros meet zeros
+    use = interpret or (attention._on_tpu() and rank % 128 == 0
+                        and page % _sublanes(arena.dtype) == 0)
+    attention._path_counts["latent_decode_pallas" if use
+                           else "latent_decode_reference"] += 1
+    if use:
+        return _latent_decode_pallas(
+            q, arena, layer, block_table, lengths, rank=rank,
+            sm_scale=sm_scale, pages_per_block=pages_per_block,
+            interpret=interpret)
+    return _latent_decode_reference(q, arena, layer, block_table, lengths,
+                                    rank=rank, sm_scale=sm_scale)

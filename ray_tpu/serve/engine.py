@@ -27,6 +27,11 @@ engine is OURS):
   None for every other model. Its stack is not a scan over identical layers
   but SEGMENTS (`LlamaConfig.segments`): a scan over each run of state-space
   layers, the attention layers between them inline.
+  A model with latent attention (`mcfg.latent`) keeps ONE row a position a
+  layer (`paged_kv.empty_latent`) where the others keep K and V: its arena
+  takes `kc`'s place in every program, `vc` is None, and nothing else of the
+  engine knows. Its stack runs as segments too (leading dense layers, then
+  the sparse ones, a scan each).
 - **Reservation admission**: a request is admitted when the pages
   `PagePool.pages_for` says it can ever need are free: growth can then
   never fail mid-decode, so there is no preemption/recompute path.
@@ -146,6 +151,8 @@ def _make_prefill_core(mcfg):
     those of the attention layers alone."""
     if mcfg.ssm_state:
         return _make_hybrid_prefill_core(mcfg)
+    if mcfg.latent:
+        return _make_latent_prefill_core(mcfg)
     import jax
     import jax.numpy as jnp
 
@@ -317,6 +324,95 @@ def _make_hybrid_prefill_core(mcfg):
     return core
 
 
+def _latent_rope_tables(mcfg, width):
+    """(cos, sin) [width, qk_rope_dim // 2] of a latent-attention model."""
+    from ray_tpu.ops.norms import rope_frequencies, yarn_frequencies
+    if mcfg.rope_yarn:
+        return yarn_frequencies(mcfg.qk_rope_dim, width, mcfg.rope_theta,
+                                *mcfg.rope_yarn[:4])
+    return rope_frequencies(mcfg.qk_rope_dim, width, mcfg.rope_theta)
+
+
+def _share_stats(counts, live, mcfg):
+    """One sparse layer's routing as a latent-attention program hands it
+    back, `[held + 2]` int32 that add up: `expert_stats` of the HELD experts
+    (tokens per expert, then the distinct ones touched), then the
+    assignments the router made of the live rows, to whichever share."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.block import expert_stats
+    routed = jnp.sum(live, dtype=jnp.int32) * mcfg.top_k_experts
+    return jnp.concatenate([expert_stats(counts), routed[None]])
+
+
+def _make_latent_prefill_core(mcfg):
+    """`_make_prefill_core` for latent attention (MLA): the segments in order
+    (`LlamaConfig.segments`: the leading dense layers, then the sparse ones),
+    a scan over each; `ks` is what the cache keeps, `[L, B, rank + dr]` (the
+    normed latent, then the rotated shared key), and `vs` None.
+    `experts` is `_share_stats` summed over the sparse layers."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.block import (expert_stacks, feed_forward,
+                                      latent_attention_inputs)
+    from ray_tpu.ops.attention import latent_flash_attention
+    from ray_tpu.ops.norms import apply_rope, rms_norm
+
+    dt = mcfg.dtype
+    sparse = mcfg.n_experts > 0
+
+    def layer_fn(stacks, tables, live, x, layer):
+        lp, l = layer
+        routed_layer = "router" in lp
+        lp = dict(lp, **stacks)
+        B, Sq, _ = x.shape
+        q_n, q_r, k_n, v, c, kr = latent_attention_inputs(
+            lp, x, mcfg, lambda t: apply_rope(t, *tables))
+        with jax.named_scope("attn"):
+            attn = latent_flash_attention(q_n, q_r, k_n, kr, v,
+                                          mcfg.softmax_scale)
+            attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, -1)
+        with jax.named_scope("attn_out"):
+            x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
+        # A share's sparse half meets a quarter of `rows x experts a token`
+        # rows at once (`ops.moe._share_experts`): no blocks of `_MOE_ROWS`.
+        x, routed = feed_forward(lp, x, mcfg, live,
+                                 l if routed_layer else None)
+        ys = (c[0], kr[0])                        # [S, rank], [S, dr]
+        if routed_layer:
+            ys += (_share_stats(routed[1], live, mcfg),)
+        return x, ys
+
+    def core(params, tokens, length):
+        width = tokens.shape[1]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+        with jax.named_scope("rope"):
+            tables = _latent_rope_tables(mcfg, width)
+        live = jnp.arange(width)[None] < length
+        rows, experts = [], None
+        with jax.named_scope("layers"):
+            for kind, lo, hi in mcfg.segments():
+                sliced, stacks = expert_stacks(params[kind], mcfg)
+                x, (c, kr, *stats) = jax.lax.scan(
+                    functools.partial(layer_fn, stacks, tables, live), x,
+                    (sliced, jnp.arange(lo, hi)))
+                rows.append(jnp.concatenate([c, kr], axis=-1))
+                if stats:
+                    experts = jnp.sum(stats[0], axis=0)
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
+            last_h = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
+                                                  keepdims=False)
+            logits = _head_logits(params, last_h, mcfg)
+            first = jnp.argmax(logits[0]).astype(jnp.int32)
+        return (first, jnp.concatenate(rows), None,
+                logits[0].astype(jnp.float32), experts if sparse else None)
+
+    return core
+
+
 def _layer_of(stack, i):
     """Layer `i` of a stack of layers (a leading axis on every leaf): what a
     scan over the stack hands its body, read by index."""
@@ -372,10 +468,13 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     import jax.numpy as jnp
 
     from ray_tpu.models.block import (attention_inputs, expert_stacks,
-                                      expert_stats, feed_forward, mamba_mixer)
+                                      expert_stats, feed_forward,
+                                      latent_attention_inputs,
+                                      latent_attention_output, mamba_mixer)
     from ray_tpu.ops.norms import mrope_tables, rms_norm, rope_frequencies
-    from ray_tpu.ops.paged_kv import (empty, empty_index,
-                                      paged_decode_attention, write_prompt,
+    from ray_tpu.ops.paged_kv import (empty, empty_index, empty_latent,
+                                      latent_rows, paged_decode_attention,
+                                      paged_latent_decode, write_prompt,
                                       write_prompt_rows, write_token,
                                       write_token_rows)
     from ray_tpu.ops.slot_state import (empty_state, layer_state,
@@ -385,6 +484,7 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     sparse = mcfg.n_experts > 0
     indexed = mcfg.index_topk > 0
     hybrid = mcfg.ssm_state > 0
+    latent = mcfg.latent
     S = mcfg.max_seq
     H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
     dt = mcfg.dtype
@@ -393,7 +493,11 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     def empty_caches():
         """-> (kc, vc), the arena of the layers that keep K and V; after
         them ic for a model with an indexer, or the recurrent state
-        (`ops/slot_state.py`) for one with state-space layers."""
+        (`ops/slot_state.py`) for one with state-space layers. A model with
+        latent attention: (its arena of latent rows, None)."""
+        if latent:
+            return (empty_latent(mcfg.n_layers, n_pages, page,
+                                 mcfg.latent_width, dt), None)
         kv = empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt,
                    by_token=indexed)
         if indexed:
@@ -516,6 +620,47 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
                                              jnp.arange(lo, hi))
         return x, kc, vc, state
 
+    def _latent_layers(params, x, kc, experts, bt, pos, act, cos, sin):
+        """One token a slot through a latent-attention stack's segments, a
+        scan each: the absorbed query against the slot's cached rows
+        (`paged_latent_decode`), the step's own row written first. The arena
+        rides the carry as K and V do (see `_step`); its layer is the
+        layer's place in the whole stack."""
+        w = jnp.minimum(pos, S - 1)
+        lengths = jnp.where(act, w + 1, 0)
+        with jax.named_scope("rope"):
+            c, s = cos[w][:, None], sin[w][:, None]
+
+        def body(stacks, base, carry, layer):
+            x, kc, experts = carry
+            lp, l = layer
+            routed_layer = "router" in lp
+            lp = dict(lp, **stacks)
+            ql, q_r, row, kr = latent_attention_inputs(
+                lp, x, mcfg, lambda t: _rope_one(t, c, s), absorb=True)
+            kc = write_token_rows(kc, base + l, bt, w, act,
+                                  latent_rows(row, kr, kc))
+            with jax.named_scope("attn"):
+                ol = paged_latent_decode(ql, q_r, kc, base + l, bt, lengths,
+                                         sm_scale=mcfg.softmax_scale)
+            with jax.named_scope("attn_out"):
+                x = x + latent_attention_output(lp, ol, mcfg) \
+                    @ lp["wo"].astype(dt)
+            x, routed = feed_forward(lp, x, mcfg, act,
+                                     l if routed_layer else None)
+            if routed_layer:
+                experts = experts + _share_stats(routed[1], act, mcfg)
+            return (x, kc, experts), None
+
+        base = 0
+        for kind, lo, hi in mcfg.segments():
+            sliced, stacks = expert_stacks(params[kind], mcfg)
+            (x, kc, experts), _ = jax.lax.scan(
+                functools.partial(body, stacks, base), (x, kc, experts),
+                (sliced, jnp.arange(lo, hi)))
+            base += hi - lo
+        return x, kc, experts
+
     def _step(params, sliced, stacks, kc, vc, ic, experts, bt, last, pos,
               active, cos, sin, itables, temp, topk, keys, state=None):
         # sliced, stacks: `expert_stacks` of the layers, split (and where
@@ -549,6 +694,12 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             if hybrid:      # segments, not one scan: `_hybrid_layers`
                 x, kc, vc, state = _hybrid_layers(params, x, kc, vc, state,
                                                   bt, pos, act)
+            elif latent:    # segments too: `_latent_layers`
+                x, kc, stats = _latent_layers(
+                    params, x, kc,
+                    experts[0] if sparse else jnp.zeros((), jnp.int32), bt,
+                    pos, act, cos, sin)
+                experts = [stats] if sparse else []
             else:
                 (x, kc, vc, ic, *experts), _ = jax.lax.scan(
                     body, (x, kc, vc, ic, *experts),
@@ -569,15 +720,21 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         indexer, `ic` follows; with state-space layers, `state`."""
         cos = sin = None
         itables = ()
-        if mcfg.rope:
+        if latent:
+            with jax.named_scope("rope"):
+                cos, sin = _latent_rope_tables(mcfg, S)
+        elif mcfg.rope:
             with jax.named_scope("rope"):
                 cos, sin = rope_frequencies(hd, S, mcfg.rope_theta)
                 itables = rope_frequencies(mcfg.index_head_dim, S,
                                            mcfg.rope_theta) if indexed else ()
         out0 = jnp.zeros((ns, chunk), jnp.int32)
-        experts0 = [jnp.zeros(mcfg.n_experts + 1, jnp.int32)] if sparse \
-            else []
-        sliced, stacks = expert_stacks(params["layers"], mcfg)
+        # `expert_stats`' width, and `_share_stats`' for a share.
+        experts0 = [jnp.zeros(mcfg.n_held + 1 + latent, jnp.int32)] \
+            if sparse else []
+        # A latent-attention stack splits each segment's (`_latent_layers`).
+        sliced, stacks = (None, None) if latent \
+            else expert_stacks(params["layers"], mcfg)
 
         def body(i, carry):
             kc, vc, ic, state, last, pos, out, *experts = carry
@@ -679,9 +836,12 @@ class Engine:
         # the warm-up thread allocates its scratch arena: the projections
         # twice over are 0.6 GB at 12 Mistral layers, 0.2 on OLMoE, whose
         # warm-up peaks within 0.9 GB of the chip's memory (PERF.md, §4).
-        self._params = fuse_qkv(self._experts_in_compute_dtype(params, mcfg))
-        for name in set(params["layers"]) - set(self._params["layers"]):
-            params["layers"][name].delete()
+        self._params = fuse_qkv(self._experts_in_compute_dtype(params, mcfg),
+                                mcfg)
+        for stack in ("layers", "dense"):
+            for name in set(params.get(stack, ())) - set(
+                    self._params.get(stack, ())):
+                params[stack][name].delete()
         self.pool = PagePool(n_slots, mcfg.max_seq, page_size, n_pages)
         self.n_pages = self.pool.n_pages
         (self._prefill, self._decode, self._adopt, self._poke,
@@ -692,6 +852,7 @@ class Engine:
         # of a model with state-space layers (`ops/slot_state.py`). Each None
         # for every other model, and no model has both.
         self._hybrid = mcfg.ssm_state > 0
+        self._latent = mcfg.latent
         self._kc, self._vc, *more = self._empty()
         self._ic, self._state = self._third(more)
         # Prefill shape buckets (`prefill_widths`): a 50-token prompt
@@ -751,9 +912,20 @@ class Engine:
         # tokens per expert over prefills and decode steps, and the distinct
         # experts touched, summed over a chunk's steps and the layers.
         self._sparse = mcfg.n_experts > 0
-        self.expert_tokens = np.zeros(mcfg.n_experts, np.int64)
+        self.expert_tokens = np.zeros(mcfg.n_held, np.int64)
         self.decode_experts_touched = 0
         self._touched_last_chunk = 0
+        # A latent-attention model: the bytes of its arena of latent rows
+        # (all it caches); and, where it is sparse, its share of the routing
+        # (`_share_stats`): the assignments its routers made and those that
+        # fell to the experts held here, `expert_tokens` being per HELD
+        # expert. The last chunk's local assignments ride the next dispatch
+        # span as `experts_touched` does.
+        self.latent_cache_bytes = int(self._kc.nbytes) if self._latent else 0
+        self.routed_assignments = 0
+        self.local_assignments = 0
+        self._local_last_chunk = 0
+        self._routed_last_chunk = 0
         self._next_rid = 0
         self._pending: deque = deque()
         # What the loop waits on (`_stand`), under one lock: the pending
@@ -855,7 +1027,8 @@ class Engine:
                 0 if self._hybrid else None)
         if more:     # no PD handoff carries an indexer's keys or a state
             return (kc, vc, *self._third(more), first)
-        if width not in self._adopt_widths:     # no handoff has this width
+        # no handoff has this width, or carries latent rows
+        if width not in self._adopt_widths or self._latent:
             return kc, vc, ic, state, first
         # The PD adopt program for this width too (a first cross-pool
         # handoff must not compile in the loop).
@@ -905,7 +1078,7 @@ class Engine:
 
         return self._prefill.lower(
             jax.tree.map(shape_of, self._params), shape_of(self._kc),
-            shape_of(self._vc),
+            jax.tree.map(shape_of, self._vc),
             jax.ShapeDtypeStruct((self.pool.maxp,), jnp.int32),
             jax.ShapeDtypeStruct((1, width), jnp.int32), 1, 0.0, 0,
             jax.ShapeDtypeStruct((2,), jnp.uint32),
@@ -973,11 +1146,12 @@ class Engine:
         only tokens AFTER `first`."""
         if self.error is not None or not self._thread.is_alive():
             raise RuntimeError(f"LLM engine died:\n{self.error}")
-        if self._ic is not None or self._hybrid:
+        if self._ic is not None or self._hybrid or self._latent:
             raise NotImplementedError(
                 "a PD handoff carries K and V, not a sparse-attention "
-                "indexer's keys nor a state-space layer's recurrent state: "
-                "this model serves from one engine")
+                "indexer's keys nor a state-space layer's recurrent state "
+                "nor latent attention's rows (kv_lora_rank > 0): this model "
+                "serves from one engine")
         req = _Request([0] * min(length, self.mcfg.max_seq - 1),
                        max_tokens, adopt_kv=(ks, vs), first=first,
                        temperature=temperature, top_k=top_k, seed=seed)
@@ -1028,7 +1202,12 @@ class Engine:
         state-space layers adds `state_bytes`, the recurrent state it holds
         on the device for all slots, and `state_writes`, the admissions that
         overwrote a slot's (a decode chunk moves the active slots' share of
-        `state_bytes` once a step)."""
+        `state_bytes` once a step). A latent-attention model adds
+        `latent_cache_bytes`, its arena of latent rows, and, where it is
+        sparse, `routed_assignments` (what its routers assigned of live
+        rows, to any share) and `local_assignments` (those that fell to the
+        experts held here, the sum of `expert_tokens`, which is then per
+        HELD expert): their ratio is this share's part of the routed work."""
         out = {k: getattr(self, k) for k in (
             "admitted", "queue_wait_s_sum", "admit_chunks_ahead",
             "admit_decoding_slots", "admit_pending", "slot_idle_s_sum",
@@ -1045,6 +1224,11 @@ class Engine:
         if self._hybrid:
             out["state_bytes"] = self.state_bytes
             out["state_writes"] = self.state_writes
+        if self._latent:
+            out["latent_cache_bytes"] = self.latent_cache_bytes
+            if self._sparse:
+                out["routed_assignments"] = self.routed_assignments
+                out["local_assignments"] = self.local_assignments
         return out
 
     def stop(self) -> None:
@@ -1253,10 +1437,12 @@ class Engine:
                     if experts is not None:
                         # After the token is out. A span of no length: the
                         # profiler fixes a span's arguments when it opens.
-                        touched = self._count_experts(experts)
+                        touched, local, routed = self._count_experts(experts)
+                        share = {"local": local, "routed": routed} \
+                            if self._latent else {}
                         with tracing.span("serve.engine.prefill_experts",
                                           ctx=req.ctx, rid=req.rid,
-                                          touched=touched):
+                                          touched=touched, **share):
                             pass
                 else:  # ("chunk", out_d, plan, experts)
                     _, out_d, plan, experts = item
@@ -1276,7 +1462,8 @@ class Engine:
                             if fin:
                                 req.out.put(None)
                     if experts is not None:
-                        self._touched_last_chunk = self._count_experts(experts)
+                        (self._touched_last_chunk, self._local_last_chunk,
+                         self._routed_last_chunk) = self._count_experts(experts)
                         self.decode_experts_touched += self._touched_last_chunk
             except BaseException:
                 import traceback
@@ -1294,12 +1481,23 @@ class Engine:
         device (a test holds the emitter here)."""
         return self._np.asarray(out_d)
 
-    def _count_experts(self, experts) -> int:
-        """Emitter thread: add one program's `expert_stats` to the running
-        `expert_tokens`; returns its distinct experts touched."""
+    def _count_experts(self, experts) -> Tuple[int, int, int]:
+        """Emitter thread: add one program's `expert_stats` (a share's
+        `_share_stats`) to the running totals; returns its distinct experts
+        touched, its assignments to experts held here, and all the
+        assignments its routers made (the same, for a model that holds every
+        expert)."""
         stats = self._np.asarray(experts)
+        routed = None
+        if self._latent:
+            routed, stats = int(stats[-1]), stats[:-1]
+        local = int(stats[:-1].sum())
+        routed = local if routed is None else routed
+        # `routed_assignments` last: a reader that sees it moved sees all.
         self.expert_tokens = self.expert_tokens + stats[:-1]
-        return int(stats[-1])
+        self.local_assignments += local
+        self.routed_assignments += routed
+        return int(stats[-1]), local, routed
 
     def _stand(self, ready) -> None:
         """The loop's one wait: admit what has arrived, then stand until
@@ -1372,6 +1570,9 @@ class Engine:
             routed = {"experts_touched": self._touched_last_chunk,
                       "expert_tokens": ":".join(map(str, self.expert_tokens))
                       } if self._sparse and tracing.recording() else {}
+            if routed and self._latent:
+                routed.update(local_assignments=self._local_last_chunk,
+                              routed_assignments=self._routed_last_chunk)
             if self._index_topk:
                 # What each step of the chunk reads, a layer: a slot at
                 # position p attends to p + 1 positions, the indexer's

@@ -23,7 +23,7 @@ sys.path.insert(0, ROOT)
 from benchmark import models, program_trace  # noqa: E402
 from benchmark.tests import test_benchmark as cases  # noqa: E402
 
-ARCHS = ["llama", "olmoe", "keye", "jamba"]
+ARCHS = ["llama", "olmoe", "keye", "jamba", "dots"]
 # config.json of allenai/OLMoE-1B-7B-0125-Instruct, as the catalog beside the
 # model-configs guide has it.
 OLMOE_PUBLISHED = dict(
@@ -607,3 +607,136 @@ def test_state_space_readers_on_a_synthetic_trace(monkeypatch):
                                   deployment=m["deployment"]))
     for name in ("scan_roofline_pct", "decode_state_roofline_pct"):
         assert _reader(name)(other) is None, name
+
+
+# -- arch `dots`: the manifest's entries, and the latent-attention readers ----
+
+def test_dots_manifest_entries_are_the_catalogs_row_cut_to_a_share():
+    manifest = cases.load(ROOT, "BENCHMARK.json")
+    entry = manifest["configs"][-1]
+    assert entry["name"] == "dots.vlm1.inst-serve"
+    cfg = cases.load(ROOT, entry["file"])
+    assert entry["source"] == cfg["source_url"] and cfg["arch"] == "dots"
+    assert entry["reduced"] == list(cfg["reduced"]) == [
+        "num_hidden_layers", "first_k_dense_replace", "n_routed_experts",
+        "vocab_size", "num_nextn_predict_layers"]
+    eng = cfg["deployment"]["engine"]
+    assert (eng["n_slots"], eng["max_seq"], eng["decode_chunk"]) == (32, 4096, 8)
+    assert eng["kv_pages"] == 1 + eng["n_slots"] * eng["max_seq"] // eng["page_size"]
+    cell = manifest["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        "serve-batch-dots-vlm1", "dots.vlm1.inst-serve",
+        "batch-summarize-dots-vlm1", 1)
+    mine = cases.load(cases.BENCH, "traffic", "batch-summarize-dots-vlm1.json")
+    theirs = cases.load(cases.BENCH, "traffic", "batch-summarize-jamba2.json")
+    assert {k for k in mine if mine[k] != theirs.get(k)} == {
+        "what", "arrivals", "shape_seed", "check"}
+    assert mine["arrivals"] == dict(theirs["arrivals"], clients=64)
+    assert len(mine["check"]["prompt_lengths"]) * mine["check"]["tokens"] == 1024
+    lists = {p["name"]: p.get("workloads", []) for p in manifest["per_layer"]}
+    new = ["prefill_mla_ms_per_ktok", "decode_mla_ms",
+           "latent_decode_roofline_pct", "latent_prefill_attn_roofline_pct",
+           "moe_share_experts_roofline_pct", "local_assignment_share_pct"]
+    assert list(lists)[-6:] == new
+    assert all(lists[n] == ["serve-batch-dots-vlm1"] for n in new)
+    # no share of a roofline that counts work this chip does not do
+    for name in ("moe_experts_roofline_pct", "decode_attn_roofline_pct"):
+        assert "serve-batch-dots-vlm1" not in lists[name]
+    for name in ("prefill_ms_per_ktok", "kv_pages_peak_pct", "decode_moe_ms",
+                 "prefill_moe_ms_per_ktok", "expert_load_max_over_mean",
+                 "engine_slot_refill_ms", "prefill_stall_pct"):
+        assert lists[name][-1] == "serve-batch-dots-vlm1"
+
+
+def test_latent_attention_readers_on_a_synthetic_trace(monkeypatch):
+    """The six readers of PR 39 on a trace built by hand: a prefill of 2,000
+    prompt tokens and one decode chunk of 2 steps, their scopes, the prompt
+    kernel's event, the counters on the spans. A program without the scopes
+    (the parent, every other model) reads None and raises nothing."""
+    from benchmark import latent_trace, peaks
+    Span = program_trace.Span
+    spans = [
+        Span("serve.engine.admit", 900, 950, dict(
+            rid=7, kind="prefill", prompt_tokens=2000, bucket=2048,
+            queue_wait_us=1, decoding=0, slot_idle_us=0)),
+        Span("serve.engine.emit", 2100, 2110, dict(rid=7, kind="first")),
+        Span("serve.engine.prefill_experts", 2120, 2120, dict(
+            rid=7, touched=8, local=1000, routed=16000)),
+        Span("serve.engine.decode_dispatch", 2200, 2210, dict(
+            useful=16, capacity=16, active=2, live_kv_tokens=4000,
+            experts_touched=6, expert_tokens="1:2", local_assignments=4,
+            routed_assignments=32)),
+        Span("serve.engine.decode_dispatch", 3200, 3210, dict(
+            useful=16, capacity=16, active=2, live_kv_tokens=4000,
+            experts_touched=6, expert_tokens="3:6", local_assignments=4,
+            routed_assignments=32)),
+    ]
+    modules = [("jit_poke", 0, 10), ("jit_prefill", 1000, 2000),
+               ("jit_decode", 2300, 3000), ("jit_poke", 4000, 4010)]
+    pre = "jit(prefill)/layers/while/body/"
+    dec = "jit(decode)/while/body/layers/while/body/"
+    ops = [(pre + "qkv/q_latent/dot_general:", 1000, 1200),
+           (pre + "qkv/kv_up/dot_general:", 1200, 1300),
+           (pre + "attn/latent_flash_fwd/pallas_call:", 1300, 1700),
+           (pre + "mlp/experts/ragged_dot:", 1700, 1800),
+           (pre + "mlp/shared_expert/dot_general:", 1800, 2000),
+           (dec + "qkv/absorb/dot_general:", 2300, 2400),
+           (dec + "attn/paged_latent_decode/pallas_call:", 2400, 2700),
+           (dec + "attn_out/absorb/dot_general:", 2700, 2800),
+           (dec + "mlp/experts/ragged_dot:", 2800, 3000)]
+    t = program_trace.ProgramTrace(spans, modules, ops)
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    flash = ("%latent_flash_fwd.1 = bf16[128,2048,128]{2,1,0} custom-call("
+             "bf16[128,2048,128]{2,1,0} %a, bf16[128,2048,64]{2,1,0} %b, "
+             "bf16[128,2048,128]{2,1,0} %c, bf16[1,2048,64]{2,1,0} %d, "
+             "bf16[128,2048,128]{2,1,0} %e), "
+             'custom_call_target="tpu_custom_call"')
+    other = ("%flash_fwd.1 = bf16[32,2048,128]{2,1,0} custom-call("
+             "bf16[32,2048,128]{2,1,0} %a, bf16[32,2048,128]{2,1,0} %b, "
+             "bf16[32,2048,128]{2,1,0} %c), "
+             'custom_call_target="tpu_custom_call"')
+    kernel = _device_view([(flash, 1300.0, 1700.0), (other, 1.0, 2.0)])
+    m = cases.load(ROOT, "benchmark/configs/dots.vlm1.inst-serve.json")
+    m["deployment"]["engine"]["decode_chunk"] = 2
+    run = {"config": m, "cell": "x", "seed": 0, "trace_data": kernel,
+           "device": {"kind": "TPU v5 lite"}}
+    assert latent_trace.latent_flash_calls(run) == [
+        (128, 2048, 128, 64, 128, pytest.approx(400e-9))]
+    assert _reader("prefill_mla_ms_per_ktok")(run) == \
+        pytest.approx(700 / 1e6 / 2.0)
+    assert _reader("decode_mla_ms")(run) == pytest.approx(500 / 1e6 / 2)
+    assert _reader("local_assignment_share_pct")(run) == \
+        pytest.approx(100 * 1008 / 16064)
+    counts = models.adapter("dots").counts
+    f, b = peaks.peak("TPU v5 lite", "bf16_flops_per_s"), \
+        peaks.peak("TPU v5 lite", "hbm_bytes_per_s")
+    ops_, byts = counts.latent_decode_ops_bytes(m, [2000, 2000], 2)
+    assert _reader("latent_decode_roofline_pct")(run) == pytest.approx(
+        100 * 2 * 5 * max(ops_ / f, byts / b) / 300e-9)
+    ops_, byts = counts.latent_flash_call_ops_bytes(128, 2048, 128, 64, 128, 2)
+    assert _reader("latent_prefill_attn_roofline_pct")(run) == pytest.approx(
+        100 * max(ops_ / f, byts / b) / 400e-9)
+
+    def least(local, touched):
+        o, y = counts.experts_ops_bytes(m, local, touched, 2, 2)
+        return max(o / f, y / b)
+
+    want = (4 * least(250, 2) + 8 * least(0.5, 0.75)) / (300 / 1e9)
+    assert _reader("moe_share_experts_roofline_pct")(run) == \
+        pytest.approx(100 * want)
+    # a program without the scopes or counters: no metric, no error
+    bare = program_trace.ProgramTrace(
+        [Span(s.name, s.start, s.end, {
+            k: v for k, v in s.args.items()
+            if k not in ("local", "routed", "local_assignments",
+                         "routed_assignments")}) for s in spans],
+        modules, [(re.sub("/(q_latent|kv_up|absorb)", "", p), s, e)
+                  for p, s, e in ops])
+    monkeypatch.setattr(program_trace, "load", lambda run: bare)
+    for name in ("prefill_mla_ms_per_ktok", "decode_mla_ms",
+                 "latent_decode_roofline_pct",
+                 "latent_prefill_attn_roofline_pct",
+                 "moe_share_experts_roofline_pct",
+                 "local_assignment_share_pct"):
+        assert _reader(name)(dict(run, trace_data=_device_view([]))) is None, \
+            name
