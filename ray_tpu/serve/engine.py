@@ -59,8 +59,19 @@ half; the smallest and every rung wider than half of max_seq warmed before
 the loop starts, the rest on a background thread, and until a width is warm
 a prompt rounds UP to the next one that is: nothing compiles inside the loop),
 an `adopt` twin for a PD handoff at each of the doubling widths
-(`doubling_widths`), the n-step decode chunk over all slots, and the slot
-poke.
+(`doubling_widths`) where the engine is a decode pool's (`adopts`), the
+n-step decode chunk over all slots, and the slot poke.
+
+- **Riders**: a prompt is prefilled whole while the live slots wait, and
+  what they wait to do is a decode step, which is weight reads that the
+  prefill of the same layers makes anyway. So where a prompt leaves
+  `n_slots` rows of its bucket free, the prefill program of a riding rung
+  (`rung_rides`: the octave under max_seq, of a dense or a sparse stack)
+  carries ONE decode step of every live slot in those rows
+  (`_make_prefill_core`); on the host the riders advance as a chunk of one
+  step would (`_ride_plan`, `_place`), and the emitter streams their tokens
+  after the prompt's first. Who rides is read off the stack and the shapes:
+  no option, field or environment variable.
 """
 
 from __future__ import annotations
@@ -136,6 +147,17 @@ def prefill_widths(max_seq: int) -> List[int]:
     return sorted({*doubling, *range(top, max_seq, step)})
 
 
+def rung_rides(max_seq: int, n_slots: int, width: int) -> bool:
+    """Whether the prefill program of this width carries the live slots
+    (`_make_prefill_core`, riders): the rungs of the octave under `max_seq`,
+    where a prefill is long enough for a decode step's weight reads to hide
+    in it and where the long prompts of a batch land, and none narrower (a
+    riding program holds a decode step's attention kernel and a sampler over
+    the slots' rows, traced, lowered and loaded at every start). The slots'
+    rows have to fit in the rung beside a prompt."""
+    return 2 * width >= max_seq and n_slots < width
+
+
 def _make_prefill_core(mcfg):
     """fn(params, tokens[1, B], length) -> (first_token, ks, vs, the last
     position's logits, experts) where ks/vs are [L, B, KVH, hd] and `experts`
@@ -148,7 +170,21 @@ def _make_prefill_core(mcfg):
     keys [L, B, Id]; a model with state-space layers, after `experts`
     (None), its layers' final (ssm state [Lm, N, Di], convolution window
     [Lm, K - 1, Di]) after the prompt's last real token, and its ks/vs are
-    those of the attention layers alone."""
+    those of the attention layers alone.
+
+    RIDERS. A dense or a sparse stack's core (`core.takes_riders`; an
+    indexed, a hybrid and a latent stack take nobody) also runs as
+    fn(params, tokens, length, arena=(kc, vc), riders=(bt, last, pos,
+    riding)): ONE decode step of the slots `riding` marks [n_slots], in the
+    bucket's last n_slots rows, which the prompt has to leave free. Slot i's
+    last token is embedded in row B - n_slots + i and rotated at its own
+    position; per layer the tail rows' q, k and v do what a decode step does
+    (the row written to the slot's page, the `paged_decode` kernel against
+    the arena) and the result takes the tail of the flash output's place; the
+    feed-forward and the head run over the bucket as they do anyway, so the
+    step's weight reads are the prefill's. Returns (first, ks, vs, logits
+    [1 + n_slots, V]: the prompt's last row, then the tail rows; experts,
+    counting the riding rows; (kc, vc))."""
     if mcfg.ssm_state:
         return _make_hybrid_prefill_core(mcfg)
     if mcfg.latent:
@@ -161,6 +197,7 @@ def _make_prefill_core(mcfg):
     from ray_tpu.ops.attention import flash_attention, repeat_kv
     from ray_tpu.ops.norms import (apply_rope, mrope_tables, rms_norm,
                                    rope_frequencies)
+    from ray_tpu.ops.paged_kv import paged_decode_attention, write_token
     from ray_tpu.ops.sparse_attention import sparse_attention
 
     H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
@@ -181,14 +218,35 @@ def _make_prefill_core(mcfg):
             counts = counts + n
         return jnp.concatenate(outs, axis=1), (None, counts)
 
-    def _prefill_layer(stacks, carry, layer):
-        x, cos, sin, live, *itables = carry
-        lp, l = layer if sparse else (layer, None)
+    @jax.jit
+    def _token_step(kc, vc, l, bt, w, act, q, k, v):
+        """One token a slot against the cache: a step's k and v `[n_slots,
+        kv_heads, hd]` written at the slots' positions `w`, then each active
+        slot's q `[n_slots, heads, hd]` against its positions 0..w (an idle
+        slot reads nothing). A jit of its own, and ONE for the riders and for
+        the decode program's layers (`_build_fns` takes it from
+        `core.token_step`): no prefill width enters its shapes, so the kernel
+        is traced once a process, not once a riding rung and again for
+        decode (a second of every start, each, on the chip's host: PERF.md
+        section 6, PR 41)."""
+        kc, vc = write_token(kc, vc, l, bt, w, act, k, v)
+        with jax.named_scope("attn"):
+            attn = paged_decode_attention(q, kc, vc, l, bt,
+                                          jnp.where(act, w + 1, 0))
+            attn = attn.reshape(q.shape[0], H * hd)
+        return kc, vc, attn
+
+    def _prefill_layer(stacks, riders, carry, layer):
+        # `rest`: an indexed stack's own rotary tables or, with riders (an
+        # indexed stack takes none), the arena, which rides the carry as it
+        # does in decode (`_build_fns`' `_step` says why).
+        x, cos, sin, live, *rest = carry
+        lp, l = layer if sparse or riders else (layer, None)
         lp = dict(lp, **stacks)
         B, Sq, _ = x.shape
         q, k, v, *index = attention_inputs(
             lp, x, mcfg, lambda t: apply_rope(t, cos, sin),
-            (lambda t: apply_rope(t, *itables)) if indexed else None)
+            (lambda t: apply_rope(t, *rest)) if indexed else None)
         with jax.named_scope("attn"):
             if indexed:
                 qi, ki, w = index[0]
@@ -198,18 +256,28 @@ def _make_prefill_core(mcfg):
                 attn = flash_attention(q, repeat_kv(k, H // KVH),
                                        repeat_kv(v, H // KVH), True)
             attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd)
+        if riders:
+            bt, w, act = riders
+            tail = slice(Sq - act.shape[0], Sq)
+            *rest, rode = _token_step(
+                *rest, l, bt, w, act, *(t[0, :, tail].transpose(1, 0, 2)
+                                        for t in (q, k, v)))
+            attn = attn.at[0, tail].set(
+                jnp.where(act[:, None], rode, attn[0, tail]))
         with jax.named_scope("attn_out"):
             x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
-        x, routed = _feed_forward(lp, x, live, l)
+        x, routed = _feed_forward(lp, x, live, l if sparse else None)
         # cache pre-repeat k/v: [S, KVH, hd] (B == 1 squeezed)
         ys = (k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2))
         if sparse:
             ys += (expert_stats(routed[1]),)
         if indexed:
             ys += (ki[0, 0],)                                  # [S, Id]
-        return (x, cos, sin, live, *itables), ys
+        return (x, cos, sin, live, *rest), ys
 
-    def core(params, tokens, length):
+    def core(params, tokens, length, arena=None, riders=None):
+        if riders is not None:
+            return riding_core(params, tokens, length, arena, riders)
         width = tokens.shape[1]
         with jax.named_scope("embed"):
             x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
@@ -231,7 +299,7 @@ def _make_prefill_core(mcfg):
             sliced = (sliced, jnp.arange(mcfg.n_layers))
         with jax.named_scope("layers"):
             (x, *_), (ks, vs, *more) = jax.lax.scan(
-                functools.partial(_prefill_layer, stacks),
+                functools.partial(_prefill_layer, stacks, None),
                 (x, cos, sin, live, *itables), sliced)
         with jax.named_scope("head"):
             x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
@@ -244,6 +312,47 @@ def _make_prefill_core(mcfg):
         out = (first, ks, vs, logits[0].astype(jnp.float32), experts)
         return out + ((more[-1],) if indexed else ())
 
+    def riding_core(params, tokens, length, arena, riders):
+        """`core` with the live slots in the bucket's tail rows (see
+        `_make_prefill_core`). A row that does not ride is the prompt's or
+        padding, as without riders."""
+        bt, last, pos, riding = riders
+        width, ns, S = tokens.shape[1], riding.shape[0], mcfg.max_seq
+        tail = slice(width - ns, width)
+        act = riding & (pos < S)
+        w = jnp.minimum(pos, S - 1)
+        rows = jnp.arange(width)
+        with jax.named_scope("embed"):
+            tokens = tokens.at[0, tail].set(
+                jnp.where(act, last, tokens[0, tail]))
+            x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+        with jax.named_scope("rope"):
+            # A row's own position: the prompt's run 0.., a rider's is its
+            # slot's, anywhere under max_seq.
+            at = rows.at[tail].set(jnp.where(act, w, rows[tail]))
+            cos, sin = (t[at] for t in
+                        rope_frequencies(hd, S, mcfg.rope_theta))
+        live = ((rows < length) | jnp.zeros(width, bool).at[tail].set(act)
+                )[None] if sparse else None
+        sliced, stacks = expert_stacks(params["layers"], mcfg)
+        with jax.named_scope("layers"):
+            (x, _, _, _, kc, vc), (ks, vs, *more) = jax.lax.scan(
+                functools.partial(_prefill_layer, stacks, (bt, w, act)),
+                (x, cos, sin, live, *arena),
+                (sliced, jnp.arange(mcfg.n_layers)))
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
+            last_h = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
+                                                  keepdims=False)
+            logits = jnp.einsum(
+                "bd,dv->bv", jnp.concatenate([last_h, x[0, tail]]),
+                params["lm_head"].astype(dt))
+            first = jnp.argmax(logits[0]).astype(jnp.int32)
+        experts = jnp.sum(more[0], axis=0) if sparse else None
+        return (first, ks, vs, logits.astype(jnp.float32), experts, (kc, vc))
+
+    core.takes_riders = not indexed
+    core.token_step = _token_step
     return core
 
 
@@ -321,6 +430,7 @@ def _make_hybrid_prefill_core(mcfg):
                 logits[0].astype(jnp.float32), None,
                 (jnp.concatenate(states), jnp.concatenate(windows)))
 
+    core.takes_riders = False
     return core
 
 
@@ -410,6 +520,7 @@ def _make_latent_prefill_core(mcfg):
         return (first, jnp.concatenate(rows), None,
                 logits[0].astype(jnp.float32), experts if sparse else None)
 
+    core.takes_riders = False
     return core
 
 
@@ -515,7 +626,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     _core = _make_prefill_core(mcfg)
 
     def prefill(params, kc, vc, pages, tokens, length, temp, topk, key,
-                ic=None, state=None, slot=None):
+                ic=None, state=None, slot=None, last=None, pos=None,
+                riders=None):
         """tokens [1, B] padded to a BUCKET width (a rung of
         `prefill_widths` — jax.jit compiles one program per bucket shape, so
         a prompt pays a prefill of about its own length, not a max_seq one);
@@ -523,7 +635,17 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         or greedy when temp == 0) and the core's `experts` (and `ic`, the
         indexer keys' arena, where the model has one; or `state`, the
         recurrent state with slot `slot`'s rows overwritten by the prompt's
-        final ones, where it has state-space layers)."""
+        final ones, where it has state-space layers).
+
+        With `riders` = (block table, riding [ns], the slots' temp, topk,
+        keys) and the slots' `last` and `pos` (the program of a riding rung,
+        `rung_rides`): the core's one decode step of the riding slots, and
+        after `experts` come `last` and `pos` moved by it, as a decode chunk
+        of one step would leave them, and the tokens [ns] the step sampled
+        (a riding slot's is its next one; the others' rows are not slots')."""
+        if riders is not None:
+            return _riding_prefill(params, kc, vc, pages, tokens, length,
+                                   temp, topk, key, last, pos, *riders)
         _, ks, vs, logits_row, experts, *iks = _core(params, tokens, length)
         kc, vc = write_prompt(kc, vc, pages, ks, vs)
         first = _sample_tokens(logits_row[None],
@@ -536,6 +658,22 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         if hybrid:
             return kc, vc, first, experts, write_state(state, slot, *iks[0])
         return kc, vc, first, experts
+
+    def _riding_prefill(params, kc, vc, pages, tokens, length, temp, topk,
+                        key, last, pos, bt, riding, temps, topks, keys):
+        _, ks, vs, logits, experts, (kc, vc) = _core(
+            params, tokens, length, (kc, vc), (bt, last, pos, riding))
+        kc, vc = write_prompt(kc, vc, pages, ks, vs)
+        # The prompt's row and the riders' through ONE sampler, each row at
+        # its own temperature, key and position, as `_step` samples.
+        toks = _sample_tokens(
+            logits, jnp.concatenate([jnp.asarray(temp)[None], temps]),
+            jnp.concatenate([jnp.asarray(topk)[None], topks]),
+            jnp.concatenate([key[None], keys]),
+            jnp.concatenate([jnp.asarray(length - 1)[None], pos]))
+        act = riding & (pos < S)
+        return (kc, vc, toks[0], experts, jnp.where(act, toks[1:], last),
+                jnp.where(act, pos + 1, pos), toks[1:])
 
     def adopt(kc, vc, pages, ks, vs):
         """Write externally-prefilled k/v (a PrefillServer handoff) into
@@ -572,20 +710,26 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             lp, x, mcfg,
             (lambda t: _rope_one(t, c, s)) if mcfg.rope else (lambda t: t),
             (lambda t: _rope_one(t, ci, si)) if indexed else None)
-        kc, vc = write_token(kc, vc, l, bt, w, act, k, v)
-        if indexed:
-            qi, ki, iw = index[0]
-            ic = write_token_rows(ic, l, bt, w, act, ki[:, 0])
-        # Each active slot's query against its positions 0..w; an idle slot
-        # reads nothing.
-        with jax.named_scope("attn"):
-            lengths = jnp.where(act, w + 1, 0)
+        if _core.takes_riders:
+            # The write and the kernel as the riders' step has them, traced
+            # once for both (`_token_step` in `_make_prefill_core`).
+            kc, vc, attn = _core.token_step(kc, vc, l, bt, w, act, q, k, v)
+        else:
+            kc, vc = write_token(kc, vc, l, bt, w, act, k, v)
             if indexed:
-                attn = sparse_decode_attention(
-                    q, qi, iw, kc, vc, ic, l, bt, lengths, mcfg.index_topk)
-            else:
-                attn = paged_decode_attention(q, kc, vc, l, bt, lengths)
-            attn = attn.reshape(ns, H * hd)
+                qi, ki, iw = index[0]
+                ic = write_token_rows(ic, l, bt, w, act, ki[:, 0])
+            # Each active slot's query against its positions 0..w; an idle
+            # slot reads nothing.
+            with jax.named_scope("attn"):
+                lengths = jnp.where(act, w + 1, 0)
+                if indexed:
+                    attn = sparse_decode_attention(
+                        q, qi, iw, kc, vc, ic, l, bt, lengths,
+                        mcfg.index_topk)
+                else:
+                    attn = paged_decode_attention(q, kc, vc, l, bt, lengths)
+                attn = attn.reshape(ns, H * hd)
         with jax.named_scope("attn_out"):
             x = x + attn @ lp["wo"].astype(dt)
         # An idle slot's row is computed like any other, from itself alone,
@@ -761,7 +905,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         return last.at[slot].set(first), pos.at[slot].set(length)
 
     import jax as _jax
-    prefill_jit = _jax.jit(prefill, donate_argnums=(1, 2, 9, 10))
+    prefill_jit = _jax.jit(prefill, donate_argnums=(1, 2, 9, 10, 12, 13))
+    prefill_jit.takes_riders = _core.takes_riders
     decode_jit = _jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10, 11))
     adopt_jit = _jax.jit(adopt, donate_argnums=(0, 1))
     poke_jit = _jax.jit(poke, donate_argnums=(0, 1))
@@ -813,9 +958,13 @@ class Engine:
 
     def __init__(self, params, mcfg, *, n_slots: int = 8,
                  decode_chunk: int = 8, page_size: int = 64,
-                 n_pages: Optional[int] = None):
+                 n_pages: Optional[int] = None, adopts: bool = False):
         """`params` is the published tree, on the device; its `wq`, `wk` and
-        `wv` stacks are consumed (see below), the rest is shared."""
+        `wv` stacks are consumed (see below), the rest is shared. `adopts`:
+        the engine is a decode pool's, handed prompts prefilled elsewhere
+        (`submit_prefilled`), and warms an `adopt` program a doubling width;
+        what the server class that builds it says, not a deployment's
+        choice: one that serves whole requests is never sent a hand-off."""
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -861,7 +1010,7 @@ class Engine:
         # padding, that the reference gets from vLLM's chunked prefill. A PD
         # handoff is adopted at the doubling widths alone, its sender's.
         self.buckets: List[int] = prefill_widths(mcfg.max_seq)
-        self._adopt_widths = doubling_widths(mcfg.max_seq)
+        self._adopt_widths = doubling_widths(mcfg.max_seq) if adopts else []
         # host-side slot state (control flow is host-predicted; only token
         # VALUES come back from the device)
         self._slot_req: List[Optional[_Request]] = [None] * n_slots
@@ -891,6 +1040,10 @@ class Engine:
         self.prefill_padded_tokens = 0     # bucket width less the prompt
         self.decode_chunks = 0
         self.decode_useful_tokens = 0
+        # Tokens decoded inside prefills (a riding slot's one step in a
+        # prompt's padding rows), and the prefills that carried any.
+        self.rider_tokens = 0
+        self.rider_steps = 0
         # Positions the active slots held when each chunk was dispatched:
         # what decode attention had to read, a layer, at the chunk's first
         # step (against n_slots * max_seq, what a whole-table gather moves).
@@ -1018,16 +1171,25 @@ class Engine:
         (kc, vc, ic, state, first token on the device)."""
         jnp, m = self._jnp, self.mcfg
         null_pages = jnp.zeros(self.pool.maxp, jnp.int32)
+        # A riding rung's program with nobody riding, on slots' state of its
+        # own: the live `_last_d` and `_pos_d` are the loop's to donate.
+        rides = self._rides(width)
+        slots = (None, None, None) if not rides else (
+            jnp.zeros(self.n_slots, jnp.int32),
+            jnp.zeros(self.n_slots, jnp.int32),
+            self._riders(self._np.zeros(self.n_slots, bool)))
         with tracing.compile_span("serve.engine.warm", program="prefill",
                                   width=width):
             kc, vc, first, _, *more = self._prefill(
                 self._params, kc, vc, null_pages,
                 jnp.zeros((1, width), jnp.int32), 1, 0.0, 0,
                 jnp.zeros(2, jnp.uint32), ic, state,
-                0 if self._hybrid else None)
-        if more:     # no PD handoff carries an indexer's keys or a state
+                0 if self._hybrid else None, *slots)
+        if more and not rides:
+            # no PD handoff carries an indexer's keys or a state
             return (kc, vc, *self._third(more), first)
-        # no handoff has this width, or carries latent rows
+        # no handoff has this width (none at all is sent an engine that
+        # serves whole requests), or carries latent rows
         if width not in self._adopt_widths or self._latent:
             return kc, vc, ic, state, first
         # The PD adopt program for this width too (a first cross-pool
@@ -1038,6 +1200,22 @@ class Engine:
                            m.dtype)
             kc, vc = self._adopt(kc, vc, null_pages, kv, kv)
         return kc, vc, ic, state, first
+
+    def _rides(self, width: int) -> bool:
+        """Whether the prefill program of this width takes the live slots
+        along: read off the stack (`_make_prefill_core`) and the rung."""
+        return self._prefill.takes_riders and rung_rides(
+            self.mcfg.max_seq, self.n_slots, width)
+
+    def _riders(self, riding):
+        """A riding program's last argument: the block table and the slots
+        `riding` marks (host arrays, copied: the loop mutates them while the
+        program is queued), with the slots' sampling state."""
+        jnp = self._jnp
+        return (jnp.asarray(self.pool.block_table.copy()),
+                jnp.asarray(riding), jnp.asarray(self._temp.copy()),
+                jnp.asarray(self._topk.copy()),
+                jnp.asarray(self._skeys.copy()))
 
     def _warm_buckets(self, widths: List[int]) -> None:
         """Warm intermediate prefill buckets off the engine loop; each
@@ -1076,6 +1254,11 @@ class Engine:
         def shape_of(x):
             return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
+        slots = riders = None
+        if self._rides(width):     # as `_place` calls it
+            slots = shape_of(self._last_d)
+            riders = jax.tree.map(shape_of, self._riders(
+                self._np.zeros(self.n_slots, bool)))
         return self._prefill.lower(
             jax.tree.map(shape_of, self._params), shape_of(self._kc),
             jax.tree.map(shape_of, self._vc),
@@ -1084,7 +1267,7 @@ class Engine:
             jax.ShapeDtypeStruct((2,), jnp.uint32),
             None if self._ic is None else shape_of(self._ic),
             jax.tree.map(shape_of, self._state),
-            0 if self._hybrid else None).as_text()
+            0 if self._hybrid else None, slots, slots, riders).as_text()
 
     # ------------------------------------------------------------------
     @property
@@ -1152,6 +1335,11 @@ class Engine:
                 "indexer's keys nor a state-space layer's recurrent state "
                 "nor latent attention's rows (kv_lora_rank > 0): this model "
                 "serves from one engine")
+        if not self._adopt_widths:
+            raise RuntimeError(
+                "this engine warmed no `adopt` program and would compile one "
+                "inside its loop: an engine that is handed prefilled prompts "
+                "is built with `adopts=True`, as `DecodeServer` builds it")
         req = _Request([0] * min(length, self.mcfg.max_seq - 1),
                        max_tokens, adopt_kv=(ks, vs), first=first,
                        temperature=temperature, top_k=top_k, seed=seed)
@@ -1188,7 +1376,10 @@ class Engine:
         of slot-time left unfilled.
         Occupancy is
         `decode_useful_tokens` over
-        `decode_chunks * n_slots * chunk`; padding is
+        `decode_chunks * n_slots * chunk`; `rider_tokens` are the tokens
+        decoded outside the chunks, a live slot's one step inside another
+        request's prefill (`rider_steps`: the prefills that carried any), so
+        the tokens decoded are the two added; padding is
         `prefill_padded_tokens` over it plus `prefill_tokens`.
         `live_kv_tokens` over `decode_chunks * n_slots * max_seq` is the
         share of the block tables that was live at dispatch. A sparse
@@ -1213,8 +1404,8 @@ class Engine:
             "admit_decoding_slots", "admit_pending", "slot_idle_s_sum",
             "prefill_tokens",
             "prefill_padded_tokens", "decode_chunks",
-            "decode_useful_tokens", "live_kv_tokens", "peak_pages_used",
-            "n_slots", "chunk")}
+            "decode_useful_tokens", "rider_tokens", "rider_steps",
+            "live_kv_tokens", "peak_pages_used", "n_slots", "chunk")}
         if self._sparse:
             out["expert_tokens"] = [int(n) for n in self.expert_tokens]
             out["decode_experts_touched"] = self.decode_experts_touched
@@ -1258,7 +1449,7 @@ class Engine:
         Prefills for a BURST of admissions are all dispatched (and their
         first-token transfers started) before any is handed to the
         emitter, so N admissions cost ~one round-trip, not N."""
-        emits: List[Tuple] = []  # (req, first, done, experts)
+        emits: List[Tuple] = []  # (req, first, done, experts, rode, riders)
         while True:
             with self._cv:
                 req = self._pending[0] if self._pending else None
@@ -1298,33 +1489,64 @@ class Engine:
             if not adopting:
                 self.prefill_tokens += width
                 self.prefill_padded_tokens += bucket - width
+            # The live slots ride a riding rung's prefill where the prompt
+            # leaves them their rows (`_ride_plan`); the span says how many.
+            riders = None
+            if not adopting and self._rides(bucket):
+                riders = self._ride_plan(bucket - width)
+                self.rider_tokens += len(riders)
+                self.rider_steps += bool(riders)
             with tracing.span(
                     "serve.engine.admit", ctx=req.ctx, rid=req.rid,
                     kind="adopt" if adopting else "prefill",
                     prompt_tokens=len(req.ids), bucket=bucket,
                     queue_wait_us=int(waited * 1e6), pending=left,
                     pages_free=self.pool.free - need, chunks_ahead=ahead,
-                    decoding=decoding, slot_idle_us=int(slot_idle * 1e6)):
-                emits.append(self._place(req, slot, need, bucket))
+                    decoding=decoding, slot_idle_us=int(slot_idle * 1e6),
+                    **({} if riders is None else {"riders": len(riders)})):
+                emits.append(self._place(req, slot, need, bucket, riders))
         # Start EVERY device->host copy first (async), THEN enqueue: a
         # burst overlaps all its transfers.
-        for _, first, _, _ in emits:
-            try:
-                first.copy_to_host_async()
-            except AttributeError:
-                pass  # host int (adopt path)
+        for _, first, _, _, rode, _ in emits:
+            for out in (first, rode):
+                try:
+                    out.copy_to_host_async()
+                except AttributeError:
+                    pass  # host int (adopt path); nobody rode
         for item in emits:
             # The emitter thread performs the int(first) sync — the
             # dispatch loop never blocks on the device.
             self._emit_q.put(("first",) + item)
 
-    def _place(self, req: _Request, slot: int, need: int,
-               bucket: int) -> Tuple[_Request, Any, bool, Any]:
+    def _ride_plan(self, free_rows: int) -> List[Tuple]:
+        """[(slot, request, finishes)] of the slots that ride a prefill whose
+        prompt leaves `free_rows` of its bucket: every live slot, where their
+        rows fit. Each takes one token, as in a decode chunk of one step
+        (`_run_inner`'s plan): a live slot has one to take and a position
+        under max_seq, or it would have finished."""
+        if free_rows < self.n_slots:
+            return []
+        plan = []
+        for slot in self._np.flatnonzero(self._active):
+            req = self._slot_req[slot]
+            req.produced += 1
+            plan.append((int(slot), req,
+                         req.produced >= req.max_tokens
+                         or self._pos[slot] + 1 >= self.mcfg.max_seq))
+        return plan
+
+    def _place(self, req: _Request, slot: int, need: int, bucket: int,
+               riders: Optional[List[Tuple]]
+               ) -> Tuple[_Request, Any, bool, Any, Any, List[Tuple]]:
         """Grant `need` pages and the slot, dispatch the prefill (or the
-        adopt) at width `bucket` and the poke. Returns the emitter's item:
-        (req, first token, finished already, the prefill's `experts`)."""
+        adopt) at width `bucket` and the poke. `riders`: `_ride_plan` for a
+        riding rung's program (None: it takes nobody), whose slots move a
+        step with it. Returns the emitter's item: (req, first token, finished
+        already, the prefill's `experts`, the riders' tokens [n_slots] on the
+        device, `riders`)."""
         np, jnp = self._np, self._jnp
         S = self.mcfg.max_seq
+        rode = None
         pages_arr = jnp.asarray(self.pool.grant(slot, need))
         self.peak_pages_used = max(self.peak_pages_used,
                                    self.pool.in_use())
@@ -1351,13 +1573,25 @@ class Engine:
         else:
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :len(req.ids)] = req.ids
+            slots = (None, None, None)
+            if riders is not None:
+                riding = np.zeros(self.n_slots, bool)
+                riding[[s for s, _, _ in riders]] = True
+                slots = (self._last_d, self._pos_d, self._riders(riding))
             self._kc, self._vc, first, experts, *more = self._prefill(
                 self._params, self._kc, self._vc, pages_arr,
                 jnp.asarray(toks), len(req.ids),
                 float(req.temperature), int(req.top_k),
                 jnp.asarray(_seed_key(req.seed)), self._ic, self._state,
-                slot if self._hybrid else None)
-            self._ic, self._state = self._third(more)
+                slot if self._hybrid else None, *slots)
+            if riders is None:
+                self._ic, self._state = self._third(more)
+            else:
+                self._last_d, self._pos_d, rode = more
+                self._pos[riding] += 1
+                for s, _, fin in riders:
+                    if fin:     # its slot and pages are free at once
+                        self._finish_state(s)
             self.state_writes += self._hybrid
         req.slot = slot
         self._slot_req[slot] = req
@@ -1377,7 +1611,7 @@ class Engine:
                     or self._pos[slot] >= S)
         if done:
             self._finish_state(slot)
-        return req, first, done, experts
+        return req, first, done, experts, rode, riders or []
 
     def _finish_state(self, slot: int) -> None:
         """Free the slot + pages (host control state only — the stream's
@@ -1425,7 +1659,7 @@ class Engine:
                 return
             try:
                 if item[0] == "first":
-                    _, req, first, done, experts = item
+                    _, req, first, done, experts, rode, riders = item
                     # Ends at the engine's first-token instant.
                     with tracing.span(
                             "serve.engine.emit", ctx=req.ctx, rid=req.rid,
@@ -1434,6 +1668,13 @@ class Engine:
                             req.out.put([int(first)])
                         if done:
                             req.out.put(None)
+                    # The token each rider's step made, after the prompt's.
+                    if riders:
+                        rode = np.asarray(rode)
+                    for slot, rider, fin in riders:
+                        rider.out.put([int(rode[slot])])
+                        if fin:
+                            rider.out.put(None)
                     if experts is not None:
                         # After the token is out. A span of no length: the
                         # profiler fixes a span's arguments when it opens.
@@ -1471,7 +1712,8 @@ class Engine:
                 # Terminate the affected streams rather than stranding
                 # their consumers.
                 if item[0] == "first":
-                    item[1].out.put(None)
+                    for req in [item[1]] + [r for _, r, _ in item[6]]:
+                        req.out.put(None)
                 else:
                     for _, req, _, _ in item[2]:
                         req.out.put(None)

@@ -352,7 +352,7 @@ class DecodeServer:
                              n_slots=cfg.max_ongoing_requests,
                              decode_chunk=cfg.decode_chunk,
                              page_size=cfg.page_size,
-                             n_pages=cfg.kv_pages)
+                             n_pages=cfg.kv_pages, adopts=True)
 
     def check_health(self) -> None:
         _check_engine_health(self.engine)

@@ -637,7 +637,8 @@ def test_dots_manifest_entries_are_the_catalogs_row_cut_to_a_share():
     new = ["prefill_mla_ms_per_ktok", "decode_mla_ms",
            "latent_decode_roofline_pct", "latent_prefill_attn_roofline_pct",
            "moe_share_experts_roofline_pct", "local_assignment_share_pct"]
-    assert list(lists)[-6:] == new
+    at = list(lists).index(new[0])      # appended by PR 39; later PRs after
+    assert list(lists)[at:at + 6] == new
     assert all(lists[n] == ["serve-batch-dots-vlm1"] for n in new)
     # no share of a roofline that counts work this chip does not do
     for name in ("moe_experts_roofline_pct", "decode_attn_roofline_pct"):

@@ -501,15 +501,26 @@ def test_the_configuration_is_the_catalogs_row_cut_to_a_share():
 # serving programs at their adapters' rehearsal widths and of a dense train
 # step at `LlamaConfig.tiny`, on the parent commit of PR 39 (00d21d1; jax
 # 0.9.0 on the CPU: no Mosaic payload, no source locations in the text).
+# Since PR 41 a dense and a sparse stack's rungs of the octave under `max_seq`
+# (64 and 128 here) carry the live slots (`engine.rung_rides`) and lower to
+# another text on purpose: their pin is the 32 rung, taken on PR 41's parent
+# (5481b82), as are this model's own (`latent`), which takes nobody.
+# tests/test_prefill_riders.py pins every rung of every stack. The same two
+# stacks' decode programs hand the arena to the jit they share with the riders
+# (`_token_step`: the same write and kernel, one trace a process) and were
+# taken anew on PR 41's tree (their parent's: 4ce2defd4ff49240 and
+# 278d751dc50fcfc4); the three stacks that take nobody keep the parent's.
 PARENT = {
-    "dense.decode": "4ce2defd4ff49240",
-    "dense.prefill64": "d5061fe7c8b0f160",
+    "dense.decode": "d87712c9b4ee5285",
+    "dense.prefill32": "c948937b09fe2fee",
     "hybrid.decode": "98e6e614b5625848",
     "hybrid.prefill64": "b6847a6dfe909d84",
     "indexed.decode": "7f5193fdda9e8db0",
     "indexed.prefill64": "2b26fc68f7f5f898",
-    "sparse.decode": "278d751dc50fcfc4",
-    "sparse.prefill64": "01d0cbc9e60958cc",
+    "latent.decode": "3cbcf9da23401fa5",
+    "latent.prefill64": "f96e02f0c080c3fb",
+    "sparse.decode": "94dff0eb228ce990",
+    "sparse.prefill32": "7a5fc5aa7c158c94",
     "train.tiny": "569d197c86234e93",
 }
 KINDS = {
@@ -520,6 +531,7 @@ KINDS = {
                              norm_topk_prob=True)),
     "hybrid": ("jamba", dict(rms_norm_eps=1e-6, num_experts=1,
                              tie_word_embeddings=True)),
+    "latent": ("dots", PUBLISHED),
 }
 
 
@@ -555,17 +567,20 @@ def _lowered(kind):
             arg((2,), jnp.int32), arg((2,), jnp.bool_),
             arg((2,), jnp.float32), arg((2,), jnp.int32),
             arg((2, 2), jnp.uint32), sds(eng._ic), sds(eng._state)).as_text()
-        return {f"{kind}.prefill64": _sha(eng.lowered_prefill_text(64)),
+        width = 32 if eng._rides(64) else 64
+        return {f"{kind}.prefill{width}":
+                _sha(eng.lowered_prefill_text(width)),
                 f"{kind}.decode": _sha(decode)}
     finally:
         eng.stop()
 
 
 @pytest.mark.parametrize("kind", ["dense", "sparse", "indexed", "hybrid",
-                                  "train"])
+                                  "latent", "train"])
 def test_the_other_models_programs_are_the_parents(kind):
-    """What a dense, a sparse (softmax router, every expert), an indexed and
-    a hybrid engine's prefill and decode, and a dense train step, lower to is
-    letter for letter what the parent commit lowers them to."""
+    """What a dense, a sparse (softmax router, every expert), an indexed, a
+    hybrid and a latent engine's prefill (a rung that takes no riders) and
+    decode, and a dense train step, lower to is letter for letter what the
+    parent commit lowers them to."""
     got = _lowered(kind)
     assert got == {k: PARENT[k] for k in got}

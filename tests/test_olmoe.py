@@ -168,7 +168,7 @@ def test_engine_tokens_with_the_decode_kernel_equal_the_reference_paths(
 
     def served():
         eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=2,
-                     decode_chunk=4, page_size=16)
+                     decode_chunk=4, page_size=16, adopts=True)
         try:
             a = eng.submit(prompts[0], 11)             # positions 14..24
             first, ks, vs, _, _ = core(fuse_qkv(params), jnp.asarray(

@@ -196,32 +196,44 @@ def warm_spans(monkeypatch):
     return spans
 
 
+@pytest.mark.parametrize("adopts", [True, False],
+                         ids=["as-DecodeServer", "as-LLMServer"])
 def test_wide_rungs_warm_in_the_constructor_and_adopt_at_doubling_widths_only(
-        warm_spans):
+        warm_spans, adopts):
     """Every rung wider than half of `max_seq` is warm when the constructor
     returns, warmed by the constructing thread (against the live arena: a
     wide prefill's temporaries do not fit beside the warm-up thread's scratch
-    arena on the chip); the thread warms the rest; an `adopt` program is
-    compiled at the doubling widths and at no other."""
+    arena on the chip); the thread warms the rest. An engine built as
+    `DecodeServer` builds it (`adopts=True`) compiles an `adopt` program at
+    the doubling widths and at no other; one built as `LLMServer` builds it
+    is never sent a hand-off, warms none (2-3 s of every start at the
+    benchmark's widths: PERF.md section 6, PR 41) and refuses
+    `submit_prefilled` with the reason, instead of compiling inside its
+    loop."""
     _, _, cfg, params = _tiny("dense", 4096)
     here = threading.current_thread().name
-    eng = Engine(params, cfg, n_slots=2, decode_chunk=4, page_size=64)
+    eng = Engine(params, cfg, n_slots=2, decode_chunk=4, page_size=64,
+                 adopts=adopts)
+    adopted = doubling_widths(4096) if adopts else []
     try:
         wide = [2560, 3072, 3584, 4096]
         assert set(wide) <= eng._warm
         built = [(p, w) for p, w, name in warm_spans if name == here]
         assert [w for p, w in built if p == "prefill"] == [32] + wide
-        assert [w for p, w in built if p == "adopt"] == [32, 4096]
+        assert [w for p, w in built if p == "adopt"] == adopted[:1] + adopted[-1:]
         _until_all_warm(eng)
+        if not adopts:
+            kv = jnp.zeros((cfg.n_layers, 32, cfg.n_kv_heads, cfg.head_dim))
+            with pytest.raises(RuntimeError, match="warmed no `adopt`"):
+                eng.submit_prefilled(kv, kv, 20, 7, 4)
     finally:
         eng.stop()
     assert sorted(w for p, w, _ in warm_spans if p == "prefill") \
         == LADDERS[4096]
-    assert sorted(w for p, w, _ in warm_spans if p == "adopt") \
-        == doubling_widths(4096)
+    assert sorted(w for p, w, _ in warm_spans if p == "adopt") == adopted
     assert {w for p, w, name in warm_spans if name != here} \
         == set(LADDERS[4096]) - {32} - set(wide)
-    assert eng._adopt._cache_size() == len(doubling_widths(4096))
+    assert eng._adopt._cache_size() == len(adopted)
 
 
 def test_a_prefill_pool_pads_to_doubling_widths_and_its_hand_off_is_adopted():

@@ -1,0 +1,326 @@
+"""The live slots ride the wide prefills (`serve/engine.py`, PR 41): where a
+prompt leaves `n_slots` rows of its bucket free, a riding rung's program
+(`rung_rides`: the octave under `max_seq`) carries ONE decode step of every
+live slot in those rows. On the CPU at the adapters' rehearsal widths in
+float32, a dense and a sparse stack: every stream is what the same engine
+serves with nobody riding, and the plain reference's greedy tokens; the
+counters and the admit spans agree; a burst of admissions moves the riders a
+step each; a rider that finishes on a riding step frees its slot and pages at
+once; an indexed, a hybrid and a latent stack take nobody, and every program
+that takes nobody lowers to the parent's text.
+
+Tolerance: program and reference compute the same mathematics in float32 and
+differ in the order of their sums; LOGIT_TOL is tests/test_prefill_ladder.py's.
+"""
+
+import hashlib
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark import models
+from ray_tpu.serve.engine import (_DEPTH, Engine, _make_prefill_core,
+                                  prefill_widths, rung_rides)
+from ray_tpu.utils import tracing
+from test_dots import PUBLISHED
+from test_prefill_ladder import F32, KINDS, LOGIT_TOL, _tiny, _tokens
+
+MAX_SEQ, SLOTS, CHUNK = 256, 4, 4
+# (prompt tokens, max_tokens, temperature): rungs 256 and 128 ride, 64 and 32
+# do not; the prompt of 253 leaves its bucket three rows, so nobody rides it.
+ASKS = [(200, 21, 0.0), (131, 14, 0.8), (253, 3, 0.0), (100, 18, 0.0),
+        (230, 12, 0.8), (40, 11, 0.0), (180, 25, 0.0), (124, 7, 0.8),
+        (222, 16, 0.0), (150, 10, 0.0)]
+
+
+def _until(cond, seconds=60.0):
+    deadline = time.monotonic() + seconds
+    while not cond():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.002)
+
+
+def _drain(q, seconds=120.0):
+    out = []
+    while (item := q.get(timeout=seconds)) is not None:
+        out.extend(item)
+    return out
+
+
+def _build(kind, n_slots=SLOTS):
+    adapter, model, cfg, params = _tiny(kind, MAX_SEQ)
+    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=n_slots,
+                 decode_chunk=CHUNK, page_size=16,
+                 n_pages=n_slots * MAX_SEQ // 16 + 1)   # pages never bind
+    _until(lambda: sorted(eng._warm) == eng.buckets or eng.warm_error)
+    assert not eng.warm_error, eng.warm_error
+    return adapter, model, params, eng
+
+
+def _serve(eng, asks=ASKS):
+    streams = [eng.submit(_tokens(n, seed), m, temperature=t, top_k=16,
+                          seed=seed)
+               for seed, (n, m, t) in enumerate(asks)]
+    return [_drain(q) for q in streams]
+
+
+class _Spans:
+    """`with _Spans() as spans:` records (name, arguments) of every span the
+    program opens meanwhile, beside what `tracing.span` does with it."""
+
+    def __enter__(self):
+        self.seen, self._span = [], tracing.span
+
+        def recording(name, **args):
+            self.seen.append((name, args))
+            return self._span(name, **args)
+
+        tracing.span = recording
+        return self
+
+    def __exit__(self, *exc):
+        tracing.span = self._span
+
+    def named(self, name):
+        return [args for n, args in self.seen if n == name]
+
+
+@pytest.fixture(scope="module", params=["dense", "sparse"])
+def served(request):
+    """One engine a stack: `ASKS` served with the live slots riding, then by
+    the same engine, the same programs, with nobody marked as riding."""
+    adapter, model, params, eng = _build(request.param)
+    try:
+        before = eng.counters()
+        with _Spans() as spans:
+            riding = _serve(eng)
+        after = eng.counters()
+        eng._ride_plan = lambda free_rows: []
+        plain = _serve(eng)
+        last = eng.counters()
+        assert eng.error is None, eng.error
+    finally:
+        eng.stop()
+    delta = {k: after[k] - before[k] for k in (
+        "rider_tokens", "rider_steps", "decode_useful_tokens", "admitted")}
+    return dict(adapter=adapter, model=model, params=params, eng=eng,
+                riding=riding, plain=plain, spans=spans, delta=delta,
+                rode_plain=last["rider_tokens"] - after["rider_tokens"])
+
+
+def test_every_stream_is_what_the_engine_serves_with_nobody_riding(served):
+    """Greedy and sampled alike: a rider's token is its slot's next decode
+    step's, at the same position, key and temperature."""
+    assert [len(s) for s in served["riding"]] == [m for _, m, _ in ASKS]
+    assert served["riding"] == served["plain"]
+    assert served["delta"]["rider_tokens"] > 0 == served["rode_plain"]
+
+
+def test_the_greedy_streams_are_the_plain_references(served):
+    reference = served["adapter"].reference()
+    for seed, (n, _, temp) in enumerate(ASKS):
+        if temp:
+            continue
+        gaps = reference.served_token_gaps(
+            served["params"], served["model"], _tokens(n, seed),
+            served["riding"][seed])
+        assert max(gaps) < LOGIT_TOL, (seed, gaps)
+
+
+def test_the_admit_spans_riders_add_up_to_the_counters(served):
+    """`riders` is on the admit span of every riding rung's prefill and on no
+    other; their sum is `rider_tokens`, and with the chunks' `useful` every
+    token after a request's first is accounted for."""
+    admits = served["spans"].named("serve.engine.admit")
+    assert len(admits) == served["delta"]["admitted"] == len(ASKS)
+    for a in admits:
+        assert ("riders" in a) == rung_rides(MAX_SEQ, SLOTS, a["bucket"])
+        if a["bucket"] - a["prompt_tokens"] < SLOTS:
+            assert not a.get("riders")
+        elif "riders" in a:
+            assert a["riders"] == a["decoding"]
+    rode = [a["riders"] for a in admits if a.get("riders")]
+    assert sum(rode) == served["delta"]["rider_tokens"]
+    assert len(rode) == served["delta"]["rider_steps"]
+    useful = sum(c["useful"] for c in
+                 served["spans"].named("serve.engine.decode_dispatch"))
+    assert useful == served["delta"]["decode_useful_tokens"]
+    assert useful + sum(rode) == sum(m - 1 for _, m, _ in ASKS)
+
+
+def test_the_reader_gives_the_riders_share_and_none_without_them(
+        served, monkeypatch):
+    """benchmark/layer_metrics/decode_rider_share_pct.py on this run's spans,
+    and on the same run as a program that has no riders records it."""
+    from benchmark import program_trace
+    from benchmark.run import HERE, load_reader
+    read = load_reader(HERE, "layer_metrics", "decode_rider_share_pct")
+    spans = [program_trace.Span(name, i, i + 1, args)
+             for i, (name, args) in enumerate(served["spans"].seen)]
+    t = program_trace.ProgramTrace(spans, [], [])
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    d = served["delta"]
+    assert read({}) == pytest.approx(100.0 * d["rider_tokens"] / (
+        d["rider_tokens"] + d["decode_useful_tokens"]))
+    older = program_trace.ProgramTrace(
+        [program_trace.Span(s.name, s.start, s.end, {
+            k: v for k, v in s.args.items() if k != "riders"})
+         for s in spans], [], [])
+    monkeypatch.setattr(program_trace, "load", lambda run: older)
+    assert read({}) is None
+    monkeypatch.setattr(program_trace, "load", lambda run: None)
+    assert read({}) is None
+
+
+def test_the_manifest_entry_of_the_riders_share():
+    from benchmark.tests.test_benchmark import load
+    from test_engine_trace import ROOT
+    last = load(ROOT, "BENCHMARK.json")["per_layer"][-1]
+    assert last == dict(
+        name="decode_rider_share_pct", unit="%", better="higher",
+        source="program_counter", layer="scheduler (serve)",
+        moves="batch_tokens_per_s",
+        workloads=["serve-batch", "serve-batch-olmoe"])
+
+
+@pytest.fixture(scope="module")
+def held():
+    """A dense engine of three slots whose emitter the test holds at its first
+    chunk, so that the loop stands with `_DEPTH` chunks in flight and nothing
+    moves but what the test submits."""
+    _, _, _, eng = _build("dense", n_slots=3)
+    gate = threading.Event()
+    fetch = eng._fetch
+
+    def hold(out_d):
+        gate.wait(60)
+        return fetch(out_d)
+
+    eng._fetch = hold
+    yield eng, gate
+    gate.set()
+    eng.stop()
+
+
+def test_a_burst_moves_the_riders_a_step_an_admission_and_a_finished_rider_frees_its_slot_at_once(
+        held):
+    """One burst of three admissions (submitted under the loop's own lock, so
+    one round of `_admit` sees all three) beside a live request A: A rides all
+    three prefills, three steps; B, admitted first with two tokens to make,
+    rides the second prefill, finishes on it and frees its slot and pages
+    there and then, so the third of the burst is admitted into B's slot in
+    the same round, on a three-slot engine. Every stream is what the engine
+    serves one request at a time."""
+    eng, gate = held
+    asks = [(150, 40), (140, 2), (200, 6), (170, 5)]       # A, B, C, D
+    gate.set()
+    alone = [_drain(eng.submit(_tokens(n, 20 + seed), m))
+             for seed, (n, m) in enumerate(asks)]
+    _until(lambda: not eng._active.any() and eng._in_flight == 0)
+    gate.clear()
+    before = eng.counters()
+    a = eng.submit(_tokens(150, 20), 40)
+    _until(lambda: eng._in_flight == _DEPTH)    # the loop stands, A is live
+    slot_a = int(np.flatnonzero(eng._active)[0])
+    pos_a = int(eng._pos[slot_a])
+    assert pos_a == 150 + _DEPTH * CHUNK
+    # B, C, D in one round; D fits only because B's finish frees a slot.
+    with eng._cv:
+        rest = [eng.submit(_tokens(n, 21 + i), m)
+                for i, (n, m) in enumerate(asks[1:])]
+    # The emitter is handed a round's first tokens when the round is over;
+    # it stands at A's first chunk, the second queued behind it.
+    _until(lambda: eng._emit_q.qsize() == _DEPTH - 1 + 3)
+    now = eng.counters()
+    assert now["admitted"] - before["admitted"] == 4
+    assert now["decode_chunks"] - before["decode_chunks"] == _DEPTH
+    assert int(eng._pos[slot_a]) == pos_a + 3           # three riding steps
+    # A rode B's, C's and D's prefills; B rode C's; C rode D's.
+    assert now["rider_tokens"] - before["rider_tokens"] == 1 + 2 + 2
+    assert now["rider_steps"] - before["rider_steps"] == 3
+    assert eng._active.all() and eng.pool.in_use() == sum(
+        eng.pool.pages_for(n, m) for n, m in (asks[0], asks[2], asks[3]))
+    gate.set()
+    assert [_drain(a)] + [_drain(q) for q in rest] == alone
+
+
+RIDES = [   # (max_seq, n_slots, width) -> rides
+    ((4096, 16, 4096), True), ((4096, 16, 3584), True),
+    ((4096, 16, 2048), True), ((4096, 16, 1024), False),
+    ((4096, 16, 32), False), ((8192, 16, 4096), True),
+    ((8192, 16, 2048), False), ((2048, 16, 1536), True),
+    ((3000, 8, 2048), True), ((3000, 8, 1024), False),
+    ((16, 2, 16), True), ((16, 16, 16), False), ((128, 64, 64), False),
+]
+
+
+@pytest.mark.parametrize("case,rides", RIDES,
+                         ids=["-".join(map(str, c)) for c, _ in RIDES])
+def test_rung_rides_in_the_octave_under_max_seq_where_the_slots_fit(case,
+                                                                    rides):
+    assert rung_rides(*case) is rides
+
+
+@pytest.mark.parametrize("max_seq", [2048, 4096, 8192])
+def test_the_riding_rungs_are_the_top_octaves(max_seq):
+    ladder = prefill_widths(max_seq)
+    riding = [w for w in ladder if rung_rides(max_seq, 16, w)]
+    assert riding == [w for w in ladder if w >= max_seq // 2]
+    assert len(riding) == (3 if max_seq == 2048 else 5)
+
+
+# sha256 (first 16 hex digits) of the lowered text of every prefill program
+# of the five stacks at their adapters' rehearsal widths, `max_seq` 128 and
+# two slots, on PR 41's parent (5481b82; jax 0.9.0 on the CPU: no Mosaic
+# payload, no source locations in the text). The rungs 64 and 128 of the dense
+# and the sparse stack ride since PR 41 and have no pin; tests/test_dots.py
+# pins the decode programs.
+PARENT = {
+    "dense": {32: "c948937b09fe2fee"},
+    "sparse": {32: "7a5fc5aa7c158c94"},
+    "indexed": {32: "bfe2a2df64e53893", 64: "2b26fc68f7f5f898",
+                128: "69ca4b8800557a63"},
+    "hybrid": {32: "bf109118a1785278", 64: "b6847a6dfe909d84",
+               128: "4dd7ed9434604dd1"},
+    "latent": {32: "8da32aa0051287f3", 64: "f96e02f0c080c3fb",
+               128: "4b487bf21d58472e"},
+}
+# What the riding rungs lowered to there: another text now, on purpose.
+PARENT_RIDERLESS = {
+    "dense": {64: "d5061fe7c8b0f160", 128: "0f8a98c45565c6ea"},
+    "sparse": {64: "01d0cbc9e60958cc", 128: "6ea775ec4038bec1"},
+}
+STACKS = dict(KINDS, latent=("dots", PUBLISHED))
+
+
+@pytest.mark.parametrize("kind", sorted(PARENT))
+def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
+    """A dense and a sparse stack's programs of the octave under `max_seq`
+    take riders and hold a decode step's attention; their narrow rungs, and
+    every rung of an indexed, a hybrid and a latent stack, take nobody and
+    lower to the parent's text, letter for letter. Asked of the built
+    program; no option, field or environment variable has a say."""
+    adapter = models.adapter(STACKS[kind][0])
+    cfg = adapter.build_config(dict(adapter.REHEARSE, **STACKS[kind][1]),
+                               F32, 128)
+    takes = kind in ("dense", "sparse")
+    assert _make_prefill_core(cfg).takes_riders is takes
+    eng = Engine(adapter.init_params(cfg, 3), cfg, n_slots=2, decode_chunk=2,
+                 page_size=16)
+    try:
+        assert eng._prefill.takes_riders is takes
+        assert [w for w in eng.buckets if eng._rides(w)] == (
+            [64, 128] if takes else [])
+        texts = {w: eng.lowered_prefill_text(w) for w in eng.buckets}
+    finally:
+        eng.stop()
+    got = {w: hashlib.sha256(t.encode()).hexdigest()[:16]
+           for w, t in texts.items()}
+    assert {w: d for w, d in got.items() if not eng._rides(w)} == PARENT[kind]
+    for w, was in PARENT_RIDERLESS.get(kind, {}).items():
+        assert eng._rides(w) and got[w] != was

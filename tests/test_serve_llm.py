@@ -421,14 +421,23 @@ def test_decode_arena_is_a_scan_carry_only_written_and_attended(
     # Inside the layer: no slab exists, and the arena (as it came in, or
     # as the write left it) is consumed by those ops alone. (What is
     # inside the kernel's own jaxpr is its business: it sees references.)
+    # Since PR 41 the write and the attention are one jit of their own
+    # (`_token_step`, which the riders in a prefill share): the layer hands
+    # the arena to it and to nothing else, and inside it the rule holds.
     body = layers.params["jaxpr"].jaxpr
-    consumers = []
-    for eqn in body.eqns:
-        shapes = [getattr(v.aval, "shape", None)
-                  for v in list(eqn.invars) + list(eqn.outvars)]
-        assert slab not in shapes, eqn
-        if arena in shapes[:len(eqn.invars)]:
-            consumers.append(eqn.primitive.name)
+
+    def touching(eqns):
+        for eqn in eqns:
+            shapes = [getattr(v.aval, "shape", None)
+                      for v in list(eqn.invars) + list(eqn.outvars)]
+            assert slab not in shapes, eqn
+            if arena in shapes[:len(eqn.invars)]:
+                yield eqn
+
+    step, = touching(body.eqns)
+    assert step.primitive.name == "jit" and step.params["name"] == "_token_step"
+    consumers = [eqn.primitive.name
+                 for eqn in touching(step.params["jaxpr"].jaxpr.eqns)]
     write = ["gather", "scatter"] * 2
     attend = ["pallas_call"] if path == "kernel" else ["gather"] * 2
     assert sorted(consumers) == sorted(write + attend)
@@ -455,7 +464,7 @@ def _prefill_adopt_and_two_chunks(cfg, params):
 
     # A copy: the engine takes its tree's q/k/v stacks over.
     eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=2,
-                 decode_chunk=4, page_size=16)
+                 decode_chunk=4, page_size=16, adopts=True)
     try:
         a = eng.submit(list(range(3, 17)), 11)     # positions 14..24
         prompt = [5] * 20

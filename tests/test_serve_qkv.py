@@ -123,7 +123,7 @@ def test_engine_on_the_fused_stack_serves_the_three_matrix_tokens(kind):
     prompts = [list(range(3, 17)), [5] * 20, [9, 8, 7]]
     # A copy: the engine takes its tree's q/k/v stacks over.
     eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=2,
-                 decode_chunk=4, page_size=16)
+                 decode_chunk=4, page_size=16, adopts=True)
     try:
         a = eng.submit(prompts[0], 11)
         first, ks, vs, _, _ = jax.jit(_make_prefill_core(cfg))(

@@ -418,6 +418,90 @@ def test_jamba_programs_keep_arena_and_state_in_place_on_v5e(
 
 
 # ---------------------------------------------------------------------------
+# A riding rung's prefill (serve/engine.py::rung_rides) at the cells' sizes
+# ---------------------------------------------------------------------------
+
+RIDING_RUNGS = [("mistral-7b-v0.3-serve", 4096), ("olmoe-1b-7b-serve", 4096),
+                ("olmoe-1b-7b-serve", 2048)]
+
+
+@pytest.mark.parametrize("config,width", RIDING_RUNGS,
+                         ids=[f"{c}-{w}" for c, w in RIDING_RUNGS])
+def test_a_riding_prefill_updates_the_arena_in_place_and_fits_on_v5e(
+        topo, config, width, monkeypatch):
+    """The prefill program of a riding rung, compiled for the chip at the two
+    riding cells' sizes, beside the same width's program with nobody to take
+    (the parent's text): the arena rides the layer scan's carry through the
+    riders' page writes and the `paged_decode` kernel and still aliases the
+    donated entry buffers, nothing arena- or slab-shaped is copied, sliced
+    out or re-laid, and the step's page rows and 17 rows of logits stay
+    within 5% + 16 MiB of the riderless program's temporaries (OLMoE serves
+    within 0.9 GB of the chip's memory: PERF.md section 4)."""
+    import json
+    import re
+
+    from benchmark import models
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.serve.engine import Engine, _build_fns, rung_rides
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", config + ".json")) as f:
+        model = json.load(f)
+    eng = model["deployment"]["engine"]
+    adapter = models.adapter(model["arch"])
+    cfg = adapter.build_config(model, model["dtypes"], eng["max_seq"])
+    ns, page = eng["n_slots"], eng["page_size"]
+    maxp = eng["max_seq"] // page
+    assert rung_rides(eng["max_seq"], ns, width)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    prefill, _, _, _, empty = _build_fns(cfg, ns, eng["decode_chunk"], page,
+                                         eng["kv_pages"])
+    assert prefill.takes_riders
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: fuse_qkv(Engine._experts_in_compute_dtype(
+                adapter.init_params(cfg, 0), cfg), cfg)))
+    kc, vc = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(empty))
+    slots = sds((ns,), jnp.int32)
+    riders = (sds((ns, maxp), jnp.int32), sds((ns,), jnp.bool_),
+              sds((ns,), jnp.float32), slots, sds((ns, 2), jnp.uint32))
+
+    def compiled(*more):
+        lowered = prefill.lower(
+            params, kc, vc, sds((maxp,), jnp.int32), sds((1, width), jnp.int32),
+            1, 0.0, 0, sds((2,), jnp.uint32), None, None, None, *more)
+        return lowered.as_text(), lowered.compile()
+
+    plain_text, plain = compiled(None, None, None)
+    text, riding = compiled(slots, slots, riders)
+    assert "paged_decode" in text and "paged_decode" not in plain_text
+    hlo = riding.as_text()
+    calls = [kind.count('custom_call_target="tpu_custom_call"')
+             for kind in (plain.as_text(), hlo)]
+    assert calls[1] == calls[0] + 1, calls
+    # Nothing whose result is arena- or slab-shaped is a copy, a slice or an
+    # update-slice, bare or fused by name (see the decode program's test).
+    arena, slab = tuple(kc.shape), tuple(kc.shape[1:])
+    on_arena = [(name, op) for name, dims, op in re.findall(
+        r"%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", hlo)
+        if tuple(int(d) for d in dims.split(",")) in (arena, slab)]
+    assert "scatter" in {op for _, op in on_arena}  # the pattern still reads
+    moved = [name for name, op in on_arena
+             if op == "copy" or "dynamic-" in op + name]
+    assert not moved, moved
+    mem, was = riding.memory_analysis(), plain.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in (kc, vc))
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes <= 1.05 * was.temp_size_in_bytes + (16 << 20)
+
+
+# ---------------------------------------------------------------------------
 # Chip pinning env (no compiler needed)
 # ---------------------------------------------------------------------------
 

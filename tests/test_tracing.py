@@ -123,8 +123,15 @@ def test_engine_spans_land_in_the_profile_and_agree_with_counters(
                 prof.events("serve.engine.decode_dispatch")]
     streamed_after_first = sum(len(c) for got in chunks for c in got[1:])
     assert streamed_after_first == sum(m - 1 for _, m in asks)
-    assert sum(c["useful"] for c in chunks_d) == streamed_after_first \
-        == delta["decode_useful_tokens"]
+    # A token after a request's first is a decode chunk's or, since PR 41, a
+    # riding step's inside a later admission's prefill (the 64 rung of this
+    # `max_seq` of 128 rides: `engine.rung_rides`), counted on its admit span.
+    rode = sum(a.get("riders", 0) for a in admits)
+    assert [("riders" in a) for a in admits] == [
+        a["bucket"] == 64 for a in admits]
+    assert rode == delta["rider_tokens"] >= delta["rider_steps"]
+    assert sum(c["useful"] for c in chunks_d) \
+        == delta["decode_useful_tokens"] == streamed_after_first - rode
     assert len(chunks_d) == delta["decode_chunks"]
     assert all(c["capacity"] == 16 and 1 <= c["active"] <= 3
                and c["useful"] <= c["active"] * 4 for c in chunks_d)
