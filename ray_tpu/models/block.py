@@ -13,6 +13,12 @@ engine's prefill and its decode step (`serve/engine.py`) all call them.
                      up-projection absorbed into q (a decode step, whose
                      attention then reads the cached rows themselves;
                      `latent_attention_output` is the value's half)
+  mixed_attention_inputs
+                     its peer for a stack of window and full attention layers
+                     (`cfg.attn_pattern`): attn_norm -> q, k, v at the widths
+                     of the layer's KIND, each q and k head in two parts, the
+                     one RoPE turns (at the kind's theta) and the one it
+                     passes
   feed_forward       mlp_norm -> dense SwiGLU, or router + experts (+ a
                      shared expert; the experts a share of the router's)
   mamba_mixer        a state-space layer's whole mixer, over a sequence or
@@ -185,6 +191,69 @@ def _up_projections(lp, cfg):
     return w[..., :cfg.qk_nope_dim], w[..., cfg.qk_nope_dim:]
 
 
+def mixed_attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
+                           stack: str,
+                           rope: Callable[[jax.Array], jax.Array]) -> Tuple:
+    """The cache-free half of a layer of a mixed-attention stack
+    (`cfg.attn_pattern`), `stack` the layer's kind (`window`, or `dense` /
+    `layers`: full attention), which decides its kv heads
+    (`cfg.attention_kind`); the caller's `rope` rotates at that kind's theta.
+
+    A q or k head is `cfg.head_dim` wide, of which RoPE turns the first
+    `cfg.rotary_dim` (r) and passes the rest (n); a v head is
+    `cfg.v_head_dim`. -> (q_n, q_r, k_n, k_r, v): `[batch, heads, seq, d]`
+    for a prompt, `[slots, heads, d]` for a decode step, q_r and k_r rotated,
+    v times `cfg.value_scale`.
+    The two parts apart, because 192 = 128 + 64 is a tile and a half: the
+    prefill kernels take a key in two parts (`ops.attention.
+    mixed_flash_attention`), and the caches hold `[k_n ; k_r]`, the passed
+    part on the tile's boundary. `lp` holds the projections as published,
+    `wq`, `wk`, `wv` (a head `[r ; n]`), or as serving does, one `wqkv` whose
+    column groups are the five results (`fuse_qkv`), so that nothing is cut
+    inside a program."""
+    lead = x.shape[:-1]
+    H, dk, dv, dr = cfg.n_heads, cfg.head_dim, cfg.v_head_dim, cfg.rotary_dim
+    KVH = cfg.attention_kind(stack)[0]
+    dt = cfg.dtype
+
+    def heads(t, n):
+        t = t.reshape(*lead, n, -1)
+        return t.transpose(0, 2, 1, 3) if len(lead) == 2 else t
+
+    with jax.named_scope("attn_norm"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        if "wqkv" in lp:
+            q_n, k_n, v, q_r, k_r = jnp.split(
+                h @ lp["wqkv"].astype(dt), _mixed_ends(cfg, KVH), axis=-1)
+            q_n, q_r, k_n, k_r = (heads(q_n, H), heads(q_r, H),
+                                  heads(k_n, KVH), heads(k_r, KVH))
+        else:
+            q = heads(h @ lp["wq"].astype(dt), H)
+            k = heads(h @ lp["wk"].astype(dt), KVH)
+            v = h @ lp["wv"].astype(dt)
+            q_r, q_n, k_r, k_n = (q[..., :dr], q[..., dr:], k[..., :dr],
+                                  k[..., dr:])
+        # The values are scaled, which is the attention's output scaled, at
+        # KVH heads a position and not H: what a cache keeps is this v.
+        v = heads(v, KVH) * jnp.asarray(cfg.value_scale, v.dtype)
+    with jax.named_scope("rope"):
+        q_r, k_r = rope(q_r), rope(k_r)
+    return q_n, q_r, k_n, k_r, v
+
+
+def _mixed_ends(cfg, n_kv: int) -> List[int]:
+    """Where each column group of a mixed-attention layer's fused projection
+    but the last ends: q_n, k_n, v, q_r, k_r."""
+    H, dr, dv = cfg.n_heads, cfg.rotary_dim, cfg.v_head_dim
+    dn = cfg.head_dim - dr
+    ends, at = [], 0
+    for width in (H * dn, n_kv * dn, n_kv * dv, H * dr):
+        at += width
+        ends.append(at)
+    return ends
+
+
 def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
                  live: Optional[jax.Array] = None,
                  layer: Optional[jax.Array] = None
@@ -204,7 +273,7 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
     with jax.named_scope("mlp"):
         if cfg.n_experts > 0 and "router" in lp:
             more = {}
-            if cfg.latent:
+            if cfg.latent or cfg.mixed:
                 routing = cfg.routing()
                 if routing is not None:
                     routing = dict(routing, bias=lp["router_bias"])
@@ -306,6 +375,55 @@ def _qkv_ends(cfg) -> List[int]:
 
 
 _LATENT_STACKS = ("layers", "dense")
+_MIXED_STACKS = ("dense", "window", "layers")
+
+
+def _fuse_mixed(params, cfg):
+    """A mixed-attention model as the serving programs take it: each stack's
+    `wq`, `wk`, `wv` joined into ONE `wqkv` whose column groups are what
+    `mixed_attention_inputs` hands on: every head's passed part of q, of k,
+    v, then every head's rotary part of q and of k (`_mixed_ends`; at the
+    published widths every group ends on a multiple of 128 columns). A head's
+    columns are cut at `rotary_dim` here, once; cut inside a program, at 64
+    of a head's 192 columns, it is a copy of q and of k every layer."""
+    H, dr = cfg.n_heads, cfg.rotary_dim
+    out = dict(params)
+    for name in _MIXED_STACKS:
+        if name not in params:
+            continue
+        layers = dict(params[name])
+        KVH = cfg.attention_kind(name)[0]
+        wq, wk, wv = (layers.pop(k) for k in _QKV)
+        L, D, _ = wq.shape
+        wq, wk = wq.reshape(L, D, H, -1), wk.reshape(L, D, KVH, -1)
+        layers["wqkv"] = jnp.concatenate(
+            [wq[..., dr:].reshape(L, D, -1), wk[..., dr:].reshape(L, D, -1),
+             wv, wq[..., :dr].reshape(L, D, -1),
+             wk[..., :dr].reshape(L, D, -1)], axis=-1)
+        out[name] = layers
+    return out
+
+
+def _split_mixed(params, cfg):
+    H = cfg.n_heads
+    out = dict(params)
+    for name in _MIXED_STACKS:
+        if name not in params:
+            continue
+        layers = dict(params[name])
+        KVH = cfg.attention_kind(name)[0]
+        w = layers.pop("wqkv")
+        L, D, _ = w.shape
+        q_n, k_n, wv, q_r, k_r = jnp.split(w, _mixed_ends(cfg, KVH), axis=-1)
+
+        def join(r, n, heads):
+            return jnp.concatenate(
+                [r.reshape(L, D, heads, -1), n.reshape(L, D, heads, -1)],
+                axis=-1).reshape(L, D, -1)
+
+        out[name] = dict(layers, wq=join(q_r, q_n, H), wk=join(k_r, k_n, KVH),
+                         wv=wv)
+    return out
 
 
 def _fuse_latent(params, cfg):
@@ -367,9 +485,12 @@ def fuse_qkv(params: Dict[str, Any], cfg=None) -> Dict[str, Any]:
     (`attention_inputs`): its gradient, optimizer state, checkpoints and `tp`
     sharding are by matrix. The result holds no reference to the stacks it
     joined. A latent-attention model (`cfg` says which) has no q, k and v
-    matrices to join: its serving layout is `_fuse_latent`'s."""
+    matrices to join: its serving layout is `_fuse_latent`'s; a
+    mixed-attention model's stacks by kind are joined by `_fuse_mixed`."""
     if cfg is not None and cfg.latent:
         return _fuse_latent(params, cfg)
+    if cfg is not None and cfg.mixed:
+        return _fuse_mixed(params, cfg)
     layers = dict(params["layers"])
     names = _QKV + tuple(n for n in _INDEX if n in layers)
     layers["wqkv"] = jnp.concatenate([layers.pop(k) for k in names], axis=-1)
@@ -383,6 +504,8 @@ def split_qkv(params: Dict[str, Any], cfg) -> Dict[str, Any]:
     projections longer than its caller does."""
     if cfg.latent:
         return _split_latent(params)
+    if cfg.mixed:
+        return _split_mixed(params, cfg)
     layers = dict(params["layers"])
     parts = jnp.split(layers.pop("wqkv"), _qkv_ends(cfg), axis=-1)
     return dict(params, layers=dict(layers, **dict(zip(_fused_names(cfg),

@@ -122,6 +122,28 @@ class LlamaConfig:
     # deployment, and computes their part of every layer's mixture
     # (`ops.moe.moe_ffn`); None: every expert.
     experts_held: Optional[Tuple[int, int]] = None
+    # Two kinds of ATTENTION in one stack (the MiMo-V2 family): attn_pattern
+    # says of each layer whether it attends to every earlier position (0) or
+    # to the last `window` of them, itself included (1). Both kinds have
+    # n_heads query heads, keys of head_dim and values of v_head_dim; a
+    # window layer has window_kv_heads kv heads (a full one n_kv_heads), its
+    # own window_rope_theta and, with `window_sink`, a learned logit a head
+    # that joins every query's softmax and carries no value (`sink`, a leaf).
+    # RoPE turns the first rotary_dim numbers of a q or k head and passes the
+    # rest; the values are multiplied by value_scale. The
+    # parameters are stacks by kind, none holding a weight of a kind it is
+    # not: `dense` (the first_dense leading layers, full attention), `window`
+    # (the window layers) and `layers` (the other full ones); `segments()` has
+    # the order they run in. A serving cache of two shapes: pages for the full
+    # layers, a ring of `window` positions a slot for the window layers
+    # (`ops/paged_kv.py`).
+    attn_pattern: Optional[Tuple[int, ...]] = None
+    window: int = 0
+    window_kv_heads: int = 0
+    window_rope_theta: float = 10000.0
+    window_sink: bool = False
+    rotary_dim: int = 0
+    value_scale: float = 1.0
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -145,23 +167,76 @@ class LlamaConfig:
             raise ValueError("latent attention (kv_lora_rank > 0) comes with "
                              "no state-space layers, indexer, q/k norm, "
                              "mrope or tied head")
-        if self.first_dense and not (self.latent and self.n_experts
+        if self.attn_pattern is not None:
+            object.__setattr__(self, "attn_pattern",
+                               tuple(int(k) for k in self.attn_pattern))
+            self._check_mixed()
+        segmented = self.latent or self.mixed
+        if self.first_dense and not (segmented and self.n_experts
                                      and self.first_dense < self.n_layers):
             raise ValueError("first_dense: leading dense layers under a "
-                             "sparse latent-attention stack")
-        if (self.experts_held or self.n_shared_experts
-                or self.router_score != "softmax") and not self.latent:
-            raise ValueError("a share of the experts, shared experts and the "
-                             "sigmoid router are served by the latent-"
+                             "sparse latent-attention or mixed-attention "
+                             "stack")
+        if (self.experts_held or self.router_score != "softmax") \
+                and not segmented:
+            raise ValueError("a share of the experts and the sigmoid router "
+                             "are served by the stacks that run as segments: "
+                             "latent attention (kv_lora_rank > 0) and mixed "
+                             "attention (attn_pattern)")
+        if self.n_shared_experts and not self.latent:
+            raise ValueError("shared experts are served by the latent-"
                              "attention stack alone (kv_lora_rank > 0)")
         if bool(self.ssm_state) != (self.attn_layers is not None):
             raise ValueError("ssm_state and attn_layers come together: the "
                              "state-space widths and which layers are not "
                              "state-space layers")
 
+    def _check_mixed(self) -> None:
+        if self.latent or self.ssm_state or self.index_topk or self.qk_norm \
+                or self.mrope_section or self.tie_embeddings \
+                or self.rope_yarn or not self.rope:
+            raise ValueError("mixed attention (attn_pattern) comes with no "
+                             "latent attention, state-space layers, indexer, "
+                             "q/k norm, mrope, YaRN or tied head")
+        pattern = self.attn_pattern
+        if len(pattern) != self.n_layers or set(pattern) - {0, 1}:
+            raise ValueError("attn_pattern: one of 0 (full) or 1 (window) a "
+                             "layer")
+        if any(pattern[:self.first_dense]):
+            raise ValueError("attn_pattern: the first_dense leading layers "
+                             "are full-attention layers (the stack `dense`)")
+        if 1 in pattern and not (self.window > 0
+                                 and self.window_kv_heads > 0):
+            raise ValueError("attn_pattern has window layers: window and "
+                             "window_kv_heads say their size")
+        if not self.v_head_dim:
+            object.__setattr__(self, "v_head_dim", self.head_dim)
+        if self.rotary_dim % 2 or not 0 < self.rotary_dim < self.head_dim:
+            raise ValueError("rotary_dim: mixed attention rotates an even "
+                             "part of a head, 0 < rotary_dim < head_dim (the "
+                             "kernels and the caches take a key in two "
+                             "parts)")
+
+    @property
+    def mixed(self) -> bool:
+        """Window and full attention in one stack (`attn_pattern`)."""
+        return self.attn_pattern is not None
+
+    def attention_kind(self, stack: str) -> Tuple[int, float, int, bool]:
+        """(kv heads, rope theta, window, sink) of the layers of one stack of
+        a mixed-attention model: `window`, or a full-attention one (`dense`,
+        `layers`), whose window is 0."""
+        if stack == "window":
+            return (self.window_kv_heads, self.window_rope_theta, self.window,
+                    self.window_sink)
+        return self.n_kv_heads, self.rope_theta, 0, False
+
     @property
     def kv_layers(self) -> int:
-        """Layers that keep K and V: the attention layers."""
+        """Layers that keep K and V under the block table: the attention
+        layers, of a mixed-attention stack the full-attention ones."""
+        if self.mixed:
+            return self.attn_pattern.count(0)
         return self.n_layers if self.attn_layers is None \
             else len(self.attn_layers)
 
@@ -211,7 +286,20 @@ class LlamaConfig:
         in `mamba`, or ("attn", a, a + 1), one attention layer in `layers`.
         A latent-attention stack, each kind the name of its stack:
         ("dense", 0, first_dense), the leading dense layers, if it has any,
-        then ("layers", 0, n_layers - first_dense), the rest."""
+        then ("layers", 0, n_layers - first_dense), the rest. A
+        mixed-attention stack likewise, its kinds `dense`, `window` and
+        `layers`: each run of layers of one stack a segment."""
+        if self.mixed:
+            out, at = [], {"dense": 0, "window": 0, "layers": 0}
+            for i, kind in enumerate(self.attn_pattern):
+                name = "dense" if i < self.first_dense \
+                    else "window" if kind else "layers"
+                if out and out[-1][0] == name:
+                    out[-1] = (name, out[-1][1], at[name] + 1)
+                else:
+                    out.append((name, at[name], at[name] + 1))
+                at[name] += 1
+            return tuple(out)
         if self.latent:
             lead = (("dense", 0, self.first_dense),) if self.first_dense \
                 else ()
@@ -287,9 +375,85 @@ def _latent_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     return out
 
 
+def _mixed_stacks(cfg: LlamaConfig) -> Dict[str, Tuple[int, bool]]:
+    """name -> (layers, sparse) of each stack a mixed-attention model has."""
+    sparse = cfg.attn_pattern[cfg.first_dense:]
+    out = {"dense": (cfg.first_dense, False),
+           "window": (sparse.count(1), cfg.n_experts > 0),
+           "layers": (sparse.count(0), cfg.n_experts > 0)}
+    return {k: v for k, v in out.items() if v[0]}
+
+
+def _mixed_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    out = {"embed": ("vocab", "embed"), "final_norm": ("embed",),
+           "lm_head": ("embed", "vocab")}
+    for name, (_, sparse) in _mixed_stacks(cfg).items():
+        lead = ("layers", "expert") if sparse else ("layers",)
+        stack = {"attn_norm": ("layers", "embed"),
+                 "wq": ("layers", "embed", "heads"),
+                 "wk": ("layers", "embed", "kv_heads"),
+                 "wv": ("layers", "embed", "kv_heads"),
+                 "wo": ("layers", "heads", "embed"),
+                 "mlp_norm": ("layers", "embed"),
+                 "w_gate": (*lead, "embed", "mlp"),
+                 "w_up": (*lead, "embed", "mlp"),
+                 "w_down": (*lead, "mlp", "embed")}
+        if cfg.attention_kind(name)[3]:
+            stack["sink"] = ("layers", "heads")
+        if sparse:
+            stack["router"] = ("layers", "embed", "expert")
+            if cfg.router_score == "sigmoid":
+                stack["router_bias"] = ("layers", "expert")
+        out[name] = stack
+    return out
+
+
+def _init_mixed(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
+    """A mixed-attention model: a stack a kind (`_mixed_stacks`), each with
+    its own kind's projections (a window layer's k and v have
+    window_kv_heads heads, and it alone has a `sink`), the experts' stacks
+    holding the experts HELD. Keys from lists of this function's own."""
+    D, H, V, pd = cfg.d_model, cfg.n_heads, cfg.vocab_size, cfg.param_dtype
+    dk, dv = cfg.head_dim, cfg.v_head_dim
+
+    def norm(shape, k, scale=0.02):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(pd)
+
+    top = iter(jax.random.split(key, 5))
+    out = {"embed": norm((V, D), next(top)),
+           "final_norm": jnp.ones((D,), pd),
+           "lm_head": norm((D, V), next(top))}
+    for name, (L, sparse) in _mixed_stacks(cfg).items():
+        ks = iter(jax.random.split(next(top), 16))
+        KVH, _, _, sink = cfg.attention_kind(name)
+        stack = {"attn_norm": jnp.ones((L, D), pd),
+                 "wq": norm((L, D, H * dk), next(ks)),
+                 "wk": norm((L, D, KVH * dk), next(ks)),
+                 "wv": norm((L, D, KVH * dv), next(ks)),
+                 "wo": norm((L, H * dv, D), next(ks)),
+                 "mlp_norm": jnp.ones((L, D), pd)}
+        if sink:
+            # Published as a learned logit a head; drawn so that it takes a
+            # share of the softmax that a wrong or missing sink would move.
+            stack["sink"] = norm((L, H), next(ks), 1.0)
+        lead, F = ((L, cfg.n_held), cfg.d_ff) if sparse \
+            else ((L,), cfg.d_ff_dense or cfg.d_ff)
+        stack.update(w_gate=norm((*lead, D, F), next(ks)),
+                     w_up=norm((*lead, D, F), next(ks)),
+                     w_down=norm((*lead, F, D), next(ks)))
+        if sparse:
+            stack["router"] = norm((L, D, cfg.n_experts), next(ks))
+            if cfg.router_score == "sigmoid":
+                stack["router_bias"] = norm((L, cfg.n_experts), next(ks))
+        out[name] = stack
+    return out
+
+
 def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     if cfg.latent:
         return _latent_axes(cfg)
+    if cfg.mixed:
+        return _mixed_axes(cfg)
     layers: Dict[str, Tuple] = {
         "attn_norm": ("layers", "embed"),
         "wq": ("layers", "embed", "heads"),
@@ -413,6 +577,8 @@ def _init_latent(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
 def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     if cfg.latent:
         return _init_latent(cfg, key)
+    if cfg.mixed:
+        return _init_mixed(cfg, key)
     L, D, H, KVH = cfg.kv_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads
     hd, F, V = cfg.head_dim, cfg.d_ff, cfg.vocab_size
     pd = cfg.param_dtype
@@ -670,6 +836,13 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
             "training forward has no stack of dense-then-sparse segments, "
             "and a share of the experts (experts_held) takes no gradient "
             "for the experts that are absent (ROADMAP, Reach)")
+    if cfg.mixed:
+        raise NotImplementedError(
+            "mixed attention (attn_pattern: window beside full attention) "
+            "runs through Serve only: the training forward has no stack of "
+            "segments by kind, the window and the sink have no backward "
+            "kernel, and a share of the experts (experts_held) takes no "
+            "gradient for the experts that are absent (ROADMAP, Reach)")
     dt = cfg.dtype
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dt)

@@ -42,7 +42,8 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # paged_kv.paged_decode_attention call took, counted at TRACE time
 # ("fwd_pallas", "fwd_reference", "bwd_pallas", "bwd_reference",
 # "decode_pallas", "decode_reference"; latent attention's "latent_fwd_*" and
-# "latent_decode_*"): the dispatch is otherwise invisible
+# "latent_decode_*"; a mixed stack's "window_fwd_*", "full_fwd_*" and
+# "window_decode_reference"): the dispatch is otherwise invisible
 # from outside a jitted program, and a benchmark must be able to assert that
 # the kernel it names is the one that ran.
 _path_counts: collections.Counter = collections.Counter()
@@ -290,9 +291,15 @@ def _latent_flash_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
 
 
 def _latent_flash_pallas(q_n, q_r, k_n, k_r, v, *, sm_scale, interpret,
-                         block_q=1024, block_k=1024):
+                         block_q=1024, block_k=1024,
+                         name="latent_flash_fwd"):
+    """k_n and v `[b, KVH, s, d]`: query head h reads kv head h // (H // KVH)
+    where it lies (no copy a query head). k_r `[b, s, dr]`, one for all
+    heads, or `[b, KVH, s, dr]`, one a kv head."""
     b, h, s, dn = q_n.shape
     dr, dv = q_r.shape[-1], v.shape[-1]
+    kvh = k_n.shape[1]
+    groups = h // kvh
     block_q = _pick_block(s, block_q)
     block_k = _pick_block(s, block_k)
     kernel = functools.partial(
@@ -302,17 +309,20 @@ def _latent_flash_pallas(q_n, q_r, k_n, k_r, v, *, sm_scale, interpret,
     def by_q(d):
         return pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
 
-    def by_k(d):
-        return pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0))
+    def by_k(d, heads=kvh):
+        # kv head bh // groups of `heads`; 1 of them: batch row bh // h
+        over = h // heads
+        return pl.BlockSpec((1, block_k, d), (
+            lambda bh, qi, ki: (bh, ki, 0)) if over == 1 else (
+            lambda bh, qi, ki: (bh // over, ki, 0)))
 
     out = pl.pallas_call(
         kernel,
-        name="latent_flash_fwd",
+        name=name,
         grid=(b * h, s // block_q, s // block_k),
         in_specs=[by_q(dn), by_q(dr), by_k(dn),
-                  # the shared rotary key: head bh of batch row bh // h
-                  pl.BlockSpec((1, block_k, dr),
-                               lambda bh, qi, ki: (bh // h, ki, 0)),
+                  # the rotary key: one for all heads, or one a kv head
+                  by_k(dr, 1 if k_r.ndim == 3 else kvh),
                   by_k(dv)],
         out_specs=by_q(dv),
         out_shape=_out_struct((b * h, s, dv), v.dtype, v),
@@ -325,7 +335,8 @@ def _latent_flash_pallas(q_n, q_r, k_n, k_r, v, *, sm_scale, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q_n.reshape(b * h, s, dn), q_r.reshape(b * h, s, dr),
-      k_n.reshape(b * h, s, dn), k_r, v.reshape(b * h, s, dv))
+      k_n.reshape(b * kvh, s, dn), k_r.reshape(-1, s, dr),
+      v.reshape(b * kvh, s, dv))
     return out.reshape(b, h, s, dv)
 
 
@@ -360,6 +371,171 @@ def latent_flash_attention(q_n, q_r, k_n, k_r, v, sm_scale: float, *,
                        DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+# ---------------------------------------------------------------------------
+# A prompt's attention in a mixed stack: window layers with a sink beside
+# full layers, keys in two parts (rotary and passed), values narrower
+# ---------------------------------------------------------------------------
+
+def _window_flash_kernel(qn_ref, qr_ref, pkn_ref, pkr_ref, pv_ref, kn_ref,
+                         kr_ref, v_ref, sink_ref, o_ref, *, sm_scale: float,
+                         window: int, block: int):
+    """One grid step = one block of `block` queries of ALL the query heads
+    of one kv head (`[g, block, d]`, run as `g * block` rows) against the
+    only keys its window touches: the block on the diagonal (`kn`, `kr`,
+    `v`) and the one before it (`pkn`, `pkr`, `pv`; `window <= block`). No
+    loop over key blocks and no running statistics: one softmax over the 2 x
+    `block` columns and the sink's, whose probability is dropped."""
+    qi = pl.program_id(1)
+    g, _, dn = qn_ref.shape[1:]
+    rows = g * block
+    qn = qn_ref[0].reshape(rows, dn)
+    qr = qr_ref[0].reshape(rows, qr_ref.shape[-1])
+    contract = (((1,), (1,)), ((), ()))
+
+    def scores(kn, kr):
+        return (jax.lax.dot_general(qn, kn[0], contract,
+                                    preferred_element_type=jnp.float32)
+                + jax.lax.dot_general(qr, kr[0], contract,
+                                      preferred_element_type=jnp.float32)
+                ) * sm_scale
+    # a row's query is `r` rows into the block, a column's key `c`
+    r = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0),
+                    block)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+    s_cur = jnp.where((c <= r) & (r - c < window), scores(kn_ref, kr_ref),
+                      DEFAULT_MASK_VALUE)
+    # the block before: `block` positions further back; none before block 0
+    s_prev = jnp.where((r + block - c < window) & (qi > 0),
+                       scores(pkn_ref, pkr_ref), DEFAULT_MASK_VALUE)
+    sink = sink_ref[0]                                       # [rows, 1]
+    m = jnp.maximum(jnp.maximum(jnp.max(s_cur, axis=-1, keepdims=True),
+                                jnp.max(s_prev, axis=-1, keepdims=True)),
+                    sink)
+    p_cur, p_prev = jnp.exp(s_cur - m), jnp.exp(s_prev - m)
+    l = (jnp.sum(p_cur, axis=-1, keepdims=True)
+         + jnp.sum(p_prev, axis=-1, keepdims=True) + jnp.exp(sink - m))
+    pv = (((1,), (0,)), ((), ()))
+    o = (jax.lax.dot_general(p_cur.astype(v_ref.dtype), v_ref[0], pv,
+                             preferred_element_type=jnp.float32)
+         + jax.lax.dot_general(p_prev.astype(pv_ref.dtype), pv_ref[0], pv,
+                               preferred_element_type=jnp.float32))
+    o_ref[0] = (o / l).astype(o_ref.dtype).reshape(o_ref.shape[1:])
+
+
+def _window_flash_pallas(q_n, q_r, k_n, k_r, v, sink, *, sm_scale, window,
+                         interpret):
+    b, h, s, dn = q_n.shape
+    dr, dv = q_r.shape[-1], v.shape[-1]
+    kvh = k_n.shape[1]
+    g = h // kvh
+    block = -(-window // 128) * 128
+    kernel = functools.partial(_window_flash_kernel, sm_scale=sm_scale,
+                               window=window, block=block)
+
+    def by_q(d):
+        return pl.BlockSpec((1, g, block, d), lambda bk, qi: (bk, 0, qi, 0))
+
+    def by_k(d, back):
+        return pl.BlockSpec(
+            (1, block, d), lambda bk, qi: (bk, jnp.maximum(qi - back, 0), 0))
+
+    # the sink's logit a row of a grid step: head `row // block` of the group
+    sink_rows = jnp.repeat(sink.astype(jnp.float32).reshape(kvh, g), block,
+                           axis=1)[:, :, None]
+    kv = [k_n.reshape(b * kvh, s, dn), k_r.reshape(b * kvh, s, dr),
+          v.reshape(b * kvh, s, dv)]
+    out = pl.pallas_call(
+        kernel,
+        name="window_flash_fwd",
+        grid=(b * kvh, s // block),
+        in_specs=[by_q(dn), by_q(dr),
+                  by_k(dn, 1), by_k(dr, 1), by_k(dv, 1),
+                  by_k(dn, 0), by_k(dr, 0), by_k(dv, 0),
+                  pl.BlockSpec((1, g * block, 1),
+                               lambda bk, qi: (bk % kvh, 0, 0))],
+        out_specs=by_q(dv),
+        out_shape=_out_struct((b * kvh, g, s, dv), v.dtype, v),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(q_n.reshape(b * kvh, g, s, dn), q_r.reshape(b * kvh, g, s, dr),
+      *kv, *kv, sink_rows)
+    return out.reshape(b, h, s, dv)
+
+
+def mixed_attention_reference(q_n, q_r, k_n, k_r, v, sm_scale, window=0,
+                              sink=None):
+    """The XLA path of `mixed_flash_attention`: every score, a mask, a
+    float32 softmax with the sink's column."""
+    b, h, s, _ = q_n.shape
+    groups = h // k_n.shape[1]
+    k_n, k_r, v = (repeat_kv(t, groups) for t in (k_n, k_r, v))
+    scores = (jnp.einsum("bhqd,bhkd->bhqk", q_n, k_n,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhqd,bhkd->bhqk", q_r, k_r,
+                           preferred_element_type=jnp.float32)) * sm_scale
+    pos = jnp.arange(s)
+    live = pos[:, None] >= pos[None, :]
+    if window:
+        live &= pos[:, None] - pos[None, :] < window
+    scores = jnp.where(live, scores, DEFAULT_MASK_VALUE)
+    if sink is None:
+        p = jax.nn.softmax(scores, axis=-1)
+    else:
+        col = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, None, None],
+                               (b, h, s, 1))
+        p = jax.nn.softmax(jnp.concatenate([scores, col], -1), -1)[..., :-1]
+    return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+def mixed_flash_attention(q_n, q_r, k_n, k_r, v, sm_scale: float, *,
+                          window: int = 0, sink=None,
+                          interpret: bool = False):
+    """Causal attention of a prompt in a stack of window and full attention
+    layers (`LlamaConfig.attn_pattern`). Query head h is `[q_n[h] ; q_r[h]]`
+    (the part RoPE passed, the part it turned: 128 and 64 at MiMo-V2's
+    widths), its key `[k_n ; k_r]` of kv head `h // (H // KVH)`, its value
+    that head's `v`, of a width of its own (128). q_n `[b, H, s, dn]`, q_r
+    `[b, H, s, dr]`, k_n `[b, KVH, s, dn]`, k_r `[b, KVH, s, dr]`, v `[b,
+    KVH, s, dv]` -> `[b, H, s, dv]`. K and V are read by kv head, never
+    repeated for the query heads. No gradient: serving's.
+
+    `window` > 0: query i attends to keys j with `0 <= i - j < window`, and
+    with `sink` `[H]` one further column of that logit a head joins the
+    softmax and carries no value. On a TPU (or with `interpret`) the Pallas
+    kernel `window_flash_fwd`, which visits ONLY the key blocks a query
+    block's window touches, the diagonal one and the one before it: its work
+    grows with `s`, not `s^2`. Counted as `window_fwd_pallas` /
+    `window_fwd_reference`.
+
+    `window` 0: every earlier position; the kernel `full_flash_fwd`
+    (`_latent_flash_kernel`'s online softmax over key blocks, the key in two
+    parts), counted as `full_fwd_pallas` / `full_fwd_reference`.
+
+    Elsewhere, or where a shape is not a kernel's (`s` no multiple of the
+    block, a part of the head no multiple of its tile), the XLA reference
+    `mixed_attention_reference`."""
+    s, dn, dv = q_n.shape[2], q_n.shape[-1], v.shape[-1]
+    kind = "window" if window else "full"
+    block = -(-window // 128) * 128 if window else 128
+    use = interpret or _on_tpu()
+    use = use and s % block == 0 and dn % 128 == 0 and dv % 128 == 0
+    _path_counts[f"{kind}_fwd_pallas" if use else f"{kind}_fwd_reference"] += 1
+    if not use:
+        return mixed_attention_reference(q_n, q_r, k_n, k_r, v, sm_scale,
+                                         window, sink)
+    if window:
+        if sink is None:
+            sink = jnp.full((q_n.shape[1],), DEFAULT_MASK_VALUE, jnp.float32)
+        return _window_flash_pallas(q_n, q_r, k_n, k_r, v, sink,
+                                    sm_scale=sm_scale, window=window,
+                                    interpret=interpret)
+    if sink is not None:
+        raise NotImplementedError("a sink on a full-attention layer")
+    return _latent_flash_pallas(q_n, q_r, k_n, k_r, v, sm_scale=sm_scale,
+                                interpret=interpret, name="full_flash_fwd")
 
 
 # ---------------------------------------------------------------------------

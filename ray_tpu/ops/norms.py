@@ -83,6 +83,33 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     return out.astype(x.dtype)
 
 
+def apply_rope_narrow(x: jax.Array, cos: jax.Array, sin: jax.Array
+                      ) -> jax.Array:
+    """`apply_rope` at positions 0..s-1 for a rotary part NARROWER than a
+    tile's 128 lanes (x `[b, h, s, d]`, d = 64 at MiMo-V2's widths; cos/sin
+    `[s, d//2]`): `apply_rope`'s own products and sums and no other rounding,
+    so to the bit its result op by op, and under jit in bfloat16
+    (tests/test_mimo.py holds both). Cutting a 64-wide minor
+    axis into halves of 32 and joining them again re-lays every vector (1.4
+    ms a layer for q and k of a prompt of 8,192 on a v5e; PERF.md, PR 42;
+    PR 39 read 4.6 ms a prefill the same way), so the halves are swapped,
+    with the sign, by one small matmul against a signed permutation instead
+    (each output is ONE input times +-1: exact in any dtype), and the tables
+    are laid side by side: out = x * [cos ; cos] + (x P) * [sin ; sin]."""
+    d = x.shape[-1]
+    half = d // 2
+    i = jnp.arange(d)
+    # (x P)[j] = -x[j + half] for j < half, x[j - half] for j >= half
+    swap = (jnp.where(i[:, None] == (i[None, :] + half) % d, 1.0, 0.0)
+            * jnp.where(i[None, :] < half, -1.0, 1.0)).astype(x.dtype)
+    turned = jnp.einsum("bhsd,de->bhse", x, swap,
+                        precision=jax.lax.Precision.HIGHEST)
+    c = jnp.concatenate([cos, cos], axis=-1)[None, None, :x.shape[2]]
+    si = jnp.concatenate([sin, sin], axis=-1)[None, None, :x.shape[2]]
+    out = x.astype(jnp.float32) * c + turned.astype(jnp.float32) * si
+    return out.astype(x.dtype)
+
+
 def mrope_tables(cos: jax.Array, sin: jax.Array, positions: jax.Array,
                  sections) -> tuple:
     """Multimodal RoPE (Qwen2-VL's `mrope_section`): three position streams

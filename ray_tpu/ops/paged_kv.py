@@ -44,6 +44,15 @@ data-dependent branch. Which pages a slot holds is the host's business
     ``paged_latent_decode`` reads it in place: all heads of a slot against
     each block of its live rows, counted as `latent_decode_pallas` /
     `latent_decode_reference`.
+  * A model of window and full attention layers (`LlamaConfig.attn_pattern`)
+    keeps pages for its FULL layers alone, the arena's layers being their
+    ordinals, with keys wider than values (``empty(..., v_head_dim=...)``): a
+    key of 192 numbers lies in 256 lanes (two tiles, the second half empty,
+    as the latent row's 576 lie in 640), `[k_n ; k_r ; zeros]` with the part
+    RoPE passed on the tile's boundary, a value of 128 in one: 768 B a kv
+    head a token a layer in bfloat16 for 640 of use (3,072 B a token a layer
+    at MiMo-V2's 4 kv heads, 2,560 of use). Its window layers' rows are no
+    pages at all: a ring a slot (`ops/slot_state.py`).
   * ``paged_decode_attention`` is one query token a slot against the arena:
     a Pallas TPU kernel that reads a slot's live pages where they lie (the
     XLA gather over the whole block table elsewhere), counted at trace time
@@ -72,9 +81,15 @@ from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
 # ---------------------------------------------------------------------------
 
 def empty(n_layers: int, n_pages: int, kv_heads: int, page: int,
-          head_dim: int, dtype, by_token: bool = False):
+          head_dim: int, dtype, by_token: bool = False,
+          v_head_dim: Optional[int] = None):
     """-> (kc, vc), the zeroed arena; `by_token`: for a reader that gathers
-    positions (see the top)."""
+    positions (see the top); `v_head_dim`: values of a width of their own,
+    and each width in rows of the next multiple of 128 lanes."""
+    if v_head_dim is not None:
+        return tuple(jnp.zeros((n_layers, n_pages, kv_heads, page,
+                                _lanes(d)), dtype)
+                     for d in (head_dim, v_head_dim))
     shape = (n_layers, n_pages, page, kv_heads * head_dim) if by_token \
         else (n_layers, n_pages, kv_heads, page, head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
@@ -89,12 +104,16 @@ def empty_index(n_layers: int, n_pages: int, page: int, index_dim: int,
 _LANES = 128
 
 
+def _lanes(width: int) -> int:
+    """`width` rounded up to whole tiles of 128 lanes."""
+    return -(-width // _LANES) * _LANES
+
+
 def empty_latent(n_layers: int, n_pages: int, page: int, width: int, dtype):
     """-> the zeroed arena of a latent-attention model's rows (see the top),
     all it caches: `width` numbers a position (latent + rotary key), in rows
     of the next multiple of 128 lanes."""
-    return jnp.zeros((n_layers, n_pages, page, -(-width // _LANES) * _LANES),
-                     dtype)
+    return jnp.zeros((n_layers, n_pages, page, _lanes(width)), dtype)
 
 
 def _to_width(rows, arena):
@@ -130,6 +149,10 @@ def write_prompt(kc, vc, pages, ks, vs):
     if vc is None:          # a latent arena: `ks` [L, W, rank + dr]
         return write_prompt_rows(kc, pages, _to_width(ks, kc)), None
     L, W, KVH, hd = ks.shape
+    if kc.ndim == 5 and (hd, vs.shape[-1]) != (kc.shape[-1], vc.shape[-1]):
+        # a mixed stack's: each narrower than the lanes it lies in
+        ks, vs = _to_width(ks, kc), _to_width(vs, vc)
+        hd = kc.shape[-1]
     if kc.ndim == 4:        # by token
         return (write_prompt_rows(kc, pages, ks.reshape(L, W, KVH * hd)),
                 write_prompt_rows(vc, pages, vs.reshape(L, W, KVH * hd)))
@@ -140,7 +163,7 @@ def write_prompt(kc, vc, pages, ks, vs):
         ksp = jnp.pad(ks, ((0, 0), (0, pad), (0, 0), (0, 0)))
         vsp = jnp.pad(vs, ((0, 0), (0, pad), (0, 0), (0, 0)))
         ksp = ksp.reshape(L, wp, page, KVH, hd).transpose(0, 1, 3, 2, 4)
-        vsp = vsp.reshape(L, wp, page, KVH, hd).transpose(0, 1, 3, 2, 4)
+        vsp = vsp.reshape(L, wp, page, KVH, -1).transpose(0, 1, 3, 2, 4)
         kc = kc.at[:, pages[:wp]].set(ksp)
         vc = vc.at[:, pages[:wp]].set(vsp)
     return kc, vc
@@ -178,6 +201,8 @@ def write_token(kc, vc, layer, block_table, w, active, k, v):
                 write_token_rows(vc, layer, block_table, w, active,
                                  v.reshape(ns, -1)))
     ns, page = k.shape[0], kc.shape[3]
+    if (k.shape[-1], v.shape[-1]) != (kc.shape[-1], vc.shape[-1]):
+        k, v = _to_width(k, kc), _to_width(v, vc)   # narrower than their lanes
     with jax.named_scope("kv_write"):
         idx = jnp.arange(ns)
         pp = jnp.where(active, block_table[idx, w // page], 0)
@@ -303,6 +328,7 @@ def _paged_decode_pallas(q, kc, vc, layer, block_table, lengths, *,
                          sm_scale, pages_per_block, interpret):
     ns, H, hd = q.shape
     _, _, n_kv, page, _ = kc.shape
+    hv = vc.shape[-1]       # values of a width of their own (a mixed stack)
     groups = H // n_kv
     # float32 softmax weights against a narrower cache: see `split` above.
     split = jnp.dtype(kc.dtype).itemsize < 4
@@ -322,34 +348,35 @@ def _paged_decode_pallas(q, kc, vc, layer, block_table, lengths, *,
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=sm_scale, groups=groups, split=split,
         pages_per_block=pages_per_block)
-    slot_block = pl.BlockSpec((1, n_kv, rows, hd),
-                              lambda s, *_: (s, 0, 0, 0))
+    def slot_block(d):
+        return pl.BlockSpec((1, n_kv, rows, d), lambda s, *_: (s, 0, 0, 0))
+
     out = pl.pallas_call(
         kernel,
         name="paged_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,       # layer, lengths, block table
             grid=(ns,),
-            in_specs=[slot_block,
+            in_specs=[slot_block(hd),
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=slot_block,
+            out_specs=slot_block(hv),
             scratch_shapes=[
                 pltpu.VMEM((2, n_kv, T, hd), kc.dtype),
-                pltpu.VMEM((2, n_kv, T, hd), vc.dtype),
+                pltpu.VMEM((2, n_kv, T, hv), vc.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((n_kv, rows, 1), jnp.float32),
                 pltpu.VMEM((n_kv, rows, 1), jnp.float32),
-                pltpu.VMEM((n_kv, rows, hd), jnp.float32),
+                pltpu.VMEM((n_kv, rows, hv), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((ns, n_kv, rows, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((ns, n_kv, rows, hv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
       block_table.astype(jnp.int32), qg, kc, vc)
     out = sum(out[:, :, i * groups:(i + 1) * groups] for i in range(copies))
-    return out.reshape(ns, H, hd).astype(q.dtype)
+    return out.reshape(ns, H, hv).astype(q.dtype)
 
 
 def _paged_decode_reference(q, kc, vc, layer, block_table, lengths, *,
@@ -372,7 +399,7 @@ def _paged_decode_reference(q, kc, vc, layer, block_table, lengths, *,
     # What a dead position holds is masked out of v too: 0 x NaN is NaN.
     vh = jnp.where(live.reshape(ns, ctx // page, 1, page, 1), vh, 0.0)
     out = jnp.einsum("nkgpt,npktd->nkgd", wts, vh)
-    return out.reshape(ns, H, hd).astype(q.dtype)
+    return out.reshape(ns, H, vc.shape[-1]).astype(q.dtype)
 
 
 def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
@@ -381,7 +408,8 @@ def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
                            interpret: bool = False):
     """Attention of ONE query token a slot against a paged KV cache.
 
-    q [ns, H, hd]; kc, vc the WHOLE arena [L, n_pages, KVH, page, hd] and
+    q [ns, H, hd]; kc, vc the WHOLE arena [L, n_pages, KVH, page, hd] (vc's
+    rows may be of a width of their own, which is then the result's) and
     `layer` the index into it (a traced scalar: a kernel handed `kc[layer]`
     is first given a copy of that slab); block_table [ns, max_pages] of
     physical page ids; lengths [ns], the positions each slot attends to

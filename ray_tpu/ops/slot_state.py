@@ -23,14 +23,42 @@ with N as the minor axis the first would take eight times its size.
   * Like the arena, the state rides the decode program's loop CARRY and is
     donated: `update_layer` is an in-place write of one layer's rows.
     Nothing outside this module indexes it.
+
+A WINDOW layer's cache is the other thing that has no pages (a model of
+window and full attention layers, `LlamaConfig.attn_pattern`): a query sees
+its own position and the `window - 1` before it, so a slot keeps a RING of
+the last R = `window` (rounded up to a tile's 16 rows) positions a window
+layer, whatever the prompt's length, position t in row `t % R`:
+
+  kw  [n_layers, n_slots, kv_heads, R, lanes(head_dim)]    `[k_n ; k_r ; 0]`
+  vw  [n_layers, n_slots, kv_heads, R, lanes(v_head_dim)]
+
+(`n_layers` the window layers, ordinals into the `window` stack; widths in
+whole tiles of 128 lanes as the full layers' pages are, `ops/paged_kv.py`).
+At MiMo-V2's widths, 8 kv heads of 256 + 128 lanes, a slot holds 786 KB a
+layer, at 200 positions as at 8,000.
+
+  * ``empty_window`` makes it; ``write_window_prompt`` puts the TAIL of a
+    prefill's keys and values into one slot's rings, all layers at once
+    (rows whose position the prompt never reached are zeroed: the previous
+    tenant's are gone); ``write_window_token`` is a decode step's row a slot
+    of one layer, written before ``window_decode_attention`` reads the ring:
+    the step's own position, the `window - 1` before it, and the sink.
+    A row's position is not stored: at step w, row r holds the largest
+    position <= w that is r modulo R, which is live if it is >= 0 and less
+    than `window` back.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops import attention
+from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
+from ray_tpu.ops.paged_kv import _lanes, _to_width
 
 State = Tuple[jax.Array, jax.Array]
 
@@ -69,3 +97,87 @@ def update_layer(state: State, layer, active, ssm_rows, conv_rows) -> State:
     conv_rows = jnp.where(active[None, :, None],
                           conv_rows.astype(conv.dtype), conv[layer])
     return ssm.at[layer].set(ssm_rows), conv.at[layer].set(conv_rows)
+
+
+# ---------------------------------------------------------------------------
+# A window layer's ring
+# ---------------------------------------------------------------------------
+
+_ROWS = 16      # a packed bfloat16 tile's rows
+
+
+def empty_window(n_layers: int, n_slots: int, kv_heads: int, window: int,
+                 head_dim: int, v_head_dim: int, dtype) -> State:
+    """-> (kw, vw), zeroed."""
+    rows = -(-window // _ROWS) * _ROWS
+    return tuple(jnp.zeros((n_layers, n_slots, kv_heads, rows, _lanes(d)),
+                           dtype)
+                 for d in (head_dim, v_head_dim))
+
+
+def write_window_prompt(state: State, slot, length, ks, vs) -> State:
+    """A prefill's keys `[n_layers, W, kv_heads, head_dim]` and values into
+    slot `slot`'s rings (`slot`, `length` traced scalars): row r takes the
+    largest position under `length` that is r modulo R, or zeros where the
+    prompt has none."""
+    kw, vw = state
+    R = kw.shape[3]
+    last = length - 1
+    at = last - (last - jnp.arange(R)) % R                  # [R]
+    reached = (at >= 0)[None, None, :, None]
+
+    def tail(rows, ring):       # [L, W, KVH, d] -> [L, KVH, R, lanes]
+        rows = rows[:, jnp.clip(at, 0, rows.shape[1] - 1)].transpose(
+            0, 2, 1, 3)
+        return jnp.where(reached, _to_width(rows, ring), 0)
+
+    with jax.named_scope("window_write"):
+        return (kw.at[:, slot].set(tail(ks, kw)),
+                vw.at[:, slot].set(tail(vs, vw)))
+
+
+def write_window_token(state: State, layer, w, active, k, v) -> State:
+    """One decode step's k `[n_slots, kv_heads, head_dim]` and v into row
+    `w % R` of each ACTIVE slot's ring of one layer; the others' stay."""
+    kw, vw = state
+    R = kw.shape[3]
+    with jax.named_scope("window_write"):
+        here = ((jnp.arange(R) == (w % R)[:, None])
+                & active[:, None])[:, None, :, None]
+        return (kw.at[layer].set(jnp.where(
+                    here, _to_width(k, kw)[:, :, None], kw[layer])),
+                vw.at[layer].set(jnp.where(
+                    here, _to_width(v, vw)[:, :, None], vw[layer])))
+
+
+def window_decode_attention(q, state: State, layer, w, active, *,
+                            window: int, sm_scale: float,
+                            sink: Optional[jax.Array] = None) -> jax.Array:
+    """ONE query token a slot, at position `w` `[n_slots]`, against its ring
+    of one window layer and nothing else: q `[n_slots, H, lanes(head_dim)]`
+    laid out as the ring's keys are, query head h reading kv head `h // (H //
+    KVH)`; `sink` `[H]` one further logit a head in the softmax, which
+    carries no value. -> float32 `[n_slots, H, lanes(v_head_dim)]`, zeros for
+    an idle slot. An XLA program (counted as `window_decode_reference`): a
+    slot's ring is R rows, a block of one."""
+    kw, vw = state
+    ns, H, _ = q.shape
+    KVH, R = kw.shape[2], kw.shape[3]
+    attention._path_counts["window_decode_reference"] += 1
+    qg = _to_width(q, kw).reshape(ns, KVH, H // KVH, -1)
+    scores = jnp.einsum("nkgd,nkrd->nkgr", qg, kw[layer],
+                        preferred_element_type=jnp.float32) * sm_scale
+    age = (w[:, None] - jnp.arange(R)) % R                  # [ns, R]
+    live = (age < window) & (age <= w[:, None]) & active[:, None]
+    scores = jnp.where(live[:, None, None, :], scores, DEFAULT_MASK_VALUE)
+    if sink is not None:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, KVH, H // KVH, 1),
+            (ns, KVH, H // KVH, 1))
+        scores = jnp.concatenate([scores, col], axis=-1)
+    p = jax.nn.softmax(scores, axis=-1)[..., :R]
+    # What a dead row holds is masked out of v too: 0 x NaN is NaN.
+    vh = jnp.where(live[:, None, :, None], vw[layer], 0).astype(jnp.float32)
+    out = jnp.einsum("nkgr,nkrd->nkgd", p, vh,
+                     precision=jax.lax.Precision.HIGHEST)
+    return jnp.where(active[:, None, None], out.reshape(ns, H, -1), 0.0)

@@ -32,6 +32,14 @@ engine is OURS):
   takes `kc`'s place in every program, `vc` is None, and nothing else of the
   engine knows. Its stack runs as segments too (leading dense layers, then
   the sparse ones, a scan each).
+  A model of window and full attention layers (`mcfg.mixed`) has TWO caches
+  of two shapes: pages under the block table for its full-attention layers
+  alone (`kc`, `vc`, keys wider than values), and for its window layers a
+  ring of the last `window` positions a slot (`ops/slot_state.py`), which
+  takes `state`'s place in every program: written by the prefill that admits
+  a request (the prompt's tail) and by each decode step, the same size at
+  200 positions as at 8,000. `PagePool` reserves for the full layers alone.
+  Segments by kind (`dense`, `window`, `layers`), a scan each.
 - **Reservation admission**: a request is admitted when the pages
   `PagePool.pages_for` says it can ever need are free: growth can then
   never fail mid-decode, so there is no preemption/recompute path.
@@ -189,6 +197,8 @@ def _make_prefill_core(mcfg):
         return _make_hybrid_prefill_core(mcfg)
     if mcfg.latent:
         return _make_latent_prefill_core(mcfg)
+    if mcfg.mixed:
+        return _make_mixed_prefill_core(mcfg)
     import jax
     import jax.numpy as jnp
 
@@ -524,6 +534,97 @@ def _make_latent_prefill_core(mcfg):
     return core
 
 
+def _mixed_rope_tables(mcfg, width):
+    """{stack: (cos, sin) [width, rotary_dim // 2]} of a mixed-attention
+    model: each kind of attention turns at its own theta."""
+    from ray_tpu.ops.norms import rope_frequencies
+    return {kind: rope_frequencies(mcfg.rotary_dim, width,
+                                   mcfg.attention_kind(kind)[1])
+            for kind, _, _ in mcfg.segments()}
+
+
+def _make_mixed_prefill_core(mcfg):
+    """`_make_prefill_core` for a stack of window and full attention layers
+    (`mcfg.attn_pattern`): the segments in order (`LlamaConfig.segments`:
+    `dense`, `window`, `layers`), a scan over each. `ks`, `vs` are what the
+    pages keep, the FULL layers' `[Lf, B, KVH, head_dim]` (a key `[k_n ;
+    k_r]`) and `[Lf, B, KVH, v_head_dim]`; after `experts` (`_share_stats`
+    summed over the sparse layers) come the window layers' (ks, vs) at THEIR
+    kv heads, of which a slot's ring keeps the prompt's tail
+    (`slot_state.write_window_prompt`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.block import (expert_stacks, feed_forward,
+                                      mixed_attention_inputs)
+    from ray_tpu.ops.attention import mixed_flash_attention
+    from ray_tpu.ops.norms import apply_rope_narrow, rms_norm
+
+    dt = mcfg.dtype
+    sparse = mcfg.n_experts > 0
+
+    def layer_fn(kind, stacks, tables, live, x, layer):
+        lp, l = layer
+        routed_layer = "router" in lp
+        lp = dict(lp, **stacks)
+        B, Sq, _ = x.shape
+        _, _, window, sink = mcfg.attention_kind(kind)
+        q_n, q_r, k_n, k_r, v = mixed_attention_inputs(
+            lp, x, mcfg, kind, lambda t: apply_rope_narrow(t, *tables))
+        with jax.named_scope("attn"):
+            # The kernel and nothing else: what a roofline counts is read
+            # inside the scope that times it.
+            with jax.named_scope("window_attn" if window else "full_attn"):
+                attn = mixed_flash_attention(
+                    q_n, q_r, k_n, k_r, v, mcfg.softmax_scale, window=window,
+                    sink=lp["sink"] if sink else None)
+            attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, -1)
+        with jax.named_scope("attn_out"):
+            x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
+        x, routed = feed_forward(lp, x, mcfg, live,
+                                 l if routed_layer else None)
+        ys = (jnp.concatenate([k_n, k_r], -1)[0].transpose(1, 0, 2),
+              v[0].transpose(1, 0, 2))            # [S, KVH, dk], [S, KVH, dv]
+        if routed_layer:
+            ys += (_share_stats(routed[1], live, mcfg),)
+        return x, ys
+
+    def core(params, tokens, length):
+        width = tokens.shape[1]
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+        with jax.named_scope("rope"):
+            tables = _mixed_rope_tables(mcfg, width)
+        live = jnp.arange(width)[None] < length
+        kept = {"full": ([], []), "window": ([], [])}
+        experts = 0
+        with jax.named_scope("layers"):
+            for kind, lo, hi in mcfg.segments():
+                sliced, stacks = expert_stacks(params[kind], mcfg)
+                x, (k, v, *stats) = jax.lax.scan(
+                    functools.partial(layer_fn, kind, stacks, tables[kind],
+                                      live), x,
+                    (sliced, jnp.arange(lo, hi)))
+                ks, vs = kept["window" if kind == "window" else "full"]
+                ks.append(k)
+                vs.append(v)
+                if stats:
+                    experts = experts + jnp.sum(stats[0], axis=0)
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], mcfg.norm_eps)
+            last_h = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
+                                                  keepdims=False)
+            logits = _head_logits(params, last_h, mcfg)
+            first = jnp.argmax(logits[0]).astype(jnp.int32)
+        (ks, vs), (kws, vws) = (tuple(jnp.concatenate(t) for t in kept[k])
+                                for k in ("full", "window"))
+        return (first, ks, vs, logits[0].astype(jnp.float32),
+                experts if sparse else None, (kws, vws))
+
+    core.takes_riders = False
+    return core
+
+
 def _layer_of(stack, i):
     """Layer `i` of a stack of layers (a leading axis on every leaf): what a
     scan over the stack hands its body, read by index."""
@@ -581,21 +682,26 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     from ray_tpu.models.block import (attention_inputs, expert_stacks,
                                       expert_stats, feed_forward,
                                       latent_attention_inputs,
-                                      latent_attention_output, mamba_mixer)
+                                      latent_attention_output, mamba_mixer,
+                                      mixed_attention_inputs)
     from ray_tpu.ops.norms import mrope_tables, rms_norm, rope_frequencies
     from ray_tpu.ops.paged_kv import (empty, empty_index, empty_latent,
                                       latent_rows, paged_decode_attention,
                                       paged_latent_decode, write_prompt,
                                       write_prompt_rows, write_token,
                                       write_token_rows)
-    from ray_tpu.ops.slot_state import (empty_state, layer_state,
-                                        update_layer, write_state)
+    from ray_tpu.ops.slot_state import (empty_state, empty_window,
+                                        layer_state, update_layer,
+                                        window_decode_attention, write_state,
+                                        write_window_prompt,
+                                        write_window_token)
     from ray_tpu.ops.sparse_attention import sparse_decode_attention
 
     sparse = mcfg.n_experts > 0
     indexed = mcfg.index_topk > 0
     hybrid = mcfg.ssm_state > 0
     latent = mcfg.latent
+    mixed = mcfg.mixed
     S = mcfg.max_seq
     H, KVH, hd = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim
     dt = mcfg.dtype
@@ -605,10 +711,17 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         """-> (kc, vc), the arena of the layers that keep K and V; after
         them ic for a model with an indexer, or the recurrent state
         (`ops/slot_state.py`) for one with state-space layers. A model with
-        latent attention: (its arena of latent rows, None)."""
+        latent attention: (its arena of latent rows, None); one of window
+        and full attention layers: the full layers' arena, then the window
+        layers' rings (`ops/slot_state.py`), which take `state`'s place."""
         if latent:
             return (empty_latent(mcfg.n_layers, n_pages, page,
                                  mcfg.latent_width, dt), None)
+        if mixed:   # pages for the full layers, a ring a slot for the rest
+            return empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt,
+                         v_head_dim=mcfg.v_head_dim) + (empty_window(
+                mcfg.n_layers - mcfg.kv_layers, ns, mcfg.window_kv_heads,
+                mcfg.window, hd, mcfg.v_head_dim, dt),)
         kv = empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt,
                    by_token=indexed)
         if indexed:
@@ -635,7 +748,8 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         or greedy when temp == 0) and the core's `experts` (and `ic`, the
         indexer keys' arena, where the model has one; or `state`, the
         recurrent state with slot `slot`'s rows overwritten by the prompt's
-        final ones, where it has state-space layers).
+        final ones, where it has state-space layers; or the window layers'
+        rings with slot `slot`'s overwritten by the prompt's tail).
 
         With `riders` = (block table, riding [ns], the slots' temp, topk,
         keys) and the slots' `last` and `pos` (the program of a riding rung,
@@ -657,6 +771,9 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
                     write_prompt_rows(ic, pages, iks[0]))
         if hybrid:
             return kc, vc, first, experts, write_state(state, slot, *iks[0])
+        if mixed:
+            return kc, vc, first, experts, write_window_prompt(
+                state, slot, length, *iks[0])
         return kc, vc, first, experts
 
     def _riding_prefill(params, kc, vc, pages, tokens, length, temp, topk,
@@ -805,6 +922,64 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             base += hi - lo
         return x, kc, experts
 
+    def _mixed_layers(params, x, kc, vc, state, experts, bt, pos, act,
+                      tables):
+        """One token a slot through the segments of a stack of window and
+        full attention layers, a scan each. A full layer writes the step's
+        row to the slot's page and reads its live pages in place
+        (`paged_decode_attention`, the arena's layer the full layer's
+        ordinal); a window layer writes it to the slot's ring and reads the
+        ring alone. Both caches ride the carry (see `_step`)."""
+        w = jnp.minimum(pos, S - 1)
+        lengths = jnp.where(act, w + 1, 0)
+        dv, scale = mcfg.v_head_dim, mcfg.softmax_scale
+
+        def body(kind, stacks, base, c, s, carry, layer):
+            x, kc, vc, state, experts = carry
+            lp, l = layer
+            routed_layer = "router" in lp
+            lp = dict(lp, **stacks)
+            _, _, window, sink = mcfg.attention_kind(kind)
+            q_n, q_r, k_n, k_r, v = mixed_attention_inputs(
+                lp, x, mcfg, kind, lambda t: _rope_one(t, c, s))
+            q, k = (jnp.concatenate(t, -1) for t in ((q_n, q_r), (k_n, k_r)))
+            if window:
+                state = write_window_token(state, l, w, act, k, v)
+                with jax.named_scope("attn"):
+                    with jax.named_scope("window_attn"):
+                        attn = window_decode_attention(
+                            q, state, l, w, act, window=window,
+                            sm_scale=scale, sink=lp["sink"] if sink else None)
+            else:
+                kc, vc = write_token(kc, vc, base + l, bt, w, act, k, v)
+                with jax.named_scope("attn"):
+                    with jax.named_scope("full_attn"):
+                        # q in the lanes a cached key lies in: zeros meet
+                        # the arena's padding
+                        attn = paged_decode_attention(
+                            jnp.pad(q, ((0, 0), (0, 0),
+                                        (0, kc.shape[-1] - q.shape[-1]))),
+                            kc, vc, base + l, bt, lengths, sm_scale=scale)
+            with jax.named_scope("attn_out"):
+                x = x + attn[..., :dv].astype(dt).reshape(ns, -1) \
+                    @ lp["wo"].astype(dt)
+            x, routed = feed_forward(lp, x, mcfg, act,
+                                     l if routed_layer else None)
+            if routed_layer:
+                experts = experts + _share_stats(routed[1], act, mcfg)
+            return (x, kc, vc, state, experts), None
+
+        for kind, lo, hi in mcfg.segments():
+            sliced, stacks = expert_stacks(params[kind], mcfg)
+            with jax.named_scope("rope"):
+                c, s = (t[w][:, None] for t in tables[kind])
+            # the arena's layer: the leading dense layers, then `layers`
+            base = mcfg.first_dense if kind == "layers" else 0
+            (x, kc, vc, state, experts), _ = jax.lax.scan(
+                functools.partial(body, kind, stacks, base, c, s),
+                (x, kc, vc, state, experts), (sliced, jnp.arange(lo, hi)))
+        return x, kc, vc, state, experts
+
     def _step(params, sliced, stacks, kc, vc, ic, experts, bt, last, pos,
               active, cos, sin, itables, temp, topk, keys, state=None):
         # sliced, stacks: `expert_stacks` of the layers, split (and where
@@ -844,6 +1019,12 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
                     experts[0] if sparse else jnp.zeros((), jnp.int32), bt,
                     pos, act, cos, sin)
                 experts = [stats] if sparse else []
+            elif mixed:     # segments by kind: `_mixed_layers` (`cos`: the
+                x, kc, vc, state, stats = _mixed_layers(    # kinds' tables)
+                    params, x, kc, vc, state,
+                    experts[0] if sparse else jnp.zeros((), jnp.int32), bt,
+                    pos, act, cos)
+                experts = [stats] if sparse else []
             else:
                 (x, kc, vc, ic, *experts), _ = jax.lax.scan(
                     body, (x, kc, vc, ic, *experts),
@@ -861,12 +1042,16 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         """-> (kc, vc, last, pos, tokens [ns, chunk], experts): `experts` is
         None for a dense model, else `expert_stats` of the live slots'
         tokens summed over the chunk's steps and the layers. With an
-        indexer, `ic` follows; with state-space layers, `state`."""
+        indexer, `ic` follows; with state-space layers, or window layers'
+        rings, `state`."""
         cos = sin = None
         itables = ()
         if latent:
             with jax.named_scope("rope"):
                 cos, sin = _latent_rope_tables(mcfg, S)
+        elif mixed:
+            with jax.named_scope("rope"):
+                cos = _mixed_rope_tables(mcfg, S)
         elif mcfg.rope:
             with jax.named_scope("rope"):
                 cos, sin = rope_frequencies(hd, S, mcfg.rope_theta)
@@ -874,10 +1059,11 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
                                            mcfg.rope_theta) if indexed else ()
         out0 = jnp.zeros((ns, chunk), jnp.int32)
         # `expert_stats`' width, and `_share_stats`' for a share.
-        experts0 = [jnp.zeros(mcfg.n_held + 1 + latent, jnp.int32)] \
-            if sparse else []
-        # A latent-attention stack splits each segment's (`_latent_layers`).
-        sliced, stacks = (None, None) if latent \
+        experts0 = [jnp.zeros(mcfg.n_held + 1 + (latent or mixed),
+                              jnp.int32)] if sparse else []
+        # A stack of segments splits each segment's (`_latent_layers`,
+        # `_mixed_layers`).
+        sliced, stacks = (None, None) if latent or mixed \
             else expert_stacks(params["layers"], mcfg)
 
         def body(i, carry):
@@ -893,7 +1079,7 @@ def _build_fns(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         experts = experts[0] if sparse else None
         if indexed:
             return kc, vc, last, pos, out, experts, ic
-        if hybrid:
+        if hybrid or mixed:
             return kc, vc, last, pos, out, experts, state
         return kc, vc, last, pos, out, experts
 
@@ -987,7 +1173,7 @@ class Engine:
         # warm-up peaks within 0.9 GB of the chip's memory (PERF.md, §4).
         self._params = fuse_qkv(self._experts_in_compute_dtype(params, mcfg),
                                 mcfg)
-        for stack in ("layers", "dense"):
+        for stack in ("layers", "dense", "window"):
             for name in set(params.get(stack, ())) - set(
                     self._params.get(stack, ())):
                 params[stack][name].delete()
@@ -1002,6 +1188,13 @@ class Engine:
         # for every other model, and no model has both.
         self._hybrid = mcfg.ssm_state > 0
         self._latent = mcfg.latent
+        # Window layers' rings (`ops/slot_state.py`) ride where a hybrid's
+        # recurrent state does: `_state`, per slot, written by the prefill
+        # that admits a request into the slot.
+        self._mixed = mcfg.mixed
+        self._by_slot = self._hybrid or self._mixed
+        # Stacks whose programs count a SHARE's routing (`_share_stats`).
+        self._shares = self._latent or self._mixed
         self._kc, self._vc, *more = self._empty()
         self._ic, self._state = self._third(more)
         # Prefill shape buckets (`prefill_widths`): a 50-token prompt
@@ -1075,6 +1268,18 @@ class Engine:
         # expert. The last chunk's local assignments ride the next dispatch
         # span as `experts_touched` does.
         self.latent_cache_bytes = int(self._kc.nbytes) if self._latent else 0
+        # A model of window and full attention layers: the bytes of its two
+        # caches (the full layers' pages; the window layers' rings, which no
+        # prompt's length moves), and the ring rows its decode steps read,
+        # over the chunks' steps and the active slots (min(position + 1,
+        # window) a slot a step, a layer), against `live_kv_tokens`. Host
+        # arithmetic on the positions, as `decode_selected_keys` is.
+        self._window = mcfg.window if self._mixed else 0
+        self.full_cache_bytes = int(self._kc.nbytes + self._vc.nbytes) \
+            if self._mixed else 0
+        self.window_cache_bytes = state_bytes(self._state) \
+            if self._mixed else 0
+        self.window_kv_tokens = 0
         self.routed_assignments = 0
         self.local_assignments = 0
         self._local_last_chunk = 0
@@ -1161,7 +1366,7 @@ class Engine:
         the one further cache this model has, if it has one."""
         if not more:
             return None, None
-        return (None, more[0]) if self._hybrid else (more[0], None)
+        return (None, more[0]) if self._by_slot else (more[0], None)
 
     def _warm_width(self, kc, vc, ic, state, width: int):
         """First call of the prefill program of one bucket width and, at a
@@ -1184,13 +1389,13 @@ class Engine:
                 self._params, kc, vc, null_pages,
                 jnp.zeros((1, width), jnp.int32), 1, 0.0, 0,
                 jnp.zeros(2, jnp.uint32), ic, state,
-                0 if self._hybrid else None, *slots)
+                0 if self._by_slot else None, *slots)
         if more and not rides:
             # no PD handoff carries an indexer's keys or a state
             return (kc, vc, *self._third(more), first)
         # no handoff has this width (none at all is sent an engine that
         # serves whole requests), or carries latent rows
-        if width not in self._adopt_widths or self._latent:
+        if width not in self._adopt_widths or self._latent or self._mixed:
             return kc, vc, ic, state, first
         # The PD adopt program for this width too (a first cross-pool
         # handoff must not compile in the loop).
@@ -1267,7 +1472,7 @@ class Engine:
             jax.ShapeDtypeStruct((2,), jnp.uint32),
             None if self._ic is None else shape_of(self._ic),
             jax.tree.map(shape_of, self._state),
-            0 if self._hybrid else None, slots, slots, riders).as_text()
+            0 if self._by_slot else None, slots, slots, riders).as_text()
 
     # ------------------------------------------------------------------
     @property
@@ -1287,19 +1492,20 @@ class Engine:
         compute dtype: stored otherwise, they are cast here, once, and the
         log says so (the engine then holds that copy of the experts; the
         caller may drop its own)."""
-        names = [k for k in ("w_gate", "w_up", "w_down")
-                 if mcfg.n_experts > 0
-                 and params["layers"][k].dtype != mcfg.dtype]
-        if not names:
+        cast = {stack: [k for k in ("w_gate", "w_up", "w_down")
+                        if params[stack][k].dtype != mcfg.dtype]
+                for stack in ("layers", "window")
+                if mcfg.n_experts > 0 and "router" in params.get(stack, ())}
+        if not any(cast.values()):
             return params
         logger.warning(
             "expert weights are stored as %s and computed in %s: the engine "
-            "casts its own copy once", params["layers"][names[0]].dtype,
-            mcfg.dtype)
-        layers = dict(params["layers"])
-        for k in names:
-            layers[k] = layers[k].astype(mcfg.dtype)
-        return dict(params, layers=layers)
+            "casts its own copy once", mcfg.param_dtype, mcfg.dtype)
+        out = dict(params)
+        for stack, names in cast.items():
+            out[stack] = dict(params[stack], **{
+                k: params[stack][k].astype(mcfg.dtype) for k in names})
+        return out
 
     def submit(self, ids: List[int], max_tokens: int, *,
                temperature: float = 0.0, top_k: int = 0,
@@ -1329,12 +1535,14 @@ class Engine:
         only tokens AFTER `first`."""
         if self.error is not None or not self._thread.is_alive():
             raise RuntimeError(f"LLM engine died:\n{self.error}")
-        if self._ic is not None or self._hybrid or self._latent:
+        if self._ic is not None or self._hybrid or self._latent \
+                or self._mixed:
             raise NotImplementedError(
                 "a PD handoff carries K and V, not a sparse-attention "
                 "indexer's keys nor a state-space layer's recurrent state "
-                "nor latent attention's rows (kv_lora_rank > 0): this model "
-                "serves from one engine")
+                "nor latent attention's rows (kv_lora_rank > 0) nor mixed "
+                "attention's two caches (attn_pattern: pages and window "
+                "rings): this model serves from one engine")
         if not self._adopt_widths:
             raise RuntimeError(
                 "this engine warmed no `adopt` program and would compile one "
@@ -1398,7 +1606,14 @@ class Engine:
         sparse, `routed_assignments` (what its routers assigned of live
         rows, to any share) and `local_assignments` (those that fell to the
         experts held here, the sum of `expert_tokens`, which is then per
-        HELD expert): their ratio is this share's part of the routed work."""
+        HELD expert): their ratio is this share's part of the routed work. A
+        model of window and full attention layers adds `full_cache_bytes`
+        (the pages of its full layers, all `PagePool` hands out),
+        `window_cache_bytes` (its window layers' rings: `window` positions a
+        slot a layer whatever the prompts) and `window_kv_tokens`, the ring
+        rows a window layer's decode steps read (min(position + 1, window) a
+        live slot a step) against `live_kv_tokens`; and, holding a share of
+        its experts, the same two assignment counts."""
         out = {k: getattr(self, k) for k in (
             "admitted", "queue_wait_s_sum", "admit_chunks_ahead",
             "admit_decoding_slots", "admit_pending", "slot_idle_s_sum",
@@ -1417,9 +1632,13 @@ class Engine:
             out["state_writes"] = self.state_writes
         if self._latent:
             out["latent_cache_bytes"] = self.latent_cache_bytes
-            if self._sparse:
-                out["routed_assignments"] = self.routed_assignments
-                out["local_assignments"] = self.local_assignments
+        if self._mixed:
+            out["full_cache_bytes"] = self.full_cache_bytes
+            out["window_cache_bytes"] = self.window_cache_bytes
+            out["window_kv_tokens"] = self.window_kv_tokens
+        if self._shares and self._sparse:
+            out["routed_assignments"] = self.routed_assignments
+            out["local_assignments"] = self.local_assignments
         return out
 
     def stop(self) -> None:
@@ -1583,7 +1802,7 @@ class Engine:
                 jnp.asarray(toks), len(req.ids),
                 float(req.temperature), int(req.top_k),
                 jnp.asarray(_seed_key(req.seed)), self._ic, self._state,
-                slot if self._hybrid else None, *slots)
+                slot if self._by_slot else None, *slots)
             if riders is None:
                 self._ic, self._state = self._third(more)
             else:
@@ -1680,7 +1899,7 @@ class Engine:
                         # profiler fixes a span's arguments when it opens.
                         touched, local, routed = self._count_experts(experts)
                         share = {"local": local, "routed": routed} \
-                            if self._latent else {}
+                            if self._shares else {}
                         with tracing.span("serve.engine.prefill_experts",
                                           ctx=req.ctx, rid=req.rid,
                                           touched=touched, **share):
@@ -1731,7 +1950,7 @@ class Engine:
         expert)."""
         stats = self._np.asarray(experts)
         routed = None
-        if self._latent:
+        if self._shares:
             routed, stats = int(stats[-1]), stats[:-1]
         local = int(stats[:-1].sum())
         routed = local if routed is None else routed
@@ -1812,19 +2031,25 @@ class Engine:
             routed = {"experts_touched": self._touched_last_chunk,
                       "expert_tokens": ":".join(map(str, self.expert_tokens))
                       } if self._sparse and tracing.recording() else {}
-            if routed and self._latent:
+            if routed and self._shares:
                 routed.update(local_assignments=self._local_last_chunk,
                               routed_assignments=self._routed_last_chunk)
-            if self._index_topk:
+            if self._index_topk or self._window:
                 # What each step of the chunk reads, a layer: a slot at
-                # position p attends to p + 1 positions, the indexer's
-                # index_topk of them at most.
+                # position p attends to p + 1 positions.
                 reads = (self._pos[self._active][:, None] + 1
                          + np.arange(self.chunk)[None, :]).clip(max=S)
+            if self._index_topk:    # the indexer's index_topk of them at most
                 picked = int(np.minimum(reads, self._index_topk).sum())
                 self.decode_selected_keys += picked
                 self.decode_live_keys += int(reads.sum())
                 routed.update(selected_keys=picked, live_keys=int(reads.sum()))
+            if self._window:
+                # Of a window layer's ring: a slot's own row and the window - 1
+                # before it, p + 1 rows while it has fewer.
+                ring = int(np.minimum(reads, self._window).sum())
+                self.window_kv_tokens += ring
+                routed.update(window_kv_tokens=ring)
             with tracing.span("serve.engine.decode_dispatch", useful=useful,
                               capacity=self.n_slots * self.chunk,
                               active=len(plan), live_kv_tokens=live_kv,

@@ -243,12 +243,14 @@ class PrefillServer:
         cfg: LLMConfig = cloudpickle.loads(cfg_blob)
         self.cfg = cfg
         self.mcfg, params = _model_from_cfg(cfg)
-        if self.mcfg.index_topk or self.mcfg.ssm_state or self.mcfg.latent:
+        if self.mcfg.index_topk or self.mcfg.ssm_state or self.mcfg.latent \
+                or self.mcfg.mixed:
             raise NotImplementedError(
                 "a PD handoff carries K and V, not a sparse-attention "
                 "indexer's keys nor a state-space layer's recurrent state "
-                "nor latent attention's rows (kv_lora_rank > 0): this model "
-                "serves from one engine")
+                "nor latent attention's rows (kv_lora_rank > 0) nor mixed "
+                "attention's two caches (attn_pattern: pages and window "
+                "rings): this model serves from one engine")
         # The layout the shared prefill core reads, as in the engine.
         self.params = fuse_qkv(params)
         self._core = jax.jit(_make_prefill_core(self.mcfg))

@@ -23,7 +23,7 @@ sys.path.insert(0, ROOT)
 from benchmark import models, program_trace  # noqa: E402
 from benchmark.tests import test_benchmark as cases  # noqa: E402
 
-ARCHS = ["llama", "olmoe", "keye", "jamba", "dots"]
+ARCHS = ["llama", "olmoe", "keye", "jamba", "dots", "mimo"]
 # config.json of allenai/OLMoE-1B-7B-0125-Instruct, as the catalog beside the
 # model-configs guide has it.
 OLMOE_PUBLISHED = dict(
@@ -613,8 +613,8 @@ def test_state_space_readers_on_a_synthetic_trace(monkeypatch):
 
 def test_dots_manifest_entries_are_the_catalogs_row_cut_to_a_share():
     manifest = cases.load(ROOT, "BENCHMARK.json")
-    entry = manifest["configs"][-1]
-    assert entry["name"] == "dots.vlm1.inst-serve"
+    entry = next(c for c in manifest["configs"]     # PR 39's; later PRs after
+                 if c["name"] == "dots.vlm1.inst-serve")
     cfg = cases.load(ROOT, entry["file"])
     assert entry["source"] == cfg["source_url"] and cfg["arch"] == "dots"
     assert entry["reduced"] == list(cfg["reduced"]) == [
@@ -623,10 +623,10 @@ def test_dots_manifest_entries_are_the_catalogs_row_cut_to_a_share():
     eng = cfg["deployment"]["engine"]
     assert (eng["n_slots"], eng["max_seq"], eng["decode_chunk"]) == (32, 4096, 8)
     assert eng["kv_pages"] == 1 + eng["n_slots"] * eng["max_seq"] // eng["page_size"]
-    cell = manifest["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        "serve-batch-dots-vlm1", "dots.vlm1.inst-serve",
-        "batch-summarize-dots-vlm1", 1)
+    cell = next(w for w in manifest["workloads"]
+                if w["name"] == "serve-batch-dots-vlm1")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "dots.vlm1.inst-serve", "batch-summarize-dots-vlm1", 1)
     mine = cases.load(cases.BENCH, "traffic", "batch-summarize-dots-vlm1.json")
     theirs = cases.load(cases.BENCH, "traffic", "batch-summarize-jamba2.json")
     assert {k for k in mine if mine[k] != theirs.get(k)} == {
@@ -639,14 +639,14 @@ def test_dots_manifest_entries_are_the_catalogs_row_cut_to_a_share():
            "moe_share_experts_roofline_pct", "local_assignment_share_pct"]
     at = list(lists).index(new[0])      # appended by PR 39; later PRs after
     assert list(lists)[at:at + 6] == new
-    assert all(lists[n] == ["serve-batch-dots-vlm1"] for n in new)
+    assert all(lists[n][0] == "serve-batch-dots-vlm1" for n in new)
     # no share of a roofline that counts work this chip does not do
     for name in ("moe_experts_roofline_pct", "decode_attn_roofline_pct"):
         assert "serve-batch-dots-vlm1" not in lists[name]
     for name in ("prefill_ms_per_ktok", "kv_pages_peak_pct", "decode_moe_ms",
                  "prefill_moe_ms_per_ktok", "expert_load_max_over_mean",
                  "engine_slot_refill_ms", "prefill_stall_pct"):
-        assert lists[name][-1] == "serve-batch-dots-vlm1"
+        assert "serve-batch-dots-vlm1" in lists[name]
 
 
 def test_latent_attention_readers_on_a_synthetic_trace(monkeypatch):
@@ -741,3 +741,173 @@ def test_latent_attention_readers_on_a_synthetic_trace(monkeypatch):
                  "local_assignment_share_pct"):
         assert _reader(name)(dict(run, trace_data=_device_view([]))) is None, \
             name
+
+
+# -- arch `mimo`: the manifest's entries, and the window readers -------------
+
+MIMO_CELL = "serve-longdoc-mimo-v2"
+MIMO_READERS = ["prefill_window_attn_ms_per_ktok",
+                "prefill_full_attn_ms_per_ktok", "decode_window_attn_ms",
+                "decode_full_attn_ms", "window_prefill_roofline_pct",
+                "window_decode_roofline_pct",
+                "full_prefill_attn_roofline_pct",
+                "full_decode_attn_roofline_pct", "window_kv_share_pct"]
+
+
+def test_mimo_manifest_entries_are_the_catalogs_row_and_the_issues_traffic():
+    manifest = cases.load(ROOT, "BENCHMARK.json")
+    entry = next(c for c in manifest["configs"]
+                 if c["name"] == "mimo-v2-flash-serve")
+    cfg = cases.load(ROOT, entry["file"])
+    assert entry["source"] == cfg["source_url"] and cfg["arch"] == "mimo"
+    assert entry["reduced"] == list(cfg["reduced"])
+    cell = next(w for w in manifest["workloads"] if w["name"] == MIMO_CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "mimo-v2-flash-serve", "longdoc-qa-mimo-v2", 1)
+    assert "29%" in cell["why"] and "19%" in cell["why"]
+    mix = cases.load(cases.BENCH, "traffic", "longdoc-qa-mimo-v2.json")
+    keye = cases.load(cases.BENCH, "traffic", "longdoc-qa-keye.json")
+    assert mix["kind"] == "serve_closed_checked"
+    assert mix["arrivals"] == dict(keye["arrivals"], clients=64)
+    assert mix["output_tokens"] == keye["output_tokens"]
+    assert mix["prompt_tokens"]["dist"] == "uniform"
+    assert mix["shape_seed"] == 4201 and mix["trace"]["seconds"] == 4
+    chk = mix["check"]
+    assert len(chk["prompt_lengths"]) * chk["tokens"] == 2048
+    # every checked stream crosses the window and wraps the ring
+    assert min(chk["prompt_lengths"]) > cfg["sliding_window"]
+    assert max(chk["prompt_lengths"]) <= mix["prompt_tokens"]["max"]
+    lists = {p["name"]: p.get("workloads", []) for p in manifest["per_layer"]}
+    assert list(lists)[-9:] == MIMO_READERS
+    assert all(lists[n] == [MIMO_CELL] for n in MIMO_READERS)
+    for name in ("prefill_ms_per_ktok", "kv_pages_peak_pct", "decode_moe_ms",
+                 "prefill_moe_ms_per_ktok", "expert_load_max_over_mean",
+                 "engine_slot_refill_ms", "prefill_stall_pct",
+                 "moe_share_experts_roofline_pct",
+                 "local_assignment_share_pct"):
+        assert lists[name][-1] == MIMO_CELL
+    # no share that multiplies by one layer count and one head width, or
+    # counts experts this chip does not hold
+    for name in ("decode_attn_roofline_pct", "moe_experts_roofline_pct",
+                 "decode_rider_share_pct", "latent_decode_roofline_pct"):
+        assert MIMO_CELL not in lists[name]
+
+
+def test_window_readers_on_a_synthetic_trace(monkeypatch):
+    """The nine readers of PR 42 on a trace built by hand: a prefill of 7,000
+    prompt tokens and one decode chunk of 2 steps, their scopes, the
+    counters on the spans. A program without the scopes (the parent, every
+    other model) reads None and raises nothing."""
+    from benchmark import peaks, window_trace
+    Span = program_trace.Span
+    dispatch = dict(useful=16, capacity=16, active=2, live_kv_tokens=14000)
+    spans = [
+        Span("serve.engine.admit", 900, 950, dict(
+            rid=7, kind="prefill", prompt_tokens=7000, bucket=7168,
+            queue_wait_us=1, decoding=0, slot_idle_us=0)),
+        Span("serve.engine.emit", 2100, 2110, dict(rid=7, kind="first")),
+        Span("serve.engine.decode_dispatch", 2200, 2210,
+             dict(dispatch, window_kv_tokens=512)),
+        Span("serve.engine.decode_dispatch", 3200, 3210,
+             dict(dispatch, window_kv_tokens=512)),
+    ]
+    modules = [("jit_poke", 0, 10), ("jit_prefill", 1000, 2000),
+               ("jit_decode", 2300, 3000), ("jit_poke", 4000, 4010)]
+    pre = "jit(prefill)/layers/while/body/"
+    dec = "jit(decode)/while/body/layers/while/body/"
+    ops = [(pre + "qkv/dot_general:", 1000, 1200),
+           (pre + "attn/window_attn/pallas_call:", 1200, 1300),
+           (pre + "attn/full_attn/pallas_call:", 1300, 1700),
+           (pre + "attn/mul:", 1700, 1710),
+           (pre + "mlp/experts/ragged_dot:", 1710, 2000),
+           (dec + "window_write/select_n:", 2300, 2340),
+           (dec + "attn/window_attn/dot_general:", 2340, 2400),
+           (dec + "kv_write/scatter:", 2400, 2450),
+           (dec + "attn/full_attn/pallas_call:", 2450, 2700),
+           (dec + "attn/mul:", 2700, 2710),
+           (dec + "mlp/experts/ragged_dot:", 2710, 3000)]
+    t = program_trace.ProgramTrace(spans, modules, ops)
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    m = cases.load(ROOT, "benchmark/configs/mimo-v2-flash-serve.json")
+    m["deployment"]["engine"]["decode_chunk"] = 2
+    run = {"config": m, "cell": "x", "seed": 0, "trace_data": None,
+           "device": {"kind": "TPU v5 lite"}}
+    got = {name: _reader(name)(run) for name in MIMO_READERS}
+    assert got["prefill_window_attn_ms_per_ktok"] == \
+        pytest.approx(100 / 1e6 / 7.0)
+    assert got["prefill_full_attn_ms_per_ktok"] == \
+        pytest.approx(400 / 1e6 / 7.0)
+    assert got["decode_window_attn_ms"] == pytest.approx(100 / 1e6 / 2)
+    assert got["decode_full_attn_ms"] == pytest.approx(300 / 1e6 / 2)
+    assert got["window_kv_share_pct"] == pytest.approx(
+        100 * 1024 / (28000 * 2))
+    counts = models.adapter("mimo").counts
+    f, b = peaks.peak("TPU v5 lite", "bf16_flops_per_s"), \
+        peaks.peak("TPU v5 lite", "hbm_bytes_per_s")
+    for window, layers, ns in ((True, 5, 100e-9), (False, 2, 400e-9)):
+        ops_, byts = counts.prefill_attn_ops_bytes(m, 7000, window, 2)
+        name = "window_prefill_roofline_pct" if window \
+            else "full_prefill_attn_roofline_pct"
+        assert got[name] == pytest.approx(
+            100 * layers * max(ops_ / f, byts / b) / ns)
+    assert got["window_decode_roofline_pct"] == pytest.approx(
+        100 * 5 * 512 * 8 * 320 * 2 / b / 60e-9)
+    assert got["full_decode_attn_roofline_pct"] == pytest.approx(
+        100 * 2 * 14000 * 2 * 4 * 320 * 2 / b / 250e-9)
+    # a trace without this stack's scopes or counters: every reader is silent
+    bare = program_trace.ProgramTrace(
+        [Span(s.name, s.start, s.end,
+              {k: v for k, v in s.args.items() if k != "window_kv_tokens"})
+         for s in spans], modules,
+        [(p.replace("window_attn/", "").replace("full_attn/", "")
+           .replace("window_write/", "kv_write/"), s, e) for p, s, e in ops])
+    monkeypatch.setattr(program_trace, "load", lambda run: bare)
+    assert [_reader(name)(run) for name in MIMO_READERS] == [None] * 9
+    monkeypatch.setattr(program_trace, "load", lambda run: None)
+    assert [_reader(name)(run) for name in MIMO_READERS] == [None] * 9
+    assert window_trace.deepest_scope(
+        dec + "attn/window_attn/dot_general:") == "window_attn"
+
+
+def test_the_engines_spans_carry_what_the_window_readers_read():
+    """The names `benchmark/window_trace.py` and the readers look for are the
+    ones the program emits: the scopes in the lowered programs of a mixed
+    stack, the span argument and the counters in the engine."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import window_trace
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.serve import engine as engine_mod
+
+    adapter = models.adapter("mimo")
+    m = cases.load(ROOT, "benchmark/configs/mimo-v2-flash-serve.json")
+    model = dict(m, **adapter.REHEARSE)
+    cfg = adapter.build_config(model, {"params": "float32",
+                                       "activations": "float32"}, 128)
+    prefill, decode, _, _, empty = engine_mod._build_fns(cfg, 2, 2, 16, 17)
+    params = jax.eval_shape(lambda: fuse_qkv(
+        init_params(cfg, jax.random.PRNGKey(0)), cfg))
+    kc, vc, state = jax.eval_shape(empty)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    text = decode.lower(
+        params, kc, vc, arg((2, 8), jnp.int32), arg((2,), jnp.int32),
+        arg((2,), jnp.int32), arg((2,), jnp.bool_), arg((2,), jnp.float32),
+        arg((2,), jnp.int32), arg((2, 2), jnp.uint32), None,
+        state).as_text(debug_info=True)
+    for scope in window_trace.SCOPES + ("kv_write",):
+        assert f"/{scope}/" in text or f"{scope}/" in text, scope
+    text = prefill.lower(
+        params, kc, vc, arg((8,), jnp.int32), arg((1, 64), jnp.int32), 1,
+        0.0, 0, arg((2,), jnp.uint32), None, state,
+        0).as_text(debug_info=True)
+    for scope in window_trace.SCOPES:
+        assert f"{scope}/" in text, scope
+    src = open(engine_mod.__file__).read()
+    for name in ("window_kv_tokens", "window_cache_bytes",
+                 "full_cache_bytes"):
+        assert f'"{name}"' in src or f"{name}=" in src, name

@@ -418,6 +418,113 @@ def test_jamba_programs_keep_arena_and_state_in_place_on_v5e(
 
 
 # ---------------------------------------------------------------------------
+# A stack of window and full attention layers (PR 42) at the cell's sizes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("window,kvh", [(128, 8), (0, 4)],
+                         ids=["window", "full"])
+@pytest.mark.parametrize("S", [7168, 512])
+def test_mixed_flash_kernels_compile_for_v5e(topo, window, kvh, S):
+    """`window_flash_fwd` (8 query heads of a kv head a grid step, two key
+    blocks of 128) and `full_flash_fwd` (keys in two parts a kv head, values
+    of 128) at MiMo-V2's widths, at a rung that is no power of two and at the
+    narrowest a kernel takes."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds(1, 64, S, 128), sds(1, 64, S, 64), sds(1, kvh, S, 128),
+            sds(1, kvh, S, 64), sds(1, kvh, S, 128))
+    if window:
+        fn = jax.jit(lambda *a: attention._window_flash_pallas(
+            *a, sm_scale=192 ** -0.5, window=window, interpret=False))
+        args += (sds(64, dtype=jnp.float32),)
+    else:
+        fn = jax.jit(lambda *a: attention._latent_flash_pallas(
+            *a, sm_scale=192 ** -0.5, interpret=False,
+            name="full_flash_fwd"))
+    lowered = fn.lower(*args)
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text
+    assert ("window_flash_fwd" if window else "full_flash_fwd") in text
+    assert lowered.compile().memory_analysis().temp_size_in_bytes >= 0
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_mimo_programs_keep_both_caches_in_place_on_v5e(
+        topo, program, monkeypatch):
+    """The mixed stack's decode chunk and its 2,048-bucket prefill (the
+    8,192-wide one compiles as well, 1.71 GB of temporaries, in 24 s of every
+    core: a builder's compile, PERF.md section 4) at the cell's sizes (benchmark/configs/mimo-v2-flash-serve.json): the pages of
+    the 2 full layers (keys in 256 lanes, values in 128) and the rings of the
+    5 window layers are donated and alias the outputs; decode's full layers
+    run the `paged_decode` kernel at those widths, prefill the two flash
+    kernels; and serving fits the chip beside the widest prefill's
+    temporaries."""
+    import json
+
+    from benchmark import models
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.serve.engine import _build_fns
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "mimo-v2-flash-serve.json")) as f:
+        model = json.load(f)
+    eng = model["deployment"]["engine"]
+    cfg = models.adapter("mimo").build_config(model, model["dtypes"],
+                                              eng["max_seq"])
+    ns, page = eng["n_slots"], eng["page_size"]
+    maxp = eng["max_seq"] // page
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    prefill, decode, _, _, empty = _build_fns(cfg, ns, eng["decode_chunk"],
+                                              page, eng["kv_pages"])
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)), cfg)))
+    kc, vc, state = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                                 jax.eval_shape(empty))
+    assert kc.shape == (2, eng["kv_pages"], 4, page, 256)
+    assert vc.shape == (2, eng["kv_pages"], 4, page, 128)
+    assert [tuple(x.shape) for x in state] == [(5, ns, 8, 128, 256),
+                                               (5, ns, 8, 128, 128)]
+    before = dict(attention.attention_path_counts())
+    if program == "decode":
+        lowered = decode.lower(
+            params, kc, vc, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
+            sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
+            sds((ns,), jnp.float32), sds((ns,), jnp.int32),
+            sds((ns, 2), jnp.uint32), None, state)
+        kernels, paths = ["paged_decode"], ["decode_pallas",
+                                            "window_decode_reference"]
+    else:
+        lowered = prefill.lower(
+            params, kc, vc, sds((maxp,), jnp.int32), sds((1, 2048), jnp.int32),
+            1, 0.0, 0, sds((2,), jnp.uint32), None, state, 0)
+        kernels, paths = ["window_flash_fwd", "full_flash_fwd"], [
+            "window_fwd_pallas", "full_fwd_pallas"]
+    text = lowered.as_text()
+    assert all(k in text for k in kernels)
+    counts = attention.attention_path_counts()
+    assert all(counts[p] > before.get(p, 0) for p in paths)
+    mem = lowered.compile().memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in (kc, vc) + tuple(state))
+    assert held == 1_736_835_072
+    assert mem.alias_size_in_bytes >= held
+    # decode sets nothing aside; a prefill's temporaries are its activations
+    # (1.71 GB at 8,192 rows), and arguments + temporaries fit the chip's 15.75
+    assert mem.temp_size_in_bytes < ((64 << 20) if program == "decode"
+                                     else (1 << 30))
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 << 30
+
+
+# ---------------------------------------------------------------------------
 # A riding rung's prefill (serve/engine.py::rung_rides) at the cells' sizes
 # ---------------------------------------------------------------------------
 
