@@ -10,10 +10,19 @@ selected k (Mixtral), and EVERY assignment computed. There is no capacity and
 so no dropped token: a token's output depends on that token alone, never on
 who shares its batch. Shapes stay static without a capacity because the
 `tokens * k` assignments are sorted by expert and the experts run as grouped
-matmuls over the sorted rows (`jax.lax.ragged_dot`: group e is the
+matmuls over the sorted rows (`grouped_matmul`: group e is the
 `group_sizes[e]` rows after those of the experts before it), so compute is
 O(tokens * k * d * f) whatever the skew. The experts' weight leading axis
 carries the logical "expert" axis which the sharding rules map onto `ep`.
+
+The grouped matmul has two implementations behind `grouped_matmul`, chosen
+in one place (`_tiling`) from static shapes and the platform: on a TPU a
+Pallas kernel (`_grouped_kernel`: row tiles of 256 visited by halves, K
+never cut and a group's `[K, tn]` block read once a run of its visits, only
+(row tile, group) pairs that hold a row visited, the stack of all layers
+indexed where it lies);
+everywhere else, and under differentiation, `jax.lax.ragged_dot` and its
+VJP. Same operands, same float32 accumulation, a row's result its own.
 
 The router has the published variants as arguments (`top_k_routing`): softmax
 scores (OLMoE, Mixtral, the Qwen3 family) or sigmoid scores with a selection
@@ -27,10 +36,15 @@ the place of the absent chips or of their exchange.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import attention
 
 
 def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True,
@@ -83,6 +97,166 @@ def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
            w_down: jax.Array) -> jax.Array:
     """(silu(x w_gate) * (x w_up)) w_down: one dense expert."""
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+# The grouped matmul's row tile on a TPU, visited by HALVES: a (row tile,
+# group) pair costs the half it touches, 128 rows, however few of them are the
+# group's. XLA's kernel pays a tile of 512 a pair: 1.6-1.9 ms a call over a
+# share's 16 groups of 30-130 rows where this one takes 0.8-0.9 (PERF.md,
+# PR 43, the probe's table).
+_ROW_TILE = 256
+# VMEM the kernel's blocks may fill, two buffers each: under the 16 MiB every
+# program's kernels are given unasked. Asking for more (a group's whole
+# 7168 x 2048 matrix as one block read 12% faster alone) takes the room XLA
+# keeps its own buffers in: `moe_combine`'s gather ran 15 ms a prefill slower
+# beside such a kernel than beside `ragged_dot` (PERF.md, PR 43).
+_BLOCKS_VMEM = 13 << 20
+
+
+def _tiling(m: int, k: int, n: int) -> Optional[Tuple[int, int]]:
+    """THE choice between the two grouped matmuls, from static shapes: the
+    Pallas kernel's (row tile, column tile), or None for `ragged_dot`. The
+    kernel won at every shape the probe tried, a decode step's 64 and 128
+    rows included, so all it asks is shapes it can tile: the rows in whole
+    tiles (one tile of all the rows under `_ROW_TILE`) whose halves are whole
+    bfloat16 sublane tiles, K and N in lanes. K is never cut (a group's
+    `[K, tn]` block is read once a run of its visits); the column tile is the
+    widest the blocks' room allows, and a row tile of half the rows is taken
+    where that lets it be wider (K = 7168: a tile's rows are read once a
+    column tile)."""
+    best = None
+    for tm in (min(_ROW_TILE, m), min(_ROW_TILE // 2, m)):
+        if m % tm or tm % 32 or k % 128 or n % 128:
+            continue
+        tn = max((t for t in range(128, n + 1, 128) if n % t == 0
+                  and 4 * (k * t + tm * k + tm * t) + 4 * tm * min(t, 512)
+                  <= _BLOCKS_VMEM), default=0)
+        if tn and (best is None or tn > best[1]):
+            best = (tm, tn)
+    return best
+
+
+@functools.partial(jax.jit, static_argnames=("m", "tm"))
+def _visits(groups, *, m, tm):
+    """The (row tile, group) pairs that hold a grouped row, in row order:
+    -> (group [V], tile [V], lo [V], hi [V], count), V = tiles + groups - 1
+    the most there can be; visit v computes rows lo[v] <= r < hi[v] of its
+    tile, its group's. An empty group has no visit."""
+    ends = jnp.cumsum(groups)
+    starts = ends - groups
+    first = starts // tm
+    tiles = jnp.where(groups > 0, (ends - 1) // tm - first + 1, 0)
+    upto = jnp.cumsum(tiles)
+    v = jnp.arange(-(-m // tm) + groups.shape[0] - 1, dtype=jnp.int32)
+    group = jnp.minimum(jnp.sum(v[:, None] >= upto[None, :], axis=1,
+                                dtype=jnp.int32), groups.shape[0] - 1)
+    # a visit's tile: its group's first, and one on for each visit since
+    tile, lo, hi = jnp.stack([first - (upto - tiles), starts, ends])[:, group]
+    return group, tile + v, lo, hi, upto[-1]
+
+
+def _grouped_kernel(group, tile, lo, hi, x_ref, w_ref, o_ref, *, tm, nc):
+    """One visit: the tile's rows against the group's `[K, tn]` block BY
+    HALVES, a half the group has no row in skipped, `nc` columns at a time.
+    Only the group's rows are stored, so the tile's other rows keep what
+    their own groups' visits (consecutive: the block stays in VMEM) left
+    there, and a row in no group keeps what the buffer held."""
+    del group
+    v = pl.program_id(1)
+    base, half = tile[v] * tm, tm // 2
+
+    def rows(h, _):
+        r = pl.ds(pl.multiple_of(h * half, half), half)
+        at = base + h * half + jax.lax.broadcasted_iota(
+            jnp.int32, (half, 1), 0)
+        mine = (at >= lo[v]) & (at < hi[v])
+        x = x_ref[r, :]
+
+        def columns(j, _):
+            c = pl.ds(pl.multiple_of(j * nc, nc), nc)
+            y = jnp.dot(x, w_ref[:, c], preferred_element_type=jnp.float32)
+            o_ref[r, c] = jnp.where(mine, y.astype(o_ref.dtype), o_ref[r, c])
+
+        jax.lax.fori_loop(0, o_ref.shape[1] // nc, columns, None)
+
+    # halves 0 and 1: from the one the group's first row here lies in, up to
+    # the one its last row does
+    jax.lax.fori_loop((lo[v] >= base + half).astype(jnp.int32),
+                      1 + (hi[v] > base + half).astype(jnp.int32), rows, None)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
+def _grouped_pallas(xs, w, groups, *, tm, tn, interpret):
+    """Both under a `jit` of their own: a sparse layer's three matmuls share
+    one list of visits and its gate and up one kernel, traced and lowered
+    once a program (a start-up pays every program's tracing: PERF.md,
+    PR 41)."""
+    m, k = xs.shape
+    n = w.shape[-1]
+    *meta, count = _visits(groups, m=m, tm=tm)
+    nc = next(c for c in (512, 384, 256, 128) if tn % c == 0)
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, tm=tm, nc=nc),
+        out_shape=jax.ShapeDtypeStruct((m, n), xs.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, count),
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda j, v, g, t, lo, hi: (t[v], 0)),
+                pl.BlockSpec((None, k, tn),
+                             lambda j, v, g, t, lo, hi: (g[v], 0, j)),
+            ],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, v, g, t, lo, hi: (t[v], j)),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="grouped_matmul",
+    )(*meta, xs, w)
+
+
+def _ragged_dot(xs, w, groups):
+    attention._path_counts["experts_ragged_dot"] += 1
+    return jax.lax.ragged_dot(xs, w, groups)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _grouped(xs, w, groups, tiling, interpret):
+    attention._path_counts["experts_grouped_pallas"] += 1
+    return _grouped_pallas(xs, w, groups, tm=tiling[0], tn=tiling[1],
+                           interpret=interpret)
+
+
+def _grouped_fwd(xs, w, groups, tiling, interpret):
+    return jax.vjp(lambda a, b: _ragged_dot(a, b, groups), xs, w)
+
+
+def _grouped_bwd(tiling, interpret, vjp, dy):
+    return (*vjp(dy), None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def grouped_matmul(xs: jax.Array, w: jax.Array, groups: jax.Array, *,
+                   interpret: bool = False) -> jax.Array:
+    """xs [m, K] sorted by group, w [G, K, N], groups [G] int32 -> [m, N]:
+    rows sum(groups[:g]) .. sum(groups[:g + 1]) - 1 times w[g], float32
+    accumulation over K, a row's result that row's alone; rows past
+    sum(groups) hold anything. `w` may be the stack of every layer with the
+    other layers' groups empty: it is indexed where it lies, and an empty
+    group costs nothing.
+
+    On a TPU (or with `interpret`, for tests on the CPU), at the shapes
+    `_tiling` takes, a Pallas kernel; elsewhere, and under differentiation,
+    `jax.lax.ragged_dot` and its VJP. Which one is counted at trace time in
+    `attention.attention_path_counts()` as `experts_grouped_pallas` /
+    `experts_ragged_dot`."""
+    tiling = _tiling(xs.shape[0], xs.shape[1], w.shape[-1])
+    if tiling is None or not (interpret or attention._on_tpu()):
+        return _ragged_dot(xs, w, groups)
+    return _grouped(xs, w, groups, tiling, interpret)
 
 
 # Rows of a share's sorted assignments that meet the grouped matmuls at once,
@@ -144,9 +318,9 @@ def _share_experts(x, w_gate, w_up, w_down, layer, idx, weights, held,
                 groups = jax.lax.dynamic_update_slice(
                     jnp.zeros(stacked, jnp.int32), groups, (layer * n_held,))
         with jax.named_scope("experts"):
-            h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, groups)) \
-                * jax.lax.ragged_dot(xs, w_up, groups)
-            ys = jax.lax.ragged_dot(h, w_down, groups)       # [rows, d]
+            h = jax.nn.silu(grouped_matmul(xs, w_gate, groups)) \
+                * grouped_matmul(xs, w_up, groups)
+            ys = grouped_matmul(h, w_down, groups)           # [rows, d]
         with jax.named_scope("moe_combine"):
             # A row in no group is whatever the grouped matmul left there:
             # it is replaced, not multiplied by 0 (0 x NaN is NaN).
@@ -185,9 +359,10 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
     layer: with it, w_up/w_gate/w_down are the STACKS of all the model's
     layers, `[n_layers, n_experts, ...]`, and `layer` (a traced index) says
     whose experts these tokens meet. The grouped matmul then reads the stack
-    where it lies, the other layers' experts as empty groups; handed one
-    layer sliced out of a scanned stack, its kernel is first given a copy of
-    that layer's experts (0.8 GB a layer at OLMoE's widths, every step).
+    where it lies, the other layers' experts as empty groups (no visit in the
+    Pallas kernel); handed one layer sliced out of a scanned stack, either
+    kernel is first given a copy of that layer's experts (0.8 GB a layer at
+    OLMoE's widths, every step).
     routing: the router's variant, `top_k_routing`'s keyword arguments (None:
     softmax).
     held: (offset, count), static: w_up/w_gate/w_down hold experts offset ..
@@ -234,9 +409,9 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
                                         for w in (w_up, w_gate, w_down))
 
         with jax.named_scope("experts"):
-            h = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, groups)) \
-                * jax.lax.ragged_dot(xs, w_up, groups)
-            ys = jax.lax.ragged_dot(h, w_down, groups)          # [t*k, d]
+            h = jax.nn.silu(grouped_matmul(xs, w_gate, groups)) \
+                * grouped_matmul(xs, w_up, groups)
+            ys = grouped_matmul(h, w_down, groups)              # [t*k, d]
 
         with jax.named_scope("moe_combine"):
             # Back to token-major by a gather and one sum over a token's k

@@ -525,6 +525,122 @@ def test_mimo_programs_keep_both_caches_in_place_on_v5e(
 
 
 # ---------------------------------------------------------------------------
+# The experts' grouped matmul (ops/moe.py::grouped_matmul)
+# ---------------------------------------------------------------------------
+
+def _moved_stacks(hlo, stacks):
+    """Names of the compiled program's instructions whose result has the
+    shape of an expert stack, of one layer of one or of one expert's matrix
+    and is a copy, a slice or an update-slice, bare or fused by name (the
+    decode program's test has the pattern): a kernel handed one layer of a
+    stack is first given a copy of it (PERF.md, PR 27)."""
+    import re
+    shapes = set()
+    for s in stacks:
+        shapes |= {s, s[1:], s[2:], (s[0] * s[1],) + s[2:]}
+    return [name for name, dims, op in re.findall(
+        r"%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", hlo)
+        if tuple(int(d) for d in dims.split(",")) in shapes
+        and (op == "copy" or "dynamic-" in op + name or "slice" in op + name)]
+
+
+# (rows, groups stacked, K, N): each sparse configuration's widest prefill
+GROUPED_SHAPES = {"dots-4096": (8192, 64, 7168, 2048),
+                  "mimo-8192": (16384, 96, 4096, 2048),
+                  "olmoe-4096": (32768, 512, 2048, 1024),
+                  "keye-8192": (65536, 512, 2048, 768)}
+
+
+@pytest.mark.parametrize("matrix", ["gate-up", "down"])
+@pytest.mark.parametrize("shape", sorted(GROUPED_SHAPES))
+def test_grouped_matmul_compiles_for_v5e(topo, shape, matrix, monkeypatch):
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    m, g, k, n = GROUPED_SHAPES[shape]
+    if matrix == "down":
+        k, n = n, k
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    before = attention.attention_path_counts().get("experts_grouped_pallas", 0)
+    lowered = jax.jit(moe.grouped_matmul).lower(
+        sds((m, k), jnp.bfloat16), sds((g, k, n), jnp.bfloat16),
+        sds((g,), jnp.int32))
+    assert attention.attention_path_counts()["experts_grouped_pallas"] \
+        == before + 1
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text and "grouped_matmul" in text
+    compiled = lowered.compile()
+    assert not _moved_stacks(compiled.as_text(), [(1, g, k, n)])
+    # the visit lists and nothing else: no second result, no copy of a stack
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("config", ["dots.vlm1.inst-serve",
+                                    "mimo-v2-flash-serve"])
+def test_a_shares_prefill_reads_the_expert_stacks_where_they_lie_on_v5e(
+        topo, config, monkeypatch):
+    """The 2,048-wide prefill of the two configurations that hold a SHARE of
+    their experts, at the cells' sizes: every sparse layer's three grouped
+    matmuls are the Pallas kernel, handed the stacks of all layers, and the
+    compiled program holds no copy or slice of a stack, of a layer of one or
+    of an expert's matrix."""
+    import json
+
+    from benchmark import models
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.serve.engine import _build_fns
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", config + ".json")) as f:
+        model = json.load(f)
+    eng = model["deployment"]["engine"]
+    cfg = models.adapter(model["arch"]).build_config(model, model["dtypes"],
+                                                     eng["max_seq"])
+    maxp = eng["max_seq"] // eng["page_size"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    prefill, _, _, _, empty = _build_fns(cfg, eng["n_slots"],
+                                         eng["decode_chunk"],
+                                         eng["page_size"], eng["kv_pages"])
+    params = shaped(jax.eval_shape(
+        lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)), cfg)))
+    caches = shaped(jax.eval_shape(empty))
+    kc, vc = caches[0], caches[1]
+    state = caches[2] if len(caches) > 2 else None
+    before = attention.attention_path_counts()
+    lowered = prefill.lower(
+        params, kc, vc, sds((maxp,), jnp.int32), sds((1, 2048), jnp.int32),
+        1, 0.0, 0, sds((2,), jnp.uint32), None, state,
+        None if state is None else 0)
+    counts = attention.attention_path_counts()
+    assert counts["experts_grouped_pallas"] > before.get(
+        "experts_grouped_pallas", 0)
+    assert counts.get("experts_ragged_dot", 0) == before.get(
+        "experts_ragged_dot", 0)
+    assert "grouped_matmul" in lowered.as_text()
+    stacks = [tuple(params[stack][w].shape)
+              for stack in ("layers", "window") if stack in params
+              for w in ("w_gate", "w_up", "w_down")
+              if "router" in params[stack]]
+    assert stacks and all(len(s) == 4 for s in stacks)
+    hlo = lowered.compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
+    assert not _moved_stacks(hlo, stacks)
+
+
+# ---------------------------------------------------------------------------
 # A riding rung's prefill (serve/engine.py::rung_rides) at the cells' sizes
 # ---------------------------------------------------------------------------
 
