@@ -17,12 +17,12 @@ data-dependent branch. Which pages a slot holds is the host's business
     its own, `[n_layers, n_pages, page, index_dim]` (``empty_index``,
     ``write_prompt_rows``, ``write_token_rows``). Same pages, same null
     page, same host policy: a slot's page p holds its K, V and indexer keys.
-    `ops.sparse_attention.sparse_decode_attention` reads all three. Its
-    decode gathers single positions, not pages, so its K and V lie BY TOKEN
-    (``empty(..., by_token=True)``): `[n_layers, n_pages, page, kv_heads *
-    head_dim]`, a position's K of every kv head one row of 1 KiB, which a
-    gather moves three times as fast as a row of 256 B a head (0.53 against
-    1.50 ms for 16 x 2,048 positions of one layer on a v5e; PERF.md, PR 32).
+    `ops.sparse_attention.sparse_decode_attention` reads all three. Its K
+    and V lie BY TOKEN (``empty(..., by_token=True)``): `[n_layers, n_pages,
+    page, kv_heads * head_dim]`. A page is then ONE contiguous run, which its
+    decode kernel streams as `paged_decode` does a page here (PERF.md, PR 44),
+    and a position's K of every kv head one row of 1 KiB, which the gather it
+    keeps for wide tables moves three times as fast as 256 B a head (PR 32).
     ``write_prompt`` and ``write_token`` tell the two layouts by their rank,
     and write a by-token arena as they write the indexer's: a row a position.
   * A latent-attention model (MLA) keeps a THIRD kind of row and no other:

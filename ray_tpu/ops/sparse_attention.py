@@ -18,15 +18,15 @@ accumulated in float32 from the inputs' dtype and never exist as
 
   * ``sparse_attention`` is the whole-sequence form (training, the engine's
     prefill): on a TPU two Pallas kernels, `index_select` (scores and
-    selection of a block of query rows in fast memory, out comes the
-    selection's mask) and `masked_flash` (flash attention under that mask,
-    a kv head's whole group of query heads a grid step, so K, V and the mask
-    are read once a group); elsewhere XLA, a block of queries at a time.
-    Which ran is counted at trace time in `attention.attention_path_counts()`
-    as `sparse_pallas` / `sparse_reference`.
+    selection of a block of query rows in fast memory, out comes the mask)
+    and `masked_flash` (flash attention under it, a kv head's whole group of
+    query heads a grid step); elsewhere XLA, a block of queries at a time
+    (`attention.attention_path_counts()`: `sparse_pallas`, `sparse_reference`).
   * ``sparse_decode_attention`` is one query token a slot against the paged
-    caches (`ops/paged_kv.py`): it scores the slot's live indexer keys,
-    selects, and gathers ONLY the selected K and V rows out of the arena.
+    caches (`ops/paged_kv.py`; K and V BY TOKEN): it scores the slot's live
+    indexer keys, selects, and on a TPU STREAMS the slot's live pages under
+    the selection's mask (`sparse_paged_decode`: a page is one contiguous
+    run) while `_streams` says so; else it gathers the selected rows alone.
 
 Scope names `indexer`, `select` and `sparse_attn` lie inside the caller's
 `attn`.
@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, paged_kv
 from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
 
 _INT_MIN = -2 ** 31
@@ -385,9 +385,202 @@ def sparse_attention(q, k, v, qi, ki, w, topk: int, *,
 # Decode: one query token a slot against the paged caches
 # ---------------------------------------------------------------------------
 
+# Which way a decode step reads the selected K and V (`_streams`). A gather
+# of the selected rows costs by `topk` (1.2 ms a layer for 16 slots x 2,048
+# rows of 1 KiB on a v5e, 7% of the memory's rate: a DMA a row); a stream of
+# the slot's live pages under the selection's mask costs by the live context.
+# The table's static width bounds that context, so the rule is the width
+# against `topk`; the constant is the probe's (PERF.md, PR 44).
+_STREAM_UP_TO = 8
+
+# The stream's unit of DMA and of matmul, K and V of a block in two buffers
+# each (4 MiB at 4 kv heads of 128 in bfloat16). Where `paged_decode`'s layout
+# gains nothing past 512 tokens (`paged_kv._DECODE_BLOCK_TOKENS`), a block by
+# token is 16 DMAs of 64 KiB and reads 8-13% faster at 1,024 than at 512 from
+# 7,000 live positions a slot on, the same under them (a v5e; PERF.md, PR 44).
+_STREAM_BLOCK_TOKENS = 1024
+
+
+def _streams(ctx: int, topk: int) -> bool:
+    """Whether a decode step over a block table `ctx` positions wide streams
+    the live pages (True) or gathers the `topk` selected rows."""
+    return ctx <= _STREAM_UP_TO * topk
+
+
+def decode_select_mask(scores: jax.Array, topk: int) -> jax.Array:
+    """scores [ns, ctx] float32, -inf at the positions a slot may not read ->
+    int8 [ns, ctx]: 1 at the positions `jax.lax.top_k(scores, topk)` returns
+    with a finite score, exactly: every score above the topk-th largest, and
+    of the ties AT it the earliest (`top_k`'s order), as many as are left."""
+    live = scores > -jnp.inf
+    if topk >= scores.shape[-1]:
+        return live.astype(jnp.int8)
+    vals, _ = jax.lax.top_k(scores, topk)
+    t = vals[:, -1:]
+    above, tied = scores > t, scores == t
+    need = topk - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    nth = jnp.cumsum(tied, axis=-1, dtype=jnp.int32)   # a tie's rank, from 1
+    return ((above | (tied & (nth <= need))) & live).astype(jnp.int8)
+
+
+def _sparse_paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, mask_ref,
+                                k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref,
+                                l_ref, acc_ref, *, sm_scale: float,
+                                groups: int, split: bool,
+                                pages_per_block: int):
+    """One grid step = one slot, as `paged_kv._paged_decode_kernel`: its live
+    pages come in by DMA, a block of `pages_per_block` at a time,
+    double-buffered, under a DYNAMIC trip count. The arena lies by token, so
+    a page `[page, KVH * hd]` is one contiguous run and kv head h's keys are
+    the lanes `h * hd ..` of the block. A score counts where the slot's row
+    of the selection's mask is set and the position is under its length.
+    Online softmax a kv head over its query heads, float32 statistics and
+    accumulator."""
+    _, T, _ = kbuf.shape
+    n_kv, rows, hd = q_ref.shape[1:]
+    page = T // pages_per_block
+    max_pages = bt_ref.shape[1]
+    slot = pl.program_id(0)
+    layer = layer_ref[0]
+    length = len_ref[slot]
+    live_pages = pl.cdiv(length, page)
+    n_blocks = pl.cdiv(live_pages, pages_per_block)
+
+    @pl.when(slot == 0)
+    def _clear():
+        # A block's tail past the live pages is never fetched, only masked:
+        # what lies there must be finite (0 x NaN is NaN).
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    def each_copy(block, buf, fn):
+        for i in range(pages_per_block):
+            idx = block * pages_per_block + i
+            page_id = bt_ref[slot, jnp.minimum(idx, max_pages - 1)]
+            at = pl.ds(i * page, page)
+
+            @pl.when(idx < live_pages)
+            def _():
+                fn(pltpu.make_async_copy(k_hbm.at[layer, page_id],
+                                         kbuf.at[buf, at, :], sem.at[0, buf]))
+                fn(pltpu.make_async_copy(v_hbm.at[layer, page_id],
+                                         vbuf.at[buf, at, :], sem.at[1, buf]))
+
+    m_ref[...] = jnp.full_like(m_ref, DEFAULT_MASK_VALUE)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        each_copy(0, 0, lambda c: c.start())
+
+    def block_body(b, carry):
+        buf = b % 2
+
+        @pl.when(b + 1 < n_blocks)
+        def _next():
+            each_copy(b + 1, 1 - buf, lambda c: c.start())
+
+        each_copy(b, buf, lambda c: c.wait())
+        picked = mask_ref[0, :, pl.ds(pl.multiple_of(b * T, T), T)]  # [1, T]
+        keep = (picked.astype(jnp.int32) != 0) & (
+            b * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1) < length)
+        keep = jnp.broadcast_to(keep, (rows, T))
+        upper = jax.lax.broadcasted_iota(jnp.int32, (rows, T), 0) < groups
+        for h in range(n_kv):
+            lanes = pl.ds(h * hd, hd)
+            k = kbuf[buf, :, lanes]                              # [T, hd]
+            v = vbuf[buf, :, lanes]
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale   # [rows, T]
+            s = jnp.where(keep, s, DEFAULT_MASK_VALUE)
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # While a head has met no selected position m is the mask value
+            # and p is 1 at every masked column: `corr` == 0 wipes that sum
+            # when its first real score arrives, and a live slot selects some.
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            if split:
+                # q's rows come twice: the upper copy carries p rounded to
+                # the cache's dtype, the lower what the rounding dropped
+                # (`paged_kv._paged_decode_kernel`).
+                p = jnp.where(upper, p,
+                              p - p.astype(v.dtype).astype(jnp.float32))
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block_body, 0)
+    # An idle slot (length 0) walked nothing: l is 0 and so is its output.
+    o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+def _sparse_paged_decode(q, mask, kc, vc, layer, block_table, lengths, *,
+                         sm_scale, interpret=False):
+    """q [ns, H, hd], mask int8 [ns, ctx], kc/vc `[L, n_pages, page, KVH *
+    hd]`: softmax attention of each slot's query heads over the positions
+    its mask names, under `lengths`. -> [ns, H, hd]."""
+    ns, H, hd = q.shape
+    _, _, page, row = kc.shape
+    n_kv = row // hd
+    groups = H // n_kv
+    # float32 softmax weights against a narrower cache: see `split` above.
+    split = jnp.dtype(kc.dtype).itemsize < 4
+    copies = 2 if split else 1
+    tile = paged_kv._sublanes(kc.dtype)
+    rows = -(-copies * groups // tile) * tile
+    qg = q.reshape(ns, n_kv, groups, hd).astype(kc.dtype)
+    qg = jnp.concatenate(
+        [qg] * copies + [jnp.zeros((ns, n_kv, rows - copies * groups, hd),
+                                   kc.dtype)], axis=2)
+    pages_per_block = min(max(1, _STREAM_BLOCK_TOKENS // page),
+                          block_table.shape[1])
+    T = pages_per_block * page
+    ctx = mask.shape[1]
+    mask = jnp.pad(mask, ((0, 0), (0, -ctx % T)))[:, None]     # whole blocks
+    kernel = functools.partial(
+        _sparse_paged_decode_kernel, sm_scale=sm_scale, groups=groups,
+        split=split, pages_per_block=pages_per_block)
+    slot_block = pl.BlockSpec((1, n_kv, rows, hd), lambda s, *_: (s, 0, 0, 0))
+    out = pl.pallas_call(
+        kernel,
+        name="sparse_paged_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,       # layer, lengths, block table
+            grid=(ns,),
+            in_specs=[slot_block,
+                      pl.BlockSpec((1, 1, mask.shape[2]),
+                                   lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=slot_block,
+            scratch_shapes=[
+                pltpu.VMEM((2, T, row), kc.dtype),
+                pltpu.VMEM((2, T, row), vc.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rows, hd), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((ns, n_kv, rows, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), lengths.astype(jnp.int32),
+      block_table.astype(jnp.int32), qg, mask, kc, vc)
+    out = sum(out[:, :, i * groups:(i + 1) * groups] for i in range(copies))
+    return out.reshape(ns, H, hd).astype(q.dtype)
+
+
 def sparse_decode_attention(q, qi, w, kc, vc, ic, layer, block_table,
                             lengths, topk: int, *,
-                            sm_scale: Optional[float] = None) -> jax.Array:
+                            sm_scale: Optional[float] = None,
+                            interpret: bool = False) -> jax.Array:
     """q [ns, H, hd]; qi [ns, IH, Id] and w [ns, IH] this token's indexer
     query and head weights; kc, vc the K/V arena laid out BY TOKEN, `[L,
     n_pages, page, KVH * hd]`, and ic the indexer keys' `[L, n_pages, page,
@@ -396,19 +589,36 @@ def sparse_decode_attention(q, qi, w, kc, vc, ic, layer, block_table,
     read (0: an idle slot, whose output is 0). -> [ns, H, hd].
 
     Scores every live indexer key of the slot (its pages of `ic`: 128 B a
-    position), selects the `topk` largest exactly, and gathers the selected
-    rows of K and V alone out of the arena."""
+    position) and selects the `topk` largest exactly. Then, on a TPU (or
+    with `interpret`) and while the table is no wider than `_streams` says,
+    the Pallas kernel `sparse_paged_decode` streams the slot's live pages of
+    K and V where they lie and counts the selected positions alone;
+    otherwise XLA gathers the selected rows out of the arena. Which one a
+    program took is in `attention.attention_path_counts()` as
+    `sparse_decode_stream_pallas` / `sparse_decode_gather`."""
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     ns, H, hd = q.shape
     _, n_pages, page, row = kc.shape
     KVH = row // hd
     ctx = block_table.shape[1] * page
     kk = min(topk, ctx)
+    stream = interpret or (
+        attention._on_tpu() and _streams(ctx, topk) and hd % 128 == 0
+        and page % paged_kv._sublanes(kc.dtype) == 0)
+    attention._path_counts["sparse_decode_stream_pallas" if stream
+                           else "sparse_decode_gather"] += 1
     with jax.named_scope("indexer"):
         keys = ic[layer, block_table].reshape(ns, ctx, ic.shape[-1])
         scores = index_scores(qi[:, None], keys, w[:, None])[:, 0]   # [ns, ctx]
         live = jnp.arange(ctx)[None, :] < lengths[:, None]
         scores = jnp.where(live, scores, -jnp.inf)
+    if stream:
+        with jax.named_scope("select"):
+            mask = decode_select_mask(scores, kk)
+        with jax.named_scope("sparse_attn"):
+            return _sparse_paged_decode(
+                q, mask, kc, vc, layer, block_table, lengths, sm_scale=scale,
+                interpret=interpret)
     with jax.named_scope("select"):
         vals, idx = jax.lax.top_k(scores, kk)                 # [ns, kk]
         picked = vals > -jnp.inf

@@ -297,6 +297,144 @@ def test_sparse_attention_kernels_equal_the_xla_path():
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
 
 
+def _decode_case(lengths, dtype=jnp.float32, nulls=False, seed=0):
+    """Four slots of a by-token arena (pages of 16, a table of 8: 128
+    positions), 4 query heads over 2 kv heads of 128, 4 indexer heads of 16;
+    the arena's every row random, so what a slot must not read would show."""
+    ns, H, KVH, hd, IH, Id, page, maxp, L = len(lengths), 4, 2, 128, 4, 16, \
+        16, 8, 2
+    n_pages = 1 + ns * maxp
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    kc, vc = (jax.random.normal(k, (L, n_pages, page, KVH * hd)).astype(dtype)
+              for k in ks[:2])
+    ic = jax.random.normal(ks[2], (L, n_pages, page, Id)).astype(dtype)
+    q = jax.random.normal(ks[3], (ns, H, hd)).astype(dtype)
+    qi = jax.random.normal(ks[4], (ns, IH, Id)).astype(dtype)
+    w = jax.random.normal(ks[5], (ns, IH))
+    table = 1 + np.asarray(jax.random.permutation(ks[6], n_pages - 1)
+                           ).reshape(ns, maxp)
+    if nulls:       # as the pool leaves a table: null past the pages held
+        for slot, n in enumerate(lengths):
+            table[slot, -(-n // page):] = 0
+    return (q, qi, w, kc, vc, ic, 1, jnp.asarray(table, jnp.int32),
+            jnp.asarray(lengths, jnp.int32))
+
+
+@pytest.mark.parametrize("case,lengths,kw", [
+    ("under top-k", [5, 20, 31, 32], {}),
+    ("over top-k", [33, 64, 100, 128], {}),
+    ("not a multiple of the page", [17, 45, 99, 127], {}),
+    ("across a page boundary", [16, 17, 48, 49], {}),
+    ("an idle slot", [0, 70, 0, 3], {}),
+    ("a table with null pages", [1, 40, 0, 97], {"nulls": True}),
+    ("bfloat16 caches", [5, 40, 100, 0], {"dtype": jnp.bfloat16}),
+])
+def test_streaming_decode_equals_the_gather(case, lengths, kw, monkeypatch):
+    """`sparse_paged_decode` (interpreted here; blocks of two pages, so a
+    slot walks up to four) against the XLA gather of the selected rows, on
+    one indexer's scores: the same attention over the same top-32 set,
+    whatever else lies in the slot's pages, the null page or its buffers."""
+    monkeypatch.setattr(sparse_attention, "_STREAM_BLOCK_TOKENS", 32)
+    args = _decode_case(lengths, **kw)
+    before = sparse_attention.attention.attention_path_counts()
+    want = sparse_attention.sparse_decode_attention(*args, 32)
+    got = sparse_attention.sparse_decode_attention(*args, 32, interpret=True)
+    after = sparse_attention.attention.attention_path_counts()
+    assert [after.get(k, 0) - before.get(k, 0) for k in
+            ("sparse_decode_gather", "sparse_decode_stream_pallas")] == [1, 1]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    tol = 1e-5 if got.dtype == jnp.float32 else 2e-2
+    assert np.abs(np.asarray(got, np.float32)
+                  - np.asarray(want, np.float32)).max() < tol
+    idle = np.asarray(lengths) == 0
+    assert not np.asarray(got, np.float32)[idle].any()
+
+
+@pytest.mark.parametrize("ties", ["none", "some", "all"])
+def test_decodes_mask_is_exactly_top_ks_set_ties_included(ties):
+    """`decode_select_mask` against a scatter of `lax.top_k`'s indices on
+    the same scores, dead positions at -inf: rows shorter than top-k, longer,
+    and idle; integer-valued scores tie at the threshold, equal scores tie
+    everywhere, and the earliest positions win."""
+    ns, ctx, topk = 6, 256, 64
+    scores = jax.random.normal(jax.random.PRNGKey(5), (ns, ctx)) * 3
+    if ties == "some":
+        scores = jnp.round(scores)
+    if ties == "all":        # +0.0, as `index_scores` leaves an exact zero
+        scores = jnp.zeros_like(scores)
+    lengths = jnp.asarray([0, 1, 63, 64, 65, 256])
+    scores = jnp.where(jnp.arange(ctx)[None] < lengths[:, None], scores,
+                       -jnp.inf)
+    vals, idx = jax.lax.top_k(scores, topk)
+    want = np.zeros((ns, ctx), bool)
+    for row in range(ns):
+        want[row, np.asarray(idx[row])[np.asarray(vals[row]) > -np.inf]] = True
+    got = np.asarray(sparse_attention.decode_select_mask(scores, topk))
+    assert got.dtype == np.int8
+    np.testing.assert_array_equal(got != 0, want)
+    assert list(want.sum(1)) == [0, 1, 63, 64, 64, 64]
+    if ties == "all":
+        assert want[5, :topk].all()
+    if ties == "some":       # the threshold really is tied, and cut
+        t = np.asarray(vals[5, -1])
+        assert (np.asarray(scores[5]) == t).sum() > (want[5] & (
+            np.asarray(scores[5]) == t)).sum() > 0
+    np.testing.assert_array_equal(
+        np.asarray(sparse_attention.decode_select_mask(scores, ctx)) != 0,
+        np.asarray(scores) > -np.inf)
+
+
+def test_an_engine_decodes_through_the_streaming_kernel(tiny, monkeypatch):
+    """The engine's decode program with `sparse_paged_decode` in it
+    (interpreted; blocks of two pages): a prompt under top-k whose decode
+    crosses it and two page boundaries, and one that starts past it, serve
+    the reference's tokens."""
+    import functools
+    cfg, params = tiny
+    monkeypatch.setattr(sparse_attention, "_STREAM_BLOCK_TOKENS", 32)
+    monkeypatch.setattr(
+        sparse_attention, "sparse_decode_attention", functools.partial(
+            sparse_attention.sparse_decode_attention, interpret=True))
+    before = sparse_attention.attention.attention_path_counts()
+    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=2,
+                 decode_chunk=4, page_size=16)
+    try:
+        prompts = [_tokens(21, 5), _tokens(70, 6)]
+        served = _serve(eng, prompts, 16)
+    finally:
+        eng.stop()
+    after = sparse_attention.attention.attention_path_counts()
+    assert after.get("sparse_decode_stream_pallas", 0) \
+        > before.get("sparse_decode_stream_pallas", 0)
+    assert after.get("sparse_decode_gather", 0) \
+        == before.get("sparse_decode_gather", 0)
+    for prompt, toks in zip(prompts, served):
+        assert len(toks) == 16
+        assert max(ref.served_token_gaps(params, MODEL, prompt, toks)) \
+            < LOGIT_TOL
+
+
+def test_the_tables_width_against_top_k_chooses_the_decode_path(monkeypatch):
+    """One rule in one place: a decode step streams while the block table is
+    at most `_STREAM_UP_TO` x top-k positions wide, on a TPU; wider, or off
+    the TPU, it gathers."""
+    assert sparse_attention._streams(8192, 2048)
+    assert sparse_attention._streams(16384, 2048)
+    assert not sparse_attention._streams(16384 + 64, 2048)
+    args = _decode_case([5, 40, 100, 0])
+    monkeypatch.setattr(sparse_attention.attention, "_on_tpu", lambda: True)
+
+    def path(topk):
+        before = sparse_attention.attention.attention_path_counts()
+        jax.eval_shape(lambda *a: sparse_attention.sparse_decode_attention(
+            *a, topk), *args)
+        after = sparse_attention.attention.attention_path_counts()
+        return {k for k in after if after[k] != before.get(k, 0)}
+
+    assert path(8) == {"sparse_decode_gather"}          # 128 > 8 x 8
+    assert path(16) == {"sparse_decode_stream_pallas"}
+
+
 # -- (d) what the check's tolerance means ------------------------------------
 
 def test_bf16_and_float32_select_the_same_keys_but_for_near_ties(tiny):

@@ -263,6 +263,36 @@ def test_sparse_attention_kernels_compile_for_v5e(topo, kernel, S):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.mark.parametrize("max_pages", [128, 256])
+def test_sparse_paged_decode_compiles_for_v5e(topo, max_pages):
+    """Decode's streaming kernel at the cell's sizes: 16 slots, 128 pages of
+    64 a slot (and the widest table that still streams at top-k 2,048), 4 kv
+    heads of 128 by token, bfloat16, the selection an int8 row a slot. One
+    Mosaic kernel, the arenas its operands as they come, nothing set aside."""
+    import re
+
+    from ray_tpu.ops import sparse_attention as sa
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ns, page = 16, 64
+    assert sa._streams(max_pages * page, 2048)
+    arena = sds((4, ns * max_pages + 1, page, 4 * 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, m, kc, vc, layer, bt, n: sa._sparse_paged_decode(
+            q, m, kc, vc, layer, bt, n, sm_scale=128 ** -0.5)).lower(
+        sds((ns, 32, 128), jnp.bfloat16), sds((ns, max_pages * page), jnp.int8),
+        arena, arena, sds((), jnp.int32), sds((ns, max_pages), jnp.int32),
+        sds((ns,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not re.search(r"bf16\[4,%d,64,512\]\S* (copy|transpose)\("
+                         % (ns * max_pages + 1), text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def test_sparse_decode_leaves_the_arenas_where_they_lie_on_v5e(
         topo, monkeypatch):
     """The decode chunk of a model with an indexer, at Keye's widths and the
@@ -316,6 +346,13 @@ def test_sparse_decode_leaves_the_arenas_where_they_lie_on_v5e(
     assert kc.shape == (2, eng["kv_pages"], page, 4 * 128)
     one_slab = kc.shape[1] * page * kc.shape[3] * 2
     assert compiled.memory_analysis().temp_size_in_bytes < one_slab
+    # The table is 4 x top-k wide, so the step streams (`sa._streams`): the
+    # kernel is in the program and no gathered `[ns * topk, KVH * hd]` rows
+    # (2 x 32 MiB a layer, until PR 44) are left in it.
+    text = compiled.as_text()
+    assert "sparse_paged_decode" in text
+    assert not re.search(r"\[%d,%d\]" % (ns * cfg.index_topk, kc.shape[3]),
+                         text)
 
 
 # ---------------------------------------------------------------------------
