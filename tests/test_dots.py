@@ -29,6 +29,7 @@ from ray_tpu.models.block import (fuse_qkv, latent_attention_inputs,
 from ray_tpu.ops import attention, moe, paged_kv
 from ray_tpu.ops.norms import apply_rope, yarn_inv_frequencies
 from ray_tpu.serve.engine import Engine, _make_prefill_core
+from test_mimo import PUBLISHED as MIMO
 
 LOGIT_TOL = 2e-4
 F32 = {"params": "float32", "activations": "float32"}
@@ -510,6 +511,8 @@ def test_the_configuration_is_the_catalogs_row_cut_to_a_share():
 # (`_token_step`: the same write and kernel, one trace a process) and were
 # taken anew on PR 41's tree (their parent's: 4ce2defd4ff49240 and
 # 278d751dc50fcfc4); the three stacks that take nobody keep the parent's.
+# `mixed` (PR 42's stack, which takes nobody) was taken on PR 45's parent
+# (6c2c097), before that PR moved a line under ray_tpu/.
 PARENT = {
     "dense.decode": "d87712c9b4ee5285",
     "dense.prefill32": "c948937b09fe2fee",
@@ -519,6 +522,8 @@ PARENT = {
     "indexed.prefill64": "2b26fc68f7f5f898",
     "latent.decode": "3cbcf9da23401fa5",
     "latent.prefill64": "f96e02f0c080c3fb",
+    "mixed.decode": "c31b6808d133c647",
+    "mixed.prefill64": "ef4db6b4bc528b78",
     "sparse.decode": "94dff0eb228ce990",
     "sparse.prefill32": "7a5fc5aa7c158c94",
     "train.tiny": "569d197c86234e93",
@@ -532,6 +537,7 @@ KINDS = {
     "hybrid": ("jamba", dict(rms_norm_eps=1e-6, num_experts=1,
                              tie_word_embeddings=True)),
     "latent": ("dots", PUBLISHED),
+    "mixed": ("mimo", MIMO),
 }
 
 
@@ -576,10 +582,10 @@ def _lowered(kind):
 
 
 @pytest.mark.parametrize("kind", ["dense", "sparse", "indexed", "hybrid",
-                                  "latent", "train"])
+                                  "latent", "mixed", "train"])
 def test_the_other_models_programs_are_the_parents(kind):
     """What a dense, a sparse (softmax router, every expert), an indexed, a
-    hybrid and a latent engine's prefill (a rung that takes no riders) and
+    hybrid, a latent and a mixed engine's prefill (a rung that takes no riders) and
     decode, and a dense train step, lower to is letter for letter what the
     parent commit lowers them to."""
     got = _lowered(kind)
