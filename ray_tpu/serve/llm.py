@@ -238,7 +238,8 @@ class PrefillServer:
         import jax.numpy as jnp
 
         from ray_tpu.models.block import fuse_qkv
-        from ray_tpu.serve.engine import _make_prefill_core, doubling_widths
+        from ray_tpu.models.serving import prefill_core
+        from ray_tpu.serve.engine import doubling_widths
 
         cfg: LLMConfig = cloudpickle.loads(cfg_blob)
         self.cfg = cfg
@@ -253,12 +254,12 @@ class PrefillServer:
                 "rings): this model serves from one engine")
         # The layout the shared prefill core reads, as in the engine.
         self.params = fuse_qkv(params)
-        self._core = jax.jit(_make_prefill_core(self.mcfg))
-        from ray_tpu.serve.engine import _sample_tokens
+        self._core = jax.jit(prefill_core(self.mcfg))
+        from ray_tpu.models.serving import sample_tokens
 
         def _sample_first(row, temp, topk, key, pos):
             import jax.numpy as jnp
-            return _sample_tokens(row[None], jnp.asarray(temp)[None],
+            return sample_tokens(row[None], jnp.asarray(temp)[None],
                                   jnp.asarray(topk)[None], key[None],
                                   jnp.asarray(pos)[None])[0]
 

@@ -879,6 +879,7 @@ def test_the_engines_spans_carry_what_the_window_readers_read():
     from benchmark import window_trace
     from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import init_params
+    from ray_tpu.models.serving import build_programs
     from ray_tpu.serve import engine as engine_mod
 
     adapter = models.adapter("mimo")
@@ -886,7 +887,7 @@ def test_the_engines_spans_carry_what_the_window_readers_read():
     model = dict(m, **adapter.REHEARSE)
     cfg = adapter.build_config(model, {"params": "float32",
                                        "activations": "float32"}, 128)
-    prefill, decode, _, _, empty = engine_mod._build_fns(cfg, 2, 2, 16, 17)
+    prefill, decode, _, _, empty = build_programs(cfg, 2, 2, 16, 17)
     params = jax.eval_shape(lambda: fuse_qkv(
         init_params(cfg, jax.random.PRNGKey(0)), cfg))
     kc, vc, state = jax.eval_shape(empty)

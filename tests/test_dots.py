@@ -28,7 +28,8 @@ from ray_tpu.models.block import (fuse_qkv, latent_attention_inputs,
                                   latent_attention_output, split_qkv)
 from ray_tpu.ops import attention, moe, paged_kv
 from ray_tpu.ops.norms import apply_rope, yarn_inv_frequencies
-from ray_tpu.serve.engine import Engine, _make_prefill_core
+from ray_tpu.models.serving import prefill_core
+from ray_tpu.serve.engine import Engine
 from test_mimo import PUBLISHED as MIMO
 
 LOGIT_TOL = 2e-4
@@ -246,8 +247,8 @@ def test_a_share_walked_in_several_blocks_is_the_share_in_one(
 # ---------------------------------------------------------------------------
 
 def _rope_tables(cfg, n):
-    from ray_tpu.serve.engine import _latent_rope_tables
-    return _latent_rope_tables(cfg, n)
+    from ray_tpu.models.serving import latent_rope_tables
+    return latent_rope_tables(cfg, n)
 
 
 def test_the_absorbed_decode_form_is_the_naive_form(tiny):
@@ -357,7 +358,7 @@ def test_latent_flash_kernel_is_its_reference_path(S):
 @pytest.fixture(scope="module")
 def engine(tiny):
     """The engine with both latent-attention kernels interpreted: what
-    `_build_fns` and the prefill core import is the function with its
+    `build_programs` and the prefill core import is the function with its
     `interpret` argument set."""
     _, _, cfg, params = tiny
     mp = pytest.MonkeyPatch()
@@ -382,7 +383,7 @@ def test_prefill_then_decode_through_the_latent_cache_is_the_reference(
     adapter, model, cfg, params = tiny
     prompt = _tokens(n, n)
     ref = adapter.reference()
-    _, rows, vs, logits, experts = jax.jit(_make_prefill_core(cfg))(
+    _, rows, vs, logits, experts = jax.jit(prefill_core(cfg))(
         fuse_qkv(params, cfg),
         jnp.asarray([prompt + [0] * (bucket - n)], jnp.int32), n)
     want = np.asarray(ref.logits_last(params, model, prompt, 1))[0]

@@ -30,7 +30,8 @@ from benchmark import reference_jamba as ref
 from ray_tpu.models import block, llama
 from ray_tpu.models.block import fuse_qkv, mamba_mixer
 from ray_tpu.ops import attention, ssm
-from ray_tpu.serve.engine import Engine, _make_prefill_core
+from ray_tpu.models.serving import prefill_core
+from ray_tpu.serve.engine import Engine
 
 LOGIT_TOL = 2e-4
 SCAN_TOL = 2e-5
@@ -233,7 +234,7 @@ def test_engine_prefill_then_decode_match_the_reference(tiny, engine):
     for prompt, toks in zip(prompts, served):
         gaps = ref.served_token_gaps(params, MODEL, prompt, toks)
         assert max(gaps) < LOGIT_TOL, gaps
-    core = jax.jit(_make_prefill_core(cfg))
+    core = jax.jit(prefill_core(cfg))
     for prompt, width in zip(prompts, (64, 128, 32)):
         padded = jnp.asarray([prompt + [9] * (width - len(prompt))], jnp.int32)
         _, ks, _, logits, experts, (ssm_rows, conv_rows) = core(
@@ -324,7 +325,7 @@ def test_the_head_is_the_embedding_and_attention_takes_no_position(tiny):
     assert params["layers"]["wq"].shape[0] == 1       # one attention layer
     assert params["mamba"]["in_proj"].shape[0] == 3   # three Mamba layers
     prompt = _tokens(64, 9)
-    core = jax.jit(_make_prefill_core(cfg))
+    core = jax.jit(prefill_core(cfg))
     got = np.asarray(core(fuse_qkv(params), jnp.asarray([prompt]), 64)[3])
     plain = np.asarray(ref.logits_last(params, MODEL, prompt, 1))[0]
     turned = np.asarray(ref.logits_last(params, MODEL, prompt, 1,

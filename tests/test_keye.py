@@ -29,7 +29,8 @@ from benchmark import reference_keye as ref
 from ray_tpu.models import llama
 from ray_tpu.models.block import attention_inputs, fuse_qkv
 from ray_tpu.ops import norms, sparse_attention
-from ray_tpu.serve.engine import Engine, _make_prefill_core
+from ray_tpu.models.serving import prefill_core
+from ray_tpu.serve.engine import Engine
 
 LOGIT_TOL = 2e-4
 GRAD_REL_TOL = 1e-4
@@ -193,7 +194,7 @@ def test_engine_prefill_then_paged_decode_match_the_reference(tiny, engine):
     for prompt, toks in zip(prompts, served):
         gaps = ref.served_token_gaps(params, MODEL, prompt, toks)
         assert max(gaps) < LOGIT_TOL, gaps
-    core = jax.jit(_make_prefill_core(cfg))
+    core = jax.jit(prefill_core(cfg))
     for prompt, width in zip(prompts, (32, 128, 256)):
         padded = jnp.asarray([prompt + [0] * (width - len(prompt))], jnp.int32)
         _, ks, _, logits, experts, iks = core(fuse_qkv(params), padded,
@@ -214,12 +215,12 @@ def test_a_wide_bucket_meets_the_experts_in_row_blocks_and_nothing_changes(
     program hands the sparse feed-forward at most `_MOE_ROWS` rows at a
     time. Every row is computed from itself alone, so logits, caches and
     the experts' counts are what one pass gives."""
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.models import serving
     cfg, params = tiny
     prompt = jnp.asarray([_tokens(100, 8) + [0] * 28], jnp.int32)
-    whole = jax.jit(_make_prefill_core(cfg))(fuse_qkv(params), prompt, 100)
-    monkeypatch.setattr(engine_mod, "_MOE_ROWS", 32)
-    blocks = jax.jit(_make_prefill_core(cfg))(fuse_qkv(params), prompt, 100)
+    whole = jax.jit(prefill_core(cfg))(fuse_qkv(params), prompt, 100)
+    monkeypatch.setattr(serving, "_MOE_ROWS", 32)
+    blocks = jax.jit(prefill_core(cfg))(fuse_qkv(params), prompt, 100)
     for a, b in zip(whole, blocks):
         assert np.abs(np.asarray(a, np.float32)
                       - np.asarray(b, np.float32)).max() < 1e-5

@@ -26,7 +26,8 @@ from benchmark import models, reference_mimo
 from ray_tpu.models import llama
 from ray_tpu.models.block import fuse_qkv, split_qkv
 from ray_tpu.ops import attention, moe, paged_kv, slot_state
-from ray_tpu.serve.engine import Engine, _make_prefill_core
+from ray_tpu.models.serving import prefill_core
+from ray_tpu.serve.engine import Engine
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGIT_TOL = 2e-4
@@ -408,7 +409,7 @@ def test_prefill_then_decode_through_both_caches_is_the_reference(
     adapter, model, cfg, params = tiny
     prompt = _tokens(n, n)
     ref = adapter.reference()
-    _, ks, vs, logits, experts, (kws, vws) = jax.jit(_make_prefill_core(cfg))(
+    _, ks, vs, logits, experts, (kws, vws) = jax.jit(prefill_core(cfg))(
         fuse_qkv(params, cfg),
         jnp.asarray([prompt + [0] * (bucket - n)], jnp.int32), n)
     want = np.asarray(ref.logits_last(params, model, prompt, 1))[0]

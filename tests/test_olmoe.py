@@ -23,7 +23,8 @@ from benchmark import models
 from benchmark import reference_olmoe as ref
 from ray_tpu.models import llama
 from ray_tpu.models.block import feed_forward, fuse_qkv
-from ray_tpu.serve.engine import Engine, _make_prefill_core
+from ray_tpu.models.serving import prefill_core
+from ray_tpu.serve.engine import Engine
 
 LOGIT_TOL = 2e-4
 # Gradients are sums over 47 positions of products of such numbers: relative
@@ -137,7 +138,7 @@ def test_engine_prefill_then_paged_decode_match_the_reference(tiny, engine):
     for prompt, toks in zip(prompts, served):
         gaps = ref.served_token_gaps(params, MODEL, prompt, toks)
         assert max(gaps) < LOGIT_TOL, gaps
-    core = jax.jit(_make_prefill_core(cfg))
+    core = jax.jit(prefill_core(cfg))
     for prompt in prompts:
         padded = jnp.asarray([prompt + [0] * (64 - len(prompt))], jnp.int32)
         first, _, _, logits, experts = core(fuse_qkv(params), padded,
@@ -163,7 +164,7 @@ def test_engine_tokens_with_the_decode_kernel_equal_the_reference_paths(
     from ray_tpu.ops import attention
 
     cfg, params = tiny
-    core = jax.jit(_make_prefill_core(cfg))
+    core = jax.jit(prefill_core(cfg))
     prompts = [_tokens(14, 11), _tokens(20, 12), _tokens(3, 13)]
 
     def served():
@@ -208,7 +209,7 @@ def test_a_request_alone_beside_fifteen_others_and_in_two_buckets(tiny, engine):
     assert alone == crowd
     # The prefill program in two bucket widths: the padding is computed, takes
     # nobody's place, and changes nothing (float32 sums in another order).
-    core = jax.jit(_make_prefill_core(cfg))
+    core = jax.jit(prefill_core(cfg))
     rows = [np.asarray(core(fuse_qkv(params), jnp.asarray(
         [prompt + [9] * (width - len(prompt))], jnp.int32), len(prompt))[3])
         for width in (32, 128)]
@@ -242,7 +243,7 @@ def test_experts_stored_in_another_dtype_are_cast_once_and_loudly(
     assert {k: str(v.dtype) for k, v in held["layers"].items()
             if v.dtype != jnp.float32} == {
         "w_gate": "bfloat16", "w_up": "bfloat16", "w_down": "bfloat16"}
-    core = jax.jit(_make_prefill_core(half))
+    core = jax.jit(prefill_core(half))
     prompt = jnp.asarray([_tokens(64, 4)], jnp.int32)
     for a, b in zip(core(fuse_qkv(params), prompt, 50),
                     core(fuse_qkv(held), prompt, 50)):
@@ -259,7 +260,7 @@ def test_every_token_to_the_same_experts_still_equals_the_reference(tiny):
     seq = _tokens(64, 8)
     got = np.asarray(llama.forward(params, jnp.asarray([seq], jnp.int32), cfg))[0]
     assert np.abs(got - _ref_logits(params, seq, 64)).max() < LOGIT_TOL
-    core = jax.jit(_make_prefill_core(cfg))
+    core = jax.jit(prefill_core(cfg))
     experts = np.asarray(
         core(fuse_qkv(params), jnp.asarray([seq], jnp.int32), 64)[4])
     assert list(experts) == [128, 128, 0, 0, 0, 0, 0, 0, 4]

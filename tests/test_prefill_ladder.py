@@ -29,8 +29,8 @@ from ray_tpu.models import block
 from ray_tpu.models.block import fuse_qkv
 from ray_tpu.ops import ssm
 from ray_tpu.ops.attention import attention_path_counts
-from ray_tpu.serve.engine import (Engine, _make_prefill_core, doubling_widths,
-                                  prefill_widths)
+from ray_tpu.models.serving import prefill_core
+from ray_tpu.serve.engine import Engine, doubling_widths, prefill_widths
 from ray_tpu.utils import tracing
 
 LOGIT_TOL = 2e-4
@@ -158,7 +158,7 @@ def test_a_prompt_of_1100_prefills_1536_wide_and_serves_the_reference_tokens(
         assert _padded_since(eng, before) == (PROMPT, 1536 - PROMPT)
         if kind == "hybrid":
             state, window = (np.asarray(a) for a in eng._state)
-            *_, (want_ssm, want_window) = jax.jit(_make_prefill_core(cfg))(
+            *_, (want_ssm, want_window) = jax.jit(prefill_core(cfg))(
                 fuse_qkv(params), jnp.asarray([prompt], jnp.int32), PROMPT)
             # values of size ~1, summed in another order at another width
             assert np.abs(state[:, 0] - np.asarray(want_ssm)).max() < 2e-5

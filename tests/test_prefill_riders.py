@@ -24,8 +24,9 @@ import jax
 import jax.numpy as jnp
 
 from benchmark import models
-from ray_tpu.serve.engine import (_DEPTH, Engine, _make_prefill_core,
-                                  prefill_widths, rung_rides)
+from ray_tpu.models.serving import prefill_core
+from ray_tpu.serve.engine import (_DEPTH, Engine, prefill_widths,
+                                  rung_rides)
 from ray_tpu.utils import tracing
 from test_dots import MIMO, PUBLISHED
 from test_prefill_ladder import F32, KINDS, LOGIT_TOL, _tiny, _tokens
@@ -318,7 +319,7 @@ def _rung_digests(kind):
     eng = Engine(adapter.init_params(cfg, 3), cfg, n_slots=2, decode_chunk=2,
                  page_size=16)
     try:
-        assert eng._prefill.takes_riders is _make_prefill_core(
+        assert eng._prefill.takes_riders is prefill_core(
             cfg).takes_riders
         riding = [w for w in eng.buckets if eng._rides(w)]
         texts = {w: eng.lowered_prefill_text(w) for w in eng.buckets}

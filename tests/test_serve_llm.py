@@ -373,13 +373,13 @@ def _tiny_decode(n_layers=3):
 
     from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import LlamaConfig, init_params
-    from ray_tpu.serve.engine import _build_fns
+    from ray_tpu.models.serving import build_programs
 
     cfg = LlamaConfig(vocab_size=128, d_model=32, n_layers=n_layers,
                       n_heads=4, n_kv_heads=2, d_ff=64, max_seq=64,
                       dtype=np.float32)
     ns, chunk, page, n_pages = 3, 4, 16, 9
-    _, decode, _, _, empty = _build_fns(cfg, ns, chunk, page, n_pages)
+    _, decode, _, _, empty = build_programs(cfg, ns, chunk, page, n_pages)
     kc, vc = empty()
     args = (fuse_qkv(init_params(cfg, jax.random.PRNGKey(0))), kc, vc,
             jnp.zeros((ns, cfg.max_seq // page), jnp.int32),
@@ -460,7 +460,8 @@ def _prefill_adopt_and_two_chunks(cfg, params):
     import jax.numpy as jnp
 
     from ray_tpu.models.block import fuse_qkv
-    from ray_tpu.serve.engine import Engine, _make_prefill_core
+    from ray_tpu.models.serving import prefill_core
+    from ray_tpu.serve.engine import Engine
 
     # A copy: the engine takes its tree's q/k/v stacks over.
     eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=2,
@@ -468,7 +469,7 @@ def _prefill_adopt_and_two_chunks(cfg, params):
     try:
         a = eng.submit(list(range(3, 17)), 11)     # positions 14..24
         prompt = [5] * 20
-        first, ks, vs, _, _ = jax.jit(_make_prefill_core(cfg))(
+        first, ks, vs, _, _ = jax.jit(prefill_core(cfg))(
             fuse_qkv(params), jnp.asarray([prompt + [0] * 12], jnp.int32),
             len(prompt))
         b = eng.submit_prefilled(ks, vs, len(prompt), int(first), 6)
@@ -527,7 +528,8 @@ def paged3():
     import numpy as np
 
     from ray_tpu.models.llama import LlamaConfig, forward, init_params
-    from ray_tpu.serve.engine import Engine, _sample_tokens, _seed_key
+    from ray_tpu.models.serving import sample_tokens
+    from ray_tpu.serve.engine import Engine, _seed_key
 
     cfg = LlamaConfig(vocab_size=128, d_model=32, n_layers=3, n_heads=4,
                       n_kv_heads=2, d_ff=64, max_seq=64, dtype=np.float32)
@@ -543,7 +545,7 @@ def paged3():
             toks = np.zeros((1, cfg.max_seq), np.int32)
             toks[0, :len(ids)] = ids
             row = fwd(params, jnp.asarray(toks))[0, len(ids) - 1]
-            out.append(int(_sample_tokens(
+            out.append(int(sample_tokens(
                 row[None], jnp.asarray([temperature], jnp.float32),
                 jnp.asarray([top_k], jnp.int32), key,
                 jnp.asarray([len(ids) - 1], jnp.int32))[0]))

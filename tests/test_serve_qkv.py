@@ -80,7 +80,7 @@ def test_fused_projection_gives_the_three_matrices_q_k_v(kind):
     import numpy as np
 
     from ray_tpu.models.block import attention_inputs, fuse_qkv, split_qkv
-    from ray_tpu.serve.engine import _make_prefill_core
+    from ray_tpu.models.serving import prefill_core
 
     cfg, params = _qkv_model(kind)
     fused = fuse_qkv(params)
@@ -101,7 +101,7 @@ def test_fused_projection_gives_the_three_matrices_q_k_v(kind):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-5, atol=1e-6)
     with pytest.raises(ValueError, match="fuse_qkv"):
-        jax.jit(_make_prefill_core(cfg))(
+        jax.jit(prefill_core(cfg))(
             params, jnp.zeros((1, 32), jnp.int32), 3)
 
 
@@ -117,7 +117,8 @@ def test_engine_on_the_fused_stack_serves_the_three_matrix_tokens(kind):
     import jax.numpy as jnp
 
     from ray_tpu.models.block import fuse_qkv
-    from ray_tpu.serve.engine import Engine, _make_prefill_core
+    from ray_tpu.models.serving import prefill_core
+    from ray_tpu.serve.engine import Engine
 
     cfg, params = _qkv_model(kind)
     prompts = [list(range(3, 17)), [5] * 20, [9, 8, 7]]
@@ -126,7 +127,7 @@ def test_engine_on_the_fused_stack_serves_the_three_matrix_tokens(kind):
                  decode_chunk=4, page_size=16, adopts=True)
     try:
         a = eng.submit(prompts[0], 11)
-        first, ks, vs, _, _ = jax.jit(_make_prefill_core(cfg))(
+        first, ks, vs, _, _ = jax.jit(prefill_core(cfg))(
             fuse_qkv(params), jnp.asarray([prompts[1] + [0] * 12], jnp.int32),
             len(prompts[1]))
         b = eng.submit_prefilled(ks, vs, len(prompts[1]), int(first), 6)

@@ -141,7 +141,7 @@ def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
 
     from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import LlamaConfig, init_params
-    from ray_tpu.serve.engine import _build_fns
+    from ray_tpu.models.serving import build_programs
 
     # The engine asks jax.devices() which attention path to take and sees
     # this sandbox's CPU, so the test, not the program, steers it.
@@ -157,7 +157,7 @@ def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    _, decode, _, _, _ = _build_fns(cfg, ns, chunk, page, n_pages)
+    _, decode, _, _, _ = build_programs(cfg, ns, chunk, page, n_pages)
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
             lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)))))
@@ -307,7 +307,7 @@ def test_sparse_decode_leaves_the_arenas_where_they_lie_on_v5e(
     from benchmark import models
     from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import init_params
-    from ray_tpu.serve.engine import _build_fns
+    from ray_tpu.models.serving import build_programs
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -325,7 +325,7 @@ def test_sparse_decode_leaves_the_arenas_where_they_lie_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
-    _, decode, _, _, empty = _build_fns(cfg, ns, eng["decode_chunk"], page,
+    _, decode, _, _, empty = build_programs(cfg, ns, eng["decode_chunk"], page,
                                         eng["kv_pages"])
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
@@ -401,7 +401,7 @@ def test_jamba_programs_keep_arena_and_state_in_place_on_v5e(
     from benchmark import models
     from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import init_params
-    from ray_tpu.serve.engine import _build_fns
+    from ray_tpu.models.serving import build_programs
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -417,7 +417,7 @@ def test_jamba_programs_keep_arena_and_state_in_place_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
-    prefill, decode, _, _, empty = _build_fns(cfg, ns, eng["decode_chunk"],
+    prefill, decode, _, _, empty = build_programs(cfg, ns, eng["decode_chunk"],
                                               page, eng["kv_pages"])
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
@@ -504,7 +504,7 @@ def test_mimo_programs_keep_both_caches_in_place_on_v5e(
     from benchmark import models
     from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import init_params
-    from ray_tpu.serve.engine import _build_fns
+    from ray_tpu.models.serving import build_programs
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -520,7 +520,7 @@ def test_mimo_programs_keep_both_caches_in_place_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
-    prefill, decode, _, _, empty = _build_fns(cfg, ns, eng["decode_chunk"],
+    prefill, decode, _, _, empty = build_programs(cfg, ns, eng["decode_chunk"],
                                               page, eng["kv_pages"])
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
@@ -630,7 +630,7 @@ def test_a_shares_prefill_reads_the_expert_stacks_where_they_lie_on_v5e(
     from benchmark import models
     from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import init_params
-    from ray_tpu.serve.engine import _build_fns
+    from ray_tpu.models.serving import build_programs
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -648,7 +648,7 @@ def test_a_shares_prefill_reads_the_expert_stacks_where_they_lie_on_v5e(
     def shaped(tree):
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
 
-    prefill, _, _, _, empty = _build_fns(cfg, eng["n_slots"],
+    prefill, _, _, _, empty = build_programs(cfg, eng["n_slots"],
                                          eng["decode_chunk"],
                                          eng["page_size"], eng["kv_pages"])
     params = shaped(jax.eval_shape(
@@ -702,7 +702,8 @@ def test_a_riding_prefill_updates_the_arena_in_place_and_fits_on_v5e(
 
     from benchmark import models
     from ray_tpu.models.block import fuse_qkv
-    from ray_tpu.serve.engine import Engine, _build_fns, rung_rides
+    from ray_tpu.models.serving import build_programs
+    from ray_tpu.serve.engine import Engine, rung_rides
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -719,7 +720,8 @@ def test_a_riding_prefill_updates_the_arena_in_place_and_fits_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
-    prefill, _, _, _, empty = _build_fns(cfg, ns, eng["decode_chunk"], page,
+    prefill, _, _, _, empty = build_programs(cfg, ns, eng["decode_chunk"],
+                                             page,
                                          eng["kv_pages"])
     assert prefill.takes_riders
     params = jax.tree.map(
