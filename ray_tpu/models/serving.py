@@ -9,6 +9,7 @@ nothing from `serve/`.
 from __future__ import annotations
 
 import functools
+from typing import Any, Callable, Dict, NamedTuple
 
 # Rows of a prefill that meet the sparse feed-forward at once: its sorted
 # copies are `rows x experts a token` wide (2.5 GiB of temporaries at 4,096
@@ -525,8 +526,61 @@ def sample_tokens(logits, temp, topk, keys, pos, cap=TOPK_CAP):
         return jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
 
 
-def build_programs(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
-    """Build (prefill_jit, decode_jit, adopt_jit, poke_jit, empty_caches)."""
+class Caches(NamedTuple):
+    """What a model keeps between programs, ONE pytree: `Programs.empty()`
+    makes it, every program takes it first after `params` and hands it back
+    first, donated, and the scheduler never opens it. `kc`, `vc`: the arena of
+    the layers that keep K and V under the block table (`ops/paged_kv.py`; a
+    latent-attention model's arena of latent rows is `kc`, and its `vc` None).
+    `ic`: the indexer keys' arena of a model with sparse attention, under the
+    same block table. `state`: what a model keeps a SLOT, no pages
+    (`ops/slot_state.py`): the recurrent state of state-space layers, or the
+    rings of window layers. None where the model has no such cache."""
+    kc: Any = None
+    vc: Any = None
+    ic: Any = None
+    state: Any = None
+
+
+class Programs(NamedTuple):
+    """A model's serving programs and what a scheduler has to ask of it
+    without knowing it (`build_programs`).
+
+    `empty() -> caches`; `prefill(params, caches, pages, tokens, length, temp,
+    topk, key, slot, last, pos, riders) -> (caches, first, experts[, last,
+    pos, tokens])`; `decode(params, caches, bt, last, pos, active, temp, topk,
+    keys) -> (caches, last, pos, out, experts)`; `adopt(caches, pages, ks, vs)
+    -> caches`; `poke(last, pos, slot, first, length) -> (last, pos)`."""
+    empty: Callable
+    prefill: Callable
+    decode: Callable
+    adopt: Callable
+    poke: Callable
+    # Whether the prefill program of a riding rung carries the live slots.
+    takes_riders: bool
+    # Whether a hand-off's K and V can be adopted (`adopts`).
+    adopts: bool
+    # Whether a prefill writes per-slot state, so `slot` is passed.
+    by_slot: bool
+    # Whether the programs hand back a SHARE's routing (`_share_stats`:
+    # `[held + 2]` counts) where a whole model's is `expert_stats`.
+    shares: bool
+    # caches -> the counters `Engine.counters()` shows of them.
+    cache_bytes: Callable[[Caches], Dict[str, int]]
+
+
+def adopts(mcfg) -> bool:
+    """Whether a PD hand-off can carry what this model caches: K and V of
+    one shape a layer, and nothing else (no indexer's keys, recurrent state,
+    latent rows or window rings). A function of the configuration alone: a
+    `PrefillServer` builds no engine."""
+    return not (mcfg.index_topk or mcfg.ssm_state or mcfg.latent
+                or mcfg.mixed)
+
+
+def build_programs(mcfg, n_slots: int, chunk: int, page: int,
+                   n_pages: int) -> Programs:
+    """The serving programs of one model at one engine's sizes."""
     import jax
     import jax.numpy as jnp
 
@@ -542,7 +596,8 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
                                       write_prompt_rows, write_token,
                                       write_token_rows)
     from ray_tpu.ops.slot_state import (empty_state, empty_window,
-                                        layer_state, update_layer,
+                                        layer_state, state_bytes,
+                                        update_layer,
                                         window_decode_attention, write_state,
                                         write_window_prompt,
                                         write_window_token)
@@ -558,49 +613,51 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
     dt = mcfg.dtype
     ns = n_slots
 
-    def empty_caches():
-        """-> (kc, vc), the arena of the layers that keep K and V; after
-        them ic for a model with an indexer, or the recurrent state
-        (`ops/slot_state.py`) for one with state-space layers. A model with
-        latent attention: (its arena of latent rows, None); one of window
-        and full attention layers: the full layers' arena, then the window
-        layers' rings (`ops/slot_state.py`), which take `state`'s place."""
+    def empty_caches() -> Caches:
         if latent:
-            return (empty_latent(mcfg.n_layers, n_pages, page,
-                                 mcfg.latent_width, dt), None)
+            return Caches(kc=empty_latent(mcfg.n_layers, n_pages, page,
+                                          mcfg.latent_width, dt))
         if mixed:   # pages for the full layers, a ring a slot for the rest
-            return empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt,
-                         v_head_dim=mcfg.v_head_dim) + (empty_window(
+            return Caches(*empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt,
+                                 v_head_dim=mcfg.v_head_dim),
+                          state=empty_window(
                 mcfg.n_layers - mcfg.kv_layers, ns, mcfg.window_kv_heads,
-                mcfg.window, hd, mcfg.v_head_dim, dt),)
-        kv = empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt,
-                   by_token=indexed)
-        if indexed:
-            kv += (empty_index(mcfg.n_layers, n_pages, page,
-                               mcfg.index_head_dim, dt),)
+                mcfg.window, hd, mcfg.v_head_dim, dt))
+        return Caches(
+            *empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt,
+                   by_token=indexed),
+            ic=empty_index(mcfg.n_layers, n_pages, page, mcfg.index_head_dim,
+                           dt) if indexed else None,
+            state=empty_state(mcfg.n_layers - mcfg.kv_layers, ns,
+                              mcfg.ssm_state, mcfg.ssm_inner, mcfg.ssm_conv,
+                              dt) if hybrid else None)
+
+    def cache_bytes(caches: Caches) -> Dict[str, int]:
         if hybrid:
-            kv += (empty_state(mcfg.n_layers - mcfg.kv_layers, ns,
-                               mcfg.ssm_state, mcfg.ssm_inner, mcfg.ssm_conv,
-                               dt),)
-        return kv
+            return {"state_bytes": state_bytes(caches.state)}
+        if latent:
+            return {"latent_cache_bytes": int(caches.kc.nbytes)}
+        if mixed:
+            return {"full_cache_bytes": int(caches.kc.nbytes
+                                            + caches.vc.nbytes),
+                    "window_cache_bytes": state_bytes(caches.state)}
+        return {}
 
     # ------------------------------------------------------------------
     # prefill: full causal pass over ONE padded prompt, k/v -> pages
     # ------------------------------------------------------------------
     _core = prefill_core(mcfg)
 
-    def prefill(params, kc, vc, pages, tokens, length, temp, topk, key,
-                ic=None, state=None, slot=None, last=None, pos=None,
-                riders=None):
+    def prefill(params, caches, pages, tokens, length, temp, topk, key,
+                slot=None, last=None, pos=None, riders=None):
         """tokens [1, B] padded to a BUCKET width (a rung of
         `prefill_widths` — jax.jit compiles one program per bucket shape, so
         a prompt pays a prefill of about its own length, not a max_seq one);
-        writes the slot's pages, returns the first generated token (sampled,
-        or greedy when temp == 0) and the core's `experts` (and `ic`, the
-        indexer keys' arena, where the model has one; or `state`, the
-        recurrent state with slot `slot`'s rows overwritten by the prompt's
-        final ones, where it has state-space layers; or the window layers'
-        rings with slot `slot`'s overwritten by the prompt's tail).
+        writes the slot's pages (and the indexer's keys, where the model has
+        them; or slot `slot`'s recurrent state, overwritten by the prompt's
+        final one; or its window layers' rings, by the prompt's tail),
+        returns the caches, the first generated token (sampled, or greedy
+        when temp == 0) and the core's `experts`.
 
         With `riders` = (block table, riding [ns], the slots' temp, topk,
         keys) and the slots' `last` and `pos` (the program of a riding rung,
@@ -608,6 +665,7 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         after `experts` come `last` and `pos` moved by it, as a decode chunk
         of one step would leave them, and the tokens [ns] the step sampled
         (a riding slot's is its next one; the others' rows are not slots')."""
+        kc, vc, ic, state = caches
         if riders is not None:
             return _riding_prefill(params, kc, vc, pages, tokens, length,
                                    temp, topk, key, last, pos, *riders)
@@ -618,14 +676,12 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
                                jnp.asarray(topk)[None], key[None],
                                jnp.asarray(length - 1)[None])[0]
         if indexed:
-            return (kc, vc, first, experts,
-                    write_prompt_rows(ic, pages, iks[0]))
+            ic = write_prompt_rows(ic, pages, iks[0])
         if hybrid:
-            return kc, vc, first, experts, write_state(state, slot, *iks[0])
+            state = write_state(state, slot, *iks[0])
         if mixed:
-            return kc, vc, first, experts, write_window_prompt(
-                state, slot, length, *iks[0])
-        return kc, vc, first, experts
+            state = write_window_prompt(state, slot, length, *iks[0])
+        return Caches(kc, vc, ic, state), first, experts
 
     def _riding_prefill(params, kc, vc, pages, tokens, length, temp, topk,
                         key, last, pos, bt, riding, temps, topks, keys):
@@ -640,13 +696,15 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
             jnp.concatenate([key[None], keys]),
             jnp.concatenate([jnp.asarray(length - 1)[None], pos]))
         act = riding & (pos < S)
-        return (kc, vc, toks[0], experts, jnp.where(act, toks[1:], last),
-                jnp.where(act, pos + 1, pos), toks[1:])
+        return (Caches(kc, vc), toks[0], experts,
+                jnp.where(act, toks[1:], last), jnp.where(act, pos + 1, pos),
+                toks[1:])
 
-    def adopt(kc, vc, pages, ks, vs):
+    def adopt(caches, pages, ks, vs):
         """Write externally-prefilled k/v (a PrefillServer handoff) into
         the slot's pages."""
-        return write_prompt(kc, vc, pages, ks, vs)
+        kc, vc = write_prompt(caches.kc, caches.vc, pages, ks, vs)
+        return caches._replace(kc=kc, vc=vc)
 
     # ------------------------------------------------------------------
     # decode: one token for every active slot per step, `chunk` steps
@@ -888,13 +946,11 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         pos2 = jnp.where(act, pos + 1, pos)
         return kc, vc, ic, experts, nxt, pos2, state
 
-    def decode(params, kc, vc, bt, last, pos, active, temp, topk, keys,
-               ic=None, state=None):
-        """-> (kc, vc, last, pos, tokens [ns, chunk], experts): `experts` is
+    def decode(params, caches, bt, last, pos, active, temp, topk, keys):
+        """-> (caches, last, pos, tokens [ns, chunk], experts): `experts` is
         None for a dense model, else `expert_stats` of the live slots'
-        tokens summed over the chunk's steps and the layers. With an
-        indexer, `ic` follows; with state-space layers, or window layers'
-        rings, `state`."""
+        tokens summed over the chunk's steps and the layers."""
+        kc, vc, ic, state = caches
         cos = sin = None
         itables = ()
         if latent:
@@ -927,12 +983,8 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
 
         kc, vc, ic, state, last, pos, out, *experts = jax.lax.fori_loop(
             0, chunk, body, (kc, vc, ic, state, last, pos, out0, *experts0))
-        experts = experts[0] if sparse else None
-        if indexed:
-            return kc, vc, last, pos, out, experts, ic
-        if hybrid or mixed:
-            return kc, vc, last, pos, out, experts, state
-        return kc, vc, last, pos, out, experts
+        return (Caches(kc, vc, ic, state), last, pos, out,
+                experts[0] if sparse else None)
 
     def poke(last, pos, slot, first, length):
         """Admission bookkeeping ON DEVICE: set one slot's (last, pos).
@@ -941,10 +993,13 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int, n_pages: int):
         before the TTFT token could be emitted."""
         return last.at[slot].set(first), pos.at[slot].set(length)
 
-    import jax as _jax
-    prefill_jit = _jax.jit(prefill, donate_argnums=(1, 2, 9, 10, 12, 13))
-    prefill_jit.takes_riders = _core.takes_riders
-    decode_jit = _jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10, 11))
-    adopt_jit = _jax.jit(adopt, donate_argnums=(0, 1))
-    poke_jit = _jax.jit(poke, donate_argnums=(0, 1))
-    return prefill_jit, decode_jit, adopt_jit, poke_jit, empty_caches
+    # Donated: the caches, and the slots' `last` and `pos`.
+    return Programs(
+        empty=empty_caches,
+        prefill=jax.jit(prefill, donate_argnums=(1, 9, 10)),
+        decode=jax.jit(decode, donate_argnums=(1, 3, 4)),
+        adopt=jax.jit(adopt, donate_argnums=(0,)),
+        poke=jax.jit(poke, donate_argnums=(0, 1)),
+        takes_riders=_core.takes_riders, adopts=adopts(mcfg),
+        by_slot=hybrid or mixed, shares=latent or mixed,
+        cache_bytes=cache_bytes)

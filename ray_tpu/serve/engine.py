@@ -218,7 +218,6 @@ class Engine:
 
         from ray_tpu.models.block import fuse_qkv
         from ray_tpu.models.serving import build_programs
-        from ray_tpu.ops.slot_state import state_bytes
 
         self._np = np
         self._jnp = jnp
@@ -241,24 +240,12 @@ class Engine:
                 params[stack][name].delete()
         self.pool = PagePool(n_slots, mcfg.max_seq, page_size, n_pages)
         self.n_pages = self.pool.n_pages
-        (self._prefill, self._decode, self._adopt, self._poke,
-         self._empty) = build_programs(mcfg, n_slots, decode_chunk,
-                                   self.pool.page, self.n_pages)
-        # `_ic`: the indexer keys' arena of a model with sparse attention,
-        # under the same block table. `_state`: the per-slot recurrent state
-        # of a model with state-space layers (`ops/slot_state.py`). Each None
-        # for every other model, and no model has both.
-        self._hybrid = mcfg.ssm_state > 0
-        self._latent = mcfg.latent
-        # Window layers' rings (`ops/slot_state.py`) ride where a hybrid's
-        # recurrent state does: `_state`, per slot, written by the prefill
-        # that admits a request into the slot.
-        self._mixed = mcfg.mixed
-        self._by_slot = self._hybrid or self._mixed
-        # Stacks whose programs count a SHARE's routing (`_share_stats`).
-        self._shares = self._latent or self._mixed
-        self._kc, self._vc, *more = self._empty()
-        self._ic, self._state = self._third(more)
+        # The model's programs, and its caches: one bundle that every
+        # program takes and hands back, and that nothing here opens
+        # (`models/serving.py::Programs`, `Caches`).
+        self._programs = build_programs(mcfg, n_slots, decode_chunk,
+                                        self.pool.page, self.n_pages)
+        self._caches = self._programs.empty()
         # Prefill shape buckets (`prefill_widths`): a 50-token prompt
         # prefills 64 wide and, under a max_seq of 4096, a 2,100-token one
         # 2,560 wide, not max_seq wide — the TTFT lever, and most of the
@@ -311,9 +298,12 @@ class Engine:
         self._index_topk = mcfg.index_topk
         self.decode_selected_keys = 0
         self.decode_live_keys = 0
-        # A model with state-space layers: the bytes of recurrent state held
-        # on the device, and the admissions that overwrote a slot's.
-        self.state_bytes = state_bytes(self._state) if self._hybrid else 0
+        # What the model's caches hold on the device, under the counters'
+        # names (`Programs.cache_bytes`: a model with state-space layers'
+        # `state_bytes`, a latent-attention model's `latent_cache_bytes`, the
+        # `full_cache_bytes` and `window_cache_bytes` of one with window
+        # layers), and the admissions that overwrote a slot's state.
+        self._cache_bytes = self._programs.cache_bytes(self._caches)
         self.state_writes = 0
         # A sparse model's routing, as the programs count it on the device
         # (`models.block.expert_stats`) and the emitter thread adds it up:
@@ -323,24 +313,16 @@ class Engine:
         self.expert_tokens = np.zeros(mcfg.n_held, np.int64)
         self.decode_experts_touched = 0
         self._touched_last_chunk = 0
-        # A latent-attention model: the bytes of its arena of latent rows
-        # (all it caches); and, where it is sparse, its share of the routing
-        # (`_share_stats`): the assignments its routers made and those that
-        # fell to the experts held here, `expert_tokens` being per HELD
-        # expert. The last chunk's local assignments ride the next dispatch
-        # span as `experts_touched` does.
-        self.latent_cache_bytes = int(self._kc.nbytes) if self._latent else 0
-        # A model of window and full attention layers: the bytes of its two
-        # caches (the full layers' pages; the window layers' rings, which no
-        # prompt's length moves), and the ring rows its decode steps read,
+        # A model that holds a SHARE of its experts (`Programs.shares`): the
+        # assignments its routers made and those that fell to the experts
+        # held here, `expert_tokens` being per HELD expert. The last chunk's
+        # local assignments ride the next dispatch span as `experts_touched`
+        # does.
+        # A model with window layers: the ring rows its decode steps read,
         # over the chunks' steps and the active slots (min(position + 1,
         # window) a slot a step, a layer), against `live_kv_tokens`. Host
         # arithmetic on the positions, as `decode_selected_keys` is.
-        self._window = mcfg.window if self._mixed else 0
-        self.full_cache_bytes = int(self._kc.nbytes + self._vc.nbytes) \
-            if self._mixed else 0
-        self.window_cache_bytes = state_bytes(self._state) \
-            if self._mixed else 0
+        self._window = mcfg.window
         self.window_kv_tokens = 0
         self.routed_assignments = 0
         self.local_assignments = 0
@@ -380,28 +362,25 @@ class Engine:
         self._warm = {self.buckets[0]} | {
             b for b in self.buckets if 2 * b > mcfg.max_seq}
         for width in sorted(self._warm):
-            self._kc, self._vc, self._ic, self._state, first = \
-                self._warm_width(self._kc, self._vc, self._ic, self._state,
-                                 width)
+            self._caches, first = self._warm_width(self._caches, width)
         with tracing.compile_span("serve.engine.warm", program="decode",
                                   width=n_slots):
-            (self._kc, self._vc, self._last_d, self._pos_d, out, _,
-             *more) = self._decode(
-                    self._params, self._kc, self._vc,
+            self._caches, self._last_d, self._pos_d, _, _ = \
+                self._programs.decode(
+                    self._params, self._caches,
                     jnp.asarray(self.pool.block_table),
                     self._last_d, self._pos_d, jnp.zeros(n_slots, bool),
                     jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._skeys), self._ic, self._state)
-            self._ic, self._state = self._third(more)
+                    jnp.asarray(self._skeys))
         # Warm both poke variants: host-int `first` (adopt path) and
         # device-scalar `first` (prefill path).
         with tracing.compile_span("serve.engine.warm", program="poke",
                                   width=n_slots):
-            self._last_d, self._pos_d = self._poke(
+            self._last_d, self._pos_d = self._programs.poke(
                 self._last_d, self._pos_d, 0, 0, 0)
-            self._last_d, self._pos_d = self._poke(
+            self._last_d, self._pos_d = self._programs.poke(
                 self._last_d, self._pos_d, 0, first, 0)
-            self._last_d, self._pos_d = self._poke(
+            self._last_d, self._pos_d = self._programs.poke(
                 self._last_d, self._pos_d, 0, 0, 0)
         int(first)
         # Emission FIFO: the dispatch loop enqueues device arrays; the
@@ -423,19 +402,12 @@ class Engine:
                 name="llm-bucket-warm")
             self._warm_thread.start()
 
-    def _third(self, more):
-        """(ic, state) from what a program returned after its fixed results:
-        the one further cache this model has, if it has one."""
-        if not more:
-            return None, None
-        return (None, more[0]) if self._by_slot else (more[0], None)
-
-    def _warm_width(self, kc, vc, ic, state, width: int):
+    def _warm_width(self, caches, width: int):
         """First call of the prefill program of one bucket width and, at a
         doubling width, of its adopt twin, writing to the null page of the
-        arena given (pages = zeros: never real KV state; a recurrent state's
+        caches given (pages = zeros: never real KV state; a per-slot state's
         slot 0, before any request holds it, or a scratch one's). Returns
-        (kc, vc, ic, state, first token on the device)."""
+        (caches, first token on the device)."""
         jnp, m = self._jnp, self.mcfg
         null_pages = jnp.zeros(self.pool.maxp, jnp.int32)
         # A riding rung's program with nobody riding, on slots' state of its
@@ -447,31 +419,28 @@ class Engine:
             self._riders(self._np.zeros(self.n_slots, bool)))
         with tracing.compile_span("serve.engine.warm", program="prefill",
                                   width=width):
-            kc, vc, first, _, *more = self._prefill(
-                self._params, kc, vc, null_pages,
+            caches, first, *_ = self._programs.prefill(
+                self._params, caches, null_pages,
                 jnp.zeros((1, width), jnp.int32), 1, 0.0, 0,
-                jnp.zeros(2, jnp.uint32), ic, state,
-                0 if self._by_slot else None, *slots)
-        if more and not rides:
-            # no PD handoff carries an indexer's keys or a state
-            return (kc, vc, *self._third(more), first)
+                jnp.zeros(2, jnp.uint32),
+                0 if self._programs.by_slot else None, *slots)
         # no handoff has this width (none at all is sent an engine that
-        # serves whole requests), or carries latent rows
-        if width not in self._adopt_widths or self._latent or self._mixed:
-            return kc, vc, ic, state, first
+        # serves whole requests), or carries what this model caches
+        if width not in self._adopt_widths or not self._programs.adopts:
+            return caches, first
         # The PD adopt program for this width too (a first cross-pool
         # handoff must not compile in the loop).
         with tracing.compile_span("serve.engine.warm", program="adopt",
                                   width=width):
             kv = jnp.zeros((m.n_layers, width, m.n_kv_heads, m.head_dim),
                            m.dtype)
-            kc, vc = self._adopt(kc, vc, null_pages, kv, kv)
-        return kc, vc, ic, state, first
+            caches = self._programs.adopt(caches, null_pages, kv, kv)
+        return caches, first
 
     def _rides(self, width: int) -> bool:
         """Whether the prefill program of this width takes the live slots
         along: read off the stack (`prefill_core`) and the rung."""
-        return self._prefill.takes_riders and rung_rides(
+        return self._programs.takes_riders and rung_rides(
             self.mcfg.max_seq, self.n_slots, width)
 
     def _riders(self, riding):
@@ -488,17 +457,15 @@ class Engine:
         """Warm intermediate prefill buckets off the engine loop; each
         becomes eligible the moment its compile lands. Runs real calls
         (the only way to reliably populate jit's dispatch cache) against
-        a SCRATCH kv arena (and recurrent state) — the live ones are donated
-        on every engine call and must never be touched from this thread.
-        Costs one transient extra arena while warming."""
+        SCRATCH caches — the live ones are donated on every engine call and
+        must never be touched from this thread. Costs one transient extra
+        arena while warming."""
         try:
-            kc, vc, *more = self._empty()
-            ic, state = self._third(more)
+            caches = self._programs.empty()
             for width in widths:
                 if self._stop:
                     return
-                kc, vc, ic, state, first = self._warm_width(kc, vc, ic,
-                                                            state, width)
+                caches, first = self._warm_width(caches, width)
                 int(first)  # host sync: compile fully landed
                 self._warm.add(width)
         except Exception:
@@ -511,30 +478,41 @@ class Engine:
                          [w for w in widths if w not in self._warm],
                          self.warm_error)
 
+    def prefill_shapes(self, width: int) -> Tuple:
+        """The prefill program's arguments for one bucket width as `_place`
+        passes them, riders and all: shapes for arrays."""
+        riding = (self._last_d, self._pos_d, self._riders(
+            self._np.zeros(self.n_slots, bool))) if self._rides(width) \
+            else (None, None, None)
+        return self._shapes((
+            self._params, self._caches,
+            self._np.zeros(self.pool.maxp, self._np.int32),
+            self._np.zeros((1, width), self._np.int32), 1, 0.0, 0,
+            _seed_key(0), 0 if self._programs.by_slot else None, *riding))
+
+    def decode_shapes(self) -> Tuple:
+        """The decode program's arguments as the loop passes them."""
+        return self._shapes((
+            self._params, self._caches, self.pool.block_table, self._last_d,
+            self._pos_d, self._active, self._temp, self._topk, self._skeys))
+
+    @staticmethod
+    def _shapes(args):
+        import jax
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+            if hasattr(x, "shape") else x, args)
+
     def lowered_prefill_text(self, width: int) -> str:
         """StableHLO text of the prefill program for one bucket width —
         what a check reads to see which attention path (a Pallas
         `tpu_custom_call` or the XLA reference) that width compiled to."""
-        import jax
-        import jax.numpy as jnp
+        return self._programs.prefill.lower(
+            *self.prefill_shapes(width)).as_text()
 
-        def shape_of(x):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype)
-
-        slots = riders = None
-        if self._rides(width):     # as `_place` calls it
-            slots = shape_of(self._last_d)
-            riders = jax.tree.map(shape_of, self._riders(
-                self._np.zeros(self.n_slots, bool)))
-        return self._prefill.lower(
-            jax.tree.map(shape_of, self._params), shape_of(self._kc),
-            jax.tree.map(shape_of, self._vc),
-            jax.ShapeDtypeStruct((self.pool.maxp,), jnp.int32),
-            jax.ShapeDtypeStruct((1, width), jnp.int32), 1, 0.0, 0,
-            jax.ShapeDtypeStruct((2,), jnp.uint32),
-            None if self._ic is None else shape_of(self._ic),
-            jax.tree.map(shape_of, self._state),
-            0 if self._by_slot else None, slots, slots, riders).as_text()
+    def lowered_decode_text(self) -> str:
+        """StableHLO text of the decode program."""
+        return self._programs.decode.lower(*self.decode_shapes()).as_text()
 
     # ------------------------------------------------------------------
     @property
@@ -597,8 +575,7 @@ class Engine:
         only tokens AFTER `first`."""
         if self.error is not None or not self._thread.is_alive():
             raise RuntimeError(f"LLM engine died:\n{self.error}")
-        if self._ic is not None or self._hybrid or self._latent \
-                or self._mixed:
+        if not self._programs.adopts:
             raise NotImplementedError(
                 "a PD handoff carries K and V, not a sparse-attention "
                 "indexer's keys nor a state-space layer's recurrent state "
@@ -689,16 +666,12 @@ class Engine:
         if self._index_topk:
             out["decode_selected_keys"] = self.decode_selected_keys
             out["decode_live_keys"] = self.decode_live_keys
-        if self._hybrid:
-            out["state_bytes"] = self.state_bytes
+        out.update(self._cache_bytes)
+        if "state_bytes" in out:
             out["state_writes"] = self.state_writes
-        if self._latent:
-            out["latent_cache_bytes"] = self.latent_cache_bytes
-        if self._mixed:
-            out["full_cache_bytes"] = self.full_cache_bytes
-            out["window_cache_bytes"] = self.window_cache_bytes
+        if "window_cache_bytes" in out:
             out["window_kv_tokens"] = self.window_kv_tokens
-        if self._shares and self._sparse:
+        if self._programs.shares and self._sparse:
             out["routed_assignments"] = self.routed_assignments
             out["local_assignments"] = self.local_assignments
         return out
@@ -848,8 +821,8 @@ class Engine:
                 pk[:, :width] = np.asarray(ks)
                 pv[:, :width] = np.asarray(vs)
                 ks, vs = jnp.asarray(pk), jnp.asarray(pv)
-            self._kc, self._vc = self._adopt(
-                self._kc, self._vc, pages_arr, ks, vs)
+            self._caches = self._programs.adopt(self._caches, pages_arr,
+                                                ks, vs)
             first, experts = req.first, None
         else:
             toks = np.zeros((1, bucket), np.int32)
@@ -859,21 +832,19 @@ class Engine:
                 riding = np.zeros(self.n_slots, bool)
                 riding[[s for s, _, _ in riders]] = True
                 slots = (self._last_d, self._pos_d, self._riders(riding))
-            self._kc, self._vc, first, experts, *more = self._prefill(
-                self._params, self._kc, self._vc, pages_arr,
+            self._caches, first, experts, *more = self._programs.prefill(
+                self._params, self._caches, pages_arr,
                 jnp.asarray(toks), len(req.ids),
                 float(req.temperature), int(req.top_k),
-                jnp.asarray(_seed_key(req.seed)), self._ic, self._state,
-                slot if self._by_slot else None, *slots)
-            if riders is None:
-                self._ic, self._state = self._third(more)
-            else:
+                jnp.asarray(_seed_key(req.seed)),
+                slot if self._programs.by_slot else None, *slots)
+            if riders is not None:
                 self._last_d, self._pos_d, rode = more
                 self._pos[riding] += 1
                 for s, _, fin in riders:
                     if fin:     # its slot and pages are free at once
                         self._finish_state(s)
-            self.state_writes += self._hybrid
+            self.state_writes += self._programs.by_slot
         req.slot = slot
         self._slot_req[slot] = req
         self._pos[slot] = len(req.ids)
@@ -886,7 +857,7 @@ class Engine:
         req.produced = 1
         # Device-side slot bookkeeping (async — never a host round-trip;
         # `first` stays a device scalar on the prefill path).
-        self._last_d, self._pos_d = self._poke(
+        self._last_d, self._pos_d = self._programs.poke(
             self._last_d, self._pos_d, slot, first, int(self._pos[slot]))
         done = bool(req.produced >= req.max_tokens
                     or self._pos[slot] >= S)
@@ -961,7 +932,7 @@ class Engine:
                         # profiler fixes a span's arguments when it opens.
                         touched, local, routed = self._count_experts(experts)
                         share = {"local": local, "routed": routed} \
-                            if self._shares else {}
+                            if self._programs.shares else {}
                         with tracing.span("serve.engine.prefill_experts",
                                           ctx=req.ctx, rid=req.rid,
                                           touched=touched, **share):
@@ -1012,7 +983,7 @@ class Engine:
         expert)."""
         stats = self._np.asarray(experts)
         routed = None
-        if self._shares:
+        if self._programs.shares:
             routed, stats = int(stats[-1]), stats[:-1]
         local = int(stats[:-1].sum())
         routed = local if routed is None else routed
@@ -1093,7 +1064,7 @@ class Engine:
             routed = {"experts_touched": self._touched_last_chunk,
                       "expert_tokens": ":".join(map(str, self.expert_tokens))
                       } if self._sparse and tracing.recording() else {}
-            if routed and self._shares:
+            if routed and self._programs.shares:
                 routed.update(local_assignments=self._local_last_chunk,
                               routed_assignments=self._routed_last_chunk)
             if self._index_topk or self._window:
@@ -1116,17 +1087,15 @@ class Engine:
                               capacity=self.n_slots * self.chunk,
                               active=len(plan), live_kv_tokens=live_kv,
                               **routed):
-                (self._kc, self._vc, self._last_d, self._pos_d, out_d,
-                 experts_d, *more) = \
-                    self._decode(self._params, self._kc, self._vc,
-                                 jnp.asarray(self.pool.block_table.copy()),
-                                 self._last_d, self._pos_d,
-                                 jnp.asarray(self._active.copy()),
-                                 jnp.asarray(self._temp.copy()),
-                                 jnp.asarray(self._topk.copy()),
-                                 jnp.asarray(self._skeys.copy()), self._ic,
-                                 self._state)
-                self._ic, self._state = self._third(more)
+                (self._caches, self._last_d, self._pos_d, out_d,
+                 experts_d) = self._programs.decode(
+                    self._params, self._caches,
+                    jnp.asarray(self.pool.block_table.copy()),
+                    self._last_d, self._pos_d,
+                    jnp.asarray(self._active.copy()),
+                    jnp.asarray(self._temp.copy()),
+                    jnp.asarray(self._topk.copy()),
+                    jnp.asarray(self._skeys.copy()))
                 self._pos = np.where(
                     self._active, np.minimum(self._pos + self.chunk, S),
                     self._pos).astype(np.int32)

@@ -879,6 +879,7 @@ def test_the_engines_spans_carry_what_the_window_readers_read():
     from benchmark import window_trace
     from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import init_params
+    from ray_tpu.models import serving
     from ray_tpu.models.serving import build_programs
     from ray_tpu.serve import engine as engine_mod
 
@@ -887,28 +888,27 @@ def test_the_engines_spans_carry_what_the_window_readers_read():
     model = dict(m, **adapter.REHEARSE)
     cfg = adapter.build_config(model, {"params": "float32",
                                        "activations": "float32"}, 128)
-    prefill, decode, _, _, empty = build_programs(cfg, 2, 2, 16, 17)
+    built = build_programs(cfg, 2, 2, 16, 17)
     params = jax.eval_shape(lambda: fuse_qkv(
         init_params(cfg, jax.random.PRNGKey(0)), cfg))
-    kc, vc, state = jax.eval_shape(empty)
+    caches = jax.eval_shape(built.empty)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
 
-    text = decode.lower(
-        params, kc, vc, arg((2, 8), jnp.int32), arg((2,), jnp.int32),
+    text = built.decode.lower(
+        params, caches, arg((2, 8), jnp.int32), arg((2,), jnp.int32),
         arg((2,), jnp.int32), arg((2,), jnp.bool_), arg((2,), jnp.float32),
-        arg((2,), jnp.int32), arg((2, 2), jnp.uint32), None,
-        state).as_text(debug_info=True)
+        arg((2,), jnp.int32), arg((2, 2), jnp.uint32)
+        ).as_text(debug_info=True)
     for scope in window_trace.SCOPES + ("kv_write",):
         assert f"/{scope}/" in text or f"{scope}/" in text, scope
-    text = prefill.lower(
-        params, kc, vc, arg((8,), jnp.int32), arg((1, 64), jnp.int32), 1,
-        0.0, 0, arg((2,), jnp.uint32), None, state,
-        0).as_text(debug_info=True)
+    text = built.prefill.lower(
+        params, caches, arg((8,), jnp.int32), arg((1, 64), jnp.int32), 1,
+        0.0, 0, arg((2,), jnp.uint32), 0).as_text(debug_info=True)
     for scope in window_trace.SCOPES:
         assert f"{scope}/" in text, scope
-    src = open(engine_mod.__file__).read()
+    src = open(engine_mod.__file__).read() + open(serving.__file__).read()
     for name in ("window_kv_tokens", "window_cache_bytes",
                  "full_cache_bytes"):
         assert f'"{name}"' in src or f"{name}=" in src, name

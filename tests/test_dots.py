@@ -26,9 +26,9 @@ from benchmark import models, reference_dots
 from ray_tpu.models import llama
 from ray_tpu.models.block import (fuse_qkv, latent_attention_inputs,
                                   latent_attention_output, split_qkv)
+from ray_tpu.models.serving import Caches, prefill_core
 from ray_tpu.ops import attention, moe, paged_kv
 from ray_tpu.ops.norms import apply_rope, yarn_inv_frequencies
-from ray_tpu.models.serving import prefill_core
 from ray_tpu.serve.engine import Engine
 from test_mimo import PUBLISHED as MIMO
 
@@ -416,15 +416,15 @@ def test_prefill_then_decode_through_the_latent_cache_is_the_reference(
     assert routed >= want and 0 < local < routed
     assert sum(after["expert_tokens"]) == after["local_assignments"]
     assert len(after["expert_tokens"]) == held
-    assert after["latent_cache_bytes"] == engine._kc.nbytes \
-        and engine._vc is None
+    assert after["latent_cache_bytes"] == engine._caches.kc.nbytes \
+        and engine._caches.vc is None
 
 
 def test_the_engine_took_the_latent_paths(engine):
     counts = attention.attention_path_counts()
     assert counts["latent_decode_pallas"] >= 1      # interpreted, in decode
     assert counts["latent_fwd_reference"] >= 1      # the CPU's prefill path
-    assert engine._kc.shape[-1] == 128 and engine._kc.ndim == 4
+    assert engine._caches.kc.shape[-1] == 128 and engine._caches.kc.ndim == 4
 
 
 def test_a_pd_handoff_and_the_training_forward_refuse_latent_attention_by_name(
@@ -561,25 +561,52 @@ def _lowered(kind):
     eng = Engine(adapter.init_params(cfg, 3), cfg, n_slots=2, decode_chunk=2,
                  page_size=16)
     try:
-        def sds(tree):
-            return jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
-
-        def arg(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype)
-
-        decode = eng._decode.lower(
-            sds(eng._params), sds(eng._kc), sds(eng._vc),
-            arg(eng.pool.block_table.shape, jnp.int32), arg((2,), jnp.int32),
-            arg((2,), jnp.int32), arg((2,), jnp.bool_),
-            arg((2,), jnp.float32), arg((2,), jnp.int32),
-            arg((2, 2), jnp.uint32), sds(eng._ic), sds(eng._state)).as_text()
         width = 32 if eng._rides(64) else 64
-        return {f"{kind}.prefill{width}":
-                _sha(eng.lowered_prefill_text(width)),
-                f"{kind}.decode": _sha(decode)}
+        return {f"{kind}.prefill{width}": _sha(parents_prefill_text(eng,
+                                                                    width)),
+                f"{kind}.decode": _sha(parents_decode_text(eng))}
     finally:
         eng.stop()
+
+
+# The pins were taken when the programs took the caches apart (`kc, vc` after
+# `params`; `ic`, `state` and `slot` after `key`; the one further cache a
+# model has LAST among the results), and lowered text names arguments by
+# position. So a pinned program is lowered from its own function (the jit's
+# `__wrapped__`) under the parent's name, argument order, result order and
+# `donate_argnums`, which puts the bundle together and takes it apart again:
+# what is compared is then the parent's text, or the program changed.
+
+def parents_prefill_text(eng, width):
+    def prefill(params, kc, vc, pages, tokens, length, temp, topk, key,
+                ic=None, state=None, slot=None, last=None, pos=None,
+                riders=None):
+        caches, first, experts, *rode = eng._programs.prefill.__wrapped__(
+            params, Caches(kc, vc, ic, state), pages, tokens, length, temp,
+            topk, key, slot, last, pos, riders)
+        return (caches.kc, caches.vc, first, experts, *(
+            c for c in (caches.ic, caches.state) if c is not None), *rode)
+
+    params, caches, pages, tokens, length, temp, topk, key, slot, *riding = \
+        eng.prefill_shapes(width)
+    return jax.jit(prefill, donate_argnums=(1, 2, 9, 10, 12, 13)).lower(
+        params, caches.kc, caches.vc, pages, tokens, length, temp, topk, key,
+        caches.ic, caches.state, slot, *riding).as_text()
+
+
+def parents_decode_text(eng):
+    def decode(params, kc, vc, bt, last, pos, active, temp, topk, keys,
+               ic=None, state=None):
+        caches, last, pos, out, experts = eng._programs.decode.__wrapped__(
+            params, Caches(kc, vc, ic, state), bt, last, pos, active, temp,
+            topk, keys)
+        return (caches.kc, caches.vc, last, pos, out, experts, *(
+            c for c in (caches.ic, caches.state) if c is not None))
+
+    params, caches, *slots = eng.decode_shapes()
+    return jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10, 11)).lower(
+        params, caches.kc, caches.vc, *slots, caches.ic,
+        caches.state).as_text()
 
 
 @pytest.mark.parametrize("kind", ["dense", "sparse", "indexed", "hybrid",

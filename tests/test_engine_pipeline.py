@@ -512,7 +512,7 @@ def test_a_failing_dispatch_ends_the_streams_still_pending_too():
             clients.send(k, list(range(1, 9 + k)), 40, {})
         _until(lambda: eng._in_flight == _DEPTH)
         assert eng._active.sum() == 2 and len(eng._pending) == 5
-        eng._decode = broken
+        eng._programs = eng._programs._replace(decode=broken)
         emitter.release()
         got = clients.join()
     _until(lambda: not eng._thread.is_alive())      # the drain's last None

@@ -247,7 +247,7 @@ def test_engine_prefill_then_decode_match_the_reference(tiny, engine):
     counts = engine.counters()
     assert counts["state_writes"] == 3
     assert counts["state_bytes"] == 3 * 4 * (16 * 128 * 4 + 3 * 128 * 4)
-    assert engine._kc.shape[0] == 1 and engine._ic is None
+    assert engine._caches.kc.shape[0] == 1 and engine._caches.ic is None
 
 
 def test_a_bfloat16_state_is_outside_the_tolerance(tiny, engine):

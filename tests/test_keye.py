@@ -206,7 +206,7 @@ def test_engine_prefill_then_paged_decode_match_the_reference(tiny, engine):
     counts = engine.counters()
     # 21 -> 44 reads 22..32 keys a step and then 32; the others always 32.
     assert 0 < counts["decode_selected_keys"] < counts["decode_live_keys"]
-    assert engine._ic.shape == (2, engine.n_pages, 16, 16)
+    assert engine._caches.ic.shape == (2, engine.n_pages, 16, 16)
 
 
 def test_a_wide_bucket_meets_the_experts_in_row_blocks_and_nothing_changes(

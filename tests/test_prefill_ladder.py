@@ -157,7 +157,7 @@ def test_a_prompt_of_1100_prefills_1536_wide_and_serves_the_reference_tokens(
         first = _drain(eng.submit(prompt, 1))
         assert _padded_since(eng, before) == (PROMPT, 1536 - PROMPT)
         if kind == "hybrid":
-            state, window = (np.asarray(a) for a in eng._state)
+            state, window = (np.asarray(a) for a in eng._caches.state)
             *_, (want_ssm, want_window) = jax.jit(prefill_core(cfg))(
                 fuse_qkv(params), jnp.asarray([prompt], jnp.int32), PROMPT)
             # values of size ~1, summed in another order at another width
@@ -233,7 +233,7 @@ def test_wide_rungs_warm_in_the_constructor_and_adopt_at_doubling_widths_only(
     assert sorted(w for p, w, _ in warm_spans if p == "adopt") == adopted
     assert {w for p, w, name in warm_spans if name != here} \
         == set(LADDERS[4096]) - {32} - set(wide)
-    assert eng._adopt._cache_size() == len(adopted)
+    assert eng._programs.adopt._cache_size() == len(adopted)
 
 
 def test_a_prefill_pool_pads_to_doubling_widths_and_its_hand_off_is_adopted():
@@ -267,7 +267,7 @@ def test_a_prefill_pool_pads_to_doubling_widths_and_its_hand_off_is_adopted():
     eng = decode.engine
     try:
         _until_all_warm(eng)
-        programs = eng._adopt._cache_size()
+        programs = eng._programs.adopt._cache_size()
         before = eng.counters()
         want = _drain(eng.submit(prompt, 8))
         assert _padded_since(eng, before) == (PROMPT, 2048 - PROMPT)
@@ -276,7 +276,7 @@ def test_a_prefill_pool_pads_to_doubling_widths_and_its_hand_off_is_adopted():
             jnp.concatenate([ks, pad], axis=1),
             jnp.concatenate([vs, pad], axis=1), PROMPT, int(first), 8))
         assert _padded_since(eng, before) == (PROMPT, 2048 - PROMPT)
-        assert eng._adopt._cache_size() == programs == 8
+        assert eng._programs.adopt._cache_size() == programs == 8
     finally:
         eng.stop()
     assert len(want) == 8 and [int(first)] + rest == want

@@ -28,7 +28,7 @@ from ray_tpu.models.serving import prefill_core
 from ray_tpu.serve.engine import (_DEPTH, Engine, prefill_widths,
                                   rung_rides)
 from ray_tpu.utils import tracing
-from test_dots import MIMO, PUBLISHED
+from test_dots import MIMO, PUBLISHED, parents_prefill_text
 from test_prefill_ladder import F32, KINDS, LOGIT_TOL, _tiny, _tokens
 
 MAX_SEQ, SLOTS, CHUNK = 256, 4, 4
@@ -319,13 +319,13 @@ def _rung_digests(kind):
     eng = Engine(adapter.init_params(cfg, 3), cfg, n_slots=2, decode_chunk=2,
                  page_size=16)
     try:
-        assert eng._prefill.takes_riders is prefill_core(
+        assert eng._programs.takes_riders is prefill_core(
             cfg).takes_riders
         riding = [w for w in eng.buckets if eng._rides(w)]
-        texts = {w: eng.lowered_prefill_text(w) for w in eng.buckets}
+        texts = {w: parents_prefill_text(eng, w) for w in eng.buckets}
     finally:
         eng.stop()
-    return eng._prefill.takes_riders, riding, {
+    return eng._programs.takes_riders, riding, {
         w: hashlib.sha256(t.encode()).hexdigest()[:16]
         for w, t in texts.items()}
 

@@ -379,9 +379,9 @@ def _tiny_decode(n_layers=3):
                       n_heads=4, n_kv_heads=2, d_ff=64, max_seq=64,
                       dtype=np.float32)
     ns, chunk, page, n_pages = 3, 4, 16, 9
-    _, decode, _, _, empty = build_programs(cfg, ns, chunk, page, n_pages)
-    kc, vc = empty()
-    args = (fuse_qkv(init_params(cfg, jax.random.PRNGKey(0))), kc, vc,
+    built = build_programs(cfg, ns, chunk, page, n_pages)
+    decode = built.decode
+    args = (fuse_qkv(init_params(cfg, jax.random.PRNGKey(0))), built.empty(),
             jnp.zeros((ns, cfg.max_seq // page), jnp.int32),
             jnp.zeros(ns, jnp.int32), jnp.zeros(ns, jnp.int32),
             jnp.zeros(ns, bool), jnp.zeros(ns, jnp.float32),
@@ -405,7 +405,7 @@ def test_decode_arena_is_a_scan_carry_only_written_and_attended(
     if path == "kernel":
         request.getfixturevalue("kernel_in_interpret_mode")
     decode, args, chunk = _tiny_decode(n_layers=3)
-    arena = args[1].shape
+    arena = args[1].kc.shape
     slab = arena[1:]
     scans = [e for e in _all_eqns(jax.make_jaxpr(decode)(*args).jaxpr)
              if e.primitive.name == "scan"]
@@ -509,13 +509,13 @@ def test_decode_call_donates_the_arena():
     """In place across calls too: the arena handed to decode_jit is
     consumed (deleted), not copied, wherever the backend donates."""
     decode, args, _ = _tiny_decode(n_layers=2)
-    kc, vc, last, pos = args[1], args[2], args[4], args[5]
+    (kc, vc, _, _), last, pos = args[1], args[3], args[4]
     out = decode(*args)
-    out[0].block_until_ready()
+    out[0].kc.block_until_ready()
     if not last.is_deleted():
         pytest.skip("this backend does not donate buffers")
     assert kc.is_deleted() and vc.is_deleted() and pos.is_deleted()
-    assert out[0].shape == kc.shape and out[1].shape == vc.shape
+    assert out[0].kc.shape == kc.shape and out[0].vc.shape == vc.shape
 
 
 @pytest.fixture(scope="module")

@@ -141,7 +141,7 @@ def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
 
     from ray_tpu.models.block import fuse_qkv
     from ray_tpu.models.llama import LlamaConfig, init_params
-    from ray_tpu.models.serving import build_programs
+    from ray_tpu.models.serving import Caches, build_programs
 
     # The engine asks jax.devices() which attention path to take and sees
     # this sandbox's CPU, so the test, not the program, steers it.
@@ -157,14 +157,14 @@ def test_decode_program_reads_and_updates_the_arena_in_place_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    _, decode, _, _, _ = build_programs(cfg, ns, chunk, page, n_pages)
+    decode = build_programs(cfg, ns, chunk, page, n_pages).decode
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
             lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)))))
     slab = (n_pages, KVH, page, hd)
     arena = sds((cfg.n_layers,) + slab, jnp.bfloat16)
     compiled = decode.lower(
-        params, arena, arena, sds((ns, maxp), jnp.int32),
+        params, Caches(arena, arena), sds((ns, maxp), jnp.int32),
         sds((ns,), jnp.int32), sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
         sds((ns,), jnp.float32), sds((ns,), jnp.int32),
         sds((ns, 2), jnp.uint32)).compile()
@@ -325,16 +325,18 @@ def test_sparse_decode_leaves_the_arenas_where_they_lie_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
-    _, decode, _, _, empty = build_programs(cfg, ns, eng["decode_chunk"], page,
-                                        eng["kv_pages"])
+    built = build_programs(cfg, ns, eng["decode_chunk"], page,
+                           eng["kv_pages"])
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
             lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)))))
-    kc, vc, ic = (sds(x.shape, x.dtype) for x in jax.eval_shape(empty))
-    compiled = decode.lower(
-        params, kc, vc, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
+    caches = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(built.empty))
+    kc = caches.kc
+    compiled = built.decode.lower(
+        params, caches, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
         sds((ns,), jnp.int32), sds((ns,), jnp.bool_), sds((ns,), jnp.float32),
-        sds((ns,), jnp.int32), sds((ns, 2), jnp.uint32), ic).compile()
+        sds((ns,), jnp.int32), sds((ns, 2), jnp.uint32)).compile()
     # (The indexer keys' arena, 64 wide under 128 lanes, is re-tiled once at
     # the chunk's entry and exit: 2 x 67 MB a chunk of 8 steps, not a layer.)
     shapes = {tuple(kc.shape), tuple(kc.shape[1:])}
@@ -417,29 +419,30 @@ def test_jamba_programs_keep_arena_and_state_in_place_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
-    prefill, decode, _, _, empty = build_programs(cfg, ns, eng["decode_chunk"],
-                                              page, eng["kv_pages"])
+    built = build_programs(cfg, ns, eng["decode_chunk"], page,
+                           eng["kv_pages"])
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
             lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)))))
     assert "lm_head" not in params
-    kc, vc, state = jax.tree.map(lambda x: sds(x.shape, x.dtype),
-                                 jax.eval_shape(empty))
+    caches = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(built.empty))
+    kc, vc, _, state = caches
     assert kc.shape == (2, eng["kv_pages"], 1, page, 128)
     assert [tuple(x.shape) for x in state] == [(26, ns, 16, 5120),
                                                (26, 3, ns, 5120)]
     before = dict(attention.attention_path_counts())
     if program == "decode":
-        lowered = decode.lower(
-            params, kc, vc, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
+        lowered = built.decode.lower(
+            params, caches, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
             sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
             sds((ns,), jnp.float32), sds((ns,), jnp.int32),
-            sds((ns, 2), jnp.uint32), None, state)
+            sds((ns, 2), jnp.uint32))
         kernel, path = "paged_decode", "decode_pallas"
     else:
-        lowered = prefill.lower(
-            params, kc, vc, sds((maxp,), jnp.int32), sds((1, 4096), jnp.int32),
-            1, 0.0, 0, sds((2,), jnp.uint32), None, state, 0)
+        lowered = built.prefill.lower(
+            params, caches, sds((maxp,), jnp.int32), sds((1, 4096), jnp.int32),
+            1, 0.0, 0, sds((2,), jnp.uint32), 0)
         kernel, path = "selective_scan", "scan_pallas"
     text = lowered.as_text()
     assert "tpu_custom_call" in text and kernel in text
@@ -520,30 +523,31 @@ def test_mimo_programs_keep_both_caches_in_place_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
-    prefill, decode, _, _, empty = build_programs(cfg, ns, eng["decode_chunk"],
-                                              page, eng["kv_pages"])
+    built = build_programs(cfg, ns, eng["decode_chunk"], page,
+                           eng["kv_pages"])
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
             lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)), cfg)))
-    kc, vc, state = jax.tree.map(lambda x: sds(x.shape, x.dtype),
-                                 jax.eval_shape(empty))
+    caches = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(built.empty))
+    kc, vc, _, state = caches
     assert kc.shape == (2, eng["kv_pages"], 4, page, 256)
     assert vc.shape == (2, eng["kv_pages"], 4, page, 128)
     assert [tuple(x.shape) for x in state] == [(5, ns, 8, 128, 256),
                                                (5, ns, 8, 128, 128)]
     before = dict(attention.attention_path_counts())
     if program == "decode":
-        lowered = decode.lower(
-            params, kc, vc, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
+        lowered = built.decode.lower(
+            params, caches, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
             sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
             sds((ns,), jnp.float32), sds((ns,), jnp.int32),
-            sds((ns, 2), jnp.uint32), None, state)
+            sds((ns, 2), jnp.uint32))
         kernels, paths = ["paged_decode"], ["decode_pallas",
                                             "window_decode_reference"]
     else:
-        lowered = prefill.lower(
-            params, kc, vc, sds((maxp,), jnp.int32), sds((1, 2048), jnp.int32),
-            1, 0.0, 0, sds((2,), jnp.uint32), None, state, 0)
+        lowered = built.prefill.lower(
+            params, caches, sds((maxp,), jnp.int32), sds((1, 2048), jnp.int32),
+            1, 0.0, 0, sds((2,), jnp.uint32), 0)
         kernels, paths = ["window_flash_fwd", "full_flash_fwd"], [
             "window_fwd_pallas", "full_fwd_pallas"]
     text = lowered.as_text()
@@ -648,19 +652,15 @@ def test_a_shares_prefill_reads_the_expert_stacks_where_they_lie_on_v5e(
     def shaped(tree):
         return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
 
-    prefill, _, _, _, empty = build_programs(cfg, eng["n_slots"],
-                                         eng["decode_chunk"],
-                                         eng["page_size"], eng["kv_pages"])
+    built = build_programs(cfg, eng["n_slots"], eng["decode_chunk"],
+                           eng["page_size"], eng["kv_pages"])
     params = shaped(jax.eval_shape(
         lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)), cfg)))
-    caches = shaped(jax.eval_shape(empty))
-    kc, vc = caches[0], caches[1]
-    state = caches[2] if len(caches) > 2 else None
     before = attention.attention_path_counts()
-    lowered = prefill.lower(
-        params, kc, vc, sds((maxp,), jnp.int32), sds((1, 2048), jnp.int32),
-        1, 0.0, 0, sds((2,), jnp.uint32), None, state,
-        None if state is None else 0)
+    lowered = built.prefill.lower(
+        params, shaped(jax.eval_shape(built.empty)), sds((maxp,), jnp.int32),
+        sds((1, 2048), jnp.int32), 1, 0.0, 0, sds((2,), jnp.uint32),
+        0 if built.by_slot else None)
     counts = attention.attention_path_counts()
     assert counts["experts_grouped_pallas"] > before.get(
         "experts_grouped_pallas", 0)
@@ -720,24 +720,25 @@ def test_a_riding_prefill_updates_the_arena_in_place_and_fits_on_v5e(
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
-    prefill, _, _, _, empty = build_programs(cfg, ns, eng["decode_chunk"],
-                                             page,
-                                         eng["kv_pages"])
-    assert prefill.takes_riders
+    built = build_programs(cfg, ns, eng["decode_chunk"], page,
+                           eng["kv_pages"])
+    assert built.takes_riders
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
             lambda: fuse_qkv(Engine._experts_in_compute_dtype(
                 adapter.init_params(cfg, 0), cfg), cfg)))
-    kc, vc = jax.tree.map(lambda x: sds(x.shape, x.dtype),
-                          jax.eval_shape(empty))
+    caches = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(built.empty))
+    kc, vc = caches.kc, caches.vc
     slots = sds((ns,), jnp.int32)
     riders = (sds((ns, maxp), jnp.int32), sds((ns,), jnp.bool_),
               sds((ns,), jnp.float32), slots, sds((ns, 2), jnp.uint32))
 
     def compiled(*more):
-        lowered = prefill.lower(
-            params, kc, vc, sds((maxp,), jnp.int32), sds((1, width), jnp.int32),
-            1, 0.0, 0, sds((2,), jnp.uint32), None, None, None, *more)
+        lowered = built.prefill.lower(
+            params, caches, sds((maxp,), jnp.int32),
+            sds((1, width), jnp.int32), 1, 0.0, 0, sds((2,), jnp.uint32),
+            None, *more)
         return lowered.as_text(), lowered.compile()
 
     plain_text, plain = compiled(None, None, None)

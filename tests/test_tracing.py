@@ -187,26 +187,11 @@ def test_scope_names_are_in_the_lowered_program(engine, program):
         wanted = SCOPES_TRAIN
     else:
         e = engine
-        arenas = (shape_of(e._kc), shape_of(e._vc))
-        params = jax.tree.map(shape_of, e._params)
         if program == "decode":
-            n = e.n_slots
-            lowered = e._decode.lower(
-                params, *arenas,
-                jax.ShapeDtypeStruct((n, e.pool.maxp), jnp.int32),
-                jax.ShapeDtypeStruct((n,), jnp.int32),
-                jax.ShapeDtypeStruct((n,), jnp.int32),
-                jax.ShapeDtypeStruct((n,), jnp.bool_),
-                jax.ShapeDtypeStruct((n,), jnp.float32),
-                jax.ShapeDtypeStruct((n,), jnp.int32),
-                jax.ShapeDtypeStruct((n, 2), jnp.uint32))
+            lowered = e._programs.decode.lower(*e.decode_shapes())
             wanted = SCOPES_DECODE
         else:
-            lowered = e._prefill.lower(
-                params, *arenas,
-                jax.ShapeDtypeStruct((e.pool.maxp,), jnp.int32),
-                jax.ShapeDtypeStruct((1, 32), jnp.int32), 1, 0.0, 0,
-                jax.ShapeDtypeStruct((2,), jnp.uint32))
+            lowered = e._programs.prefill.lower(*e.prefill_shapes(32))
             wanted = SCOPES_PREFILL
     # `loc("jit(f)/attn/dot_general"`; relative to an outlined scan body,
     # `loc("attn/dot_general"`; differentiated, `jvp(attn)`.
