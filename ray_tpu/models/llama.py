@@ -280,10 +280,11 @@ class LlamaConfig:
         return self.ssm_expand * self.d_model
 
     def segments(self) -> Tuple[Tuple[str, int, int], ...]:
-        """A stack that is not one scan over `layers`, in the order it runs,
-        each segment a kind and ordinals lo..hi-1 into that kind's stack of
-        parameters. A hybrid: ("mamba", lo, hi), a run of state-space layers
-        in `mamba`, or ("attn", a, a + 1), one attention layer in `layers`.
+        """The stack in the order it runs, each segment a kind and ordinals
+        lo..hi-1 into that kind's stack of parameters. A uniform stack is one
+        segment, ("layers", 0, n_layers). A hybrid: ("mamba", lo, hi), a run
+        of state-space layers in `mamba`, or ("attn", a, a + 1), one
+        attention layer in `layers`.
         A latent-attention stack, each kind the name of its stack:
         ("dense", 0, first_dense), the leading dense layers, if it has any,
         then ("layers", 0, n_layers - first_dense), the rest. A
@@ -304,6 +305,8 @@ class LlamaConfig:
             lead = (("dense", 0, self.first_dense),) if self.first_dense \
                 else ()
             return lead + (("layers", 0, self.n_layers - self.first_dense),)
+        if self.attn_layers is None:
+            return (("layers", 0, self.n_layers),)
         out, a, m = [], 0, 0
         for i in range(self.n_layers):
             if i in self.attn_layers:
