@@ -18,7 +18,7 @@ write, the read) and its fields in `LlamaConfig`.
 The caches travel as one bundle (`Caches`) that the scheduler never opens. A
 decode program updates them IN PLACE, as a loop carry that nothing but
 `ops/`'s writes and reads touches, so no copy of an arena (or of a layer's
-slab) is ever made (see `_run_decode`).
+slab) is ever made (see `_over`).
 """
 
 from __future__ import annotations
@@ -121,9 +121,9 @@ class _Kind(NamedTuple):
     # The caches that ride a decode step's scan (all of them, or a state
     # alone: an arena that a segment never touches stays out of its loop).
     carries: Tuple[str, ...] = Caches._fields
-    # Decode: `begin(ctx) -> ctx` before a segment's scan, for what a step or
-    # a segment reads of the tables once for all its layers (`ctx` is the
-    # step's own dict: what one segment leaves there the next one finds).
+    # Decode: `begin(ctx)` before a segment's scan puts into `ctx` what a
+    # step or a segment reads of the tables once for all its layers (`ctx` is
+    # the step's own dict: what one segment leaves there the next one finds).
     begin: Optional[Callable] = None
     # Prefill: `pack(kept) -> kept` of a segment's stacked rows.
     pack: Optional[Callable] = None
@@ -622,9 +622,8 @@ def _keep_pages(c, pages, slot, length, ks, vs=None):
 
 
 # A prompt's write to each cache, (caches, pages, slot, length, *rows) ->
-# caches, in the order a prefill makes them: the pages first (every model
-# pages something, and the sampler follows that write), then what else it
-# keeps. A kind names the cache of each array it keeps (`_Kind.keeps`).
+# caches: every model pages something; what else it keeps follows. A kind
+# names the cache of each array it keeps (`_Kind.keeps`).
 _KEEP = {
     "pages": _keep_pages,
     "index": lambda c, pages, slot, length, ik: c._replace(
@@ -702,6 +701,7 @@ def _prefill_walk(mcfg, stack: _Stack):
     counts the riding rows."""
     dt, S = mcfg.dtype, mcfg.max_seq
     sparse = mcfg.n_experts > 0
+    segments = {name for name, _, _ in mcfg.segments()}
 
     def walk(params, tokens, length, caches=None, riders=None):
         width = tokens.shape[1]
@@ -744,11 +744,10 @@ def _prefill_walk(mcfg, stack: _Stack):
                 return (x, ride, caches), (*kept[:2], counts, *kept[2:])
             return body
 
-        runs = {name: _over(
-            stack.kinds[name], params[stack.kinds[name].stack or name], mcfg,
-            layer(stack.kinds[name]),
-            stack.kinds[name].over != "slices" or riders is not None)
-            for name, _, _ in mcfg.segments()}
+        runs = {name: _over(kind, params[kind.stack or name], mcfg,
+                            layer(kind),
+                            kind.over != "slices" or riders is not None)
+                for name, kind in stack.kinds.items() if name in segments}
         kept = {cache: [] for cache in _KEEP}
         experts = 0 if stack.tally == "zero" else None
         with jax.named_scope("layers"):
@@ -762,11 +761,10 @@ def _prefill_walk(mcfg, stack: _Stack):
                 for cache in set(kind.keeps):
                     kept[cache].append((kind.over == "inline", tuple(
                         y for y, to in zip(ys, kind.keeps) if to == cache)))
-                if counts is not None and stack.tally == "late":
-                    experts = counts
-                elif counts is not None:
-                    total = jnp.sum(counts, axis=0)
-                    experts = total if experts is None else experts + total
+                if counts is not None and stack.tally != "late":
+                    counts = jnp.sum(counts, axis=0)
+                    counts = counts if experts is None else experts + counts
+                experts = experts if counts is None else counts
         with jax.named_scope("head"):
             x = norms.rms_norm(x, params["final_norm"], mcfg.norm_eps)
             last_h = jax.lax.dynamic_index_in_dim(x, length - 1, axis=1,
@@ -833,6 +831,7 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
     stack = _stack(mcfg)
     sparse = mcfg.n_experts > 0
     S, dt, ns = mcfg.max_seq, mcfg.dtype, n_slots
+    segments = {name for name, _, _ in mcfg.segments()}
     walk = _prefill_walk(mcfg, stack)
 
     # ------------------------------------------------------------------
@@ -869,19 +868,19 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
             return prompts if slots is None \
                 else jnp.concatenate([prompts, slots])
 
-        for cache, kept_rows in kept.items():
-            caches = _KEEP[cache](caches, pages, slot, length, *kept_rows)
-            if cache == "pages":
-                # The prompt's row and the riders' through ONE sampler, each
-                # row at its own temperature, key and position, as `_step`
-                # samples.
-                toks = sample_tokens(logits[None] if riders is None
-                                     else logits, rows(temp, temps),
-                                     rows(topk, topks), rows(key, keys),
-                                     rows(length - 1, at))
-                if riders is not None:
-                    act = riding & (pos < S)
-                first = toks[0]
+        # The pages first: the sampler follows that write. The prompt's row
+        # and the riders' go through ONE sampler, each row at its own
+        # temperature, key and position, as `_step` samples.
+        caches = _keep_pages(caches, pages, slot, length,
+                             *kept.pop("pages"))
+        toks = sample_tokens(logits[None] if riders is None else logits,
+                             rows(temp, temps), rows(topk, topks),
+                             rows(key, keys), rows(length - 1, at))
+        if riders is not None:
+            act = riding & (pos < S)
+        first = toks[0]
+        for cache, rest in kept.items():
+            caches = _KEEP[cache](caches, pages, slot, length, *rest)
         if riders is None:
             return caches, first, experts
         return (caches, first, experts, jnp.where(act, toks[1:], last),
@@ -911,9 +910,9 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
                                      else [counts[0] + n])), None
             return body
 
-        runs = {name: _over(
-            stack.kinds[name], params[stack.kinds[name].stack or name], mcfg,
-            layer(stack.kinds[name])) for name, _, _ in mcfg.segments()}
+        runs = {name: _over(kind, params[kind.stack or name], mcfg,
+                            layer(kind))
+                for name, kind in stack.kinds.items() if name in segments}
         at = dict.fromkeys(_KEEP, 0)    # layers so far, a cache
         with jax.named_scope("layers"):
             for name, lo, hi in mcfg.segments():

@@ -5,41 +5,21 @@ python/ray/llm/_internal/serve/deployments/llm/vllm/vllm_models.py:170 —
 engine_kwargs feed vLLM's continuous batcher + paged attention; here the
 engine is OURS):
 
+- **The model's programs and caches are not this module's.** It is the
+  SCHEDULER: slots, pages, the ladder of prefill widths, admission, riders,
+  the emitter. What runs on the chip is built by `models/serving.py`
+  (`build_programs` -> `Programs`), and what a model keeps between programs
+  is ONE bundle (`Caches`) that `Engine` holds, hands to every program first
+  after the parameters, gets back first, and never opens; what the scheduler
+  has to know of a model it asks of `Programs` (`takes_riders`, `adopts`,
+  `by_slot`, `shares`, `cache_bytes`). No program is built here, and no
+  architecture is named.
 - **Paged KV cache**, kept behind two names. The DEVICE side is
-  `ops/paged_kv.py`: the arena's layout, the null page, and the only ops
-  on it (`empty`, `write_prompt`, `write_token`, `paged_decode_attention`).
-  The HOST side is `serve/page_pool.py::PagePool`: which physical pages
-  each slot holds, the reservation rule, and the block table
-  `[n_slots, max_pages]` a decode chunk is handed (vLLM's block-table
-  design). This module lays nothing out and does no page arithmetic. What
-  it owes the cache: the decode program updates the arena IN PLACE, as a
-  loop carry that nothing but those ops touches, so no copy of it (or of a
-  layer's slab) is ever made (see `_step`). A model with a sparse-attention
-  indexer (`mcfg.index_topk`) has a third array under the same block table,
-  its indexer keys (`ic`, `paged_kv.empty_index`): the programs take and
-  return it after everything else, and it is None for every other model.
-  A model with state-space layers (`mcfg.ssm_state`) keeps K and V for its
-  attention layers only, the arena's layers being their ordinals, and beside
-  it a per-SLOT recurrent state of fixed size (`ops/slot_state.py`): no
-  pages, overwritten whole by the prefill that admits a request into the
-  slot, moved by a decode step only where the slot is active, carried and
-  donated as the arena is; `state`, the programs' last argument and result,
-  None for every other model. Its stack is not a scan over identical layers
-  but SEGMENTS (`LlamaConfig.segments`): a scan over each run of state-space
-  layers, the attention layers between them inline.
-  A model with latent attention (`mcfg.latent`) keeps ONE row a position a
-  layer (`paged_kv.empty_latent`) where the others keep K and V: its arena
-  takes `kc`'s place in every program, `vc` is None, and nothing else of the
-  engine knows. Its stack runs as segments too (leading dense layers, then
-  the sparse ones, a scan each).
-  A model of window and full attention layers (`mcfg.mixed`) has TWO caches
-  of two shapes: pages under the block table for its full-attention layers
-  alone (`kc`, `vc`, keys wider than values), and for its window layers a
-  ring of the last `window` positions a slot (`ops/slot_state.py`), which
-  takes `state`'s place in every program: written by the prefill that admits
-  a request (the prompt's tail) and by each decode step, the same size at
-  200 positions as at 8,000. `PagePool` reserves for the full layers alone.
-  Segments by kind (`dense`, `window`, `layers`), a scan each.
+  `ops/paged_kv.py` (the arena's layout, the null page, the ops on it), which
+  only the programs touch. The HOST side is `serve/page_pool.py::PagePool`:
+  which physical pages each slot holds, the reservation rule, and the block
+  table `[n_slots, max_pages]` a decode chunk is handed (vLLM's block-table
+  design). This module lays nothing out and does no page arithmetic.
 - **Reservation admission**: a request is admitted when the pages
   `PagePool.pages_for` says it can ever need are free: growth can then
   never fail mid-decode, so there is no preemption/recompute path.
@@ -76,7 +56,7 @@ n-step decode chunk over all slots, and the slot poke.
   `n_slots` rows of its bucket free, the prefill program of a riding rung
   (`rung_rides`: the octave under max_seq, of a dense or a sparse stack)
   carries ONE decode step of every live slot in those rows
-  (`prefill_core`); on the host the riders advance as a chunk of one
+  (`models/serving.py`); on the host the riders advance as a chunk of one
   step would (`_ride_plan`, `_place`), and the emitter streams their tokens
   after the prompt's first. Who rides is read off the stack and the shapes:
   no option, field or environment variable.
@@ -150,7 +130,7 @@ def prefill_widths(max_seq: int) -> List[int]:
 
 def rung_rides(max_seq: int, n_slots: int, width: int) -> bool:
     """Whether the prefill program of this width carries the live slots
-    (`prefill_core`, riders): the rungs of the octave under `max_seq`,
+    (`models/serving.py`, riders): the rungs of the octave under `max_seq`,
     where a prefill is long enough for a decode step's weight reads to hide
     in it and where the long prompts of a batch land, and none narrower (a
     riding program holds a decode step's attention kernel and a sampler over
@@ -439,7 +419,7 @@ class Engine:
 
     def _rides(self, width: int) -> bool:
         """Whether the prefill program of this width takes the live slots
-        along: read off the stack (`prefill_core`) and the rung."""
+        along: the stack's answer (`Programs.takes_riders`) and the rung."""
         return self._programs.takes_riders and rung_rides(
             self.mcfg.max_seq, self.n_slots, width)
 
@@ -669,7 +649,7 @@ class Engine:
         out.update(self._cache_bytes)
         if "state_bytes" in out:
             out["state_writes"] = self.state_writes
-        if "window_cache_bytes" in out:
+        if self._window:
             out["window_kv_tokens"] = self.window_kv_tokens
         if self._programs.shares and self._sparse:
             out["routed_assignments"] = self.routed_assignments

@@ -238,14 +238,14 @@ class PrefillServer:
         import jax.numpy as jnp
 
         from ray_tpu.models.block import fuse_qkv
-        from ray_tpu.models.serving import prefill_core
+        from ray_tpu.models.serving import (adopts, prefill_core,
+                                            sample_tokens)
         from ray_tpu.serve.engine import doubling_widths
 
         cfg: LLMConfig = cloudpickle.loads(cfg_blob)
         self.cfg = cfg
         self.mcfg, params = _model_from_cfg(cfg)
-        if self.mcfg.index_topk or self.mcfg.ssm_state or self.mcfg.latent \
-                or self.mcfg.mixed:
+        if not adopts(self.mcfg):
             raise NotImplementedError(
                 "a PD handoff carries K and V, not a sparse-attention "
                 "indexer's keys nor a state-space layer's recurrent state "
@@ -255,13 +255,11 @@ class PrefillServer:
         # The layout the shared prefill core reads, as in the engine.
         self.params = fuse_qkv(params)
         self._core = jax.jit(prefill_core(self.mcfg))
-        from ray_tpu.models.serving import sample_tokens
 
         def _sample_first(row, temp, topk, key, pos):
-            import jax.numpy as jnp
             return sample_tokens(row[None], jnp.asarray(temp)[None],
-                                  jnp.asarray(topk)[None], key[None],
-                                  jnp.asarray(pos)[None])[0]
+                                 jnp.asarray(topk)[None], key[None],
+                                 jnp.asarray(pos)[None])[0]
 
         self._sample1 = jax.jit(_sample_first)
         # The doubling widths, which are those of the decode side's `adopt`

@@ -96,9 +96,9 @@ def pytest_runtest_call(item):
 @pytest.fixture
 def kernel_in_interpret_mode(monkeypatch):
     """An engine built under this fixture takes the Pallas decode-attention
-    kernel, interpreted, on this CPU: what `serve.engine._build_fns` imports
-    is the function with its `interpret` argument set, so the program needs
-    no switch for the tests' sake."""
+    kernel, interpreted, on this CPU: what `models.serving.build_programs`
+    binds is the function with its `interpret` argument set, so the program
+    needs no switch for the tests' sake."""
     import functools
 
     from ray_tpu.ops import paged_kv

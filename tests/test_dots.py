@@ -613,8 +613,8 @@ def parents_decode_text(eng):
                                   "latent", "mixed", "train"])
 def test_the_other_models_programs_are_the_parents(kind):
     """What a dense, a sparse (softmax router, every expert), an indexed, a
-    hybrid, a latent and a mixed engine's prefill (a rung that takes no riders) and
-    decode, and a dense train step, lower to is letter for letter what the
-    parent commit lowers them to."""
+    hybrid, a latent and a mixed engine's prefill (a rung that takes no
+    riders) and decode, and a dense train step, lower to is letter for letter
+    what the parent commit lowers them to."""
     got = _lowered(kind)
     assert got == {k: PARENT[k] for k in got}

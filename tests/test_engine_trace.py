@@ -256,3 +256,107 @@ def test_the_guard_sees_the_names_it_is_for():
     assert {"serve.engine.admit", "serve.engine.decode_dispatch",
             "data.iter.next_ref", "data.iter.format", "train.step",
             "serve.engine.emit_block", "serve.engine.idle"} <= set(_read())
+
+
+# -- the layering: serve/ -> models/ -> ops/, and a scheduler that builds no
+# -- program and knows no architecture (PR 45) -------------------------------
+
+import ast  # noqa: E402
+
+
+def _tree(*path):
+    with open(os.path.join(ROOT, "ray_tpu", *path)) as f:
+        return ast.parse(f.read())
+
+
+def _imported(tree):
+    """Every module a file imports, at any depth of its functions."""
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            yield node.module or ""
+            yield from (f"{node.module}.{a.name}" for a in node.names)
+
+
+def _named(tree):
+    """Every identifier a file reads or binds: names, attributes, arguments,
+    imported names. Strings (a counter's key, a refusal's message) are not
+    identifiers."""
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Name):
+            yield node.id
+        elif isinstance(node, ast.Attribute):
+            yield node.attr
+        elif isinstance(node, ast.arg):
+            yield node.arg
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            yield from (a.asname or a.name.split(".")[-1]
+                        for a in node.names)
+
+
+_LOWER = sorted(os.path.relpath(p, os.path.join(ROOT, "ray_tpu"))
+                for d in ("models", "ops")
+                for p in glob.glob(os.path.join(ROOT, "ray_tpu", d, "*.py")))
+
+
+@pytest.mark.parametrize("path", _LOWER)
+def test_models_and_ops_import_nothing_from_serve(path):
+    assert not [m for m in _imported(_tree(path))
+                if m.startswith("ray_tpu.serve")]
+
+
+@pytest.mark.parametrize("name", [
+    "named_scope", "lax", "hybrid", "latent", "mixed", "_hybrid", "_latent",
+    "_mixed", "_by_slot", "_third"])
+def test_the_scheduler_builds_no_program_and_names_no_architecture(name):
+    assert name not in set(_named(_tree("serve", "engine.py")))
+
+
+def test_the_scheduler_imports_the_parameter_layout_and_the_programs_alone():
+    """Nothing of `ray_tpu.ops`; of `models.block` the layout the engine
+    owns; of `models.serving` the builder. And it is importable without jax
+    (jax is imported inside functions)."""
+    tree = _tree("serve", "engine.py")
+    models = {m for m in _imported(tree)
+              if m.startswith(("ray_tpu.models.", "ray_tpu.ops"))}
+    assert models == {"ray_tpu.models.block", "ray_tpu.models.serving",
+                      "ray_tpu.models.block.fuse_qkv",
+                      "ray_tpu.models.block.split_qkv",
+                      "ray_tpu.models.serving.build_programs"}
+    top = {m for node in tree.body
+           if isinstance(node, (ast.Import, ast.ImportFrom))
+           for m in _imported(ast.Module([node], []))}
+    assert not [m for m in top if m.split(".")[0] in ("jax", "numpy")]
+
+
+def test_the_prefill_pool_asks_adopts_and_names_no_architectures_field():
+    named = set(_named(_tree("serve", "llm.py")))
+    assert "adopts" in named
+    assert not named & {"index_topk", "ssm_state", "latent", "mixed",
+                        "kv_lora_rank", "attn_pattern", "attn_layers"}
+
+
+def _own(fn):
+    """The nodes of a function's body, less those of functions nested in it."""
+    todo = list(fn.body)
+    while todo:
+        node = todo.pop()
+        if not isinstance(node, (ast.FunctionDef, ast.Lambda)):
+            yield node
+            todo.extend(ast.iter_child_nodes(node))
+
+
+def test_one_walk_for_a_prompt_and_one_for_a_decode_step():
+    """Two functions of `models/serving.py` loop over `mcfg.segments()`, one
+    for a prompt and one for a decode step, and neither names an
+    architecture."""
+    walks = [fn for fn in ast.walk(_tree("models", "serving.py"))
+             if isinstance(fn, ast.FunctionDef) and any(
+                 isinstance(n, ast.For) and "segments" in ast.dump(n.iter)
+                 for n in _own(fn))]
+    assert sorted(fn.name for fn in walks) == ["_step", "walk"]
+    for fn in walks:
+        assert not set(_named(fn)) & {"hybrid", "latent", "mixed",
+                                      "ssm_state", "kv_lora_rank",
+                                      "attn_pattern"}

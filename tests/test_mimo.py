@@ -456,13 +456,13 @@ def test_the_two_caches_are_two_shapes_and_the_pool_is_the_full_layers(
     assert counts["window_fwd_reference"] >= 1      # the CPU's prefill path
     assert counts["full_fwd_reference"] >= 1
     # pages: the 2 full layers alone, 1 kv head, keys and values in lanes
-    assert engine._caches.kc.shape == (2, engine.n_pages, 1, 16, 128)
-    assert engine._caches.vc.shape == (2, engine.n_pages, 1, 16, 128)
+    kc, vc, _, rings = engine._caches
+    assert kc.shape == vc.shape == (2, engine.n_pages, 1, 16, 128)
     # rings: the 2 window layers, 2 slots, 2 kv heads, 16 rows, never more
-    assert [s.shape for s in engine._caches.state] == [(2, 2, 2, 16, 128)] * 2
-    assert c["window_cache_bytes"] == sum(s.nbytes for s in engine._caches.state) \
+    assert [s.shape for s in rings] == [(2, 2, 2, 16, 128)] * 2
+    assert c["window_cache_bytes"] == sum(s.nbytes for s in rings) \
         == 2 * 2 * 2 * 2 * 16 * 128 * 4
-    assert c["full_cache_bytes"] == engine._caches.kc.nbytes + engine._caches.vc.nbytes
+    assert c["full_cache_bytes"] == kc.nbytes + vc.nbytes
     assert engine.pool.pages_for(100, 40) == 9      # positions, not layers
     assert c["routed_assignments"] > c["local_assignments"] > 0
     assert sum(c["expert_tokens"]) == c["local_assignments"]
