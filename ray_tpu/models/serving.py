@@ -7,7 +7,7 @@ nothing from `serve/`.
 
 A stack is SEGMENTS (`LlamaConfig.segments`): runs of layers of one kind, each
 kind a stack of parameters. ONE function walks them for a prompt
-(`prefill_core`) and one for a decode step (`build_programs`' `_step`): embed,
+(`_prefill_walk`) and one for a decode step (`build_programs`' `_step`): embed,
 the rotary tables, the live mask, each segment's layers with the caches in the
 carry, the head, the sampler, the routing counts. What differs between kinds
 of layer is `_stack`'s table: a kind's prefill body, its decode body, and
