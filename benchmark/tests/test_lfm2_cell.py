@@ -1,0 +1,128 @@
+"""The cell `serve-generate-lfm2` (PR 46) as the harness finds it: its files
+by name from a COPY of the manifest, the adapter's refusals, a rehearsal at
+the adapter's `REHEARSE` widths through `run.py`, and its six readers on a
+trace recorded on the chip from a program that has none of their scopes.
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests/test_lfm2_cell.py -q
+"""
+
+import importlib
+import json
+import os
+import shutil
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+BENCH = os.path.dirname(HERE)
+ROOT = os.path.dirname(BENCH)
+sys.path.insert(0, ROOT)
+
+from benchmark import models, program_trace, run  # noqa: E402
+from benchmark.tests import test_benchmark as cases  # noqa: E402
+
+CELL = "serve-generate-lfm2"
+READERS = ["prefill_conv_ms_per_ktok", "decode_conv_ms",
+           "hybrid_experts_roofline_pct", "head64_prefill_attn_roofline_pct",
+           "head64_decode_attn_roofline_pct", "decode_mfu_pct"]
+
+
+def test_the_cells_files_are_found_by_name_in_a_copy_of_the_manifest(
+        tmp_path):
+    root = str(tmp_path)
+    shutil.copytree(BENCH, os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
+    manifest = run.load_json(root, "BENCHMARK.json")
+    cell = run.find_cell(manifest, CELL)
+    assert cell["config_file"] == "benchmark/configs/lfm2-24b-a2b-serve.json"
+    config = run.load_json(root, cell["config_file"])
+    mix = run.load_json(root, "benchmark", "traffic",
+                        cell["traffic"] + ".json")
+    assert os.path.exists(os.path.join(root, "benchmark", "drivers",
+                                       mix["kind"] + ".py"))
+    adapter = models.adapter(config["arch"])
+    assert not [n for n in cases.CONTRACT if not hasattr(adapter, n)]
+    assert not [n for n in cases.COUNTS
+                if not callable(getattr(adapter.counts, n))]
+    for group, folder in (("end_to_end", "end_to_end"),
+                          ("per_layer", "layer_metrics")):
+        mine = [m["name"] for m in run.metrics_of(manifest, group, CELL)]
+        assert mine, group
+        for name in mine:
+            assert callable(run.load_reader(
+                os.path.join(root, "benchmark"), folder, name))
+    per_layer = [m["name"] for m in run.metrics_of(manifest, "per_layer",
+                                                   CELL)]
+    assert per_layer[-6:] == READERS
+    e2e = [m["name"] for m in run.metrics_of(manifest, "end_to_end", CELL)]
+    assert e2e == ["batch_tokens_per_s", "setup_s"]
+    # importing the adapter imported neither jax's backend nor the program
+    assert importlib.import_module("benchmark.models.lfm2") is adapter
+
+
+def test_the_adapter_takes_the_configuration_and_refuses_a_neighbour():
+    adapter = models.adapter("lfm2")
+    config = run.load_json(BENCH, "configs", "lfm2-24b-a2b-serve.json")
+    adapter.check_supported(config)
+    adapter.check_supported(dict(config, **adapter.REHEARSE))
+    for change, said in ((dict(conv_bias=True), "conv_bias"),
+                         (dict(use_expert_bias=False), "expert_bias"),
+                         (dict(layer_types=["conv"] * 9), "both kinds")):
+        with pytest.raises(ValueError, match=said):
+            adapter.check_supported(dict(config, **change))
+
+
+def test_a_program_without_the_models_fields_is_refused_by_name(monkeypatch):
+    """What the parent commit does under this PR's benchmark files:
+    `build_config`, which the cell's driver calls in `run.py`'s own process
+    before any cluster starts, names the fields `LlamaConfig` lacks."""
+    import dataclasses
+
+    from ray_tpu.models import llama
+    adapter = models.adapter("lfm2")
+    config = run.load_json(BENCH, "configs", "lfm2-24b-a2b-serve.json")
+    older = dataclasses.make_dataclass("LlamaConfig", [
+        (f.name, f.type, f) for f in dataclasses.fields(llama.LlamaConfig)
+        if f.name not in ("conv_layers", "conv_taps", "router_norm_eps")])
+    monkeypatch.setattr(llama, "LlamaConfig", older)
+    with pytest.raises(ValueError, match="conv_layers.*conv_taps.*"
+                                         "router_norm_eps"):
+        adapter.build_config(config, config["dtypes"], 2048)
+
+
+def test_the_cell_rehearses_through_the_adapters_widths():
+    """`run.py --rehearse`: the adapter's `REHEARSE` over the configuration,
+    `rehearse.json`'s engine, the whole control flow on the CPU: the run
+    reaches its end (exit 3), serves its check's streams through pages that
+    hold two heads of 64 to a row and the conv layers' windows, and reports."""
+    result = cases._rehearse(ROOT, CELL, 0, "4")
+    assert result["device"]["platform"] == "cpu"
+    assert result["attempted"] > 0 and result["failed"] == 0
+    assert set(result["metrics"]) == {"batch_tokens_per_s", "setup_s"}
+    rec = json.load(open(os.path.join(
+        BENCH, "out", CELL, "2147483999", "run-trace0.json")))
+    assert rec["config"]["hidden_size"] == 256          # REHEARSE's
+    assert rec["config"]["layer_types"] == ["conv", "full_attention", "conv",
+                                            "conv"]
+    assert len(rec["check"]["prompt_lengths"]) == 8
+    paths = rec["replica"]["attention_paths"]
+    assert paths.get("decode_reference") and paths.get("fwd_reference")
+    assert rec["replica"]["inflight_peak"] > 8
+
+
+@pytest.mark.parametrize("fixture", ["tiny24.xplane.pb", "tiny.xplane.pb"])
+def test_every_new_reader_is_silent_on_a_trace_without_its_scopes(
+        fixture, monkeypatch):
+    """The traces recorded on the chip at PR 24 and PR 23: a dense model's
+    programs, no `conv_in`, no `experts_touched` on a span."""
+    with open(os.path.join(HERE, fixture), "rb") as f:
+        t = program_trace.parse(f.read())
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    config = run.load_json(BENCH, "configs", "lfm2-24b-a2b-serve.json")
+    record = {"config": config, "cell": "x", "seed": 0, "trace_data": None,
+              "device": {"kind": "TPU v5 lite"}}
+    for name in READERS:
+        assert run.load_reader(BENCH, "layer_metrics", name)(record) is None, \
+            name

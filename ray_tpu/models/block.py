@@ -24,6 +24,9 @@ engine's prefill and its decode step (`serve/engine.py`) all call them.
   mamba_mixer        a state-space layer's whole mixer, over a sequence or
                      for one token a slot: its state is an argument and a
                      result, so where the state lives is the caller's
+  conv_mixer         a gated short-convolution layer's whole operator (the
+                     LFM2 family), likewise once for a sequence and for one
+                     token a slot, its window an argument and a result
 
 and the two ways a program that runs no gradient (serving) holds its layer
 stacks differently from training, each so that the compiler reads a layer's
@@ -272,16 +275,15 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     with jax.named_scope("mlp"):
         if cfg.n_experts > 0 and "router" in lp:
-            more = {}
-            if cfg.latent or cfg.mixed:
-                routing = cfg.routing()
-                if routing is not None:
-                    routing = dict(routing, bias=lp["router_bias"])
-                more = dict(
-                    routing=routing, held=cfg.experts_held,
-                    shared=tuple(lp["ws_" + k].astype(dt)
-                                 for k in ("gate", "up", "down"))
-                    if cfg.n_shared_experts else None)
+            # (None, None, None for a uniform stack: `__post_init__`.)
+            routing = cfg.routing()
+            if routing is not None:
+                routing = dict(routing, bias=lp["router_bias"])
+            more = dict(
+                routing=routing, held=cfg.experts_held,
+                shared=tuple(lp["ws_" + k].astype(dt)
+                             for k in ("gate", "up", "down"))
+                if cfg.n_shared_experts else None)
             out, aux, counts = moe_ffn(
                 h.reshape(-1, h.shape[-1]), lp["router"].astype(dt),
                 lp["w_up"].astype(dt), lp["w_gate"].astype(dt),
@@ -350,6 +352,41 @@ def mamba_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
         if step:
             y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
         return x + y @ lp["out_proj"].astype(dt), state, window
+
+
+def conv_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, window=None, *,
+               step: bool = False, length=None
+               ) -> Tuple[jax.Array, jax.Array]:
+    """x + operator(norm(x)) for a gated short-convolution layer, the LFM2
+    family's (`cfg.conv_layers`):
+
+      B, C, X = split(norm(x) W_in);  z = B * X                scope conv_in
+      c_t     = sum_j w[j] * z_{t-K+1+j}     (K = cfg.conv_taps, no bias,
+                zeros before the sequence's start)                   conv
+      out     = x + (C * c) W_out                               conv_out
+
+    No position signal, and nothing kept of a sequence but the last K - 1
+    rows of `z`. x `[S, D]`, one sequence, from `window` `[K - 1, D]` (None:
+    a sequence's start); the window handed back is the K - 1 rows of z before
+    row `length` (S if None), so a bucket's padding never enters it. Or, with
+    `step`, x `[ns, D]`, one token a slot, from `window` `[K - 1, ns, D]`
+    (`ops/slot_state.py`'s layout). The convolution is `ops.ssm.causal_conv`,
+    Jamba's, without its bias. -> (out, window)."""
+    dt = cfg.dtype
+    with jax.named_scope("conv_in"):
+        h = rms_norm(x, lp["norm"], cfg.norm_eps)
+        b, c, u = jnp.split(h @ lp["in_proj"].astype(dt), 3, axis=-1)
+        z = b * u
+    with jax.named_scope("conv"):
+        if step:    # each slot a sequence of one row, its window its own
+            y, window = jax.vmap(
+                lambda row, win: causal_conv(row, lp["conv_w"], None, win),
+                (0, 1), (0, 1))(z[:, None], window)
+            y = y[:, 0]
+        else:
+            y, window = causal_conv(z, lp["conv_w"], None, window, length)
+    with jax.named_scope("conv_out"):
+        return x + (c * y.astype(dt)) @ lp["out_proj"].astype(dt), window
 
 
 _QKV = ("wq", "wk", "wv")
@@ -524,7 +561,7 @@ def expert_stacks(layers: Dict[str, jax.Array], cfg
     it is built), a copy of all the experts a call where they are not. Training keeps the slice
     (`llama._layer_fwd`): a gradient through the stack would be written whole
     once a layer."""
-    if "wqkv" not in layers and "w_uk" not in layers:
+    if "wq" in layers or "w_ukv" in layers:
         raise ValueError("a serving program takes `fuse_qkv(params)`: one "
                          "q/k/v projection stack, not a matrix each")
     names = ("w_gate", "w_up", "w_down") \

@@ -144,6 +144,25 @@ class LlamaConfig:
     window_sink: bool = False
     rotary_dim: int = 0
     value_scale: float = 1.0
+    # Gated short-convolution layers among the attention layers (the LFM2
+    # family): every layer whose index is in `conv_layers` has, in the place
+    # of attention, `models.block.conv_mixer`: an input projection cut in
+    # three, B, C and X, a depthwise causal convolution of `conv_taps` taps
+    # over B * X, the gate C and an output projection; no position signal.
+    # The stack is sparse (n_experts > 0) after `first_dense` leading dense
+    # layers, which are conv layers, and its router may be either variant.
+    # The parameters are stacks by kind, none holding a weight of a kind it
+    # is not: `dense` (a conv operator over the dense feed-forward), `conv`
+    # (a conv operator over the experts) and `layers` (attention over the
+    # experts); `segments()` has the order they run in. A serving cache of
+    # two shapes: pages for the attention layers, and for each conv layer
+    # the last `conv_taps - 1` inputs of the convolution a slot, whatever the
+    # context (`ops/slot_state.py`).
+    conv_layers: Optional[Tuple[int, ...]] = None
+    conv_taps: int = 3
+    # What the sigmoid router adds to the sum it renormalises by
+    # (`ops.moe.top_k_routing`): DeepSeek-V3's 1e-20, the LFM2 family's 1e-6.
+    router_norm_eps: float = 1e-20
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -171,18 +190,23 @@ class LlamaConfig:
             object.__setattr__(self, "attn_pattern",
                                tuple(int(k) for k in self.attn_pattern))
             self._check_mixed()
-        segmented = self.latent or self.mixed
+        if self.conv_layers is not None:
+            object.__setattr__(self, "conv_layers",
+                               tuple(sorted(int(i) for i in self.conv_layers)))
+            self._check_conv()
+        segmented = self.latent or self.mixed or self.conv
         if self.first_dense and not (segmented and self.n_experts
                                      and self.first_dense < self.n_layers):
             raise ValueError("first_dense: leading dense layers under a "
-                             "sparse latent-attention or mixed-attention "
-                             "stack")
+                             "sparse latent-attention, mixed-attention or "
+                             "short-convolution stack")
         if (self.experts_held or self.router_score != "softmax") \
                 and not segmented:
             raise ValueError("a share of the experts and the sigmoid router "
                              "are served by the stacks that run as segments: "
-                             "latent attention (kv_lora_rank > 0) and mixed "
-                             "attention (attn_pattern)")
+                             "latent attention (kv_lora_rank > 0), mixed "
+                             "attention (attn_pattern) and short-convolution "
+                             "layers (conv_layers)")
         if self.n_shared_experts and not self.latent:
             raise ValueError("shared experts are served by the latent-"
                              "attention stack alone (kv_lora_rank > 0)")
@@ -217,10 +241,43 @@ class LlamaConfig:
                              "kernels and the caches take a key in two "
                              "parts)")
 
+    def _check_conv(self) -> None:
+        if self.latent or self.mixed or self.ssm_state or self.index_topk \
+                or self.mrope_section or self.rope_yarn:
+            raise ValueError("short-convolution layers (conv_layers) come "
+                             "with plain attention: no latent or mixed "
+                             "attention, state-space layers, indexer, mrope "
+                             "or YaRN")
+        conv = self.conv_layers
+        if not conv or len(set(conv)) != len(conv) \
+                or not 0 <= conv[0] <= conv[-1] < self.n_layers \
+                or len(conv) == self.n_layers:
+            raise ValueError("conv_layers: distinct indices under n_layers, "
+                             "some layer a conv layer and some attention")
+        if not self.n_experts or self.experts_held:
+            raise ValueError("short-convolution layers (conv_layers) are "
+                             "served over sparse experts, every one held "
+                             "(n_experts > 0, no experts_held): the stacks "
+                             "`dense`, `conv` and `layers`")
+        if set(range(self.first_dense)) - set(conv):
+            raise ValueError("conv_layers: the first_dense leading layers "
+                             "are conv layers (the stack `dense`)")
+        if self.first_dense and not self.d_ff_dense:
+            raise ValueError("first_dense: d_ff_dense says the dense "
+                             "feed-forward's width")
+        if self.conv_taps < 2:
+            raise ValueError("conv_taps: a convolution over two inputs at "
+                             "least")
+
     @property
     def mixed(self) -> bool:
         """Window and full attention in one stack (`attn_pattern`)."""
         return self.attn_pattern is not None
+
+    @property
+    def conv(self) -> bool:
+        """Gated short-convolution layers beside attention (`conv_layers`)."""
+        return self.conv_layers is not None
 
     def attention_kind(self, stack: str) -> Tuple[int, float, int, bool]:
         """(kv heads, rope theta, window, sink) of the layers of one stack of
@@ -237,6 +294,8 @@ class LlamaConfig:
         layers, of a mixed-attention stack the full-attention ones."""
         if self.mixed:
             return self.attn_pattern.count(0)
+        if self.conv:
+            return self.n_layers - len(self.conv_layers)
         return self.n_layers if self.attn_layers is None \
             else len(self.attn_layers)
 
@@ -273,7 +332,8 @@ class LlamaConfig:
         if self.router_score == "softmax":
             return None
         return dict(score=self.router_score, n_group=self.n_group,
-                    topk_group=self.topk_group, scale=self.routed_scale)
+                    topk_group=self.topk_group, scale=self.routed_scale,
+                    norm_eps=self.router_norm_eps)
 
     @property
     def ssm_inner(self) -> int:
@@ -289,12 +349,17 @@ class LlamaConfig:
         ("dense", 0, first_dense), the leading dense layers, if it has any,
         then ("layers", 0, n_layers - first_dense), the rest. A
         mixed-attention stack likewise, its kinds `dense`, `window` and
-        `layers`: each run of layers of one stack a segment."""
-        if self.mixed:
-            out, at = [], {"dense": 0, "window": 0, "layers": 0}
-            for i, kind in enumerate(self.attn_pattern):
+        `layers`: each run of layers of one stack a segment. A stack with
+        short-convolution layers the same way, its kinds `dense`, `conv` and
+        `layers`."""
+        if self.mixed or self.conv:
+            out, at = [], {"dense": 0, "window": 0, "conv": 0, "layers": 0}
+            for i in range(self.n_layers):
+                second = self.attn_pattern[i] if self.mixed \
+                    else i in self.conv_layers
                 name = "dense" if i < self.first_dense \
-                    else "window" if kind else "layers"
+                    else ("window" if self.mixed else "conv") if second \
+                    else "layers"
                 if out and out[-1][0] == name:
                     out[-1] = (name, out[-1][1], at[name] + 1)
                 else:
@@ -452,11 +517,107 @@ def _init_mixed(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     return out
 
 
+def _conv_stacks(cfg: LlamaConfig) -> Dict[str, Tuple[int, bool, bool]]:
+    """name -> (layers, conv operator or attention, sparse) of each stack a
+    model with short-convolution layers has."""
+    conv = len(cfg.conv_layers) - cfg.first_dense
+    out = {"dense": (cfg.first_dense, True, False),
+           "conv": (conv, True, True),
+           "layers": (cfg.kv_layers, False, True)}
+    return {k: v for k, v in out.items() if v[0]}
+
+
+def _conv_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    out = {"embed": ("vocab", "embed"), "final_norm": ("embed",)}
+    if not cfg.tie_embeddings:
+        out["lm_head"] = ("embed", "vocab")
+    for name, (_, conv, sparse) in _conv_stacks(cfg).items():
+        lead = ("layers", "expert") if sparse else ("layers",)
+        stack = {"norm": ("layers", "embed"),
+                 "in_proj": ("layers", "embed", "mlp"),
+                 "conv_w": ("layers", None, "embed"),
+                 "out_proj": ("layers", "embed", "embed")} if conv else {
+                 "attn_norm": ("layers", "embed"),
+                 "wq": ("layers", "embed", "heads"),
+                 "wk": ("layers", "embed", "kv_heads"),
+                 "wv": ("layers", "embed", "kv_heads"),
+                 "wo": ("layers", "heads", "embed")}
+        if not conv and cfg.qk_norm:
+            per_head = cfg.qk_norm == "head"
+            stack.update(
+                q_norm=("layers", "head_dim" if per_head else "heads"),
+                k_norm=("layers", "head_dim" if per_head else "kv_heads"))
+        stack.update(mlp_norm=("layers", "embed"),
+                     w_gate=(*lead, "embed", "mlp"),
+                     w_up=(*lead, "embed", "mlp"),
+                     w_down=(*lead, "mlp", "embed"))
+        if sparse:
+            stack["router"] = ("layers", "embed", "expert")
+            if cfg.router_score == "sigmoid":
+                stack["router_bias"] = ("layers", "expert")
+        out[name] = stack
+    return out
+
+
+def _init_conv(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
+    """A model with short-convolution layers: a stack a kind
+    (`_conv_stacks`). A conv operator's leaves are `norm`, `in_proj` `[D, 3
+    D]` (its columns B, then C, then X), `conv_w` `[taps, D]` (the channel
+    axis the minor one, as `ops/ssm.py` has it; tap j meets the input `taps
+    - 1 - j` positions back) and `out_proj`; an attention layer's are the
+    uniform stack's, with the q and k norms. Keys from lists of this
+    function's own."""
+    D, H, KVH, V = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.vocab_size
+    hd, pd = cfg.head_dim, cfg.param_dtype
+
+    def norm(shape, k, scale=0.02):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(pd)
+
+    top = iter(jax.random.split(key, 5))
+    out = {"embed": norm((V, D), next(top)), "final_norm": jnp.ones((D,), pd)}
+    head_key = next(top)
+    if not cfg.tie_embeddings:
+        out["lm_head"] = norm((D, V), head_key)
+    for name, (L, conv, sparse) in _conv_stacks(cfg).items():
+        ks = iter(jax.random.split(next(top), 16))
+        if conv:
+            stack = {"norm": jnp.ones((L, D), pd),
+                     "in_proj": norm((L, D, 3 * D), next(ks)),
+                     "conv_w": norm((L, cfg.conv_taps, D), next(ks),
+                                    cfg.conv_taps ** -0.5),
+                     "out_proj": norm((L, D, D), next(ks))}
+        else:
+            stack = {"attn_norm": jnp.ones((L, D), pd),
+                     "wq": norm((L, D, H * hd), next(ks)),
+                     "wk": norm((L, D, KVH * hd), next(ks)),
+                     "wv": norm((L, D, KVH * hd), next(ks)),
+                     "wo": norm((L, H * hd, D), next(ks))}
+            if cfg.qk_norm:
+                per_head = cfg.qk_norm == "head"
+                stack.update(
+                    q_norm=jnp.ones((L, hd if per_head else H * hd), pd),
+                    k_norm=jnp.ones((L, hd if per_head else KVH * hd), pd))
+        stack["mlp_norm"] = jnp.ones((L, D), pd)
+        lead, F = ((L, cfg.n_experts), cfg.d_ff) if sparse \
+            else ((L,), cfg.d_ff_dense)
+        stack.update(w_gate=norm((*lead, D, F), next(ks)),
+                     w_up=norm((*lead, D, F), next(ks)),
+                     w_down=norm((*lead, F, D), next(ks)))
+        if sparse:
+            stack["router"] = norm((L, D, cfg.n_experts), next(ks))
+            if cfg.router_score == "sigmoid":
+                stack["router_bias"] = norm((L, cfg.n_experts), next(ks))
+        out[name] = stack
+    return out
+
+
 def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     if cfg.latent:
         return _latent_axes(cfg)
     if cfg.mixed:
         return _mixed_axes(cfg)
+    if cfg.conv:
+        return _conv_axes(cfg)
     layers: Dict[str, Tuple] = {
         "attn_norm": ("layers", "embed"),
         "wq": ("layers", "embed", "heads"),
@@ -582,6 +743,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         return _init_latent(cfg, key)
     if cfg.mixed:
         return _init_mixed(cfg, key)
+    if cfg.conv:
+        return _init_conv(cfg, key)
     L, D, H, KVH = cfg.kv_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads
     hd, F, V = cfg.head_dim, cfg.d_ff, cfg.vocab_size
     pd = cfg.param_dtype
@@ -846,6 +1009,12 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
             "segments by kind, the window and the sink have no backward "
             "kernel, and a share of the experts (experts_held) takes no "
             "gradient for the experts that are absent (ROADMAP, Reach)")
+    if cfg.conv:
+        raise NotImplementedError(
+            "short-convolution layers (conv_layers) run through Serve only: "
+            "the training forward has no stack of segments by kind, and no "
+            "flash kernel here has a backward at a head of half a tile "
+            "(ROADMAP, Reach)")
     dt = cfg.dtype
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dt)

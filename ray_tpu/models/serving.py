@@ -52,8 +52,9 @@ class Caches(NamedTuple):
     latent-attention model's arena of latent rows is `kc`, and its `vc` None).
     `ic`: the indexer keys' arena of a model with sparse attention, under the
     same block table. `state`: what a model keeps a SLOT, no pages
-    (`ops/slot_state.py`): the recurrent state of state-space layers, or the
-    rings of window layers. None where the model has no such cache."""
+    (`ops/slot_state.py`): the recurrent state of state-space layers, the
+    rings of window layers, or the windows of short-convolution layers. None
+    where the model has no such cache."""
     kc: Any = None
     vc: Any = None
     ic: Any = None
@@ -113,8 +114,9 @@ class _Kind(NamedTuple):
     # sliced a layer at a time, and the layers' ordinals (the experts' stacks
     # stay whole, `block.expert_stacks`, read by ordinal); "slices": over the
     # weights alone, the ordinals too only under riders; "index": over the
-    # ordinals alone, the body reading its layer from the whole stack;
-    # "inline": no scan, one layer where it stands.
+    # ordinals alone, the body reading its layer from the whole stack (so a
+    # segment may be PART of its stack; the experts' stacks stay whole here
+    # too); "inline": no scan, one layer where it stands.
     over: str = "scan"
     # Entries of `ctx` that ride a prompt's scan as CARRY, not closed over.
     rides: Tuple[str, ...] = ()
@@ -388,6 +390,42 @@ def _mamba_kind(mcfg) -> _Kind:
                  carries=("state",))
 
 
+def _conv_kind(mcfg) -> _Kind:
+    """A gated short-convolution layer (`block.conv_mixer`; the LFM2 family)
+    over a dense or a sparse feed-forward: no K and V, for each slot the
+    convolution's window and nothing else (`ops/slot_state.py`: a state with
+    no recurrent part), whose layer is the layer's ordinal among ALL the conv
+    layers, the leading dense ones first. Rows past `length` reach no real
+    row: the convolution is causal, and the operator is told `length`. A
+    sparse layer hands back its routing counts."""
+    def prefill(lp, x, caches, l, ctx):
+        routed_layer = "router" in lp
+        y, window = block.conv_mixer(lp, x[0], mcfg, length=ctx["length"])
+        y, routed = block.feed_forward(lp, y[None], mcfg, ctx["live"],
+                                       l if routed_layer else None)
+        return y, caches, (None, window), \
+            block.expert_stats(routed[1]) if routed_layer else None
+
+    def decode(lp, x, caches, l, ctx):
+        routed_layer = "router" in lp
+        state, at = caches.state, ctx["base"] + l
+        # The window's read and its write back are the convolution's
+        # traffic: under the scope that times it.
+        with jax.named_scope("conv"):
+            _, window = slot_state.layer_state(state, at)
+        x, window = block.conv_mixer(lp, x, mcfg, window, step=True)
+        with jax.named_scope("conv"):
+            state = slot_state.update_layer(state, at, ctx["act"], None,
+                                            window)
+        x, routed = block.feed_forward(lp, x, mcfg, ctx["act"],
+                                       l if routed_layer else None)
+        return x, caches._replace(state=state), \
+            block.expert_stats(routed[1]) if routed_layer else None
+
+    return _Kind(prefill, decode, keeps=("state", "state"), over="index",
+                 carries=("state",))
+
+
 def _latent_kind(mcfg) -> _Kind:
     """A latent-attention (MLA) layer: a prompt through
     `latent_flash_attention`; a decode step's absorbed query against the
@@ -566,6 +604,20 @@ def _stack(mcfg) -> _Stack:
                     unpaged, ns, mcfg.ssm_state, mcfg.ssm_inner,
                     mcfg.ssm_conv, dt)),
             lambda c: {"state_bytes": slot_state.state_bytes(c.state)})
+    if mcfg.conv:
+        kind = _conv_kind(mcfg)
+        return _Stack(
+            {"dense": kind, "conv": kind,
+             "layers": _attention_kind(mcfg, False, over="index",
+                                       carries=("kc", "vc"))},
+            uniform_tables,
+            # pages for the attention layers, a window a slot for the rest
+            lambda ns, page, n_pages: Caches(
+                *paged_kv.empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt),
+                state=slot_state.empty_state(unpaged, ns, 0, mcfg.d_model,
+                                             mcfg.conv_taps, dt)),
+            lambda c: {"conv_state_bytes": slot_state.state_bytes(c.state)},
+            tally="zero")
     if mcfg.latent:
         kind = _latent_kind(mcfg)
         return _Stack(
@@ -655,12 +707,11 @@ def _over(kind: _Kind, layers, mcfg, body, ordinals=True):
     if kind.over == "inline":
         return lambda lo, hi, carry: body(_layer_of(layers, lo), lo, carry)
     by_index = kind.over == "index"
-    sliced, whole = (None, {}) if by_index \
-        else block.expert_stacks(layers, mcfg)
+    sliced, whole = block.expert_stacks(layers, mcfg)
 
     def layer(carry, xs):
         if by_index:
-            return body(_layer_of(layers, xs), xs, carry)
+            return body(dict(_layer_of(sliced, xs), **whole), xs, carry)
         lp, l = xs if ordinals else (xs, None)
         return body(dict(lp, **whole), l, carry)
 

@@ -9,7 +9,12 @@ Design:
   * ``attention_reference`` — pure jnp, fp32 softmax; ground truth for tests
     and the CPU path.
   * ``_flash_fwd_pallas`` — Pallas TPU forward kernel, online-softmax over KV
-    blocks with VMEM accumulators (MXU-aligned 128-multiple block shapes).
+    blocks with VMEM accumulators (MXU-aligned 128-multiple block shapes). A
+    head of 64 (half a tile, the LFM2 family's) lies in blocks `[rows, 64]`,
+    the whole head the block's last dimension: q, k and v are read as they
+    are projected, no lane padded in memory; the MXU contracts over 64 and
+    writes 64 columns, half of what it could (PERF.md section 5 has the
+    share of the roofline that leaves). No backward at that width.
   * ``flash_attention`` — custom_vjp: Pallas forward on TPU (reference forward
     elsewhere); backward is the standard two-kernel Pallas flash backward
     (dK/dV pass + dQ pass, bf16 MXU matmuls with f32 accumulation), with a
@@ -58,17 +63,26 @@ def _on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
-def pallas_eligible(q: jax.Array, k: jax.Array) -> bool:
+# A head of half a tile (the LFM2 family's 64): the forward kernel takes it
+# as a block whose last dimension is the whole head, which is a legal block
+# (a last dimension is a multiple of 128 or the array's own); the backward
+# kernels have no such form and fall back to the blockwise XLA path.
+_HALF_TILE = 64
+
+
+def pallas_eligible(q: jax.Array, k: jax.Array,
+                    backward: bool = False) -> bool:
     """The dispatch gate: Pallas on a TPU backend for 128-aligned
-    sequence lengths and head dims, the XLA reference path otherwise
-    (CPU tests, unaligned shapes)."""
+    sequence lengths and head dims (the forward kernel a head of 64 too),
+    the XLA reference path otherwise (CPU tests, unaligned shapes)."""
+    d = q.shape[-1]
     return (_on_tpu() and q.shape[2] % 128 == 0 and k.shape[2] % 128 == 0
-            and q.shape[-1] % 128 == 0)
+            and (d % 128 == 0 or (d == _HALF_TILE and not backward)))
 
 
 def _use_pallas(kind: str, q: jax.Array, k: jax.Array) -> bool:
     """pallas_eligible, with the choice counted and logged."""
-    use = pallas_eligible(q, k)
+    use = pallas_eligible(q, k, backward=kind == "bwd")
     path = "pallas" if use else "reference"
     _path_counts[f"{kind}_{path}"] += 1
     logger.debug("flash_attention %s: %s path, q=%s kv_len=%d", kind, path,
@@ -179,7 +193,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q=1024,
-                      block_k=1024):
+                      block_k=1024, interpret=False):
     b, h, sq, d = q.shape
     skv = k.shape[2]
     block_q = _pick_block(sq, block_q)
@@ -217,6 +231,7 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q=1024,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 

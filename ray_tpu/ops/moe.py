@@ -50,8 +50,8 @@ from ray_tpu.ops import attention
 def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True,
                   *, score: str = "softmax",
                   bias: Optional[jax.Array] = None, n_group: int = 1,
-                  topk_group: int = 1, scale: float = 1.0
-                  ) -> Tuple[jax.Array, jax.Array]:
+                  topk_group: int = 1, scale: float = 1.0,
+                  norm_eps: float = 1e-20) -> Tuple[jax.Array, jax.Array]:
     """gate_logits: [tokens, n_experts] -> (weights [tokens, k], idx [tokens, k]).
 
     `score` "softmax": the weights are the float32 softmax over all experts at
@@ -64,8 +64,9 @@ def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True,
     `n_group` groups of equal size, a group scores the sum of its two largest
     s', the `topk_group` best groups stay and the k largest s' are taken among
     their experts alone (ties to the smaller index, here and there); the
-    weights are s (NOT s') at the chosen k, renormalised if `norm_topk_prob`,
-    times `scale` (`routed_scaling_factor`).
+    weights are s (NOT s') at the chosen k, renormalised if `norm_topk_prob`
+    (over their sum + `norm_eps`: DeepSeek-V3 publishes 1e-20, the LFM2 family
+    1e-6), times `scale` (`routed_scaling_factor`).
     """
     if score == "softmax":
         probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
@@ -89,7 +90,8 @@ def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True,
     _, idx = jax.lax.top_k(choice, k)
     weights = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk_prob:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + norm_eps)
     return weights * scale, idx
 
 

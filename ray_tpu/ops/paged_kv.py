@@ -53,6 +53,18 @@ data-dependent branch. Which pages a slot holds is the host's business
     head a token a layer in bfloat16 for 640 of use (3,072 B a token a layer
     at MiMo-V2's 4 kv heads, 2,560 of use). Its window layers' rows are no
     pages at all: a ring a slot (`ops/slot_state.py`).
+  * A head of HALF a tile (64 numbers, the LFM2 family's) lies two kv heads
+    to a row: `[n_layers, n_pages, kv_heads / 2, page, 128]`, kv heads 2r and
+    2r + 1 side by side in row r's lanes 0..63 and 64..127 (``empty`` packs
+    where `head_dim` is 64 and the kv heads are even). Declared 64 wide a
+    head would lie in 128 lanes all the same, half of the arena and of
+    every read padding; packed, a token is 2 x kv_heads x 64 numbers a layer
+    and nothing else (2,048 B at 8 kv heads in bfloat16). ``write_prompt``
+    and ``write_token`` tell the layout by the arena's shape and write a row
+    of two heads; ``paged_decode_attention`` hands its kernel each query head
+    in ITS kv head's half of the lanes, zeros in the other (a score is then
+    q . k of its own head, the other's lanes times zero), and keeps that half
+    of the output.
   * ``paged_decode_attention`` is one query token a slot against the arena:
     a Pallas TPU kernel that reads a slot's live pages where they lie (the
     XLA gather over the whole block table elsewhere), counted at trace time
@@ -85,11 +97,14 @@ def empty(n_layers: int, n_pages: int, kv_heads: int, page: int,
           v_head_dim: Optional[int] = None):
     """-> (kc, vc), the zeroed arena; `by_token`: for a reader that gathers
     positions (see the top); `v_head_dim`: values of a width of their own,
-    and each width in rows of the next multiple of 128 lanes."""
+    and each width in rows of the next multiple of 128 lanes. Heads of half
+    a tile lie two to a row (`_packed`)."""
     if v_head_dim is not None:
         return tuple(jnp.zeros((n_layers, n_pages, kv_heads, page,
                                 _lanes(d)), dtype)
                      for d in (head_dim, v_head_dim))
+    if not by_token and _packed(kv_heads, head_dim):
+        kv_heads, head_dim = kv_heads // 2, 2 * head_dim
     shape = (n_layers, n_pages, page, kv_heads * head_dim) if by_token \
         else (n_layers, n_pages, kv_heads, page, head_dim)
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
@@ -107,6 +122,12 @@ _LANES = 128
 def _lanes(width: int) -> int:
     """`width` rounded up to whole tiles of 128 lanes."""
     return -(-width // _LANES) * _LANES
+
+
+def _packed(kv_heads: int, head_dim: int) -> bool:
+    """Whether an arena of these heads holds two to a row: a head of half a
+    tile, an even number of them."""
+    return 2 * head_dim == _LANES and kv_heads % 2 == 0
 
 
 def empty_latent(n_layers: int, n_pages: int, page: int, width: int, dtype):
@@ -149,6 +170,11 @@ def write_prompt(kc, vc, pages, ks, vs):
     if vc is None:          # a latent arena: `ks` [L, W, rank + dr]
         return write_prompt_rows(kc, pages, _to_width(ks, kc)), None
     L, W, KVH, hd = ks.shape
+    if kc.ndim == 5 and kc.shape[2] != KVH:
+        # heads of half a tile, two to a row: adjacent heads' numbers are
+        # adjacent in a position's row already
+        KVH, hd = kc.shape[2], kc.shape[-1]
+        ks, vs = ks.reshape(L, W, KVH, hd), vs.reshape(L, W, KVH, hd)
     if kc.ndim == 5 and (hd, vs.shape[-1]) != (kc.shape[-1], vc.shape[-1]):
         # a mixed stack's: each narrower than the lanes it lies in
         ks, vs = _to_width(ks, kc), _to_width(vs, vc)
@@ -201,6 +227,8 @@ def write_token(kc, vc, layer, block_table, w, active, k, v):
                 write_token_rows(vc, layer, block_table, w, active,
                                  v.reshape(ns, -1)))
     ns, page = k.shape[0], kc.shape[3]
+    if kc.shape[2] != k.shape[1]:       # heads of half a tile, two to a row
+        k, v = (t.reshape(ns, kc.shape[2], -1) for t in (k, v))
     if (k.shape[-1], v.shape[-1]) != (kc.shape[-1], vc.shape[-1]):
         k, v = _to_width(k, kc), _to_width(v, vc)   # narrower than their lanes
     with jax.named_scope("kv_write"):
@@ -380,16 +408,22 @@ def _paged_decode_pallas(q, kc, vc, layer, block_table, lengths, *,
 
 
 def _paged_decode_reference(q, kc, vc, layer, block_table, lengths, *,
-                            sm_scale):
+                            sm_scale, packed=False):
     """The XLA path: gather every page of every slot's table out of the
-    layer, float32 softmax over the whole context under a length mask."""
+    layer, float32 softmax over the whole context under a length mask.
+    `packed`: a row holds two kv heads, taken apart after the gather."""
     ns, H, hd = q.shape
     _, _, n_kv, page, _ = kc.shape
+    n_kv *= 2 if packed else 1
     groups, ctx = H // n_kv, block_table.shape[1] * page
     qg = q.reshape(ns, n_kv, groups, hd).astype(jnp.float32)
     # [ns, max_pages, n_kv, page, hd]
     kh = kc[layer, block_table].astype(jnp.float32)
     vh = vc[layer, block_table].astype(jnp.float32)
+    if packed:
+        kh, vh = (t.reshape(*t.shape[:-1], 2, hd).transpose(
+            0, 1, 2, 4, 3, 5).reshape(ns, -1, n_kv, page, hd)
+            for t in (kh, vh))
     scores = jnp.einsum("nkgd,npktd->nkgpt", qg, kh).reshape(
         ns, n_kv, groups, ctx) * sm_scale
     live = jnp.arange(ctx)[None, :] < lengths[:, None]          # [ns, ctx]
@@ -399,7 +433,7 @@ def _paged_decode_reference(q, kc, vc, layer, block_table, lengths, *,
     # What a dead position holds is masked out of v too: 0 x NaN is NaN.
     vh = jnp.where(live.reshape(ns, ctx // page, 1, page, 1), vh, 0.0)
     out = jnp.einsum("nkgpt,npktd->nkgd", wts, vh)
-    return out.reshape(ns, H, vc.shape[-1]).astype(q.dtype)
+    return out.reshape(ns, H, vh.shape[-1]).astype(q.dtype)
 
 
 def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
@@ -428,12 +462,26 @@ def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
     use = interpret or (attention._on_tpu() and hd % 128 == 0
                         and page % _sublanes(kc.dtype) == 0)
     attention._path_counts["decode_pallas" if use else "decode_reference"] += 1
-    if use:
-        return _paged_decode_pallas(
-            q, kc, vc, layer, block_table, lengths, sm_scale=scale,
-            pages_per_block=pages_per_block, interpret=interpret)
-    return _paged_decode_reference(q, kc, vc, layer, block_table, lengths,
-                                   sm_scale=scale)
+    # Heads of half a tile, two to a row (see the top): q's own width says so.
+    packed = hd == 2 * q.shape[-1] and vc.shape[4] == hd
+    if not use:
+        return _paged_decode_reference(q, kc, vc, layer, block_table, lengths,
+                                       sm_scale=scale, packed=packed)
+    if packed:
+        ns, H, d = q.shape
+        # [row, kv head of the row, query head of the kv head]: each query
+        # head into its kv head's half of the lanes, and that half kept
+        halves = jnp.eye(2, dtype=q.dtype)
+        q = jnp.einsum("nrpgd,pq->nrpgqd", q.reshape(ns, kc.shape[2], 2, -1, d),
+                       halves).reshape(ns, H, hd)
+    out = _paged_decode_pallas(
+        q, kc, vc, layer, block_table, lengths, sm_scale=scale,
+        pages_per_block=pages_per_block, interpret=interpret)
+    if packed:
+        out = jnp.einsum("nrpgqd,pq->nrpgd",
+                         out.reshape(ns, kc.shape[2], 2, -1, 2, d),
+                         halves).reshape(ns, H, d)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,12 @@ The channel axis is the minor one of both and the axis before it a whole
 tile (N = 16 rows of float32; 16 slots of bfloat16), so neither is padded:
 with N as the minor axis the first would take eight times its size.
 
+A gated short convolution's layers (`LlamaConfig.conv_layers`, the LFM2
+family) keep the window ALONE, no recurrent state: `n_state` 0 makes the
+pair (None, conv), `conv` the last K - 1 gated inputs `z = B * X` of each
+channel (2 x 2,048 numbers a slot a layer at LFM2's widths, whatever the
+context), and every op here passes the None through.
+
   * ``empty_state`` makes it; ``write_state`` puts a prefill's final state
     and window into ONE slot's rows, all layers at once, overwriting the
     whole of what the slot's previous tenant left; ``layer_state`` and
@@ -60,18 +66,21 @@ from ray_tpu.ops import attention
 from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
 from ray_tpu.ops.paged_kv import _lanes, _to_width
 
-State = Tuple[jax.Array, jax.Array]
+State = Tuple[Optional[jax.Array], jax.Array]
 
 
 def empty_state(n_layers: int, n_slots: int, n_state: int, channels: int,
                 conv: int, dtype) -> State:
-    """-> (ssm, conv), zeroed."""
-    return (jnp.zeros((n_layers, n_slots, n_state, channels), jnp.float32),
+    """-> (ssm, conv), zeroed; `n_state` 0: (None, conv), a window and no
+    recurrent state."""
+    return (jnp.zeros((n_layers, n_slots, n_state, channels), jnp.float32)
+            if n_state else None,
             jnp.zeros((n_layers, conv - 1, n_slots, channels), dtype))
 
 
 def state_bytes(state: State) -> int:
-    return sum(int(a.size) * a.dtype.itemsize for a in state)
+    return sum(int(a.size) * a.dtype.itemsize for a in state
+               if a is not None)
 
 
 def write_state(state: State, slot, ssm_rows, conv_rows) -> State:
@@ -79,24 +88,27 @@ def write_state(state: State, slot, ssm_rows, conv_rows) -> State:
     `[n_layers, N, Di]`, `conv_rows` `[n_layers, K - 1, Di]`."""
     ssm, conv = state
     with jax.named_scope("state_write"):
-        return (ssm.at[:, slot].set(ssm_rows.astype(ssm.dtype)),
+        return (None if ssm is None
+                else ssm.at[:, slot].set(ssm_rows.astype(ssm.dtype)),
                 conv.at[:, :, slot].set(conv_rows.astype(conv.dtype)))
 
 
 def layer_state(state: State, layer) -> State:
     """Layer `layer`'s (ssm `[n_slots, N, Di]`, conv `[K - 1, n_slots,
     Di]`)."""
-    return state[0][layer], state[1][layer]
+    return None if state[0] is None else state[0][layer], state[1][layer]
 
 
 def update_layer(state: State, layer, active, ssm_rows, conv_rows) -> State:
     """A decode step's new state of one layer, kept only for the slots
     `active` `[n_slots]` marks: the others' rows stay what they were."""
     ssm, conv = state
-    ssm_rows = jnp.where(active[:, None, None], ssm_rows, ssm[layer])
+    if ssm is not None:
+        ssm_rows = jnp.where(active[:, None, None], ssm_rows, ssm[layer])
     conv_rows = jnp.where(active[None, :, None],
                           conv_rows.astype(conv.dtype), conv[layer])
-    return ssm.at[layer].set(ssm_rows), conv.at[layer].set(conv_rows)
+    return (None if ssm is None else ssm.at[layer].set(ssm_rows),
+            conv.at[layer].set(conv_rows))
 
 
 # ---------------------------------------------------------------------------

@@ -54,11 +54,12 @@ _BLOCK_CHANNELS = 1024
 _BLOCK_ROWS = 512
 
 
-def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
+def causal_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array],
                 window: Optional[jax.Array] = None,
                 length=None) -> Tuple[jax.Array, jax.Array]:
     """Depthwise causal convolution over x `[S, Di]`:
-    y_t = b + sum_k w[k] * x_{t-K+1+k}, the K - 1 inputs before the first row
+    y_t = b + sum_k w[k] * x_{t-K+1+k} (`b` None: no bias, a gated short
+    convolution's), the K - 1 inputs before the first row
     taken from `window` `[K - 1, Di]` (zeros if None: a prompt's start).
     -> (y `[S, Di]` float32, the window to carry on: the last K - 1 inputs
     before row `length` (S if None), so a bucket's padding past `length`
@@ -68,8 +69,12 @@ def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
     if window is None:
         window = jnp.zeros((K - 1, Di), x.dtype)
     xp = jnp.concatenate([window.astype(x.dtype), x], axis=0)   # [S+K-1, Di]
-    y = b.astype(F32) + sum(
-        w[k].astype(F32) * xp[k:k + S].astype(F32) for k in range(K))
+    # (the bias is converted BEFORE the taps: the order of the hybrid stack's
+    # pinned programs, tests/test_dots.py)
+    bias = None if b is None else b.astype(F32)
+    y = sum(w[k].astype(F32) * xp[k:k + S].astype(F32) for k in range(K))
+    if bias is not None:
+        y = bias + y
     start = S if length is None else length
     carried = jax.lax.dynamic_slice_in_dim(xp, start, K - 1, axis=0)
     return y, carried

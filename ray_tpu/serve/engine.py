@@ -513,9 +513,9 @@ class Engine:
         log says so (the engine then holds that copy of the experts; the
         caller may drop its own)."""
         cast = {stack: [k for k in ("w_gate", "w_up", "w_down")
-                        if params[stack][k].dtype != mcfg.dtype]
-                for stack in ("layers", "window")
-                if mcfg.n_experts > 0 and "router" in params.get(stack, ())}
+                        if leaves[k].dtype != mcfg.dtype]
+                for stack, leaves in params.items()
+                if isinstance(leaves, dict) and "router" in leaves}
         if not any(cast.values()):
             return params
         logger.warning(
@@ -561,7 +561,8 @@ class Engine:
                 "indexer's keys nor a state-space layer's recurrent state "
                 "nor latent attention's rows (kv_lora_rank > 0) nor mixed "
                 "attention's two caches (attn_pattern: pages and window "
-                "rings): this model serves from one engine")
+                "rings) nor a short-convolution layer's window (conv_layers)"
+                ": this model serves from one engine")
         if not self._adopt_widths:
             raise RuntimeError(
                 "this engine warmed no `adopt` program and would compile one "

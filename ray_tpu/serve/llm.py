@@ -251,7 +251,8 @@ class PrefillServer:
                 "indexer's keys nor a state-space layer's recurrent state "
                 "nor latent attention's rows (kv_lora_rank > 0) nor mixed "
                 "attention's two caches (attn_pattern: pages and window "
-                "rings): this model serves from one engine")
+                "rings) nor a short-convolution layer's window (conv_layers)"
+                ": this model serves from one engine")
         # The layout the shared prefill core reads, as in the engine.
         self.params = fuse_qkv(params)
         self._core = jax.jit(prefill_core(self.mcfg))

@@ -23,7 +23,7 @@ sys.path.insert(0, ROOT)
 from benchmark import models, program_trace  # noqa: E402
 from benchmark.tests import test_benchmark as cases  # noqa: E402
 
-ARCHS = ["llama", "olmoe", "keye", "jamba", "dots", "mimo"]
+ARCHS = ["llama", "olmoe", "keye", "jamba", "dots", "mimo", "lfm2"]
 # config.json of allenai/OLMoE-1B-7B-0125-Instruct, as the catalog beside the
 # model-configs guide has it.
 OLMOE_PUBLISHED = dict(
@@ -778,14 +778,15 @@ def test_mimo_manifest_entries_are_the_catalogs_row_and_the_issues_traffic():
     assert min(chk["prompt_lengths"]) > cfg["sliding_window"]
     assert max(chk["prompt_lengths"]) <= mix["prompt_tokens"]["max"]
     lists = {p["name"]: p.get("workloads", []) for p in manifest["per_layer"]}
-    assert list(lists)[-9:] == MIMO_READERS
+    at = list(lists).index(MIMO_READERS[0])
+    assert list(lists)[at:at + 9] == MIMO_READERS
     assert all(lists[n] == [MIMO_CELL] for n in MIMO_READERS)
     for name in ("prefill_ms_per_ktok", "kv_pages_peak_pct", "decode_moe_ms",
                  "prefill_moe_ms_per_ktok", "expert_load_max_over_mean",
                  "engine_slot_refill_ms", "prefill_stall_pct",
                  "moe_share_experts_roofline_pct",
                  "local_assignment_share_pct"):
-        assert lists[name][-1] == MIMO_CELL
+        assert MIMO_CELL in lists[name]
     # no share that multiplies by one layer count and one head width, or
     # counts experts this chip does not hold
     for name in ("decode_attn_roofline_pct", "moe_experts_roofline_pct",
@@ -911,4 +912,208 @@ def test_the_engines_spans_carry_what_the_window_readers_read():
     src = open(engine_mod.__file__).read() + open(serving.__file__).read()
     for name in ("window_kv_tokens", "window_cache_bytes",
                  "full_cache_bytes"):
+        assert f'"{name}"' in src or f"{name}=" in src, name
+
+
+# ---------------------------------------------------------------------------
+# lfm2: gated short-convolution layers beside attention on heads of 64 (PR 46)
+# ---------------------------------------------------------------------------
+
+LFM2_CELL = "serve-generate-lfm2"
+LFM2_READERS = ["prefill_conv_ms_per_ktok", "decode_conv_ms",
+                "hybrid_experts_roofline_pct",
+                "head64_prefill_attn_roofline_pct",
+                "head64_decode_attn_roofline_pct", "decode_mfu_pct"]
+
+
+def test_lfm2_manifest_entries_are_the_catalogs_row_and_the_issues_cell():
+    manifest = cases.load(ROOT, "BENCHMARK.json")
+    entry = manifest["configs"][-1]
+    assert entry["name"] == "lfm2-24b-a2b-serve"
+    cfg = cases.load(ROOT, entry["file"])
+    assert entry["source"] == cfg["source_url"] and cfg["arch"] == "lfm2"
+    assert entry["reduced"] == list(cfg["reduced"]) == [
+        "num_hidden_layers", "num_dense_layers", "layer_types"]
+    cell = manifest["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        LFM2_CELL, "lfm2-24b-a2b-serve", "generate-long-lfm2", 1)
+    assert "22%" in cell["why"] and "25%" in cell["why"] \
+        and len(cell["why"]) <= 200
+    mix = cases.load(cases.BENCH, "traffic", "generate-long-lfm2.json")
+    assert mix["kind"] == "serve_closed_checked"
+    chk = mix["check"]
+    assert len(chk["prompt_lengths"]) * chk["tokens"] == 1024
+    # a prompt for each rung of the ladder the mix uses (512, 1024, and the
+    # 256 its shortest prompts fall in is under the 300's 512: 100 -> 128)
+    assert max(chk["prompt_lengths"]) <= mix["prompt_tokens"]["max"]
+    assert chk["logit_tolerance"] > chk["mean_logit_tolerance"] > 0
+    lists = {p["name"]: p.get("workloads", []) for p in manifest["per_layer"]}
+    assert list(lists)[-6:] == LFM2_READERS
+    assert all(lists[n] == [LFM2_CELL] for n in LFM2_READERS)
+    moved = {p["name"]: p["moves"] for p in manifest["per_layer"]}
+    assert {moved[n] for n in LFM2_READERS} == {"batch_tokens_per_s"}
+    for name in ("prefill_ms_per_ktok", "kv_pages_peak_pct", "decode_moe_ms",
+                 "prefill_moe_ms_per_ktok", "expert_load_max_over_mean",
+                 "engine_slot_refill_ms", "prefill_stall_pct"):
+        assert lists[name][-1] == LFM2_CELL
+    e2e = {p["name"]: p.get("workloads", []) for p in manifest["end_to_end"]}
+    assert e2e["batch_tokens_per_s"][-1] == LFM2_CELL
+    # no share that multiplies by `num_hidden_layers` where two layers of
+    # nine have attention and eight have experts
+    for name in ("decode_attn_roofline_pct", "moe_experts_roofline_pct",
+                 "decode_rider_share_pct", "moe_share_experts_roofline_pct"):
+        assert LFM2_CELL not in lists[name]
+
+
+def test_lfm2_counts_against_a_hand_count():
+    m = cases.load(ROOT, "benchmark/configs/lfm2-24b-a2b-serve.json")
+    counts = models.adapter("lfm2").counts
+    d, e, f, v = 2048, 3 * 2048 * 1536, 3 * 2048 * 11776, 65536 * 2048
+    conv = 4 * d * d + 3 * d                    # W_in, W_out, the taps
+    attn = 2 * d * 64 * (32 + 8)
+    sparse = 64 * e + (d + 1) * 64 + 2 * d
+    assert counts.total_params(m) == (
+        conv + f + 2 * d                        # the dense conv layer
+        + 2 * (attn + 2 * 64 + sparse) + 6 * (conv + sparse)
+        + v + d) == 5_177_950_976
+    assert counts.layers(m) == (1, 8)
+    # one decode step of 2 slots at 100 and 28 cached positions, 60 experts
+    # touched a sparse layer
+    ops, byts = counts.decode_step_ops_bytes(m, [100, 28], 2, 2,
+                                             experts_touched=60)
+    matmuls = 2.0 * (2 * attn + 7 * 4 * d * d + f + 8 * (d * 64 + 4 * e) + v)
+    assert ops == 2 * (matmuls + 7 * 8.0 * d) + 2 * 4.0 * 32 * 64 * 128
+    weights = (2 * (attn + 128) + 7 * conv + f + 8 * ((d + 1) * 64 + 60 * e)
+               + 9 * 2 * d + v + d)
+    assert byts == 2.0 * weights + 2 * 128 * 2048 + 2.0 * 2 * 7 * 2 * d * 2
+    with pytest.raises(TypeError):
+        counts.decode_step_ops_bytes(m, [100], 2, 2)
+    # a prompt's attention in ONE layer: the lower triangle, q k v o once
+    ops, byts = counts.prefill_attn_ops_bytes(m, 1000, 2)
+    assert ops == 4.0 * 32 * 64 * 1000 * 1001 / 2
+    assert byts == 1000 * (2 * 32 + 2 * 8) * 64 * 2
+    ops, byts = counts.experts_ops_bytes(m, 256, 62.9, 2, 2)
+    assert ops == 2.0 * e * 256 and byts == 62.9 * e * 2 + 2.0 * 256 * d * 2
+    ops, byts = counts.conv_ops_bytes(m, 10, 2)
+    assert ops == 8.0 * 10 * d and byts == 4 * 10 * d * 2 + 3 * d * 2
+
+
+def test_conv_stack_readers_on_a_synthetic_trace(monkeypatch):
+    """The six readers of PR 46 on a trace built by hand: a prefill of 1,000
+    prompt tokens and one decode chunk of 2 steps, their scopes, the counters
+    on the spans. A program without the scopes (the parent, every other
+    model) reads None and raises nothing."""
+    from benchmark import conv_trace, peaks
+    Span = program_trace.Span
+    dispatch = dict(useful=128, capacity=128, active=64,
+                    live_kv_tokens=64000, experts_touched=2 * 8 * 62)
+    spans = [
+        Span("serve.engine.admit", 900, 950, dict(
+            rid=7, kind="prefill", prompt_tokens=1000, bucket=1024,
+            queue_wait_us=1, decoding=0, slot_idle_us=0)),
+        Span("serve.engine.prefill_experts", 2050, 2060,
+             dict(rid=7, touched=8 * 64)),
+        Span("serve.engine.emit", 2100, 2110, dict(rid=7, kind="first")),
+        Span("serve.engine.decode_dispatch", 2200, 2210, dispatch),
+        Span("serve.engine.decode_dispatch", 3200, 3210, dispatch),
+    ]
+    modules = [("jit_poke", 0, 10), ("jit_prefill", 1000, 2000),
+               ("jit_decode", 2300, 3000), ("jit_poke", 4000, 4010)]
+    pre = "jit(prefill)/layers/while/body/"
+    dec = "jit(decode)/while/body/layers/while/body/"
+    ops = [(pre + "conv_in/dot_general:", 1000, 1100),
+           (pre + "conv/mul:", 1100, 1150),
+           (pre + "conv_out/dot_general:", 1150, 1200),
+           (pre + "attn/pallas_call:", 1200, 1500),
+           (pre + "mlp/experts/pallas_call:", 1500, 2000),
+           (dec + "conv_in/dot_general:", 2300, 2340),
+           (dec + "conv/select_n:", 2340, 2360),
+           (dec + "conv_out/dot_general:", 2360, 2400),
+           (dec + "kv_write/scatter:", 2400, 2450),
+           (dec + "attn/pallas_call:", 2450, 2550),
+           (dec + "mlp/experts/pallas_call:", 2550, 3000)]
+    t = program_trace.ProgramTrace(spans, modules, ops)
+    monkeypatch.setattr(program_trace, "load", lambda run: t)
+    m = cases.load(ROOT, "benchmark/configs/lfm2-24b-a2b-serve.json")
+    m["deployment"]["engine"]["decode_chunk"] = 2
+    run = {"config": m, "cell": "x", "seed": 0, "trace_data": None,
+           "device": {"kind": "TPU v5 lite"}}
+    got = {name: _reader(name)(run) for name in LFM2_READERS}
+    assert got["prefill_conv_ms_per_ktok"] == pytest.approx(200 / 1e6 / 1.0)
+    assert got["decode_conv_ms"] == pytest.approx(100 / 1e6 / 2)
+    counts = models.adapter("lfm2").counts
+    f, b = peaks.peak("TPU v5 lite", "bf16_flops_per_s"), \
+        peaks.peak("TPU v5 lite", "hbm_bytes_per_s")
+
+    def least(ops_bytes):
+        return max(ops_bytes[0] / f, ops_bytes[1] / b)
+
+    assert got["head64_prefill_attn_roofline_pct"] == pytest.approx(
+        100 * 2 * least(counts.prefill_attn_ops_bytes(m, 1000, 2)) / 300e-9)
+    assert got["head64_decode_attn_roofline_pct"] == pytest.approx(
+        100 * 2 * 64000 * 2 * 2048 / b / 100e-9)
+    # the experts: 8 sparse layers, not num_hidden_layers 9
+    want = 8 * least(counts.experts_ops_bytes(m, 4000, 64, 2, 2)) \
+        + 2 * 8 * least(counts.experts_ops_bytes(m, 256, 62, 2, 2))
+    assert got["hybrid_experts_roofline_pct"] == pytest.approx(
+        100 * want / 950e-9)
+    step = counts.decode_step_ops_bytes(m, [1000.0] * 64, 2, 2,
+                                        experts_touched=62.0)
+    assert got["decode_mfu_pct"] == pytest.approx(
+        100 * least(step) / (700e-9 / 2))
+    # a program without the scopes, and a run without a trace
+    bare = program_trace.ProgramTrace(spans, modules, [
+        (p.replace("conv_in/", "qkv/").replace("conv_out/", "attn_out/")
+         .replace("conv/", "attn/"), s, e) for p, s, e in ops])
+    monkeypatch.setattr(program_trace, "load", lambda run: bare)
+    assert [_reader(name)(run) for name in LFM2_READERS] == [None] * 6
+    monkeypatch.setattr(program_trace, "load", lambda run: None)
+    assert [_reader(name)(run) for name in LFM2_READERS] == [None] * 6
+    assert conv_trace.deepest_scope(dec + "conv_in/mul:") == "conv_in"
+    assert conv_trace.deepest_scope(dec + "mlp/experts/x:") == "experts"
+
+
+def test_the_engines_spans_carry_what_the_conv_stack_readers_read():
+    """The names `benchmark/conv_trace.py` and the readers look for are the
+    ones the program emits: the scopes in the lowered programs of a stack
+    with conv layers, the span arguments and the counter in the engine."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import conv_trace, moe_trace
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.models import serving
+    from ray_tpu.models.serving import build_programs
+    from ray_tpu.serve import engine as engine_mod
+
+    adapter = models.adapter("lfm2")
+    m = cases.load(ROOT, "benchmark/configs/lfm2-24b-a2b-serve.json")
+    cfg = adapter.build_config(dict(m, **adapter.REHEARSE), {
+        "params": "float32", "activations": "float32"}, 128)
+    built = build_programs(cfg, 2, 2, 16, 17)
+    params = jax.eval_shape(lambda: fuse_qkv(
+        init_params(cfg, jax.random.PRNGKey(0)), cfg))
+    caches = jax.eval_shape(built.empty)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    scopes = conv_trace.SCOPES + moe_trace.MOE_SCOPES + (
+        "qkv", "qk_norm", "rope", "attn", "attn_out")
+    text = built.decode.lower(
+        params, caches, arg((2, 8), jnp.int32), arg((2,), jnp.int32),
+        arg((2,), jnp.int32), arg((2,), jnp.bool_), arg((2,), jnp.float32),
+        arg((2,), jnp.int32), arg((2, 2), jnp.uint32)
+        ).as_text(debug_info=True)
+    for scope in scopes + ("kv_write",):
+        assert f"{scope}/" in text, scope
+    text = built.prefill.lower(
+        params, caches, arg((8,), jnp.int32), arg((1, 64), jnp.int32), 1,
+        0.0, 0, arg((2,), jnp.uint32), 0).as_text(debug_info=True)
+    for scope in scopes:
+        assert f"{scope}/" in text, scope
+    src = open(engine_mod.__file__).read() + open(serving.__file__).read()
+    for name in ("conv_state_bytes", "live_kv_tokens", "experts_touched",
+                 "touched"):
         assert f'"{name}"' in src or f"{name}=" in src, name

@@ -308,7 +308,7 @@ def test_models_and_ops_import_nothing_from_serve(path):
 
 @pytest.mark.parametrize("name", [
     "named_scope", "lax", "hybrid", "latent", "mixed", "_hybrid", "_latent",
-    "_mixed", "_by_slot", "_third"])
+    "_mixed", "_by_slot", "_third", "conv", "_conv", "conv_layers"])
 def test_the_scheduler_builds_no_program_and_names_no_architecture(name):
     assert name not in set(_named(_tree("serve", "engine.py")))
 
@@ -334,7 +334,8 @@ def test_the_prefill_pool_asks_adopts_and_names_no_architectures_field():
     named = set(_named(_tree("serve", "llm.py")))
     assert "adopts" in named
     assert not named & {"index_topk", "ssm_state", "latent", "mixed",
-                        "kv_lora_rank", "attn_pattern", "attn_layers"}
+                        "kv_lora_rank", "attn_pattern", "attn_layers",
+                        "conv", "conv_layers"}
 
 
 def _own(fn):

@@ -565,6 +565,90 @@ def test_mimo_programs_keep_both_caches_in_place_on_v5e(
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 << 30
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_lfm2_programs_keep_pages_and_windows_in_place_on_v5e(
+        topo, program, monkeypatch):
+    """The stack of short-convolution layers beside attention on heads of 64:
+    its decode chunk of 64 slots and its widest prefill (2,048 rows) at the
+    cell's sizes (benchmark/configs/lfm2-24b-a2b-serve.json). The pages of
+    the 2 attention layers hold two kv heads to a 128-lane row (2,048 B a
+    token a layer, no padded lane) and the 7 conv layers' windows are 2 x
+    2,048 numbers a slot; both are donated and alias the outputs. Decode's
+    attention is the `paged_decode` kernel over that arena, a prompt's the
+    `flash_fwd` kernel at a head of half a tile, the experts the grouped
+    matmul with no copy of a stack; and the bytes are PERF.md section 4's
+    row: 10.90 GB of arguments, temporaries of 4.6 MB (decode) and 63.5 MB
+    (the widest prefill)."""
+    import json
+
+    from benchmark import models
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.models.serving import build_programs
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "lfm2-24b-a2b-serve.json")) as f:
+        model = json.load(f)
+    eng = model["deployment"]["engine"]
+    cfg = models.adapter("lfm2").build_config(model, model["dtypes"],
+                                              eng["max_seq"])
+    ns, page = eng["n_slots"], eng["page_size"]
+    maxp = eng["max_seq"] // page
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    built = build_programs(cfg, ns, eng["decode_chunk"], page,
+                           eng["kv_pages"])
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)), cfg)))
+    caches = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(built.empty))
+    kc, vc, ic, (ssm, window) = caches
+    assert kc.shape == vc.shape == (2, eng["kv_pages"], 4, page, 128)
+    assert ic is None and ssm is None and window.shape == (7, 2, ns, 2048)
+    before = dict(attention.attention_path_counts())
+    if program == "decode":
+        lowered = built.decode.lower(
+            params, caches, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
+            sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
+            sds((ns,), jnp.float32), sds((ns,), jnp.int32),
+            sds((ns, 2), jnp.uint32))
+        kernels, paths = ["paged_decode", "grouped_matmul"], [
+            "decode_pallas", "experts_grouped_pallas"]
+    else:
+        lowered = built.prefill.lower(
+            params, caches, sds((maxp,), jnp.int32), sds((1, 2048), jnp.int32),
+            1, 0.0, 0, sds((2,), jnp.uint32), 0)
+        kernels, paths = ["flash_fwd", "grouped_matmul"], [
+            "fwd_pallas", "experts_grouped_pallas"]
+    text = lowered.as_text()
+    assert all(k in text for k in kernels)
+    counts = attention.attention_path_counts()
+    assert all(counts[p] > before.get(p, 0) for p in paths)
+    assert counts.get("experts_ragged_dot", 0) == before.get(
+        "experts_ragged_dot", 0)
+    compiled = lowered.compile()
+    stacks = [tuple(params[stack][w].shape) for stack in ("conv", "layers")
+              for w in ("w_gate", "w_up", "w_down")]
+    assert not _moved_stacks(compiled.as_text(), stacks)
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in (kc, vc, window))
+    assert held == 2 * 2 * eng["kv_pages"] * 4 * page * 128 * 2 \
+        + 7 * 2 * ns * 2048 * 2 == 540_803_072
+    assert mem.alias_size_in_bytes >= held
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert weights == 2 * 5_177_950_976
+    # arguments: the weights, the caches and a step's few vectors
+    assert 0 <= mem.argument_size_in_bytes - weights - held < 1 << 20
+    assert mem.temp_size_in_bytes < ((8 << 20) if program == "decode"
+                                     else (96 << 20))
+
+
 # ---------------------------------------------------------------------------
 # The experts' grouped matmul (ops/moe.py::grouped_matmul)
 # ---------------------------------------------------------------------------
