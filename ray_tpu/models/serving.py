@@ -197,14 +197,16 @@ def sample_tokens(logits, temp, topk, keys, pos, cap=TOPK_CAP):
     trace-time width, min(TOPK_CAP, vocab)); temp==0 slots stay greedy.
     `keys` are per-slot base PRNG keys; folding in `pos` makes a
     request's sample stream deterministic for its (seed, position)
-    regardless of slot assignment or co-tenants."""
+    regardless of slot assignment or co-tenants. The top-`cap`, the draws
+    and the gather run only where some row of the call asks for a sample
+    (a branch on the device, from `temp`): a call whose rows are all greedy
+    takes its argmax alone, and the tokens are the same either way."""
     cap = min(cap, logits.shape[-1])
 
     def one_gumbel(key, p):
         return jax.random.gumbel(jax.random.fold_in(key, p), (cap,))
 
-    with jax.named_scope("sample"):
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    def draw(greedy):
         vals, idxs = jax.lax.top_k(logits.astype(jnp.float32), cap)
         k_eff = jnp.where(topk > 0, jnp.minimum(topk, cap), cap)
         mask = jnp.arange(cap)[None, :] < k_eff[:, None]
@@ -214,6 +216,10 @@ def sample_tokens(logits, temp, topk, keys, pos, cap=TOPK_CAP):
         pick = jnp.argmax(scaled + g, axis=-1)
         sampled = jnp.take_along_axis(idxs, pick[:, None], axis=1)[:, 0]
         return jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
+
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jax.lax.cond(jnp.any(temp > 0), draw, lambda g: g, greedy)
 
 
 # ---------------------------------------------------------------------------

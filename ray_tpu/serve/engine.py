@@ -261,6 +261,7 @@ class Engine:
         self.prefill_tokens = 0
         self.prefill_padded_tokens = 0     # bucket width less the prompt
         self.decode_chunks = 0
+        self.decode_chunks_sampling = 0    # those with a slot at temp > 0
         self.decode_useful_tokens = 0
         # Tokens decoded inside prefills (a riding slot's one step in a
         # prompt's padding rows), and the prefills that carried any.
@@ -609,6 +610,9 @@ class Engine:
         request's prefill (`rider_steps`: the prefills that carried any), so
         the tokens decoded are the two added; padding is
         `prefill_padded_tokens` over it plus `prefill_tokens`.
+        `decode_chunks_sampling` are the chunks dispatched with a live slot
+        at a temperature above 0 (the span's `sampling` counts the slots):
+        the others' steps took no top-k (`serving.sample_tokens`).
         `live_kv_tokens` over `decode_chunks * n_slots * max_seq` is the
         share of the block tables that was live at dispatch. A sparse
         model adds `expert_tokens` (assignments per expert, all layers,
@@ -639,7 +643,8 @@ class Engine:
             "admit_decoding_slots", "admit_pending", "slot_idle_s_sum",
             "prefill_tokens",
             "prefill_padded_tokens", "decode_chunks",
-            "decode_useful_tokens", "rider_tokens", "rider_steps",
+            "decode_chunks_sampling", "decode_useful_tokens",
+            "rider_tokens", "rider_steps",
             "live_kv_tokens", "peak_pages_used", "n_slots", "chunk")}
         if self._sparse:
             out["expert_tokens"] = [int(n) for n in self.expert_tokens]
@@ -1035,7 +1040,11 @@ class Engine:
             # reach into the in-flight computation.
             useful = sum(take for _, _, take, _ in plan)
             live_kv = int(self._pos[self._active].sum())
+            # Live slots that ask for a sample: with none, the chunk's steps
+            # take their argmax alone (`serving.sample_tokens`).
+            sampling = int((self._active & (self._temp > 0)).sum())
             self.decode_chunks += 1
+            self.decode_chunks_sampling += int(sampling > 0)
             self.decode_useful_tokens += useful
             self.live_kv_tokens += live_kv
             # What the emitter has fetched so far: the chunk before's distinct
@@ -1066,8 +1075,8 @@ class Engine:
                 routed.update(window_kv_tokens=ring)
             with tracing.span("serve.engine.decode_dispatch", useful=useful,
                               capacity=self.n_slots * self.chunk,
-                              active=len(plan), live_kv_tokens=live_kv,
-                              **routed):
+                              active=len(plan), sampling=sampling,
+                              live_kv_tokens=live_kv, **routed):
                 (self._caches, self._last_d, self._pos_d, out_d,
                  experts_d) = self._programs.decode(
                     self._params, self._caches,

@@ -60,7 +60,11 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         module = item.nodeid.split("::")[0].rsplit("/", 1)[-1][:-3]
         if module in _SLOW_MODULES:
-            item.add_marker(pytest.mark.slow)
+            # But for an explicit @pytest.mark.fast inside a slow module: a
+            # light test kept beside the slow ones it belongs with
+            # (test_serve_llm.py's of the sampler), which tier-1 then runs.
+            if item.get_closest_marker("fast") is None:
+                item.add_marker(pytest.mark.slow)
         elif item.get_closest_marker("slow") is None:
             # Respect an explicit @pytest.mark.slow inside an otherwise
             # fast module (e.g. the full graftload soak): adding `fast`

@@ -513,7 +513,8 @@ def test_jamba_manifest_entries_are_the_catalogs_row_uncut():
         "batch_tokens_per_s", "prefill_ms_per_ktok", "kv_pages_peak_pct",
         "prefill_ssm_ms_per_ktok", "scan_roofline_pct", "decode_ssm_ms",
         "decode_state_roofline_pct",
-        "engine_slot_refill_ms", "prefill_stall_pct"}        # PR 37
+        "engine_slot_refill_ms", "prefill_stall_pct",        # PR 37
+        "decode_sample_ms"}                                  # PR 47
     # not `decode_attn_roofline_pct`: its bytes multiply by ALL the layers
 
 
@@ -948,7 +949,10 @@ def test_lfm2_manifest_entries_are_the_catalogs_row_and_the_issues_cell():
     assert max(chk["prompt_lengths"]) <= mix["prompt_tokens"]["max"]
     assert chk["logit_tolerance"] > chk["mean_logit_tolerance"] > 0
     lists = {p["name"]: p.get("workloads", []) for p in manifest["per_layer"]}
-    assert list(lists)[-6:] == LFM2_READERS
+    e2e = {p["name"]: p.get("workloads", []) for p in manifest["end_to_end"]}
+    # PR 47's reader came after them
+    assert list(lists)[-7:] == LFM2_READERS + ["decode_sample_ms"]
+    assert lists["decode_sample_ms"] == e2e["batch_tokens_per_s"]
     assert all(lists[n] == [LFM2_CELL] for n in LFM2_READERS)
     moved = {p["name"]: p["moves"] for p in manifest["per_layer"]}
     assert {moved[n] for n in LFM2_READERS} == {"batch_tokens_per_s"}
@@ -956,7 +960,6 @@ def test_lfm2_manifest_entries_are_the_catalogs_row_and_the_issues_cell():
                  "prefill_moe_ms_per_ktok", "expert_load_max_over_mean",
                  "engine_slot_refill_ms", "prefill_stall_pct"):
         assert lists[name][-1] == LFM2_CELL
-    e2e = {p["name"]: p.get("workloads", []) for p in manifest["end_to_end"]}
     assert e2e["batch_tokens_per_s"][-1] == LFM2_CELL
     # no share that multiplies by `num_hidden_layers` where two layers of
     # nine have attention and eight have experts

@@ -15,6 +15,7 @@ import functools
 import hashlib
 import math
 import time
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -23,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmark import models, reference_dots
-from ray_tpu.models import llama
+from ray_tpu.models import llama, serving
 from ray_tpu.models.block import (fuse_qkv, latent_attention_inputs,
                                   latent_attention_output, split_qkv)
 from ray_tpu.models.serving import Caches, prefill_core
@@ -31,6 +32,7 @@ from ray_tpu.ops import attention, moe, paged_kv
 from ray_tpu.ops.norms import apply_rope, yarn_inv_frequencies
 from ray_tpu.serve.engine import Engine
 from test_mimo import PUBLISHED as MIMO
+from test_serve_llm import parents_sample_tokens
 
 LOGIT_TOL = 2e-4
 F32 = {"params": "float32", "activations": "float32"}
@@ -514,6 +516,11 @@ def test_the_configuration_is_the_catalogs_row_cut_to_a_share():
 # 278d751dc50fcfc4); the three stacks that take nobody keep the parent's.
 # `mixed` (PR 42's stack, which takes nobody) was taken on PR 45's parent
 # (6c2c097), before that PR moved a line under ray_tpu/.
+# Since PR 47 `serving.sample_tokens` takes its top-k behind a conditional, so
+# every serving program's text moves, by design, inside `sample` and nowhere
+# else: the pinned programs are lowered with the sampler of PR 47's parent
+# (7f64f96; `test_serve_llm.parents_sample_tokens`, which the sampler is held
+# to token for token there) in its place, and every digest stands unmoved.
 PARENT = {
     "dense.decode": "d87712c9b4ee5285",
     "dense.prefill32": "c948937b09fe2fee",
@@ -575,7 +582,13 @@ def _lowered(kind):
 # position. So a pinned program is lowered from its own function (the jit's
 # `__wrapped__`) under the parent's name, argument order, result order and
 # `donate_argnums`, which puts the bundle together and takes it apart again:
-# what is compared is then the parent's text, or the program changed.
+# what is compared is then the parent's text, or the program changed. The
+# one callee that changed on purpose since (PR 47's sampler) is lowered as the
+# parent had it: `_parents_sampler`.
+
+def _parents_sampler():
+    return mock.patch.object(serving, "sample_tokens", parents_sample_tokens)
+
 
 def parents_prefill_text(eng, width):
     def prefill(params, kc, vc, pages, tokens, length, temp, topk, key,
@@ -589,9 +602,10 @@ def parents_prefill_text(eng, width):
 
     params, caches, pages, tokens, length, temp, topk, key, slot, *riding = \
         eng.prefill_shapes(width)
-    return jax.jit(prefill, donate_argnums=(1, 2, 9, 10, 12, 13)).lower(
-        params, caches.kc, caches.vc, pages, tokens, length, temp, topk, key,
-        caches.ic, caches.state, slot, *riding).as_text()
+    with _parents_sampler():
+        return jax.jit(prefill, donate_argnums=(1, 2, 9, 10, 12, 13)).lower(
+            params, caches.kc, caches.vc, pages, tokens, length, temp, topk,
+            key, caches.ic, caches.state, slot, *riding).as_text()
 
 
 def parents_decode_text(eng):
@@ -604,9 +618,10 @@ def parents_decode_text(eng):
             c for c in (caches.ic, caches.state) if c is not None))
 
     params, caches, *slots = eng.decode_shapes()
-    return jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10, 11)).lower(
-        params, caches.kc, caches.vc, *slots, caches.ic,
-        caches.state).as_text()
+    with _parents_sampler():
+        return jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10, 11)).lower(
+            params, caches.kc, caches.vc, *slots, caches.ic,
+            caches.state).as_text()
 
 
 @pytest.mark.parametrize("kind", ["dense", "sparse", "indexed", "hybrid",

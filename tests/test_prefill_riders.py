@@ -282,7 +282,10 @@ def test_the_riding_rungs_are_the_top_octaves(max_seq):
 # payload, no source locations in the text). The rungs 64 and 128 of the dense
 # and the sparse stack ride since PR 41: `PARENT_RIDING` below;
 # tests/test_dots.py pins the decode programs. `mixed` (PR 42's stack) was
-# taken on PR 45's parent (6c2c097).
+# taken on PR 45's parent (6c2c097). All three tables stand since PR 47 with
+# that PR's parent's sampler in `serving.sample_tokens`' place while a
+# program is lowered (`test_dots.parents_prefill_text` puts it there): the one
+# part of every serving program that PR moved.
 PARENT = {
     "dense": {32: "c948937b09fe2fee"},
     "sparse": {32: "7a5fc5aa7c158c94"},

@@ -5,6 +5,7 @@ Mirrors the reference's LLM-serve smoke coverage (reference:
 python/ray/llm/tests/serve/ deployment tests) on a CPU-sized model.
 """
 
+import functools
 import json
 import urllib.request
 
@@ -344,6 +345,173 @@ def test_sampling_temperature_topk_seed():
         for t in ts:
             t.join(timeout=120)
         assert outs[0] == greedy and outs[1] == s1 and outs[2] == s3
+    finally:
+        eng.stop()
+
+
+# ----------------------------------------------------------------------
+# The sampler takes its top-k only where a row asks for a sample (PR 47)
+# ----------------------------------------------------------------------
+def parents_sample_tokens(logits, temp, topk, keys, pos, cap=64):
+    """`models/serving.py::sample_tokens` as PR 47's parent (7f64f96) had it,
+    letter for letter but for the names it imports: the top-`cap`, the draws
+    and the gather for every row of every call, thrown away where `temp` is
+    0. The reference the sampler is held to, token for token; and, put on
+    `serving.sample_tokens`, what the pinned programs of tests/test_dots.py
+    and tests/test_prefill_riders.py are lowered with."""
+    import jax
+    import jax.numpy as jnp
+
+    cap = min(cap, logits.shape[-1])
+
+    def one_gumbel(key, p):
+        return jax.random.gumbel(jax.random.fold_in(key, p), (cap,))
+
+    with jax.named_scope("sample"):
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        vals, idxs = jax.lax.top_k(logits.astype(jnp.float32), cap)
+        k_eff = jnp.where(topk > 0, jnp.minimum(topk, cap), cap)
+        mask = jnp.arange(cap)[None, :] < k_eff[:, None]
+        scaled = jnp.where(mask, vals / jnp.maximum(temp, 1e-6)[:, None],
+                           -1e30)
+        g = jax.vmap(one_gumbel)(keys, pos)
+        pick = jnp.argmax(scaled + g, axis=-1)
+        sampled = jnp.take_along_axis(idxs, pick[:, None], axis=1)[:, 0]
+        return jnp.where(temp > 0, sampled, greedy).astype(jnp.int32)
+
+
+# Eight rows a call: (temperature, top_k) a row; top_k 0 is "the cap", 100 is
+# over it.
+ROWS = {
+    "greedy": [(0.0, 0), (0.0, 5), (0.0, 100), (0.0, 1)] * 2,
+    "sampling": [(0.7, 0), (1.0, 5), (1.3, 100), (0.2, 1), (5.0, 0),
+                 (1.0, 2), (0.7, 64), (3.0, 63)],
+    "mixed": [(0.0, 0), (0.7, 0), (0.0, 5), (1.0, 5), (1.3, 100), (0.0, 100),
+              (0.0, 1), (5.0, 3)],
+    "one_sampling_slot": [(0.0, 0)] * 5 + [(0.9, 40)] + [(0.0, 0)] * 2,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _samplers():
+    import jax
+
+    from ray_tpu.models.serving import sample_tokens
+    return jax.jit(sample_tokens), jax.jit(parents_sample_tokens)
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("vocab", [300, 40])     # over and under TOPK_CAP
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("rows", sorted(ROWS))
+def test_sample_tokens_is_the_parents_token_for_token(rows, dtype, vocab):
+    """Whatever the rows ask for, together: the tokens are the parent's, to
+    the bit, at several positions of several keys. bfloat16 logits hold
+    ties (300 draws on a grid of 2**-6 near 1), which argmax and top_k must
+    go on breaking the same way."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.serve.engine import _seed_key
+
+    new, was = _samplers()
+    temp = jnp.asarray([t for t, _ in ROWS[rows]], jnp.float32)
+    topk = jnp.asarray([k for _, k in ROWS[rows]], jnp.int32)
+    n = len(ROWS[rows])
+    keys = jnp.asarray(np.stack([_seed_key(1000 + i) for i in range(n)]))
+    differs = False
+    for draw in range(4):
+        logits = (2.0 * jax.random.normal(jax.random.PRNGKey(draw),
+                                          (n, vocab))).astype(dtype)
+        pos = jnp.arange(n, dtype=jnp.int32) * 7 + draw
+        got = np.asarray(new(logits, temp, topk, keys, pos))
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(
+            got, np.asarray(was(logits, temp, topk, keys, pos)))
+        greedy = np.asarray(jnp.argmax(logits, axis=-1))
+        still = np.asarray(temp) == 0
+        np.testing.assert_array_equal(got[still], greedy[still])
+        differs |= bool((got[~still] != greedy[~still]).any())
+    assert differs is (rows != "greedy")  # a sample is not always the argmax
+
+
+@pytest.mark.fast
+def test_top_k_is_only_inside_the_samplers_branch():
+    """A decode program holds ONE `top_k`, inside one branch of the one
+    conditional under `sample`; the other branch holds no instruction at
+    all, and nothing outside the conditional takes a top-k: a chunk whose
+    slots are all greedy runs the argmax alone. Read on the jaxpr and on the
+    lowered text, so any backend shows it."""
+    import jax
+
+    decode, args, _ = _tiny_decode(n_layers=2)
+
+    def top_ks(jaxpr):
+        return sum(e.primitive.name == "top_k" for e in _all_eqns(jaxpr))
+
+    whole = jax.make_jaxpr(decode)(*args).jaxpr
+    (cond,) = [e for e in _all_eqns(whole) if e.primitive.name == "cond"]
+    assert "sample" in str(cond.source_info.name_stack)
+    greedy, draw = (b.jaxpr for b in cond.params["branches"])
+    assert not greedy.eqns and greedy.outvars == greedy.invars[-1:]
+    assert top_ks(whole) == 1 == top_ks(draw)
+    text = decode.lower(*args).as_text()
+    assert text.count("chlo.top_k") == 1 and text.count("stablehlo.case") == 1
+
+
+@pytest.mark.fast
+def test_a_sampling_stream_between_greedy_ones_is_counted_and_unmoved(
+        tmp_path):
+    """`decode_chunks_sampling` and the dispatch span's `sampling` say how
+    often the sampler's branch engages: 0 on an all-greedy run, and where a
+    stream at temperature 0.8 decodes between two greedy ones, the chunks it
+    is live in (its 9 tokens: one from the prefill, two chunks of 4) and no
+    other; its tokens are the same request's alone, and the greedy streams'
+    are theirs."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.models.llama import LlamaConfig, init_params
+    from ray_tpu.serve.engine import Engine
+    from test_tracing import _Profiled
+
+    cfg = LlamaConfig(vocab_size=128, d_model=32, n_layers=2, n_heads=2,
+                      n_kv_heads=2, d_ff=64, max_seq=64, dtype=np.float32)
+    eng = Engine(init_params(cfg, jax.random.PRNGKey(0)), cfg, n_slots=3,
+                 decode_chunk=4, page_size=16)
+
+    def gen(prompt, n, **kw):
+        q = eng.submit(prompt, n, **kw)
+        out = []
+        while (item := q.get(timeout=60)) is not None:
+            out.extend(item)
+        return out
+
+    def run(asks, directory):
+        before = eng.counters()
+        with _Profiled(directory) as prof:
+            outs = _together(gen, asks)
+        after = eng.counters()
+        spans = [s for _, _, _, s in
+                 prof.events("serve.engine.decode_dispatch")]
+        assert len(spans) == after["decode_chunks"] - before["decode_chunks"]
+        return outs, [s["sampling"] for s in spans], (
+            after["decode_chunks_sampling"] - before["decode_chunks_sampling"])
+
+    sampled = ([1, 2, 3], 9, {"temperature": 0.8, "top_k": 5, "seed": 42})
+    quiet = [([4, 5, 6, 7], 17, {}), ([9, 8], 14, {"temperature": 0.0})]
+    try:
+        assert eng.counters()["decode_chunks_sampling"] == 0
+        greedy, sampling, chunks = run(quiet, tmp_path / "greedy")
+        assert chunks == 0 and set(sampling) == {0}
+        (alone,), sampling, chunks = run([sampled], tmp_path / "alone")
+        assert chunks == 2 and sampling == [1, 1]
+        assert len(alone) == 9 and alone != gen(*sampled[:2])
+        outs, sampling, chunks = run([quiet[0], sampled, quiet[1]],
+                                     tmp_path / "mixed")
+        assert outs == [greedy[0], alone, greedy[1]]
+        assert chunks == 2 == sum(sampling) and set(sampling) == {0, 1}
     finally:
         eng.stop()
 
