@@ -31,7 +31,12 @@ bias that chooses and does not weigh, group-limited top-k and a scaling factor
 one chip of an expert-parallel deployment): it routes over every expert,
 computes its own experts' part of the mixture, and a token's assignments to
 absent experts weigh nothing here. No capacity, no dropped token, nothing in
-the place of the absent chips or of their exchange.
+the place of the absent chips or of their exchange. A share's combine has two
+implementations behind `local_combine`, chosen as the grouped matmul's are
+(`_combine_tiling`): on a TPU a Pallas kernel that adds the block's LOCAL rows
+to their tokens' rows, one in sixteen of the `tokens x k` assignments on a
+share of 16 of 256 experts; elsewhere a gather back to token-major. Either
+way a token's sum is float32 in an order its own routing fixes.
 """
 
 from __future__ import annotations
@@ -110,9 +115,20 @@ _ROW_TILE = 256
 # VMEM the kernel's blocks may fill, two buffers each: under the 16 MiB every
 # program's kernels are given unasked. Asking for more (a group's whole
 # 7168 x 2048 matrix as one block read 12% faster alone) takes the room XLA
-# keeps its own buffers in: `moe_combine`'s gather ran 15 ms a prefill slower
-# beside such a kernel than beside `ragged_dot` (PERF.md, PR 43).
+# keeps its own buffers in: the gather `moe_combine` then was (its operand
+# 112 MiB at 7,168 tokens) ran 15 ms a prefill slower beside such a kernel than
+# beside `ragged_dot` (PERF.md, PR 43). The local combine's blocks keep to it
+# too.
 _BLOCKS_VMEM = 13 << 20
+# Rows of a share's block that the local combine's kernel fetches a visit,
+# one bfloat16 sublane tile: the run it wants is about (token tile) x k /
+# experts rows of them, 8 at a tile of 256, and chunks of 32 and 64 read as
+# fast at best and a third slower at worst (PERF.md, PR 48, the probe's
+# table). And the most rows a block may have:
+# their tokens and weights lie in scalar memory, 8 bytes a row of its 1 MiB
+# (the cells' widest block has 16,384; 65,536 still compile for a v5e).
+_COMBINE_CHUNK = 16
+_COMBINE_ROWS = 1 << 15
 
 
 def _tiling(m: int, k: int, n: int) -> Optional[Tuple[int, int]]:
@@ -261,14 +277,167 @@ def grouped_matmul(xs: jax.Array, w: jax.Array, groups: jax.Array, *,
     return _grouped(xs, w, groups, tiling, interpret)
 
 
+def _combine_tiling(tokens: int, rows: int, d: int,
+                    itemsize: int) -> Optional[int]:
+    """THE choice between a share's two combines, from static shapes: the
+    Pallas kernel's token tile, or None for the gather. The kernel won at
+    every shape the probe tried, a decode step's 32 tokens included (PERF.md,
+    PR 48), so all it asks is shapes it can tile: `d` in lanes, the rows in
+    whole chunks of `_COMBINE_CHUNK` and few enough for their two tables to
+    lie in scalar memory, the tokens in whole tiles of whole float32 sublane
+    tiles; the tile is the tallest whose float32 block, two buffers, fits the
+    blocks' room beside a chunk's (256 at 4,096 columns, 128 at 7,168)."""
+    rc = _COMBINE_CHUNK
+    if d % 128 or rows % rc or rows > _COMBINE_ROWS:
+        return None
+    return next((t for t in (256, 128, 64, 32, 16, 8) if tokens % t == 0
+                 and (8 * t + (2 * itemsize + 4) * rc) * d <= _BLOCKS_VMEM),
+                None)
+
+
+def _runs(token, groups, tokens, tt):
+    """The (token tile, group, row chunk) triples that hold a grouped row,
+    tile by tile and within a tile by group: -> (tile [V], chunk [V], lo [V],
+    hi [V], count); visit v meets rows lo[v] <= r < hi[v], which lie in ITS
+    chunk: the rows of its group whose tokens lie in its tile. Inside a group
+    the rows' tokens ascend (the sort is stable), so those are one run of
+    rows, cut where it crosses a chunk's edge. A tile's first group is
+    visited even where it has no row there: every tile's block is written."""
+    rows, n_groups, n_tiles = token.shape[0], groups.shape[0], tokens // tt
+    rc, pairs = _COMBINE_CHUNK, n_tiles * n_groups
+    ends = jnp.cumsum(groups)
+    r = jnp.arange(rows, dtype=jnp.int32)
+    group_of = jnp.sum(r[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    # ascending over the grouped rows: where pair p's run starts is a count
+    key = jnp.where(r < ends[-1], group_of * n_tiles + token // tt, pairs)
+    edges = jnp.sum(key[None, :] < jnp.arange(pairs + 1)[:, None], axis=1,
+                    dtype=jnp.int32)
+    lo, hi = (e.reshape(n_groups, n_tiles).T.reshape(-1)
+              for e in (edges[:-1], edges[1:]))       # tile-major
+    chunks = jnp.where(hi > lo, (hi - 1) // rc - lo // rc + 1, 0)
+    chunks = jnp.maximum(chunks, jnp.arange(pairs) % n_groups == 0)
+    upto = jnp.cumsum(chunks)
+    # a run more or a chunk's edge crossed is a visit more: no more than these
+    v = jnp.arange(pairs + rows // rc - 1, dtype=jnp.int32)
+    pair = jnp.minimum(jnp.sum(v[:, None] >= upto[None, :], axis=1,
+                               dtype=jnp.int32), pairs - 1)
+    chunk = jnp.minimum(lo[pair] // rc + v - (upto - chunks)[pair],
+                        rows // rc - 1)
+    return (pair // n_groups, chunk, jnp.maximum(lo[pair], chunk * rc),
+            jnp.minimum(hi[pair], (chunk + 1) * rc), upto[-1])
+
+
+def _combine_kernel(tile, chunk, lo, hi, fresh, token, weight, ys_ref,
+                    prev_ref, o_ref, rows_ref, sem, *, tt):
+    """One visit: the run's rows, one by one, each times its float32 weight
+    added to its token's row of the tile's float32 block. A tile's visits
+    are consecutive, its groups ascend and a group's rows ascend, so a
+    token's sum is taken in the order of its own groups, whoever shares its
+    tile; a row outside the run is never read. The block starts as zeros,
+    or as what the blocks before this one left (`fresh` 0: read once a tile,
+    where it lies)."""
+    v = pl.program_id(0)
+    base = tile[v] * tt
+
+    @pl.when((v == 0) | (tile[v] != tile[jnp.maximum(v - 1, 0)]))
+    def _():
+        @pl.when(fresh[0] != 0)
+        def _():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        @pl.when(fresh[0] == 0)
+        def _():
+            dma = pltpu.make_async_copy(
+                prev_ref.at[pl.ds(pl.multiple_of(base, tt), tt), :], o_ref,
+                sem)
+            dma.start()
+            dma.wait()
+
+    @pl.when(hi[v] > lo[v])
+    def _():
+        # a row is read alone from 32-bit sublanes, not from packed ones
+        rows_ref[...] = ys_ref[...].astype(jnp.float32)
+        first = chunk[v] * _COMBINE_CHUNK
+
+        def row(r, _):
+            at = pl.ds(token[r] - base, 1)
+            o_ref[at, :] = o_ref[at, :] \
+                + weight[r] * rows_ref[pl.ds(r - first, 1), :]
+
+        jax.lax.fori_loop(lo[v], hi[v], row, None)
+
+
+@functools.partial(jax.jit, static_argnames=("tt", "interpret"))
+def _combine_pallas(out, fresh, ys, token, weight, groups, *, tt, interpret):
+    """Under a `jit` of its own, as `_grouped_pallas` is: traced and lowered
+    once a program. The run tables carry `moe_dispatch`'s scope, the kernel
+    the caller's."""
+    tokens, d = out.shape
+    with jax.named_scope("moe_dispatch"):
+        *meta, count = _runs(token, groups, tokens, tt)
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, tt=tt),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(count,),
+            in_specs=[
+                pl.BlockSpec((_COMBINE_CHUNK, d),
+                             lambda v, t, c, *_: (c[v], 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((tt, d), lambda v, t, *_: (t[v], 0)),
+            scratch_shapes=[pltpu.VMEM((_COMBINE_CHUNK, d), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())],
+        ),
+        input_output_aliases={8: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="local_combine",
+    )(*meta, fresh.astype(jnp.int32).reshape(1), token, weight, ys, out)
+
+
+def local_combine(out: jax.Array, fresh: jax.Array, ys: jax.Array,
+                  assignment: jax.Array, weights: jax.Array,
+                  groups: jax.Array, *,
+                  interpret: bool = False) -> Optional[jax.Array]:
+    """out [tokens, d] float32 + the grouped rows of ys [rows, d], row r the
+    result of assignment `assignment[r]` = token * k + j, added to its
+    token's row times `weights[token, j]` (float32): group g is the
+    `groups[g]` rows after those of the groups before it, as
+    `grouped_matmul` has them, and inside a group the tokens ascend strictly.
+    A token's sum is taken in float32 in the order of its groups, which is
+    its own routing's whoever shares the batch; a row in no group may hold
+    anything, NaN included. `fresh` (a traced bool) says that `out` holds
+    zeros, which are then not read.
+
+    It reads the grouped rows and writes `out`, never a row an assignment:
+    on a TPU (or with `interpret`) at the shapes `_combine_tiling` takes, a
+    Pallas kernel, counted at trace time in
+    `attention.attention_path_counts()` as `share_combine_local`; elsewhere
+    None, and the caller gathers (`share_combine_gather`)."""
+    tt = _combine_tiling(out.shape[0], *ys.shape, ys.dtype.itemsize)
+    if tt is None or not (interpret or attention._on_tpu()):
+        attention._path_counts["share_combine_gather"] += 1
+        return None
+    attention._path_counts["share_combine_local"] += 1
+    with jax.named_scope("moe_dispatch"):
+        token = assignment // weights.shape[1]
+        weight = weights.reshape(-1)[assignment]
+    return _combine_pallas(out, fresh, ys, token, weight, groups, tt=tt,
+                           interpret=interpret)
+
+
 # Rows of a share's sorted assignments that meet the grouped matmuls at once,
 # as a multiple of the rows that would under even routing (`tokens x k x held
 # / experts`): the held experts' rows come first in the sorted order, so a
 # block of this many holds them all but for a routing four times as skewed
 # towards this share, and then a second block follows (`_share_experts`).
 # Walking all `tokens x k` rows, fifteen in sixteen of them in no group, took
-# the grouped matmuls 20 and the combine 13 of a 137 ms prefill (PERF.md,
-# PR 39); nothing is dropped at any skew.
+# the grouped matmuls 20 of a 137 ms prefill (PERF.md, PR 39); the combine
+# walks the block's rows too since PR 48 (`local_combine`). Nothing is dropped
+# at any skew.
 _SHARE_BLOCK = 4
 
 
@@ -284,10 +453,13 @@ def _share_experts(x, w_gate, w_up, w_down, layer, idx, weights, held,
     tokens), and walked in blocks of `rows` rows while a block still holds a
     local one: a dynamic trip count, one block in all but a freak routing.
     A block's rows are gathered, run through the grouped matmuls with the
-    group sizes clipped to the block, and combined by a gather back to
-    token-major and one sum over a token's k assignments in a fixed order
-    (no scatter-add), an assignment outside the block, or to an absent
-    expert, weighing 0."""
+    group sizes clipped to the block, and combined from the block's own rows
+    (`local_combine`: each grouped row times its weight added to its token's
+    row, by held expert in ascending order); where that kernel does not run,
+    by a gather back to token-major and one sum over a token's k assignments
+    in ascending j, an assignment outside the block, or to an absent expert,
+    weighing 0. No scatter-add either way: the order of a token's sum is its
+    own routing's."""
     tokens, top_k = idx.shape
     offset, n_held = held
     total = tokens * top_k
@@ -313,17 +485,22 @@ def _share_experts(x, w_gate, w_up, w_down, layer, idx, weights, held,
         with jax.named_scope("moe_dispatch"):
             # order[lo + r], without reading past the list's end
             take = jnp.minimum(lo + jnp.arange(rows), total - 1)
-            xs = x[order[take] // top_k]                     # [rows, d]
-            groups = (jnp.clip(ends, lo, lo + rows)
-                      - jnp.clip(ends - group_sizes, lo, lo + rows))
+            assignment = order[take]
+            xs = x[assignment // top_k]                      # [rows, d]
+            groups = local = (jnp.clip(ends, lo, lo + rows)
+                              - jnp.clip(ends - group_sizes, lo, lo + rows))
             if layer is not None:
                 groups = jax.lax.dynamic_update_slice(
-                    jnp.zeros(stacked, jnp.int32), groups, (layer * n_held,))
+                    jnp.zeros(stacked, jnp.int32), local, (layer * n_held,))
         with jax.named_scope("experts"):
             h = jax.nn.silu(grouped_matmul(xs, w_gate, groups)) \
                 * grouped_matmul(xs, w_up, groups)
             ys = grouped_matmul(h, w_down, groups)           # [rows, d]
         with jax.named_scope("moe_combine"):
+            combined = local_combine(out, lo == 0, ys, assignment, weights,
+                                     local)
+            if combined is not None:
+                return lo + rows, combined
             # A row in no group is whatever the grouped matmul left there:
             # it is replaced, not multiplied by 0 (0 x NaN is NaN).
             ys = jnp.where((lo + jnp.arange(rows) < n_local)[:, None], ys, 0)

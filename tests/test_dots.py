@@ -244,6 +244,49 @@ def test_a_share_walked_in_several_blocks_is_the_share_in_one(
     assert np.abs(np.asarray(want)).max() > 1e-2
 
 
+@pytest.mark.parametrize("case", ["no-local-assignment", "every-one-local",
+                                  "rows-in-no-group-are-NaN"])
+def test_a_shares_combine_at_the_edges_off_the_chip(tiny, case, monkeypatch):
+    """The combine that stands off the chip (a gather back to token-major;
+    tests/test_grouped_matmul.py holds the chip's kernel to the same cases):
+    a share nobody chose gives exact zeros, one every token chose with all
+    its k (four blocks of the even load) gives what one block gives, and the
+    rows a grouped matmul leaves in no group may hold NaN."""
+    model, cfg, lp, g = _sparse_layer(tiny, tokens=48)
+    ks = jax.random.split(jax.random.PRNGKey(9), 3)
+    mine = [0.16 * jax.random.normal(k, (4,) + lp[name].shape[1:])
+            for name, k in zip(("w_up", "w_gate", "w_down"), ks)]
+    lean = {"no-local-assignment": -5.0, "every-one-local": 5.0,
+            "rows-in-no-group-are-NaN": 0.3}[case]
+    routing = dict(cfg.routing(), bias=jnp.zeros(16).at[4:8].set(lean))
+    args = dict(top_k=4, routing=routing, held=(4, 4))
+    before = attention.attention_path_counts().get("share_combine_gather", 0)
+    got, _, counts = moe.moe_ffn(g, lp["router"], *mine, **args)
+    assert attention.attention_path_counts()["share_combine_gather"] \
+        == before + 1
+    if case == "no-local-assignment":
+        assert int(counts.sum()) == 0 and not np.asarray(got).any()
+        monkeypatch.setattr(moe, "_SHARE_BLOCK", 100)   # a block, walked
+        got, _, _ = moe.moe_ffn(g, lp["router"], *mine, **args)
+        assert not np.asarray(got).any()
+    elif case == "every-one-local":
+        assert int(counts.sum()) == 48 * 4
+        monkeypatch.setattr(moe, "_SHARE_BLOCK", 1)     # 48 rows a block
+        cut, _, cut_counts = moe.moe_ffn(g, lp["router"], *mine, **args)
+        assert (np.asarray(cut_counts) == np.asarray(counts)).all()
+        assert np.abs(np.asarray(cut) - np.asarray(got)).max() < 1e-5
+        assert np.abs(np.asarray(got)).max() > 1e-2
+    else:
+        assert 0 < int(counts.sum()) < 96       # rows in no group at the end
+        real = moe.grouped_matmul
+        monkeypatch.setattr(moe, "grouped_matmul", lambda xs, w, groups: (
+            jnp.where((jnp.arange(xs.shape[0]) < jnp.sum(groups))[:, None],
+                      real(xs, w, groups), jnp.nan)))
+        poisoned, _, _ = moe.moe_ffn(g, lp["router"], *mine, **args)
+        assert np.array_equal(np.asarray(poisoned), np.asarray(got))
+        assert np.abs(np.asarray(got)).max() > 1e-2
+
+
 # ---------------------------------------------------------------------------
 # The absorbed form, the kernels
 # ---------------------------------------------------------------------------
@@ -426,6 +469,9 @@ def test_the_engine_took_the_latent_paths(engine):
     counts = attention.attention_path_counts()
     assert counts["latent_decode_pallas"] >= 1      # interpreted, in decode
     assert counts["latent_fwd_reference"] >= 1      # the CPU's prefill path
+    # a share's combine: off the chip the gather (the chip's kernel, counted
+    # as `share_combine_local`: tests/test_tpu_compile.py)
+    assert counts["share_combine_gather"] >= 1
     assert engine._caches.kc.shape[-1] == 128 and engine._caches.kc.ndim == 4
 
 

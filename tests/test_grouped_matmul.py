@@ -163,3 +163,95 @@ def test_moe_ffn_through_the_kernel(held, monkeypatch):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=2 ** -6, atol=2 ** -6)
+
+
+# ---------------------------------------------------------------------------
+# A share's combine (`_share_experts`): the local kernel, interpreted here,
+# and the gather that stands off the chip
+# ---------------------------------------------------------------------------
+
+FORMS = ["kernel", "off-chip"]
+
+
+def _share(form, monkeypatch, tokens=64, seed=5, lean=0.0, held=(4, 4),
+           x=None, poison=False):
+    """-> (out [tokens, d] float32 bits as the layer returns them, counts,
+    x): a share of 4 of 32 experts over one layer's weights, `lean` added to
+    the held experts' router columns (at 200 every assignment is local, at -200
+    none), through the local combine's kernel or through the gather. With
+    `poison` every grouped matmul's rows in no group come back NaN."""
+    d, f, n_experts, top_k = 128, 128, 32, 4
+    rng = np.random.default_rng(seed)
+    if x is None:
+        x = rng.standard_normal((tokens, d)).astype(np.float32)
+        x[:, 0] = 1.0
+    else:
+        rng.standard_normal((tokens, d))
+    gate_w = rng.standard_normal((d, n_experts)).astype(np.float32)
+    gate_w[0, held[0]:held[0] + held[1]] += lean
+    w_up, w_gate = (jnp.asarray(rng.standard_normal((held[1], d, f))
+                                / d ** 0.5, jnp.bfloat16) for _ in range(2))
+    w_down = jnp.asarray(rng.standard_normal((held[1], f, d)) / f ** 0.5,
+                         jnp.bfloat16)
+    if form == "kernel":
+        monkeypatch.setattr(moe, "local_combine", functools.partial(
+            moe.local_combine, interpret=True))
+    if poison:
+        real = moe.grouped_matmul
+
+        def poisoned(xs, w, groups, **kw):
+            y = real(xs, w, groups, **kw)
+            return jnp.where((jnp.arange(y.shape[0]) < jnp.sum(groups))[
+                :, None], y, jnp.nan)
+
+        monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+    name = "share_combine_local" if form == "kernel" \
+        else "share_combine_gather"
+    before = attention.attention_path_counts().get(name, 0)
+    out, _, counts = jax.jit(lambda a: moe.moe_ffn(
+        a, jnp.asarray(gate_w, jnp.bfloat16), w_up, w_gate, w_down,
+        top_k=top_k, held=held))(jnp.asarray(x, jnp.bfloat16))
+    assert attention.attention_path_counts().get(name, 0) == before + 1
+    return np.asarray(out.view(jnp.uint16)), np.asarray(counts), x
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_a_tokens_part_from_a_share_is_that_tokens_alone(form, monkeypatch):
+    """Bit for bit the same in a batch, in that batch reversed and with
+    nobody beside it (the other rows zeros): the sum over a token's local
+    assignments is taken in an order its own routing fixes."""
+    batch, counts, x = _share(form, monkeypatch, lean=10.0)
+    assert counts.sum() > 64          # tokens with two and more local rows
+    turned, _, _ = _share(form, monkeypatch, lean=10.0, x=x[::-1])
+    assert np.array_equal(turned[::-1], batch) and batch.any()
+    for t in (0, 17, 63):
+        alone = np.zeros_like(x)
+        alone[5] = x[t]
+        got, _, _ = _share(form, monkeypatch, lean=10.0, x=alone)
+        assert np.array_equal(got[5], batch[t])
+
+
+@pytest.mark.parametrize("case", ["no-local-assignment", "every-one-local",
+                                  "rows-in-no-group-are-NaN"])
+@pytest.mark.parametrize("form", FORMS)
+def test_a_shares_combine_at_the_edges(form, case, monkeypatch):
+    if case == "no-local-assignment":
+        # one block that holds every row, and two that are never walked
+        for block in (100, moe._SHARE_BLOCK):
+            monkeypatch.setattr(moe, "_SHARE_BLOCK", block)
+            out, counts, _ = _share(form, monkeypatch, lean=-200.0)
+            assert counts.sum() == 0 and not out.any()
+    elif case == "every-one-local":
+        # the freak routing: two blocks of 4 x the even load give what one
+        # block of every row gives
+        out, counts, _ = _share(form, monkeypatch, lean=200.0)
+        assert counts.sum() == 64 * 4
+        monkeypatch.setattr(moe, "_SHARE_BLOCK", 100)
+        want, want_counts, _ = _share(form, monkeypatch, lean=200.0)
+        assert np.array_equal(counts, want_counts)
+        assert np.array_equal(out, want) and out.any()
+    else:
+        want, _, _ = _share(form, monkeypatch, lean=10.0)
+        out, _, _ = _share(form, monkeypatch, lean=10.0, poison=True)
+        assert np.array_equal(out, want) and want.any()
+        assert np.isfinite(out.view(jnp.bfloat16).astype(np.float32)).all()

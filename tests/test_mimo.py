@@ -455,6 +455,7 @@ def test_the_two_caches_are_two_shapes_and_the_pool_is_the_full_layers(
     assert counts["window_decode_reference"] >= 1
     assert counts["window_fwd_reference"] >= 1      # the CPU's prefill path
     assert counts["full_fwd_reference"] >= 1
+    assert counts["share_combine_gather"] >= 1      # off the chip, the gather
     # pages: the 2 full layers alone, 1 kv head, keys and values in lanes
     kc, vc, _, rings = engine._caches
     assert kc.shape == vc.shape == (2, engine.n_pages, 1, 16, 128)

@@ -704,6 +704,42 @@ def test_grouped_matmul_compiles_for_v5e(topo, shape, matrix, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+# (tokens, rows of a share's block, d): the two share cells' widest prefill
+# and their decode step
+COMBINE_SHAPES = {"dots-4096": (4096, 8192, 7168),
+                  "mimo-8192": (8192, 16384, 4096),
+                  "dots-decode": (32, 64, 7168),
+                  "mimo-decode": (32, 64, 4096)}
+
+
+@pytest.mark.parametrize("shape", sorted(COMBINE_SHAPES))
+def test_local_combine_compiles_for_v5e(topo, shape, monkeypatch):
+    """A share's combine at the cells' shapes is the Pallas kernel, its
+    float32 result in the buffer it was handed, and beside it the run tables
+    alone: nothing of `tokens x k` rows, nothing row-sized at all."""
+    from ray_tpu.ops import moe
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    tokens, rows, d = COMBINE_SHAPES[shape]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    before = attention.attention_path_counts().get("share_combine_local", 0)
+    lowered = jax.jit(moe.local_combine, donate_argnums=(0,)).lower(
+        sds((tokens, d), jnp.float32), sds((), jnp.bool_),
+        sds((rows, d), jnp.bfloat16), sds((rows,), jnp.int32),
+        sds((tokens, 8), jnp.float32), sds((16,), jnp.int32))
+    assert attention.attention_path_counts()["share_combine_local"] \
+        == before + 1
+    text = lowered.as_text()
+    assert "tpu_custom_call" in text and "local_combine" in text
+    mem = lowered.compile().memory_analysis()
+    assert mem.alias_size_in_bytes == tokens * d * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("config", ["dots.vlm1.inst-serve",
                                     "mimo-v2-flash-serve"])
 def test_a_shares_prefill_reads_the_expert_stacks_where_they_lie_on_v5e(
@@ -750,14 +786,20 @@ def test_a_shares_prefill_reads_the_expert_stacks_where_they_lie_on_v5e(
         "experts_grouped_pallas", 0)
     assert counts.get("experts_ragged_dot", 0) == before.get(
         "experts_ragged_dot", 0)
-    assert "grouped_matmul" in lowered.as_text()
+    # and every sparse segment's combine the local kernel, none the gather
+    assert counts["share_combine_local"] > before.get(
+        "share_combine_local", 0)
+    assert counts.get("share_combine_gather", 0) == before.get(
+        "share_combine_gather", 0)
+    assert "grouped_matmul" in lowered.as_text() \
+        and "local_combine" in lowered.as_text()
     stacks = [tuple(params[stack][w].shape)
               for stack in ("layers", "window") if stack in params
               for w in ("w_gate", "w_up", "w_down")
               if "router" in params[stack]]
     assert stacks and all(len(s) == 4 for s in stacks)
     hlo = lowered.compile().as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 4
     assert not _moved_stacks(hlo, stacks)
 
 
