@@ -24,6 +24,9 @@ engine's prefill and its decode step (`serve/engine.py`) all call them.
   mamba_mixer        a state-space layer's whole mixer, over a sequence or
                      for one token a slot: its state is an argument and a
                      result, so where the state lives is the caller's
+  mamba2_mixer       its peer for Mamba-2 (`cfg.ssm_heads`; the Granite 4.0-H
+                     family): heads of channels with one scalar decay each,
+                     a prompt by the chunked dual form
   conv_mixer         a gated short-convolution layer's whole operator (the
                      LFM2 family), likewise once for a sequence and for one
                      token a slot, its window an argument and a result
@@ -52,7 +55,8 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.ops.norms import layer_norm, rms_norm
-from ray_tpu.ops.ssm import causal_conv, selective_scan, ssm_step
+from ray_tpu.ops.ssm import (causal_conv, selective_scan, ssd_scan, ssd_step,
+                             ssm_step)
 
 
 def attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
@@ -266,6 +270,7 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
     rows `live` marks (`x`'s shape less its last axis; every row if None).
     With `layer`, the experts' weights in `lp` are the stacks of all layers
     and `layer` this block's index (`expert_stacks`, `ops.moe.moe_ffn`).
+    The branch is multiplied by `cfg.residual_scale` before the add.
     A layer is sparse if it has a router: a sparse model's leading dense
     layers (`cfg.first_dense`) have none. The router's variant, the share of
     the experts held (the count is then per HELD expert) and a shared expert
@@ -291,10 +296,19 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
                 norm_topk_prob=cfg.norm_topk_prob,
                 live=None if live is None else live.reshape(-1), layer=layer,
                 **more)
-            return x + out.reshape(x.shape), (aux, counts)
+            return x + scaled(out.reshape(x.shape), cfg), (aux, counts)
         gate = h @ lp["w_gate"].astype(dt)
         up = h @ lp["w_up"].astype(dt)
-        return x + (jax.nn.silu(gate) * up) @ lp["w_down"].astype(dt), None
+        return x + scaled((jax.nn.silu(gate) * up) @ lp["w_down"].astype(dt),
+                           cfg), None
+
+
+def scaled(branch: jax.Array, cfg) -> jax.Array:
+    """A residual branch times `cfg.residual_scale` (the Granite family's
+    `residual_multiplier`), in the branch's dtype; 1.0 emits nothing."""
+    if cfg.residual_scale == 1.0:
+        return branch
+    return branch * jnp.asarray(cfg.residual_scale, branch.dtype)
 
 
 def mamba_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
@@ -351,7 +365,60 @@ def mamba_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
     with jax.named_scope("ssm_out"):
         if step:
             y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
-        return x + y @ lp["out_proj"].astype(dt), state, window
+        return x + scaled(y @ lp["out_proj"].astype(dt), cfg), state, window
+
+
+def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
+                 window=None, *, step: bool = False, length=None
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x + residual_scale * mixer(norm(x)) for a Mamba-2 layer
+    (`cfg.ssm_heads` heads of `Di / H` channels, one group of B and C; the
+    Granite 4.0-H family's, which is Bamba's), under `mamba_mixer`'s scopes:
+
+      z, xBC, dt = split(norm(x) W_in, [Di, Di + 2N, H])  (no bias)  ssm_in
+      xBC   = silu(b + sum_k w[k] * xBC_{t-K+1+k})   (over x, B and C)  conv
+      u, B, C = split(xBC);  dt = softplus(dt + b_dt) (float32, a head),
+      A = -exp(A_log) (a head)                                   ssm_params
+      s_t   = exp(dt_t A) s_{t-1} + (dt_t u_t) (x) B_t;
+      y_t   = s_t . C_t + D u_t                  (a head's scalars)    scan
+      out   = x + residual_scale * rmsnorm(y * silu(z); w_norm) W_out
+              (the gate BEFORE the norm, which is over all Di)      ssm_out
+
+    x `[S, D]`, one sequence, from `state` `[N, Di]` and `window` `[K - 1, Di
+    + 2N]` (None: a sequence's start), through `ops.ssm.ssd_scan`; rows at
+    and past `length` leave state and window as they were. Or, with `step`,
+    x `[ns, D]`, one token a slot, from `state` `[ns, N, Di]` and `window` `[K
+    - 1, ns, Di + 2N]`. -> (out, state, window)."""
+    dt = cfg.dtype
+    Di, N, eps = cfg.ssm_inner, cfg.ssm_state, cfg.norm_eps
+    with jax.named_scope("ssm_in"):
+        h = rms_norm(x, lp["norm"], eps)
+        z, xbc, r = jnp.split(h @ lp["in_proj"].astype(dt),
+                              [Di, 2 * Di + 2 * N], axis=-1)
+    with jax.named_scope("conv"):
+        if step:    # each slot a sequence of one row, its window its own
+            xbc, window = jax.vmap(causal_conv, (0, None, None, 1), (0, 1))(
+                xbc[:, None], lp["conv_w"], lp["conv_b"], window)
+            xbc = xbc[:, 0]
+        else:
+            xbc, window = causal_conv(xbc, lp["conv_w"], lp["conv_b"], window,
+                                      length)
+        xbc = jax.nn.silu(xbc).astype(dt)
+    with jax.named_scope("ssm_params"):
+        u, b, c = jnp.split(xbc, [Di, Di + N], axis=-1)
+        step_size = jax.nn.softplus(r.astype(jnp.float32)
+                                    + lp["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(lp["A_log"].astype(jnp.float32))
+    with jax.named_scope("scan"):
+        if step:
+            y, state = ssd_step(u, step_size, a, b, c, lp["D"], state)
+        else:
+            y, state = ssd_scan(u, step_size, a, b, c, lp["D"], state,
+                                length)
+    with jax.named_scope("ssm_out"):
+        gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        y = rms_norm(gated, lp["w_norm"], eps).astype(dt)
+        return x + scaled(y @ lp["out_proj"].astype(dt), cfg), state, window
 
 
 def conv_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, window=None, *,

@@ -81,6 +81,14 @@ class LlamaConfig:
     ssm_conv: int = 4
     ssm_dt_rank: int = 0
     attn_layers: Optional[Tuple[int, ...]] = None
+    # ssm_heads > 0 => the state-space layers are Mamba-2's
+    # (`models.block.mamba2_mixer`): ssm_heads heads of ssm_inner / ssm_heads
+    # channels, ONE scalar decay, time step and D a head, B and C of
+    # ssm_state numbers shared by the heads, the convolution over x, B and C
+    # together, a gated RMS norm before the output projection; no
+    # ssm_dt_rank. Either hybrid's feed-forward may be sparse (n_experts > 0),
+    # a share of it held (experts_held), with shared experts beside it.
+    ssm_heads: int = 0
     # False: attention takes no position signal at all (no RoPE), as in a
     # hybrid whose state-space layers carry the order of the sequence.
     rope: bool = True
@@ -163,6 +171,17 @@ class LlamaConfig:
     # What the sigmoid router adds to the sum it renormalises by
     # (`ops.moe.top_k_routing`): DeepSeek-V3's 1e-20, the LFM2 family's 1e-6.
     router_norm_eps: float = 1e-20
+    # The Granite family's scalar multipliers, each emitted only where it is
+    # not its default: the embedding's rows times embed_scale; every residual
+    # branch (a mixer's, attention's, the feed-forward's) times
+    # residual_scale before its add; the logits times logit_scale (published
+    # as a divisor, `logits_scaling` 16: 1/16); q . k times attn_scale in the
+    # place of head_dim^-1/2 (0.0: that default). Served by the uniform and
+    # the state-space hybrid stacks.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    attn_scale: float = 0.0
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -195,25 +214,51 @@ class LlamaConfig:
                                tuple(sorted(int(i) for i in self.conv_layers)))
             self._check_conv()
         segmented = self.latent or self.mixed or self.conv
+        if self.multipliers and segmented:
+            raise ValueError("embed_scale, residual_scale, logit_scale and "
+                             "attn_scale are served by the uniform stack and "
+                             "the state-space hybrid: no latent or mixed "
+                             "attention, no short-convolution layers")
         if self.first_dense and not (segmented and self.n_experts
                                      and self.first_dense < self.n_layers):
             raise ValueError("first_dense: leading dense layers under a "
                              "sparse latent-attention, mixed-attention or "
                              "short-convolution stack")
-        if (self.experts_held or self.router_score != "softmax") \
-                and not segmented:
-            raise ValueError("a share of the experts and the sigmoid router "
-                             "are served by the stacks that run as segments: "
-                             "latent attention (kv_lora_rank > 0), mixed "
-                             "attention (attn_pattern) and short-convolution "
-                             "layers (conv_layers)")
-        if self.n_shared_experts and not self.latent:
-            raise ValueError("shared experts are served by the latent-"
-                             "attention stack alone (kv_lora_rank > 0)")
+        if self.experts_held and not (segmented or self.ssm_state):
+            raise ValueError("a share of the experts is served by the stacks "
+                             "that run as segments: latent attention "
+                             "(kv_lora_rank > 0), mixed attention "
+                             "(attn_pattern) and the state-space hybrid "
+                             "(ssm_state)")
+        if self.router_score != "softmax" and not segmented:
+            raise ValueError("the sigmoid router is served by three of the "
+                             "stacks that run as segments: latent attention "
+                             "(kv_lora_rank > 0), mixed attention "
+                             "(attn_pattern) and short-convolution layers "
+                             "(conv_layers)")
+        if self.n_shared_experts and not (
+                self.n_experts and (self.latent or self.ssm_state)):
+            raise ValueError("shared experts are served beside sparse "
+                             "experts, by the latent-attention stack "
+                             "(kv_lora_rank > 0) and the state-space hybrid "
+                             "(ssm_state)")
         if bool(self.ssm_state) != (self.attn_layers is not None):
             raise ValueError("ssm_state and attn_layers come together: the "
                              "state-space widths and which layers are not "
                              "state-space layers")
+        if self.ssm_heads and (not self.ssm_state
+                               or self.ssm_inner % self.ssm_heads):
+            raise ValueError("ssm_heads: Mamba-2's heads, of ssm_inner / "
+                             "ssm_heads channels each, in a hybrid stack "
+                             "(ssm_state, attn_layers)")
+        if self.ssm_state and self.index_topk:
+            raise ValueError("a state-space hybrid has plain attention: no "
+                             "indexer")
+        if self.experts_held:
+            offset, count = self.experts_held
+            if not 0 <= offset < offset + count <= self.n_experts:
+                raise ValueError("experts_held: (offset, count) inside the "
+                                 "router's n_experts")
 
     def _check_mixed(self) -> None:
         if self.latent or self.ssm_state or self.index_topk or self.qk_norm \
@@ -315,11 +360,12 @@ class LlamaConfig:
 
     @property
     def softmax_scale(self) -> float:
-        """What attention multiplies q . k by: head width^-1/2, times YaRN's
-        m^2, m = 0.1 mscale_all_dim ln(factor) + 1."""
+        """What attention multiplies q . k by: head width^-1/2 (or
+        `attn_scale`, where the model publishes its own), times YaRN's m^2, m
+        = 0.1 mscale_all_dim ln(factor) + 1."""
         import math
         if not self.latent:
-            return self.head_dim ** -0.5
+            return self.attn_scale or self.head_dim ** -0.5
         scale = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
         if self.rope_yarn:
             factor, *_, mscale_all_dim = self.rope_yarn
@@ -338,6 +384,18 @@ class LlamaConfig:
     @property
     def ssm_inner(self) -> int:
         return self.ssm_expand * self.d_model
+
+    @property
+    def multipliers(self) -> bool:
+        """Whether any of the four scalar multipliers is off its default."""
+        return (self.embed_scale, self.residual_scale, self.logit_scale,
+                self.attn_scale) != (1.0, 1.0, 1.0, 0.0)
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """Channels the state-space layers' convolution runs over: the inner
+        width, and under Mamba-2 B and C beside it."""
+        return self.ssm_inner + (2 * self.ssm_state if self.ssm_heads else 0)
 
     def segments(self) -> Tuple[Tuple[str, int, int], ...]:
         """The stack in the order it runs, each segment a kind and ordinals
@@ -658,23 +716,34 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     }
     if cfg.tie_embeddings:
         del out["lm_head"]
+    if cfg.n_shared_experts:
+        layers.update({"ws_gate": ("layers", "embed", "mlp"),
+                       "ws_up": ("layers", "embed", "mlp"),
+                       "ws_down": ("layers", "mlp", "embed")})
     if cfg.ssm_state:
-        out["mamba"] = {
+        mixer = {
             "norm": ("layers", "embed"),
             "in_proj": ("layers", "embed", "mlp"),
             "conv_w": ("layers", None, "mlp"),
             "conv_b": ("layers", "mlp"),
-            "x_proj": ("layers", "mlp", None),
-            "dt_norm": ("layers", None),
-            "b_norm": ("layers", None),
-            "c_norm": ("layers", None),
-            "dt_proj": ("layers", None, "mlp"),
-            "dt_bias": ("layers", "mlp"),
-            "A_log": ("layers", None, "mlp"),
-            "D": ("layers", "mlp"),
-            "out_proj": ("layers", "mlp", "embed"),
-            **{k: layers[k] for k in ("mlp_norm", "w_gate", "w_up",
-                                      "w_down")}}
+            "out_proj": ("layers", "mlp", "embed")}
+        if cfg.ssm_heads:
+            mixer.update({"dt_bias": ("layers", None),
+                          "A_log": ("layers", None), "D": ("layers", None),
+                          "w_norm": ("layers", "mlp")})
+        else:
+            mixer.update({"x_proj": ("layers", "mlp", None),
+                          "dt_norm": ("layers", None),
+                          "b_norm": ("layers", None),
+                          "c_norm": ("layers", None),
+                          "dt_proj": ("layers", None, "mlp"),
+                          "dt_bias": ("layers", "mlp"),
+                          "A_log": ("layers", None, "mlp"),
+                          "D": ("layers", "mlp")})
+        out["mamba"] = dict(mixer, **{
+            k: layers[k] for k in ("mlp_norm", "router", "w_gate", "w_up",
+                                   "w_down", "ws_gate", "ws_up", "ws_down")
+            if k in layers})
     return out
 
 
@@ -770,12 +839,14 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             "q_norm": jnp.ones((L, hd if per_head else H * hd), pd),
             "k_norm": jnp.ones((L, hd if per_head else KVH * hd), pd)})
     if cfg.n_experts > 0:
-        E = cfg.n_experts
+        # The experts' stacks hold the experts HELD (`cfg.experts_held`: a
+        # hybrid's share); the router scores them all.
+        E, held = cfg.n_experts, cfg.n_held
         layers.update({
             "router": norm((L, D, E), next(ks)),
-            "w_gate": norm((L, E, D, F), next(ks)),
-            "w_up": norm((L, E, D, F), next(ks)),
-            "w_down": norm((L, E, F, D), next(ks)),
+            "w_gate": norm((L, held, D, F), next(ks)),
+            "w_up": norm((L, held, D, F), next(ks)),
+            "w_down": norm((L, held, F, D), next(ks)),
         })
     else:
         layers.update({
@@ -784,7 +855,13 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             "w_down": norm((L, F, D), next(ks)),
         })
     out = {
-        "embed": norm((V, D), next(ks)),
+        # A model that multiplies its embedding's rows (`embed_scale`) is
+        # drawn so that the PRODUCT starts the stream at the scale every
+        # other model's does: at 0.02 itself, twelve times a row would drown
+        # every branch, and under a tied head a token's own logit every
+        # other's (the model would repeat its last token whatever the
+        # layers computed, and no check could see them).
+        "embed": norm((V, D), next(ks), 0.02 / cfg.embed_scale),
         "layers": layers,
         "final_norm": jnp.ones((D,), pd),
         "lm_head": norm((D, V), next(ks)),
@@ -792,10 +869,6 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     if cfg.tie_embeddings:
         del out["lm_head"]
     if cfg.ssm_state:
-        if cfg.n_experts or cfg.index_topk:
-            raise NotImplementedError(
-                "a hybrid stack (ssm_state > 0) has a dense feed-forward and "
-                "plain attention: no sparse experts, no indexer")
         out["mamba"] = _init_mamba(cfg, jax.random.fold_in(key, 1), norm)
     if cfg.index_topk:
         IH, Id = cfg.index_heads, cfg.index_head_dim
@@ -804,42 +877,79 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                        "wiw": norm((L, D, IH), next(ks)),
                        "ik_norm": jnp.ones((L, Id), pd),
                        "ik_bias": jnp.zeros((L, Id), pd)})
+    if cfg.n_shared_experts:    # (a hybrid's: drawn last, as the indexer's)
+        layers.update(_shared_expert(cfg, L, ks, norm))
     return out
 
 
+def _shared_expert(cfg: LlamaConfig, L: int, ks, norm) -> Dict[str, Any]:
+    """The dense expert every token meets, `n_shared_experts * d_ff` wide."""
+    D, Fs = cfg.d_model, cfg.n_shared_experts * cfg.d_ff
+    return {"ws_gate": norm((L, D, Fs), next(ks)),
+            "ws_up": norm((L, D, Fs), next(ks)),
+            "ws_down": norm((L, Fs, D), next(ks))}
+
+
 def _init_mamba(cfg: LlamaConfig, key: jax.Array, norm) -> Dict[str, Any]:
-    """The stack of state-space layers, each with its own feed-forward. The
-    channel axis is the minor one of every leaf (`ops/ssm.py`): `A_log` is
-    `[N, Di]` and the convolution's weights `[K, Di]`. A and the time step's
-    bias start as Mamba's own do (A = -(1..N); softplus(bias) log-uniform in
-    1e-3..1e-1), so that a state carries over hundreds of rows, not two."""
+    """The stack of state-space layers, each with its own feed-forward (a
+    dense one, or a router, the experts held and the shared expert). The
+    channel axis is the minor one of every leaf (`ops/ssm.py`): Mamba-1's
+    `A_log` is `[N, Di]` and the convolution's weights `[K, Di]`. A and the
+    time step's bias start as Mamba's own do (A = -(1..N), under Mamba-2 A =
+    -uniform(1..16) a head; softplus(bias) log-uniform in 1e-3..1e-1), so
+    that a state carries over hundreds of rows, not two."""
     Lm = cfg.n_layers - cfg.kv_layers
     D, F, Di, N = cfg.d_model, cfg.d_ff, cfg.ssm_inner, cfg.ssm_state
     K, R, pd = cfg.ssm_conv, cfg.ssm_dt_rank, cfg.param_dtype
     ks = iter(jax.random.split(key, 16))
+    # a time step a head under Mamba-2, a channel under Mamba-1
     step = jnp.exp(jax.random.uniform(
-        next(ks), (Lm, Di), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
-    return {
-        "norm": jnp.ones((Lm, D), pd),
-        "in_proj": norm((Lm, D, 2 * Di), next(ks)),
-        "conv_w": norm((Lm, K, Di), next(ks), K ** -0.5),
-        "conv_b": norm((Lm, Di), next(ks)),
-        "x_proj": norm((Lm, Di, R + 2 * N), next(ks)),
-        "dt_norm": jnp.ones((Lm, R), pd),
-        "b_norm": jnp.ones((Lm, N), pd),
-        "c_norm": jnp.ones((Lm, N), pd),
-        "dt_proj": norm((Lm, R, Di), next(ks)),
-        "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pd),
-        "A_log": (jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[:, None]
-                  + jax.random.normal(next(ks), (Lm, N, Di)) * 0.02
-                  ).astype(pd),
-        "D": jnp.ones((Lm, Di), pd),
-        "out_proj": norm((Lm, Di, D), next(ks)),
-        "mlp_norm": jnp.ones((Lm, D), pd),
-        "w_gate": norm((Lm, D, F), next(ks)),
-        "w_up": norm((Lm, D, F), next(ks)),
-        "w_down": norm((Lm, F, D), next(ks)),
-    }
+        next(ks), (Lm, cfg.ssm_heads or Di), jnp.float32, jnp.log(1e-3),
+        jnp.log(1e-1)))
+    dt_bias = (step + jnp.log(-jnp.expm1(-step))).astype(pd)
+    if cfg.ssm_heads:
+        H, Dc = cfg.ssm_heads, cfg.ssm_conv_channels
+        out = {
+            "norm": jnp.ones((Lm, D), pd),
+            # its columns: the gate z, then x, B and C, then a head's dt
+            "in_proj": norm((Lm, D, Di + Dc + H), next(ks)),
+            "conv_w": norm((Lm, K, Dc), next(ks), K ** -0.5),
+            "conv_b": norm((Lm, Dc), next(ks)),
+            "dt_bias": dt_bias,
+            "A_log": jnp.log(jax.random.uniform(
+                next(ks), (Lm, H), jnp.float32, 1.0, 16.0)).astype(pd),
+            "D": jnp.ones((Lm, H), pd),
+            "w_norm": jnp.ones((Lm, Di), pd),
+            "out_proj": norm((Lm, Di, D), next(ks)),
+        }
+    else:
+        out = {
+            "norm": jnp.ones((Lm, D), pd),
+            "in_proj": norm((Lm, D, 2 * Di), next(ks)),
+            "conv_w": norm((Lm, K, Di), next(ks), K ** -0.5),
+            "conv_b": norm((Lm, Di), next(ks)),
+            "x_proj": norm((Lm, Di, R + 2 * N), next(ks)),
+            "dt_norm": jnp.ones((Lm, R), pd),
+            "b_norm": jnp.ones((Lm, N), pd),
+            "c_norm": jnp.ones((Lm, N), pd),
+            "dt_proj": norm((Lm, R, Di), next(ks)),
+            "dt_bias": dt_bias,
+            "A_log": (jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32))[:, None]
+                      + jax.random.normal(next(ks), (Lm, N, Di)) * 0.02
+                      ).astype(pd),
+            "D": jnp.ones((Lm, Di), pd),
+            "out_proj": norm((Lm, Di, D), next(ks)),
+        }
+    out["mlp_norm"] = jnp.ones((Lm, D), pd)
+    if cfg.n_experts:
+        out["router"] = norm((Lm, D, cfg.n_experts), next(ks))
+    lead = (Lm, cfg.n_held) if cfg.n_experts else (Lm,)
+    out.update(w_gate=norm((*lead, D, F), next(ks)),
+               w_up=norm((*lead, D, F), next(ks)),
+               w_down=norm((*lead, F, D), next(ks)))
+    if cfg.n_shared_experts:
+        out.update(_shared_expert(cfg, Lm, ks, norm))
+    return out
 
 
 def param_count(cfg: LlamaConfig) -> int:
@@ -1014,6 +1124,12 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
             "short-convolution layers (conv_layers) run through Serve only: "
             "the training forward has no stack of segments by kind, and no "
             "flash kernel here has a backward at a head of half a tile "
+            "(ROADMAP, Reach)")
+    if cfg.multipliers:
+        raise NotImplementedError(
+            "embed_scale, residual_scale, logit_scale and attn_scale run "
+            "through Serve only: the training forward's attention paths "
+            "(flash under a mesh, ring) take no scale of the model's "
             "(ROADMAP, Reach)")
     dt = cfg.dtype
     with jax.named_scope("embed"):

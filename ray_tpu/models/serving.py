@@ -160,10 +160,24 @@ def _layer_of(stack, i):
 
 def _head_logits(params, h, mcfg):
     """h [rows, D] -> logits [rows, V]: the head, or the embedding transposed
-    where the model ties them."""
+    where the model ties them, times `mcfg.logit_scale` where it has one."""
     if mcfg.tie_embeddings:
-        return jnp.einsum("bd,vd->bv", h, params["embed"].astype(mcfg.dtype))
-    return h @ params["lm_head"].astype(mcfg.dtype)
+        logits = jnp.einsum("bd,vd->bv", h,
+                            params["embed"].astype(mcfg.dtype))
+    else:
+        logits = h @ params["lm_head"].astype(mcfg.dtype)
+    if mcfg.logit_scale != 1.0:
+        logits = logits * jnp.asarray(mcfg.logit_scale, logits.dtype)
+    return logits
+
+
+def _embed(params, tokens, mcfg):
+    """The tokens' rows of the embedding in the compute dtype, times
+    `mcfg.embed_scale` where the model has one."""
+    x = jnp.take(params["embed"], tokens, axis=0).astype(mcfg.dtype)
+    if mcfg.embed_scale != 1.0:
+        x = x * jnp.asarray(mcfg.embed_scale, x.dtype)
+    return x
 
 
 def _rope_one(x, c, s):
@@ -189,6 +203,15 @@ def _share_stats(counts, live, mcfg):
     share."""
     routed = jnp.sum(live, dtype=jnp.int32) * mcfg.top_k_experts
     return jnp.concatenate([block.expert_stats(counts), routed[None]])
+
+
+def _routing_stats(mcfg):
+    """(counts, live) -> what a program hands back of one sparse layer's
+    routing: `_share_stats` where the model holds a share of its experts,
+    `block.expert_stats` where it holds them all."""
+    if mcfg.experts_held:
+        return lambda counts, live: _share_stats(counts, live, mcfg)
+    return lambda counts, live: block.expert_stats(counts)
 
 
 def sample_tokens(logits, temp, topk, keys, pos, cap=TOPK_CAP):
@@ -229,12 +252,17 @@ def sample_tokens(logits, temp, topk, keys, pos, cap=TOPK_CAP):
 def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
     """The uniform stack's layer: attention over K and V under the block
     table (flash, or the indexer's sparse attention), a dense or a sparse
-    feed-forward. `ridden`: its prefill takes riders, and its decode step is
-    the riders' (`token_step`: ONE jit for both)."""
+    feed-forward (a hybrid's may be a share of the experts, whose routing
+    comes back as `_share_stats`). `ridden`: its prefill takes riders, and
+    its decode step is the riders' (`token_step`: ONE jit for both)."""
     H, KVH, hd, S = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim, mcfg.max_seq
     dt = mcfg.dtype
     sparse = mcfg.n_experts > 0
     indexed = mcfg.index_topk > 0
+    stats = _routing_stats(mcfg)
+    # The model's own scale of q . k, where it publishes one; else nothing is
+    # passed and the kernels take head_dim^-1/2.
+    scaled = {"sm_scale": mcfg.attn_scale} if mcfg.attn_scale else {}
     # What a program is built with is the function as it stands on its
     # module now (a test puts an interpreted kernel there first).
     paged_decode = paged_kv.paged_decode_attention
@@ -266,7 +294,8 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
         PERF.md section 6, PR 41)."""
         kc, vc = paged_kv.write_token(kc, vc, l, bt, w, act, k, v)
         with jax.named_scope("attn"):
-            attn = paged_decode(q, kc, vc, l, bt, jnp.where(act, w + 1, 0))
+            attn = paged_decode(q, kc, vc, l, bt, jnp.where(act, w + 1, 0),
+                                **scaled)
             attn = attn.reshape(q.shape[0], H * hd)
         return kc, vc, attn
 
@@ -287,7 +316,7 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
             else:
                 attn = attention.flash_attention(
                     q, attention.repeat_kv(k, H // KVH),
-                    attention.repeat_kv(v, H // KVH), True)
+                    attention.repeat_kv(v, H // KVH), True, **scaled)
             attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd)
         if ctx["riders"]:
             # ONE decode step of the riding slots in the bucket's tail rows:
@@ -302,11 +331,12 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
             attn = attn.at[0, tail].set(
                 jnp.where(act[:, None], rode, attn[0, tail]))
         with jax.named_scope("attn_out"):
-            x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
+            x = x + block.scaled(
+                jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt)), mcfg)
         x, routed = _feed_forward(lp, x, ctx["live"], l if sparse else None)
         # cache pre-repeat k/v: [S, KVH, hd] (B == 1 squeezed)
         kept = (k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2))
-        counts = block.expert_stats(routed[1]) if sparse else None
+        counts = stats(routed[1], ctx["live"]) if sparse else None
         if indexed:
             kept += (ki[0, 0],)                                # [S, Id]
         return x, caches, kept, counts
@@ -352,45 +382,55 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
                     attn = sparse_decode(q, qi, iw, kc, vc, ic, l, bt,
                                          lengths, mcfg.index_topk)
                 else:
-                    attn = paged_decode(q, kc, vc, l, bt, lengths)
+                    attn = paged_decode(q, kc, vc, l, bt, lengths, **scaled)
                 attn = attn.reshape(ns, H * hd)
         with jax.named_scope("attn_out"):
-            x = x + attn @ lp["wo"].astype(dt)
+            x = x + block.scaled(attn @ lp["wo"].astype(dt), mcfg)
         # An idle slot's row is computed like any other, from itself alone,
         # and left out of the count.
         x, routed = block.feed_forward(lp, x, mcfg, act,
                                        l if sparse else None)
         return x, caches._replace(kc=kc, vc=vc, ic=ic), \
-            block.expert_stats(routed[1]) if sparse else None
+            stats(routed[1], act) if sparse else None
 
     return _Kind(prefill, decode,
                  keeps=("pages", "pages") + ("index",) * indexed, **how)
 
 
 def _mamba_kind(mcfg) -> _Kind:
-    """A hybrid's state-space layer (`block.mamba_mixer`): no K and V, a
-    recurrent state a slot (`ops/slot_state.py`), whose layer is the layer's
-    ordinal among the state-space layers. Rows past `length` reach no real
-    row: the convolution is causal, and the mixer is told `length`."""
+    """A hybrid's state-space layer (`block.mamba_mixer`, or Mamba-2's
+    `block.mamba2_mixer` where the model has `ssm_heads`) over a dense or a
+    sparse feed-forward: no K and V, a recurrent state a slot
+    (`ops/slot_state.py`), whose layer is the layer's ordinal among the
+    state-space layers. Rows past `length` reach no real row: the convolution
+    is causal, and the mixer is told `length`. A sparse layer hands back its
+    routing counts."""
+    mixer = block.mamba2_mixer if mcfg.ssm_heads else block.mamba_mixer
+    stats = _routing_stats(mcfg)
+
     def prefill(lp, x, caches, l, ctx):
-        y, state, window = block.mamba_mixer(lp, x[0], mcfg,
-                                             length=ctx["length"])
-        y, _ = block.feed_forward(lp, y[None], mcfg)
-        return y, caches, (state, window), None
+        routed_layer = "router" in lp
+        y, state, window = mixer(lp, x[0], mcfg, length=ctx["length"])
+        y, routed = block.feed_forward(lp, y[None], mcfg, ctx["live"],
+                                       l if routed_layer else None)
+        return y, caches, (state, window), \
+            stats(routed[1], ctx["live"]) if routed_layer else None
 
     def decode(lp, x, caches, l, ctx):
+        routed_layer = "router" in lp
         state = caches.state
         # The state's read and its write back are the update's traffic:
         # under the scope that times the update (`scan`).
         with jax.named_scope("scan"):
             ssm, window = slot_state.layer_state(state, l)
-        x, ssm, window = block.mamba_mixer(lp, x, mcfg, ssm, window,
-                                           step=True)
+        x, ssm, window = mixer(lp, x, mcfg, ssm, window, step=True)
         with jax.named_scope("scan"):
             state = slot_state.update_layer(state, l, ctx["act"], ssm,
                                             window)
-        x, _ = block.feed_forward(lp, x, mcfg)
-        return x, caches._replace(state=state), None
+        x, routed = block.feed_forward(lp, x, mcfg, ctx["act"],
+                                       l if routed_layer else None)
+        return x, caches._replace(state=state), \
+            stats(routed[1], ctx["act"]) if routed_layer else None
 
     return _Kind(prefill, decode, keeps=("state", "state"), over="index",
                  carries=("state",))
@@ -595,10 +635,6 @@ def _stack(mcfg) -> _Stack:
             else ())
 
     if mcfg.ssm_state:
-        if mcfg.n_experts or mcfg.index_topk:
-            raise NotImplementedError(
-                "a hybrid stack serves a dense feed-forward and plain "
-                "attention")
         return _Stack(
             {"attn": _attention_kind(mcfg, False, stack="layers",
                                      over="inline"),
@@ -608,8 +644,9 @@ def _stack(mcfg) -> _Stack:
                 *paged_kv.empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt),
                 state=slot_state.empty_state(
                     unpaged, ns, mcfg.ssm_state, mcfg.ssm_inner,
-                    mcfg.ssm_conv, dt)),
-            lambda c: {"state_bytes": slot_state.state_bytes(c.state)})
+                    mcfg.ssm_conv, dt, mcfg.ssm_conv_channels)),
+            lambda c: {"state_bytes": slot_state.state_bytes(c.state)},
+            shares=bool(mcfg.experts_held), tally="zero")
     if mcfg.conv:
         kind = _conv_kind(mcfg)
         return _Stack(
@@ -710,10 +747,11 @@ def _over(kind: _Kind, layers, mcfg, body, ordinals=True):
     and copy that back as the next step's carry (2.9 GB a step at 12 layers x
     929 pages; PERF.md, PR 25). The xs are the layer's weights and its
     index."""
-    if kind.over == "inline":
-        return lambda lo, hi, carry: body(_layer_of(layers, lo), lo, carry)
-    by_index = kind.over == "index"
     sliced, whole = block.expert_stacks(layers, mcfg)
+    if kind.over == "inline":
+        return lambda lo, hi, carry: body(
+            dict(_layer_of(sliced, lo), **whole), lo, carry)
+    by_index = kind.over == "index"
 
     def layer(carry, xs):
         if by_index:
@@ -774,7 +812,7 @@ def _prefill_walk(mcfg, stack: _Stack):
             if riders is not None:
                 tokens = tokens.at[0, tail].set(
                     jnp.where(act, last, tokens[0, tail]))
-            x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+            x = _embed(params, tokens, mcfg)
         with jax.named_scope("rope"):
             if riders is None:
                 tables = stack.tables(width, True)
@@ -819,7 +857,9 @@ def _prefill_walk(mcfg, stack: _Stack):
                     kept[cache].append((kind.over == "inline", tuple(
                         y for y, to in zip(ys, kind.keeps) if to == cache)))
                 if counts is not None and stack.tally != "late":
-                    counts = jnp.sum(counts, axis=0)
+                    # (an inline layer's counts are its own, not a stack's)
+                    if kind.over != "inline":
+                        counts = jnp.sum(counts, axis=0)
                     counts = counts if experts is None else experts + counts
                 experts = experts if counts is None else counts
         with jax.named_scope("head"):
@@ -956,7 +996,7 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         """ONE walk over the stack's segments for a decode step."""
         act = active & (pos < S)
         with jax.named_scope("embed"):
-            x = jnp.take(params["embed"], last, axis=0).astype(dt)
+            x = _embed(params, last, mcfg)
         ctx = dict(tables, bt=bt, pos=pos, act=act)
 
         def layer(kind):

@@ -9,7 +9,9 @@ The state is a pair of arrays, for the model's `n_layers` state-space layers
 
   ssm   [n_layers, n_slots, N, Di]      float32: `ops.ssm`'s state
   conv  [n_layers, K - 1, n_slots, Di]  the convolution's window, the last
-                                        K - 1 inputs of each channel
+                                        K - 1 inputs of each channel (Di + 2 N
+                                        channels under Mamba-2, whose
+                                        convolution takes B and C too)
 
 The channel axis is the minor one of both and the axis before it a whole
 tile (N = 16 rows of float32; 16 slots of bfloat16), so neither is padded:
@@ -70,12 +72,16 @@ State = Tuple[Optional[jax.Array], jax.Array]
 
 
 def empty_state(n_layers: int, n_slots: int, n_state: int, channels: int,
-                conv: int, dtype) -> State:
+                conv: int, dtype, conv_channels: Optional[int] = None
+                ) -> State:
     """-> (ssm, conv), zeroed; `n_state` 0: (None, conv), a window and no
-    recurrent state."""
+    recurrent state. `conv_channels`: the window's width where it is not the
+    state's (Mamba-2's convolution runs over x, B and C together, Di + 2 N
+    channels)."""
     return (jnp.zeros((n_layers, n_slots, n_state, channels), jnp.float32)
             if n_state else None,
-            jnp.zeros((n_layers, conv - 1, n_slots, channels), dtype))
+            jnp.zeros((n_layers, conv - 1, n_slots, conv_channels or channels),
+                      dtype))
 
 
 def state_bytes(state: State) -> int:

@@ -1,6 +1,8 @@
-"""State-space (Mamba-1) ops: the causal depthwise convolution with a
-carried window, the selective scan over a sequence, and the one-token state
-update of a decode step.
+"""State-space ops: the causal depthwise convolution with a carried window,
+and the two recurrences, each over a sequence and as the one-token state
+update of a decode step: Mamba-1's (`selective_scan`, `ssm_step`: a decay of
+its own for every channel and state) and Mamba-2's (`ssd_scan`, `ssd_step`:
+ONE scalar decay a head of channels, at the end of this text).
 
 Layouts, chosen so that nothing is padded on a TPU (the channel axis `Di` is
 always the minor one; a state axis of 16 as the minor one would be padded to
@@ -25,6 +27,17 @@ walks a block of the sequence 16 rows at a time; nothing of size S x Di x N
 is ever written) and a `lax.scan` elsewhere, which is the reference path and
 is differentiable. The path taken is counted at trace time in
 `attention.attention_path_counts()` as `scan_pallas` / `scan_reference`.
+
+Mamba-2 is the same recurrence with `A[n, c] = a[head(c)]` and `dt[t, c] =
+dt[t, head(c)]`, heads of `P = Di / H` channels next to each other:
+
+  dt  [S, H] float32   A, D  [H]   x, y [S, Di]   B, C [S, N]   state [N, Di]
+
+so `selective_scan` fed the broadcast `A` and `dt` computes it too (the tests'
+cross-check), at `S x Di x N` vector operations. `ssd_scan` is the chunked
+dual form, plain XLA: within a chunk of Q rows the outputs are matrix
+products on the matrix unit, and the state moves once a chunk (counted as
+`ssd_chunked`).
 """
 
 from __future__ import annotations
@@ -42,6 +55,11 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops import attention
 
 F32 = jnp.float32
+
+# Rows of a chunk of the dual form (`ssd_scan`): the published
+# `mamba_chunk_size`. What a chunk makes is `[H, Q, Q]` float32, 33 MB at 128
+# heads.
+_SSD_CHUNK = 256
 
 # Rows the kernel walks between two loads: a packed bfloat16 tile's.
 _CHUNK = 16
@@ -229,3 +247,93 @@ def selective_scan(x, dt, A, B, C, D, state0=None, length=None, *, z=None,
     if length is not None:
         dt = jnp.where(jnp.arange(S)[:, None] < length, dt, 0.0)
     return _scan_reference(x, dt, A, B, C, D, state0, z)
+
+
+def _by_head(v, channels: int):
+    """A head's scalar `[..., H]` at each of its channels `[..., Di]`."""
+    return jnp.repeat(v, channels // v.shape[-1], axis=-1)
+
+
+def ssd_step(x, dt, A, B, C, D, state):
+    """Mamba-2, one token a slot: x `[ns, Di]`, dt `[ns, H]` float32, A, D
+    `[H]`, B, C `[ns, N]`, state `[ns, N, Di]` float32 -> (y `[ns, Di]`
+    float32, the new state). The decay is `ns x H` exponentials, each
+    broadcast over its head's channels and the N states."""
+    Di = x.shape[-1]
+    dt = dt.astype(F32)
+    x = x.astype(F32)
+    decay = _by_head(jnp.exp(dt * A.astype(F32)), Di)           # [ns, Di]
+    state = decay[:, None, :] * state \
+        + (_by_head(dt, Di) * x)[:, None, :] * B.astype(F32)[:, :, None]
+    y = jnp.sum(state * C.astype(F32)[:, :, None], axis=1)
+    return y + _by_head(D.astype(F32), Di) * x, state
+
+
+def ssd_scan(x, dt, A, B, C, D, state0=None, length=None, *,
+             chunk: int = _SSD_CHUNK):
+    """Mamba-2 over x `[S, Di]` (layouts at the top) from `state0` (zeros if
+    None) -> (y `[S, Di]` in x's dtype, NOT gated: the family's gate sits
+    before a norm, the mixer's; the state `[N, Di]` float32 after the last
+    row). With `length` (a traced scalar) rows at and past it take dt = 0
+    (decay 1, no input), as `selective_scan`'s do, so the state returned is
+    the one after row `length - 1`; y there is not meaningful (and finite).
+
+    The chunked dual form, chunks of `chunk` rows in order under one loop
+    that carries the state. `chunk` is no option of a model: the mixer never
+    passes it, and it is here for the tests of a chunk's edges, which need
+    several chunks at tiny widths. Within a chunk, with c_t the running sum
+    of dt_t A a head (float32; every exponent below is <= 0):
+
+      G = C B^T                         [Q, Q], once for all heads
+      y = (G * L^h) (dt x)^h            L^h[t, s] = exp(c_t - c_s), s <= t
+        + exp(c_t) * (C S_prev) + D x
+      S_next = exp(c_Q) S_prev + B^T (exp(c_Q - c_s) dt x)
+
+    Decays, running sums and the state are float32; every matrix product
+    accumulates in float32, its operands in x's dtype (bfloat16 where the
+    model computes in it: B, C, `dt x`, `G * L`, the decayed `dt x` and, for
+    `C S_prev` alone, the carried state's rounded COPY; the state carried on
+    is never rounded). Nothing of size `S x Di x N` is made, and nothing
+    larger than `[H, Q, Q]` float32 a chunk."""
+    S, Di = x.shape
+    H, N = dt.shape[-1], B.shape[-1]
+    P = Di // H
+    dtype = x.dtype
+    attention._path_counts["ssd_chunked"] += 1
+    if state0 is None:
+        state0 = jnp.zeros((N, Di), F32)
+    dt = dt.astype(F32)
+    if length is not None:
+        dt = jnp.where(jnp.arange(S)[:, None] < length, dt, 0.0)
+    Q = min(chunk, S)
+    pad = -S % Q
+    if pad:     # rows that move nothing: dt = 0
+        x, dt, B, C = (jnp.pad(a, ((0, pad), (0, 0))) for a in (x, dt, B, C))
+    n = (S + pad) // Q
+    a, d = A.astype(F32), D.astype(F32)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def one(state, xs):
+        xc, dc, bc, cc = xs                     # [Q, Di], [Q, H], [Q, N] x 2
+        c = jnp.cumsum(dc * a, axis=0)                          # [Q, H]
+        xf = xc.astype(F32).reshape(Q, H, P)
+        dtx = dc[:, :, None] * xf                               # [Q, H, P]
+        g = jnp.einsum("tn,sn->ts", cc, bc, preferred_element_type=F32)
+        ct = c.T                                                # [H, Q]
+        seg = jnp.where(causal, ct[:, :, None] - ct[:, None, :], -jnp.inf)
+        m = (g * jnp.exp(seg)).astype(dtype)                    # [H, Q, Q]
+        y = jnp.einsum("hts,shp->thp", m, dtx.astype(dtype),
+                       preferred_element_type=F32)
+        inter = jnp.dot(cc, state.astype(dtype), preferred_element_type=F32)
+        y = y + jnp.exp(c)[:, :, None] * inter.reshape(Q, H, P) \
+            + d[:, None] * xf
+        last = c[-1]                                            # [H]
+        fed = (jnp.exp(last - c)[:, :, None] * dtx).astype(dtype)
+        state = _by_head(jnp.exp(last), Di) * state + jnp.einsum(
+            "sn,sd->nd", bc, fed.reshape(Q, Di), preferred_element_type=F32)
+        return state, y.reshape(Q, Di).astype(dtype)
+
+    state, y = jax.lax.scan(
+        one, state0, tuple(m.reshape(n, Q, -1) for m in (
+            x, dt, B.astype(dtype), C.astype(dtype))))
+    return y.reshape(n * Q, Di)[:S], state

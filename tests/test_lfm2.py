@@ -155,12 +155,19 @@ def test_what_the_stack_cannot_mix_is_refused_by_name(change, said):
 
 
 def test_a_mamba_hybrid_and_a_uniform_stack_still_refuse_what_they_did():
-    """What this PR does NOT close: sparse experts under state-space layers,
-    and the sigmoid router or leading dense layers under a uniform stack."""
+    """What PR 46 did NOT close: the sigmoid router or leading dense layers
+    under a uniform stack, and either beside state-space layers (sparse
+    experts under state-space layers came with PR 49: the `mamba` stack has
+    its router and experts)."""
     cfg = llama.LlamaConfig.tiny(n_experts=4, ssm_state=4, ssm_dt_rank=2,
                                  attn_layers=(1,))
-    with pytest.raises(NotImplementedError, match="no sparse experts"):
-        llama.init_params(cfg, jax.random.PRNGKey(0))
+    shapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    assert shapes["mamba"]["w_gate"].shape[:2] == (1, 4) \
+        and shapes["mamba"]["router"].shape == (1, 64, 4)
+    with pytest.raises(ValueError, match="run as segments"):
+        llama.LlamaConfig.tiny(n_experts=4, router_score="sigmoid",
+                               ssm_state=4, ssm_dt_rank=2, attn_layers=(1,))
     with pytest.raises(ValueError, match="run as segments"):
         llama.LlamaConfig.tiny(n_experts=4, router_score="sigmoid")
     with pytest.raises(ValueError, match="first_dense"):

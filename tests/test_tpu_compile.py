@@ -649,6 +649,94 @@ def test_lfm2_programs_keep_pages_and_windows_in_place_on_v5e(
                                      else (96 << 20))
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_granite_programs_keep_pages_and_state_in_place_on_v5e(
+        topo, program, monkeypatch):
+    """The Mamba-2 hybrid over a share of the experts: its decode chunk of 64
+    slots and its 1,024-row prefill at the cell's sizes
+    (benchmark/configs/granite-4.0-h-small-serve.json). The recurrent state
+    is 2.42 GB (9 layers x 64 slots x 128 x 8,192 float32) and rides the
+    decode loop's carry: it, its windows over 8,448 channels and the pages of
+    the ONE attention layer are donated and alias the outputs, so no second
+    copy of the state is made (a copy would show as 2.4 GB of temporaries).
+    Decode's attention is the `paged_decode` kernel, a prompt's `flash_fwd`,
+    the recurrence over a prompt the chunked dual form in plain XLA, the
+    experts the grouped matmul with no copy of a stack and the share's
+    combine the local kernel; and the bytes are PERF.md section 4's row."""
+    import json
+
+    from benchmark import models
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.models.serving import build_programs
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "granite-4.0-h-small-serve.json")) as f:
+        model = json.load(f)
+    eng = model["deployment"]["engine"]
+    cfg = models.adapter("granitemoehybrid").build_config(
+        model, model["dtypes"], eng["max_seq"])
+    ns, page = eng["n_slots"], eng["page_size"]
+    maxp = eng["max_seq"] // page
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    built = build_programs(cfg, ns, eng["decode_chunk"], page,
+                           eng["kv_pages"])
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)), cfg)))
+    caches = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(built.empty))
+    kc, vc, ic, (ssm, window) = caches
+    assert kc.shape == vc.shape == (1, eng["kv_pages"], 8, page, 128)
+    assert ic is None and ssm.shape == (9, ns, 128, 8192) \
+        and ssm.dtype == jnp.float32 and window.shape == (9, 3, ns, 8448)
+    before = dict(attention.attention_path_counts())
+    if program == "decode":
+        lowered = built.decode.lower(
+            params, caches, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
+            sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
+            sds((ns,), jnp.float32), sds((ns,), jnp.int32),
+            sds((ns, 2), jnp.uint32))
+        kernels, paths = ["paged_decode", "grouped_matmul", "local_combine"], [
+            "decode_pallas", "experts_grouped_pallas", "share_combine_local"]
+    else:
+        lowered = built.prefill.lower(
+            params, caches, sds((maxp,), jnp.int32), sds((1, 1024), jnp.int32),
+            1, 0.0, 0, sds((2,), jnp.uint32), 0)
+        kernels, paths = ["flash_fwd", "grouped_matmul", "local_combine"], [
+            "fwd_pallas", "experts_grouped_pallas", "share_combine_local",
+            "ssd_chunked"]
+    text = lowered.as_text()
+    assert all(k in text for k in kernels)
+    counts = attention.attention_path_counts()
+    assert all(counts[p] > before.get(p, 0) for p in paths)
+    assert counts.get("experts_ragged_dot", 0) == before.get(
+        "experts_ragged_dot", 0)
+    compiled = lowered.compile()
+    stacks = [tuple(params[stack][w].shape) for stack in ("mamba", "layers")
+              for w in ("w_gate", "w_up", "w_down")]
+    assert not _moved_stacks(compiled.as_text(), stacks)
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in (kc, vc, ssm, window))
+    assert held == 2 * eng["kv_pages"] * 8 * page * 128 * 2 \
+        + 9 * ns * 128 * 8192 * 4 + 9 * 3 * ns * 8448 * 2 == 2_982_248_448
+    assert mem.alias_size_in_bytes >= held
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert weights == 2 * 4_757_211_776
+    # arguments: the weights, the caches and a step's few vectors
+    assert 0 <= mem.argument_size_in_bytes - weights - held < 1 << 20
+    print(program, "temp", mem.temp_size_in_bytes, "args",
+          mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < ((64 << 20) if program == "decode"
+                                     else (1 << 30))
+
+
 # ---------------------------------------------------------------------------
 # The experts' grouped matmul (ops/moe.py::grouped_matmul)
 # ---------------------------------------------------------------------------
