@@ -55,8 +55,9 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.ops.norms import layer_norm, rms_norm
-from ray_tpu.ops.ssm import (causal_conv, selective_scan, ssd_scan, ssd_step,
-                             ssm_step)
+# The module, not its name: tests put an interpreted `step_layer` in its place.
+from ray_tpu.ops import slot_state
+from ray_tpu.ops.ssm import causal_conv, selective_scan, ssd_scan, ssm_step
 
 
 def attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
@@ -369,8 +370,9 @@ def mamba_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
 
 
 def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
-                 window=None, *, step: bool = False, length=None
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                 window=None, *, step: bool = False, length=None,
+                 layer=None, active=None
+                 ) -> Tuple[jax.Array, Any, jax.Array]:
     """x + residual_scale * mixer(norm(x)) for a Mamba-2 layer
     (`cfg.ssm_heads` heads of `Di / H` channels, one group of B and C; the
     Granite 4.0-H family's, which is Bamba's), under `mamba_mixer`'s scopes:
@@ -387,8 +389,12 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
     x `[S, D]`, one sequence, from `state` `[N, Di]` and `window` `[K - 1, Di
     + 2N]` (None: a sequence's start), through `ops.ssm.ssd_scan`; rows at
     and past `length` leave state and window as they were. Or, with `step`,
-    x `[ns, D]`, one token a slot, from `state` `[ns, N, Di]` and `window` `[K
-    - 1, ns, Di + 2N]`. -> (out, state, window)."""
+    x `[ns, D]`, one token a slot, from `window` `[K - 1, ns, Di + 2N]` and
+    `state` the slots' WHOLE state (`ops/slot_state.py`'s pair), of which
+    layer `layer`'s rows of the slots `active` marks are read, updated and
+    written where they lie, in one visit (`slot_state.step_layer`): the state
+    handed back is the pair, and an idle slot's output row is not meaningful
+    (and finite). -> (out, state, window)."""
     dt = cfg.dtype
     Di, N, eps = cfg.ssm_inner, cfg.ssm_state, cfg.norm_eps
     with jax.named_scope("ssm_in"):
@@ -411,7 +417,8 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
         a = -jnp.exp(lp["A_log"].astype(jnp.float32))
     with jax.named_scope("scan"):
         if step:
-            y, state = ssd_step(u, step_size, a, b, c, lp["D"], state)
+            y, state = slot_state.step_layer(state, layer, active, u,
+                                             step_size, a, b, c, lp["D"])
         else:
             y, state = ssd_scan(u, step_size, a, b, c, lp["D"], state,
                                 length)

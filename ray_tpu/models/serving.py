@@ -418,15 +418,22 @@ def _mamba_kind(mcfg) -> _Kind:
 
     def decode(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
-        state = caches.state
+        state, act = caches.state, ctx["act"]
         # The state's read and its write back are the update's traffic:
         # under the scope that times the update (`scan`).
         with jax.named_scope("scan"):
             ssm, window = slot_state.layer_state(state, l)
-        x, ssm, window = mixer(lp, x, mcfg, ssm, window, step=True)
+        if mcfg.ssm_heads:
+            # Mamba-2's step is handed the slots' whole state and visits the
+            # layer's rows where they lie, once (`slot_state.step_layer`);
+            # the slice above is never read, so never made.
+            x, state, window = mixer(lp, x, mcfg, state, window, step=True,
+                                     layer=l, active=act)
+            ssm = None
+        else:
+            x, ssm, window = mixer(lp, x, mcfg, ssm, window, step=True)
         with jax.named_scope("scan"):
-            state = slot_state.update_layer(state, l, ctx["act"], ssm,
-                                            window)
+            state = slot_state.update_layer(state, l, act, ssm, window)
         x, routed = block.feed_forward(lp, x, mcfg, ctx["act"],
                                        l if routed_layer else None)
         return x, caches._replace(state=state), \

@@ -28,9 +28,18 @@ context), and every op here passes the None through.
     whole of what the slot's previous tenant left; ``layer_state`` and
     ``update_layer`` are a decode step's read and write of one layer, the
     write only where a slot is active, so an idle slot's state never moves.
+  * ``step_layer`` is a Mamba-2 layer's read, update and write of its
+    recurrent state in ONE op, so that a decode step visits the state once:
+    on a TPU `ops.ssm.ssd_state_step`, a kernel handed the whole `ssm`
+    array and the layer's index that reads each active slot's tile where it
+    lies, updates it, reduces it to the step's output and writes it back in
+    place (a read and a write of the state: the least a step can move;
+    `layer_state`, `ops.ssm.ssd_step` and `update_layer` compile to a read
+    more); elsewhere those three. The window keeps `update_layer`.
   * Like the arena, the state rides the decode program's loop CARRY and is
-    donated: `update_layer` is an in-place write of one layer's rows.
-    Nothing outside this module indexes it.
+    donated: `update_layer` is an in-place write of one layer's rows, and
+    `step_layer`'s kernel aliases the state it is handed. Nothing outside
+    this module indexes it.
 
 A WINDOW layer's cache is the other thing that has no pages (a model of
 window and full attention layers, `LlamaConfig.attn_pattern`): a query sees
@@ -65,6 +74,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import attention
+from ray_tpu.ops import ssm as ssm_ops
 from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
 from ray_tpu.ops.paged_kv import _lanes, _to_width
 
@@ -107,14 +117,44 @@ def layer_state(state: State, layer) -> State:
 
 def update_layer(state: State, layer, active, ssm_rows, conv_rows) -> State:
     """A decode step's new state of one layer, kept only for the slots
-    `active` `[n_slots]` marks: the others' rows stay what they were."""
+    `active` `[n_slots]` marks: the others' rows stay what they were.
+    Rows given as None leave their array alone (`ssm_rows`: a model that
+    has no recurrent state, and a Mamba-2 layer's, which `step_layer` writes;
+    `conv_rows`: `step_layer`'s own write)."""
     ssm, conv = state
-    if ssm is not None:
+    if ssm_rows is not None:
         ssm_rows = jnp.where(active[:, None, None], ssm_rows, ssm[layer])
-    conv_rows = jnp.where(active[None, :, None],
-                          conv_rows.astype(conv.dtype), conv[layer])
-    return (None if ssm is None else ssm.at[layer].set(ssm_rows),
-            conv.at[layer].set(conv_rows))
+    if conv_rows is not None:
+        conv_rows = jnp.where(active[None, :, None],
+                              conv_rows.astype(conv.dtype), conv[layer])
+    return (ssm if ssm_rows is None else ssm.at[layer].set(ssm_rows),
+            conv if conv_rows is None else conv.at[layer].set(conv_rows))
+
+
+def step_layer(state: State, layer, active, x, dt, A, B, C, D, *,
+               interpret: bool = False) -> Tuple[jax.Array, State]:
+    """A Mamba-2 decode step's read, update and write of one layer's
+    recurrent state in ONE visit (`ops.ssm.ssd_step`'s arguments, one token
+    a slot) -> (y `[n_slots, Di]` float32, zeros for an idle slot; the state,
+    whose idle slots' rows and other layers stay what they were). On a TPU
+    (or with `interpret`, for tests on the CPU), where the state is whole
+    tiles, `ops.ssm.ssd_state_step`, which crosses each active slot's state
+    once, where it lies; elsewhere `ssd_step` on the layer's rows and their
+    write back. The path taken is counted at trace time in
+    `attention.attention_path_counts()` as `ssd_step_pallas` /
+    `ssd_step_reference`. The window is not this op's: `update_layer`."""
+    ssm, conv = state
+    use = (interpret or attention._on_tpu()) \
+        and ssm_ops.state_step_tiles(ssm.shape)
+    attention._path_counts[
+        "ssd_step_pallas" if use else "ssd_step_reference"] += 1
+    if use:
+        y, ssm = ssm_ops.ssd_state_step(ssm, layer, active, x, dt, A, B, C,
+                                        D, interpret=interpret)
+        return y, (ssm, conv)
+    y, rows = ssm_ops.ssd_step(x, dt, A, B, C, D, ssm[layer])
+    return jnp.where(active[:, None], y, 0.0), \
+        update_layer(state, layer, active, rows, None)
 
 
 # ---------------------------------------------------------------------------

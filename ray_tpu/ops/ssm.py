@@ -38,6 +38,19 @@ cross-check), at `S x Di x N` vector operations. `ssd_scan` is the chunked
 dual form, plain XLA: within a chunk of Q rows the outputs are matrix
 products on the matrix unit, and the state moves once a chunk (counted as
 `ssd_chunked`).
+
+A decode step's update is bound by the state's bytes (`[ns, N, Di]` float32
+a layer: 268 MB at 64 slots x 128 x 8,192), so what counts is how often it
+crosses them. `ssd_step`, plain XLA on a layer's rows, is the reference and
+the path off a TPU; compiled for one it crosses them three times (the update
+in place reads and writes; `y = sum_n s * C` is a fusion of its own that
+reads them again and recomputes the update). `ssd_state_step` is a Pallas
+kernel (named `ssd_state_step`) handed the slots' WHOLE state `[L, ns, N,
+Di]` and the layer's index, which visits each ACTIVE slot's state ONCE: a
+grid step reads a `[N, block]` tile from where it lies, updates it, reduces
+it to y and writes it back in its place (the state is the kernel's input and
+its output). `ops/slot_state.py::step_layer` chooses between the two and
+counts the path (`ssd_step_pallas` / `ssd_step_reference`).
 """
 
 from __future__ import annotations
@@ -70,6 +83,13 @@ _CHUNK = 16
 # memory a kernel may use.
 _BLOCK_CHANNELS = 1024
 _BLOCK_ROWS = 512
+# Channels of one slot's state a grid step of `ssd_state_step` holds, `[N,
+# block]` float32 (512 KB at 128 states; in and out, two buffers each, 2 MB
+# of the 16 MiB a kernel may use, beside 1.5 MB of the slots' vectors and
+# y). On a v5e, nine layers of 64 slots x 128 states x 8,192 channels, 4.83
+# GB in and out, take 7.36 ms at 1,024 (80.1% of HBM's rate), 7.41 at 2,048,
+# 7.48 at 4,096 and 8.64 at 512 (my chip run, PR 50).
+_STEP_BLOCK_CHANNELS = 1024
 
 
 def causal_conv(x: jax.Array, w: jax.Array, b: Optional[jax.Array],
@@ -254,19 +274,121 @@ def _by_head(v, channels: int):
     return jnp.repeat(v, channels // v.shape[-1], axis=-1)
 
 
-def ssd_step(x, dt, A, B, C, D, state):
-    """Mamba-2, one token a slot: x `[ns, Di]`, dt `[ns, H]` float32, A, D
-    `[H]`, B, C `[ns, N]`, state `[ns, N, Di]` float32 -> (y `[ns, Di]`
-    float32, the new state). The decay is `ns x H` exponentials, each
-    broadcast over its head's channels and the N states."""
+def _step_vectors(x, dt, A):
+    """What a step's update takes of a slot beside B and C, float32 `[ns,
+    Di]` each: x, the decay (`ns x H` exponentials, each broadcast over its
+    head's channels) and `dt * x`."""
     Di = x.shape[-1]
     dt = dt.astype(F32)
     x = x.astype(F32)
-    decay = _by_head(jnp.exp(dt * A.astype(F32)), Di)           # [ns, Di]
+    return x, _by_head(jnp.exp(dt * A.astype(F32)), Di), _by_head(dt, Di) * x
+
+
+def ssd_step(x, dt, A, B, C, D, state):
+    """Mamba-2, one token a slot: x `[ns, Di]`, dt `[ns, H]` float32, A, D
+    `[H]`, B, C `[ns, N]`, state `[ns, N, Di]` float32 -> (y `[ns, Di]`
+    float32, the new state). The decay is broadcast over the N states too.
+    The reference of `ssd_state_step`, and the path off a TPU."""
+    x, decay, dtx = _step_vectors(x, dt, A)
     state = decay[:, None, :] * state \
-        + (_by_head(dt, Di) * x)[:, None, :] * B.astype(F32)[:, :, None]
+        + dtx[:, None, :] * B.astype(F32)[:, :, None]
     y = jnp.sum(state * C.astype(F32)[:, :, None], axis=1)
-    return y + _by_head(D.astype(F32), Di) * x, state
+    return y + _by_head(D.astype(F32), x.shape[-1]) * x, state
+
+
+def _state_step_kernel(layer_ref, slots_ref, decay_ref, dtx_ref, b_ref, c_ref,
+                       s_ref, y_ref, o_ref):
+    """Grid (blocks of channels, ACTIVE slots), slots innermost: a grid step
+    holds one slot's `[N, block]` tile of the layer's state, which the block
+    specs read from where it lies and write back there. The slots' vectors
+    of the block of channels (`[ns, block]`) and y stay in fast memory while
+    the slots go by, B and C (`[ns, N]`) throughout: a slot's row of each is
+    read by its index."""
+    del layer_ref
+    row = pl.ds(slots_ref[pl.program_id(1)], 1)
+    N = s_ref.shape[0]
+    diagonal = jax.lax.broadcasted_iota(jnp.int32, (N, N), 0) \
+        == jax.lax.broadcasted_iota(jnp.int32, (N, N), 1)
+
+    # The slot's row `[1, N]` laid along the state's rows, `[N, 1]`, under a
+    # mask (16 vector registers at 128 states; a tile is 128). B and C handed
+    # over transposed would do without it, and XLA then lays their producer's
+    # input, the decode program's window, with the slots as the minor axis:
+    # 58 MB of copies around every chunk (compiled for a v5e, PR 50).
+    def column(ref):
+        return jnp.sum(jnp.where(diagonal, ref[row, :], 0.0), axis=1,
+                       keepdims=True)
+
+    s = decay_ref[row, :] * s_ref[...] + dtx_ref[row, :] * column(b_ref)
+    o_ref[...] = s
+    y_ref[row, :] = jnp.sum(s * column(c_ref), axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("bd", "interpret"))
+def _state_step_pallas(ssm, layer, active, decay, dtx, B, C, *, bd,
+                       interpret):
+    """Under a `jit` of its own, as `ops/moe.py::_grouped_pallas` is: a
+    decode program's segments of state-space layers trace and lower it
+    once."""
+    _, ns, N, Di = ssm.shape
+    # The active slots' indices, in order, then zeros; only the first
+    # `count` are visited. (A compare of every slot with every rank: 4,096
+    # pairs at 64 slots, where a sort would be a program of its own.)
+    at = jnp.arange(ns, dtype=jnp.int32)
+    rank = jnp.cumsum(active, dtype=jnp.int32) - 1
+    slots = jnp.sum(jnp.where(active & (rank == at[:, None]), at, 0), axis=1)
+    vectors = pl.BlockSpec((ns, bd), lambda j, i, *_: (0, j))
+    maps = pl.BlockSpec((ns, N), lambda j, i, *_: (0, 0))
+    tile = pl.BlockSpec((None, None, N, bd),
+                        lambda j, i, layer, slots: (layer[0], slots[i], 0, j))
+    return pl.pallas_call(
+        _state_step_kernel,
+        name="ssd_state_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                    # layer, slots
+            grid=(Di // bd, rank[-1] + 1),
+            in_specs=[vectors, vectors, maps, maps, tile],
+            out_specs=[vectors, tile]),
+        out_shape=[jax.ShapeDtypeStruct((ns, Di), F32),
+                   jax.ShapeDtypeStruct(ssm.shape, F32)],
+        # The state out is the state in: a tile never visited (an idle
+        # slot's, another layer's) keeps its bytes.
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), slots, decay, dtx, B, C, ssm)
+
+
+def state_step_tiles(ssm_shape) -> bool:
+    """Whether `ssd_state_step`'s tiling takes a state `[L, ns, N, Di]`:
+    whole float32 tiles of 8 x 128 a slot."""
+    return ssm_shape[2] % 8 == 0 and ssm_shape[3] % 128 == 0
+
+
+def ssd_state_step(ssm, layer, active, x, dt, A, B, C, D, *,
+                   interpret: bool = False,
+                   block_channels: int = _STEP_BLOCK_CHANNELS):
+    """`ssd_step` of ONE layer's ACTIVE slots on the slots' whole state `ssm`
+    `[L, ns, N, Di]` float32 where it lies, each slot's state crossed once:
+    the Pallas kernel `ssd_state_step` reads a tile, updates it, reduces it
+    to y and writes it back in its place (`ssm` is the kernel's input AND its
+    output; donate it). It is handed the whole array and `layer` (a traced
+    scalar), never `ssm[layer]`: a custom call handed a slice is first
+    handed a copy. x `[ns, Di]`, dt `[ns, H]`, A, D `[H]`, B, C `[ns, N]`,
+    `active` `[ns]` -> (y `[ns, Di]` float32, zeros for an idle slot; the
+    state, an idle slot's and every other layer's rows as they were, to the
+    bit). The decay and `dt * x` are made here, a row a slot, by `ssd_step`'s
+    own ops, and `D * x` is added here; the arithmetic on the state is
+    `ssd_step`'s, in its order, in float32. Needs `state_step_tiles`."""
+    Di = x.shape[-1]
+    x, decay, dtx = _step_vectors(x, dt, A)
+    bd = next(b for b in (block_channels, 512, 256, 128) if Di % b == 0)
+    y, ssm = _state_step_pallas(ssm, layer, active, decay, dtx,
+                                B.astype(F32), C.astype(F32), bd=bd,
+                                interpret=interpret)
+    y = y + _by_head(D.astype(F32), Di) * x
+    return jnp.where(active[:, None], y, 0.0), ssm
 
 
 def ssd_scan(x, dt, A, B, C, D, state0=None, length=None, *,

@@ -24,7 +24,7 @@ from benchmark import models
 from benchmark import reference_granite as ref
 from ray_tpu.models import block, llama, serving
 from ray_tpu.models.block import fuse_qkv, mamba2_mixer
-from ray_tpu.ops import attention, ssm
+from ray_tpu.ops import attention, slot_state, ssm
 from ray_tpu.serve.engine import Engine
 from ray_tpu.utils import tracing
 
@@ -189,15 +189,28 @@ def test_the_chunk_is_an_implementations_size():
 
 # -- (b) steps and split prompts ---------------------------------------------
 
-def test_one_token_steps_are_the_scan():
+def _kernel_step(x, dt, A, B, C, D, state):
+    """`ssd_step`'s signature over `slot_state.step_layer`'s kernel path,
+    interpreted: the slots' rows as the one layer of a whole state."""
+    before = attention.attention_path_counts().get("ssd_step_pallas", 0)
+    y, (ssm_all, _) = slot_state.step_layer(
+        (state[None], None), jnp.int32(0), jnp.ones(len(x), bool), x, dt, A,
+        B, C, D, interpret=True)
+    assert attention.attention_path_counts()["ssd_step_pallas"] == before + 1
+    return y, ssm_all[0]
+
+
+@pytest.mark.parametrize("step", [ssm.ssd_step, _kernel_step],
+                         ids=["reference", "kernel"])
+def test_one_token_steps_are_the_scan(step):
     """`ssd_step` a row at a time, two slots at once, is `ssd_scan` over the
-    two sequences."""
+    two sequences; and so is the step kernel, on the state where it lies."""
     a, b = _scan_inputs(S=40, seed=1), _scan_inputs(S=40, seed=2)
     A, D = a[2], a[5]
     state = jnp.stack([a[6], b[6]])
     ys = []
     for t in range(40):
-        y, state = ssm.ssd_step(
+        y, state = step(
             *(jnp.stack([a[i][t], b[i][t]]) for i in (0, 1)), A,
             *(jnp.stack([a[i][t], b[i][t]]) for i in (3, 4)), D, state)
         ys.append(y)
@@ -208,6 +221,37 @@ def test_one_token_steps_are_the_scan():
         assert np.abs(ys[slot] - np.asarray(y)).max() < SCAN_TOL
         assert np.abs(np.asarray(state[slot]) - np.asarray(s)).max() \
             < SCAN_TOL
+
+
+@pytest.mark.parametrize("blocks", [1, 2], ids=["one_block", "two_blocks"])
+@pytest.mark.parametrize("layer", [0, 2], ids=["first_layer", "last_layer"])
+@pytest.mark.parametrize("active", [(1, 1, 1, 1), (0, 1, 1, 0), (0, 0, 0, 0)],
+                         ids=["all_active", "some_idle", "none_active"])
+def test_the_step_kernel_is_ssd_step_and_update_layer_in_one_visit(
+        active, layer, blocks):
+    """`ssd_state_step` (interpreted) on a state of three layers and four
+    slots against `ssd_step` on the layer's rows and `update_layer`: y and
+    the active slots' new state to float32 rounding (the kernel sums the N
+    states in another order); every idle slot's state and every OTHER
+    layer's bit for bit; an idle slot's row of y zeros."""
+    L, ns, N, Di, H = 3, 4, 16, 256, 8
+    x, dt, A, B, C, D, _ = _scan_inputs(S=ns, Di=Di, N=N, H=H, seed=5)
+    ssm0 = jax.random.normal(jax.random.PRNGKey(6), (L, ns, N, Di))
+    act = jnp.array(active, bool)
+    want_y, rows = ssm.ssd_step(x, dt, A, B, C, D, ssm0[layer])
+    want, _ = slot_state.update_layer((ssm0, None), layer, act, rows, None)
+    y, got = ssm.ssd_state_step(ssm0, jnp.int32(layer), act, x, dt, A, B, C,
+                                D, interpret=True,
+                                block_channels=Di // blocks)
+    y, got, want, idle = (np.asarray(a) for a in (y, got, want, ~act))
+    assert got.shape == ssm0.shape and got.dtype == np.float32
+    if not idle.all():
+        assert _err(y[~idle], np.asarray(want_y)[~idle]) < 1e-6
+    assert _err(got, want) < 1e-6
+    assert (y[idle] == 0).all()
+    assert (got[:, idle] == np.asarray(ssm0)[:, idle]).all()
+    others = [l for l in range(L) if l != layer]
+    assert (got[others] == np.asarray(ssm0)[others]).all()
 
 
 @pytest.mark.parametrize("cut", [1, 17, 32, 47])
@@ -229,12 +273,14 @@ def test_a_split_prompt_is_the_unsplit_one(tiny, cut):
     assert _err(s2, state) < SCAN_TOL
     assert _err(w2, window) < SCAN_TOL
     # ... and the rows after the cut a token at a time, as a decode step has
-    # them (one slot: a leading axis of 1 on the state, the window's second)
-    s, w = s1[None], w1[:, None]
+    # them: the step is handed the slots' whole state (`ops/slot_state.py`'s
+    # pair, here one layer of one slot) and the layer's window of that slot
+    s, w = (s1[None, None], None), w1[:, None]
     for t in range(cut, 48):
-        y, s, w = mamba2_mixer(lp, x[t][None], cfg, s, w, step=True)
+        y, s, w = mamba2_mixer(lp, x[t][None], cfg, s, w, step=True,
+                               layer=0, active=jnp.ones(1, bool))
         assert _err(y[0], whole[t]) < SCAN_TOL
-    assert _err(s[0], state) < SCAN_TOL
+    assert _err(s[0][0, 0], state) < SCAN_TOL
 
 
 # -- (c) the mixer, the attention block, the multipliers ---------------------
@@ -458,8 +504,31 @@ def test_the_hybrid_engine_took_its_paths_and_its_spans_carry_the_share(
     counts = attention.attention_path_counts()
     assert counts["ssd_chunked"] >= 1 and counts["fwd_reference"] >= 1
     assert counts["decode_reference"] >= 1      # the CPU's decode path
+    assert counts["ssd_step_reference"] >= 1    # ... and its state's update
     assert counts["share_combine_gather"] >= 1  # off the chip, the gather
     assert counts["experts_ragged_dot"] >= 1
+
+
+def test_an_engine_decodes_through_the_step_kernel(tiny, monkeypatch):
+    """An engine built with `slot_state.step_layer` interpreted (what the
+    mixer calls is the function as it stands on the module) updates its
+    slots' state through the kernel's own code, in place in the decode
+    program's carry, one slot of four live: the served tokens are the
+    reference's to the engine's tolerance."""
+    import functools
+    cfg, params = tiny
+    monkeypatch.setattr(slot_state, "step_layer", functools.partial(
+        slot_state.step_layer, interpret=True))
+    before = attention.attention_path_counts().get("ssd_step_pallas", 0)
+    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=4,
+                 decode_chunk=4, page_size=16)
+    try:
+        assert attention.attention_path_counts()["ssd_step_pallas"] > before
+        prompt = _tokens(40, 41)
+        toks = _serve(eng, [prompt], 12)[0]
+    finally:
+        eng.stop()
+    assert max(ref.served_token_gaps(params, MODEL, prompt, toks)) < LOGIT_TOL
 
 
 def test_a_bfloat16_state_is_outside_the_tolerance(tiny, engine):

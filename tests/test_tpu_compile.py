@@ -387,6 +387,32 @@ def test_selective_scan_kernel_compiles_for_v5e(topo, S):
     assert mem.temp_size_in_bytes < 4 * S * N * 4 + (1 << 20)  # B, C re-laid
 
 
+def test_ssd_state_step_kernel_compiles_for_v5e(topo):
+    """A Mamba-2 decode step's update of one layer at the Granite cell's
+    widths (9 layers x 64 slots x 128 states x 8,192 channels of float32,
+    2.42 GB; bfloat16 rows, a float32 time step a head): one Mosaic kernel
+    handed the WHOLE state, which it aliases; nothing the size of a layer's
+    state (268 MB) is set aside, only the slots' vectors."""
+    from ray_tpu.ops import ssm
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, ns, N, Di, H = 9, 64, 128, 8192, 128
+    rows, maps = sds((ns, Di), jnp.bfloat16), sds((ns, N), jnp.bfloat16)
+    head = sds((H,), jnp.float32)
+    lowered = jax.jit(ssm.ssd_state_step, donate_argnums=0).lower(
+        sds((L, ns, N, Di), jnp.float32), sds((), jnp.int32),
+        sds((ns,), jnp.bool_), rows, sds((ns, H), jnp.float32), head, maps,
+        maps, head)
+    text = lowered.as_text()
+    assert text.count("tpu_custom_call") == 1 and "ssd_state_step" in text
+    mem = lowered.compile().memory_analysis()
+    assert mem.alias_size_in_bytes == L * ns * N * Di * 4
+    assert mem.temp_size_in_bytes < 16 << 20
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_jamba_programs_keep_arena_and_state_in_place_on_v5e(
         topo, program, monkeypatch):
@@ -659,10 +685,12 @@ def test_granite_programs_keep_pages_and_state_in_place_on_v5e(
     decode loop's carry: it, its windows over 8,448 channels and the pages of
     the ONE attention layer are donated and alias the outputs, so no second
     copy of the state is made (a copy would show as 2.4 GB of temporaries).
-    Decode's attention is the `paged_decode` kernel, a prompt's `flash_fwd`,
-    the recurrence over a prompt the chunked dual form in plain XLA, the
-    experts the grouped matmul with no copy of a stack and the share's
-    combine the local kernel; and the bytes are PERF.md section 4's row."""
+    Decode's attention is the `paged_decode` kernel, its state's update the
+    `ssd_state_step` kernel handed the whole state (one layer's copy would be
+    268 MB of temporaries), a prompt's attention `flash_fwd`, the recurrence
+    over a prompt the chunked dual form in plain XLA, the experts the grouped
+    matmul with no copy of a stack and the share's combine the local kernel;
+    and the bytes are PERF.md section 4's row."""
     import json
 
     from benchmark import models
@@ -702,8 +730,10 @@ def test_granite_programs_keep_pages_and_state_in_place_on_v5e(
             sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
             sds((ns,), jnp.float32), sds((ns,), jnp.int32),
             sds((ns, 2), jnp.uint32))
-        kernels, paths = ["paged_decode", "grouped_matmul", "local_combine"], [
-            "decode_pallas", "experts_grouped_pallas", "share_combine_local"]
+        kernels, paths = ["paged_decode", "grouped_matmul", "local_combine",
+                          "ssd_state_step"], [
+            "decode_pallas", "experts_grouped_pallas", "share_combine_local",
+            "ssd_step_pallas"]
     else:
         lowered = built.prefill.lower(
             params, caches, sds((maxp,), jnp.int32), sds((1, 1024), jnp.int32),
