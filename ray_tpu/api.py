@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import functools
 import inspect
+import os
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 from ray_tpu.core.common import Address
@@ -85,9 +87,31 @@ def init(address: Optional[str] = None, *,
             "agent_address": agent_addr}
 
 
+def _dump_timeline(cw: CoreWorker, session_dir: str) -> None:
+    """The session's spans outlive it: `<session_dir>/timeline.json`, which
+    `state.load_timeline` and `ray_tpu timeline --session` read back. This
+    process's buffered spans go first (a proxy and a router live here), then
+    every worker's; a controller that does not answer leaves no file."""
+    from ray_tpu import state
+    t0 = time.monotonic()
+    path = os.path.join(session_dir, state.TIMELINE_FILE)
+    try:
+        cw._run(cw.flush_spans()).result(2.0)
+        state._ctl("flush_spans", timeout=4.0)
+        events = state.timeline(path, timeout=10.0)
+        state._last_session_dir = session_dir
+        logger.info("session timeline: %d events, %d bytes in %.3f s: %s",
+                    len(events), os.path.getsize(path),
+                    time.monotonic() - t0, path)
+    except Exception:
+        pass  # observability is best-effort
+
+
 def shutdown() -> None:
     global _global_node, _core_worker
     if _core_worker is not None:
+        if _global_node is not None:    # the session ends with this process
+            _dump_timeline(_core_worker, _global_node.session_dir)
         _core_worker.shutdown()
         _core_worker = None
     from ray_tpu.core import ref as _ref

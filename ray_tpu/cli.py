@@ -13,6 +13,10 @@ Analogue of the reference's CLI (reference: python/ray/scripts/scripts.py
     python -m ray_tpu.cli audit --address ... [--json]
     python -m ray_tpu.cli timeline --address ... --out trace.json
     python -m ray_tpu.cli timeline --address ... --native --format chrome
+    python -m ray_tpu.cli timeline --session DIR --native   # after it ended:
+        # ray_tpu.shutdown() writes DIR/timeline.json (DIR: the session's
+        # directory, $TMPDIR/ray_tpu_session_*); state.load_timeline(DIR)
+        # is the same list in Python
     python -m ray_tpu.cli soak --profile smoke|bench|full
     python -m ray_tpu.cli stack --address ... [--profile N]
     python -m ray_tpu.cli prof top --address ... [--task F] [--seconds N]
@@ -315,12 +319,33 @@ def cmd_audit(args) -> int:
 
 
 def cmd_timeline(args) -> int:
-    _connect(args.address)
     from ray_tpu import state
     fmt = getattr(args, "format", "events")
-    trace = state.timeline(args.out, native=args.native, fmt=fmt)
+    if args.session:
+        # A session that ended: what `ray_tpu.shutdown()` left in its
+        # directory, the list `state.timeline()` gave at that instant.
+        trace = state.load_timeline(args.session)
+        if trace is None:
+            print(f"no {state.TIMELINE_FILE} under {args.session}",
+                  file=sys.stderr)
+            return 1
+        if not args.native:
+            trace = [ev for ev in trace if ev.get("cat") == "task"]
+        state.write_trace(args.out, trace, fmt)
+    elif args.address:
+        _connect(args.address)
+        trace = state.timeline(args.out, native=args.native, fmt=fmt)
+    else:
+        print("timeline: give --address (a live cluster) or --session "
+              "(the directory of one that ended)", file=sys.stderr)
+        return 2
     n_native = sum(1 for ev in trace if ev.get("cat") == "native")
     extra = f" ({n_native} native spans)" if args.native else ""
+    lost = next((ev["args"]["dropped"] for ev in trace
+                 if ev.get("name") == "program_spans"
+                 and ev.get("ph") == "M"), 0)
+    if lost:
+        extra += f" ({lost} program spans dropped)"
     shape = " [chrome trace-event format]" if fmt == "chrome" else ""
     print(f"wrote {len(trace)} trace events to {args.out}{extra}{shape}")
     return 0
@@ -736,7 +761,11 @@ def main(argv=None) -> int:
     sp.set_defaults(fn=cmd_logs)
 
     sp = sub.add_parser("timeline")
-    sp.add_argument("--address", required=True)
+    sp.add_argument("--address")
+    sp.add_argument("--session", metavar="DIR",
+                    help="read a session that ended: its directory "
+                         "(ray_tpu.shutdown() leaves timeline.json there) "
+                         "instead of a live cluster")
     sp.add_argument("--out", default="timeline.json")
     sp.add_argument("--native", action="store_true",
                     help="include graftscope native-plane spans "

@@ -21,6 +21,7 @@ Redis-backed persistence is the fault-tolerance extension point).
 from __future__ import annotations
 
 import asyncio
+import itertools
 import logging
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -132,6 +133,15 @@ class Controller:
         # sidecar spans in timeline().
         self.native_spans: "deque" = deque(maxlen=50000)
         self._oid_trace: Dict[int, tuple] = {}
+        # The program's own spans (utils/tracing.span: `cat` "program")
+        # arrive by the same report and keep a ring of their own: a
+        # streamed chunk leaves native spans by the dozen and would push
+        # a run's few thousand program spans out of a shared one. What
+        # this ring, or a process on the way to it, had to let go is
+        # counted, with the latest `mono_ns` among it: a reader of the
+        # record takes nothing from before that instant.
+        self.program_spans: "deque" = deque(maxlen=50000)
+        self._program_lost = [0, 0]   # count, latest mono_ns among them
         # graftpulse: per-node pulse time series + cluster SLO aggregates
         # (keyed by node_id.hex()[:12], same as node_metrics). The health
         # FSM in _health_loop reads pulse cadence from here; the
@@ -789,12 +799,29 @@ class Controller:
         snap["enabled"] = True
         return snap
 
-    async def report_native_spans(self, spans: list) -> None:
+    async def report_native_spans(self, spans: list,
+                                  lost: Optional[list] = None) -> None:
         """graftscope spans from worker flushers / agent metric ticks.
         Put-side spans teach us oid64 -> trace context; sidecar-side
         spans for the same object arrive context-free from the agent
-        and get parented at timeline() time."""
+        and get parented at timeline() time. `lost`: [count, latest
+        mono_ns] of the program spans the sender gave up on since its
+        last report."""
         t0 = time.perf_counter_ns()
+        program = [s for s in spans if s.get("cat") == "program"]
+        if program:
+            spans = [s for s in spans if s.get("cat") != "program"]
+            ring = self.program_spans
+            # What the ring lets go to take these: its oldest, then, of a
+            # report wider than the ring, the report's own head.
+            gone = list(itertools.islice(
+                itertools.chain(ring, program),
+                max(0, len(ring) + len(program) - ring.maxlen)))
+            ring.extend(program)
+            self._note_lost(len(gone), *(s["args"].get("mono_ns", 0)
+                                         for s in gone))
+        if lost:
+            self._note_lost(*lost)
         for s in spans:
             oid = s.get("oid64")
             if oid and s.get("trace_id"):
@@ -806,6 +833,24 @@ class Controller:
                 del self._oid_trace[k]
         self.native_spans.extend(spans)
         self._meta_note("scope", len(spans), 64 * len(spans), t0)
+
+    def _note_lost(self, count: int, *ends: int) -> None:
+        if count:
+            self._program_lost = [self._program_lost[0] + count,
+                                  max(self._program_lost[1], *ends)]
+
+    async def flush_spans(self, timeout: float = 2.0) -> None:
+        """Have every live worker ship the spans its 2 s flusher still
+        holds, and wait for them (bounded): what the session's dump asks
+        before it pulls `timeline()`. A node that does not answer in time
+        keeps what it holds."""
+        async def one(node: "NodeEntry") -> None:
+            try:
+                await asyncio.wait_for(
+                    node.client.call("flush_spans", timeout), timeout + 0.5)
+            except Exception:
+                pass  # observability is best-effort
+        await asyncio.gather(*(one(n) for n in self._alive_nodes()))
 
     async def native_latency(self) -> list:
         """Hot-path latency rollup over the retained native spans, for
@@ -857,7 +902,7 @@ class Controller:
                 })
         if not native:
             return trace
-        for s in self.native_spans:
+        for s in itertools.chain(self.native_spans, self.program_spans):
             trace_id = s.get("trace_id", "")
             parent = s.get("parent_span", "")
             if not trace_id and s.get("oid64"):
@@ -880,6 +925,14 @@ class Controller:
                 "ph": "X", "ts": s["ts"], "dur": s.get("dur", 0.0),
                 "pid": pid, "tid": tid, "args": args,
             })
+        # What the record lacks, as a metadata event of the same list.
+        trace.append({
+            "name": "program_spans", "cat": "meta", "ph": "M",
+            "pid": "controller", "tid": "timeline",
+            "args": {"kept": len(self.program_spans),
+                     "dropped": self._program_lost[0],
+                     "dropped_until_mono_ns": self._program_lost[1]},
+        })
         return trace
 
     # ------------------------------------------------------------------

@@ -304,6 +304,7 @@ class CoreWorker:
         # flushed to the controller on the task-event flusher tick.
         self._scope = None
         self._scope_spans: list = []
+        self._scope_lost = [0, 0]   # program spans given up: count, mono_ns
         # graftpulse pre-aggregation: the cumulative scope block as of
         # the last report_scope_delta flush (counters, hists).
         self._scope_sent: tuple = ({}, {})
@@ -756,14 +757,34 @@ class CoreWorker:
         them (plus Python-timed put spans buffered by user threads) to
         the controller. Rides the 2s task-event flusher tick so the hot
         paths never touch span assembly."""
+        spans = self._take_spans()
+        if spans:
+            self._spawn(self._send_native_spans(spans))
+
+    async def flush_spans(self) -> int:
+        """What the flusher would ship at its next tick, shipped now and
+        waited for: asked of a process that is about to be stopped, and
+        of every process before the session's timeline is written
+        (`api.shutdown`)."""
+        spans = self._take_spans()
+        if spans:
+            await self._send_native_spans(spans)
+        return len(spans)
+
+    def _take_spans(self) -> list:
         from ray_tpu.core._native import graftscope
         asm = self._scope_asm()
         if asm is None:
-            return
+            return []
         spans = asm.feed(graftscope.drain_records())
         if self._scope_spans:
             buf, self._scope_spans = self._scope_spans, []
             spans.extend(buf)
+        if len(spans) > 5000:
+            # Bound the batch: a controller outage must not turn the
+            # span buffer into a leak.
+            self._lost_spans(spans[:-5000])
+            spans = spans[-5000:]
         # Worker-process counters (rpc send/flush, copy) fold into this
         # process's metrics registry on the same tick. The node pulse
         # needs the client-side op deltas too, but the agent's tick must
@@ -779,16 +800,29 @@ class CoreWorker:
                                              graftscope.histograms())
             if deltas:
                 self._spawn(self._send_scope_delta(deltas))
-        if spans:
-            # Bound the batch: a controller outage must not turn the
-            # span buffer into a leak.
-            self._spawn(self._send_native_spans(spans[-5000:]))
+        return spans
+
+    def _lost_spans(self, spans: list) -> None:
+        """Program spans this process gave up on (its buffer's bound, a
+        report the controller did not take): the count and the latest
+        `mono_ns` among them go with the next report that arrives, so
+        that the record says what it lacks."""
+        ends = [s["args"].get("mono_ns", 0) for s in spans
+                if s.get("cat") == "program"]
+        self._note_lost(len(ends), *ends)
+
+    def _note_lost(self, count: int, *ends: int) -> None:
+        if count:
+            self._scope_lost = [self._scope_lost[0] + count,
+                                max(self._scope_lost[1], *ends)]
 
     async def _send_native_spans(self, spans: list) -> None:
+        lost, self._scope_lost = self._scope_lost, [0, 0]
         try:
-            await self.controller.call("report_native_spans", spans)
-        except Exception:
-            pass  # observability is best-effort
+            await self.controller.call("report_native_spans", spans, lost)
+        except Exception:   # observability is best-effort
+            self._note_lost(*lost)
+            self._lost_spans(spans)
 
     def _diff_scope_blocks(self, counters: dict, hists: dict) -> dict:
         """Sparse per-kind delta of this process's cumulative scope

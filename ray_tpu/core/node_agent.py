@@ -1813,8 +1813,24 @@ class NodeAgent:
             if w.dedicated_actor == actor_id:
                 w.dedicated_actor = None  # suppress death report (intended)
                 await self._release_actor_allocation(actor_id)
+                # SIGTERM takes the spans of its last 2 s with it.
+                await self._flush_worker_spans(w, 1.0)
                 self._stop_worker(w)
                 return
+
+    async def _flush_worker_spans(self, w: WorkerProc, timeout: float) -> None:
+        if w.client is None or w.proc.poll() is not None:
+            return
+        try:
+            await asyncio.wait_for(w.client.call("flush_spans"), timeout)
+        except Exception:
+            pass  # observability is best-effort
+
+    async def flush_spans(self, timeout: float = 2.0) -> None:
+        """Every live worker's buffered spans to the controller, now
+        (`Controller.flush_spans`)."""
+        await asyncio.gather(*(self._flush_worker_spans(w, timeout)
+                               for w in list(self.workers.values())))
 
     # ------------------------------------------------------------------
     # object store control plane (local workers call these)

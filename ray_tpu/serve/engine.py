@@ -741,8 +741,8 @@ class Engine:
                     kind="adopt" if adopting else "prefill",
                     prompt_tokens=len(req.ids), bucket=bucket,
                     queue_wait_us=int(waited * 1e6), pending=left,
-                    pages_free=self.pool.free - need, chunks_ahead=ahead,
-                    decoding=decoding, slot_idle_us=int(slot_idle * 1e6),
+                    chunks_ahead=ahead, decoding=decoding,
+                    slot_idle_us=int(slot_idle * 1e6),
                     **({} if riders is None else {"riders": len(riders)})):
                 emits.append(self._place(req, slot, need, bucket, riders))
         # Start EVERY device->host copy first (async), THEN enqueue: a

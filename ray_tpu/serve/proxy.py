@@ -14,6 +14,7 @@ import asyncio
 import json
 import os
 import threading
+import time
 from typing import Any, Dict, Optional
 
 import ray_tpu
@@ -150,13 +151,13 @@ class HttpProxy:
         # behalf, and every span under that, carry its id.
         trace_id = os.urandom(16)
         with tracing.span("serve.proxy.request", ctx=(trace_id, b""),
-                          profiler=False, path=path, stream=stream):
+                          profiler=False, path=path, stream=stream) as sp:
             if stream:
                 # Stream errors terminate the chunked body/connection; a
                 # 500 status after chunks were sent would corrupt the
                 # protocol.
                 await self._stream_response(handle, payload, writer, loop,
-                                            trace_id)
+                                            trace_id, sp)
                 return
 
             def call():
@@ -173,9 +174,12 @@ class HttpProxy:
             await writer.drain()
 
     async def _stream_response(self, handle, payload, writer, loop,
-                               trace_id: bytes) -> None:
+                               trace_id: bytes, sp: dict) -> None:
         """Chunked transfer from a streaming deployment method — tokens
-        flow as the replica yields (TTFT = first chunk)."""
+        flow as the replica yields (TTFT = first chunk). `sp`: the
+        request's span, told when the first item was written
+        (`first_chunk_us`, from the span's start)."""
+        t_open = time.monotonic_ns()
         writer.write(b"HTTP/1.1 200 OK\r\n"
                      b"Content-Type: text/plain\r\n"
                      b"Transfer-Encoding: chunked\r\n\r\n")
@@ -247,6 +251,9 @@ class HttpProxy:
                 writer.write(f"{len(chunk):x}\r\n".encode() + chunk
                              + b"\r\n")
                 await writer.drain()
+                if "first_chunk_us" not in sp and kind == "item":
+                    sp["first_chunk_us"] = \
+                        (time.monotonic_ns() - t_open) // 1000
             writer.write(b"0\r\n\r\n")
             await writer.drain()
         except (ConnectionResetError, BrokenPipeError):

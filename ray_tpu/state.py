@@ -166,8 +166,13 @@ def native_latency() -> List[dict]:
     return _ctl("native_latency")
 
 
+TIMELINE_FILE = "timeline.json"      # in a session's directory
+_last_session_dir: Optional[str] = None   # the last this process shut down
+
+
 def timeline(filename: Optional[str] = None,
-             native: bool = True, fmt: str = "events") -> List[dict]:
+             native: bool = True, fmt: str = "events",
+             timeout: float = 30.0) -> List[dict]:
     """Chrome-trace events for every recorded task — plus, with
     ``native`` (default), the graftscope native-plane spans (dispatch,
     wire, sidecar service, copy) nested under the submitting task. Pass
@@ -179,15 +184,42 @@ def timeline(filename: Optional[str] = None,
     ({"traceEvents": [...]} with integer pid/tid plus process_name/
     thread_name metadata) instead of the raw event array — the shape
     Perfetto's UI ingests directly. The returned value is always the
-    raw event list."""
-    trace = _ctl("timeline", native)
+    raw event list.
+
+    With ``native`` the list ends in one metadata event (`ph` "M", name
+    `program_spans`): how many of the program's own spans (`cat`
+    "program", utils/tracing.span) the record holds, how many it had to
+    let go, and the latest `mono_ns` among those."""
+    trace = _ctl("timeline", native, timeout=timeout)
     if filename:
-        payload = to_chrome_trace(trace) if fmt == "chrome" else trace
-        tmp = filename + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(payload, f)
-        os.replace(tmp, filename)
+        write_trace(filename, trace, fmt)
     return trace
+
+
+def write_trace(filename: str, trace: List[dict],
+                fmt: str = "events") -> None:
+    """`timeline()`'s dump: the event list, or with fmt="chrome" its
+    Chrome trace-event form, through a tmp file and a rename."""
+    payload = to_chrome_trace(trace) if fmt == "chrome" else trace
+    tmp = filename + ".tmp"
+    with open(tmp, "w") as f:
+        f.write(json.dumps(payload))   # one C call; json.dump is not
+    os.replace(tmp, filename)
+
+
+def load_timeline(path: Optional[str] = None) -> Optional[List[dict]]:
+    """The event list `timeline()` gave when a session ended, with no
+    cluster: `ray_tpu.shutdown()` writes it to `<session_dir>/timeline.json`
+    before it stops the node. `path` is a session directory or the file;
+    None is the last session this process shut down. None where there is
+    no such file (the controller was gone before the session was)."""
+    path = path or _last_session_dir
+    if path and os.path.isdir(path):
+        path = os.path.join(path, TIMELINE_FILE)
+    if not path or not os.path.exists(path):
+        return None
+    with open(path) as f:
+        return json.load(f)
 
 
 def to_chrome_trace(events: List[dict]) -> dict:

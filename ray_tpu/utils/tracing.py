@@ -13,6 +13,15 @@ A span has two sinks and no buffer of its own:
   `state.timeline()` / `ray_tpu timeline` nests it under the task whose
   context it carries. A thread that acts for a task submitted elsewhere (the
   engine loop for a replica call) passes that task's `context()` as `ctx`.
+  This sink is on in every run, in every process, from its start, and every
+  span on it carries `mono_ns` (CLOCK_MONOTONIC at its end; its start is
+  that less `dur`). It ends in a file: `ray_tpu.shutdown()` has every
+  process ship what it still holds (an actor that is killed ships first),
+  pulls the controller's timeline and writes `<session_dir>/timeline.json`,
+  which `state.load_timeline()` and `ray_tpu timeline --session` read when
+  the cluster is gone. The controller keeps these spans (`cat` "program")
+  in a ring of their own; what a ring or a process had to let go is counted
+  in the list's last event (`program_spans`).
 
 What the profiler records is fixed when the span opens; the dict the `with`
 yields is the timeline's copy, and keys set on it inside the body reach the
@@ -102,6 +111,7 @@ def span(name: str, ctx: Optional[Context] = None, profiler: bool = True,
             buf = worker._scope_spans
             buf.append(s)
             if len(buf) > _MAX_BUFFERED:
+                worker._lost_spans(buf[:len(buf) - _MAX_BUFFERED])
                 del buf[:len(buf) - _MAX_BUFFERED]
 
 

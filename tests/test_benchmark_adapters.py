@@ -35,6 +35,13 @@ OLMOE_PUBLISHED = dict(
     rms_norm_eps=1e-05, rope_scaling=None, rope_theta=10000,
     tie_word_embeddings=False, vocab_size=50304)
 
+# PR 51's readers over the session's timeline that a closed-loop serve cell
+# lists: the set-up's four and the window's three.
+TIMELINE_READERS_OF_A_BATCH_CELL = {
+    "setup_boot_s", "setup_warm_s", "setup_compile_s", "setup_check_s",
+    "decode_occupancy_window_pct", "engine_slot_refill_window_ms",
+    "engine_window_tokens_per_s"}
+
 
 @pytest.mark.parametrize("arch", ARCHS)
 def test_adapter_exposes_the_whole_contract(arch):
@@ -515,7 +522,7 @@ def test_jamba_manifest_entries_are_the_catalogs_row_uncut():
         "prefill_ssm_ms_per_ktok", "scan_roofline_pct", "decode_ssm_ms",
         "decode_state_roofline_pct",
         "engine_slot_refill_ms", "prefill_stall_pct",        # PR 37
-        "decode_sample_ms"}                                  # PR 47
+        "decode_sample_ms"} | TIMELINE_READERS_OF_A_BATCH_CELL  # PRs 47, 51
     # not `decode_attn_roofline_pct`: its bytes multiply by ALL the layers
 
 
@@ -1179,7 +1186,8 @@ def test_granite_manifest_entries_are_the_catalogs_row_and_the_issues_cell():
         "scheduler (serve)", "batch_tokens_per_s", "lower",
         "program_counter")
     mine = [n for n, cells in lists.items() if GRANITE_CELL in cells]
-    assert set(mine) == set(GRANITE_SERVED) | {"decode_state_share_pct"}
+    assert set(mine) == set(GRANITE_SERVED) | {"decode_state_share_pct"} \
+        | TIMELINE_READERS_OF_A_BATCH_CELL
     assert not [n for n in GRANITE_SILENT if GRANITE_CELL in lists[n]]
     # no reader of another stack's vocabulary, and no whole-model share
     for name in ("moe_experts_roofline_pct", "hybrid_experts_roofline_pct",

@@ -199,6 +199,7 @@ _SPAN_CALL = re.compile(
     r"tracing\.(?:span|compile_span)\(\s*\"([^\"]+)\"")
 _READ_CALL = re.compile(r"\b(?:named|per_step_ms)\(")
 _SPAN_NAME = re.compile(r"\"((?:serve|train|data)\.[a-z_.]+)\"")
+_ARG_READ = re.compile(r"args(?:\.get\(|\[)\"([a-z_]+)\"")
 
 
 def _emitted():
@@ -210,10 +211,36 @@ def _emitted():
     return sorted(names)
 
 
+def _timeline_readers():
+    """benchmark/timeline_record.py and the readers over it (PR 51)."""
+    shared = os.path.join(ROOT, "benchmark", "timeline_record.py")
+    files = [shared]
+    for path in glob.glob(os.path.join(ROOT, "benchmark", "layer_metrics",
+                                       "*.py")):
+        with open(path) as f:
+            if "timeline_record" in f.read():
+                files.append(path)
+    return files
+
+
+def _timeline_reads():
+    """(span names, span arguments) that `timeline_record.py` and its readers
+    name as literals: its constants (`ADMIT = "serve.engine.admit"`) and
+    every `args["x"]` / `args.get("x"`."""
+    names, args = set(), set()
+    for path in _timeline_readers():
+        with open(path) as f:
+            text = f.read()
+        names.update(_SPAN_NAME.findall(text))
+        args.update(_ARG_READ.findall(text))
+    return sorted(names), sorted(args)
+
+
 def _read():
     """Span names in the arguments of every `named(` / `per_step_ms(` call of
-    the readers and of the modules they share, and `engine_trace`'s states."""
-    names = set(engine_trace.STATES)
+    the readers and of the modules they share, `engine_trace`'s states, and
+    the names `timeline_record.py` holds as constants."""
+    names = set(engine_trace.STATES) | set(_timeline_reads()[0])
     for path in glob.glob(os.path.join(ROOT, "benchmark", "layer_metrics",
                                        "*.py")) + \
             glob.glob(os.path.join(ROOT, "benchmark", "*.py")):
@@ -250,12 +277,38 @@ def test_span_names_are_one_vocabulary(kind, name):
         assert name in _emitted(), f"a reader asks for {name}: nothing opens it"
 
 
+@pytest.mark.parametrize("arg", _timeline_reads()[1])
+def test_span_arguments_the_timeline_readers_read_are_set_under_ray_tpu(arg):
+    """An argument `timeline_record.py` or a reader over it takes from a span
+    is one the program sets, as a keyword (`queue_wait_us=`) or as a key
+    (`"first_chunk_us"`), in a file that opens spans or on the span's way to
+    the timeline, and PERF.md section 3's paragraph names it."""
+    texts = []
+    for path in glob.glob(os.path.join(ROOT, "ray_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            text = f.read()
+        if "tracing." in text or path.endswith(("tracing.py",
+                                                "controller.py")):
+            texts.append(text)
+    assert any(re.search(rf"\b{arg}=|\"{arg}\"", t) for t in texts), \
+        f"a timeline reader takes `{arg}` from a span: nothing sets it"
+    assert f"`{arg}`" in _perf_md_names(), \
+        f"PERF.md section 3 does not name the span argument `{arg}`"
+
+
 def test_the_guard_sees_the_names_it_is_for():
     assert {"serve.engine.idle", "serve.engine.admit", "data.iter.next_ref",
             "train.compile", "serve.engine.warm"} <= set(_emitted())
     assert {"serve.engine.admit", "serve.engine.decode_dispatch",
             "data.iter.next_ref", "data.iter.format", "train.step",
-            "serve.engine.emit_block", "serve.engine.idle"} <= set(_read())
+            "serve.engine.emit_block", "serve.engine.idle",
+            "serve.proxy.request", "serve.replica.call", "serve.engine.warm",
+            "train.compile"} <= set(_read())
+    assert len(_timeline_readers()) == 12       # the module and its eleven
+    assert {"queue_wait_us", "slot_idle_us", "first_chunk_us", "compile_s",
+            "useful", "capacity", "riders", "prompt_tokens", "trace_id",
+            "rid", "kind"} <= set(_timeline_reads()[1])
 
 
 # -- the layering: serve/ -> models/ -> ops/, and a scheduler that builds no
