@@ -286,34 +286,48 @@ class Dataset:
 
     def iter_batches(self, *, batch_size: Optional[int] = None,
                      batch_format: str = "numpy", prefetch_blocks: int = 2,
-                     drop_last: bool = False) -> Iterator[Any]:
+                     drop_last: bool = False,
+                     prefetch_batches: int = 1) -> Iterator[Any]:
+        """The next `prefetch_batches` batches are made on a thread while
+        the caller works on this one (0: inline); `prefetch_blocks` only
+        holds references (data/iterator.py)."""
         return iter_batches_from_refs(
             self.iter_block_refs(), batch_size=batch_size,
             batch_format=batch_format, prefetch_blocks=prefetch_blocks,
-            drop_last=drop_last)
+            drop_last=drop_last, prefetch_batches=prefetch_batches)
+
+    def _iter_row_batches(self) -> Iterator[List[Any]]:
+        """For the consumers below, which do no work between batches or stop
+        early: inline, so that no thread is left to reap."""
+        return self.iter_batches(batch_format="rows", prefetch_batches=0)
 
     def iter_rows(self) -> Iterator[Any]:
-        for batch in self.iter_batches(batch_format="rows"):
+        for batch in self._iter_row_batches():
             yield from batch
 
     def iter_jax_batches(self, *, batch_size: Optional[int] = None,
                          sharding: Optional[Any] = None,
                          global_batch: bool = False,
                          prefetch_blocks: int = 2,
-                         drop_last: bool = True) -> Iterator[Dict[str, Any]]:
+                         drop_last: bool = True,
+                         prefetch_batches: int = 1
+                         ) -> Iterator[Dict[str, Any]]:
         """Batches as jax.Arrays — the north-star ingest hop (host path is
-        zero-copy out of the shm store; device transfer is the only copy)."""
+        zero-copy out of the shm store; device transfer is the only copy).
+        The next `prefetch_batches` are fetched, cut and placed on the device
+        while the caller's step runs: that many + 1 more batches of HBM."""
         return iter_jax_batches_from_refs(
             self.iter_block_refs(), batch_size=batch_size,
             sharding=sharding, global_batch=global_batch,
-            prefetch_blocks=prefetch_blocks, drop_last=drop_last)
+            prefetch_blocks=prefetch_blocks, drop_last=drop_last,
+            prefetch_batches=prefetch_batches)
 
     # ------------------------------------------------------------------
     # consumption helpers
     # ------------------------------------------------------------------
     def take(self, k: int = 20) -> List[Any]:
         out: List[Any] = []
-        for batch in self.iter_batches(batch_format="rows"):
+        for batch in self._iter_row_batches():
             out.extend(batch)
             if len(out) >= k:
                 return out[:k]
@@ -321,7 +335,7 @@ class Dataset:
 
     def take_all(self) -> List[Any]:
         out: List[Any] = []
-        for batch in self.iter_batches(batch_format="rows"):
+        for batch in self._iter_row_batches():
             out.extend(batch)
         return out
 
@@ -594,21 +608,24 @@ class DataIterator:
 
     def iter_batches(self, *, batch_size: Optional[int] = None,
                      batch_format: str = "numpy", prefetch_blocks: int = 2,
-                     drop_last: bool = False) -> Iterator[Any]:
+                     drop_last: bool = False,
+                     prefetch_batches: int = 1) -> Iterator[Any]:
         return iter_batches_from_refs(
             self._factory(), batch_size=batch_size,
             batch_format=batch_format, prefetch_blocks=prefetch_blocks,
-            drop_last=drop_last)
+            drop_last=drop_last, prefetch_batches=prefetch_batches)
 
     def iter_jax_batches(self, *, batch_size: Optional[int] = None,
                          sharding: Optional[Any] = None,
                          global_batch: bool = False,
                          prefetch_blocks: int = 2,
-                         drop_last: bool = True) -> Iterator[Dict[str, Any]]:
+                         drop_last: bool = True,
+                         prefetch_batches: int = 1
+                         ) -> Iterator[Dict[str, Any]]:
         return iter_jax_batches_from_refs(
             self._factory(), batch_size=batch_size, sharding=sharding,
             global_batch=global_batch, prefetch_blocks=prefetch_blocks,
-            drop_last=drop_last)
+            drop_last=drop_last, prefetch_batches=prefetch_batches)
 
     def __repr__(self):
         return f"DataIterator({self._name})"

@@ -11,8 +11,10 @@ A span has two sinks and no buffer of its own:
 * `core_worker._scope_spans`, when this process has a core worker whose
   graftscope assembler is on: the 2 s flusher ships it to the controller and
   `state.timeline()` / `ray_tpu timeline` nests it under the task whose
-  context it carries. A thread that acts for a task submitted elsewhere (the
-  engine loop for a replica call) passes that task's `context()` as `ctx`.
+  context it carries. A thread that acts for tasks submitted elsewhere (the
+  engine loop for a replica call) passes that task's `context()` as `ctx`;
+  one that works for ONE task all its life (the Data iterator's producer)
+  runs `under(ctx)`, and the tasks it submits carry the context too.
   This sink is on in every run, in every process, from its start, and every
   span on it carries `mono_ns` (CLOCK_MONOTONIC at its end; its start is
   that less `dur`). It ends in a file: `ray_tpu.shutdown()` has every
@@ -59,16 +61,27 @@ def _worker():
 
 
 @contextlib.contextmanager
-def root(trace_id: bytes) -> Iterator[None]:
-    """Make this thread the root of a trace, as a task's exec thread is:
-    tasks submitted and spans opened inside carry `trace_id`."""
+def under(ctx: Optional[Context]) -> Iterator[None]:
+    """Make `ctx` (another thread's `context()`) this thread's, as a task's
+    exec thread has its task's: tasks submitted and spans opened inside carry
+    it. For a thread that works for that task all its life; None (no task, no
+    runtime) changes nothing."""
+    if ctx is None:
+        yield
+        return
     from ray_tpu.core import core_worker as cw
     before = getattr(cw._trace_local, "ctx", None)
-    cw._trace_local.ctx = (trace_id, b"")
+    cw._trace_local.ctx = ctx
     try:
         yield
     finally:
         cw._trace_local.ctx = before
+
+
+def root(trace_id: bytes):
+    """Make this thread the root of a trace: `under` a context with no
+    parent span."""
+    return under((trace_id, b""))
 
 
 def recording() -> bool:

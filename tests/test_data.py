@@ -7,6 +7,8 @@ test_streaming_executor.py backpressure) at this framework's scale.
 """
 
 import os
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -14,6 +16,8 @@ import pytest
 import ray_tpu
 import ray_tpu.data as rdata
 from ray_tpu.core.cluster_utils import Cluster
+from ray_tpu.data.iterator import (PRODUCER_THREAD, iter_batches_from_refs,
+                                   iter_jax_batches_from_refs)
 
 
 @pytest.fixture(scope="module")
@@ -323,3 +327,220 @@ def test_dataset_stats_exposes_operator_metrics(cluster):
     ops = ds.stats()["operators"]
     assert ops["read->map"]["tasks_launched"] == 4
     assert ops["read->map"]["blocks_out"] == 4
+
+
+# -- the next batch is made ahead (data/iterator.py::_ahead) -----------------
+# This module is a slow one (conftest._SLOW_MODULES); these are light and are
+# marked `fast`, so tier-1 runs them.
+
+
+def _producers():
+    return {t for t in threading.enumerate()
+            if t.name == PRODUCER_THREAD and t.is_alive()}
+
+
+def _until(cond, seconds=10.0):
+    deadline = time.monotonic() + seconds
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
+class _Source:
+    """Block references handed out one at a time, counted; `fail_at` raises
+    where the n-th would have been."""
+
+    def __init__(self, n_blocks, rows=4, fail_at=None):
+        self.refs = [ray_tpu.put({"id": np.arange(i * rows, (i + 1) * rows)})
+                     for i in range(n_blocks)]
+        self.asked, self.fail_at = 0, fail_at
+
+    def __iter__(self):
+        for i, ref in enumerate(self.refs):
+            self.asked += 1
+            if i == self.fail_at:
+                raise RuntimeError(f"block {i} is lost")
+            yield ref
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("api", ["iter_batches", "iter_jax_batches"])
+@pytest.mark.parametrize("rows_a_block", [8, 9],
+                         ids=["blocks_divide", "blocks_do_not_divide"])
+@pytest.mark.parametrize("drop_last", [False, True])
+@pytest.mark.parametrize("prefetch_batches", [0, 1, 3])
+def test_batches_made_ahead_are_the_inline_paths_in_its_order(
+        cluster, prefetch_batches, drop_last, rows_a_block, api):
+    ds = rdata.range(5 * rows_a_block, num_blocks=5)
+    want = [np.arange(s, min(s + 4, 5 * rows_a_block))
+            for s in range(0, 5 * rows_a_block, 4)]
+    if drop_last:
+        want = [w for w in want if len(w) == 4]
+    before = _producers()
+    inline, got = ([b["id"] for b in getattr(ds, api)(
+        batch_size=4, drop_last=drop_last, prefetch_batches=n)]
+        for n in (0, prefetch_batches))
+    assert len(got) == len(inline) == len(want)
+    for g, i, w in zip(got, inline, want):
+        assert type(g) is type(i) and g.dtype == i.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(i))
+        np.testing.assert_array_equal(np.asarray(i), w)
+    if api == "iter_jax_batches":
+        import jax
+        assert all(isinstance(g, jax.Array) for g in got)
+    assert _until(lambda: _producers() <= before)    # the end ends the thread
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("prefetch_batches", [1, 3])
+def test_the_producer_is_at_most_prefetch_batches_plus_one_ahead(
+        cluster, prefetch_batches):
+    """A block a batch and no reference held, so the source's count is the
+    batches made: the one the consumer took, the queue's, and the one the
+    producer holds while it waits for room."""
+    src = _Source(12)
+    it = iter_batches_from_refs(iter(src), batch_size=4, prefetch_blocks=0,
+                                prefetch_batches=prefetch_batches)
+    assert src.asked == 0 and isinstance(next(it)["id"], np.ndarray)
+    assert _until(lambda: src.asked == 1 + prefetch_batches + 1)
+    time.sleep(0.2)
+    assert src.asked == 1 + prefetch_batches + 1
+    next(it)
+    assert _until(lambda: src.asked == 2 + prefetch_batches + 1)
+    time.sleep(0.2)
+    assert src.asked == 2 + prefetch_batches + 1
+    assert [int(b["id"][0]) for b in it] == list(range(8, 48, 4))
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("jax_batches", [False, True])
+def test_a_sources_exception_reaches_the_consumer_where_it_happened(
+        cluster, jax_batches):
+    src = _Source(6, fail_at=3)
+    before = _producers()
+    it = (iter_jax_batches_from_refs if jax_batches
+          else iter_batches_from_refs)(iter(src), batch_size=4,
+                                       prefetch_blocks=0, prefetch_batches=2)
+    got = []
+    with pytest.raises(RuntimeError, match="block 3 is lost"):
+        for b in it:
+            got.append(int(b["id"][0]))
+    assert got == [0, 4, 8] and src.asked == 4
+    assert _until(lambda: _producers() <= before)
+    assert list(it) == []                    # and the iterator is at its end
+
+    # A `get` that fails (the block's task raised) arrives the same way.
+    @ray_tpu.remote
+    def lost():
+        raise ValueError("no such block")
+
+    refs = [ray_tpu.put({"id": np.arange(4)}), lost.remote()]
+    it = iter_batches_from_refs(iter(refs), batch_size=4, prefetch_batches=1)
+    assert list(next(it)["id"]) == [0, 1, 2, 3]
+    with pytest.raises(Exception, match="no such block"):
+        next(it)
+    assert _until(lambda: _producers() <= before)
+
+
+@pytest.mark.fast
+@pytest.mark.parametrize("how", ["close", "del", "break"])
+def test_an_iterator_that_is_let_go_stops_its_thread_and_asks_no_more(
+        cluster, how):
+    src = _Source(12)
+    before = _producers()
+    it = iter_batches_from_refs(iter(src), batch_size=4, prefetch_blocks=0,
+                                prefetch_batches=1)
+    if how == "break":
+        for _ in it:
+            break
+    else:
+        next(it)
+    (mine,) = _producers() - before
+    assert _until(lambda: src.asked == 3)    # taken, queued, held: it waits
+    if how == "close":
+        it.close()
+    else:
+        del it
+    assert _until(lambda: not mine.is_alive(), seconds=1.0)
+    time.sleep(0.1)
+    assert src.asked == 3
+
+
+@pytest.mark.fast
+def test_an_iterator_never_started_starts_no_thread(cluster):
+    src = _Source(3)
+    before = _producers()
+    it = iter_batches_from_refs(iter(src), batch_size=4, prefetch_batches=1)
+    time.sleep(0.05)
+    assert src.asked == 0 and _producers() <= before
+    del it
+    # The row-at-a-time consumers take the inline path: no thread to reap.
+    ds = rdata.range(40, num_blocks=4)
+    assert [r["id"] for r in ds.take(3)] == [0, 1, 2]
+    assert next(iter(ds.iter_rows()))["id"] == 0
+    assert len(ds.take_all()) == 40
+    assert _producers() <= before
+
+
+@pytest.mark.fast
+def test_streaming_split_equal_pair_sees_equal_batches_made_ahead(cluster):
+    """Each consumer of `equal=True` has its own queue at the coordinator:
+    one that runs ahead, or quits, takes nothing from the other."""
+    its = rdata.range(96, num_blocks=8).streaming_split(2, equal=True)
+    out = [[], []]
+
+    def consume(i):
+        for b in its[i].iter_jax_batches(batch_size=4, prefetch_batches=2):
+            out[i].append(np.asarray(b["id"]))
+
+    ts = [threading.Thread(target=consume, args=(i,)) for i in range(2)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join(timeout=120)
+    assert len(out[0]) == len(out[1]) == 12
+    assert sorted(int(x) for o in out for b in o for x in b) == list(range(96))
+
+    # One consumer quits after a batch; the other still gets its whole half.
+    its = rdata.range(96, num_blocks=8).streaming_split(2, equal=True)
+    before = _producers()
+    quitter = its[0].iter_batches(batch_size=4)
+    next(quitter)
+    quitter.close()
+    assert sum(len(b["id"]) for b in its[1].iter_batches(batch_size=4)) == 48
+    assert _until(lambda: _producers() <= before)
+
+
+@pytest.mark.fast
+def test_many_iterators_let_go_at_any_point_leave_no_thread(cluster):
+    """More consumers than cores under a short switch interval, each let go
+    after a batch count of its own: every producer ends, none made more than
+    the batches taken, the queue's, the one in its hands and the one a close
+    may race, and what was taken is the source's head in order."""
+    import sys
+    before = _producers()
+    srcs = [_Source(10) for _ in range(24)]
+    heads = [None] * len(srcs)
+
+    def consume(i):
+        it = iter_batches_from_refs(iter(srcs[i]), batch_size=4,
+                                    prefetch_blocks=0, prefetch_batches=2)
+        heads[i] = [int(next(it)["id"][0]) for _ in range(i % 7)]
+        it.close()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ts = [threading.Thread(target=consume, args=(i,))
+              for i in range(len(srcs))]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in ts)
+        assert _until(lambda: _producers() <= before, seconds=5.0)
+    finally:
+        sys.setswitchinterval(interval)
+    for i, src in enumerate(srcs):
+        assert heads[i] == [4 * k for k in range(i % 7)]
+        assert src.asked <= i % 7 + 2 + 1 + 1

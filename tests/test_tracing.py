@@ -258,12 +258,85 @@ def test_iter_jax_batches_same_arrays_and_its_spans_a_block(
         np.testing.assert_array_equal(np.asarray(batch["tokens"]),
                                       block["tokens"])
     names = [n for n, _, _, _ in prof.events("data.iter.")]
-    assert sorted(names) == sorted(
-        ["data.iter.get_block", "data.iter.format",
-         "data.iter.device_put"] * 3
-        + ["data.iter.next_ref"] * 4)      # the last finds the source dry
+    made = (["data.iter.get_block", "data.iter.format",
+             "data.iter.device_put"] * 3
+            + ["data.iter.next_ref"] * 4)   # the last finds the source dry
+    # The default makes the next batch ahead: the consumer's four takes (the
+    # last is told of the end) beside the producer's spans.
+    assert sorted(names) == sorted(made + ["data.iter.take"] * 4)
     puts = [s for n, _, _, s in prof.events("data.iter.device_put")]
     assert all(p["rows"] == 4 and p["bytes"] == 4 * 8 * 4 for p in puts)
+    assert all(s["ready"] in (0, 1)
+               for _, _, _, s in prof.events("data.iter.take"))
+    # Inline, the parent's spans exactly.
+    with _Profiled(tmp_path / "inline") as prof:
+        again = list(iter_jax_batches_from_refs(iter(refs), batch_size=4,
+                                                prefetch_batches=0))
+    for batch, block in zip(again, blocks):
+        np.testing.assert_array_equal(np.asarray(batch["tokens"]),
+                                      block["tokens"])
+    assert sorted(n for n, _, _, _ in prof.events("data.iter.")) \
+        == sorted(made)
+
+
+class _Sink:
+    """What `tracing.span` asks of a core worker, with the timeline's buffer
+    kept here (the worker's own is shipped away every 2 s)."""
+
+    def __init__(self):
+        from ray_tpu.core._native import graftscope
+        self._asm, self._scope_spans = graftscope.SpanAssembler("test"), []
+
+    def _scope_asm(self):
+        return self._asm
+
+    def _lost_spans(self, spans):
+        raise AssertionError("the buffer's bound was passed")
+
+
+def test_producers_spans_carry_the_consumers_trace_and_take_says_ready(
+        cluster, monkeypatch):
+    import threading
+
+    import ray_tpu
+    from ray_tpu.data.iterator import (PRODUCER_THREAD,
+                                       iter_jax_batches_from_refs)
+    refs = [ray_tpu.put({"tokens": np.full((4, 8), i, np.int32)})
+            for i in range(3)]
+    sink = _Sink()
+    monkeypatch.setattr(tracing, "_worker", lambda: sink)
+    trace_id = bytes(range(16))
+    with tracing.root(trace_id):
+        it = iter_jax_batches_from_refs(iter(refs), batch_size=4)
+        first = next(it)
+        # Wait until all is made: one batch in the queue, one in the
+        # producer's hands.
+        deadline = time.time() + 10
+        while time.time() < deadline and sum(
+                s["name"] == "data.iter.device_put"
+                for s in sink._scope_spans) < 3:
+            time.sleep(0.005)
+        rest = list(it)
+    assert int(first["tokens"][0, 0]) == 0 and len(rest) == 2
+    spans = list(sink._scope_spans)
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    assert {n: len(v) for n, v in by_name.items()} == {
+        "data.iter.next_ref": 4, "data.iter.get_block": 3,
+        "data.iter.format": 3, "data.iter.device_put": 3,
+        "data.iter.take": 4}
+    me = threading.current_thread().name
+    for s in spans:
+        assert s["trace_id"] == trace_id.hex() and s["cat"] == "program"
+        assert s["tid"] == (me if s["name"] == "data.iter.take"
+                            else PRODUCER_THREAD)
+    takes = by_name["data.iter.take"]
+    assert all(0 <= t["args"]["waited_us"] <= t["dur"] + 1 for t in takes)
+    # The second take's batch lay waiting; the first races the producer's
+    # start, the later ones its `put` of what it held.
+    assert takes[1]["args"]["ready"] == 1
+    assert all(t["args"]["ready"] in (0, 1) for t in takes)
 
 
 def test_span_inside_an_actor_task_is_on_the_timeline_under_its_trace(
