@@ -182,6 +182,18 @@ class LlamaConfig:
     residual_scale: float = 1.0
     logit_scale: float = 1.0
     attn_scale: float = 0.0
+    # Generation by diffusion over blocks (the SDAR family): block_length > 1
+    # => every attention layer's mask lets a position see ALL of its own block
+    # of block_length positions, both ways, and every block before it; a
+    # position predicts its OWN token; and Serve generates a block at a time:
+    # denoise_steps forwards of the block's rows, each committing the most
+    # confident of the rows still masked (embedded as mask_id), then one
+    # forward of the committed block, whose K and V the cache keeps
+    # (`models/serving.py`, the block step). Served by the uniform stack of
+    # plain attention, dense or sparse; training's forward is not built.
+    block_length: int = 1
+    denoise_steps: int = 1
+    mask_id: int = 0
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -254,6 +266,11 @@ class LlamaConfig:
         if self.ssm_state and self.index_topk:
             raise ValueError("a state-space hybrid has plain attention: no "
                              "indexer")
+        if self.block_length > 1:
+            self._check_block()
+        elif self.block_length < 1 or self.denoise_steps != 1:
+            raise ValueError("block_length is 1 (one token a step) or more; "
+                             "denoise_steps come with a block")
         if self.experts_held:
             offset, count = self.experts_held
             if not 0 <= offset < offset + count <= self.n_experts:
@@ -285,6 +302,25 @@ class LlamaConfig:
                              "part of a head, 0 < rotary_dim < head_dim (the "
                              "kernels and the caches take a key in two "
                              "parts)")
+
+    def _check_block(self) -> None:
+        if self.latent or self.mixed or self.conv or self.ssm_state \
+                or self.index_topk or self.mrope_section \
+                or self.tie_embeddings or self.multipliers or not self.rope:
+            raise ValueError("generation by blocks (block_length > 1) is "
+                             "served by the uniform stack of plain attention "
+                             "under RoPE: no latent or mixed attention, "
+                             "state-space or short-convolution layers, "
+                             "indexer, mrope, tied head or multipliers")
+        if not 1 <= self.denoise_steps <= self.block_length:
+            raise ValueError("denoise_steps: 1..block_length forwards commit "
+                             "a block's rows, one at least a forward")
+        if self.max_seq % self.block_length \
+                or 128 % self.block_length:
+            raise ValueError("block_length divides max_seq, and the kernels' "
+                             "tiles and the cache's pages (a divisor of 128)")
+        if not 0 <= self.mask_id < self.vocab_size:
+            raise ValueError("mask_id: a row of the embedding")
 
     def _check_conv(self) -> None:
         if self.latent or self.mixed or self.ssm_state or self.index_topk \
@@ -1131,6 +1167,12 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
             "through Serve only: the training forward's attention paths "
             "(flash under a mesh, ring) take no scale of the model's "
             "(ROADMAP, Reach)")
+    if cfg.block_length > 1:
+        raise NotImplementedError(
+            "generation by blocks (block_length > 1) runs through Serve "
+            "only: the training forward's attention paths are causal, its "
+            "loss is the next token's, and the flash backward kernels have "
+            "no block mask (ROADMAP, Reach)")
     dt = cfg.dtype
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dt)

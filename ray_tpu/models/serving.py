@@ -69,7 +69,16 @@ class Programs(NamedTuple):
     topk, key, slot, last, pos, riders) -> (caches, first, experts[, last,
     pos, tokens])`; `decode(params, caches, bt, last, pos, active, temp, topk,
     keys) -> (caches, last, pos, out, experts)`; `adopt(caches, pages, ks, vs)
-    -> caches`; `poke(last, pos, slot, first, length) -> (last, pos)`."""
+    -> caches`; `poke(last, pos, slot, first, length) -> (last, pos)`.
+
+    A model that generates by BLOCKS (`block` B > 1): the slots' `last` is
+    `[n_slots, B]`, a slot's open block (an id, or -1 for a row still
+    masked); `prefill` keeps the prompt's whole blocks, `B * floor(length /
+    B)` rows, yields NO token, and its `first` is the opening block `[B]`,
+    the prompt's tail then -1s, which `poke` sets beside `pos`, the block's
+    first position; `decode` is `chunk / B` blocks of `block_forwards`
+    forwards each, and `out [n_slots, chunk]` holds every position of those
+    blocks, a slot's first chunk the prompt's tail too."""
     empty: Callable
     prefill: Callable
     decode: Callable
@@ -86,6 +95,10 @@ class Programs(NamedTuple):
     shares: bool
     # caches -> the counters `Engine.counters()` shows of them.
     cache_bytes: Callable[[Caches], Dict[str, int]]
+    # Positions a slot's step yields: 1, a token a forward; B > 1, a block of
+    # B positions in `block_forwards` forwards of B rows a slot (see above).
+    block: int = 1
+    block_forwards: int = 1
 
 
 class _Kind(NamedTuple):
@@ -259,6 +272,8 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
     dt = mcfg.dtype
     sparse = mcfg.n_experts > 0
     indexed = mcfg.index_topk > 0
+    # Generation by blocks: the prompt's mask, and a step of B rows a slot.
+    B = mcfg.block_length
     stats = _routing_stats(mcfg)
     # The model's own scale of q . k, where it publishes one; else nothing is
     # passed and the kernels take head_dim^-1/2.
@@ -300,7 +315,7 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
         return kc, vc, attn
 
     def prefill(lp, x, caches, l, ctx):
-        B, Sq, _ = x.shape
+        nb, Sq, _ = x.shape
         q, k, v, *index = block.attention_inputs(
             lp, x, mcfg,
             (lambda t: norms.apply_rope(t, *ctx["tables"])) if mcfg.rope
@@ -313,11 +328,15 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
                 attn = sparse_attention.sparse_attention(
                     q, k, v, qi.transpose(0, 2, 1, 3), ki[:, 0], w,
                     mcfg.index_topk)
+            elif B > 1:
+                attn = attention.block_flash_attention(
+                    q, attention.repeat_kv(k, H // KVH),
+                    attention.repeat_kv(v, H // KVH), B, **scaled)
             else:
                 attn = attention.flash_attention(
                     q, attention.repeat_kv(k, H // KVH),
                     attention.repeat_kv(v, H // KVH), True, **scaled)
-            attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd)
+            attn = attn.transpose(0, 2, 1, 3).reshape(nb, Sq, H * hd)
         if ctx["riders"]:
             # ONE decode step of the riding slots in the bucket's tail rows:
             # their q, k and v do what a decode step does, and the result
@@ -393,7 +412,39 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
         return x, caches._replace(kc=kc, vc=vc, ic=ic), \
             stats(routed[1], act) if sparse else None
 
-    return _Kind(prefill, decode,
+    def decode_rows(lp, x, caches, l, ctx):
+        """`decode` for a step of B rows a slot, x [ns * B, D], slot s's
+        block in rows s * B..: `ctx["pos"]` [ns] the blocks' first positions
+        (multiples of B). The block's K and V are written over the slot's
+        rows pos..pos + B - 1, then every row of the block attends to
+        positions 0..pos + B - 1, its own block's rows both ways: the mask of
+        a block is its length."""
+        bt, pos, act = ctx["bt"], ctx["pos"], ctx["act"]
+        ns = pos.shape[0]
+        with jax.named_scope("rope"):
+            w = jnp.minimum(pos, S - B)
+            at = (w[:, None] + jnp.arange(B)).reshape(-1)
+            c, s = (t[at][:, None] for t in ctx["tables"])
+        q, k, v = block.attention_inputs(lp, x, mcfg,
+                                         lambda t: _rope_one(t, c, s))
+        kc, vc = paged_kv.write_token(
+            caches.kc, caches.vc, l, bt, w, act,
+            k.reshape(ns, B, KVH, hd), v.reshape(ns, B, KVH, hd))
+        with jax.named_scope("attn"):
+            attn = paged_decode(q.reshape(ns, B, H, hd), kc, vc, l, bt,
+                                jnp.where(act, w + B, 0), **scaled)
+            attn = attn.reshape(ns * B, H * hd)
+        with jax.named_scope("attn_out"):
+            x = x + block.scaled(attn @ lp["wo"].astype(dt), mcfg)
+        # An idle slot's rows are computed like any others and left out of
+        # the count.
+        live = jnp.repeat(act, B)
+        x, routed = block.feed_forward(lp, x, mcfg, live,
+                                       l if sparse else None)
+        return x, caches._replace(kc=kc, vc=vc), \
+            stats(routed[1], live) if sparse else None
+
+    return _Kind(prefill, decode_rows if B > 1 else decode,
                  keeps=("pages", "pages") + ("index",) * indexed, **how)
 
 
@@ -696,9 +747,12 @@ def _stack(mcfg) -> _Stack:
                        "window_cache_bytes": slot_state.state_bytes(c.state)},
             shares=True, tally="zero")
     indexed = mcfg.index_topk > 0
+    # Riders are a decode step of one row a slot, a hand-off a first token:
+    # a stack that generates by blocks has neither.
+    plain = not indexed and mcfg.block_length == 1
     return _Stack(
         {"layers": _attention_kind(
-            mcfg, not indexed, over="scan" if mcfg.n_experts else "slices",
+            mcfg, plain, over="scan" if mcfg.n_experts else "slices",
             rides=("tables", "live", "itables"))},
         uniform_tables,
         lambda ns, page, n_pages: Caches(
@@ -707,7 +761,7 @@ def _stack(mcfg) -> _Stack:
             ic=paged_kv.empty_index(mcfg.n_layers, n_pages, page,
                                     mcfg.index_head_dim, dt)
             if indexed else None),
-        lambda c: {}, takes_riders=not indexed, adopts=not indexed)
+        lambda c: {}, takes_riders=plain, adopts=plain)
 
 
 def adopts(mcfg) -> bool:
@@ -998,14 +1052,11 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
     # ------------------------------------------------------------------
     # decode: one token for every active slot per step, `chunk` steps
     # ------------------------------------------------------------------
-    def _step(params, caches, counts, tables, bt, last, pos, active, temp,
-              topk, keys):
-        """ONE walk over the stack's segments for a decode step."""
-        act = active & (pos < S)
-        with jax.named_scope("embed"):
-            x = _embed(params, last, mcfg)
-        ctx = dict(tables, bt=bt, pos=pos, act=act)
-
+    def _step(params, caches, counts, ctx, x):
+        """ONE walk over the stack's segments for a decode step: the step's
+        rows `x` (a token a slot, or a block's rows a slot) through the
+        layers, the caches in the carry -> (x, caches, counts). `ctx`: the
+        step's own dict (the rotary tables, `bt`, `pos`, `act`)."""
         def layer(kind):
             def body(lp, l, carry):
                 x, caches, *counts = carry
@@ -1033,6 +1084,17 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
                                  for c in kind.carries}), *counts))
                 caches = caches._replace(
                     **{c: getattr(rode, c) for c in kind.carries})
+        return x, caches, counts
+
+    def _token(params, caches, counts, tables, bt, last, pos, active, temp,
+               topk, keys):
+        """A decode step of one token a slot: embed, `_step`, the head, the
+        sampler."""
+        act = active & (pos < S)
+        with jax.named_scope("embed"):
+            x = _embed(params, last, mcfg)
+        x, caches, counts = _step(
+            params, caches, counts, dict(tables, bt=bt, pos=pos, act=act), x)
         with jax.named_scope("head"):
             x = norms.rms_norm(x, params["final_norm"], mcfg.norm_eps)
             logits = _head_logits(params, x, mcfg)         # [ns, V]
@@ -1053,7 +1115,7 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
 
         def body(i, carry):
             caches, last, pos, out, *counts = carry
-            caches, counts, nxt, pos = _step(
+            caches, counts, nxt, pos = _token(
                 params, caches, counts, tables, bt, last, pos, active, temp,
                 topk, keys)
             return (caches, nxt, pos, out.at[:, i].set(nxt), *counts)
@@ -1061,6 +1123,123 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         caches, last, pos, out, *counts = jax.lax.fori_loop(
             0, chunk, body, (caches, last, pos, out0, *counts0))
         return caches, last, pos, out, counts[0] if sparse else None
+
+    # ------------------------------------------------------------------
+    # generation by blocks: a prompt's whole blocks kept, then a block of
+    # B positions a slot in T denoising forwards and a commit
+    # ------------------------------------------------------------------
+    B, T = mcfg.block_length, mcfg.denoise_steps
+
+    def block_prefill(params, caches, pages, tokens, length, temp, topk, key,
+                      slot=None, last=None, pos=None, riders=None):
+        """`prefill` of a model that generates by blocks: the prompt's whole
+        blocks, P = B * floor(length / B) rows, under the block mask, their K
+        and V to the slot's pages (the bucket's rows past P are written too,
+        computed from what they hold, and every one is written again by its
+        block's forwards before anything reads it). No token: a position
+        predicts its OWN token, so the prompt's last row predicts nothing
+        new. -> (caches, the opening block [B]: the prompt's tail `tokens[P:
+        length]` then -1s, `experts`)."""
+        whole = length // B * B
+        _, kept, _, experts, _ = walk(params, tokens, whole)
+        caches = _keep_pages(caches, pages, slot, length, *kept.pop("pages"))
+        at = whole + jnp.arange(B)
+        opening = jnp.where(
+            at < length,
+            jnp.take(tokens[0], jnp.minimum(at, tokens.shape[1] - 1)), -1)
+        return caches, opening.astype(jnp.int32), experts
+
+    def unmask(logits, z, masked, n, temp, topk, keys, at):
+        """The commit rule of one denoising step. logits [ns * B, V] of the
+        blocks' rows, z [ns, B] their ids and `masked` [ns, B] the rows
+        still to decide: each masked row's candidate is `sample_tokens` of
+        its logits (the argmax at temperature 0; a draw keyed by its slot's
+        seed and `at`, the row's position and the step), its confidence the
+        float32 softmax's probability of that candidate; the `n` masked rows
+        of a block with the largest confidence take their candidates, ties
+        to the smaller position, all of them where fewer are left. -> (z,
+        masked)."""
+        with jax.named_scope("unmask"):
+            x = sample_tokens(logits, temp, topk, keys, at)
+            lg = logits.astype(jnp.float32)
+            conf = jnp.exp(
+                jnp.take_along_axis(lg, x[:, None], axis=1)[:, 0]
+                - jax.nn.logsumexp(lg, axis=-1)).reshape(ns, B)
+            conf = jnp.where(masked, conf, -1.0)
+            i = jnp.arange(B)
+            # rows of the block that go before row i: a larger confidence,
+            # or the same at a smaller position
+            ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                (conf[:, None, :] == conf[:, :, None])
+                & (i[None, :] < i[:, None]))
+            take = masked & (jnp.sum(ahead, axis=-1) < n)
+            return jnp.where(take, x.reshape(ns, B), z), masked & ~take
+
+    def block_decode(params, caches, bt, last, pos, active, temp, topk, keys):
+        """-> (caches, last, pos, tokens [ns, chunk], experts): `chunk / B`
+        blocks a live slot. A block at positions pos..pos + B - 1 is `last`
+        [ns, B] as it opens (an id, or -1: masked, embedded as `mask_id`), T
+        forwards of its B rows, each followed by `unmask` (step s of T
+        commits B // T rows, one more while s <= B mod T), and one more
+        forward of the block with nothing masked, under the scope `commit`,
+        whose K and V are the rows the cache keeps (every forward writes the
+        block's rows over the last one's: no second store; its head is not
+        computed, nobody reads it). Then the next block opens all masked.
+        `experts` counts every forward's live rows."""
+        with jax.named_scope("rope"):
+            tables = stack.tables(S, False)
+        out0 = jnp.zeros((ns, chunk), jnp.int32)
+        counts0 = [jnp.zeros(mcfg.n_held + 1 + stack.shares,
+                             jnp.int32)] if sparse else []
+        temps, topks, rkeys = (jnp.repeat(t, B, axis=0)
+                               for t in (temp, topk, keys))
+
+        def forward(caches, counts, z, pos, act, head):
+            with jax.named_scope("embed"):
+                x = _embed(params, z.reshape(-1), mcfg)
+            x, caches, counts = _step(
+                params, caches, counts,
+                dict(tables, bt=bt, pos=pos, act=act), x)
+            if not head:
+                return caches, counts, None
+            with jax.named_scope("head"):
+                x = norms.rms_norm(x, params["final_norm"], mcfg.norm_eps)
+                return caches, counts, _head_logits(params, x, mcfg)
+
+        def body(b, carry):
+            caches, last, pos, out, *counts = carry
+            act = active & (pos < S)
+            masked = last < 0
+            z = jnp.where(masked, mcfg.mask_id, last)
+            at = (pos[:, None] + jnp.arange(B)).reshape(-1)
+            for s in range(T):
+                caches, counts, logits = forward(caches, counts, z, pos, act,
+                                                 True)
+                z, masked = unmask(logits, z, masked, B // T + (s < B % T),
+                                   temps, topks, rkeys, at * T + s)
+            with jax.named_scope("commit"):
+                caches, counts, _ = forward(caches, counts, z, pos, act,
+                                            False)
+            out = jax.lax.dynamic_update_slice(out, z, (0, b * B))
+            return (caches, jnp.where(act[:, None], -1, last),
+                    jnp.where(act, pos + B, pos), out, *counts)
+
+        caches, last, pos, out, *counts = jax.lax.fori_loop(
+            0, chunk // B, body, (caches, last, pos, out0, *counts0))
+        return caches, last, pos, out, counts[0] if sparse else None
+
+    if B > 1:
+        if chunk % B:
+            raise ValueError(
+                f"decode_chunk {chunk} is whole blocks of block_length {B}")
+        if page % B:
+            raise ValueError(
+                f"block_length {B} divides the page of {page} positions, so "
+                "that a block never crosses one")
+        # Under the names every program of these two kinds has: a trace's
+        # readers find `jit_prefill` and `jit_decode`.
+        block_prefill.__name__, block_decode.__name__ = "prefill", "decode"
+        prefill, decode = block_prefill, block_decode
 
     def poke(last, pos, slot, first, length):
         """Admission bookkeeping ON DEVICE: set one slot's (last, pos).
@@ -1079,4 +1258,5 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         takes_riders=stack.takes_riders, adopts=stack.adopts,
         by_slot=any(cache in ("state", "ring") for kind in
                     stack.kinds.values() for cache in kind.keeps),
-        shares=stack.shares, cache_bytes=stack.cache_bytes)
+        shares=stack.shares, cache_bytes=stack.cache_bytes,
+        block=B, block_forwards=T + 1 if B > 1 else 1)

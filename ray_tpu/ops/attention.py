@@ -48,7 +48,9 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # ("fwd_pallas", "fwd_reference", "bwd_pallas", "bwd_reference",
 # "decode_pallas", "decode_reference"; latent attention's "latent_fwd_*" and
 # "latent_decode_*"; a mixed stack's "window_fwd_*", "full_fwd_*" and
-# "window_decode_reference"): the dispatch is otherwise invisible
+# "window_decode_reference"; generation by blocks' "block_fwd_*", a prompt
+# under the block mask, and "block_decode_*", a step of a block of rows a
+# slot): the dispatch is otherwise invisible
 # from outside a jitted program, and a benchmark must be able to assert that
 # the kernel it names is the one that ran.
 _path_counts: collections.Counter = collections.Counter()
@@ -114,11 +116,12 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True,
                         sm_scale: Optional[float] = None,
                         q_offset: int = 0,
-                        kv_offset: int = 0) -> jax.Array:
+                        kv_offset: int = 0, block: int = 1) -> jax.Array:
     """Plain softmax attention with fp32 accumulation.
 
     ``q_offset``/``kv_offset`` give the global positions of the local q/kv
     shards — needed by ring attention where each sp shard sees rotated K/V.
+    ``block`` > 1: the mask of generation by blocks (`_sees`).
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -127,9 +130,20 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[2])[:, None]
         k_pos = kv_offset + jnp.arange(k.shape[2])[None, :]
-        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+        s = jnp.where(_sees(q_pos, k_pos, block), s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
+
+
+def _sees(q_pos, k_pos, block: int = 1):
+    """Whether the query at `q_pos` attends to the key at `k_pos`. `block` 1:
+    the causal mask. `block` B > 1, generation by blocks of B positions: all
+    of the query's own block, both ways, and every block before it, floor(k /
+    B) <= floor(q / B). `block` is static, and 1 emits the causal
+    comparison alone."""
+    if block == 1:
+        return q_pos >= k_pos
+    return (q_pos // block + 1) * block > k_pos
 
 
 # ---------------------------------------------------------------------------
@@ -138,7 +152,8 @@ def attention_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *, sm_scale: float, causal: bool,
-                      block_q: int, block_k: int, kv_seq_len: int):
+                      block_q: int, block_k: int, kv_seq_len: int,
+                      block: int = 1):
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
 
@@ -159,7 +174,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 0)
             k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+            s = jnp.where(_sees(q_pos, k_pos, block), s, DEFAULT_MASK_VALUE)
         m_prev = m_ref[:]
         l_prev = l_ref[:]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -176,7 +191,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     if causal:
         # Three block classes: fully masked (skip entirely), fully visible
         # (no mask arithmetic — the bulk below the diagonal), diagonal
-        # (per-element mask).
+        # (per-element mask). The mask of generation by blocks (`block` > 1,
+        # a divisor of both tile edges) differs from the causal one inside
+        # the diagonal tiles alone, so the classes are the same.
         visible = kv_idx * block_k <= q_idx * block_q + (block_q - 1)
         full = kv_idx * block_k + (block_k - 1) <= q_idx * block_q
         pl.when(visible & jnp.logical_not(full))(
@@ -193,7 +210,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q=1024,
-                      block_k=1024, interpret=False):
+                      block_k=1024, interpret=False, block=1):
     b, h, sq, d = q.shape
     skv = k.shape[2]
     block_q = _pick_block(sq, block_q)
@@ -204,10 +221,11 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q=1024,
     vr = v.reshape(b * h, skv, d)
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, kv_seq_len=skv)
+        block_q=block_q, block_k=block_k, kv_seq_len=skv,
+        **({} if block == 1 else {"block": block}))
     out, lse = pl.pallas_call(
         kernel,
-        name="flash_fwd",
+        name="flash_fwd" if block == 1 else "block_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -236,19 +254,43 @@ def _flash_fwd_pallas(q, k, v, *, causal, sm_scale, block_q=1024,
     return out.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
 
-def _fwd_with_lse_reference(q, k, v, *, causal, sm_scale):
+def _fwd_with_lse_reference(q, k, v, *, causal, sm_scale, block=1):
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
         q_pos = jnp.arange(q.shape[2])[:, None]
         k_pos = jnp.arange(k.shape[2])[None, :]
-        s = jnp.where(q_pos >= k_pos, s, DEFAULT_MASK_VALUE)
+        s = jnp.where(_sees(q_pos, k_pos, block), s, DEFAULT_MASK_VALUE)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum("bhqk,bhkd->bhqd", (p / l).astype(v.dtype), v)
     lse = (m + jnp.log(l))[..., 0]
     return out, lse
+
+
+def block_flash_attention(q, k, v, block: int, *,
+                          sm_scale: Optional[float] = None,
+                          interpret: bool = False) -> jax.Array:
+    """Attention of a prompt under the mask of generation by blocks (the SDAR
+    family): position t attends to s iff floor(s / block) <= floor(t /
+    block), all of its own block of `block` positions, both ways, and every
+    block before it. q, k, v `[b, H, s, d]` -> `[b, H, s, d]`. No gradient:
+    serving's.
+
+    On a TPU (or with `interpret`) `_flash_fwd_kernel` with that comparison
+    in its diagonal tiles, under the name `block_flash_fwd` (`block` divides
+    128, so every tile edge: the tiles skipped, taken whole and masked are the
+    causal kernel's); elsewhere the XLA reference. Counted in
+    `attention_path_counts()` as `block_fwd_pallas` / `block_fwd_reference`."""
+    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    use = interpret or pallas_eligible(q, k)
+    _path_counts["block_fwd_pallas" if use else "block_fwd_reference"] += 1
+    if use:
+        return _flash_fwd_pallas(q, k, v, causal=True, sm_scale=scale,
+                                 interpret=interpret, block=block)[0]
+    return _fwd_with_lse_reference(q, k, v, causal=True, sm_scale=scale,
+                                   block=block)[0]
 
 
 # ---------------------------------------------------------------------------

@@ -70,6 +70,16 @@ data-dependent branch. Which pages a slot holds is the host's business
     XLA gather over the whole block table elsewhere), counted at trace time
     in `attention.attention_path_counts()` as `decode_pallas` /
     `decode_reference`.
+  * Generation by blocks (`LlamaConfig.block_length` B > 1): a step is B
+    rows a slot, at positions p..p + B - 1, p a multiple of B. B divides the
+    page, so a block never crosses one: ``write_token`` takes k/v `[ns, B,
+    KVH, hd]` and replaces ONE cell of B rows of the slot's page; every row
+    of a block attends to the same keys, 0..p + B - 1, so
+    ``paged_decode_attention`` takes q `[ns, B, H, hd]` as B x the query
+    heads of each kv head and needs no mask of its own (counted as
+    `block_decode_pallas` / `block_decode_reference`). A denoising forward
+    overwrites the block's rows and the block's last forward leaves the
+    final ones: no second store.
 """
 
 from __future__ import annotations
@@ -220,6 +230,8 @@ def write_token(kc, vc, layer, block_table, w, active, k, v):
     Inactive slots (and positions past a slot's reservation) route to the
     NULL page 0, which attention never reads: the write stays a fixed-shape
     scatter with no data-dependent branches."""
+    if k.ndim == 4:         # a block of rows a slot
+        return _write_block(kc, vc, layer, block_table, w, active, k, v)
     if kc.ndim == 4:        # by token
         ns = k.shape[0]
         return (write_token_rows(kc, layer, block_table, w, active,
@@ -241,6 +253,34 @@ def write_token(kc, vc, layer, block_table, w, active, k, v):
         vc = vc.at[layer, pp].set(
             jnp.where(here, v[:, :, None], vc[layer, pp]))
     return kc, vc
+
+
+def _write_block(kc, vc, layer, block_table, w, active, k, v):
+    """`write_token` for a step of B rows a slot (generation by blocks): k/v
+    [ns, B, KVH, hd] at each slot's positions w..w + B - 1, w [ns] a multiple
+    of B. B divides the page, so a block lies in ONE page, at rows that are
+    a whole cell of the page cut in cells of B rows: the slot's page is
+    read, that cell replaced, and the page scattered back, as a token's row
+    is."""
+    ns, B, KVH, hd = k.shape
+    page = kc.shape[3]
+    if kc.ndim != 5 or kc.shape[2:] != (KVH, page, hd) \
+            or vc.shape != kc.shape or page % B:
+        raise NotImplementedError(
+            "a block of rows a slot is written to an arena of whole heads by "
+            "(page, head), K and V alike, whose page the block divides")
+    with jax.named_scope("kv_write"):
+        pp = jnp.where(active, block_table[jnp.arange(ns), w // page], 0)
+        cell = jnp.where(active, (w % page) // B, 0)
+        here = (jnp.arange(page // B) == cell[:, None])[:, None, :, None, None]
+
+        def put(arena, rows):
+            old = arena[layer, pp].reshape(ns, KVH, page // B, B, hd)
+            new = rows.astype(arena.dtype).transpose(0, 2, 1, 3)[:, :, None]
+            return arena.at[layer, pp].set(
+                jnp.where(here, new, old).reshape(ns, KVH, page, hd))
+
+        return put(kc, k), put(vc, v)
 
 
 # ---------------------------------------------------------------------------
@@ -440,7 +480,8 @@ def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
                            sm_scale: Optional[float] = None,
                            pages_per_block: Optional[int] = None,
                            interpret: bool = False):
-    """Attention of ONE query token a slot against a paged KV cache.
+    """Attention of ONE query token a slot against a paged KV cache (or of a
+    block of B, q `[ns, B, H, hd]` -> `[ns, B, H, hd]`: see below).
 
     q [ns, H, hd]; kc, vc the WHOLE arena [L, n_pages, KVH, page, hd] (vc's
     rows may be of a width of their own, which is then the result's) and
@@ -461,6 +502,24 @@ def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
     page, hd = kc.shape[3], kc.shape[4]
     use = interpret or (attention._on_tpu() and hd % 128 == 0
                         and page % _sublanes(kc.dtype) == 0)
+    if q.ndim == 4:
+        # A block of B rows a slot (generation by blocks), [ns, B, H, hd]:
+        # every row of a block sees the same keys, positions 0..lengths-1
+        # (the block's own rows among them), so there is no mask to add:
+        # the B rows of a kv head's query heads are B x as many query heads
+        # of that kv head, [ns, KVH, B x groups, hd] to the kernel.
+        attention._path_counts["block_decode_pallas" if use
+                               else "block_decode_reference"] += 1
+        ns, B, H, d = q.shape
+        n_kv = kc.shape[2]
+        wide = q.reshape(ns, B, n_kv, H // n_kv, d).transpose(
+            0, 2, 1, 3, 4).reshape(ns, B * H, d)
+        run = functools.partial(
+            _paged_decode_pallas, pages_per_block=pages_per_block,
+            interpret=interpret) if use else _paged_decode_reference
+        out = run(wide, kc, vc, layer, block_table, lengths, sm_scale=scale)
+        return out.reshape(ns, n_kv, B, H // n_kv, -1).transpose(
+            0, 2, 1, 3, 4).reshape(ns, B, H, -1)
     attention._path_counts["decode_pallas" if use else "decode_reference"] += 1
     # Heads of half a tile, two to a row (see the top): q's own width says so.
     packed = hd == 2 * q.shape[-1] and vc.shape[4] == hd

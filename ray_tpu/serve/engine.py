@@ -12,8 +12,8 @@ engine is OURS):
   is ONE bundle (`Caches`) that `Engine` holds, hands to every program first
   after the parameters, gets back first, and never opens; what the scheduler
   has to know of a model it asks of `Programs` (`takes_riders`, `adopts`,
-  `by_slot`, `shares`, `cache_bytes`). No program is built here, and no
-  architecture is named.
+  `by_slot`, `shares`, `cache_bytes`, `block`). No program is built here,
+  and no architecture is named.
 - **Paged KV cache**, kept behind two names. The DEVICE side is
   `ops/paged_kv.py` (the arena's layout, the null page, the ops on it), which
   only the programs touch. The HOST side is `serve/page_pool.py::PagePool`:
@@ -151,7 +151,7 @@ def _seed_key(seed: int):
 class _Request:
     __slots__ = ("ids", "max_tokens", "out", "produced", "slot",
                  "adopt_kv", "first", "temperature", "top_k", "seed",
-                 "rid", "t_submit", "ctx")
+                 "rid", "t_submit", "ctx", "tail")
 
     def __init__(self, ids: List[int], max_tokens: int,
                  adopt_kv: Optional[Tuple[Any, Any]] = None,
@@ -176,6 +176,9 @@ class _Request:
         self.rid = -1
         self.t_submit = 0.0
         self.ctx = None
+        # A model of blocks: the prompt ids that open the slot's first block
+        # (`_place`), which its first chunk hands back before its tokens.
+        self.tail = 0
 
 
 class Engine:
@@ -226,6 +229,8 @@ class Engine:
         self._programs = build_programs(mcfg, n_slots, decode_chunk,
                                         self.pool.page, self.n_pages)
         self._caches = self._programs.empty()
+        # Positions a slot's step yields (`Programs.block`): 1, or a block.
+        self._block = self._programs.block
         # Prefill shape buckets (`prefill_widths`): a 50-token prompt
         # prefills 64 wide and, under a max_seq of 4096, a 2,100-token one
         # 2,560 wide, not max_seq wide — the TTFT lever, and most of the
@@ -243,7 +248,9 @@ class Engine:
         self._temp = np.zeros(n_slots, np.float32)
         self._topk = np.zeros(n_slots, np.int32)
         self._skeys = np.zeros((n_slots, 2), np.uint32)
-        self._last_d = jnp.zeros(n_slots, jnp.int32)
+        # The slots' state on the device: the last token (a model of blocks:
+        # the open block, `[n_slots, block]`) and the position.
+        self._last_d = self._slots_last()
         self._pos_d = jnp.zeros(n_slots, jnp.int32)
         self.peak_pages_used = 0
         # Running totals since start (`counters()`; what the engine's spans
@@ -267,6 +274,14 @@ class Engine:
         # prompt's padding rows), and the prefills that carried any.
         self.rider_tokens = 0
         self.rider_steps = 0
+        # A model of blocks: the forwards its decode chunks ran (a block is
+        # `Programs.block_forwards` of them), the positions its live slots'
+        # blocks covered, and the prompt ids among those (a prompt's tail,
+        # which opens the slot's first block): the tokens the blocks made are
+        # `block_tokens - tail_tokens`.
+        self.denoise_forwards = 0
+        self.block_tokens = 0
+        self.tail_tokens = 0
         # Positions the active slots held when each chunk was dispatched:
         # what decode attention had to read, a layer, at the chunk's first
         # step (against n_slots * max_seq, what a whole-table gather moves).
@@ -363,7 +378,7 @@ class Engine:
                 self._last_d, self._pos_d, 0, first, 0)
             self._last_d, self._pos_d = self._programs.poke(
                 self._last_d, self._pos_d, 0, 0, 0)
-        int(first)
+        jax.block_until_ready(first)
         # Emission FIFO: the dispatch loop enqueues device arrays; the
         # emitter thread performs the host syncs. No `put` blocks: it holds
         # at most `_DEPTH` chunks (the loop's own count, `_in_flight`) and a
@@ -383,6 +398,11 @@ class Engine:
                 name="llm-bucket-warm")
             self._warm_thread.start()
 
+    def _slots_last(self):
+        """The slots' `last` as the programs take it, zeroed."""
+        shape = (self.n_slots,) + ((self._block,) if self._block > 1 else ())
+        return self._jnp.zeros(shape, self._jnp.int32)
+
     def _warm_width(self, caches, width: int):
         """First call of the prefill program of one bucket width and, at a
         doubling width, of its adopt twin, writing to the null page of the
@@ -395,7 +415,7 @@ class Engine:
         # own: the live `_last_d` and `_pos_d` are the loop's to donate.
         rides = self._rides(width)
         slots = (None, None, None) if not rides else (
-            jnp.zeros(self.n_slots, jnp.int32),
+            self._slots_last(),
             jnp.zeros(self.n_slots, jnp.int32),
             self._riders(self._np.zeros(self.n_slots, bool)))
         with tracing.compile_span("serve.engine.warm", program="prefill",
@@ -447,7 +467,7 @@ class Engine:
                 if self._stop:
                     return
                 caches, first = self._warm_width(caches, width)
-                int(first)  # host sync: compile fully landed
+                first.block_until_ready()   # compile fully landed
                 self._warm.add(width)
         except Exception:
             # Prompts keep rounding up to the buckets that did warm, but
@@ -563,6 +583,7 @@ class Engine:
                 "nor latent attention's rows (kv_lora_rank > 0) nor mixed "
                 "attention's two caches (attn_pattern: pages and window "
                 "rings) nor a short-convolution layer's window (conv_layers)"
+                " nor a block that a prompt's tail opens (block_length > 1)"
                 ": this model serves from one engine")
         if not self._adopt_widths:
             raise RuntimeError(
@@ -610,6 +631,12 @@ class Engine:
         request's prefill (`rider_steps`: the prefills that carried any), so
         the tokens decoded are the two added; padding is
         `prefill_padded_tokens` over it plus `prefill_tokens`.
+        A model of blocks (`block` > 1) adds `denoise_forwards` (the
+        forwards of `n_slots * block` rows its decode chunks ran, a block's
+        denoising steps and its commit), `block_tokens` (the positions the
+        live slots' blocks covered) and `tail_tokens` (the prompt ids among
+        those, a prompt's tail in its slot's first block): forwards over
+        `block_tokens - tail_tokens`, a slot, is what a token cost.
         `decode_chunks_sampling` are the chunks dispatched with a live slot
         at a temperature above 0 (the span's `sampling` counts the slots):
         the others' steps took no top-k (`serving.sample_tokens`).
@@ -646,6 +673,11 @@ class Engine:
             "decode_chunks_sampling", "decode_useful_tokens",
             "rider_tokens", "rider_steps",
             "live_kv_tokens", "peak_pages_used", "n_slots", "chunk")}
+        if self._block > 1:
+            out.update(block=self._block,
+                       denoise_forwards=self.denoise_forwards,
+                       block_tokens=self.block_tokens,
+                       tail_tokens=self.tail_tokens)
         if self._sparse:
             out["expert_tokens"] = [int(n) for n in self.expert_tokens]
             out["decode_experts_touched"] = self.decode_experts_touched
@@ -833,14 +865,20 @@ class Engine:
             self.state_writes += self._programs.by_slot
         req.slot = slot
         self._slot_req[slot] = req
-        self._pos[slot] = len(req.ids)
+        # A model of blocks kept the prompt's whole blocks: the rest, its
+        # tail, opens the slot's first block, at that block's first position.
+        req.tail = len(req.ids) % self._block
+        self.tail_tokens += req.tail
+        self._pos[slot] = len(req.ids) - req.tail
         self._active[slot] = True
         # Sampling state applies on BOTH branches (a PD handoff
         # continues decoding with the request's params).
         self._temp[slot] = req.temperature
         self._topk[slot] = req.top_k
         self._skeys[slot] = _seed_key(req.seed)
-        req.produced = 1
+        # The prefill (or the hand-off) made the first token; a model of
+        # blocks makes it with its first block.
+        req.produced = int(self._block == 1)
         # Device-side slot bookkeeping (async — never a host round-trip;
         # `first` stays a device scalar on the prefill path).
         self._last_d, self._pos_d = self._programs.poke(
@@ -898,11 +936,17 @@ class Engine:
             try:
                 if item[0] == "first":
                     _, req, first, done, experts, rode, riders = item
-                    # Ends at the engine's first-token instant.
+                    # Ends at the engine's first-token instant. A model of
+                    # blocks has no token yet (its `first` is the block the
+                    # prompt's tail opens): the span ends when the prefill
+                    # has left the device, and the first token's instant is
+                    # the end of the `opening` span of the slot's first chunk.
                     with tracing.span(
                             "serve.engine.emit", ctx=req.ctx, rid=req.rid,
                             kind="first" if req.first < 0 else "adopt"):
-                        if req.first < 0:
+                        if self._block > 1:
+                            first.block_until_ready()
+                        elif req.first < 0:
                             req.out.put([int(first)])
                         if done:
                             req.out.put(None)
@@ -934,9 +978,17 @@ class Engine:
                             with self._cv:
                                 self._in_flight -= 1
                                 self._cv.notify_all()
-                        for slot, req, take, fin in plan:
-                            toks = [int(t) for t in out_h[slot, :take]]
-                            if toks:
+                        for slot, req, take, fin, skip, opens in plan:
+                            toks = [int(t)
+                                    for t in out_h[slot, skip:skip + take]]
+                            if opens:
+                                # A model of blocks: the request's first
+                                # token comes with its first block.
+                                with tracing.span(
+                                        "serve.engine.emit", ctx=req.ctx,
+                                        rid=req.rid, kind="opening"):
+                                    req.out.put(toks)
+                            elif toks:
                                 req.out.put(toks)
                             if fin:
                                 req.out.put(None)
@@ -953,7 +1005,7 @@ class Engine:
                     for req in [item[1]] + [r for _, r, _ in item[6]]:
                         req.out.put(None)
                 else:
-                    for _, req, _, _ in item[2]:
+                    for _, req, *_ in item[2]:
                         req.out.put(None)
 
     def _fetch(self, out_d):
@@ -1028,17 +1080,21 @@ class Engine:
                 if req is None or not self._active[slot]:
                     continue
                 valid = int(max(0, min(self.chunk, S - self._pos[slot])))
-                take = int(min(valid, req.max_tokens - req.produced))
+                # A model of blocks: the first `skip` of the chunk's
+                # positions are the prompt's tail (the slot's first chunk).
+                skip, req.tail = req.tail, 0
+                take = int(min(valid - skip, req.max_tokens - req.produced))
                 fin = (req.produced + take >= req.max_tokens
                        or self._pos[slot] + valid >= S)
+                opens = self._block > 1 and req.produced == 0
                 req.produced += take
-                plan.append((slot, req, take, fin))
+                plan.append((slot, req, take, fin, skip, opens))
             # COPIES, not views: jnp.asarray may alias numpy memory
             # (zero-copy on the CPU backend), and this loop mutates the
             # block table and _active in place while the dispatched chunk
             # is still queued — an aliased buffer would let those mutations
             # reach into the in-flight computation.
-            useful = sum(take for _, _, take, _ in plan)
+            useful = sum(p[2] for p in plan)
             live_kv = int(self._pos[self._active].sum())
             # Live slots that ask for a sample: with none, the chunk's steps
             # take their argmax alone (`serving.sample_tokens`).
@@ -1073,6 +1129,17 @@ class Engine:
                 ring = int(np.minimum(reads, self._window).sum())
                 self.window_kv_tokens += ring
                 routed.update(window_kv_tokens=ring)
+            if self._block > 1:
+                # What the chunk's blocks are: forwards of `rows` rows each,
+                # positions covered in the live slots, prompt tails included.
+                blocks = self.chunk // self._block
+                forwards = blocks * self._programs.block_forwards
+                committed = len(plan) * self.chunk
+                self.denoise_forwards += forwards
+                self.block_tokens += committed
+                routed.update(blocks=blocks, forwards=forwards,
+                              rows=self.n_slots * self._block,
+                              committed=committed)
             with tracing.span("serve.engine.decode_dispatch", useful=useful,
                               capacity=self.n_slots * self.chunk,
                               active=len(plan), sampling=sampling,
@@ -1089,7 +1156,7 @@ class Engine:
                 self._pos = np.where(
                     self._active, np.minimum(self._pos + self.chunk, S),
                     self._pos).astype(np.int32)
-                for slot, req, take, fin in plan:
+                for slot, req, _, fin, *_ in plan:
                     if fin and self._slot_req[slot] is req:
                         self._finish_state(slot)
                 try:

@@ -767,6 +767,94 @@ def test_granite_programs_keep_pages_and_state_in_place_on_v5e(
                                      else (1 << 30))
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_sdar_block_programs_keep_the_pages_in_place_on_v5e(
+        topo, program, monkeypatch):
+    """Generation by blocks at the cell's sizes (benchmark/configs/
+    sdar-30b-a3b-chat-serve.json: 6 layers of the published widths, every
+    expert, 64 slots, a chunk of two blocks of 4): the decode program, six
+    forwards of 256 rows with the pages in the loops' carry, and the
+    1,024-row prefill under the block mask. Decode's attention is the
+    `paged_decode` kernel at 4 rows a slot (counted `block_decode_pallas`), a
+    prompt's the flash kernel with the block comparison in its diagonal tiles
+    (`block_flash_fwd`, counted `block_fwd_pallas`), the experts the grouped
+    matmul with no copy of a stack; the arena is donated and aliases the
+    output, and a block's write moves pages, not the arena."""
+    import json
+
+    from benchmark import models
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.models.serving import build_programs
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "sdar-30b-a3b-chat-serve.json")) as f:
+        model = json.load(f)
+    eng = model["deployment"]["engine"]
+    cfg = models.adapter("sdar").build_config(model, model["dtypes"],
+                                              eng["max_seq"])
+    ns, page, B = eng["n_slots"], eng["page_size"], model["block_length"]
+    maxp = eng["max_seq"] // page
+    layers = model["num_hidden_layers"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    built = build_programs(cfg, ns, eng["decode_chunk"], page,
+                           eng["kv_pages"])
+    assert (built.block, built.block_forwards) == (B, 3) \
+        and not built.takes_riders and not built.adopts
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)), cfg)))
+    caches = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(built.empty))
+    kc, vc = caches.kc, caches.vc
+    assert kc.shape == vc.shape == (layers, eng["kv_pages"], 4, page, 128)
+    before = dict(attention.attention_path_counts())
+    if program == "decode":
+        lowered = built.decode.lower(
+            params, caches, sds((ns, maxp), jnp.int32),
+            sds((ns, B), jnp.int32), sds((ns,), jnp.int32),
+            sds((ns,), jnp.bool_), sds((ns,), jnp.float32),
+            sds((ns,), jnp.int32), sds((ns, 2), jnp.uint32))
+        kernels, paths = ["paged_decode", "grouped_matmul"], [
+            "block_decode_pallas", "experts_grouped_pallas"]
+    else:
+        lowered = built.prefill.lower(
+            params, caches, sds((maxp,), jnp.int32), sds((1, 1024), jnp.int32),
+            1, 0.0, 0, sds((2,), jnp.uint32), None)
+        kernels, paths = ["block_flash_fwd", "grouped_matmul"], [
+            "block_fwd_pallas", "experts_grouped_pallas"]
+    text = lowered.as_text()
+    assert all(k in text for k in kernels)
+    counts = attention.attention_path_counts()
+    assert all(counts[p] > before.get(p, 0) for p in paths)
+    for other in ("fwd_pallas", "decode_pallas"):   # the causal paths: unused
+        assert counts.get(other, 0) == before.get(other, 0)
+    compiled = lowered.compile()
+    stacks = [tuple(params["layers"][w].shape)
+              for w in ("w_gate", "w_up", "w_down")]
+    assert not _moved_stacks(compiled.as_text(), stacks)
+    mem = compiled.memory_analysis()
+    held = 2 * kc.size * kc.dtype.itemsize
+    assert held == 2 * layers * eng["kv_pages"] * 4 * page * 128 * 2
+    assert mem.alias_size_in_bytes >= held
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert weights == 2 * models.adapter("sdar").counts.total_params(model)
+    print(program, "temp", mem.temp_size_in_bytes, "args",
+          mem.argument_size_in_bytes)
+    if program == "prefill":
+        # a prefill yields no token: it computes no head, and never reads it
+        head = params["lm_head"]
+        weights -= head.size * head.dtype.itemsize
+    assert 0 <= mem.argument_size_in_bytes - weights - held < 1 << 20
+    assert mem.temp_size_in_bytes < ((512 << 20) if program == "decode"
+                                     else (1 << 30))
+
+
 # ---------------------------------------------------------------------------
 # The experts' grouped matmul (ops/moe.py::grouped_matmul)
 # ---------------------------------------------------------------------------
