@@ -187,10 +187,12 @@ class LlamaConfig:
     # of block_length positions, both ways, and every block before it; a
     # position predicts its OWN token; and Serve generates a block at a time:
     # denoise_steps forwards of the block's rows, each committing the most
-    # confident of the rows still masked (embedded as mask_id), then one
-    # forward of the committed block, whose K and V the cache keeps
-    # (`models/serving.py`, the block step). Served by the uniform stack of
-    # plain attention, dense or sparse; training's forward is not built.
+    # confident of the rows still masked (embedded as mask_id); the committed
+    # block's rows, whose K and V the cache keeps, go through the model beside
+    # the next block's in that block's first forward, the two streams of one
+    # forward (`models/serving.py`, the block step). Served by the uniform
+    # stack of plain attention, dense or sparse; training's forward is not
+    # built.
     block_length: int = 1
     denoise_steps: int = 1
     mask_id: int = 0

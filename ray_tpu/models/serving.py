@@ -15,6 +15,15 @@ what the walk cannot guess of how to run them. A new architecture is a kind's
 two bodies, its cache's ops in `ops/` (empty, a prompt's write, a token's
 write, the read) and its fields in `LlamaConfig`.
 
+A model that generates by BLOCKS (`LlamaConfig.block_length` B > 1) has a
+prefill and a decode program of its own under the same names (`block_prefill`,
+`block_decode`; the same walks). A block is `denoise_steps` T forwards and
+`Programs.block_forwards` is T: no forward is a commit's own. The K and V the
+cache keeps of block b are written by the FIRST forward of block b + 1, which
+carries b's final ids beside its own rows (2B rows a slot; the others B), so a
+chunk of `chunk / B` blocks runs `chunk / B x T` forwards, which is what the
+scheduler's dispatch span calls `forwards`.
+
 The caches travel as one bundle (`Caches`) that the scheduler never opens. A
 decode program updates them IN PLACE, as a loop carry that nothing but
 `ops/`'s writes and reads touches, so no copy of an arena (or of a layer's
@@ -72,13 +81,16 @@ class Programs(NamedTuple):
     -> caches`; `poke(last, pos, slot, first, length) -> (last, pos)`.
 
     A model that generates by BLOCKS (`block` B > 1): the slots' `last` is
-    `[n_slots, B]`, a slot's open block (an id, or -1 for a row still
-    masked); `prefill` keeps the prompt's whole blocks, `B * floor(length /
-    B)` rows, yields NO token, and its `first` is the opening block `[B]`,
-    the prompt's tail then -1s, which `poke` sets beside `pos`, the block's
-    first position; `decode` is `chunk / B` blocks of `block_forwards`
-    forwards each, and `out [n_slots, chunk]` holds every position of those
-    blocks, a slot's first chunk the prompt's tail too."""
+    `[n_slots, 2B]`, a slot's PENDING block (its final ids, whose K and V the
+    next forward keeps; -1s: none) then its open block (an id, or -1 for a
+    row still masked); `prefill` keeps the prompt's whole blocks, `B *
+    floor(length / B)` rows, yields NO token, and its `first` is the slot's
+    state as it opens `[2B]`, nothing pending then the prompt's tail then
+    -1s, which `poke` sets beside `pos`, the open block's first position;
+    `decode` is `chunk / B` blocks of `block_forwards` forwards each (the
+    denoising steps: the first, of 2B rows a slot, commits the block before;
+    no forward is the commit's own), and `out [n_slots, chunk]` holds every
+    position of those blocks, a slot's first chunk the prompt's tail too."""
     empty: Callable
     prefill: Callable
     decode: Callable
@@ -96,7 +108,7 @@ class Programs(NamedTuple):
     # caches -> the counters `Engine.counters()` shows of them.
     cache_bytes: Callable[[Caches], Dict[str, int]]
     # Positions a slot's step yields: 1, a token a forward; B > 1, a block of
-    # B positions in `block_forwards` forwards of B rows a slot (see above).
+    # B positions in `block_forwards` forwards (see above).
     block: int = 1
     block_forwards: int = 1
 
@@ -418,27 +430,50 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
         (multiples of B). The block's K and V are written over the slot's
         rows pos..pos + B - 1, then every row of the block attends to
         positions 0..pos + B - 1, its own block's rows both ways: the mask of
-        a block is its length."""
+        a block is its length.
+
+        Or of 2B rows a slot, x [ns * 2B, D]: the block BEFORE the open one
+        (its final ids; positions pos - B..pos - 1) then the open one, ONE
+        forward of the two streams. Both blocks' K and V are written before
+        the attention (the first's only where `ctx["rides"]` [ns] says the
+        slot has such a block: a slot's first block has the prompt's last
+        whole block there, which stays), the first block's rows attend to
+        0..pos - 1 and the open one's to 0..pos + B - 1, the first's K and V
+        among them."""
         bt, pos, act = ctx["bt"], ctx["pos"], ctx["act"]
         ns = pos.shape[0]
+        R = x.shape[0] // ns
+        lag = R - B         # 0, or B: the rows that go before the open block's
         with jax.named_scope("rope"):
             w = jnp.minimum(pos, S - B)
-            at = (w[:, None] + jnp.arange(B)).reshape(-1)
+            at = w[:, None] - lag + jnp.arange(R)
+            if lag:     # a slot at 0 has no block before it: its rows' are 0
+                at = jnp.maximum(at, 0)
+            at = at.reshape(-1)
             c, s = (t[at][:, None] for t in ctx["tables"])
         q, k, v = block.attention_inputs(lp, x, mcfg,
                                          lambda t: _rope_one(t, c, s))
-        kc, vc = paged_kv.write_token(
-            caches.kc, caches.vc, l, bt, w, act,
-            k.reshape(ns, B, KVH, hd), v.reshape(ns, B, KVH, hd))
+        k, v = k.reshape(ns, R, KVH, hd), v.reshape(ns, R, KVH, hd)
+        kc, vc = caches.kc, caches.vc
+        live = jnp.repeat(act[:, None], B, axis=1)
+        if lag:
+            rides = ctx["rides"]
+            kc, vc = paged_kv.write_token(
+                kc, vc, l, bt, jnp.maximum(w - B, 0), rides,
+                k[:, :B], v[:, :B])
+            live = jnp.concatenate(
+                [jnp.repeat(rides[:, None], B, axis=1), live], axis=1)
+        kc, vc = paged_kv.write_token(kc, vc, l, bt, w, act,
+                                      k[:, lag:], v[:, lag:])
         with jax.named_scope("attn"):
-            attn = paged_decode(q.reshape(ns, B, H, hd), kc, vc, l, bt,
-                                jnp.where(act, w + B, 0), **scaled)
-            attn = attn.reshape(ns * B, H * hd)
+            attn = paged_decode(q.reshape(ns, R, H, hd), kc, vc, l, bt,
+                                jnp.where(act, w + B, 0), lag=lag, **scaled)
+            attn = attn.reshape(ns * R, H * hd)
         with jax.named_scope("attn_out"):
             x = x + block.scaled(attn @ lp["wo"].astype(dt), mcfg)
-        # An idle slot's rows are computed like any others and left out of
-        # the count.
-        live = jnp.repeat(act, B)
+        # An idle slot's rows (and the rows before a slot's first block) are
+        # computed like any others and left out of the count.
+        live = live.reshape(-1)
         x, routed = block.feed_forward(lp, x, mcfg, live,
                                        l if sparse else None)
         return x, caches._replace(kc=kc, vc=vc), \
@@ -1126,7 +1161,8 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
 
     # ------------------------------------------------------------------
     # generation by blocks: a prompt's whole blocks kept, then a block of
-    # B positions a slot in T denoising forwards and a commit
+    # B positions a slot in T denoising forwards, the first of which commits
+    # the block before
     # ------------------------------------------------------------------
     B, T = mcfg.block_length, mcfg.denoise_steps
 
@@ -1138,16 +1174,17 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         computed from what they hold, and every one is written again by its
         block's forwards before anything reads it). No token: a position
         predicts its OWN token, so the prompt's last row predicts nothing
-        new. -> (caches, the opening block [B]: the prompt's tail `tokens[P:
-        length]` then -1s, `experts`)."""
+        new. -> (caches, the slot's state as it opens [2B]: B -1s, no block
+        pending, then the opening block: the prompt's tail `tokens[P: length]`
+        then -1s; `experts`)."""
         whole = length // B * B
         _, kept, _, experts, _ = walk(params, tokens, whole)
         caches = _keep_pages(caches, pages, slot, length, *kept.pop("pages"))
-        at = whole + jnp.arange(B)
-        opening = jnp.where(
-            at < length,
-            jnp.take(tokens[0], jnp.minimum(at, tokens.shape[1] - 1)), -1)
-        return caches, opening.astype(jnp.int32), experts
+        at = whole - B + jnp.arange(2 * B)
+        first = jnp.where(
+            (at >= whole) & (at < length),
+            jnp.take(tokens[0], jnp.clip(at, 0, tokens.shape[1] - 1)), -1)
+        return caches, first.astype(jnp.int32), experts
 
     def unmask(logits, z, masked, n, temp, topk, keys, at):
         """The commit rule of one denoising step. logits [ns * B, V] of the
@@ -1177,15 +1214,27 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
 
     def block_decode(params, caches, bt, last, pos, active, temp, topk, keys):
         """-> (caches, last, pos, tokens [ns, chunk], experts): `chunk / B`
-        blocks a live slot. A block at positions pos..pos + B - 1 is `last`
-        [ns, B] as it opens (an id, or -1: masked, embedded as `mask_id`), T
-        forwards of its B rows, each followed by `unmask` (step s of T
-        commits B // T rows, one more while s <= B mod T), and one more
-        forward of the block with nothing masked, under the scope `commit`,
-        whose K and V are the rows the cache keeps (every forward writes the
-        block's rows over the last one's: no second store; its head is not
-        computed, nobody reads it). Then the next block opens all masked.
-        `experts` counts every forward's live rows."""
+        blocks a live slot, T forwards each. `last` [ns, 2B] is a slot's
+        PENDING block (its B final ids, whose K and V the cache does not keep
+        yet; -1s: none, a slot's first block) beside its OPEN one at
+        positions pos..pos + B - 1 (an id, or -1: masked, embedded as
+        `mask_id`); the pending block lies at pos - B..pos - 1.
+
+        A block's first forward is of 2B rows a slot, the pending block's
+        then the open one's (`decode_rows`): the pending rows' K and V, from
+        their final ids, are the rows the cache keeps of that block, written
+        a layer before the layer's attention, where the open rows read them.
+        The commit of a block rides the next one's first forward (the scope
+        `commit` names that forward) and has none of its own; the head,
+        `unmask` and the sampler take the open rows alone. Forwards 2..T are
+        of the open block's B rows; each forward is followed by `unmask`
+        (step s of T commits B // T rows, one more while s <= B mod T) and
+        writes the open block's rows over the last one's. Then the block is
+        the pending one and the next opens all masked. What the cache keeps:
+        every block but a request's LAST, which no later forward commits: its
+        slot is freed, and nobody reads those K and V. `experts` counts every
+        forward's live rows (pending rows of a slot with no pending block
+        are not)."""
         with jax.named_scope("rope"):
             tables = stack.tables(S, False)
         out0 = jnp.zeros((ns, chunk), jnp.int32)
@@ -1194,34 +1243,38 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         temps, topks, rkeys = (jnp.repeat(t, B, axis=0)
                                for t in (temp, topk, keys))
 
-        def forward(caches, counts, z, pos, act, head):
+        def forward(caches, counts, z, pos, act, rides=None):
+            """z [ns, B], or [ns, 2B] with `rides` -> the open rows' logits
+            [ns * B, V]."""
             with jax.named_scope("embed"):
                 x = _embed(params, z.reshape(-1), mcfg)
             x, caches, counts = _step(
                 params, caches, counts,
-                dict(tables, bt=bt, pos=pos, act=act), x)
-            if not head:
-                return caches, counts, None
+                dict(tables, bt=bt, pos=pos, act=act, rides=rides), x)
             with jax.named_scope("head"):
+                x = x.reshape(ns, -1, x.shape[-1])[:, -B:].reshape(ns * B, -1)
                 x = norms.rms_norm(x, params["final_norm"], mcfg.norm_eps)
                 return caches, counts, _head_logits(params, x, mcfg)
 
         def body(b, carry):
             caches, last, pos, out, *counts = carry
             act = active & (pos < S)
-            masked = last < 0
-            z = jnp.where(masked, mcfg.mask_id, last)
+            masked = last[:, B:] < 0
+            z = jnp.where(last < 0, mcfg.mask_id, last)
             at = (pos[:, None] + jnp.arange(B)).reshape(-1)
+            with jax.named_scope("commit"):     # the pending block's rides
+                caches, counts, logits = forward(
+                    caches, counts, z, pos, act, act & (last[:, 0] >= 0))
+            z = z[:, B:]
             for s in range(T):
-                caches, counts, logits = forward(caches, counts, z, pos, act,
-                                                 True)
+                if s:
+                    caches, counts, logits = forward(caches, counts, z, pos,
+                                                     act)
                 z, masked = unmask(logits, z, masked, B // T + (s < B % T),
                                    temps, topks, rkeys, at * T + s)
-            with jax.named_scope("commit"):
-                caches, counts, _ = forward(caches, counts, z, pos, act,
-                                            False)
             out = jax.lax.dynamic_update_slice(out, z, (0, b * B))
-            return (caches, jnp.where(act[:, None], -1, last),
+            opened = jnp.concatenate([z, jnp.full_like(z, -1)], axis=1)
+            return (caches, jnp.where(act[:, None], opened, last),
                     jnp.where(act, pos + B, pos), out, *counts)
 
         caches, last, pos, out, *counts = jax.lax.fori_loop(
@@ -1259,4 +1312,4 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         by_slot=any(cache in ("state", "ring") for kind in
                     stack.kinds.values() for cache in kind.keeps),
         shares=stack.shares, cache_bytes=stack.cache_bytes,
-        block=B, block_forwards=T + 1 if B > 1 else 1)
+        block=B, block_forwards=T)

@@ -78,8 +78,18 @@ data-dependent branch. Which pages a slot holds is the host's business
     ``paged_decode_attention`` takes q `[ns, B, H, hd]` as B x the query
     heads of each kv head and needs no mask of its own (counted as
     `block_decode_pallas` / `block_decode_reference`). A denoising forward
-    overwrites the block's rows and the block's last forward leaves the
-    final ones: no second store.
+    overwrites the block's rows, and the rows the cache KEEPS of a block are
+    written by the next block's first forward, which carries the finished
+    block's B rows (its final ids) before its own: TWO blocks a slot, q `[ns,
+    2B, H, hd]` with `lag` = B. Both blocks' K and V are written first
+    (``write_token`` twice: with p at a page's first row the finished block
+    lies in the page BEFORE the open one's), then one call reads the slot's K
+    and V ONCE for the 2B x the query heads of each kv head, the first
+    block's rows masked B positions short (they see 0..p - 1, the open
+    block's 0..p + B - 1). Which rows lag is fixed by the layout, so the
+    mask is a static per-row length, one more compare a block of keys, and a
+    call without `lag` (one block: a block's later forwards; a token a slot:
+    every other stack) lowers to the kernel it was.
 """
 
 from __future__ import annotations
@@ -304,12 +314,16 @@ def _sublanes(dtype) -> int:
 def _paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
                          o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *,
                          sm_scale: float, groups: int, split: bool,
-                         pages_per_block: int):
+                         pages_per_block: int, lag: int = 0,
+                         lag_rows: int = 0):
     """One grid step = one slot. Its live pages come in by DMA, a block of
     `pages_per_block` at a time, double-buffered; the loop over blocks has a
     DYNAMIC trip count, so a short or idle slot costs what it holds and the
     grid does not grow with the block table. Online softmax a kv head, f32
-    statistics and accumulator."""
+    statistics and accumulator. `lag` > 0: the first `lag_rows` of a kv
+    head's `groups` rows (of each copy, see `split`) see `lag` positions
+    fewer than the slot's length; static, so without it the kernel is the
+    one it was."""
     _, n_kv, T, _ = kbuf.shape
     page = T // pages_per_block
     max_pages = bt_ref.shape[1]
@@ -359,8 +373,16 @@ def _paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
 
         each_copy(b, buf, lambda c: c.wait())
         rows = q_ref.shape[2]
+        reach = length
+        if lag:
+            # each row's own length: the lagging rows of each copy see less
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, T), 0)
+            lagging = row < lag_rows
+            if split:
+                lagging |= (row >= groups) & (row < groups + lag_rows)
+            reach = length - jnp.where(lagging, lag, 0)
         live = (b * T + jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
-                < length)
+                < reach)
         upper = jax.lax.broadcasted_iota(jnp.int32, (rows, T), 0) < groups
         for h in range(n_kv):
             k = kbuf[buf, h]                                   # [T, hd]
@@ -393,7 +415,10 @@ def _paged_decode_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
 
 
 def _paged_decode_pallas(q, kc, vc, layer, block_table, lengths, *,
-                         sm_scale, pages_per_block, interpret):
+                         sm_scale, pages_per_block, interpret, lag=0,
+                         lag_rows=0):
+    """`lag`, `lag_rows`: `_paged_decode_kernel`'s, the rows counted in a
+    kv head's `groups` of q's heads."""
     ns, H, hd = q.shape
     _, _, n_kv, page, _ = kc.shape
     hv = vc.shape[-1]       # values of a width of their own (a mixed stack)
@@ -415,7 +440,7 @@ def _paged_decode_pallas(q, kc, vc, layer, block_table, lengths, *,
     T = pages_per_block * page
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=sm_scale, groups=groups, split=split,
-        pages_per_block=pages_per_block)
+        pages_per_block=pages_per_block, lag=lag, lag_rows=lag_rows)
     def slot_block(d):
         return pl.BlockSpec((1, n_kv, rows, d), lambda s, *_: (s, 0, 0, 0))
 
@@ -448,10 +473,13 @@ def _paged_decode_pallas(q, kc, vc, layer, block_table, lengths, *,
 
 
 def _paged_decode_reference(q, kc, vc, layer, block_table, lengths, *,
-                            sm_scale, packed=False):
+                            sm_scale, packed=False, lag=0, lag_rows=0):
     """The XLA path: gather every page of every slot's table out of the
     layer, float32 softmax over the whole context under a length mask.
-    `packed`: a row holds two kv heads, taken apart after the gather."""
+    `packed`: a row holds two kv heads, taken apart after the gather. `lag`,
+    `lag_rows`: as the kernel's, the first `lag_rows` query heads of each kv
+    head masked `lag` positions short (their weights on the positions past
+    their own length are 0, so v's mask by the slot's length serves both)."""
     ns, H, hd = q.shape
     _, _, n_kv, page, _ = kc.shape
     n_kv *= 2 if packed else 1
@@ -467,7 +495,11 @@ def _paged_decode_reference(q, kc, vc, layer, block_table, lengths, *,
     scores = jnp.einsum("nkgd,npktd->nkgpt", qg, kh).reshape(
         ns, n_kv, groups, ctx) * sm_scale
     live = jnp.arange(ctx)[None, :] < lengths[:, None]          # [ns, ctx]
-    scores = jnp.where(live[:, None, None, :], scores, DEFAULT_MASK_VALUE)
+    seen = live[:, None, None, :]
+    if lag:
+        seen = jnp.arange(ctx) < lengths[:, None, None, None] - jnp.where(
+            jnp.arange(groups) < lag_rows, lag, 0)[:, None]
+    scores = jnp.where(seen, scores, DEFAULT_MASK_VALUE)
     wts = jax.nn.softmax(scores, axis=-1).reshape(
         ns, n_kv, groups, ctx // page, page)
     # What a dead position holds is masked out of v too: 0 x NaN is NaN.
@@ -479,9 +511,10 @@ def _paged_decode_reference(q, kc, vc, layer, block_table, lengths, *,
 def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
                            sm_scale: Optional[float] = None,
                            pages_per_block: Optional[int] = None,
-                           interpret: bool = False):
+                           interpret: bool = False, lag: int = 0):
     """Attention of ONE query token a slot against a paged KV cache (or of a
-    block of B, q `[ns, B, H, hd]` -> `[ns, B, H, hd]`: see below).
+    block of R rows, q `[ns, R, H, hd]` -> `[ns, R, H, hd]`, the first `lag`
+    of them `lag` positions short: see below).
 
     q [ns, H, hd]; kc, vc the WHOLE arena [L, n_pages, KVH, page, hd] (vc's
     rows may be of a width of their own, which is then the result's) and
@@ -491,6 +524,8 @@ def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
     (0: an idle slot, whose output is 0). Slot s reads positions
     0..lengths[s]-1, position t at page block_table[s, t // page], row
     t % page. Query head h reads kv head h // (H // KVH). -> [ns, H, hd].
+    `lag` (static; rows of a slot only, q `[ns, R, H, hd]`): the first `lag`
+    rows read positions 0..lengths[s]-lag-1, the others as above.
 
     On a TPU (or with `interpret`, for tests on the CPU) a Pallas kernel
     that walks only the live pages, in place; elsewhere XLA's gather of the
@@ -508,6 +543,10 @@ def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
         # (the block's own rows among them), so there is no mask to add:
         # the B rows of a kv head's query heads are B x as many query heads
         # of that kv head, [ns, KVH, B x groups, hd] to the kernel.
+        # TWO blocks a slot (`lag` = B, q [ns, 2B, H, hd]: the block before
+        # the open one, then the open one) read the slot's K and V once: the
+        # first block's rows are the first `lag x groups` of a kv head's, and
+        # see positions 0..lengths - lag - 1.
         attention._path_counts["block_decode_pallas" if use
                                else "block_decode_reference"] += 1
         ns, B, H, d = q.shape
@@ -517,9 +556,12 @@ def paged_decode_attention(q, kc, vc, layer, block_table, lengths, *,
         run = functools.partial(
             _paged_decode_pallas, pages_per_block=pages_per_block,
             interpret=interpret) if use else _paged_decode_reference
-        out = run(wide, kc, vc, layer, block_table, lengths, sm_scale=scale)
+        out = run(wide, kc, vc, layer, block_table, lengths, sm_scale=scale,
+                  lag=lag, lag_rows=lag * (H // n_kv))
         return out.reshape(ns, n_kv, B, H // n_kv, -1).transpose(
             0, 2, 1, 3, 4).reshape(ns, B, H, -1)
+    if lag:
+        raise NotImplementedError("a token a slot has no row to lag")
     attention._path_counts["decode_pallas" if use else "decode_reference"] += 1
     # Heads of half a tile, two to a row (see the top): q's own width says so.
     packed = hd == 2 * q.shape[-1] and vc.shape[4] == hd

@@ -249,7 +249,8 @@ class Engine:
         self._topk = np.zeros(n_slots, np.int32)
         self._skeys = np.zeros((n_slots, 2), np.uint32)
         # The slots' state on the device: the last token (a model of blocks:
-        # the open block, `[n_slots, block]`) and the position.
+        # the pending block and the open one, `[n_slots, 2 * block]`) and the
+        # position.
         self._last_d = self._slots_last()
         self._pos_d = jnp.zeros(n_slots, jnp.int32)
         self.peak_pages_used = 0
@@ -275,11 +276,14 @@ class Engine:
         self.rider_tokens = 0
         self.rider_steps = 0
         # A model of blocks: the forwards its decode chunks ran (a block is
-        # `Programs.block_forwards` of them), the positions its live slots'
-        # blocks covered, and the prompt ids among those (a prompt's tail,
-        # which opens the slot's first block): the tokens the blocks made are
-        # `block_tokens - tail_tokens`.
+        # `Programs.block_forwards` of them), the blocks of live slots whose
+        # first forward also committed the block before (every block but a
+        # slot's first), the positions its live slots' blocks covered, and
+        # the prompt ids among those (a prompt's tail, which opens the slot's
+        # first block): the tokens the blocks made are `block_tokens -
+        # tail_tokens`.
         self.denoise_forwards = 0
+        self.commits_rode = 0
         self.block_tokens = 0
         self.tail_tokens = 0
         # Positions the active slots held when each chunk was dispatched:
@@ -400,7 +404,8 @@ class Engine:
 
     def _slots_last(self):
         """The slots' `last` as the programs take it, zeroed."""
-        shape = (self.n_slots,) + ((self._block,) if self._block > 1 else ())
+        shape = (self.n_slots,) + (
+            (2 * self._block,) if self._block > 1 else ())
         return self._jnp.zeros(shape, self._jnp.int32)
 
     def _warm_width(self, caches, width: int):
@@ -632,11 +637,14 @@ class Engine:
         the tokens decoded are the two added; padding is
         `prefill_padded_tokens` over it plus `prefill_tokens`.
         A model of blocks (`block` > 1) adds `denoise_forwards` (the
-        forwards of `n_slots * block` rows its decode chunks ran, a block's
-        denoising steps and its commit), `block_tokens` (the positions the
-        live slots' blocks covered) and `tail_tokens` (the prompt ids among
-        those, a prompt's tail in its slot's first block): forwards over
-        `block_tokens - tail_tokens`, a slot, is what a token cost.
+        forwards its decode chunks ran, a block's denoising steps: the first
+        of `2 * n_slots * block` rows, the block before beside the open one,
+        the others of `n_slots * block`), `commits_rode` (the blocks of live
+        slots whose first forward committed the block before it: all but a
+        slot's first), `block_tokens` (the positions the live slots' blocks
+        covered) and `tail_tokens` (the prompt ids among those, a prompt's
+        tail in its slot's first block): forwards over `block_tokens -
+        tail_tokens`, a slot, is what a token cost.
         `decode_chunks_sampling` are the chunks dispatched with a live slot
         at a temperature above 0 (the span's `sampling` counts the slots):
         the others' steps took no top-k (`serving.sample_tokens`).
@@ -676,6 +684,7 @@ class Engine:
         if self._block > 1:
             out.update(block=self._block,
                        denoise_forwards=self.denoise_forwards,
+                       commits_rode=self.commits_rode,
                        block_tokens=self.block_tokens,
                        tail_tokens=self.tail_tokens)
         if self._sparse:
@@ -1130,16 +1139,22 @@ class Engine:
                 self.window_kv_tokens += ring
                 routed.update(window_kv_tokens=ring)
             if self._block > 1:
-                # What the chunk's blocks are: forwards of `rows` rows each,
+                # What the chunk's blocks are: the forwards it runs, the
+                # widest of `rows` rows (a block's first: the pending block
+                # beside the open one), the live slots' blocks whose pending
+                # block that forward commits (a slot's first has none),
                 # positions covered in the live slots, prompt tails included.
                 blocks = self.chunk // self._block
                 forwards = blocks * self._programs.block_forwards
                 committed = len(plan) * self.chunk
+                rode = sum(min(blocks, int(S - self._pos[slot]) // self._block)
+                           - opens for slot, *_, opens in plan)
                 self.denoise_forwards += forwards
+                self.commits_rode += rode
                 self.block_tokens += committed
                 routed.update(blocks=blocks, forwards=forwards,
-                              rows=self.n_slots * self._block,
-                              committed=committed)
+                              rows=2 * self.n_slots * self._block,
+                              committed=committed, commits_rode=rode)
             with tracing.span("serve.engine.decode_dispatch", useful=useful,
                               capacity=self.n_slots * self.chunk,
                               active=len(plan), sampling=sampling,

@@ -1486,7 +1486,7 @@ def test_the_engines_spans_and_scopes_carry_what_the_sdar_readers_read():
     cfg = adapter.build_config(dict(m, **adapter.REHEARSE), {
         "params": "float32", "activations": "float32"}, 128)
     built = build_programs(cfg, 2, 8, 16, 17)
-    assert (built.block, built.block_forwards) == (4, 3) \
+    assert (built.block, built.block_forwards) == (4, 2) \
         and not built.adopts and not built.takes_riders \
         and not built.by_slot
     params = jax.eval_shape(lambda: fuse_qkv(
@@ -1497,7 +1497,7 @@ def test_the_engines_spans_and_scopes_carry_what_the_sdar_readers_read():
         return jax.ShapeDtypeStruct(shape, dtype)
 
     lowered = built.decode.lower(
-        params, caches, arg((2, 8), jnp.int32), arg((2, 4), jnp.int32),
+        params, caches, arg((2, 8), jnp.int32), arg((2, 8), jnp.int32),
         arg((2,), jnp.int32), arg((2,), jnp.bool_), arg((2,), jnp.float32),
         arg((2,), jnp.int32), arg((2, 2), jnp.uint32))
     text = lowered.as_text(debug_info=True)
@@ -1511,7 +1511,7 @@ def test_the_engines_spans_and_scopes_carry_what_the_sdar_readers_read():
         0.0, 0, arg((2,), jnp.uint32), None)
     assert lowered.as_text().startswith("module @jit_prefill ")
     src = open(engine_mod.__file__).read()
-    for name in ("blocks", "forwards", "rows", "committed",
+    for name in ("blocks", "forwards", "rows", "committed", "commits_rode",
                  "denoise_forwards", "block_tokens", "tail_tokens"):
         assert f"{name}=" in src, name
     assert 'kind="opening"' in src

@@ -202,6 +202,84 @@ def test_blocks_through_the_pages_are_plain_attention(interpret):
                   else "block_decode_reference"] >= 4
 
 
+@pytest.mark.parametrize("pos", [24, 32], ids=["inside", "page_start"])
+@pytest.mark.parametrize("groups", [1, 8])
+@pytest.mark.parametrize("B", [2, 4])
+def test_two_blocks_in_one_call_are_two_calls(B, groups, pos):
+    """The fused call: q [ns, 2B, H, hd], the block before the open one
+    (positions pos - B..pos - 1, which sees 0..pos - 1) then the open one
+    (which sees 0..pos + B - 1), `lag` = B. The interpreted kernel reads what
+    two calls of the one-block kernel read (`lengths - B`, `lengths`), to the
+    bit, in bfloat16 at 8 query heads a kv head (the cell's: the split of p
+    doubles a kv head's rows) and in float32 at one, and what the XLA path
+    reads to rounding; with `pos` at a page's first row the lagging block
+    lies in the page before. An idle slot reads nothing."""
+    KVH, hd, page, ns = 2, 128, 16, 3
+    H = KVH * groups
+    rng = np.random.default_rng(B * 100 + groups * 10 + pos)
+    bt = jnp.asarray([[3, 5, 1], [0, 0, 0], [7, 2, 4]], jnp.int32)
+    lengths = jnp.asarray([pos + B, 0, pos + B - page], jnp.int32)
+    for dtype, tol in [(jnp.float32, 2e-5) if groups == 1
+                       else (jnp.bfloat16, 2e-2)]:
+        kc, vc = (jnp.asarray(rng.standard_normal((1, 9, KVH, page, hd)),
+                              dtype) for _ in range(2))
+        q = jnp.asarray(rng.standard_normal((ns, 2 * B, H, hd)), dtype)
+        fused = paged_kv.paged_decode_attention(
+            q, kc, vc, 0, bt, lengths, interpret=True, lag=B)
+        two = jnp.concatenate([
+            paged_kv.paged_decode_attention(
+                q[:, :B], kc, vc, 0, bt, jnp.maximum(lengths - B, 0),
+                interpret=True),
+            paged_kv.paged_decode_attention(
+                q[:, B:], kc, vc, 0, bt, lengths, interpret=True)], axis=1)
+        assert fused.shape == (ns, 2 * B, H, hd)
+        assert np.array_equal(np.asarray(fused, np.float32),
+                              np.asarray(two, np.float32))
+        xla = paged_kv.paged_decode_attention(q, kc, vc, 0, bt, lengths,
+                                              lag=B)
+        np.testing.assert_allclose(np.asarray(xla, np.float32),
+                                   np.asarray(two, np.float32), atol=tol)
+        assert not np.asarray(fused[1], np.float32).any()
+        # the lag is a mask: without it the first block reads B keys more
+        assert np.abs(np.asarray(paged_kv.paged_decode_attention(
+            q, kc, vc, 0, bt, lengths, interpret=True)[0, :B] - two[0, :B],
+            np.float32)).max() > 1e-3
+    with pytest.raises(NotImplementedError, match="no row to lag"):
+        paged_kv.paged_decode_attention(q[:, 0], kc, vc, 0, bt, lengths,
+                                        lag=B)
+
+
+def test_a_token_and_one_block_lower_to_the_parents_kernel_text():
+    """The row lag is static: a call without it (a token a slot, every other
+    stack's step; one block a slot, a block's forwards 2..T) lowers to the
+    text it lowered to on PR 54's parent, the interpreted kernel's body
+    inlined: the digests were taken there (and on this tree: equal, as the
+    two trees' jaxprs of the TPU call at the cell's sizes are). Two blocks
+    with the lag are another text."""
+    import hashlib
+    KVH, page, hd, ns = 2, 16, 128, 3
+    kc, vc = paged_kv.empty(1, 9, KVH, page, hd, jnp.bfloat16)
+    bt, lengths = jnp.zeros((ns, 3), jnp.int32), jnp.zeros((ns,), jnp.int32)
+
+    def text(shape, **kw):
+        return jax.jit(lambda q, kc, vc, bt, lengths:
+                       paged_kv.paged_decode_attention(
+                           q, kc, vc, 0, bt, lengths, interpret=True, **kw)
+                       ).lower(jnp.zeros(shape, jnp.bfloat16), kc, vc, bt,
+                               lengths).as_text()
+
+    def digest(t):
+        return hashlib.sha256(t.encode()).hexdigest()
+
+    assert digest(text((ns, 4, hd))) == (
+        "0abccb973a33bca65a2991d7dd695747dd0f63c62cec51cc3ad71f144aa04dee")
+    one = text((ns, 4, 4, hd))
+    assert digest(one) == (
+        "f8bf795708cf39333acdd2307bcbf20eb4730f223eea8d29a6e2e466d64c9362")
+    assert text((ns, 4, 4, hd), lag=0) == one
+    assert text((ns, 4, 4, hd), lag=2) != one
+
+
 # ---------------------------------------------------------------------------
 # (c) through the engine, float32
 # ---------------------------------------------------------------------------
@@ -210,6 +288,7 @@ CASES = [  # (block, steps, prompt length, tokens)
     (4, 2, 8, 12), (4, 2, 9, 12), (4, 2, 10, 9), (4, 2, 11, 8),   # r = 0..3
     (4, 2, 3, 7),          # a prompt shorter than a block
     (4, 2, 13, 2),         # max_tokens ends inside the first block
+    (4, 2, 12, 10),        # and inside the second chunk's first block
     (4, 1, 10, 11), (4, 4, 9, 10), (8, 2, 13, 17), (2, 2, 7, 9)]
 
 
@@ -251,20 +330,99 @@ def test_engine_generates_the_references_tokens(engines, B, T, L, n):
                for s in range(T))
 
 
+def test_an_engine_decodes_through_the_kernel_with_the_row_lag(
+        kernel_in_interpret_mode):
+    """The whole engine through the interpreted `paged_decode` kernel, the
+    fused forward's call with the lag and the plain forward's without: the
+    reference's tokens, for a prompt whose first block has nothing pending
+    and whose blocks cross a page (pages of 16; positions 8..27)."""
+    model = _model()
+    eng = _engine(model, n_slots=2)
+    try:
+        ids = _prompt(10, seed=12)
+        assert _serve(eng, ids, 18) == ref.generate(eng.params, model, ids,
+                                                    18)
+        paths = attention.attention_path_counts()
+        assert paths["block_decode_pallas"] >= 2
+    finally:
+        eng.stop()
+
+
+def test_a_slots_next_tenant_inherits_no_pending_block():
+    """One slot: the second request is admitted into the slot the first just
+    left, whose stream ended inside a chunk, so the slot's state on the
+    device still holds the first's pending block: `poke` sets "nothing
+    pending" with the opening block, and the second is served as the
+    reference generates it (a pending block inherited would be written over
+    the prompt's last whole block, positions 8..11 here, before the first
+    forward reads them)."""
+    model = _model()
+    eng = _engine(model, n_slots=1)
+    try:
+        assert eng._last_d.shape == (1, 8)
+        _serve(eng, _prompt(9, seed=31), 6)
+        assert (np.asarray(eng._last_d)[0, :4] >= 0).all()  # a block pending
+        ids = _prompt(14, seed=32)
+        got = _serve(eng, ids, 11)
+        assert got == ref.generate(eng.params, model, ids, 11)
+    finally:
+        eng.stop()
+
+
+def test_a_first_blocks_pending_rows_leave_the_prompts_last_block():
+    """The programs alone, one slot: a prompt of 10 (two whole blocks kept,
+    a tail of 2) opens with nothing pending, `first` = -1 x 4, the tail, -1
+    x 2; a chunk of two blocks then leaves the K and V of positions 0..7, the
+    prompt's last whole block among them, as the prefill wrote them, to the
+    bit (the first fused forward's pending rows, positions 4..7, go to the
+    null page), writes the rows of 8..15, and leaves the second block
+    pending."""
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.serving import build_programs
+    cfg = ADAPTER.build_config(_model(), F32, 128)
+    built = build_programs(cfg, 1, 8, 16, 5)
+    params = fuse_qkv(_params(cfg), cfg)
+    ids = _prompt(10, seed=3)
+    pages = jnp.asarray([2, 0, 0, 0, 0, 0, 0, 0], jnp.int32)
+    caches, first, _ = built.prefill(
+        params, built.empty(), pages,
+        jnp.asarray([ids + [0] * 54], jnp.int32), 10, 0.0, 0,
+        jnp.zeros(2, jnp.uint32), None)
+    assert np.asarray(first).tolist() == [-1] * 4 + ids[8:] + [-1] * 2
+    kept = [np.asarray(c)[:, 2] for c in (caches.kc, caches.vc)]
+    last, pos = built.poke(jnp.zeros((1, 8), jnp.int32),
+                           jnp.zeros(1, jnp.int32), 0, first, 8)
+    caches, last, pos, out, _ = built.decode(
+        params, caches, pages[None], last, pos, jnp.ones(1, bool),
+        jnp.zeros(1, jnp.float32), jnp.zeros(1, jnp.int32),
+        jnp.zeros((1, 2), jnp.uint32))
+    for before, cache in zip(kept, (caches.kc, caches.vc)):
+        after = np.asarray(cache)[:, 2]
+        assert np.array_equal(before[:, :, :8], after[:, :, :8])
+        assert (np.abs(after[:, :, 8:] - before[:, :, 8:]).max(axis=(0, 1, 3))
+                > 0).all()
+    out, last = np.asarray(out)[0], np.asarray(last)[0]
+    assert out[:2].tolist() == ids[8:] and int(pos[0]) == 16
+    assert last.tolist() == out[4:].tolist() + [-1] * 4
+
+
 def test_the_engines_counters_and_paths_say_blocks(engines):
     eng = engines(4, 2)
     before = eng.counters()
     _serve(eng, _prompt(10), 9)        # tail 2; 9 tokens: two chunks of 8
     c = eng.counters()
     assert c["block"] == 4
-    assert c["denoise_forwards"] - before["denoise_forwards"] == 2 * 2 * 3
+    # two chunks of two blocks of two forwards: no forward is the commit's
+    assert c["denoise_forwards"] - before["denoise_forwards"] == 2 * 2 * 2
+    # every block but the slot's first committed the block before it
+    assert c["commits_rode"] - before["commits_rode"] == 3
     assert c["block_tokens"] - before["block_tokens"] == 16
     assert c["tail_tokens"] - before["tail_tokens"] == 2
     assert c["decode_useful_tokens"] - before["decode_useful_tokens"] == 9
     paths = attention.attention_path_counts()
     assert paths["block_fwd_reference"] and paths["block_decode_reference"]
     programs = eng._programs
-    assert (programs.block, programs.block_forwards) == (4, 3)
+    assert (programs.block, programs.block_forwards) == (4, 2)
     assert not programs.takes_riders and not programs.adopts
     with pytest.raises(NotImplementedError, match="block_length > 1"):
         eng.submit_prefilled(None, None, 8, 0, 4)

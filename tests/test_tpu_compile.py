@@ -772,10 +772,12 @@ def test_sdar_block_programs_keep_the_pages_in_place_on_v5e(
         topo, program, monkeypatch):
     """Generation by blocks at the cell's sizes (benchmark/configs/
     sdar-30b-a3b-chat-serve.json: 6 layers of the published widths, every
-    expert, 64 slots, a chunk of two blocks of 4): the decode program, six
-    forwards of 256 rows with the pages in the loops' carry, and the
+    expert, 64 slots, a chunk of two blocks of 4): the decode program, four
+    forwards (two of 512 rows, the pending block beside the open one, and two
+    of 256) with the pages in the loops' carry, and the
     1,024-row prefill under the block mask. Decode's attention is the
-    `paged_decode` kernel at 4 rows a slot (counted `block_decode_pallas`), a
+    `paged_decode` kernel at 8 and at 4 rows a slot (counted
+    `block_decode_pallas`; at 8 the first 4 lag a block: 128 rows a kv head), a
     prompt's the flash kernel with the block comparison in its diagonal tiles
     (`block_flash_fwd`, counted `block_fwd_pallas`), the experts the grouped
     matmul with no copy of a stack; the arena is donated and aliases the
@@ -804,7 +806,7 @@ def test_sdar_block_programs_keep_the_pages_in_place_on_v5e(
 
     built = build_programs(cfg, ns, eng["decode_chunk"], page,
                            eng["kv_pages"])
-    assert (built.block, built.block_forwards) == (B, 3) \
+    assert (built.block, built.block_forwards) == (B, 2) \
         and not built.takes_riders and not built.adopts
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype), jax.eval_shape(
@@ -817,7 +819,7 @@ def test_sdar_block_programs_keep_the_pages_in_place_on_v5e(
     if program == "decode":
         lowered = built.decode.lower(
             params, caches, sds((ns, maxp), jnp.int32),
-            sds((ns, B), jnp.int32), sds((ns,), jnp.int32),
+            sds((ns, 2 * B), jnp.int32), sds((ns,), jnp.int32),
             sds((ns,), jnp.bool_), sds((ns,), jnp.float32),
             sds((ns,), jnp.int32), sds((ns, 2), jnp.uint32))
         kernels, paths = ["paged_decode", "grouped_matmul"], [
