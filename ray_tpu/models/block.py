@@ -274,8 +274,10 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
     The branch is multiplied by `cfg.residual_scale` before the add.
     A layer is sparse if it has a router: a sparse model's leading dense
     layers (`cfg.first_dense`) have none. The router's variant, the share of
-    the experts held (the count is then per HELD expert) and a shared expert
-    are `cfg`'s (`LlamaConfig.routing`, `experts_held`, `n_shared_experts`)."""
+    the experts held (the count is then per HELD expert), a shared expert and
+    the expert's form (gated or not, its activation) are `cfg`'s
+    (`LlamaConfig.routing`, `experts_held`, `n_shared_experts`, `ffn`,
+    `up_out_in`); an ungated expert has no `w_gate` in `lp`."""
     dt = cfg.dtype
     with jax.named_scope("mlp_norm"):
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -285,15 +287,18 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
             routing = cfg.routing()
             if routing is not None:
                 routing = dict(routing, bias=lp["router_bias"])
+            def leaf(name):     # None: an ungated expert's gate
+                return lp[name].astype(dt) if name in lp else None
+
             more = dict(
-                routing=routing, held=cfg.experts_held,
-                shared=tuple(lp["ws_" + k].astype(dt)
-                             for k in ("gate", "up", "down"))
+                routing=routing, held=cfg.experts_held, act=cfg.ffn_act,
+                out_in=cfg.up_out_in,
+                shared=tuple(leaf("ws_" + k) for k in ("gate", "up", "down"))
                 if cfg.n_shared_experts else None)
             out, aux, counts = moe_ffn(
                 h.reshape(-1, h.shape[-1]), lp["router"].astype(dt),
-                lp["w_up"].astype(dt), lp["w_gate"].astype(dt),
-                lp["w_down"].astype(dt), top_k=cfg.top_k_experts,
+                leaf("w_up"), leaf("w_gate"), leaf("w_down"),
+                top_k=cfg.top_k_experts,
                 norm_topk_prob=cfg.norm_topk_prob,
                 live=None if live is None else live.reshape(-1), layer=layer,
                 **more)
@@ -374,22 +379,26 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
                  layer=None, active=None
                  ) -> Tuple[jax.Array, Any, jax.Array]:
     """x + residual_scale * mixer(norm(x)) for a Mamba-2 layer
-    (`cfg.ssm_heads` heads of `Di / H` channels, one group of B and C; the
-    Granite 4.0-H family's, which is Bamba's), under `mamba_mixer`'s scopes:
+    (`cfg.ssm_heads` heads of `Di / H` channels, `cfg.ssm_groups` G groups of
+    B and C, head h reading group `h // (H / G)`: one in the Granite 4.0-H
+    family's, which is Bamba's; eight in the Nemotron-H family's), under
+    `mamba_mixer`'s scopes:
 
-      z, xBC, dt = split(norm(x) W_in, [Di, Di + 2N, H])  (no bias)  ssm_in
+      z, xBC, dt = split(norm(x) W_in, [Di, Di + 2GN, H]) (no bias)  ssm_in
       xBC   = silu(b + sum_k w[k] * xBC_{t-K+1+k})   (over x, B and C)  conv
-      u, B, C = split(xBC);  dt = softplus(dt + b_dt) (float32, a head),
-      A = -exp(A_log) (a head)                                   ssm_params
+      u, B, C = split(xBC, [Di, GN, GN]);
+      dt = softplus(dt + b_dt) (float32, a head), A = -exp(A_log) (a head)
+                                                                 ssm_params
       s_t   = exp(dt_t A) s_{t-1} + (dt_t u_t) (x) B_t;
-      y_t   = s_t . C_t + D u_t                  (a head's scalars)    scan
+      y_t   = s_t . C_t + D u_t     (a head's scalars, its group's B, C) scan
       out   = x + residual_scale * rmsnorm(y * silu(z); w_norm) W_out
-              (the gate BEFORE the norm, which is over all Di)      ssm_out
+              (the gate BEFORE the norm, which is over each group's Di / G
+              channels apart: all Di where there is one group)     ssm_out
 
     x `[S, D]`, one sequence, from `state` `[N, Di]` and `window` `[K - 1, Di
-    + 2N]` (None: a sequence's start), through `ops.ssm.ssd_scan`; rows at
+    + 2GN]` (None: a sequence's start), through `ops.ssm.ssd_scan`; rows at
     and past `length` leave state and window as they were. Or, with `step`,
-    x `[ns, D]`, one token a slot, from `window` `[K - 1, ns, Di + 2N]` and
+    x `[ns, D]`, one token a slot, from `window` `[K - 1, ns, Di + 2GN]` and
     `state` the slots' WHOLE state (`ops/slot_state.py`'s pair), of which
     layer `layer`'s rows of the slots `active` marks are read, updated and
     written where they lie, in one visit (`slot_state.step_layer`): the state
@@ -397,10 +406,11 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
     (and finite). -> (out, state, window)."""
     dt = cfg.dtype
     Di, N, eps = cfg.ssm_inner, cfg.ssm_state, cfg.norm_eps
+    G = cfg.ssm_groups
     with jax.named_scope("ssm_in"):
         h = rms_norm(x, lp["norm"], eps)
         z, xbc, r = jnp.split(h @ lp["in_proj"].astype(dt),
-                              [Di, 2 * Di + 2 * N], axis=-1)
+                              [Di, Di + cfg.ssm_conv_channels], axis=-1)
     with jax.named_scope("conv"):
         if step:    # each slot a sequence of one row, its window its own
             xbc, window = jax.vmap(causal_conv, (0, None, None, 1), (0, 1))(
@@ -411,7 +421,9 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
                                       length)
         xbc = jax.nn.silu(xbc).astype(dt)
     with jax.named_scope("ssm_params"):
-        u, b, c = jnp.split(xbc, [Di, Di + N], axis=-1)
+        u, b, c = jnp.split(xbc, [Di, Di + G * N], axis=-1)
+        if G > 1:   # a group's B and C apart: [rows, G, N]
+            b, c = (t.reshape(-1, G, N) for t in (b, c))
         step_size = jax.nn.softplus(r.astype(jnp.float32)
                                     + lp["dt_bias"].astype(jnp.float32))
         a = -jnp.exp(lp["A_log"].astype(jnp.float32))
@@ -424,7 +436,12 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
                                 length)
     with jax.named_scope("ssm_out"):
         gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        y = rms_norm(gated, lp["w_norm"], eps).astype(dt)
+        if G > 1:   # the norm over each group's channels apart
+            y = rms_norm(gated.reshape(-1, G, Di // G),
+                         lp["w_norm"].reshape(G, Di // G), eps).reshape(-1, Di)
+        else:
+            y = rms_norm(gated, lp["w_norm"], eps)
+        y = y.astype(dt)
         return x + scaled(y @ lp["out_proj"].astype(dt), cfg), state, window
 
 
@@ -638,7 +655,7 @@ def expert_stacks(layers: Dict[str, jax.Array], cfg
     if "wq" in layers or "w_ukv" in layers:
         raise ValueError("a serving program takes `fuse_qkv(params)`: one "
                          "q/k/v projection stack, not a matrix each")
-    names = ("w_gate", "w_up", "w_down") \
+    names = tuple(k for k in ("w_gate", "w_up", "w_down") if k in layers) \
         if cfg.n_experts > 0 and "router" in layers else ()
     return ({k: v for k, v in layers.items() if k not in names},
             {k: layers[k].astype(cfg.dtype) for k in names})

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -29,6 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.models.block import attention_inputs, feed_forward
 from ray_tpu.ops.attention import (flash_attention, pallas_eligible,
                                    repeat_kv)
+from ray_tpu.ops.moe import up_out_in
 from ray_tpu.ops.norms import (apply_rope, mrope_tables, rms_norm,
                                rope_frequencies)
 from ray_tpu.ops.ring_attention import ring_attention
@@ -73,9 +75,10 @@ class LlamaConfig:
     # is a Mamba mixer (ops/ssm.py; `models.block.mamba_mixer`) of inner
     # width ssm_expand * d_model, ssm_state states a channel, a causal
     # convolution over ssm_conv inputs and a time-step projection of rank
-    # ssm_dt_rank; every layer keeps its feed-forward. The parameters are two
-    # stacks, `layers` (the attention layers, in order) and `mamba` (the
-    # rest), so no layer holds weights of the kind it is not.
+    # ssm_dt_rank; every layer keeps its feed-forward (but under
+    # `layer_parts`, below). The parameters are two stacks, `layers` (the
+    # attention layers, in order) and `mamba` (the rest), so no layer holds
+    # weights of the kind it is not.
     ssm_state: int = 0
     ssm_expand: int = 2
     ssm_conv: int = 4
@@ -84,11 +87,32 @@ class LlamaConfig:
     # ssm_heads > 0 => the state-space layers are Mamba-2's
     # (`models.block.mamba2_mixer`): ssm_heads heads of ssm_inner / ssm_heads
     # channels, ONE scalar decay, time step and D a head, B and C of
-    # ssm_state numbers shared by the heads, the convolution over x, B and C
-    # together, a gated RMS norm before the output projection; no
-    # ssm_dt_rank. Either hybrid's feed-forward may be sparse (n_experts > 0),
-    # a share of it held (experts_held), with shared experts beside it.
+    # ssm_state numbers in `ssm_groups` groups (head h reads group h //
+    # (ssm_heads / ssm_groups); one group: shared by every head), the
+    # convolution over x, B and C together, a gated RMS norm (over each
+    # group's channels) before the output projection; no ssm_dt_rank.
+    # ssm_head_dim > 0: a head's channels, where the inner width is not
+    # ssm_expand * d_model (`ssm_inner`). Either hybrid's feed-forward may be
+    # sparse (n_experts > 0), a share of it held (experts_held), with shared
+    # experts beside it.
     ssm_heads: int = 0
+    ssm_groups: int = 1
+    ssm_head_dim: int = 0
+    # A hybrid whose layers are ONE part each (the Nemotron-H family):
+    # `layer_parts` has a letter a layer, as published: "M" a Mamba-2 mixer
+    # (ssm_heads), "E" the sparse experts (with their shared expert), "*"
+    # attention; block i is h + part_i(rmsnorm(h)) and nothing else, so no
+    # layer keeps a feed-forward beside its mixer. `attn_layers` is then the
+    # places of the "*". The parameters are a stack a kind, none holding a
+    # weight of a kind it is not: `mamba` (the mixers), `experts` (a norm, the
+    # router, the experts held, the shared expert) and `layers` (attention);
+    # `segments()` has the order they run in.
+    layer_parts: Optional[str] = None
+    # The feed-forward's expert, one of the two forms there are: "swiglu",
+    # silu(x W_gate) * (x W_up) through W_down, or "relu2", relu(x W_up)^2
+    # W_down (no `w_gate` leaf, the shared expert's neither), which the
+    # one-part stack (`layer_parts`) serves.
+    ffn: str = "swiglu"
     # False: attention takes no position signal at all (no RoPE), as in a
     # hybrid whose state-space layers carry the order of the sequence.
     rope: bool = True
@@ -211,6 +235,8 @@ class LlamaConfig:
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.layer_parts is not None:
+            self._check_parts()
         if self.attn_layers is not None:
             object.__setattr__(self, "attn_layers",
                                tuple(sorted(self.attn_layers)))
@@ -244,12 +270,14 @@ class LlamaConfig:
                              "(kv_lora_rank > 0), mixed attention "
                              "(attn_pattern) and the state-space hybrid "
                              "(ssm_state)")
-        if self.router_score != "softmax" and not segmented:
-            raise ValueError("the sigmoid router is served by three of the "
+        if self.router_score != "softmax" and not (segmented
+                                                   or self.layer_parts):
+            raise ValueError("the sigmoid router is served by four of the "
                              "stacks that run as segments: latent attention "
                              "(kv_lora_rank > 0), mixed attention "
-                             "(attn_pattern) and short-convolution layers "
-                             "(conv_layers)")
+                             "(attn_pattern), short-convolution layers "
+                             "(conv_layers) and the state-space hybrid of "
+                             "one-part layers (layer_parts)")
         if self.n_shared_experts and not (
                 self.n_experts and (self.latent or self.ssm_state)):
             raise ValueError("shared experts are served beside sparse "
@@ -265,6 +293,19 @@ class LlamaConfig:
             raise ValueError("ssm_heads: Mamba-2's heads, of ssm_inner / "
                              "ssm_heads channels each, in a hybrid stack "
                              "(ssm_state, attn_layers)")
+        if self.ssm_groups < 1 or (self.ssm_groups > 1 and (
+                not self.ssm_heads or self.ssm_heads % self.ssm_groups)):
+            raise ValueError("ssm_groups: groups of B and C, each read by "
+                             "ssm_heads / ssm_groups of Mamba-2's heads")
+        if self.ssm_head_dim and not self.ssm_heads:
+            raise ValueError("ssm_head_dim: the channels of one of Mamba-2's "
+                             "heads (ssm_heads)")
+        if self.ffn != "swiglu" and (self.layer_parts is None
+                                     or self.ffn != "relu2"):
+            raise ValueError("ffn 'swiglu' or 'relu2': a feed-forward other "
+                             "than the gated silu one is served as the "
+                             "experts of a stack of one-part layers "
+                             "(layer_parts)")
         if self.ssm_state and self.index_topk:
             raise ValueError("a state-space hybrid has plain attention: no "
                              "indexer")
@@ -278,6 +319,25 @@ class LlamaConfig:
             if not 0 <= offset < offset + count <= self.n_experts:
                 raise ValueError("experts_held: (offset, count) inside the "
                                  "router's n_experts")
+
+    def _check_parts(self) -> None:
+        parts = self.layer_parts
+        if len(parts) != self.n_layers or set(parts) - set("ME*"):
+            raise ValueError("layer_parts: one of 'M' (a Mamba-2 mixer), 'E' "
+                             "(the experts) or '*' (attention) a layer")
+        if self.attn_layers is not None:
+            raise ValueError("layer_parts says which layers are attention: "
+                             "no attn_layers beside it")
+        if not (self.ssm_state and self.ssm_heads and self.n_experts):
+            raise ValueError("layer_parts: a stack of one-part layers has "
+                             "Mamba-2 mixers (ssm_state, ssm_heads) and "
+                             "sparse experts (n_experts > 0)")
+        if self.multipliers:
+            raise ValueError("embed_scale, residual_scale, logit_scale and "
+                             "attn_scale are not served by the stack of "
+                             "one-part layers (layer_parts)")
+        object.__setattr__(self, "attn_layers", tuple(
+            i for i, part in enumerate(parts) if part == "*"))
 
     def _check_mixed(self) -> None:
         if self.latent or self.ssm_state or self.index_topk or self.qk_norm \
@@ -397,11 +457,26 @@ class LlamaConfig:
         return self.experts_held[1] if self.experts_held else self.n_experts
 
     @property
+    def ffn_gated(self) -> bool:
+        return self.ffn == "swiglu"
+
+    @property
+    def ffn_act(self) -> str:
+        """`ops.moe.activation`'s name for the form's."""
+        return "silu" if self.ffn == "swiglu" else self.ffn
+
+    @property
+    def up_out_in(self) -> bool:
+        """The experts' up and gate matrices are held `[d_ff, d_model]`: the
+        one-part stack draws them by `ops.moe.up_out_in`'s rule, from the
+        width; the older stacks hold `[in, out]` whatever theirs."""
+        return self.layer_parts is not None and up_out_in(self.d_ff)
+
+    @property
     def softmax_scale(self) -> float:
         """What attention multiplies q . k by: head width^-1/2 (or
         `attn_scale`, where the model publishes its own), times YaRN's m^2, m
         = 0.1 mscale_all_dim ln(factor) + 1."""
-        import math
         if not self.latent:
             return self.attn_scale or self.head_dim ** -0.5
         scale = (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
@@ -421,7 +496,27 @@ class LlamaConfig:
 
     @property
     def ssm_inner(self) -> int:
+        """The state-space layers' inner width Di: Mamba-2's heads times a
+        head's channels where the model says them, else ssm_expand x
+        d_model."""
+        if self.ssm_head_dim:
+            return self.ssm_heads * self.ssm_head_dim
         return self.ssm_expand * self.d_model
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a recurrent state a slot: the state-space ones."""
+        if self.layer_parts is not None:
+            return self.layer_parts.count("M")
+        return self.n_layers - self.kv_layers if self.ssm_state else 0
+
+    @property
+    def sparse_layers(self) -> int:
+        """Layers that have a router and experts: what a program's routing
+        counts are summed over."""
+        if self.layer_parts is not None:
+            return self.layer_parts.count("E")
+        return self.n_layers - self.first_dense if self.n_experts else 0
 
     @property
     def multipliers(self) -> bool:
@@ -432,15 +527,18 @@ class LlamaConfig:
     @property
     def ssm_conv_channels(self) -> int:
         """Channels the state-space layers' convolution runs over: the inner
-        width, and under Mamba-2 B and C beside it."""
-        return self.ssm_inner + (2 * self.ssm_state if self.ssm_heads else 0)
+        width, and under Mamba-2 every group's B and C beside it."""
+        return self.ssm_inner + (2 * self.ssm_groups * self.ssm_state
+                                 if self.ssm_heads else 0)
 
     def segments(self) -> Tuple[Tuple[str, int, int], ...]:
         """The stack in the order it runs, each segment a kind and ordinals
         lo..hi-1 into that kind's stack of parameters. A uniform stack is one
         segment, ("layers", 0, n_layers). A hybrid: ("mamba", lo, hi), a run
         of state-space layers in `mamba`, or ("attn", a, a + 1), one
-        attention layer in `layers`.
+        attention layer in `layers`; a hybrid of one-part layers
+        (`layer_parts`) likewise, ("experts", lo, hi) a run of expert layers
+        in `experts` beside them.
         A latent-attention stack, each kind the name of its stack:
         ("dense", 0, first_dense), the leading dense layers, if it has any,
         then ("layers", 0, n_layers - first_dense), the rest. A
@@ -468,6 +566,17 @@ class LlamaConfig:
             return lead + (("layers", 0, self.n_layers - self.first_dense),)
         if self.attn_layers is None:
             return (("layers", 0, self.n_layers),)
+        if self.layer_parts is not None:
+            names = {"M": "mamba", "E": "experts", "*": "attn"}
+            out, at = [], dict.fromkeys(names.values(), 0)
+            for part in self.layer_parts:
+                name = names[part]
+                if name != "attn" and out and out[-1][0] == name:
+                    out[-1] = (name, out[-1][1], at[name] + 1)
+                else:
+                    out.append((name, at[name], at[name] + 1))
+                at[name] += 1
+            return tuple(out)
         out, a, m = [], 0, 0
         for i in range(self.n_layers):
             if i in self.attn_layers:
@@ -707,6 +816,105 @@ def _init_conv(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     return out
 
 
+_MIXER2_AXES = {
+    "norm": ("layers", "embed"),
+    "in_proj": ("layers", "embed", "mlp"),
+    "conv_w": ("layers", None, "mlp"),
+    "conv_b": ("layers", "mlp"),
+    "out_proj": ("layers", "mlp", "embed"),
+    "dt_bias": ("layers", None), "A_log": ("layers", None),
+    "D": ("layers", None), "w_norm": ("layers", "mlp")}
+
+
+def _parts_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    into = ("mlp", "embed") if cfg.up_out_in else ("embed", "mlp")
+    experts = {"mlp_norm": ("layers", "embed"),
+               "router": ("layers", "embed", "expert"),
+               "w_up": ("layers", "expert", *into),
+               "w_down": ("layers", "expert", "mlp", "embed")}
+    if cfg.ffn_gated:
+        experts["w_gate"] = experts["w_up"]
+    if cfg.router_score == "sigmoid":
+        experts["router_bias"] = ("layers", "expert")
+    if cfg.n_shared_experts:
+        experts.update({"ws_up": ("layers", *into),
+                        "ws_down": ("layers", "mlp", "embed")})
+        if cfg.ffn_gated:
+            experts["ws_gate"] = experts["ws_up"]
+    out = {"embed": ("vocab", "embed"), "final_norm": ("embed",),
+           "lm_head": ("embed", "vocab"),
+           "layers": {"attn_norm": ("layers", "embed"),
+                      "wq": ("layers", "embed", "heads"),
+                      "wk": ("layers", "embed", "kv_heads"),
+                      "wv": ("layers", "embed", "kv_heads"),
+                      "wo": ("layers", "heads", "embed")},
+           "mamba": dict(_MIXER2_AXES), "experts": experts}
+    if cfg.tie_embeddings:
+        del out["lm_head"]
+    for name, n in _part_layers(cfg).items():
+        if not n:       # a kind the pattern does not have has no stack
+            del out[name]
+    return out
+
+
+def _part_layers(cfg: LlamaConfig) -> Dict[str, int]:
+    """name -> layers of each stack a model of one-part layers has."""
+    return {"layers": cfg.kv_layers, "mamba": cfg.state_layers,
+            "experts": cfg.sparse_layers}
+
+
+def _init_parts(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
+    """A model of one-part layers (`cfg.layer_parts`): a stack a kind, each
+    with its own part's leaves and a norm, nothing else: `layers` (attention:
+    `attn_norm`, `wq`, `wk`, `wv`, `wo`), `mamba` (`_init_mamba`'s Mamba-2
+    mixer, no feed-forward) and `experts` (`mlp_norm`, `router` over all
+    n_experts, the experts HELD, `w_up` and `w_down` and, where the expert is
+    gated, `w_gate`; the up and gate matrices `[d_ff, d_model]`, as `w_down`
+    is, where the width is off the lanes, `cfg.up_out_in`; the sigmoid
+    router's `router_bias`; the shared expert).
+    A kind the pattern does not have has no stack. Keys from lists of this
+    function's own."""
+    D, H, KVH, V = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.vocab_size
+    hd, F, pd = cfg.head_dim, cfg.d_ff, cfg.param_dtype
+
+    def norm(shape, k, scale=0.02):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(pd)
+
+    top = iter(jax.random.split(key, 5))
+    out = {"embed": norm((V, D), next(top)), "final_norm": jnp.ones((D,), pd)}
+    head_key = next(top)
+    if not cfg.tie_embeddings:
+        out["lm_head"] = norm((D, V), head_key)
+    La, Lm, Le = cfg.kv_layers, cfg.state_layers, cfg.sparse_layers
+    ks = iter(jax.random.split(next(top), 4))
+    if La:
+        out["layers"] = {"attn_norm": jnp.ones((La, D), pd),
+                         "wq": norm((La, D, H * hd), next(ks)),
+                         "wk": norm((La, D, KVH * hd), next(ks)),
+                         "wv": norm((La, D, KVH * hd), next(ks)),
+                         "wo": norm((La, H * hd, D), next(ks))}
+    mixer_key = next(top)
+    if Lm:
+        out["mamba"] = _init_mamba(cfg, mixer_key, norm, ffn=False)
+    if Le:
+        ks = iter(jax.random.split(next(top), 8))
+        up = (Le, cfg.n_held, F, D) if cfg.up_out_in \
+            else (Le, cfg.n_held, D, F)
+        experts = {"mlp_norm": jnp.ones((Le, D), pd),
+                   "router": norm((Le, D, cfg.n_experts), next(ks)),
+                   "w_up": norm(up, next(ks)),
+                   "w_down": norm((Le, cfg.n_held, F, D), next(ks))}
+        if cfg.ffn_gated:
+            experts["w_gate"] = norm(up, next(ks))
+        if cfg.router_score == "sigmoid":
+            # (drawn as every other model's: `_init_latent` says why)
+            experts["router_bias"] = norm((Le, cfg.n_experts), next(ks))
+        if cfg.n_shared_experts:
+            experts.update(_shared_expert(cfg, Le, ks, norm))
+        out["experts"] = experts
+    return out
+
+
 def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     if cfg.latent:
         return _latent_axes(cfg)
@@ -714,6 +922,8 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         return _mixed_axes(cfg)
     if cfg.conv:
         return _conv_axes(cfg)
+    if cfg.layer_parts is not None:
+        return _parts_axes(cfg)
     layers: Dict[str, Tuple] = {
         "attn_norm": ("layers", "embed"),
         "wq": ("layers", "embed", "heads"),
@@ -766,9 +976,7 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
             "conv_b": ("layers", "mlp"),
             "out_proj": ("layers", "mlp", "embed")}
         if cfg.ssm_heads:
-            mixer.update({"dt_bias": ("layers", None),
-                          "A_log": ("layers", None), "D": ("layers", None),
-                          "w_norm": ("layers", "mlp")})
+            mixer = dict(_MIXER2_AXES)
         else:
             mixer.update({"x_proj": ("layers", "mlp", None),
                           "dt_norm": ("layers", None),
@@ -852,6 +1060,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         return _init_mixed(cfg, key)
     if cfg.conv:
         return _init_conv(cfg, key)
+    if cfg.layer_parts is not None:
+        return _init_parts(cfg, key)
     L, D, H, KVH = cfg.kv_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads
     hd, F, V = cfg.head_dim, cfg.d_ff, cfg.vocab_size
     pd = cfg.param_dtype
@@ -921,22 +1131,27 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
 
 
 def _shared_expert(cfg: LlamaConfig, L: int, ks, norm) -> Dict[str, Any]:
-    """The dense expert every token meets, `n_shared_experts * d_ff` wide."""
+    """The dense expert every token meets, `n_shared_experts * d_ff` wide
+    (no `ws_gate` where the model's expert is not gated; `ws_up` and
+    `ws_gate` laid as the routed experts' are, `cfg.up_out_in`)."""
     D, Fs = cfg.d_model, cfg.n_shared_experts * cfg.d_ff
-    return {"ws_gate": norm((L, D, Fs), next(ks)),
-            "ws_up": norm((L, D, Fs), next(ks)),
-            "ws_down": norm((L, Fs, D), next(ks))}
+    up = (L, Fs, D) if cfg.up_out_in else (L, D, Fs)
+    out = {"ws_gate": norm(up, next(ks))} if cfg.ffn_gated else {}
+    return dict(out, ws_up=norm(up, next(ks)),
+                ws_down=norm((L, Fs, D), next(ks)))
 
 
-def _init_mamba(cfg: LlamaConfig, key: jax.Array, norm) -> Dict[str, Any]:
+def _init_mamba(cfg: LlamaConfig, key: jax.Array, norm, ffn: bool = True
+                ) -> Dict[str, Any]:
     """The stack of state-space layers, each with its own feed-forward (a
-    dense one, or a router, the experts held and the shared expert). The
+    dense one, or a router, the experts held and the shared expert; none
+    where `ffn` is False: a one-part layer is its mixer alone). The
     channel axis is the minor one of every leaf (`ops/ssm.py`): Mamba-1's
     `A_log` is `[N, Di]` and the convolution's weights `[K, Di]`. A and the
     time step's bias start as Mamba's own do (A = -(1..N), under Mamba-2 A =
     -uniform(1..16) a head; softplus(bias) log-uniform in 1e-3..1e-1), so
     that a state carries over hundreds of rows, not two."""
-    Lm = cfg.n_layers - cfg.kv_layers
+    Lm = cfg.state_layers
     D, F, Di, N = cfg.d_model, cfg.d_ff, cfg.ssm_inner, cfg.ssm_state
     K, R, pd = cfg.ssm_conv, cfg.ssm_dt_rank, cfg.param_dtype
     ks = iter(jax.random.split(key, 16))
@@ -949,7 +1164,8 @@ def _init_mamba(cfg: LlamaConfig, key: jax.Array, norm) -> Dict[str, Any]:
         H, Dc = cfg.ssm_heads, cfg.ssm_conv_channels
         out = {
             "norm": jnp.ones((Lm, D), pd),
-            # its columns: the gate z, then x, B and C, then a head's dt
+            # its columns: the gate z, then x, every group's B and every
+            # group's C, then a head's dt
             "in_proj": norm((Lm, D, Di + Dc + H), next(ks)),
             "conv_w": norm((Lm, K, Dc), next(ks), K ** -0.5),
             "conv_b": norm((Lm, Dc), next(ks)),
@@ -978,6 +1194,8 @@ def _init_mamba(cfg: LlamaConfig, key: jax.Array, norm) -> Dict[str, Any]:
             "D": jnp.ones((Lm, Di), pd),
             "out_proj": norm((Lm, Di, D), next(ks)),
         }
+    if not ffn:
+        return out
     out["mlp_norm"] = jnp.ones((Lm, D), pd)
     if cfg.n_experts:
         out["router"] = norm((Lm, D, cfg.n_experts), next(ks))
@@ -992,7 +1210,8 @@ def _init_mamba(cfg: LlamaConfig, key: jax.Array, norm) -> Dict[str, Any]:
 
 def param_count(cfg: LlamaConfig) -> int:
     shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
-    return sum(int(jnp.prod(jnp.array(l.shape))) for l in jax.tree.leaves(shapes))
+    # (Python's integers: a stack of experts may pass 2^31 elements)
+    return sum(math.prod(l.shape) for l in jax.tree.leaves(shapes))
 
 
 # ---------------------------------------------------------------------------

@@ -279,10 +279,13 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
     table (flash, or the indexer's sparse attention), a dense or a sparse
     feed-forward (a hybrid's may be a share of the experts, whose routing
     comes back as `_share_stats`). `ridden`: its prefill takes riders, and
-    its decode step is the riders' (`token_step`: ONE jit for both)."""
+    its decode step is the riders' (`token_step`: ONE jit for both). In a
+    stack of one-part layers (`mcfg.layer_parts`) the layer is attention
+    ALONE: no feed-forward follows, and it routes nothing."""
     H, KVH, hd, S = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim, mcfg.max_seq
     dt = mcfg.dtype
-    sparse = mcfg.n_experts > 0
+    alone = mcfg.layer_parts is not None
+    sparse = mcfg.n_experts > 0 and not alone
     indexed = mcfg.index_topk > 0
     # Generation by blocks: the prompt's mask, and a step of B rows a slot.
     B = mcfg.block_length
@@ -298,6 +301,8 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
     def _feed_forward(lp, x, live, l):
         """`feed_forward` over at most `_MOE_ROWS` rows at a time."""
         Sq = x.shape[1]
+        if alone:
+            return x, None
         if not sparse or Sq <= _MOE_ROWS:
             return block.feed_forward(lp, x, mcfg, live, l)
         outs, counts = [], 0
@@ -419,8 +424,9 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
             x = x + block.scaled(attn @ lp["wo"].astype(dt), mcfg)
         # An idle slot's row is computed like any other, from itself alone,
         # and left out of the count.
-        x, routed = block.feed_forward(lp, x, mcfg, act,
-                                       l if sparse else None)
+        if not alone:
+            x, routed = block.feed_forward(lp, x, mcfg, act,
+                                           l if sparse else None)
         return x, caches._replace(kc=kc, vc=vc, ic=ic), \
             stats(routed[1], act) if sparse else None
 
@@ -490,13 +496,18 @@ def _mamba_kind(mcfg) -> _Kind:
     (`ops/slot_state.py`), whose layer is the layer's ordinal among the
     state-space layers. Rows past `length` reach no real row: the convolution
     is causal, and the mixer is told `length`. A sparse layer hands back its
-    routing counts."""
+    routing counts. In a stack of one-part layers (`mcfg.layer_parts`) the
+    layer is the mixer ALONE: its stack holds no feed-forward's leaves, none
+    runs, and it routes nothing."""
     mixer = block.mamba2_mixer if mcfg.ssm_heads else block.mamba_mixer
     stats = _routing_stats(mcfg)
+    alone = mcfg.layer_parts is not None
 
     def prefill(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
         y, state, window = mixer(lp, x[0], mcfg, length=ctx["length"])
+        if alone:
+            return y[None], caches, (state, window), None
         y, routed = block.feed_forward(lp, y[None], mcfg, ctx["live"],
                                        l if routed_layer else None)
         return y, caches, (state, window), \
@@ -520,6 +531,8 @@ def _mamba_kind(mcfg) -> _Kind:
             x, ssm, window = mixer(lp, x, mcfg, ssm, window, step=True)
         with jax.named_scope("scan"):
             state = slot_state.update_layer(state, l, act, ssm, window)
+        if alone:
+            return x, caches._replace(state=state), None
         x, routed = block.feed_forward(lp, x, mcfg, ctx["act"],
                                        l if routed_layer else None)
         return x, caches._replace(state=state), \
@@ -527,6 +540,24 @@ def _mamba_kind(mcfg) -> _Kind:
 
     return _Kind(prefill, decode, keeps=("state", "state"), over="index",
                  carries=("state",))
+
+
+def _experts_kind(mcfg) -> _Kind:
+    """A layer of a stack of one-part layers that is the feed-forward ALONE
+    (`block.feed_forward`: its norm, the router, the experts held, the shared
+    expert): no mixer before it, nothing kept of a prompt and no cache read or
+    written in a step. It hands back its routing counts."""
+    stats = _routing_stats(mcfg)
+
+    def prefill(lp, x, caches, l, ctx):
+        x, routed = block.feed_forward(lp, x, mcfg, ctx["live"], l)
+        return x, caches, (None, None), stats(routed[1], ctx["live"])
+
+    def decode(lp, x, caches, l, ctx):
+        x, routed = block.feed_forward(lp, x, mcfg, ctx["act"], l)
+        return x, caches, stats(routed[1], ctx["act"])
+
+    return _Kind(prefill, decode, keeps=(), over="index", carries=())
 
 
 def _conv_kind(mcfg) -> _Kind:
@@ -728,15 +759,17 @@ def _stack(mcfg) -> _Stack:
             else ())
 
     if mcfg.ssm_state:
+        kinds = {"attn": _attention_kind(mcfg, False, stack="layers",
+                                         over="inline"),
+                 "mamba": _mamba_kind(mcfg)}
+        if mcfg.layer_parts is not None:    # each of the three a part alone
+            kinds["experts"] = _experts_kind(mcfg)
         return _Stack(
-            {"attn": _attention_kind(mcfg, False, stack="layers",
-                                     over="inline"),
-             "mamba": _mamba_kind(mcfg)},
-            uniform_tables,
+            kinds, uniform_tables,
             lambda ns, page, n_pages: Caches(
                 *paged_kv.empty(mcfg.kv_layers, n_pages, KVH, page, hd, dt),
                 state=slot_state.empty_state(
-                    unpaged, ns, mcfg.ssm_state, mcfg.ssm_inner,
+                    mcfg.state_layers, ns, mcfg.ssm_state, mcfg.ssm_inner,
                     mcfg.ssm_conv, dt, mcfg.ssm_conv_channels)),
             lambda c: {"state_bytes": slot_state.state_bytes(c.state)},
             shares=bool(mcfg.experts_held), tally="zero")
@@ -1107,9 +1140,10 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         with jax.named_scope("layers"):
             for name, lo, hi in mcfg.segments():
                 kind = stack.kinds[name]
-                # `base + l`: the layer of its cache an ordinal `l` writes
-                ctx["base"] = at[kind.keeps[0]] - lo
-                at[kind.keeps[0]] += hi - lo
+                if kind.keeps:
+                    # `base + l`: the layer of its cache an ordinal `l` writes
+                    ctx["base"] = at[kind.keeps[0]] - lo
+                    at[kind.keeps[0]] += hi - lo
                 if kind.begin:
                     kind.begin(ctx)
                 # The caches of this kind alone ride its scan: an arena a

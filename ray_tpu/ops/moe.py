@@ -3,8 +3,12 @@
 The reference framework only passes expert-parallel sizes through to vLLM
 (SURVEY.md §2.3 — EP row: "Not in Ray"); here MoE is a native layer.
 
-One path, and it computes the published mixture exactly: router logits and
-softmax in float32, top-k, the weights either left as the softmax over ALL
+One path for every expert's form (a gated expert, `act(x W_gate) * (x W_up)`
+through `W_down`: three grouped matmuls; or an ungated one, `act(x W_up)
+W_down`: two; `act` silu or relu squared: `expert_mlp`; the up and gate
+matrices `[in, out]` or, where a stack's width is off the lanes, `[out, in]`:
+`up_out_in`), and it computes the
+published mixture exactly: router logits and softmax in float32, top-k, the weights either left as the softmax over ALL
 experts gives them (OLMoE, `norm_topk_prob: false`) or renormalised over the
 selected k (Mixtral), and EVERY assignment computed. There is no capacity and
 so no dropped token: a token's output depends on that token alone, never on
@@ -100,10 +104,48 @@ def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True,
     return weights * scale, idx
 
 
-def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-           w_down: jax.Array) -> jax.Array:
-    """(silu(x w_gate) * (x w_up)) w_down: one dense expert."""
-    return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+def activation(act: str):
+    """An expert's activation by name: "silu", or "relu2", relu(x)^2."""
+    if act == "silu":
+        return jax.nn.silu
+    if act == "relu2":
+        return lambda x: jnp.square(jax.nn.relu(x))
+    raise ValueError(f"feed-forward activation {act!r}: 'silu' or 'relu2'")
+
+
+def _dense_matmul(x, w, transposed=False):
+    return x @ (jnp.swapaxes(w, -1, -2) if transposed else w)
+
+
+def up_out_in(width: int) -> bool:
+    """THE layout of an expert's up and gate matrices, from the expert's
+    width alone: `[F, D]` (`[out, in]`, as an `nn.Linear` weight is
+    published, the hidden size minor as in the down matrix) where the width
+    is not whole lanes, `[D, F]` else. 1,856 = 14.5 x 128: as a minor axis
+    the TPU's compiler lays such a stack out with the hidden size minor all
+    the same, and a grouped matmul that asks for `[in, out]` rows (the Pallas
+    kernel, and XLA's own `ragged-dot`) is first handed a copy of the whole
+    stack: 4.5 GB at 7 layers of 64 such experts, compiled for a v5e, PR 55.
+    The stack that is drawn by this rule (`LlamaConfig.up_out_in`) says so to
+    `moe_ffn`; gated or not, and whatever the activation, is another
+    matter."""
+    return width % 128 != 0
+
+
+def expert_mlp(x: jax.Array, w_gate: Optional[jax.Array], w_up: jax.Array,
+               w_down: jax.Array, act: str = "silu", matmul=_dense_matmul,
+               out_in: bool = False) -> jax.Array:
+    """One expert's form over its rows, dense (`matmul` the plain one) or
+    grouped (`matmul(xs, w, transposed)` a grouped matmul over sorted rows):
+    (act(x w_gate) * (x w_up)) w_down, or with no gate (`w_gate` None)
+    act(x w_up) w_down. Every matrix is `[in, out]`; with `out_in` the up and
+    gate matrices are `[F, D]` and multiplied by their transpose
+    (`up_out_in` has why)."""
+    up = activation(act)(matmul(x, w_up if w_gate is None else w_gate,
+                                out_in))
+    if w_gate is not None:
+        up = up * matmul(x, w_up, out_in)
+    return matmul(up, w_down)
 
 
 # The grouped matmul's row tile on a TPU, visited by HALVES: a (row tile,
@@ -137,16 +179,20 @@ def _tiling(m: int, k: int, n: int) -> Optional[Tuple[int, int]]:
     kernel won at every shape the probe tried, a decode step's 64 and 128
     rows included, so all it asks is shapes it can tile: the rows in whole
     tiles (one tile of all the rows under `_ROW_TILE`) whose halves are whole
-    bfloat16 sublane tiles, K and N in lanes. K is never cut (a group's
-    `[K, tn]` block is read once a run of its visits); the column tile is the
-    widest the blocks' room allows, and a row tile of half the rows is taken
-    where that lets it be wider (K = 7168: a tile's rows are read once a
-    column tile)."""
-    best = None
+    bfloat16 sublane tiles, K in whole packed sublane tiles (it is never cut:
+    a group's `[K, tn]` block is read once a run of its visits, and a block
+    that takes the whole of an axis need not be whole lanes of it) and N a
+    whole tile of 64 columns at least. The column tile is the widest the
+    blocks' room allows that tiles N ROUNDED UP to lanes (N = 1,856 = 14.5 x
+    128: tiles of 640 over 1,920, the last one's columns past N read as
+    anything and never written back), and a row tile of half the rows is
+    taken where that lets it be wider (K = 7168: a tile's rows are read once
+    a column tile)."""
+    best, lanes = None, -(-n // 128) * 128
     for tm in (min(_ROW_TILE, m), min(_ROW_TILE // 2, m)):
-        if m % tm or tm % 32 or k % 128 or n % 128:
+        if m % tm or tm % 32 or k % 16 or n % 64:
             continue
-        tn = max((t for t in range(128, n + 1, 128) if n % t == 0
+        tn = max((t for t in range(128, lanes + 1, 128) if lanes % t == 0
                   and 4 * (k * t + tm * k + tm * t) + 4 * tm * min(t, 512)
                   <= _BLOCKS_VMEM), default=0)
         if tn and (best is None or tn > best[1]):
@@ -173,9 +219,11 @@ def _visits(groups, *, m, tm):
     return group, tile + v, lo, hi, upto[-1]
 
 
-def _grouped_kernel(group, tile, lo, hi, x_ref, w_ref, o_ref, *, tm, nc):
-    """One visit: the tile's rows against the group's `[K, tn]` block BY
-    HALVES, a half the group has no row in skipped, `nc` columns at a time.
+def _grouped_kernel(group, tile, lo, hi, x_ref, w_ref, o_ref, *, tm, nc,
+                    transposed):
+    """One visit: the tile's rows against the group's `[K, tn]` block (`[tn,
+    K]` where `transposed`: the product with its transpose) BY HALVES, a
+    half the group has no row in skipped, `nc` columns at a time.
     Only the group's rows are stored, so the tile's other rows keep what
     their own groups' visits (consecutive: the block stays in VMEM) left
     there, and a row in no group keeps what the buffer held."""
@@ -192,7 +240,13 @@ def _grouped_kernel(group, tile, lo, hi, x_ref, w_ref, o_ref, *, tm, nc):
 
         def columns(j, _):
             c = pl.ds(pl.multiple_of(j * nc, nc), nc)
-            y = jnp.dot(x, w_ref[:, c], preferred_element_type=jnp.float32)
+            if transposed:
+                y = jax.lax.dot_general(
+                    x, w_ref[c, :], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            else:
+                y = jnp.dot(x, w_ref[:, c],
+                            preferred_element_type=jnp.float32)
             o_ref[r, c] = jnp.where(mine, y.astype(o_ref.dtype), o_ref[r, c])
 
         jax.lax.fori_loop(0, o_ref.shape[1] // nc, columns, None)
@@ -203,24 +257,29 @@ def _grouped_kernel(group, tile, lo, hi, x_ref, w_ref, o_ref, *, tm, nc):
                       1 + (hi[v] > base + half).astype(jnp.int32), rows, None)
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
-def _grouped_pallas(xs, w, groups, *, tm, tn, interpret):
+@functools.partial(jax.jit, static_argnames=("tm", "tn", "transposed",
+                                               "interpret"))
+def _grouped_pallas(xs, w, groups, *, tm, tn, interpret, transposed=False):
     """Both under a `jit` of their own: a sparse layer's three matmuls share
     one list of visits and its gate and up one kernel, traced and lowered
     once a program (a start-up pays every program's tracing: PERF.md,
     PR 41)."""
     m, k = xs.shape
-    n = w.shape[-1]
+    n = w.shape[1 if transposed else 2]
     *meta, count = _visits(groups, m=m, tm=tm)
     nc = next(c for c in (512, 384, 256, 128) if tn % c == 0)
     return pl.pallas_call(
-        functools.partial(_grouped_kernel, tm=tm, nc=nc),
+        functools.partial(_grouped_kernel, tm=tm, nc=nc,
+                          transposed=transposed),
         out_shape=jax.ShapeDtypeStruct((m, n), xs.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(n // tn, count),
+            grid=(-(-n // tn), count),
             in_specs=[
                 pl.BlockSpec((tm, k), lambda j, v, g, t, lo, hi: (t[v], 0)),
+                pl.BlockSpec((None, tn, k),
+                             lambda j, v, g, t, lo, hi: (g[v], j, 0))
+                if transposed else
                 pl.BlockSpec((None, k, tn),
                              lambda j, v, g, t, lo, hi: (g[v], 0, j)),
             ],
@@ -234,23 +293,24 @@ def _grouped_pallas(xs, w, groups, *, tm, tn, interpret):
     )(*meta, xs, w)
 
 
-def _ragged_dot(xs, w, groups):
+def _ragged_dot(xs, w, groups, transposed=False):
     attention._path_counts["experts_ragged_dot"] += 1
-    return jax.lax.ragged_dot(xs, w, groups)
+    return jax.lax.ragged_dot(
+        xs, jnp.swapaxes(w, 1, 2) if transposed else w, groups)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _grouped(xs, w, groups, tiling, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _grouped(xs, w, groups, tiling, interpret, transposed):
     attention._path_counts["experts_grouped_pallas"] += 1
     return _grouped_pallas(xs, w, groups, tm=tiling[0], tn=tiling[1],
-                           interpret=interpret)
+                           transposed=transposed, interpret=interpret)
 
 
-def _grouped_fwd(xs, w, groups, tiling, interpret):
-    return jax.vjp(lambda a, b: _ragged_dot(a, b, groups), xs, w)
+def _grouped_fwd(xs, w, groups, tiling, interpret, transposed):
+    return jax.vjp(lambda a, b: _ragged_dot(a, b, groups, transposed), xs, w)
 
 
-def _grouped_bwd(tiling, interpret, vjp, dy):
+def _grouped_bwd(tiling, interpret, transposed, vjp, dy):
     return (*vjp(dy), None)
 
 
@@ -258,8 +318,11 @@ _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
 def grouped_matmul(xs: jax.Array, w: jax.Array, groups: jax.Array, *,
+                   transposed: bool = False,
                    interpret: bool = False) -> jax.Array:
-    """xs [m, K] sorted by group, w [G, K, N], groups [G] int32 -> [m, N]:
+    """xs [m, K] sorted by group, w [G, K, N] (with `transposed` [G, N, K]:
+    every group's matrix as an `nn.Linear` weight, the product with its
+    transpose), groups [G] int32 -> [m, N]:
     rows sum(groups[:g]) .. sum(groups[:g + 1]) - 1 times w[g], float32
     accumulation over K, a row's result that row's alone; rows past
     sum(groups) hold anything. `w` may be the stack of every layer with the
@@ -271,10 +334,17 @@ def grouped_matmul(xs: jax.Array, w: jax.Array, groups: jax.Array, *,
     `jax.lax.ragged_dot` and its VJP. Which one is counted at trace time in
     `attention.attention_path_counts()` as `experts_grouped_pallas` /
     `experts_ragged_dot`."""
-    tiling = _tiling(xs.shape[0], xs.shape[1], w.shape[-1])
+    tiling = _tiling(*xs.shape, w.shape[1 if transposed else 2])
     if tiling is None or not (interpret or attention._on_tpu()):
-        return _ragged_dot(xs, w, groups)
-    return _grouped(xs, w, groups, tiling, interpret)
+        return _ragged_dot(xs, w, groups, transposed)
+    return _grouped(xs, w, groups, tiling, interpret, transposed)
+
+
+def _over_groups(groups: jax.Array):
+    """`expert_mlp`'s `matmul` over rows sorted by group: the grouped matmul
+    as it stands on this module now (a test puts its own there)."""
+    return lambda xs, w, transposed=False: grouped_matmul(
+        xs, w, groups, transposed=transposed)
 
 
 def _combine_tiling(tokens: int, rows: int, d: int,
@@ -442,7 +512,7 @@ _SHARE_BLOCK = 4
 
 
 def _share_experts(x, w_gate, w_up, w_down, layer, idx, weights, held,
-                   n_experts):
+                   n_experts, act="silu", out_in=False):
     """The routed part that the experts `held` = (offset, count) give: `idx`,
     `weights` [tokens, k] the router's choice over all `n_experts`. ->
     (out [tokens, d] float32, chosen [tokens * k, count] bool: which
@@ -477,8 +547,9 @@ def _share_experts(x, w_gate, w_up, w_down, layer, idx, weights, held,
         stacked = None
         if layer is not None:
             stacked = w_up.shape[0] * n_held
-            w_up, w_gate, w_down = (w.reshape(stacked, *w.shape[2:])
-                                    for w in (w_up, w_gate, w_down))
+            w_up, w_gate, w_down = (
+                None if w is None else w.reshape(stacked, *w.shape[2:])
+                for w in (w_up, w_gate, w_down))
 
     def block(carry):
         lo, out = carry
@@ -493,9 +564,8 @@ def _share_experts(x, w_gate, w_up, w_down, layer, idx, weights, held,
                 groups = jax.lax.dynamic_update_slice(
                     jnp.zeros(stacked, jnp.int32), local, (layer * n_held,))
         with jax.named_scope("experts"):
-            h = jax.nn.silu(grouped_matmul(xs, w_gate, groups)) \
-                * grouped_matmul(xs, w_up, groups)
-            ys = grouped_matmul(h, w_down, groups)           # [rows, d]
+            ys = expert_mlp(xs, w_gate, w_up, w_down, act,    # [rows, d]
+                            _over_groups(groups), out_in)
         with jax.named_scope("moe_combine"):
             combined = local_combine(out, lo == 0, ys, assignment, weights,
                                      local)
@@ -519,19 +589,25 @@ def _share_experts(x, w_gate, w_up, w_down, layer, idx, weights, held,
     return out, chosen
 
 
-def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
-            w_down: jax.Array, *, top_k: int = 2, norm_topk_prob: bool = True,
+def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array,
+            w_gate: Optional[jax.Array], w_down: jax.Array, *, top_k: int = 2,
+            norm_topk_prob: bool = True,
             live: Optional[jax.Array] = None,
             layer: Optional[jax.Array] = None,
             routing: Optional[Dict[str, Any]] = None,
             held: Optional[Tuple[int, int]] = None,
-            shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+            shared: Optional[Tuple[Optional[jax.Array], jax.Array,
+                                   jax.Array]] = None,
+            act: str = "silu", out_in: bool = False,
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """SwiGLU MoE feed-forward over sorted assignments.
+    """MoE feed-forward over sorted assignments (SwiGLU experts, or what
+    `act` and a `w_gate` of None say: `expert_mlp`).
 
     x: [tokens, d_model]
     gate_w: [d_model, n_experts] router weights
     w_up/w_gate: [n_experts, d_model, d_ff]; w_down: [n_experts, d_ff, d_model]
+    (with `out_in`, w_up/w_gate `[n_experts, d_ff, d_model]` and the shared
+    expert's alike: `up_out_in`)
     live: [tokens] bool, the rows that are somebody's token (not a bucket's
     padding, not an idle slot). Every row is computed either way, each from
     itself alone; `live` only keeps the others out of the count.
@@ -550,8 +626,9 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
     assignments to held experts are computed (`_share_experts`) and the rest
     weigh 0. What comes back is this share's PART of the mixture: the parts
     of all shares add up to the whole layer's.
-    shared: (w_gate, w_up, w_down) of a dense expert every token meets, added
-    to the routed part (once: a deployment's other shares add none).
+    shared: (w_gate, w_up, w_down) of a dense expert every token meets, of the
+    routed experts' form (w_gate None: ungated), added to the routed part
+    (once: a deployment's other shares add none).
     Returns (out [tokens, d_model], aux_loss scalar, tokens per expert
     [n_experts] int32 over the live rows; per HELD expert `[count]` with
     `held`, whose sum is the local assignments).
@@ -566,7 +643,7 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
 
     if held is not None:
         out, chosen = _share_experts(x, w_gate, w_up, w_down, layer, idx,
-                                     weights, held, n_experts)
+                                     weights, held, n_experts, act, out_in)
         group_sizes = jnp.sum(chosen, axis=0, dtype=jnp.int32)
     else:
         with jax.named_scope("moe_dispatch"):
@@ -584,13 +661,13 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
                 groups = jax.lax.dynamic_update_slice(
                     jnp.zeros(stacked, jnp.int32), group_sizes,
                     (layer * n_experts,))
-                w_up, w_gate, w_down = (w.reshape(stacked, *w.shape[2:])
-                                        for w in (w_up, w_gate, w_down))
+                w_up, w_gate, w_down = (
+                    None if w is None else w.reshape(stacked, *w.shape[2:])
+                    for w in (w_up, w_gate, w_down))
 
         with jax.named_scope("experts"):
-            h = jax.nn.silu(grouped_matmul(xs, w_gate, groups)) \
-                * grouped_matmul(xs, w_up, groups)
-            ys = grouped_matmul(h, w_down, groups)              # [t*k, d]
+            ys = expert_mlp(xs, w_gate, w_up, w_down, act,      # [t*k, d]
+                            _over_groups(groups), out_in)
 
         with jax.named_scope("moe_combine"):
             # Back to token-major by a gather and one sum over a token's k
@@ -603,7 +680,8 @@ def moe_ffn(x: jax.Array, gate_w: jax.Array, w_up: jax.Array, w_gate: jax.Array,
 
     if shared is not None:
         with jax.named_scope("shared_expert"):
-            out = out + swiglu(x, *shared).astype(jnp.float32)
+            out = out + expert_mlp(x, *shared, act,
+                                   out_in=out_in).astype(jnp.float32)
 
     if live is None:
         counts = group_sizes

@@ -9,9 +9,10 @@ The state is a pair of arrays, for the model's `n_layers` state-space layers
 
   ssm   [n_layers, n_slots, N, Di]      float32: `ops.ssm`'s state
   conv  [n_layers, K - 1, n_slots, Di]  the convolution's window, the last
-                                        K - 1 inputs of each channel (Di + 2 N
-                                        channels under Mamba-2, whose
-                                        convolution takes B and C too)
+                                        K - 1 inputs of each channel (Di + 2 G
+                                        N channels under Mamba-2, whose
+                                        convolution takes its G groups' B
+                                        and C too)
 
 The channel axis is the minor one of both and the axis before it a whole
 tile (N = 16 rows of float32; 16 slots of bfloat16), so neither is padded:
@@ -86,7 +87,7 @@ def empty_state(n_layers: int, n_slots: int, n_state: int, channels: int,
                 ) -> State:
     """-> (ssm, conv), zeroed; `n_state` 0: (None, conv), a window and no
     recurrent state. `conv_channels`: the window's width where it is not the
-    state's (Mamba-2's convolution runs over x, B and C together, Di + 2 N
+    state's (Mamba-2's convolution runs over x, B and C together, Di + 2 G N
     channels)."""
     return (jnp.zeros((n_layers, n_slots, n_state, channels), jnp.float32)
             if n_state else None,
@@ -135,7 +136,7 @@ def step_layer(state: State, layer, active, x, dt, A, B, C, D, *,
                interpret: bool = False) -> Tuple[jax.Array, State]:
     """A Mamba-2 decode step's read, update and write of one layer's
     recurrent state in ONE visit (`ops.ssm.ssd_step`'s arguments, one token
-    a slot) -> (y `[n_slots, Di]` float32, zeros for an idle slot; the state,
+    a slot; B and C `[n_slots, N]` or `[n_slots, G, N]`) -> (y `[n_slots, Di]` float32, zeros for an idle slot; the state,
     whose idle slots' rows and other layers stay what they were). On a TPU
     (or with `interpret`, for tests on the CPU), where the state is whole
     tiles, `ops.ssm.ssd_state_step`, which crosses each active slot's state
@@ -145,7 +146,7 @@ def step_layer(state: State, layer, active, x, dt, A, B, C, D, *,
     `ssd_step_reference`. The window is not this op's: `update_layer`."""
     ssm, conv = state
     use = (interpret or attention._on_tpu()) \
-        and ssm_ops.state_step_tiles(ssm.shape)
+        and ssm_ops.state_step_tiles(ssm.shape, ssm_ops.groups_of(B))
     attention._path_counts[
         "ssd_step_pallas" if use else "ssd_step_reference"] += 1
     if use:

@@ -34,7 +34,11 @@ dt[t, head(c)]`, heads of `P = Di / H` channels next to each other:
   dt  [S, H] float32   A, D  [H]   x, y [S, Di]   B, C [S, N]   state [N, Di]
 
 so `selective_scan` fed the broadcast `A` and `dt` computes it too (the tests'
-cross-check), at `S x Di x N` vector operations. `ssd_scan` is the chunked
+cross-check), at `S x Di x N` vector operations. With G GROUPS of B and C
+(`[S, G, N]`; `[ns, G, N]` a decode step) channel c reads group `c // (Di /
+G)`, its head's: G Mamba-2 mixers of H / G heads side by side, which share
+nothing but the layout. One group may come without the axis, `[S, N]`, and
+is then computed by the text it always was. `ssd_scan` is the chunked
 dual form, plain XLA: within a chunk of Q rows the outputs are matrix
 products on the matrix unit, and the state moves once a chunk (counted as
 `ssd_chunked`).
@@ -284,16 +288,37 @@ def _step_vectors(x, dt, A):
     return x, _by_head(jnp.exp(dt * A.astype(F32)), Di), _by_head(dt, Di) * x
 
 
+def _one_group(m):
+    """B or C as given, `[rows, N]` or `[rows, G, N]`: without the group
+    axis where there is one group."""
+    return m[:, 0] if m.ndim == 3 and m.shape[1] == 1 else m
+
+
+def groups_of(m) -> int:
+    """The groups of a B or C as given: 1 without the group axis."""
+    return m.shape[1] if m.ndim == 3 else 1
+
+
+def _by_group(m, channels: int):
+    """A slot's B or C `[ns, N]` (one group) or `[ns, G, N]` against the
+    state's `[ns, N, Di]`: `[ns, N, 1]`, or a group's row at each of its
+    channels, `[ns, N, Di]`."""
+    if m.ndim == 2:
+        return m[:, :, None]
+    return jnp.repeat(jnp.swapaxes(m, 1, 2), channels // m.shape[1], axis=-1)
+
+
 def ssd_step(x, dt, A, B, C, D, state):
     """Mamba-2, one token a slot: x `[ns, Di]`, dt `[ns, H]` float32, A, D
-    `[H]`, B, C `[ns, N]`, state `[ns, N, Di]` float32 -> (y `[ns, Di]`
-    float32, the new state). The decay is broadcast over the N states too.
-    The reference of `ssd_state_step`, and the path off a TPU."""
+    `[H]`, B, C `[ns, N]` or `[ns, G, N]`, state `[ns, N, Di]` float32 -> (y
+    `[ns, Di]` float32, the new state). The decay is broadcast over the N
+    states too. The reference of `ssd_state_step`, and the path off a TPU."""
     x, decay, dtx = _step_vectors(x, dt, A)
-    state = decay[:, None, :] * state \
-        + dtx[:, None, :] * B.astype(F32)[:, :, None]
-    y = jnp.sum(state * C.astype(F32)[:, :, None], axis=1)
-    return y + _by_head(D.astype(F32), x.shape[-1]) * x, state
+    Di = x.shape[-1]
+    B, C = _one_group(B.astype(F32)), _one_group(C.astype(F32))
+    state = decay[:, None, :] * state + dtx[:, None, :] * _by_group(B, Di)
+    y = jnp.sum(state * _by_group(C, Di), axis=1)
+    return y + _by_head(D.astype(F32), Di) * x, state
 
 
 def _state_step_kernel(layer_ref, slots_ref, decay_ref, dtx_ref, b_ref, c_ref,
@@ -302,8 +327,9 @@ def _state_step_kernel(layer_ref, slots_ref, decay_ref, dtx_ref, b_ref, c_ref,
     holds one slot's `[N, block]` tile of the layer's state, which the block
     specs read from where it lies and write back there. The slots' vectors
     of the block of channels (`[ns, block]`) and y stay in fast memory while
-    the slots go by, B and C (`[ns, N]`) throughout: a slot's row of each is
-    read by its index."""
+    the slots go by, and so do B and C (`[ns, N]`) of the ONE group the block
+    of channels lies in (a block never crosses a group's edge): a slot's row
+    of each is read by its index."""
     del layer_ref
     row = pl.ds(slots_ref[pl.program_id(1)], 1)
     N = s_ref.shape[0]
@@ -329,8 +355,11 @@ def _state_step_pallas(ssm, layer, active, decay, dtx, B, C, *, bd,
                        interpret):
     """Under a `jit` of its own, as `ops/moe.py::_grouped_pallas` is: a
     decode program's segments of state-space layers trace and lower it
-    once."""
+    once. B, C `[ns, G N]`, group g's in columns g N..: block j of `bd`
+    channels, which divides a group's, reads the columns of the group it
+    lies in."""
     _, ns, N, Di = ssm.shape
+    width = Di // (B.shape[1] // N)     # a group's channels
     # The active slots' indices, in order, then zeros; only the first
     # `count` are visited. (A compare of every slot with every rank: 4,096
     # pairs at 64 slots, where a sort would be a program of its own.)
@@ -338,7 +367,8 @@ def _state_step_pallas(ssm, layer, active, decay, dtx, B, C, *, bd,
     rank = jnp.cumsum(active, dtype=jnp.int32) - 1
     slots = jnp.sum(jnp.where(active & (rank == at[:, None]), at, 0), axis=1)
     vectors = pl.BlockSpec((ns, bd), lambda j, i, *_: (0, j))
-    maps = pl.BlockSpec((ns, N), lambda j, i, *_: (0, 0))
+    maps = pl.BlockSpec((ns, N), (lambda j, i, *_: (0, 0)) if width == Di
+                        else (lambda j, i, *_: (0, j * bd // width)))
     tile = pl.BlockSpec((None, None, N, bd),
                         lambda j, i, layer, slots: (layer[0], slots[i], 0, j))
     return pl.pallas_call(
@@ -360,10 +390,14 @@ def _state_step_pallas(ssm, layer, active, decay, dtx, B, C, *, bd,
     )(jnp.asarray(layer, jnp.int32).reshape(1), slots, decay, dtx, B, C, ssm)
 
 
-def state_step_tiles(ssm_shape) -> bool:
+def state_step_tiles(ssm_shape, groups: int = 1) -> bool:
     """Whether `ssd_state_step`'s tiling takes a state `[L, ns, N, Di]`:
-    whole float32 tiles of 8 x 128 a slot."""
-    return ssm_shape[2] % 8 == 0 and ssm_shape[3] % 128 == 0
+    whole float32 tiles of 8 x 128 a slot and, with `groups` > 1 of B and C,
+    a group's states and its channels in whole lanes (a block of channels is
+    cut at the groups' edges)."""
+    N, Di = ssm_shape[2:]
+    return N % 8 == 0 and Di % 128 == 0 and (
+        groups == 1 or (N % 128 == 0 and Di % (128 * groups) == 0))
 
 
 def ssd_state_step(ssm, layer, active, x, dt, A, B, C, D, *,
@@ -375,17 +409,21 @@ def ssd_state_step(ssm, layer, active, x, dt, A, B, C, D, *,
     to y and writes it back in its place (`ssm` is the kernel's input AND its
     output; donate it). It is handed the whole array and `layer` (a traced
     scalar), never `ssm[layer]`: a custom call handed a slice is first
-    handed a copy. x `[ns, Di]`, dt `[ns, H]`, A, D `[H]`, B, C `[ns, N]`,
+    handed a copy. x `[ns, Di]`, dt `[ns, H]`, A, D `[H]`, B, C `[ns, N]` or
+    `[ns, G, N]` (G groups: a block of channels is a group's or part of
+    one's, 512 of 4,096 channels in 8 groups where one group takes 1,024),
     `active` `[ns]` -> (y `[ns, Di]` float32, zeros for an idle slot; the
     state, an idle slot's and every other layer's rows as they were, to the
     bit). The decay and `dt * x` are made here, a row a slot, by `ssd_step`'s
     own ops, and `D * x` is added here; the arithmetic on the state is
     `ssd_step`'s, in its order, in float32. Needs `state_step_tiles`."""
-    Di = x.shape[-1]
+    ns, Di = x.shape
     x, decay, dtx = _step_vectors(x, dt, A)
-    bd = next(b for b in (block_channels, 512, 256, 128) if Di % b == 0)
+    width = Di // groups_of(B)      # a group's channels
+    bd = next(b for b in (block_channels, 512, 256, 128) if width % b == 0)
     y, ssm = _state_step_pallas(ssm, layer, active, decay, dtx,
-                                B.astype(F32), C.astype(F32), bd=bd,
+                                B.astype(F32).reshape(ns, -1),
+                                C.astype(F32).reshape(ns, -1), bd=bd,
                                 interpret=interpret)
     y = y + _by_head(D.astype(F32), Di) * x
     return jnp.where(active[:, None], y, 0.0), ssm
@@ -399,6 +437,10 @@ def ssd_scan(x, dt, A, B, C, D, state0=None, length=None, *,
     row). With `length` (a traced scalar) rows at and past it take dt = 0
     (decay 1, no input), as `selective_scan`'s do, so the state returned is
     the one after row `length - 1`; y there is not meaningful (and finite).
+    B, C `[S, N]`, or `[S, G, N]`: G groups, each the B and C of its H / G
+    heads and of nothing else, so the G groups are G scans side by side
+    (`jax.vmap` of one group's, whose matrix products then carry a batch axis
+    of G).
 
     The chunked dual form, chunks of `chunk` rows in order under one loop
     that carries the state. `chunk` is no option of a model: the mixer never
@@ -406,7 +448,7 @@ def ssd_scan(x, dt, A, B, C, D, state0=None, length=None, *,
     several chunks at tiny widths. Within a chunk, with c_t the running sum
     of dt_t A a head (float32; every exponent below is <= 0):
 
-      G = C B^T                         [Q, Q], once for all heads
+      G = C B^T                         [Q, Q], once for all a group's heads
       y = (G * L^h) (dt x)^h            L^h[t, s] = exp(c_t - c_s), s <= t
         + exp(c_t) * (C S_prev) + D x
       S_next = exp(c_Q) S_prev + B^T (exp(c_Q - c_s) dt x)
@@ -418,15 +460,32 @@ def ssd_scan(x, dt, A, B, C, D, state0=None, length=None, *,
     is never rounded). Nothing of size `S x Di x N` is made, and nothing
     larger than `[H, Q, Q]` float32 a chunk."""
     S, Di = x.shape
-    H, N = dt.shape[-1], B.shape[-1]
-    P = Di // H
-    dtype = x.dtype
+    N = B.shape[-1]
     attention._path_counts["ssd_chunked"] += 1
     if state0 is None:
         state0 = jnp.zeros((N, Di), F32)
     dt = dt.astype(F32)
     if length is not None:
         dt = jnp.where(jnp.arange(S)[:, None] < length, dt, 0.0)
+    B, C = _one_group(B), _one_group(C)
+    if B.ndim == 2:
+        return _ssd_chunks(x, dt, A, B, C, D, state0, chunk)
+    G = B.shape[1]
+    y, state = jax.vmap(
+        functools.partial(_ssd_chunks, chunk=chunk),
+        in_axes=(1, 1, 0, 1, 1, 0, 1), out_axes=1)(
+        x.reshape(S, G, -1), dt.reshape(S, G, -1), A.reshape(G, -1), B, C,
+        D.reshape(G, -1), state0.reshape(N, G, -1))
+    return y.reshape(S, Di), state.reshape(N, Di)
+
+
+def _ssd_chunks(x, dt, A, B, C, D, state0, chunk):
+    """`ssd_scan` of ONE group: x `[S, Di]`, dt `[S, H]` float32 (dead rows'
+    0), B, C `[S, N]`, state0 `[N, Di]`."""
+    S, Di = x.shape
+    H, N = dt.shape[-1], B.shape[-1]
+    P = Di // H
+    dtype = x.dtype
     Q = min(chunk, S)
     pad = -S % Q
     if pad:     # rows that move nothing: dt = 0

@@ -539,7 +539,7 @@ class Engine:
         log says so (the engine then holds that copy of the experts; the
         caller may drop its own)."""
         cast = {stack: [k for k in ("w_gate", "w_up", "w_down")
-                        if leaves[k].dtype != mcfg.dtype]
+                        if k in leaves and leaves[k].dtype != mcfg.dtype]
                 for stack, leaves in params.items()
                 if isinstance(leaves, dict) and "router" in leaves}
         if not any(cast.values()):
@@ -653,8 +653,10 @@ class Engine:
         model adds `expert_tokens` (assignments per expert, all layers,
         prefills and decode steps, as far as the emitter has fetched them)
         and `decode_experts_touched` (distinct experts, summed over decode
-        steps and layers: over `decode_chunks * chunk * n_layers` it is the
-        experts whose weights a layer reads in a step). A sparse-attention
+        steps and SPARSE layers: over `decode_chunks * chunk *
+        mcfg.sparse_layers` it is the experts whose weights a sparse layer
+        reads in a step; a stack of one-part layers has fewer of those than
+        layers). A sparse-attention
         model adds `decode_selected_keys` over `decode_live_keys`: the share
         of the live positions its decode steps read K and V of. A model with
         state-space layers adds `state_bytes`, the recurrent state it holds

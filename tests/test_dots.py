@@ -279,9 +279,9 @@ def test_a_shares_combine_at_the_edges_off_the_chip(tiny, case, monkeypatch):
     else:
         assert 0 < int(counts.sum()) < 96       # rows in no group at the end
         real = moe.grouped_matmul
-        monkeypatch.setattr(moe, "grouped_matmul", lambda xs, w, groups: (
+        monkeypatch.setattr(moe, "grouped_matmul", lambda xs, w, groups, **kw: (
             jnp.where((jnp.arange(xs.shape[0]) < jnp.sum(groups))[:, None],
-                      real(xs, w, groups), jnp.nan)))
+                      real(xs, w, groups, **kw), jnp.nan)))
         poisoned, _, _ = moe.moe_ffn(g, lp["router"], *mine, **args)
         assert np.array_equal(np.asarray(poisoned), np.asarray(got))
         assert np.abs(np.asarray(got)).max() > 1e-2

@@ -861,6 +861,102 @@ def test_sdar_block_programs_keep_the_pages_in_place_on_v5e(
 # The experts' grouped matmul (ops/moe.py::grouped_matmul)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("program", ["decode", "prefill1024", "prefill2048",
+                                     "prefill2560", "prefill3072",
+                                     "prefill3584"])
+def test_nemotron_programs_keep_pages_and_state_in_place_on_v5e(
+        topo, program, monkeypatch):
+    """The stack of one-part layers at the cell's sizes
+    (benchmark/configs/nemotron-3-nano-30b-a3b-serve.json): its decode chunk
+    of 32 slots and its prefill at every bucket the mix's prompts of
+    1,024-3,584 land in. The recurrent state of the 7 mixers is 0.47 GB (7 x
+    32 slots x 128 x 4,096 float32) and rides the decode loop's carry: it,
+    its windows over 6,144 channels (x and 8 groups' B and C) and the pages of
+    the TWO attention layers are donated and alias the outputs. Decode's
+    attention is the `paged_decode` kernel at 16 query heads a kv head, its
+    state's update the `ssd_state_step` kernel with groups handed the whole
+    state, a prompt's attention `flash_fwd`, the recurrence over a prompt
+    the chunked dual form in plain XLA, the experts' TWO grouped matmuls the
+    Pallas kernel at 2688 -> 1856 -> 2688 with no copy of a stack, and the
+    share's combine the local kernel; and the bytes are PERF.md section 4's
+    row."""
+    import json
+
+    from benchmark import models
+    from ray_tpu.models.block import fuse_qkv
+    from ray_tpu.models.llama import init_params
+    from ray_tpu.models.serving import build_programs
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs",
+                           "nemotron-3-nano-30b-a3b-serve.json")) as f:
+        model = json.load(f)
+    eng = model["deployment"]["engine"]
+    cfg = models.adapter("nemotron_h").build_config(
+        model, model["dtypes"], eng["max_seq"])
+    ns, page = eng["n_slots"], eng["page_size"]
+    maxp = eng["max_seq"] // page
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    built = build_programs(cfg, ns, eng["decode_chunk"], page,
+                           eng["kv_pages"])
+    params = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+            lambda: fuse_qkv(init_params(cfg, jax.random.PRNGKey(0)), cfg)))
+    caches = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(built.empty))
+    kc, vc, ic, (ssm, window) = caches
+    assert kc.shape == vc.shape == (2, eng["kv_pages"], 2, page, 128)
+    assert ic is None and ssm.shape == (7, ns, 128, 4096) \
+        and ssm.dtype == jnp.float32 and window.shape == (7, 3, ns, 6144)
+    before = dict(attention.attention_path_counts())
+    if program == "decode":
+        lowered = built.decode.lower(
+            params, caches, sds((ns, maxp), jnp.int32), sds((ns,), jnp.int32),
+            sds((ns,), jnp.int32), sds((ns,), jnp.bool_),
+            sds((ns,), jnp.float32), sds((ns,), jnp.int32),
+            sds((ns, 2), jnp.uint32))
+        kernels, paths = ["paged_decode", "grouped_matmul", "local_combine",
+                          "ssd_state_step"], [
+            "decode_pallas", "experts_grouped_pallas", "share_combine_local",
+            "ssd_step_pallas"]
+    else:
+        lowered = built.prefill.lower(
+            params, caches, sds((maxp,), jnp.int32),
+            sds((1, int(program[7:])), jnp.int32), 1, 0.0, 0,
+            sds((2,), jnp.uint32), 0)
+        kernels, paths = ["flash_fwd", "grouped_matmul", "local_combine"], [
+            "fwd_pallas", "experts_grouped_pallas", "share_combine_local",
+            "ssd_chunked"]
+    text = lowered.as_text()
+    assert all(k in text for k in kernels)
+    counts = attention.attention_path_counts()
+    assert all(counts[p] > before.get(p, 0) for p in paths)
+    assert counts.get("experts_ragged_dot", 0) == before.get(
+        "experts_ragged_dot", 0)
+    compiled = lowered.compile()
+    stacks = [tuple(params["experts"][w].shape) for w in ("w_up", "w_down")]
+    assert not _moved_stacks(compiled.as_text(), stacks)
+    mem = compiled.memory_analysis()
+    held = sum(x.size * x.dtype.itemsize for x in (kc, vc, ssm, window))
+    assert held == 2 * 2 * eng["kv_pages"] * 2 * page * 128 * 2 \
+        + 7 * ns * 128 * 4096 * 4 + 7 * 3 * ns * 6144 * 2 == 746_586_112
+    assert mem.alias_size_in_bytes >= held
+    weights = sum(x.size * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert weights == 2 * 5_282_534_208
+    # arguments: the weights, the caches and a step's few vectors
+    assert 0 <= mem.argument_size_in_bytes - weights - held < 1 << 20
+    print(program, "temp", mem.temp_size_in_bytes, "args",
+          mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < ((64 << 20) if program == "decode"
+                                     else (2 << 30))
+
+
 def _moved_stacks(hlo, stacks):
     """Names of the compiled program's instructions whose result has the
     shape of an expert stack, of one layer of one or of one expert's matrix
