@@ -14,6 +14,7 @@ import os
 
 os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
     " --xla_force_host_platform_device_count=8"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
 
 import jax  # noqa: E402
 
@@ -32,6 +33,36 @@ def devices8():
     devs = jax.devices()
     assert len(devs) == 8, devs
     return devs
+
+
+# ---------------------------------------------------------------------------
+# A TPU that is described, not attached (tests/test_tpu_compile_*.py,
+# tests/compile_for_v5e.py)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="session")
+def topo():
+    try:
+        from jax.experimental import topologies
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or it cannot describe v5e
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+
+
+@pytest.fixture
+def _no_compile_cache():
+    """An executable compiled for a described chip is written to the
+    persistent cache but cannot be read back without the chip; keep the
+    cache out of it. (A file that compiles for the described chip takes this
+    for all its tests: `pytestmark = pytest.mark.usefixtures(...)`.)"""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
 
 
 # ---------------------------------------------------------------------------

@@ -2,9 +2,9 @@
 leading dense layer, a sigmoid group-limited router over experts of which a
 SHARE is held, a shared expert) at small float32 widths on the CPU: the
 program against `benchmark/reference_dots.py`, its two kernels in interpret
-mode against their reference paths, the share against the uncut layer, the
-refusals, and the other models' programs against the parent's (sha256 of
-their lowered text).
+mode against their reference paths, the share against the uncut layer, and
+the refusals. (The other models' programs against the parent's, which
+this model's PR pinned first: tests/test_parents_programs.py.)
 
 Tolerance: program and reference compute the same mathematics in float32 and
 differ in the order of their sums; LOGIT_TOL 2e-4 is the one test_olmoe.py,
@@ -12,10 +12,8 @@ test_keye.py and test_jamba.py hold the same pairs to.
 """
 
 import functools
-import hashlib
 import math
 import time
-from unittest import mock
 
 import numpy as np
 import pytest
@@ -24,15 +22,13 @@ import jax
 import jax.numpy as jnp
 
 from benchmark import models, reference_dots
-from ray_tpu.models import llama, serving
+from ray_tpu.models import llama
 from ray_tpu.models.block import (fuse_qkv, latent_attention_inputs,
                                   latent_attention_output, split_qkv)
-from ray_tpu.models.serving import Caches, prefill_core
+from ray_tpu.models.serving import prefill_core
 from ray_tpu.ops import attention, moe, paged_kv
 from ray_tpu.ops.norms import apply_rope, yarn_inv_frequencies
 from ray_tpu.serve.engine import Engine
-from test_mimo import PUBLISHED as MIMO
-from test_serve_llm import parents_sample_tokens
 
 LOGIT_TOL = 2e-4
 F32 = {"params": "float32", "activations": "float32"}
@@ -178,6 +174,7 @@ def _sparse_layer(tiny, tokens=48, seed=1):
     return model, cfg, lp, g
 
 
+@pytest.mark.timeout(240)
 def test_the_shares_parts_and_the_shared_expert_once_are_the_uncut_layer(tiny):
     """16 experts in 4 shares of 4: every share in turn holds its 4 experts'
     weights (drawn here for all 16), routes over all 16 and computes its
@@ -296,6 +293,7 @@ def _rope_tables(cfg, n):
     return latent_rope_tables(cfg, n)
 
 
+@pytest.mark.timeout(240)
 def test_the_absorbed_decode_form_is_the_naive_form(tiny):
     """The last token of a sequence through the prompt's form (keys and
     values up-projected a head, `latent_flash_attention`) and through the
@@ -541,141 +539,3 @@ def test_the_configuration_is_the_catalogs_row_cut_to_a_share():
     assert cfg.segments() == (("dense", 0, 1), ("layers", 0, 4))
     ops, byts = counts.latent_decode_ops_bytes(m, [1000], 2)
     assert ops / (1000 * 1152) == pytest.approx(241.8, abs=0.1)
-
-
-# ---------------------------------------------------------------------------
-# Nobody else's program moved
-# ---------------------------------------------------------------------------
-
-# sha256 (first 16 hex digits) of the lowered text of the other models'
-# serving programs at their adapters' rehearsal widths and of a dense train
-# step at `LlamaConfig.tiny`, on the parent commit of PR 39 (00d21d1; jax
-# 0.9.0 on the CPU: no Mosaic payload, no source locations in the text).
-# Since PR 41 a dense and a sparse stack's rungs of the octave under `max_seq`
-# (64 and 128 here) carry the live slots (`engine.rung_rides`) and lower to
-# another text on purpose: their pin is the 32 rung, taken on PR 41's parent
-# (5481b82), as are this model's own (`latent`), which takes nobody.
-# tests/test_prefill_riders.py pins every rung of every stack. The same two
-# stacks' decode programs hand the arena to the jit they share with the riders
-# (`_token_step`: the same write and kernel, one trace a process) and were
-# taken anew on PR 41's tree (their parent's: 4ce2defd4ff49240 and
-# 278d751dc50fcfc4); the three stacks that take nobody keep the parent's.
-# `mixed` (PR 42's stack, which takes nobody) was taken on PR 45's parent
-# (6c2c097), before that PR moved a line under ray_tpu/.
-# Since PR 47 `serving.sample_tokens` takes its top-k behind a conditional, so
-# every serving program's text moves, by design, inside `sample` and nowhere
-# else: the pinned programs are lowered with the sampler of PR 47's parent
-# (7f64f96; `test_serve_llm.parents_sample_tokens`, which the sampler is held
-# to token for token there) in its place, and every digest stands unmoved.
-PARENT = {
-    "dense.decode": "d87712c9b4ee5285",
-    "dense.prefill32": "c948937b09fe2fee",
-    "hybrid.decode": "98e6e614b5625848",
-    "hybrid.prefill64": "b6847a6dfe909d84",
-    "indexed.decode": "7f5193fdda9e8db0",
-    "indexed.prefill64": "2b26fc68f7f5f898",
-    "latent.decode": "3cbcf9da23401fa5",
-    "latent.prefill64": "f96e02f0c080c3fb",
-    "mixed.decode": "c31b6808d133c647",
-    "mixed.prefill64": "ef4db6b4bc528b78",
-    "sparse.decode": "94dff0eb228ce990",
-    "sparse.prefill32": "7a5fc5aa7c158c94",
-    "train.tiny": "569d197c86234e93",
-}
-KINDS = {
-    "dense": ("llama", dict(rope_theta=10000, rms_norm_eps=1e-5)),
-    "sparse": ("olmoe", dict(rope_theta=10000, rms_norm_eps=1e-5,
-                             norm_topk_prob=False)),
-    "indexed": ("keye", dict(rope_theta=10000000, rms_norm_eps=1e-6,
-                             norm_topk_prob=True)),
-    "hybrid": ("jamba", dict(rms_norm_eps=1e-6, num_experts=1,
-                             tie_word_embeddings=True)),
-    "latent": ("dots", PUBLISHED),
-    "mixed": ("mimo", MIMO),
-}
-
-
-def _sha(text):
-    return hashlib.sha256(text.encode()).hexdigest()[:16]
-
-
-def _lowered(kind):
-    if kind == "train":
-        cfg = llama.LlamaConfig.tiny()
-        params = jax.eval_shape(
-            lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
-        step = jax.jit(jax.value_and_grad(
-            lambda p, t: llama.loss_fn(p, t, cfg)[0]))
-        return {"train.tiny": _sha(step.lower(
-            params, jax.ShapeDtypeStruct((2, 64), jnp.int32)).as_text())}
-    adapter = models.adapter(KINDS[kind][0])
-    model = dict(adapter.REHEARSE, **KINDS[kind][1])
-    cfg = adapter.build_config(model, F32, 128)
-    eng = Engine(adapter.init_params(cfg, 3), cfg, n_slots=2, decode_chunk=2,
-                 page_size=16)
-    try:
-        width = 32 if eng._rides(64) else 64
-        return {f"{kind}.prefill{width}": _sha(parents_prefill_text(eng,
-                                                                    width)),
-                f"{kind}.decode": _sha(parents_decode_text(eng))}
-    finally:
-        eng.stop()
-
-
-# The pins were taken when the programs took the caches apart (`kc, vc` after
-# `params`; `ic`, `state` and `slot` after `key`; the one further cache a
-# model has LAST among the results), and lowered text names arguments by
-# position. So a pinned program is lowered from its own function (the jit's
-# `__wrapped__`) under the parent's name, argument order, result order and
-# `donate_argnums`, which puts the bundle together and takes it apart again:
-# what is compared is then the parent's text, or the program changed. The
-# one callee that changed on purpose since (PR 47's sampler) is lowered as the
-# parent had it: `_parents_sampler`.
-
-def _parents_sampler():
-    return mock.patch.object(serving, "sample_tokens", parents_sample_tokens)
-
-
-def parents_prefill_text(eng, width):
-    def prefill(params, kc, vc, pages, tokens, length, temp, topk, key,
-                ic=None, state=None, slot=None, last=None, pos=None,
-                riders=None):
-        caches, first, experts, *rode = eng._programs.prefill.__wrapped__(
-            params, Caches(kc, vc, ic, state), pages, tokens, length, temp,
-            topk, key, slot, last, pos, riders)
-        return (caches.kc, caches.vc, first, experts, *(
-            c for c in (caches.ic, caches.state) if c is not None), *rode)
-
-    params, caches, pages, tokens, length, temp, topk, key, slot, *riding = \
-        eng.prefill_shapes(width)
-    with _parents_sampler():
-        return jax.jit(prefill, donate_argnums=(1, 2, 9, 10, 12, 13)).lower(
-            params, caches.kc, caches.vc, pages, tokens, length, temp, topk,
-            key, caches.ic, caches.state, slot, *riding).as_text()
-
-
-def parents_decode_text(eng):
-    def decode(params, kc, vc, bt, last, pos, active, temp, topk, keys,
-               ic=None, state=None):
-        caches, last, pos, out, experts = eng._programs.decode.__wrapped__(
-            params, Caches(kc, vc, ic, state), bt, last, pos, active, temp,
-            topk, keys)
-        return (caches.kc, caches.vc, last, pos, out, experts, *(
-            c for c in (caches.ic, caches.state) if c is not None))
-
-    params, caches, *slots = eng.decode_shapes()
-    with _parents_sampler():
-        return jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10, 11)).lower(
-            params, caches.kc, caches.vc, *slots, caches.ic,
-            caches.state).as_text()
-
-
-@pytest.mark.parametrize("kind", ["dense", "sparse", "indexed", "hybrid",
-                                  "latent", "mixed", "train"])
-def test_the_other_models_programs_are_the_parents(kind):
-    """What a dense, a sparse (softmax router, every expert), an indexed, a
-    hybrid, a latent and a mixed engine's prefill (a rung that takes no
-    riders) and decode, and a dense train step, lower to is letter for letter
-    what the parent commit lowers them to."""
-    got = _lowered(kind)
-    assert got == {k: PARENT[k] for k in got}

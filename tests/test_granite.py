@@ -6,8 +6,9 @@ with the family's four multipliers, a SHARE of the experts beside a shared
 expert in a hybrid stack, and the whole of it through `Engine`.
 
 (a) the scan alone; (b) steps and split prompts; (c) the mixer, the attention
-block and the multipliers; (d) the share; (e) the engine; (f) the adapter and
-the counts; (g) the path counters.
+block and the multipliers; (d) the share; (e) the engine
+(tests/test_granite_engine.py); (f) the adapter and the counts; (g) the path
+counters.
 """
 
 import dataclasses
@@ -25,8 +26,6 @@ from benchmark import reference_granite as ref
 from ray_tpu.models import block, llama, serving
 from ray_tpu.models.block import fuse_qkv, mamba2_mixer
 from ray_tpu.ops import attention, slot_state, ssm
-from ray_tpu.serve.engine import Engine
-from ray_tpu.utils import tracing
 
 # Float32 everywhere on the CPU: what is left between the program and the
 # reference is the order of float32 sums (a chunk's matrix products against
@@ -92,17 +91,6 @@ def tiny():
 def _tokens(n, seed=0):
     return [int(t) for t in
             np.random.default_rng(seed).integers(0, 256, n, dtype=np.int32)]
-
-
-def _serve(engine, prompts, n):
-    outs = [engine.submit(p, n) for p in prompts]
-    served = []
-    for q in outs:
-        toks = []
-        while (chunk := q.get(timeout=300)) is not None:
-            toks += chunk
-        served.append(toks)
-    return served
 
 
 def _err(got, want):
@@ -398,199 +386,6 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
         total = total + (got - x)
     assert np.abs(np.asarray(total) - np.asarray(whole)).max() < SCAN_TOL
     assert np.abs(np.asarray(whole)).max() > 0.1
-
-
-# -- (e) the engine ----------------------------------------------------------
-
-@pytest.fixture
-def engine(tiny):
-    cfg, params = tiny
-    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=4,
-                 decode_chunk=4, page_size=16)
-    yield eng
-    eng.stop()
-
-
-def test_engine_prefill_then_decode_match_the_reference(tiny, engine):
-    """Three slots at once: a prompt that fills its bucket (64), one that
-    leaves padding behind it (70 in 128) and one whose decode crosses two
-    page boundaries (21 -> 45, pages of 16). At every served position the
-    token the engine chose is the reference's largest logit to float32
-    rounding, and the logits the prefill program itself returns are the
-    reference's, with the K and V of ONE layer, the state of three and the
-    share's routing counts."""
-    cfg, params = tiny
-    prompts = [_tokens(64, 5), _tokens(70, 6), _tokens(21, 7)]
-    served = _serve(engine, prompts, 24)
-    assert [len(s) for s in served] == [24, 24, 24]
-    for prompt, toks in zip(prompts, served):
-        gaps = ref.served_token_gaps(params, MODEL, prompt, toks)
-        assert max(gaps) < LOGIT_TOL, gaps
-    core = jax.jit(serving.prefill_core(cfg))
-    for prompt, width in zip(prompts, (64, 128, 32)):
-        padded = jnp.asarray([prompt + [9] * (width - len(prompt))], jnp.int32)
-        _, ks, _, logits, experts, (ssm_rows, conv_rows) = core(
-            fuse_qkv(params, cfg), padded, len(prompt))
-        want = np.asarray(ref.logits_last(params, MODEL, prompt, 1))[0]
-        assert np.abs(np.asarray(logits) - want).max() < LOGIT_TOL
-        assert ks.shape == (1, width, 2, 32)
-        assert ssm_rows.shape == (3, 16, 256) and conv_rows.shape == (3, 3, 288)
-        # 4 layers x the prompt's rows x 3 experts a token, about half local
-        assert experts.shape == (6,) and int(experts[-1]) == 12 * len(prompt)
-        assert 0 < int(experts[:4].sum()) < int(experts[-1])
-    counts = engine.counters()
-    assert counts["state_writes"] == 3
-    assert counts["state_bytes"] == 3 * 4 * (16 * 256 * 4 + 3 * 288 * 4)
-    assert 0 < counts["local_assignments"] < counts["routed_assignments"]
-    assert len(counts["expert_tokens"]) == 4
-    assert engine._caches.kc.shape[0] == 1 and engine._caches.ic is None
-
-
-class _Spans:
-    """`with _Spans() as spans:` records (name, arguments) of every span the
-    program opens meanwhile, beside what `tracing.span` does with it, and
-    says a recorder is there (the loop puts a chunk's routing together only
-    where a span is recorded)."""
-
-    def __enter__(self):
-        self.seen, self._span = [], tracing.span
-        self._recording = tracing.recording
-
-        def recording(name, **args):
-            self.seen.append((name, args))
-            return self._span(name, **args)
-
-        tracing.span, tracing.recording = recording, lambda: True
-        return self
-
-    def __exit__(self, *exc):
-        tracing.span, tracing.recording = self._span, self._recording
-
-    def named(self, name):
-        return [args for n, args in self.seen if n == name]
-
-
-def test_the_hybrid_engine_took_its_paths_and_its_spans_carry_the_share(
-        tiny, engine):
-    """What the share's readers need, as the latent and the mixed engines'
-    spans carry it (tests/test_dots.py, tests/test_mimo.py):
-    `serve.engine.prefill_experts` has `local` and `routed` beside `touched`,
-    `serve.engine.decode_dispatch` `local_assignments`, `experts_touched`,
-    `active` and `live_kv_tokens`; and the paths the programs took."""
-    import time
-    cfg, _ = tiny
-    prompt = _tokens(40, 31)
-    with _Spans() as spans:
-        assert len(_serve(engine, [prompt], 16)[0]) == 16
-        # a chunk's routing is reported by the NEXT dispatch: one more request
-        assert len(_serve(engine, [_tokens(9, 32)], 12)[0]) == 12
-        deadline = time.monotonic() + 30
-        while len(spans.named("serve.engine.prefill_experts")) < 2 \
-                and time.monotonic() < deadline:
-            time.sleep(0.01)    # the emitter counts AFTER the tokens
-    pre = spans.named("serve.engine.prefill_experts")
-    layers, k = cfg.n_layers, cfg.top_k_experts
-    assert [a["routed"] for a in pre] == [40 * k * layers, 9 * k * layers]
-    assert all(0 < a["local"] < a["routed"] for a in pre)
-    assert all(0 < a["touched"] <= 4 * layers for a in pre)
-    chunks = [a for a in spans.named("serve.engine.decode_dispatch")
-              if a.get("local_assignments")]
-    assert chunks
-    for a in chunks:
-        assert 0 < a["local_assignments"] < a["routed_assignments"]
-        assert 0 < a["experts_touched"] <= 4 * layers * engine.chunk
-        assert a["active"] == 1 and a["live_kv_tokens"] > 0
-        assert len(str(a["expert_tokens"]).split(":")) == 4
-    counts = attention.attention_path_counts()
-    assert counts["ssd_chunked"] >= 1 and counts["fwd_reference"] >= 1
-    assert counts["decode_reference"] >= 1      # the CPU's decode path
-    assert counts["ssd_step_reference"] >= 1    # ... and its state's update
-    assert counts["share_combine_gather"] >= 1  # off the chip, the gather
-    assert counts["experts_ragged_dot"] >= 1
-
-
-def test_an_engine_decodes_through_the_step_kernel(tiny, monkeypatch):
-    """An engine built with `slot_state.step_layer` interpreted (what the
-    mixer calls is the function as it stands on the module) updates its
-    slots' state through the kernel's own code, in place in the decode
-    program's carry, one slot of four live: the served tokens are the
-    reference's to the engine's tolerance."""
-    import functools
-    cfg, params = tiny
-    monkeypatch.setattr(slot_state, "step_layer", functools.partial(
-        slot_state.step_layer, interpret=True))
-    before = attention.attention_path_counts().get("ssd_step_pallas", 0)
-    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=4,
-                 decode_chunk=4, page_size=16)
-    try:
-        assert attention.attention_path_counts()["ssd_step_pallas"] > before
-        prompt = _tokens(40, 41)
-        toks = _serve(eng, [prompt], 12)[0]
-    finally:
-        eng.stop()
-    assert max(ref.served_token_gaps(params, MODEL, prompt, toks)) < LOGIT_TOL
-
-
-def test_a_bfloat16_state_is_outside_the_tolerance(tiny, engine):
-    """The tolerance tells a narrower recurrence from the real one: the
-    reference with its state rounded to bfloat16 after every token is not
-    within LOGIT_TOL of what the engine serves."""
-    cfg, params = tiny
-    prompt = _tokens(70, 6)
-    toks = _serve(engine, [prompt], 8)[0]
-    seq = prompt + toks[:-1]
-    exact = np.asarray(ref.logits_last(params, MODEL, seq, 8))
-    coarse = np.asarray(ref.logits_last(params, MODEL, seq, 8,
-                                        state_dtype=jnp.bfloat16))
-    assert max(ref.served_token_gaps(params, MODEL, prompt, toks)) < LOGIT_TOL
-    assert np.abs(coarse - exact).max() > 10 * LOGIT_TOL
-
-
-def test_a_request_is_served_alike_alone_after_another_and_beside_idle_slots(
-        tiny):
-    """One slot: the same prompt first, then after a longer tenant of the
-    same slot (whose state and window the admission must overwrite whole),
-    gives the same tokens. Four slots: beside three idle ones, and while a
-    neighbour decodes and finishes (an idle slot's state must not move, an
-    active one's must not leak), the same again; all the reference's."""
-    cfg, params = tiny
-    a, b = _tokens(60, 21), _tokens(140, 22)
-    one = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=1,
-                 decode_chunk=4, page_size=16)
-    try:
-        first = _serve(one, [a], 12)[0]
-        other = _serve(one, [b], 12)[0]
-        again = _serve(one, [a], 12)[0]
-        assert one.counters()["state_writes"] == 3
-    finally:
-        one.stop()
-    four = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=4,
-                  decode_chunk=4, page_size=16)
-    try:
-        alone = _serve(four, [a], 12)[0]
-        beside = _serve(four, [a, b], 12)
-        later = _serve(four, [b[:30], a], 12)[1]
-    finally:
-        four.stop()
-    assert first == again == alone == beside[0] == later
-    assert other == beside[1]
-    for prompt, toks in ((a, first), (b, other)):
-        assert max(ref.served_token_gaps(params, MODEL, prompt, toks)) \
-            < LOGIT_TOL
-
-
-def test_a_pd_handoff_and_the_training_forward_are_refused_by_name(tiny,
-                                                                   engine):
-    cfg, params = tiny
-    assert not serving.adopts(cfg)
-    with pytest.raises(NotImplementedError, match="recurrent state"):
-        engine.submit_prefilled(None, None, 8, 1, 4)
-    with pytest.raises(NotImplementedError, match="state-space layers"):
-        llama.forward(params, jnp.zeros((1, 8), jnp.int32), cfg)
-    dense = llama.LlamaConfig.tiny(embed_scale=12.0)
-    with pytest.raises(NotImplementedError, match="embed_scale"):
-        llama.forward(llama.init_params(dense, jax.random.PRNGKey(0)),
-                      jnp.zeros((1, 8), jnp.int32), dense)
 
 
 # -- (f) the adapter and the counts ------------------------------------------

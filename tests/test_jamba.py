@@ -15,6 +15,10 @@ leaves room for that and none for a lower precision: the same reference with
 its recurrent state kept in bfloat16 misses it by two orders
 (`test_a_bfloat16_state_is_outside_the_tolerance`). SCAN_TOL 2e-5 is for the
 scan alone on inputs of size ~1 (state and y of size ~1, sums of 16 terms).
+
+Here: (a) the scan alone, (d) a prompt split at any point, (e) the tied head,
+(f) the adapter, and the helpers; (b) the engine against the reference and
+(c) a slot's state its tenant's alone are tests/test_jamba_engine.py.
 """
 
 import functools
@@ -31,7 +35,6 @@ from ray_tpu.models import block, llama
 from ray_tpu.models.block import fuse_qkv, mamba_mixer
 from ray_tpu.ops import attention, ssm
 from ray_tpu.models.serving import prefill_core
-from ray_tpu.serve.engine import Engine
 
 LOGIT_TOL = 2e-4
 SCAN_TOL = 2e-5
@@ -92,17 +95,6 @@ def scan_in_interpret_mode(monkeypatch):
 def _tokens(n, seed=0):
     return [int(t) for t in
             np.random.default_rng(seed).integers(0, 256, n, dtype=np.int32)]
-
-
-def _serve(engine, prompts, n):
-    outs = [engine.submit(p, n) for p in prompts]
-    served = []
-    for q in outs:
-        toks = []
-        while (chunk := q.get(timeout=300)) is not None:
-            toks += chunk
-        served.append(toks)
-    return served
 
 
 # -- (a) the scan alone ------------------------------------------------------
@@ -206,103 +198,6 @@ def test_a_split_prompt_is_the_unsplit_one(tiny, cut, scan_in_interpret_mode):
     assert np.abs(got - np.asarray(whole)).max() < SCAN_TOL
     assert np.abs(np.asarray(s2) - np.asarray(state)).max() < SCAN_TOL
     np.testing.assert_allclose(np.asarray(w2), np.asarray(window), atol=1e-6)
-
-
-# -- (b) the engine against the reference -----------------------------------
-
-@pytest.fixture
-def engine(tiny, scan_in_interpret_mode):
-    cfg, params = tiny
-    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=4,
-                 decode_chunk=4, page_size=16)
-    yield eng
-    eng.stop()
-
-
-def test_engine_prefill_then_decode_match_the_reference(tiny, engine):
-    """Three slots at once: a prompt that fills its bucket (64), one that
-    leaves padding behind it (70 in 128) and one whose decode crosses two
-    page boundaries (21 -> 45, pages of 16). At every served position the
-    token the engine chose is the reference's largest logit to float32
-    rounding, and the logits the prefill program itself returns are the
-    reference's, with the K and V of ONE layer and the state of three."""
-    cfg, params = tiny
-    before = attention.attention_path_counts().get("scan_pallas", 0)
-    prompts = [_tokens(64, 5), _tokens(70, 6), _tokens(21, 7)]
-    served = _serve(engine, prompts, 24)
-    assert [len(s) for s in served] == [24, 24, 24]
-    for prompt, toks in zip(prompts, served):
-        gaps = ref.served_token_gaps(params, MODEL, prompt, toks)
-        assert max(gaps) < LOGIT_TOL, gaps
-    core = jax.jit(prefill_core(cfg))
-    for prompt, width in zip(prompts, (64, 128, 32)):
-        padded = jnp.asarray([prompt + [9] * (width - len(prompt))], jnp.int32)
-        _, ks, _, logits, experts, (ssm_rows, conv_rows) = core(
-            fuse_qkv(params), padded, len(prompt))
-        want = np.asarray(ref.logits_last(params, MODEL, prompt, 1))[0]
-        assert np.abs(np.asarray(logits) - want).max() < LOGIT_TOL
-        assert ks.shape == (1, width, 1, 16) and experts is None
-        assert ssm_rows.shape == (3, 16, 128) and conv_rows.shape == (3, 3, 128)
-    assert attention.attention_path_counts()["scan_pallas"] > before
-    counts = engine.counters()
-    assert counts["state_writes"] == 3
-    assert counts["state_bytes"] == 3 * 4 * (16 * 128 * 4 + 3 * 128 * 4)
-    assert engine._caches.kc.shape[0] == 1 and engine._caches.ic is None
-
-
-def test_a_bfloat16_state_is_outside_the_tolerance(tiny, engine):
-    """The tolerance tells a narrower recurrence from the real one: the
-    reference with its state rounded to bfloat16 after every token is not
-    within LOGIT_TOL of what the engine serves."""
-    cfg, params = tiny
-    prompt = _tokens(70, 6)
-    toks = _serve(engine, [prompt], 8)[0]
-    seq = prompt + toks[:-1]
-    exact = np.asarray(ref.logits_last(params, MODEL, seq, 8))
-    coarse = np.asarray(ref.logits_last(params, MODEL, seq, 8,
-                                        state_dtype=jnp.bfloat16))
-    assert max(ref.served_token_gaps(params, MODEL, prompt, toks)) < LOGIT_TOL
-    assert np.abs(coarse - exact).max() > 10 * LOGIT_TOL
-
-
-# -- (c) a slot's state is its tenant's alone -------------------------------
-
-def test_a_request_is_served_alike_alone_after_another_and_beside_idle_slots(
-        tiny, scan_in_interpret_mode):
-    """One slot: the same prompt first, then after a longer tenant of the
-    same slot (whose state and window the admission must overwrite whole),
-    gives the same tokens. Four slots: beside three idle ones, and while a
-    neighbour decodes and finishes (an idle slot's state must not move, an
-    active one's must not leak), the same again; all the reference's."""
-    cfg, params = tiny
-    a, b = _tokens(60, 21), _tokens(140, 22)
-    one = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=1,
-                 decode_chunk=4, page_size=16)
-    try:
-        first = _serve(one, [a], 12)[0]
-        other = _serve(one, [b], 12)[0]
-        again = _serve(one, [a], 12)[0]
-        assert one.counters()["state_writes"] == 3
-    finally:
-        one.stop()
-    four = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=4,
-                  decode_chunk=4, page_size=16)
-    try:
-        alone = _serve(four, [a], 12)[0]
-        beside = _serve(four, [a, b], 12)
-        later = _serve(four, [b[:30], a], 12)[1]
-    finally:
-        four.stop()
-    assert first == again == alone == beside[0] == later
-    assert other == beside[1]
-    for prompt, toks in ((a, first), (b, other)):
-        assert max(ref.served_token_gaps(params, MODEL, prompt, toks)) \
-            < LOGIT_TOL
-
-
-def test_a_pd_handoff_is_refused_not_served_without_its_state(tiny, engine):
-    with pytest.raises(NotImplementedError, match="recurrent state"):
-        engine.submit_prefilled(None, None, 8, 1, 4)
 
 
 def test_the_training_forward_refuses_state_space_layers_by_name(tiny):

@@ -8,8 +8,8 @@ shared expert, attention alone with no position signal, and the whole of it
 through `Engine`.
 
 (a) the recurrence with groups; (b) the parts against the reference: mixer,
-router, experts, shares; (c) the engine; (d) the pattern, the stacks and the
-refusals.
+router, experts, shares; (c) the engine (tests/test_nemotron_h_engine.py);
+(d) the pattern, the stacks and the refusals.
 """
 
 import hashlib
@@ -23,9 +23,8 @@ import jax.numpy as jnp
 from benchmark import models
 from benchmark import reference_nemotron_h as ref
 from ray_tpu.models import llama, serving
-from ray_tpu.models.block import feed_forward, fuse_qkv, mamba2_mixer
+from ray_tpu.models.block import feed_forward, mamba2_mixer
 from ray_tpu.ops import attention, moe, slot_state, ssm
-from ray_tpu.serve.engine import Engine
 
 # Float32 everywhere on the CPU: what is left between the program and the
 # reference is the order of float32 sums (a chunk's matrix products against
@@ -93,22 +92,6 @@ def tiny():
                               ("mamba", 1, 2), ("attn", 0, 1),
                               ("experts", 1, 2), ("mamba", 2, 3))
     return cfg, _params(cfg)
-
-
-def _tokens(n, seed=0):
-    return [int(t) for t in
-            np.random.default_rng(seed).integers(0, 256, n, dtype=np.int32)]
-
-
-def _serve(engine, prompts, n):
-    outs = [engine.submit(p, n) for p in prompts]
-    served = []
-    for q in outs:
-        toks = []
-        while (chunk := q.get(timeout=300)) is not None:
-            toks += chunk
-        served.append(toks)
-    return served
 
 
 def _layer_f32(params, name, i):
@@ -431,117 +414,6 @@ def test_the_shares_add_up_to_the_uncut_layer(tiny):
     want = h[0] + ref.experts_part(un, lp, MODEL, layer=1)
     assert np.abs(np.asarray(got[0]) - np.asarray(want)).max() < 4e-5
     assert n.shape == (4,)
-
-
-# -- (c) the engine -----------------------------------------------------------
-
-@pytest.fixture
-def engine(tiny):
-    cfg, params = tiny
-    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=4,
-                 decode_chunk=4, page_size=16)
-    yield eng
-    eng.stop()
-
-
-def test_engine_prefill_then_decode_match_the_reference(tiny, engine):
-    """Three slots at once: a prompt that fills its bucket and ends on a
-    chunk's edge (256: one chunk of the dual form), one that ends inside the
-    second chunk with dead rows behind it (300 in 512) and one whose decode
-    crosses two page boundaries (21 -> 45, pages of 16). At every served
-    position the token the engine chose is the reference's largest logit to
-    float32 rounding, and the logits the prefill program itself returns are
-    the reference's, with the K and V of ONE layer, the state of three and
-    the share's routing counts over the TWO sparse layers."""
-    cfg, params = tiny
-    prompts = [_tokens(256, 5), _tokens(300, 6), _tokens(21, 7)]
-    served = _serve(engine, prompts, 24)
-    assert [len(s) for s in served] == [24, 24, 24]
-    for prompt, toks in zip(prompts, served):
-        gaps = ref.served_token_gaps(params, MODEL, prompt, toks)
-        assert max(gaps) < LOGIT_TOL, gaps
-    core = jax.jit(serving.prefill_core(cfg))
-    for prompt, width in zip(prompts, (256, 512, 32)):
-        padded = jnp.asarray([prompt + [9] * (width - len(prompt))], jnp.int32)
-        _, ks, _, logits, experts, (ssm_rows, conv_rows) = core(
-            fuse_qkv(params, cfg), padded, len(prompt))
-        want = np.asarray(ref.logits_last(params, MODEL, prompt, 1))[0]
-        assert np.abs(np.asarray(logits) - want).max() < LOGIT_TOL
-        assert ks.shape == (1, width, 2, 32)
-        assert ssm_rows.shape == (3, 16, 128) and conv_rows.shape == (3, 3, 192)
-        # 2 sparse layers x the prompt's rows x 3 experts a token
-        assert experts.shape == (6,) and int(experts[-1]) == 6 * len(prompt)
-        assert 0 < int(experts[:4].sum()) < int(experts[-1])
-    counts = engine.counters()
-    assert counts["state_writes"] == 3
-    assert counts["state_bytes"] == 3 * 4 * (16 * 128 * 4 + 3 * 192 * 4)
-    # (a request's 23 decoded tokens take six whole chunks of 4 steps)
-    routed = (256 + 300 + 21 + 3 * 24) * 3 * cfg.sparse_layers
-    assert counts["routed_assignments"] == routed
-    assert 0 < counts["local_assignments"] < routed
-    assert len(counts["expert_tokens"]) == 4
-    assert engine._caches.kc.shape[0] == 1 and engine._caches.ic is None
-    paths = attention.attention_path_counts()
-    assert paths["ssd_chunked"] >= 1 and paths["ssd_step_reference"] >= 1
-
-
-def test_an_engine_decodes_through_the_step_kernel(monkeypatch):
-    """An engine at 128 states (the kernel's lanes a group) built with
-    `slot_state.step_layer` interpreted updates its slots' state through the
-    grouped kernel's own code, in place in the decode program's carry: the
-    served tokens are the reference's to the engine's tolerance."""
-    import functools
-    model = dict(MODEL, ssm_state_size=128, mamba_head_dim=32,
-                 hybrid_override_pattern="ME*M", num_hidden_layers=4)
-    cfg = ADAPTER.build_config(model, F32, 128)
-    params = _params(cfg)
-    monkeypatch.setattr(slot_state, "step_layer", functools.partial(
-        slot_state.step_layer, interpret=True))
-    before = attention.attention_path_counts().get("ssd_step_pallas", 0)
-    eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=2,
-                 decode_chunk=2, page_size=16)
-    try:
-        assert attention.attention_path_counts()["ssd_step_pallas"] > before
-        prompt = _tokens(40, 41)
-        toks = _serve(eng, [prompt], 8)[0]
-    finally:
-        eng.stop()
-    assert max(ref.served_token_gaps(params, model, prompt, toks)) < LOGIT_TOL
-
-
-def test_bfloat16_is_outside_the_tolerance(tiny):
-    """The tolerance tells a lower precision from the stated one: the same
-    program with parameters and activations in bfloat16 is not within
-    LOGIT_TOL of the reference on the very weights it holds, and neither is
-    the reference with its state rounded to bfloat16 after every token."""
-    cfg, params = tiny
-    prompt = _tokens(70, 6)
-    padded = jnp.asarray([prompt + [9] * 58], jnp.int32)
-    exact = np.asarray(ref.logits_last(params, MODEL, prompt, 1))[0]
-    got = jax.jit(serving.prefill_core(cfg))(
-        fuse_qkv(params, cfg), padded, 70)[3]
-    assert np.abs(np.asarray(got) - exact).max() < LOGIT_TOL
-    cfg16 = ADAPTER.build_config(
-        MODEL, {"params": "bfloat16", "activations": "bfloat16"}, 512)
-    params16 = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
-    low = jax.jit(serving.prefill_core(cfg16))(
-        fuse_qkv(params16, cfg16), padded, 70)[3]
-    held = np.asarray(ref.logits_last(params16, MODEL, prompt, 1))[0]
-    assert np.abs(np.asarray(low) - held).max() > 100 * LOGIT_TOL
-    coarse = np.asarray(ref.logits_last(params, MODEL, prompt, 1,
-                                        state_dtype=jnp.bfloat16))[0]
-    assert np.abs(coarse - exact).max() > 10 * LOGIT_TOL
-
-
-def test_a_model_with_rope_is_another_model(tiny):
-    """The attention layer takes NO position signal: the reference given a
-    rotary theta is not within the tolerance of what the program computes."""
-    cfg, params = tiny
-    prompt = _tokens(40, 8)
-    turned = np.asarray(ref.logits_last(params, MODEL, prompt, 1,
-                                        rope_theta=10000.0))[0]
-    exact = np.asarray(ref.logits_last(params, MODEL, prompt, 1))[0]
-    assert np.abs(turned - exact).max() > 100 * LOGIT_TOL
 
 
 # -- (d) the pattern, the stacks and the refusals -----------------------------

@@ -1,0 +1,224 @@
+"""Nobody else's program moved: what every stack's serving programs (and a
+dense train step) lower to is letter for letter what the parent commit of the
+PR that pinned them lowered them to (sha256 of the lowered text). The pins
+of tests/test_dots.py (PR 39: one prefill rung that takes nobody and the
+decode program of each stack) and of tests/test_prefill_riders.py (PR 41:
+every rung of every stack, and who takes riders) in ONE file, read off ONE
+engine a stack: an engine compiles its programs when it is built, and three
+tests built each stack's engine anew to lower a rung each.
+"""
+
+import functools
+import hashlib
+from unittest import mock
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from benchmark import models
+from ray_tpu.models import llama, serving
+from ray_tpu.models.serving import Caches, prefill_core
+from ray_tpu.serve.engine import Engine
+from test_dots import PUBLISHED
+from test_mimo import PUBLISHED as MIMO
+from test_prefill_ladder import F32, KINDS
+from test_serve_llm import parents_sample_tokens
+
+# sha256 (first 16 hex digits) of the lowered text of the other models'
+# serving programs at their adapters' rehearsal widths and of a dense train
+# step at `LlamaConfig.tiny`, on the parent commit of PR 39 (00d21d1; jax
+# 0.9.0 on the CPU: no Mosaic payload, no source locations in the text).
+# Since PR 41 a dense and a sparse stack's rungs of the octave under `max_seq`
+# (64 and 128 here) carry the live slots (`engine.rung_rides`) and lower to
+# another text on purpose: their pin is the 32 rung, taken on PR 41's parent
+# (5481b82), as are the latent stack's own, which takes nobody.
+# `PARENT_RUNGS` below pins every rung of every stack. The same two
+# stacks' decode programs hand the arena to the jit they share with the riders
+# (`_token_step`: the same write and kernel, one trace a process) and were
+# taken anew on PR 41's tree (their parent's: 4ce2defd4ff49240 and
+# 278d751dc50fcfc4); the three stacks that take nobody keep the parent's.
+# `mixed` (PR 42's stack, which takes nobody) was taken on PR 45's parent
+# (6c2c097), before that PR moved a line under ray_tpu/.
+# Since PR 47 `serving.sample_tokens` takes its top-k behind a conditional, so
+# every serving program's text moves, by design, inside `sample` and nowhere
+# else: the pinned programs are lowered with the sampler of PR 47's parent
+# (7f64f96; `test_serve_llm.parents_sample_tokens`, which the sampler is held
+# to token for token there) in its place, and every digest stands unmoved.
+PARENT_PROGRAMS = {
+    "dense.decode": "d87712c9b4ee5285",
+    "dense.prefill32": "c948937b09fe2fee",
+    "hybrid.decode": "98e6e614b5625848",
+    "hybrid.prefill64": "b6847a6dfe909d84",
+    "indexed.decode": "7f5193fdda9e8db0",
+    "indexed.prefill64": "2b26fc68f7f5f898",
+    "latent.decode": "3cbcf9da23401fa5",
+    "latent.prefill64": "f96e02f0c080c3fb",
+    "mixed.decode": "c31b6808d133c647",
+    "mixed.prefill64": "ef4db6b4bc528b78",
+    "sparse.decode": "94dff0eb228ce990",
+    "sparse.prefill32": "7a5fc5aa7c158c94",
+    "train.tiny": "569d197c86234e93",
+}
+
+# sha256 (first 16 hex digits) of the lowered text of every prefill program
+# of the five stacks at their adapters' rehearsal widths, `max_seq` 128 and
+# two slots, on PR 41's parent (5481b82; jax 0.9.0 on the CPU: no Mosaic
+# payload, no source locations in the text). The rungs 64 and 128 of the dense
+# and the sparse stack ride since PR 41: `PARENT_RIDING` below;
+# `PARENT_PROGRAMS` above pins the decode programs. `mixed` (PR 42's stack) was
+# taken on PR 45's parent (6c2c097). All three tables stand since PR 47 with
+# that PR's parent's sampler in `serving.sample_tokens`' place while a
+# program is lowered (`parents_prefill_text` puts it there): the one
+# part of every serving program that PR moved.
+PARENT_RUNGS = {
+    "dense": {32: "c948937b09fe2fee"},
+    "sparse": {32: "7a5fc5aa7c158c94"},
+    "indexed": {32: "bfe2a2df64e53893", 64: "2b26fc68f7f5f898",
+                128: "69ca4b8800557a63"},
+    "hybrid": {32: "bf109118a1785278", 64: "b6847a6dfe909d84",
+               128: "4dd7ed9434604dd1"},
+    "latent": {32: "8da32aa0051287f3", 64: "f96e02f0c080c3fb",
+               128: "4b487bf21d58472e"},
+    "mixed": {32: "7ce5961c0ca08aab", 64: "ef4db6b4bc528b78",
+              128: "d8c5fc514a414023"},
+}
+# What the riding rungs lowered to there: another text now, on purpose.
+PARENT_RIDERLESS = {
+    "dense": {64: "d5061fe7c8b0f160", 128: "0f8a98c45565c6ea"},
+    "sparse": {64: "01d0cbc9e60958cc", 128: "6ea775ec4038bec1"},
+}
+# What the riding rungs lower to with the riders' shapes as `_place` passes
+# them (`Engine.lowered_prefill_text`): the programs `serve-batch` and
+# `serve-batch-olmoe` spend their prefill time in, on PR 45's parent (6c2c097).
+PARENT_RIDING = {
+    "dense": {64: "8de5c32ccafe6475", 128: "3a4649dd358ebc16"},
+    "sparse": {64: "c033be69300aac6b", 128: "c2b37a166224c151"},
+}
+STACKS = dict(KINDS, latent=("dots", PUBLISHED), mixed=("mimo", MIMO))
+
+
+def _sha(text):
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+# The pins were taken when the programs took the caches apart (`kc, vc` after
+# `params`; `ic`, `state` and `slot` after `key`; the one further cache a
+# model has LAST among the results), and lowered text names arguments by
+# position. So a pinned program is lowered from its own function (the jit's
+# `__wrapped__`) under the parent's name, argument order, result order and
+# `donate_argnums`, which puts the bundle together and takes it apart again:
+# what is compared is then the parent's text, or the program changed. The
+# one callee that changed on purpose since (PR 47's sampler) is lowered as the
+# parent had it: `_parents_sampler`.
+
+def _parents_sampler():
+    return mock.patch.object(serving, "sample_tokens", parents_sample_tokens)
+
+
+def parents_prefill_text(eng, width):
+    def prefill(params, kc, vc, pages, tokens, length, temp, topk, key,
+                ic=None, state=None, slot=None, last=None, pos=None,
+                riders=None):
+        caches, first, experts, *rode = eng._programs.prefill.__wrapped__(
+            params, Caches(kc, vc, ic, state), pages, tokens, length, temp,
+            topk, key, slot, last, pos, riders)
+        return (caches.kc, caches.vc, first, experts, *(
+            c for c in (caches.ic, caches.state) if c is not None), *rode)
+
+    params, caches, pages, tokens, length, temp, topk, key, slot, *riding = \
+        eng.prefill_shapes(width)
+    with _parents_sampler():
+        return jax.jit(prefill, donate_argnums=(1, 2, 9, 10, 12, 13)).lower(
+            params, caches.kc, caches.vc, pages, tokens, length, temp, topk,
+            key, caches.ic, caches.state, slot, *riding).as_text()
+
+
+def parents_decode_text(eng):
+    def decode(params, kc, vc, bt, last, pos, active, temp, topk, keys,
+               ic=None, state=None):
+        caches, last, pos, out, experts = eng._programs.decode.__wrapped__(
+            params, Caches(kc, vc, ic, state), bt, last, pos, active, temp,
+            topk, keys)
+        return (caches.kc, caches.vc, last, pos, out, experts, *(
+            c for c in (caches.ic, caches.state) if c is not None))
+
+    params, caches, *slots = eng.decode_shapes()
+    with _parents_sampler():
+        return jax.jit(decode, donate_argnums=(1, 2, 4, 5, 10, 11)).lower(
+            params, caches.kc, caches.vc, *slots, caches.ic,
+            caches.state).as_text()
+
+
+@functools.cache
+def _programs(kind):
+    """(takes riders, the riding rungs, {width: digest of the rung's lowered
+    text}, digest of the decode program's) of the stack's engine at its
+    adapter's rehearsal widths, built once: the tests below read their pins
+    off it and leave it as it was built."""
+    adapter = models.adapter(STACKS[kind][0])
+    cfg = adapter.build_config(dict(adapter.REHEARSE, **STACKS[kind][1]),
+                               F32, 128)
+    eng = Engine(adapter.init_params(cfg, 3), cfg, n_slots=2, decode_chunk=2,
+                 page_size=16)
+    try:
+        assert eng._programs.takes_riders is prefill_core(
+            cfg).takes_riders
+        riding = [w for w in eng.buckets if eng._rides(w)]
+        rungs = {w: _sha(parents_prefill_text(eng, w)) for w in eng.buckets}
+        decode = _sha(parents_decode_text(eng))
+    finally:
+        eng.stop()
+    return eng._programs.takes_riders, riding, rungs, decode
+
+
+def _train_step_digest():
+    cfg = llama.LlamaConfig.tiny()
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    step = jax.jit(jax.value_and_grad(
+        lambda p, t: llama.loss_fn(p, t, cfg)[0]))
+    return _sha(step.lower(
+        params, jax.ShapeDtypeStruct((2, 64), jnp.int32)).as_text())
+
+
+@pytest.mark.parametrize("kind", ["dense", "sparse", "indexed", "hybrid",
+                                  "latent", "mixed", "train"])
+def test_the_other_models_programs_are_the_parents(kind):
+    """What a dense, a sparse (softmax router, every expert), an indexed, a
+    hybrid, a latent and a mixed engine's prefill (a rung that takes no
+    riders) and decode, and a dense train step, lower to is letter for letter
+    what the parent commit lowers them to."""
+    if kind == "train":
+        got = {"train.tiny": _train_step_digest()}
+    else:
+        _, riding, rungs, decode = _programs(kind)
+        width = 32 if 64 in riding else 64
+        got = {f"{kind}.prefill{width}": rungs[width],
+               f"{kind}.decode": decode}
+    assert got == {k: PARENT_PROGRAMS[k] for k in got}
+
+
+@pytest.mark.parametrize("kind", sorted(PARENT_RUNGS))
+def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
+    """A dense and a sparse stack's programs of the octave under `max_seq`
+    take riders and hold a decode step's attention; their narrow rungs, and
+    every rung of an indexed, a hybrid, a latent and a mixed stack, take
+    nobody and lower to the parent's text, letter for letter. Asked of the
+    built program; no option, field or environment variable has a say."""
+    takes, riding, got, _ = _programs(kind)
+    assert takes is (kind in ("dense", "sparse"))
+    assert riding == ([64, 128] if takes else [])
+    assert {w: d for w, d in got.items()
+            if w not in riding} == PARENT_RUNGS[kind]
+    for w, was in PARENT_RIDERLESS.get(kind, {}).items():
+        assert w in riding and got[w] != was
+
+
+@pytest.mark.parametrize("kind", sorted(PARENT_RIDING))
+def test_the_riding_rungs_are_the_parents(kind):
+    """The riding rungs of a dense and a sparse stack, lowered with the
+    riders' shapes as `_place` passes them, are the parent's text too."""
+    _, riding, got, _ = _programs(kind)
+    assert {w: got[w] for w in riding} == PARENT_RIDING[kind]

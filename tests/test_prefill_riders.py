@@ -6,14 +6,13 @@ float32, a dense and a sparse stack: every stream is what the same engine
 serves with nobody riding, and the plain reference's greedy tokens; the
 counters and the admit spans agree; a burst of admissions moves the riders a
 step each; a rider that finishes on a riding step frees its slot and pages at
-once; an indexed, a hybrid and a latent stack take nobody, and every program
-that takes nobody lowers to the parent's text.
+once. (An indexed, a hybrid and a latent stack take nobody, and every program
+that takes nobody lowers to the parent's text: tests/test_parents_programs.py.)
 
 Tolerance: program and reference compute the same mathematics in float32 and
 differ in the order of their sums; LOGIT_TOL is tests/test_prefill_ladder.py's.
 """
 
-import hashlib
 import threading
 import time
 
@@ -23,13 +22,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from benchmark import models
-from ray_tpu.models.serving import prefill_core
 from ray_tpu.serve.engine import (_DEPTH, Engine, prefill_widths,
                                   rung_rides)
 from ray_tpu.utils import tracing
-from test_dots import MIMO, PUBLISHED, parents_prefill_text
-from test_prefill_ladder import F32, KINDS, LOGIT_TOL, _tiny, _tokens
+from test_prefill_ladder import LOGIT_TOL, _tiny, _tokens
 
 MAX_SEQ, SLOTS, CHUNK = 256, 4, 4
 # (prompt tokens, max_tokens, temperature): rungs 256 and 128 ride, 64 and 32
@@ -274,83 +270,3 @@ def test_the_riding_rungs_are_the_top_octaves(max_seq):
     riding = [w for w in ladder if rung_rides(max_seq, 16, w)]
     assert riding == [w for w in ladder if w >= max_seq // 2]
     assert len(riding) == (3 if max_seq == 2048 else 5)
-
-
-# sha256 (first 16 hex digits) of the lowered text of every prefill program
-# of the five stacks at their adapters' rehearsal widths, `max_seq` 128 and
-# two slots, on PR 41's parent (5481b82; jax 0.9.0 on the CPU: no Mosaic
-# payload, no source locations in the text). The rungs 64 and 128 of the dense
-# and the sparse stack ride since PR 41: `PARENT_RIDING` below;
-# tests/test_dots.py pins the decode programs. `mixed` (PR 42's stack) was
-# taken on PR 45's parent (6c2c097). All three tables stand since PR 47 with
-# that PR's parent's sampler in `serving.sample_tokens`' place while a
-# program is lowered (`test_dots.parents_prefill_text` puts it there): the one
-# part of every serving program that PR moved.
-PARENT = {
-    "dense": {32: "c948937b09fe2fee"},
-    "sparse": {32: "7a5fc5aa7c158c94"},
-    "indexed": {32: "bfe2a2df64e53893", 64: "2b26fc68f7f5f898",
-                128: "69ca4b8800557a63"},
-    "hybrid": {32: "bf109118a1785278", 64: "b6847a6dfe909d84",
-               128: "4dd7ed9434604dd1"},
-    "latent": {32: "8da32aa0051287f3", 64: "f96e02f0c080c3fb",
-               128: "4b487bf21d58472e"},
-    "mixed": {32: "7ce5961c0ca08aab", 64: "ef4db6b4bc528b78",
-              128: "d8c5fc514a414023"},
-}
-# What the riding rungs lowered to there: another text now, on purpose.
-PARENT_RIDERLESS = {
-    "dense": {64: "d5061fe7c8b0f160", 128: "0f8a98c45565c6ea"},
-    "sparse": {64: "01d0cbc9e60958cc", 128: "6ea775ec4038bec1"},
-}
-# What the riding rungs lower to with the riders' shapes as `_place` passes
-# them (`Engine.lowered_prefill_text`): the programs `serve-batch` and
-# `serve-batch-olmoe` spend their prefill time in, on PR 45's parent (6c2c097).
-PARENT_RIDING = {
-    "dense": {64: "8de5c32ccafe6475", 128: "3a4649dd358ebc16"},
-    "sparse": {64: "c033be69300aac6b", 128: "c2b37a166224c151"},
-}
-STACKS = dict(KINDS, latent=("dots", PUBLISHED), mixed=("mimo", MIMO))
-
-
-def _rung_digests(kind):
-    """(takes riders, the riding rungs, {width: digest of the lowered text})
-    of the stack's engine at its adapter's rehearsal widths."""
-    adapter = models.adapter(STACKS[kind][0])
-    cfg = adapter.build_config(dict(adapter.REHEARSE, **STACKS[kind][1]),
-                               F32, 128)
-    eng = Engine(adapter.init_params(cfg, 3), cfg, n_slots=2, decode_chunk=2,
-                 page_size=16)
-    try:
-        assert eng._programs.takes_riders is prefill_core(
-            cfg).takes_riders
-        riding = [w for w in eng.buckets if eng._rides(w)]
-        texts = {w: parents_prefill_text(eng, w) for w in eng.buckets}
-    finally:
-        eng.stop()
-    return eng._programs.takes_riders, riding, {
-        w: hashlib.sha256(t.encode()).hexdigest()[:16]
-        for w, t in texts.items()}
-
-
-@pytest.mark.parametrize("kind", sorted(PARENT))
-def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
-    """A dense and a sparse stack's programs of the octave under `max_seq`
-    take riders and hold a decode step's attention; their narrow rungs, and
-    every rung of an indexed, a hybrid, a latent and a mixed stack, take
-    nobody and lower to the parent's text, letter for letter. Asked of the
-    built program; no option, field or environment variable has a say."""
-    takes, riding, got = _rung_digests(kind)
-    assert takes is (kind in ("dense", "sparse"))
-    assert riding == ([64, 128] if takes else [])
-    assert {w: d for w, d in got.items() if w not in riding} == PARENT[kind]
-    for w, was in PARENT_RIDERLESS.get(kind, {}).items():
-        assert w in riding and got[w] != was
-
-
-@pytest.mark.parametrize("kind", sorted(PARENT_RIDING))
-def test_the_riding_rungs_are_the_parents(kind):
-    """The riding rungs of a dense and a sparse stack, lowered with the
-    riders' shapes as `_place` passes them, are the parent's text too."""
-    _, riding, got = _rung_digests(kind)
-    assert {w: got[w] for w in riding} == PARENT_RIDING[kind]
