@@ -659,11 +659,3 @@ def expert_stacks(layers: Dict[str, jax.Array], cfg
         if cfg.n_experts > 0 and "router" in layers else ()
     return ({k: v for k, v in layers.items() if k not in names},
             {k: layers[k].astype(cfg.dtype) for k in names})
-
-
-def expert_stats(counts: jax.Array) -> jax.Array:
-    """What a serving program hands back of one layer's routing, `[E + 1]`
-    int32 that add up over layers and steps: tokens per expert, then the
-    number of distinct experts touched."""
-    return jnp.concatenate(
-        [counts, jnp.sum(counts > 0, dtype=jnp.int32)[None]])

@@ -13,16 +13,16 @@ carry, the head, the sampler, the routing counts. What differs between kinds
 of layer is `_stack`'s table: a kind's prefill body, its decode body, and
 what the walk cannot guess of how to run them. A new architecture is a kind's
 two bodies, its cache's ops in `ops/` (empty, a prompt's write, a token's
-write, the read) and its fields in `LlamaConfig`.
+write, the read), its books (`_Counts`, beside the kind) and its fields in
+`LlamaConfig`. The weights' serving layout is `serving_params`'.
 
 A model that generates by BLOCKS (`LlamaConfig.block_length` B > 1) has a
 prefill and a decode program of its own under the same names (`block_prefill`,
-`block_decode`; the same walks). A block is `denoise_steps` T forwards and
-`Programs.block_forwards` is T: no forward is a commit's own. The K and V the
-cache keeps of block b are written by the FIRST forward of block b + 1, which
-carries b's final ids beside its own rows (2B rows a slot; the others B), so a
-chunk of `chunk / B` blocks runs `chunk / B x T` forwards, which is what the
-scheduler's dispatch span calls `forwards`.
+`block_decode`; the same walks). A block is `denoise_steps` T forwards: no
+forward is a commit's own. The K and V the cache keeps of block b are written
+by the FIRST forward of block b + 1, which carries b's final ids beside its
+own rows (2B rows a slot; the others B), so a chunk of `chunk / B` blocks
+runs `chunk / B x T` forwards, which its dispatch span calls `forwards`.
 
 The caches travel as one bundle (`Caches`) that the scheduler never opens. A
 decode program updates them IN PLACE, as a loop carry that nothing but
@@ -37,10 +37,14 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models import block
 from ray_tpu.ops import (attention, norms, paged_kv, slot_state,
                          sparse_attention)
+from ray_tpu.utils import get_logger
+
+logger = get_logger("models.serving")
 
 # Rows of a prefill that meet the sparse feed-forward at once: its sorted
 # copies are `rows x experts a token` wide (2.5 GiB of temporaries at 4,096
@@ -87,7 +91,7 @@ class Programs(NamedTuple):
     floor(length / B)` rows, yields NO token, and its `first` is the slot's
     state as it opens `[2B]`, nothing pending then the prompt's tail then
     -1s, which `poke` sets beside `pos`, the open block's first position;
-    `decode` is `chunk / B` blocks of `block_forwards` forwards each (the
+    `decode` is `chunk / B` blocks of `denoise_steps` forwards each (the
     denoising steps: the first, of 2B rows a slot, commits the block before;
     no forward is the commit's own), and `out [n_slots, chunk]` holds every
     position of those blocks, a slot's first chunk the prompt's tail too."""
@@ -102,15 +106,11 @@ class Programs(NamedTuple):
     adopts: bool
     # Whether a prefill writes per-slot state, so `slot` is passed.
     by_slot: bool
-    # Whether the programs hand back a SHARE's routing (`_share_stats`:
-    # `[held + 2]` counts) where a whole model's is `expert_stats`.
-    shares: bool
-    # caches -> the counters `Engine.counters()` shows of them.
-    cache_bytes: Callable[[Caches], Dict[str, int]]
+    # caches -> an engine's `Books`: what this model alone counts.
+    books: Callable[[Caches], "Books"]
     # Positions a slot's step yields: 1, a token a forward; B > 1, a block of
-    # B positions in `block_forwards` forwards (see above).
+    # B positions in `denoise_steps` forwards (see above).
     block: int = 1
-    block_forwards: int = 1
 
 
 class _Kind(NamedTuple):
@@ -154,6 +154,8 @@ class _Kind(NamedTuple):
     begin: Optional[Callable] = None
     # Prefill: `pack(kept) -> kept` of a segment's stacked rows.
     pack: Optional[Callable] = None
+    # What its layers make the model count (`_Counts`); None: nothing.
+    counts: Optional[_Counts] = None
 
 
 class _Stack(NamedTuple):
@@ -165,7 +167,8 @@ class _Stack(NamedTuple):
     tables: Callable
     # empty(n_slots, page, n_pages) -> Caches, zeroed.
     empty: Callable
-    # caches -> the counters `Engine.counters()` shows of them.
+    # caches -> the counters `Engine.counters()` shows of them (a mixed
+    # model's rings are `window` positions a slot a layer, whatever it serves).
     cache_bytes: Callable
     takes_riders: bool = False
     adopts: bool = False
@@ -220,6 +223,14 @@ def latent_rope_tables(mcfg, width):
     return norms.rope_frequencies(mcfg.qk_rope_dim, width, mcfg.rope_theta)
 
 
+def expert_stats(counts: jax.Array) -> jax.Array:
+    """What a serving program hands back of one layer's routing, `[E + 1]`
+    int32 that add up over layers and steps: tokens per expert, then the
+    number of distinct experts touched."""
+    return jnp.concatenate(
+        [counts, jnp.sum(counts > 0, dtype=jnp.int32)[None]])
+
+
 def _share_stats(counts, live, mcfg):
     """One sparse layer's routing as a program that holds a SHARE of the
     experts hands it back, `[held + 2]` int32 that add up: `expert_stats` of
@@ -227,16 +238,131 @@ def _share_stats(counts, live, mcfg):
     then the assignments the router made of the live rows, to whichever
     share."""
     routed = jnp.sum(live, dtype=jnp.int32) * mcfg.top_k_experts
-    return jnp.concatenate([block.expert_stats(counts), routed[None]])
+    return jnp.concatenate([expert_stats(counts), routed[None]])
 
 
 def _routing_stats(mcfg):
     """(counts, live) -> what a program hands back of one sparse layer's
     routing: `_share_stats` where the model holds a share of its experts,
-    `block.expert_stats` where it holds them all."""
+    `expert_stats` where it holds them all."""
     if mcfg.experts_held:
         return lambda counts, live: _share_stats(counts, live, mcfg)
-    return lambda counts, live: block.expert_stats(counts)
+    return lambda counts, live: expert_stats(counts)
+
+
+class _Counts(NamedTuple):
+    """What ONE cause (a kind of layer, `block_decode`) makes a model count
+    that not every model counts, beside its cause: `Books` adds them up."""
+    # Of the arguments `dispatch` returns, the counter each is added to.
+    advances: Dict[str, str] = {}
+    # dispatch(pos [n_slots], active [n_slots], chunk, plan) -> its arguments
+    # of a `serve.engine.decode_dispatch` span, from the slots' positions and
+    # the loop's plan (a live slot's `(slot, ..., opens)` each).
+    dispatch: Optional[Callable] = None
+    keeps: Dict[str, int] = {}      # its other counters, as they start
+
+
+def _reads(S, cap, **advances) -> _Counts:
+    """Counts the positions a chunk's decode steps read, a layer, over the
+    active slots (a slot at p attends to p + 1): under the first name `cap`
+    of them at most a slot a step, under a second one all of them."""
+    def dispatch(pos, active, chunk, plan):
+        reads = (pos[active][:, None] + 1 + np.arange(chunk)).clip(max=S)
+        return dict(zip(advances, (int(np.minimum(reads, cap).sum()),
+                                   int(reads.sum()))))
+    return _Counts(advances, dispatch)
+
+
+class Books:
+    """What ONE engine's model counts beyond what every model counts
+    (`Programs.books(caches)`), in numpy and plain Python. The scheduler asks
+    four things and knows no counter's name: `dispatch` and `placed` (its
+    loop), `routed` (its emitter), `counters` (anyone). A counter is an entry
+    of `totals` that `_add` alone advances; its paragraph stands with its
+    cause. The routing's: a sparse model's `expert_tokens` (assignments per
+    expert, all layers, prefills and decode steps, as far as the emitter has
+    fetched them) and `decode_experts_touched` (distinct experts, summed over
+    decode steps and SPARSE layers: over `decode_chunks * chunk *
+    mcfg.sparse_layers`, the experts a sparse layer reads in a step); one
+    that holds a SHARE of its experts adds `routed_assignments` (what its
+    routers assigned of live rows, to any share) and `local_assignments`
+    (those that fell to the experts held here, the sum of `expert_tokens`,
+    then per HELD expert): their ratio is this share's part of the work."""
+
+    def __init__(self, mcfg, counts: Tuple[_Counts, ...], share: bool,
+                 cache_bytes: Dict[str, int]):
+        self.share = share
+        self._counts = counts
+        self._sparse = mcfg.n_experts > 0
+        # The last chunk's (touched, local, routed): the next dispatch span's.
+        self._last = (0, 0, 0)
+        t = self.totals = {}
+        for c in counts:
+            t.update(dict.fromkeys(c.advances.values(), 0), **c.keeps)
+        if self._sparse:
+            t.update(expert_tokens=np.zeros(mcfg.n_held, np.int64),
+                     decode_experts_touched=0)
+            if share:
+                t.update(routed_assignments=0, local_assignments=0)
+        t.update(cache_bytes)               # under the counters' names
+
+    def _add(self, **by) -> None:
+        """Each increment onto the counter of its name, in the order given,
+        where this model keeps one (rebound, never changed in place)."""
+        for name, n in by.items():
+            if name in self.totals:
+                self.totals[name] = self.totals[name] + n
+
+    def dispatch(self, pos, active, chunk, plan, recording) -> Dict[str, Any]:
+        """Loop thread: the MODEL's arguments of a chunk's
+        `serve.engine.decode_dispatch` span, its totals advanced. Of the
+        routing, what the emitter has fetched (the chunk before's figures;
+        the tokens per expert `:`-joined, as the profiler splits arguments
+        at `,`), put together only where a span is `recording`."""
+        args: Dict[str, Any] = {}
+        if self._sparse and recording:
+            touched, local, routed = self._last
+            args.update(experts_touched=touched, expert_tokens=":".join(
+                map(str, self.totals["expert_tokens"])))
+            if self.share:
+                args.update(local_assignments=local,
+                            routed_assignments=routed)
+        for c in self._counts:
+            if c.dispatch is not None:
+                own = c.dispatch(pos, active, chunk, plan)
+                args.update(own)
+                self._add(**{c.advances[a]: n for a, n in own.items()
+                             if a in c.advances})
+        return args
+
+    def routed(self, experts, chunk: bool = False) -> Dict[str, int]:
+        """Emitter thread: one program's fetched `experts` (`expert_stats`,
+        a share's `_share_stats`) taken apart and onto the totals
+        (`routed_assignments` last: a reader that sees it moved sees all) ->
+        the arguments of a prefill's `serve.engine.prefill_experts` span. A
+        decode `chunk`'s figures are kept for the next dispatch span."""
+        stats = np.asarray(experts)
+        held = stats[:-1 - self.share]      # tokens per held expert
+        touched, local = int(stats[-1 - self.share]), int(held.sum())
+        # all the routers assigned: the same, where every expert is held
+        routed = int(stats[-1]) if self.share else local
+        self._add(expert_tokens=held, local_assignments=local,
+                  routed_assignments=routed)
+        if chunk:
+            self._last = (touched, local, routed)
+            self._add(decode_experts_touched=touched)
+        return dict(touched=touched, **(
+            dict(local=local, routed=routed) if self.share else {}))
+
+    def placed(self, tail: int, prefilled: bool) -> None:
+        """Loop thread, a request placed: `tail` prompt ids open its slot's
+        first block; a prefill (no hand-off) wrote its slot's state over."""
+        self._add(tail_tokens=tail, state_writes=int(prefilled))
+
+    def counters(self) -> Dict[str, Any]:
+        """The model's part of `Engine.counters()`."""
+        return {k: v.tolist() if isinstance(v, np.ndarray) else v
+                for k, v in self.totals.items()}
 
 
 def sample_tokens(logits, temp, topk, keys, pos, cap=TOPK_CAP):
@@ -485,8 +611,14 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
         return x, caches._replace(kc=kc, vc=vc), \
             stats(routed[1], live) if sparse else None
 
+    # The keys an indexer's decode steps select (a layer reads that many K
+    # and V rows) over the positions dense attention would read.
     return _Kind(prefill, decode_rows if B > 1 else decode,
-                 keeps=("pages", "pages") + ("index",) * indexed, **how)
+                 keeps=("pages", "pages") + ("index",) * indexed,
+                 counts=_reads(S, mcfg.index_topk,
+                               selected_keys="decode_selected_keys",
+                               live_keys="decode_live_keys")
+                 if indexed else None, **how)
 
 
 def _mamba_kind(mcfg) -> _Kind:
@@ -538,8 +670,10 @@ def _mamba_kind(mcfg) -> _Kind:
         return x, caches._replace(state=state), \
             stats(routed[1], ctx["act"]) if routed_layer else None
 
+    # `state_writes`: the admissions that overwrote a slot's state (a decode
+    # chunk moves the active slots' share of `state_bytes` once a step).
     return _Kind(prefill, decode, keeps=("state", "state"), over="index",
-                 carries=("state",))
+                 carries=("state",), counts=_Counts(keeps={"state_writes": 0}))
 
 
 def _experts_kind(mcfg) -> _Kind:
@@ -574,7 +708,7 @@ def _conv_kind(mcfg) -> _Kind:
         y, routed = block.feed_forward(lp, y[None], mcfg, ctx["live"],
                                        l if routed_layer else None)
         return y, caches, (None, window), \
-            block.expert_stats(routed[1]) if routed_layer else None
+            expert_stats(routed[1]) if routed_layer else None
 
     def decode(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
@@ -590,7 +724,7 @@ def _conv_kind(mcfg) -> _Kind:
         x, routed = block.feed_forward(lp, x, mcfg, ctx["act"],
                                        l if routed_layer else None)
         return x, caches._replace(state=state), \
-            block.expert_stats(routed[1]) if routed_layer else None
+            expert_stats(routed[1]) if routed_layer else None
 
     return _Kind(prefill, decode, keeps=("state", "state"), over="index",
                  carries=("state",))
@@ -734,9 +868,13 @@ def _mixed_kind(mcfg, kind: str) -> _Kind:
         return x, caches._replace(kc=kc, vc=vc, state=state), \
             _share_stats(routed[1], act, mcfg) if routed_layer else None
 
+    # The ring rows a window layer's decode steps read (a slot's own and the
+    # window - 1 before it, p + 1 while it has fewer), of `live_kv_tokens`.
     return _Kind(prefill, decode,
                  keeps=("ring", "ring") if window else ("pages", "pages"),
-                 begin=begin)
+                 begin=begin, counts=_reads(
+                     S, window, window_kv_tokens="window_kv_tokens")
+                 if window else None)
 
 
 def _stack(mcfg) -> _Stack:
@@ -794,7 +932,7 @@ def _stack(mcfg) -> _Stack:
             lambda n, rows: dict(tables=latent_rope_tables(mcfg, n)),
             lambda ns, page, n_pages: Caches(kc=paged_kv.empty_latent(
                 mcfg.n_layers, n_pages, page, mcfg.latent_width, dt)),
-            lambda c: {"latent_cache_bytes": int(c.kc.nbytes)},
+            lambda c: {"latent_cache_bytes": slot_state.state_bytes([c.kc])},
             shares=True, tally="first")
     if mcfg.mixed:
         return _Stack(
@@ -811,7 +949,7 @@ def _stack(mcfg) -> _Stack:
                 state=slot_state.empty_window(
                     unpaged, ns, mcfg.window_kv_heads, mcfg.window, hd,
                     mcfg.v_head_dim, dt)),
-            lambda c: {"full_cache_bytes": int(c.kc.nbytes + c.vc.nbytes),
+            lambda c: {"full_cache_bytes": slot_state.state_bytes(c[:2]),
                        "window_cache_bytes": slot_state.state_bytes(c.state)},
             shares=True, tally="zero")
     indexed = mcfg.index_topk > 0
@@ -905,7 +1043,7 @@ def _prefill_walk(mcfg, stack: _Stack):
     each cache keeps of the prompt, {cache of `_KEEP`: its arrays, a leading
     axis over the layers that write to it}; `logits` the last position's,
     float32; `experts` None for a dense model, else the routing counts of the
-    prompt's tokens summed over the layers (`block.expert_stats`, or a
+    prompt's tokens summed over the layers (`expert_stats`, or a
     share's `_share_stats`). The bucket's padding is computed like any row,
     each from itself alone (no capacity for it to take), and left out of the
     counts; rows past `length` reach no real row (attention and a
@@ -1315,7 +1453,27 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
             0, chunk // B, body, (caches, last, pos, out0, *counts0))
         return caches, last, pos, out, counts[0] if sparse else None
 
+    def blocks(pos, active, chunk, plan):
+        """What a chunk's blocks are: the `forwards` it runs
+        (`denoise_forwards`; the widest of `rows` rows, a block's first: the
+        pending block beside the open one), the live slots' blocks whose
+        pending block that forward commits (`commits_rode`: all but the one
+        that `opens` a slot), the positions covered in the live slots
+        (`block_tokens`), the prompts' tails (`tail_tokens`) among them: a
+        slot's forwards over `block_tokens - tail_tokens` is a token's cost."""
+        n = chunk // B
+        return dict(blocks=n, forwards=n * T, rows=2 * ns * B,
+                    committed=len(plan) * chunk, commits_rode=sum(
+                        min(n, int(S - pos[slot]) // B) - opens
+                        for slot, *_, opens in plan))
+
+    counts = tuple({id(k): k.counts for k in stack.kinds.values()
+                    if k.counts is not None}.values())
     if B > 1:
+        counts += (_Counts({"forwards": "denoise_forwards",
+                            "commits_rode": "commits_rode",
+                            "committed": "block_tokens"}, blocks,
+                           {"block": B, "tail_tokens": 0}),)
         if chunk % B:
             raise ValueError(
                 f"decode_chunk {chunk} is whole blocks of block_length {B}")
@@ -1345,5 +1503,55 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         takes_riders=stack.takes_riders, adopts=stack.adopts,
         by_slot=any(cache in ("state", "ring") for kind in
                     stack.kinds.values() for cache in kind.keeps),
-        shares=stack.shares, cache_bytes=stack.cache_bytes,
-        block=B, block_forwards=T)
+        books=lambda caches: Books(mcfg, counts, stack.shares,
+                                   stack.cache_bytes(caches)),
+        block=B)
+
+
+def _experts_in_compute_dtype(params, mcfg):
+    """A sparse model's expert stacks (whatever stacks hold a `router`) are
+    read whole by every layer of every program (`block.expert_stacks`), so
+    they are held in the compute dtype: stored otherwise they are cast here,
+    once, and the log says so (the caller may drop its own copy)."""
+    cast = {stack: {k: leaves[k].astype(mcfg.dtype)
+                    for k in ("w_gate", "w_up", "w_down")
+                    if k in leaves and leaves[k].dtype != mcfg.dtype}
+            for stack, leaves in params.items()
+            if isinstance(leaves, dict) and "router" in leaves}
+    if not any(cast.values()):
+        return params
+    logger.warning(
+        "expert weights are stored as %s and computed in %s: the engine "
+        "casts its own copy once", mcfg.param_dtype, mcfg.dtype)
+    return dict(params, **{stack: dict(params[stack], **leaves)
+                           for stack, leaves in cast.items() if leaves})
+
+
+def serving_params(params, mcfg):
+    """The tree the programs read, made once from the published one on the
+    device: the experts in the compute dtype, then one q/k/v stack
+    (`block.fuse_qkv`). The caller's projections are TAKEN OVER, as a donated
+    argument is: every leaf the fused tree no longer holds is deleted,
+    whoever holds it (`published_params` gives them back). A caller holds its
+    tree while a server warms up: the projections twice over are 0.6 GB at 12
+    Mistral layers, 0.2 on OLMoE, whose warm-up peaks within 0.9 GB of the
+    chip's memory (PERF.md, section 4)."""
+    cast = _experts_in_compute_dtype(params, mcfg)
+    fused = block.fuse_qkv(cast, mcfg)
+    held = {id(leaf) for leaf in jax.tree.leaves(fused)}
+    for leaf in jax.tree.leaves(cast):
+        if id(leaf) not in held:
+            leaf.delete()
+    return fused
+
+
+# (params, mcfg) -> `serving_params`' tree as the model is published (`wq`,
+# `wk`, `wv` a matrix each: a checkpoint's layout), split anew at every call.
+published_params = block.split_qkv
+
+
+def empty_handoff(mcfg, width: int):
+    """The (K, V) `adopt` takes of a hand-off of `width` rows, zeroed."""
+    kv = jnp.zeros((mcfg.n_layers, width, mcfg.n_kv_heads, mcfg.head_dim),
+                   mcfg.dtype)
+    return kv, kv

@@ -5,7 +5,7 @@ see SURVEY.md §5.7; it delegates to engines like vLLM). Here it is first-class:
 sequences are sharded over the ``sp`` mesh axis; each device holds a Q/K/V
 shard, K/V shards rotate around the ICI ring via ``lax.ppermute`` while an
 online-softmax accumulator folds in one block per step (Ring Attention,
-blockwise-parallel pattern from the public literature — see PAPERS.md).
+blockwise-parallel pattern from the public literature).
 
 Call **inside** shard_map with q, k, v already sharded on the sp axis:
 shapes [batch_local, heads_local, seq_local, head_dim].

@@ -12,8 +12,9 @@ engine is OURS):
   is ONE bundle (`Caches`) that `Engine` holds, hands to every program first
   after the parameters, gets back first, and never opens; what the scheduler
   has to know of a model it asks of `Programs` (`takes_riders`, `adopts`,
-  `by_slot`, `shares`, `cache_bytes`, `block`). No program is built here,
-  and no architecture is named.
+  `by_slot`, `block`); what a model alone counts its `Books` keep, and the
+  weights' layout is `serving_params`'. No program is built here, no
+  architecture is named, and of the configuration `max_seq` alone is read.
 - **Paged KV cache**, kept behind two names. The DEVICE side is
   `ops/paged_kv.py` (the arena's layout, the null page, the ops on it), which
   only the programs touch. The HOST side is `serve/page_pool.py::PagePool`:
@@ -139,7 +140,6 @@ def rung_rides(max_seq: int, n_slots: int, width: int) -> bool:
     return 2 * width >= max_seq and n_slots < width
 
 
-
 def _seed_key(seed: int):
     """Threefry key = [hi, lo] words of the seed — host-side PRNGKey
     construction (no device round-trip at admit)."""
@@ -199,36 +199,27 @@ class Engine:
         import jax.numpy as jnp
         import numpy as np
 
-        from ray_tpu.models.block import fuse_qkv
-        from ray_tpu.models.serving import build_programs
+        from ray_tpu.models import serving
 
         self._np = np
         self._jnp = jnp
         self.mcfg = mcfg
         self.n_slots = n_slots
         self.chunk = decode_chunk
-        # The weights as the programs read them: one q/k/v stack, the experts
-        # in the compute dtype, made once, here. The caller's three projection
-        # stacks are TAKEN OVER, as a donated argument is: deleted once fused,
-        # whoever holds them (`self.params` gives them back). A caller still
-        # holds its tree while this constructor warms up, and may still when
-        # the warm-up thread allocates its scratch arena: the projections
-        # twice over are 0.6 GB at 12 Mistral layers, 0.2 on OLMoE, whose
-        # warm-up peaks within 0.9 GB of the chip's memory (PERF.md, §4).
-        self._params = fuse_qkv(self._experts_in_compute_dtype(params, mcfg),
-                                mcfg)
-        for stack in ("layers", "dense", "window"):
-            for name in set(params.get(stack, ())) - set(
-                    self._params.get(stack, ())):
-                params[stack][name].delete()
+        # The weights as the programs read them, made before anything else
+        # is allocated; the caller's projections are TAKEN OVER (`params`).
+        self._serving = serving
+        self._params = serving.serving_params(params, mcfg)
         self.pool = PagePool(n_slots, mcfg.max_seq, page_size, n_pages)
         self.n_pages = self.pool.n_pages
         # The model's programs, and its caches: one bundle that every
         # program takes and hands back, and that nothing here opens
         # (`models/serving.py::Programs`, `Caches`).
-        self._programs = build_programs(mcfg, n_slots, decode_chunk,
-                                        self.pool.page, self.n_pages)
+        self._programs = serving.build_programs(
+            mcfg, n_slots, decode_chunk, self.pool.page, self.n_pages)
         self._caches = self._programs.empty()
+        # What this model alone counts, its caches' bytes among it.
+        self._books = self._programs.books(self._caches)
         # Positions a slot's step yields (`Programs.block`): 1, or a block.
         self._block = self._programs.block
         # Prefill shape buckets (`prefill_widths`): a 50-token prompt
@@ -275,59 +266,10 @@ class Engine:
         # prompt's padding rows), and the prefills that carried any.
         self.rider_tokens = 0
         self.rider_steps = 0
-        # A model of blocks: the forwards its decode chunks ran (a block is
-        # `Programs.block_forwards` of them), the blocks of live slots whose
-        # first forward also committed the block before (every block but a
-        # slot's first), the positions its live slots' blocks covered, and
-        # the prompt ids among those (a prompt's tail, which opens the slot's
-        # first block): the tokens the blocks made are `block_tokens -
-        # tail_tokens`.
-        self.denoise_forwards = 0
-        self.commits_rode = 0
-        self.block_tokens = 0
-        self.tail_tokens = 0
         # Positions the active slots held when each chunk was dispatched:
         # what decode attention had to read, a layer, at the chunk's first
         # step (against n_slots * max_seq, what a whole-table gather moves).
         self.live_kv_tokens = 0
-        # A sparse-attention model: the keys its decode steps selected, over
-        # the chunks' steps and the active slots (min(positions, index_topk)
-        # a slot a step; a layer reads that many K and V rows), against
-        # `decode_live_keys`, the positions those slots held (what dense
-        # attention would read). Host arithmetic on the positions.
-        self._index_topk = mcfg.index_topk
-        self.decode_selected_keys = 0
-        self.decode_live_keys = 0
-        # What the model's caches hold on the device, under the counters'
-        # names (`Programs.cache_bytes`: a model with state-space layers'
-        # `state_bytes`, a latent-attention model's `latent_cache_bytes`, the
-        # `full_cache_bytes` and `window_cache_bytes` of one with window
-        # layers), and the admissions that overwrote a slot's state.
-        self._cache_bytes = self._programs.cache_bytes(self._caches)
-        self.state_writes = 0
-        # A sparse model's routing, as the programs count it on the device
-        # (`models.block.expert_stats`) and the emitter thread adds it up:
-        # tokens per expert over prefills and decode steps, and the distinct
-        # experts touched, summed over a chunk's steps and the layers.
-        self._sparse = mcfg.n_experts > 0
-        self.expert_tokens = np.zeros(mcfg.n_held, np.int64)
-        self.decode_experts_touched = 0
-        self._touched_last_chunk = 0
-        # A model that holds a SHARE of its experts (`Programs.shares`): the
-        # assignments its routers made and those that fell to the experts
-        # held here, `expert_tokens` being per HELD expert. The last chunk's
-        # local assignments ride the next dispatch span as `experts_touched`
-        # does.
-        # A model with window layers: the ring rows its decode steps read,
-        # over the chunks' steps and the active slots (min(position + 1,
-        # window) a slot a step, a layer), against `live_kv_tokens`. Host
-        # arithmetic on the positions, as `decode_selected_keys` is.
-        self._window = mcfg.window
-        self.window_kv_tokens = 0
-        self.routed_assignments = 0
-        self.local_assignments = 0
-        self._local_last_chunk = 0
-        self._routed_last_chunk = 0
         self._next_rid = 0
         self._pending: deque = deque()
         # What the loop waits on (`_stand`), under one lock: the pending
@@ -414,7 +356,7 @@ class Engine:
         caches given (pages = zeros: never real KV state; a per-slot state's
         slot 0, before any request holds it, or a scratch one's). Returns
         (caches, first token on the device)."""
-        jnp, m = self._jnp, self.mcfg
+        jnp = self._jnp
         null_pages = jnp.zeros(self.pool.maxp, jnp.int32)
         # A riding rung's program with nobody riding, on slots' state of its
         # own: the live `_last_d` and `_pos_d` are the loop's to donate.
@@ -438,9 +380,9 @@ class Engine:
         # handoff must not compile in the loop).
         with tracing.compile_span("serve.engine.warm", program="adopt",
                                   width=width):
-            kv = jnp.zeros((m.n_layers, width, m.n_kv_heads, m.head_dim),
-                           m.dtype)
-            caches = self._programs.adopt(caches, null_pages, kv, kv)
+            caches = self._programs.adopt(
+                caches, null_pages,
+                *self._serving.empty_handoff(self.mcfg, width))
         return caches, first
 
     def _rides(self, width: int) -> bool:
@@ -526,32 +468,8 @@ class Engine:
         """The model's parameters as they are published (`wq`, `wk`, `wv` a
         matrix each: what a checkpoint or a plain reference reads), split
         from the engine's fused stack at every call: the engine holds no
-        second copy of the projections, and the caller holds this one only
-        as long as it keeps it."""
-        from ray_tpu.models.block import split_qkv
-        return split_qkv(self._params, self.mcfg)
-
-    @staticmethod
-    def _experts_in_compute_dtype(params, mcfg):
-        """A sparse model's expert stacks are read whole by every layer of
-        every program (`models.block.expert_stacks`), so they are held in the
-        compute dtype: stored otherwise, they are cast here, once, and the
-        log says so (the engine then holds that copy of the experts; the
-        caller may drop its own)."""
-        cast = {stack: [k for k in ("w_gate", "w_up", "w_down")
-                        if k in leaves and leaves[k].dtype != mcfg.dtype]
-                for stack, leaves in params.items()
-                if isinstance(leaves, dict) and "router" in leaves}
-        if not any(cast.values()):
-            return params
-        logger.warning(
-            "expert weights are stored as %s and computed in %s: the engine "
-            "casts its own copy once", mcfg.param_dtype, mcfg.dtype)
-        out = dict(params)
-        for stack, names in cast.items():
-            out[stack] = dict(params[stack], **{
-                k: params[stack][k].astype(mcfg.dtype) for k in names})
-        return out
+        second copy of the projections."""
+        return self._serving.published_params(self._params, self.mcfg)
 
     def submit(self, ids: List[int], max_tokens: int, *,
                temperature: float = 0.0, top_k: int = 0,
@@ -628,82 +546,28 @@ class Engine:
         `admitted` is how long the slot a request was given had stood
         empty since its last tenant finished (nothing for a slot's first
         tenant); over `n_slots` times the seconds elapsed it is the share
-        of slot-time left unfilled.
-        Occupancy is
-        `decode_useful_tokens` over
+        of slot-time left unfilled. Occupancy is `decode_useful_tokens` over
         `decode_chunks * n_slots * chunk`; `rider_tokens` are the tokens
         decoded outside the chunks, a live slot's one step inside another
         request's prefill (`rider_steps`: the prefills that carried any), so
         the tokens decoded are the two added; padding is
         `prefill_padded_tokens` over it plus `prefill_tokens`.
-        A model of blocks (`block` > 1) adds `denoise_forwards` (the
-        forwards its decode chunks ran, a block's denoising steps: the first
-        of `2 * n_slots * block` rows, the block before beside the open one,
-        the others of `n_slots * block`), `commits_rode` (the blocks of live
-        slots whose first forward committed the block before it: all but a
-        slot's first), `block_tokens` (the positions the live slots' blocks
-        covered) and `tail_tokens` (the prompt ids among those, a prompt's
-        tail in its slot's first block): forwards over `block_tokens -
-        tail_tokens`, a slot, is what a token cost.
         `decode_chunks_sampling` are the chunks dispatched with a live slot
         at a temperature above 0 (the span's `sampling` counts the slots):
         the others' steps took no top-k (`serving.sample_tokens`).
         `live_kv_tokens` over `decode_chunks * n_slots * max_seq` is the
-        share of the block tables that was live at dispatch. A sparse
-        model adds `expert_tokens` (assignments per expert, all layers,
-        prefills and decode steps, as far as the emitter has fetched them)
-        and `decode_experts_touched` (distinct experts, summed over decode
-        steps and SPARSE layers: over `decode_chunks * chunk *
-        mcfg.sparse_layers` it is the experts whose weights a sparse layer
-        reads in a step; a stack of one-part layers has fewer of those than
-        layers). A sparse-attention
-        model adds `decode_selected_keys` over `decode_live_keys`: the share
-        of the live positions its decode steps read K and V of. A model with
-        state-space layers adds `state_bytes`, the recurrent state it holds
-        on the device for all slots, and `state_writes`, the admissions that
-        overwrote a slot's (a decode chunk moves the active slots' share of
-        `state_bytes` once a step). A latent-attention model adds
-        `latent_cache_bytes`, its arena of latent rows, and, where it is
-        sparse, `routed_assignments` (what its routers assigned of live
-        rows, to any share) and `local_assignments` (those that fell to the
-        experts held here, the sum of `expert_tokens`, which is then per
-        HELD expert): their ratio is this share's part of the routed work. A
-        model of window and full attention layers adds `full_cache_bytes`
-        (the pages of its full layers, all `PagePool` hands out),
-        `window_cache_bytes` (its window layers' rings: `window` positions a
-        slot a layer whatever the prompts) and `window_kv_tokens`, the ring
-        rows a window layer's decode steps read (min(position + 1, window) a
-        live slot a step) against `live_kv_tokens`; and, holding a share of
-        its experts, the same two assignment counts."""
-        out = {k: getattr(self, k) for k in (
+        share of the block tables that was live at dispatch. What a model
+        alone counts its `Books` merge in (`models/serving.py`), where each
+        such counter's paragraph stands beside its cause."""
+        return {**{k: getattr(self, k) for k in (
             "admitted", "queue_wait_s_sum", "admit_chunks_ahead",
             "admit_decoding_slots", "admit_pending", "slot_idle_s_sum",
             "prefill_tokens",
             "prefill_padded_tokens", "decode_chunks",
             "decode_chunks_sampling", "decode_useful_tokens",
             "rider_tokens", "rider_steps",
-            "live_kv_tokens", "peak_pages_used", "n_slots", "chunk")}
-        if self._block > 1:
-            out.update(block=self._block,
-                       denoise_forwards=self.denoise_forwards,
-                       commits_rode=self.commits_rode,
-                       block_tokens=self.block_tokens,
-                       tail_tokens=self.tail_tokens)
-        if self._sparse:
-            out["expert_tokens"] = [int(n) for n in self.expert_tokens]
-            out["decode_experts_touched"] = self.decode_experts_touched
-        if self._index_topk:
-            out["decode_selected_keys"] = self.decode_selected_keys
-            out["decode_live_keys"] = self.decode_live_keys
-        out.update(self._cache_bytes)
-        if "state_bytes" in out:
-            out["state_writes"] = self.state_writes
-        if self._window:
-            out["window_kv_tokens"] = self.window_kv_tokens
-        if self._programs.shares and self._sparse:
-            out["routed_assignments"] = self.routed_assignments
-            out["local_assignments"] = self.local_assignments
-        return out
+            "live_kv_tokens", "peak_pages_used", "n_slots", "chunk")},
+            **self._books.counters()}
 
     def stop(self) -> None:
         with self._cv:
@@ -873,13 +737,12 @@ class Engine:
                 for s, _, fin in riders:
                     if fin:     # its slot and pages are free at once
                         self._finish_state(s)
-            self.state_writes += self._programs.by_slot
         req.slot = slot
         self._slot_req[slot] = req
         # A model of blocks kept the prompt's whole blocks: the rest, its
         # tail, opens the slot's first block, at that block's first position.
         req.tail = len(req.ids) % self._block
-        self.tail_tokens += req.tail
+        self._books.placed(req.tail, prefilled=req.first < 0)
         self._pos[slot] = len(req.ids) - req.tail
         self._active[slot] = True
         # Sampling state applies on BOTH branches (a PD handoff
@@ -971,12 +834,9 @@ class Engine:
                     if experts is not None:
                         # After the token is out. A span of no length: the
                         # profiler fixes a span's arguments when it opens.
-                        touched, local, routed = self._count_experts(experts)
-                        share = {"local": local, "routed": routed} \
-                            if self._programs.shares else {}
                         with tracing.span("serve.engine.prefill_experts",
                                           ctx=req.ctx, rid=req.rid,
-                                          touched=touched, **share):
+                                          **self._books.routed(experts)):
                             pass
                 else:  # ("chunk", out_d, plan, experts)
                     _, out_d, plan, experts = item
@@ -1004,9 +864,7 @@ class Engine:
                             if fin:
                                 req.out.put(None)
                     if experts is not None:
-                        (self._touched_last_chunk, self._local_last_chunk,
-                         self._routed_last_chunk) = self._count_experts(experts)
-                        self.decode_experts_touched += self._touched_last_chunk
+                        self._books.routed(experts, chunk=True)
             except BaseException:
                 import traceback
                 self.error = self.error or traceback.format_exc()
@@ -1023,24 +881,6 @@ class Engine:
         """A decode chunk's tokens on the host: the emitter's wait for the
         device (a test holds the emitter here)."""
         return self._np.asarray(out_d)
-
-    def _count_experts(self, experts) -> Tuple[int, int, int]:
-        """Emitter thread: add one program's `expert_stats` (a share's
-        `_share_stats`) to the running totals; returns its distinct experts
-        touched, its assignments to experts held here, and all the
-        assignments its routers made (the same, for a model that holds every
-        expert)."""
-        stats = self._np.asarray(experts)
-        routed = None
-        if self._programs.shares:
-            routed, stats = int(stats[-1]), stats[:-1]
-        local = int(stats[:-1].sum())
-        routed = local if routed is None else routed
-        # `routed_assignments` last: a reader that sees it moved sees all.
-        self.expert_tokens = self.expert_tokens + stats[:-1]
-        self.local_assignments += local
-        self.routed_assignments += routed
-        return int(stats[-1]), local, routed
 
     def _stand(self, ready) -> None:
         """The loop's one wait: admit what has arrived, then stand until
@@ -1114,53 +954,13 @@ class Engine:
             self.decode_chunks_sampling += int(sampling > 0)
             self.decode_useful_tokens += useful
             self.live_kv_tokens += live_kv
-            # What the emitter has fetched so far: the chunk before's distinct
-            # experts (over its steps and the layers) and the running tokens
-            # per expert, `:`-joined (the profiler splits arguments at `,`);
-            # put together only where a span is recorded.
-            routed = {"experts_touched": self._touched_last_chunk,
-                      "expert_tokens": ":".join(map(str, self.expert_tokens))
-                      } if self._sparse and tracing.recording() else {}
-            if routed and self._programs.shares:
-                routed.update(local_assignments=self._local_last_chunk,
-                              routed_assignments=self._routed_last_chunk)
-            if self._index_topk or self._window:
-                # What each step of the chunk reads, a layer: a slot at
-                # position p attends to p + 1 positions.
-                reads = (self._pos[self._active][:, None] + 1
-                         + np.arange(self.chunk)[None, :]).clip(max=S)
-            if self._index_topk:    # the indexer's index_topk of them at most
-                picked = int(np.minimum(reads, self._index_topk).sum())
-                self.decode_selected_keys += picked
-                self.decode_live_keys += int(reads.sum())
-                routed.update(selected_keys=picked, live_keys=int(reads.sum()))
-            if self._window:
-                # Of a window layer's ring: a slot's own row and the window - 1
-                # before it, p + 1 rows while it has fewer.
-                ring = int(np.minimum(reads, self._window).sum())
-                self.window_kv_tokens += ring
-                routed.update(window_kv_tokens=ring)
-            if self._block > 1:
-                # What the chunk's blocks are: the forwards it runs, the
-                # widest of `rows` rows (a block's first: the pending block
-                # beside the open one), the live slots' blocks whose pending
-                # block that forward commits (a slot's first has none),
-                # positions covered in the live slots, prompt tails included.
-                blocks = self.chunk // self._block
-                forwards = blocks * self._programs.block_forwards
-                committed = len(plan) * self.chunk
-                rode = sum(min(blocks, int(S - self._pos[slot]) // self._block)
-                           - opens for slot, *_, opens in plan)
-                self.denoise_forwards += forwards
-                self.commits_rode += rode
-                self.block_tokens += committed
-                routed.update(blocks=blocks, forwards=forwards,
-                              rows=2 * self.n_slots * self._block,
-                              committed=committed, commits_rode=rode)
+            # What the model counts of the chunk, its totals advanced.
+            model = self._books.dispatch(self._pos, self._active, self.chunk,
+                                         plan, tracing.recording())
             with tracing.span("serve.engine.decode_dispatch", useful=useful,
                               capacity=self.n_slots * self.chunk,
                               active=len(plan), sampling=sampling,
-                              live_kv_tokens=live_kv, **routed):
+                              live_kv_tokens=live_kv, **model):
                 (self._caches, self._last_d, self._pos_d, out_d,
                  experts_d) = self._programs.decode(
                     self._params, self._caches,

@@ -237,9 +237,8 @@ class PrefillServer:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models.block import fuse_qkv
         from ray_tpu.models.serving import (adopts, prefill_core,
-                                            sample_tokens)
+                                            sample_tokens, serving_params)
         from ray_tpu.serve.engine import doubling_widths
 
         cfg: LLMConfig = cloudpickle.loads(cfg_blob)
@@ -254,7 +253,7 @@ class PrefillServer:
                 "rings) nor a short-convolution layer's window (conv_layers)"
                 ": this model serves from one engine")
         # The layout the shared prefill core reads, as in the engine.
-        self.params = fuse_qkv(params)
+        self.params = serving_params(params, self.mcfg)
         self._core = jax.jit(prefill_core(self.mcfg))
 
         def _sample_first(row, temp, topk, key, pos):

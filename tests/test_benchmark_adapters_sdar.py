@@ -111,12 +111,15 @@ def test_the_engines_spans_and_scopes_carry_what_the_sdar_readers_read():
     cfg = adapter.build_config(dict(m, **adapter.REHEARSE), {
         "params": "float32", "activations": "float32"}, 128)
     built = build_programs(cfg, 2, 8, 16, 17)
-    assert (built.block, built.block_forwards) == (4, 2) \
+    assert built.block == 4 \
         and not built.adopts and not built.takes_riders \
         and not built.by_slot
     params = jax.eval_shape(lambda: fuse_qkv(
         init_params(cfg, jax.random.PRNGKey(0)), cfg))
     caches = jax.eval_shape(built.empty)
+    # a chunk of 8 positions: two blocks of two forwards
+    assert built.books(caches).dispatch(
+        [0, 0], [False, False], 8, [], False)["forwards"] == 4
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype)
@@ -135,10 +138,11 @@ def test_the_engines_spans_and_scopes_carry_what_the_sdar_readers_read():
         params, caches, arg((8,), jnp.int32), arg((1, 64), jnp.int32), 1,
         0.0, 0, arg((2,), jnp.uint32), None)
     assert lowered.as_text().startswith("module @jit_prefill ")
-    src = open(engine_mod.__file__).read()
+    from ray_tpu.models import serving
+    src = open(engine_mod.__file__).read() + open(serving.__file__).read()
     for name in ("blocks", "forwards", "rows", "committed", "commits_rode",
                  "denoise_forwards", "block_tokens", "tail_tokens"):
-        assert f"{name}=" in src, name
+        assert f'"{name}"' in src or f"{name}=" in src, name
     assert 'kind="opening"' in src
 
 

@@ -56,7 +56,7 @@ def test_read_webdataset_streams_samples(cluster, tmp_path):
 
 
 def test_webdataset_through_iter_jax_batches(cluster, tmp_path):
-    """The VERDICT acceptance: a webdataset tar streams through
+    """The round-1 review's acceptance: a webdataset tar streams through
     iter_jax_batches into device arrays."""
     _make_wds_shard(str(tmp_path / "s.tar"), 8)
     ds = rdata.read_webdataset(str(tmp_path / "s.tar")).map_batches(

@@ -361,22 +361,46 @@ def test_models_and_ops_import_nothing_from_serve(path):
 
 @pytest.mark.parametrize("name", [
     "named_scope", "lax", "hybrid", "latent", "mixed", "_hybrid", "_latent",
-    "_mixed", "_by_slot", "_third", "conv", "_conv", "conv_layers"])
+    "_mixed", "_by_slot", "_third", "conv", "_conv", "conv_layers",
+    # what a model counts, the layout of its routing counts and of its
+    # weights (PR 57: `models/serving.py::Books`, `serving_params`)
+    "index_topk", "_index_topk", "window", "_window", "n_experts", "n_held",
+    "_sparse", "shares", "block_forwards", "cache_bytes", "fuse_qkv",
+    "split_qkv", "param_dtype"])
 def test_the_scheduler_builds_no_program_and_names_no_architecture(name):
     assert name not in set(_named(_tree("serve", "engine.py")))
 
 
+def test_the_scheduler_reads_max_seq_alone_of_the_configuration():
+    """Every attribute `serve/engine.py` reads off the configuration, under
+    any name it gives it (`mcfg`, `self.mcfg`, an alias of either)."""
+    tree = _tree("serve", "engine.py")
+
+    def is_cfg(node):
+        return (isinstance(node, ast.Name) and node.id in held) or (
+            isinstance(node, ast.Attribute) and node.attr == "mcfg")
+
+    held = {"mcfg"}
+    for node in ast.walk(tree):         # `m = self.mcfg`, `a, m = b, mcfg`
+        if isinstance(node, ast.Assign):
+            for to, what in zip(*(
+                    n.elts if isinstance(n, ast.Tuple) else [n]
+                    for n in (node.targets[0], node.value))):
+                if isinstance(to, ast.Name) and is_cfg(what):
+                    held.add(to.id)
+    assert held == {"mcfg"}
+    assert {n.attr for n in ast.walk(tree)
+            if isinstance(n, ast.Attribute) and is_cfg(n.value)} == {"max_seq"}
+
+
 def test_the_scheduler_imports_the_parameter_layout_and_the_programs_alone():
-    """Nothing of `ray_tpu.ops`; of `models.block` the layout the engine
-    owns; of `models.serving` the builder. And it is importable without jax
-    (jax is imported inside functions)."""
+    """Nothing of `ray_tpu.ops` nor of `models.block`: of `models.serving`
+    the programs, the books and the layout of the weights. And it is
+    importable without jax (jax is imported inside functions)."""
     tree = _tree("serve", "engine.py")
     models = {m for m in _imported(tree)
               if m.startswith(("ray_tpu.models.", "ray_tpu.ops"))}
-    assert models == {"ray_tpu.models.block", "ray_tpu.models.serving",
-                      "ray_tpu.models.block.fuse_qkv",
-                      "ray_tpu.models.block.split_qkv",
-                      "ray_tpu.models.serving.build_programs"}
+    assert models == {"ray_tpu.models.serving"}
     top = {m for node in tree.body
            if isinstance(node, (ast.Import, ast.ImportFrom))
            for m in _imported(ast.Module([node], []))}

@@ -19,7 +19,7 @@ from ray_tpu.models import llama, serving
 from ray_tpu.models.block import fuse_qkv
 from ray_tpu.ops import attention, slot_state
 from ray_tpu.serve.engine import Engine
-from ray_tpu.utils import tracing
+from engine_pins import Spans as _Spans, pinned
 from test_granite import LOGIT_TOL, MODEL, _tokens, tiny
 
 
@@ -84,30 +84,6 @@ def test_engine_prefill_then_decode_match_the_reference(tiny, engine):
     assert 0 < counts["local_assignments"] < counts["routed_assignments"]
     assert len(counts["expert_tokens"]) == 4
     assert engine._caches.kc.shape[0] == 1 and engine._caches.ic is None
-
-
-class _Spans:
-    """`with _Spans() as spans:` records (name, arguments) of every span the
-    program opens meanwhile, beside what `tracing.span` does with it, and
-    says a recorder is there (the loop puts a chunk's routing together only
-    where a span is recorded)."""
-
-    def __enter__(self):
-        self.seen, self._span = [], tracing.span
-        self._recording = tracing.recording
-
-        def recording(name, **args):
-            self.seen.append((name, args))
-            return self._span(name, **args)
-
-        tracing.span, tracing.recording = recording, lambda: True
-        return self
-
-    def __exit__(self, *exc):
-        tracing.span, tracing.recording = self._span, self._recording
-
-    def named(self, name):
-        return [args for n, args in self.seen if n == name]
 
 
 def test_the_hybrid_engine_took_its_paths_and_its_spans_carry_the_share(
@@ -226,3 +202,8 @@ def test_a_pd_handoff_and_the_training_forward_are_refused_by_name(tiny,
     with pytest.raises(NotImplementedError, match="embed_scale"):
         llama.forward(llama.init_params(dense, jax.random.PRNGKey(0)),
                       jnp.zeros((1, 8), jnp.int32), dense)
+
+
+def test_what_the_engine_counts_is_what_the_parent_counted(engine):
+    """The keys of `Engine.counters()`: tests/engine_pins.py's row."""
+    assert pinned(engine, "granite")

@@ -17,6 +17,7 @@ from ray_tpu.models.block import fuse_qkv
 from ray_tpu.ops import sparse_attention
 from ray_tpu.models.serving import prefill_core
 from ray_tpu.serve.engine import Engine
+from engine_pins import Spans
 from test_keye import LOGIT_TOL, MODEL, _ref_logits, _tokens, tiny
 
 
@@ -69,6 +70,20 @@ def test_engine_prefill_then_paged_decode_match_the_reference(tiny, engine):
     # 21 -> 44 reads 22..32 keys a step and then 32; the others always 32.
     assert 0 < counts["decode_selected_keys"] < counts["decode_live_keys"]
     assert engine._caches.ic.shape == (2, engine.n_pages, 16, 16)
+
+
+def test_a_dispatch_span_carries_the_selected_and_the_live_keys(engine):
+    """A prompt of 30: the prefill's token stands at position 30, so the
+    first chunk's four steps read 31, 32, 33 and 34 positions a layer
+    (`live_keys`), of which the indexer selects 32 at most
+    (`selected_keys`); the next chunk's all read more than it selects."""
+    with Spans() as spans:
+        assert len(_serve(engine, [_tokens(30, 8)], 8)[0]) == 8
+    first, second = [
+        (a["selected_keys"], a["live_keys"])
+        for a in spans.named("serve.engine.decode_dispatch")][:2]
+    assert first == (31 + 3 * 32, 31 + 32 + 33 + 34)
+    assert second == (4 * 32, 35 + 36 + 37 + 38)
 
 
 def test_a_wide_bucket_meets_the_experts_in_row_blocks_and_nothing_changes(

@@ -21,7 +21,7 @@ from benchmark import models, reference_lfm2
 from ray_tpu.models import llama, serving
 from ray_tpu.models.block import fuse_qkv
 from ray_tpu.ops import attention
-from ray_tpu.serve.engine import Engine
+from engine_pins import pinned
 from test_lfm2 import LOGIT_TOL, ROOT, _drain, _engine, _tokens, tiny
 
 
@@ -174,7 +174,7 @@ def test_the_engine_took_the_paths_and_keeps_two_shapes_of_cache(tiny,
 
 
 def test_the_cast_of_the_experts_asks_the_stacks_and_never_an_array(tiny):
-    """`Engine._experts_in_compute_dtype` walks whatever stacks hold a
+    """`serving._experts_in_compute_dtype` walks whatever stacks hold a
     `router` (it names none), and asks a top-level ARRAY nothing: `"router"
     in array` is an element-wise compare of the whole embedding, seconds of
     every sparse model's start on the chip (my chip runs, PR 46)."""
@@ -187,10 +187,10 @@ def test_the_cast_of_the_experts_asks_the_stacks_and_never_an_array(tiny):
             raise AssertionError("a leaf was searched for a stack's key")
 
     tree = dict(params, embed=Leaf(), final_norm=Leaf())
-    assert Engine._experts_in_compute_dtype(tree, cfg) is tree
+    assert serving._experts_in_compute_dtype(tree, cfg) is tree
     import dataclasses
     half = dataclasses.replace(cfg, dtype=jnp.bfloat16)
-    cast = Engine._experts_in_compute_dtype(tree, half)
+    cast = serving._experts_in_compute_dtype(tree, half)
     for stack in ("conv", "layers"):
         assert cast[stack]["w_up"].dtype == jnp.bfloat16
         assert cast[stack]["router"].dtype == jnp.float32
@@ -212,3 +212,8 @@ def test_a_pd_handoff_and_the_training_forward_refuse_the_stack_by_name(
     with pytest.raises(NotImplementedError, match="short-convolution"):
         models.adapter("lfm2").loss_fn(params, jnp.zeros((1, 8), jnp.int32),
                                        cfg, None)
+
+
+def test_what_the_engine_counts_is_what_the_parent_counted(engine):
+    """The keys of `Engine.counters()`: tests/engine_pins.py's row."""
+    assert pinned(engine, "lfm2")

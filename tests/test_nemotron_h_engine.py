@@ -17,6 +17,7 @@ from ray_tpu.models import serving
 from ray_tpu.models.block import fuse_qkv
 from ray_tpu.ops import attention, slot_state
 from ray_tpu.serve.engine import Engine
+from engine_pins import pinned
 from test_nemotron_h import ADAPTER, F32, LOGIT_TOL, MODEL, _params, tiny
 
 
@@ -38,8 +39,10 @@ def _serve(engine, prompts, n):
 
 # -- (c) the engine -----------------------------------------------------------
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def engine(tiny):
+    """ONE engine for the tests that serve through it and leave its slots
+    free behind them, or read what is static of it."""
     cfg, params = tiny
     eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=4,
                  decode_chunk=4, page_size=16)
@@ -146,3 +149,8 @@ def test_a_model_with_rope_is_another_model(tiny):
                                         rope_theta=10000.0))[0]
     exact = np.asarray(ref.logits_last(params, MODEL, prompt, 1))[0]
     assert np.abs(turned - exact).max() > 100 * LOGIT_TOL
+
+
+def test_what_the_engine_counts_is_what_the_parent_counted(engine):
+    """The keys of `Engine.counters()`: tests/engine_pins.py's row."""
+    assert pinned(engine, "nemotron_h")

@@ -231,14 +231,15 @@ def test_experts_stored_in_another_dtype_are_cast_once_and_loudly(
     says so, and serves what one handed the cast copy serves."""
     import dataclasses
 
-    from ray_tpu.serve import engine as engine_mod
+    from ray_tpu.models import serving
     cfg, params = tiny
     half = dataclasses.replace(cfg, dtype=jnp.bfloat16)
     said = []
-    monkeypatch.setattr(engine_mod.logger, "warning",
+    monkeypatch.setattr(serving.logger, "warning",
                         lambda msg, *a: said.append(msg % a))
-    assert Engine._experts_in_compute_dtype(params, cfg) is params and not said
-    held = Engine._experts_in_compute_dtype(params, half)
+    assert serving._experts_in_compute_dtype(params, cfg) is params \
+        and not said
+    held = serving._experts_in_compute_dtype(params, half)
     assert len(said) == 1 and "casts its own copy once" in said[0]
     assert {k: str(v.dtype) for k, v in held["layers"].items()
             if v.dtype != jnp.float32} == {

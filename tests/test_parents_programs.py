@@ -21,6 +21,7 @@ from benchmark import models
 from ray_tpu.models import llama, serving
 from ray_tpu.models.serving import Caches, prefill_core
 from ray_tpu.serve.engine import Engine
+from engine_pins import GENERIC, MODEL
 from test_dots import PUBLISHED
 from test_mimo import PUBLISHED as MIMO
 from test_prefill_ladder import F32, KINDS
@@ -154,9 +155,9 @@ def parents_decode_text(eng):
 @functools.cache
 def _programs(kind):
     """(takes riders, the riding rungs, {width: digest of the rung's lowered
-    text}, digest of the decode program's) of the stack's engine at its
-    adapter's rehearsal widths, built once: the tests below read their pins
-    off it and leave it as it was built."""
+    text}, digest of the decode program's, the keys of its counters) of the
+    stack's engine at its adapter's rehearsal widths, built once: the tests
+    below read their pins off it and leave it as it was built."""
     adapter = models.adapter(STACKS[kind][0])
     cfg = adapter.build_config(dict(adapter.REHEARSE, **STACKS[kind][1]),
                                F32, 128)
@@ -168,9 +169,10 @@ def _programs(kind):
         riding = [w for w in eng.buckets if eng._rides(w)]
         rungs = {w: _sha(parents_prefill_text(eng, w)) for w in eng.buckets}
         decode = _sha(parents_decode_text(eng))
+        keys = set(eng.counters())
     finally:
         eng.stop()
-    return eng._programs.takes_riders, riding, rungs, decode
+    return eng._programs.takes_riders, riding, rungs, decode, keys
 
 
 def _train_step_digest():
@@ -193,7 +195,7 @@ def test_the_other_models_programs_are_the_parents(kind):
     if kind == "train":
         got = {"train.tiny": _train_step_digest()}
     else:
-        _, riding, rungs, decode = _programs(kind)
+        _, riding, rungs, decode, _ = _programs(kind)
         width = 32 if 64 in riding else 64
         got = {f"{kind}.prefill{width}": rungs[width],
                f"{kind}.decode": decode}
@@ -207,7 +209,7 @@ def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
     every rung of an indexed, a hybrid, a latent and a mixed stack, take
     nobody and lower to the parent's text, letter for letter. Asked of the
     built program; no option, field or environment variable has a say."""
-    takes, riding, got, _ = _programs(kind)
+    takes, riding, got, _, _ = _programs(kind)
     assert takes is (kind in ("dense", "sparse"))
     assert riding == ([64, 128] if takes else [])
     assert {w: d for w, d in got.items()
@@ -220,5 +222,15 @@ def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
 def test_the_riding_rungs_are_the_parents(kind):
     """The riding rungs of a dense and a sparse stack, lowered with the
     riders' shapes as `_place` passes them, are the parent's text too."""
-    _, riding, got, _ = _programs(kind)
+    _, riding, got, _, _ = _programs(kind)
     assert {w: got[w] for w in riding} == PARENT_RIDING[kind]
+
+
+@pytest.mark.parametrize("kind", ["dense", "sparse", "indexed", "hybrid",
+                                  "latent", "mixed"])
+def test_what_each_stack_counts_is_what_the_parent_counted(kind):
+    """The keys of `Engine.counters()`, read in the same build as the digests:
+    the scheduler's own and the stack's row of tests/engine_pins.py."""
+    *_, keys = _programs(kind)
+    assert GENERIC <= keys and keys - GENERIC == MODEL[kind], \
+        sorted(keys - GENERIC)

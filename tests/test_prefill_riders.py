@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from ray_tpu.serve.engine import (_DEPTH, Engine, prefill_widths,
                                   rung_rides)
-from ray_tpu.utils import tracing
+from engine_pins import Spans as _Spans
 from test_prefill_ladder import LOGIT_TOL, _tiny, _tokens
 
 MAX_SEQ, SLOTS, CHUNK = 256, 4, 4
@@ -64,27 +64,6 @@ def _serve(eng, asks=ASKS):
                           seed=seed)
                for seed, (n, m, t) in enumerate(asks)]
     return [_drain(q) for q in streams]
-
-
-class _Spans:
-    """`with _Spans() as spans:` records (name, arguments) of every span the
-    program opens meanwhile, beside what `tracing.span` does with it."""
-
-    def __enter__(self):
-        self.seen, self._span = [], tracing.span
-
-        def recording(name, **args):
-            self.seen.append((name, args))
-            return self._span(name, **args)
-
-        tracing.span = recording
-        return self
-
-    def __exit__(self, *exc):
-        tracing.span = self._span
-
-    def named(self, name):
-        return [args for n, args in self.seen if n == name]
 
 
 @pytest.fixture(scope="module", params=["dense", "sparse"])

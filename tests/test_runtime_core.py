@@ -262,7 +262,7 @@ def test_concurrent_task_burst(cluster):
 
 def test_actor_method_num_returns(cluster):
     """Multiple returns from actor methods via .options(num_returns=N)
-    (reference parity: VERDICT flagged this as unsupported in round 1)."""
+    (reference parity: the review flagged this as unsupported in round 1)."""
     @ray_tpu.remote
     class Splitter:
         def pair(self, x):

@@ -17,6 +17,7 @@ from benchmark import control
 from benchmark import reference_sdar as ref
 from ray_tpu.ops import attention
 from ray_tpu.serve.engine import Engine
+from engine_pins import Spans, pinned
 from test_sdar import ADAPTER, BF16, F32, GAP_TOL, _model, _params
 
 
@@ -180,7 +181,7 @@ def test_the_engines_counters_and_paths_say_blocks(engines):
     paths = attention.attention_path_counts()
     assert paths["block_fwd_reference"] and paths["block_decode_reference"]
     programs = eng._programs
-    assert (programs.block, programs.block_forwards) == (4, 2)
+    assert programs.block == 4      # of two forwards each: counted above
     assert not programs.takes_riders and not programs.adopts
     with pytest.raises(NotImplementedError, match="block_length > 1"):
         eng.submit_prefilled(None, None, 8, 0, 4)
@@ -193,6 +194,21 @@ def test_the_engines_counters_and_paths_say_blocks(engines):
 # ---------------------------------------------------------------------------
 # (d) bfloat16, tenants, temperature
 # ---------------------------------------------------------------------------
+
+def test_a_dispatch_span_says_what_the_chunks_blocks_are(engines):
+    """A prompt of 10 (a tail of 2) served 9 tokens is two chunks of 8
+    positions: each two blocks of two forwards, the widest of 2 x 4 slots x 4
+    rows, 8 positions covered in the one live slot; the first chunk's first
+    block opens the slot and commits nothing, every other block commits the
+    one before it."""
+    eng = engines(4, 2)
+    with Spans() as spans:
+        assert len(_serve(eng, _prompt(10), 9)) == 9
+    chunks = spans.named("serve.engine.decode_dispatch")
+    assert [[a[k] for k in ("blocks", "forwards", "rows", "committed",
+                            "commits_rode")] for a in chunks] == [
+        [2, 4, 32, 8, 1], [2, 4, 32, 8, 2]]
+
 
 def test_a_request_is_served_alike_alone_after_another_and_beside_idle_slots(
         engines):
@@ -285,3 +301,8 @@ def test_served_token_gaps_finds_the_steps_and_a_wrong_commit(engines):
         wrong[worst - 1] = (toks[worst - 1] + 1) % 255
     bad = ref.served_token_gaps(params, model, ids, wrong)
     assert max(bad) > 0.05 and sum(bad) > 100 * GAP_TOL
+
+
+def test_what_the_engine_counts_is_what_the_parent_counted(engines):
+    """The keys of `Engine.counters()`: tests/engine_pins.py's row."""
+    assert pinned(engines(4, 2), "sdar")

@@ -211,6 +211,36 @@ def test_engine_params_are_the_published_tree_and_no_second_copy(kind):
         eng.stop()
 
 
+def test_a_stack_with_a_name_of_its_own_is_left_no_projection(monkeypatch):
+    """`serving.serving_params` finds what to take over as the leaves the
+    fused tree no longer holds, not in a list of stack names: a tree whose
+    attention stack is called something of its own (and a layout that fuses
+    it) is left holding no `wq`, `wk`, `wv`, and every leaf the programs
+    still read stays, the caller's own array."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import block, serving
+
+    cfg, params = _qkv_model("dense")
+    tree = {"towers" if k == "layers" else k: v for k, v in params.items()}
+
+    def fuse(params, cfg):
+        towers = dict(params["towers"])
+        towers["wqkv"] = jnp.concatenate(
+            [towers.pop(k) for k in ("wq", "wk", "wv")], axis=-1)
+        return dict(params, towers=towers)
+
+    monkeypatch.setattr(block, "fuse_qkv", fuse)
+    fused = serving.serving_params(tree, cfg)
+    assert all(tree["towers"][k].is_deleted() for k in ("wq", "wk", "wv"))
+    assert {k for k, w in tree["towers"].items() if not w.is_deleted()} \
+        == set(fused["towers"]) - {"wqkv"}
+    assert all(fused["towers"][k] is tree["towers"][k]
+               for k in fused["towers"] if k != "wqkv")
+    assert not any(w.is_deleted() for w in jax.tree.leaves(fused))
+
+
 def test_train_step_keeps_a_projection_matrix_each():
     """Training's parameters, gradients and optimizer state stay by matrix
     (`tp` shards `wq` over heads and `wk`, `wv` over kv heads; checkpoints

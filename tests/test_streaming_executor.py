@@ -50,7 +50,7 @@ def test_plan_lowering_shapes(cluster):
 
 
 def test_shuffle_actor_map_streaming_split(cluster):
-    """The VERDICT-r3 composite: shuffle -> actor-pool map ->
+    """The round-3 review's composite: shuffle -> actor-pool map ->
     streaming_split runs end-to-end through the operator graph."""
 
     class AddOffset:

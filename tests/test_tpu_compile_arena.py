@@ -180,11 +180,12 @@ def test_a_riding_prefill_updates_the_arena_in_place_and_fits_on_v5e(
     out or re-laid, and the step's page rows and 17 rows of logits stay
     within 5% + 16 MiB of the riderless program's temporaries (OLMoE serves
     within 0.9 GB of the chip's memory: PERF.md section 4)."""
-    from ray_tpu.serve.engine import Engine, rung_rides
+    from ray_tpu.models import serving
+    from ray_tpu.serve.engine import rung_rides
 
     cell = described_cell(
         topo, monkeypatch, config,
-        init=lambda adapter, cfg: Engine._experts_in_compute_dtype(
+        init=lambda adapter, cfg: serving._experts_in_compute_dtype(
             adapter.init_params(cfg, 0), cfg))
     ns, maxp, sds = cell.ns, cell.maxp, cell.sds
     assert rung_rides(cell.eng["max_seq"], ns, width)

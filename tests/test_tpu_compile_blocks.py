@@ -84,8 +84,9 @@ def test_sdar_block_programs_keep_the_pages_in_place_on_v5e(
                                          cell.params, cell.caches)
     page, B = cell.page, model["block_length"]
     layers = model["num_hidden_layers"]
-    assert (built.block, built.block_forwards) == (B, 2) \
-        and not built.takes_riders and not built.adopts
+    assert built.block == B and not built.takes_riders and not built.adopts
+    assert built.books(caches).dispatch(     # a block is two forwards
+        [0], [False], B, [], False)["forwards"] == 2
     kc, vc = caches.kc, caches.vc
     assert kc.shape == vc.shape == (layers, eng["kv_pages"], 4, page, 128)
     before = dict(attention.attention_path_counts())
