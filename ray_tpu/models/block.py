@@ -317,9 +317,35 @@ def scaled(branch: jax.Array, cfg) -> jax.Array:
     return branch * jnp.asarray(cfg.residual_scale, branch.dtype)
 
 
+def _slot_conv(lp, rows, window):
+    """A decode step's convolution: each slot a sequence of ONE row (`rows`
+    `[ns, C]`), its window (`[K - 1, ns, C]`) its own -> (the rows, float32;
+    the windows moved on a row)."""
+    rows, window = jax.vmap(causal_conv, (0, None, None, 1), (0, 1))(
+        rows[:, None], lp["conv_w"], lp["conv_b"], window)
+    return rows[:, 0], window
+
+
+def _ride(rows, rode, active):
+    """`rows` `[S, ...]` of a prompt's bucket with the riding slots' `rode`
+    `[ns, ...]` in its LAST ns rows: slot i's in row S - ns + i where `active`
+    marks it; any other row stays the prompt's (or its padding). An update
+    of ns rows IN PLACE, so `rows` is what a kernel or a matmul reads next
+    and is written out anyway (the convolution's output after its
+    activation, in the compute dtype; a scan's output): rows that a fusion
+    would have kept to itself are first written out whole (the
+    convolution's float32 sums: 84 MB a layer of a 4,096-row bucket at
+    Jamba's widths; compiled for a v5e, PR 58), and a select over all the
+    rows costs every layer a pass (my chip runs, PR 58: §6)."""
+    ns = active.shape[0]
+    keep = active.reshape((ns,) + (1,) * (rode.ndim - 1))
+    return rows.at[-ns:].set(
+        jnp.where(keep, rode.astype(rows.dtype), rows[-ns:]))
+
+
 def mamba_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
-                window=None, *, step: bool = False, length=None
-                ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                window=None, *, step: bool = False, length=None, riders=None,
+                layer=None, active=None) -> Tuple[jax.Array, ...]:
     """x + mixer(norm(x)) for a state-space (Mamba-1) layer, Jamba's: the
     time step, B and C each RMS-normalised after their projection.
 
@@ -337,21 +363,40 @@ def mamba_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
     also applies the gate; rows at and past `length` leave state and window
     as they were. Or, with `step`, x `[ns, D]`, one token a slot, from
     `state` `[ns, N, Di]` and `window` `[K - 1, ns, Di]` (`ops/slot_state.py`
-    has both layouts). -> (out, state, window)."""
+    has both layouts). -> (out, state, window).
+
+    RIDERS: a sequence whose LAST ns rows (past `length`) are one token a
+    slot: `riders` the slots' whole state (`ops/slot_state.py`'s pair), of
+    which layer `layer`'s rows of the slots `active` `[ns]` marks are read
+    and written. Every row-wise stage runs once over all the rows;
+    between them a riding slot's row takes the step's path (`_slot_conv`
+    against its own window, `ssm_step` from its own state, the gate) and the
+    sequence's rows the sequence's, which are told `length`, so neither
+    reaches the other: the sequence's rows, state and window are what they
+    are without riders, a riding slot's row and its state what a step gives
+    it, and an idle slot's stay. -> (out, state, window, the slots' state)."""
     dt = cfg.dtype
     R, N, eps = cfg.ssm_dt_rank, cfg.ssm_state, cfg.norm_eps
+    if riders is not None:
+        ns = active.shape[0]
+        # As in a decode step, the state's read and its write back are the
+        # update's traffic, under the scope that times it.
+        with jax.named_scope("scan"):
+            slot_ssm, slot_window = slot_state.layer_state(riders, layer)
     with jax.named_scope("ssm_in"):
         h = rms_norm(x, lp["norm"], eps)
         u, z = jnp.split(h @ lp["in_proj"].astype(dt), 2, axis=-1)
     with jax.named_scope("conv"):
-        if step:    # each slot a sequence of one row, its window its own
-            u, window = jax.vmap(causal_conv, (0, None, None, 1), (0, 1))(
-                u[:, None], lp["conv_w"], lp["conv_b"], window)
-            u = u[:, 0]
+        if step:
+            u, window = _slot_conv(lp, u, window)
         else:
-            u, window = causal_conv(u, lp["conv_w"], lp["conv_b"], window,
+            rows = u
+            u, window = causal_conv(rows, lp["conv_w"], lp["conv_b"], window,
                                     length)
         u = jax.nn.silu(u).astype(dt)
+        if riders is not None:
+            rode, slot_window = _slot_conv(lp, rows[-ns:], slot_window)
+            u = _ride(u, jax.nn.silu(rode), active)
     with jax.named_scope("ssm_params"):
         r, b, c = jnp.split(u @ lp["x_proj"].astype(dt), [R, R + N], axis=-1)
         r = rms_norm(r, lp["dt_norm"], eps)
@@ -362,22 +407,33 @@ def mamba_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
                     preferred_element_type=jnp.float32)
             + lp["dt_bias"].astype(jnp.float32))
         a = -jnp.exp(lp["A_log"].astype(jnp.float32))
+
+    def gated(y, z):    # a step's gate; a sequence's is the scan's own
+        return (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
+
     with jax.named_scope("scan"):
         if step:
             y, state = ssm_step(u, step_size, a, b, c, lp["D"], state)
         else:
             y, state = selective_scan(u, step_size, a, b, c, lp["D"], state,
                                       length, z=z)
+            if riders is not None:
+                rode, slot_ssm = ssm_step(u[-ns:], step_size[-ns:], a,
+                                          b[-ns:], c[-ns:], lp["D"], slot_ssm)
+                y = _ride(y, gated(rode, z[-ns:]), active)
+                riders = slot_state.update_layer(riders, layer, active,
+                                                 slot_ssm, slot_window)
     with jax.named_scope("ssm_out"):
         if step:
-            y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(dt)
-        return x + scaled(y @ lp["out_proj"].astype(dt), cfg), state, window
+            y = gated(y, z)
+        out = x + scaled(y @ lp["out_proj"].astype(dt), cfg)
+        return (out, state, window) + (() if riders is None else (riders,))
 
 
 def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
                  window=None, *, step: bool = False, length=None,
-                 layer=None, active=None
-                 ) -> Tuple[jax.Array, Any, jax.Array]:
+                 layer=None, active=None, riders=None
+                 ) -> Tuple[jax.Array, ...]:
     """x + residual_scale * mixer(norm(x)) for a Mamba-2 layer
     (`cfg.ssm_heads` heads of `Di / H` channels, `cfg.ssm_groups` G groups of
     B and C, head h reading group `h // (H / G)`: one in the Granite 4.0-H
@@ -403,23 +459,35 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
     layer `layer`'s rows of the slots `active` marks are read, updated and
     written where they lie, in one visit (`slot_state.step_layer`): the state
     handed back is the pair, and an idle slot's output row is not meaningful
-    (and finite). -> (out, state, window)."""
+    (and finite). -> (out, state, window).
+
+    RIDERS, as `mamba_mixer`'s: `riders` the slots' whole state with `layer`
+    and `active`, the sequence's last ns rows one token a slot; a riding
+    slot's row goes through `_slot_conv` against its own window and
+    `slot_state.step_layer` on its own state where it lies, the gate and the
+    norm are every row's. -> (out, state, window, the slots' state)."""
     dt = cfg.dtype
     Di, N, eps = cfg.ssm_inner, cfg.ssm_state, cfg.norm_eps
     G = cfg.ssm_groups
+    if riders is not None:
+        ns = active.shape[0]
+        with jax.named_scope("scan"):   # the window's read: a decode step's
+            _, slot_window = slot_state.layer_state(riders, layer)
     with jax.named_scope("ssm_in"):
         h = rms_norm(x, lp["norm"], eps)
         z, xbc, r = jnp.split(h @ lp["in_proj"].astype(dt),
                               [Di, Di + cfg.ssm_conv_channels], axis=-1)
     with jax.named_scope("conv"):
-        if step:    # each slot a sequence of one row, its window its own
-            xbc, window = jax.vmap(causal_conv, (0, None, None, 1), (0, 1))(
-                xbc[:, None], lp["conv_w"], lp["conv_b"], window)
-            xbc = xbc[:, 0]
+        if step:
+            xbc, window = _slot_conv(lp, xbc, window)
         else:
-            xbc, window = causal_conv(xbc, lp["conv_w"], lp["conv_b"], window,
-                                      length)
+            rows = xbc
+            xbc, window = causal_conv(rows, lp["conv_w"], lp["conv_b"],
+                                      window, length)
         xbc = jax.nn.silu(xbc).astype(dt)
+        if riders is not None:
+            rode, slot_window = _slot_conv(lp, rows[-ns:], slot_window)
+            xbc = _ride(xbc, jax.nn.silu(rode), active)
     with jax.named_scope("ssm_params"):
         u, b, c = jnp.split(xbc, [Di, Di + G * N], axis=-1)
         if G > 1:   # a group's B and C apart: [rows, G, N]
@@ -434,6 +502,13 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
         else:
             y, state = ssd_scan(u, step_size, a, b, c, lp["D"], state,
                                 length)
+            if riders is not None:
+                rode, riders = slot_state.step_layer(
+                    riders, layer, active, u[-ns:], step_size[-ns:], a,
+                    b[-ns:], c[-ns:], lp["D"])
+                y = _ride(y, rode, active)
+                riders = slot_state.update_layer(riders, layer, active, None,
+                                                 slot_window)
     with jax.named_scope("ssm_out"):
         gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
         if G > 1:   # the norm over each group's channels apart
@@ -442,7 +517,8 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
         else:
             y = rms_norm(gated, lp["w_norm"], eps)
         y = y.astype(dt)
-        return x + scaled(y @ lp["out_proj"].astype(dt), cfg), state, window
+        out = x + scaled(y @ lp["out_proj"].astype(dt), cfg)
+        return (out, state, window) + (() if riders is None else (riders,))
 
 
 def conv_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, window=None, *,

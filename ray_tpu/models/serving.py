@@ -120,7 +120,11 @@ class _Kind(NamedTuple):
     rows `x [1, B, D]` through the layer `lp` (its ordinal `l`, where the walk
     passes one); `kept`, a tuple, is what the caches keep of the rows,
     `counts` the layer's routing (None: it routes nothing), `caches` what
-    riders ride against (None without them). `decode(lp, x, caches, l, ctx)
+    riders ride against (None without them): under `ctx["riders"]` = (bt, w,
+    act) the rows' last n_slots are one token a slot, and a kind of a stack
+    that takes riders gives them its decode step there, against `caches` (its
+    pages, its state), and hands the caches back moved; what a kind does row
+    by row needs no word of them. `decode(lp, x, caches, l, ctx)
     -> (x, caches, counts)`: one token a slot, `x [n_slots, D]`, the step's
     rows written and the cached ones read. `ctx` is the walk's: the rotary
     tables, the live mask, the block table, the positions.
@@ -630,14 +634,29 @@ def _mamba_kind(mcfg) -> _Kind:
     is causal, and the mixer is told `length`. A sparse layer hands back its
     routing counts. In a stack of one-part layers (`mcfg.layer_parts`) the
     layer is the mixer ALONE: its stack holds no feed-forward's leaves, none
-    runs, and it routes nothing."""
+    runs, and it routes nothing. Its prefill takes RIDERS (`mixer(riders=)`):
+    the tail rows' convolution against each riding slot's own window and
+    their state's step where it lies, both written back as a decode step
+    writes them (under the mixer's `conv` and `scan` scopes), between
+    projections, a norm, a gate and a feed-forward that run once over the
+    bucket; the step is the one `decode` runs, written once in the mixer."""
     mixer = block.mamba2_mixer if mcfg.ssm_heads else block.mamba_mixer
     stats = _routing_stats(mcfg)
     alone = mcfg.layer_parts is not None
 
     def prefill(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
-        y, state, window = mixer(lp, x[0], mcfg, length=ctx["length"])
+        rides = {}
+        if ctx["riders"]:
+            # ONE decode step of the riding slots in the bucket's tail rows,
+            # from and to their own state, which rides the scan's carry; the
+            # layer's weights are read once, for the prompt and for them.
+            rides = dict(riders=caches.state, layer=l,
+                         active=ctx["riders"][2])
+        y, state, window, *rode = mixer(lp, x[0], mcfg, length=ctx["length"],
+                                        **rides)
+        if rode:
+            caches = caches._replace(state=rode[0])
         if alone:
             return y[None], caches, (state, window), None
         y, routed = block.feed_forward(lp, y[None], mcfg, ctx["live"],
@@ -670,8 +689,11 @@ def _mamba_kind(mcfg) -> _Kind:
         return x, caches._replace(state=state), \
             stats(routed[1], ctx["act"]) if routed_layer else None
 
-    # `state_writes`: the admissions that overwrote a slot's state (a decode
-    # chunk moves the active slots' share of `state_bytes` once a step).
+    # `state_writes`: the admissions that overwrote a slot's state. (What
+    # moves a slot's share of `state_bytes` is counted by the scheduler: a
+    # decode chunk its active slots' once a step, `decode_dispatch`'s
+    # `active`; a riding step its riders' once, the admit span's `riders`,
+    # `rider_tokens`. No counter of the model's own.)
     return _Kind(prefill, decode, keeps=("state", "state"), over="index",
                  carries=("state",), counts=_Counts(keeps={"state_writes": 0}))
 
@@ -897,7 +919,7 @@ def _stack(mcfg) -> _Stack:
             else ())
 
     if mcfg.ssm_state:
-        kinds = {"attn": _attention_kind(mcfg, False, stack="layers",
+        kinds = {"attn": _attention_kind(mcfg, True, stack="layers",
                                          over="inline"),
                  "mamba": _mamba_kind(mcfg)}
         if mcfg.layer_parts is not None:    # each of the three a part alone
@@ -910,7 +932,7 @@ def _stack(mcfg) -> _Stack:
                     mcfg.state_layers, ns, mcfg.ssm_state, mcfg.ssm_inner,
                     mcfg.ssm_conv, dt, mcfg.ssm_conv_channels)),
             lambda c: {"state_bytes": slot_state.state_bytes(c.state)},
-            shares=bool(mcfg.experts_held), tally="zero")
+            takes_riders=True, shares=bool(mcfg.experts_held), tally="zero")
     if mcfg.conv:
         kind = _conv_kind(mcfg)
         return _Stack(
@@ -1057,8 +1079,11 @@ def _prefill_walk(mcfg, stack: _Stack):
     k and v do what a decode step does (the row written to the slot's page,
     the `paged_decode` kernel against the arena, which rides the scan's carry
     as it does in decode) and the result takes the tail of the flash output's
-    place; the feed-forward and the head run over the bucket as they do
-    anyway, so the step's weight reads are the prefill's. Then logits is [1 +
+    place; a state-space layer's tail rows take the mixer's step from and to
+    the slots' own state, in the same carry (`_mamba_kind`); the projections,
+    the feed-forward (a one-part stack's expert layers: `live` holds the
+    riders) and the head run over the bucket as they do anyway, so the
+    step's weight reads are the prefill's. Then logits is [1 +
     n_slots, V]: the prompt's last row, then the tail rows; and `experts`
     counts the riding rows."""
     dt, S = mcfg.dtype, mcfg.max_seq

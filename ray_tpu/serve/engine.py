@@ -55,7 +55,8 @@ n-step decode chunk over all slots, and the slot poke.
   what they wait to do is a decode step, which is weight reads that the
   prefill of the same layers makes anyway. So where a prompt leaves
   `n_slots` rows of its bucket free, the prefill program of a riding rung
-  (`rung_rides`: the octave under max_seq, of a dense or a sparse stack)
+  (`rung_rides`: the octave under max_seq, of a stack whose programs take
+  riders, `Programs.takes_riders`: a dense, a sparse or a state-space hybrid)
   carries ONE decode step of every live slot in those rows
   (`models/serving.py`); on the host the riders advance as a chunk of one
   step would (`_ride_plan`, `_place`), and the emitter streams their tokens
@@ -134,9 +135,11 @@ def rung_rides(max_seq: int, n_slots: int, width: int) -> bool:
     (`models/serving.py`, riders): the rungs of the octave under `max_seq`,
     where a prefill is long enough for a decode step's weight reads to hide
     in it and where the long prompts of a batch land, and none narrower (a
-    riding program holds a decode step's attention kernel and a sampler over
-    the slots' rows, traced, lowered and loaded at every start). The slots'
-    rows have to fit in the rung beside a prompt."""
+    riding program holds a decode step's attention kernel, a hybrid's its
+    state's step too, and a sampler over the slots' rows, traced, lowered and
+    loaded at every start). The slots' rows have to fit in the rung beside a
+    prompt. Whether the stack's programs take riders at all is the stack's
+    answer (`Programs.takes_riders`), not the rung's."""
     return 2 * width >= max_seq and n_slots < width
 
 

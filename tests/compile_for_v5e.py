@@ -80,9 +80,20 @@ class Described:
             sds((ns,), jnp.float32), sds((ns,), jnp.int32),
             sds((ns, 2), jnp.uint32))
 
+    def riding(self):
+        """A riding rung's last three arguments as `Engine._place` passes
+        them: the slots' `last` and `pos`, and the riders (the block table,
+        who rides, the slots' temperatures, top-ks and keys)."""
+        ns, sds = self.ns, self.sds
+        slots = sds((ns,), jnp.int32)
+        return slots, slots, (
+            sds((ns, self.maxp), jnp.int32), sds((ns,), jnp.bool_),
+            sds((ns,), jnp.float32), slots, sds((ns, 2), jnp.uint32))
+
     def lower_prefill(self, width, slot, *riding):
         """The prefill of one prompt `width` wide into `slot` (None where
-        the model keeps nothing by slot), lowered."""
+        the model keeps nothing by slot), lowered; `riding`: `self.riding()`
+        for a riding rung's program as the engine calls it."""
         sds = self.sds
         return self.built.prefill.lower(
             self.params, self.caches, sds((self.maxp,), jnp.int32),
@@ -127,6 +138,23 @@ def described_cell(topo, monkeypatch, config, layers=None, init=None):
         shaped(jax.eval_shape(built.empty)), sds)
 
 
+def results(hlo):
+    """(name, shape, op) of every instruction of a compiled program's text
+    whose result is one array."""
+    return [(name, tuple(int(d) for d in dims.split(",")), op)
+            for name, dims, op in re.findall(
+                r"%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", hlo)]
+
+
+def copies_of(hlo, *arrays):
+    """Names of the compiled program's `copy` instructions whose result has
+    the shape of one of `arrays` or of one layer of it: a cache that rides a
+    loop's carry and aliases the donated buffers has none."""
+    shapes = {tuple(a.shape[i:]) for a in arrays for i in (0, 1)}
+    return [name for name, shape, op in results(hlo)
+            if op == "copy" and shape in shapes]
+
+
 def moved_stacks(hlo, stacks):
     """Names of the compiled program's instructions whose result has the
     shape of an expert stack, of one layer of one or of one expert's matrix
@@ -136,7 +164,6 @@ def moved_stacks(hlo, stacks):
     shapes = set()
     for s in stacks:
         shapes |= {s, s[1:], s[2:], (s[0] * s[1],) + s[2:]}
-    return [name for name, dims, op in re.findall(
-        r"%(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", hlo)
-        if tuple(int(d) for d in dims.split(",")) in shapes
-        and (op == "copy" or "dynamic-" in op + name or "slice" in op + name)]
+    return [name for name, shape, op in results(hlo) if shape in shapes
+            and (op == "copy" or "dynamic-" in op + name
+                 or "slice" in op + name)]

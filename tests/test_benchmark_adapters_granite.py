@@ -215,7 +215,7 @@ def test_the_engines_spans_carry_what_the_granite_readers_read():
     cfg = adapter.build_config(dict(m, **adapter.REHEARSE), {
         "params": "float32", "activations": "float32"}, 128)
     built = build_programs(cfg, 2, 2, 16, 17)
-    assert built.by_slot and not built.adopts and not built.takes_riders
+    assert built.by_slot and not built.adopts and built.takes_riders
     params = jax.eval_shape(lambda: fuse_qkv(
         init_params(cfg, jax.random.PRNGKey(0)), cfg))
     caches = jax.eval_shape(built.empty)

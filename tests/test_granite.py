@@ -26,6 +26,7 @@ from benchmark import reference_granite as ref
 from ray_tpu.models import block, llama, serving
 from ray_tpu.models.block import fuse_qkv, mamba2_mixer
 from ray_tpu.ops import attention, slot_state, ssm
+import mixer_riders
 
 # Float32 everywhere on the CPU: what is left between the program and the
 # reference is the order of float32 sums (a chunk's matrix products against
@@ -269,6 +270,24 @@ def test_a_split_prompt_is_the_unsplit_one(tiny, cut):
                                layer=0, active=jnp.ones(1, bool))
         assert _err(y[0], whole[t]) < SCAN_TOL
     assert _err(s[0][0, 0], state) < SCAN_TOL
+
+
+def test_riders_in_a_prompts_tail_rows_take_a_step_and_leave_the_prompt_alone(
+        tiny):
+    """`mamba2_mixer(riders=)`: tests/mixer_riders.py says what is held.
+    The step alone is `step=True` on the slots' whole state and the window's
+    write back, as `models/serving.py::_mamba_kind`'s decode body has it."""
+    cfg, params = tiny
+    lp = jax.tree.map(lambda w: w[1], params["mamba"])
+
+    def step(x, slots, layer, active):
+        out, slots, window = mamba2_mixer(
+            lp, x, cfg, slots, slot_state.layer_state(slots, layer)[1],
+            step=True, layer=layer, active=active)
+        return out, slot_state.update_layer(slots, layer, active, None,
+                                            window)
+
+    mixer_riders.check(mamba2_mixer, lp, cfg, step, SCAN_TOL)
 
 
 # -- (c) the mixer, the attention block, the multipliers ---------------------

@@ -33,8 +33,9 @@ from benchmark import models
 from benchmark import reference_jamba as ref
 from ray_tpu.models import block, llama
 from ray_tpu.models.block import fuse_qkv, mamba_mixer
-from ray_tpu.ops import attention, ssm
+from ray_tpu.ops import attention, slot_state, ssm
 from ray_tpu.models.serving import prefill_core
+import mixer_riders
 
 LOGIT_TOL = 2e-4
 SCAN_TOL = 2e-5
@@ -198,6 +199,23 @@ def test_a_split_prompt_is_the_unsplit_one(tiny, cut, scan_in_interpret_mode):
     assert np.abs(got - np.asarray(whole)).max() < SCAN_TOL
     assert np.abs(np.asarray(s2) - np.asarray(state)).max() < SCAN_TOL
     np.testing.assert_allclose(np.asarray(w2), np.asarray(window), atol=1e-6)
+
+
+def test_riders_in_a_prompts_tail_rows_take_a_step_and_leave_the_prompt_alone(
+        tiny, scan_in_interpret_mode):
+    """`mamba_mixer(riders=)`: tests/mixer_riders.py says what is held; the
+    prompt's scan is the kernel's own code, told `length`. The step alone is `step=True` on the layer's rows and their write back, as
+    `models/serving.py::_mamba_kind`'s decode body has it."""
+    cfg, params = tiny
+    lp = jax.tree.map(lambda w: w[1], params["mamba"])
+
+    def step(x, slots, layer, active):
+        out, ssm, window = mamba_mixer(
+            lp, x, cfg, *slot_state.layer_state(slots, layer), step=True)
+        return out, slot_state.update_layer(slots, layer, active, ssm,
+                                            window)
+
+    mixer_riders.check(mamba_mixer, lp, cfg, step, SCAN_TOL)
 
 
 def test_the_training_forward_refuses_state_space_layers_by_name(tiny):

@@ -25,6 +25,7 @@ from benchmark import reference_nemotron_h as ref
 from ray_tpu.models import llama, serving
 from ray_tpu.models.block import feed_forward, mamba2_mixer
 from ray_tpu.ops import attention, moe, slot_state, ssm
+import mixer_riders
 
 # Float32 everywhere on the CPU: what is left between the program and the
 # reference is the order of float32 sums (a chunk's matrix products against
@@ -271,6 +272,24 @@ def test_the_mixer_is_the_references_and_its_steps_go_on_from_its_state(
             lp, x[48 + t][None], cfg, slots, window, step=True, layer=0,
             active=jnp.ones(1, bool))
         assert np.abs(np.asarray(out)[0] - want[length + t]).max() < SCAN_TOL
+
+
+def test_riders_in_a_prompts_tail_rows_take_a_step_and_leave_the_prompt_alone(
+        tiny):
+    """`mamba2_mixer(riders=)`: tests/mixer_riders.py says what is held; the one-part stack's mixer has two groups of B and C.
+    The step alone is `step=True` on the slots' whole state and the window's
+    write back, as `models/serving.py::_mamba_kind`'s decode body has it."""
+    cfg, params = tiny
+    lp = jax.tree.map(lambda w: w[1], params["mamba"])
+
+    def step(x, slots, layer, active):
+        out, slots, window = mamba2_mixer(
+            lp, x, cfg, slots, slot_state.layer_state(slots, layer)[1],
+            step=True, layer=layer, active=active)
+        return out, slot_state.update_layer(slots, layer, active, None,
+                                            window)
+
+    mixer_riders.check(mamba2_mixer, lp, cfg, step, SCAN_TOL)
 
 
 def test_the_adapter_draws_the_routed_down_matrices_smaller():

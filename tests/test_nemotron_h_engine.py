@@ -17,7 +17,7 @@ from ray_tpu.models import serving
 from ray_tpu.models.block import fuse_qkv
 from ray_tpu.ops import attention, slot_state
 from ray_tpu.serve.engine import Engine
-from engine_pins import pinned
+from engine_pins import Spans, pinned
 from test_nemotron_h import ADAPTER, F32, LOGIT_TOL, MODEL, _params, tiny
 
 
@@ -62,7 +62,9 @@ def test_engine_prefill_then_decode_match_the_reference(tiny, engine):
     the share's routing counts over the TWO sparse layers."""
     cfg, params = tiny
     prompts = [_tokens(256, 5), _tokens(300, 6), _tokens(21, 7)]
-    served = _serve(engine, prompts, 24)
+    before = engine.counters()
+    with Spans() as spans:
+        served = _serve(engine, prompts, 24)
     assert [len(s) for s in served] == [24, 24, 24]
     for prompt, toks in zip(prompts, served):
         gaps = ref.served_token_gaps(params, MODEL, prompt, toks)
@@ -82,8 +84,16 @@ def test_engine_prefill_then_decode_match_the_reference(tiny, engine):
     counts = engine.counters()
     assert counts["state_writes"] == 3
     assert counts["state_bytes"] == 3 * 4 * (16 * 128 * 4 + 3 * 192 * 4)
-    # (a request's 23 decoded tokens take six whole chunks of 4 steps)
-    routed = (256 + 300 + 21 + 3 * 24) * 3 * cfg.sparse_layers
+    # The rows the routers saw: the prompts', a row a live slot a step of a
+    # chunk (whole chunks of 4 steps: a request's last may overshoot), and a
+    # row a rider of a riding rung's prefill (256 and 512 here; who rides
+    # what follows the order the requests are admitted in).
+    steps = sum(4 * c["active"] for c in
+                spans.named("serve.engine.decode_dispatch"))
+    rode = sum(a.get("riders", 0) for a in spans.named("serve.engine.admit"))
+    assert rode == counts["rider_tokens"] - before["rider_tokens"]
+    assert 3 * 23 <= steps + rode <= 3 * 27
+    routed = (256 + 300 + 21 + steps + rode) * 3 * cfg.sparse_layers
     assert counts["routed_assignments"] == routed
     assert 0 < counts["local_assignments"] < routed
     assert len(counts["expert_tokens"]) == 4

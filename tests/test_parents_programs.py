@@ -39,7 +39,12 @@ from test_serve_llm import parents_sample_tokens
 # stacks' decode programs hand the arena to the jit they share with the riders
 # (`_token_step`: the same write and kernel, one trace a process) and were
 # taken anew on PR 41's tree (their parent's: 4ce2defd4ff49240 and
-# 278d751dc50fcfc4); the three stacks that take nobody keep the parent's.
+# 278d751dc50fcfc4); the stacks that take nobody keep the parent's.
+# Since PR 58 the hybrid stack takes riders too: its pin is the 32 rung, the
+# parent's (a8cc334), and its decode program, whose attention layers now go
+# through `_token_step` as well, was taken anew on PR 58's tree (the
+# parent's: 98e6e614b5625848, which the tree still lowers to with the
+# attention kind built `ridden=False`: the mixers' steps did not move).
 # `mixed` (PR 42's stack, which takes nobody) was taken on PR 45's parent
 # (6c2c097), before that PR moved a line under ray_tpu/.
 # Since PR 47 `serving.sample_tokens` takes its top-k behind a conditional, so
@@ -50,8 +55,8 @@ from test_serve_llm import parents_sample_tokens
 PARENT_PROGRAMS = {
     "dense.decode": "d87712c9b4ee5285",
     "dense.prefill32": "c948937b09fe2fee",
-    "hybrid.decode": "98e6e614b5625848",
-    "hybrid.prefill64": "b6847a6dfe909d84",
+    "hybrid.decode": "112c069064bd2652",
+    "hybrid.prefill32": "bf109118a1785278",
     "indexed.decode": "7f5193fdda9e8db0",
     "indexed.prefill64": "2b26fc68f7f5f898",
     "latent.decode": "3cbcf9da23401fa5",
@@ -67,7 +72,8 @@ PARENT_PROGRAMS = {
 # of the five stacks at their adapters' rehearsal widths, `max_seq` 128 and
 # two slots, on PR 41's parent (5481b82; jax 0.9.0 on the CPU: no Mosaic
 # payload, no source locations in the text). The rungs 64 and 128 of the dense
-# and the sparse stack ride since PR 41: `PARENT_RIDING` below;
+# and the sparse stack ride since PR 41, the hybrid's since PR 58:
+# `PARENT_RIDING` below;
 # `PARENT_PROGRAMS` above pins the decode programs. `mixed` (PR 42's stack) was
 # taken on PR 45's parent (6c2c097). All three tables stand since PR 47 with
 # that PR's parent's sampler in `serving.sample_tokens`' place while a
@@ -78,8 +84,7 @@ PARENT_RUNGS = {
     "sparse": {32: "7a5fc5aa7c158c94"},
     "indexed": {32: "bfe2a2df64e53893", 64: "2b26fc68f7f5f898",
                 128: "69ca4b8800557a63"},
-    "hybrid": {32: "bf109118a1785278", 64: "b6847a6dfe909d84",
-               128: "4dd7ed9434604dd1"},
+    "hybrid": {32: "bf109118a1785278"},
     "latent": {32: "8da32aa0051287f3", 64: "f96e02f0c080c3fb",
                128: "4b487bf21d58472e"},
     "mixed": {32: "7ce5961c0ca08aab", 64: "ef4db6b4bc528b78",
@@ -89,13 +94,18 @@ PARENT_RUNGS = {
 PARENT_RIDERLESS = {
     "dense": {64: "d5061fe7c8b0f160", 128: "0f8a98c45565c6ea"},
     "sparse": {64: "01d0cbc9e60958cc", 128: "6ea775ec4038bec1"},
+    "hybrid": {64: "b6847a6dfe909d84", 128: "4dd7ed9434604dd1"},
 }
 # What the riding rungs lower to with the riders' shapes as `_place` passes
 # them (`Engine.lowered_prefill_text`): the programs `serve-batch` and
-# `serve-batch-olmoe` spend their prefill time in, on PR 45's parent (6c2c097).
+# `serve-batch-olmoe` spend their prefill time in, on PR 45's parent
+# (6c2c097); the hybrid's (a Mamba-1 layer's step in the mixer's tail rows,
+# `block.mamba_mixer(riders=)`: the programs `serve-batch-jamba2` spends its
+# prefill time in) taken on PR 58's tree, which made them.
 PARENT_RIDING = {
     "dense": {64: "8de5c32ccafe6475", 128: "3a4649dd358ebc16"},
     "sparse": {64: "c033be69300aac6b", 128: "c2b37a166224c151"},
+    "hybrid": {64: "a87192cc5a56bac3", 128: "f0b6c2f60d1080fc"},
 }
 STACKS = dict(KINDS, latent=("dots", PUBLISHED), mixed=("mimo", MIMO))
 
@@ -204,13 +214,14 @@ def test_the_other_models_programs_are_the_parents(kind):
 
 @pytest.mark.parametrize("kind", sorted(PARENT_RUNGS))
 def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
-    """A dense and a sparse stack's programs of the octave under `max_seq`
-    take riders and hold a decode step's attention; their narrow rungs, and
-    every rung of an indexed, a hybrid, a latent and a mixed stack, take
-    nobody and lower to the parent's text, letter for letter. Asked of the
-    built program; no option, field or environment variable has a say."""
+    """A dense, a sparse and a hybrid stack's programs of the octave under
+    `max_seq` take riders and hold a decode step's attention (a hybrid's its
+    state-space layers' step too); their narrow rungs, and every rung of an
+    indexed, a latent and a mixed stack, take nobody and lower to the
+    parent's text, letter for letter. Asked of the built program; no option,
+    field or environment variable has a say."""
     takes, riding, got, _, _ = _programs(kind)
-    assert takes is (kind in ("dense", "sparse"))
+    assert takes is (kind in ("dense", "sparse", "hybrid"))
     assert riding == ([64, 128] if takes else [])
     assert {w: d for w, d in got.items()
             if w not in riding} == PARENT_RUNGS[kind]
@@ -220,8 +231,8 @@ def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
 
 @pytest.mark.parametrize("kind", sorted(PARENT_RIDING))
 def test_the_riding_rungs_are_the_parents(kind):
-    """The riding rungs of a dense and a sparse stack, lowered with the
-    riders' shapes as `_place` passes them, are the parent's text too."""
+    """The riding rungs of a dense, a sparse and a hybrid stack, lowered
+    with the riders' shapes as `_place` passes them, are the pinned text."""
     _, riding, got, _, _ = _programs(kind)
     assert {w: got[w] for w in riding} == PARENT_RIDING[kind]
 

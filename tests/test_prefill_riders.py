@@ -2,12 +2,16 @@
 prompt leaves `n_slots` rows of its bucket free, a riding rung's program
 (`rung_rides`: the octave under `max_seq`) carries ONE decode step of every
 live slot in those rows. On the CPU at the adapters' rehearsal widths in
-float32, a dense and a sparse stack: every stream is what the same engine
-serves with nobody riding, and the plain reference's greedy tokens; the
-counters and the admit spans agree; a burst of admissions moves the riders a
-step each; a rider that finishes on a riding step frees its slot and pages at
-once. (An indexed, a hybrid and a latent stack take nobody, and every program
-that takes nobody lowers to the parent's text: tests/test_parents_programs.py.)
+float32, a dense and a sparse stack and, since PR 58, the three hybrids
+(Mamba-1 over a dense feed-forward; Mamba-2, one group, over a share of the
+experts; the stack of one-part layers, Mamba-2 with groups): every stream is
+what the same engine serves with nobody riding, and the plain reference's
+greedy tokens; the counters and the admit spans agree; a burst of admissions
+moves the riders a step each; a rider that finishes on a riding step frees its
+slot, its pages and its slot's recurrent state at once, and the next admission
+overwrites them. (An indexed, a latent, a mixed and a conv stack take nobody,
+and every program that takes nobody lowers to the parent's text:
+tests/test_parents_programs.py.)
 
 Tolerance: program and reference compute the same mathematics in float32 and
 differ in the order of their sums; LOGIT_TOL is tests/test_prefill_ladder.py's.
@@ -24,8 +28,10 @@ import jax.numpy as jnp
 
 from ray_tpu.serve.engine import (_DEPTH, Engine, prefill_widths,
                                   rung_rides)
+import test_granite
+import test_nemotron_h
 from engine_pins import Spans as _Spans
-from test_prefill_ladder import LOGIT_TOL, _tiny, _tokens
+from test_prefill_ladder import F32, LOGIT_TOL, _tiny, _tokens
 
 MAX_SEQ, SLOTS, CHUNK = 256, 4, 4
 # (prompt tokens, max_tokens, temperature): rungs 256 and 128 ride, 64 and 32
@@ -49,8 +55,23 @@ def _drain(q, seconds=120.0):
     return out
 
 
+# The hybrids beside test_prefill_ladder's "hybrid" (Jamba: Mamba-1 over a
+# dense feed-forward), each as its own model's tests build it: the adapter's
+# rehearsal widths, weights that decide.
+HYBRIDS = {"mamba2": test_granite, "one-part": test_nemotron_h}
+STACKS = ["dense", "sparse", "hybrid", *HYBRIDS]
+
+
+def _model(kind):
+    if kind not in HYBRIDS:
+        return _tiny(kind, MAX_SEQ)
+    tests = HYBRIDS[kind]
+    cfg = tests.ADAPTER.build_config(tests.MODEL, F32, MAX_SEQ)
+    return tests.ADAPTER, tests.MODEL, cfg, tests._params(cfg)
+
+
 def _build(kind, n_slots=SLOTS):
-    adapter, model, cfg, params = _tiny(kind, MAX_SEQ)
+    adapter, model, cfg, params = _model(kind)
     eng = Engine(jax.tree.map(jnp.copy, params), cfg, n_slots=n_slots,
                  decode_chunk=CHUNK, page_size=16,
                  n_pages=n_slots * MAX_SEQ // 16 + 1)   # pages never bind
@@ -66,7 +87,7 @@ def _serve(eng, asks=ASKS):
     return [_drain(q) for q in streams]
 
 
-@pytest.fixture(scope="module", params=["dense", "sparse"])
+@pytest.fixture(scope="module", params=STACKS)
 def served(request):
     """One engine a stack: `ASKS` served with the live slots riding, then by
     the same engine, the same programs, with nobody marked as riding."""
@@ -165,12 +186,13 @@ def test_the_manifest_entry_of_the_riders_share():
         workloads=["serve-batch", "serve-batch-olmoe"])
 
 
-@pytest.fixture(scope="module")
-def held():
-    """A dense engine of three slots whose emitter the test holds at its first
-    chunk, so that the loop stands with `_DEPTH` chunks in flight and nothing
-    moves but what the test submits."""
-    _, _, _, eng = _build("dense", n_slots=3)
+@pytest.fixture(scope="module", params=["dense", "hybrid"])
+def held(request):
+    """An engine of three slots (a dense stack's; a hybrid's, whose slots hold
+    a recurrent state too) whose emitter the test holds at its first chunk, so
+    that the loop stands with `_DEPTH` chunks in flight and nothing moves but
+    what the test submits."""
+    _, _, _, eng = _build(request.param, n_slots=3)
     gate = threading.Event()
     fetch = eng._fetch
 
@@ -191,8 +213,9 @@ def test_a_burst_moves_the_riders_a_step_an_admission_and_a_finished_rider_frees
     three prefills, three steps; B, admitted first with two tokens to make,
     rides the second prefill, finishes on it and frees its slot and pages
     there and then, so the third of the burst is admitted into B's slot in
-    the same round, on a three-slot engine. Every stream is what the engine
-    serves one request at a time."""
+    the same round, on a three-slot engine, and its prefill writes the slot's
+    recurrent state over what B's riding step left there. Every stream is
+    what the engine serves one request at a time."""
     eng, gate = held
     asks = [(150, 40), (140, 2), (200, 6), (170, 5)]       # A, B, C, D
     gate.set()

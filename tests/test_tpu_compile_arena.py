@@ -187,20 +187,17 @@ def test_a_riding_prefill_updates_the_arena_in_place_and_fits_on_v5e(
         topo, monkeypatch, config,
         init=lambda adapter, cfg: serving._experts_in_compute_dtype(
             adapter.init_params(cfg, 0), cfg))
-    ns, maxp, sds = cell.ns, cell.maxp, cell.sds
+    ns = cell.ns
     assert rung_rides(cell.eng["max_seq"], ns, width)
     assert cell.built.takes_riders
     kc, vc = cell.caches.kc, cell.caches.vc
-    slots = sds((ns,), jnp.int32)
-    riders = (sds((ns, maxp), jnp.int32), sds((ns,), jnp.bool_),
-              sds((ns,), jnp.float32), slots, sds((ns, 2), jnp.uint32))
 
     def compiled(*more):
         lowered = cell.lower_prefill(width, None, *more)
         return lowered.as_text(), lowered.compile()
 
     plain_text, plain = compiled(None, None, None)
-    text, riding = compiled(slots, slots, riders)
+    text, riding = compiled(*cell.riding())
     assert "paged_decode" in text and "paged_decode" not in plain_text
     hlo = riding.as_text()
     calls = [kind.count('custom_call_target="tpu_custom_call"')

@@ -1,13 +1,16 @@
 """The state-space hybrids' serving programs, compiled for a described
 `v5e:2x2` at the cells' sizes (tests/compile_for_v5e.py says why): Jamba's
 selective scan beside attention, Granite's Mamba-2 over a share of the
-experts."""
+experts. Each cell's prefill is a RIDING rung's (`engine.rung_rides`), lowered
+as the engine calls it, the live slots' decode step in its tail rows (PR
+58)."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from compile_for_v5e import described_cell, moved_stacks
+from compile_for_v5e import copies_of, described_cell, moved_stacks
+from ray_tpu.serve.engine import rung_rides
 from ray_tpu.ops import attention
 
 pytestmark = pytest.mark.usefixtures("_no_compile_cache")
@@ -23,7 +26,12 @@ def test_jamba_programs_keep_arena_and_state_in_place_on_v5e(
     `paged_decode` kernel at MQA `groups` 20, prefill's scan the
     `selective_scan` kernel; and no program sets a layer's weights aside
     (the stacks are read by index inside the segment's loop, the attention
-    layers' by a constant one)."""
+    layers' by a constant one). The prefill is the riding rung's: the 16
+    slots' step in its last 16 rows (`paged_decode` beside `flash_fwd`, the
+    slots' state read and written where it lies in the segments' carry), and
+    neither the arena nor the state is copied (the WINDOWS are, once a
+    program, 12.8 MB: XLA lays them out anew for the prompt's `write_state`,
+    which reads one slot's rows of every layer)."""
     cell = described_cell(topo, monkeypatch, "jamba2-3b-serve")
     eng, caches, ns, page = cell.eng, cell.caches, cell.ns, cell.page
     assert "lm_head" not in cell.params
@@ -36,17 +44,24 @@ def test_jamba_programs_keep_arena_and_state_in_place_on_v5e(
         lowered = cell.lower_decode()
         kernel, path = "paged_decode", "decode_pallas"
     else:
-        lowered = cell.lower_prefill(4096, 0)
+        assert rung_rides(eng["max_seq"], ns, 4096) and cell.built.takes_riders
+        lowered = cell.lower_prefill(4096, 0, *cell.riding())
         kernel, path = "selective_scan", "scan_pallas"
     text = lowered.as_text()
     assert "tpu_custom_call" in text and kernel in text
+    assert "paged_decode" in text
     counts = attention.attention_path_counts()
     assert counts[path] > before.get(path, 0)
-    mem = lowered.compile().memory_analysis()
+    assert counts["decode_pallas"] > before.get("decode_pallas", 0)
+    compiled = lowered.compile()
+    assert not copies_of(compiled.as_text(), kc, state[0])
+    mem = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize for x in (kc, vc) + tuple(state))
     assert mem.alias_size_in_bytes >= held
     # A layer's weights set aside would be 0.2 GB (a Mamba layer), a
-    # segment's 1.4; the prefill's own temporaries are its activations.
+    # segment's 1.4; the prefill's own temporaries are its activations (146
+    # MB with nobody to take, 234 MB with the riders' rows selected into the
+    # convolution's and the scan's outputs).
     assert mem.temp_size_in_bytes < ((16 << 20) if program == "decode"
                                      else (256 << 20))
 
@@ -66,7 +81,11 @@ def test_granite_programs_keep_pages_and_state_in_place_on_v5e(
     268 MB of temporaries), a prompt's attention `flash_fwd`, the recurrence
     over a prompt the chunked dual form in plain XLA, the experts the grouped
     matmul with no copy of a stack and the share's combine the local kernel;
-    and the bytes are PERF.md section 4's row."""
+    and the bytes are PERF.md section 4's row. The 1,024-row prefill is a
+    riding rung's (`max_seq` 2,048): the 64 slots' step in its last 64 rows,
+    their state through the SAME `ssd_state_step` kernel on the whole state
+    in the segments' carry, their pages through `paged_decode`; no copy of
+    the arena or of the 2.4 GB state."""
     cell = described_cell(topo, monkeypatch, "granite-4.0-h-small-serve")
     eng, params, caches, ns, page = (cell.eng, cell.params, cell.caches,
                                       cell.ns, cell.page)
@@ -82,10 +101,12 @@ def test_granite_programs_keep_pages_and_state_in_place_on_v5e(
             "decode_pallas", "experts_grouped_pallas", "share_combine_local",
             "ssd_step_pallas"]
     else:
-        lowered = cell.lower_prefill(1024, 0)
-        kernels, paths = ["flash_fwd", "grouped_matmul", "local_combine"], [
+        assert rung_rides(eng["max_seq"], ns, 1024) and cell.built.takes_riders
+        lowered = cell.lower_prefill(1024, 0, *cell.riding())
+        kernels, paths = ["flash_fwd", "grouped_matmul", "local_combine",
+                          "paged_decode", "ssd_state_step"], [
             "fwd_pallas", "experts_grouped_pallas", "share_combine_local",
-            "ssd_chunked"]
+            "ssd_chunked", "decode_pallas", "ssd_step_pallas"]
     text = lowered.as_text()
     assert all(k in text for k in kernels)
     counts = attention.attention_path_counts()
@@ -96,6 +117,7 @@ def test_granite_programs_keep_pages_and_state_in_place_on_v5e(
     stacks = [tuple(params[stack][w].shape) for stack in ("mamba", "layers")
               for w in ("w_gate", "w_up", "w_down")]
     assert not moved_stacks(compiled.as_text(), stacks)
+    assert not copies_of(compiled.as_text(), kc, ssm)
     mem = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize for x in (kc, vc, ssm, window))
     assert held == 2 * eng["kv_pages"] * 8 * page * 128 * 2 \

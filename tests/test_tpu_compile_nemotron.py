@@ -2,13 +2,16 @@
 `v5e:2x2` at the cell's sizes (tests/compile_for_v5e.py says why): the decode
 chunk and the prefill buckets up to 2,048 that the mix lands in; the wider
 ones, the same check, are tests/test_tpu_compile_nemotron_wide.py (a compile
-is about 50 s of a loaded worker, and six are more than a file may take)."""
+is about 50 s of a loaded worker, and six are more than a file may take). A
+bucket from 2,048 up is a RIDING rung's (`engine.rung_rides`), lowered as the
+engine calls it, the 32 slots' decode step in its tail rows (PR 58)."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from compile_for_v5e import described_cell, moved_stacks
+from compile_for_v5e import copies_of, described_cell, moved_stacks
+from ray_tpu.serve.engine import rung_rides
 from ray_tpu.ops import attention
 
 pytestmark = pytest.mark.usefixtures("_no_compile_cache")
@@ -29,7 +32,10 @@ def nemotron_program_keeps_pages_and_state_in_place(topo, program,
     the chunked dual form in plain XLA, the experts' TWO grouped matmuls the
     Pallas kernel at 2688 -> 1856 -> 2688 with no copy of a stack, and the
     share's combine the local kernel; and the bytes are PERF.md section 4's
-    row."""
+    row. A riding rung's prefill also holds the slots' step in its last 32
+    rows: `paged_decode` on their pages and the SAME `ssd_state_step` kernel
+    on the whole state where it lies in the segments' carry, and neither the
+    arena nor the state is copied."""
     cell = described_cell(topo, monkeypatch, "nemotron-3-nano-30b-a3b-serve")
     eng, params, caches, ns, page = (cell.eng, cell.params, cell.caches,
                                       cell.ns, cell.page)
@@ -45,10 +51,17 @@ def nemotron_program_keeps_pages_and_state_in_place(topo, program,
             "decode_pallas", "experts_grouped_pallas", "share_combine_local",
             "ssd_step_pallas"]
     else:
-        lowered = cell.lower_prefill(int(program[7:]), 0)
+        width = int(program[7:])
+        rides = rung_rides(eng["max_seq"], ns, width)
+        assert rides is (width >= 2048) and cell.built.takes_riders
+        lowered = cell.lower_prefill(width, 0,
+                                     *(cell.riding() if rides else ()))
         kernels, paths = ["flash_fwd", "grouped_matmul", "local_combine"], [
             "fwd_pallas", "experts_grouped_pallas", "share_combine_local",
             "ssd_chunked"]
+        if rides:
+            kernels += ["paged_decode", "ssd_state_step"]
+            paths += ["decode_pallas", "ssd_step_pallas"]
     text = lowered.as_text()
     assert all(k in text for k in kernels)
     counts = attention.attention_path_counts()
@@ -58,6 +71,7 @@ def nemotron_program_keeps_pages_and_state_in_place(topo, program,
     compiled = lowered.compile()
     stacks = [tuple(params["experts"][w].shape) for w in ("w_up", "w_down")]
     assert not moved_stacks(compiled.as_text(), stacks)
+    assert not copies_of(compiled.as_text(), kc, ssm)
     mem = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize for x in (kc, vc, ssm, window))
     assert held == 2 * 2 * eng["kv_pages"] * 2 * page * 128 * 2 \
