@@ -30,6 +30,10 @@ engine's prefill and its decode step (`serve/engine.py`) all call them.
   conv_mixer         a gated short-convolution layer's whole operator (the
                      LFM2 family), likewise once for a sequence and for one
                      token a slot, its window an argument and a result
+  retention_mixer    a power-retention layer's whole mixer (the Brumby
+                     family): the block's own q, k, v, q/k norm and RoPE, a
+                     gate a kv head, `ops/retention.py`, likewise once for a
+                     prompt and for one token a slot
 
 and the two ways a program that runs no gradient (serving) holds its layer
 stacks differently from training, each so that the compiler reads a layer's
@@ -53,6 +57,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import retention
 from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.ops.norms import layer_norm, rms_norm
 # The module, not its name: tests put an interpreted `step_layer` in its place.
@@ -554,6 +559,51 @@ def conv_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, window=None, *,
             y, window = causal_conv(z, lp["conv_w"], None, window, length)
     with jax.named_scope("conv_out"):
         return x + (c * y.astype(dt)) @ lp["out_proj"].astype(dt), window
+
+
+def retention_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg,
+                    rope: Callable[[jax.Array], jax.Array], state=None, *,
+                    step: bool = False, length=None, layer=None, active=None
+                    ) -> Tuple[jax.Array, ...]:
+    """x + retention(attn_norm(x)) W_o for a power-retention layer
+    (`cfg.mixer` "retention", degree 2; `ops/retention.py` has the
+    equations), under three scopes:
+
+      q, k, v = `attention_inputs` (the block's projections, its q/k norm
+                and RoPE, `rope` handed a tensor in its layout);
+      g = logsigmoid(attn_norm(x) W_g + b_g)  float32, one a kv head  ret_in
+      y = the operator: scores (q . k)^2 / d under the gates' running
+          sums, normalised by their sum                              retention
+      out = x + y W_o                                                  ret_out
+
+    x `[1, S, D]`, one prompt, through `retention.retention_prompt` (rows at
+    and past `length` write nothing to the state) -> (out, S, z), the state a
+    slot keeps of the layer after row `length - 1`. Or, with `step`, x `[ns,
+    D]`, one token a slot, and `state` the slots' WHOLE state pair
+    (`ops/slot_state.py::empty_retention`), of which layer `layer`'s tiles of
+    the slots `active` marks are read, updated and written where they lie in
+    one visit (`slot_state.retention_step_layer`) -> (out, state); an idle
+    slot's output row is not meaningful (and finite)."""
+    dt = cfg.dtype
+    with jax.named_scope("ret_in"):
+        q, k, v = attention_inputs(lp, x, cfg, rope)
+        # (the norm `attention_inputs` took, once more: one computation)
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        gamma = jax.nn.log_sigmoid(
+            (h @ lp["wg"].astype(dt)).astype(jnp.float32)
+            + lp["bg"].astype(jnp.float32))
+    with jax.named_scope("retention"):
+        if step:
+            y, state = slot_state.retention_step_layer(
+                state, layer, active, q, k, v, gamma)
+            y = y.reshape(x.shape[0], -1)
+        else:
+            y, S, z = retention.retention_prompt(q[0], k[0], v[0], gamma[0],
+                                                 length)
+            y = y.transpose(1, 0, 2).reshape(1, x.shape[1], -1)
+    with jax.named_scope("ret_out"):
+        out = x + y.astype(dt) @ lp["wo"].astype(dt)
+    return (out, state) if step else (out, S, z)
 
 
 _QKV = ("wq", "wk", "wv")

@@ -220,6 +220,16 @@ class LlamaConfig:
     block_length: int = 1
     denoise_steps: int = 1
     mask_id: int = 0
+    # What mixes positions in the uniform stack's layers: "attention", or
+    # "retention": power retention of degree `retention_degree` in EVERY
+    # layer (`ops/retention.py`; the Brumby family), the block's q, k, v,
+    # `qk_norm` and RoPE kept, one more projection `wg` `[d_model,
+    # n_kv_heads]` (and a constant `bg` a kv head inside its logsigmoid) for
+    # the gate. Such a model keeps NO K and V: `kv_layers` is 0, nothing is
+    # paged, and a slot holds a state of fixed size a layer
+    # (`state_layers` = n_layers; `ops/slot_state.py::empty_retention`).
+    mixer: str = "attention"
+    retention_degree: int = 2
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -309,6 +319,8 @@ class LlamaConfig:
         if self.ssm_state and self.index_topk:
             raise ValueError("a state-space hybrid has plain attention: no "
                              "indexer")
+        if self.mixer != "attention":
+            self._check_retention()
         if self.block_length > 1:
             self._check_block()
         elif self.block_length < 1 or self.denoise_steps != 1:
@@ -365,9 +377,28 @@ class LlamaConfig:
                              "kernels and the caches take a key in two "
                              "parts)")
 
+    def _check_retention(self) -> None:
+        if self.mixer != "retention":
+            raise ValueError("mixer: 'attention' or 'retention'")
+        if self.latent or self.mixed or self.conv or self.ssm_state \
+                or self.index_topk or self.mrope_section or self.n_experts \
+                or self.multipliers or self.block_length > 1 \
+                or not self.rope:
+            raise ValueError("power retention (mixer 'retention') is served "
+                             "in the uniform dense stack under RoPE: no "
+                             "latent or mixed attention, state-space or "
+                             "short-convolution layers, indexer, mrope, "
+                             "experts, multipliers or generation by blocks")
+        if self.retention_degree != 2:
+            raise ValueError("retention_degree: the expansion this program "
+                             "builds is the degree-2 one (ops/retention.py)")
+        if self.head_dim % 2 or self.n_heads % self.n_kv_heads:
+            raise ValueError("power retention: an even head_dim, and whole "
+                             "groups of query heads a kv head")
+
     def _check_block(self) -> None:
         if self.latent or self.mixed or self.conv or self.ssm_state \
-                or self.index_topk or self.mrope_section \
+                or self.index_topk or self.mrope_section or self.retention \
                 or self.tie_embeddings or self.multipliers or not self.rope:
             raise ValueError("generation by blocks (block_length > 1) is "
                              "served by the uniform stack of plain attention "
@@ -432,9 +463,17 @@ class LlamaConfig:
         return self.n_kv_heads, self.rope_theta, 0, False
 
     @property
+    def retention(self) -> bool:
+        """Power retention in every layer (`mixer`)."""
+        return self.mixer == "retention"
+
+    @property
     def kv_layers(self) -> int:
         """Layers that keep K and V under the block table: the attention
-        layers, of a mixed-attention stack the full-attention ones."""
+        layers, of a mixed-attention stack the full-attention ones; none of
+        a stack of retention layers."""
+        if self.retention:
+            return 0
         if self.mixed:
             return self.attn_pattern.count(0)
         if self.conv:
@@ -505,7 +544,10 @@ class LlamaConfig:
 
     @property
     def state_layers(self) -> int:
-        """Layers that keep a recurrent state a slot: the state-space ones."""
+        """Layers that keep a recurrent state a slot: the state-space ones,
+        or every layer of a stack of retention layers."""
+        if self.retention:
+            return self.n_layers
         if self.layer_parts is not None:
             return self.layer_parts.count("M")
         return self.n_layers - self.kv_layers if self.ssm_state else 0
@@ -937,6 +979,9 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         layers.update({
             "q_norm": ("layers", "head_dim" if per_head else "heads"),
             "k_norm": ("layers", "head_dim" if per_head else "kv_heads")})
+    if cfg.retention:
+        layers.update({"wg": ("layers", "embed", None),
+                       "bg": ("layers", None)})
     if cfg.index_topk:
         layers.update({"wiq": ("layers", "embed", None),
                        "wik": ("layers", "embed", None),
@@ -1063,6 +1108,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     if cfg.layer_parts is not None:
         return _init_parts(cfg, key)
     L, D, H, KVH = cfg.kv_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads
+    if cfg.retention:       # `layers` is the whole stack, and pages nothing
+        L = cfg.n_layers
     hd, F, V = cfg.head_dim, cfg.d_ff, cfg.vocab_size
     pd = cfg.param_dtype
     # One list of keys for every model: a leaf's key is its place in it, so
@@ -1127,6 +1174,19 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
                        "ik_bias": jnp.zeros((L, Id), pd)})
     if cfg.n_shared_experts:    # (a hybrid's: drawn last, as the indexer's)
         layers.update(_shared_expert(cfg, L, ks, norm))
+    if cfg.retention:
+        # The gate's constant: a kv head's half-life ln 2 / -logsigmoid(bg)
+        # log-uniform in 16..4,096 positions (as `_init_mamba` draws
+        # `dt_bias`), so that a state carries a prompt over hundreds of
+        # steps; at 0 the gate is a half and a prompt is gone in ten. Keys of
+        # its own: no other model's weights move.
+        kg, kb = jax.random.split(jax.random.fold_in(key, 2))
+        half = jnp.exp(jax.random.uniform(kb, (L, KVH), jnp.float32,
+                                          math.log(16.0), math.log(4096.0)))
+        keep = jnp.exp(-math.log(2.0) / half)       # the gate: sigmoid(bg)
+        layers.update(wg=norm((L, D, KVH), kg),
+                      bg=(jnp.log(keep) - jnp.log1p(-keep)).astype(
+                          jnp.float32))
     return out
 
 
@@ -1209,7 +1269,13 @@ def _init_mamba(cfg: LlamaConfig, key: jax.Array, norm, ffn: bool = True
 
 
 def param_count(cfg: LlamaConfig) -> int:
+    """The model's weights. A retention layer's `bg` is a constant of the
+    initialisation inside the gate's logsigmoid, no published weight, and is
+    not counted."""
     shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    if cfg.retention:
+        shapes = dict(shapes, layers={k: v for k, v in
+                                      shapes["layers"].items() if k != "bg"})
     # (Python's integers: a stack of experts may pass 2^31 elements)
     return sum(math.prod(l.shape) for l in jax.tree.leaves(shapes))
 
@@ -1363,6 +1429,11 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
             "state-space layers (ssm_state > 0) run through Serve only: the "
             "training forward has no hybrid stack and `ops.ssm`'s kernel no "
             "backward (ROADMAP, Reach)")
+    if cfg.retention:
+        raise NotImplementedError(
+            "power retention (mixer 'retention') runs through Serve only: "
+            "the training forward has no retention layer and "
+            "`ops.retention`'s kernels no backward (ROADMAP, Reach)")
     if cfg.latent:
         raise NotImplementedError(
             "latent attention (kv_lora_rank > 0) runs through Serve only: the "

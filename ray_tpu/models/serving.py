@@ -66,8 +66,9 @@ class Caches(NamedTuple):
     `ic`: the indexer keys' arena of a model with sparse attention, under the
     same block table. `state`: what a model keeps a SLOT, no pages
     (`ops/slot_state.py`): the recurrent state of state-space layers, the
-    rings of window layers, or the windows of short-convolution layers. None
-    where the model has no such cache."""
+    rings of window layers, the windows of short-convolution layers, or the
+    state of power-retention layers (whose model keeps nothing else: `kc`
+    and `vc` are None). None where the model has no such cache."""
     kc: Any = None
     vc: Any = None
     ic: Any = None
@@ -106,6 +107,9 @@ class Programs(NamedTuple):
     adopts: bool
     # Whether a prefill writes per-slot state, so `slot` is passed.
     by_slot: bool
+    # Whether any layer keeps pages under the block table: a request of a
+    # model that has none reserves no page, and the table is read by nobody.
+    paged: bool
     # caches -> an engine's `Books`: what this model alone counts.
     books: Callable[[Caches], "Books"]
     # Positions a slot's step yields: 1, a token a forward; B > 1, a block of
@@ -698,6 +702,54 @@ def _mamba_kind(mcfg) -> _Kind:
                  carries=("state",), counts=_Counts(keeps={"state_writes": 0}))
 
 
+def _retention_kind(mcfg) -> _Kind:
+    """A power-retention layer (`block.retention_mixer`; `mcfg.mixer`
+    "retention") over the dense feed-forward: no K and V and no page, for
+    each slot a state of fixed size (`ops/slot_state.py::empty_retention`),
+    whose layer is the layer's place in the stack. A prompt's rows go through
+    the attention form and leave the state after row `length - 1` (rows past
+    it write nothing: the operator is told `length`); a decode step is handed
+    the slots' WHOLE state and visits the layer's tiles of the active slots
+    where they lie, once. It takes no riders and routes nothing."""
+    S = mcfg.max_seq
+
+    def prefill(lp, x, caches, l, ctx):
+        x, state, norm = block.retention_mixer(
+            lp, x, mcfg, lambda t: norms.apply_rope(t, *ctx["tables"]),
+            length=ctx["length"])
+        x, _ = block.feed_forward(lp, x, mcfg)
+        return x, caches, (state, norm), None
+
+    def decode(lp, x, caches, l, ctx):
+        with jax.named_scope("rope"):
+            w = jnp.minimum(ctx["pos"], S - 1)
+            c, s = (t[w][:, None] for t in ctx["tables"])
+        x, state = block.retention_mixer(
+            lp, x, mcfg, lambda t: _rope_one(t, c, s), caches.state,
+            step=True, layer=l, active=ctx["act"])
+        x, _ = block.feed_forward(lp, x, mcfg, ctx["act"])
+        return x, caches._replace(state=state), None
+
+    # A slot's state, all layers: what one of its decode steps reads, and
+    # writes back.
+    slot_bytes = slot_state.state_bytes(jax.eval_shape(
+        lambda: slot_state.empty_retention(mcfg.n_layers, 1, mcfg.n_kv_heads,
+                                           mcfg.head_dim)))
+
+    def dispatch(pos, active, chunk, plan):
+        steps = int(np.clip(S - pos[active], 0, chunk).sum())
+        return {"state_bytes": 2 * slot_bytes * steps}
+
+    # `state_writes`, as a hybrid's: the admissions that overwrote a slot's
+    # state. `state_bytes_moved`: the bytes of state the decode chunks' steps
+    # read and wrote (a chunk's are its dispatch span's `state_bytes`), three
+    # quarters of a step's traffic at Brumby's widths.
+    return _Kind(prefill, decode, keeps=("retention", "retention"),
+                 carries=("state",), counts=_Counts(
+                     {"state_bytes": "state_bytes_moved"}, dispatch,
+                     {"state_writes": 0}))
+
+
 def _experts_kind(mcfg) -> _Kind:
     """A layer of a stack of one-part layers that is the feed-forward ALONE
     (`block.feed_forward`: its norm, the router, the experts held, the shared
@@ -933,6 +985,15 @@ def _stack(mcfg) -> _Stack:
                     mcfg.ssm_conv, dt, mcfg.ssm_conv_channels)),
             lambda c: {"state_bytes": slot_state.state_bytes(c.state)},
             takes_riders=True, shares=bool(mcfg.experts_held), tally="zero")
+    if mcfg.retention:
+        # Nothing is paged: there is no arena (an arena of no layer would be
+        # arrays of no byte for every program to carry), the block table is
+        # read by no program, and a slot costs the same at every position.
+        return _Stack(
+            {"layers": _retention_kind(mcfg)}, uniform_tables,
+            lambda ns, page, n_pages: Caches(
+                state=slot_state.empty_retention(mcfg.n_layers, ns, KVH, hd)),
+            lambda c: {"state_bytes": slot_state.state_bytes(c.state)})
     if mcfg.conv:
         kind = _conv_kind(mcfg)
         return _Stack(
@@ -1016,6 +1077,8 @@ _KEEP = {
         state=slot_state.write_state(c.state, slot, ssm, conv)),
     "ring": lambda c, pages, slot, length, ks, vs: c._replace(
         state=slot_state.write_window_prompt(c.state, slot, length, ks, vs)),
+    "retention": lambda c, pages, slot, length, S, z: c._replace(
+        state=slot_state.write_retention(c.state, slot, S, z)),
 }
 
 
@@ -1177,7 +1240,7 @@ def _prefill_walk(mcfg, stack: _Stack):
         # rest after, as the pinned programs have it.)
         out = joined("pages", "ring")
         logits = (logits[0] if riders is None else logits).astype(jnp.float32)
-        out.update(joined("index", "state"))
+        out.update(joined("index", "state", "retention"))
         return first, out, logits, experts if sparse else None, caches
 
     return walk
@@ -1260,8 +1323,9 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         # The pages first: the sampler follows that write. The prompt's row
         # and the riders' go through ONE sampler, each row at its own
         # temperature, key and position, as `_step` samples.
-        caches = _keep_pages(caches, pages, slot, length,
-                             *kept.pop("pages"))
+        if "pages" in kept:     # (a stack of retention layers pages nothing)
+            caches = _keep_pages(caches, pages, slot, length,
+                                 *kept.pop("pages"))
         toks = sample_tokens(logits[None] if riders is None else logits,
                              rows(temp, temps), rows(topk, topks),
                              rows(key, keys), rows(length - 1, at))
@@ -1526,8 +1590,9 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         adopt=jax.jit(adopt, donate_argnums=(0,)),
         poke=jax.jit(poke, donate_argnums=(0, 1)),
         takes_riders=stack.takes_riders, adopts=stack.adopts,
-        by_slot=any(cache in ("state", "ring") for kind in
+        by_slot=any(cache in ("state", "ring", "retention") for kind in
                     stack.kinds.values() for cache in kind.keeps),
+        paged=mcfg.kv_layers > 0,
         books=lambda caches: Books(mcfg, counts, stack.shares,
                                    stack.cache_bytes(caches)),
         block=B)

@@ -42,6 +42,12 @@ context), and every op here passes the None through.
     `step_layer`'s kernel aliases the state it is handed. Nothing outside
     this module indexes it.
 
+A stack of POWER-RETENTION layers (`LlamaConfig.mixer` "retention") keeps
+NOTHING but such a state, and a far larger one: a pair `(S, z)` of
+`empty_retention`, `[n_layers, n_slots, kv_heads, d / 2 + 1, d(, d)]`
+float32 (34.08 MB a slot a layer at 8 kv heads of 128), `write_retention` a
+prefill's write, `retention_step_layer` a decode step's one visit.
+
 A WINDOW layer's cache is the other thing that has no pages (a model of
 window and full attention layers, `LlamaConfig.attn_pattern`): a query sees
 its own position and the `window - 1` before it, so a slot keeps a RING of
@@ -75,6 +81,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import attention
+from ray_tpu.ops import retention as retention_ops
 from ray_tpu.ops import ssm as ssm_ops
 from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
 from ray_tpu.ops.paged_kv import _lanes, _to_width
@@ -156,6 +163,61 @@ def step_layer(state: State, layer, active, x, dt, A, B, C, D, *,
     y, rows = ssm_ops.ssd_step(x, dt, A, B, C, D, ssm[layer])
     return jnp.where(active[:, None], y, 0.0), \
         update_layer(state, layer, active, rows, None)
+
+
+# ---------------------------------------------------------------------------
+# A retention layer's state
+# ---------------------------------------------------------------------------
+
+def empty_retention(n_layers: int, n_slots: int, kv_heads: int,
+                    head_dim: int) -> State:
+    """-> (S, z), zeroed, of a stack of power-retention layers: for each
+    layer, slot and kv head the state `S [d / 2 + 1, d, d]` float32 (the
+    expansion's layout: `ops/retention.py`; 4.26 MB at d = 128, 34.08 MB a
+    slot a layer over 8 kv heads, whatever the context) and its normaliser
+    `z [d / 2 + 1, d]`, in rows up to whole tiles
+    (`ops.retention.z_rows`). The slot is the second axis of both."""
+    return tuple(jnp.zeros(shape, jnp.float32) for shape in
+                 retention_ops.state_shapes(n_layers, n_slots, kv_heads,
+                                            head_dim))
+
+
+def write_retention(state: State, slot, S_rows, z_rows) -> State:
+    """A prefill's result into slot `slot` (a traced scalar), all layers at
+    once, the whole of what its previous tenant left overwritten: `S_rows`
+    `[n_layers, KVH, NB, d, d]`, `z_rows` `[n_layers, KVH, rows, d]`."""
+    S, z = state
+    with jax.named_scope("state_write"):
+        return (S.at[:, slot].set(S_rows.astype(S.dtype)),
+                z.at[:, slot].set(z_rows.astype(z.dtype)))
+
+
+def retention_step_layer(state: State, layer, active, q, k, v, gamma, *,
+                         interpret: bool = False
+                         ) -> Tuple[jax.Array, State]:
+    """A retention layer's decode step, one token a slot, on the slots'
+    whole state in ONE visit (`ops.retention.retention_step`'s arguments) ->
+    (y `[n_slots, H, d]` float32, zeros for an idle slot; the state, whose
+    idle slots' tiles and other layers stay what they were). On a TPU (or
+    with `interpret`) `ops.retention.retention_state_step`, which crosses
+    each active slot's tiles once, where they lie; elsewhere `retention_step`
+    on the layer's rows and their write back under a select. Counted at
+    trace time as `retention_step_pallas` / `retention_step_reference`."""
+    S, z = state
+    use = interpret or (attention._on_tpu()
+                        and retention_ops.kernel_tiles(q.shape[-1]))
+    attention._path_counts[
+        "retention_step_pallas" if use else "retention_step_reference"] += 1
+    if use:
+        y, S, z = retention_ops.retention_state_step(
+            S, z, layer, active, q, k, v, gamma, interpret=interpret)
+        return y, (S, z)
+    y, S_rows, z_rows = retention_ops.retention_step(S[layer], z[layer], q,
+                                                     k, v, gamma)
+    keep = active[:, None, None, None]
+    return jnp.where(active[:, None, None], y, 0.0), (
+        S.at[layer].set(jnp.where(keep[..., None], S_rows, S[layer])),
+        z.at[layer].set(jnp.where(keep, z_rows, z[layer])))
 
 
 # ---------------------------------------------------------------------------

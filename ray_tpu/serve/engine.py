@@ -306,6 +306,11 @@ class Engine:
         # cache read at the first request is a compilation inside the loop.
         self._warm = {self.buckets[0]} | {
             b for b in self.buckets if 2 * b > mcfg.max_seq}
+        if not self._scratch_fits():
+            # No room for the thread's scratch caches (a model whose slots
+            # hold gigabytes of state): every bucket warms here, against the
+            # live ones, before the loop starts.
+            self._warm = set(self.buckets)
         for width in sorted(self._warm):
             self._caches, first = self._warm_width(self._caches, width)
         with tracing.compile_span("serve.engine.warm", program="decode",
@@ -346,6 +351,18 @@ class Engine:
                 target=self._warm_buckets, args=(middles,), daemon=True,
                 name="llm-bucket-warm")
             self._warm_thread.start()
+
+    def _scratch_fits(self) -> bool:
+        """Whether a second set of caches, which `_warm_buckets` holds while
+        it warms, fits on the device beside what is there now (the weights
+        and the live caches), by the device's own count of its memory; a
+        device that keeps none (the CPU) is taken to have the room."""
+        import jax
+        stats = jax.local_devices()[0].memory_stats() or {}
+        if not stats.get("bytes_limit"):
+            return True
+        scratch = sum(leaf.nbytes for leaf in jax.tree.leaves(self._caches))
+        return stats["bytes_in_use"] + scratch <= stats["bytes_limit"]
 
     def _slots_last(self):
         """The slots' `last` as the programs take it, zeroed."""
@@ -510,6 +527,7 @@ class Engine:
                 "attention's two caches (attn_pattern: pages and window "
                 "rings) nor a short-convolution layer's window (conv_layers)"
                 " nor a block that a prompt's tail opens (block_length > 1)"
+                " nor a power-retention layer's state (mixer 'retention')"
                 ": this model serves from one engine")
         if not self._adopt_widths:
             raise RuntimeError(
@@ -608,7 +626,10 @@ class Engine:
             slot = next((i for i in range(self.n_slots)
                          if not self._active[i]
                          and self._slot_req[i] is None), None)
-            need = self.pool.pages_for(len(req.ids), req.max_tokens)
+            # (a model that pages nothing reserves nothing: a free slot is
+            # all its request waits for)
+            need = self.pool.pages_for(len(req.ids), req.max_tokens) \
+                if self._programs.paged else 0
             if slot is None or self.pool.free < need:
                 break  # head-of-line waits for a finish
 
@@ -949,7 +970,8 @@ class Engine:
             # is still queued — an aliased buffer would let those mutations
             # reach into the in-flight computation.
             useful = sum(p[2] for p in plan)
-            live_kv = int(self._pos[self._active].sum())
+            live_kv = int(self._pos[self._active].sum()) \
+                if self._programs.paged else 0
             # Live slots that ask for a sample: with none, the chunk's steps
             # take their argmax alone (`serving.sample_tokens`).
             sampling = int((self._active & (self._temp > 0)).sum())

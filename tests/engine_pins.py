@@ -36,6 +36,7 @@ MODEL = {
     "sdar": _ROUTING | {"block", "denoise_forwards", "commits_rode",
                         "block_tokens", "tail_tokens"},
     "nemotron_h": _SHARE | {"state_bytes", "state_writes"},
+    "brumby": {"state_bytes", "state_writes", "state_bytes_moved"},
 }
 
 

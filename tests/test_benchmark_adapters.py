@@ -29,7 +29,7 @@ from benchmark import models, program_trace  # noqa: E402
 from benchmark.tests import test_benchmark as cases  # noqa: E402
 
 ARCHS = ["llama", "olmoe", "keye", "jamba", "dots", "mimo", "lfm2",
-         "granitemoehybrid", "sdar", "nemotron_h"]
+         "granitemoehybrid", "sdar", "nemotron_h", "brumby"]
 # config.json of allenai/OLMoE-1B-7B-0125-Instruct, as the catalog beside the
 # model-configs guide has it.
 OLMOE_PUBLISHED = dict(
