@@ -327,7 +327,7 @@ def _slot_conv(lp, rows, window):
     `[ns, C]`), its window (`[K - 1, ns, C]`) its own -> (the rows, float32;
     the windows moved on a row)."""
     rows, window = jax.vmap(causal_conv, (0, None, None, 1), (0, 1))(
-        rows[:, None], lp["conv_w"], lp["conv_b"], window)
+        rows[:, None], lp["conv_w"], lp.get("conv_b"), window)
     return rows[:, 0], window
 
 
@@ -527,8 +527,8 @@ def mamba2_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, state=None,
 
 
 def conv_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, window=None, *,
-               step: bool = False, length=None
-               ) -> Tuple[jax.Array, jax.Array]:
+               step: bool = False, length=None, riders=None, layer=None,
+               active=None) -> Tuple[jax.Array, ...]:
     """x + operator(norm(x)) for a gated short-convolution layer, the LFM2
     family's (`cfg.conv_layers`):
 
@@ -543,22 +543,44 @@ def conv_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg, window=None, *,
     row `length` (S if None), so a bucket's padding never enters it. Or, with
     `step`, x `[ns, D]`, one token a slot, from `window` `[K - 1, ns, D]`
     (`ops/slot_state.py`'s layout). The convolution is `ops.ssm.causal_conv`,
-    Jamba's, without its bias. -> (out, window)."""
+    Jamba's, without its bias. -> (out, window).
+
+    RIDERS, as `mamba_mixer`'s: a sequence whose LAST ns rows (past `length`)
+    are one token a slot, `riders` the slots' whole state (`ops/slot_state.py`'s
+    pair, no recurrent part), of which layer `layer`'s window of the slots
+    `active` `[ns]` marks is read and written. `conv_in` and `conv_out` run
+    once over all the rows; between them a riding slot's row of z goes through
+    `_slot_conv` against its own window, the step's path, and the sequence's
+    rows through the sequence's, which is told `length`, so neither reaches
+    the other. The riders' rows ride into the product `C * c` (what `W_out`
+    reads, written out anyway), not into the convolution's float32 sums
+    (`_ride`). -> (out, window, the slots' state)."""
     dt = cfg.dtype
+    if riders is not None:
+        ns = active.shape[0]
+        # As in a decode step, the window's read and its write back are the
+        # convolution's traffic, under the scope that times it.
+        with jax.named_scope("conv"):
+            _, slot_window = slot_state.layer_state(riders, layer)
     with jax.named_scope("conv_in"):
         h = rms_norm(x, lp["norm"], cfg.norm_eps)
         b, c, u = jnp.split(h @ lp["in_proj"].astype(dt), 3, axis=-1)
         z = b * u
     with jax.named_scope("conv"):
-        if step:    # each slot a sequence of one row, its window its own
-            y, window = jax.vmap(
-                lambda row, win: causal_conv(row, lp["conv_w"], None, win),
-                (0, 1), (0, 1))(z[:, None], window)
-            y = y[:, 0]
+        if step:
+            y, window = _slot_conv(lp, z, window)
         else:
             y, window = causal_conv(z, lp["conv_w"], None, window, length)
+        if riders is not None:
+            rode, slot_window = _slot_conv(lp, z[-ns:], slot_window)
+            riders = slot_state.update_layer(riders, layer, active, None,
+                                             slot_window)
     with jax.named_scope("conv_out"):
-        return x + (c * y.astype(dt)) @ lp["out_proj"].astype(dt), window
+        y = c * y.astype(dt)
+        if riders is not None:
+            y = _ride(y, c[-ns:] * rode.astype(dt), active)
+        out = x + y @ lp["out_proj"].astype(dt)
+        return (out, window) + (() if riders is None else (riders,))
 
 
 def retention_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg,

@@ -101,7 +101,10 @@ class Programs(NamedTuple):
     decode: Callable
     adopt: Callable
     poke: Callable
-    # Whether the prefill program of a riding rung carries the live slots.
+    # Whether the prefill program of a riding rung carries the live slots
+    # (`_stack`'s answer: a dense, a sparse, a state-space and a conv stack do;
+    # an indexed, a latent, a mixed, a retention stack and one that generates
+    # by blocks take nobody).
     takes_riders: bool
     # Whether a hand-off's K and V can be adopted (`adopts`).
     adopts: bool
@@ -629,6 +632,17 @@ def _attention_kind(mcfg, ridden: bool, **how) -> _Kind:
                  if indexed else None, **how)
 
 
+def _mixer_riders(ctx, caches, layer):
+    """What a mixer of `models/block.py` is handed beside a prompt's rows
+    under `ctx["riders"]` (nothing without them): ONE decode step of the
+    riding slots in the bucket's tail rows, from and to their own state of
+    layer `layer`, which rides the scan's carry; the layer's weights are read
+    once, for the prompt and for them."""
+    if not ctx["riders"]:
+        return {}
+    return dict(riders=caches.state, layer=layer, active=ctx["riders"][2])
+
+
 def _mamba_kind(mcfg) -> _Kind:
     """A hybrid's state-space layer (`block.mamba_mixer`, or Mamba-2's
     `block.mamba2_mixer` where the model has `ssm_heads`) over a dense or a
@@ -650,15 +664,8 @@ def _mamba_kind(mcfg) -> _Kind:
 
     def prefill(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
-        rides = {}
-        if ctx["riders"]:
-            # ONE decode step of the riding slots in the bucket's tail rows,
-            # from and to their own state, which rides the scan's carry; the
-            # layer's weights are read once, for the prompt and for them.
-            rides = dict(riders=caches.state, layer=l,
-                         active=ctx["riders"][2])
         y, state, window, *rode = mixer(lp, x[0], mcfg, length=ctx["length"],
-                                        **rides)
+                                        **_mixer_riders(ctx, caches, l))
         if rode:
             caches = caches._replace(state=rode[0])
         if alone:
@@ -768,17 +775,27 @@ def _experts_kind(mcfg) -> _Kind:
     return _Kind(prefill, decode, keeps=(), over="index", carries=())
 
 
-def _conv_kind(mcfg) -> _Kind:
+def _conv_kind(mcfg, first: int) -> _Kind:
     """A gated short-convolution layer (`block.conv_mixer`; the LFM2 family)
     over a dense or a sparse feed-forward: no K and V, for each slot the
     convolution's window and nothing else (`ops/slot_state.py`: a state with
     no recurrent part), whose layer is the layer's ordinal among ALL the conv
     layers, the leading dense ones first. Rows past `length` reach no real
     row: the convolution is causal, and the operator is told `length`. A
-    sparse layer hands back its routing counts."""
+    sparse layer hands back its routing counts. Its prefill takes RIDERS
+    (`conv_mixer(riders=)`): the tail rows' convolution against each riding
+    slot's own window, written back as a decode step writes it (under the
+    operator's `conv` scope), between projections and a feed-forward that
+    run once over the bucket; the step is the one `decode` runs, written once
+    in the operator. `first`: the ordinal among all the conv layers of this
+    kind's layer 0 (what the decode walk's `ctx["base"]` comes to)."""
     def prefill(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
-        y, window = block.conv_mixer(lp, x[0], mcfg, length=ctx["length"])
+        rides = _mixer_riders(ctx, caches, first + l)
+        y, window, *rode = block.conv_mixer(lp, x[0], mcfg,
+                                            length=ctx["length"], **rides)
+        if rode:
+            caches = caches._replace(state=rode[0])
         y, routed = block.feed_forward(lp, y[None], mcfg, ctx["live"],
                                        l if routed_layer else None)
         return y, caches, (None, window), \
@@ -786,7 +803,7 @@ def _conv_kind(mcfg) -> _Kind:
 
     def decode(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
-        state, at = caches.state, ctx["base"] + l
+        state, at = caches.state, first + l
         # The window's read and its write back are the convolution's
         # traffic: under the scope that times it.
         with jax.named_scope("conv"):
@@ -995,10 +1012,11 @@ def _stack(mcfg) -> _Stack:
                 state=slot_state.empty_retention(mcfg.n_layers, ns, KVH, hd)),
             lambda c: {"state_bytes": slot_state.state_bytes(c.state)})
     if mcfg.conv:
-        kind = _conv_kind(mcfg)
         return _Stack(
-            {"dense": kind, "conv": kind,
-             "layers": _attention_kind(mcfg, False, over="index",
+            # (the leading dense layers are conv layers: the windows' first)
+            {"dense": _conv_kind(mcfg, 0),
+             "conv": _conv_kind(mcfg, mcfg.first_dense),
+             "layers": _attention_kind(mcfg, True, over="index",
                                        carries=("kc", "vc"))},
             uniform_tables,
             # pages for the attention layers, a window a slot for the rest
@@ -1007,7 +1025,7 @@ def _stack(mcfg) -> _Stack:
                 state=slot_state.empty_state(unpaged, ns, 0, mcfg.d_model,
                                              mcfg.conv_taps, dt)),
             lambda c: {"conv_state_bytes": slot_state.state_bytes(c.state)},
-            tally="zero")
+            takes_riders=True, tally="zero")
     if mcfg.latent:
         kind = _latent_kind(mcfg)
         return _Stack(
@@ -1143,7 +1161,9 @@ def _prefill_walk(mcfg, stack: _Stack):
     the `paged_decode` kernel against the arena, which rides the scan's carry
     as it does in decode) and the result takes the tail of the flash output's
     place; a state-space layer's tail rows take the mixer's step from and to
-    the slots' own state, in the same carry (`_mamba_kind`); the projections,
+    the slots' own state, in the same carry (`_mamba_kind`), a
+    short-convolution layer's the operator's from and to the slots' own
+    windows (`_conv_kind`); the projections,
     the feed-forward (a one-part stack's expert layers: `live` holds the
     riders) and the head run over the bucket as they do anyway, so the
     step's weight reads are the prefill's. Then logits is [1 +

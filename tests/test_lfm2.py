@@ -33,8 +33,9 @@ import jax.numpy as jnp
 from benchmark import models, reference_lfm2
 from ray_tpu.models import block, llama, serving
 from ray_tpu.models.block import fuse_qkv
-from ray_tpu.ops import attention, moe, paged_kv
+from ray_tpu.ops import attention, moe, paged_kv, slot_state
 from ray_tpu.serve.engine import Engine
+import mixer_riders
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 LOGIT_TOL = 2e-4
@@ -197,6 +198,37 @@ def test_the_conv_operators_prompt_form_is_its_step_form_token_by_token(tiny):
     with jax.default_matmul_precision("highest"):
         ref = x + reference_lfm2.conv_operator(u, lp, {"conv_L_cache": 3})
     assert np.abs(np.asarray(whole - ref)).max() < 1e-5 * scale
+
+
+def test_riders_in_a_prompts_tail_rows_take_a_step_and_leave_the_prompt_alone(
+        tiny):
+    """`conv_mixer(riders=)`: tests/mixer_riders.py says what is held, of a
+    state with no recurrent part. The step alone is `step=True` on the layer's
+    windows and their write back, as `models/serving.py::_conv_kind`'s decode
+    body has it."""
+    _, _, cfg, params = tiny
+    lp = jax.tree.map(lambda w: w[1], params["conv"])
+
+    def step(x, slots, layer, active):
+        out, window = block.conv_mixer(
+            lp, x, cfg, slot_state.layer_state(slots, layer)[1], step=True)
+        return out, slot_state.update_layer(slots, layer, active, None,
+                                            window)
+
+    empty = slot_state.empty_state(
+        mixer_riders.LAYERS, mixer_riders.SLOTS, 0, cfg.d_model,
+        cfg.conv_taps, jnp.float32)
+    tol = 1e-3      # rows of ~100 at 1e-5, as the test above
+    mixer_riders.check(block.conv_mixer, lp, cfg, step, tol, empty)
+
+    def deaf(lp, x, cfg, riders=None, layer=None, active=None, **kw):
+        """An operator that ignores its riders: the prompt's own rows and
+        the slots' windows handed back as they came."""
+        return block.conv_mixer(lp, x, cfg, **kw) + (
+            () if riders is None else (riders,))
+
+    with pytest.raises(AssertionError):     # teeth: the check says so
+        mixer_riders.check(deaf, lp, cfg, step, tol, empty)
 
 
 def test_top_k_routing_with_the_published_eps_is_a_plain_transcription():

@@ -10,6 +10,7 @@ worker).
 """
 
 import os
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -21,7 +22,7 @@ from benchmark import models, reference_lfm2
 from ray_tpu.models import llama, serving
 from ray_tpu.models.block import fuse_qkv
 from ray_tpu.ops import attention
-from engine_pins import pinned
+from engine_pins import Spans, pinned
 from test_lfm2 import LOGIT_TOL, ROOT, _drain, _engine, _tokens, tiny
 
 
@@ -120,6 +121,44 @@ def test_prefill_then_decode_through_the_caches_is_the_reference(
     assert engine._slot_req == [None, None]
 
 
+def test_a_burst_rides_the_prefills_through_the_kernel_and_is_the_riderless_engines(
+        tiny, engine):
+    """Three requests AT ONCE on the two slots (their rungs, 128, ride; their
+    pages, 6 + 7 then 5 of the pool's 16, fit): the second's prefill carries
+    the first's slot a step, the third waits for the second's slot and
+    carries the first's again; the riders' `_token_step` runs
+    the `paged_decode` kernel (interpreted) over pages of two heads of 64 to
+    a row, their windows move in the prefill's carry. Every stream is token
+    for token what the same engine serves with nobody riding, and the plain
+    reference's greedy continuation; the admit spans' `riders` add up to the
+    counters."""
+    _, model, _, params = tiny
+    asks = [(70, 20), (100, 8), (66, 6)]
+    prompts = [_tokens(n, 40 + n) for n, _ in asks]
+
+    def serve():
+        streams = [engine.submit(p, m) for p, (_, m) in zip(prompts, asks)]
+        return [_drain(q) for q in streams]
+
+    before = engine.counters()
+    with Spans() as spans:
+        riding = serve()
+    after = engine.counters()
+    with mock.patch.object(engine, "_ride_plan", lambda free_rows: []):
+        plain = serve()
+    assert engine.counters()["rider_tokens"] == after["rider_tokens"]
+    assert [len(s) for s in riding] == [m for _, m in asks]
+    assert riding == plain
+    rode = [a["riders"] for a in spans.named("serve.engine.admit")]
+    assert rode == [0, 1, 1]
+    assert sum(rode) == after["rider_tokens"] - before["rider_tokens"]
+    assert sum(map(bool, rode)) == after["rider_steps"] - before["rider_steps"]
+    for prompt, served in zip(prompts, riding):
+        gaps = reference_lfm2.served_token_gaps(params, model, prompt, served)
+        assert max(gaps) < LOGIT_TOL, gaps
+    assert engine._slot_req == [None, None]
+
+
 def test_a_chunks_routing_counts_are_the_references(tiny):
     """The decode program's `experts` of one chunk of 4 steps over one live
     slot of two: tokens per expert of the 4 rows the steps fed, summed over
@@ -170,7 +209,8 @@ def test_the_engine_took_the_paths_and_keeps_two_shapes_of_cache(tiny,
     assert len(c["expert_tokens"]) == cfg.n_experts
     assert sum(c["expert_tokens"]) > 0 and c["decode_experts_touched"] > 0
     assert engine.pool.pages_for(100, 40) == 9      # positions, not layers
-    assert not engine._programs.takes_riders and engine._programs.by_slot
+    assert engine._programs.takes_riders and engine._programs.by_slot
+    assert [w for w in engine.buckets if engine._rides(w)] == [128, 256]
 
 
 def test_the_cast_of_the_experts_asks_the_stacks_and_never_an_array(tiny):

@@ -23,6 +23,7 @@ from ray_tpu.models.serving import Caches, prefill_core
 from ray_tpu.serve.engine import Engine
 from engine_pins import GENERIC, MODEL
 from test_dots import PUBLISHED
+from test_lfm2 import PUBLISHED as LFM2
 from test_mimo import PUBLISHED as MIMO
 from test_prefill_ladder import F32, KINDS
 from test_serve_llm import parents_sample_tokens
@@ -45,6 +46,13 @@ from test_serve_llm import parents_sample_tokens
 # through `_token_step` as well, was taken anew on PR 58's tree (the
 # parent's: 98e6e614b5625848, which the tree still lowers to with the
 # attention kind built `ridden=False`: the mixers' steps did not move).
+# Since PR 60 the conv stack (LFM2's short-convolution layers beside
+# attention) is pinned here and takes riders too: its 32 rung is the text of
+# PR 60's parent (0ed315d), and its decode program, whose two attention layers
+# now go through `_token_step` as every riding stack's do, was taken anew on
+# PR 60's tree (the parent's: bd26e37b38f9f1e5, which the tree still lowers to
+# with the attention kind built `ridden=False`: the operator's step, moved
+# into `block._slot_conv`, is the same text).
 # `mixed` (PR 42's stack, which takes nobody) was taken on PR 45's parent
 # (6c2c097), before that PR moved a line under ray_tpu/.
 # Since PR 47 `serving.sample_tokens` takes its top-k behind a conditional, so
@@ -53,6 +61,8 @@ from test_serve_llm import parents_sample_tokens
 # (7f64f96; `test_serve_llm.parents_sample_tokens`, which the sampler is held
 # to token for token there) in its place, and every digest stands unmoved.
 PARENT_PROGRAMS = {
+    "conv.decode": "e95d7ce3f111b0ea",
+    "conv.prefill32": "ff719c8f70fb6d00",
     "dense.decode": "d87712c9b4ee5285",
     "dense.prefill32": "c948937b09fe2fee",
     "hybrid.decode": "112c069064bd2652",
@@ -72,7 +82,8 @@ PARENT_PROGRAMS = {
 # of the five stacks at their adapters' rehearsal widths, `max_seq` 128 and
 # two slots, on PR 41's parent (5481b82; jax 0.9.0 on the CPU: no Mosaic
 # payload, no source locations in the text). The rungs 64 and 128 of the dense
-# and the sparse stack ride since PR 41, the hybrid's since PR 58:
+# and the sparse stack ride since PR 41, the hybrid's since PR 58, the conv
+# stack's (every rung taken on PR 60's parent, 0ed315d) since PR 60:
 # `PARENT_RIDING` below;
 # `PARENT_PROGRAMS` above pins the decode programs. `mixed` (PR 42's stack) was
 # taken on PR 45's parent (6c2c097). All three tables stand since PR 47 with
@@ -85,6 +96,7 @@ PARENT_RUNGS = {
     "indexed": {32: "bfe2a2df64e53893", 64: "2b26fc68f7f5f898",
                 128: "69ca4b8800557a63"},
     "hybrid": {32: "bf109118a1785278"},
+    "conv": {32: "ff719c8f70fb6d00"},
     "latent": {32: "8da32aa0051287f3", 64: "f96e02f0c080c3fb",
                128: "4b487bf21d58472e"},
     "mixed": {32: "7ce5961c0ca08aab", 64: "ef4db6b4bc528b78",
@@ -95,19 +107,25 @@ PARENT_RIDERLESS = {
     "dense": {64: "d5061fe7c8b0f160", 128: "0f8a98c45565c6ea"},
     "sparse": {64: "01d0cbc9e60958cc", 128: "6ea775ec4038bec1"},
     "hybrid": {64: "b6847a6dfe909d84", 128: "4dd7ed9434604dd1"},
+    "conv": {64: "0d6746047675a641", 128: "e080b12a6d548004"},
 }
 # What the riding rungs lower to with the riders' shapes as `_place` passes
 # them (`Engine.lowered_prefill_text`): the programs `serve-batch` and
 # `serve-batch-olmoe` spend their prefill time in, on PR 45's parent
 # (6c2c097); the hybrid's (a Mamba-1 layer's step in the mixer's tail rows,
 # `block.mamba_mixer(riders=)`: the programs `serve-batch-jamba2` spends its
-# prefill time in) taken on PR 58's tree, which made them.
+# prefill time in) taken on PR 58's tree, which made them; the conv stack's
+# (the operator's step in its tail rows, `block.conv_mixer(riders=)`, and
+# `_token_step` in its two attention layers': the programs
+# `serve-generate-lfm2` spends 0.58 of its prefills in) on PR 60's.
 PARENT_RIDING = {
     "dense": {64: "8de5c32ccafe6475", 128: "3a4649dd358ebc16"},
     "sparse": {64: "c033be69300aac6b", 128: "c2b37a166224c151"},
     "hybrid": {64: "a87192cc5a56bac3", 128: "f0b6c2f60d1080fc"},
+    "conv": {64: "5aef5bb379a666c0", 128: "892c8e058afa9478"},
 }
-STACKS = dict(KINDS, latent=("dots", PUBLISHED), mixed=("mimo", MIMO))
+STACKS = dict(KINDS, latent=("dots", PUBLISHED), mixed=("mimo", MIMO),
+              conv=("lfm2", LFM2))
 
 
 def _sha(text):
@@ -196,10 +214,10 @@ def _train_step_digest():
 
 
 @pytest.mark.parametrize("kind", ["dense", "sparse", "indexed", "hybrid",
-                                  "latent", "mixed", "train"])
+                                  "latent", "mixed", "conv", "train"])
 def test_the_other_models_programs_are_the_parents(kind):
     """What a dense, a sparse (softmax router, every expert), an indexed, a
-    hybrid, a latent and a mixed engine's prefill (a rung that takes no
+    hybrid, a latent, a mixed and a conv engine's prefill (a rung that takes no
     riders) and decode, and a dense train step, lower to is letter for letter
     what the parent commit lowers them to."""
     if kind == "train":
@@ -214,14 +232,15 @@ def test_the_other_models_programs_are_the_parents(kind):
 
 @pytest.mark.parametrize("kind", sorted(PARENT_RUNGS))
 def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
-    """A dense, a sparse and a hybrid stack's programs of the octave under
-    `max_seq` take riders and hold a decode step's attention (a hybrid's its
-    state-space layers' step too); their narrow rungs, and every rung of an
+    """A dense, a sparse, a hybrid and a conv stack's programs of the octave
+    under `max_seq` take riders and hold a decode step's attention (a
+    hybrid's its state-space layers' step too, a conv stack's its
+    short-convolution layers'); their narrow rungs, and every rung of an
     indexed, a latent and a mixed stack, take nobody and lower to the
     parent's text, letter for letter. Asked of the built program; no option,
     field or environment variable has a say."""
     takes, riding, got, _, _ = _programs(kind)
-    assert takes is (kind in ("dense", "sparse", "hybrid"))
+    assert takes is (kind in ("dense", "sparse", "hybrid", "conv"))
     assert riding == ([64, 128] if takes else [])
     assert {w: d for w, d in got.items()
             if w not in riding} == PARENT_RUNGS[kind]
@@ -231,8 +250,9 @@ def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
 
 @pytest.mark.parametrize("kind", sorted(PARENT_RIDING))
 def test_the_riding_rungs_are_the_parents(kind):
-    """The riding rungs of a dense, a sparse and a hybrid stack, lowered
-    with the riders' shapes as `_place` passes them, are the pinned text."""
+    """The riding rungs of a dense, a sparse, a hybrid and a conv stack,
+    lowered with the riders' shapes as `_place` passes them, are the pinned
+    text."""
     _, riding, got, _, _ = _programs(kind)
     assert {w: got[w] for w in riding} == PARENT_RIDING[kind]
 

@@ -4,13 +4,14 @@ prompt leaves `n_slots` rows of its bucket free, a riding rung's program
 live slot in those rows. On the CPU at the adapters' rehearsal widths in
 float32, a dense and a sparse stack and, since PR 58, the three hybrids
 (Mamba-1 over a dense feed-forward; Mamba-2, one group, over a share of the
-experts; the stack of one-part layers, Mamba-2 with groups): every stream is
+experts; the stack of one-part layers, Mamba-2 with groups) and, since PR 60,
+LFM2's stack of short-convolution layers beside attention: every stream is
 what the same engine serves with nobody riding, and the plain reference's
 greedy tokens; the counters and the admit spans agree; a burst of admissions
 moves the riders a step each; a rider that finishes on a riding step frees its
-slot, its pages and its slot's recurrent state at once, and the next admission
-overwrites them. (An indexed, a latent, a mixed and a conv stack take nobody,
-and every program that takes nobody lowers to the parent's text:
+slot, its pages and its slot's recurrent state (or windows) at once, and the
+next admission overwrites them. (An indexed, a latent and a mixed stack take
+nobody, and every program that takes nobody lowers to the parent's text:
 tests/test_parents_programs.py.)
 
 Tolerance: program and reference compute the same mathematics in float32 and
@@ -29,6 +30,7 @@ import jax.numpy as jnp
 from ray_tpu.serve.engine import (_DEPTH, Engine, prefill_widths,
                                   rung_rides)
 import test_granite
+import test_lfm2
 import test_nemotron_h
 from engine_pins import Spans as _Spans
 from test_prefill_ladder import F32, LOGIT_TOL, _tiny, _tokens
@@ -59,10 +61,14 @@ def _drain(q, seconds=120.0):
 # dense feed-forward), each as its own model's tests build it: the adapter's
 # rehearsal widths, weights that decide.
 HYBRIDS = {"mamba2": test_granite, "one-part": test_nemotron_h}
-STACKS = ["dense", "sparse", "hybrid", *HYBRIDS]
+# ... and, since PR 60, the stack of short-convolution layers beside attention
+# (LFM2: a slot keeps a window a conv layer and pages for the rest).
+STACKS = ["dense", "sparse", "hybrid", *HYBRIDS, "conv"]
 
 
 def _model(kind):
+    if kind == "conv":
+        return test_lfm2._tiny(max_seq=MAX_SEQ)
     if kind not in HYBRIDS:
         return _tiny(kind, MAX_SEQ)
     tests = HYBRIDS[kind]
@@ -186,10 +192,11 @@ def test_the_manifest_entry_of_the_riders_share():
         workloads=["serve-batch", "serve-batch-olmoe"])
 
 
-@pytest.fixture(scope="module", params=["dense", "hybrid"])
+@pytest.fixture(scope="module", params=["dense", "hybrid", "conv"])
 def held(request):
     """An engine of three slots (a dense stack's; a hybrid's, whose slots hold
-    a recurrent state too) whose emitter the test holds at its first chunk, so
+    a recurrent state too; a conv stack's, whose slots hold a window a conv
+    layer) whose emitter the test holds at its first chunk, so
     that the loop stands with `_DEPTH` chunks in flight and nothing moves but
     what the test submits."""
     _, _, _, eng = _build(request.param, n_slots=3)

@@ -5,13 +5,14 @@ why)."""
 import jax
 import pytest
 
-from compile_for_v5e import described_cell, moved_stacks
+from compile_for_v5e import copies_of, described_cell, moved_stacks
 from ray_tpu.ops import attention
+from ray_tpu.serve.engine import rung_rides
 
 pytestmark = pytest.mark.usefixtures("_no_compile_cache")
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "riding"])
 def test_lfm2_programs_keep_pages_and_windows_in_place_on_v5e(
         topo, program, monkeypatch):
     """The stack of short-convolution layers beside attention on heads of 64:
@@ -24,7 +25,16 @@ def test_lfm2_programs_keep_pages_and_windows_in_place_on_v5e(
     `flash_fwd` kernel at a head of half a tile, the experts the grouped
     matmul with no copy of a stack; and the bytes are PERF.md section 4's
     row: 10.90 GB of arguments, temporaries of 4.6 MB (decode) and 63.5 MB
-    (the widest prefill)."""
+    (the widest prefill). `riding` (PR 60) is the 1,024 rung as the engine
+    calls it, where 0.58 of the cell's prompts land with room: the 64 slots'
+    decode step in its last 64 rows, `paged_decode` beside `flash_fwd` in the
+    2 attention layers, each conv layer's windows read and written where they
+    lie in the segments' carry and the riders' rows put into the product
+    `C * c` by an update of 64 rows; no copy of the arena, ONE of the windows
+    (3.7 MB: XLA lays them out anew for the prompt's `write_state`, which
+    reads one slot's rows of every layer, as it does a hybrid's); temporaries
+    37.0 MB where the same rung with nobody to take, the parent's program
+    (0ed315d), holds 34.6."""
     cell = described_cell(topo, monkeypatch, "lfm2-24b-a2b-serve")
     eng, params, caches, ns, page = (cell.eng, cell.params, cell.caches,
                                       cell.ns, cell.page)
@@ -36,20 +46,29 @@ def test_lfm2_programs_keep_pages_and_windows_in_place_on_v5e(
         lowered = cell.lower_decode()
         kernels, paths = ["paged_decode", "grouped_matmul"], [
             "decode_pallas", "experts_grouped_pallas"]
-    else:
+    elif program == "prefill":
         lowered = cell.lower_prefill(2048, 0)
         kernels, paths = ["flash_fwd", "grouped_matmul"], [
             "fwd_pallas", "experts_grouped_pallas"]
+    else:
+        assert rung_rides(eng["max_seq"], ns, 1024) and cell.built.takes_riders
+        lowered = cell.lower_prefill(1024, 0, *cell.riding())
+        kernels, paths = ["flash_fwd", "grouped_matmul", "paged_decode"], [
+            "fwd_pallas", "experts_grouped_pallas", "decode_pallas"]
     text = lowered.as_text()
     assert all(k in text for k in kernels)
+    assert ("paged_decode" in text) == (program != "prefill")
     counts = attention.attention_path_counts()
     assert all(counts[p] > before.get(p, 0) for p in paths)
     assert counts.get("experts_ragged_dot", 0) == before.get(
         "experts_ragged_dot", 0)
     compiled = lowered.compile()
+    hlo = compiled.as_text()
     stacks = [tuple(params[stack][w].shape) for stack in ("conv", "layers")
               for w in ("w_gate", "w_up", "w_down")]
-    assert not moved_stacks(compiled.as_text(), stacks)
+    assert not moved_stacks(hlo, stacks)
+    assert not copies_of(hlo, kc)
+    assert len(copies_of(hlo, window)) <= (program == "riding")
     mem = compiled.memory_analysis()
     held = sum(x.size * x.dtype.itemsize for x in (kc, vc, window))
     assert held == 2 * 2 * eng["kv_pages"] * 4 * page * 128 * 2 \
@@ -60,8 +79,8 @@ def test_lfm2_programs_keep_pages_and_windows_in_place_on_v5e(
     assert weights == 2 * 5_177_950_976
     # arguments: the weights, the caches and a step's few vectors
     assert 0 <= mem.argument_size_in_bytes - weights - held < 1 << 20
-    assert mem.temp_size_in_bytes < ((8 << 20) if program == "decode"
-                                     else (96 << 20))
+    assert mem.temp_size_in_bytes < {"decode": 8 << 20, "prefill": 96 << 20,
+                                     "riding": 48 << 20}[program]
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
