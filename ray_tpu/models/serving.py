@@ -102,8 +102,8 @@ class Programs(NamedTuple):
     adopt: Callable
     poke: Callable
     # Whether the prefill program of a riding rung carries the live slots
-    # (`_stack`'s answer: a dense, a sparse, a state-space and a conv stack do;
-    # an indexed, a latent, a mixed, a retention stack and one that generates
+    # (`_stack`'s answer: a dense, a sparse, a state-space, a conv and a latent
+    # stack do; an indexed, a mixed, a retention stack and one that generates
     # by blocks take nobody).
     takes_riders: bool
     # Whether a hand-off's K and V can be adopted (`adopts`).
@@ -827,9 +827,30 @@ def _latent_kind(mcfg) -> _Kind:
     slot's cached rows (`paged_latent_decode`), the step's own row written
     first. What the cache keeps of a token is ONE row, the normed latent then
     the rotated shared key; its layer is the layer's place in the whole
-    stack."""
+    stack (`ctx["base"] + l`). Its prefill takes RIDERS: the tail rows'
+    absorbed inputs, their rows written at the slots' positions and the
+    kernel against the arena, which is the step `decode` runs (`_token_step`:
+    ONE jit for both), before a `wo` and a feed-forward that run once over
+    the bucket."""
     dt, S = mcfg.dtype, mcfg.max_seq
     latent_decode = paged_kv.paged_latent_decode
+
+    @jax.jit
+    def _token_step(kc, l, bt, w, act, ql, q_r, c, kr):
+        """One token a slot against the latent arena: a step's row (`c`
+        `[n_slots, rank]`, `kr` `[n_slots, dr]`) written at the slots'
+        positions `w` into layer `l`, then each active slot's absorbed query
+        (`ql` `[n_slots, heads, rank]`, `q_r` `[n_slots, heads, dr]`) against
+        its rows 0..w (an idle slot reads nothing) -> (arena, ol `[n_slots,
+        heads, rank]`). A jit of its own, and ONE for the riders and for the
+        decode program's layers, as `_attention_kind`'s is (which see): the
+        kernel is traced once a process, not once a riding rung."""
+        kc = paged_kv.write_token_rows(kc, l, bt, w, act,
+                                       paged_kv.latent_rows(c, kr, kc))
+        with jax.named_scope("attn"):
+            ol = latent_decode(ql, q_r, kc, l, bt, jnp.where(act, w + 1, 0),
+                               sm_scale=mcfg.softmax_scale)
+        return kc, ol
 
     def prefill(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
@@ -839,6 +860,31 @@ def _latent_kind(mcfg) -> _Kind:
         with jax.named_scope("attn"):
             attn = attention.latent_flash_attention(q_n, q_r, k_n, kr, v,
                                                     mcfg.softmax_scale)
+        if ctx["riders"]:
+            # ONE decode step of the riding slots in the bucket's tail rows,
+            # as `decode` takes it: the absorbed form's inputs of those rows
+            # ALONE, from `x`, and the result put into the kernel's own
+            # output `[1, heads, rows, dv]` before its transpose, by an update
+            # of n_slots rows a head. (A second reader of the bucket's `q_n`
+            # and `q_r`, and an update after the transpose, make the compiler
+            # lay them and the output out anew: 5.2 ms of a 3,584-row prefill
+            # together, my chip runs, PR 61; the 105 MB of projections read
+            # again a layer cost less.)
+            bt, w, act = ctx["riders"]
+            ns = act.shape[0]
+            tail = slice(Sq - ns, Sq)
+            cos, sin = (t[tail][:, None] for t in ctx["tables"])
+            kc, ol = _token_step(
+                caches.kc, ctx["base"] + l, bt, w, act,
+                *block.latent_attention_inputs(
+                    lp, x[0, tail], mcfg, lambda t: _rope_one(t, cos, sin),
+                    absorb=True))
+            caches = caches._replace(kc=kc)
+            rode = block.latent_attention_output(lp, ol, mcfg).reshape(
+                ns, attn.shape[1], -1).transpose(1, 0, 2)
+            attn = attn.at[0, :, tail].set(
+                jnp.where(act[None, :, None], rode, attn[0, :, tail]))
+        with jax.named_scope("attn"):
             attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, -1)
         with jax.named_scope("attn_out"):
             x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
@@ -853,21 +899,19 @@ def _latent_kind(mcfg) -> _Kind:
     def begin(ctx):
         if "w" not in ctx:
             ctx["w"] = w = jnp.minimum(ctx["pos"], S - 1)
-            ctx["lengths"] = jnp.where(ctx["act"], w + 1, 0)
             with jax.named_scope("rope"):
                 ctx["c"], ctx["s"] = (t[w][:, None] for t in ctx["tables"])
 
     def decode(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
-        bt, w, act, kc = ctx["bt"], ctx["w"], ctx["act"], caches.kc
-        ql, q_r, row, kr = block.latent_attention_inputs(
-            lp, x, mcfg, lambda t: _rope_one(t, ctx["c"], ctx["s"]),
-            absorb=True)
-        kc = paged_kv.write_token_rows(kc, ctx["base"] + l, bt, w, act,
-                                       paged_kv.latent_rows(row, kr, kc))
-        with jax.named_scope("attn"):
-            ol = latent_decode(ql, q_r, kc, ctx["base"] + l, bt,
-                               ctx["lengths"], sm_scale=mcfg.softmax_scale)
+        act = ctx["act"]
+        # The write and the kernel as the riders' step has them, traced once
+        # for both.
+        kc, ol = _token_step(
+            caches.kc, ctx["base"] + l, ctx["bt"], ctx["w"], act,
+            *block.latent_attention_inputs(
+                lp, x, mcfg, lambda t: _rope_one(t, ctx["c"], ctx["s"]),
+                absorb=True))
         with jax.named_scope("attn_out"):
             x = x + block.latent_attention_output(lp, ol, mcfg) \
                 @ lp["wo"].astype(dt)
@@ -1034,7 +1078,7 @@ def _stack(mcfg) -> _Stack:
             lambda ns, page, n_pages: Caches(kc=paged_kv.empty_latent(
                 mcfg.n_layers, n_pages, page, mcfg.latent_width, dt)),
             lambda c: {"latent_cache_bytes": slot_state.state_bytes([c.kc])},
-            shares=True, tally="first")
+            takes_riders=True, shares=True, tally="first")
     if mcfg.mixed:
         return _Stack(
             {kind: _mixed_kind(mcfg, kind)
@@ -1100,6 +1144,20 @@ _KEEP = {
 }
 
 
+def _segments(mcfg, stack: _Stack):
+    """`mcfg.segments()` as the walks run them -> (name, kind, lo, hi, base)
+    a segment: `base + l` is the layer of its cache (the kind's first
+    `keeps`) that the segment's ordinal `l` writes, a prompt's rows and a
+    step's alike; None for a kind that keeps nothing."""
+    at = dict.fromkeys(_KEEP, 0)    # layers so far, a cache
+    for name, lo, hi in mcfg.segments():
+        kind, base = stack.kinds[name], None
+        if kind.keeps:
+            base = at[kind.keeps[0]] - lo
+            at[kind.keeps[0]] += hi - lo
+        yield name, kind, lo, hi, base
+
+
 def _over(kind: _Kind, layers, mcfg, body, ordinals=True):
     """How a kind's segments run (`_Kind.over`) -> run(lo, hi, carry) ->
     (carry, ys) over the layers `lo..hi-1` of the stack `layers`, where
@@ -1163,7 +1221,9 @@ def _prefill_walk(mcfg, stack: _Stack):
     place; a state-space layer's tail rows take the mixer's step from and to
     the slots' own state, in the same carry (`_mamba_kind`), a
     short-convolution layer's the operator's from and to the slots' own
-    windows (`_conv_kind`); the projections,
+    windows (`_conv_kind`), a latent-attention layer's the absorbed form's
+    step (the slot's ONE row written, `paged_latent_decode` against the arena
+    of latent rows: `_latent_kind`); the projections,
     the feed-forward (a one-part stack's expert layers: `live` holds the
     riders) and the head run over the bucket as they do anyway, so the
     step's weight reads are the prefill's. Then logits is [1 +
@@ -1221,8 +1281,8 @@ def _prefill_walk(mcfg, stack: _Stack):
         kept = {cache: [] for cache in _KEEP}
         experts = 0 if stack.tally == "zero" else None
         with jax.named_scope("layers"):
-            for name, lo, hi in mcfg.segments():
-                kind = stack.kinds[name]
+            for name, kind, lo, hi, base in _segments(mcfg, stack):
+                ctx["base"] = base      # (read by a kind's riders alone)
                 (x, _, caches), (k, v, counts, *more) = runs[name](
                     lo, hi, (x, tuple(ctx[c] for c in kind.rides), caches))
                 ys = (k, v, *more)
@@ -1383,14 +1443,9 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         runs = {name: _over(kind, params[kind.stack or name], mcfg,
                             layer(kind))
                 for name, kind in stack.kinds.items() if name in segments}
-        at = dict.fromkeys(_KEEP, 0)    # layers so far, a cache
         with jax.named_scope("layers"):
-            for name, lo, hi in mcfg.segments():
-                kind = stack.kinds[name]
-                if kind.keeps:
-                    # `base + l`: the layer of its cache an ordinal `l` writes
-                    ctx["base"] = at[kind.keeps[0]] - lo
-                    at[kind.keeps[0]] += hi - lo
+            for name, kind, lo, hi, base in _segments(mcfg, stack):
+                ctx["base"] = base
                 if kind.begin:
                     kind.begin(ctx)
                 # The caches of this kind alone ride its scan: an arena a

@@ -56,12 +56,13 @@ n-step decode chunk over all slots, and the slot poke.
   prefill of the same layers makes anyway. So where a prompt leaves
   `n_slots` rows of its bucket free, the prefill program of a riding rung
   (`rung_rides`: the octave under max_seq, of a stack whose programs take
-  riders, `Programs.takes_riders`: a dense, a sparse, a state-space hybrid or
-  a stack of short-convolution layers beside attention) carries ONE decode
-  step of every live slot in those rows (`models/serving.py`); on the host the riders advance as a chunk of one
-  step would (`_ride_plan`, `_place`), and the emitter streams their tokens
-  after the prompt's first. Who rides is read off the stack and the shapes:
-  no option, field or environment variable.
+  riders, `Programs.takes_riders`: a dense, a sparse, a state-space hybrid, a
+  stack of short-convolution layers beside attention or one of
+  latent-attention layers) carries ONE decode step of every live slot in
+  those rows (`models/serving.py`); on the host the riders advance as a chunk
+  of one step would (`_ride_plan`, `_place`), and the emitter streams their
+  tokens after the prompt's first. Who rides is read off the stack and the
+  shapes: no option, field or environment variable.
 """
 
 from __future__ import annotations
@@ -135,12 +136,12 @@ def rung_rides(max_seq: int, n_slots: int, width: int) -> bool:
     (`models/serving.py`, riders): the rungs of the octave under `max_seq`,
     where a prefill is long enough for a decode step's weight reads to hide
     in it and where the long prompts of a batch land, and none narrower (a
-    riding program holds a decode step's attention kernel, a hybrid's its
-    state's step and a conv stack's its windows' too, and a sampler over the
-    slots' rows, traced, lowered and loaded at every start). The slots' rows
-    have to fit in the rung beside a prompt. Whether the stack's programs
-    take riders at all is the stack's answer (`Programs.takes_riders`), not
-    the rung's."""
+    riding program holds a decode step's attention kernel (a latent stack's
+    the absorbed form's), a hybrid's its state's step and a conv stack's its
+    windows' too, and a sampler over the slots' rows, traced, lowered and
+    loaded at every start). The slots' rows have to fit in the rung beside a
+    prompt. Whether the stack's programs take riders at all is the stack's
+    answer (`Programs.takes_riders`), not the rung's."""
     return 2 * width >= max_seq and n_slots < width
 
 

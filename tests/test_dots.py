@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmark import models, reference_dots
-from ray_tpu.models import llama
+from ray_tpu.models import llama, serving
 from ray_tpu.models.block import (fuse_qkv, latent_attention_inputs,
                                   latent_attention_output, split_qkv)
 from ray_tpu.models.serving import prefill_core
@@ -461,6 +461,92 @@ def test_prefill_then_decode_through_the_latent_cache_is_the_reference(
     assert len(after["expert_tokens"]) == held
     assert after["latent_cache_bytes"] == engine._caches.kc.nbytes \
         and engine._caches.vc is None
+
+
+@pytest.mark.parametrize("riding", [(True, True), (False, True)],
+                         ids=["both-ride", "one-rides"])
+def test_a_riding_prefills_tail_rows_are_one_decode_step_and_the_references(
+        tiny, monkeypatch, riding):
+    """Two slots hold prompts of 40 and 23 tokens; a prompt of 100 is then
+    prefilled in the riding rung 128 (`max_seq` 256) with the slots' next
+    tokens in its last two rows, the decode kernel interpreted. The logits of
+    a riding slot's row are those of ONE decode step from the same caches and
+    the float32 reference's at that position, the prompt's own row is the
+    reference's too, the slots' `last` and `pos` move as the step moves them,
+    and every layer of the arena holds in the slots' pages what the step
+    leaves there (the dense layer is the arena's layer 0, the sparse ones 1
+    and 2). A slot that does not ride stands still: its pages, `last` and
+    `pos` are what they were."""
+    adapter, model, cfg, params = tiny
+    ns, page, V = 2, 16, cfg.vocab_size
+    maxp = cfg.max_seq // page
+    monkeypatch.setattr(paged_kv, "paged_latent_decode", functools.partial(
+        paged_kv.paged_latent_decode, interpret=True))
+    seen = []
+    sample = serving.sample_tokens
+
+    def spy(logits, *how):      # every program samples through the module's
+        jax.debug.callback(lambda l: seen.append(np.asarray(l)), logits)
+        return sample(logits, *how)
+
+    monkeypatch.setattr(serving, "sample_tokens", spy)
+    programs = serving.build_programs(cfg, ns, 1, page, 3 * maxp + 1)
+    assert programs.takes_riders
+    fused = fuse_qkv(params, cfg)
+    bt = jnp.arange(1, 1 + ns * maxp, dtype=jnp.int32).reshape(ns, maxp)
+    key = jnp.zeros(2, jnp.uint32)
+
+    def prefill(caches, pages, prompt, bucket, *slots):
+        return programs.prefill(
+            fused, caches, pages,
+            jnp.asarray([prompt + [0] * (bucket - len(prompt))], jnp.int32),
+            len(prompt), 0.0, 0, key, None, *slots)
+
+    prompts = [_tokens(40, 1), _tokens(23, 2)]
+    caches, firsts = programs.empty(), []
+    for slot, (prompt, bucket) in enumerate(zip(prompts, (64, 32))):
+        caches, first, _ = prefill(caches, bt[slot], prompt, bucket)
+        firsts.append(int(first))
+    # (host arrays: both programs are handed, and donate, their own copies)
+    last, pos = np.asarray(firsts, np.int32), np.asarray([40, 23], np.int32)
+    sampling = (jnp.zeros(ns), jnp.zeros(ns, jnp.int32),
+                jnp.zeros((ns, 2), jnp.uint32))
+    marks = jnp.asarray(riding)
+    arena = np.asarray(caches.kc)
+    jax.effects_barrier()
+    seen.clear()
+    third = _tokens(100, 3)
+    rode, _, _, last_r, pos_r, toks = prefill(
+        jax.tree.map(jnp.copy, caches),
+        jnp.arange(1 + ns * maxp, 1 + 3 * maxp, dtype=jnp.int32), third, 128,
+        jnp.asarray(last), jnp.asarray(pos), (bt, marks, *sampling))
+    stepped, last_d, pos_d, out, _ = programs.decode(
+        fused, jax.tree.map(jnp.copy, caches), bt, jnp.asarray(last),
+        jnp.asarray(pos), marks, *sampling)
+    jax.effects_barrier()
+    riders, step = seen
+    assert riders.shape == (1 + ns, V) and step.shape == (ns, V)
+    ref = adapter.reference()
+    own = np.asarray(ref.logits_last(params, model, third, 1))[0]
+    assert np.abs(riders[0] - own).max() < LOGIT_TOL
+    for slot, rides in enumerate(riding):
+        mine = np.asarray(bt[slot])
+        if not rides:
+            assert np.array_equal(np.asarray(rode.kc)[:, mine],
+                                  arena[:, mine])
+            continue
+        assert np.abs(riders[1 + slot] - step[slot]).max() < LOGIT_TOL
+        want = np.asarray(ref.logits_last(
+            params, model, prompts[slot] + [firsts[slot]], 1))[0]
+        assert np.abs(riders[1 + slot] - want).max() < LOGIT_TOL
+        assert int(toks[slot]) == int(out[slot, 0]) == int(want.argmax())
+        # the step's row, in every layer, and nothing else of the slot's
+        assert np.abs(np.asarray(rode.kc)[:, mine]
+                      - np.asarray(stepped.kc)[:, mine]).max() < LOGIT_TOL
+        assert np.abs(np.asarray(rode.kc)[:, mine] - arena[:, mine]).max() > 0
+    assert np.array_equal(np.asarray(last_r), np.asarray(last_d))
+    assert np.array_equal(np.asarray(pos_r), np.asarray(pos_d))
+    assert np.array_equal(np.asarray(pos_r), pos + np.asarray(riding))
 
 
 def test_the_engine_took_the_latent_paths(engine):

@@ -426,14 +426,16 @@ def _own(fn):
 
 
 def test_one_walk_for_a_prompt_and_one_for_a_decode_step():
-    """Two functions of `models/serving.py` loop over `mcfg.segments()`, one
-    for a prompt and one for a decode step, and neither names an
+    """Two functions of `models/serving.py` walk the segments, one for a
+    prompt and one for a decode step, both as `_segments` lists them (since
+    PR 61: each segment with the layer of its cache its first ordinal
+    writes, reckoned once for both), and none of the three names an
     architecture."""
     walks = [fn for fn in ast.walk(_tree("models", "serving.py"))
              if isinstance(fn, ast.FunctionDef) and any(
                  isinstance(n, ast.For) and "segments" in ast.dump(n.iter)
                  for n in _own(fn))]
-    assert sorted(fn.name for fn in walks) == ["_step", "walk"]
+    assert sorted(fn.name for fn in walks) == ["_segments", "_step", "walk"]
     for fn in walks:
         assert not set(_named(fn)) & {"hybrid", "latent", "mixed",
                                       "ssm_state", "kv_lora_rank",

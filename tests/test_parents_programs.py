@@ -35,7 +35,7 @@ from test_serve_llm import parents_sample_tokens
 # Since PR 41 a dense and a sparse stack's rungs of the octave under `max_seq`
 # (64 and 128 here) carry the live slots (`engine.rung_rides`) and lower to
 # another text on purpose: their pin is the 32 rung, taken on PR 41's parent
-# (5481b82), as are the latent stack's own, which takes nobody.
+# (5481b82), as are the latent stack's own (which took nobody until PR 61).
 # `PARENT_RUNGS` below pins every rung of every stack. The same two
 # stacks' decode programs hand the arena to the jit they share with the riders
 # (`_token_step`: the same write and kernel, one trace a process) and were
@@ -53,6 +53,13 @@ from test_serve_llm import parents_sample_tokens
 # PR 60's tree (the parent's: bd26e37b38f9f1e5, which the tree still lowers to
 # with the attention kind built `ridden=False`: the operator's step, moved
 # into `block._slot_conv`, is the same text).
+# Since PR 61 the latent stack (dots: MLA layers over a share of the experts)
+# takes riders too: its pin is the 32 rung, the parent's (b8b4411), and its
+# decode program, whose write and kernel now go through the jit they share
+# with the riders (`_latent_kind`'s `_token_step`; the lengths a step reads
+# are made there, from the same `act` and `w`), was taken anew on PR 61's tree
+# (the parent's: 3cbcf9da23401fa5; the mathematics of a step did not move:
+# tests/test_dots.py holds it to the reference).
 # `mixed` (PR 42's stack, which takes nobody) was taken on PR 45's parent
 # (6c2c097), before that PR moved a line under ray_tpu/.
 # Since PR 47 `serving.sample_tokens` takes its top-k behind a conditional, so
@@ -69,8 +76,8 @@ PARENT_PROGRAMS = {
     "hybrid.prefill32": "bf109118a1785278",
     "indexed.decode": "7f5193fdda9e8db0",
     "indexed.prefill64": "2b26fc68f7f5f898",
-    "latent.decode": "3cbcf9da23401fa5",
-    "latent.prefill64": "f96e02f0c080c3fb",
+    "latent.decode": "d16fcac2d9f7e040",
+    "latent.prefill32": "8da32aa0051287f3",
     "mixed.decode": "c31b6808d133c647",
     "mixed.prefill64": "ef4db6b4bc528b78",
     "sparse.decode": "94dff0eb228ce990",
@@ -83,8 +90,8 @@ PARENT_PROGRAMS = {
 # two slots, on PR 41's parent (5481b82; jax 0.9.0 on the CPU: no Mosaic
 # payload, no source locations in the text). The rungs 64 and 128 of the dense
 # and the sparse stack ride since PR 41, the hybrid's since PR 58, the conv
-# stack's (every rung taken on PR 60's parent, 0ed315d) since PR 60:
-# `PARENT_RIDING` below;
+# stack's (every rung taken on PR 60's parent, 0ed315d) since PR 60, the
+# latent stack's since PR 61: `PARENT_RIDING` below;
 # `PARENT_PROGRAMS` above pins the decode programs. `mixed` (PR 42's stack) was
 # taken on PR 45's parent (6c2c097). All three tables stand since PR 47 with
 # that PR's parent's sampler in `serving.sample_tokens`' place while a
@@ -97,8 +104,7 @@ PARENT_RUNGS = {
                 128: "69ca4b8800557a63"},
     "hybrid": {32: "bf109118a1785278"},
     "conv": {32: "ff719c8f70fb6d00"},
-    "latent": {32: "8da32aa0051287f3", 64: "f96e02f0c080c3fb",
-               128: "4b487bf21d58472e"},
+    "latent": {32: "8da32aa0051287f3"},
     "mixed": {32: "7ce5961c0ca08aab", 64: "ef4db6b4bc528b78",
               128: "d8c5fc514a414023"},
 }
@@ -108,6 +114,7 @@ PARENT_RIDERLESS = {
     "sparse": {64: "01d0cbc9e60958cc", 128: "6ea775ec4038bec1"},
     "hybrid": {64: "b6847a6dfe909d84", 128: "4dd7ed9434604dd1"},
     "conv": {64: "0d6746047675a641", 128: "e080b12a6d548004"},
+    "latent": {64: "f96e02f0c080c3fb", 128: "4b487bf21d58472e"},
 }
 # What the riding rungs lower to with the riders' shapes as `_place` passes
 # them (`Engine.lowered_prefill_text`): the programs `serve-batch` and
@@ -117,12 +124,16 @@ PARENT_RIDERLESS = {
 # prefill time in) taken on PR 58's tree, which made them; the conv stack's
 # (the operator's step in its tail rows, `block.conv_mixer(riders=)`, and
 # `_token_step` in its two attention layers': the programs
-# `serve-generate-lfm2` spends 0.58 of its prefills in) on PR 60's.
+# `serve-generate-lfm2` spends 0.58 of its prefills in) on PR 60's; the latent
+# stack's (the absorbed form's step in the tail rows of each MLA layer,
+# `_latent_kind`'s `_token_step`: the programs `serve-batch-dots-vlm1` spends
+# nearly all its prefill time in) on PR 61's.
 PARENT_RIDING = {
     "dense": {64: "8de5c32ccafe6475", 128: "3a4649dd358ebc16"},
     "sparse": {64: "c033be69300aac6b", 128: "c2b37a166224c151"},
     "hybrid": {64: "a87192cc5a56bac3", 128: "f0b6c2f60d1080fc"},
     "conv": {64: "5aef5bb379a666c0", 128: "892c8e058afa9478"},
+    "latent": {64: "6c612fcd1cce9799", 128: "1b7702520c1c27e5"},
 }
 STACKS = dict(KINDS, latent=("dots", PUBLISHED), mixed=("mimo", MIMO),
               conv=("lfm2", LFM2))
@@ -232,15 +243,15 @@ def test_the_other_models_programs_are_the_parents(kind):
 
 @pytest.mark.parametrize("kind", sorted(PARENT_RUNGS))
 def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
-    """A dense, a sparse, a hybrid and a conv stack's programs of the octave
-    under `max_seq` take riders and hold a decode step's attention (a
-    hybrid's its state-space layers' step too, a conv stack's its
-    short-convolution layers'); their narrow rungs, and every rung of an
-    indexed, a latent and a mixed stack, take nobody and lower to the
-    parent's text, letter for letter. Asked of the built program; no option,
-    field or environment variable has a say."""
+    """A dense, a sparse, a hybrid, a conv and a latent stack's programs of
+    the octave under `max_seq` take riders and hold a decode step's attention
+    (a hybrid's its state-space layers' step too, a conv stack's its
+    short-convolution layers'; a latent stack's is the absorbed form's);
+    their narrow rungs, and every rung of an indexed and a mixed stack, take
+    nobody and lower to the parent's text, letter for letter. Asked of the
+    built program; no option, field or environment variable has a say."""
     takes, riding, got, _, _ = _programs(kind)
-    assert takes is (kind in ("dense", "sparse", "hybrid", "conv"))
+    assert takes is (kind in ("dense", "sparse", "hybrid", "conv", "latent"))
     assert riding == ([64, 128] if takes else [])
     assert {w: d for w, d in got.items()
             if w not in riding} == PARENT_RUNGS[kind]
@@ -250,9 +261,9 @@ def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
 
 @pytest.mark.parametrize("kind", sorted(PARENT_RIDING))
 def test_the_riding_rungs_are_the_parents(kind):
-    """The riding rungs of a dense, a sparse, a hybrid and a conv stack,
-    lowered with the riders' shapes as `_place` passes them, are the pinned
-    text."""
+    """The riding rungs of a dense, a sparse, a hybrid, a conv and a latent
+    stack, lowered with the riders' shapes as `_place` passes them, are the
+    pinned text."""
     _, riding, got, _, _ = _programs(kind)
     assert {w: got[w] for w in riding} == PARENT_RIDING[kind]
 

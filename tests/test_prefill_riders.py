@@ -5,12 +5,14 @@ live slot in those rows. On the CPU at the adapters' rehearsal widths in
 float32, a dense and a sparse stack and, since PR 58, the three hybrids
 (Mamba-1 over a dense feed-forward; Mamba-2, one group, over a share of the
 experts; the stack of one-part layers, Mamba-2 with groups) and, since PR 60,
-LFM2's stack of short-convolution layers beside attention: every stream is
+LFM2's stack of short-convolution layers beside attention and, since PR 61,
+dots' stack of latent-attention (MLA) layers, whose riders take the absorbed
+form's step against the arena of latent rows: every stream is
 what the same engine serves with nobody riding, and the plain reference's
 greedy tokens; the counters and the admit spans agree; a burst of admissions
 moves the riders a step each; a rider that finishes on a riding step frees its
 slot, its pages and its slot's recurrent state (or windows) at once, and the
-next admission overwrites them. (An indexed, a latent and a mixed stack take
+next admission overwrites them. (An indexed and a mixed stack take
 nobody, and every program that takes nobody lowers to the parent's text:
 tests/test_parents_programs.py.)
 
@@ -29,6 +31,7 @@ import jax.numpy as jnp
 
 from ray_tpu.serve.engine import (_DEPTH, Engine, prefill_widths,
                                   rung_rides)
+import test_dots
 import test_granite
 import test_lfm2
 import test_nemotron_h
@@ -63,12 +66,16 @@ def _drain(q, seconds=120.0):
 HYBRIDS = {"mamba2": test_granite, "one-part": test_nemotron_h}
 # ... and, since PR 60, the stack of short-convolution layers beside attention
 # (LFM2: a slot keeps a window a conv layer and pages for the rest).
-STACKS = ["dense", "sparse", "hybrid", *HYBRIDS, "conv"]
+# ... and, since PR 61, the stack of latent-attention layers (dots: a slot
+# keeps ONE row a position a layer, and the riders read them absorbed).
+STACKS = ["dense", "sparse", "hybrid", *HYBRIDS, "conv", "latent"]
 
 
 def _model(kind):
     if kind == "conv":
         return test_lfm2._tiny(max_seq=MAX_SEQ)
+    if kind == "latent":
+        return test_dots._tiny(max_seq=MAX_SEQ)
     if kind not in HYBRIDS:
         return _tiny(kind, MAX_SEQ)
     tests = HYBRIDS[kind]
@@ -192,13 +199,13 @@ def test_the_manifest_entry_of_the_riders_share():
         workloads=["serve-batch", "serve-batch-olmoe"])
 
 
-@pytest.fixture(scope="module", params=["dense", "hybrid", "conv"])
+@pytest.fixture(scope="module", params=["dense", "hybrid", "conv", "latent"])
 def held(request):
     """An engine of three slots (a dense stack's; a hybrid's, whose slots hold
     a recurrent state too; a conv stack's, whose slots hold a window a conv
-    layer) whose emitter the test holds at its first chunk, so
-    that the loop stands with `_DEPTH` chunks in flight and nothing moves but
-    what the test submits."""
+    layer; a latent stack's, whose pages hold latent rows) whose emitter the
+    test holds at its first chunk, so that the loop stands with `_DEPTH`
+    chunks in flight and nothing moves but what the test submits."""
     _, _, _, eng = _build(request.param, n_slots=3)
     gate = threading.Event()
     fetch = eng._fetch

@@ -1,12 +1,14 @@
 """The programs of the two configurations that hold a SHARE of their experts,
 compiled for a described `v5e:2x2` at the cells' sizes
-(tests/compile_for_v5e.py says why): MiMo-V2's mixed stack, and both shares'
-prefill over the expert stacks."""
+(tests/compile_for_v5e.py says why): MiMo-V2's mixed stack, both shares'
+prefill over the expert stacks, and dots' riding rung against its arena of
+latent rows."""
 
 import pytest
 
-from compile_for_v5e import described_cell, moved_stacks
+from compile_for_v5e import copies_of, described_cell, moved_stacks
 from ray_tpu.ops import attention
+from ray_tpu.serve.engine import rung_rides
 
 pytestmark = pytest.mark.usefixtures("_no_compile_cache")
 
@@ -86,3 +88,47 @@ def test_a_shares_prefill_reads_the_expert_stacks_where_they_lie_on_v5e(
     hlo = lowered.compile().as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') >= 4
     assert not moved_stacks(hlo, stacks)
+
+
+@pytest.mark.timeout(240)
+def test_dots_riding_prefill_steps_the_slots_in_the_latent_arena_in_place_on_v5e(
+        topo, monkeypatch):
+    """The 2,048-wide prefill of the latent stack at the cell's sizes
+    (benchmark/configs/dots.vlm1.inst-serve.json: 32 slots, `max_seq` 4,096,
+    so the rung rides), beside the same width's program with nobody to take:
+    the riding one holds the `latent_decode` kernel once a segment (the dense
+    layer's, the sparse layers' scan's), the 0.84 GB arena of latent rows
+    rides both scans' carry through the riders' page writes and the kernel
+    and still aliases the donated entry buffer, nothing arena- or
+    layer-shaped is copied, no expert stack is copied or sliced (the riders'
+    assignments go through the prompt's grouped matmuls), and the step's rows
+    stay within 5% + 16 MiB of the riderless program's temporaries."""
+    cell = described_cell(topo, monkeypatch, "dots.vlm1.inst-serve")
+    kc = cell.caches.kc
+    assert rung_rides(cell.eng["max_seq"], cell.ns, 2048)
+    assert cell.built.takes_riders and cell.caches.vc is None
+    assert kc.shape == (5, cell.eng["kv_pages"], cell.page, 640)
+    before = attention.attention_path_counts().get("latent_decode_pallas", 0)
+
+    def compiled(*more):
+        lowered = cell.lower_prefill(2048, None, *more)
+        return lowered.as_text(), lowered.compile()
+
+    plain_text, plain = compiled(None, None, None)
+    assert attention.attention_path_counts().get(
+        "latent_decode_pallas", 0) == before
+    text, riding = compiled(*cell.riding())
+    assert attention.attention_path_counts()["latent_decode_pallas"] > before
+    assert "latent_decode" in text and "latent_decode" not in plain_text
+    assert "latent_flash_fwd" in text and "latent_flash_fwd" in plain_text
+    hlo = riding.as_text()
+    calls = [kind.count('custom_call_target="tpu_custom_call"')
+             for kind in (plain.as_text(), hlo)]
+    assert calls[1] == calls[0] + 2, calls
+    assert not copies_of(hlo, kc)
+    assert not moved_stacks(hlo, [
+        tuple(cell.params["layers"][w].shape)
+        for w in ("w_gate", "w_up", "w_down")])
+    mem, was = riding.memory_analysis(), plain.memory_analysis()
+    assert mem.alias_size_in_bytes >= kc.size * kc.dtype.itemsize
+    assert mem.temp_size_in_bytes <= 1.05 * was.temp_size_in_bytes + (16 << 20)
