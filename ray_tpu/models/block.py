@@ -16,9 +16,10 @@ engine's prefill and its decode step (`serve/engine.py`) all call them.
   mixed_attention_inputs
                      its peer for a stack of window and full attention layers
                      (`cfg.attn_pattern`): attn_norm -> q, k, v at the widths
-                     of the layer's KIND, each q and k head in two parts, the
-                     one RoPE turns (at the kind's theta) and the one it
-                     passes
+                     of the layer's KIND (its query heads too), each q and k
+                     head in two parts, the one RoPE turns (at the kind's
+                     frequencies) and the one it passes (none where the kind
+                     turns the whole head), and a gate's logit a query head
   feed_forward       mlp_norm -> dense SwiGLU, or router + experts (+ a
                      shared expert; the experts a share of the router's)
   mamba_mixer        a state-space layer's whole mixer, over a sequence or
@@ -52,6 +53,7 @@ trace is reduced by (benchmark/program_trace.py); the sparse half's own
 
 from __future__ import annotations
 
+import itertools
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -209,24 +211,29 @@ def mixed_attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
                            rope: Callable[[jax.Array], jax.Array]) -> Tuple:
     """The cache-free half of a layer of a mixed-attention stack
     (`cfg.attn_pattern`), `stack` the layer's kind (`window`, or `dense` /
-    `layers`: full attention), which decides its kv heads
-    (`cfg.attention_kind`); the caller's `rope` rotates at that kind's theta.
+    `layers`: full attention), which decides BY KIND its query heads, its kv
+    heads and how much of a head RoPE turns (`cfg.attention_kind`); the
+    caller's `rope` rotates at that kind's frequencies.
 
-    A q or k head is `cfg.head_dim` wide, of which RoPE turns the first
-    `cfg.rotary_dim` (r) and passes the rest (n); a v head is
-    `cfg.v_head_dim`. -> (q_n, q_r, k_n, k_r, v): `[batch, heads, seq, d]`
-    for a prompt, `[slots, heads, d]` for a decode step, q_r and k_r rotated,
-    v times `cfg.value_scale`.
+    A q or k head is `cfg.head_dim` wide, of which RoPE turns the kind's
+    first `rotary_dim` (r) and passes the rest (n); a v head is
+    `cfg.v_head_dim`. -> (q_n, q_r, k_n, k_r, v, gate): `[batch, heads, seq,
+    d]` for a prompt, `[slots, heads, d]` for a decode step, q_r and k_r
+    rotated, v times `cfg.value_scale`; q_n and k_n None where the kind
+    turns the whole head; gate None without `cfg.attn_gate`, else the gate's
+    logits a query head, `[batch, seq, heads]` or `[slots, heads]`, from the
+    same normed input.
     The two parts apart, because 192 = 128 + 64 is a tile and a half: the
-    prefill kernels take a key in two parts (`ops.attention.
-    mixed_flash_attention`), and the caches hold `[k_n ; k_r]`, the passed
+    prefill kernels take such a key in two parts (`ops.attention.
+    mixed_flash_attention`, which joins the parts of a 128-wide head
+    itself), and the caches hold `[k_n ; k_r]`, the passed
     part on the tile's boundary. `lp` holds the projections as published,
-    `wq`, `wk`, `wv` (a head `[r ; n]`), or as serving does, one `wqkv` whose
-    column groups are the five results (`fuse_qkv`), so that nothing is cut
-    inside a program."""
+    `wq`, `wk`, `wv` (a head `[r ; n]`) and `wg`, or as serving does, one
+    `wqkv` whose column groups are the results (`fuse_qkv`), so that nothing
+    is cut inside a program and the gate costs no second pass over the
+    input."""
     lead = x.shape[:-1]
-    H, dk, dv, dr = cfg.n_heads, cfg.head_dim, cfg.v_head_dim, cfg.rotary_dim
-    KVH = cfg.attention_kind(stack)[0]
+    KVH, _, _, _, H, dr = cfg.attention_kind(stack)
     dt = cfg.dtype
 
     def heads(t, n):
@@ -237,34 +244,64 @@ def mixed_attention_inputs(lp: Dict[str, jax.Array], x: jax.Array, cfg,
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     with jax.named_scope("qkv"):
         if "wqkv" in lp:
-            q_n, k_n, v, q_r, k_r = jnp.split(
-                h @ lp["wqkv"].astype(dt), _mixed_ends(cfg, KVH), axis=-1)
-            q_n, q_r, k_n, k_r = (heads(q_n, H), heads(q_r, H),
-                                  heads(k_n, KVH), heads(k_r, KVH))
+            parts = dict(zip(_mixed_widths(cfg, stack), jnp.split(
+                h @ lp["wqkv"].astype(dt), _mixed_ends(cfg, stack),
+                axis=-1)))
+            q_n, q_r, k_n, k_r = (
+                heads(parts[k], n) if k in parts else None
+                for k, n in (("q_n", H), ("q_r", H), ("k_n", KVH),
+                             ("k_r", KVH)))
+            # (the gate's group is padded to whole tiles: `_mixed_widths`)
+            v, gate = parts["v"], parts["g"][..., :H] if "g" in parts \
+                else None
         else:
             q = heads(h @ lp["wq"].astype(dt), H)
             k = heads(h @ lp["wk"].astype(dt), KVH)
             v = h @ lp["wv"].astype(dt)
+            gate = h @ lp["wg"].astype(dt) if cfg.attn_gate else None
             q_r, q_n, k_r, k_n = (q[..., :dr], q[..., dr:], k[..., :dr],
-                                  k[..., dr:])
+                                  k[..., dr:]) if dr < cfg.head_dim \
+                else (q, None, k, None)
         # The values are scaled, which is the attention's output scaled, at
         # KVH heads a position and not H: what a cache keeps is this v.
         v = heads(v, KVH) * jnp.asarray(cfg.value_scale, v.dtype)
     with jax.named_scope("rope"):
         q_r, k_r = rope(q_r), rope(k_r)
-    return q_n, q_r, k_n, k_r, v
+    return q_n, q_r, k_n, k_r, v, gate
 
 
-def _mixed_ends(cfg, n_kv: int) -> List[int]:
-    """Where each column group of a mixed-attention layer's fused projection
-    but the last ends: q_n, k_n, v, q_r, k_r."""
-    H, dr, dv = cfg.n_heads, cfg.rotary_dim, cfg.v_head_dim
+def gated(attn: jax.Array, gate: Optional[jax.Array]) -> jax.Array:
+    """A mixed-attention layer's output `[.., H, dv]` times sigmoid of its
+    gate's logits `[.., H]`, a head (`cfg.attn_gate`), in float32, back in
+    `attn`'s dtype; `gate` None: `attn` as it is."""
+    if gate is None:
+        return attn
+    with jax.named_scope("attn_gate"):
+        return (attn.astype(jnp.float32)
+                * jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+                ).astype(attn.dtype)
+
+
+def _mixed_widths(cfg, stack: str) -> Dict[str, int]:
+    """The column groups of a mixed-attention layer's fused projection, in
+    their order, each with its width, the empty ones left out: every head's
+    passed part of q, of k, v, every head's rotary part of q, of k, and the
+    gate's logit a query head, in whole tiles of 128 columns (zeros after the
+    heads'): a stack whose rows are no whole tiles is laid out anew at every
+    program's entry (0.42 GB a decode chunk at Laguna's 72 heads, compiled
+    for a v5e, PR 62)."""
+    KVH, _, _, _, H, dr = cfg.attention_kind(stack)
     dn = cfg.head_dim - dr
-    ends, at = [], 0
-    for width in (H * dn, n_kv * dn, n_kv * dv, H * dr):
-        at += width
-        ends.append(at)
-    return ends
+    widths = {"q_n": H * dn, "k_n": KVH * dn, "v": KVH * cfg.v_head_dim,
+              "q_r": H * dr, "k_r": KVH * dr,
+              "g": -(-H // 128) * 128 if cfg.attn_gate else 0}
+    return {k: w for k, w in widths.items() if w}
+
+
+def _mixed_ends(cfg, stack: str) -> List[int]:
+    """Where each column group of a mixed-attention layer's fused projection
+    but the last ends."""
+    return list(itertools.accumulate(_mixed_widths(cfg, stack).values()))[:-1]
 
 
 def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
@@ -290,7 +327,7 @@ def feed_forward(lp: Dict[str, jax.Array], x: jax.Array, cfg,
         if cfg.n_experts > 0 and "router" in lp:
             # (None, None, None for a uniform stack: `__post_init__`.)
             routing = cfg.routing()
-            if routing is not None:
+            if routing is not None and "router_bias" in lp:
                 routing = dict(routing, bias=lp["router_bias"])
             def leaf(name):     # None: an ungated expert's gate
                 return lp[name].astype(dt) if name in lp else None
@@ -656,49 +693,62 @@ _MIXED_STACKS = ("dense", "window", "layers")
 
 def _fuse_mixed(params, cfg):
     """A mixed-attention model as the serving programs take it: each stack's
-    `wq`, `wk`, `wv` joined into ONE `wqkv` whose column groups are what
-    `mixed_attention_inputs` hands on: every head's passed part of q, of k,
-    v, then every head's rotary part of q and of k (`_mixed_ends`; at the
-    published widths every group ends on a multiple of 128 columns). A head's
-    columns are cut at `rotary_dim` here, once; cut inside a program, at 64
-    of a head's 192 columns, it is a copy of q and of k every layer."""
-    H, dr = cfg.n_heads, cfg.rotary_dim
+    `wq`, `wk`, `wv` (and the gate's `wg`) joined into ONE `wqkv` whose
+    column groups are what `mixed_attention_inputs` hands on: every head's
+    passed part of q, of k, v, then every head's rotary part of q and of k,
+    then the gate's column a query head, at the KIND's heads and rotary
+    width (`_mixed_widths`; at MiMo-V2's and at Laguna's published widths
+    every group ends on a multiple of 128 columns, the gate's padded to
+    one). A head's
+    columns are cut at the kind's `rotary_dim` here, once; cut inside a
+    program, at 64 of a head's 192 columns, it is a copy of q and of k every
+    layer."""
     out = dict(params)
     for name in _MIXED_STACKS:
         if name not in params:
             continue
         layers = dict(params[name])
-        KVH = cfg.attention_kind(name)[0]
+        KVH, _, _, _, H, dr = cfg.attention_kind(name)
         wq, wk, wv = (layers.pop(k) for k in _QKV)
         L, D, _ = wq.shape
         wq, wk = wq.reshape(L, D, H, -1), wk.reshape(L, D, KVH, -1)
+        groups = {"q_n": wq[..., dr:].reshape(L, D, -1),
+                  "k_n": wk[..., dr:].reshape(L, D, -1), "v": wv,
+                  "q_r": wq[..., :dr].reshape(L, D, -1),
+                  "k_r": wk[..., :dr].reshape(L, D, -1)}
+        if cfg.attn_gate:
+            wg = layers.pop("wg")
+            groups["g"] = jnp.pad(wg, ((0, 0), (0, 0), (0, -H % 128)))
         layers["wqkv"] = jnp.concatenate(
-            [wq[..., dr:].reshape(L, D, -1), wk[..., dr:].reshape(L, D, -1),
-             wv, wq[..., :dr].reshape(L, D, -1),
-             wk[..., :dr].reshape(L, D, -1)], axis=-1)
+            [groups[k] for k in _mixed_widths(cfg, name)], axis=-1)
         out[name] = layers
     return out
 
 
 def _split_mixed(params, cfg):
-    H = cfg.n_heads
     out = dict(params)
     for name in _MIXED_STACKS:
         if name not in params:
             continue
         layers = dict(params[name])
-        KVH = cfg.attention_kind(name)[0]
+        KVH, _, _, _, H, _ = cfg.attention_kind(name)
         w = layers.pop("wqkv")
         L, D, _ = w.shape
-        q_n, k_n, wv, q_r, k_r = jnp.split(w, _mixed_ends(cfg, KVH), axis=-1)
+        parts = dict(zip(_mixed_widths(cfg, name),
+                         jnp.split(w, _mixed_ends(cfg, name), axis=-1)))
 
         def join(r, n, heads):
+            if n is None:
+                return r
             return jnp.concatenate(
                 [r.reshape(L, D, heads, -1), n.reshape(L, D, heads, -1)],
                 axis=-1).reshape(L, D, -1)
 
-        out[name] = dict(layers, wq=join(q_r, q_n, H), wk=join(k_r, k_n, KVH),
-                         wv=wv)
+        out[name] = dict(layers, wv=parts["v"],
+                         wq=join(parts["q_r"], parts.get("q_n"), H),
+                         wk=join(parts["k_r"], parts.get("k_n"), KVH),
+                         **({"wg": parts["g"][..., :H]} if "g" in parts
+                            else {}))
     return out
 
 

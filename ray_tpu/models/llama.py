@@ -21,7 +21,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,10 +32,21 @@ from ray_tpu.ops.attention import (flash_attention, pallas_eligible,
                                    repeat_kv)
 from ray_tpu.ops.moe import up_out_in
 from ray_tpu.ops.norms import (apply_rope, mrope_tables, rms_norm,
-                               rope_frequencies)
+                               rope_frequencies, yarn_frequencies)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.sparse_attention import sparse_attention
 from ray_tpu.parallel.context import ParallelContext
+
+
+class AttentionKind(NamedTuple):
+    """One kind of a mixed-attention model's layers
+    (`LlamaConfig.attention_kind`)."""
+    kv_heads: int
+    theta: float
+    window: int
+    sink: bool
+    heads: int
+    rotary_dim: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,18 +165,28 @@ class LlamaConfig:
     # deployment, and computes their part of every layer's mixture
     # (`ops.moe.moe_ffn`); None: every expert.
     experts_held: Optional[Tuple[int, int]] = None
-    # Two kinds of ATTENTION in one stack (the MiMo-V2 family): attn_pattern
-    # says of each layer whether it attends to every earlier position (0) or
-    # to the last `window` of them, itself included (1). Both kinds have
-    # n_heads query heads, keys of head_dim and values of v_head_dim; a
-    # window layer has window_kv_heads kv heads (a full one n_kv_heads), its
-    # own window_rope_theta and, with `window_sink`, a learned logit a head
-    # that joins every query's softmax and carries no value (`sink`, a leaf).
-    # RoPE turns the first rotary_dim numbers of a q or k head and passes the
-    # rest; the values are multiplied by value_scale. The
+    # Two kinds of ATTENTION in one stack (the MiMo-V2 and Laguna families):
+    # attn_pattern says of each layer whether it attends to every earlier
+    # position (0) or to the last `window` of them, itself included (1). Both
+    # kinds have keys of head_dim and values of v_head_dim; the rest is BY
+    # KIND (`attention_kind`): a full layer has n_heads query heads on
+    # n_kv_heads kv heads, turns the first rotary_dim numbers of a q or k
+    # head at rope_theta and passes the rest, and with `rope_yarn` (its first
+    # four numbers) turns at YaRN's frequencies, its cos and sin times
+    # rope_magnitude (YaRN's `attention_factor`: on the turned part of a
+    # logit alone, squared); a window layer has window_heads query heads (0:
+    # n_heads) on window_kv_heads kv heads, turns window_rotary_dim numbers
+    # (0: rotary_dim; either kind's may be the whole head) at
+    # window_rope_theta, never under YaRN, and, with `window_sink`, has a
+    # learned logit a head that joins every query's softmax and carries no
+    # value (`sink`, a leaf). The values are multiplied by value_scale. With
+    # `attn_gate` every layer has one more projection of its normed input,
+    # `wg` `[d_model, the kind's query heads]`: head h's output is multiplied
+    # by sigmoid(h wg)_h before `wo`. The
     # parameters are stacks by kind, none holding a weight of a kind it is
     # not: `dense` (the first_dense leading layers, full attention), `window`
-    # (the window layers) and `layers` (the other full ones); `segments()` has
+    # (the window layers) and `layers` (the other full ones), `wq`, `wo` and
+    # `wg` at the kind's own width; `segments()` has
     # the order they run in. A serving cache of two shapes: pages for the full
     # layers, a ring of `window` positions a slot for the window layers
     # (`ops/paged_kv.py`).
@@ -176,6 +197,10 @@ class LlamaConfig:
     window_sink: bool = False
     rotary_dim: int = 0
     value_scale: float = 1.0
+    window_heads: int = 0
+    window_rotary_dim: int = 0
+    rope_magnitude: float = 1.0
+    attn_gate: bool = False
     # Gated short-convolution layers among the attention layers (the LFM2
     # family): every layer whose index is in `conv_layers` has, in the place
     # of attention, `models.block.conv_mixer`: an input projection cut in
@@ -289,11 +314,19 @@ class LlamaConfig:
                              "(conv_layers) and the state-space hybrid of "
                              "one-part layers (layer_parts)")
         if self.n_shared_experts and not (
-                self.n_experts and (self.latent or self.ssm_state)):
+                self.n_experts and (self.latent or self.ssm_state
+                                    or self.mixed)):
             raise ValueError("shared experts are served beside sparse "
                              "experts, by the latent-attention stack "
-                             "(kv_lora_rank > 0) and the state-space hybrid "
+                             "(kv_lora_rank > 0), the mixed-attention stack "
+                             "(attn_pattern) and the state-space hybrid "
                              "(ssm_state)")
+        if not self.mixed and (self.window_heads or self.window_rotary_dim
+                               or self.attn_gate
+                               or self.rope_magnitude != 1.0):
+            raise ValueError("window_heads, window_rotary_dim, attn_gate and "
+                             "rope_magnitude are a mixed-attention stack's "
+                             "(attn_pattern)")
         if bool(self.ssm_state) != (self.attn_layers is not None):
             raise ValueError("ssm_state and attn_layers come together: the "
                              "state-space widths and which layers are not "
@@ -354,10 +387,13 @@ class LlamaConfig:
     def _check_mixed(self) -> None:
         if self.latent or self.ssm_state or self.index_topk or self.qk_norm \
                 or self.mrope_section or self.tie_embeddings \
-                or self.rope_yarn or not self.rope:
+                or not self.rope:
             raise ValueError("mixed attention (attn_pattern) comes with no "
                              "latent attention, state-space layers, indexer, "
-                             "q/k norm, mrope, YaRN or tied head")
+                             "q/k norm, mrope or tied head")
+        if self.rope_yarn and len(self.rope_yarn) < 4:
+            raise ValueError("rope_yarn: (factor, original_max, beta_fast, "
+                             "beta_slow) for the full-attention layers")
         pattern = self.attn_pattern
         if len(pattern) != self.n_layers or set(pattern) - {0, 1}:
             raise ValueError("attn_pattern: one of 0 (full) or 1 (window) a "
@@ -371,11 +407,18 @@ class LlamaConfig:
                              "window_kv_heads say their size")
         if not self.v_head_dim:
             object.__setattr__(self, "v_head_dim", self.head_dim)
-        if self.rotary_dim % 2 or not 0 < self.rotary_dim < self.head_dim:
-            raise ValueError("rotary_dim: mixed attention rotates an even "
-                             "part of a head, 0 < rotary_dim < head_dim (the "
-                             "kernels and the caches take a key in two "
-                             "parts)")
+        for kind in ("layers", "window"):
+            _, _, _, _, heads, rotary = self.attention_kind(kind)
+            if rotary % 2 or not 0 < rotary <= self.head_dim:
+                raise ValueError(
+                    "rotary_dim, window_rotary_dim: mixed attention rotates "
+                    "an even part of a head, 0 < rotary_dim <= head_dim")
+            if heads % self.attention_kind(kind).kv_heads:
+                raise ValueError("n_heads, window_heads: whole groups of "
+                                 "query heads a kv head, by kind")
+        if self.attn_gate and self.window_sink:
+            raise ValueError("attn_gate with window_sink: no published "
+                             "model has both, and none is computed")
 
     def _check_retention(self) -> None:
         if self.mixer != "retention":
@@ -453,14 +496,28 @@ class LlamaConfig:
         """Gated short-convolution layers beside attention (`conv_layers`)."""
         return self.conv_layers is not None
 
-    def attention_kind(self, stack: str) -> Tuple[int, float, int, bool]:
-        """(kv heads, rope theta, window, sink) of the layers of one stack of
-        a mixed-attention model: `window`, or a full-attention one (`dense`,
-        `layers`), whose window is 0."""
+    def attention_kind(self, stack: str) -> "AttentionKind":
+        """(kv heads, rope theta, window, sink, query heads, rotary width) of
+        the layers of one stack of a mixed-attention model: `window`, or a
+        full-attention one (`dense`, `layers`), whose window is 0."""
         if stack == "window":
-            return (self.window_kv_heads, self.window_rope_theta, self.window,
-                    self.window_sink)
-        return self.n_kv_heads, self.rope_theta, 0, False
+            return AttentionKind(
+                self.window_kv_heads, self.window_rope_theta, self.window,
+                self.window_sink, self.window_heads or self.n_heads,
+                self.window_rotary_dim or self.rotary_dim)
+        return AttentionKind(self.n_kv_heads, self.rope_theta, 0, False,
+                             self.n_heads, self.rotary_dim)
+
+    def rope_tables(self, stack: str, n: int):
+        """(cos, sin) `[n, rotary width // 2]` of one kind of a
+        mixed-attention model's layers: the window kind's plain, the full
+        kind's YaRN's times `rope_magnitude` where the model has them."""
+        kind = self.attention_kind(stack)
+        if stack == "window" or not self.rope_yarn:
+            return rope_frequencies(kind.rotary_dim, n, kind.theta)
+        cos, sin = yarn_frequencies(kind.rotary_dim, n, kind.theta,
+                                    *self.rope_yarn[:4])
+        return cos * self.rope_magnitude, sin * self.rope_magnitude
 
     @property
     def retention(self) -> bool:
@@ -526,9 +583,10 @@ class LlamaConfig:
 
     def routing(self) -> Optional[Dict[str, Any]]:
         """`ops.moe.moe_ffn`'s `routing` but the bias, which is a leaf; None
-        for the softmax router."""
+        for the softmax router with no factor on its weights."""
         if self.router_score == "softmax":
-            return None
+            return dict(scale=self.routed_scale) \
+                if self.routed_scale != 1.0 else None
         return dict(score=self.router_score, n_group=self.n_group,
                     topk_group=self.topk_group, scale=self.routed_scale,
                     norm_eps=self.router_norm_eps)
@@ -713,22 +771,30 @@ def _mixed_axes(cfg: LlamaConfig) -> Dict[str, Any]:
                  "w_gate": (*lead, "embed", "mlp"),
                  "w_up": (*lead, "embed", "mlp"),
                  "w_down": (*lead, "mlp", "embed")}
-        if cfg.attention_kind(name)[3]:
+        if cfg.attention_kind(name).sink:
             stack["sink"] = ("layers", "heads")
+        if cfg.attn_gate:
+            stack["wg"] = ("layers", "embed", "heads")
         if sparse:
             stack["router"] = ("layers", "embed", "expert")
             if cfg.router_score == "sigmoid":
                 stack["router_bias"] = ("layers", "expert")
+            if cfg.n_shared_experts:
+                stack.update(ws_gate=("layers", "embed", "mlp"),
+                             ws_up=("layers", "embed", "mlp"),
+                             ws_down=("layers", "mlp", "embed"))
         out[name] = stack
     return out
 
 
 def _init_mixed(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     """A mixed-attention model: a stack a kind (`_mixed_stacks`), each with
-    its own kind's projections (a window layer's k and v have
-    window_kv_heads heads, and it alone has a `sink`), the experts' stacks
-    holding the experts HELD. Keys from lists of this function's own."""
-    D, H, V, pd = cfg.d_model, cfg.n_heads, cfg.vocab_size, cfg.param_dtype
+    its own kind's projections (a window layer's q, and with it `wo` and the
+    gate's `wg`, have window_heads heads, its k and v window_kv_heads, and it
+    alone has a `sink`), the experts' stacks holding the experts HELD, a
+    shared expert beside them where the model has one. Keys from lists of
+    this function's own."""
+    D, V, pd = cfg.d_model, cfg.vocab_size, cfg.param_dtype
     dk, dv = cfg.head_dim, cfg.v_head_dim
 
     def norm(shape, k, scale=0.02):
@@ -740,7 +806,7 @@ def _init_mixed(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
            "lm_head": norm((D, V), next(top))}
     for name, (L, sparse) in _mixed_stacks(cfg).items():
         ks = iter(jax.random.split(next(top), 16))
-        KVH, _, _, sink = cfg.attention_kind(name)
+        KVH, _, _, sink, H, _ = cfg.attention_kind(name)
         stack = {"attn_norm": jnp.ones((L, D), pd),
                  "wq": norm((L, D, H * dk), next(ks)),
                  "wk": norm((L, D, KVH * dk), next(ks)),
@@ -760,6 +826,10 @@ def _init_mixed(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             stack["router"] = norm((L, D, cfg.n_experts), next(ks))
             if cfg.router_score == "sigmoid":
                 stack["router_bias"] = norm((L, cfg.n_experts), next(ks))
+            if cfg.n_shared_experts:
+                stack.update(_shared_expert(cfg, L, ks, norm))
+        if cfg.attn_gate:
+            stack["wg"] = norm((L, D, H), next(ks))
         out[name] = stack
     return out
 

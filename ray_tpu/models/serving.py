@@ -147,7 +147,8 @@ class _Kind(NamedTuple):
     # The stack of parameters its layers are; None: the kind's name.
     stack: Optional[str] = None
     # How a segment's layers run. "scan": a scan over the stack's weights,
-    # sliced a layer at a time, and the layers' ordinals (the experts' stacks
+    # sliced a layer at a time, and the layers' ordinals (a segment that is
+    # part of its stack: as "index"; the experts' stacks
     # stay whole, `block.expert_stacks`, read by ordinal); "slices": over the
     # weights alone, the ordinals too only under riders; "index": over the
     # ordinals alone, the body reading its layer from the whole stack (so a
@@ -930,18 +931,26 @@ def _mixed_kind(mcfg, kind: str) -> _Kind:
     `layers`) writes a step's row to the slot's page and reads its live pages
     in place (the arena's layer its ordinal among the full layers); a window
     layer writes it to the slot's ring and reads the ring alone
-    (`ops/slot_state.py`). Each kind of attention turns at its own theta."""
+    (`ops/slot_state.py`). Each kind of attention has its own query heads
+    and turns its own part of a head at its own frequencies
+    (`LlamaConfig.attention_kind`, `rope_tables`); with `mcfg.attn_gate` a
+    head's output is gated before `wo` (`block.gated`)."""
     dt, S = mcfg.dtype, mcfg.max_seq
     dv, scale = mcfg.v_head_dim, mcfg.softmax_scale
-    _, _, window, sink = mcfg.attention_kind(kind)
+    _, _, window, sink, _, rotary = mcfg.attention_kind(kind)
     paged_decode = paged_kv.paged_decode_attention
+    # (a part of a head narrower than a tile is turned by a small matmul)
+    prompt_rope = norms.apply_rope_narrow if rotary < 128 else norms.apply_rope
+
+    def joined(n, r):       # a head's two parts as the caches hold them
+        return r if n is None else jnp.concatenate([n, r], -1)
 
     def prefill(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
         B, Sq, _ = x.shape
-        q_n, q_r, k_n, k_r, v = block.mixed_attention_inputs(
+        q_n, q_r, k_n, k_r, v, gate = block.mixed_attention_inputs(
             lp, x, mcfg, kind,
-            lambda t: norms.apply_rope_narrow(t, *ctx["tables"][kind]))
+            lambda t: prompt_rope(t, *ctx["tables"][kind]))
         with jax.named_scope("attn"):
             # The kernel and nothing else: what a roofline counts is read
             # inside the scope that times it.
@@ -949,12 +958,13 @@ def _mixed_kind(mcfg, kind: str) -> _Kind:
                 attn = attention.mixed_flash_attention(
                     q_n, q_r, k_n, k_r, v, scale, window=window,
                     sink=lp["sink"] if sink else None)
-            attn = attn.transpose(0, 2, 1, 3).reshape(B, Sq, -1)
+            attn = block.gated(attn.transpose(0, 2, 1, 3), gate).reshape(
+                B, Sq, -1)
         with jax.named_scope("attn_out"):
             x = x + jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt))
         x, routed = block.feed_forward(lp, x, mcfg, ctx["live"],
                                        l if routed_layer else None)
-        kept = (jnp.concatenate([k_n, k_r], -1)[0].transpose(1, 0, 2),
+        kept = (joined(k_n, k_r)[0].transpose(1, 0, 2),
                 v[0].transpose(1, 0, 2))          # [S, KVH, dk], [S, KVH, dv]
         return x, caches, kept, \
             _share_stats(routed[1], ctx["live"], mcfg) if routed_layer \
@@ -973,9 +983,9 @@ def _mixed_kind(mcfg, kind: str) -> _Kind:
         ns = x.shape[0]
         bt, w, act = ctx["bt"], ctx["w"], ctx["act"]
         kc, vc, _, state = caches
-        q_n, q_r, k_n, k_r, v = block.mixed_attention_inputs(
+        q_n, q_r, k_n, k_r, v, gate = block.mixed_attention_inputs(
             lp, x, mcfg, kind, lambda t: _rope_one(t, ctx["c"], ctx["s"]))
-        q, k = (jnp.concatenate(t, -1) for t in ((q_n, q_r), (k_n, k_r)))
+        q, k = joined(q_n, q_r), joined(k_n, k_r)
         if window:
             state = slot_state.write_window_token(state, l, w, act, k, v)
             with jax.named_scope("attn"):
@@ -989,15 +999,16 @@ def _mixed_kind(mcfg, kind: str) -> _Kind:
             with jax.named_scope("attn"):
                 with jax.named_scope("full_attn"):
                     # q in the lanes a cached key lies in: zeros meet the
-                    # arena's padding
+                    # arena's padding (none where a key is whole tiles)
+                    lanes = kc.shape[-1] - q.shape[-1]
                     attn = paged_decode(
-                        jnp.pad(q, ((0, 0), (0, 0),
-                                    (0, kc.shape[-1] - q.shape[-1]))),
+                        jnp.pad(q, ((0, 0), (0, 0), (0, lanes)))
+                        if lanes else q,
                         kc, vc, ctx["base"] + l, bt, ctx["lengths"],
                         sm_scale=scale)
         with jax.named_scope("attn_out"):
-            x = x + attn[..., :dv].astype(dt).reshape(ns, -1) \
-                @ lp["wo"].astype(dt)
+            x = x + block.gated(attn[..., :dv].astype(dt), gate).reshape(
+                ns, -1) @ lp["wo"].astype(dt)
         x, routed = block.feed_forward(lp, x, mcfg, act,
                                        l if routed_layer else None)
         return x, caches._replace(kc=kc, vc=vc, state=state), \
@@ -1084,8 +1095,7 @@ def _stack(mcfg) -> _Stack:
             {kind: _mixed_kind(mcfg, kind)
              for kind in ("dense", "window", "layers")},
             lambda n, rows: dict(tables={
-                kind: norms.rope_frequencies(mcfg.rotary_dim, n,
-                                             mcfg.attention_kind(kind)[1])
+                kind: mcfg.rope_tables(kind, n)
                 for kind, _, _ in mcfg.segments()}),
             # pages for the full layers, a ring a slot for the rest
             lambda ns, page, n_pages: Caches(
@@ -1113,6 +1123,36 @@ def _stack(mcfg) -> _Stack:
                                     mcfg.index_head_dim, dt)
             if indexed else None),
         lambda c: {}, takes_riders=plain, adopts=plain)
+
+
+def rung_refusal(mcfg, width: int) -> Optional[str]:
+    """Why a prefill of `width` rows would run its attention in XLA on a TPU,
+    not in a Pallas kernel; None: it would not. A mixed stack's kernels take
+    some shapes and `ops.attention.mixed_flash_attention` falls to its
+    reference for the rest, which from outside shows in the path counts
+    alone: a rung of a block or more (128 rows: a prompt under one is a few
+    score matrices in XLA, by design) that no kernel takes is a
+    configuration to refuse when its server is built (`Engine`)."""
+    if not mcfg.mixed or width < 128:
+        return None
+    for name in ("layers", "window"):       # the full kind, the window kind
+        kind = mcfg.attention_kind(name)
+        why = attention.mixed_kernel_refusal(
+            width, mcfg.head_dim - kind.rotary_dim, kind.rotary_dim,
+            mcfg.v_head_dim, kind.window, kind.sink)
+        if why:
+            return f"stack `{name}`: {why}"
+    return None
+
+
+def check_rungs(mcfg, widths) -> None:
+    """On a TPU, refuse a model whose prefills of these widths would fall
+    to XLA's attention (`rung_refusal`), naming each shape."""
+    refused = [why for why in (rung_refusal(mcfg, w) for w in widths) if why] \
+        if attention._on_tpu() else []
+    if refused:
+        raise ValueError("this model's prompts would run their attention in "
+                         "XLA, not in a kernel: " + "; ".join(refused))
 
 
 def adopts(mcfg) -> bool:
@@ -1180,16 +1220,28 @@ def _over(kind: _Kind, layers, mcfg, body, ordinals=True):
         return lambda lo, hi, carry: body(
             dict(_layer_of(sliced, lo), **whole), lo, carry)
     by_index = kind.over == "index"
+    stacked = jax.tree.leaves(sliced)[0].shape[0]
 
     def layer(carry, xs):
-        if by_index:
-            return body(dict(_layer_of(sliced, xs), **whole), xs, carry)
         lp, l = xs if ordinals else (xs, None)
         return body(dict(lp, **whole), l, carry)
 
-    return lambda lo, hi, carry: jax.lax.scan(
-        layer, carry, jnp.arange(lo, hi) if by_index
-        else (sliced, jnp.arange(lo, hi)) if ordinals else sliced)
+    def indexed(carry, l):
+        return body(dict(_layer_of(sliced, l), **whole),
+                    l if ordinals or by_index else None, carry)
+
+    def run(lo, hi, carry):
+        # A segment that is PART of its stack (a kind whose layers another
+        # kind's interrupt: Laguna's window layers) reads its layers by
+        # index too: a slice of the stack as a scan's xs is a copy of those
+        # layers' weights every call (1.3 GB a decode chunk at Laguna's
+        # widths, compiled for a v5e, PR 62).
+        if by_index or hi - lo != stacked:
+            return jax.lax.scan(indexed, carry, jnp.arange(lo, hi))
+        return jax.lax.scan(layer, carry, (sliced, jnp.arange(lo, hi))
+                            if ordinals else sliced)
+
+    return run
 
 
 # ---------------------------------------------------------------------------

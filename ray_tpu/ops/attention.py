@@ -303,7 +303,7 @@ def _latent_flash_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
     """`_flash_fwd_kernel`, causal, with a head's score the sum of two
     products: its own part of the key (`kn`) and the rotary part every head
     shares (`kr`, one array for all heads: the grid hands each head the same
-    block of it)."""
+    block of it). `qr_ref` and `kr_ref` None: a key of ONE part."""
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
 
@@ -315,11 +315,12 @@ def _latent_flash_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
 
     def _body(masked: bool):
         contract = (((1,), (1,)), ((), ()))
-        s = (jax.lax.dot_general(qn_ref[0], kn_ref[0], contract,
-                                 preferred_element_type=jnp.float32)
-             + jax.lax.dot_general(qr_ref[0], kr_ref[0], contract,
-                                   preferred_element_type=jnp.float32)
-             ) * sm_scale
+        s = jax.lax.dot_general(qn_ref[0], kn_ref[0], contract,
+                                preferred_element_type=jnp.float32)
+        if qr_ref is not None:
+            s = s + jax.lax.dot_general(qr_ref[0], kr_ref[0], contract,
+                                        preferred_element_type=jnp.float32)
+        s = s * sm_scale
         if masked:
             q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
@@ -352,16 +353,21 @@ def _latent_flash_pallas(q_n, q_r, k_n, k_r, v, *, sm_scale, interpret,
                          name="latent_flash_fwd"):
     """k_n and v `[b, KVH, s, d]`: query head h reads kv head h // (H // KVH)
     where it lies (no copy a query head). k_r `[b, s, dr]`, one for all
-    heads, or `[b, KVH, s, dr]`, one a kv head."""
+    heads, or `[b, KVH, s, dr]`, one a kv head; q_r and k_r None: a key of
+    one part, q_n and k_n the whole of it."""
     b, h, s, dn = q_n.shape
-    dr, dv = q_r.shape[-1], v.shape[-1]
+    dv = v.shape[-1]
     kvh = k_n.shape[1]
-    groups = h // kvh
     block_q = _pick_block(s, block_q)
     block_k = _pick_block(s, block_k)
     kernel = functools.partial(
         _latent_flash_kernel, sm_scale=sm_scale, block_q=block_q,
         block_k=block_k, kv_seq_len=s)
+    if q_r is None:
+        two_parts = kernel
+
+        def kernel(q_ref, k_ref, v_ref, *rest):
+            two_parts(q_ref, None, k_ref, None, v_ref, *rest)
 
     def by_q(d):
         return pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
@@ -373,14 +379,24 @@ def _latent_flash_pallas(q_n, q_r, k_n, k_r, v, *, sm_scale, interpret,
             lambda bh, qi, ki: (bh, ki, 0)) if over == 1 else (
             lambda bh, qi, ki: (bh // over, ki, 0)))
 
+    if q_r is None:
+        in_specs = [by_q(dn), by_k(dn), by_k(dv)]
+        operands = (q_n.reshape(b * h, s, dn), k_n.reshape(b * kvh, s, dn),
+                    v.reshape(b * kvh, s, dv))
+    else:
+        dr = q_r.shape[-1]
+        in_specs = [by_q(dn), by_q(dr), by_k(dn),
+                    # the rotary key: one for all heads, or one a kv head
+                    by_k(dr, 1 if k_r.ndim == 3 else kvh),
+                    by_k(dv)]
+        operands = (q_n.reshape(b * h, s, dn), q_r.reshape(b * h, s, dr),
+                    k_n.reshape(b * kvh, s, dn), k_r.reshape(-1, s, dr),
+                    v.reshape(b * kvh, s, dv))
     out = pl.pallas_call(
         kernel,
         name=name,
         grid=(b * h, s // block_q, s // block_k),
-        in_specs=[by_q(dn), by_q(dr), by_k(dn),
-                  # the rotary key: one for all heads, or one a kv head
-                  by_k(dr, 1 if k_r.ndim == 3 else kvh),
-                  by_k(dv)],
+        in_specs=in_specs,
         out_specs=by_q(dv),
         out_shape=_out_struct((b * h, s, dv), v.dtype, v),
         scratch_shapes=[
@@ -391,9 +407,7 @@ def _latent_flash_pallas(q_n, q_r, k_n, k_r, v, *, sm_scale, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q_n.reshape(b * h, s, dn), q_r.reshape(b * h, s, dr),
-      k_n.reshape(b * kvh, s, dn), k_r.reshape(-1, s, dr),
-      v.reshape(b * kvh, s, dv))
+    )(*operands)
     return out.reshape(b, h, s, dv)
 
 
@@ -522,17 +536,110 @@ def _window_flash_pallas(q_n, q_r, k_n, k_r, v, sink, *, sm_scale, window,
     return out.reshape(b, h, s, dv)
 
 
+def _window_blocks_kernel(q_ref, *refs, sm_scale: float, window: int,
+                          block: int, n_back: int):
+    """`_window_flash_kernel` for a window of SEVERAL blocks and a key of one
+    part: one grid step = one block of `block` queries of ALL the query heads
+    of one kv head (`g * block` rows) against the `n_back + 1` key blocks its
+    window touches, the oldest first and the diagonal one last (`refs`: their
+    keys, then their values, then the result). One softmax over their
+    columns: no running statistics, the scores of a step are `g * block` by
+    `(n_back + 1) * block` float32 (2.9 MB at 9 heads a group, a window of
+    512 and blocks of 128). Element masks on the diagonal block (causal) and
+    on those the window's far edge crosses alone; a block before position 0
+    (the index map handed block 0 again) is masked whole."""
+    qi = pl.program_id(1)
+    k_refs, v_refs, o_ref = refs[:n_back + 1], refs[n_back + 1:-1], refs[-1]
+    g, _, d = q_ref.shape[1:]
+    rows = g * block
+    q = q_ref[0].reshape(rows, d)
+    # a row's query is `r` rows into its block, a column's key `c` into its
+    r = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0),
+                    block)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+    scores = []
+    for j, k_ref in enumerate(k_refs):
+        back = n_back - j           # the key block lies `back` blocks back
+        s = jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+        ago = r - c + back * block  # positions from the key to the query
+        if back == 0:
+            live = (ago >= 0) & (ago < window)
+        elif (back + 1) * block - 1 < window:
+            live = qi >= back
+        else:
+            live = (ago < window) & (qi >= back)
+        scores.append(jnp.where(live, s, DEFAULT_MASK_VALUE))
+    m = functools.reduce(jnp.maximum, (jnp.max(s, axis=-1, keepdims=True)
+                                       for s in scores))
+    l, o = 0.0, 0.0
+    for s, v_ref in zip(scores, v_refs):
+        p = jnp.exp(s - m)
+        l = l + jnp.sum(p, axis=-1, keepdims=True)
+        o = o + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    o_ref[0] = (o / l).astype(o_ref.dtype).reshape(o_ref.shape[1:])
+
+
+# The query block of `_window_blocks_kernel`: of a window of 512 it computes
+# 640 columns a query where the window holds 512.
+_WINDOW_BLOCK = 128
+
+
+def _window_blocks_pallas(q, k, v, *, sm_scale, window, interpret,
+                          block=_WINDOW_BLOCK):
+    b, h, s, d = q.shape
+    dv, kvh = v.shape[-1], k.shape[1]
+    g = h // kvh
+    block = min(block, s)
+    # blocks before the diagonal one that a query block's window reaches
+    n_back = min(-(-(window - 1) // block), s // block - 1)
+    kernel = functools.partial(_window_blocks_kernel, sm_scale=sm_scale,
+                               window=window, block=block, n_back=n_back)
+
+    def by_q(width):
+        return pl.BlockSpec((1, g, block, width),
+                            lambda bk, qi: (bk, 0, qi, 0))
+
+    def by_k(width, back):
+        return pl.BlockSpec(
+            (1, block, width),
+            lambda bk, qi: (bk, jnp.maximum(qi - back, 0), 0))
+
+    backs = range(n_back, -1, -1)
+    k, v = k.reshape(b * kvh, s, d), v.reshape(b * kvh, s, dv)
+    out = pl.pallas_call(
+        kernel,
+        name="window_blocks_fwd",
+        grid=(b * kvh, s // block),
+        in_specs=[by_q(d), *(by_k(d, back) for back in backs),
+                  *(by_k(dv, back) for back in backs)],
+        out_specs=by_q(dv),
+        out_shape=_out_struct((b * kvh, g, s, dv), v.dtype, v),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(q.reshape(b * kvh, g, s, d), *(k for _ in backs), *(v for _ in backs))
+    return out.reshape(b, h, s, dv)
+
+
 def mixed_attention_reference(q_n, q_r, k_n, k_r, v, sm_scale, window=0,
                               sink=None):
     """The XLA path of `mixed_flash_attention`: every score, a mask, a
     float32 softmax with the sink's column."""
-    b, h, s, _ = q_n.shape
-    groups = h // k_n.shape[1]
-    k_n, k_r, v = (repeat_kv(t, groups) for t in (k_n, k_r, v))
-    scores = (jnp.einsum("bhqd,bhkd->bhqk", q_n, k_n,
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("bhqd,bhkd->bhqk", q_r, k_r,
-                           preferred_element_type=jnp.float32)) * sm_scale
+    b, h, s, _ = q_r.shape
+    groups = h // k_r.shape[1]
+    k_n, k_r, v = (None if t is None else repeat_kv(t, groups)
+                   for t in (k_n, k_r, v))
+
+    def dots(q, k):
+        return jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                          preferred_element_type=jnp.float32)
+
+    scores = dots(q_r, k_r) if q_n is None \
+        else dots(q_n, k_n) + dots(q_r, k_r)
+    scores = scores * sm_scale
     pos = jnp.arange(s)
     live = pos[:, None] >= pos[None, :]
     if window:
@@ -547,13 +654,44 @@ def mixed_attention_reference(q_n, q_r, k_n, k_r, v, sm_scale, window=0,
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v)
 
 
+def mixed_kernel_refusal(s: int, dn: int, dr: int, dv: int, window: int,
+                         sink: bool = False) -> Optional[str]:
+    """Why `mixed_flash_attention` has no Pallas kernel for a prompt of `s`
+    rows whose heads pass `dn` numbers and turn `dr`, with values of `dv`, in
+    a window layer (`window` > 0) or a full one; None: it has one. A key in
+    two parts needs its passed part in whole tiles and `s` in whole blocks of
+    the window rounded up to a tile; a key whose parts together are one or
+    more whole tiles is joined and goes as ONE part, in blocks of 128 rows
+    (a shorter prompt one block), with no sink. A server asks this for every
+    rung it will prefill at (`models.serving.rung_refusal`): elsewhere the
+    fall to `mixed_attention_reference` is silent but for the path counts."""
+    kind = f"{'window ' + str(window) if window else 'full'} attention, " \
+        f"{s} rows, heads of {dn} passed + {dr} turned numbers, values of " \
+        f"{dv}"
+    if dv % 128:
+        return f"{kind}: values in whole tiles of 128"
+    if dn and dn % 128 == 0:
+        block = -(-window // 128) * 128 if window else 128
+        return None if s % block == 0 else \
+            f"{kind}: rows in whole blocks of {block}"
+    if (dn + dr) % 128:
+        return f"{kind}: a key of whole tiles of 128, or a passed part of " \
+            "them"
+    if window and sink:
+        return f"{kind}: no sink beside a key of one part"
+    return None if s % min(s, 128) == 0 and s % 16 == 0 else \
+        f"{kind}: rows in whole blocks of 128, or one block of whole tiles"
+
+
 def mixed_flash_attention(q_n, q_r, k_n, k_r, v, sm_scale: float, *,
                           window: int = 0, sink=None,
                           interpret: bool = False):
     """Causal attention of a prompt in a stack of window and full attention
     layers (`LlamaConfig.attn_pattern`). Query head h is `[q_n[h] ; q_r[h]]`
     (the part RoPE passed, the part it turned: 128 and 64 at MiMo-V2's
-    widths), its key `[k_n ; k_r]` of kv head `h // (H // KVH)`, its value
+    widths; 64 and 64 in Laguna's full layers; q_n and k_n None where it
+    turned the whole head, Laguna's window layers), its key `[k_n ; k_r]` of
+    kv head `h // (H // KVH)`, its value
     that head's `v`, of a width of its own (128). q_n `[b, H, s, dn]`, q_r
     `[b, H, s, dr]`, k_n `[b, KVH, s, dn]`, k_r `[b, KVH, s, dr]`, v `[b,
     KVH, s, dv]` -> `[b, H, s, dv]`. K and V are read by kv head, never
@@ -561,28 +699,43 @@ def mixed_flash_attention(q_n, q_r, k_n, k_r, v, sm_scale: float, *,
 
     `window` > 0: query i attends to keys j with `0 <= i - j < window`, and
     with `sink` `[H]` one further column of that logit a head joins the
-    softmax and carries no value. On a TPU (or with `interpret`) the Pallas
-    kernel `window_flash_fwd`, which visits ONLY the key blocks a query
-    block's window touches, the diagonal one and the one before it: its work
-    grows with `s`, not `s^2`. Counted as `window_fwd_pallas` /
+    softmax and carries no value. On a TPU (or with `interpret`) a Pallas
+    kernel that visits ONLY the key blocks a query block's window touches,
+    so that its work grows with `s`, not `s^2`: `window_flash_fwd` for a key
+    in two parts and a window of one block (the diagonal block and the one
+    before it), `window_blocks_fwd` for a key of one part and a window of
+    any number of blocks of 128. Counted as `window_fwd_pallas` /
     `window_fwd_reference`.
 
     `window` 0: every earlier position; the kernel `full_flash_fwd`
     (`_latent_flash_kernel`'s online softmax over key blocks, the key in two
-    parts), counted as `full_fwd_pallas` / `full_fwd_reference`.
+    parts or in one), counted as `full_fwd_pallas` / `full_fwd_reference`.
 
-    Elsewhere, or where a shape is not a kernel's (`s` no multiple of the
-    block, a part of the head no multiple of its tile), the XLA reference
-    `mixed_attention_reference`."""
-    s, dn, dv = q_n.shape[2], q_n.shape[-1], v.shape[-1]
+    A key whose passed part is no whole tile but whose parts together are
+    (64 + 64, 0 + 128) goes to the kernels as ONE part, `[k_n ; k_r]` joined
+    here: one product over 128 numbers where two parts of 64 are two over
+    half a tile each.
+
+    Elsewhere, or where a shape is not a kernel's (`mixed_kernel_refusal`),
+    the XLA reference `mixed_attention_reference`."""
+    s, dr, dv = q_r.shape[2], q_r.shape[-1], v.shape[-1]
+    dn = 0 if q_n is None else q_n.shape[-1]
     kind = "window" if window else "full"
-    block = -(-window // 128) * 128 if window else 128
-    use = interpret or _on_tpu()
-    use = use and s % block == 0 and dn % 128 == 0 and dv % 128 == 0
+    use = (interpret or _on_tpu()) and mixed_kernel_refusal(
+        s, dn, dr, dv, window, sink is not None) is None
     _path_counts[f"{kind}_fwd_pallas" if use else f"{kind}_fwd_reference"] += 1
     if not use:
         return mixed_attention_reference(q_n, q_r, k_n, k_r, v, sm_scale,
                                          window, sink)
+    if dn % 128 or not dn:
+        q, k = (t[1] if t[0] is None else jnp.concatenate(t, -1)
+                for t in ((q_n, q_r), (k_n, k_r)))
+        if window:
+            return _window_blocks_pallas(q, k, v, sm_scale=sm_scale,
+                                         window=window, interpret=interpret)
+        return _latent_flash_pallas(q, None, k, None, v, sm_scale=sm_scale,
+                                    interpret=interpret,
+                                    name="full_flash_fwd")
     if window:
         if sink is None:
             sink = jnp.full((q_n.shape[1],), DEFAULT_MASK_VALUE, jnp.float32)

@@ -65,7 +65,8 @@ def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True,
 
     `score` "softmax": the weights are the float32 softmax over all experts at
     the k largest; `norm_topk_prob` renormalises them to sum to one (which
-    equals the softmax over the selected k, Mixtral's; OLMoE publishes False).
+    equals the softmax over the selected k, Mixtral's; OLMoE publishes False);
+    times `scale` where it is not 1 (the Laguna family's 2.5).
 
     `score` "sigmoid" (DeepSeek-V3's `noaux_tc`): s = sigmoid(logits) in
     float32; the CHOICE is made on s' = s + `bias` (the selection bias, which
@@ -82,7 +83,7 @@ def top_k_routing(gate_logits: jax.Array, k: int, norm_topk_prob: bool = True,
         weights, idx = jax.lax.top_k(probs, k)
         if norm_topk_prob:
             weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-        return weights, idx
+        return (weights if scale == 1.0 else weights * scale), idx
     if score != "sigmoid":
         raise ValueError(f"router score {score!r}: 'softmax' or 'sigmoid'")
     s = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
@@ -168,9 +169,10 @@ _BLOCKS_VMEM = 13 << 20
 # fast at best and a third slower at worst (PERF.md, PR 48, the probe's
 # table). And the most rows a block may have:
 # their tokens and weights lie in scalar memory, 8 bytes a row of its 1 MiB
-# (the cells' widest block has 16,384; 65,536 still compile for a v5e).
+# (the cells' widest block has 40,960, Laguna's 8,192 rows x 10 experts a
+# token at a share of an eighth; 65,536 still compile for a v5e).
 _COMBINE_CHUNK = 16
-_COMBINE_ROWS = 1 << 15
+_COMBINE_ROWS = 1 << 16
 
 
 def _tiling(m: int, k: int, n: int) -> Optional[Tuple[int, int]]:

@@ -206,6 +206,7 @@ class Engine:
 
         from ray_tpu.models import serving
 
+        serving.check_rungs(mcfg, prefill_widths(mcfg.max_seq))
         self._np = np
         self._jnp = jnp
         self.mcfg = mcfg

@@ -813,7 +813,9 @@ def test_mimo_manifest_entries_are_the_catalogs_row_and_the_issues_traffic():
     lists = {p["name"]: p.get("workloads", []) for p in manifest["per_layer"]}
     at = list(lists).index(MIMO_READERS[0])
     assert list(lists)[at:at + 9] == MIMO_READERS
-    assert all(lists[n] == [MIMO_CELL] for n in MIMO_READERS)
+    # (PR 62's cell, the other mixed stack, reads them too)
+    assert all(lists[n] == [MIMO_CELL, "serve-longdoc-laguna"]
+               for n in MIMO_READERS)
     for name in ("prefill_ms_per_ktok", "kv_pages_peak_pct", "decode_moe_ms",
                  "prefill_moe_ms_per_ktok", "expert_load_max_over_mean",
                  "engine_slot_refill_ms", "prefill_stall_pct",

@@ -76,8 +76,8 @@ def test_the_stack_is_segments_by_kind_and_stacks_hold_their_own_kind(tiny):
     assert cfg.segments() == (("dense", 0, 1), ("window", 0, 2),
                               ("layers", 0, 1))
     assert cfg.kv_layers == 2 and cfg.rotary_dim == 16
-    assert cfg.attention_kind("window") == (2, 10000.0, WINDOW, True)
-    assert cfg.attention_kind("layers") == (1, 5000000.0, 0, False)
+    assert cfg.attention_kind("window") == (2, 10000.0, WINDOW, True, 4, 16)
+    assert cfg.attention_kind("layers") == (1, 5000000.0, 0, False, 4, 16)
     # the published order: layer 0 full, 1-4 window, 5 full, then five
     # window and one full, seven times
     long = llama.LlamaConfig.tiny(
@@ -111,12 +111,12 @@ def test_the_stack_is_segments_by_kind_and_stacks_hold_their_own_kind(tiny):
 @pytest.mark.parametrize("change,said", [
     (dict(kv_lora_rank=8), "latent attention"),
     (dict(window=0), "window and window_kv_heads"),
-    (dict(rotary_dim=48), "rotary_dim"),
+    (dict(rotary_dim=50), "rotary_dim"),
     (dict(attn_pattern=(1, 1, 1, 0)), "leading layers"),
     (dict(attn_pattern=(0, 1, 1)), "one of 0"),
     (dict(attn_pattern=None), "first_dense"),
     (dict(attn_pattern=None, first_dense=0), "a share of the experts"),
-], ids=["latent", "no-window", "whole-head-turned", "dense-window",
+], ids=["latent", "no-window", "more-than-a-head-turned", "dense-window",
         "short-pattern", "dense-without-segments", "share-without-segments"])
 def test_the_config_refuses_by_name(tiny, change, said):
     import dataclasses
