@@ -102,9 +102,9 @@ class Programs(NamedTuple):
     adopt: Callable
     poke: Callable
     # Whether the prefill program of a riding rung carries the live slots
-    # (`_stack`'s answer: a dense, a sparse, a state-space, a conv and a latent
-    # stack do; an indexed, a mixed, a retention stack and one that generates
-    # by blocks take nobody).
+    # (`_stack`'s answer: a dense, a sparse, a state-space, a conv, a latent
+    # and a mixed stack do; an indexed, a retention stack and one that
+    # generates by blocks take nobody).
     takes_riders: bool
     # Whether a hand-off's K and V can be adopted (`adopts`).
     adopts: bool
@@ -925,7 +925,53 @@ def _latent_kind(mcfg) -> _Kind:
                  pack=lambda c, kr: (jnp.concatenate([c, kr], axis=-1), None))
 
 
-def _mixed_kind(mcfg, kind: str) -> _Kind:
+def _mixed_steps(mcfg) -> Tuple[Callable, Callable]:
+    """One token a slot against each of a mixed stack's two caches -> (ring,
+    pages): the step's k and v `[n_slots, kv_heads, d]` written at the slots'
+    positions `w`, then each active slot's q `[n_slots, heads, head_dim]`
+    against what the cache holds of it (an idle slot reads nothing) -> the
+    cache moved and the kernel's own result, float32 or padded as it comes.
+    `ring(state, l, w, act, q, k, v, sink)`: a window layer's, the row into
+    the slot's ring and the ring alone read. `pages(kc, vc, base, l, bt, w,
+    act, lengths, q, k, v)`: a full layer's (the arena's layer `base + l`),
+    the row into the slot's page and its live pages read in place. A jit
+    each, and ONE for the riders, for the decode program's layers and for
+    both stacks of full layers, as `_attention_kind`'s `_token_step` is
+    (which see): no prefill width enters their shapes, so each is traced once
+    a process, not once a riding rung and again for decode."""
+    scale = mcfg.softmax_scale
+    window = mcfg.attention_kind("window").window
+    # What a program is built with is the function as it stands on its
+    # module now (a test puts an interpreted kernel there first).
+    paged_decode = paged_kv.paged_decode_attention
+
+    @jax.jit
+    def ring(state, l, w, act, q, k, v, sink):
+        state = slot_state.write_window_token(state, l, w, act, k, v)
+        with jax.named_scope("attn"):
+            with jax.named_scope("window_attn"):
+                attn = slot_state.window_decode_attention(
+                    q, state, l, w, act, window=window, sm_scale=scale,
+                    sink=sink)
+        return state, attn
+
+    @jax.jit
+    def pages(kc, vc, base, l, bt, w, act, lengths, q, k, v):
+        kc, vc = paged_kv.write_token(kc, vc, base + l, bt, w, act, k, v)
+        with jax.named_scope("attn"):
+            with jax.named_scope("full_attn"):
+                # q in the lanes a cached key lies in: zeros meet the
+                # arena's padding (none where a key is whole tiles)
+                lanes = kc.shape[-1] - q.shape[-1]
+                attn = paged_decode(
+                    jnp.pad(q, ((0, 0), (0, 0), (0, lanes))) if lanes else q,
+                    kc, vc, base + l, bt, lengths, sm_scale=scale)
+        return kc, vc, attn
+
+    return ring, pages
+
+
+def _mixed_kind(mcfg, kind: str, steps: Tuple[Callable, Callable]) -> _Kind:
     """A layer of a stack of window and full attention layers
     (`mcfg.attn_pattern`), of the stack `kind`. A full layer (`dense`,
     `layers`) writes a step's row to the slot's page and reads its live pages
@@ -934,16 +980,36 @@ def _mixed_kind(mcfg, kind: str) -> _Kind:
     (`ops/slot_state.py`). Each kind of attention has its own query heads
     and turns its own part of a head at its own frequencies
     (`LlamaConfig.attention_kind`, `rope_tables`); with `mcfg.attn_gate` a
-    head's output is gated before `wo` (`block.gated`)."""
+    head's output is gated before `wo` (`block.gated`). Its prefill takes
+    RIDERS: the tail rows' q, k and v from those rows alone, turned at the
+    slots' positions, through the step `decode` runs (`steps`, the stack's
+    `_mixed_steps`), before a gate, a `wo` and a feed-forward that run once
+    over the bucket."""
     dt, S = mcfg.dtype, mcfg.max_seq
     dv, scale = mcfg.v_head_dim, mcfg.softmax_scale
     _, _, window, sink, _, rotary = mcfg.attention_kind(kind)
-    paged_decode = paged_kv.paged_decode_attention
+    ring, pages = steps
     # (a part of a head narrower than a tile is turned by a small matmul)
     prompt_rope = norms.apply_rope_narrow if rotary < 128 else norms.apply_rope
 
     def joined(n, r):       # a head's two parts as the caches hold them
         return r if n is None else jnp.concatenate([n, r], -1)
+
+    def token_step(lp, caches, base, l, bt, w, act, lengths, q_n, q_r, k_n,
+                   k_r, v):
+        """One token a slot through this layer's attention against its
+        cache, from a step's `block.mixed_attention_inputs` -> (caches, attn
+        `[n_slots, heads, dv]`)."""
+        q, k = joined(q_n, q_r), joined(k_n, k_r)
+        if window:
+            state, attn = ring(caches.state, l, w, act, q, k, v,
+                               lp["sink"] if sink else None)
+            caches = caches._replace(state=state)
+        else:
+            kc, vc, attn = pages(caches.kc, caches.vc, base, l, bt, w, act,
+                                 lengths, q, k, v)
+            caches = caches._replace(kc=kc, vc=vc)
+        return caches, attn[..., :dv].astype(dt)
 
     def prefill(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
@@ -958,6 +1024,26 @@ def _mixed_kind(mcfg, kind: str) -> _Kind:
                 attn = attention.mixed_flash_attention(
                     q_n, q_r, k_n, k_r, v, scale, window=window,
                     sink=lp["sink"] if sink else None)
+        if ctx["riders"]:
+            # ONE decode step of the riding slots in the bucket's tail rows,
+            # as `decode` takes it: those rows' inputs from `x` ALONE, turned
+            # at the slots' positions (the walk's tables hold a row's own),
+            # and the result put into the kernel's own output `[1, heads,
+            # rows, dv]` before its transpose and the gate, by an update of
+            # n_slots rows a head (`_latent_kind`, which see). The gate's
+            # rows there are the bucket's: the same rows of `x`.
+            bt, w, act = ctx["riders"]
+            tail = slice(Sq - act.shape[0], Sq)
+            cos, sin = (t[tail][:, None] for t in ctx["tables"][kind])
+            caches, rode = token_step(
+                lp, caches, ctx["base"], l, bt, w, act,
+                jnp.where(act, w + 1, 0), *block.mixed_attention_inputs(
+                    lp, x[0, tail], mcfg, kind,
+                    lambda t: _rope_one(t, cos, sin))[:5])
+            attn = attn.at[0, :, tail].set(jnp.where(
+                act[None, :, None], rode.transpose(1, 0, 2),
+                attn[0, :, tail]))
+        with jax.named_scope("attn"):
             attn = block.gated(attn.transpose(0, 2, 1, 3), gate).reshape(
                 B, Sq, -1)
         with jax.named_scope("attn_out"):
@@ -981,37 +1067,19 @@ def _mixed_kind(mcfg, kind: str) -> _Kind:
     def decode(lp, x, caches, l, ctx):
         routed_layer = "router" in lp
         ns = x.shape[0]
-        bt, w, act = ctx["bt"], ctx["w"], ctx["act"]
-        kc, vc, _, state = caches
-        q_n, q_r, k_n, k_r, v, gate = block.mixed_attention_inputs(
+        act = ctx["act"]
+        # The write and the kernel as the riders' step has them, traced once
+        # for both.
+        *qkv, gate = block.mixed_attention_inputs(
             lp, x, mcfg, kind, lambda t: _rope_one(t, ctx["c"], ctx["s"]))
-        q, k = joined(q_n, q_r), joined(k_n, k_r)
-        if window:
-            state = slot_state.write_window_token(state, l, w, act, k, v)
-            with jax.named_scope("attn"):
-                with jax.named_scope("window_attn"):
-                    attn = slot_state.window_decode_attention(
-                        q, state, l, w, act, window=window, sm_scale=scale,
-                        sink=lp["sink"] if sink else None)
-        else:
-            kc, vc = paged_kv.write_token(kc, vc, ctx["base"] + l, bt, w,
-                                          act, k, v)
-            with jax.named_scope("attn"):
-                with jax.named_scope("full_attn"):
-                    # q in the lanes a cached key lies in: zeros meet the
-                    # arena's padding (none where a key is whole tiles)
-                    lanes = kc.shape[-1] - q.shape[-1]
-                    attn = paged_decode(
-                        jnp.pad(q, ((0, 0), (0, 0), (0, lanes)))
-                        if lanes else q,
-                        kc, vc, ctx["base"] + l, bt, ctx["lengths"],
-                        sm_scale=scale)
+        caches, attn = token_step(lp, caches, ctx["base"], l, ctx["bt"],
+                                  ctx["w"], act, ctx["lengths"], *qkv)
         with jax.named_scope("attn_out"):
-            x = x + block.gated(attn[..., :dv].astype(dt), gate).reshape(
-                ns, -1) @ lp["wo"].astype(dt)
+            x = x + block.gated(attn, gate).reshape(ns, -1) \
+                @ lp["wo"].astype(dt)
         x, routed = block.feed_forward(lp, x, mcfg, act,
                                        l if routed_layer else None)
-        return x, caches._replace(kc=kc, vc=vc, state=state), \
+        return x, caches, \
             _share_stats(routed[1], act, mcfg) if routed_layer else None
 
     # The ring rows a window layer's decode steps read (a slot's own and the
@@ -1091,8 +1159,9 @@ def _stack(mcfg) -> _Stack:
             lambda c: {"latent_cache_bytes": slot_state.state_bytes([c.kc])},
             takes_riders=True, shares=True, tally="first")
     if mcfg.mixed:
+        steps = _mixed_steps(mcfg)
         return _Stack(
-            {kind: _mixed_kind(mcfg, kind)
+            {kind: _mixed_kind(mcfg, kind, steps)
              for kind in ("dense", "window", "layers")},
             lambda n, rows: dict(tables={
                 kind: mcfg.rope_tables(kind, n)
@@ -1106,7 +1175,7 @@ def _stack(mcfg) -> _Stack:
                     mcfg.v_head_dim, dt)),
             lambda c: {"full_cache_bytes": slot_state.state_bytes(c[:2]),
                        "window_cache_bytes": slot_state.state_bytes(c.state)},
-            shares=True, tally="zero")
+            takes_riders=True, shares=True, tally="zero")
     indexed = mcfg.index_topk > 0
     # Riders are a decode step of one row a slot, a hand-off a first token:
     # a stack that generates by blocks has neither.
@@ -1177,8 +1246,9 @@ _KEEP = {
         ic=paged_kv.write_prompt_rows(c.ic, pages, ik)),
     "state": lambda c, pages, slot, length, ssm, conv: c._replace(
         state=slot_state.write_state(c.state, slot, ssm, conv)),
-    "ring": lambda c, pages, slot, length, ks, vs: c._replace(
-        state=slot_state.write_window_prompt(c.state, slot, length, ks, vs)),
+    "ring": lambda c, pages, slot, length, ks, vs, **how: c._replace(
+        state=slot_state.write_window_prompt(c.state, slot, length, ks, vs,
+                                             **how)),
     "retention": lambda c, pages, slot, length, S, z: c._replace(
         state=slot_state.write_retention(c.state, slot, S, z)),
 }
@@ -1275,7 +1345,9 @@ def _prefill_walk(mcfg, stack: _Stack):
     short-convolution layer's the operator's from and to the slots' own
     windows (`_conv_kind`), a latent-attention layer's the absorbed form's
     step (the slot's ONE row written, `paged_latent_decode` against the arena
-    of latent rows: `_latent_kind`); the projections,
+    of latent rows: `_latent_kind`), a mixed stack's window layers' the row
+    written to the slot's ring and the ring alone read, its full layers' the
+    page's write and `paged_decode` (`_mixed_kind`); the projections,
     the feed-forward (a one-part stack's expert layers: `live` holds the
     riders) and the head run over the bucket as they do anyway, so the
     step's weight reads are the prefill's. Then logits is [1 +
@@ -1464,8 +1536,13 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         if riders is not None:
             act = riding & (pos < S)
         first = toks[0]
+        # (rings that riders moved come out of the layers' loops, where a
+        # scatter's read of the rows it replaces costs a copy of them all:
+        # `write_window_prompt`)
+        how = {"ring": {"in_bounds": True}} if riders is not None else {}
         for cache, rest in kept.items():
-            caches = _KEEP[cache](caches, pages, slot, length, *rest)
+            caches = _KEEP[cache](caches, pages, slot, length, *rest,
+                                  **how.get(cache, {}))
         if riders is None:
             return caches, first, experts
         return (caches, first, experts, jnp.where(act, toks[1:], last),

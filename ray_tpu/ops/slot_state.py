@@ -236,11 +236,17 @@ def empty_window(n_layers: int, n_slots: int, kv_heads: int, window: int,
                  for d in (head_dim, v_head_dim))
 
 
-def write_window_prompt(state: State, slot, length, ks, vs) -> State:
+def write_window_prompt(state: State, slot, length, ks, vs,
+                        in_bounds: bool = False) -> State:
     """A prefill's keys `[n_layers, W, kv_heads, head_dim]` and values into
     slot `slot`'s rings (`slot`, `length` traced scalars): row r takes the
     largest position under `length` that is r modulo R, or zeros where the
-    prompt has none."""
+    prompt has none. `in_bounds`: the caller's word that `slot` is a slot,
+    so that the write is an update in place that reads nothing of what it
+    replaces (a scatter keeps the old rows of an index out of bounds, and to
+    read ONE slot's the compiler lays ALL the rings out anew where they come
+    out of a loop: 0.8 GB copied a riding prefill at Laguna's sizes, compiled
+    for a v5e, PR 64)."""
     kw, vw = state
     R = kw.shape[3]
     last = length - 1
@@ -252,9 +258,14 @@ def write_window_prompt(state: State, slot, length, ks, vs) -> State:
             0, 2, 1, 3)
         return jnp.where(reached, _to_width(rows, ring), 0)
 
+    def put(ring, rows):
+        if in_bounds:
+            return jax.lax.dynamic_update_slice(ring, rows[:, None],
+                                                (0, slot, 0, 0, 0))
+        return ring.at[:, slot].set(rows)
+
     with jax.named_scope("window_write"):
-        return (kw.at[:, slot].set(tail(ks, kw)),
-                vw.at[:, slot].set(tail(vs, vw)))
+        return put(kw, tail(ks, kw)), put(vw, tail(vs, vw))
 
 
 def write_window_token(state: State, layer, w, active, k, v) -> State:

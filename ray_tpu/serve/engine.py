@@ -57,8 +57,9 @@ n-step decode chunk over all slots, and the slot poke.
   `n_slots` rows of its bucket free, the prefill program of a riding rung
   (`rung_rides`: the octave under max_seq, of a stack whose programs take
   riders, `Programs.takes_riders`: a dense, a sparse, a state-space hybrid, a
-  stack of short-convolution layers beside attention or one of
-  latent-attention layers) carries ONE decode step of every live slot in
+  stack of short-convolution layers beside attention, one of
+  latent-attention layers or one of window and full attention layers)
+  carries ONE decode step of every live slot in
   those rows (`models/serving.py`); on the host the riders advance as a chunk
   of one step would (`_ride_plan`, `_place`), and the emitter streams their
   tokens after the prompt's first. Who rides is read off the stack and the
@@ -137,9 +138,9 @@ def rung_rides(max_seq: int, n_slots: int, width: int) -> bool:
     where a prefill is long enough for a decode step's weight reads to hide
     in it and where the long prompts of a batch land, and none narrower (a
     riding program holds a decode step's attention kernel (a latent stack's
-    the absorbed form's), a hybrid's its state's step and a conv stack's its
-    windows' too, and a sampler over the slots' rows, traced, lowered and
-    loaded at every start). The slots' rows have to fit in the rung beside a
+    the absorbed form's), a hybrid's its state's step, a conv stack's its
+    windows' and a mixed stack's its rings' too, and a sampler over the slots'
+    rows, traced, lowered and loaded at every start). The slots' rows have to fit in the rung beside a
     prompt. Whether the stack's programs take riders at all is the stack's
     answer (`Programs.takes_riders`), not the rung's."""
     return 2 * width >= max_seq and n_slots < width

@@ -101,6 +101,45 @@ class Described:
             slot, *riding)
 
 
+def mixed_riding_rung(cell, width):
+    """A mixed stack's prefill of `width` rows with nobody to take and as the
+    riding rung's program, both compiled -> (memory of the first, of the
+    second): the riding one holds the `paged_decode` kernel once a stack of
+    full layers (the dense layer's, the sparse ones' scan's) more, the pages
+    and the rings ride the scans' carry through the riders' writes and reads
+    and still alias the donated entry buffers, and nothing shaped like a
+    cache or a layer of one is copied (a prompt's ring write after riders is
+    an update in place: `slot_state.write_window_prompt(in_bounds=True)`)."""
+    from ray_tpu.serve.engine import rung_rides
+    assert rung_rides(cell.eng["max_seq"], cell.ns, width)
+    assert cell.built.takes_riders
+    kc, vc, _, state = cell.caches
+    before = attention.attention_path_counts()
+
+    def compiled(*more):
+        lowered = cell.lower_prefill(width, 0, *more)
+        return lowered.as_text(), lowered.compile()
+
+    plain_text, plain = compiled(None, None, None)
+    counts = attention.attention_path_counts()
+    assert all(counts.get(p, 0) == before.get(p, 0)
+               for p in ("decode_pallas", "window_decode_reference"))
+    text, riding = compiled(*cell.riding())
+    counts = attention.attention_path_counts()
+    assert all(counts[p] > before.get(p, 0)
+               for p in ("decode_pallas", "window_decode_reference"))
+    assert "paged_decode" in text and "paged_decode" not in plain_text
+    hlo = riding.as_text()
+    calls = [kind.count('custom_call_target="tpu_custom_call"')
+             for kind in (plain.as_text(), hlo)]
+    assert calls[1] == calls[0] + 2, calls
+    assert not copies_of(hlo, kc, vc, *state)
+    mem, was = riding.memory_analysis(), plain.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in (kc, vc) + tuple(state))
+    return was, mem
+
+
 def described_cell(topo, monkeypatch, config, layers=None, init=None):
     """`benchmark/configs/<config>.json` (cut to `layers` layers where given)
     as the benchmark builds it: the adapter's config at the cell's `max_seq`,

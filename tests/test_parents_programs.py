@@ -60,8 +60,14 @@ from test_serve_llm import parents_sample_tokens
 # are made there, from the same `act` and `w`), was taken anew on PR 61's tree
 # (the parent's: 3cbcf9da23401fa5; the mathematics of a step did not move:
 # tests/test_dots.py holds it to the reference).
-# `mixed` (PR 42's stack, which takes nobody) was taken on PR 45's parent
-# (6c2c097), before that PR moved a line under ray_tpu/.
+# `mixed` (PR 42's stack) was taken on PR 45's parent (6c2c097), before that
+# PR moved a line under ray_tpu/. Since PR 64 it takes riders too: its pin is
+# the 32 rung, that parent's text still (and PR 64's parent's, 320e6dd), and
+# its decode program, whose ring and page steps now go through the two jits
+# they share with the riders (`serving._mixed_steps`), was taken anew on PR
+# 64's tree (the parent's, of 6c2c097 and of 320e6dd alike: c31b6808d133c647,
+# which the tree still lowers to with the steps built without the shared
+# jits: `test_the_mixed_decode_without_the_shared_jits_is_the_parents`).
 # Since PR 47 `serving.sample_tokens` takes its top-k behind a conditional, so
 # every serving program's text moves, by design, inside `sample` and nowhere
 # else: the pinned programs are lowered with the sampler of PR 47's parent
@@ -78,8 +84,8 @@ PARENT_PROGRAMS = {
     "indexed.prefill64": "2b26fc68f7f5f898",
     "latent.decode": "d16fcac2d9f7e040",
     "latent.prefill32": "8da32aa0051287f3",
-    "mixed.decode": "c31b6808d133c647",
-    "mixed.prefill64": "ef4db6b4bc528b78",
+    "mixed.decode": "e89d77bc0eac84b0",
+    "mixed.prefill32": "7ce5961c0ca08aab",
     "sparse.decode": "94dff0eb228ce990",
     "sparse.prefill32": "7a5fc5aa7c158c94",
     "train.tiny": "569d197c86234e93",
@@ -91,9 +97,9 @@ PARENT_PROGRAMS = {
 # payload, no source locations in the text). The rungs 64 and 128 of the dense
 # and the sparse stack ride since PR 41, the hybrid's since PR 58, the conv
 # stack's (every rung taken on PR 60's parent, 0ed315d) since PR 60, the
-# latent stack's since PR 61: `PARENT_RIDING` below;
-# `PARENT_PROGRAMS` above pins the decode programs. `mixed` (PR 42's stack) was
-# taken on PR 45's parent (6c2c097). All three tables stand since PR 47 with
+# latent stack's since PR 61, the mixed stack's (every rung taken on PR 45's
+# parent, 6c2c097) since PR 64: `PARENT_RIDING` below;
+# `PARENT_PROGRAMS` above pins the decode programs. All three tables stand since PR 47 with
 # that PR's parent's sampler in `serving.sample_tokens`' place while a
 # program is lowered (`parents_prefill_text` puts it there): the one
 # part of every serving program that PR moved.
@@ -105,8 +111,7 @@ PARENT_RUNGS = {
     "hybrid": {32: "bf109118a1785278"},
     "conv": {32: "ff719c8f70fb6d00"},
     "latent": {32: "8da32aa0051287f3"},
-    "mixed": {32: "7ce5961c0ca08aab", 64: "ef4db6b4bc528b78",
-              128: "d8c5fc514a414023"},
+    "mixed": {32: "7ce5961c0ca08aab"},
 }
 # What the riding rungs lowered to there: another text now, on purpose.
 PARENT_RIDERLESS = {
@@ -115,6 +120,7 @@ PARENT_RIDERLESS = {
     "hybrid": {64: "b6847a6dfe909d84", 128: "4dd7ed9434604dd1"},
     "conv": {64: "0d6746047675a641", 128: "e080b12a6d548004"},
     "latent": {64: "f96e02f0c080c3fb", 128: "4b487bf21d58472e"},
+    "mixed": {64: "ef4db6b4bc528b78", 128: "d8c5fc514a414023"},
 }
 # What the riding rungs lower to with the riders' shapes as `_place` passes
 # them (`Engine.lowered_prefill_text`): the programs `serve-batch` and
@@ -127,14 +133,22 @@ PARENT_RIDERLESS = {
 # `serve-generate-lfm2` spends 0.58 of its prefills in) on PR 60's; the latent
 # stack's (the absorbed form's step in the tail rows of each MLA layer,
 # `_latent_kind`'s `_token_step`: the programs `serve-batch-dots-vlm1` spends
-# nearly all its prefill time in) on PR 61's.
+# nearly all its prefill time in) on PR 61's; the mixed stack's (a window
+# layer's row into the slot's ring and the ring alone read, a full layer's
+# into its page and `paged_decode`, `_mixed_steps`: the programs
+# `serve-longdoc-mimo-v2` and `serve-longdoc-laguna` spend all their prefill
+# time in) on PR 64's.
 PARENT_RIDING = {
     "dense": {64: "8de5c32ccafe6475", 128: "3a4649dd358ebc16"},
     "sparse": {64: "c033be69300aac6b", 128: "c2b37a166224c151"},
     "hybrid": {64: "a87192cc5a56bac3", 128: "f0b6c2f60d1080fc"},
     "conv": {64: "5aef5bb379a666c0", 128: "892c8e058afa9478"},
     "latent": {64: "6c612fcd1cce9799", 128: "1b7702520c1c27e5"},
+    "mixed": {64: "e2deceaccded5d52", 128: "06a1790488ab9bee"},
 }
+# The mixed stack's decode program on PR 64's parent (320e6dd; on 6c2c097
+# too), which PR 64's tree lowers to with `_mixed_steps`' two jits taken off.
+MIXED_DECODE_UNSHARED = "c31b6808d133c647"
 STACKS = dict(KINDS, latent=("dots", PUBLISHED), mixed=("mimo", MIMO),
               conv=("lfm2", LFM2))
 
@@ -194,9 +208,11 @@ def parents_decode_text(eng):
 @functools.cache
 def _programs(kind):
     """(takes riders, the riding rungs, {width: digest of the rung's lowered
-    text}, digest of the decode program's, the keys of its counters) of the
-    stack's engine at its adapter's rehearsal widths, built once: the tests
-    below read their pins off it and leave it as it was built."""
+    text}, digest of the decode program's, the keys of its counters, digest
+    of the mixed stack's decode program built without the jits its steps
+    share with the riders: None for another stack) of the stack's engine at
+    its adapter's rehearsal widths, built once: the tests below read their
+    pins off it and leave it as it was built."""
     adapter = models.adapter(STACKS[kind][0])
     cfg = adapter.build_config(dict(adapter.REHEARSE, **STACKS[kind][1]),
                                F32, 128)
@@ -209,9 +225,20 @@ def _programs(kind):
         rungs = {w: _sha(parents_prefill_text(eng, w)) for w in eng.buckets}
         decode = _sha(parents_decode_text(eng))
         keys = set(eng.counters())
+        takes, unshared = eng._programs.takes_riders, None
+        if kind == "mixed":
+            steps = serving._mixed_steps
+
+            def unjitted(mcfg):
+                with mock.patch.object(jax, "jit", lambda f: f):
+                    return steps(mcfg)
+
+            with mock.patch.object(serving, "_mixed_steps", unjitted):
+                eng._programs = serving.build_programs(cfg, 2, 2, 16, 17)
+            unshared = _sha(parents_decode_text(eng))
     finally:
         eng.stop()
-    return eng._programs.takes_riders, riding, rungs, decode, keys
+    return takes, riding, rungs, decode, keys, unshared
 
 
 def _train_step_digest():
@@ -234,7 +261,7 @@ def test_the_other_models_programs_are_the_parents(kind):
     if kind == "train":
         got = {"train.tiny": _train_step_digest()}
     else:
-        _, riding, rungs, decode, _ = _programs(kind)
+        _, riding, rungs, decode, *_ = _programs(kind)
         width = 32 if 64 in riding else 64
         got = {f"{kind}.prefill{width}": rungs[width],
                f"{kind}.decode": decode}
@@ -243,15 +270,16 @@ def test_the_other_models_programs_are_the_parents(kind):
 
 @pytest.mark.parametrize("kind", sorted(PARENT_RUNGS))
 def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
-    """A dense, a sparse, a hybrid, a conv and a latent stack's programs of
-    the octave under `max_seq` take riders and hold a decode step's attention
-    (a hybrid's its state-space layers' step too, a conv stack's its
-    short-convolution layers'; a latent stack's is the absorbed form's);
-    their narrow rungs, and every rung of an indexed and a mixed stack, take
-    nobody and lower to the parent's text, letter for letter. Asked of the
-    built program; no option, field or environment variable has a say."""
-    takes, riding, got, _, _ = _programs(kind)
-    assert takes is (kind in ("dense", "sparse", "hybrid", "conv", "latent"))
+    """A dense, a sparse, a hybrid, a conv, a latent and a mixed stack's
+    programs of the octave under `max_seq` take riders and hold a decode
+    step's attention (a hybrid's its state-space layers' step too, a conv
+    stack's its short-convolution layers'; a latent stack's is the absorbed
+    form's; a mixed stack's window layers' reads their slot's ring); their
+    narrow rungs, and every rung of an indexed stack, take nobody and lower
+    to the parent's text, letter for letter. Asked of the built program; no
+    option, field or environment variable has a say."""
+    takes, riding, got, *_ = _programs(kind)
+    assert takes is (kind != "indexed")
     assert riding == ([64, 128] if takes else [])
     assert {w: d for w, d in got.items()
             if w not in riding} == PARENT_RUNGS[kind]
@@ -261,11 +289,19 @@ def test_who_takes_riders_and_every_other_program_is_the_parents(kind):
 
 @pytest.mark.parametrize("kind", sorted(PARENT_RIDING))
 def test_the_riding_rungs_are_the_parents(kind):
-    """The riding rungs of a dense, a sparse, a hybrid, a conv and a latent
-    stack, lowered with the riders' shapes as `_place` passes them, are the
-    pinned text."""
-    _, riding, got, _, _ = _programs(kind)
+    """The riding rungs of a dense, a sparse, a hybrid, a conv, a latent and a
+    mixed stack, lowered with the riders' shapes as `_place` passes them, are
+    the pinned text."""
+    _, riding, got, *_ = _programs(kind)
     assert {w: got[w] for w in riding} == PARENT_RIDING[kind]
+
+
+def test_the_mixed_decode_without_the_shared_jits_is_the_parents():
+    """The mixed stack's decode program moved by the two calls alone: with
+    `_mixed_steps`' jits taken off (the steps traced in line, as the parent
+    wrote them in the layer's body) the tree lowers it to the parent's text,
+    letter for letter."""
+    assert _programs("mixed")[-1] == MIXED_DECODE_UNSHARED
 
 
 @pytest.mark.parametrize("kind", ["dense", "sparse", "indexed", "hybrid",
@@ -273,6 +309,6 @@ def test_the_riding_rungs_are_the_parents(kind):
 def test_what_each_stack_counts_is_what_the_parent_counted(kind):
     """The keys of `Engine.counters()`, read in the same build as the digests:
     the scheduler's own and the stack's row of tests/engine_pins.py."""
-    *_, keys = _programs(kind)
+    *_, keys, _ = _programs(kind)
     assert GENERIC <= keys and keys - GENERIC == MODEL[kind], \
         sorted(keys - GENERIC)

@@ -7,13 +7,17 @@ float32, a dense and a sparse stack and, since PR 58, the three hybrids
 experts; the stack of one-part layers, Mamba-2 with groups) and, since PR 60,
 LFM2's stack of short-convolution layers beside attention and, since PR 61,
 dots' stack of latent-attention (MLA) layers, whose riders take the absorbed
-form's step against the arena of latent rows: every stream is
+form's step against the arena of latent rows, and, since PR 64, the two
+stacks of window and full attention layers (MiMo's: a sink, a key in two
+parts; Laguna's: a gate a head, more window heads than full ones, YaRN),
+whose riders write a row to their slot's ring or page and read the ring alone
+or the live pages: every stream is
 what the same engine serves with nobody riding, and the plain reference's
 greedy tokens; the counters and the admit spans agree; a burst of admissions
 moves the riders a step each; a rider that finishes on a riding step frees its
 slot, its pages and its slot's recurrent state (or windows) at once, and the
-next admission overwrites them. (An indexed and a mixed stack take
-nobody, and every program that takes nobody lowers to the parent's text:
+next admission overwrites them. (An indexed stack takes nobody, and every
+program that takes nobody lowers to the parent's text:
 tests/test_parents_programs.py.)
 
 Tolerance: program and reference compute the same mathematics in float32 and
@@ -33,7 +37,9 @@ from ray_tpu.serve.engine import (_DEPTH, Engine, prefill_widths,
                                   rung_rides)
 import test_dots
 import test_granite
+import test_laguna
 import test_lfm2
+import test_mimo
 import test_nemotron_h
 from engine_pins import Spans as _Spans
 from test_prefill_ladder import F32, LOGIT_TOL, _tiny, _tokens
@@ -68,7 +74,11 @@ HYBRIDS = {"mamba2": test_granite, "one-part": test_nemotron_h}
 # (LFM2: a slot keeps a window a conv layer and pages for the rest).
 # ... and, since PR 61, the stack of latent-attention layers (dots: a slot
 # keeps ONE row a position a layer, and the riders read them absorbed).
-STACKS = ["dense", "sparse", "hybrid", *HYBRIDS, "conv", "latent"]
+# ... and, since PR 64, the two stacks of window and full attention layers
+# (a slot keeps a ring a window layer and pages for the full ones; the
+# rehearsal's window of 16 has wrapped at every position these prompts reach).
+MIXED = {"mixed": test_mimo, "mixed-gated": test_laguna}
+STACKS = ["dense", "sparse", "hybrid", *HYBRIDS, "conv", "latent", *MIXED]
 
 
 def _model(kind):
@@ -76,6 +86,8 @@ def _model(kind):
         return test_lfm2._tiny(max_seq=MAX_SEQ)
     if kind == "latent":
         return test_dots._tiny(max_seq=MAX_SEQ)
+    if kind in MIXED:
+        return MIXED[kind]._tiny(max_seq=MAX_SEQ)
     if kind not in HYBRIDS:
         return _tiny(kind, MAX_SEQ)
     tests = HYBRIDS[kind]
@@ -199,11 +211,13 @@ def test_the_manifest_entry_of_the_riders_share():
         workloads=["serve-batch", "serve-batch-olmoe"])
 
 
-@pytest.fixture(scope="module", params=["dense", "hybrid", "conv", "latent"])
+@pytest.fixture(scope="module", params=["dense", "hybrid", "conv", "latent",
+                                        "mixed"])
 def held(request):
     """An engine of three slots (a dense stack's; a hybrid's, whose slots hold
     a recurrent state too; a conv stack's, whose slots hold a window a conv
-    layer; a latent stack's, whose pages hold latent rows) whose emitter the
+    layer; a latent stack's, whose pages hold latent rows; a mixed stack's,
+    whose slots hold a ring a window layer) whose emitter the
     test holds at its first chunk, so that the loop stands with `_DEPTH`
     chunks in flight and nothing moves but what the test submits."""
     _, _, _, eng = _build(request.param, n_slots=3)

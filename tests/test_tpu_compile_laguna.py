@@ -5,7 +5,7 @@ one tile, a gate a head, and a share of the experts beside a shared one."""
 
 import pytest
 
-from compile_for_v5e import copies_of, described_cell
+from compile_for_v5e import copies_of, described_cell, mixed_riding_rung
 from ray_tpu.ops import attention
 
 pytestmark = pytest.mark.usefixtures("_no_compile_cache")
@@ -57,4 +57,22 @@ def test_laguna_programs_run_their_kernels_and_fit_on_v5e(
     # temporaries are its activations; together inside the chip's 15.75 GB
     assert mem.temp_size_in_bytes < ((64 << 20) if program == "decode"
                                      else (1 << 30))
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11 << 30
+
+
+@pytest.mark.timeout(300)
+def test_lagunas_widest_riding_prefill_steps_the_slots_in_both_caches_on_v5e(
+        topo, monkeypatch):
+    """The 8,192-wide prefill at the cell's sizes (32 slots, `max_seq`
+    8,192: the rung every prompt over 7,168 tokens lands in) as the riding
+    rung's program, beside the same width's with nobody to take
+    (`compile_for_v5e.mixed_riding_rung`): the riders' step costs no
+    temporaries beyond the riderless program's (1.21 GB against 1.45: the
+    gated transpose goes in bfloat16 once the riders' rows are put into the
+    kernel's output), and arguments and temporaries stay inside the 11 GiB
+    that leave the cell's pages (`kv_pages_peak_pct` 90.8) their room on a
+    chip of 15.75."""
+    was, mem = mixed_riding_rung(described_cell(topo, monkeypatch, CONFIG),
+                                 8192)
+    assert mem.temp_size_in_bytes <= was.temp_size_in_bytes + (64 << 20)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 11 << 30

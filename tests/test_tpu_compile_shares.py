@@ -6,7 +6,8 @@ latent rows."""
 
 import pytest
 
-from compile_for_v5e import copies_of, described_cell, moved_stacks
+from compile_for_v5e import (copies_of, described_cell, mixed_riding_rung,
+                             moved_stacks)
 from ray_tpu.ops import attention
 from ray_tpu.serve.engine import rung_rides
 
@@ -52,6 +53,22 @@ def test_mimo_programs_keep_both_caches_in_place_on_v5e(
     # (1.71 GB at 8,192 rows), and arguments + temporaries fit the chip's 15.75
     assert mem.temp_size_in_bytes < ((64 << 20) if program == "decode"
                                      else (1 << 30))
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 << 30
+
+
+@pytest.mark.timeout(300)
+def test_mimos_widest_riding_prefill_steps_the_slots_in_both_caches_on_v5e(
+        topo, monkeypatch):
+    """The 8,192-wide prefill of MiMo-V2's mixed stack at the cell's sizes
+    (32 slots, `max_seq` 8,192) as the riding rung's program, beside the same
+    width's with nobody to take (`compile_for_v5e.mixed_riding_rung`): the
+    riders' step (keys of 192 in 256 lanes, a sink, a ring of 128) adds 0.43
+    GB of temporaries to the riderless program's 1.03, and arguments and
+    temporaries stay inside the 12 GiB the cell's other programs are held
+    to on a chip of 15.75."""
+    was, mem = mixed_riding_rung(
+        described_cell(topo, monkeypatch, "mimo-v2-flash-serve"), 8192)
+    assert mem.temp_size_in_bytes <= was.temp_size_in_bytes + (512 << 20)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 << 30
 
 
