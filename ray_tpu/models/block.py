@@ -36,6 +36,15 @@ engine's prefill and its decode step (`serve/engine.py`) all call them.
                      gate a kv head, `ops/retention.py`, likewise once for a
                      prompt and for one token a slot
 
+  block_sparse_attention_inputs
+                     the cache-free half of a layer that selects BLOCKS (the
+                     MiniCPM-SALA family's `minicpm4` kind): attn_norm -> q,
+                     k, v and the output gate's logits, NO rotation
+  linear_mixer       a decayed linear-attention layer's whole mixer (the same
+                     family's `lightning-attn` kind): q, k, v, a norm a head,
+                     RoPE, `ops/linear_attention.py`, the output's norm and
+                     gate, likewise once for a prompt and for one token a slot
+
 and the two ways a program that runs no gradient (serving) holds its layer
 stacks differently from training, each so that the compiler reads a layer's
 weights where they lie: `fuse_qkv` / `split_qkv` and `expert_stacks`.
@@ -59,7 +68,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import retention
+from ray_tpu.ops import linear_attention, retention
 from ray_tpu.ops.moe import moe_ffn
 from ray_tpu.ops.norms import layer_norm, rms_norm
 # The module, not its name: tests put an interpreted `step_layer` in its place.
@@ -665,6 +674,143 @@ def retention_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg,
     return (out, state) if step else (out, S, z)
 
 
+def _sala_widths(cfg, stack: str) -> Dict[str, int]:
+    """The column groups of the fused projection of a layer of the stack
+    `stack` (`sparse`, `linear`) of a model of linear and block-sparse
+    layers, in their order, each with its width: q, k, v at the kind's own
+    heads, then the output gate's logits, one a number of attention's
+    output (left out where the kind has no gate)."""
+    if stack == "sparse":
+        q = cfg.n_heads * cfg.head_dim
+        kv, gate = cfg.n_kv_heads * cfg.head_dim, cfg.sparse_gate
+    else:
+        q = kv = cfg.lightning_heads * cfg.lightning_head_dim
+        gate = cfg.lightning_gate
+    return {"wq": q, "wk": kv, "wv": kv, **({"wg": q} if gate else {})}
+
+
+def _sala_parts(lp, h, cfg, stack: str) -> Dict[str, jax.Array]:
+    """The normed input `h` through the layer's projections -> q, k, v
+    (and the gate's logits) by the names of their matrices, from either
+    layout: one `wqkv` (serving) or a matrix each (as published)."""
+    dt = cfg.dtype
+    widths = _sala_widths(cfg, stack)
+    if "wqkv" in lp:
+        ends = list(itertools.accumulate(widths.values()))[:-1]
+        return dict(zip(widths, jnp.split(h @ lp["wqkv"].astype(dt), ends,
+                                          axis=-1)))
+    return {name: h @ lp[name].astype(dt) for name in widths}
+
+
+def block_sparse_attention_inputs(lp: Dict[str, jax.Array], x: jax.Array,
+                                  cfg) -> Tuple:
+    """The cache-free half of a layer that selects blocks
+    (`cfg.mixer_types` "minicpm4"): attn_norm -> q `[batch, heads, seq, hd]`,
+    k and v `[batch, kv_heads, seq, hd]` (`[slots, heads, hd]` for a decode
+    step), NO rotation and no norm of q or k, and the output gate's logits
+    `[batch, seq, heads * hd]` (`[slots, heads * hd]`; None without
+    `cfg.sparse_gate`), from the same normed input."""
+    lead = x.shape[:-1]
+    hd = cfg.head_dim
+
+    def heads(t, n):
+        t = t.reshape(*lead, n, hd)
+        return t.transpose(0, 2, 1, 3) if len(lead) == 2 else t
+
+    with jax.named_scope("attn_norm"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        parts = _sala_parts(lp, h, cfg, "sparse")
+        return (heads(parts["wq"], cfg.n_heads),
+                heads(parts["wk"], cfg.n_kv_heads),
+                heads(parts["wv"], cfg.n_kv_heads), parts.get("wg"))
+
+
+def output_gated(attn: jax.Array, gate: Optional[jax.Array],
+                 norm: Optional[jax.Array] = None, eps: float = 1e-6
+                 ) -> jax.Array:
+    """Attention's joined output `[.., heads * hd]`, RMS-normed over its
+    whole width where `norm` (the weight) is given, times sigmoid of the
+    gate's logits, one a number (None: no gate), in float32, back in the
+    gate's dtype (`attn`'s without one): what `wo` takes of a linear or a
+    block-sparse layer."""
+    if gate is None and norm is None:
+        return attn
+    dt = attn.dtype if gate is None else gate.dtype
+    with jax.named_scope("attn_gate"):
+        y = attn.astype(jnp.float32)
+        if norm is not None:
+            y = rms_norm(y, norm, eps)
+        if gate is not None:
+            y = y * jax.nn.sigmoid(gate.astype(jnp.float32))
+        return y.astype(dt)
+
+
+def linear_mixer(lp: Dict[str, jax.Array], x: jax.Array, cfg,
+                 rope: Callable[[jax.Array], jax.Array], rates: jax.Array,
+                 state=None, *, step: bool = False, length=None, layer=None,
+                 active=None) -> Tuple[jax.Array, jax.Array]:
+    """x + residual_scale * mixer(attn_norm(x)) for a decayed
+    linear-attention layer (`cfg.mixer_types` "lightning-attn";
+    `ops/linear_attention.py` has the equations), under the block's scopes:
+
+      q, k, v, g = attn_norm(x) W   (heads of `lightning_head_dim`, as many
+                   kv heads as query heads); an RMS norm over each head of q
+                   and of k (`lightning_qk_norm`)                       qkv
+      q, k rotated by `rope`, handed a tensor in its layout
+                   (`lightning_rope`; the whole head)                   rope
+      o = the operator under the layer's `rates` [heads], q scaled by
+          head width^-1/2                                  attn / linear_attn
+      y = rmsnorm(o over the joined heads; o_norm) * sigmoid(g)
+                   (`lightning_norm`, `lightning_gate`)       attn / attn_gate
+      out = x + residual_scale * y W_o                              attn_out
+
+    x `[1, S, D]`, one prompt, through `linear_attention.linear_prompt` (rows
+    at and past `length` write nothing to the state) -> (out, S `[heads, d,
+    d]` float32, the state a slot keeps of the layer after row `length - 1`).
+    Or, with `step`, x `[ns, D]`, one token a slot, and `state` the slots'
+    WHOLE state `[layers, ns, heads, d, d]`, of which layer `layer`'s tiles
+    of the slots `active` marks are read, updated and written where they lie
+    in one visit (`slot_state.linear_step_layer`) -> (out, state); an idle
+    slot's output row is not meaningful (and finite)."""
+    dt = cfg.dtype
+    lead = x.shape[:-1]
+    H, d = cfg.lightning_heads, cfg.lightning_head_dim
+    scale = d ** -0.5
+
+    def heads(t):
+        t = t.reshape(*lead, H, d)
+        return t.transpose(0, 2, 1, 3) if len(lead) == 2 else t
+
+    with jax.named_scope("attn_norm"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        parts = _sala_parts(lp, h, cfg, "linear")
+        q, k, v = (heads(parts[n]) for n in _QKV)
+        if cfg.lightning_qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+                k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    if cfg.lightning_rope:
+        with jax.named_scope("rope"):
+            q, k = rope(q), rope(k)
+    with jax.named_scope("attn"):
+        with jax.named_scope("linear_attn"):
+            if step:
+                o, state = slot_state.linear_step_layer(
+                    state, layer, active, q, k, v, rates, scale)
+                o = o.reshape(lead[0], H * d)
+            else:
+                o, state = linear_attention.linear_prompt(
+                    q[0], k[0], v[0], rates, scale, length)
+                o = o.transpose(1, 0, 2).reshape(1, lead[1], H * d)
+        y = output_gated(o, parts.get("wg"),
+                         lp["o_norm"] if cfg.lightning_norm else None,
+                         cfg.norm_eps).astype(dt)
+    with jax.named_scope("attn_out"):
+        return x + scaled(y @ lp["wo"].astype(dt), cfg), state
+
+
 _QKV = ("wq", "wk", "wv")
 _INDEX = ("wiq", "wik", "wiw")
 
@@ -689,6 +835,34 @@ def _qkv_ends(cfg) -> List[int]:
 
 _LATENT_STACKS = ("layers", "dense")
 _MIXED_STACKS = ("dense", "window", "layers")
+_SALA_STACKS = ("sparse", "linear")
+
+
+def _fuse_sala(params, cfg):
+    """A model of linear and block-sparse layers as the serving programs
+    take it: each stack's `wq`, `wk`, `wv` and the gate's `wg` joined into
+    ONE `wqkv` (`_sala_widths`; every group whole tiles at the published
+    widths), so that the gate costs no second pass over the input."""
+    out = dict(params)
+    for name in _SALA_STACKS:
+        if name in params:
+            layers = dict(params[name])
+            layers["wqkv"] = jnp.concatenate(
+                [layers.pop(k) for k in _sala_widths(cfg, name)], axis=-1)
+            out[name] = layers
+    return out
+
+
+def _split_sala(params, cfg):
+    out = dict(params)
+    for name in _SALA_STACKS:
+        if name in params:
+            layers = dict(params[name])
+            widths = _sala_widths(cfg, name)
+            ends = list(itertools.accumulate(widths.values()))[:-1]
+            parts = jnp.split(layers.pop("wqkv"), ends, axis=-1)
+            out[name] = dict(layers, **dict(zip(widths, parts)))
+    return out
 
 
 def _fuse_mixed(params, cfg):
@@ -817,6 +991,8 @@ def fuse_qkv(params: Dict[str, Any], cfg=None) -> Dict[str, Any]:
         return _fuse_latent(params, cfg)
     if cfg is not None and cfg.mixed:
         return _fuse_mixed(params, cfg)
+    if cfg is not None and cfg.sala:
+        return _fuse_sala(params, cfg)
     layers = dict(params["layers"])
     names = _QKV + tuple(n for n in _INDEX if n in layers)
     layers["wqkv"] = jnp.concatenate([layers.pop(k) for k in names], axis=-1)
@@ -832,6 +1008,8 @@ def split_qkv(params: Dict[str, Any], cfg) -> Dict[str, Any]:
         return _split_latent(params)
     if cfg.mixed:
         return _split_mixed(params, cfg)
+    if cfg.sala:
+        return _split_sala(params, cfg)
     layers = dict(params["layers"])
     parts = jnp.split(layers.pop("wqkv"), _qkv_ends(cfg), axis=-1)
     return dict(params, layers=dict(layers, **dict(zip(_fused_names(cfg),

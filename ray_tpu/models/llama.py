@@ -25,6 +25,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.block import attention_inputs, feed_forward
@@ -34,7 +35,8 @@ from ray_tpu.ops.moe import up_out_in
 from ray_tpu.ops.norms import (apply_rope, mrope_tables, rms_norm,
                                rope_frequencies, yarn_frequencies)
 from ray_tpu.ops.ring_attention import ring_attention
-from ray_tpu.ops.sparse_attention import sparse_attention
+from ray_tpu.ops.linear_attention import decay_rates
+from ray_tpu.ops.sparse_attention import BlockSparse, sparse_attention
 from ray_tpu.parallel.context import ParallelContext
 
 
@@ -255,6 +257,50 @@ class LlamaConfig:
     # (`state_layers` = n_layers; `ops/slot_state.py::empty_retention`).
     mixer: str = "attention"
     retention_degree: int = 2
+    # Decayed LINEAR attention beside attention that selects BLOCKS (the
+    # MiniCPM-SALA family): `mixer_types` says of each layer, in no period,
+    # whether it is "minicpm4" (the SPARSE kind: grouped-query attention with
+    # NO rotation over n_heads query heads on n_kv_heads kv heads, which
+    # reads every earlier key while its context is under `dense_len` and from
+    # there on the `sparse_topk` blocks of `sparse_block` positions that
+    # score best against keys mean-pooled over `sparse_kernel` positions
+    # every `sparse_stride`, the first `sparse_init_blocks` and the
+    # `sparse_window` positions before its own always among them, ONE
+    # selection a kv head: `ops/sparse_attention.py::BlockSparse`) or
+    # "lightning-attn" (the LINEAR kind: `lightning_heads` heads of
+    # `lightning_head_dim` with as many kv heads, an RMS norm over each head
+    # of q and of k (`lightning_qk_norm`), RoPE at rope_theta over the whole
+    # head (`lightning_rope`), and a state `[d, d]` float32 a head under a
+    # constant decay of the head and of the layer's place among the
+    # `published_layers` the model is published with, whatever depth is
+    # built: `ops/linear_attention.py`). The sparse kind's output is
+    # multiplied by sigmoid of one more projection of the block's normed
+    # input, `wg` `[d_model, n_heads * head_dim]`, before `wo`
+    # (`sparse_gate`); the linear kind's is RMS-normed over the joined heads
+    # (`lightning_norm`, the leaf `o_norm`) and gated the same way
+    # (`lightning_gate`). Every layer has the dense feed-forward; the scalar
+    # multipliers apply. The parameters are stacks by kind, `sparse` and
+    # `linear`, none holding a weight of a kind it is not; `segments()` has
+    # the order they run in. A serving cache of three shapes: pages for the
+    # sparse layers, one kv head a layer of the arena and a page a block;
+    # their pooled keys a slot; the linear layers' state a slot
+    # (`ops/slot_state.py`). Serving only.
+    mixer_types: Optional[Tuple[str, ...]] = None
+    published_layers: int = 0
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    lightning_qk_norm: bool = True
+    lightning_rope: bool = True
+    lightning_gate: bool = True
+    lightning_norm: bool = True
+    sparse_gate: bool = True
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    dense_len: int = 8192
     dtype: Any = jnp.bfloat16          # activation/compute dtype
     param_dtype: Any = jnp.float32     # master parameter dtype
     remat: bool = True
@@ -288,6 +334,9 @@ class LlamaConfig:
             object.__setattr__(self, "conv_layers",
                                tuple(sorted(int(i) for i in self.conv_layers)))
             self._check_conv()
+        if self.mixer_types is not None:
+            object.__setattr__(self, "mixer_types", tuple(self.mixer_types))
+            self._check_sala()
         segmented = self.latent or self.mixed or self.conv
         if self.multipliers and segmented:
             raise ValueError("embed_scale, residual_scale, logit_scale and "
@@ -439,6 +488,55 @@ class LlamaConfig:
             raise ValueError("power retention: an even head_dim, and whole "
                              "groups of query heads a kv head")
 
+    def _check_sala(self) -> None:
+        if self.latent or self.mixed or self.conv or self.ssm_state \
+                or self.index_topk or self.mrope_section or self.n_experts \
+                or self.retention or self.block_length > 1 or self.qk_norm \
+                or self.tie_embeddings or self.attn_scale:
+            raise ValueError("linear and block-sparse layers (mixer_types) "
+                             "come with the dense feed-forward and an untied "
+                             "head: no latent or mixed attention, "
+                             "state-space, short-convolution or retention "
+                             "layers, indexer, mrope, experts, generation by "
+                             "blocks, q/k norm in the sparse layers or "
+                             "attn_scale")
+        kinds = self.mixer_types
+        if len(kinds) != self.n_layers \
+                or set(kinds) - {"minicpm4", "lightning-attn"}:
+            raise ValueError("mixer_types: one of 'minicpm4' (block-sparse "
+                             "attention) or 'lightning-attn' (linear "
+                             "attention) a layer")
+        if not self.published_layers:
+            object.__setattr__(self, "published_layers", self.n_layers)
+        if self.published_layers < self.n_layers:
+            raise ValueError("published_layers: the depth the decay and the "
+                             "residual scale are published for, at least "
+                             "n_layers")
+        if not self.lightning_heads:
+            object.__setattr__(self, "lightning_heads", self.n_heads)
+        if not self.lightning_head_dim:
+            object.__setattr__(self, "lightning_head_dim", self.head_dim)
+        if self.lightning_head_dim % 2:
+            raise ValueError("lightning_head_dim: RoPE turns pairs")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError("n_heads: whole groups of query heads a kv head")
+        b = self.block_sparse
+        if b.kernel != 2 * b.stride or b.block % b.stride \
+                or b.window % b.block or b.dense_len % b.block \
+                or self.max_seq % b.block:
+            raise ValueError(
+                "the sparse sizes: sparse_kernel is two sparse_stride, and "
+                "sparse_block divides into strides and divides sparse_window, "
+                "dense_len and max_seq")
+        if b.init_blocks + b.window_blocks > b.topk or b.topk < 1:
+            raise ValueError("sparse_topk: at least the sparse_init_blocks "
+                             "and the sparse_window's blocks, which every "
+                             "query reads")
+        if self.rope and "minicpm4" in kinds:
+            raise ValueError("rope: the sparse layers take no rotation "
+                             "(rope False; the linear layers' is "
+                             "lightning_rope)")
+
     def _check_block(self) -> None:
         if self.latent or self.mixed or self.conv or self.ssm_state \
                 or self.index_topk or self.mrope_section or self.retention \
@@ -525,12 +623,36 @@ class LlamaConfig:
         return self.mixer == "retention"
 
     @property
+    def sala(self) -> bool:
+        """Linear layers beside block-sparse ones (`mixer_types`)."""
+        return self.mixer_types is not None
+
+    @property
+    def block_sparse(self):
+        """The sparse layers' sizes (`ops.sparse_attention.BlockSparse`)."""
+        return BlockSparse(self.sparse_kernel, self.sparse_stride,
+                           self.sparse_block, self.sparse_topk,
+                           self.sparse_init_blocks, self.sparse_window,
+                           self.dense_len)
+
+    def linear_rates(self):
+        """`[linear layers, lightning_heads]` float32: each linear layer's
+        decay rates, by its place in the published stack
+        (`ops.linear_attention.decay_rates`)."""
+        return np.stack([
+            decay_rates(self.lightning_heads, i, self.published_layers)
+            for i, kind in enumerate(self.mixer_types)
+            if kind == "lightning-attn"])
+
+    @property
     def kv_layers(self) -> int:
         """Layers that keep K and V under the block table: the attention
         layers, of a mixed-attention stack the full-attention ones; none of
         a stack of retention layers."""
         if self.retention:
             return 0
+        if self.sala:
+            return self.mixer_types.count("minicpm4")
         if self.mixed:
             return self.attn_pattern.count(0)
         if self.conv:
@@ -606,6 +728,8 @@ class LlamaConfig:
         or every layer of a stack of retention layers."""
         if self.retention:
             return self.n_layers
+        if self.sala:
+            return self.mixer_types.count("lightning-attn")
         if self.layer_parts is not None:
             return self.layer_parts.count("M")
         return self.n_layers - self.kv_layers if self.ssm_state else 0
@@ -645,7 +769,19 @@ class LlamaConfig:
         mixed-attention stack likewise, its kinds `dense`, `window` and
         `layers`: each run of layers of one stack a segment. A stack with
         short-convolution layers the same way, its kinds `dense`, `conv` and
-        `layers`."""
+        `layers`; one of linear and block-sparse layers (`mixer_types`) too,
+        its kinds `sparse` and `linear`."""
+        if self.sala:
+            names = {"minicpm4": "sparse", "lightning-attn": "linear"}
+            out, at = [], dict.fromkeys(names.values(), 0)
+            for kind in self.mixer_types:
+                name = names[kind]
+                if out and out[-1][0] == name:
+                    out[-1] = (name, out[-1][1], at[name] + 1)
+                else:
+                    out.append((name, at[name], at[name] + 1))
+                at[name] += 1
+            return tuple(out)
         if self.mixed or self.conv:
             out, at = [], {"dense": 0, "window": 0, "conv": 0, "layers": 0}
             for i in range(self.n_layers):
@@ -938,6 +1074,84 @@ _MIXER2_AXES = {
     "D": ("layers", None), "w_norm": ("layers", "mlp")}
 
 
+def _sala_stacks(cfg: LlamaConfig) -> Dict[str, Tuple[int, int, int, int]]:
+    """name -> (layers, query heads, kv heads, head width) of each stack a
+    model of linear and block-sparse layers has."""
+    out = {"sparse": (cfg.kv_layers, cfg.n_heads, cfg.n_kv_heads,
+                      cfg.head_dim),
+           "linear": (cfg.state_layers, cfg.lightning_heads,
+                      cfg.lightning_heads, cfg.lightning_head_dim)}
+    return {k: v for k, v in out.items() if v[0]}
+
+
+def _sala_gated(cfg: LlamaConfig, name: str) -> bool:
+    return cfg.sparse_gate if name == "sparse" else cfg.lightning_gate
+
+
+def _sala_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    out = {"embed": ("vocab", "embed"), "final_norm": ("embed",),
+           "lm_head": ("embed", "vocab")}
+    for name in _sala_stacks(cfg):
+        stack = {"attn_norm": ("layers", "embed"),
+                 "wq": ("layers", "embed", "heads"),
+                 "wk": ("layers", "embed", "kv_heads"),
+                 "wv": ("layers", "embed", "kv_heads"),
+                 "wo": ("layers", "heads", "embed"),
+                 "mlp_norm": ("layers", "embed"),
+                 "w_gate": ("layers", "embed", "mlp"),
+                 "w_up": ("layers", "embed", "mlp"),
+                 "w_down": ("layers", "mlp", "embed")}
+        if _sala_gated(cfg, name):
+            stack["wg"] = ("layers", "embed", "heads")
+        if name == "linear" and cfg.lightning_qk_norm:
+            stack.update(q_norm=("layers", "head_dim"),
+                         k_norm=("layers", "head_dim"))
+        if name == "linear" and cfg.lightning_norm:
+            stack["o_norm"] = ("layers", "heads")
+        out[name] = stack
+    return out
+
+
+def _init_sala(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
+    """A model of linear and block-sparse layers (`cfg.mixer_types`): a
+    stack a kind (`_sala_stacks`), each with the block's projections at the
+    kind's own heads, the gate's `wg` `[D, heads * head width]`, the dense
+    feed-forward, and for the linear kind the norms of q and k a head and of
+    the joined output. The embedding is drawn so that its rows times
+    `embed_scale` start the stream at every other model's scale
+    (`init_params` says why). Keys from lists of this function's own."""
+    D, F, V, pd = cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.param_dtype
+
+    def norm(shape, k, scale=0.02):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(pd)
+
+    top = iter(jax.random.split(key, 4))
+    out = {"embed": norm((V, D), next(top), 0.02 / cfg.embed_scale),
+           "final_norm": jnp.ones((D,), pd),
+           "lm_head": norm((D, V), next(top))}
+    for name, (L, H, KVH, hd) in _sala_stacks(cfg).items():
+        ks = iter(jax.random.split(next(top), 8))
+        stack = {"attn_norm": jnp.ones((L, D), pd),
+                 "wq": norm((L, D, H * hd), next(ks)),
+                 "wk": norm((L, D, KVH * hd), next(ks)),
+                 "wv": norm((L, D, KVH * hd), next(ks)),
+                 "wo": norm((L, H * hd, D), next(ks)),
+                 "mlp_norm": jnp.ones((L, D), pd),
+                 "w_gate": norm((L, D, F), next(ks)),
+                 "w_up": norm((L, D, F), next(ks)),
+                 "w_down": norm((L, F, D), next(ks))}
+        gate_key = next(ks)
+        if _sala_gated(cfg, name):
+            stack["wg"] = norm((L, D, H * hd), gate_key)
+        if name == "linear" and cfg.lightning_qk_norm:
+            stack.update(q_norm=jnp.ones((L, hd), pd),
+                         k_norm=jnp.ones((L, hd), pd))
+        if name == "linear" and cfg.lightning_norm:
+            stack["o_norm"] = jnp.ones((L, H * hd), pd)
+        out[name] = stack
+    return out
+
+
 def _parts_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     into = ("mlp", "embed") if cfg.up_out_in else ("embed", "mlp")
     experts = {"mlp_norm": ("layers", "embed"),
@@ -1034,6 +1248,8 @@ def logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
         return _mixed_axes(cfg)
     if cfg.conv:
         return _conv_axes(cfg)
+    if cfg.sala:
+        return _sala_axes(cfg)
     if cfg.layer_parts is not None:
         return _parts_axes(cfg)
     layers: Dict[str, Tuple] = {
@@ -1175,6 +1391,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         return _init_mixed(cfg, key)
     if cfg.conv:
         return _init_conv(cfg, key)
+    if cfg.sala:
+        return _init_sala(cfg, key)
     if cfg.layer_parts is not None:
         return _init_parts(cfg, key)
     L, D, H, KVH = cfg.kv_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads
@@ -1523,6 +1741,12 @@ def forward_with_aux(params: Dict[str, Any], tokens: jax.Array,
             "the training forward has no stack of segments by kind, and no "
             "flash kernel here has a backward at a head of half a tile "
             "(ROADMAP, Reach)")
+    if cfg.sala:
+        raise NotImplementedError(
+            "linear and block-sparse layers (mixer_types) run through Serve "
+            "only: the training forward has no stack of segments by kind, "
+            "and `ops.linear_attention`'s and the block mask's kernels no "
+            "backward (ROADMAP, Reach)")
     if cfg.multipliers:
         raise NotImplementedError(
             "embed_scale, residual_scale, logit_scale and attn_scale run "

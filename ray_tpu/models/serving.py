@@ -40,8 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import block
-from ray_tpu.ops import (attention, norms, paged_kv, slot_state,
-                         sparse_attention)
+from ray_tpu.ops import (attention, linear_attention, norms, paged_kv,
+                         slot_state, sparse_attention)
 from ray_tpu.utils import get_logger
 
 logger = get_logger("models.serving")
@@ -283,6 +283,20 @@ def _reads(S, cap, **advances) -> _Counts:
         return dict(zip(advances, (int(np.minimum(reads, cap).sum()),
                                    int(reads.sum()))))
     return _Counts(advances, dispatch)
+
+
+def _state_moves(S, slot_bytes: int) -> _Counts:
+    """Counts what a stack whose decode steps move a slot's WHOLE state of
+    fixed size counts: `state_writes`, the admissions that overwrote a slot's
+    state, and `state_bytes_moved`, the bytes of state the decode chunks'
+    steps read and wrote (`slot_bytes`, a slot's state of all its layers,
+    twice a step a live slot; a chunk's are its dispatch span's
+    `state_bytes`)."""
+    def dispatch(pos, active, chunk, plan):
+        steps = int(np.clip(S - pos[active], 0, chunk).sum())
+        return {"state_bytes": 2 * slot_bytes * steps}
+    return _Counts({"state_bytes": "state_bytes_moved"}, dispatch,
+                   {"state_writes": 0})
 
 
 class Books:
@@ -744,18 +758,9 @@ def _retention_kind(mcfg) -> _Kind:
         lambda: slot_state.empty_retention(mcfg.n_layers, 1, mcfg.n_kv_heads,
                                            mcfg.head_dim)))
 
-    def dispatch(pos, active, chunk, plan):
-        steps = int(np.clip(S - pos[active], 0, chunk).sum())
-        return {"state_bytes": 2 * slot_bytes * steps}
-
-    # `state_writes`, as a hybrid's: the admissions that overwrote a slot's
-    # state. `state_bytes_moved`: the bytes of state the decode chunks' steps
-    # read and wrote (a chunk's are its dispatch span's `state_bytes`), three
-    # quarters of a step's traffic at Brumby's widths.
+    # (three quarters of a step's traffic at Brumby's widths)
     return _Kind(prefill, decode, keeps=("retention", "retention"),
-                 carries=("state",), counts=_Counts(
-                     {"state_bytes": "state_bytes_moved"}, dispatch,
-                     {"state_writes": 0}))
+                 carries=("state",), counts=_state_moves(S, slot_bytes))
 
 
 def _experts_kind(mcfg) -> _Kind:
@@ -1091,6 +1096,158 @@ def _mixed_kind(mcfg, kind: str, steps: Tuple[Callable, Callable]) -> _Kind:
                  if window else None)
 
 
+def _linear_kind(mcfg) -> _Kind:
+    """A decayed linear-attention layer (`block.linear_mixer`;
+    `mcfg.mixer_types` "lightning-attn") over the dense feed-forward: no K
+    and V and no page, for each slot a state of fixed size
+    (`ops/slot_state.py::empty_linear`), whose layer is the layer's ordinal
+    among the linear layers, which also says its decay (`mcfg.linear_rates`).
+    A prompt's rows go through the chunked form and leave the state after row
+    `length - 1` (rows past it write nothing: the operator is told
+    `length`); a decode step is handed the slots' WHOLE state and visits the
+    layer's tiles of the active slots where they lie, once. It takes no
+    riders and routes nothing."""
+    S = mcfg.max_seq
+    table = mcfg.linear_rates()
+
+    def rates(l):       # the layer's, by its ordinal (a traced scalar)
+        return jnp.asarray(table)[l]
+
+    def prefill(lp, x, caches, l, ctx):
+        x, state = block.linear_mixer(
+            lp, x, mcfg, lambda t: norms.apply_rope(t, *ctx["tables"]),
+            rates(l), length=ctx["length"])
+        x, _ = block.feed_forward(lp, x, mcfg)
+        return x, caches, (state, None), None
+
+    def begin(ctx):
+        if "c" not in ctx:
+            with jax.named_scope("rope"):
+                w = jnp.minimum(ctx["pos"], S - 1)
+                ctx["c"], ctx["s"] = (t[w][:, None] for t in ctx["tables"])
+
+    def decode(lp, x, caches, l, ctx):
+        x, state = block.linear_mixer(
+            lp, x, mcfg, lambda t: _rope_one(t, ctx["c"], ctx["s"]),
+            rates(l), caches.state, step=True, layer=l,
+            active=ctx["act"])
+        x, _ = block.feed_forward(lp, x, mcfg, ctx["act"])
+        return x, caches._replace(state=state), None
+
+    # A slot's state, all linear layers: what one of its decode steps reads,
+    # and writes back.
+    slot_bytes = slot_state.state_bytes(jax.eval_shape(
+        lambda: slot_state.empty_linear(mcfg.state_layers, 1,
+                                        mcfg.lightning_heads,
+                                        mcfg.lightning_head_dim)))
+
+    return _Kind(prefill, decode, keeps=("linear", "linear"), over="index",
+                 carries=("state",), begin=begin,
+                 counts=_state_moves(S, slot_bytes))
+
+
+def _block_sparse_kind(mcfg) -> _Kind:
+    """A layer that selects BLOCKS (`mcfg.mixer_types` "minicpm4";
+    `ops/sparse_attention.py::BlockSparse`) over the dense feed-forward:
+    grouped-query attention with no rotation, K and V under the block table
+    with ONE kv head a layer of the arena (kv head g of the layer's ordinal l
+    its layer `l * kv_heads + g`: a selection is a kv head's own, and each
+    reads by a table of its own) and a page a block, and the slot's pooled
+    keys beside them (`ops/slot_state.py::empty_pooled`). A prompt: plain
+    flash attention where the bucket is under `dense_len`, else the pooled
+    keys, the selection and `block_flash` under the mask by blocks
+    (`block_sparse_attention`; a row under `dense_len` reads every block up
+    to its own there). A decode step writes K and V to the page, its key into
+    the slot's pooled keys, and reads every live page or the selected ones
+    alone, by `pos`, on the device (`block_sparse_decode`). The output is
+    gated before `wo` (`block.output_gated`). It takes no riders and routes
+    nothing."""
+    H, KVH, hd, S = mcfg.n_heads, mcfg.n_kv_heads, mcfg.head_dim, mcfg.max_seq
+    dt = mcfg.dtype
+    sizes = mcfg.block_sparse
+    scale = mcfg.softmax_scale
+    # What a program is built with is the function as it stands on its
+    # module now (a test puts an interpreted kernel there first).
+    paged_decode = paged_kv.paged_decode_attention
+    block_sparse = sparse_attention.block_sparse_attention
+
+    def prefill(lp, x, caches, l, ctx):
+        nb, Sq, _ = x.shape
+        q, k, v, gate = block.block_sparse_attention_inputs(lp, x, mcfg)
+        with jax.named_scope("attn"):
+            with jax.named_scope("compress"):
+                pooled, sums = sparse_attention.compress(k[0], sizes,
+                                                         ctx["length"])
+            if Sq < sizes.dense_len:    # no row's context reaches it
+                attn = attention.flash_attention(
+                    q, attention.repeat_kv(k, H // KVH),
+                    attention.repeat_kv(v, H // KVH), True, scale)[0]
+            else:
+                attn = block_sparse(q[0], k[0], v[0], pooled, sizes,
+                                    sm_scale=scale)
+            attn = block.output_gated(
+                attn.transpose(1, 0, 2).reshape(nb, Sq, H * hd), gate)
+        with jax.named_scope("attn_out"):
+            x = x + block.scaled(
+                jnp.einsum("bsh,hd->bsd", attn, lp["wo"].astype(dt)), mcfg)
+        x, _ = block.feed_forward(lp, x, mcfg)
+        kept = (k[0][:, :, None], v[0][:, :, None],     # [KVH, S, 1, hd]
+                pooled.transpose(1, 0, 2).reshape(pooled.shape[1], KVH * hd),
+                sums.transpose(1, 0, 2).reshape(2, KVH * hd))
+        return x, caches, kept, None
+
+    def begin(ctx):
+        if "w" not in ctx:
+            ctx["w"] = jnp.minimum(ctx["pos"], S - 1)
+
+    def decode(lp, x, caches, l, ctx):
+        ns = x.shape[0]
+        bt, w, act = ctx["bt"], ctx["w"], ctx["act"]
+        kc, vc, ic, _ = caches
+        q, k, v, gate = block.block_sparse_attention_inputs(lp, x, mcfg)
+        for g in range(KVH):
+            kc, vc = paged_kv.write_token(kc, vc, l * KVH + g, bt, w, act,
+                                          k[:, g:g + 1], v[:, g:g + 1])
+        with jax.named_scope("attn"):
+            pooled, ic = slot_state.pooled_step_layer(ic, l, w, act, k, sizes)
+            attn = sparse_attention.block_sparse_decode(
+                q, pooled, kc, vc, l, bt, w, act, sizes, paged_decode,
+                sm_scale=scale)
+            attn = block.output_gated(attn.reshape(ns, H * hd), gate)
+        with jax.named_scope("attn_out"):
+            x = x + block.scaled(attn @ lp["wo"].astype(dt), mcfg)
+        x, _ = block.feed_forward(lp, x, mcfg, act)
+        return x, caches._replace(kc=kc, vc=vc, ic=ic), None
+
+    def dispatch(pos, active, chunk, plan):
+        # a slot at p reads min(blocks up to its own, topk) once its context
+        # reaches dense_len, every one of them under it
+        at = (pos[active][:, None] + np.arange(chunk)).clip(max=S - 1)
+        visible = at // sizes.block + 1
+        dense = at + 1 < sizes.dense_len
+        return {"blocks_selected": int(np.where(
+                    dense, visible, np.minimum(visible, sizes.topk)).sum())
+                * KVH,
+                "blocks_visible": int(visible.sum()) * KVH,
+                "dense_rows": int(dense.sum())}
+
+    def pack(ks, vs, pooled, sums):
+        # the arena's layers are (layer, kv head): [L, KVH, S, 1, hd] ->
+        return (ks.reshape(-1, *ks.shape[2:]), vs.reshape(-1, *vs.shape[2:]),
+                pooled, sums)
+
+    # `blocks_selected` / `blocks_visible`: the pages a kv head's decode steps
+    # read over those its slot held (their ratio: the share of the context
+    # read); `dense_rows`: the steps served under `dense_len`.
+    return _Kind(prefill, decode,
+                 keeps=("pages", "pages", "pooled", "pooled"), over="index",
+                 carries=("kc", "vc", "ic"), begin=begin, pack=pack,
+                 counts=_Counts({"blocks_selected": "decode_blocks_selected",
+                                 "blocks_visible": "decode_blocks_visible",
+                                 "dense_rows": "decode_dense_rows"},
+                                dispatch))
+
+
 def _stack(mcfg) -> _Stack:
     """The table of a model's kinds of layer, keyed as `mcfg.segments()`
     names them, and what the walks ask of the stack as a whole. The ONE place
@@ -1134,6 +1291,40 @@ def _stack(mcfg) -> _Stack:
             lambda ns, page, n_pages: Caches(
                 state=slot_state.empty_retention(mcfg.n_layers, ns, KVH, hd)),
             lambda c: {"state_bytes": slot_state.state_bytes(c.state)})
+    if mcfg.sala:
+        lh, ld = mcfg.lightning_heads, mcfg.lightning_head_dim
+        sizes = mcfg.block_sparse
+
+        def empty(ns, page, n_pages):
+            if mcfg.kv_layers and page != sizes.block:
+                raise ValueError(
+                    f"page_size {page}: a block-sparse layer's selected "
+                    f"block IS a page of the slot's table (sparse_block "
+                    f"{sizes.block})")
+            return Caches(
+                *paged_kv.empty(mcfg.kv_layers * KVH, n_pages, 1, page, hd,
+                                dt),
+                ic=slot_state.empty_pooled(
+                    mcfg.kv_layers, ns, mcfg.max_seq // sizes.stride,
+                    KVH * hd, dt) if mcfg.kv_layers else None,
+                state=slot_state.empty_linear(mcfg.state_layers, ns, lh, ld)
+                if mcfg.state_layers else None)
+
+        return _Stack(
+            # (a kind the list does not have has no stack, and no kind)
+            {name: kind(mcfg) for name, kind, n in (
+                ("sparse", _block_sparse_kind, mcfg.kv_layers),
+                ("linear", _linear_kind, mcfg.state_layers)) if n},
+            # the linear layers' tables (the sparse layers turn nothing)
+            lambda n, rows: dict(tables=norms.rope_frequencies(
+                ld, n, mcfg.rope_theta)),
+            # pages for the sparse layers, a kv head a layer of the arena;
+            # their pooled keys and the linear layers' state a slot
+            empty,
+            lambda c: {"linear_state_bytes": slot_state.state_bytes(
+                           c.state or ()),
+                       "pooled_key_bytes": slot_state.state_bytes(
+                           c.ic or ())})
     if mcfg.conv:
         return _Stack(
             # (the leading dense layers are conv layers: the windows' first)
@@ -1201,8 +1392,33 @@ def rung_refusal(mcfg, width: int) -> Optional[str]:
     reference for the rest, which from outside shows in the path counts
     alone: a rung of a block or more (128 rows: a prompt under one is a few
     score matrices in XLA, by design) that no kernel takes is a
-    configuration to refuse when its server is built (`Engine`)."""
-    if not mcfg.mixed or width < 128:
+    configuration to refuse when its server is built (`Engine`). A stack of
+    linear and block-sparse layers likewise: its three kernels take whole
+    tiles, and fall to `jnp` for the rest."""
+    if width < 128:
+        return None
+    if mcfg.sala:
+        # a prompt is one program: its linear layers in whole chunks of whole
+        # tiles, its sparse layers under `dense_len` in the flash kernel and
+        # from there on in `block_flash`
+        chunk = min(linear_attention.CHUNK, width)
+        sizes = mcfg.block_sparse
+        if mcfg.state_layers and (width % chunk or not
+                                  linear_attention.kernel_tiles(
+                                      mcfg.lightning_head_dim, chunk)):
+            return (f"stack `linear`: {width} rows of heads of "
+                    f"{mcfg.lightning_head_dim} are no whole chunks of whole "
+                    "tiles")
+        if mcfg.kv_layers and (
+                width % 128 or mcfg.head_dim % 128
+                or (width >= sizes.dense_len and not
+                    sparse_attention.block_flash_tiles(
+                        width, mcfg.head_dim, sizes.block))):
+            return (f"stack `sparse`: {width} rows of heads of "
+                    f"{mcfg.head_dim} under blocks of {sizes.block} are no "
+                    "whole tiles")
+        return None
+    if not mcfg.mixed:
         return None
     for name in ("layers", "window"):       # the full kind, the window kind
         kind = mcfg.attention_kind(name)
@@ -1251,6 +1467,10 @@ _KEEP = {
                                              **how)),
     "retention": lambda c, pages, slot, length, S, z: c._replace(
         state=slot_state.write_retention(c.state, slot, S, z)),
+    "pooled": lambda c, pages, slot, length, pooled, sums: c._replace(
+        ic=slot_state.write_pooled(c.ic, slot, pooled, sums)),
+    "linear": lambda c, pages, slot, length, S, _: c._replace(
+        state=slot_state.write_linear(c.state, slot, S)),
 }
 
 
@@ -1444,7 +1664,7 @@ def _prefill_walk(mcfg, stack: _Stack):
         # rest after, as the pinned programs have it.)
         out = joined("pages", "ring")
         logits = (logits[0] if riders is None else logits).astype(jnp.float32)
-        out.update(joined("index", "state", "retention"))
+        out.update(joined("index", "state", "retention", "pooled", "linear"))
         return first, out, logits, experts if sparse else None, caches
 
     return walk
@@ -1794,7 +2014,7 @@ def build_programs(mcfg, n_slots: int, chunk: int, page: int,
         adopt=jax.jit(adopt, donate_argnums=(0,)),
         poke=jax.jit(poke, donate_argnums=(0, 1)),
         takes_riders=stack.takes_riders, adopts=stack.adopts,
-        by_slot=any(cache in ("state", "ring", "retention") for kind in
+        by_slot=any(cache not in ("pages", "index") for kind in
                     stack.kinds.values() for cache in kind.keeps),
         paged=mcfg.kv_layers > 0,
         books=lambda caches: Books(mcfg, counts, stack.shares,

@@ -50,7 +50,10 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # "latent_decode_*"; a mixed stack's "window_fwd_*", "full_fwd_*" and
 # "window_decode_reference"; generation by blocks' "block_fwd_*", a prompt
 # under the block mask, and "block_decode_*", a step of a block of rows a
-# slot): the dispatch is otherwise invisible
+# slot; a linear layer's "linear_*", its prompt's chunks and its step, and a
+# block-selecting layer's prompt, "block_sparse_*", whose decode step is
+# "decode_*" by a table of the selected pages): the dispatch is otherwise
+# invisible
 # from outside a jitted program, and a benchmark must be able to assert that
 # the kernel it names is the one that ran.
 _path_counts: collections.Counter = collections.Counter()

@@ -71,6 +71,24 @@ layer, at 200 positions as at 8,000.
     A row's position is not stored: at step w, row r holds the largest
     position <= w that is r modulo R, which is live if it is >= 0 and less
     than `window` back.
+
+A model of LINEAR and BLOCK-SPARSE layers (`LlamaConfig.mixer_types`, the
+MiniCPM-SALA family) keeps two things a slot beside its sparse layers' pages:
+
+  * each linear layer's state, ONE array `[n_layers, n_slots, heads, d, d]`
+    float32 (2.1 MB a slot a layer at 32 heads of 128, whatever the
+    context): ``empty_linear``, ``write_linear`` a prefill's write (the
+    prompt's final state, all layers at once), ``linear_step_layer`` a decode
+    step's one visit (`ops/linear_attention.py::linear_state_step`);
+  * each sparse layer's POOLED keys, a pair: `[n_layers, n_slots, max_seq /
+    stride, kv_heads * head_dim]`, entry i the mean of the slot's keys
+    `stride * i .. stride * i + kernel - 1`, and `[n_layers, n_slots, 2,
+    kv_heads * head_dim]` float32, the running sums of the last two groups
+    of `stride` keys, from which a decode step finishes the pooled key that
+    ends at its position (`ops/sparse_attention.py::compress_step`):
+    ``empty_pooled``, ``write_pooled`` (a prompt's pooled keys over the
+    slot's first rows; what the previous tenant left past them is finished
+    anew before anything reads it), ``pooled_step_layer``.
 """
 
 from __future__ import annotations
@@ -81,7 +99,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import attention
+from ray_tpu.ops import linear_attention as linear_ops
 from ray_tpu.ops import retention as retention_ops
+from ray_tpu.ops import sparse_attention as sparse_ops
 from ray_tpu.ops import ssm as ssm_ops
 from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
 from ray_tpu.ops.paged_kv import _lanes, _to_width
@@ -218,6 +238,72 @@ def retention_step_layer(state: State, layer, active, q, k, v, gamma, *,
     return jnp.where(active[:, None, None], y, 0.0), (
         S.at[layer].set(jnp.where(keep[..., None], S_rows, S[layer])),
         z.at[layer].set(jnp.where(keep, z_rows, z[layer])))
+
+
+# ---------------------------------------------------------------------------
+# A linear layer's state, and a block-sparse layer's pooled keys
+# ---------------------------------------------------------------------------
+
+def empty_linear(n_layers: int, n_slots: int, heads: int, head_dim: int
+                 ) -> State:
+    """-> (S,), zeroed: `[n_layers, n_slots, heads, d, d]` float32."""
+    return (jnp.zeros((n_layers, n_slots, heads, head_dim, head_dim),
+                      jnp.float32),)
+
+
+def write_linear(state: State, slot, S_rows) -> State:
+    """A prefill's result into slot `slot` (a traced scalar), all layers at
+    once, the whole of what its previous tenant left overwritten: `S_rows`
+    `[n_layers, heads, d, d]`."""
+    with jax.named_scope("state_write"):
+        return (state[0].at[:, slot].set(S_rows.astype(jnp.float32)),)
+
+
+def linear_step_layer(state: State, layer, active, q, k, v, rates,
+                      scale: float, *, interpret: bool = False
+                      ) -> Tuple[jax.Array, State]:
+    """A linear layer's decode step, one token a slot, on the slots' whole
+    state in ONE visit (`ops.linear_attention.linear_state_step`, which
+    counts its path) -> (o `[n_slots, heads, d]` float32, zeros for an idle
+    slot; the state, whose idle slots' tiles and other layers stay what they
+    were)."""
+    o, S = linear_ops.linear_state_step(state[0], layer, active, q, k, v,
+                                        rates, scale, interpret=interpret)
+    return o, (S,)
+
+
+def empty_pooled(n_layers: int, n_slots: int, entries: int, width: int,
+                 dtype) -> State:
+    """-> (pooled `[n_layers, n_slots, entries, width]` in `dtype`, sums
+    `[n_layers, n_slots, 2, width]` float32), zeroed."""
+    return (jnp.zeros((n_layers, n_slots, entries, width), dtype),
+            jnp.zeros((n_layers, n_slots, 2, width), jnp.float32))
+
+
+def write_pooled(state: State, slot, pooled_rows, sum_rows) -> State:
+    """A prefill's pooled keys `[n_layers, W / stride, width]` into slot
+    `slot`'s first rows, and its running sums `[n_layers, 2, width]`."""
+    pooled, sums = state
+    with jax.named_scope("state_write"):
+        return (jax.lax.dynamic_update_slice(
+                    pooled, pooled_rows.astype(pooled.dtype)[:, None],
+                    (0, slot, 0, 0)),
+                sums.at[:, slot].set(sum_rows.astype(sums.dtype)))
+
+
+def pooled_step_layer(state: State, layer, w, active, k,
+                      sizes: "sparse_ops.BlockSparse"
+                      ) -> Tuple[jax.Array, State]:
+    """A decode step's keys `[n_slots, kv_heads, head_dim]` at positions
+    `w` into one layer's pooled keys (`compress_step`; an idle slot's rows
+    stay) -> (the layer's pooled keys `[n_slots, entries, width]` with this
+    step's in; the state)."""
+    pooled, sums = state
+    with jax.named_scope("compress"):
+        rows, moved = sparse_ops.compress_step(
+            pooled[layer], sums[layer], k.reshape(k.shape[0], -1), w, active,
+            sizes)
+        return rows, (pooled.at[layer].set(rows), sums.at[layer].set(moved))
 
 
 # ---------------------------------------------------------------------------

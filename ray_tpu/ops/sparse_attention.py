@@ -35,7 +35,7 @@ Scope names `indexer`, `select` and `sparse_attn` lie inside the caller's
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -642,3 +642,348 @@ def sparse_decode_attention(q, qi, w, kc, vc, ic, layer, block_table,
         vs = jnp.where(picked[:, :, None, None], vs, 0)
         out = jnp.einsum("nkgs,nskd->nkgd", p.astype(vs.dtype), vs)
     return out.reshape(ns, H, hd).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Selection by BLOCKS (InfLLM-v2, the MiniCPM4 / MiniCPM-SALA family's sparse
+# layers): no learned scorer. Keys are mean-pooled over `kernel` positions
+# every `stride`, a query's softmax over the pooled keys it may see is summed
+# over the query heads of its kv head, a block of `block` positions scores the
+# max over the pooled keys that overlap it, and the query reads the `topk`
+# best blocks (the first `init_blocks` and the `window` positions that end at
+# its own always among them), ONE selection a kv head. A query whose context
+# is under `dense_len` reads every earlier key. No rotation anywhere.
+# ---------------------------------------------------------------------------
+
+class BlockSparse(NamedTuple):
+    """The sizes of a block-selecting layer (`LlamaConfig.block_sparse`)."""
+    kernel: int         # positions a pooled key is the mean of
+    stride: int         # positions between two pooled keys' first ones
+    block: int          # positions a selected block holds: the cache's page
+    topk: int           # blocks a query reads
+    init_blocks: int    # the leading blocks every query reads
+    window: int         # positions before a query's own that it always reads
+    dense_len: int      # contexts under it are read whole
+
+    @property
+    def window_blocks(self) -> int:
+        return self.window // self.block
+
+
+def compress(k: jax.Array, sizes: BlockSparse, length=None):
+    """k `[KVH, T, hd]` -> (pooled keys `[KVH, T / stride, hd]` float32, entry
+    i the mean of rows `stride * i .. stride * i + kernel - 1`, the last
+    `kernel / stride - 1` entries and whatever reaches a row at or past
+    `length` not meaningful; sums `[KVH, 2, hd]` float32: of the whole
+    `stride` rows before row `length`'s own group, and of that group's rows
+    under `length`: what a decode step adds its key to
+    (`compress_step`)). `kernel` is two strides."""
+    KVH, T, hd = k.shape
+    stride = sizes.stride
+    rows = k.astype(jnp.float32)
+    if length is not None:
+        rows = jnp.where((jnp.arange(T) < length)[None, :, None], rows, 0.0)
+    else:
+        length = T
+    groups = rows.reshape(KVH, T // stride, stride, hd).sum(axis=2)
+    padded = jnp.pad(groups, ((0, 0), (1, 1), (0, 0)))
+    pooled = (padded[:, 1:-1] + padded[:, 2:]) / sizes.kernel
+    at = length // stride
+    sums = jnp.stack([jax.lax.dynamic_index_in_dim(padded, at + i, 1, False)
+                      for i in (0, 1)], axis=1)
+    return pooled, sums
+
+
+def compress_step(pooled, sums, k, w, active, sizes: BlockSparse):
+    """A decode step's key into a slot's pooled keys: pooled `[ns, NK, KVH *
+    hd]`, sums `[ns, 2, KVH * hd]` float32 (`compress`'s), k `[ns, KVH * hd]`
+    the key at position `w` `[ns]`. The key joins its group's sum; at a
+    group's last row (`w % stride == stride - 1`) the pooled key that ends
+    there, entry `(w + 1 - kernel) / stride`, is the two sums over `kernel`,
+    and the group's sum becomes the one before. An idle slot's rows stay."""
+    ns, NK, _ = pooled.shape
+    stride = sizes.stride
+    before, group = sums[:, 0], sums[:, 1] + k.astype(jnp.float32)
+    ends = active & (w % stride == stride - 1)
+    entry = (w + 1 - sizes.kernel) // stride
+    done = ((before + group) / sizes.kernel).astype(pooled.dtype)
+    here = (jnp.arange(NK)[None, :] == entry[:, None]) \
+        & (ends & (entry >= 0))[:, None]
+    pooled = jnp.where(here[..., None], done[:, None], pooled)
+    keep = active[:, None]
+    new = jnp.stack([jnp.where(ends[:, None], group, before),
+                     jnp.where(ends[:, None], 0.0, group)], axis=1)
+    return pooled, jnp.where(keep[..., None], new, sums)
+
+
+def block_scores(q, pooled, pos, sizes: BlockSparse, sm_scale: float):
+    """q `[.., KVH, G, R, hd]`, pooled `[.., KVH, NK, hd]`, pos `[.., R]` the
+    rows' positions -> `[.., KVH, R, NB]` float32, NB = NK * stride / block:
+    each block's score for each row, the max over the pooled keys that
+    overlap the block of the group's summed softmax over the pooled keys the
+    row may see (entry i when `stride * i + kernel - 1 <= pos`); 0 where it
+    sees none of them."""
+    NK = pooled.shape[-2]
+    per, lead = sizes.block // sizes.stride, sizes.kernel // sizes.stride - 1
+    s = jnp.einsum("...kgrd,...kid->...kgri", q, pooled.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * sm_scale
+    seen = (jnp.arange(NK) * sizes.stride + sizes.kernel - 1
+            <= pos[..., None])[..., None, None, :, :]
+    p = jax.nn.softmax(jnp.where(seen, s, DEFAULT_MASK_VALUE), axis=-1)
+    p = jnp.sum(jnp.where(seen, p, 0.0), axis=-3)            # [.., KVH, R, NK]
+    # block b meets entries per * b - lead .. per * b + per - 1
+    pad = [(0, 0)] * (p.ndim - 1) + [(lead, 0)]
+    p = jnp.pad(p, pad)
+    NB = NK // per
+    best = p[..., :per * NB].reshape(*p.shape[:-1], NB, per).max(axis=-1)
+    for j in range(lead):
+        best = jnp.maximum(best, p[..., per + j::per][..., :NB])
+    return best
+
+
+def block_select(scores, pos, sizes: BlockSparse):
+    """scores `[.., KVH, R, NB]` float32 (`block_scores`), pos `[.., R]` ->
+    bool `[.., KVH, R, NB]`: the blocks each row reads. A row whose context
+    `pos + 1` is under `dense_len`: every block up to its own. Else the
+    `topk` of largest score among them, the first `init_blocks` and the
+    `window / block` that end at its own scoring +inf, ties to the smaller
+    block, exactly: a block is read when fewer than `topk` go before it."""
+    NB = scores.shape[-1]
+    b = jnp.arange(NB)
+    own = (pos // sizes.block)[..., None, :, None]
+    valid = b <= own
+    forced = (b < sizes.init_blocks) | (b > own - sizes.window_blocks)
+    s = jnp.where(valid, jnp.where(forced, jnp.inf, scores), -jnp.inf)
+    ahead = (s[..., None, :] > s[..., :, None]) | (
+        (s[..., None, :] == s[..., :, None]) & (b[None, :] < b[:, None]))
+    picked = (jnp.sum(ahead, axis=-1, dtype=jnp.int32) < sizes.topk) & valid
+    dense = (pos + 1 < sizes.dense_len)[..., None, :, None]
+    return jnp.where(dense, valid, picked)
+
+
+_SELECT_ROWS = 256      # rows of a prompt scored and selected at once
+
+
+def block_mask(q, pooled, sizes: BlockSparse, sm_scale: float):
+    """q `[KVH, G, T, hd]`, pooled `[KVH, T / stride, hd]` -> bool `[KVH, T,
+    T / block]`: `block_select` of every row of a prompt at positions 0..T-1,
+    `_SELECT_ROWS` rows at a time from the first row whose context reaches
+    `dense_len` (the rows before it read every block up to their own, and
+    score nothing)."""
+    KVH, G, T, hd = q.shape
+    NB = T // sizes.block
+    rows = attention._pick_block(T, _SELECT_ROWS) if T % 128 == 0 else T
+    first = max(sizes.dense_len - 1, 0) // rows * rows
+    causal = jnp.broadcast_to(
+        jnp.arange(NB)[None, :] <= (jnp.arange(first) // sizes.block)[:, None],
+        (KVH, first, NB))
+    if first >= T:
+        return causal[:, :T]
+
+    def select(start):
+        pos = start + jnp.arange(rows)
+        qs = jax.lax.dynamic_slice_in_dim(q, start, rows, 2)
+        return block_select(block_scores(qs, pooled, pos, sizes, sm_scale),
+                            pos, sizes)
+
+    picked = jax.lax.map(select, jnp.arange(first, T, rows))
+    picked = picked.transpose(1, 0, 2, 3).reshape(KVH, T - first, NB)
+    return jnp.concatenate([causal, picked], axis=1)
+
+
+def _block_sparse_reference(q, k, v, mask, sizes, sm_scale):
+    """q `[KVH, G, T, hd]`, k/v `[KVH, T, hd]`, mask `[KVH, T, NB]` bool."""
+    T = q.shape[2]
+    keep = jnp.repeat(mask, sizes.block, axis=-1) \
+        & (jnp.arange(T)[None, :] <= jnp.arange(T)[:, None])
+    s = jnp.einsum("kgtd,ksd->kgts", q, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    p = jax.nn.softmax(jnp.where(keep[:, None], s, DEFAULT_MASK_VALUE), -1)
+    return jnp.einsum("kgts,ksd->kgtd", p.astype(v.dtype), v)
+
+
+def _block_flash_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref,
+                        l_ref, *, sm_scale: float, block_q: int, block_k: int,
+                        shift: int):
+    """`_masked_flash_kernel` under a mask BY BLOCKS: `mask_ref` `[block_q,
+    block_k >> shift]` names, for each query row, the blocks of `1 << shift`
+    keys it reads of this block of keys; spread to the keys by a product
+    with a constant of ones (lanes are not repeated otherwise), then cut to
+    the causal half inside a row's own block."""
+    q_idx, kv_idx = pl.program_id(1), pl.program_id(2)
+    G, _, hd = q_ref.shape[1:]
+    nb = block_k >> shift
+
+    @pl.when(kv_idx == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, DEFAULT_MASK_VALUE)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(kv_idx * block_k <= q_idx * block_q + (block_q - 1))
+    def _body():
+        q = q_ref[0].reshape(G * block_q, hd)
+        s = jax.lax.dot_general(
+            q, k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        spread = (jax.lax.broadcasted_iota(jnp.int32, (nb, block_k), 1) >> shift
+                  == jax.lax.broadcasted_iota(jnp.int32, (nb, block_k), 0)
+                  ).astype(mask_ref.dtype)
+        picked = jax.lax.dot_general(
+            mask_ref[0, 0], spread, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) > 0.5        # [block_q, block_k]
+        rows = q_idx * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        cols = kv_idx * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        keep = picked & (cols <= rows)
+        s = jnp.where(keep[None], s.reshape(G, block_q, block_k),
+                      DEFAULT_MASK_VALUE).reshape(G * block_q, block_k)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # (a row that has met no selected key yet: `_masked_flash_kernel`;
+        # every row reads its own block)
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    def _finalize():
+        out = acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+        o_ref[0] = out.reshape(G, block_q, hd).astype(o_ref.dtype)
+
+
+# Query rows of a kv head's whole group a grid step of `block_flash` holds
+# (their scores against a block of keys are float32 in fast memory).
+_BLOCK_FLASH_ROWS = 2048
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block", "sm_scale", "interpret"))
+def _block_flash_pallas(q, k, v, mask, *, block, sm_scale, interpret=False):
+    """q `[KVH, G, T, hd]`, k/v `[KVH, T, hd]`, mask bool `[KVH, T, T /
+    block]` -> `[KVH, G, T, hd]`. Under a `jit` of its own."""
+    KVH, G, T, hd = q.shape
+    block_q = attention._pick_block(T, max(128, _BLOCK_FLASH_ROWS // G))
+    block_k = attention._pick_block(T, 1024)
+    nb = block_k // block
+    # [KVH, key blocks, T, blocks of a key block]: a grid step's part is a
+    # block whose last dimension is the array's own.
+    laid = mask.astype(q.dtype).reshape(KVH, T, T // block_k, nb).transpose(
+        0, 2, 1, 3)
+
+    def last_block(qi):          # the last kv block a query block can see
+        return (qi * block_q + block_q - 1) // block_k
+
+    kernel = functools.partial(
+        _block_flash_kernel, sm_scale=sm_scale, block_q=block_q,
+        block_k=block_k, shift=block.bit_length() - 1)
+    kv_spec = pl.BlockSpec(
+        (1, block_k, hd),
+        lambda h, qi, ki: (h, jnp.minimum(ki, last_block(qi)), 0))
+    q_spec = pl.BlockSpec((1, G, block_q, hd), lambda h, qi, ki: (h, 0, qi, 0))
+    return pl.pallas_call(
+        kernel,
+        name="block_flash",
+        grid=(KVH, T // block_q, T // block_k),
+        in_specs=[
+            q_spec, kv_spec, kv_spec,
+            pl.BlockSpec((1, 1, block_q, nb), lambda h, qi, ki: (
+                h, jnp.minimum(ki, last_block(qi)), qi, 0)),
+        ],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((G * block_q, hd), jnp.float32),
+            pltpu.VMEM((G * block_q, 1), jnp.float32),
+            pltpu.VMEM((G * block_q, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(q, k, v, laid)
+
+
+def block_flash_tiles(T: int, hd: int, block: int) -> bool:
+    """Whether `block_flash` takes a prompt of T rows: whole tiles, and a
+    block that is a power of two and divides a block of keys."""
+    return T % 128 == 0 and hd % 128 == 0 and block & (block - 1) == 0 \
+        and attention._pick_block(T, 1024) % block == 0
+
+
+def block_sparse_attention(q, k, v, pooled, sizes: BlockSparse, *,
+                           sm_scale: Optional[float] = None,
+                           interpret: bool = False) -> jax.Array:
+    """Causal attention of a whole prompt under the selection by blocks: q
+    `[H, T, hd]`, k, v `[KVH, T, hd]` (query head h reads kv head h // (H //
+    KVH)), pooled `[KVH, T / stride, hd]` (`compress`) -> `[H, T, hd]`. On a
+    TPU the kernel `block_flash` under the mask by blocks `[KVH, T, T /
+    block]`; elsewhere XLA over every pair. Counted as `block_sparse_pallas`
+    / `block_sparse_reference`."""
+    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    H, T, hd = q.shape
+    KVH = k.shape[0]
+    qg = q.reshape(KVH, H // KVH, T, hd)
+    use = interpret or (attention._on_tpu()
+                        and block_flash_tiles(T, hd, sizes.block))
+    attention._path_counts["block_sparse_pallas" if use
+                           else "block_sparse_reference"] += 1
+    with jax.named_scope("block_select"):
+        mask = block_mask(qg, pooled, sizes, scale)
+    with jax.named_scope("block_sparse_attn"):
+        out = _block_flash_pallas(
+            qg, k, v, mask, block=sizes.block, sm_scale=float(scale),
+            interpret=interpret) if use \
+            else _block_sparse_reference(qg, k, v, mask, sizes, scale)
+    return out.reshape(H, T, hd)
+
+
+def block_sparse_decode(q, pooled, kc, vc, layer, block_table, w, active,
+                        sizes: BlockSparse, paged_decode, *,
+                        sm_scale: Optional[float] = None) -> jax.Array:
+    """One query token a slot: q `[ns, H, hd]` at positions `w` `[ns]`;
+    pooled `[ns, NK, KVH * hd]` the slots' pooled keys (`compress_step`'s,
+    this step's key in); kc, vc the arena with ONE kv head a layer, `[L * KVH,
+    n_pages, 1, page, hd]`, kv head g of this layer its layer `layer * KVH +
+    g` (a selection is a kv head's own, so each reads by a table of its
+    own); `page` is `sizes.block`. -> `[ns, H, hd]`, zeros for an idle slot.
+
+    A slot whose context `w + 1` is under `dense_len` reads its live pages,
+    every one. Any other scores the pooled keys it may see, selects (`block_
+    select`), and reads the selected pages ALONE: their entries of its row
+    of the block table moved to the row's front, in order, the row's own
+    (partial) page last, so `paged_decode` (`ops.paged_kv.
+    paged_decode_attention` as the caller holds it) walks `topk` pages and
+    masks the last one's tail, as it does a dense slot's."""
+    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    ns, H, hd = q.shape
+    KVH = pooled.shape[-1] // hd
+    NB = block_table.shape[1]
+    with jax.named_scope("block_select"):
+        qg = q.reshape(ns, KVH, H // KVH, 1, hd)
+        keys = pooled.reshape(ns, -1, KVH, hd).transpose(0, 2, 1, 3)
+        scores = block_scores(qg, keys, w[:, None], sizes, scale)
+        # (a table wider than the pooled keys' blocks has none to read there)
+        scores = jnp.pad(scores, [(0, 0)] * 3 + [(0, max(
+            0, NB - scores.shape[-1]))])[..., :NB]
+        picked = block_select(scores, w[:, None], sizes)[:, :, 0]  # [ns,KVH,NB]
+        count = jnp.sum(picked, axis=-1, dtype=jnp.int32)
+        # the selected blocks first, in order (a stable sort of 0s and 1s)
+        order = jnp.argsort(~picked, axis=-1, stable=True)
+        tables = jnp.take_along_axis(
+            jnp.broadcast_to(block_table[:, None], picked.shape), order,
+            axis=-1)
+        lengths = jnp.where(
+            active[:, None], (count - 1) * sizes.block + w[:, None]
+            % sizes.block + 1, 0)
+    with jax.named_scope("block_sparse_attn"):
+        G = H // KVH
+        out = [paged_decode(q[:, g * G:(g + 1) * G], kc, vc, layer * KVH + g,
+                            tables[:, g], lengths[:, g], sm_scale=scale)
+               for g in range(KVH)]
+    return jnp.concatenate(out, axis=1)

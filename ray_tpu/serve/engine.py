@@ -532,7 +532,9 @@ class Engine:
                 "rings) nor a short-convolution layer's window (conv_layers)"
                 " nor a block that a prompt's tail opens (block_length > 1)"
                 " nor a power-retention layer's state (mixer 'retention')"
-                ": this model serves from one engine")
+                " nor a linear layer's state beside a block-sparse layer's "
+                "pooled keys (mixer_types): this model serves from one "
+                "engine")
         if not self._adopt_widths:
             raise RuntimeError(
                 "this engine warmed no `adopt` program and would compile one "

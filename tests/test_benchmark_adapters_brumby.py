@@ -86,8 +86,8 @@ def test_brumby_manifest_entries_are_the_catalogs_row_and_the_issues_cell():
     # appended after the twelve cells that were there: nothing moved
     assert manifest["workloads"].index(cell) == 12 \
         and manifest["configs"].index(entry) == 11
-    # (PR 62 appended its two after them)
-    assert [p["name"] for p in manifest["per_layer"][-7:-2]] == NEW
+    # (PR 62 appended its two after them, PR 65 its nine after those)
+    assert [p["name"] for p in manifest["per_layer"][-16:-11]] == NEW
 
 
 def test_brumby_traffic_is_generate_long_granite4hs_but_for_the_clients():

@@ -32,17 +32,19 @@ def test_adapter_exposes_the_whole_contract():
 
 def test_manifest_entries_are_the_catalogs_row_and_the_issues_traffic():
     manifest = cases.load(ROOT, "BENCHMARK.json")
-    entry = manifest["configs"][-1]
+    # (PR 65 appended its configuration, its cell and nine readers after
+    # this PR's: nothing of these moved)
+    entry = manifest["configs"][12]
     assert entry["name"] == CONFIG
     cfg = cases.load(ROOT, entry["file"])
     assert entry["source"] == cfg["source_url"] and cfg["arch"] == "laguna"
     assert entry["reduced"] == list(cfg["reduced"]) == [
         "num_hidden_layers", "num_experts", "vocab_size"]
     assert len(cfg["assumed"]) >= 10 and cfg["expert_parallel"]["chips"] == 8
-    cell = manifest["workloads"][-1]
+    cell = manifest["workloads"][13]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL, CONFIG, "longdoc-qa-laguna", 1)
-    assert "1/8 of its load" in cell["why"] and len(manifest["workloads"]) == 14
+    assert "1/8 of its load" in cell["why"] and len(manifest["workloads"]) == 15
     mix = cases.load(cases.BENCH, "traffic", "longdoc-qa-laguna.json")
     mimo = cases.load(cases.BENCH, "traffic", "longdoc-qa-mimo-v2.json")
     assert mix["kind"] == "serve_closed_checked"
@@ -59,7 +61,7 @@ def test_manifest_entries_are_the_catalogs_row_and_the_issues_traffic():
     # every checked stream is longer than the window and wraps its ring
     assert min(chk["prompt_lengths"]) > cfg["sliding_window"]
     lists = {p["name"]: p.get("workloads", []) for p in manifest["per_layer"]}
-    assert list(lists)[-2:] == GATE_READERS
+    assert list(lists)[-11:-9] == GATE_READERS
     assert all(lists[n] == [CELL] for n in GATE_READERS)
     for name in WINDOW_READERS + [
             "prefill_ms_per_ktok", "kv_pages_peak_pct", "decode_moe_ms",
@@ -69,12 +71,12 @@ def test_manifest_entries_are_the_catalogs_row_and_the_issues_traffic():
             "prefill_mfu_pct", "setup_boot_s", "setup_warm_s",
             "setup_compile_s", "setup_check_s", "decode_occupancy_window_pct",
             "engine_slot_refill_window_ms", "engine_window_tokens_per_s"]:
-        assert lists[name][-1] == CELL, name
+        assert CELL in lists[name][-2:], name
     # the whole decode step's share reads another stack's scopes
     # (benchmark/conv_trace.py): not this adapter's to serve (PERF.md, 7)
     assert CELL not in lists["decode_mfu_pct"]
     e2e = {m["name"]: m.get("workloads") for m in manifest["end_to_end"]}
-    assert e2e["batch_tokens_per_s"][-1] == CELL
+    assert e2e["batch_tokens_per_s"][-2] == CELL
 
 
 def test_the_counts_are_the_live_pairs_at_each_kinds_heads():

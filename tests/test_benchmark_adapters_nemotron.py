@@ -98,9 +98,10 @@ def test_nemotron_manifest_entries_are_the_catalogs_row_and_the_issues_cell():
         assert (new["layer"], new["moves"], new["source"],
                 new["workloads"]) == (
             "model step (prefill)", "batch_tokens_per_s", "device_trace",
-            # (PR 62's cell reports the whole prefill's share too)
-            [NEMOTRON_CELL] + ["serve-longdoc-laguna"] * (
-                name == "prefill_mfu_pct"))
+            # (PR 62's and PR 65's cells report the whole prefill's share
+            # too)
+            [NEMOTRON_CELL] + ["serve-longdoc-laguna", "serve-longdoc-sala"]
+            * (name == "prefill_mfu_pct"))
     mine = [n for n, cells in lists.items() if NEMOTRON_CELL in cells]
     assert set(mine) == set(NEMOTRON_SERVED) | set(NEMOTRON_NEW) \
         | TIMELINE_READERS_OF_A_BATCH_CELL
